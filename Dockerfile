@@ -18,7 +18,8 @@ COPY scripts/ scripts/
 COPY csrc/ csrc/
 COPY torchbeast_tpu/ torchbeast_tpu/
 COPY tests/ tests/
-COPY bench.py __graft_entry__.py ./
+COPY benchmarks/ benchmarks/
+COPY bench.py chip_smoke.py __graft_entry__.py ./
 
 RUN bash scripts/build_native.sh
 

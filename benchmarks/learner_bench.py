@@ -45,7 +45,7 @@ documented:
   passes over master-sized arrays) where the compiled program fuses it
   into ~2 HBM passes on both sides. The on-chip compiled ratio is
   therefore >= the reported one; the fwd_bwd row isolates the
-  memory-bound section the roofline evidence (mfu_ablation.md) pinned.
+  memory-bound section.
 - Under supersteps the lowered scan body is counted ONCE, so a K-row's
   figure is directly per-update (plus the K-stack staging operands).
 

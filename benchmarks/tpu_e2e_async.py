@@ -23,6 +23,12 @@ the Python runtime over sockets, then the C++ runtime over shm rings
 the native/python steady-SPS ratio, gated >= 1.5x at >= 8 actors. The
 verdict is written to --artifact (default
 benchmarks/artifacts/native_parity_bench.json).
+
+One process per chip: this launcher never initialises a JAX backend.
+Each leg's polybeast child is the one process that holds the chip, and
+legs run strictly one after another. `--fleet_hosts N` starts N drivers
+at once, which one chip cannot serve — it is a forced-CPU lane and
+requires `--xla_device_count`.
 """
 
 import argparse
@@ -398,6 +404,12 @@ def main():
                          "the write; --compare_native only).")
     ap.add_argument("--timeout_s", type=int, default=1500)
     args = ap.parse_args()
+    if args.fleet_hosts >= 2 and not args.xla_device_count:
+        ap.error(
+            "--fleet_hosts starts several drivers at once; a chip "
+            "belongs to one process, so pass --xla_device_count N to "
+            "run the fleet lane on forced CPU devices"
+        )
 
     if not args.compare_native:
         summary = run_config(

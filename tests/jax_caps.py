@@ -1,191 +1,24 @@
-"""Capability probes for jax-version-dependent test families.
+"""Capability probes that are False somewhere this suite runs.
 
-The attention/ulysses/pp/mosaic suites exercise APIs that moved or grew
-between jax releases (top-level `jax.shard_map`, the `check_vma` kwarg,
-string partition specs, Mosaic lowering coverage). On a container whose
-jax predates them, those tests used to FAIL at call time — burning
-tier-1 signal on version skew instead of numerics. Each probe here
-detects one capability so the owning test module can
-`pytest.mark.skipif` on it: unavailable features SKIP (visible,
-countable, reversible when the container's jax moves), and the suites'
-numerics are untouched wherever the capability exists.
+Every probe of JAX-version skew that used to live here (top-level
+`jax.shard_map`, `check_vma`, Mosaic's stop_gradient rule, a sound SPMD
+partitioner for dense TP) is True on the one installation there is
+(jax 0.9.0), so those probes left together with their skipifs. The
+ring-attention family's probe — NamedSharding taking a bare-string spec
+— is False on jax 0.9.0 and named a real gap; ops/attention.py now
+builds PartitionSpecs, so that family runs unconditionally too.
+
+What remains depends on how the process was started, not on the JAX
+version.
 """
-
-import numpy as np
-
-
-def has_top_level_shard_map() -> bool:
-    """`from jax import shard_map` (moved out of jax.experimental in
-    newer jax; ops/attention.py's ulysses path imports it there)."""
-    try:
-        from jax import shard_map  # noqa: F401
-    except ImportError:
-        return False
-    return True
-
-
-def shard_map_supports_check_vma() -> bool:
-    """shard_map(check_vma=...) (parallel/pp.py's GPipe schedule passes
-    it; older jax calls it check_rep or lacks it)."""
-    if not has_top_level_shard_map():
-        return False
-    import inspect
-
-    from jax import shard_map
-
-    fn = getattr(shard_map, "shard_map", shard_map)
-    try:
-        return "check_vma" in inspect.signature(fn).parameters
-    except (TypeError, ValueError):  # pragma: no cover
-        return False
-
-
-def namedsharding_accepts_str_specs() -> bool:
-    """NamedSharding(mesh, "axis") with a bare-string spec (newer jax
-    canonicalizes strings to PartitionSpec; ops/attention.py's ring
-    path relies on it)."""
-    import jax
-    from jax.sharding import Mesh, NamedSharding
-
-    try:
-        mesh = Mesh(np.asarray(jax.devices("cpu")[:1]), ("x",))
-        NamedSharding(mesh, "x")
-    except TypeError:
-        return False
-    except Exception:  # pragma: no cover - no devices etc.
-        return False
-    return True
-
-
-def _dense_tp_grad_repro(use_shardy: bool) -> bool:
-    """Run the minimal dense-TP grad-path program (the
-    RecurrentPolicyHead pattern: two hidden layers, the second's kernel
-    sharded on its input dim, trunk features concatenated with
-    reward/one-hot columns, jax.grad over the lot) under the requested
-    partitioner and compare against the unsharded reference. Returns
-    True when loss AND grads match — i.e. the partitioner is SOUND for
-    parallel/tp.dense_kernel_shardings programs."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    devices = jax.devices()
-    if len(devices) < 2:
-        return False
-    knob = "jax_use_shardy_partitioner"
-    if use_shardy and not hasattr(jax.config, knob):
-        return False
-    old = getattr(jax.config, knob, None)
-    try:
-        if old is not None:
-            jax.config.update(knob, bool(use_shardy))
-        mesh = Mesh(np.asarray(devices[:2]).reshape(1, 2),
-                    ("data", "model"))
-        rng = np.random.default_rng(0)
-        t, b, d, h, a = 5, 4, 16, 128, 4
-        frame = rng.standard_normal((t, b, d)).astype(np.float32)
-        reward = rng.standard_normal((t, b)).astype(np.float32)
-        act = rng.integers(0, a, (t, b)).astype(np.int32)
-        w1 = rng.standard_normal((d, h)).astype(np.float32) * 0.1
-        w2 = rng.standard_normal((h, h)).astype(np.float32) * 0.1
-        wp = rng.standard_normal((h + 1 + a, a)).astype(np.float32) * 0.1
-
-        def f(frame, reward, act, w1, w2, wp):
-            x = jax.nn.relu(frame.reshape(t * b, d) @ w1)
-            x = jax.nn.relu(x @ w2)
-            z = jnp.concatenate(
-                [
-                    x,
-                    jnp.clip(reward, -1, 1).reshape(t * b, 1),
-                    jax.nn.one_hot(act.reshape(t * b), a),
-                ],
-                axis=-1,
-            )
-            return ((z @ wp) ** 2).sum()
-
-        args = (frame, reward, act, w1, w2, wp)
-        ref_l = f(*args)
-        ref_g = jax.grad(f, argnums=4)(*args)
-        bsh = NamedSharding(mesh, P(None, "data"))
-        row = NamedSharding(mesh, P("model", None))
-        repl = NamedSharding(mesh, P())
-        shardings = (bsh, bsh, bsh, repl, row, repl)
-        run = jax.jit(
-            lambda *a: jax.value_and_grad(f, argnums=4)(*a),
-            in_shardings=shardings,
-        )
-        loss, grad = run(
-            *[jax.device_put(x, s) for x, s in zip(args, shardings)]
-        )
-        return bool(
-            np.allclose(float(ref_l), float(loss), rtol=1e-4)
-            and np.allclose(np.asarray(ref_g), np.asarray(grad),
-                            rtol=1e-3, atol=1e-5)
-        )
-    except Exception:  # pragma: no cover - partitioner API churn
-        return False
-    finally:
-        if old is not None:
-            jax.config.update(knob, old)
-
-
-def legacy_spmd_dense_tp_grad_sound() -> bool:
-    """Whether the default (legacy GSPMD) partitioner correctly
-    compiles dense-TP grad programs. On this container it silently
-    computes ~40%-wrong losses/grads (the five-PR test_dp_plus_tp
-    failure; parallel/tp.py module docstring has the full story) — so
-    dense-TP consumers compile under tp.shardy_partitioner(). When this
-    probe turns True the workaround is droppable."""
-    return _dense_tp_grad_repro(use_shardy=False)
-
-
-def shardy_spmd_dense_tp_grad_sound() -> bool:
-    """Whether the Shardy partitioner exists and correctly compiles
-    dense-TP grad programs — the workaround path test_dp_plus_tp and
-    dryrun_multichip rely on."""
-    return _dense_tp_grad_repro(use_shardy=True)
 
 
 def has_multi_device_cpu(n: int = 2) -> bool:
     """Whether this process sees >= n jax devices. tests/conftest.py
     forces `--xla_force_host_platform_device_count=8` before jax
-    initializes; on a jax/XLA where that flag is unsupported (or was
-    overridden) the process sees a single device and the Sebulba
-    device-split suites (tests/test_sebulba.py) SKIP visibly instead
-    of failing — same contract as the other probes here."""
+    initializes; a caller that overrode XLA_FLAGS with a smaller count
+    sees fewer, and the Sebulba device-split suites
+    (tests/test_sebulba.py) SKIP visibly instead of failing."""
     import jax
 
-    try:
-        return len(jax.devices()) >= n
-    except Exception:  # pragma: no cover - backend init failure
-        return False
-
-
-def mosaic_lowers_stop_gradient() -> bool:
-    """Client-side Mosaic (Pallas->TPU) lowering of a kernel containing
-    stop_gradient — the construct ops/pallas_attention.py uses; some
-    jax versions have no Mosaic lowering rule for it."""
-    import jax
-    import jax.export
-    import jax.numpy as jnp
-    from jax import lax
-
-    try:
-        from jax.experimental import pallas as pl
-
-        def kernel(x_ref, o_ref):
-            o_ref[:] = lax.stop_gradient(x_ref[:]) * 2.0
-
-        def run(x):
-            return pl.pallas_call(
-                kernel,
-                out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
-            )(x)
-
-        jax.export.export(jax.jit(run), platforms=["tpu"])(
-            jax.ShapeDtypeStruct((8, 128), jnp.float32)
-        )
-    except Exception:
-        return False
-    return True
+    return len(jax.devices()) >= n

@@ -9,24 +9,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from tests import jax_caps
-
 from torchbeast_tpu.ops.attention import (
     causal_attention,
     ring_attention,
     segment_ids_from_done,
 )
 from torchbeast_tpu.parallel import create_mesh
-
-# ring_attention needs the top-level jax.shard_map AND bare-string
-# partition specs (newer jax canonicalizes them); skip the ring family
-# on version skew instead of failing before any numerics run.
-requires_ring_shard_map = pytest.mark.skipif(
-    not (jax_caps.has_top_level_shard_map()
-         and jax_caps.namedsharding_accepts_str_specs()),
-    reason="this jax lacks top-level shard_map / string partition "
-           "specs (ops/attention.ring_attention requires both)",
-)
 
 B, T, H, D = 2, 16, 4, 8  # T divisible by the 8-way ring
 
@@ -71,7 +59,6 @@ def test_segment_mask_blocks_cross_episode():
 
 
 @pytest.mark.parametrize("with_segments", [False, True])
-@requires_ring_shard_map
 def test_ring_matches_dense(with_segments):
     mesh = create_mesh(8)
     q, k, v = make_qkv()
@@ -96,7 +83,6 @@ def test_ring_matches_dense(with_segments):
 
 
 @pytest.mark.slow
-@requires_ring_shard_map
 def test_ring_gradients_match_dense():
     mesh = create_mesh(8)
     q, k, v = make_qkv(seed=3)
@@ -116,7 +102,6 @@ def test_ring_gradients_match_dense():
         )
 
 
-@requires_ring_shard_map
 def test_ring_long_sequence():
     # 512 tokens over the 8-way ring: 64-token blocks, no full [T, T]
     # materialization per device.
@@ -131,7 +116,6 @@ def test_ring_long_sequence():
 
 
 @pytest.mark.parametrize("with_segments", [False, True])
-@requires_ring_shard_map
 def test_zigzag_ring_matches_dense(with_segments):
     mesh = create_mesh(8)
     q, k, v = make_qkv(seed=5)
@@ -156,7 +140,6 @@ def test_zigzag_ring_matches_dense(with_segments):
 
 
 @pytest.mark.slow
-@requires_ring_shard_map
 def test_zigzag_ring_gradients_match_dense():
     mesh = create_mesh(8)
     q, k, v = make_qkv(seed=6)
@@ -181,7 +164,6 @@ def test_zigzag_ring_gradients_match_dense():
 
 @pytest.mark.parametrize("with_segments", [False, True])
 @pytest.mark.slow
-@requires_ring_shard_map
 def test_zigzag_ring_long_sequence(with_segments):
     # T=512 on the 8-way mesh -> chunk size 32: exercises the intra-chunk
     # tril-and-segment interaction at c > 1 (T=16 degenerates to c=1).

@@ -9,8 +9,6 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from tests import jax_caps
-
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import create_model
 from torchbeast_tpu.parallel import (
@@ -101,29 +99,12 @@ def test_parallel_update_matches_single_device(setup):
 
 def test_dp_plus_tp_update_matches_single_device(setup):
     """(data=4, model=2) mesh: dense kernels sharded over the model axis,
-    batch over data — numerics must match the single-device update.
-
-    Compiled under tp.shardy_partitioner(): the legacy GSPMD
-    partitioner on this container MIScompiles the dense-TP grad path
-    (~40%-wrong loss — the failure that survived PRs 8-12; root cause
-    and minimal repro in parallel/tp.py's module docstring and
-    jax_caps._dense_tp_grad_repro). The skipif drops out the moment
-    either partitioner handles the pattern."""
+    batch over data — numerics must match the single-device update."""
     from torchbeast_tpu.models import create_model
     from torchbeast_tpu.parallel import (
         dense_kernel_shardings,
         place_params,
-        shardy_partitioner,
     )
-
-    if not (
-        jax_caps.shardy_spmd_dense_tp_grad_sound()
-        or jax_caps.legacy_spmd_dense_tp_grad_sound()
-    ):  # pragma: no cover - this container has a sound shardy
-        pytest.skip(
-            "neither SPMD partitioner compiles dense-TP grad programs "
-            "correctly on this jax (see parallel/tp.py)"
-        )
 
     model = create_model("mlp", num_actions=A)
     batch = make_batch()
@@ -145,16 +126,15 @@ def test_dp_plus_tp_update_matches_single_device(setup):
         not s.is_fully_replicated
         for s in jax.tree_util.tree_leaves(shardings)
     )
-    with shardy_partitioner():
-        par = make_parallel_update_step(
-            model, optimizer, hp, mesh, param_shardings=shardings
-        )
-        params_s = place_params(
-            mesh, jax.tree_util.tree_map(jnp.copy, params), shardings
-        )
-        opt_s = optimizer.init(params_s)
-        batch_s, _ = shard_batch(mesh, batch, ())
-        p2, _, stats2 = par(params_s, opt_s, batch_s, ())
+    par = make_parallel_update_step(
+        model, optimizer, hp, mesh, param_shardings=shardings
+    )
+    params_s = place_params(
+        mesh, jax.tree_util.tree_map(jnp.copy, params), shardings
+    )
+    opt_s = optimizer.init(params_s)
+    batch_s, _ = shard_batch(mesh, batch, ())
+    p2, _, stats2 = par(params_s, opt_s, batch_s, ())
 
     np.testing.assert_allclose(
         float(stats1["total_loss"]), float(stats2["total_loss"]), rtol=2e-4

@@ -9,21 +9,10 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
-from tests import jax_caps
-
 from torchbeast_tpu.parallel.pp import (
     pipeline_apply,
     stack_stages,
     stage_param_shardings,
-)
-
-# The GPipe shard_map passes check_vma= (newer jax); running the
-# schedule on an older jax TypeErrors before any numerics — skip those
-# tests on version skew (validation-only tests still run).
-requires_pipeline_shard_map = pytest.mark.skipif(
-    not jax_caps.shard_map_supports_check_vma(),
-    reason="this jax's shard_map lacks check_vma "
-           "(parallel/pp.pipeline_apply passes it)",
 )
 
 D = 16
@@ -78,7 +67,6 @@ def _sequential(stage_params, x, carry=None, shared=None):
 
 
 @pytest.mark.parametrize("n_microbatches", [None, 8])
-@requires_pipeline_shard_map
 def test_pipeline_matches_sequential(n_microbatches):
     n_stages, B = 4, 8
     mesh = _mesh(n_stages)
@@ -96,7 +84,6 @@ def test_pipeline_matches_sequential(n_microbatches):
     np.testing.assert_allclose(y_pipe, y_seq, rtol=1e-6, atol=1e-6)
 
 
-@requires_pipeline_shard_map
 def test_pipeline_carry_and_shared():
     n_stages, B = 4, 8
     mesh = _mesh(n_stages)
@@ -121,7 +108,6 @@ def test_pipeline_carry_and_shared():
 
 
 @pytest.mark.slow
-@requires_pipeline_shard_map
 def test_pipeline_gradients_match_sequential():
     """Backprop through the schedule == backprop through the stack; the
     fill/drain bubble computations must be gradient-invisible."""
@@ -153,7 +139,6 @@ def test_pipeline_gradients_match_sequential():
     )
 
 
-@requires_pipeline_shard_map
 def test_pipeline_under_jit_with_shardings():
     """jit + explicitly placed stage params (the dryrun/driver path)."""
     n_stages, B = 4, 8
@@ -195,7 +180,6 @@ def test_pipeline_rejects_bad_microbatching():
         )
 
 
-@requires_pipeline_shard_map
 def test_multi_pass_pipeline_matches_sequential():
     """8 stages on 4 devices: the looped schedule (2 passes of the
     4-stage pipeline) must equal the sequential 8-stage tower, carries
@@ -225,7 +209,6 @@ def test_multi_pass_pipeline_matches_sequential():
 
 
 @pytest.mark.slow
-@requires_pipeline_shard_map
 def test_multi_pass_pipeline_gradients_match_sequential():
     from torchbeast_tpu.parallel.pp import pipeline_apply_multi
 

@@ -8,22 +8,11 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
-from tests import jax_caps
-
 from torchbeast_tpu.models import create_model
 from torchbeast_tpu.ops.attention import (
     causal_attention,
     segment_ids_from_done,
     ulysses_attention,
-)
-
-# ulysses_attention imports the top-level `jax.shard_map` (newer jax);
-# skip-on-unavailable instead of failing on version skew (the numerics
-# run untouched wherever the API exists).
-pytestmark = pytest.mark.skipif(
-    not jax_caps.has_top_level_shard_map(),
-    reason="this jax has no top-level jax.shard_map "
-           "(ops/attention.ulysses_attention requires it)",
 )
 
 B, T, H, D = 2, 16, 8, 4

@@ -36,6 +36,7 @@ from torchbeast_tpu.utils import (
     load_checkpoint,
     save_checkpoint,
 )
+from torchbeast_tpu.utils.backend import log_backend
 
 log = logging.getLogger("torchbeast_tpu.anakin")
 
@@ -377,15 +378,16 @@ def train(flags):
 
 def main(flags):
     _configure_logging()
+    log_backend(log, flags)
     return train(flags)
 
 
 def cli():
     from torchbeast_tpu.utils import install_preemption_handler
+    from torchbeast_tpu.utils.xla_cache import use_compile_cache
 
     install_preemption_handler()  # SIGTERM -> clean checkpointed exit
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    use_compile_cache()
     main(make_parser().parse_args())
 
 

@@ -9,7 +9,9 @@ Two implementations:
 - SerialEnvPool: in-process loop — zero IPC, right for cheap/mock envs and
   tests.
 - ProcessEnvPool: one OS process per env (spawn context so workers never
-  inherit JAX/TPU state), pipes carrying numpy arrays. Equivalent role to the
+  inherit JAX/TPU state, started with JAX pinned to the CPU platform so
+  they can never claim the chip — utils/spawn.py), pipes carrying numpy
+  arrays. Equivalent role to the
   reference's actor processes; the heavy C++ shared-memory transport arrives
   with the native runtime.
 """
@@ -21,6 +23,7 @@ from typing import Callable, Dict, List
 import numpy as np
 
 from torchbeast_tpu.envs.environment import Environment
+from torchbeast_tpu.utils.spawn import start_cpu_pinned
 
 log = logging.getLogger(__name__)
 
@@ -115,7 +118,7 @@ class ProcessEnvPool:
             target=_env_worker, args=(child, self._env_fns[i]),
             daemon=True,
         )
-        proc.start()
+        start_cpu_pinned(proc)
         child.close()
         self._parents[i] = parent
         self._procs[i] = proc

@@ -58,7 +58,7 @@ class HParams(NamedTuple):
     batch_size: int = 8
     # V-trace backward recursion: "associative" (lax.associative_scan,
     # O(log T) depth — the default; 2.56x at T=4000 and within noise at
-    # T=80, vtrace_scan_bench.md), "sequential" (lax.scan, the
+    # T=80 in a 2026-07-31 chip record), "sequential" (lax.scan, the
     # reference formulation), or "pallas" (the fused single-kernel
     # variant — TPU-compiled, interpreted elsewhere).
     vtrace_impl: str = "associative"
@@ -118,10 +118,10 @@ def _scale_by_rms_torch(
     decay: float, eps: float, state_dtype=None
 ) -> optax.GradientTransformation:
     """optax.scale_by_rms with TORCH denominator semantics:
-    g / (sqrt(v) + eps), not g / sqrt(v + eps). Used on optax < 0.2.4,
-    where rmsprop has no eps_in_sqrt knob (the two differ materially at
-    this model's eps=0.01; see google-deepmind/optax#532). Pinned
-    against torch.optim.RMSprop by test_rmsprop_matches_torch_semantics.
+    g / (sqrt(v) + eps), not g / sqrt(v + eps) (the two differ
+    materially at this model's eps=0.01; see google-deepmind/optax#532).
+    Pinned against torch.optim.RMSprop by
+    test_rmsprop_matches_torch_semantics.
 
     `state_dtype` (e.g. jnp.bfloat16) compacts the STORED second moment;
     the EMA itself is accumulated in the gradient dtype (f32) every
@@ -354,24 +354,14 @@ def _rmsprop_torch(
     learning_rate, decay: float, eps: float, momentum,
     state_dtype=None, factored: bool = False,
 ) -> optax.GradientTransformation:
-    """torch.optim.RMSprop as an optax chain. Prefers the upstream
-    rmsprop(eps_in_sqrt=False) (optax >= 0.2.4); otherwise composes the
-    identical transform from primitives that exist on 0.2.3: torch-
-    denominator RMS scaling, then momentum as a plain accumulator trace
-    (torch: buf = m*buf + update; param -= lr*buf), then LR. Compact
-    state (`state_dtype`/`factored`) always takes the composed path —
-    upstream rmsprop has no storage-dtype knob."""
-    if state_dtype is None and not factored:
-        try:
-            return optax.rmsprop(
-                learning_rate=learning_rate,
-                decay=decay,
-                eps=eps,
-                eps_in_sqrt=False,
-                momentum=momentum or None,
-            )
-        except TypeError:
-            pass
+    """torch.optim.RMSprop as an optax chain: torch-denominator RMS
+    scaling (g / (sqrt(nu) + eps)), then momentum as a plain accumulator
+    trace, then the LR (torch: buf = m*buf + update; param -= lr*buf).
+    The installed optax's own rmsprop(eps_in_sqrt=False, momentum=m)
+    applies the LR *before* the trace, which is a different optimizer
+    once the LR is scheduled — tests/test_pallas_opt.py's momentum case
+    tells them apart — so the chain is composed here for every
+    configuration, compact state (`state_dtype`/`factored`) included."""
     if factored:
         parts = [_scale_by_factored_rms_torch(decay, eps)]
     else:
@@ -385,8 +375,8 @@ def _rmsprop_torch(
 def make_optimizer(hp: HParams) -> optax.GradientTransformation:
     """torch.optim.RMSprop semantics + grad clip + linear LR decay.
 
-    torch RMSProp divides by (sqrt(v) + eps) — _rmsprop_torch expresses
-    that on every installed optax. The LR decays linearly to 0 over
+    torch RMSProp divides by (sqrt(v) + eps) and applies the LR after
+    the momentum trace — _rmsprop_torch composes exactly that. The LR decays linearly to 0 over
     total_steps env frames; each optimizer step consumes T*B frames (the
     reference's LambdaLR closure, monobeast.py:395-398).
 

@@ -14,10 +14,11 @@ each leaf is read once (grad, second moment, momentum, master), every
 intermediate lives in VMEM/registers, and exactly the new state is
 written back.
 
-Leaves run in their NATIVE shapes — no flatten/pad/reshape plumbing
-(those would lower to real pre-opt HLO ops and re-inflate the very
-bytes figure the kernel exists to shrink; the lowered accounting of
-this module is pure operand/result traffic). Leaves above a VMEM-sized
+Leaves run in their NATIVE shapes — no flatten/pad plumbing (those
+would lower to real pre-opt HLO ops and re-inflate the very bytes
+figure the kernel exists to shrink; the lowered accounting of this
+module is pure operand/result traffic). The one view change is free:
+scalars and vectors are presented as one-row matrices (_run_leaf). Leaves above a VMEM-sized
 threshold are chunked by a grid over their leading axis; everything
 else is one whole-leaf block.
 
@@ -59,10 +60,9 @@ import jax
 import jax.numpy as jnp
 import optax
 
-# Chunk leaves whose per-array block would exceed this many bytes (f32
-# accounting): with up to 7 resident arrays per kernel instance the
-# worst-case VMEM footprint stays ~14 MiB under a 16 MiB VMEM.
-_CHUNK_BYTES = 2 * 1024 * 1024
+# Mosaic's default scoped-VMEM limit is 16 MiB per kernel; leave
+# headroom for the body's elementwise temporaries.
+_VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _interpret_default() -> bool:
@@ -112,18 +112,33 @@ def _tail_kernel(refs, *, alpha, eps, momentum, max_norm, res_dtype,
         nmst_ref[:] = new_mst
 
 
-def _leaf_grid(shape) -> Optional[int]:
+def _leaf_grid(shape, n_arrays: int) -> Optional[int]:
     """Rows-per-block for leaves too big to sit whole in VMEM (None =
     whole-leaf single block, the common case). Only the leading axis
-    chunks; 4-byte accounting bounds the worst (f32) array."""
+    chunks. `n_arrays` leaf-shaped operands and results are resident at
+    once and the grid pipeline double-buffers each block, so one block
+    may take budget / (2 * n_arrays) — the flagship's [3872, 256] fc
+    kernel with momentum on (7 arrays) asked for 24 MB of scoped VMEM
+    at a flat 2 MiB per block. Bytes are counted f32 and tile-padded
+    (the last dim to 128 lanes, the one before it to 8 sublanes)."""
     if len(shape) < 2:
         return None
-    row_bytes = 4 * int(
-        functools.reduce(lambda a, b: a * b, shape[1:], 1)
+    lanes = -(-shape[-1] // 128) * 128
+    inner = shape[1:-1]
+    if inner:
+        inner = inner[:-1] + (-(-inner[-1] // 8) * 8,)
+    row_bytes = 4 * lanes * int(
+        functools.reduce(lambda a, b: a * b, inner, 1)
     )
-    if shape[0] * row_bytes <= _CHUNK_BYTES:
+    block_bytes = _VMEM_BUDGET // (2 * n_arrays)
+    if shape[0] * row_bytes <= block_bytes:
         return None
-    return max(1, _CHUNK_BYTES // max(row_bytes, 1))
+    rows = max(1, block_bytes // row_bytes)
+    if len(shape) == 2:
+        # The chunked axis is the sublane axis: a partial block must be
+        # a whole number of tiles in every dtype (16 rows for bf16).
+        rows = max(16, rows - rows % 16)
+    return rows
 
 
 def _run_leaf(
@@ -138,10 +153,14 @@ def _run_leaf(
 
     has_mom = bool(momentum)
     emit_master = res_dtype != mst.dtype
-    ndim = max(g.ndim, 1)
+    # Scalars and vectors ride as one-row matrices: Mosaic stores a 1-D
+    # bf16 vector only when its length is a multiple of 256 ("offset
+    # not aligned to sublanes" / "masked along subelements" for every
+    # bias of the flagship tree), while [1, n] compiles in both dtypes.
+    shape = g.shape if g.ndim >= 2 else (1, g.size)
+    ndim = len(shape)
     ones = (1,) * ndim
-    shape = g.shape if g.ndim else (1,)
-    leaf = lambda x: x.reshape(shape)  # noqa: E731 — 0-d -> (1,) only
+    leaf = lambda x: x.reshape(shape)  # noqa: E731
     scalars = (
         sumsq.reshape(ones).astype(jnp.float32),
         lr.reshape(ones).astype(jnp.float32),
@@ -167,7 +186,7 @@ def _run_leaf(
     if emit_master:
         out_shape.append(jax.ShapeDtypeStruct(shape, jnp.float32))
 
-    block_rows = _leaf_grid(shape)
+    block_rows = _leaf_grid(shape, len(inputs) - 2 + len(out_shape))
     if block_rows is None:
         out = pl.pallas_call(
             lambda *refs: kernel(refs),

@@ -57,7 +57,8 @@ def _kernel(x_ref, y_ref, g_ref, gx_ref, *, H, WC, C, taps=3):
     y_ref/g_ref hold the doubled grid [bn, 2Ho + 2, (2Wo + 2) * C] with a
     one-slot border (y border = +inf so it never equals x; g border = 0).
     """
-    x = x_ref[:]
+    # v5e's VPU has no bf16 compare/select: widen once on load.
+    x = x_ref[:].astype(jnp.float32)
     # Parity masks: tap (kh, kw) reaches input (h, w) iff h + 1 - kh and
     # w + 1 - kw are both even (i.e. land on an even doubled-grid slot).
     h_idx = lax.broadcasted_iota(jnp.int32, (1, H, WC), 1)
@@ -72,12 +73,14 @@ def _kernel(x_ref, y_ref, g_ref, gx_ref, *, H, WC, C, taps=3):
             # h + 1 - kh, i.e. padded row h + 2 - kh; same for lanes in
             # units of C.
             y_tap = y_ref[:, 2 - kh : 2 - kh + H,
-                          (2 - kw) * C : (2 - kw) * C + WC]
+                          (2 - kw) * C : (2 - kw) * C + WC
+                          ].astype(jnp.float32)
             g_tap = g_ref[:, 2 - kh : 2 - kh + H,
-                          (2 - kw) * C : (2 - kw) * C + WC]
+                          (2 - kw) * C : (2 - kw) * C + WC
+                          ].astype(jnp.float32)
             hit = (x == y_tap) & mh & mw
             gx = gx + jnp.where(hit, g_tap, jnp.zeros_like(g_tap))
-    gx_ref[:] = gx
+    gx_ref[:] = gx.astype(gx_ref.dtype)
 
 
 def _doubled_grid(a, H_pad_value):
@@ -94,23 +97,40 @@ def _doubled_grid(a, H_pad_value):
     return up.reshape(N, 2 * Ho + 2, (2 * Wo + 2) * C)
 
 
-# Per-block VMEM budget for choosing block_n. Mosaic's scoped-vmem
-# limit is 16 MB and the pipeline double-buffers every block, so the
-# live footprint is ~2x the block buffers plus elementwise temporaries;
-# 5 MB of single-buffered block bytes keeps the trunk stage-1 shape
-# (found OOM at 50.7 MB scoped with block_n=8 on a v5e — see
-# benchmarks/artifacts/tpu_capture_raw/pallas_smoke pre-fix) inside it.
-_VMEM_BLOCK_BUDGET = 5 * 1024 * 1024
+# Mosaic's default scoped-VMEM limit is 16 MiB per kernel; leave
+# headroom for what the model below does not see.
+_VMEM_BUDGET = 14 * 1024 * 1024
+
+# f32 temporaries the unrolled 9-tap body keeps live, in units of one
+# tile-padded [H, W*C] block (fitted to the compiler's scoped-allocation
+# sizes at the three flagship trunk stages, N=2592: 20.76M / 41.10M at
+# block_n 2 / 4 for [84, 1344], 22.49M at block_n 4 for [42, 1344]).
+_TEMP_BLOCKS = 14
 
 
-def _auto_block_n(H, WC, Ho, WoC2):
-    """Largest batch rows per block whose buffers fit the VMEM budget.
+def _tile_bytes(rows, lanes, dtype):
+    """Bytes of one [rows, lanes] VMEM buffer: Mosaic pads the last two
+    dims to the dtype's (sublane, 128) tile — (8, 128) f32, (16, 128)
+    bf16 — so 84 x 1344 f32 occupies 88 x 1408."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublane = 8 * (4 // itemsize)
+    rows = -(-rows // sublane) * sublane
+    lanes = -(-lanes // 128) * 128
+    return rows * lanes * itemsize
 
-    Bytes per batch row: x + gx ([H, WC] f32 each) and the doubled
-    y + g grids ([2Ho+2, WoC2] f32 each).
+
+def _auto_block_n(H, WC, Hd, WdC, dtype):
+    """Largest batch rows per block whose footprint fits the budget.
+
+    Per batch row: x, gx ([H, WC]) and the doubled y, g grids
+    ([Hd, WdC]) in the storage dtype, each double-buffered by the
+    pipeline, plus the body's f32 temporaries.
     """
-    per_n = 4 * (2 * H * WC + 2 * (2 * Ho + 2) * WoC2)
-    return max(1, _VMEM_BLOCK_BUDGET // per_n)
+    per_n = (
+        2 * 2 * (_tile_bytes(H, WC, dtype) + _tile_bytes(Hd, WdC, dtype))
+        + _TEMP_BLOCKS * _tile_bytes(H, WC, jnp.float32)
+    )
+    return max(1, _VMEM_BUDGET // per_n)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -119,7 +139,7 @@ def pool_bwd(x, y, g, block_n: int | None = None, interpret: bool = False):
 
     x: [N, H, W, C] pool input; y: pooled output; g: cotangent of y.
     block_n: batch rows per grid cell; None picks the largest that fits
-    the scoped-VMEM budget (big trunk shapes tile down to 1).
+    the scoped-VMEM budget (the flagship's stage-1 shape tiles down to 1).
     """
     from jax.experimental import pallas as pl
 
@@ -127,7 +147,10 @@ def pool_bwd(x, y, g, block_n: int | None = None, interpret: bool = False):
     _, Ho, Wo, _ = y.shape
     WC = W * C
     if block_n is None:
-        block_n = min(N, _auto_block_n(H, WC, Ho, (2 * Wo + 2) * C))
+        block_n = min(
+            N,
+            _auto_block_n(H, WC, 2 * Ho + 2, (2 * Wo + 2) * C, x.dtype),
+        )
 
     y_d = _doubled_grid(y, jnp.inf)
     g_d = _doubled_grid(g, 0)

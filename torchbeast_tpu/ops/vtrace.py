@@ -7,7 +7,7 @@ TPU-native formulation: the backward recursion
 is a first-order linear recurrence, so it runs by default as a
 `lax.associative_scan` over the affine maps f_t(x) = a_t x + b_t —
 O(log T) depth, 2.56x over the sequential scan at T=4000 and within
-noise at T=80 (benchmarks/artifacts/vtrace_scan_bench.md) — fused into
+noise at T=80 (2026-07-31 chip record, deleted in PR 21) — fused into
 the learner's XLA program. The reference's sequential `lax.scan`
 formulation stays available (`scan_impl="sequential"`), and a fused
 Pallas kernel variant (`"pallas"`, ops/pallas_vtrace.py) computes vs
@@ -181,8 +181,9 @@ def from_importance_weights(
       delta_t — the recursion is a first-order linear recurrence, so
       suffix composition solves it in O(log T) depth instead of O(T).
       2.56x at T=4000, within noise at the usual T<=80
-      (vtrace_scan_bench.md). Differs from sequential only by float
-      reassociation (parity matrix in tests/test_vtrace.py).
+      (2026-07-31 chip record, deleted in PR 21). Differs from
+      sequential only by float reassociation (parity matrix in
+      tests/test_vtrace.py).
     - "sequential": `lax.scan(reverse=True)` — T dependent steps, the
       reference formulation.
     - "pallas": the fused single-kernel variant (ops/pallas_vtrace.py)

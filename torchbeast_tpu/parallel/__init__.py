@@ -29,6 +29,5 @@ from torchbeast_tpu.parallel.tp import (  # noqa: F401
     dense_kernel_shardings,
     merge_param_shardings,
     place_params,
-    shardy_partitioner,
     transformer_tp_shardings,
 )

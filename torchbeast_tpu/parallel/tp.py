@@ -13,22 +13,6 @@ training loop. Two levels:
   better generic rule than the column layout it replaced in ISSUE 13
   (one all-reduce vs a gather per layer).
 
-KNOWN MISCOMPILATION (the five-PR test_dp_plus_tp numerics failure,
-root-caused in ISSUE 13): this container's LEGACY GSPMD partitioner
-miscompiles the grad path of a dense-TP'd RecurrentPolicyHead family —
-a hidden-layer kernel sharded on the dim adjacent to the trunk
-activation (either layout: column output-dim OR row input-dim) whose
-activation feeds the head's uneven `concatenate([features, reward,
-one_hot])`, under `jax.grad`, silently computes ~40%-wrong loss AND
-gradients (forward-only programs are correct; the backward's
-slice-of-concat cotangents confuse the propagation). The SHARDY
-partitioner compiles the same programs correctly. Dense-TP consumers
-therefore compile under `shardy_partitioner()` (below);
-tests/jax_caps.py carries probes for both partitioners so the
-workaround is visibly droppable when the container's XLA moves.
-Megatron TP (`transformer_tp_shardings`) is unaffected: its row/column
-pairs keep activations sharded between the pair and nothing concats on
-a sharded dim.
 - `transformer_tp_shardings`: Megatron-style COLUMN/ROW pairing for the
   transformer tower — q/k/v projections and the FFN up-projection are
   column-parallel (heads / d_ff sharded), the attention out-projection
@@ -43,33 +27,10 @@ param_shardings=...), polybeast's --tensor_parallel, and
 __graft_entry__.dryrun_multichip on a (data x model) mesh.
 """
 
-import contextlib
 from typing import Any
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-@contextlib.contextmanager
-def shardy_partitioner():
-    """Compile the programs traced/compiled inside this context under
-    XLA's Shardy partitioner. Dense-TP update steps REQUIRE it on this
-    container: the legacy GSPMD partitioner miscompiles their grad path
-    (module docstring has the exact pattern; jax_caps probes both
-    partitioners). Scoped — only compiles happening inside the context
-    switch, so the rest of the process keeps the default partitioner.
-    A jax without the knob is a no-op (its default partitioner is then
-    whatever that jax ships)."""
-    name = "jax_use_shardy_partitioner"
-    if not hasattr(jax.config, name):  # pragma: no cover - future jax
-        yield
-        return
-    old = getattr(jax.config, name)
-    jax.config.update(name, True)
-    try:
-        yield
-    finally:
-        jax.config.update(name, old)
 
 
 def dense_kernel_shardings(mesh: Mesh, params: Any) -> Any:

@@ -49,6 +49,7 @@ from torchbeast_tpu.utils import (
     load_checkpoint,
     save_checkpoint,
 )
+from torchbeast_tpu.utils.backend import log_backend
 
 log = logging.getLogger("torchbeast_tpu.polybeast")
 
@@ -580,6 +581,8 @@ def train(flags):
         # No-ops (with a log line) when no coordinator is configured by
         # flag or TORCHBEAST_COORDINATOR env.
         initialize_distributed(flags.coordinator_address)
+    # After the rendezvous: asking for devices initialises the backend.
+    log_backend(log, flags)
     proc_count = jax.process_count()
     proc_id = jax.process_index()
     # ONE host identity for every host-scoped convention below (xpid
@@ -2563,10 +2566,10 @@ def main(flags):
 
 def cli():
     from torchbeast_tpu.utils import install_preemption_handler
+    from torchbeast_tpu.utils.xla_cache import use_compile_cache
 
     install_preemption_handler()  # SIGTERM -> clean checkpointed exit
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    use_compile_cache()
     main(make_parser().parse_args())
 
 

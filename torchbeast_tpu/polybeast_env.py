@@ -16,6 +16,7 @@ import time
 
 from torchbeast_tpu import telemetry
 from torchbeast_tpu.resilience.backoff import Backoff
+from torchbeast_tpu.utils.spawn import start_cpu_pinned
 
 log = logging.getLogger("torchbeast_tpu.polybeast_env")
 
@@ -225,7 +226,7 @@ class ServerSupervisor:
             args=(self._env_name, address, self._native, seed_base),
             daemon=True,
         )
-        p.start()
+        start_cpu_pinned(p)
         # beastlint: disable=RACE  single-writer map: the constructor fills every slot before start_watch() creates the watcher (Thread.start publishes); afterwards _spawn runs only on the watcher thread
         self._spawned_at[i] = time.monotonic()
         return p

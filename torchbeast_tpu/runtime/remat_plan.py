@@ -53,9 +53,9 @@ log = logging.getLogger(__name__)
 SETTING_NAMES = {"none": False, "front": "front", "all": True}
 _SETTING_SPELLING = {False: "none", "front": "front", True: "all"}
 
-# Default budget when --hbm_budget_gb is 0/unset and the device reports
-# no memory limit: a v5e's 16 GB minus the runtime reserve — the chip
-# the committed roofline evidence (BENCH_r05) was measured on.
+# Budget the CPU backend plans against when --hbm_budget_gb is 0/unset
+# (it reports no memory limit): a v5e's 16 GB minus the runtime reserve.
+# Accelerators are never given this number (default_budget_bytes).
 DEFAULT_BUDGET_GB = 15.75
 
 
@@ -281,17 +281,22 @@ def plan_remat(
 
 
 def default_budget_bytes() -> float:
-    """--hbm_budget_gb unset: the device's own limit when it reports
-    one, else the v5e envelope the roofline work targets."""
-    try:
-        import jax
+    """--hbm_budget_gb unset: the device's own `bytes_limit`. An
+    accelerator that reports none raises — a plan fitted to an assumed
+    envelope would be a guess about a chip nobody named. The CPU
+    backend has no device memory to report; it keeps the v5e-sized
+    default so the CPU tests plan against a stable number."""
+    import jax
 
-        stats = jax.devices()[0].memory_stats()
-        limit = (stats or {}).get("bytes_limit")
-        if limit:
-            return float(limit)
-    except Exception:  # pragma: no cover - backend without stats
-        log.debug("device memory_stats unavailable", exc_info=True)
+    device = jax.devices()[0]
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if limit:
+        return float(limit)
+    if device.platform != "cpu":
+        raise RuntimeError(
+            f"{device.device_kind} reports no memory bytes_limit; pass "
+            "--hbm_budget_gb to plan rematerialization on it"
+        )
     return DEFAULT_BUDGET_GB * (1 << 30)
 
 

@@ -1,42 +1,36 @@
-"""Per-host-keyed XLA compile-cache location.
+"""Where the persistent XLA compile cache lives.
 
-XLA:CPU AOT cache entries encode the compiling machine's ISA features; a
-cache directory shared across heterogeneous hosts (container images move)
-makes XLA load foreign AOT results and risk SIGILL. Key the directory by
-the host's CPU flags so each machine population gets its own cache while
-repeat runs on the same host still skip recompiles.
+`JAX_COMPILATION_CACHE_DIR` decides when it is set: JAX reads that
+variable itself, so nothing is configured in code and whoever launches
+the program can place the cache. Otherwise the cache is one fixed
+directory inside the checkout. The directory is part of what makes an
+entry findable again, so it never depends on the host, the user's home,
+a pid or a temp name — every entry point of one checkout (drivers,
+bench.py, chip_smoke.py, the tests, the benchmark scripts) shares one
+set of compiles.
+
+The directory is listed in .gitignore, .dockerignore and
+.chiprunignore: a copied checkout starts cold instead of loading XLA:CPU
+entries compiled for another machine's ISA.
 """
 
-import hashlib
 import os
-import platform as platform_mod
+
+import jax
+
+IN_CHECKOUT_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
 
 
-def host_keyed_cache_dir(prefix: str = "torchbeast_tpu_xla") -> str:
-    # Key by ISA flags AND the CPU identity lines (model name / family /
-    # model / stepping): LLVM tuning is derived from the CPU *model*,
-    # not the flag list, so two hosts with identical cpuinfo flags can
-    # still produce mutually-foreign AOT entries. Note the loader's
-    # "+prefer-no-gather is not supported on the host machine ... could
-    # lead to SIGILL" warning is NOT a reliable foreignness signal: the
-    # prefer-no-* entries are LLVM tuning preferences that appear in the
-    # stored compile-feature list but never in the loader's host-feature
-    # list, so that warning fires even when reloading entries compiled
-    # minutes earlier on this same host (observed 2026-07-30). The wider
-    # key guards against real model-level drift; it cannot (and does not
-    # try to) silence that warning. Hostname stays out — it would bust
-    # the cache on pod churn without adding any SIGILL protection.
-    wanted = ("flags", "model name", "cpu family", "model", "stepping")
-    fingerprint = ""
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith(wanted):
-                    fingerprint += line
-                if line.strip() == "":
-                    break  # first core only; they are homogeneous
-    except OSError:
-        pass
-    fingerprint += platform_mod.machine()
-    key = hashlib.sha1(fingerprint.encode()).hexdigest()[:10]
-    return os.path.expanduser(f"~/.cache/{prefix}_{key}")
+def use_compile_cache() -> str:
+    """Point JAX's persistent compile cache at its one place; returns
+    the directory in use."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", IN_CHECKOUT_DIR)
+    return IN_CHECKOUT_DIR
