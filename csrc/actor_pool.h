@@ -162,6 +162,12 @@ class ActorPool {
     return t;
   }
 
+  // Interval aggregate (resets on read, like the batcher's histograms)
+  // of actor.env_rtt_s: send(action) to the return of recv_step, i.e.
+  // the wire both ways plus the env server's step. With
+  // actor.request_rtt_s it makes up an actor's whole step.
+  HistSnapshot env_rtt_snapshot() { return env_rtt_s_.snapshot(true); }
+
   // Blocks until every loop exits; rethrows the first error.
   void run() {
     std::vector<std::thread> threads;
@@ -451,10 +457,14 @@ class ActorPool {
                          wire::ValueNest(wire::Value::of_string("action")));
       action_msg.emplace("action",
                          wire::ValueNest(wire::Value::of_int(action)));
+      auto sent_at = std::chrono::steady_clock::now();
       bytes_down_.fetch_add(
           static_cast<int64_t>(sock->send(wire::ValueNest(std::move(action_msg)))));
 
       env_outputs = recv_step(sock.get());
+      env_rtt_s_.observe(std::chrono::duration<double>(
+                             std::chrono::steady_clock::now() - sent_at)
+                             .count());
       ++(*progress);
       count_.fetch_add(1);
       rollout.push_back({env_outputs, agent_outputs});
@@ -541,6 +551,7 @@ class ActorPool {
   std::atomic<int64_t> dead_{0};  // retired actor loops (live_actors())
   std::atomic<int64_t> bytes_up_{0};
   std::atomic<int64_t> bytes_down_{0};
+  HistAccum env_rtt_s_;  // send(action) -> recv_step returned
   std::unique_ptr<FaultHooks> fault_hooks_;  // non-null only when armed
   mutable std::mutex error_mu_;
   std::exception_ptr first_error_;  // guarded-by: error_mu_

@@ -1419,6 +1419,15 @@ PyObject* pool_telemetry(PyActorPool* self, PyObject*) {
       static_cast<long long>(t.ring_recheck_wakeups));
 }
 
+// Interval histograms of the actor loops' own stages, keyed by the
+// registry series they fold into (NativeTelemetryFolder). Kept apart
+// from telemetry(), whose values are all cumulative scalars.
+PyObject* pool_stage_histograms(PyActorPool* self, PyObject*) {
+  PyObject* env_rtt = hist_to_py(self->pool->env_rtt_snapshot());
+  if (!env_rtt) return nullptr;
+  return Py_BuildValue("{s:N}", "actor.env_rtt_s", env_rtt);
+}
+
 PyObject* pool_first_error_message(PyActorPool* self, PyObject*) {
   std::string msg = self->pool->first_error_message();
   if (msg.empty()) Py_RETURN_NONE;
@@ -1459,6 +1468,9 @@ PyMethodDef pool_methods[] = {
      METH_VARARGS | METH_KEYWORDS, nullptr},
     {"telemetry", reinterpret_cast<PyCFunction>(pool_telemetry),
      METH_NOARGS, nullptr},
+    {"stage_histograms",
+     reinterpret_cast<PyCFunction>(pool_stage_histograms), METH_NOARGS,
+     nullptr},
     {nullptr, nullptr, 0, nullptr}};
 
 PyGetSetDef pool_getset[] = {
@@ -2062,9 +2074,10 @@ PyMODINIT_FUNC PyInit__tbt_core(void) {
   PyModule_AddObject(module, "ShedError", ShedErrorError);
   // Extension API generation (runtime/native.py REQUIRED_API_VERSION):
   // 1 = the ISSUE 14 shed protocol; 2 = the ISSUE 16 serving plane
-  // (routers, continuous batching, record_policy_lag). The default-on
-  // native runtime refuses stale builds instead of silently serving
-  // central-only without admission control.
-  PyModule_AddIntConstant(module, "API_VERSION", 2);
+  // (routers, continuous batching, record_policy_lag); 3 = ISSUE 25's
+  // ActorPool.stage_histograms. The default-on native runtime refuses
+  // stale builds instead of silently serving central-only without
+  // admission control.
+  PyModule_AddIntConstant(module, "API_VERSION", 3);
   return module;
 }

@@ -104,11 +104,10 @@ def test_timings_mean_and_summary():
 
     t = Timings()
     for _ in range(3):
-        t.reset()
-        time.sleep(0.01)
-        t.time("a")
-        time.sleep(0.02)
-        t.time("b")
+        with t.section("a"):
+            time.sleep(0.01)
+        with t.section("b"):
+            time.sleep(0.02)
     means = t.means()
     assert 0.005 < means["a"] < 0.05
     assert means["b"] > means["a"]

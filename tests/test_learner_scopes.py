@@ -1,0 +1,70 @@
+"""The update step's parts that no Flax module names carry
+`jax.named_scope`s, so a device trace can be split by them: the names
+reach the compiled HLO's `op_name` metadata (ISSUE 25)."""
+
+import re
+
+import jax
+import optax
+import pytest
+
+from tests.test_learner import make_batch
+from torchbeast_tpu import learner as learner_lib
+from torchbeast_tpu.models import create_model
+
+SCOPES = ("vtrace", "loss_terms", "optimizer", "grad_norm")
+
+
+def _in_scope(op_name, scope):
+    """Under jax.grad a scope shows as `jvp(<scope>)` and
+    `transpose(jvp(<scope>))`; outside it, bare."""
+    return re.search(rf"[/(]{scope}[/)]", op_name + "/") is not None
+
+
+def _op_names(optimizer):
+    model = create_model("shallow", num_actions=3)
+    batch = make_batch()
+    params = model.init(
+        {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
+        batch, (),
+    )
+    update_step = learner_lib.make_update_step(
+        model, optimizer, learner_lib.HParams(), donate=False
+    )
+    compiled = update_step.lower(
+        params, optimizer.init(params), batch, ()
+    ).compile()
+    return re.findall(r'op_name="([^"]+)"', compiled.as_text())
+
+
+@pytest.fixture(scope="module")
+def op_names():
+    """Plain SGD: the drivers' optimizer clips by the global norm, and
+    XLA merges that identical computation with `grad_norm`'s, keeping
+    the optimizer's name (see the last test)."""
+    return _op_names(optax.sgd(0.1))
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_scope_reaches_the_compiled_hlo(op_names, scope):
+    inside = [n for n in op_names if _in_scope(n, scope)]
+    assert inside, f"no compiled op carries the scope {scope!r}"
+
+
+def test_scopes_do_not_swallow_the_model(op_names):
+    """The model's own ops keep their Flax module names: the scopes wrap
+    only what follows the forward pass."""
+    in_scopes = [
+        n for n in op_names if any(_in_scope(n, s) for s in SCOPES)
+    ]
+    assert 0 < len(in_scopes) < len(op_names)
+    assert any("AtariNet" in n for n in op_names)
+
+
+def test_the_drivers_optimizer_keeps_three_scopes():
+    """With the drivers' optimizer the clip's norm and the reported one
+    are one computation after XLA's CSE: `grad_norm` may be absent from
+    the compiled program, the other three are there."""
+    names = _op_names(learner_lib.make_optimizer(learner_lib.HParams()))
+    for scope in ("vtrace", "loss_terms", "optimizer"):
+        assert any(_in_scope(n, scope) for n in names), scope

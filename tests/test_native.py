@@ -815,6 +815,13 @@ def test_native_telemetry_fold():
                 "ring_recheck_wakeups": self.rechecks,
             }
 
+        def stage_histograms(self):
+            """Interval snapshots (reset on read), empty here."""
+            return {"actor.env_rtt_s": {
+                "buckets": {}, "total": 0.0, "total_sq": 0.0,
+                "min": 0.0, "max": 0.0,
+            }}
+
     fake_pool = FakePool()
     registry = MetricsRegistry()
     folder = NativeTelemetryFolder(

@@ -483,18 +483,27 @@ class TestHotPathPurity:
 
         reg = telemetry.get_registry()
         tracer = telemetry.get_tracer()
-        with jax.transfer_guard("disallow"):
-            for _ in range(5):
-                with tracer.span("hot.step", cat="test"):
-                    out = table.step(slots, advance, env)
-                    fetched = table.fetch(out, 2)
-                reg.counter("hot.steps").inc()
-                reg.histogram("hot.lat_s").observe(0.001)
-                reg.gauge("hot.depth").set(1)
-                st = tracer.stage("hot.req")
-                st.stamp("reply")
-                st.finish()
-            table.read_slot(0)
+        # All three sinks on, as in a driver run with --trace_path.
+        tracer.set_annotation_factory(jax.profiler.TraceAnnotation)
+        tracer.set_recording(True)
+        hot_step = tracer.span("hot.step", cat="test")
+        try:
+            with jax.transfer_guard("disallow"):
+                for _ in range(5):
+                    with hot_step:
+                        out = table.step(slots, advance, env)
+                        fetched = table.fetch(out, 2)
+                    reg.counter("hot.steps").inc()
+                    reg.histogram("hot.lat_s").observe(0.001)
+                    reg.gauge("hot.depth").set(1)
+                    st = tracer.stage("hot.req")
+                    st.stamp("reply")
+                    st.finish()
+                table.read_slot(0)
+        finally:
+            tracer.set_annotation_factory(None)
+            tracer.set_recording(False)
+            tracer.clear()
         assert np.asarray(fetched["out"]).shape == (1, 2, H)
         assert reg.counter("hot.steps").value() >= 5
 
@@ -508,9 +517,10 @@ class TestTimingsShim:
         reg = MetricsRegistry()
         t = Timings(registry=reg, prefix="driver.")
         for _ in range(20):
-            t.reset()
-            t.time("collect")
-            t.time("learn")
+            with t.section("collect"):
+                pass
+            with t.section("learn"):
+                pass
         assert set(t.means()) == {"collect", "learn"}  # unprefixed API
         snap = telemetry.snapshot(reg)
         assert {"driver.collect", "driver.learn"} <= set(
@@ -528,8 +538,8 @@ class TestTimingsShim:
         telemetry.set_enabled(False)
         try:
             t = Timings()  # private registry: --no_telemetry unaffected
-            t.reset()
-            t.time("a")
+            with t.section("a"):
+                pass
             assert t.means()["a"] >= 0.0
             assert t.histogram("a").count == 1
         finally:
@@ -562,7 +572,9 @@ class TestQueueInstrumentation:
             batch_dim=1, minimum_batch_size=1, maximum_batch_size=4,
             timeout_ms=10, telemetry_name="tq_test_batcher",
         )
-        tracer = telemetry.get_tracer()
+        # A private tracer: the process tracer keeps Chrome events
+        # only once a driver was given --trace_path.
+        tracer = Tracer()
         trace = tracer.stage("tq_test.request")
 
         def consumer():

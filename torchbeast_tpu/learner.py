@@ -16,7 +16,6 @@ torch-style RMSProp (eps outside the sqrt); LR decayed linearly to zero over
 total_steps environment frames.
 """
 
-import time
 from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
@@ -552,43 +551,49 @@ def compute_loss(
         rewards = jnp.clip(rewards, -1.0, 1.0)
     discounts = (~done).astype(jnp.float32) * hp.discounting
 
-    if hp.loss == "impact":
-        if target_net_logits_full is None:
-            raise ValueError(
-                "--loss impact requires the target network's outputs on "
-                "the batch (make_target_forward merges them in)"
+    # Named scopes for the parts no Flax module names: they reach the
+    # compiled HLO's op_name metadata, so a device trace can be split
+    # by them (vtrace, loss_terms; optimizer and grad_norm in
+    # update_body).
+    with jax.named_scope("vtrace"):
+        if hp.loss == "impact":
+            if target_net_logits_full is None:
+                raise ValueError(
+                    "--loss impact requires the target network's outputs "
+                    "on the batch (make_target_forward merges them in)"
+                )
+            pg_loss, baseline_loss = impact_policy_losses(
+                behavior_policy_logits=behavior_logits,
+                target_net_policy_logits=target_net_logits_full[:-1],
+                learner_policy_logits=target_logits,
+                actions=actions,
+                discounts=discounts,
+                rewards=rewards,
+                target_net_values=target_net_baseline_full[:-1],
+                values=values,
+                target_net_bootstrap_value=target_net_baseline_full[-1],
+                clip_epsilon=hp.impact_clip,
+                scan_impl=hp.vtrace_impl,
             )
-        pg_loss, baseline_loss = impact_policy_losses(
-            behavior_policy_logits=behavior_logits,
-            target_net_policy_logits=target_net_logits_full[:-1],
-            learner_policy_logits=target_logits,
-            actions=actions,
-            discounts=discounts,
-            rewards=rewards,
-            target_net_values=target_net_baseline_full[:-1],
-            values=values,
-            target_net_bootstrap_value=target_net_baseline_full[-1],
-            clip_epsilon=hp.impact_clip,
-            scan_impl=hp.vtrace_impl,
-        )
-    else:
-        pg_loss, baseline_loss = vtrace_policy_losses(
-            behavior_policy_logits=behavior_logits,
-            target_policy_logits=target_logits,
-            actions=actions,
-            discounts=discounts,
-            rewards=rewards,
-            values=values,
-            bootstrap_value=bootstrap_value,
-            scan_impl=hp.vtrace_impl,
-        )
-    baseline_loss = hp.baseline_cost * baseline_loss
-    # entropy_cost may be a traced scalar (the annealed schedule from
-    # make_update_step); None = the constant from hp.
-    if entropy_cost is None:
-        entropy_cost = hp.entropy_cost
-    entropy_loss = entropy_cost * compute_entropy_loss(target_logits)
-    total_loss = pg_loss + baseline_loss + entropy_loss + aux_loss
+        else:
+            pg_loss, baseline_loss = vtrace_policy_losses(
+                behavior_policy_logits=behavior_logits,
+                target_policy_logits=target_logits,
+                actions=actions,
+                discounts=discounts,
+                rewards=rewards,
+                values=values,
+                bootstrap_value=bootstrap_value,
+                scan_impl=hp.vtrace_impl,
+            )
+    with jax.named_scope("loss_terms"):
+        baseline_loss = hp.baseline_cost * baseline_loss
+        # entropy_cost may be a traced scalar (the annealed schedule
+        # from make_update_step); None = the constant from hp.
+        if entropy_cost is None:
+            entropy_cost = hp.entropy_cost
+        entropy_loss = entropy_cost * compute_entropy_loss(target_logits)
+        total_loss = pg_loss + baseline_loss + entropy_loss + aux_loss
 
     # Episode stats: fixed-shape aggregates (a boolean-mask gather would be
     # dynamic-shaped and unjittable); the host divides sum by count.
@@ -697,21 +702,23 @@ def update_body(model, optimizer: optax.GradientTransformation, hp: HParams):
             has_aux=True,
         )
         grads, stats = grad_fn(params)
-        updates, new_opt_state = optimizer.update(
-            grads, opt_state, params
-        )
-        # Resident-aware apply (module-level apply_updates): the
-        # bf16-resident optimizer hands back the new f32 master and the
-        # resident params are one narrowing cast; every other optimizer
-        # takes the stock optax apply.
-        params = apply_updates(params, updates, new_opt_state)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, opt_state, params
+            )
+            # Resident-aware apply (module-level apply_updates): the
+            # bf16-resident optimizer hands back the new f32 master and
+            # the resident params are one narrowing cast; every other
+            # optimizer takes the stock optax apply.
+            params = apply_updates(params, updates, new_opt_state)
         # f32 upcast before the norm reduction (no-op for f32 grads;
         # bf16-resident runs emit bf16 grad arrays).
-        stats["grad_norm"] = optax.global_norm(
-            jax.tree_util.tree_map(
-                lambda g: g.astype(jnp.float32), grads
+        with jax.named_scope("grad_norm"):
+            stats["grad_norm"] = optax.global_norm(
+                jax.tree_util.tree_map(
+                    lambda g: g.astype(jnp.float32), grads
+                )
             )
-        )
         return params, new_opt_state, stats
 
     return update_step
@@ -905,7 +912,10 @@ def instrument_update_step(update_step, registry=None, superstep_k=1):
     else changes.
     """
     reg = registry if registry is not None else telemetry.get_registry()
-    h_dispatch = reg.histogram("learner.update_dispatch_s")
+    sp_dispatch = telemetry.get_tracer().span(
+        "learner.update_dispatch",
+        histogram=reg.histogram("learner.update_dispatch_s"),
+    )
     h_per_dispatch = reg.histogram("learner.updates_per_dispatch")
     c_bytes = reg.counter("learner.batch_bytes")
     c_updates = reg.counter("learner.updates")
@@ -930,9 +940,10 @@ def instrument_update_step(update_step, registry=None, superstep_k=1):
                 (params, opt_state, batch, initial_agent_state),
                 g_hbm,
             )
-        t0 = time.perf_counter()
-        out = update_step(params, opt_state, batch, initial_agent_state)
-        h_dispatch.observe(time.perf_counter() - t0)
+        with sp_dispatch:
+            out = update_step(
+                params, opt_state, batch, initial_agent_state
+            )
         c_bytes.inc(nbytes)
         c_updates.inc(superstep_k)
         h_per_dispatch.observe(superstep_k)

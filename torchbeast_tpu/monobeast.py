@@ -867,7 +867,9 @@ def train(flags):
     # distribution, and dispatch-queue occupancy land in
     # {xpid}/telemetry.jsonl on the 5s log cadence.
     tele = telemetry.DriverTelemetry(
-        flags, plogger.paths["telemetry"], driver="monobeast"
+        flags, plogger.paths["telemetry"], driver="monobeast",
+        annotation_factory=jax.profiler.TraceAnnotation,
+        annotation_active=jax.profiler.TraceAnnotation.is_enabled,
     )
     telemetry_on = tele.enabled
     reg = tele.registry
@@ -1109,11 +1111,15 @@ def train(flags):
         )
 
         # Stage latencies (collect/learn) become driver.* histograms in
-        # the snapshot; with telemetry off, a private registry keeps the
-        # 5s log line working unchanged.
+        # the snapshot and pb: spans on the profiler's clock; with
+        # telemetry off, a private registry and tracer keep the 5s log
+        # line working unchanged.
         timings = Timings(
-            registry=reg if telemetry_on else None, prefix="driver."
+            registry=reg if telemetry_on else None, prefix="driver.",
+            tracer=telemetry.get_tracer() if telemetry_on else None,
         )
+        sp_collect = timings.section("collect")
+        sp_learn = timings.section("learn")
         # The sync trainer has no inter-thread queues; its occupancy
         # analog is the delayed-stats dispatch pipeline — update
         # batches dispatched whose stats the host has NOT yet flushed
@@ -1121,11 +1127,9 @@ def train(flags):
         # after the final flush, B/batch_size in steady state).
         h_batch_size = reg.histogram("learner.batch_size")
         g_dispatch_q = reg.gauge("dispatch_queue.depth")
-        g_sps = reg.gauge("learner.sps")
         # env vs learn throughput split (ISSUE 18): env_sps counts
         # unique environment frames; learn_sps counts frames consumed
         # by updates — env_sps x replay_reuse in steady state.
-        # learner.sps stays the env-frame rate (back-compat).
         g_env_sps = reg.gauge("learner.env_sps")
         g_learn_sps = reg.gauge("learner.learn_sps")
         reg.gauge("learner.sample_reuse").set(reuse)
@@ -1209,32 +1213,31 @@ def train(flags):
     except BaseException:
         pool.close()
         raise
-    tracer = telemetry.get_tracer()
     watchdog.start()
     try:
         while step < flags.total_steps:
-            timings.reset()
-            with tracer.span("driver.collect", cat="driver"):
+            with sp_collect:
                 batch, initial_agent_state = collector.collect()
-            timings.time("collect")
-            if flags.overlap_collect:
-                # Adopt the chain head dispatched BEFORE this collect —
-                # it had the whole collect to materialize, so the next
-                # collect's first act won't block on it; the updates
-                # dispatched below hide behind the NEXT collect the same
-                # way. (Adopting before collect() would re-create the
-                # zero-lag block: the head would be moments old.)
-                params_cell[0] = place_act(latest_params)
+            with sp_learn:
+                if flags.overlap_collect:
+                    # Adopt the chain head dispatched BEFORE this
+                    # collect — it had the whole collect to materialize,
+                    # so the next collect's first act won't block on it;
+                    # the updates dispatched below hide behind the NEXT
+                    # collect the same way. (Adopting before collect()
+                    # would re-create the zero-lag block: the head would
+                    # be moments old.)
+                    params_cell[0] = place_act(latest_params)
 
-            # Split the [T+1, num_actors] unroll into learner batches of
-            # batch_size columns; aggregate stats over ALL sub-batches
-            # (losses averaged, episode sums/counts summed). With
-            # supersteps, K consecutive sub-batches stack into one
-            # [K, T+1, batch_size] dispatch — the scan applies them in
-            # the SAME order the per-update loop would, so the update
-            # sequence (and with it every schedule tick) is identical.
-            device_stats = []
-            with tracer.span("driver.learn", cat="driver"):
+                # Split the [T+1, num_actors] unroll into learner batches
+                # of batch_size columns; aggregate stats over ALL
+                # sub-batches (losses averaged, episode sums/counts
+                # summed). With supersteps, K consecutive sub-batches
+                # stack into one [K, T+1, batch_size] dispatch — the scan
+                # applies them in the SAME order the per-update loop
+                # would, so the update sequence (and with it every
+                # schedule tick) is identical.
+                device_stats = []
                 if K > 1:
                     group = K * flags.batch_size
                     for i in range(0, B, group):
@@ -1296,12 +1299,12 @@ def train(flags):
                                 step += T * flags.batch_size
                             learn_step += T * flags.batch_size
                         maybe_refresh_target()
-            if not flags.overlap_collect:
-                params_cell[0] = place_act(latest_params)  # zero policy lag
-            if pending is not None:
-                stats = flush_stats(pending)
-            pending = (device_stats, step)
-            timings.time("learn")
+                if not flags.overlap_collect:
+                    # zero policy lag
+                    params_cell[0] = place_act(latest_params)
+                if pending is not None:
+                    stats = flush_stats(pending)
+                pending = (device_stats, step)
             watchdog.ping()
 
             now = time.time()
@@ -1312,7 +1315,6 @@ def train(flags):
                 )
                 last_log_time, last_log_step = now, step
                 last_log_learn_step = learn_step
-                g_sps.set(sps)
                 g_env_sps.set(sps)
                 g_learn_sps.set(learn_sps)
                 # Dispatched-unflushed UPDATES at this instant (the

@@ -706,7 +706,9 @@ def train(flags):
     # the monitor cadence. --no_telemetry turns the global instruments
     # into no-ops.
     tele = telemetry.DriverTelemetry(
-        flags, plogger.paths["telemetry"], driver="polybeast"
+        flags, plogger.paths["telemetry"], driver="polybeast",
+        annotation_factory=jax.profiler.TraceAnnotation,
+        annotation_active=jax.profiler.TraceAnnotation.is_enabled,
     )
     telemetry_on = tele.enabled
     reg = tele.registry
@@ -1958,15 +1960,7 @@ def train(flags):
                 lambda: slice_gauge_snapshot(reg)
             )
 
-        # Fresh health/liveness gauges on every exported line, the
-        # final shutdown write included.
         if telemetry_on:
-            g_live = reg.gauge("actor.live")
-            tele.add_tick_callback(
-                lambda: g_live.set(
-                    getattr(actors, "live_actors", lambda: -1)()
-                )
-            )
             # Per-connection SLO block (ISSUE 14 satellite) on EVERY
             # telemetry line: the p99 of actor.request_rtt_s against
             # the same target the shed gate's deadline uses, plus the
@@ -1985,11 +1979,20 @@ def train(flags):
             tele.add_tick_callback(_slo_tick)
 
         # Stage latencies (dequeue/learn) become learner.* histograms
-        # in the snapshot; with telemetry off, a private registry keeps
-        # the 5s log line working unchanged.
+        # in the snapshot and pb: spans on the profiler's clock; with
+        # telemetry off, a private registry and tracer keep the 5s log
+        # line working unchanged. Inside "learn" the learner thread's
+        # stages are spans of their own: the update dispatch
+        # (learner.instrument_update_step), publish and stats_fetch.
+        tracer = telemetry.get_tracer()
         timings = Timings(
-            registry=reg if telemetry_on else None, prefix="learner."
+            registry=reg if telemetry_on else None, prefix="learner.",
+            tracer=tracer if telemetry_on else None,
         )
+        sp_dequeue = timings.section("dequeue")
+        sp_learn = timings.section("learn")
+        sp_publish = tracer.span("learner.publish")
+        sp_stats_fetch = tracer.span("learner.stats_fetch")
 
         # Host->HBM prefetch (SURVEY §7 hard part #3): the double-buffered
         # staging thread between the learner queue and the learner thread
@@ -2085,9 +2088,12 @@ def train(flags):
 
             def flush(pending_entry):
                 device_stats, at_step, release = pending_entry
-                s = learner_lib.episode_stat_postprocess(
-                    jax.device_get(device_stats)
-                )
+                # The learner thread's wait for the device: these stats
+                # arrive when the update before this one has run.
+                with sp_stats_fetch:
+                    s = learner_lib.episode_stat_postprocess(
+                        jax.device_get(device_stats)
+                    )
                 count_host_sync()
                 if release is not None:
                     # Stats arrived => that superstep's execution (which
@@ -2101,150 +2107,162 @@ def train(flags):
                 plogger.log(s)
 
             while True:
-                # reset BEFORE blocking so 'dequeue' measures the actual wait
-                # for a prefetched batch (actor starvation shows up here).
-                timings.reset()
-                try:
-                    staged = prefetcher.get(timeout=1.0)
-                except stdlib_queue.Empty:
-                    if not prefetcher.is_alive():
-                        break
-                    continue
-                if arena is not None:
-                    (batch, initial_agent_state), release = staged
-                else:
-                    batch, initial_agent_state = staged
-                    release = None
-                # Replay handouts (BatchArena re-serving a slot under
-                # --replay_reuse) carry release.fresh == False: they
-                # advance the LEARN clock but not the env-frame clock.
-                fresh = release is None or getattr(release, "fresh", True)
-                timings.time("dequeue")
-                if target_forward is not None:
-                    # Lagged target-network forward, threaded into the
-                    # batch under the learner.TARGET_*_KEYs (computed
-                    # per dispatch: replay handouts see the CURRENT
-                    # target, same as fresh ones).
-                    _, tparams = target_store.latest()
-                    t_logits, t_base = target_forward(
-                        tparams, batch, initial_agent_state
-                    )
-                    batch = {
-                        **batch,
-                        learner_lib.TARGET_LOGITS_KEY: t_logits,
-                        learner_lib.TARGET_BASELINE_KEY: t_base,
-                    }
-                if throttle is not None:
-                    # Chaos learner_stall gate: models the busy-chip
-                    # stall at the dispatch site (no-op unarmed).
-                    throttle()
-                # Dispatch under donation_lock (NOT state_lock): opt_state is
-                # donated, so the dispatch that invalidates the old opt
-                # buffers must not race a checkpoint's device_get of them —
-                # but dispatch can block behind in-flight compute, and holding
-                # state_lock here would stall every inference thread's params
-                # read for that long. Checkpointing takes donation_lock first.
-                with donation_lock:
-                    with state_lock:
-                        params_now, opt_now = state["params"], state["opt_state"]
-                    new_params, new_opt, train_stats = update_step(
-                        params_now, opt_now, batch, initial_agent_state
-                    )
-                    # Build the host view OUTSIDE state_lock: for multi-host
-                    # sharded params this blocks on the dispatched compute +
-                    # D2H/H2D, and holding the lock for that long would stall
-                    # every inference thread's params read.
-                    infer_view = local_view(new_params, device=infer_device)
-                    with state_lock:
-                        state["params"], state["opt_state"] = new_params, new_opt
-                        state["infer_params"] = infer_view
-                        # Global frames: every host ran this collective
-                        # dispatch of superstep_k updates. Replay
-                        # handouts re-consume frames already counted —
-                        # only the learn clock moves for them.
-                        if fresh:
-                            state["step"] += (
-                                superstep_k
-                                * flags.unroll_length
-                                * flags.batch_size
-                            )
-                        state["learn_step"] += (
-                            superstep_k
-                            * flags.unroll_length
-                            * flags.batch_size
+                # 'dequeue' is the whole wait for a prefetched batch, one
+                # observation per batch (actor starvation shows up here).
+                with sp_dequeue:
+                    staged = None
+                    while staged is None:
+                        try:
+                            staged = prefetcher.get(timeout=1.0)
+                        except stdlib_queue.Empty:
+                            if not prefetcher.is_alive():
+                                break
+                if staged is None:
+                    break
+                with sp_learn:
+                    if arena is not None:
+                        (batch, initial_agent_state), release = staged
+                    else:
+                        batch, initial_agent_state = staged
+                        release = None
+                    # Replay handouts (BatchArena re-serving a slot under
+                    # --replay_reuse) carry release.fresh == False: they
+                    # advance the LEARN clock but not the env-frame clock.
+                    fresh = release is None or getattr(release, "fresh", True)
+                    if target_forward is not None:
+                        # Lagged target-network forward, threaded into the
+                        # batch under the learner.TARGET_*_KEYs (computed
+                        # per dispatch: replay handouts see the CURRENT
+                        # target, same as fresh ones).
+                        _, tparams = target_store.latest()
+                        t_logits, t_base = target_forward(
+                            tparams, batch, initial_agent_state
                         )
-                        now_step = state["step"]
-                watchdog.ping()
-                updates_done += superstep_k
-                if target_store is not None and target_store.note_update(
-                    updates_done
-                ):
-                    # Full-precision target refresh (the store copies
-                    # the tree, so the next dispatch's donation of
-                    # these params cannot invalidate the snapshot).
-                    with state_lock:
-                        params_now = state["params"]
-                    target_store.publish(updates_done, params_now)
-                if fleet_coord is not None and strategy == "wire":
-                    # DCN param composition (wire strategy): one
-                    # synchronous fleet-mean round per dispatch — the
-                    # CPU-CI equivalent of the xla strategy's in-mesh
-                    # grad all-reduce (averaging post-update params
-                    # from equal starts IS gradient averaging for the
-                    # SGD step; per-host RMSprop state stays local, the
-                    # documented approximation — fleet/coordinator.py).
-                    # None = the round degraded (timeout / fleet
-                    # shutting down): keep this host's params.
-                    with state_lock:
-                        params_now = state["params"]
-                    synced = fleet_coord.sync_params(params_now)
-                    if synced is not None:
-                        if learner_device is not None:
-                            synced = jax.device_put(
-                                synced, learner_device
-                            )
-                        elif mesh is not None:
-                            synced = replicate(mesh, synced)
-                        infer_view = local_view(
-                            synced, device=infer_device
-                        )
+                        batch = {
+                            **batch,
+                            learner_lib.TARGET_LOGITS_KEY: t_logits,
+                            learner_lib.TARGET_BASELINE_KEY: t_base,
+                        }
+                    if throttle is not None:
+                        # Chaos learner_stall gate: models the busy-chip
+                        # stall at the dispatch site (no-op unarmed).
+                        throttle()
+                    # Dispatch under donation_lock (NOT state_lock):
+                    # opt_state is donated, so the dispatch that
+                    # invalidates the old opt buffers must not race a
+                    # checkpoint's device_get of them — but dispatch can
+                    # block behind in-flight compute, and holding
+                    # state_lock here would stall every inference
+                    # thread's params read for that long. Checkpointing
+                    # takes donation_lock first.
+                    with donation_lock:
                         with state_lock:
-                            state["params"] = synced
-                            state["infer_params"] = infer_view
-                if snapshot_store is not None:
-                    # Versioned snapshot publish (serving/snapshot.py):
-                    # due when the head has run >= refresh_updates past
-                    # the last snapshot — a dropped refresh (the chaos
-                    # failure hook) stays due and retries next update.
-                    # Under the split this is the CROSS-SLICE publication
-                    # path: infer_view is the learner-mesh params
-                    # (single-process local_view is a pass-through), the
-                    # bf16 cast runs on the mesh, and each slice pulls
-                    # its device copy d2d via latest_on — zero host
-                    # round-trips (tests/test_sebulba.py pins it).
-                    if snapshot_store.note_update(updates_done):
-                        if fleet_coord is not None and not is_lead:
-                            # Remote fleet hosts serve the LEAD's
-                            # policy: the wire (TAG_SNAPSHOT) feeds
-                            # this store; a local publish would fork
-                            # the fleet's serving policy. note_update
-                            # keeps advancing the head, so the stamped
-                            # policy_lag is the TRUE wire delay.
-                            pass
-                        elif snapshot_store.publish(
-                            updates_done, infer_view
-                        ) and fleet_coord is not None:
-                            # Cross-host publication (fleet/
-                            # snapshot_wire.py): same bf16 cast,
-                            # flattened leaves + dtype names riding
-                            # TAG_SNAPSHOT to every remote store.
-                            fleet_coord.publish_snapshot(
-                                updates_done, infer_view
+                            params_now = state["params"]
+                            opt_now = state["opt_state"]
+                        new_params, new_opt, train_stats = update_step(
+                            params_now, opt_now, batch, initial_agent_state
+                        )
+                        with sp_publish:
+                            # Build the host view OUTSIDE state_lock: for
+                            # multi-host sharded params this blocks on the
+                            # dispatched compute + D2H/H2D, and holding the
+                            # lock for that long would stall every inference
+                            # thread's params read.
+                            infer_view = local_view(
+                                new_params, device=infer_device
                             )
-                if pending is not None:
-                    flush(pending)
-                pending = (train_stats, now_step, release)
-                timings.time("learn")
+                            with state_lock:
+                                state["params"] = new_params
+                                state["opt_state"] = new_opt
+                                state["infer_params"] = infer_view
+                                # Global frames: every host ran this
+                                # collective dispatch of superstep_k updates.
+                                # Replay handouts re-consume frames already
+                                # counted — only the learn clock moves for
+                                # them.
+                                if fresh:
+                                    state["step"] += (
+                                        superstep_k
+                                        * flags.unroll_length
+                                        * flags.batch_size
+                                    )
+                                state["learn_step"] += (
+                                    superstep_k
+                                    * flags.unroll_length
+                                    * flags.batch_size
+                                )
+                                now_step = state["step"]
+                    with sp_publish:
+                        watchdog.ping()
+                        updates_done += superstep_k
+                        if target_store is not None and target_store.note_update(
+                            updates_done
+                        ):
+                            # Full-precision target refresh (the store copies
+                            # the tree, so the next dispatch's donation of
+                            # these params cannot invalidate the snapshot).
+                            with state_lock:
+                                params_now = state["params"]
+                            target_store.publish(updates_done, params_now)
+                        if fleet_coord is not None and strategy == "wire":
+                            # DCN param composition (wire strategy): one
+                            # synchronous fleet-mean round per dispatch — the
+                            # CPU-CI equivalent of the xla strategy's in-mesh
+                            # grad all-reduce (averaging post-update params
+                            # from equal starts IS gradient averaging for the
+                            # SGD step; per-host RMSprop state stays local, the
+                            # documented approximation — fleet/coordinator.py).
+                            # None = the round degraded (timeout / fleet
+                            # shutting down): keep this host's params.
+                            with state_lock:
+                                params_now = state["params"]
+                            synced = fleet_coord.sync_params(params_now)
+                            if synced is not None:
+                                if learner_device is not None:
+                                    synced = jax.device_put(
+                                        synced, learner_device
+                                    )
+                                elif mesh is not None:
+                                    synced = replicate(mesh, synced)
+                                infer_view = local_view(
+                                    synced, device=infer_device
+                                )
+                                with state_lock:
+                                    state["params"] = synced
+                                    state["infer_params"] = infer_view
+                        if snapshot_store is not None:
+                            # Versioned snapshot publish (serving/snapshot.py):
+                            # due when the head has run >= refresh_updates past
+                            # the last snapshot — a dropped refresh (the chaos
+                            # failure hook) stays due and retries next update.
+                            # Under the split this is the CROSS-SLICE publication
+                            # path: infer_view is the learner-mesh params
+                            # (single-process local_view is a pass-through), the
+                            # bf16 cast runs on the mesh, and each slice pulls
+                            # its device copy d2d via latest_on — zero host
+                            # round-trips (tests/test_sebulba.py pins it).
+                            if snapshot_store.note_update(updates_done):
+                                if fleet_coord is not None and not is_lead:
+                                    # Remote fleet hosts serve the LEAD's
+                                    # policy: the wire (TAG_SNAPSHOT) feeds
+                                    # this store; a local publish would fork
+                                    # the fleet's serving policy. note_update
+                                    # keeps advancing the head, so the stamped
+                                    # policy_lag is the TRUE wire delay.
+                                    pass
+                                elif snapshot_store.publish(
+                                    updates_done, infer_view
+                                ) and fleet_coord is not None:
+                                    # Cross-host publication (fleet/
+                                    # snapshot_wire.py): same bf16 cast,
+                                    # flattened leaves + dtype names riding
+                                    # TAG_SNAPSHOT to every remote store.
+                                    fleet_coord.publish_snapshot(
+                                        updates_done, infer_view
+                                    )
+                    if pending is not None:
+                        flush(pending)
+                    pending = (train_stats, now_step, release)
                 if now_step >= flags.total_steps:
                     break
             if pending is not None:
@@ -2370,11 +2388,10 @@ def train(flags):
             if telemetry_on:
                 # Gauges set here (not in the queues) also cover the
                 # native runtime, whose C++ queues carry no instruments.
-                reg.gauge("learner.sps").set(sps)
                 # env vs learn throughput split (ISSUE 18): env_sps
-                # counts unique env frames (== learner.sps, kept for
-                # back-compat); learn_sps counts frames consumed by
-                # updates — env_sps x --replay_reuse in steady state.
+                # counts unique env frames; learn_sps counts frames
+                # consumed by updates — env_sps x --replay_reuse in
+                # steady state.
                 reg.gauge("learner.env_sps").set(sps)
                 reg.gauge("learner.learn_sps").set(learn_sps)
                 reg.gauge("learner.sample_reuse").set(replay_reuse)

@@ -26,7 +26,6 @@ Two state regimes:
 
 import logging
 import threading
-import time
 from typing import Any, Callable, List
 
 import numpy as np
@@ -181,30 +180,32 @@ def inference_loop(
     `telemetry_prefix` names this loop's instrument series (default
     "inference", today's schema). The Sebulba split runs one loop per
     inference slice with prefix "inference.slice.<i>" so per-slice
-    batch/latency/poison series land on every telemetry line instead
+    batch/latency series land on every telemetry line instead
     of aggregating into one indistinguishable pile.
     """
     buckets = default_buckets(max_batch_size)
 
-    # Stage attribution for the serving loop (ISSUE 2): batch-size
-    # distribution, lock contention, dispatch latency (async — the time
-    # to hand XLA the program, not device compute), reply latency (the
-    # device fetch + row slicing actors actually wait on). Instruments
-    # resolve once; per-batch cost is a few perf_counter calls.
+    # Stage attribution for the serving loop: batch-size distribution
+    # and four spans that tile an iteration — wait_batch (blocked in the
+    # batcher: no request ready), prep (host: inputs, bucket, padding),
+    # dispatch (async — the time to hand XLA the program, not device
+    # compute) and reply (the device fetch + row slicing actors actually
+    # wait on). Each is a histogram `<span>_s` and, on the profiler's
+    # clock, `pb:<span>`; resolved once, used every batch.
     _reg = telemetry.get_registry()
     _tracer = telemetry.get_tracer()
-    _h_batch = _reg.histogram(f"{telemetry_prefix}.batch_size")
+
+    def _span(stage):
+        return _tracer.span(f"{telemetry_prefix}.{stage}", cat="inference")
+
+    _sp_wait, _sp_prep = _span("wait_batch"), _span("prep")
+    _sp_dispatch, _sp_reply = _span("dispatch"), _span("reply")
     # Registered only when a lock exists: a permanently-zero histogram
     # reads as "requests never wait", not "not measured".
-    _h_lock = (
-        _reg.histogram(f"{telemetry_prefix}.lock_wait_s")
-        if lock is not None else None
-    )
-    _h_dispatch = _reg.histogram(f"{telemetry_prefix}.dispatch_s")
-    _h_reply = _reg.histogram(f"{telemetry_prefix}.reply_s")
+    _sp_lock_wait = _span("lock_wait") if lock is not None else None
+    _h_batch = _reg.histogram(f"{telemetry_prefix}.batch_size")
     _c_batches = _reg.counter(f"{telemetry_prefix}.batches")
     _c_rows = _reg.counter(f"{telemetry_prefix}.rows")
-    _c_poison = _reg.counter(f"{telemetry_prefix}.poison_exits")
     # A Python DynamicBatcher with a telemetry_name already observes
     # inference.batch_size per dequeued batch — observing here too
     # would double-count it. The loop keeps that role only for
@@ -213,32 +214,30 @@ def inference_loop(
 
     def flush(entry):
         batch, outputs, new_state, n, annotate = entry
-        t_reply = time.perf_counter()
-        try:
-            if state_table is not None:
-                # Device-side slice + one explicit device_get; the
-                # reply carries no agent-state leaves.
-                fetched = state_table.fetch(outputs, n)
+        with _sp_reply:
+            try:
+                if state_table is not None:
+                    # Device-side slice + one explicit device_get; the
+                    # reply carries no agent-state leaves.
+                    fetched = state_table.fetch(outputs, n)
+                    if annotate is not None:
+                        fetched = annotate(fetched, n)
+                    batch.set_outputs({"outputs": fetched})
+                    return
+                outputs = nest.map(np.asarray, outputs)
+                new_state = nest.map(np.asarray, new_state)
+                outputs = slice_to(outputs, n, batch_dim)
                 if annotate is not None:
-                    fetched = annotate(fetched, n)
-                batch.set_outputs({"outputs": fetched})
-                return
-            outputs = nest.map(np.asarray, outputs)
-            new_state = nest.map(np.asarray, new_state)
-            outputs = slice_to(outputs, n, batch_dim)
-            if annotate is not None:
-                outputs = annotate(outputs, n)
-            batch.set_outputs(
-                {
-                    "outputs": outputs,
-                    "agent_state": slice_to(new_state, n, batch_dim),
-                }
-            )
-        except Exception as e:  # noqa: BLE001
-            log.exception("Inference reply failed; continuing")
-            batch.fail(e)
-        finally:
-            _h_reply.observe(time.perf_counter() - t_reply)
+                    outputs = annotate(outputs, n)
+                batch.set_outputs(
+                    {
+                        "outputs": outputs,
+                        "agent_state": slice_to(new_state, n, batch_dim),
+                    }
+                )
+            except Exception as e:  # noqa: BLE001
+                log.exception("Inference reply failed; continuing")
+                batch.fail(e)
 
     pending = None
     batches = iter(inference_batcher)
@@ -250,72 +249,64 @@ def inference_loop(
         # hold them un-expirable for the whole window.
         if throttle_fn is not None:
             throttle_fn()
-        try:
-            batch = next(batches)
-        except StopIteration:
+        with _sp_wait:
+            batch = next(batches, None)
+        if batch is None:
             break
         try:
-            inputs = batch.get_inputs()
-            env_outputs = inputs["env"]
-            n = len(batch)
-            if _observe_sizes:
-                _h_batch.observe(n)
-            _c_batches.inc()
-            _c_rows.inc(n)
-            padded = bucket_size(n, buckets)
-            env_padded = pad_to(env_outputs, padded, batch_dim)
+            with _sp_prep:
+                inputs = batch.get_inputs()
+                env_outputs = inputs["env"]
+                n = len(batch)
+                if _observe_sizes:
+                    _h_batch.observe(n)
+                _c_batches.inc()
+                _c_rows.inc(n)
+                padded = bucket_size(n, buckets)
+                env_padded = pad_to(env_outputs, padded, batch_dim)
+                # Replica mode: ONE atomic (snapshot ctx, lag
+                # annotation) pick per batch, so the lag stamped into
+                # the reply is the lag of the params this dispatch
+                # actually used.
+                ctx = annotate = None
+                if serving_hooks is not None:
+                    ctx, annotate = serving_hooks.begin_batch()
+                if state_table is not None:
+                    slots = pad_slots(
+                        inputs["slot"], padded, state_table.trash_slot
+                    )
+                    advance = pad_advance(inputs["advance"], padded)
+                else:
+                    state_padded = pad_to(
+                        inputs["agent_state"], padded, batch_dim
+                    )
+                    act_args = (env_padded, state_padded, padded)
+                    if serving_hooks is not None:
+                        act_args = act_args + (ctx,)
 
-            def dispatch(fn):
-                # inference.dispatch_s times ONLY the act dispatch (the
-                # host handing XLA the program) — padding is host prep
-                # and the lock wait has its own histogram; folding them
-                # in would double-count stages and misattribute a lock
-                # bottleneck to XLA.
-                t0 = time.perf_counter()
-                with _tracer.span(
-                    f"{telemetry_prefix}.dispatch", cat="inference",
-                    rows=n, padded=padded,
-                ):
-                    result = fn()
-                _h_dispatch.observe(time.perf_counter() - t0)
-                return result
-
-            # Replica mode: ONE atomic (snapshot ctx, lag annotation)
-            # pick per batch, so the lag stamped into the reply is the
-            # lag of the params this dispatch actually used.
-            ctx = annotate = None
-            if serving_hooks is not None:
-                ctx, annotate = serving_hooks.begin_batch()
-
+            # inference.dispatch_s times ONLY the act dispatch (the
+            # host handing XLA the program) — padding is prep and the
+            # lock wait has its own span; folding them in would
+            # double-count stages and misattribute a lock bottleneck
+            # to XLA.
             if state_table is not None:
-                slots = pad_slots(
-                    inputs["slot"], padded, state_table.trash_slot
-                )
-                advance = pad_advance(inputs["advance"], padded)
-                outputs = dispatch(
-                    lambda: state_table.step(
+                with _sp_dispatch:
+                    outputs = state_table.step(
                         slots, advance, env_padded, context=ctx
                     )
-                )
                 new_state = None
+            elif lock is not None:
+                with _sp_lock_wait:
+                    # beastlint: disable=LOCK-DISCIPLINE  the span closes on the acquire and the try/finally release follows at once
+                    lock.acquire()
+                try:
+                    with _sp_dispatch:
+                        outputs, new_state = act_fn(*act_args)
+                finally:
+                    lock.release()
             else:
-                state_padded = pad_to(
-                    inputs["agent_state"], padded, batch_dim
-                )
-                act_args = (env_padded, state_padded, padded)
-                if serving_hooks is not None:
-                    act_args = act_args + (ctx,)
-                if lock is not None:
-                    t_lock = time.perf_counter()
-                    with lock:
-                        _h_lock.observe(time.perf_counter() - t_lock)
-                        outputs, new_state = dispatch(
-                            lambda: act_fn(*act_args)
-                        )
-                else:
-                    outputs, new_state = dispatch(
-                        lambda: act_fn(*act_args)
-                    )
+                with _sp_dispatch:
+                    outputs, new_state = act_fn(*act_args)
         except Exception as e:  # noqa: BLE001
             batch.fail(e)
             if pending is not None:
@@ -332,7 +323,6 @@ def inference_loop(
                     StateTablePoisonedError,
                 )
 
-                _c_poison.inc()
                 log.exception("State table poisoned; inference thread exiting")
                 if isinstance(e, StateTablePoisonedError):
                     raise

@@ -12,7 +12,7 @@ core stamps enqueue->batch->reply per request and counts wire bytes /
 env steps / queue intake in-process; each driver monitor tick folds that
 interval's aggregates into the process-wide telemetry registry under the
 SAME series names the Python runtime writes (wire.bytes_up/down,
-actor.env_steps/connects/request_rtt_s, recovery.actor_reconnects/
+actor.env_steps/connects/request_rtt_s/env_rtt_s, recovery.actor_reconnects/
 batch_retries, inference.request_wait_s, learner_queue.items_in/
 dequeue_wait_s/batch_size) — so native runs emit a telemetry.jsonl
 indistinguishable in schema from Python-runtime runs. Histogram folds
@@ -61,8 +61,10 @@ def available() -> bool:
 # 2 = the ISSUE 16 serving plane (SliceRouter/ReplicaRouter types,
 # continuous batching + rolled counter, ActorPool record_policy_lag) —
 # an older .so would silently serve central-only, so the default-on
-# runtime falls back to Python instead.
-REQUIRED_API_VERSION = 2
+# runtime falls back to Python instead; 3 = ISSUE 25's
+# ActorPool.stage_histograms (actor.env_rtt_s), without which the fold
+# would leave that series silently empty.
+REQUIRED_API_VERSION = 3
 
 
 def gap_reason(core=None) -> Optional[str]:
@@ -171,6 +173,9 @@ class NativeTelemetryFolder:
         self._c_ring_waits = registry.counter("ring.doorbell_waits")
         self._c_ring_rechecks = registry.counter("ring.recheck_wakeups")
         self._h_rtt = registry.histogram("actor.request_rtt_s")
+        # The actor loops' own stage (pool.stage_histograms): the rest
+        # of an actor's step beside actor.request_rtt_s.
+        self._h_env_rtt = registry.histogram("actor.env_rtt_s")
         self._h_request_wait = registry.histogram("inference.request_wait_s")
         # Serving-tier fold (ISSUE 14): the C++ batcher gates admission
         # and deadline expiry in-process; its counters land on the SAME
@@ -212,13 +217,13 @@ class NativeTelemetryFolder:
         pool) into tracer spans. Stamps are steady-clock; the payload's
         "now" rebases them onto the tracer's perf_counter timebase
         (both CLOCK_MONOTONIC on Linux — the offset absorbs any epoch
-        difference). Always drained, even with tracing disabled, so
+        difference). Always drained, even when nothing records, so
         the C++ buffer never sits full."""
         spans_fn = getattr(batcher, "trace_spans", None)
         if spans_fn is None:  # extension built before ISSUE 12
             return
         payload = spans_fn()
-        if not payload["spans"] or not self._tracer.enabled():
+        if not payload["spans"] or not self._tracer.recording():
             return
         offset = time.perf_counter() - payload["now"]
         for enqueued, batched, replied in payload["spans"]:
@@ -322,6 +327,10 @@ class NativeTelemetryFolder:
                 self._inc_delta(
                     self._c_resubmits, "shed_resubmits",
                     p.get("shed_resubmits", 0),
+                )
+                self._fold_hist(
+                    self._h_env_rtt,
+                    self._pool.stage_histograms()["actor.env_rtt_s"],
                 )
             folded_delay = False
             for key, b_obj in self._batcher_sources():

@@ -657,11 +657,14 @@ class DevicePrefetcher:
         self._arena = arena
         # Staging-time series: place_fn (device_put / shard placement)
         # dispatch latency + staged-buffer occupancy.
-        self._tm_stage = self._tm_depth = None
+        self._sp_stage = self._tm_depth = None
         if telemetry_name:
-            reg = telemetry.get_registry()
-            self._tm_stage = reg.histogram(f"{telemetry_name}.stage_s")
-            self._tm_depth = reg.gauge(f"{telemetry_name}.depth")
+            self._sp_stage = telemetry.get_tracer().span(
+                f"{telemetry_name}.stage"
+            )
+            self._tm_depth = telemetry.get_registry().gauge(
+                f"{telemetry_name}.depth"
+            )
         self._q = stdlib_queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self.error: Optional[BaseException] = None
@@ -692,10 +695,9 @@ class DevicePrefetcher:
 
         try:
             for item, release in self._items():
-                if self._tm_stage is not None:
-                    t0 = time.perf_counter()
-                    staged = self._place(item)
-                    self._tm_stage.observe(time.perf_counter() - t0)
+                if self._sp_stage is not None:
+                    with self._sp_stage:
+                        staged = self._place(item)
                 else:
                     staged = self._place(item)
                 if release is not None:

@@ -42,7 +42,6 @@ Layout/contract notes:
 # beastlint: hot-module — the table dispatch runs once per acting batch.
 
 import threading
-import time
 from typing import Any, Callable, Optional
 
 import jax
@@ -112,13 +111,20 @@ class DeviceStateTable:
         self._context_fn = context_fn
         self._input_filter = input_filter
         self._lock = threading.Lock()
-        # Pure-host telemetry (perf_counter + dict increments only):
-        # adds no device syncs to the acting hot path — pinned by the
-        # transfer-guard test in tests/test_telemetry.py.
-        _reg = telemetry.get_registry()
-        self._tm_dispatches = _reg.counter("state_table.dispatches")
-        self._tm_fetch_s = _reg.histogram("state_table.fetch_s")
-        self._tm_read_slot_s = _reg.histogram("state_table.read_slot_s")
+        # Pure-host telemetry (spans + dict increments only): adds no
+        # device syncs to the acting hot path — pinned by the
+        # transfer-guard test in tests/test_telemetry.py. The three
+        # parts of step() are children of the serving loop's dispatch
+        # span; fetch is the child of its reply span.
+        self._tm_dispatches = telemetry.get_registry().counter(
+            "state_table.dispatches"
+        )
+        _tracer = telemetry.get_tracer()
+        self._sp_context = _tracer.span("state_table.context")
+        self._sp_put = _tracer.span("state_table.put")
+        self._sp_call = _tracer.span("state_table.call")
+        self._sp_fetch = _tracer.span("state_table.fetch")
+        self._sp_read_slot = _tracer.span("state_table.read_slot")
 
         bd = batch_dim
         for leaf in jax.tree_util.tree_leaves(initial_state):
@@ -272,19 +278,22 @@ class DeviceStateTable:
         the jit signature — and a prewarm built from the model schema
         would compile a signature real (unfiltered) traffic misses.
         """
-        if self._input_filter is not None:
-            env_outputs = self._input_filter(env_outputs)
-        ctx = context
-        if ctx is None and self._context_fn is not None:
-            ctx = self._context_fn()
-        slots_d = self._put_ids(slots)
-        advance_d = jax.device_put(
-            np.asarray(advance, bool).reshape(-1), self.device
-        )
-        env_d = jax.tree_util.tree_map(
-            lambda leaf: jax.device_put(leaf, self.device), env_outputs
-        )
-        with self._lock:
+        with self._sp_context:
+            if self._input_filter is not None:
+                env_outputs = self._input_filter(env_outputs)
+            ctx = context
+            if ctx is None and self._context_fn is not None:
+                ctx = self._context_fn()
+        with self._sp_put:
+            slots_d = self._put_ids(slots)
+            advance_d = jax.device_put(
+                np.asarray(advance, bool).reshape(-1), self.device
+            )
+            env_d = jax.tree_util.tree_map(
+                lambda leaf: jax.device_put(leaf, self.device),
+                env_outputs,
+            )
+        with self._sp_call, self._lock:
             self._require_alive()
             table, self._table = self._table, None
             self._table, outputs = self._step_jit(
@@ -302,8 +311,6 @@ class DeviceStateTable:
         and the padding overhead fetched here is only the small
         action/logits/baseline rows, not agent state. Transfer-guard-
         clean: the device_get is explicit, the slice is numpy."""
-        t0 = time.perf_counter()
-        host = jax.device_get(outputs)
         bd = self.batch_dim
 
         def cut(arr):
@@ -311,22 +318,19 @@ class DeviceStateTable:
             sl[bd] = slice(0, n)
             return arr[tuple(sl)]
 
-        out = jax.tree_util.tree_map(cut, host)
-        self._tm_fetch_s.observe(time.perf_counter() - t0)
-        return out
+        with self._sp_fetch:
+            return jax.tree_util.tree_map(cut, jax.device_get(outputs))
 
     def read_slot(self, slot: int) -> Any:
         """Host copy of one slot's state, shaped like `initial_state`
         (size 1 along batch_dim) — the rollout-boundary
         `initial_agent_state` fetch, once per unroll per actor."""
-        t0 = time.perf_counter()
-        ids = self._put_ids([slot])
-        with self._lock:
-            self._require_alive()
-            piece = self._gather_jit(self._table, ids)
-        out = jax.device_get(piece)
-        self._tm_read_slot_s.observe(time.perf_counter() - t0)
-        return out
+        with self._sp_read_slot:
+            ids = self._put_ids([slot])
+            with self._lock:
+                self._require_alive()
+                piece = self._gather_jit(self._table, ids)
+            return jax.device_get(piece)
 
     def reset(self, slots) -> None:
         """Reset `slots` to the initial state (actor connect/reconnect)."""

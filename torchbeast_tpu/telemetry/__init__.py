@@ -1,14 +1,17 @@
 """Process-wide observability: metrics registry, pipeline span tracing,
 and exporters (ISSUE 2 tentpole).
 
-Three modules, stdlib-only (no jax/numpy — instrumentation inside the
+Four modules, stdlib-only (no jax/numpy — instrumentation inside the
 acting hot path must never trigger a device sync or heavyweight import;
 pinned by tests/test_telemetry.py):
 
 - metrics: Counter/Gauge/Histogram with per-thread shards (no hot-path
   locks) and mergeable log-bucketed histograms (p50/p95/p99).
-- trace:   duration spans + cross-thread StageTraces, exportable as
-  Chrome trace-event JSON (chrome://tracing / Perfetto).
+- trace:   `Tracer.span`, the one way to time a stage (histogram +
+  profiler annotation + Chrome event), and cross-thread StageTraces,
+  exportable as Chrome trace-event JSON (chrome://tracing / Perfetto).
+- heartbeat: how late a 5 ms sleeper wakes (GIL pressure), and stalls
+  with the CPU time that passed in them.
 - export:  snapshot / delta / merge, the JSON-lines exporter FileWriter
   hosts (`{xpid}/telemetry.jsonl`), a Prometheus-text HTTP endpoint
   (--telemetry_port), and a `--selftest` CLI.
@@ -20,6 +23,10 @@ Typical call-site shape (instruments are resolved once, used forever):
     _rtt = _reg.histogram("actor.request_rtt_s")
     ...
     _rtt.observe(dt)
+    _prep = telemetry.get_tracer().span("inference.prep")
+    ...
+    with _prep:  # -> inference.prep_s, pb:inference.prep
+        ...
 
 `set_enabled(False)` (the drivers' --no_telemetry) turns every
 global-registry instrument and the global tracer into no-ops; private
@@ -42,6 +49,7 @@ from torchbeast_tpu.telemetry.export import (  # noqa: F401
     telemetry_block,
     validate_snapshot,
 )
+from torchbeast_tpu.telemetry.heartbeat import Heartbeat  # noqa: F401
 from torchbeast_tpu.telemetry.metrics import (  # noqa: F401
     Counter,
     Gauge,
@@ -52,6 +60,7 @@ from torchbeast_tpu.telemetry.metrics import (  # noqa: F401
     set_enabled,
 )
 from torchbeast_tpu.telemetry.trace import (  # noqa: F401
+    Span,
     StageTrace,
     Tracer,
     get_tracer,

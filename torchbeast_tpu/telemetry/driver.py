@@ -6,17 +6,20 @@ once): `add_arguments` contributes the --telemetry/--no_telemetry/
 --telemetry_port/--trace_path stanza to a driver parser;
 `DriverTelemetry` owns the exporter, the optional Prometheus endpoint
 (bind failures DEGRADE to a warning — an observability port conflict
-must never abort a training run), and the guarded shutdown writes.
+must never abort a training run), the host heartbeat, the tracer's
+sinks (profiler annotations through the factory the driver hands in,
+Chrome events only with --trace_path) and the guarded shutdown writes.
 stdlib-only, like the rest of the package.
 """
 
 import logging
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional
 
 from torchbeast_tpu.telemetry.export import (
     JsonLinesExporter,
     PrometheusServer,
 )
+from torchbeast_tpu.telemetry.heartbeat import Heartbeat
 from torchbeast_tpu.telemetry.metrics import (
     MetricsRegistry,
     get_registry,
@@ -61,18 +64,34 @@ class DriverTelemetry:
 
     `enabled` mirrors the --telemetry flag; when off, every method is a
     cheap no-op and the global registry/tracer are gated off too.
+
+    `annotation_factory` (the drivers pass
+    `jax.profiler.TraceAnnotation`) puts every span of the process
+    tracer on the profiler's clock as `pb:<name>`, while
+    `annotation_active()` (its `is_enabled`) says a profiler session
+    is open.
     """
 
-    def __init__(self, flags, jsonl_path: str, driver: str):
+    def __init__(self, flags, jsonl_path: str, driver: str,
+                 annotation_factory: Optional[Callable] = None,
+                 annotation_active: Optional[Callable[[], bool]] = None):
         self.enabled = bool(getattr(flags, "telemetry", True))
         set_enabled(self.enabled)
         self.registry: MetricsRegistry = get_registry()
         self.exporter: Optional[JsonLinesExporter] = None
         self.prometheus: Optional[PrometheusServer] = None
+        self.heartbeat: Optional[Heartbeat] = None
         self._trace_path = getattr(flags, "trace_path", None)
         self._tick_callbacks = []
+        tracer = get_tracer()
+        tracer.set_annotation_factory(
+            annotation_factory, active=annotation_active
+        )
+        # The ring's only reader is the export at shutdown.
+        tracer.set_recording(bool(self._trace_path))
         if not self.enabled:
             return
+        self.heartbeat = Heartbeat(self.registry).start()
         self.exporter = JsonLinesExporter(
             jsonl_path, registry=self.registry, static={"driver": driver}
         )
@@ -130,6 +149,9 @@ class DriverTelemetry:
         tick), Prometheus stop, optional Chrome-trace export. Every
         part guarded: teardown telemetry failures must not mask the
         run's own exit path."""
+        if self.heartbeat is not None:
+            self.heartbeat.stop()
+            self.heartbeat = None
         if self.exporter is not None:
             extra = {"final": True}
             if step is not None:

@@ -1,10 +1,20 @@
-"""Pipeline span tracing, exportable as Chrome trace-event JSON.
+"""Pipeline span tracing: one primitive to time a stage, three sinks.
 
 Two granularities:
 
-- `Tracer.span(name)` — a context-managed duration span on the calling
-  thread (nesting renders as stacked bars in chrome://tracing /
-  Perfetto, which nest "X" events on one tid by containment).
+- `Tracer.span(name)` — THE way to time a stage of a thread's loop. It
+  returns a reusable `Span`; call sites resolve it once and write
+  `with span:` around the stage on every iteration. One block (i)
+  observes the stage's histogram `<name>_s` in the tracer's registry,
+  (ii) opens a profiler annotation `pb:<name>`, so the span lies on the
+  device trace's clock and the benchmark's trace reduction names the
+  device's idle gaps by it, and (iii) appends a Chrome "X" event while
+  the tracer records (`--trace_path`; nesting renders as stacked bars
+  in chrome://tracing / Perfetto, which nest events on one tid by
+  containment). The package is stdlib-only, so sink (ii) works through
+  a factory the drivers install (`set_annotation_factory(
+  jax.profiler.TraceAnnotation, active=...is_enabled)`), and only while
+  a profiler session is open; without one a span does (i) and (iii).
 - `Tracer.stage(name)` — a StageTrace that travels WITH a request
   across threads: each pipeline stage calls `.stamp("stage")` as the
   request passes (actor -> wire -> inference-queue -> batch -> dispatch
@@ -13,7 +23,9 @@ Two granularities:
   request's time is attributed to queue wait vs. batch wait vs. reply.
 
 Events land in a bounded ring buffer (old events drop, hot paths never
-block or grow memory); `export_chrome(path)` writes the standard
+block or grow memory) and only while the tracer records: the process
+tracer records when a driver was given `--trace_path`, since nothing
+else reads the ring. `export_chrome(path)` writes the standard
 {"traceEvents": [...]} JSON that chrome://tracing and Perfetto load
 directly. Orphaned spans (begun, never ended) are tracked and counted
 but never exported — a crashed stage can't leave half-open garbage in
@@ -22,14 +34,26 @@ mapped once to the wall clock for the export's displayTimeUnit.
 """
 
 import collections
-import contextlib
 import itertools
 import json
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
-from torchbeast_tpu.telemetry.metrics import _ENABLED
+from torchbeast_tpu.telemetry.metrics import (
+    _ENABLED,
+    Histogram,
+    MetricsRegistry,
+    get_registry,
+)
+
+# What the benchmark's trace reduction (perfbench/trace.py) takes for a
+# program span among the profiler's host events.
+ANNOTATION_PREFIX = "pb:"
+
+
+def _always() -> bool:
+    return True
 
 
 class _OpenSpan:
@@ -87,10 +111,75 @@ class StageTrace:
             )
 
 
+class Span:
+    """One call site's stage: `with span:` times it into all the
+    tracer's sinks. Reusable and re-entrant across threads and nesting
+    (what is open lives on a per-thread stack), so a call site resolves
+    its span once: the histogram and the annotation's name are looked
+    up here, not per call."""
+
+    __slots__ = ("_tracer", "name", "cat", "args", "histogram",
+                 "_annotation", "_local")
+
+    def __init__(self, tracer: "Tracer", name: str, cat: str,
+                 histogram: Optional[Histogram], args: Optional[dict]):
+        self._tracer = tracer
+        self.name = name
+        self.cat = cat
+        self.args = args
+        self.histogram = histogram
+        self._annotation = ANNOTATION_PREFIX + name
+        self._local = threading.local()
+
+    def __enter__(self):
+        try:
+            stack = self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+        tracer = self._tracer
+        if not tracer.enabled():
+            stack.append(None)
+            return self
+        annotation = None
+        factory = tracer._annotation_factory
+        if factory is not None and tracer._annotation_active():
+            annotation = factory(self._annotation)
+            annotation.__enter__()
+        stack.append((annotation, time.perf_counter()))
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        entry = self._local.stack.pop()
+        if entry is None:
+            return False
+        annotation, start = entry
+        duration = time.perf_counter() - start
+        if annotation is not None:
+            annotation.__exit__(exc_type, exc, tb)
+        if self.histogram is not None:
+            self.histogram.observe(duration)
+        tracer = self._tracer
+        if tracer._record:
+            tracer.add_complete(
+                self.name, self.cat, start, duration, args=self.args
+            )
+        return False
+
+
 class Tracer:
-    def __init__(self, max_events: int = 32768, gated: bool = False):
+    """`registry` is where spans find their `<name>_s` histograms
+    (None: spans observe none); `record` is whether Chrome events are
+    kept for `export_chrome`."""
+
+    def __init__(self, max_events: int = 32768, gated: bool = False,
+                 registry: Optional[MetricsRegistry] = None,
+                 record: bool = True):
         self._events = collections.deque(maxlen=max_events)
         self._gated = gated
+        self._registry = registry
+        self._record = record
+        self._annotation_factory: Optional[Callable] = None
+        self._annotation_active: Callable[[], bool] = _always
         self._ids = itertools.count(1)
         self._open: Dict[int, _OpenSpan] = {}
         self._open_lock = threading.Lock()
@@ -101,6 +190,26 @@ class Tracer:
 
     def enabled(self) -> bool:
         return not (self._gated and not _ENABLED[0])
+
+    def recording(self) -> bool:
+        """Whether Chrome events are being kept right now."""
+        return self._record and self.enabled()
+
+    def set_recording(self, on: bool) -> None:
+        self._record = bool(on)
+
+    def set_annotation_factory(
+        self, factory: Optional[Callable],
+        active: Optional[Callable[[], bool]] = None,
+    ) -> None:
+        """`factory(name)` -> a context manager that marks the span in
+        the profiler's host trace (`jax.profiler.TraceAnnotation`);
+        `active()` says whether a profiler session is open
+        (`TraceAnnotation.is_enabled`), so that a span builds no
+        annotation nobody would record (None: always build one). The
+        drivers install both at start-up; this package imports no jax."""
+        self._annotation_factory = factory
+        self._annotation_active = active if active is not None else _always
 
     def _tid(self) -> int:
         ident = threading.get_ident()
@@ -116,7 +225,7 @@ class Tracer:
     ) -> None:
         """Record a completed span (Chrome 'X' event). `start` is a
         perf_counter timestamp; `dur` seconds."""
-        if not self.enabled():
+        if not self.recording():
             return
         event = {
             "name": name,
@@ -169,25 +278,20 @@ class Tracer:
         with self._open_lock:
             return len(self._open)
 
-    @contextlib.contextmanager
-    def span(self, name: str, cat: str = "", **args):
-        """Duration span on the calling thread; nests naturally."""
-        if not self.enabled():
-            yield
-            return
-        start = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add_complete(
-                name, cat, start, time.perf_counter() - start,
-                args=args or None,
-            )
+    def span(self, name: str, cat: str = "",
+             histogram: Optional[Histogram] = None, **args) -> Span:
+        """The stage `name` as a reusable context manager (see Span).
+        Its histogram is `<name>_s` of the tracer's registry unless one
+        is given (utils/prof.Timings keeps its sections' older names);
+        `args` ride on every Chrome event of the span."""
+        if histogram is None and self._registry is not None:
+            histogram = self._registry.histogram(name + "_s")
+        return Span(self, name, cat, histogram, args or None)
 
     def stage(self, name: str, **args) -> Optional[StageTrace]:
-        """A cross-thread request trace; None when disabled so call
-        sites guard with `if trace is not None`."""
-        if not self.enabled():
+        """A cross-thread request trace; None when nothing records, so
+        call sites guard with `if trace is not None`."""
+        if not self.recording():
             return None
         return StageTrace(self, name, **args)
 
@@ -214,8 +318,9 @@ class Tracer:
         return len(events)
 
 
-# Process-wide tracer, gated with the metrics registry.
-_GLOBAL = Tracer(gated=True)
+# Process-wide tracer, gated with the metrics registry and observing
+# into it; it records Chrome events once a driver has a --trace_path.
+_GLOBAL = Tracer(gated=True, registry=get_registry(), record=False)
 
 
 def get_tracer() -> Tracer:

@@ -1,70 +1,73 @@
-"""Online per-section timing profiler — a thin shim over telemetry
-histograms (ISSUE 2).
+"""Online per-section timing profiler — a thin shim over the telemetry
+span primitive.
 
-Same API and split-timer semantics as before (each `time(name)`
-attributes the span since the previous mark to `name`, like lap times
-on a stopwatch; `means`/`stds`/`summary` report exact running moments),
-but each section is now a telemetry.metrics.Histogram: the moments are
-tracked exactly (count/sum/sumsq per-thread shards), and the SAME
-instruments additionally expose p50/p95/p99, land in telemetry
-snapshots, and merge across threads.
+`with timings.section(name):` times a section of a driver's loop through
+`Tracer.span`, so a section is a histogram (exact running moments for
+`means`/`stds`/`summary`, p50/p95/p99, telemetry snapshots, merge across
+threads) and a `pb:<prefix><name>` span on the profiler's clock.
 
-By default every Timings owns a PRIVATE registry, so tests and
---no_telemetry runs behave exactly as the old class did. Drivers pass
-`registry=telemetry.get_registry(), prefix="learner."` so their stage
-latencies ("dequeue", "learn", "collect") become `learner.dequeue`
-etc. in the exported snapshot — the stage-latency (p50/p95) series the
-acceptance criteria name.
+By default every Timings owns a PRIVATE registry and tracer, so tests
+and --no_telemetry runs keep their 5 s log line. Drivers pass
+`registry=telemetry.get_registry(), tracer=telemetry.get_tracer(),
+prefix="learner."` so their stage latencies ("dequeue", "learn",
+"collect") become `learner.dequeue` etc. in the exported snapshot — the
+stage-latency (p50/p95) series the acceptance criteria name.
 """
 
-import timeit
 from typing import Dict, Optional
 
 from torchbeast_tpu.telemetry.metrics import Histogram, MetricsRegistry
+from torchbeast_tpu.telemetry.trace import Span, Tracer
 
 
 class Timings:
-    """Split-timer over telemetry histograms."""
+    """Named sections of a loop, each a span over a histogram."""
 
     def __init__(
         self,
         registry: Optional[MetricsRegistry] = None,
         prefix: str = "",
+        tracer: Optional[Tracer] = None,
     ):
         self._registry = (
             registry if registry is not None else MetricsRegistry()
         )
+        self._tracer = (
+            tracer if tracer is not None else Tracer(record=False)
+        )
         self._prefix = prefix
-        # name -> histogram, insertion-ordered; list(dict.items()) is a
+        # name -> span, insertion-ordered; list(dict.items()) is a
         # single C call, so monitor threads can read while the timed
         # thread inserts a new section.
-        self._sections: Dict[str, Histogram] = {}
-        self.reset()
+        self._sections: Dict[str, Span] = {}
 
-    def reset(self):
-        """Start a fresh lap without attributing the elapsed span."""
-        self._mark = timeit.default_timer()
-
-    def time(self, name: str):
-        """Record the time since the last reset()/time() call under `name`."""
-        now = timeit.default_timer()
-        section = self._sections.get(name)
-        if section is None:
-            section = self._sections[name] = self._registry.histogram(
-                self._prefix + name
+    def section(self, name: str) -> Span:
+        """The span that times section `name`; the section's older
+        histogram name (prefix + name, no `_s`) is kept."""
+        span = self._sections.get(name)
+        if span is None:
+            full = self._prefix + name
+            span = self._sections[name] = self._tracer.span(
+                full, histogram=self._registry.histogram(full)
             )
-        section.observe(now - self._mark)
-        self._mark = now
+        return span
 
     def histogram(self, name: str) -> Optional[Histogram]:
         """The backing histogram of a section (percentile access)."""
-        return self._sections.get(name)
+        span = self._sections.get(name)
+        return span.histogram if span is not None else None
 
     def means(self) -> Dict[str, float]:
-        return {name: h.mean for name, h in list(self._sections.items())}
+        return {
+            name: span.histogram.mean
+            for name, span in list(self._sections.items())
+        }
 
     def stds(self) -> Dict[str, float]:
-        return {name: h.std for name, h in list(self._sections.items())}
+        return {
+            name: span.histogram.std
+            for name, span in list(self._sections.items())
+        }
 
     def summary(self, prefix: str = "") -> str:
         means = self.means()
