@@ -189,10 +189,8 @@ class TestSlicePinning:
         env = {"obs": np.ones((1, 4, 3), np.float32)}
         for stack in serving.stacks:
             table = stack.state_table
-            # Warm the hooks' lazy rng OUTSIDE the guard (PRNGKey
-            # construction is an ordinary host->device transfer); the
-            # steady-state serving path below runs fully guarded.
-            stack.hooks.begin_batch()
+            # The steady-state serving path runs fully guarded: the
+            # table owns its rng key, so the hooks mint none.
             with jax.transfer_guard("disallow"):
                 ctx, _ = stack.hooks.begin_batch()
                 out = table.step(
@@ -209,6 +207,54 @@ class TestSlicePinning:
         # devices (a shared default placement would pass the per-slice
         # check above while time-sharing one chip).
         assert serving.stacks[0].device != serving.stacks[1].device
+
+    def test_slice_tables_draw_different_streams_from_one_seed(self):
+        """Each slice's table owns its rng key, seeded rng_seed +
+        7919 * (i + 1) as the hooks were: one rng_seed, two slices, two
+        streams — and the same build again repeats them."""
+        from torchbeast_tpu import telemetry
+        from torchbeast_tpu.parallel.sebulba import build_sebulba_serving
+
+        def key_act(ctx, env_outputs, agent_state):
+            _params, subkey = ctx
+            words = jax.random.key_data(subkey).astype(jnp.uint32)
+            b = env_outputs["obs"].shape[1]
+            return (
+                {"key": jnp.tile(words[None, None, :], (1, b, 1))},
+                agent_state,
+            )
+
+        devices = jax.devices()
+        split = resolve_device_split("inf=2,learn=rest", devices[:3])
+        env = {"obs": np.ones((1, 1, 3), np.float32)}
+
+        def streams():
+            serving = build_sebulba_serving(
+                split, _make_store(), num_slots=4, max_batch_size=4,
+                timeout_ms=20, max_policy_lag=10, rng_seed=5,
+                initial_state={"h": np.zeros((1, 1, 4), np.float32)},
+                table_act_fn=key_act,
+                registry=telemetry.MetricsRegistry(),
+            )
+            out = []
+            for stack in serving.stacks:
+                params, _ = stack.hooks.begin_batch()
+                drawn = []
+                for _ in range(4):
+                    o = stack.state_table.step(
+                        np.zeros(1, np.int32), np.ones(1, bool), env,
+                        context=params,
+                    )
+                    assert _the_device(o["key"]) == stack.device
+                    words = stack.state_table.fetch(o, 1)["key"][0, 0]
+                    drawn.append(tuple(int(w) for w in words))
+                assert len(set(drawn)) == 4
+                out.append(drawn)
+            return out
+
+        first = streams()
+        assert not set(first[0]) & set(first[1])
+        assert streams() == first
 
     def test_sharded_facade_routes_by_slot(self):
         devices = jax.devices()
@@ -378,8 +424,10 @@ class TestSnapshotDeviceToDevice:
             store, max_policy_lag=4, registry=telemetry.MetricsRegistry(),
             device=devices[1], health_key="slice1_lag",
         )
-        (params, key), annotate = hooks.begin_batch()
-        for leaf in jax.tree_util.tree_leaves(params) + [key]:
+        params, annotate = hooks.begin_batch()
+        # The key is minted only on request (a table-less slice's
+        # act_fn); it lands on the slice device like the params.
+        for leaf in jax.tree_util.tree_leaves(params) + [hooks.next_key()]:
             assert _the_device(leaf) == devices[1]
         out = annotate({"action": np.zeros((1, 3))}, 3)
         np.testing.assert_array_equal(

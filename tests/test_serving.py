@@ -353,7 +353,7 @@ def test_replica_lag_matches_snapshot_actually_used():
     store.publish(3, {"v": np.full(1, 3.0, np.float32)})
     store.note_update(5)  # head runs ahead: lag 2
 
-    (params, _key), annotate = hooks.begin_batch()
+    params, annotate = hooks.begin_batch()
     assert float(np.asarray(params["v"])[0]) == 3.0
     out = annotate({"action": np.zeros((1, 4), np.int32)}, 4)
     assert out["policy_lag"].shape == (1, 4)
@@ -363,10 +363,44 @@ def test_replica_lag_matches_snapshot_actually_used():
     # A fresh publish drops the lag for the NEXT batch atomically.
     store.note_update(6)
     store.publish(6, {"v": np.full(1, 6.0, np.float32)})
-    (params, _key), annotate = hooks.begin_batch()
+    params, annotate = hooks.begin_batch()
     assert float(np.asarray(params["v"])[0]) == 6.0
     out = annotate({"action": np.zeros((1, 2), np.int32)}, 2)
     assert (out["policy_lag"] == 0).all()
+
+
+def test_hooks_hand_out_params_and_a_key_only_on_request():
+    """The hooks' contract since the state table owns its rng key:
+    begin_batch is (params, annotate) — no key is minted for a stack
+    with a table — and a table-less caller asks next_key() for one
+    fresh key a batch from the hooks' own chain."""
+    reg = _registry()
+    store = PolicySnapshotStore(2, registry=reg)
+    hooks = ReplicaServingHooks(
+        store, max_policy_lag=10, registry=reg, batch_dim=1, rng_seed=11
+    )
+    store.note_update(0)
+    snapshot = {"v": np.full(1, 4.0, np.float32)}
+    store.publish(0, snapshot)
+    for _ in range(3):
+        params, annotate = hooks.begin_batch()
+        assert set(params) == {"v"}  # the params themselves, no tuple
+        assert float(np.asarray(params["v"])[0]) == 4.0
+        assert callable(annotate)
+    assert hooks._rng is None  # a table's batches never touched the chain
+    keys = [tuple(np.asarray(hooks.next_key()).tolist()) for _ in range(4)]
+    assert all(len(k) == 2 for k in keys)
+    assert len(set(keys)) == 4
+    # The chain is the seed's: another hook set with the same seed
+    # repeats it, another seed does not.
+    twin = ReplicaServingHooks(
+        store, max_policy_lag=10, registry=reg, rng_seed=11
+    )
+    other = ReplicaServingHooks(
+        store, max_policy_lag=10, registry=reg, rng_seed=12
+    )
+    assert tuple(np.asarray(twin.next_key()).tolist()) == keys[0]
+    assert tuple(np.asarray(other.next_key()).tolist()) not in keys
 
 
 def test_replica_degrades_and_recovers_via_health():
@@ -486,8 +520,13 @@ def test_replica_serving_stamps_lag_into_reply():
 
     batcher = DynamicBatcher(batch_dim=1, timeout_ms=10)
 
+    keys_seen = []
+
     def act_fn(env_outputs, agent_state, batch_size, ctx):
-        params, _key = ctx
+        # Table-less path: the loop pairs the hooks' params with a key
+        # it asked the hooks for.
+        params, key = ctx
+        keys_seen.append(np.asarray(key))
         value = float(np.asarray(params["v"])[0])
         done = np.asarray(env_outputs["done"])
         outputs = {
@@ -523,6 +562,7 @@ def test_replica_serving_stamps_lag_into_reply():
     assert float(out["baseline"][0, 0]) == 7.0
     assert out["policy_lag"].shape == (1, 1)
     assert int(out["policy_lag"][0, 0]) == 2
+    assert [k.shape for k in keys_seen] == [(2,)]
 
 
 # ---------------------------------------------------------------------------

@@ -361,7 +361,7 @@ def test_state_table_step_parts_lie_inside_dispatch():
     from torchbeast_tpu.runtime.state_table import DeviceStateTable
 
     reg = telemetry.get_registry()
-    names = ["context", "put", "call", "fetch"]
+    names = ["context", "call", "fetch"]
     before = {
         n: reg.histogram(f"state_table.{n}_s").merged() for n in names
     }
@@ -385,10 +385,9 @@ def test_state_table_step_parts_lie_inside_dispatch():
         n: reg.histogram(f"state_table.{n}_s").merged() for n in names
     }
     grew = {n: after[n].count - before[n].count for n in names}
-    assert grew == {"context": 3, "put": 3, "call": 3, "fetch": 1}
+    assert grew == {"context": 3, "call": 3, "fetch": 1}
     parts = sum(
-        after[n].total - before[n].total
-        for n in ("context", "put", "call")
+        after[n].total - before[n].total for n in ("context", "call")
     )
     assert after["context"].total - before["context"].total >= 3 * 0.002
     assert parts <= step_wall

@@ -227,8 +227,9 @@ class TestDeviceStateTable:
             return jnp.float32(len(calls))
 
         def act_with_ctx(ctx, env_outputs, agent_state):
+            context, _subkey = ctx
             return (
-                {"out": env_outputs["frame"] + ctx},
+                {"out": env_outputs["frame"] + context},
                 {"h": agent_state["h"]},
             )
 
@@ -260,6 +261,158 @@ class TestDeviceStateTable:
         # ctx is traced, not baked in: the second call sees ctx=2.
         np.testing.assert_array_equal(out1[0, 0], np.full(H, 1.0))
         np.testing.assert_array_equal(out2[0, 0], np.full(H, 2.0))
+
+
+def _key_act(ctx, env_outputs, agent_state):
+    """Outputs carry the batch's subkey ([1, B, 2] raw key words), so a
+    test can read which key each dispatch drew."""
+    _context, subkey = ctx
+    b = env_outputs["frame"].shape[1]
+    words = jax.random.key_data(subkey).astype(jnp.uint32)
+    return (
+        {"key": jnp.tile(words[None, None, :], (1, b, 1))},
+        {"h": agent_state["h"] + 1},
+    )
+
+
+def _key_table(rng_key=None, num_slots=2):
+    return DeviceStateTable(
+        {"h": np.zeros((1, 1, H), np.float32)},
+        num_slots=num_slots,
+        act_fn=_key_act,
+        batch_dim=1,
+        rng_key=rng_key,
+    )
+
+
+def _drawn_key(table):
+    out = table.step(
+        np.zeros(1, np.int32), np.ones(1, bool), _env([0.0])
+    )
+    return tuple(int(w) for w in table.fetch(out, 1)["key"][0, 0])
+
+
+class TestOneRuntimeCall:
+    def test_step_enters_the_runtime_once(self, monkeypatch):
+        """The one-call contract, counted: after warm-up a step makes
+        no host-side rng split and no device_put — its only runtime
+        entry is the launch of the jitted step (one per step); the
+        reply's device_get lives in fetch."""
+        table = make_table()
+        slots, advance = np.asarray([0, 1], np.int32), np.ones(2, bool)
+        env = _env([1.0, 2.0])
+        table.step(slots, advance, env)  # warm-up: trace + compile
+
+        counts = {"put": 0, "split": 0, "launch": 0}
+
+        def counting(name, fn):
+            def wrapper(*args, **kwargs):
+                counts[name] += 1
+                return fn(*args, **kwargs)
+            return wrapper
+
+        monkeypatch.setattr(
+            jax, "device_put", counting("put", jax.device_put)
+        )
+        monkeypatch.setattr(
+            jax.random, "split", counting("split", jax.random.split)
+        )
+        table._step_jit = counting("launch", table._step_jit)
+        for _ in range(5):
+            out = table.step(slots, advance, env)
+        assert counts == {"put": 0, "split": 0, "launch": 5}
+        # Still the same program: five more advances on slots 0 and 1.
+        np.testing.assert_array_equal(
+            np.asarray(jax.device_get(out["out"]))[0, 0],
+            np.full(H, 1.0 + 5.0),
+        )
+
+    def test_prewarm_and_traffic_share_one_signature(self):
+        """slots/advance arrive as whatever ints/bools the caller has
+        (prewarm: np.full(.., int32); the pool: int64 / uint8 views);
+        the host-side dtype normalisation makes them one jit
+        signature, so nothing compiles after prewarm."""
+        table = make_table()
+        env = _env([1.0, 2.0])
+        table.step(np.full(2, table.trash_slot, np.int32),
+                   np.zeros(2, bool), env)
+        table.step(np.asarray([0, 1], np.int64),
+                   np.asarray([1, 0], np.uint8), env)
+        table.step([1, 0], [True, False], env)
+        assert table._step_jit._cache_size() == 1
+
+    def test_consecutive_steps_draw_different_subkeys(self):
+        table = _key_table()
+        keys = [_drawn_key(table) for _ in range(8)]
+        assert len(set(keys)) == 8
+        # One chain, deterministic in the seed key: a second table
+        # built from the same key draws the same stream...
+        again = _key_table()
+        assert [_drawn_key(again) for _ in range(8)] == keys
+        # ...and another seed key another one.
+        other = _key_table(rng_key=jax.random.PRNGKey(1))
+        assert not set(keys) & {_drawn_key(other) for _ in range(8)}
+
+    def test_two_threads_never_see_a_repeated_subkey(self):
+        """The table lock serialises the launches, so the key in the
+        donated carry needs no lock of its own: 200 steps from two
+        threads draw 200 distinct subkeys."""
+        import sys
+
+        table = _key_table()
+        _drawn_key(table)  # compile outside the race
+        seen, errors = [[], []], []
+
+        def worker(i):
+            try:
+                for _ in range(100):
+                    seen[i].append(_drawn_key(table))
+            except Exception as e:  # noqa: BLE001
+                errors.append(e)
+
+        threads = [
+            threading.Thread(target=worker, args=(i,)) for i in range(2)
+        ]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert not errors, errors
+        assert len(seen[0]) == len(seen[1]) == 100
+        assert len(set(seen[0]) | set(seen[1])) == 200
+
+    def test_rebuild_does_not_replay_the_stream(self):
+        table = _key_table()
+        first = [_drawn_key(table) for _ in range(6)]
+        table.poison()
+        assert table.poisoned
+        table.rebuild()
+        second = [_drawn_key(table) for _ in range(6)]
+        assert not set(first) & set(second)
+        # A second cycle starts a third stream, not the second again.
+        table.poison()
+        table.rebuild()
+        third = [_drawn_key(table) for _ in range(6)]
+        assert not (set(first) | set(second)) & set(third)
+
+    def test_callers_key_is_never_donated(self):
+        """The chain starts at fold_in(rng_key, 0), a fresh buffer: the
+        caller's own array (polybeast's state["rng"], which its
+        table-less act path still splits) survives every step."""
+        mine = jax.random.PRNGKey(7)
+        table = _key_table(rng_key=mine)
+        for _ in range(3):
+            _drawn_key(table)
+        assert not mine.is_deleted()
+        np.testing.assert_array_equal(
+            np.asarray(mine), np.asarray(jax.random.PRNGKey(7))
+        )
 
 
 class TestInferenceLoopIntegration:
@@ -324,11 +477,13 @@ class TestInferenceLoopIntegration:
 class TestTransferGuard:
     def test_state_never_crosses_host_boundary_per_step(self):
         """The tentpole regression test: a full padded unroll of table
-        steps under jax.transfer_guard("disallow") — only the EXPLICIT
-        device_put of observations/ids (inside DeviceStateTable.step) and
-        the EXPLICIT device_get of outputs (fetch) are allowed; any
-        agent-state leaf crossing the boundary would be an implicit
-        transfer and raise."""
+        steps under jax.transfer_guard("disallow") — only the hand-over
+        of observations/ids to the jitted step, allowed BY NAME inside
+        DeviceStateTable.step (a host-to-device "allow" scoped to the
+        launch; there is no explicit put), and the EXPLICIT device_get
+        of outputs (fetch) cross; device-to-host stays "disallow", and
+        any agent-state leaf crossing the boundary outside that launch
+        would be an implicit transfer and raise (next test)."""
         table = make_table(num_slots=4)
         # Warm the compile caches outside the guard (compilation itself
         # may transfer constants; the guarded property is the per-step
@@ -354,6 +509,26 @@ class TestTransferGuard:
         np.testing.assert_array_equal(
             np.asarray(boundary["h"]).reshape(H), np.full(H, 6.0)
         )
+
+    def test_guard_still_fails_a_state_leaf_that_crosses(self):
+        """step's "allow" ends with the launch: under the same guard a
+        state leaf that arrives from the host (the legacy path's shape:
+        state shipped with the request) still raises, before and after
+        a table step, and step itself leaves the guard as it found
+        it."""
+        table = make_table(num_slots=2)
+        slots, advance = np.asarray([0], np.int32), np.ones(1, bool)
+        env = _env([1.0])
+        legacy = jax.jit(lambda state: state + 1)
+        legacy(jnp.zeros((1, 1, H)))  # compile outside the guard
+        table.step(slots, advance, env)
+        host_state = np.zeros((1, 1, H), np.float32)
+        with jax.transfer_guard("disallow"):
+            with pytest.raises(Exception, match="host-to-device"):
+                legacy(host_state)
+            table.step(slots, advance, env)
+            with pytest.raises(Exception, match="host-to-device"):
+                legacy(host_state)
 
     def test_pipelined_unroll_state_stays_on_device(self):
         """Lag-1 collector variant of the guard test: a device-side

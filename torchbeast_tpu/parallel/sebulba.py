@@ -265,11 +265,13 @@ def build_sebulba_serving(
     telemetry prefixes built here) stay identical.
 
     `initial_state` + `table_act_fn`: the device-resident path — one
-    pinned DeviceStateTable per slice, context (snapshot params, rng)
-    provided per batch by the slice's hooks. With `initial_state=None`
-    the legacy path serves instead: `legacy_act_fn(env, state,
-    batch_size, ctx)` receives the hook ctx as its 4th argument (the
-    replica act-path shape).
+    pinned DeviceStateTable per slice, context (snapshot params)
+    provided per batch by the slice's hooks; the table owns its rng
+    key. With `initial_state=None` the legacy path serves instead:
+    `legacy_act_fn(env, state, batch_size, ctx)` receives `(params,
+    key)` from the hooks as its 4th argument (the replica act-path
+    shape). Either way slice i draws from the stream seeded
+    `rng_seed + 7919 * (i + 1)`, so slices keep distinct streams.
 
     One shared `admission` controller gates every slice's batcher (the
     serving.* counters aggregate; the depth bound applies per queue).
@@ -293,10 +295,16 @@ def build_sebulba_serving(
     if not stateful and legacy_act_fn is None:
         raise ValueError("stateless slices need legacy_act_fn")
 
+    if stateful:
+        import jax
+
+        from torchbeast_tpu.runtime.state_table import DeviceStateTable
+
     stacks = []
     tables = []
     for i, device in enumerate(split.inference_devices):
         name = f"inference.slice.{i}"
+        slice_seed = rng_seed + 7919 * (i + 1)
         if batcher_factory is not None:
             batcher = batcher_factory(i, name)
         else:
@@ -315,7 +323,7 @@ def build_sebulba_serving(
             hooks = ReplicaServingHooks(
                 store,
                 max_policy_lag=max_policy_lag,
-                rng_seed=rng_seed + 7919 * (i + 1),
+                rng_seed=slice_seed,
                 health=health,
                 batch_dim=batch_dim,
                 registry=reg,
@@ -324,18 +332,15 @@ def build_sebulba_serving(
             )
         table = None
         if stateful:
-            from torchbeast_tpu.runtime.state_table import (
-                DeviceStateTable,
-            )
-
             table = DeviceStateTable(
                 initial_state,
                 num_slots=num_slots,
                 act_fn=table_act_fn,
-                context_fn=None,  # hooks provide ctx per batch
+                context_fn=None,  # hooks provide params per batch
                 batch_dim=batch_dim,
                 input_filter=input_filter,
                 device=device,
+                rng_key=jax.random.PRNGKey(slice_seed),
             )
             tables.append(table)
 

@@ -1353,13 +1353,13 @@ def train(flags):
         if stateful_acting:
 
             def _table_ctx():
+                # Params only: the table owns the acting rng key and
+                # splits it inside its jitted step.
                 with state_lock:
-                    params_now = state["infer_params"]
-                    state["rng"], key = jax.random.split(state["rng"])
-                return params_now, key
+                    return state["infer_params"]
 
             def _table_act(ctx, env_outputs, agent_state):
-                params_now, key = ctx
+                params_now, key = ctx  # (context, the batch's subkey)
                 # act_body consumes [B, ...] (adds T=1 itself); batcher
                 # nests are [1, B, ...]; reply framing restores [1, B].
                 model_inputs = {
@@ -1375,8 +1375,8 @@ def train(flags):
                 }
                 return outputs, new_state
 
-            # Host-side subset to the model's inputs BEFORE
-            # device_put: actor traffic carries the full _ENV_KEYS
+            # Host-side subset to the model's inputs BEFORE the
+            # launch: actor traffic carries the full _ENV_KEYS
             # nest (episode_step/episode_return included), which the
             # model never reads — without the filter those leaves
             # transfer every dispatch AND the 4-key prewarm dummy
@@ -1394,6 +1394,7 @@ def train(flags):
                 context_fn=_table_ctx,
                 batch_dim=1,
                 input_filter=_table_filter,
+                rng_key=state["rng"],
             )
 
         # The chaos learner_stall gate (shared-chip overload model):
@@ -1583,7 +1584,8 @@ def train(flags):
                                 np.asarray, act_model.initial_state(b)
                             )
                             _split_legacy_act(
-                                dummy_env, dummy_state, b, ctx
+                                dummy_env, dummy_state, b,
+                                (ctx, stack.hooks.next_key()),
                             )
                 elif state_table is not None:
                     # Compile the table step per bucket: all-trash slots,
@@ -1686,6 +1688,9 @@ def train(flags):
                             self._hooks.serving_ok()
                         )
                         return self._hooks.begin_batch()
+
+                    def next_key(self):
+                        return self._hooks.next_key()
 
                 loop_hooks = _FlagSyncHooks(
                     replica_hooks, native_replica_router

@@ -123,10 +123,10 @@ def inference_loop(
 
     With `state_table` (a runtime.state_table.DeviceStateTable), requests
     carry {"env", "slot", "advance"} instead of {"env", "agent_state"}:
-    the table's own jitted step (which owns params/rng threading via its
-    context_fn) gathers/advances/scatters agent state on device and
-    `act_fn` is ignored (pass None). Replies then hold {"outputs"} only —
-    no state leaf ever crosses the host boundary
+    the table's own jitted step (which gets params from its context_fn
+    and owns the rng key) gathers/advances/scatters agent state on
+    device and `act_fn` is ignored (pass None). Replies then hold
+    {"outputs"} only — no state leaf ever crosses the host boundary
     (tests/test_state_table.py pins this with jax.transfer_guard).
 
     act_fn owns params access and rng threading (see polybeast.py). Pass
@@ -164,13 +164,15 @@ def inference_loop(
     the batch and re-raises to kill the thread rather than serve garbage.
 
     `serving_hooks` (serving/replica.ReplicaServingHooks, or anything
-    with the same `begin_batch() -> (ctx, annotate)` shape) turns this
-    loop into a REPLICA serving loop: each batch's ctx overrides the
-    state table's own context (snapshot params instead of live ones) —
-    or, on the legacy path, rides as a 4th act_fn argument
-    (`act_fn(env, state, batch_size, ctx)`) — and `annotate(outputs, n)`
-    stamps the matching policy_lag into the reply at flush time, so the
-    lag recorded is the lag of the params that actually served.
+    with the same `begin_batch() -> (params, annotate)` shape, plus
+    `next_key()` where there is no table) turns this loop into a
+    REPLICA serving loop: each batch's params override the state
+    table's own context (snapshot params instead of live ones) — or,
+    on the legacy path, ride with a key the loop asks the hooks for as
+    a 4th act_fn argument (`act_fn(env, state, batch_size, (params,
+    key))`) — and `annotate(outputs, n)` stamps the matching policy_lag
+    into the reply at flush time, so the lag recorded is the lag of the
+    params that actually served.
 
     `throttle_fn` (resilience/chaos.ChaosController.throttle) is the
     chaos harness's shared-chip stall model: called once per batch
@@ -282,7 +284,8 @@ def inference_loop(
                     )
                     act_args = (env_padded, state_padded, padded)
                     if serving_hooks is not None:
-                        act_args = act_args + (ctx,)
+                        # No table to own the rng chain: the hooks do.
+                        act_args += ((ctx, serving_hooks.next_key()),)
 
             # inference.dispatch_s times ONLY the act dispatch (the
             # host handing XLA the program) — padding is prep and the

@@ -18,6 +18,14 @@ only host↔device traffic is observations down and actions/logits up;
 agent state never crosses (pinned by the transfer-guard test in
 tests/test_state_table.py).
 
+One runtime call per act batch: `step` hands its host inputs (slot ids,
+advance mask, env leaves) to the jitted program as the numpy arrays
+they are — the call itself transfers them — and the acting rng key
+lives in the donated carry beside the table, split INSIDE the program.
+So a serving thread enters the runtime (and lets go of the GIL) twice a
+batch: the launch, and `fetch`'s device_get. The table owns the key for
+every caller: contexts carry params only.
+
 Layout/contract notes:
 
 - Slot `num_slots` is a TRASH slot: bucket padding scatters its rows
@@ -33,10 +41,12 @@ Layout/contract notes:
   path (reference monobeast.py:145-147).
 - Dispatch is serialized under an internal lock because the table
   buffer is donated — a second dispatch against an already-donated
-  reference would be a use-after-free. `read_slot`/`reset` share the
-  lock; the host fetch in `read_slot` happens OUTSIDE it on a fresh
-  (non-donated) gather output, so the inference hot path never blocks
-  behind a rollout-boundary fetch.
+  reference would be a use-after-free. The same lock serializes the
+  rng chain (the key rides in the donated carry), so every batch draws
+  a distinct subkey with no lock of the key's own. `read_slot`/`reset`
+  share the lock; the host fetch in `read_slot` happens OUTSIDE it on a
+  fresh (non-donated) gather output, so the inference hot path never
+  blocks behind a rollout-boundary fetch.
 """
 
 # beastlint: hot-module — the table dispatch runs once per acting batch.
@@ -69,9 +79,11 @@ class DeviceStateTable:
 
     act_fn(ctx, env_outputs, agent_state) -> (outputs, new_agent_state)
         Pure/traceable; runs INSIDE the table's jitted step. `ctx` is
-        whatever `context_fn()` returns (e.g. (params, rng_key)) and is
-        passed through as traced arguments, so fresh params/rng per
-        call never trigger a recompile.
+        the pair `(context, subkey)`: `context` is whatever
+        `context_fn()` returned (or `step`'s `context=` override; the
+        drivers pass params) and `subkey` is this batch's fresh PRNG
+        key, split inside the program from the table's own chain. Both
+        are traced, so fresh params per call never trigger a recompile.
 
     Per-bucket static shapes: one compile per (batch bucket) — the
     same compile discipline as the legacy bucket-padded forward.
@@ -86,17 +98,25 @@ class DeviceStateTable:
         batch_dim: int = 1,
         input_filter: Optional[Callable] = None,
         device=None,
+        rng_key=None,
     ):
         """`device` (optional): pin the table — and every dispatch — to
         one specific jax device. The Sebulba split (runtime/placement.py)
         builds one table per inference slice this way: the initial
-        state, slot ids, and env inputs are all explicitly device_put
-        there, so the jitted step executes on that device and the
-        donated table buffer never leaves it. Context leaves (params,
-        rng) are the CALLER's placement responsibility — the slice
-        serving hooks place them on the same device (a mixed-device
-        dispatch is a jax error, not a silent transfer). None keeps
-        today's default-device behavior."""
+        state and the rng key are committed there and the jitted step
+        carries that placement (in/out shardings built here, once), so
+        the host inputs land on that device inside the call and the
+        donated table buffer never leaves it. Context leaves (params)
+        are the CALLER's placement responsibility — the slice serving
+        hooks place them on the same device (a mixed-device dispatch is
+        a jax error, not a silent transfer). None keeps the
+        default-device behavior.
+
+        `rng_key` seeds the acting rng chain (default PRNGKey(0)). The
+        table never donates the caller's array: the chain starts at
+        `fold_in(rng_key, 0)`, and the k-th `rebuild()` restarts it at
+        `fold_in(rng_key, k)`, so a rebuilt table does not replay the
+        stream."""
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         if not _leaves(initial_state):
@@ -113,7 +133,7 @@ class DeviceStateTable:
         self._lock = threading.Lock()
         # Pure-host telemetry (spans + dict increments only): adds no
         # device syncs to the acting hot path — pinned by the
-        # transfer-guard test in tests/test_telemetry.py. The three
+        # transfer-guard test in tests/test_telemetry.py. The two
         # parts of step() are children of the serving loop's dispatch
         # span; fetch is the child of its reply span.
         self._tm_dispatches = telemetry.get_registry().counter(
@@ -121,7 +141,6 @@ class DeviceStateTable:
         )
         _tracer = telemetry.get_tracer()
         self._sp_context = _tracer.span("state_table.context")
-        self._sp_put = _tracer.span("state_table.put")
         self._sp_call = _tracer.span("state_table.call")
         self._sp_fetch = _tracer.span("state_table.fetch")
         self._sp_read_slot = _tracer.span("state_table.read_slot")
@@ -155,7 +174,13 @@ class DeviceStateTable:
             return jnp.tile(leaf, reps)
 
         self._expand = expand
+        self._initial_key = (
+            jax.random.PRNGKey(0) if rng_key is None else rng_key
+        )
+        self._rebuilds = 0
+        # The donated carry: (table, key), replaced whole by every step.
         self._table = self._fresh_table()
+        self._key = self._fresh_key(0)
 
         def index(slots):
             return (slice(None),) * bd + (slots,)
@@ -170,9 +195,13 @@ class DeviceStateTable:
                 lambda t, v: t.at[index(slots)].set(v), table, values
             )
 
-        def step(table, slots, advance, ctx, env_outputs):
+        def step(carry, slots, advance, context, env_outputs):
+            table, key = carry
+            key, subkey = jax.random.split(key)
             state = gather(table, slots)
-            outputs, new_state = act_fn(ctx, env_outputs, state)
+            outputs, new_state = act_fn(
+                (context, subkey), env_outputs, state
+            )
 
             def merge(new, old):
                 shape = [1] * new.ndim
@@ -180,7 +209,7 @@ class DeviceStateTable:
                 return jnp.where(advance.reshape(shape), new, old)
 
             merged = jax.tree_util.tree_map(merge, new_state, state)
-            return scatter(table, slots, merged), outputs
+            return (scatter(table, slots, merged), key), outputs
 
         def reset(table, slots, initial):
             values = jax.tree_util.tree_map(
@@ -191,7 +220,11 @@ class DeviceStateTable:
             )
             return scatter(table, slots, values)
 
-        self._step_jit = jax.jit(step, donate_argnums=(0,))
+        placement = {}
+        if device is not None:
+            pinned = jax.sharding.SingleDeviceSharding(device)
+            placement = {"in_shardings": pinned, "out_shardings": pinned}
+        self._step_jit = jax.jit(step, donate_argnums=(0,), **placement)
         self._reset_jit = jax.jit(reset, donate_argnums=(0,))
         self._gather_jit = jax.jit(gather)
 
@@ -199,6 +232,14 @@ class DeviceStateTable:
         """A brand-new [.., num_slots+1, ..] table, every slot at the
         initial state."""
         return jax.tree_util.tree_map(self._expand, self._initial)
+
+    def _fresh_key(self, chain: int):
+        """The start of rng chain number `chain`: a new buffer (the
+        step donates it), on the pinned device if there is one."""
+        key = jax.random.fold_in(self._initial_key, chain)
+        if self.device is not None:
+            key = jax.device_put(key, self.device)
+        return key
 
     @property
     def trash_slot(self) -> int:
@@ -241,9 +282,12 @@ class DeviceStateTable:
         a bounded mid-unroll state glitch (at most one unroll per
         actor per rebuild), equivalent to the episode-boundary resets
         V-trace already absorbs; pinned acceptable by the chaos
-        harness's return-parity check."""
+        harness's return-parity check. The rng chain restarts from a
+        key no earlier table used (see `rng_key`)."""
         with self._lock:
+            self._rebuilds += 1
             self._table = self._fresh_table()
+            self._key = self._fresh_key(self._rebuilds)
 
     def _require_alive(self):
         if self._table is None:
@@ -259,46 +303,50 @@ class DeviceStateTable:
         )
 
     def step(self, slots, advance, env_outputs, context=None):
-        """One acting dispatch over already-padded inputs.
+        """One acting dispatch over already-padded inputs: ONE runtime
+        call.
 
         slots: [n] int ids (padding rows = trash_slot), advance: [n]
-        bool, env_outputs: env nest padded to n along batch_dim.
-        Returns the on-device outputs nest (fetch with `fetch`).
+        bool, env_outputs: env nest padded to n along batch_dim — host
+        (numpy) arrays, handed to the jitted step as they are: the call
+        transfers them, there is no device_put in front of it, and the
+        batch's rng subkey is split inside the program from the key in
+        the donated carry. Under `jax.transfer_guard("disallow")` this
+        hand-over is the one transfer allowed by name (a
+        host-to-device "allow" scoped to the launch); device-to-host
+        stays the caller's guard, so a state leaf that crosses still
+        raises. Returns the on-device outputs nest (fetch with `fetch`).
 
         `context` overrides the table's own context_fn for THIS
         dispatch — the replica serving path (serving/replica.py) feeds
-        snapshot params through the same jitted step (ctx leaves are
-        traced arguments, so a replica batch never recompiles); the
-        state rows gathered/scattered are the shared table's either
-        way, so state continuity is preserved across routing changes.
+        snapshot params through the same jitted step (context leaves
+        are traced arguments, so a replica batch never recompiles); the
+        state rows gathered/scattered — and the rng chain — are the
+        shared table's either way, so state continuity is preserved
+        across routing changes.
 
-        `input_filter` (host-side, BEFORE device_put) subsets the env
-        nest to what act_fn actually reads: leaves the model ignores
-        would otherwise still be transferred every dispatch and fatten
-        the jit signature — and a prewarm built from the model schema
-        would compile a signature real (unfiltered) traffic misses.
+        `input_filter` (host-side) subsets the env nest to what act_fn
+        actually reads: leaves the model ignores would otherwise still
+        be transferred every dispatch and fatten the jit signature —
+        and a prewarm built from the model schema would compile a
+        signature real (unfiltered) traffic misses. The dtype
+        normalisation of slots/advance serves the same end: prewarm's
+        dummies and live traffic make one signature.
         """
         with self._sp_context:
             if self._input_filter is not None:
                 env_outputs = self._input_filter(env_outputs)
-            ctx = context
-            if ctx is None and self._context_fn is not None:
-                ctx = self._context_fn()
-        with self._sp_put:
-            slots_d = self._put_ids(slots)
-            advance_d = jax.device_put(
-                np.asarray(advance, bool).reshape(-1), self.device
-            )
-            env_d = jax.tree_util.tree_map(
-                lambda leaf: jax.device_put(leaf, self.device),
-                env_outputs,
-            )
+            if context is None and self._context_fn is not None:
+                context = self._context_fn()
+            slots = np.asarray(slots, np.int32).reshape(-1)
+            advance = np.asarray(advance, bool).reshape(-1)
         with self._sp_call, self._lock:
             self._require_alive()
-            table, self._table = self._table, None
-            self._table, outputs = self._step_jit(
-                table, slots_d, advance_d, ctx, env_d
-            )
+            carry, self._table = (self._table, self._key), None
+            with jax.transfer_guard_host_to_device("allow"):
+                (self._table, self._key), outputs = self._step_jit(
+                    carry, slots, advance, context, env_outputs
+                )
         self._tm_dispatches.inc()
         return outputs
 

@@ -9,7 +9,7 @@ behavior logits truthful. Two pieces:
 
 - ReplicaServingHooks: the per-batch context provider a replica
   serving loop (runtime/inference.py `serving_hooks=`) uses. Each
-  batch atomically picks (snapshot version, params, rng key) and an
+  batch atomically picks (snapshot version, params) and an
   annotate closure that stamps `policy_lag` = learner head - snapshot
   version into the reply as a [1, B] int32 leaf — so the lag recorded
   in the rollout is the lag of the params that ACTUALLY served it
@@ -58,9 +58,9 @@ class ReplicaServingHooks:
         """`device` (optional) pins this hook set to one inference
         slice (the Sebulba split): begin_batch hands out the snapshot
         placed on that device via `PolicySnapshotStore.latest_on` —
-        device-to-device, no host round-trip — and the rng key is
-        device_put alongside so the slice's state-table dispatch never
-        sees mixed-device arguments. `health_key` scopes the lag
+        device-to-device, no host round-trip — and `next_key()` commits
+        its key there too, so a slice's dispatch never sees
+        mixed-device arguments. `health_key` scopes the lag
         degradation per slice (one slice's recovery must not mask
         another's stall in the health machine's keyed causes)."""
         if max_policy_lag < 1:
@@ -81,7 +81,11 @@ class ReplicaServingHooks:
         self._c_degraded = reg.counter("serving.replica_degradations")
         self._degraded = False  # guarded-by: self._rng_lock
 
-    def _next_key(self):
+    def next_key(self):
+        """A fresh acting key from this hook set's own chain, for a
+        TABLE-LESS serving path only (the legacy act_fn: stateless
+        models). A stack with a DeviceStateTable never asks: the table
+        owns its key and splits it inside its jitted step."""
         import jax
 
         with self._rng_lock:
@@ -90,7 +94,7 @@ class ReplicaServingHooks:
             self._rng, key = jax.random.split(self._rng)
         if self._device is not None:
             # 8 bytes per batch: the key must be committed to the
-            # slice device or the pinned table dispatch mixes devices.
+            # slice device or the pinned act dispatch mixes devices.
             key = jax.device_put(key, self._device)
         return key
 
@@ -120,11 +124,11 @@ class ReplicaServingHooks:
         return ok
 
     def begin_batch(self) -> Tuple[Any, Callable]:
-        """One atomic (snapshot, key) pick for a batch about to be
-        dispatched. Returns (ctx, annotate): `ctx` feeds the state
-        table's step (params, rng) — or act_fn via `params_for_batch`
-        — and `annotate(outputs, n)` stamps the matching policy_lag
-        into the reply at flush time."""
+        """One atomic snapshot pick for a batch about to be dispatched.
+        Returns (params, annotate): `params` is the context of the
+        state table's step — a table-less caller pairs it with
+        `next_key()` for its act_fn — and `annotate(outputs, n)` stamps
+        the matching policy_lag into the reply at flush time."""
         if self._device is not None:
             latest = self.store.latest_on(self._device)
         else:
@@ -145,7 +149,7 @@ class ReplicaServingHooks:
             outputs["policy_lag"] = np.full(shape, lag, np.int32)
             return outputs
 
-        return (params, self._next_key()), annotate
+        return params, annotate
 
 
 class ReplicaRouter:

@@ -131,8 +131,7 @@ class ReplicaServer:
             except StopIteration:
                 return
             try:
-                ctx, annotate = self.hooks.begin_batch()
-                params, _key = ctx
+                params, annotate = self.hooks.begin_batch()
                 outputs = dict(self._act_fn(params, batch.get_inputs()))
                 annotate(outputs, len(batch))
                 batch.set_outputs(outputs)
