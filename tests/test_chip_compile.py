@@ -176,6 +176,37 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize(
+    "rows", [81 * 32 * 8, 8 * 8], ids=["learner-81x32x8", "act-8x8"]
+)
+def test_olmoe_experts_compile_for_v5e(one_chip, monkeypatch, rows):
+    """The OLMoE cell's grouped expert matmuls (models/moe.py on the
+    shipped megablox kernels) at the published widths, forward and
+    backward: the learner's 20,736 sorted rows, and an act batch of 8
+    whose 64 rows are padded to one tile. A contracted tile of 2048
+    overflowed VMEM in `tgmm` here before the chip ever saw it."""
+    from torchbeast_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    d, width, experts = 2048, 1024, 64
+
+    def loss(x, w_gate, w_up, w_down, sizes):
+        hidden = jax.nn.silu(
+            moe.grouped_matmul(x, w_gate, sizes)
+        ) * moe.grouped_matmul(x, w_up, sizes)
+        return jnp.sum(moe.grouped_matmul(hidden, w_down, sizes))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        _struct(one_chip, (rows, d)),
+        _struct(one_chip, (experts, d, width)),
+        _struct(one_chip, (experts, d, width)),
+        _struct(one_chip, (experts, width, d)),
+        _struct(one_chip, (experts,), jnp.int32),
+    ).compile()
+    # Two forward kernels (the sum needs no third), six backward.
+    assert compiled.as_text().count("tpu_custom_call") >= 8
+
+
 def test_flagship_act_step_compiles_for_v5e(one_chip, monkeypatch):
     """The acting program at the largest inference bucket."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")

@@ -18,6 +18,7 @@ total_steps environment frames.
 
 from typing import Any, Dict, NamedTuple, Tuple
 
+import flax
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -528,12 +529,13 @@ def compute_loss(
         batch,
         initial_agent_state,
         sample_action=False,
-        mutable=["losses"],
+        mutable=["losses", "moe_stats"],
     )
     aux_loss = sum(
         jnp.sum(leaf)
         for leaf in jax.tree_util.tree_leaves(variables.get("losses", {}))
     )
+    moe_stats = _moe_stats(variables.get("moe_stats", {}))
 
     bootstrap_value = learner_outputs.baseline[-1]
 
@@ -614,8 +616,27 @@ def compute_loss(
         "aux_loss": jnp.asarray(aux_loss, jnp.float32),
         "episode_returns_sum": episode_returns_sum,
         "episode_count": episode_count,
+        **moe_stats,
     }
     return total_loss, stats
+
+
+def _moe_stats(sown) -> Dict[str, Any]:
+    """What a dropless expert layer says its router did (models/moe.py
+    DroplessMoE), over the layers: every assignment computed (8 x tokens
+    x layers for OLMoE: nothing dropped) and the fullest expert's rows
+    over the mean, worst layer. Empty for every other model."""
+    by_name: Dict[str, list] = {}
+    for path, leaf in flax.traverse_util.flatten_dict(sown).items():
+        by_name.setdefault(path[-1], []).append(leaf)
+    if not by_name:
+        return {}
+    return {
+        "moe_assignments": sum(by_name["assignments"]),
+        "moe_load_max_over_mean": jnp.max(
+            jnp.stack(by_name["load_max_over_mean"])
+        ),
+    }
 
 
 def donate_argnums_for(donate, donate_batch: bool = False) -> tuple:

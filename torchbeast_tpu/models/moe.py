@@ -23,6 +23,14 @@ selected gates are renormalized to sum to 1; experts take at most
 ties break by token order; over-capacity assignments are dropped (the
 token's output loses that expert's contribution — with the residual
 connection around the layer this degrades gracefully).
+
+`DroplessMoE` (below, the `olmoe` family's layer) is the second dispatch:
+every one of the t x K assignments is computed, whatever the router does.
+The assignments are sorted by expert, so each expert's rows are one
+contiguous group of a [t*K, d] matrix and the experts are three grouped
+matmuls (`grouped_matmul`); t x K rows is a static shape, so no
+assignment is padded to a capacity and none is dropped. The capacity
+path stays for `--num_experts` and `parallel/ep.py`.
 """
 
 import math
@@ -31,6 +39,7 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox.ops import backend as _megablox
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
@@ -146,4 +155,179 @@ class MoEFFN(nn.Module):
                 reduce_fn=lambda prev, new: new,
             )
 
+        return y.astype(jnp.float32)
+
+
+@jax.custom_vjp
+def _permute(rows, perm, inverse):
+    """rows[perm], for a permutation and its inverse. The gradient of a
+    gather is a scatter-add, which the chip serialises; a permutation's
+    is the gather by the inverse."""
+    del inverse
+    return rows[perm]
+
+
+def _permute_fwd(rows, perm, inverse):
+    return rows[perm], (perm, inverse)
+
+
+def _permute_bwd(residuals, grad):
+    perm, inverse = residuals
+    return _permute(grad, inverse, perm), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+# The grouped matmul's tiles (rows of a group, contracted, output
+# columns), tuned on the v5e at the OLMoE cell's shapes (PERF.md, PR 27).
+_GMM_TILING = (256, 1024, 1024)
+
+
+def _gmm_call(kernel, lhs, rhs, sizes, rows, **kwargs):
+    """One call of a megablox kernel. On the chip its operands are
+    bfloat16 and its sums and result float32 — what XLA makes of a
+    float32 matmul at JAX's default precision, so the experts are
+    computed as every other matmul of the model is. Elsewhere the
+    kernel is interpreted, in float32."""
+    on_chip = jax.default_backend() == "tpu"
+    if on_chip:
+        lhs, rhs = lhs.astype(jnp.bfloat16), rhs.astype(jnp.bfloat16)
+    _, tk, tn = _GMM_TILING
+    return kernel(
+        lhs, rhs, sizes, jnp.float32, (rows, tk, tn),
+        interpret=not on_chip, **kwargs,
+    )
+
+
+@jax.custom_vjp
+def grouped_matmul(lhs, rhs, sizes):
+    """lhs [m, k] in contiguous groups of `sizes` [E] rows, rhs
+    [E, k, n] -> [m, n]: rows of group e times rhs[e]. The kernels are
+    JAX's shipped megablox `gmm` / `tgmm`; this wrapper fixes their
+    operand and result types (above) and pads the rows to the kernel's
+    tile, the padding going to the last group as rows of zeros."""
+    return _grouped_matmul_fwd(lhs, rhs, sizes)[0]
+
+
+def _pad_rows(sizes, *matrices):
+    m = matrices[0].shape[0]
+    tile = min(_GMM_TILING[0], -(-m // 128) * 128)
+    pad = -m % tile
+    if pad:
+        sizes = sizes.at[-1].add(pad)
+        matrices = [jnp.pad(a, ((0, pad), (0, 0))) for a in matrices]
+    return tile, sizes, matrices
+
+
+def _grouped_matmul_fwd(lhs, rhs, sizes):
+    tile, padded_sizes, (padded,) = _pad_rows(sizes, lhs)
+    out = _gmm_call(_megablox.gmm, padded, rhs, padded_sizes, tile)
+    return out[: lhs.shape[0]], (lhs, rhs, sizes)
+
+
+def _grouped_matmul_bwd(residuals, grad):
+    lhs, rhs, sizes = residuals
+    tile, padded_sizes, (lhs_p, grad_p) = _pad_rows(sizes, lhs, grad)
+    grad_lhs = _gmm_call(
+        _megablox.gmm, grad_p, rhs, padded_sizes, tile, transpose_rhs=True
+    )[: lhs.shape[0]]
+    grad_rhs = _gmm_call(
+        _megablox.tgmm, lhs_p.swapaxes(0, 1), grad_p, padded_sizes, tile,
+        num_actual_groups=rhs.shape[0],
+    )
+    return grad_lhs.astype(lhs.dtype), grad_rhs.astype(rhs.dtype), None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def dropless_experts(x, idx, gate, w_gate, w_up, w_down):
+    """sum_k gate[t, k] * expert_{idx[t, k]}(x[t]) with SwiGLU experts,
+    every assignment computed.
+
+    x [t, d]; idx, gate [t, K]; w_gate, w_up [E, d, f]; w_down [E, f, d].
+    Returns (y [t, d], group sizes [E]).
+    """
+    tokens, K = idx.shape
+    E = w_gate.shape[0]
+    with jax.named_scope("moe_dispatch"):
+        flat = idx.reshape(tokens * K)
+        # order[i]: which (token, rank) assignment sits in sorted row i.
+        order = jnp.argsort(flat, stable=True)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(tokens * K, dtype=order.dtype), unique_indices=True
+        )
+        sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+        rows = _permute(jnp.repeat(x, K, axis=0), order, inverse)
+    with jax.named_scope("moe_experts"):
+        hidden = nn.silu(
+            grouped_matmul(rows, w_gate, sizes)
+        ) * grouped_matmul(rows, w_up, sizes)
+        out = grouped_matmul(hidden, w_down, sizes)
+    with jax.named_scope("moe_combine"):
+        out = _permute(out, inverse, order).reshape(tokens, K, -1)
+        y = jnp.einsum(
+            "tkd,tk->td", out.astype(jnp.float32), gate.astype(jnp.float32)
+        )
+    return y, sizes
+
+
+class DroplessMoE(nn.Module):
+    """[tokens, d_model] -> [tokens, d_model]: softmax router, top-k
+    gates as the selected probabilities (not renormalised), SwiGLU
+    experts without biases, no capacity (OLMoE's layer)."""
+
+    d_ff: int  # width of one expert
+    num_experts: int
+    top_k: int
+    aux_loss_weight: float = 1e-2
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        tokens, d = x.shape
+        E, K = self.num_experts, self.top_k
+        if K > E:
+            raise ValueError(f"top_k={K} exceeds num_experts={E}")
+
+        # f32 at the highest matmul precision: the logits decide WHICH
+        # experts run, and a rounded logit picks another expert where
+        # the eighth and ninth are close.
+        with jax.named_scope("moe_route"):
+            router_logits = nn.Dense(
+                E, use_bias=False, name="router",
+                precision=jax.lax.Precision.HIGHEST,
+            )(x.astype(jnp.float32))
+            probs = jax.nn.softmax(router_logits, axis=-1)  # [t, E]
+            gate, idx = jax.lax.top_k(probs, K)  # [t, K]
+
+        # fan-in is one expert's d (or f), not E times it.
+        kernel_init = nn.initializers.lecun_normal(batch_axis=(0,))
+        w_gate = self.param("w_gate", kernel_init, (E, d, self.d_ff))
+        w_up = self.param("w_up", kernel_init, (E, d, self.d_ff))
+        w_down = self.param("w_down", kernel_init, (E, self.d_ff, d))
+        y, sizes = dropless_experts(
+            x.astype(self.dtype), idx, gate,
+            w_gate.astype(self.dtype), w_up.astype(self.dtype),
+            w_down.astype(self.dtype),
+        )
+
+        # Load balance: E x sum_e (share of the K*t assignments that
+        # went to e) x (mean router probability of e); 1.0 when uniform.
+        load = sizes.astype(jnp.float32)
+        aux = E * jnp.sum(load / (tokens * K) * probs.mean(axis=0))
+        if not self.is_initializing():
+            # `losses` is added to the objective, `moe_stats` (what the
+            # router did) to the update's stats: learner.compute_loss.
+            for collection, name, value in (
+                ("losses", "moe_load_balance", self.aux_loss_weight * aux),
+                ("moe_stats", "assignments", jnp.sum(load)),
+                ("moe_stats", "load_max_over_mean",
+                 jnp.max(load) * E / (tokens * K)),
+            ):
+                self.sow(
+                    collection, name, value,
+                    reduce_fn=lambda prev, new: new,
+                )
         return y.astype(jnp.float32)

@@ -226,6 +226,9 @@ class TransformerNet(nn.Module):
     # models/cores.RecurrentPolicyHead). Closes the "transformer
     # families stay bf16-trunk-only" gap PR 8 logged.
     head_dtype: Any = jnp.float32
+    # What the uint8 frame is scaled to before the projection (models/
+    # olmoe.py centres it).
+    frame_range: Tuple[float, float] = (0.0, 1.0)
 
     @nn.compact
     def __call__(self, inputs, core_state, *, sample_action: bool = True):
@@ -233,18 +236,22 @@ class TransformerNet(nn.Module):
         T, B = frame.shape[:2]
         M = self.memory_len
 
-        x = frame.reshape((T * B, -1)).astype(self.dtype) / 255.0
-        x = nn.Dense(self.d_model, dtype=self.dtype)(x)
-        one_hot = jax.nn.one_hot(
-            inputs["last_action"].reshape(T * B), self.num_actions
-        )
-        reward = jnp.clip(
-            inputs["reward"].astype(jnp.float32), -1, 1
-        ).reshape(T * B, 1)
-        x = x.astype(jnp.float32) + nn.Dense(self.d_model, name="extras")(
-            jnp.concatenate([reward, one_hot], axis=-1)
-        )
-        x = x.reshape(T, B, self.d_model).transpose(1, 0, 2)  # [B, T, d]
+        with jax.named_scope("obs_embed"):
+            x = frame.reshape((T * B, -1)).astype(self.dtype) / 255.0
+            low, high = self.frame_range
+            if (low, high) != (0.0, 1.0):
+                x = low + (high - low) * x
+            x = nn.Dense(self.d_model, dtype=self.dtype)(x)
+            one_hot = jax.nn.one_hot(
+                inputs["last_action"].reshape(T * B), self.num_actions
+            )
+            reward = jnp.clip(
+                inputs["reward"].astype(jnp.float32), -1, 1
+            ).reshape(T * B, 1)
+            x = x.astype(jnp.float32) + nn.Dense(
+                self.d_model, name="extras"
+            )(jnp.concatenate([reward, one_hot], axis=-1))
+            x = x.reshape(T, B, self.d_model).transpose(1, 0, 2)  # [B, T, d]
 
         done = inputs["done"]  # [T, B]
         seg = segment_ids_from_done(done).T  # [B, T]
@@ -278,20 +285,7 @@ class TransformerNet(nn.Module):
                 & no_done_yet[:, :, None]
             )  # [B, T, M]
             mask = jnp.concatenate([cache_mask, seq_mask], axis=-1)
-            block_cls = nn.remat(_Block) if self.remat else _Block
-            x, k_new, v_new = block_cls(
-                d_model=self.d_model, num_heads=self.num_heads,
-                memory_len=M, dtype=self.dtype,
-                mesh=self.mesh, seq_axis=self.seq_axis,
-                ring_schedule=self.ring_schedule,
-                attention_impl=self.attention_impl,
-                sp_strategy=self.sp_strategy,
-                batch_axis=self.batch_axis,
-                num_experts=self.num_experts,
-                moe_top_k=self.moe_top_k,
-                moe_mesh=self.moe_mesh,
-                name=f"block_{layer}",
-            )(
+            x, k_new, v_new = self.make_block(f"block_{layer}")(
                 x, (k_cache_b, v_cache_b), mask, offsets,
                 cache_mask=cache_mask, seg=seg,
                 cache_valid=valid_b, no_done=no_done_yet,
@@ -310,7 +304,7 @@ class TransformerNet(nn.Module):
                 valid_roll.T,
             ))
 
-        x = nn.LayerNorm()(x)
+        x = self.make_final_norm()(x)
         core_output = x.transpose(1, 0, 2).reshape(T * B, self.d_model)
 
         out, _ = RecurrentPolicyHead(
@@ -322,6 +316,31 @@ class TransformerNet(nn.Module):
             name="head",
         )(core_output, done, (), T, B, sample_action)
         return out, tuple(new_state)
+
+    # The two things a family built on this scaffolding replaces
+    # (models/olmoe.py): its block and its last norm. Everything else —
+    # observation and extras projections, masks, cache roll, state
+    # convention, head — is this class's.
+    @nn.nowrap
+    def make_block(self, name: str):
+        block_cls = nn.remat(_Block) if self.remat else _Block
+        return block_cls(
+            d_model=self.d_model, num_heads=self.num_heads,
+            memory_len=self.memory_len, dtype=self.dtype,
+            mesh=self.mesh, seq_axis=self.seq_axis,
+            ring_schedule=self.ring_schedule,
+            attention_impl=self.attention_impl,
+            sp_strategy=self.sp_strategy,
+            batch_axis=self.batch_axis,
+            num_experts=self.num_experts,
+            moe_top_k=self.moe_top_k,
+            moe_mesh=self.moe_mesh,
+            name=name,
+        )
+
+    @nn.nowrap
+    def make_final_norm(self):
+        return nn.LayerNorm()
 
     def initial_state(self, batch_size: int) -> Tuple:
         hd = self.d_model // self.num_heads

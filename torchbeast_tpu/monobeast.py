@@ -86,7 +86,7 @@ def make_parser():
     parser.add_argument("--unroll_length", type=int, default=80,
                         help="The unroll length (time dimension).")
     parser.add_argument("--model", default="shallow",
-                        choices=["shallow", "deep", "mlp", "pipelined_mlp", "transformer", "pipelined_transformer"],
+                        choices=["shallow", "deep", "mlp", "pipelined_mlp", "transformer", "pipelined_transformer", "olmoe"],
                         help="Model family (Mono used shallow; Poly deep; "
                              "mlp for tiny frames).")
     parser.add_argument("--use_lstm", action="store_true",
@@ -158,6 +158,15 @@ def make_parser():
                              "the model's own num_layers for the "
                              "transformer. A multiple k*N runs k looped "
                              "passes.")
+    parser.add_argument("--num_layers", type=int, default=0,
+                        help="Depth of --model transformer or olmoe "
+                             "(0: the family's own, 2 and the published "
+                             "16).")
+    parser.add_argument("--memory_len", type=int, default=0,
+                        help="Steps of its own past a transformer or "
+                             "olmoe policy attends over, carried as the "
+                             "rolling KV cache (0: the family's own, 64 "
+                             "and 128).")
     parser.add_argument("--num_experts", type=int, default=0,
                         help="Replace the transformer's FFN with a top-2 "
                              "mixture of N experts (model=transformer "
@@ -698,6 +707,15 @@ def _init_model_and_params(flags, num_actions, batch_size, frame_shape,
             )
         # The actual remat kwarg comes from the plan below (the flag is
         # the deprecated spelling of `--remat` blocks=all).
+    for flag in ("num_layers", "memory_len"):
+        value = getattr(flags, flag, 0)
+        if value:
+            if flags.model not in ("transformer", "olmoe") or value < 0:
+                raise ValueError(
+                    f"--{flag} is a positive depth or window of --model "
+                    "transformer or olmoe"
+                )
+            extra[flag] = value
     trunk_channels = getattr(flags, "trunk_channels", "")
     if trunk_channels:
         if flags.model != "deep":

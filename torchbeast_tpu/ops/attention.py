@@ -600,15 +600,17 @@ def dense_transformer_attend(q, k_all, v_all, mask, offsets, rel_bias):
 
     q: [B, T, H, D]; k_all/v_all: [B, M+T, H, D] (cache prepended);
     mask: [B, T, M+T] bool; offsets: [T, M+T] int in [0, M];
-    rel_bias: [H, M+1]. Scores and softmax run in f32; the combine runs
-    in v's dtype.
+    rel_bias: [H, M+1], or None for a family whose positions enter
+    elsewhere (RoPE, models/olmoe.py). Scores and softmax run in f32;
+    the combine runs in v's dtype.
     """
     scale = q.shape[-1] ** -0.5
     scores = (
         jnp.einsum("bqhd,bkhd->bhqk", q, k_all).astype(jnp.float32)
         * scale
     )
-    scores = scores + rel_bias[:, offsets][None]
+    if rel_bias is not None:
+        scores = scores + rel_bias[:, offsets][None]
     scores = jnp.where(mask[:, None], scores, BIG_NEG)
     weights = jax.nn.softmax(scores, axis=-1).astype(v_all.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v_all)

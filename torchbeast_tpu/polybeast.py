@@ -100,7 +100,7 @@ def make_parser():
     parser.add_argument("--unroll_length", type=int, default=80)
     # beastlint: disable=FLAG-PARITY  paper defaults differ: polybeast trains the deep IMPALA net, monobeast the shallow one
     parser.add_argument("--model", default="deep",
-                        choices=["shallow", "deep", "mlp", "pipelined_mlp", "transformer", "pipelined_transformer"])
+                        choices=["shallow", "deep", "mlp", "pipelined_mlp", "transformer", "pipelined_transformer", "olmoe"])
     parser.add_argument("--use_lstm", action="store_true")
     parser.add_argument("--precision", default="f32",
                         choices=["f32", "bf16_compute", "bf16_train"],
@@ -194,6 +194,15 @@ def make_parser():
                         help="Microbatch count M for the GPipe schedule "
                              "(default: one per pipeline device; raise "
                              "to amortize the (P-1)/(M+P-1) bubble).")
+    parser.add_argument("--num_layers", type=int, default=0,
+                        help="Depth of --model transformer or olmoe "
+                             "(0: the family's own, 2 and the published "
+                             "16).")
+    parser.add_argument("--memory_len", type=int, default=0,
+                        help="Steps of its own past a transformer or "
+                             "olmoe policy attends over, carried as the "
+                             "rolling KV cache (0: the family's own, 64 "
+                             "and 128).")
     parser.add_argument("--num_experts", type=int, default=0,
                         help="Replace the transformer's FFN with a top-2 "
                              "mixture of N experts (model=transformer "
@@ -2402,6 +2411,17 @@ def train(flags):
                 reg.gauge("learner.sample_reuse").set(replay_reuse)
                 reg.gauge("learner_queue.depth").set(learner_queue.size())
                 reg.gauge("inference.depth").set(serving_depth_fn())
+                if "moe_assignments" in stats_now:
+                    # What a dropless expert layer's router did in the
+                    # last fetched update (learner._moe_stats): every
+                    # assignment computed, and the fullest expert's
+                    # rows over the mean, worst layer.
+                    reg.gauge("moe.assignments").set(
+                        stats_now["moe_assignments"]
+                    )
+                    reg.gauge("moe.load_max_over_mean").set(
+                        stats_now["moe_load_max_over_mean"]
+                    )
                 tele.write(extra={"step": now_step})
             means = timings.means()
             log.info(
