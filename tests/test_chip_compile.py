@@ -8,7 +8,8 @@ a bf16 compare the v5e VPU lacks, a program that does not fit the
 device. These tests compile every Pallas kernel a driver can select at
 the flagship learner's shapes (unroll 80, batch 32, so the trunk pools
 see N = 81 * 32 = 2592 rows), plus the flagship act step at the largest
-inference bucket; the whole update step is the `slow` case. Nothing
+inference bucket and the flagship update over the four chips against
+its one-chip quarter; the whole one-chip update step is the `slow` case. Nothing
 runs: a compile that passes says nothing about results or times.
 
 Code that asks `jax.default_backend()` still sees the CPU here, so the
@@ -42,18 +43,23 @@ MAX_INFERENCE_BATCH = 64  # polybeast --max_inference_batch_size default
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     try:
-        topo = topologies.get_topology_desc(
+        described = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:  # noqa: BLE001 — no libtpu, no description
         pytest.skip(f"cannot describe a v5e topology here: {e}")
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield described
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _on(sharding, tree):
@@ -224,6 +230,66 @@ def test_flagship_act_step_compiles_for_v5e(one_chip, monkeypatch):
         _on(one_chip, model.initial_state(n)),
     ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+def test_flagship_dp4_update_divides_over_v5e_2x2(topo, one_chip,
+                                                  monkeypatch):
+    """`deep_lstm.learner_dp4`'s program, [81, 64] over a 2x2 mesh: each
+    chip's share is the one-chip program at B = 16, and what crosses the
+    chips is the gradients' all-reduce and scalar sums. (A time-major
+    merge in the trunk makes the partitioner all-gather the frames,
+    `bf16[81,64,84,84,4]`, and every chip run all 5,184 rows.)"""
+    import chip_smoke
+    from tests.test_parallel import COLLECTIVES, result_dims
+    from torchbeast_tpu.parallel import (
+        create_mesh,
+        make_parallel_update_step,
+    )
+    from torchbeast_tpu.parallel import mesh as mesh_lib
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    chips, rows = len(topo.devices), 64
+
+    def compiled(b, make_step, shardings):
+        _, hp, model, params, optimizer, staged = (
+            chip_smoke.build_flagship_learner([], T, b)
+        )
+        repl, batch_sh, state_sh = shardings
+        batch, state = staged(T, b)
+        return make_step(model, optimizer, hp).lower(
+            _on(repl, params),
+            _on(repl, jax.eval_shape(optimizer.init, params)),
+            _on(batch_sh, batch),
+            _on(state_sh, state),
+        ).compile()
+
+    mesh = create_mesh(devices=topo.devices)
+    dp4 = compiled(
+        rows,
+        lambda model, optimizer, hp: make_parallel_update_step(
+            model, optimizer, hp, mesh
+        ),
+        (mesh_lib.replicated(mesh), mesh_lib.batch_sharding(mesh),
+         mesh_lib.state_sharding(mesh)),
+    )
+    quarter = compiled(
+        rows // chips, learner_lib.make_update_step, (one_chip,) * 3
+    )
+
+    collectives = result_dims(dp4.as_text(), *COLLECTIVES)
+    assert {kind for kind, _ in collectives} == {"all-reduce"}, collectives
+    # Nothing a chip receives has a batch axis: gradients, stats, counts.
+    batch_shaped = [
+        dims for _, dims in collectives
+        if dims[:2] in ((T + 1, rows), (T + 1, rows // chips))
+    ]
+    assert not batch_shaped, batch_shaped
+
+    def flops(program):
+        cost = program.cost_analysis()
+        return (cost[0] if isinstance(cost, (list, tuple)) else cost)["flops"]
+
+    assert flops(dp4) == pytest.approx(flops(quarter), rel=0.05)
 
 
 def flagship_update_memory(chip, argv):

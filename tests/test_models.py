@@ -6,9 +6,11 @@ reset invariants of tests/core_agent_state_test.py)."""
 import numpy as np
 import pytest
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import AtariNet, LSTMCore, ResNet, create_model
 from torchbeast_tpu.types import AgentOutput
 
@@ -221,3 +223,186 @@ def test_resnet_remat_length_validated():
             inputs,
             (),
         )
+
+
+# ---- The merge of T and B: parity with the time-major formulation ----
+#
+# Until PR 28 every family merged [T, B] time-major ([T * B] rows) in
+# front of its trunk and kept that axis through the head. Now the conv
+# trunks merge batch-major unless the whole batch is on one device, the
+# MLP does not merge, and the head takes [T, B, D] (models/cores.
+# merge_time_batch says why). The functions below are the old
+# formulation in plain jnp on the same parameters.
+
+
+def _dense(p, x):
+    return x @ p["kernel"] + p["bias"]
+
+
+def _conv(p, x, stride=1, padding="SAME"):
+    return jax.lax.conv_general_dilated(
+        x, p["kernel"], (stride, stride), padding,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+    ) + p["bias"]
+
+
+def _deep_rows(p, x):
+    p = p["trunk"]
+    for i in range(3):
+        x = _conv(p[f"feat_conv_{i}"], x)
+        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
+        for j in range(2):
+            y = _conv(p[f"res_{i}_{j}_conv1"], jax.nn.relu(x))
+            x = x + _conv(p[f"res_{i}_{j}_conv2"], jax.nn.relu(y))
+    x = jax.nn.relu(x).reshape(x.shape[0], -1)
+    return jax.nn.relu(_dense(p["fc"], x))
+
+
+def _shallow_rows(p, x):
+    for i, stride in enumerate((4, 2, 1)):
+        x = jax.nn.relu(_conv(p[f"Conv_{i}"], x, stride, "VALID"))
+    return jax.nn.relu(_dense(p["Dense_0"], x.reshape(x.shape[0], -1)))
+
+
+def _mlp_rows(p, x):
+    x = x.reshape(x.shape[0], -1)
+    return jax.nn.relu(_dense(p["Dense_1"], jax.nn.relu(_dense(p["Dense_0"], x))))
+
+
+def _lstm(p, x, notdone, state):
+    """[T, B, D] through the stacked cells, state reset where done."""
+    h, c = state
+    outs = []
+    for t in range(x.shape[0]):
+        nd = notdone[t][None, :, None]
+        h, c, y = h * nd, c * nd, x[t]
+        new_h, new_c = [], []
+        for layer in range(h.shape[0]):
+            q = p[f"layer_{layer}"]
+            i, f, g, o = (
+                y @ q["i" + k]["kernel"] + _dense(q["h" + k], h[layer])
+                for k in "ifgo"
+            )
+            c_l = jax.nn.sigmoid(f) * c[layer] + (
+                jax.nn.sigmoid(i) * jnp.tanh(g)
+            )
+            y = jax.nn.sigmoid(o) * jnp.tanh(c_l)
+            new_h.append(y)
+            new_c.append(c_l)
+        h, c = jnp.stack(new_h), jnp.stack(new_c)
+        outs.append(y)
+    return jnp.stack(outs), (h, c)
+
+
+def _time_major_forward(family, params, inputs, state):
+    """(logits [T*B, A], baseline [T*B], core state): rows merged
+    time-major from the frames to the projections."""
+    p = params["params"]
+    frame = inputs["frame"]
+    T, B = frame.shape[:2]
+    rows = frame.reshape((T * B,) + frame.shape[2:]).astype(jnp.float32)
+    x = {"deep": _deep_rows, "shallow": _shallow_rows, "mlp": _mlp_rows}[
+        family
+    ](p, rows / 255.0)
+    extras = [jnp.clip(inputs["reward"], -1, 1).reshape(T * B, 1)]
+    if family != "deep":
+        extras.append(
+            jax.nn.one_hot(inputs["last_action"].reshape(T * B), NUM_ACTIONS)
+        )
+    x = jnp.concatenate([x] + extras, axis=-1)
+    out, state = _lstm(
+        p["head"]["core"]["Scan_StackedLSTMStep_0"],
+        x.reshape(T, B, -1),
+        1.0 - inputs["done"].astype(jnp.float32),
+        state,
+    )
+    out = out.reshape(T * B, -1)
+    return (
+        _dense(p["head"]["policy"], out),
+        _dense(p["head"]["baseline"], out)[:, 0],
+        state,
+    )
+
+
+@pytest.mark.parametrize("t", [1, 5])
+@pytest.mark.parametrize(
+    "family,one_device",
+    [("deep", False), ("deep", True), ("shallow", False),
+     ("shallow", True), ("mlp", False)],
+)
+def test_forward_matches_time_major_formulation(family, one_device, t,
+                                                monkeypatch):
+    """Both merge orders of the conv families (the default, batch-major,
+    and the one learner.one_device_model selects) and the MLP, which
+    does not merge."""
+    b = 3
+    rng = np.random.default_rng(11)
+    inputs = {
+        "frame": jnp.asarray(
+            rng.integers(0, 256, size=(t, b, 48, 48, C), dtype=np.uint8)
+        ),
+        "reward": jnp.asarray(
+            2 * rng.standard_normal((t, b)).astype(np.float32)
+        ),
+        "done": jnp.asarray(rng.random((t, b)) < 0.3),
+        "last_action": jnp.asarray(rng.integers(0, NUM_ACTIONS, size=(t, b))),
+    }
+    model = create_model(family, NUM_ACTIONS, use_lstm=True)
+    if one_device:
+        model = learner_lib.one_device_model(model)
+        assert model.time_major_merge
+    else:
+        assert not getattr(model, "time_major_merge", False)
+    state = jax.tree_util.tree_map(
+        lambda s: jnp.asarray(
+            rng.standard_normal(s.shape).astype(np.float32)
+        ),
+        model.initial_state(b),
+    )
+    params = model.init(
+        {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
+        inputs,
+        state,
+    )
+
+    drawn = []
+    categorical = jax.random.categorical
+
+    def spy(key, logits, axis=-1):
+        drawn.append((key, logits))
+        return categorical(key, logits, axis=axis)
+
+    monkeypatch.setattr(jax.random, "categorical", spy)
+    out, new_state = model.apply(
+        params, inputs, state, rngs={"action": jax.random.PRNGKey(2)}
+    )
+    want_logits, want_baseline, want_state = _time_major_forward(
+        family, params, inputs, state
+    )
+
+    close = dict(rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        out.policy_logits.reshape(t * b, NUM_ACTIONS), want_logits, **close
+    )
+    np.testing.assert_allclose(
+        out.baseline.reshape(t * b), want_baseline, **close
+    )
+    for got, want in zip(new_state, want_state):
+        np.testing.assert_allclose(got, want, **close)
+
+    # The draw: one categorical over the rows in time-major order with
+    # the head's key, as before; at T == 1 (the act step) bit for bit.
+    ((key, logits),) = drawn
+    assert np.array_equal(
+        logits.reshape(t * b, NUM_ACTIONS),
+        out.policy_logits.reshape(t * b, NUM_ACTIONS),
+    )
+    if t == 1:
+        np.testing.assert_array_equal(
+            out.action.reshape(b), categorical(key, want_logits, axis=-1)
+        )
+
+
+def test_one_device_model_keeps_a_model_without_a_merge():
+    mlp = create_model("mlp", NUM_ACTIONS)
+    assert learner_lib.one_device_model(mlp) is mlp

@@ -21,18 +21,19 @@ from torchbeast_tpu.parallel import (
 T, B, A = 4, 8, 4  # B divisible by the 8-device data axis
 
 
-def make_batch(rng_seed=0):
+def make_batch(rng_seed=0, t=T, b=B):
     rng = np.random.default_rng(rng_seed)
+    lead = (t + 1, b)
     return {
-        "frame": rng.integers(0, 256, (T + 1, B, 48, 48, 1), dtype=np.uint8),
-        "reward": rng.standard_normal((T + 1, B)).astype(np.float32),
-        "done": rng.random((T + 1, B)) < 0.2,
-        "episode_return": rng.standard_normal((T + 1, B)).astype(np.float32),
-        "episode_step": rng.integers(0, 99, (T + 1, B)).astype(np.int32),
-        "last_action": rng.integers(0, A, (T + 1, B)).astype(np.int32),
-        "action": rng.integers(0, A, (T + 1, B)).astype(np.int32),
-        "policy_logits": rng.standard_normal((T + 1, B, A)).astype(np.float32),
-        "baseline": rng.standard_normal((T + 1, B)).astype(np.float32),
+        "frame": rng.integers(0, 256, lead + (48, 48, 1), dtype=np.uint8),
+        "reward": rng.standard_normal(lead).astype(np.float32),
+        "done": rng.random(lead) < 0.2,
+        "episode_return": rng.standard_normal(lead).astype(np.float32),
+        "episode_step": rng.integers(0, 99, lead).astype(np.int32),
+        "last_action": rng.integers(0, A, lead).astype(np.int32),
+        "action": rng.integers(0, A, lead).astype(np.int32),
+        "policy_logits": rng.standard_normal(lead + (A,)).astype(np.float32),
+        "baseline": rng.standard_normal(lead).astype(np.float32),
     }
 
 
@@ -295,3 +296,86 @@ def test_parallel_update_applies_entropy_anneal(setup):
     e5 = float(stats5["entropy_loss"])
     assert e0 != 0.0
     np.testing.assert_allclose(e5, 0.5 * e0, rtol=1e-5)
+
+
+# Shapes of the partitioning test: no other dimension of these programs
+# is 7, 12, 21 or 84 (frames 48x48x1; LSTM gates 1024 / 2068 / 532).
+DIVIDE_T, DIVIDE_B, DIVIDE_CHIPS = 6, 12, 4
+
+
+COLLECTIVES = (
+    "all-gather", "all-reduce", "all-to-all", "collective-permute",
+    "reduce-scatter",
+)
+
+
+def result_dims(hlo_text, *ops):
+    """Result dims of every instruction of kind `ops` in a compiled
+    module's text: [(op, (dims...)), ...]; a tuple result gives one
+    entry per element. (tests/test_chip_compile.py reads the four-chip
+    TPU program with it.)"""
+    import re
+
+    found = []
+    pattern = r"= (\(.*?\)|\S+) (%s)(?:-start)?\(" % "|".join(ops)
+    for match in re.finditer(pattern, hlo_text):
+        for dims in re.findall(r"\w+\[([\d,]*)\]", match.group(1)):
+            found.append(
+                (match.group(2), tuple(int(d) for d in dims.split(",") if d))
+            )
+    return found
+
+
+@pytest.mark.parametrize("family", ["deep", "shallow", "mlp"])
+def test_data_parallel_update_divides_the_model(family):
+    """Four chips each run a quarter of the rows, trunk included.
+
+    The conv trunks merge [T+1, B] into one axis; merged time-major the
+    partitioner cannot carry B's sharding through (a tiled sharding of a
+    merged axis follows its major factor only), all-gathers the frames
+    and every chip runs all (T+1) * B rows (0.95x of one chip on four,
+    PERF.md §6, PR 28). Read from the compiled program: no all-gather of
+    a [T+1, B, ...] array, and every convolution / matmul over frames
+    has (T+1) * B / 4 rows.
+    """
+    steps, rows = DIVIDE_T + 1, DIVIDE_B
+    lead = (steps, rows)
+    model = create_model(family, num_actions=A, use_lstm=True)
+    batch = make_batch(t=DIVIDE_T, b=rows)
+    state = model.initial_state(rows)
+    params = jax.eval_shape(
+        model.init,
+        {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
+        batch,
+        state,
+    )
+    hp = learner_lib.HParams(batch_size=rows, unroll_length=DIVIDE_T)
+    optimizer = learner_lib.make_optimizer(hp)
+    step = make_parallel_update_step(
+        model, optimizer, hp, create_mesh(DIVIDE_CHIPS), donate=False
+    )
+    text = step.lower(
+        params, jax.eval_shape(optimizer.init, params), batch, state
+    ).compile().as_text()
+
+    gathered = [
+        dims for _, dims in result_dims(text, "all-gather")
+        if dims[:2] == lead
+    ]
+    assert not gathered, f"all-gathers of [T+1, B, ...] arrays: {gathered}"
+    # What is left between chips: the gradients' all-reduce (with the
+    # stats' sums) and a scalar count.
+    kinds = {op for op, _ in result_dims(text, *COLLECTIVES)}
+    assert kinds == {"all-reduce"}, kinds
+
+    whole, quarter = steps * rows, steps * rows // DIVIDE_CHIPS
+    matmuls = result_dims(text, "convolution", "dot")
+    over_all_rows = [m for m in matmuls if whole in m[1]]
+    assert not over_all_rows, over_all_rows
+    per_chip = [m for m in matmuls if m[1][:1] == (quarter,)]
+    if family != "mlp":
+        convs = [dims for op, dims in per_chip if op == "convolution"]
+        # Forward and input-gradient convolutions of every conv layer
+        # but the first, whose input is the frame.
+        assert len(convs) >= 5, matmuls
+    assert per_chip, matmuls

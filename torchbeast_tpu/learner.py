@@ -745,6 +745,19 @@ def update_body(model, optimizer: optax.GradientTransformation, hp: HParams):
     return update_step
 
 
+def one_device_model(model):
+    """`model` for a program that holds its whole batch on one device:
+    a family whose trunk merges T and B (`time_major_merge`, models/
+    cores.merge_time_batch) merges them time-major there; same
+    parameters, same outputs. The default order is the one that stays
+    divided under any sharding of B (parallel/dp.py compiles the same
+    update_body with it), so a caller that forgets this loses a percent,
+    not the division over chips."""
+    if hasattr(model, "time_major_merge"):
+        return model.clone(time_major_merge=True)
+    return model
+
+
 def make_update_step(
     model, optimizer: optax.GradientTransformation, hp: HParams,
     donate=True, donate_batch: bool = False,
@@ -758,7 +771,7 @@ def make_update_step(
     where nothing re-reads a consumed batch).
     """
     return jax.jit(
-        update_body(model, optimizer, hp),
+        update_body(one_device_model(model), optimizer, hp),
         donate_argnums=donate_argnums_for(donate, donate_batch),
     )
 
@@ -859,7 +872,7 @@ def make_update_superstep(
     if k < 1:
         raise ValueError(f"superstep k must be >= 1, got {k}")
     jitted = jax.jit(
-        superstep_body(model, optimizer, hp),
+        superstep_body(one_device_model(model), optimizer, hp),
         # Batch/state never go to donate_argnums here — no batch-shaped
         # outputs exist to alias (consume_staged_inputs has the story).
         donate_argnums=donate_argnums_for(donate, donate_batch=False),

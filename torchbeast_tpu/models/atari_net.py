@@ -20,7 +20,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from torchbeast_tpu.models.cores import RecurrentPolicyHead, lstm_initial_state
+from torchbeast_tpu.models.cores import (
+    RecurrentPolicyHead,
+    clipped_reward_input,
+    lstm_initial_state,
+    merge_time_batch,
+    split_time_batch,
+)
 
 
 class AtariNet(nn.Module):
@@ -33,6 +39,7 @@ class AtariNet(nn.Module):
     # Rematerialize the LSTM scan's backward (the `core` stage of the
     # remat planner, runtime/remat_plan.py; no-op without --use_lstm).
     core_remat: bool = False
+    time_major_merge: bool = False  # learner.one_device_model sets it
 
     @property
     def core_output_size(self) -> int:
@@ -44,7 +51,8 @@ class AtariNet(nn.Module):
     def __call__(self, inputs, core_state=(), *, sample_action: bool = True):
         frame = inputs["frame"]  # [T, B, H, W, C] uint8
         T, B = frame.shape[:2]
-        x = frame.reshape((T * B,) + frame.shape[2:])
+        # Batch-major keeps B's sharding (cores.merge_time_batch).
+        x = merge_time_batch(frame, self.time_major_merge)
         x = x.astype(self.dtype) / 255.0
 
         conv = lambda feat, k, s: nn.Conv(  # noqa: E731
@@ -53,21 +61,24 @@ class AtariNet(nn.Module):
         x = nn.relu(conv(32, 8, 4)(x))
         x = nn.relu(conv(64, 4, 2)(x))
         x = nn.relu(conv(64, 3, 1)(x))
-        x = x.reshape((T * B, -1))  # 7*7*64 = 3136 for 84x84 input
+        x = x.reshape((B * T, -1))  # 7*7*64 = 3136 for 84x84 input
         x = nn.relu(nn.Dense(512, dtype=self.dtype)(x))
         # Trunk -> head boundary in the head's dtype (old behavior =
         # astype(float32); bf16_train keeps the activation half-width).
-        x = x.astype(self.head_dtype)
+        x = split_time_batch(
+            x.astype(self.head_dtype), T, B, self.time_major_merge
+        )
 
         one_hot_last_action = jax.nn.one_hot(
-            inputs["last_action"].reshape(T * B), self.num_actions,
-            dtype=self.head_dtype,
+            inputs["last_action"], self.num_actions, dtype=self.head_dtype
         )
-        clipped_reward = jnp.clip(
-            inputs["reward"].astype(jnp.float32), -1, 1
-        ).reshape(T * B, 1).astype(self.head_dtype)
         core_input = jnp.concatenate(
-            [x, clipped_reward, one_hot_last_action], axis=-1
+            [
+                x,
+                clipped_reward_input(inputs["reward"], self.head_dtype),
+                one_hot_last_action,
+            ],
+            axis=-1,
         )
 
         return RecurrentPolicyHead(
@@ -78,7 +89,7 @@ class AtariNet(nn.Module):
             dtype=self.head_dtype,
             remat=self.core_remat,
             name="head",
-        )(core_input, inputs["done"], core_state, T, B, sample_action)
+        )(core_input, inputs["done"], core_state, sample_action)
 
     def initial_state(self, batch_size: int) -> Tuple:
         return lstm_initial_state(

@@ -124,14 +124,55 @@ def lstm_initial_state(
     return (jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32))
 
 
+def merge_time_batch(x, time_major=False):
+    """`[T, B, ...] -> [B * T, ...]`: the one merged axis the conv
+    trunks need (a convolution has one batch dimension).
+
+    Batch-major unless told otherwise: B is the MAJOR factor of the
+    merged axis. The learner batch is sharded along B over the mesh's
+    `data` axis (parallel/mesh.batch_sharding), and a tiled sharding of
+    a merged axis can only follow its major factor: merged time-major
+    (`[T * B]`) the SPMD partitioner all-gathers the frames and every
+    chip runs the whole trunk on all T * B rows. For T == 1, the act
+    step, both orders are the same reshape.
+
+    `time_major=True` is for a program whose whole batch is on one
+    device (learner.one_device_model): there the time-major merge is a
+    bitcast of the frames, while the batch-major one moves them once
+    more and leaves the first convolution a slower layout — 1.09 ms of
+    the flagship update's 116.8 on a v5e (PERF.md §6, PR 28).
+    """
+    T, B = x.shape[:2]
+    if not time_major:
+        x = jnp.swapaxes(x, 0, 1)
+    return x.reshape((T * B,) + x.shape[2:])
+
+
+def split_time_batch(x, T, B, time_major=False):
+    """Inverse of merge_time_batch: `[B * T, ...] -> [T, B, ...]`."""
+    if time_major:
+        return x.reshape((T, B) + x.shape[1:])
+    return jnp.swapaxes(x.reshape((B, T) + x.shape[1:]), 0, 1)
+
+
+def clipped_reward_input(reward, dtype):
+    """The reward as a core input: clipped to [-1, 1], `[T, B, 1]`."""
+    return jnp.clip(reward.astype(jnp.float32), -1, 1)[..., None].astype(
+        dtype
+    )
+
+
 class RecurrentPolicyHead(nn.Module):
     """Optional LSTM core + policy/baseline heads + action selection.
 
     Shared tail of every model family (the reference duplicates this block
     across AtariNet and the deep Net, monobeast.py:594-632 /
-    polybeast_learner.py:235-264). Takes flattened `[T*B, D]` core inputs
-    plus the `[T, B]` done mask, returns (AgentOutput, new_core_state) with
-    `[T, B, ...]` outputs.
+    polybeast_learner.py:235-264). Takes `[T, B, D]` core inputs plus the
+    `[T, B]` done mask, returns (AgentOutput, new_core_state) with
+    `[T, B, ...]` outputs. Nothing here merges T and B: the LSTM scans
+    `[T, B, D]`, the projections contract the last axis, so a B axis
+    sharded over `data` stays sharded from the trunk to the losses (see
+    merge_time_batch).
 
     `dtype` is the head's compute/activation dtype (--precision
     bf16_train extends bf16 past the trunk through the LSTM core and the
@@ -152,10 +193,9 @@ class RecurrentPolicyHead(nn.Module):
     remat: bool = False
 
     @nn.compact
-    def __call__(self, core_input, done, core_state, T, B, sample_action):
-        core_input = core_input.astype(self.dtype)
+    def __call__(self, core_input, done, core_state, sample_action):
+        core_output = core_input.astype(self.dtype)
         if self.use_lstm:
-            core_input = core_input.reshape(T, B, -1)
             notdone = 1.0 - done.astype(jnp.float32)
             core_output, core_state = LSTMCore(
                 hidden_size=self.hidden_size,
@@ -163,10 +203,8 @@ class RecurrentPolicyHead(nn.Module):
                 dtype=self.dtype,
                 remat=self.remat,
                 name="core",
-            )(core_input, notdone, core_state)
-            core_output = core_output.reshape(T * B, -1)
+            )(core_output, notdone, core_state)
         else:
-            core_output = core_input
             core_state = ()
 
         policy_logits = nn.Dense(
@@ -185,9 +223,9 @@ class RecurrentPolicyHead(nn.Module):
 
         return (
             AgentOutput(
-                action=action.reshape(T, B).astype(jnp.int32),
-                policy_logits=policy_logits.reshape(T, B, self.num_actions),
-                baseline=baseline.reshape(T, B),
+                action=action.astype(jnp.int32),
+                policy_logits=policy_logits,
+                baseline=baseline[..., 0],
             ),
             core_state,
         )

@@ -10,7 +10,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from torchbeast_tpu.models.cores import RecurrentPolicyHead, lstm_initial_state
+from torchbeast_tpu.models.cores import (
+    RecurrentPolicyHead,
+    clipped_reward_input,
+    lstm_initial_state,
+)
 
 
 class MLPNet(nn.Module):
@@ -33,24 +37,27 @@ class MLPNet(nn.Module):
     def __call__(self, inputs, core_state=(), *, sample_action: bool = True):
         frame = inputs["frame"]  # [T, B, ...]
         T, B = frame.shape[:2]
-        x = frame.reshape((T * B, -1)).astype(self.dtype) / 255.0
+        # No merge of T and B: a Dense contracts the last axis of
+        # [T, B, D] as it stands, and B keeps its sharding (models/cores).
+        x = frame.reshape((T, B, -1)).astype(self.dtype) / 255.0
         for size in self.hidden_sizes:
             x = nn.relu(nn.Dense(size, dtype=self.dtype)(x))
         # Trunk -> head boundary in the HEAD's dtype: under bf16_train
-        # the [T*B, D] activation (and its backward cotangent) never
+        # the [T, B, D] activation (and its backward cotangent) never
         # round-trips through f32; under the f32/bf16_compute policies
         # this is exactly the old astype(float32) boundary.
         x = x.astype(self.head_dtype)
 
         one_hot_last_action = jax.nn.one_hot(
-            inputs["last_action"].reshape(T * B), self.num_actions,
-            dtype=self.head_dtype,
+            inputs["last_action"], self.num_actions, dtype=self.head_dtype
         )
-        clipped_reward = jnp.clip(
-            inputs["reward"].astype(jnp.float32), -1, 1
-        ).reshape(T * B, 1).astype(self.head_dtype)
         core_input = jnp.concatenate(
-            [x, clipped_reward, one_hot_last_action], axis=-1
+            [
+                x,
+                clipped_reward_input(inputs["reward"], self.head_dtype),
+                one_hot_last_action,
+            ],
+            axis=-1,
         )
 
         return RecurrentPolicyHead(
@@ -61,7 +68,7 @@ class MLPNet(nn.Module):
             dtype=self.head_dtype,
             remat=self.core_remat,
             name="head",
-        )(core_input, inputs["done"], core_state, T, B, sample_action)
+        )(core_input, inputs["done"], core_state, sample_action)
 
     def initial_state(self, batch_size: int) -> Tuple:
         return lstm_initial_state(self.use_lstm, 1, self.core_size, batch_size)

@@ -146,7 +146,7 @@ class PipelinedMLPNet(nn.Module):
             dtype=self.head_dtype,
             remat=self.core_remat,
             name="head",
-        )(x, inputs["done"], core_state, T, B, sample_action)
+        )(x.reshape(T, B, d), inputs["done"], core_state, sample_action)
 
     def initial_state(self, batch_size: int) -> Tuple:
         return lstm_initial_state(

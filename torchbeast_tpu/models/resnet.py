@@ -13,12 +13,20 @@ from typing import Any, Sequence, Tuple
 import flax.linen as nn
 import jax.numpy as jnp
 
-from torchbeast_tpu.models.cores import RecurrentPolicyHead, lstm_initial_state
+from torchbeast_tpu.models.cores import (
+    RecurrentPolicyHead,
+    clipped_reward_input,
+    lstm_initial_state,
+    merge_time_batch,
+    split_time_batch,
+)
 from torchbeast_tpu.ops.pool import max_pool2d
 
 
 class ResNetBase(nn.Module):
-    """Conv trunk shared by actor/learner; returns [T*B, 256] features."""
+    """Conv trunk shared by actor/learner: [T, B, H, W, C] frames in,
+    [T, B, 256] features out. Inside, T and B are one merged axis
+    (cores.merge_time_batch: batch-major keeps B's sharding)."""
 
     channels: Sequence[int] = (16, 32, 32)
     dtype: Any = jnp.float32
@@ -34,6 +42,7 @@ class ResNetBase(nn.Module):
     # Default: remat everything — the configuration whose fit on a
     # 15.75 GB v5e is measured.
     remat: Any = True
+    time_major_merge: bool = False  # learner.one_device_model sets it
 
     def _conv3(self, feat, name):
         return nn.Conv(
@@ -69,7 +78,7 @@ class ResNetBase(nn.Module):
     @nn.compact
     def __call__(self, frame):
         T, B = frame.shape[:2]
-        x = frame.reshape((T * B,) + frame.shape[2:])
+        x = merge_time_batch(frame, self.time_major_merge)
         x = x.astype(self.dtype) / 255.0
 
         # Rematerialize stages in the backward pass: at the reference's
@@ -105,9 +114,11 @@ class ResNetBase(nn.Module):
                 x = ResNetBase._stage(self, x, i)
 
         x = nn.relu(x)
-        x = x.reshape((T * B, -1))  # 11*11*32 = 3872 for 84x84 input
+        x = x.reshape((B * T, -1))  # 11*11*32 = 3872 for 84x84 input
         x = nn.relu(nn.Dense(256, dtype=self.dtype, name="fc")(x))
-        return x.astype(self.out_dtype)
+        return split_time_batch(
+            x.astype(self.out_dtype), T, B, self.time_major_merge
+        )
 
 
 class ResNet(nn.Module):
@@ -131,22 +142,20 @@ class ResNet(nn.Module):
     # buy model capacity at far less than proportional step-time on the
     # chip — benchmarks/mfu_ablation.py measures exactly that scaling.
     trunk_channels: Sequence[int] = (16, 32, 32)
+    time_major_merge: bool = False  # learner.one_device_model sets it
 
     @nn.compact
     def __call__(self, inputs, core_state=(), *, sample_action: bool = True):
-        frame = inputs["frame"]  # [T, B, H, W, C] uint8
-        T, B = frame.shape[:2]
-
         x = ResNetBase(
             channels=tuple(self.trunk_channels),
             dtype=self.dtype, out_dtype=self.head_dtype,
-            remat=self.remat, name="trunk"
-        )(frame)
-
-        clipped_reward = jnp.clip(
-            inputs["reward"].astype(jnp.float32), -1, 1
-        ).reshape(T * B, 1).astype(self.head_dtype)
-        core_input = jnp.concatenate([x, clipped_reward], axis=-1)
+            remat=self.remat, time_major_merge=self.time_major_merge,
+            name="trunk",
+        )(inputs["frame"])  # [T, B, H, W, C] uint8 -> [T, B, 256]
+        core_input = jnp.concatenate(
+            [x, clipped_reward_input(inputs["reward"], self.head_dtype)],
+            axis=-1,
+        )
 
         return RecurrentPolicyHead(
             num_actions=self.num_actions,
@@ -156,7 +165,7 @@ class ResNet(nn.Module):
             dtype=self.head_dtype,
             remat=self.core_remat,
             name="head",
-        )(core_input, inputs["done"], core_state, T, B, sample_action)
+        )(core_input, inputs["done"], core_state, sample_action)
 
     def initial_state(self, batch_size: int) -> Tuple:
         return lstm_initial_state(

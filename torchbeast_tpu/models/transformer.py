@@ -305,7 +305,7 @@ class TransformerNet(nn.Module):
             ))
 
         x = self.make_final_norm()(x)
-        core_output = x.transpose(1, 0, 2).reshape(T * B, self.d_model)
+        core_output = x.transpose(1, 0, 2)  # [T, B, d], the head's layout
 
         out, _ = RecurrentPolicyHead(
             num_actions=self.num_actions,
@@ -314,7 +314,7 @@ class TransformerNet(nn.Module):
             num_layers=1,
             dtype=self.head_dtype,
             name="head",
-        )(core_output, done, (), T, B, sample_action)
+        )(core_output, done, (), sample_action)
         return out, tuple(new_state)
 
     # The two things a family built on this scaffolding replaces

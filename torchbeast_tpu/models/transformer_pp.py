@@ -301,7 +301,7 @@ class PipelinedTransformerNet(nn.Module):
             self.param("final_scale", nn.initializers.ones, (d,)),
             self.param("final_bias", nn.initializers.zeros, (d,)),
         )
-        core_output = x.transpose(1, 0, 2).reshape(T * B, d)
+        core_output = x.transpose(1, 0, 2)  # [T, B, d], the head's layout
 
         out, _ = RecurrentPolicyHead(
             num_actions=self.num_actions,
@@ -310,7 +310,7 @@ class PipelinedTransformerNet(nn.Module):
             num_layers=1,
             dtype=self.head_dtype,
             name="head",
-        )(core_output, done, (), T, B, sample_action)
+        )(core_output, done, (), sample_action)
         return out, new_state
 
     def initial_state(self, batch_size: int) -> Tuple:

@@ -8,6 +8,22 @@ shardings makes XLA compute per-shard gradients and insert the ICI
 all-reduce that keeps params replicated. No hand-written collectives — the
 compiler lays them on the ICI rings.
 
+"Per-shard" holds only while every op of the model keeps B a separate
+axis, or the MAJOR factor of a merged one: the SPMD partitioner cannot
+tile the minor factor of a merged axis, so it all-gathers the operand and
+every chip computes every row behind the merge. The conv trunks need one
+merged batch axis and merge it batch-major for that reason, and the
+shared head takes [T, B, D] (models/cores.py merge_time_batch,
+RecurrentPolicyHead); only the one-device update steps ask for the
+time-major merge, which is cheaper there (learner.one_device_model:
+this module compiles update_body with the model as it is handed in).
+The compiled program is pinned by
+tests/test_parallel.py::test_data_parallel_update_divides_the_model and,
+for the described v5e:2x2, tests/test_chip_compile.py: all-reduces only,
+a chip's FLOPs those of the one-chip program at B / n. (The transformer
+families' frame projection still merges time-major, models/
+transformer.py `obs_embed`: no cell runs it across chips, PERF.md §7.)
+
 Multi-host: call `initialize_distributed()` first (jax.distributed over
 DCN), then build the mesh over `jax.devices()` (global). Each host feeds
 its local shard of the batch via `make_global_batch` (device_put to local

@@ -112,6 +112,8 @@ def batch_sharding(mesh: Mesh, leading_axes: int = 0) -> NamedSharding:
 
     `leading_axes` prepends unsharded axes — 1 for the superstep's
     [K, T, B, ...] batch stacks, where B is still the sharded axis.
+    A model that merges T and B keeps this sharding only if B is the
+    merged axis's major factor (models/cores.merge_time_batch).
     """
     return NamedSharding(mesh, P(*([None] * (leading_axes + 1)), "data"))
 
