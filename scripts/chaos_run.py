@@ -675,9 +675,9 @@ def main(argv=None) -> int:
 
     # The harness calls train() directly, so it owns logging config —
     # without this the driver's step/health/chaos lines are invisible.
-    from torchbeast_tpu import polybeast as _polybeast
+    from torchbeast_tpu.utils import configure_logging
 
-    _polybeast._configure_logging()
+    configure_logging()
 
     from torchbeast_tpu import telemetry
     from torchbeast_tpu.resilience.chaos import FaultPlan

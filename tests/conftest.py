@@ -3,9 +3,17 @@ sharding/mesh test runs without an accelerator (SURVEY.md §4
 implication; `__graft_entry__.dryrun_multichip` uses the same
 mechanism). The variables are set before jax is imported — JAX reads
 them at import, and nothing here touches a backend.
+
+The native runtime is built here too, so that what the suite counts
+does not depend on what an earlier build left in the tree.
 """
 
+import fcntl
+import glob
 import os
+import subprocess
+import sys
+import warnings
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -21,3 +29,45 @@ from torchbeast_tpu.utils.xla_cache import use_compile_cache  # noqa: E402
 # Persistent compilation cache: repeat suite runs skip XLA recompiles.
 use_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+
+
+def _ensure_native():
+    """Make `_tbt_core` importable and no older than its sources: build
+    it in place (~20 s) unless a fresh one is there. One builder at a
+    time (xdist's workers each import this file): the others wait on
+    the lock and find the build done. Where it cannot be built (no C++
+    compiler) the native tests skip as they did, and the reason is a
+    warning in the run's summary."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def fresh():
+        sources = glob.glob(os.path.join(root, "csrc", "*"))
+        sources.append(os.path.join(root, "setup.py"))
+        built = glob.glob(os.path.join(root, "_tbt_core*.so"))
+        return bool(built) and min(map(os.path.getmtime, built)) >= max(
+            map(os.path.getmtime, sources)
+        )
+
+    if fresh():
+        return
+    os.makedirs(os.path.join(root, "build"), exist_ok=True)
+    with open(os.path.join(root, "build", ".native_build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if fresh():
+            return
+        try:
+            done = subprocess.run(
+                [sys.executable, "setup.py", "build_ext", "--inplace"],
+                cwd=root, capture_output=True, text=True, timeout=600,
+            )
+            failure = done.stderr[-2000:] if done.returncode else None
+        except (OSError, subprocess.TimeoutExpired) as e:
+            failure = repr(e)
+    if failure:
+        warnings.warn(
+            "_tbt_core could not be built, so the native tests skip: "
+            + failure
+        )
+
+
+_ensure_native()

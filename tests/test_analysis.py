@@ -921,20 +921,48 @@ class TestFlagParity:
         )
         assert len(check_flag_parity(a, b)) == 1
 
+    def _assert_declared_once_and_drift_caught(self, drifts):
+        """Each shared learner flag is declared ONCE, in
+        learner_setup.py (neither driver's file repeats it), and a
+        script that re-declares it beside polybeast's parser with a
+        drifted default is CAUGHT against learner_setup.py, the second
+        anchor of polybeast's groups — the parity net still covers it
+        where it now lives."""
+        def read(*parts):
+            with open(os.path.join(REPO, *parts)) as f:
+                return f.read()
+
+        setup = FileContext(
+            "torchbeast_tpu/learner_setup.py",
+            read("torchbeast_tpu", "learner_setup.py"),
+        )
+        poly = FileContext(
+            "torchbeast_tpu/polybeast.py",
+            read("torchbeast_tpu", "polybeast.py"),
+        )
+        mono_src = read("torchbeast_tpu", "monobeast.py")
+        for flag, (orig, drifted_frag) in drifts.items():
+            assert orig in setup.source, flag
+            assert f'"{flag}"' not in poly.source, flag
+            assert f'"{flag}"' not in mono_src, flag
+            same = FileContext(
+                "scripts/s.py", f"p.add_argument({orig})\n"
+            )
+            assert not check_flag_parity(setup, same)
+            assert not check_flag_parity(poly, same)
+            drifted = FileContext(
+                "scripts/s.py", f"p.add_argument({drifted_frag})\n"
+            )
+            found = check_flag_parity(setup, drifted)
+            assert any(flag in f.message for f in found), (
+                flag, [f.message for f in found],
+            )
+            assert all(f.path == "scripts/s.py" for f in found)
+
     def test_issue13_flags_present_and_drift_caught(self):
         """The three ISSUE 13 shared flags (--remat, --opt_impl,
-        --hbm_budget_gb) exist in BOTH drivers, agree right now (the
-        in-anger test below), and an injected default drift on each is
-        CAUGHT by the rule — the parity net actually covers them."""
-        with open(os.path.join(
-            REPO, "torchbeast_tpu", "monobeast.py"
-        )) as f:
-            mono_src = f.read()
-        with open(os.path.join(
-            REPO, "torchbeast_tpu", "polybeast.py"
-        )) as f:
-            poly_src = f.read()
-        drifts = {
+        --hbm_budget_gb)."""
+        self._assert_declared_once_and_drift_caught({
             "--remat": (
                 '"--remat", default=None',
                 '"--remat", default="all"',
@@ -947,34 +975,12 @@ class TestFlagParity:
                 '"--hbm_budget_gb", type=float, default=0.0',
                 '"--hbm_budget_gb", type=float, default=15.75',
             ),
-        }
-        mono = FileContext("torchbeast_tpu/monobeast.py", mono_src)
-        for flag, (orig, drifted_frag) in drifts.items():
-            assert orig in mono_src and orig in poly_src, flag
-            drifted = FileContext(
-                "torchbeast_tpu/polybeast.py",
-                poly_src.replace(orig, drifted_frag),
-            )
-            found = check_flag_parity(mono, drifted)
-            assert any(flag in f.message for f in found), (
-                flag, [f.message for f in found],
-            )
+        })
 
     def test_issue18_flags_present_and_drift_caught(self):
         """The three ISSUE 18 shared IMPACT flags (--impact_clip,
-        --replay_reuse, --target_refresh_updates) exist in BOTH
-        drivers, agree right now, and an injected default drift on each
-        is CAUGHT — the parity net covers the lag-tolerant learner's
-        knobs."""
-        with open(os.path.join(
-            REPO, "torchbeast_tpu", "monobeast.py"
-        )) as f:
-            mono_src = f.read()
-        with open(os.path.join(
-            REPO, "torchbeast_tpu", "polybeast.py"
-        )) as f:
-            poly_src = f.read()
-        drifts = {
+        --replay_reuse, --target_refresh_updates)."""
+        self._assert_declared_once_and_drift_caught({
             "--impact_clip": (
                 '"--impact_clip", type=float, default=0.2',
                 '"--impact_clip", type=float, default=0.3',
@@ -987,36 +993,7 @@ class TestFlagParity:
                 '"--target_refresh_updates", type=int, default=8',
                 '"--target_refresh_updates", type=int, default=80',
             ),
-        }
-        mono = FileContext("torchbeast_tpu/monobeast.py", mono_src)
-        for flag, (orig, drifted_frag) in drifts.items():
-            assert orig in mono_src and orig in poly_src, flag
-            drifted = FileContext(
-                "torchbeast_tpu/polybeast.py",
-                poly_src.replace(orig, drifted_frag),
-            )
-            found = check_flag_parity(mono, drifted)
-            assert any(flag in f.message for f in found), (
-                flag, [f.message for f in found],
-            )
-
-    def test_real_drivers_in_anger(self):
-        """Shared monobeast/polybeast flags agree on type+default; the
-        two known-intentional divergences (--model, --num_actors) are
-        suppressed inline WITH reasons, so the engine output is clean."""
-        report = analysis.analyze_paths(
-            list(lint_config.FLAG_PARITY_FILES), root=REPO
-        )
-        found = _rules(report, "FLAG-PARITY")
-        assert not found, [f.render() for f in found]
-        suppressed = [
-            (f, s) for f, s in report.suppressed
-            if f.rule == "FLAG-PARITY"
-        ]
-        assert {f.message.split(" ")[1] for f, _ in suppressed} == {
-            "--model", "--num_actors",
-        }
-        assert all(s.reason for _, s in suppressed)
+        })
 
 
 # ---------------------------------------------------------------------------

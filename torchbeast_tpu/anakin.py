@@ -30,27 +30,21 @@ import optax
 
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.envs.jax_env import create_jax_env
-from torchbeast_tpu.models import create_model
+from torchbeast_tpu.learner_setup import (
+    add_learner_arguments,
+    hparams_from_flags,
+    init_model_and_params,
+)
+from torchbeast_tpu.models import MODEL_NAMES
 from torchbeast_tpu.utils import (
     FileWriter,
+    configure_logging,
     load_checkpoint,
     save_checkpoint,
 )
 from torchbeast_tpu.utils.backend import log_backend
 
 log = logging.getLogger("torchbeast_tpu.anakin")
-
-
-def _configure_logging():
-    """Called from main(), NOT at import: importing this module (as
-    every test does) must not mutate global logging state."""
-    logging.basicConfig(
-        format=(
-            "[%(levelname)s:%(process)d %(module)s:%(lineno)d "
-            "%(asctime)s] %(message)s"
-        ),
-        level=logging.INFO,
-    )
 
 
 def _agent_out_dict(out):
@@ -72,41 +66,39 @@ class ActorCarry(NamedTuple):
     rng: Any
 
 
+# The families with a path through the JAX envs here; the others
+# (pipelined_transformer, olmoe) have none yet.
+_FAMILIES = ("shallow", "deep", "mlp", "pipelined_mlp", "transformer")
+
+
 def make_parser():
     parser = argparse.ArgumentParser(description=__doc__)
-    parser.add_argument("--env", default="Catch")
-    parser.add_argument("--xpid", default=None)
-    parser.add_argument("--savedir", default="~/logs/torchbeast_tpu")
-    parser.add_argument("--total_steps", type=int, default=200000)
-    parser.add_argument("--batch_size", type=int, default=64,
-                        help="Parallel on-device environments.")
-    parser.add_argument("--unroll_length", type=int, default=16)
-    parser.add_argument("--model", default="mlp",
-                        choices=["mlp", "shallow", "deep", "pipelined_mlp", "transformer"])
-    parser.add_argument("--use_lstm", action="store_true")
-    parser.add_argument("--num_experts", type=int, default=0,
-                        help="Transformer-only: top-2 MoE FFN with N "
-                             "experts (load-balance loss in objective).")
-    parser.add_argument("--seed", type=int, default=1234)
+    add_learner_arguments(
+        parser, model_default="mlp",
+        only=(
+            "--env", "--xpid", "--savedir", "--total_steps", "--batch_size",
+            "--unroll_length", "--model", "--use_lstm", "--num_experts",
+            "--seed", "--checkpoint_interval_s", "--entropy_cost",
+            "--entropy_cost_final", "--baseline_cost", "--discounting",
+            "--reward_clipping", "--learning_rate", "--alpha", "--momentum",
+            "--epsilon", "--grad_norm_clipping",
+        ),
+        overrides={
+            "--env": dict(default="Catch", type=None),
+            "--model": dict(
+                choices=[m for m in MODEL_NAMES if m in _FAMILIES]
+            ),
+            "--total_steps": dict(default=200000),
+            "--batch_size": dict(
+                default=64, help="Parallel on-device environments."
+            ),
+            "--unroll_length": dict(default=16),
+        },
+    )
     parser.add_argument("--num_devices", type=int, default=1,
                         help="Data-parallel devices (envs sharded, params "
                              "replicated, ICI all-reduce).")
-    parser.add_argument("--checkpoint_interval_s", type=int, default=600)
     parser.add_argument("--log_interval_updates", type=int, default=20)
-    # Loss/optimizer knobs (reference defaults).
-    parser.add_argument("--entropy_cost", type=float, default=0.0006)
-    parser.add_argument("--entropy_cost_final", type=float, default=None,
-                        help="Linearly anneal entropy cost to this over "
-                             "total_steps (default: constant).")
-    parser.add_argument("--baseline_cost", type=float, default=0.5)
-    parser.add_argument("--discounting", type=float, default=0.99)
-    parser.add_argument("--reward_clipping", default="abs_one",
-                        choices=["abs_one", "none"])
-    parser.add_argument("--learning_rate", type=float, default=4.8e-4)
-    parser.add_argument("--alpha", type=float, default=0.99)
-    parser.add_argument("--momentum", type=float, default=0.0)
-    parser.add_argument("--epsilon", type=float, default=0.01)
-    parser.add_argument("--grad_norm_clipping", type=float, default=40.0)
     return parser
 
 
@@ -247,31 +239,12 @@ def train(flags):
     )
 
     env = create_jax_env(flags.env)
-    hp = learner_lib.HParams(
-        discounting=flags.discounting,
-        baseline_cost=flags.baseline_cost,
-        entropy_cost=flags.entropy_cost,
-        entropy_cost_final=getattr(flags, "entropy_cost_final", None),
-        reward_clipping=flags.reward_clipping,
-        learning_rate=flags.learning_rate,
-        rmsprop_alpha=flags.alpha,
-        rmsprop_eps=flags.epsilon,
-        rmsprop_momentum=flags.momentum,
-        grad_norm_clipping=flags.grad_norm_clipping,
-        total_steps=flags.total_steps,
-        unroll_length=flags.unroll_length,
-        batch_size=flags.batch_size,
-    )
-    extra = {}
-    if getattr(flags, "num_experts", 0):
-        if flags.model != "transformer":
-            raise ValueError(
-                "--num_experts applies to --model transformer only"
-            )
-        extra["num_experts"] = flags.num_experts
-    model = create_model(
-        flags.model, num_actions=env.num_actions, use_lstm=flags.use_lstm,
-        **extra,
+    hp = hparams_from_flags(flags)
+    # The model only: its parameters come from `initial_carry`, whose
+    # keys derive from the run's one rng.
+    model, _ = init_model_and_params(
+        flags, env.num_actions, flags.batch_size, env.frame_shape,
+        init_params=False,
     )
     optimizer = learner_lib.make_optimizer(hp)
 
@@ -377,7 +350,7 @@ def train(flags):
 
 
 def main(flags):
-    _configure_logging()
+    configure_logging()
     log_backend(log, flags)
     return train(flags)
 

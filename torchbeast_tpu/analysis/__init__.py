@@ -16,9 +16,9 @@ offending file:line. The rules encode the repo's real runtime contracts:
                      swallows on the failure-handling layers)
     WIRE-PARITY      runtime/wire.py == csrc/{wire,array,client}.h on the
                      dtype table, frame tags, and kMaxFrameBytes
-    FLAG-PARITY      flags shared across driver pairs (mono/poly,
-                     poly/polybeast_env, poly/chaos_run) agree on
-                     default and type
+    FLAG-PARITY      flags a script re-declares beside polybeast's
+                     (polybeast_env, chaos_run, capacity_bench) agree
+                     on default and type
 
 Whole-program concurrency rules (ISSUE 7) ride the module -> call ->
 thread-root graph in analysis/graph.py plus the per-function sync

@@ -131,31 +131,33 @@ SPLITMIX64_SPEC = {
 # and every Python series builder must use exactly this prefix.
 SLICE_SERIES_PREFIX = "inference.slice."
 
-# FLAG-PARITY anchors: drivers whose shared flags must agree on type and
-# default. Intentional divergences carry inline suppressions at the
-# add_argument site (with the reason), not entries here — the exemption
-# should live next to the flag it exempts. Each pair is checked
-# independently; findings anchor in the SECOND file of the pair.
-FLAG_PARITY_FILES = (
-    "torchbeast_tpu/monobeast.py",
+# FLAG-PARITY groups: (anchor, second file). The flags the second file
+# re-declares must agree on type, default and action with the anchor's.
+# polybeast's parser is two anchors: its own file, and learner_setup.py,
+# where the learner flags are declared once for every driver (so no
+# driver pair is left to compare). Intentional divergences carry inline
+# suppressions at the add_argument site (with the reason), not entries
+# here; findings anchor in the SECOND file.
+_POLYBEAST_PARSER = (
     "torchbeast_tpu/polybeast.py",
+    "torchbeast_tpu/learner_setup.py",
 )
-FLAG_PARITY_GROUPS = (
-    FLAG_PARITY_FILES,
-    # The env-server group driver shares its address/supervision flags
-    # with the learner driver (polybeast spawns ServerSupervisor from
-    # the same knobs).
-    ("torchbeast_tpu/polybeast.py", "torchbeast_tpu/polybeast_env.py"),
-    # The chaos harness builds polybeast flag lists programmatically;
-    # the flags it re-declares for itself must not silently drift from
-    # the driver's meaning (its deliberately scaled-down defaults carry
-    # inline suppressions).
-    ("torchbeast_tpu/polybeast.py", "scripts/chaos_run.py"),
-    # The capacity bench re-declares the driver flags its subprocess
-    # rows forward (ISSUE 16); its deliberately scaled-down / armed-by-
-    # default values carry inline suppressions at the add_argument
-    # sites.
-    ("torchbeast_tpu/polybeast.py", "benchmarks/capacity_bench.py"),
+FLAG_PARITY_GROUPS = tuple(
+    (anchor, second)
+    for second in (
+        # The env-server group driver shares its address/supervision
+        # flags with the learner driver (polybeast spawns
+        # ServerSupervisor from the same knobs).
+        "torchbeast_tpu/polybeast_env.py",
+        # The chaos harness builds polybeast flag lists
+        # programmatically; the flags it re-declares for itself must
+        # not silently drift from the driver's meaning.
+        "scripts/chaos_run.py",
+        # The capacity bench re-declares the driver flags its
+        # subprocess rows forward (ISSUE 16).
+        "benchmarks/capacity_bench.py",
+    )
+    for anchor in _POLYBEAST_PARSER
 )
 
 # Whole-program concurrency analysis scope (RACE / LOCK-ORDER /

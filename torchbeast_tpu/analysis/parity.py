@@ -16,7 +16,7 @@ import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from . import config
-from .engine import FileContext, Finding
+from .engine import FileContext, Finding, load_context
 
 # ---------------------------------------------------------------------------
 # C++ parsing helpers
@@ -820,10 +820,15 @@ class FlagParityRule:
         by_path = {ctx.path: ctx for ctx in contexts}
         findings: List[Finding] = []
         for path_a, path_b in config.FLAG_PARITY_GROUPS:
-            ctx_a, ctx_b = by_path.get(path_a), by_path.get(path_b)
-            if ctx_a is None or ctx_b is None:
+            ctx_b = by_path.get(path_b)
+            if ctx_b is None:
                 continue  # partial scan: this pair not in scope
-            findings.extend(check_flag_parity(ctx_a, ctx_b))
+            # The anchor is read even when the scan did not name it.
+            ctx_a = by_path.get(path_a) or load_context(
+                os.path.join(root, path_a), root
+            )
+            if ctx_a is not None:
+                findings.extend(check_flag_parity(ctx_a, ctx_b))
         return findings
 
 

@@ -48,3 +48,14 @@ def create_env(name: str, seed=None, **kwargs):
     from torchbeast_tpu.envs.atari import create_atari_env
 
     return create_atari_env(name, seed=seed, **kwargs)
+
+
+def probe_env(name: str):
+    """One throwaway env instance -> (num_actions, frame shape, frame
+    dtype): what a driver needs of the env to build its model."""
+    probe = create_env(name)
+    n = num_actions_of(probe)
+    frame = Environment(probe).initial()["frame"]
+    if hasattr(probe, "close"):
+        probe.close()
+    return int(n), frame.shape, frame.dtype

@@ -5,6 +5,8 @@ MonoBeast's AtariNet (monobeast.py:545) and PolyBeast's deep ResNet
 (polybeast_learner.py:134).
 """
 
+import dataclasses
+
 from torchbeast_tpu.models.atari_net import AtariNet  # noqa: F401
 from torchbeast_tpu.models.cores import LSTMCore  # noqa: F401
 from torchbeast_tpu.models.mlp import MLPNet  # noqa: F401
@@ -17,19 +19,34 @@ from torchbeast_tpu.models.transformer_pp import (  # noqa: F401
     PipelinedTransformerNet,
 )
 
+# The one list of policy families: `--model`'s choices in every driver,
+# and what `create_model` builds.
 _REGISTRY = {
     "shallow": AtariNet,
-    "atari": AtariNet,
     "deep": ResNet,
-    "resnet": ResNet,
     "mlp": MLPNet,
     "pipelined_mlp": PipelinedMLPNet,
     "transformer": TransformerNet,
     "pipelined_transformer": PipelinedTransformerNet,
     "olmoe": OLMoENet,
 }
-# Families whose memory is a KV cache: --use_lstm does not apply.
-_KV_CACHE_FAMILIES = (TransformerNet, PipelinedTransformerNet, OLMoENet)
+MODEL_NAMES = tuple(_REGISTRY)
+
+
+def takes_flag(name: str, field: str) -> bool:
+    """Whether `--<field>` sets a field of family `name`'s module: its
+    class declares the field and does not refuse it (a class lists in
+    `flag_refused_fields` the fields another flag or its published
+    table sets)."""
+    cls = _REGISTRY[name]
+    return field not in getattr(cls, "flag_refused_fields", ()) and any(
+        f.name == field for f in dataclasses.fields(cls)
+    )
+
+
+def families_taking(field: str):
+    """The registry's names of the families `--<field>` reaches."""
+    return [name for name in _REGISTRY if takes_flag(name, field)]
 
 
 def create_model(name: str, num_actions: int, use_lstm: bool = False, **kwargs):
@@ -39,7 +56,7 @@ def create_model(name: str, num_actions: int, use_lstm: bool = False, **kwargs):
         raise ValueError(
             f"Unknown model {name!r}; available: {sorted(_REGISTRY)}"
         ) from None
-    if cls in _KV_CACHE_FAMILIES and use_lstm:
+    if getattr(cls, "memory_is_kv_cache", False) and use_lstm:
         raise ValueError(
             "--use_lstm does not apply to the transformer family (its "
             "memory is the KV cache); drop the flag"

@@ -125,6 +125,10 @@ class _OLMoEBlock(nn.Module):
 
 
 class OLMoENet(TransformerNet):
+    # Fields the published table sets, or that the block does not read:
+    # no flag reaches them (models/__init__.py `takes_flag`).
+    flag_refused_fields = ("num_experts", "attention_impl")
+
     num_layers: int = PUBLISHED["num_layers"]
     d_model: int = PUBLISHED["d_model"]
     num_heads: int = PUBLISHED["num_heads"]

@@ -199,6 +199,10 @@ class _Block(nn.Module):
 
 
 class TransformerNet(nn.Module):
+    # The family's memory is its KV cache: --use_lstm does not apply
+    # (models/__init__.py `create_model`).
+    memory_is_kv_cache = True
+
     num_actions: int
     use_lstm: bool = False  # accepted for registry uniformity; unused
     num_layers: int = 2

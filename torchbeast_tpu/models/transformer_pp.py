@@ -118,6 +118,12 @@ class PipelinedTransformerNet(nn.Module):
         "w1", "b1", "w2", "b2",
     )
 
+    memory_is_kv_cache = True  # --use_lstm does not apply
+    # Depth is --pipeline_stages' to set (it must divide over the pipe
+    # mesh), and the window has no flag here: --num_layers and
+    # --memory_len are refused (models/__init__.py `takes_flag`).
+    flag_refused_fields = ("num_layers", "memory_len")
+
     num_actions: int
     use_lstm: bool = False  # accepted for registry uniformity; unused
     num_layers: int = 4

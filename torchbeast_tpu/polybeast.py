@@ -29,11 +29,12 @@ from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu import precision as precision_lib
 from torchbeast_tpu import telemetry
 from torchbeast_tpu import polybeast_env
-from torchbeast_tpu.monobeast import (
-    _init_model_and_params,
-    _probe_env,
+from torchbeast_tpu.envs import probe_env
+from torchbeast_tpu.learner_setup import (
+    add_learner_arguments,
     dummy_env_outputs,
     hparams_from_flags,
+    init_model_and_params,
 )
 from torchbeast_tpu.runtime import wire
 from torchbeast_tpu.runtime.actor_pool import ActorPool
@@ -46,6 +47,7 @@ from torchbeast_tpu.runtime.queues import (
 from torchbeast_tpu.utils import (
     FileWriter,
     Timings,
+    configure_logging,
     load_checkpoint,
     save_checkpoint,
 )
@@ -54,29 +56,14 @@ from torchbeast_tpu.utils.backend import log_backend
 log = logging.getLogger("torchbeast_tpu.polybeast")
 
 
-def _configure_logging():
-    """Called from main(), NOT at import: importing this module (as
-    every test does) must not mutate global logging state."""
-    logging.basicConfig(
-        format=(
-            "[%(levelname)s:%(process)d %(module)s:%(lineno)d "
-            "%(asctime)s] %(message)s"
-        ),
-        level=logging.INFO,
-    )
-
-
 def make_parser():
     parser = argparse.ArgumentParser(description=__doc__)
+    add_learner_arguments(
+        parser, model_default="deep", num_actors_default=None
+    )
+    # The async driver's own.
     parser.add_argument("--pipes_basename", default="unix:/tmp/torchbeast_tpu")
-    # beastlint: disable=FLAG-PARITY  poly derives the default from --num_servers; mono has no servers
-    parser.add_argument("--num_actors", type=int, default=None,
-                        help="Actor loops (default: one per server).")
     parser.add_argument("--num_servers", type=int, default=4)
-    parser.add_argument("--env", type=str, default="PongNoFrameskip-v4")
-    parser.add_argument("--mode", default="train", choices=["train", "test"])
-    parser.add_argument("--num_test_episodes", type=int, default=10)
-    parser.add_argument("--xpid", default=None)
     parser.add_argument("--start_servers", dest="start_servers",
                         action="store_true", default=True,
                         help="Spawn local env servers (the combined "
@@ -84,58 +71,6 @@ def make_parser():
     parser.add_argument("--no_start_servers", dest="start_servers",
                         action="store_false",
                         help="Connect to externally-launched servers.")
-    # Training.
-    parser.add_argument("--savedir", default="~/logs/torchbeast_tpu")
-    parser.add_argument("--total_steps", type=int, default=100000)
-    parser.add_argument("--batch_size", type=int, default=8)
-    parser.add_argument("--vtrace_impl", default="associative",
-                        choices=["sequential", "associative", "pallas"],
-                        help="V-trace backward recursion: "
-                             "lax.associative_scan (O(log T) depth, the "
-                             "default), lax.scan (the reference's "
-                             "T-dependent-steps formulation), or the "
-                             "fused Pallas kernel (vs + advantages in "
-                             "one VMEM pass; TPU-compiled, interpreted "
-                             "elsewhere).")
-    parser.add_argument("--unroll_length", type=int, default=80)
-    # beastlint: disable=FLAG-PARITY  paper defaults differ: polybeast trains the deep IMPALA net, monobeast the shallow one
-    parser.add_argument("--model", default="deep",
-                        choices=["shallow", "deep", "mlp", "pipelined_mlp", "transformer", "pipelined_transformer", "olmoe"])
-    parser.add_argument("--use_lstm", action="store_true")
-    parser.add_argument("--precision", default="f32",
-                        choices=["f32", "bf16_compute", "bf16_train"],
-                        help="Precision policy (torchbeast_tpu/"
-                             "precision.py): f32 everywhere; "
-                             "bf16_compute flips trunk compute to "
-                             "bfloat16; bf16_train additionally makes "
-                             "params/activations bf16-RESIDENT (f32 "
-                             "master in the optimizer state, f32 "
-                             "accumulate), stages the batch's float "
-                             "leaves as bf16, and stores the RMSprop "
-                             "second moment bf16 — the HBM-roofline "
-                             "policy.")
-    parser.add_argument("--model_dtype", default=None,
-                        choices=["float32", "bfloat16"],
-                        help="DEPRECATED alias: bfloat16 maps to "
-                             "--precision bf16_compute (with a "
-                             "warning); conflicts with an explicit "
-                             "bf16_train.")
-    parser.add_argument("--factored_opt_state", action="store_true",
-                        help="Opt-in factored RMSprop second moment "
-                             "(row/col EMAs for matrices, Adafactor-"
-                             "style O(n+m) state; an approximation — "
-                             "not torch-parity).")
-    parser.add_argument("--trunk_channels", default="",
-                        help="Opt-in deep-trunk widths as a comma list "
-                             "(e.g. 32,64,64; default: the reference's "
-                             "16/32/32). See monobeast and "
-                             "benchmarks/mfu_ablation.py.")
-    parser.add_argument("--seed", type=int, default=1234)
-    parser.add_argument("--env_seed", type=int, default=None,
-                        help="Base seed for stochastic envs (see "
-                             "polybeast_env --env_seed). Multi-host runs "
-                             "offset it per host so no two hosts share a "
-                             "stream. Default: OS entropy per env.")
     parser.add_argument("--num_inference_threads", type=int, default=2)
     # Tri-state: None (default) = native-first with a clean, logged
     # fallback to the Python pool when _tbt_core is absent/stale;
@@ -169,85 +104,6 @@ def make_parser():
                         help="Supervision budget for spawned env servers "
                              "(see polybeast_env --max_server_restarts); "
                              "0 disables restarts.")
-    parser.add_argument("--sequence_parallel", type=int, default=0,
-                        help="Shard the transformer's unroll (time) axis "
-                             "over N devices (ring attention over a `seq` "
-                             "mesh; model=transformer only, unroll_length+1 "
-                             "divisible by N; acting falls back to dense).")
-    parser.add_argument("--sp_strategy", default="ring",
-                        choices=["ring", "ulysses"],
-                        help="Sequence-parallel strategy: ppermute ring "
-                             "or all-to-all head sharding (ulysses; "
-                             "needs num_heads divisible by N).")
-    parser.add_argument("--ring_schedule", default="contiguous",
-                        choices=["contiguous", "zigzag"],
-                        help="Ring attention block schedule (zigzag "
-                             "balances causal work; unroll_length+1 "
-                             "divisible by 2N).")
-    parser.add_argument("--pipeline_parallel", type=int, default=0,
-                        help="Run the pipelined_mlp / "
-                             "pipelined_transformer tower as a GPipe "
-                             "pipeline over N devices (a `pipe` mesh "
-                             "axis). MLP tower depth = N; the "
-                             "transformer keeps its own num_layers.")
-    parser.add_argument("--pipeline_microbatches", type=int, default=0,
-                        help="Microbatch count M for the GPipe schedule "
-                             "(default: one per pipeline device; raise "
-                             "to amortize the (P-1)/(M+P-1) bubble).")
-    parser.add_argument("--num_layers", type=int, default=0,
-                        help="Depth of --model transformer or olmoe "
-                             "(0: the family's own, 2 and the published "
-                             "16).")
-    parser.add_argument("--memory_len", type=int, default=0,
-                        help="Steps of its own past a transformer or "
-                             "olmoe policy attends over, carried as the "
-                             "rolling KV cache (0: the family's own, 64 "
-                             "and 128).")
-    parser.add_argument("--num_experts", type=int, default=0,
-                        help="Replace the transformer's FFN with a top-2 "
-                             "mixture of N experts (model=transformer "
-                             "only; adds a sown load-balance loss).")
-    parser.add_argument("--expert_parallel", type=int, default=0,
-                        help="Shard the MoE experts over N devices "
-                             "(an `expert` mesh axis; dispatch/combine "
-                             "become XLA all-to-alls).")
-    parser.add_argument("--transformer_remat", action="store_true",
-                        help="DEPRECATED spelling of --remat with the "
-                             "transformer blocks stage at 'all' "
-                             "(conflicts with an explicit --remat).")
-    parser.add_argument("--remat", default=None,
-                        help="Rematerialization plan over the model's "
-                             "remat-able stages (runtime/remat_plan.py: "
-                             "the ResNet trunk's per-stage none/front/"
-                             "all, the transformer families' block "
-                             "remat, the LSTM scan): 'auto' picks the "
-                             "minimum-recompute plan whose XLA-measured "
-                             "peak fits --hbm_budget_gb; 'all'/'none' "
-                             "force every stage; 'stage0=front,"
-                             "stage1=all,core=none' pins per stage. "
-                             "Default: the static pre-planner defaults "
-                             "(trunk all-remat, transformer per "
-                             "--transformer_remat, LSTM scan saved). "
-                             "The chosen plan is logged and exported "
-                             "as the learner.remat_plan telemetry "
-                             "static.")
-    parser.add_argument("--hbm_budget_gb", type=float, default=0.0,
-                        help="HBM envelope for --remat auto, in GiB "
-                             "covering one live update dispatch "
-                             "(params + optimizer state + staged "
-                             "[K, T+1, B] stack + XLA temps). 0 = the "
-                             "device's reported limit, else the "
-                             "15.75 GiB v5e default.")
-    parser.add_argument("--opt_impl", default="xla",
-                        choices=["xla", "pallas"],
-                        help="Optimizer-tail implementation: 'xla' "
-                             "composes the optax chain; 'pallas' runs "
-                             "grad-clip finalize -> torch-RMSprop/"
-                             "momentum -> f32 master write -> bf16 "
-                             "narrowing cast as ONE VMEM-resident "
-                             "kernel per leaf (ops/pallas_opt.py; "
-                             "TPU-compiled, interpreted elsewhere; "
-                             "identical numerics, pinned by test).")
     parser.add_argument("--tensor_parallel", type=int, default=0,
                         help="Megatron column/row-paired tensor "
                              "parallelism for the transformer over a "
@@ -258,32 +114,6 @@ def make_parser():
                              "--num_learner_devices DP on one "
                              "(data x model) mesh; model=transformer "
                              "only.")
-    parser.add_argument("--device_split", default="",
-                        help="Sebulba device split (runtime/placement."
-                             "py): partition jax.devices() into "
-                             "dedicated inference slices + a learner "
-                             "mesh, so acting batches never time-share "
-                             "a chip with the update step. 'auto' pins "
-                             "1 of every 4 devices to inference; "
-                             "'inf=K,learn=rest' (or learn=M) pins "
-                             "exactly. Each inference device is one "
-                             "slice with its own batcher and pinned "
-                             "DeviceStateTable; actors hash statically "
-                             "to slices (slot state never migrates); "
-                             "slices serve versioned snapshots "
-                             "published device-to-device through the "
-                             "PolicySnapshotStore (--replica_refresh_"
-                             "updates sets the cadence, default every "
-                             "update; --max_policy_lag degradation "
-                             "applies per slice). The learner superstep "
-                             "compiles over the remaining devices as a "
-                             "DP mesh (batch_size divisible by learner "
-                             "device count). Empty = today's "
-                             "time-shared path; a single-device "
-                             "process degrades to it with a warning. "
-                             "Both runtimes: under --native_runtime "
-                             "the slot-hash routing runs in the C++ "
-                             "pool (csrc/routing.h), GIL-free.")
     parser.add_argument("--admission_depth_factor", type=int, default=4,
                         help="Admission-gate queue-depth bound as a "
                              "multiple of --max_inference_batch_size "
@@ -313,49 +143,11 @@ def make_parser():
                              "admission behavior): requests wait for "
                              "the next batch formation cycle even when "
                              "the in-flight window has room.")
-    parser.add_argument("--num_learner_devices", type=int, default=1,
-                        help="Width of the DATA-parallel axis: params "
-                             "replicated, batch sharded over it, ICI "
-                             "all-reduce for grads; batch_size must be "
-                             "divisible by it. With --expert_parallel K "
-                             "the learner consumes N x K chips total "
-                             "(one (data x expert) mesh).")
     parser.add_argument("--coordinator_address", default=None,
                         help="Multi-host: jax.distributed coordinator "
                              "(host:port); also reads "
                              "TORCHBEAST_COORDINATOR / _NUM_PROCESSES / "
                              "_PROCESS_ID env vars.")
-    parser.add_argument("--fleet", default=None,
-                        help="Multi-host Sebulba fleet membership "
-                             "(fleet/topology.py): 'host=<rank>/<n>,"
-                             "coord=<host:port>' names this host's "
-                             "rank, the fleet size, and the shared "
-                             "coordination endpoint (jax.distributed "
-                             "rendezvous on TPU/GPU; port+1 carries "
-                             "the fleet control plane — health "
-                             "heartbeats, policy snapshots, param "
-                             "sync — on every backend). Composes with "
-                             "--device_split: each host pins its OWN "
-                             "inference slices and the learner's data "
-                             "axis spans every host's learner devices "
-                             "over DCN; forced-CPU hosts compose by "
-                             "synchronous parameter averaging instead "
-                             "(the CI strategy — parallel/dp.py "
-                             "fleet_strategy). Remote hosts' slices "
-                             "serve versioned bf16 snapshots the lead "
-                             "publishes over the wire (TAG_SNAPSHOT). "
-                             "Unset = single-host, today's paths "
-                             "unchanged.")
-    parser.add_argument("--min_live_hosts", type=int, default=1,
-                        help="Fleet degradation floor (--fleet runs): "
-                             "losing a host marks the fleet DEGRADED "
-                             "(sticky fleet.host<r>_lost) while at "
-                             "least this many hosts stay live; "
-                             "crossing below it halts the WHOLE fleet "
-                             "cleanly (checkpoint-and-exit on every "
-                             "host, via the broadcast verdict) instead "
-                             "of wedging the survivors' param-"
-                             "composition plane.")
     parser.add_argument("--device_agent_state", dest="device_agent_state",
                         action="store_true", default=True,
                         help="Keep recurrent agent state in a device-"
@@ -425,19 +217,6 @@ def make_parser():
                              "allocating (a corrupt 4-byte header must "
                              "surface as WireError, not a multi-GiB "
                              "allocation).")
-    parser.add_argument("--superstep_k", type=int, default=1,
-                        help="Learner superstep: fuse K SGD updates into "
-                             "ONE lax.scan dispatch — rollouts drain "
-                             "into a preallocated [K, T+1, B, ...] host "
-                             "arena, the prefetcher stages the whole "
-                             "stack as one transfer riding behind the "
-                             "previous superstep's compute, and stats "
-                             "come back [K]-stacked (one host sync per "
-                             "K updates). Bit-identical to K sequential "
-                             "dispatches; schedules tick per-update "
-                             "inside the scan. 1 = today's per-update "
-                             "dispatch. Works on both runtimes (the "
-                             "C++ queue has the same raw-item intake).")
     parser.add_argument("--max_learner_queue_size", type=int, default=None,
                         help="Backpressure bound (default: batch_size).")
     parser.add_argument("--actor_connect_timeout_s", type=float,
@@ -475,13 +254,6 @@ def make_parser():
                              "and restart the serving threads before "
                              "the pipeline goes HALTED "
                              "(checkpoint-and-exit).")
-    parser.add_argument("--learner_stall_timeout_s", type=float,
-                        default=300.0,
-                        help="Learner stall watchdog: no update "
-                             "dispatch within this deadline transitions "
-                             "health to DEGRADED and dumps thread-stack "
-                             "diagnostics; dispatches resuming recovers "
-                             "it. 0 disables the watchdog.")
     parser.add_argument("--chaos_plan", default=None,
                         help="Arm a deterministic fault-injection plan "
                              "(JSON, see resilience/chaos.py: seeded "
@@ -492,43 +264,7 @@ def make_parser():
                              "preemption). Injected faults are counted "
                              "in telemetry so recovery can be asserted "
                              "exactly (scripts/chaos_run.py).")
-    parser.add_argument("--checkpoint_interval_s", type=int, default=600)
     telemetry.add_arguments(parser)
-    # Loss / optimizer (same knobs as monobeast).
-    parser.add_argument("--entropy_cost", type=float, default=0.0006)
-    parser.add_argument("--entropy_cost_final", type=float, default=None,
-                        help="Linearly anneal entropy cost to this over "
-                             "total_steps (default: constant). See "
-                             "monobeast --entropy_cost_final.")
-    parser.add_argument("--baseline_cost", type=float, default=0.5)
-    parser.add_argument("--discounting", type=float, default=0.99)
-    parser.add_argument("--reward_clipping", default="abs_one",
-                        choices=["abs_one", "none"])
-    parser.add_argument("--loss", default="vtrace",
-                        choices=["vtrace", "impact"],
-                        help="Objective family: IMPALA V-trace (the "
-                             "default) or the IMPACT clipped "
-                             "target-network surrogate (ops/impact.py) "
-                             "— lag-tolerant, unlocks --replay_reuse. "
-                             "Under impact the default "
-                             "--replica_refresh_updates relaxes ~10x "
-                             "(the surrogate absorbs the extra lag).")
-    parser.add_argument("--impact_clip", type=float, default=0.2,
-                        help="IMPACT surrogate clip epsilon "
-                             "(--loss impact).")
-    parser.add_argument("--replay_reuse", type=int, default=1,
-                        help="Consume each collected batch K' times "
-                             "(--loss impact; 1 = on-policy). The "
-                             "schedule clock scales with it.")
-    parser.add_argument("--target_refresh_updates", type=int, default=8,
-                        help="Refresh the IMPACT target network every "
-                             "N optimizer updates (--loss impact).")
-    parser.add_argument("--learning_rate", type=float, default=4.8e-4)
-    parser.add_argument("--alpha", type=float, default=0.99)
-    parser.add_argument("--momentum", type=float, default=0.0)
-    parser.add_argument("--epsilon", type=float, default=0.01)
-    parser.add_argument("--grad_norm_clipping", type=float, default=40.0)
-    parser.add_argument("--profile_dir", default=None)
     return parser
 
 
@@ -886,7 +622,7 @@ def train(flags):
                 pipe_parallelism=max(1, pipe_par),
             )
 
-        model, params = _init_model_and_params(
+        model, params = init_model_and_params(
             flags, num_actions, flags.batch_size, frame_shape, frame_dtype,
             moe_mesh=learner_mesh if expert_par > 1 else None,
             seq_mesh=learner_mesh if seq_par > 1 else None,
@@ -1109,7 +845,7 @@ def train(flags):
             # touch non-addressable devices. Acting uses an unmeshed twin —
             # identical flags and param tree, no mesh bindings (meshes only
             # select compute paths, never parameters).
-            act_model, _ = _init_model_and_params(
+            act_model, _ = init_model_and_params(
                 flags, num_actions, flags.batch_size, frame_shape,
                 frame_dtype, unmeshed=True, init_params=False,
             )
@@ -2591,11 +2327,11 @@ def _probe_env_via_server(flags, address, timeout_s: float = 60.0):
         "Could not probe env spec from %s (%s); probing locally.",
         address, last_error,
     )
-    return _probe_env(flags)
+    return probe_env(flags.env)
 
 
 def main(flags):
-    _configure_logging()
+    configure_logging()
     if flags.mode == "test":
         # Greedy checkpoint evaluation — shared with the mono driver. (The
         # reference's poly test() is a NotImplementedError,

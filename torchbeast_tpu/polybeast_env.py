@@ -16,22 +16,10 @@ import time
 
 from torchbeast_tpu import telemetry
 from torchbeast_tpu.resilience.backoff import Backoff
+from torchbeast_tpu.utils import configure_logging
 from torchbeast_tpu.utils.spawn import start_cpu_pinned
 
 log = logging.getLogger("torchbeast_tpu.polybeast_env")
-
-
-def _configure_logging():
-    """Called from main(), NOT at import: importing this module (as the
-    learner driver and every test does) must not mutate global logging
-    state."""
-    logging.basicConfig(
-        format=(
-            "[%(levelname)s:%(process)d %(module)s:%(lineno)d "
-            "%(asctime)s] %(message)s"
-        ),
-        level=logging.INFO,
-    )
 
 
 def make_parser():
@@ -93,7 +81,7 @@ def _serve(env_name: str, address: str, native: bool = False,
     # but never run main(), so the child configures its own logging
     # (INFO lines like "EnvServer listening" would otherwise be lost
     # now that import no longer calls basicConfig).
-    _configure_logging()
+    configure_logging()
     # SIGTERM (reap_group's terminate, a k8s preemption) must run this
     # child's teardown — for shm servers that is the owner-side ring
     # unlink sweep (EnvServer.stop). The default handler kills the
@@ -324,7 +312,7 @@ class ServerSupervisor:
 
 
 def main(flags):
-    _configure_logging()
+    configure_logging()
     # SIGTERM must run the finally below: Python's default handler kills
     # the process without atexit/finally, orphaning the daemonic server
     # children (ppid 1, still serving their ports) — exactly what

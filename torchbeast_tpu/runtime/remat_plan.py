@@ -71,7 +71,7 @@ def stages_for(model: str, use_lstm: bool) -> List[Stage]:
     """The remat lattice of one model family (empty = nothing to plan:
     the feed-forward MLP/AtariNet trunks are not remat-able levers)."""
     stages: List[Stage] = []
-    if model in ("deep", "resnet"):
+    if model == "deep":
         for i in range(3):
             stages.append(Stage(f"stage{i}", (False, "front", True)))
     if model in ("transformer", "pipelined_transformer"):
@@ -84,7 +84,7 @@ def stages_for(model: str, use_lstm: bool) -> List[Stage]:
 def model_kwargs(model: str, assignment: Dict[str, Any]) -> Dict[str, Any]:
     """Assignment -> create_model(**kwargs) for the family's levers."""
     kwargs: Dict[str, Any] = {}
-    if model in ("deep", "resnet"):
+    if model == "deep":
         kwargs["remat"] = tuple(
             assignment[f"stage{i}"] for i in range(3)
         )
