@@ -1,7 +1,8 @@
 """Env-server process group driver (the reference's polybeast_env.py role,
 /root/reference/torchbeast/polybeast_env.py:61-89): spawn `num_servers`
 processes, each serving environments on `{pipes_basename}.{i}` over the
-framed-socket protocol.
+framed-socket protocol, every accepted stream in a child process of its
+own.
 
 Run:  python -m torchbeast_tpu.polybeast_env --num_servers 4 --env Mock
 """
@@ -29,7 +30,16 @@ def make_parser():
                              "(unix:/path, host:baseport, or shm:/path "
                              "for shared-memory rings when the servers "
                              "are co-located with the learner host).")
-    parser.add_argument("--num_servers", type=int, default=4)
+    parser.add_argument("--num_servers", type=int, default=4,
+                        help="How many addresses to serve on (one "
+                             "listener process each). Addresses, not "
+                             "parallelism: a listener forks a process "
+                             "for every stream it accepts, so env "
+                             "stepping spreads over the host's cores "
+                             "however many actors share an address. A "
+                             "stream's process costs host memory: a "
+                             "few MB of its own on top of the pages it "
+                             "shares with its listener.")
     parser.add_argument("--env", type=str, default="PongNoFrameskip-v4",
                         help="Gym environment (or Mock / Counting).")
     parser.add_argument("--env_seed", type=int, default=None,
@@ -90,20 +100,9 @@ def _serve(env_name: str, address: str, native: bool = False,
 
     install_preemption_handler()
     # Import here: workers must never inherit JAX state.
-    from torchbeast_tpu.envs import create_env
+    from torchbeast_tpu.envs import Environment, create_env
 
-    if seed_base is None:
-        env_init = functools.partial(create_env, env_name)
-    else:
-        # Fresh env per actor stream (both server impls call env_init
-        # once per connection): stream s draws seed_base + s. The
-        # counter is GIL-guarded — the native server, too, invokes
-        # env_init holding the GIL. Reproducible seed SET; which stream
-        # gets which seed follows connection order.
-        counter = itertools.count()
-
-        def env_init():
-            return create_env(env_name, seed=seed_base + next(counter))
+    make_env = functools.partial(create_env, env_name)
     if native:
         from torchbeast_tpu.runtime.native import import_native
 
@@ -113,19 +112,41 @@ def _serve(env_name: str, address: str, native: bool = False,
                 "--native_server requested but _tbt_core is not built; "
                 "run scripts/build_native.sh"
             )
+        env_init = make_env
+        if seed_base is not None:
+            # Fresh env per actor stream (the server calls env_init
+            # once per connection, holding the GIL): stream s draws
+            # seed_base + s. Reproducible seed SET; which stream gets
+            # which seed follows connection order.
+            counter = itertools.count()
+
+            def env_init():
+                return make_env(seed=seed_base + next(counter))
         core.EnvServer(env_init, address).run()
         return
     from torchbeast_tpu.runtime.env_server import EnvServer
 
-    server = EnvServer(env_init, address)
+    try:
+        # One throwaway env, so that whatever an env imports on first
+        # use (gymnasium, an emulator) is imported here, once, and not
+        # in every stream's child.
+        Environment(make_env()).close()
+    except Exception:  # noqa: BLE001 - a stream reports it to its client
+        log.exception("Could not build a first %s env", env_name)
+    # This process is the server and nothing else (one thread, no JAX),
+    # so every stream gets a child of its own, forked from here: an env
+    # step then holds no other stream's GIL. The same seeds as above,
+    # drawn by the listener in accept order.
+    server = EnvServer(make_env, address, seed_base=seed_base,
+                       stream_processes=True)
     try:
         server.run()
     except KeyboardInterrupt:
         log.info("Env server on %s preempted; cleaning up.", address)
     finally:
-        # stop() severs live streams and runs the owner-side shm
-        # unlink sweep — the difference between a preempted shm server
-        # and a /dev/shm leak.
+        # stop() severs live streams (ends their processes) and runs
+        # the owner-side shm unlink sweep — the difference between a
+        # preempted shm server and a /dev/shm leak.
         server.stop()
 
 

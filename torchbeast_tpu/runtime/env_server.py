@@ -18,9 +18,13 @@ co-located with the learner process (runtime/transport.py): obs/action
 frames skip the socket data plane entirely.
 """
 
+import ctypes
+import itertools
 import logging
 import os
+import signal
 import socket
+import sys
 import threading
 import time
 from typing import Callable, Optional
@@ -35,8 +39,30 @@ from torchbeast_tpu.runtime import wire
 # Re-exported: parse_address lived here before the transport module
 # existed and tests/drivers import it from this path.
 from torchbeast_tpu.runtime.transport import parse_address  # noqa: F401
+from torchbeast_tpu.utils.preempt import install_preemption_handler
 
 log = logging.getLogger(__name__)
+
+# How often a listener that forks its streams wakes from accept() to
+# reap the children that have exited.
+_REAP_PERIOD_S = 0.5
+_PR_SET_PDEATHSIG = 1  # <linux/prctl.h>
+
+
+def _die_with_parent() -> None:
+    """Have the kernel SIGTERM this process when its parent dies (a
+    SIGKILLed listener cannot end its streams itself, and a dead server
+    has always meant dropped streams: the supervisor's and the actors'
+    accounting count on it). Linux only; elsewhere an orphaned stream
+    serves until its client hangs up."""
+    if not sys.platform.startswith("linux"):
+        return
+    prctl = ctypes.CDLL(None, use_errno=True).prctl
+    prctl.argtypes = [ctypes.c_int, ctypes.c_ulong, ctypes.c_ulong,
+                      ctypes.c_ulong, ctypes.c_ulong]
+    prctl.restype = ctypes.c_int
+    if prctl(_PR_SET_PDEATHSIG, signal.SIGTERM, 0, 0, 0) != 0:
+        raise OSError(ctypes.get_errno(), "prctl(PR_SET_PDEATHSIG)")
 
 
 def _step_to_message(step) -> dict:
@@ -46,18 +72,36 @@ def _step_to_message(step) -> dict:
 
 
 class EnvServer:
-    """Serve env streams; one thread per connection."""
+    """Serve env streams: one thread per connection or, where the server
+    has a process to itself (`stream_processes=True`), one forked child
+    per connection, so that an env step holds no other stream's GIL."""
 
     def __init__(self, env_init: Callable, address: str,
                  max_frame_bytes: Optional[int] = None,
                  obs_ring_bytes: int = transport_lib.DEFAULT_OBS_RING_BYTES,
-                 act_ring_bytes: int = transport_lib.DEFAULT_ACT_RING_BYTES):
+                 act_ring_bytes: int = transport_lib.DEFAULT_ACT_RING_BYTES,
+                 seed_base: Optional[int] = None,
+                 stream_processes: bool = False):
+        """`seed_base`: stream s (in accept order) is built as
+        `env_init(seed=seed_base + s)`; None calls `env_init()`.
+
+        `stream_processes`: run() forks a child for every accepted
+        stream and only accepts, supervises and sweeps itself. A fork
+        is safe only from a process with no other thread, so this is
+        for a server process whose main thread calls run() and that
+        never imported JAX (`polybeast_env._serve`); start() refuses
+        it."""
         self._env_init = env_init
         self._address = address
         self._shm = transport_lib.is_shm_address(address)
         self._max_frame_bytes = max_frame_bytes
         self._obs_ring_bytes = obs_ring_bytes
         self._act_ring_bytes = act_ring_bytes
+        self._seed_base = seed_base
+        self._stream_processes = stream_processes
+        # Drawn by the listener, one per accepted stream: a counter
+        # advanced inside a forked child would hand every stream index 0.
+        self._stream_index = itertools.count()
         self._family, self._target = parse_address(address)
         # Control fields shared between run() (its own thread under
         # start()), the per-stream threads, and stop() (caller thread):
@@ -69,17 +113,23 @@ class EnvServer:
         # still stop it — run() re-checks this at publish time.
         self._stopped = False  # guarded-by: self._conns_lock
         self._conns = []
-        # conn -> (shm segment names) for live shm streams: stop()'s
-        # owner-side sweep unlinks whatever a stream thread didn't get
-        # to (ISSUE 6 — SIGKILL chaos must not grow /dev/shm).
+        # Live stream children (stream_processes), by pid.
+        self._children = set()  # guarded-by: self._conns_lock
+        # stream (its conn, or its child's pid) -> shm segment names of
+        # a live shm stream: reaping a child and stop()'s owner-side
+        # sweep unlink whatever the stream itself didn't get to
+        # (ISSUE 6 — SIGKILL chaos must not grow /dev/shm).
         self._ring_names = {}  # guarded-by: self._conns_lock
         self._conns_lock = threading.Lock()
         self._running = False  # guarded-by: self._conns_lock
         # NB: env servers usually run as separate processes, so these
         # land in each server's OWN process registry (the learner-side
-        # mirror lives in ActorPool's wire.bytes_* counters).
+        # mirror lives in ActorPool's wire.bytes_* counters); a stream
+        # child's bytes and step times land in its own copy.
         reg = telemetry.get_registry()
         self._tm_conns = reg.gauge("env_server.connections")
+        self._tm_children = reg.gauge("env_server.stream_processes")
+        self._tm_child_exits = reg.counter("env_server.stream_exits")
         self._tm_bytes_in = reg.counter("env_server.bytes_in")
         self._tm_bytes_out = reg.counter("env_server.bytes_out")
         self._tm_step_s = reg.histogram("env_server.env_step_s")
@@ -113,14 +163,24 @@ class EnvServer:
             self._sock = sock
             self._running = True
         log.info("EnvServer listening on %s", self._address)
+        if self._stream_processes:
+            sock.settimeout(_REAP_PERIOD_S)  # wake to reap
         while True:
             with self._conns_lock:
                 if not self._running:
                     break
             try:
                 conn, _ = sock.accept()
+            except socket.timeout:
+                self._reap_children()
+                continue
             except OSError:
                 break  # socket closed by stop()
+            index = next(self._stream_index)
+            if self._stream_processes:
+                self._fork_stream(sock, conn, index)
+                self._reap_children()
+                continue
             # Register the conn BEFORE spawning its thread so a concurrent
             # stop() can never miss a just-accepted stream.
             with self._conns_lock:
@@ -129,7 +189,7 @@ class EnvServer:
                     break
                 self._conns.append(conn)
             t = threading.Thread(
-                target=self._serve_stream, args=(conn,), daemon=True
+                target=self._serve_stream, args=(conn, index), daemon=True
             )
             t.start()
             # Prune finished stream threads so reconnect-heavy workloads
@@ -139,8 +199,122 @@ class EnvServer:
                     x for x in self._threads if x.is_alive()
                 ] + [t]
 
+    def _fork_stream(self, listener: socket.socket, conn: socket.socket,
+                     index: int):
+        """Hand an accepted stream to a child of its own. The rings are
+        created here, before the fork, so that this process knows the
+        segment names of every child whatever becomes of it."""
+        # SIGTERM / Ctrl-C are held back across the fork: the unwinding
+        # they start must begin neither in a child that still stands in
+        # the listener's frames, nor here before the child is on record.
+        held = signal.pthread_sigmask(
+            signal.SIG_BLOCK, {signal.SIGTERM, signal.SIGINT}
+        )
+        rings = None
+        try:
+            if self._shm:
+                rings = transport_lib.create_rings(
+                    self._obs_ring_bytes, self._act_ring_bytes
+                )
+            pid = os.fork()
+            if pid == 0:
+                self._stream_child(listener, conn, index, rings, held)
+            with self._conns_lock:
+                self._children.add(pid)
+                if rings is not None:
+                    self._ring_names[pid] = tuple(r.name for r in rings)
+                self._tm_children.set(len(self._children))
+                self._tm_conns.set(len(self._children))
+            for ring in rings or ():
+                ring.detach()  # the child's to unlink now
+        except OSError:
+            # Out of pids, memory or /dev/shm: this stream is lost (its
+            # actor reconnects), the server is not.
+            log.exception("Could not start a process for stream %d", index)
+            for ring in rings or ():
+                ring.close()
+        finally:
+            # The child's copy of the connection is the stream: with
+            # ours closed, the child's death is the client's EOF.
+            conn.close()
+            signal.pthread_sigmask(signal.SIG_SETMASK, held)
+
+    def _stream_child(self, listener: socket.socket, conn: socket.socket,
+                      index: int, rings, sigmask):
+        """A stream child's whole life, never returning: the stream loop
+        on the inherited connection, then out without the listener's
+        atexit and multiprocessing teardown."""
+        code = 1
+        try:
+            _die_with_parent()
+            # SIGTERM unwinds through _serve_stream's teardown (env
+            # closed, rings unlinked); a repeat is ignored.
+            install_preemption_handler()
+            signal.pthread_sigmask(signal.SIG_SETMASK, sigmask)
+            listener.close()  # the parent's to accept on, not ours
+            self._serve_stream(conn, index, rings)
+            code = 0
+        except KeyboardInterrupt:
+            code = 0
+        except BaseException:  # noqa: BLE001 - reported, then out
+            log.exception("Stream %d's process failed", index)
+        finally:
+            logging.shutdown()
+            os._exit(code)
+
+    def _reap_children(self):
+        """Collect the stream children that have exited (no zombies)
+        and unlink what they left in /dev/shm (a SIGKILLed child)."""
+        with self._conns_lock:
+            children = list(self._children)
+        for pid in children:
+            try:
+                if os.waitpid(pid, os.WNOHANG)[0] == 0:
+                    continue
+            except ChildProcessError:
+                pass  # already collected
+            with self._conns_lock:
+                self._children.discard(pid)
+                names = self._ring_names.pop(pid, ())
+                self._tm_children.set(len(self._children))
+                self._tm_conns.set(len(self._children))
+            self._tm_child_exits.inc()
+            for name in names:
+                if transport_lib.unlink_segment(name):
+                    log.warning(
+                        "EnvServer: swept shm segment %s of dead stream "
+                        "process %d", name, pid,
+                    )
+
+    def _stop_children(self, grace_s: float = 2.0):
+        """SIGTERM the stream children, SIGKILL what outlives the
+        grace, and reap them all."""
+        for sig in (signal.SIGTERM, signal.SIGKILL):
+            with self._conns_lock:
+                children = list(self._children)
+            for pid in children:
+                try:
+                    os.kill(pid, sig)
+                except ProcessLookupError:
+                    pass
+            deadline = time.monotonic() + grace_s
+            while True:
+                self._reap_children()
+                with self._conns_lock:
+                    if not self._children:
+                        return
+                if time.monotonic() > deadline:
+                    break
+                time.sleep(0.01)
+
     def start(self):
         """Non-blocking run() in a daemon thread."""
+        if self._stream_processes:
+            raise ValueError(
+                "stream_processes forks, which is unsafe beside other "
+                "threads: call run() from the main thread of a process "
+                "of its own"
+            )
         t = threading.Thread(target=self.run, daemon=True)
         t.start()
         with self._conns_lock:
@@ -167,6 +341,7 @@ class EnvServer:
             except OSError:
                 pass
             conn.close()
+        self._stop_children()
         # Owner-side shm sweep: give the stream threads a moment to
         # close their rings (which unlinks them), then unlink whatever
         # is left. A thread wedged past the join window must not strand
@@ -195,7 +370,10 @@ class EnvServer:
             except FileNotFoundError:
                 pass
 
-    def _serve_stream(self, conn: socket.socket):
+    def _serve_stream(self, conn: socket.socket, index: int, rings=None):
+        """One stream, start to end, on this thread (or, in a stream's
+        child, this process). `rings`: the shm ring pair the listener
+        made before it forked; otherwise the stream makes its own."""
         try:
             conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         except OSError:
@@ -204,19 +382,24 @@ class EnvServer:
         env = None
         msg = None
         try:
-            # For shm addresses this creates the per-connection rings and
-            # completes the handshake BEFORE the env is built, so a
-            # client that never acks can't leak an env instance.
+            # For shm addresses this creates the per-connection rings
+            # (unless `rings` came with the stream) and completes the
+            # handshake BEFORE the env is built, so a client that never
+            # acks can't leak an env instance.
             stream = transport_lib.server_transport(
                 conn, shm=self._shm,
                 obs_ring_bytes=self._obs_ring_bytes,
                 act_ring_bytes=self._act_ring_bytes,
                 max_frame_bytes=self._max_frame_bytes,
+                rings=rings,
             )
             if self._shm:
                 with self._conns_lock:
                     self._ring_names[conn] = stream.segment_names
-            raw_env = self._env_init()
+            if self._seed_base is None:
+                raw_env = self._env_init()
+            else:
+                raw_env = self._env_init(seed=self._seed_base + index)
             env = Environment(raw_env)
             # The initial Step doubles as the env spec: remote learners
             # probe num_actions/frame shape from it instead of having to

@@ -481,6 +481,13 @@ class ShmRing:
         self._u64[self._TAIL] = self._u64[self._TAIL] + advance
 
     # -- teardown ---------------------------------------------------------
+    def detach(self):
+        """Unmap without unlinking: the segment belongs to another
+        process now (a forked child that inherited this mapping and
+        unlinks it when its stream ends)."""
+        self._owner = False
+        self.close()
+
     def close(self):
         """Unmap (and unlink, if this end created the segment). Decoded
         views must be dropped first; a racing lingering view only skips
@@ -754,23 +761,32 @@ class ShmTransport:
         self._recv_ring.close()
 
 
-def server_transport(conn: socket.socket, shm: bool = False,
-                     obs_ring_bytes: int = DEFAULT_OBS_RING_BYTES,
-                     act_ring_bytes: int = DEFAULT_ACT_RING_BYTES,
-                     max_frame_bytes: Optional[int] = None,
-                     handshake_timeout_s: float = 30.0):
-    """Wrap a server-accepted connection. For shm, creates the per-
-    connection rings (server->client sized obs_ring_bytes, client->server
-    act_ring_bytes), sends the handshake, and waits for the client's ack
-    so segment ownership is never ambiguous."""
-    if not shm:
-        return SocketTransport(conn, max_frame_bytes=max_frame_bytes)
+def create_rings(obs_ring_bytes: int = DEFAULT_OBS_RING_BYTES,
+                 act_ring_bytes: int = DEFAULT_ACT_RING_BYTES):
+    """A connection's ring pair, owned by the caller: (server->client
+    sized obs_ring_bytes, client->server act_ring_bytes)."""
     s2c = ShmRing.create(obs_ring_bytes)
     try:
         c2s = ShmRing.create(act_ring_bytes)
     except BaseException:
         s2c.close()
         raise
+    return s2c, c2s
+
+
+def server_transport(conn: socket.socket, shm: bool = False,
+                     obs_ring_bytes: int = DEFAULT_OBS_RING_BYTES,
+                     act_ring_bytes: int = DEFAULT_ACT_RING_BYTES,
+                     max_frame_bytes: Optional[int] = None,
+                     handshake_timeout_s: float = 30.0,
+                     rings=None):
+    """Wrap a server-accepted connection. For shm, creates the per-
+    connection rings (`create_rings`; or takes the pair the caller made,
+    `rings`, and owns it from here), sends the handshake, and waits for
+    the client's ack so segment ownership is never ambiguous."""
+    if not shm:
+        return SocketTransport(conn, max_frame_bytes=max_frame_bytes)
+    s2c, c2s = rings or create_rings(obs_ring_bytes, act_ring_bytes)
     try:
         prev_timeout = conn.gettimeout()
         conn.settimeout(handshake_timeout_s)
