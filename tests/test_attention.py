@@ -205,3 +205,68 @@ def test_unknown_schedule_rejected():
     q, k, v = make_qkv(seed=9)
     with pytest.raises(ValueError, match="schedule"):
         ring_attention(q, k, v, mesh, axis="data", schedule="spiral")
+
+
+# --- dense_transformer_attend: equal and grouped heads -------------------
+
+
+def _old_dense_transformer_attend(q, k_all, v_all, mask, offsets, rel_bias):
+    """The body as it was before grouped-query heads (PR 32), kept here
+    as the oracle for `Hkv == H`."""
+    scale = q.shape[-1] ** -0.5
+    scores = (
+        jnp.einsum("bqhd,bkhd->bhqk", q, k_all).astype(jnp.float32) * scale
+    )
+    if rel_bias is not None:
+        scores = scores + rel_bias[:, offsets][None]
+    scores = jnp.where(mask[:, None], scores, -1e30)
+    weights = jax.nn.softmax(scores, axis=-1).astype(v_all.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", weights, v_all)
+
+
+@pytest.mark.parametrize("kv_heads", [4, 2, 1], ids=["mha", "gqa-2", "mqa"])
+@pytest.mark.parametrize("with_bias", [False, True])
+def test_dense_transformer_attend_by_group(kv_heads, with_bias):
+    """With as many key/value heads as query heads the body is the
+    program it was, bit for bit (jitted, as the models run it); with
+    fewer it equals the old body on K and V repeated per group, values
+    and gradients."""
+    from torchbeast_tpu.ops.attention import dense_transformer_attend
+
+    rng = np.random.default_rng(kv_heads)
+    M = 5
+    q = jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.float32)
+    k, v = (
+        jnp.asarray(rng.standard_normal((B, M + T, kv_heads, D)), jnp.float32)
+        for _ in range(2)
+    )
+    mask = jnp.asarray(rng.random((B, T, M + T)) < 0.6).at[:, :, M].set(True)
+    offsets = jnp.asarray(rng.integers(0, M + 1, (T, M + T)))
+    bias = (
+        jnp.asarray(rng.standard_normal((H, M + 1)), jnp.float32)
+        if with_bias else None
+    )
+    group = H // kv_heads
+    repeat = lambda x: jnp.repeat(x, group, axis=2)
+    attend = jax.jit(dense_transformer_attend)
+    attend_as_it_was = jax.jit(_old_dense_transformer_attend)
+    new = attend(q, k, v, mask, offsets, bias)
+    old = attend_as_it_was(q, repeat(k), repeat(v), mask, offsets, bias)
+    if kv_heads == H:
+        np.testing.assert_array_equal(new, old)
+    else:
+        np.testing.assert_allclose(new, old, rtol=1e-5, atol=1e-6)
+
+    def total(fn, rep):
+        return lambda q, k, v: jnp.sum(
+            jnp.sin(fn(q, rep(k), rep(v), mask, offsets, bias))
+        )
+
+    got = jax.grad(total(dense_transformer_attend, lambda x: x), (0, 1, 2))(
+        q, k, v
+    )
+    want = jax.grad(total(_old_dense_transformer_attend, repeat), (0, 1, 2))(
+        q, k, v
+    )
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)

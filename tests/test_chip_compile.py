@@ -213,6 +213,32 @@ def test_olmoe_experts_compile_for_v5e(one_chip, monkeypatch, rows):
     assert compiled.as_text().count("tpu_custom_call") >= 8
 
 
+def test_mellum2_share_of_the_experts_compiles_for_v5e(one_chip, monkeypatch):
+    """The Mellum2 cell's grouped matmuls: the learner's 20,736 sorted
+    rows over all 64 groups, the weights of experts 16..31 alone
+    (megablox's `group_offset`), at the published 2304 -> 896 -> 2304,
+    forward and backward."""
+    from torchbeast_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, d, width, experts, held = 81 * 32 * 8, 2304, 896, 64, 16
+
+    def loss(x, w_gate, w_up, w_down, sizes):
+        hidden = jax.nn.silu(
+            moe.grouped_matmul(x, w_gate, sizes, 16)
+        ) * moe.grouped_matmul(x, w_up, sizes, 16)
+        return jnp.sum(moe.grouped_matmul(hidden, w_down, sizes, 16))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        _struct(one_chip, (rows, d)),
+        _struct(one_chip, (held, d, width)),
+        _struct(one_chip, (held, d, width)),
+        _struct(one_chip, (held, width, d)),
+        _struct(one_chip, (experts,), jnp.int32),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 8
+
+
 def test_flagship_act_step_compiles_for_v5e(one_chip, monkeypatch):
     """The acting program at the largest inference bucket."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")

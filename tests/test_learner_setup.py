@@ -165,17 +165,22 @@ FAMILY_FIELD_CASES = {
     "num_layers": (
         "3", 3, "olmoe", "pipelined_transformer",
         "--num_layers is a positive depth or window of --model "
-        "transformer or olmoe",
+        "transformer or olmoe or mellum2",
     ),
     "memory_len": (
         "9", 9, "transformer", "deep",
         "--memory_len is a positive depth or window of --model "
-        "transformer or olmoe",
+        "transformer or olmoe or mellum2",
     ),
     "num_experts": (
         "4", 4, "transformer", "olmoe",
         "--num_experts applies to --model transformer only (the "
         "conv/MLP families have no MoE formulation)",
+    ),
+    "expert_share": (
+        "1/4", (1, 4), "mellum2", "olmoe",
+        "--expert_share i/n (share i of the n chips that divide each "
+        "layer's experts) applies to --model mellum2 only",
     ),
     "trunk_channels": (
         "32,64,64", (32, 64, 64), "deep", "mlp",
@@ -226,14 +231,18 @@ def test_refusals_are_stated_on_the_class():
     assert refusing == {
         "pipelined_transformer": ("num_layers", "memory_len"),
         "olmoe": ("num_experts", "attention_impl"),
+        "mellum2": ("num_experts", "attention_impl"),
     }
     kv_cache = [
         name for name in models.MODEL_NAMES
         if getattr(models._REGISTRY[name], "memory_is_kv_cache", False)
     ]
-    assert kv_cache == ["transformer", "pipelined_transformer", "olmoe"]
+    assert kv_cache == [
+        "transformer", "pipelined_transformer", "olmoe", "mellum2",
+    ]
     for name in models.MODEL_NAMES:
-        if name in kv_cache and name != "olmoe":  # test_olmoe has that one
+        # test_olmoe and test_mellum2 have theirs
+        if name in kv_cache and name not in ("olmoe", "mellum2"):
             with pytest.raises(ValueError, match="KV cache"):
                 models.create_model(name, num_actions=A, use_lstm=True)
         elif name not in kv_cache:

@@ -1,0 +1,536 @@
+"""The `mellum2` family (models/mellum2.py; grouped-query heads in
+ops/attention.py; the share of the experts in models/moe.py DroplessMoE;
+per-layer caches in models/transformer.py): against the plain reference
+on seeded weights, batch forward against stepwise acting through the two
+rolling caches, what a window layer cannot see and a full layer can,
+YaRN by hand, and the shares of the experts adding up to the layer."""
+
+import math
+
+import numpy as np
+import pytest
+
+import jax
+import jax.flatten_util
+import jax.numpy as jnp
+
+from perfbench.reference import mellum2_policy as reference
+from torchbeast_tpu import learner as learner_lib
+from torchbeast_tpu import monobeast, polybeast
+from torchbeast_tpu.models import Mellum2Net, create_model, mellum2, moe
+from torchbeast_tpu.ops.attention import dense_transformer_attend
+
+T, B, A = 6, 2, 4
+FRAME = (8, 8, 1)
+# A shrunken `PUBLISHED`: 4 query heads on 2 key/value heads of 16, a
+# window of 4 keys (3 slots), 8 experts of 24, top 2. The full layers'
+# cache is `M` slots.
+SMALL = dict(
+    d_model=48, num_heads=4, kv_heads=2, head_dim=16, sliding_window=4,
+    num_experts=8, experts_per_token=2, expert_width=24,
+)
+M = 9
+# As tests/test_olmoe.py: on the CPU both sides compute in float32 at
+# full precision and differ by the order of their sums.
+RTOL = ATOL = 1e-5
+
+YARN_CONFIG = {
+    "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+    "original_max_position_embeddings": 8192, "beta_fast": 32,
+    "beta_slow": 1, "attention_factor": 1.2772588722239782,
+}
+
+
+def _inputs(seed, done_steps=(), t=T):
+    rng = np.random.default_rng(seed)
+    done = np.zeros((t, B), bool)
+    for step, row in done_steps:
+        done[step, row] = True
+    return {
+        "frame": jnp.asarray(
+            rng.integers(0, 256, (t, B) + FRAME, dtype=np.uint8)
+        ),
+        "reward": jnp.asarray(rng.standard_normal((t, B)), jnp.float32),
+        "done": jnp.asarray(done),
+        "last_action": jnp.asarray(rng.integers(0, A, (t, B))),
+    }
+
+
+def _learner_batch(seed, done_steps):
+    rng = np.random.default_rng(seed + 100)
+    lead = (T, B)
+    return dict(
+        _inputs(seed, done_steps),
+        episode_return=jnp.asarray(rng.standard_normal(lead), jnp.float32),
+        episode_step=jnp.zeros(lead, jnp.int32),
+        action=jnp.asarray(rng.integers(0, A, lead)),
+        policy_logits=jnp.asarray(
+            rng.standard_normal(lead + (A,)), jnp.float32
+        ),
+        baseline=jnp.asarray(rng.standard_normal(lead), jnp.float32),
+    )
+
+
+def _model(share=(0, 1), seed=0, **overrides):
+    model = Mellum2Net(
+        num_actions=A, num_layers=4, memory_len=M, expert_share=share,
+        **dict(SMALL, **overrides),
+    )
+    params = model.init(
+        {"params": jax.random.PRNGKey(seed), "action": jax.random.PRNGKey(1)},
+        _inputs(0), model.initial_state(B),
+    )
+    # The family starts its side inputs' projection at zero (below):
+    # give it weights, so that the comparisons cover that path too.
+    inner = dict(params["params"])
+    assert not np.any(inner["extras"]["kernel"])
+    inner["extras"] = dict(inner["extras"], kernel=0.3 * jax.random.normal(
+        jax.random.PRNGKey(seed + 7), inner["extras"]["kernel"].shape
+    ))
+    return model, {"params": inner}
+
+
+def _reference_config(share=(0, 1), **overrides):
+    widths = dict(SMALL, **overrides)
+    return {
+        "num_attention_heads": widths["num_heads"],
+        "num_key_value_heads": widths["kv_heads"],
+        "head_dim": widths["head_dim"],
+        "sliding_window": widths["sliding_window"],
+        "layer_types": list(mellum2.PUBLISHED["layer_period"]) * 7,
+        "num_hidden_layers": 4,
+        "published_num_experts": widths["num_experts"],
+        "num_experts": widths["num_experts"] // share[1],
+        "expert_share": list(share),
+        "num_experts_per_tok": widths["experts_per_token"],
+        "norm_topk_prob": True,
+        "rms_norm_eps": 1e-6,
+        "rope_parameters": {
+            "full_attention": YARN_CONFIG,
+            "sliding_attention": {
+                "rope_type": "default", "rope_theta": 500000,
+            },
+        },
+        "memory_len": M, "load_balance_weight": 0.001, "num_actions": A,
+        "discounting": 0.99, "baseline_cost": 0.5, "entropy_cost": 0.0006,
+    }
+
+
+def _warm_state(model, params, seed, unrolls=1):
+    """Caches an actor would hold: `unrolls` unrolls in, an episode end
+    in the first. After one, the 3-slot window caches are full and the
+    9-slot full cache is not; after two, both are."""
+    state = model.initial_state(B)
+    for i in range(unrolls):
+        _, state = model.apply(
+            params, _inputs(seed + i, done_steps=[(2, 1)] if i == 0 else ()),
+            state, sample_action=False,
+        )
+    return state
+
+
+@pytest.mark.parametrize(
+    "share", [(0, 1), (1, 4)], ids=["all-8-experts", "share-1-of-4"]
+)
+def test_family_agrees_with_the_reference(share):
+    model, params = _model(share)
+    config = _reference_config(share)
+    state = _warm_state(model, params, seed=5)
+    batch = _learner_batch(7, done_steps=[(3, 0)])
+
+    out, new_state = model.apply(params, batch, state, sample_action=False)
+    logits, baseline, ref_state, _ = reference.forward(
+        params, batch, state, config
+    )
+    np.testing.assert_allclose(out.policy_logits, logits, RTOL, ATOL)
+    np.testing.assert_allclose(out.baseline, baseline, RTOL, ATOL)
+    for got, want in zip(
+        jax.tree_util.tree_leaves(new_state),
+        jax.tree_util.tree_leaves(ref_state),
+    ):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, RTOL, ATOL)
+
+    hp = learner_lib.HParams(batch_size=B, unroll_length=T - 1)
+    (loss, stats), grads = jax.value_and_grad(
+        lambda p: learner_lib.compute_loss(model, p, batch, state, hp),
+        has_aux=True,
+    )(params)
+    ref_loss, ref_grads = jax.value_and_grad(reference.loss)(
+        params, batch, state, config
+    )
+    scale = float(reference.loss_and_scale(params, batch, state, config)[1])
+    assert abs(float(loss) - float(ref_loss)) <= RTOL * scale
+    flat, ref_flat = (
+        jax.flatten_util.ravel_pytree(g)[0] for g in (grads, ref_grads)
+    )
+    np.testing.assert_allclose(
+        flat, ref_flat, rtol=0, atol=RTOL * float(jnp.max(jnp.abs(ref_flat)))
+    )
+    # Routing is over all the experts whatever is held.
+    assert float(stats["moe_assignments"]) == 2 * T * B * 4
+    assert float(stats["aux_loss"]) > 0
+    if share == (0, 1):
+        assert "moe_held_assignments" not in stats
+    else:
+        assert 0 < float(stats["moe_held_assignments"]) < 2 * T * B * 4
+        assert float(stats["moe_held_load_max_over_mean"]) >= 1.0
+
+
+@pytest.mark.parametrize("unrolls", [0, 1, 2], ids=["empty", "part", "full"])
+def test_batch_forward_equals_stepwise_acting_through_both_caches(unrolls):
+    """The learner's [T, B] forward and the actor's T=1 forwards through
+    the rolling caches (window layers 3 slots, the full layer 9; T=6
+    evicts from the first on the way) give the same logits and leave the
+    same caches, from caches of any fill and across an episode end."""
+    model, params = _model()
+    state = _warm_state(model, params, seed=2, unrolls=unrolls)
+    inputs = _inputs(3, done_steps=[(3, 1)])
+    full, full_state = model.apply(params, inputs, state, sample_action=False)
+    logits = []
+    for t in range(T):
+        step = {k: v[t : t + 1] for k, v in inputs.items()}
+        out, state = model.apply(params, step, state, sample_action=False)
+        logits.append(out.policy_logits[0])
+    np.testing.assert_allclose(
+        np.stack(logits), full.policy_logits, rtol=2e-4, atol=2e-5
+    )
+    for got, want in zip(
+        jax.tree_util.tree_leaves(state),
+        jax.tree_util.tree_leaves(full_state),
+    ):
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+def test_layers_carry_caches_of_their_kind():
+    model, _ = _model()
+    assert [model.layer_kind(i) for i in range(4)] == [
+        mellum2.SLIDING, mellum2.SLIDING, mellum2.SLIDING, mellum2.FULL,
+    ]
+    state = model.initial_state(3)
+    assert [layer[0].shape for layer in state] == (
+        [(3, 3, 2, 16)] * 3 + [(M, 3, 2, 16)]
+    )
+    assert [layer[2].shape for layer in state] == [(3, 3)] * 3 + [(M, 3)]
+    # A full cache shorter than the window: every layer carries it.
+    short = Mellum2Net(num_actions=A, num_layers=4, memory_len=2, **SMALL)
+    assert [m for m, _, _ in short.layer_caches()] == [2, 2, 2, 2]
+    published = create_model("mellum2", num_actions=6, num_layers=8)
+    assert [m for m, _, _ in published.layer_caches()] == (
+        [1023, 1023, 1023, 4095] * 2
+    )
+    assert published.layer_caches()[0][1:] == (4, 128)
+
+
+def _one_block(kind, memory_len):
+    block = mellum2._Mellum2Block(
+        kind=kind, d_model=48, num_heads=4, kv_heads=2, head_dim=16,
+        memory_len=memory_len, num_experts=8, held=None,
+        experts_per_token=2, expert_width=24, renormalise=True,
+        rms_norm_eps=1e-6, rope_theta=500000.0,
+        yarn=mellum2.PUBLISHED["yarn"], aux_loss_weight=0.001,
+    )
+    return block
+
+
+def test_a_window_layer_ignores_the_key_a_full_layer_reads():
+    """One query (T=1) over a cache of 9 valid slots. A layer with the
+    window's cache of 3 slots is handed the last 3 and cannot tell what
+    came before; a full layer's output moves when the key and value 4
+    steps back (one past the window of 4) change."""
+    from torchbeast_tpu.ops.attention import band_relative_offsets
+
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((B, 1, 48)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((B, M, 2, 16)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((B, M, 2, 16)), jnp.float32)
+    # The slot 4 steps before the query: slot M - 4.
+    k_moved = k.at[:, M - 4].add(1.0)
+    v_moved = v.at[:, M - 4].add(1.0)
+
+    def run(kind, slots, k, v):
+        band, offsets = band_relative_offsets(1, slots)
+        mask = jnp.broadcast_to(band[None], (B, 1, slots + 1))
+        block = _one_block(kind, slots)
+        cache = (k[:, -slots:], v[:, -slots:])
+        params = block.init(jax.random.PRNGKey(0), x, cache, mask, offsets)
+        return block.apply(params, x, cache, mask, offsets)[0]
+
+    window = SMALL["sliding_window"] - 1
+    np.testing.assert_array_equal(
+        run(mellum2.SLIDING, window, k, v),
+        run(mellum2.SLIDING, window, k_moved, v_moved),
+    )
+    full, full_moved = (
+        run(mellum2.FULL, M, k, v), run(mellum2.FULL, M, k_moved, v_moved)
+    )
+    assert float(jnp.max(jnp.abs(full - full_moved))) > 1e-3
+    # And in the net: the window layers' band ends at 3 steps back.
+    band, _ = band_relative_offsets(T, window)
+    assert bool(band[4, window + 1]) and not bool(band[4, window])
+
+
+def test_yarn_by_hand():
+    """`rope_parameters.full_attention` at the published numbers, by the
+    formulas of ISSUE 32: c(r) = 128 ln(8192 / (2 pi r)) / (2 ln 500000);
+    c(32) = 18.08 -> low 18, c(1) = 34.98 -> high 35; dimension i keeps
+    its frequency up to 18, has it divided by 16 from 35, a ramp
+    between."""
+    factor, original, fast, slow, attention = mellum2.PUBLISHED["yarn"]
+    theta, dim = mellum2.PUBLISHED["rope_theta"], 128
+    c = lambda r: dim * math.log(original / (2 * math.pi * r)) / (
+        2 * math.log(theta)
+    )
+    assert (math.floor(c(fast)), math.ceil(c(slow))) == (18, 35)
+    assert c(fast) == pytest.approx(18.0806, abs=1e-3)
+    assert c(slow) == pytest.approx(34.984, abs=1e-3)
+    inv_freq = mellum2.rope_yarn(theta, dim, factor, original, fast, slow)
+    plain = np.array([theta ** (-2 * i / dim) for i in range(64)])
+    np.testing.assert_allclose(mellum2.rope_default(theta, dim), plain, 1e-12)
+    np.testing.assert_allclose(inv_freq[:19], plain[:19], 1e-12)
+    np.testing.assert_allclose(inv_freq[35:], plain[35:] / 16, 1e-12)
+    # i = 26: ramp (26 - 18) / 17 = 8/17.
+    assert inv_freq[26] == pytest.approx(
+        plain[26] * ((8 / 17) / 16 + 9 / 17), rel=1e-12
+    )
+    assert plain[1] == pytest.approx(0.814617, rel=1e-5)
+    # The attention factor is 0.1 ln(16) + 1, on cos and sin alike.
+    assert attention == pytest.approx(0.1 * math.log(16) + 1, rel=1e-12)
+    # The reference works the same numbers out on its own.
+    ref_freq, ref_factor = reference.inv_freq_and_factor(YARN_CONFIG, dim)
+    np.testing.assert_allclose(ref_freq, inv_freq, 1e-12)
+    assert ref_factor == attention
+    x = jnp.ones((1, 1, 1, 128))
+    rotated = mellum2.rope_rotate(
+        x, jnp.zeros((1,)), jnp.asarray(inv_freq, jnp.float32), attention
+    )
+    np.testing.assert_allclose(rotated, attention * x, 1e-6)
+
+
+def _layer(held=None, renormalise=True, tokens=40, seed=0, E=8, K=2):
+    count = E if held is None else held[1]
+    layer = moe.DroplessMoE(
+        d_ff=8, num_experts=E, top_k=K, renormalise=renormalise, held=held,
+    )
+    x = jax.random.normal(jax.random.PRNGKey(seed), (tokens, 16))
+    params = layer.init(jax.random.PRNGKey(seed + 1), x)
+    assert params["params"]["w_gate"].shape == (count, 16, 8)
+    assert params["params"]["router"]["kernel"].shape == (16, E)
+    return layer, x, params
+
+
+def test_renormalised_gates_sum_to_one():
+    """With every expert the same matrix the layer's output is exactly
+    that expert's: the chosen gates sum to one (OLMoE's, as they are,
+    sum to less: tests/test_olmoe.py)."""
+    layer, x, params = _layer()
+    p = params["params"]
+    same = {
+        k: jnp.broadcast_to(p[k][:1], p[k].shape)
+        for k in ("w_gate", "w_up", "w_down")
+    }
+    y = layer.apply({"params": dict(p, **same)}, x)
+    expert = (
+        jax.nn.silu(x @ p["w_gate"][0]) * (x @ p["w_up"][0])
+    ) @ p["w_down"][0]
+    np.testing.assert_allclose(y, expert, RTOL, ATOL)
+    probs = jax.nn.softmax(x @ p["router"]["kernel"])
+    gate, _ = jax.lax.top_k(probs, 2)
+    assert float(gate.sum(axis=-1).max()) < 0.95
+
+
+@pytest.mark.parametrize("side", ["program", "reference"])
+def test_the_four_shares_add_up_to_the_uncut_layer(side):
+    """The test that ties the share to the model: 64 experts, top 8, the
+    same router; the layer outputs of `held` (0, 16), (16, 16), (32, 16),
+    (48, 16), each holding its own quarter of the uncut layer's expert
+    weights, add up to the uncut 64-expert layer's output. On the
+    program (values and the gradient with respect to x) and on the
+    reference."""
+    E, K, tokens, d = 64, 8, 40, 16
+    uncut, x, params = _layer(None, tokens=tokens, seed=4, E=E, K=K)
+    p = params["params"]
+
+    def share_params(first, count):
+        return {"params": dict(
+            router=p["router"],
+            **{k: p[k][first : first + count]
+               for k in ("w_gate", "w_up", "w_down")},
+        )}
+
+    if side == "program":
+        def run(first, count, x):
+            held = None if count == E else (first, count)
+            layer = moe.DroplessMoE(
+                d_ff=8, num_experts=E, top_k=K, renormalise=True, held=held,
+            )
+            return layer.apply(share_params(first, count), x)
+    else:
+        def run(first, count, x):
+            config = {
+                "published_num_experts": E, "num_experts": count,
+                "expert_share": [first // count, E // count],
+                "num_experts_per_tok": K, "norm_topk_prob": True,
+                "load_balance_weight": 0.001,
+            }
+            return reference._experts(
+                x, share_params(first, count)["params"], config
+            )[0]
+
+    whole = run(0, E, x)
+    parts = [run(first, 16, x) for first in (0, 16, 32, 48)]
+    assert all(float(jnp.max(jnp.abs(part))) > 0 for part in parts)
+    np.testing.assert_allclose(sum(parts), whole, RTOL, ATOL)
+    # No share is the whole.
+    assert float(jnp.max(jnp.abs(parts[0] - whole))) > 1e-3
+
+    def total(first, count):
+        return lambda x: jnp.sum(jnp.sin(run(first, count, x)))
+
+    grad_whole = jax.grad(total(0, E))(x)
+    grad_parts = sum(
+        jax.grad(
+            lambda x, f=first: jnp.sum(jnp.cos(whole) * run(f, 16, x))
+        )(x)
+        for first in (0, 16, 32, 48)
+    )
+    np.testing.assert_allclose(grad_parts, grad_whole, rtol=1e-4, atol=1e-5)
+
+
+def test_a_share_costs_no_rows_of_the_other_experts():
+    """The grouped matmul over the rows of all 8 experts with the
+    weights of experts 2..3: rows of the other groups come out zero,
+    forward and in the gradient with respect to the rows, and the weight
+    gradient is the held experts' alone."""
+    rng = np.random.default_rng(1)
+    sizes = jnp.asarray([3, 0, 5, 2, 4, 1, 0, 1], jnp.int32)
+    rows = jnp.asarray(rng.standard_normal((16, 8)), jnp.float32)
+    weights = jnp.asarray(rng.standard_normal((8, 8, 4)), jnp.float32)
+    held = weights[2:4]
+    out = moe.grouped_matmul(rows, held, sizes, 2)
+    whole = moe.grouped_matmul(rows, weights, sizes)
+    np.testing.assert_allclose(out[3:10], whole[3:10], RTOL, ATOL)
+    assert not np.any(out[:3]) and not np.any(out[10:])
+    loss = lambda r, w, first: jnp.sum(
+        jnp.sin(moe.grouped_matmul(r, w, sizes, first))[3:10]
+    )
+    d_rows, d_held = jax.grad(loss, argnums=(0, 1))(rows, held, 2)
+    w_rows, w_all = jax.grad(loss, argnums=(0, 1))(rows, weights, None)
+    np.testing.assert_allclose(d_rows[3:10], w_rows[3:10], RTOL, ATOL)
+    assert not np.any(d_rows[:3]) and not np.any(d_rows[10:])
+    np.testing.assert_allclose(d_held, w_all[2:4], RTOL, ATOL)
+
+
+def test_grouped_query_heads_equal_repeated_keys():
+    """[B, T, Hkv, G, D] against K and V repeated G times, which is what
+    the contraction never materialises."""
+    rng = np.random.default_rng(2)
+    q = jnp.asarray(rng.standard_normal((2, 3, 8, 4)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, 7, 2, 4)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, 7, 2, 4)), jnp.float32)
+    mask = jnp.asarray(rng.random((2, 3, 7)) < 0.7).at[:, :, -1].set(True)
+    offsets = jnp.zeros((3, 7), jnp.int32)
+    bias = jnp.asarray(rng.standard_normal((8, 5)), jnp.float32)
+    for rel_bias in (None, bias):
+        grouped = dense_transformer_attend(q, k, v, mask, offsets, rel_bias)
+        repeated = dense_transformer_attend(
+            q, jnp.repeat(k, 4, axis=2), jnp.repeat(v, 4, axis=2),
+            mask, offsets, rel_bias,
+        )
+        np.testing.assert_allclose(grouped, repeated, RTOL, ATOL)
+    with pytest.raises(ValueError, match="do not divide"):
+        dense_transformer_attend(q, k[:, :, :1].repeat(3, 2), v, mask, offsets, None)
+
+
+def test_registry_builds_the_published_widths_and_refuses_lstm():
+    model = create_model("mellum2", num_actions=6, num_layers=4)
+    # The side inputs start at zero in this family alone.
+    assert model.zero_init_extras
+    assert not create_model("olmoe", num_actions=6).zero_init_extras
+    assert not create_model("transformer", num_actions=6).zero_init_extras
+    assert isinstance(model, Mellum2Net)
+    assert (model.d_model, model.num_heads, model.kv_heads) == (2304, 32, 4)
+    assert (model.head_dim, model.sliding_window) == (128, 1024)
+    assert (model.num_experts, model.experts_per_token) == (64, 8)
+    assert (model.expert_width, model.memory_len) == (896, 4095)
+    assert model.renormalise and model.rms_norm_eps == 1e-6
+    assert model.frame_range == (-1.0, 1.0)
+    assert model.held_experts() is None
+    assert create_model("mellum2", num_actions=6).num_layers == 28
+    share = create_model(
+        "mellum2", num_actions=6, num_layers=4, expert_share=(3, 4)
+    )
+    assert share.held_experts() == (48, 16)
+    with pytest.raises(ValueError, match="use_lstm"):
+        create_model("mellum2", num_actions=6, use_lstm=True)
+    with pytest.raises(ValueError, match="whole periods of 4"):
+        create_model("mellum2", num_actions=6, num_layers=3)
+    for bad in [(4, 4), (0, 3), (-1, 4)]:
+        with pytest.raises(ValueError, match="expert_share"):
+            create_model("mellum2", num_actions=6, expert_share=bad)
+
+
+@pytest.mark.parametrize("driver", [monobeast, polybeast], ids=["mono", "poly"])
+def test_parsers_take_the_family_and_its_flags(driver, monkeypatch):
+    parse = driver.make_parser().parse_args
+    flags = parse([
+        "--model", "mellum2", "--num_layers", "8", "--memory_len", "9",
+        "--expert_share", "1/4",
+    ])
+    assert (flags.model, flags.num_layers, flags.expert_share) == (
+        "mellum2", 8, "1/4"
+    )
+    monkeypatch.setattr(mellum2, "PUBLISHED", dict(mellum2.PUBLISHED, **SMALL))
+    model, _ = monobeast._init_model_and_params(
+        flags, A, B, FRAME, init_params=False
+    )
+    assert isinstance(model, Mellum2Net)
+    assert (model.num_layers, model.memory_len, model.d_model) == (8, 9, 48)
+    assert model.held_experts() == (2, 2)
+    # --num_layers 3 is refused, and says why.
+    with pytest.raises(ValueError, match="whole periods of 4"):
+        monobeast._init_model_and_params(
+            parse(["--model", "mellum2", "--num_layers", "3"]),
+            A, B, FRAME, init_params=False,
+        )
+    with pytest.raises(ValueError, match="'i/n'"):
+        monobeast._init_model_and_params(
+            parse(["--model", "mellum2", "--expert_share", "quarter"]),
+            A, B, FRAME, init_params=False,
+        )
+    # The share is refused for a family without experts to divide.
+    for family in ("deep", "transformer", "olmoe"):
+        with pytest.raises(ValueError, match="--model mellum2 only"):
+            monobeast._init_model_and_params(
+                parse(["--model", family, "--expert_share", "0/4"]),
+                A, B, FRAME, init_params=False,
+            )
+    # --remat reaches the family's blocks.
+    model, _ = monobeast._init_model_and_params(
+        parse(["--model", "mellum2", "--num_layers", "4", "--remat", "all"]),
+        A, B, FRAME, init_params=False,
+    )
+    assert model.remat is True
+
+
+def test_rematerialised_blocks_give_the_same_loss_and_gradients():
+    model, params = _model((1, 4))
+    remat = model.clone(remat=True)
+    state = _warm_state(model, params, seed=5)
+    batch = _learner_batch(9, done_steps=[(1, 1)])
+    hp = learner_lib.HParams(batch_size=B, unroll_length=T - 1)
+
+    def run(net):
+        (loss, stats), grads = jax.value_and_grad(
+            lambda p: learner_lib.compute_loss(net, p, batch, state, hp),
+            has_aux=True,
+        )(params)
+        return loss, stats, jax.flatten_util.ravel_pytree(grads)[0]
+
+    loss, stats, grads = run(model)
+    loss_r, stats_r, grads_r = run(remat)
+    assert float(loss) == pytest.approx(float(loss_r), rel=1e-6)
+    np.testing.assert_allclose(grads, grads_r, rtol=1e-5, atol=1e-6)
+    assert float(stats["moe_held_assignments"]) == float(
+        stats_r["moe_held_assignments"]
+    )

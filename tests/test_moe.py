@@ -267,3 +267,79 @@ def test_expert_sharding_contract_covers_opt_state():
     assert all("['moe']" in p for p in sharded)
     n_kernels_in_params = 2 * num_layers
     assert len(sharded) % n_kernels_in_params == 0
+
+
+# --- DroplessMoE: all of its experts held, or a share of them ------------
+
+
+def _old_dropless_layer(p, x, top_k):
+    """DroplessMoE as it was before `held` and `renormalise` (PR 32):
+    softmax, top-k, gates as they are, every expert's weights here."""
+    from torchbeast_tpu.models import moe
+
+    logits = jnp.dot(
+        x, p["router"]["kernel"], precision=jax.lax.Precision.HIGHEST
+    )
+    gate, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return moe.dropless_experts(
+        x, idx, gate, p["w_gate"], p["w_up"], p["w_down"]
+    )[0]
+
+
+@pytest.mark.parametrize(
+    "held", [None, (0, E), (0, 2), (2, 2)],
+    ids=["all", "whole-range", "first-half", "second-half"],
+)
+def test_dropless_layer_with_its_experts_held(held):
+    """`held` None or whole is the old layer bit for bit: same parameter
+    tree, same output, same sown terms and no `held_*` ones unless a
+    range was named. A half holds half the weights, and the two halves'
+    outputs add up to the whole layer's."""
+    from torchbeast_tpu.models.moe import DroplessMoE
+
+    x = jax.random.normal(jax.random.PRNGKey(9), (24, D))
+    whole = DroplessMoE(d_ff=FF, num_experts=E, top_k=2)
+    params = whole.init(jax.random.PRNGKey(0), x)
+    p = params["params"]
+    layer = DroplessMoE(d_ff=FF, num_experts=E, top_k=2, held=held)
+    first, count = held or (0, E)
+    mine = {"params": dict(p, **{
+        k: p[k][first : first + count] for k in ("w_gate", "w_up", "w_down")
+    })}
+    shapes = jax.tree_util.tree_map(
+        jnp.shape, layer.init(jax.random.PRNGKey(0), x)
+    )
+    assert shapes == jax.tree_util.tree_map(jnp.shape, mine)
+    apply = jax.jit(
+        lambda v, x: layer.apply(v, x, mutable=["losses", "moe_stats"])
+    )
+    as_it_was = jax.jit(lambda p, x: _old_dropless_layer(p, x, 2))
+    (y, sown), old = apply(mine, x), as_it_was(p, x)
+    y_whole, sown_whole = whole.apply(
+        params, x, mutable=["losses", "moe_stats"]
+    )
+    assert float(sown["losses"]["moe_load_balance"]) == float(
+        sown_whole["losses"]["moe_load_balance"]
+    )
+    assert float(sown["moe_stats"]["assignments"]) == 48.0
+    if count == E:
+        np.testing.assert_array_equal(y, old)
+        np.testing.assert_array_equal(y, y_whole)
+        assert ("held_assignments" in sown["moe_stats"]) == (held is not None)
+    else:
+        other = DroplessMoE(
+            d_ff=FF, num_experts=E, top_k=2, held=(2 - first, 2)
+        )
+        theirs = {"params": dict(p, **{
+            k: p[k][2 - first : 4 - first]
+            for k in ("w_gate", "w_up", "w_down")
+        })}
+        np.testing.assert_allclose(
+            y + other.apply(theirs, x), old, rtol=1e-5, atol=1e-6
+        )
+        held_rows = float(sown["moe_stats"]["held_assignments"])
+        assert 0 < held_rows < 48
+    with pytest.raises(ValueError, match="not a range"):
+        DroplessMoE(d_ff=FF, num_experts=E, top_k=2, held=(3, 2)).init(
+            jax.random.PRNGKey(0), x
+        )

@@ -510,3 +510,30 @@ def test_superstep_divisibility_rejected(tmp_path):
     )
     with pytest.raises(ValueError, match="superstep_k"):
         monobeast.train(flags)
+
+
+@pytest.mark.parametrize("share", ["", "1/4"], ids=["all-experts", "share-1-of-4"])
+def test_train_mellum2_through_main(tmp_path, monkeypatch, share):
+    """`--model mellum2` on the normal path, the family's table shrunk
+    (window 4: the sliding layers carry 3 slots, the full layer 6):
+    acting at T=1 through both rolling caches, unrolls of 5, updates,
+    the checkpoint; with and without a share of the experts."""
+    from torchbeast_tpu.models import mellum2
+
+    monkeypatch.setattr(mellum2, "PUBLISHED", dict(
+        mellum2.PUBLISHED, d_model=32, num_heads=4, kv_heads=2, head_dim=8,
+        sliding_window=4, num_experts=8, experts_per_token=2,
+        expert_width=16,
+    ))
+    overrides = dict(
+        xpid="smoke-mellum2", model="mellum2", num_layers=4, memory_len=6,
+    )
+    if share:
+        overrides["expert_share"] = share
+    stats = monobeast.main(make_flags(tmp_path, **overrides))
+    assert stats["step"] >= 40
+    assert np.isfinite(stats["total_loss"])
+    # 2 rows x 6 steps x top 2 x 4 layers, over all 8 experts.
+    assert stats["moe_assignments"] == 2 * 6 * 2 * 4
+    assert ("moe_held_assignments" in stats) == bool(share)
+    assert (tmp_path / "smoke-mellum2" / "model.ckpt").exists()

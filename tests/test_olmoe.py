@@ -292,3 +292,93 @@ def test_parsers_take_the_family_and_its_two_flags(driver, monkeypatch):
         flags, A, B, FRAME, init_params=False
     )
     assert (model.num_layers, model.memory_len) == (1, 7)
+
+
+def _tree_shapes(tree):
+    return {
+        "/".join(k.key for k in path): tuple(x.shape)
+        for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]
+    }
+
+
+_OLMOE_BLOCK = {
+    "attn_norm/scale": (32,), "q/kernel": (32, 32), "k/kernel": (32, 32),
+    "v/kernel": (32, 32), "o/kernel": (32, 32), "q_norm/scale": (32,),
+    "k_norm/scale": (32,), "moe_norm/scale": (32,),
+    "moe/router/kernel": (32, 4), "moe/w_gate": (4, 32, 8),
+    "moe/w_up": (4, 32, 8), "moe/w_down": (4, 8, 32),
+}
+_TRANSFORMER_BLOCK = {
+    "LayerNorm_0/scale": (32,), "LayerNorm_0/bias": (32,),
+    "LayerNorm_1/scale": (32,), "LayerNorm_1/bias": (32,),
+    "q/kernel": (32, 2, 16), "q/bias": (2, 16), "k/kernel": (32, 2, 16),
+    "k/bias": (2, 16), "v/kernel": (32, 2, 16), "v/bias": (2, 16),
+    "out/kernel": (2, 16, 32), "out/bias": (32,), "rel_bias": (2, 6),
+    "Dense_0/kernel": (32, 128), "Dense_0/bias": (128,),
+    "Dense_1/kernel": (128, 32), "Dense_1/bias": (32,),
+}
+_SCAFFOLDING = {
+    "Dense_0/kernel": (64, 32), "Dense_0/bias": (32,),
+    "extras/kernel": (5, 32), "extras/bias": (32,),
+    "head/policy/kernel": (32, 4), "head/policy/bias": (4,),
+    "head/baseline/kernel": (32, 1), "head/baseline/bias": (1,),
+}
+
+
+@pytest.mark.parametrize("family", ["olmoe", "transformer"])
+def test_tree_state_and_output_are_what_they_were_before_layer_caches(family):
+    """The scaffolding asks each layer for its cache since PR 32
+    (`layer_caches`); the two families that answer with their one pair
+    keep the parameter tree, the state and the numbers they had: the
+    tree and the state spelt out here, the logits pinned by their first
+    values from the parent commit's program on the same seeds."""
+    from torchbeast_tpu.models import TransformerNet
+
+    if family == "olmoe":
+        model = OLMoENet(
+            num_actions=4, memory_len=5, d_model=32, num_heads=2,
+            num_layers=2, num_experts=4, experts_per_token=2, expert_width=8,
+        )
+        block, last = _OLMOE_BLOCK, {"final_norm/scale": (32,)}
+        pinned = [-2.460061, 1.2367259, 1.4908557]
+    else:
+        model = TransformerNet(
+            num_actions=4, memory_len=5, d_model=32, num_heads=2,
+            num_layers=2,
+        )
+        block = _TRANSFORMER_BLOCK
+        last = {"LayerNorm_0/scale": (32,), "LayerNorm_0/bias": (32,)}
+        pinned = [0.02496201, 1.0791285, -1.4319977]
+    assert model.layer_caches() == ((5, 2, 16),) * 2
+    steps, rows = 3, 2
+    inputs = {
+        "frame": jnp.zeros((steps, rows, 8, 8, 1), jnp.uint8),
+        "reward": jnp.zeros((steps, rows)),
+        "done": jnp.zeros((steps, rows), bool),
+        "last_action": jnp.zeros((steps, rows), jnp.int32),
+    }
+    state = model.initial_state(rows)
+    assert [tuple(x.shape for x in layer) for layer in state] == [
+        ((5, rows, 2, 16), (5, rows, 2, 16), (5, rows))
+    ] * 2
+    params = model.init(
+        {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
+        inputs, state,
+    )
+    want = dict(_SCAFFOLDING, **last)
+    for layer in range(2):
+        want.update({f"block_{layer}/{k}": v for k, v in block.items()})
+    assert _tree_shapes(params["params"]) == want
+    frames = np.random.default_rng(0).integers(
+        0, 256, (steps, rows, 8, 8, 1), dtype=np.uint8
+    )
+    out, new_state = model.apply(
+        params, dict(inputs, frame=jnp.asarray(frames)), state,
+        sample_action=False,
+    )
+    np.testing.assert_allclose(
+        np.asarray(out.policy_logits).ravel()[:3], pinned, rtol=2e-6
+    )
+    assert jax.tree_util.tree_map(jnp.shape, new_state) == (
+        jax.tree_util.tree_map(jnp.shape, state)
+    )

@@ -214,6 +214,86 @@ class TestDeviceStateTable:
         np.testing.assert_array_equal(slot_state(table, 0), np.zeros(H))
         np.testing.assert_array_equal(slot_state(table, 1), np.full(H, 3.0))
 
+    @pytest.mark.parametrize("via", ["reset", "rebuild"])
+    def test_layers_with_caches_of_different_lengths(self, via):
+        """A state whose layers differ in their leading size (mellum2:
+        window layers 3 slots, the full layer 9; [M, B, heads, D] and
+        [M, B], batch on axis 1) through the real family's forward:
+        slots step independently, a stepped slot's caches fill from the
+        back at each layer's own length, and reset / rebuild bring back
+        the empty caches."""
+        from torchbeast_tpu.models import Mellum2Net
+
+        model = Mellum2Net(
+            num_actions=3, num_layers=4, memory_len=9, d_model=16,
+            num_heads=2, kv_heads=1, head_dim=8, sliding_window=4,
+            num_experts=4, experts_per_token=2, expert_width=8,
+        )
+        lengths = [m for m, _, _ in model.layer_caches()]
+        assert lengths == [3, 3, 3, 9]
+
+        def env(rows):
+            return {
+                "frame": np.full((1, rows, 4, 4, 1), 7, np.uint8),
+                "reward": np.zeros((1, rows), np.float32),
+                "done": np.zeros((1, rows), bool),
+                "last_action": np.zeros((1, rows), np.int32),
+            }
+
+        params = model.init(
+            {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
+            env(1), model.initial_state(1),
+        )
+
+        def act(ctx, env_outputs, agent_state):
+            out, new_state = model.apply(
+                params, env_outputs, agent_state, sample_action=False
+            )
+            return {"logits": out.policy_logits}, new_state
+
+        table = DeviceStateTable(
+            model.initial_state(1), num_slots=3, act_fn=act, batch_dim=1
+        )
+        for _ in range(5):
+            table.step(
+                np.asarray([0, 2], np.int32), np.ones(2, bool), env(2)
+            )
+        table.step(np.asarray([2], np.int32), np.ones(1, bool), env(1))
+
+        def valid(slot):
+            return [
+                np.asarray(layer[2]).reshape(-1)
+                for layer in table.read_slot(slot)
+            ]
+
+        for layer, length in enumerate(lengths):
+            k, v, mask = table.read_slot(0)[layer]
+            assert np.shape(k) == np.shape(v) == (length, 1, 1, 8)
+            assert np.shape(mask) == (length, 1)
+            # Five steps in: a window cache is full, the full layer's
+            # holds five of nine, newest last.
+            filled = min(5, length)
+            np.testing.assert_array_equal(
+                valid(0)[layer], [0.0] * (length - filled) + [1.0] * filled
+            )
+            assert np.any(np.asarray(k)[-1] != 0)
+            assert int(valid(2)[layer].sum()) == min(6, length)
+            assert not valid(1)[layer].any()  # never stepped
+        if via == "reset":
+            table.reset([0])
+            assert int(valid(2)[3].sum()) == 6  # the others keep theirs
+        else:
+            table.poison()
+            table.rebuild()
+            assert not any(v.any() for v in valid(2))
+        for layer, length in enumerate(lengths):
+            k, _, mask = table.read_slot(0)[layer]
+            assert np.shape(k) == (length, 1, 1, 8)
+            assert not np.any(k) and not np.any(mask)
+        # And it steps on from there.
+        table.step(np.asarray([0], np.int32), np.ones(1, bool), env(1))
+        assert [int(v.sum()) for v in valid(0)] == [1, 1, 1, 1]
+
     def test_read_slot_shape_matches_initial_state(self):
         table = make_table()
         piece = table.read_slot(3)

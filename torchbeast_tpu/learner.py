@@ -625,18 +625,20 @@ def _moe_stats(sown) -> Dict[str, Any]:
     """What a dropless expert layer says its router did (models/moe.py
     DroplessMoE), over the layers: every assignment computed (8 x tokens
     x layers for OLMoE: nothing dropped) and the fullest expert's rows
-    over the mean, worst layer. Empty for every other model."""
+    over the mean, worst layer; for layers that hold a share of their
+    experts (mellum2), the same two over the experts held. Empty for
+    every other model."""
     by_name: Dict[str, list] = {}
     for path, leaf in flax.traverse_util.flatten_dict(sown).items():
         by_name.setdefault(path[-1], []).append(leaf)
-    if not by_name:
-        return {}
-    return {
-        "moe_assignments": sum(by_name["assignments"]),
-        "moe_load_max_over_mean": jnp.max(
-            jnp.stack(by_name["load_max_over_mean"])
-        ),
-    }
+    stats = {}
+    for name in ("assignments", "held_assignments"):
+        if name in by_name:
+            stats["moe_" + name] = sum(by_name[name])
+    for name in ("load_max_over_mean", "held_load_max_over_mean"):
+        if name in by_name:
+            stats["moe_" + name] = jnp.max(jnp.stack(by_name[name]))
+    return stats
 
 
 def donate_argnums_for(donate, donate_batch: bool = False) -> tuple:

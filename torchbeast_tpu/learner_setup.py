@@ -44,6 +44,10 @@ _FAMILY_FIELD_REFUSALS = {
         "--trunk_channels applies to --model {families} only (the knob "
         "widens the ResNet conv trunk)"
     ),
+    "expert_share": (
+        "--expert_share i/n (share i of the n chips that divide each "
+        "layer's experts) applies to --model {families} only"
+    ),
 }
 FAMILY_FIELD_FLAGS = tuple(_FAMILY_FIELD_REFUSALS)
 
@@ -166,14 +170,24 @@ def add_learner_arguments(parser, *, model_default,
                              "M to amortize it; the learner batch must "
                              "divide into M microbatches.")
     parser.add_argument("--num_layers", type=int, default=0,
-                        help="Depth of --model transformer or olmoe "
-                             "(0: the family's own, 2 and the published "
-                             "16).")
+                        help="Depth of --model transformer, olmoe or "
+                             "mellum2 (0: the family's own, 2 and the "
+                             "published 16 and 28; mellum2 in whole "
+                             "periods of 4).")
     parser.add_argument("--memory_len", type=int, default=0,
-                        help="Steps of its own past a transformer or "
-                             "olmoe policy attends over, carried as the "
-                             "rolling KV cache (0: the family's own, 64 "
-                             "and 128).")
+                        help="Steps of its own past a transformer, olmoe "
+                             "or mellum2 policy attends over, carried as "
+                             "the rolling KV cache (0: the family's own, "
+                             "64, 128 and 4095; mellum2: its full "
+                             "layers' cache, the sliding layers carry "
+                             "min(memory_len, 1023)).")
+    parser.add_argument("--expert_share", default="",
+                        help="--model mellum2: 'i/n' holds share i of "
+                             "the n chips that divide each layer's 64 "
+                             "experts (0/4: experts 0..15). The layer "
+                             "routes over all 64 and adds its own "
+                             "experts' part of the sum; nothing stands "
+                             "in for the other chips. Empty: all.")
     parser.add_argument("--num_experts", type=int, default=0,
                         help="Replace the transformer's FFN with a top-2 "
                              "mixture of N experts (model=transformer "
@@ -682,6 +696,16 @@ def init_model_and_params(flags, num_actions, batch_size, frame_shape,
         if value:
             _check_family_takes(flags.model, flag, valid=value > 0)
             extra[flag] = value
+    expert_share = getattr(flags, "expert_share", "")
+    if expert_share:
+        _check_family_takes(flags.model, "expert_share")
+        try:
+            share, of = (int(part) for part in expert_share.split("/"))
+        except ValueError:
+            raise ValueError(
+                f"--expert_share {expert_share!r} must be 'i/n', two ints"
+            ) from None
+        extra["expert_share"] = (share, of)
     trunk_channels = getattr(flags, "trunk_channels", "")
     if trunk_channels:
         _check_family_takes(flags.model, "trunk_channels")

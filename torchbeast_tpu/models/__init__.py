@@ -10,7 +10,8 @@ import dataclasses
 from torchbeast_tpu.models.atari_net import AtariNet  # noqa: F401
 from torchbeast_tpu.models.cores import LSTMCore  # noqa: F401
 from torchbeast_tpu.models.mlp import MLPNet  # noqa: F401
-from torchbeast_tpu.models import olmoe
+from torchbeast_tpu.models import mellum2, olmoe
+from torchbeast_tpu.models.mellum2 import Mellum2Net  # noqa: F401
 from torchbeast_tpu.models.olmoe import OLMoENet  # noqa: F401
 from torchbeast_tpu.models.pipelined import PipelinedMLPNet  # noqa: F401
 from torchbeast_tpu.models.resnet import ResNet  # noqa: F401
@@ -29,7 +30,12 @@ _REGISTRY = {
     "transformer": TransformerNet,
     "pipelined_transformer": PipelinedTransformerNet,
     "olmoe": OLMoENet,
+    "mellum2": Mellum2Net,
 }
+# A family whose widths are a published table (its module's `PUBLISHED`,
+# keyed by the class's fields): read when the model is built, so that a
+# test shrinks the family there.
+_PUBLISHED_TABLES = {OLMoENet: olmoe, Mellum2Net: mellum2}
 MODEL_NAMES = tuple(_REGISTRY)
 
 
@@ -61,7 +67,6 @@ def create_model(name: str, num_actions: int, use_lstm: bool = False, **kwargs):
             "--use_lstm does not apply to the transformer family (its "
             "memory is the KV cache); drop the flag"
         )
-    if cls is OLMoENet:
-        # The published widths, read now (a test shrinks the table).
-        kwargs = {**olmoe.PUBLISHED, **kwargs}
+    if cls in _PUBLISHED_TABLES:
+        kwargs = {**_PUBLISHED_TABLES[cls].PUBLISHED, **kwargs}
     return cls(num_actions=num_actions, use_lstm=use_lstm, **kwargs)

@@ -31,10 +31,17 @@ contiguous group of a [t*K, d] matrix and the experts are three grouped
 matmuls (`grouped_matmul`); t x K rows is a static shape, so no
 assignment is padded to a capacity and none is dropped. The capacity
 path stays for `--num_experts` and `parallel/ep.py`.
+
+A `DroplessMoE` may hold a share of its experts (`held`: the chip's
+part of a layer that several chips divide, models/mellum2.py): it
+routes over all of them, has weights for its own alone, and returns
+the part of the sum that its own experts give. The sorted rows of the
+other experts are never visited (the kernels' `group_offset`).
 """
 
+import functools
 import math
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -200,14 +207,18 @@ def _gmm_call(kernel, lhs, rhs, sizes, rows, **kwargs):
     )
 
 
-@jax.custom_vjp
-def grouped_matmul(lhs, rhs, sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def grouped_matmul(lhs, rhs, sizes, first=None):
     """lhs [m, k] in contiguous groups of `sizes` [E] rows, rhs
     [E, k, n] -> [m, n]: rows of group e times rhs[e]. The kernels are
     JAX's shipped megablox `gmm` / `tgmm`; this wrapper fixes their
     operand and result types (above) and pads the rows to the kernel's
-    tile, the padding going to the last group as rows of zeros."""
-    return _grouped_matmul_fwd(lhs, rhs, sizes)[0]
+    tile, the padding going to the last group as rows of zeros.
+
+    With `first` (a Python int), rhs [C, k, n] holds groups first ..
+    first + C - 1 of the E alone: the rows of the other groups are not
+    visited, and come out as zeros (as do their gradients)."""
+    return _grouped_matmul_fwd(lhs, rhs, sizes, first)[0]
 
 
 def _pad_rows(sizes, *matrices):
@@ -220,21 +231,30 @@ def _pad_rows(sizes, *matrices):
     return tile, sizes, matrices
 
 
-def _grouped_matmul_fwd(lhs, rhs, sizes):
+def _from_group(first):
+    if first is None:
+        return {}
+    return {"group_offset": jnp.asarray(first, jnp.int32)}
+
+
+def _grouped_matmul_fwd(lhs, rhs, sizes, first):
     tile, padded_sizes, (padded,) = _pad_rows(sizes, lhs)
-    out = _gmm_call(_megablox.gmm, padded, rhs, padded_sizes, tile)
+    out = _gmm_call(
+        _megablox.gmm, padded, rhs, padded_sizes, tile, **_from_group(first)
+    )
     return out[: lhs.shape[0]], (lhs, rhs, sizes)
 
 
-def _grouped_matmul_bwd(residuals, grad):
+def _grouped_matmul_bwd(first, residuals, grad):
     lhs, rhs, sizes = residuals
     tile, padded_sizes, (lhs_p, grad_p) = _pad_rows(sizes, lhs, grad)
     grad_lhs = _gmm_call(
-        _megablox.gmm, grad_p, rhs, padded_sizes, tile, transpose_rhs=True
+        _megablox.gmm, grad_p, rhs, padded_sizes, tile, transpose_rhs=True,
+        **_from_group(first),
     )[: lhs.shape[0]]
     grad_rhs = _gmm_call(
         _megablox.tgmm, lhs_p.swapaxes(0, 1), grad_p, padded_sizes, tile,
-        num_actual_groups=rhs.shape[0],
+        num_actual_groups=rhs.shape[0], **_from_group(first),
     )
     return grad_lhs.astype(lhs.dtype), grad_rhs.astype(rhs.dtype), None
 
@@ -242,15 +262,19 @@ def _grouped_matmul_bwd(residuals, grad):
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
-def dropless_experts(x, idx, gate, w_gate, w_up, w_down):
+def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None):
     """sum_k gate[t, k] * expert_{idx[t, k]}(x[t]) with SwiGLU experts,
     every assignment computed.
 
     x [t, d]; idx, gate [t, K]; w_gate, w_up [E, d, f]; w_down [E, f, d].
     Returns (y [t, d], group sizes [E]).
+
+    `first_of` = (first, E) when the weights are those of experts first
+    .. first + C - 1 of E ([C, d, f], [C, f, d]): the sum then runs over
+    the assignments to those alone, the sizes are still all E experts'.
     """
     tokens, K = idx.shape
-    E = w_gate.shape[0]
+    first, E = first_of or (None, w_gate.shape[0])
     with jax.named_scope("moe_dispatch"):
         flat = idx.reshape(tokens * K)
         # order[i]: which (token, rank) assignment sits in sorted row i.
@@ -262,9 +286,9 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down):
         rows = _permute(jnp.repeat(x, K, axis=0), order, inverse)
     with jax.named_scope("moe_experts"):
         hidden = nn.silu(
-            grouped_matmul(rows, w_gate, sizes)
-        ) * grouped_matmul(rows, w_up, sizes)
-        out = grouped_matmul(hidden, w_down, sizes)
+            grouped_matmul(rows, w_gate, sizes, first)
+        ) * grouped_matmul(rows, w_up, sizes, first)
+        out = grouped_matmul(hidden, w_down, sizes, first)
     with jax.named_scope("moe_combine"):
         out = _permute(out, inverse, order).reshape(tokens, K, -1)
         y = jnp.einsum(
@@ -275,14 +299,23 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down):
 
 class DroplessMoE(nn.Module):
     """[tokens, d_model] -> [tokens, d_model]: softmax router, top-k
-    gates as the selected probabilities (not renormalised), SwiGLU
-    experts without biases, no capacity (OLMoE's layer)."""
+    gates as the selected probabilities (OLMoE's layer: not
+    renormalised) or over their sum (`renormalise`, a config's
+    `norm_topk_prob`), SwiGLU experts without biases, no capacity.
+
+    `held` = (first, count) of the `num_experts`: the layer routes over
+    all of them, computes gates and the load-balance term over all of
+    them, and holds `w_gate`/`w_up`/`w_down` for `count` alone; what it
+    returns is those experts' part of the sum, and nothing stands in
+    for the rest. None: all are held."""
 
     d_ff: int  # width of one expert
     num_experts: int
     top_k: int
     aux_loss_weight: float = 1e-2
     dtype: Any = jnp.float32
+    renormalise: bool = False
+    held: Optional[Tuple[int, int]] = None
 
     @nn.compact
     def __call__(self, x):
@@ -290,6 +323,11 @@ class DroplessMoE(nn.Module):
         E, K = self.num_experts, self.top_k
         if K > E:
             raise ValueError(f"top_k={K} exceeds num_experts={E}")
+        first, count = self.held or (0, E)
+        if first < 0 or count < 1 or first + count > E:
+            raise ValueError(
+                f"held={self.held} is not a range of the {E} experts"
+            )
 
         # f32 at the highest matmul precision: the logits decide WHICH
         # experts run, and a rounded logit picks another expert where
@@ -301,16 +339,19 @@ class DroplessMoE(nn.Module):
             )(x.astype(jnp.float32))
             probs = jax.nn.softmax(router_logits, axis=-1)  # [t, E]
             gate, idx = jax.lax.top_k(probs, K)  # [t, K]
+            if self.renormalise:
+                gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
 
         # fan-in is one expert's d (or f), not E times it.
         kernel_init = nn.initializers.lecun_normal(batch_axis=(0,))
-        w_gate = self.param("w_gate", kernel_init, (E, d, self.d_ff))
-        w_up = self.param("w_up", kernel_init, (E, d, self.d_ff))
-        w_down = self.param("w_down", kernel_init, (E, self.d_ff, d))
+        w_gate = self.param("w_gate", kernel_init, (count, d, self.d_ff))
+        w_up = self.param("w_up", kernel_init, (count, d, self.d_ff))
+        w_down = self.param("w_down", kernel_init, (count, self.d_ff, d))
         y, sizes = dropless_experts(
             x.astype(self.dtype), idx, gate,
             w_gate.astype(self.dtype), w_up.astype(self.dtype),
             w_down.astype(self.dtype),
+            **({} if count == E else {"first_of": (first, E)}),
         )
 
         # Load balance: E x sum_e (share of the K*t assignments that
@@ -320,12 +361,22 @@ class DroplessMoE(nn.Module):
         if not self.is_initializing():
             # `losses` is added to the objective, `moe_stats` (what the
             # router did) to the update's stats: learner.compute_loss.
-            for collection, name, value in (
+            sown = [
                 ("losses", "moe_load_balance", self.aux_loss_weight * aux),
                 ("moe_stats", "assignments", jnp.sum(load)),
                 ("moe_stats", "load_max_over_mean",
                  jnp.max(load) * E / (tokens * K)),
-            ):
+            ]
+            if self.held is not None:
+                # The part of those this layer computed, and how uneven
+                # its own experts' rows are.
+                mine = load[first : first + count]
+                sown += [
+                    ("moe_stats", "held_assignments", jnp.sum(mine)),
+                    ("moe_stats", "held_load_max_over_mean",
+                     jnp.max(mine) * count / jnp.maximum(jnp.sum(mine), 1.0)),
+                ]
+            for collection, name, value in sown:
                 self.sow(
                     collection, name, value,
                     reduce_fn=lambda prev, new: new,

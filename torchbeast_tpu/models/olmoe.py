@@ -51,16 +51,26 @@ PUBLISHED = {
 }
 
 
-def rope(x, positions, theta):
-    """Rotate-half RoPE. x [B, S, H, D]; positions [S] (may be negative:
-    only differences between a query's and a key's reach the scores)."""
+def rope_rotate(x, positions, inv_freq, factor=1.0):
+    """Rotate-half RoPE at the given frequencies. x [B, S, H, D];
+    positions [S] (may be negative: only differences between a query's
+    and a key's reach the scores); inv_freq [D/2]. `factor` scales cos
+    and sin (YaRN's attention factor, models/mellum2.py)."""
     half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     cos = jnp.tile(jnp.cos(angles), 2)[None, :, None, :]
     sin = jnp.tile(jnp.sin(angles), 2)[None, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
     return x * cos + rotated * sin
+
+
+def rope(x, positions, theta):
+    """Rotate-half RoPE at the frequencies theta^(-2i/D)."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    return rope_rotate(x, positions, inv_freq)
 
 
 class _OLMoEBlock(nn.Module):
@@ -151,7 +161,8 @@ class OLMoENet(TransformerNet):
     aux_loss_weight: float = 0.01
 
     @nn.nowrap
-    def make_block(self, name: str):
+    def make_block(self, name: str, layer: int):
+        del layer  # every layer is the same block
         return _OLMoEBlock(
             d_model=self.d_model, num_heads=self.num_heads,
             memory_len=self.memory_len,

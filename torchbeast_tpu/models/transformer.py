@@ -233,12 +233,14 @@ class TransformerNet(nn.Module):
     # What the uint8 frame is scaled to before the projection (models/
     # olmoe.py centres it).
     frame_range: Tuple[float, float] = (0.0, 1.0)
+    # The projection of reward and last action starts at zero (models/
+    # mellum2.py says why); a family's choice, not a flag.
+    zero_init_extras: bool = False
 
     @nn.compact
     def __call__(self, inputs, core_state, *, sample_action: bool = True):
         frame = inputs["frame"]  # [T, B, ...]
         T, B = frame.shape[:2]
-        M = self.memory_len
 
         with jax.named_scope("obs_embed"):
             x = frame.reshape((T * B, -1)).astype(self.dtype) / 255.0
@@ -252,8 +254,12 @@ class TransformerNet(nn.Module):
             reward = jnp.clip(
                 inputs["reward"].astype(jnp.float32), -1, 1
             ).reshape(T * B, 1)
+            side_init = (
+                {"kernel_init": nn.initializers.zeros}
+                if self.zero_init_extras else {}
+            )
             x = x.astype(jnp.float32) + nn.Dense(
-                self.d_model, name="extras"
+                self.d_model, name="extras", **side_init
             )(jnp.concatenate([reward, one_hot], axis=-1))
             x = x.reshape(T, B, self.d_model).transpose(1, 0, 2)  # [B, T, d]
 
@@ -266,18 +272,23 @@ class TransformerNet(nn.Module):
         # [t - M, t]" — encoding that as a band mask makes the batch
         # (learner) forward identical to the actor's stepwise forward for
         # ANY T and cache fill level. (Shared with the pipelined family,
-        # ops/attention.py.)
-        band, offsets = band_relative_offsets(T, M)
-
-        # In-unroll mask: band-causal + same segment.
+        # ops/attention.py.) M is a layer's own (`layer_caches`): one
+        # band and one in-unroll mask is built for each M the layers
+        # have, not for each layer.
         same = seg[:, :, None] == seg[:, None, :]
-        seq_mask = band[None, :, M:] & same  # [B, T, T]
         # Cache mask: band + validity + no done up to the query (cache
         # precedes slot 0; any done invalidates it from there on).
         no_done_yet = jnp.cumsum(done.astype(jnp.int32), axis=0).T == 0
+        geometry = {}
+        for M, _, _ in self.layer_caches():
+            if M not in geometry:
+                band, offsets = band_relative_offsets(T, M)
+                # In-unroll mask: band-causal + same segment. [B, T, T]
+                geometry[M] = band, offsets, band[None, :, M:] & same
 
         new_state = []
-        for layer in range(self.num_layers):
+        for layer, (M, _, _) in enumerate(self.layer_caches()):
+            band, offsets, seq_mask = geometry[M]
             k_cache, v_cache, valid = core_state[layer]
             # state convention [M, B, ...] -> model-internal [B, M, ...]
             k_cache_b = k_cache.transpose(1, 0, 2, 3)
@@ -289,7 +300,7 @@ class TransformerNet(nn.Module):
                 & no_done_yet[:, :, None]
             )  # [B, T, M]
             mask = jnp.concatenate([cache_mask, seq_mask], axis=-1)
-            x, k_new, v_new = self.make_block(f"block_{layer}")(
+            x, k_new, v_new = self.make_block(f"block_{layer}", layer)(
                 x, (k_cache_b, v_cache_b), mask, offsets,
                 cache_mask=cache_mask, seg=seg,
                 cache_valid=valid_b, no_done=no_done_yet,
@@ -321,12 +332,13 @@ class TransformerNet(nn.Module):
         )(core_output, done, (), sample_action)
         return out, tuple(new_state)
 
-    # The two things a family built on this scaffolding replaces
-    # (models/olmoe.py): its block and its last norm. Everything else —
-    # observation and extras projections, masks, cache roll, state
-    # convention, head — is this class's.
+    # What a family built on this scaffolding replaces (models/olmoe.py,
+    # models/mellum2.py): its block, its last norm, and each layer's
+    # cache. Everything else — observation and extras projections,
+    # masks, cache roll, state convention, head — is this class's.
     @nn.nowrap
-    def make_block(self, name: str):
+    def make_block(self, name: str, layer: int):
+        del layer  # every layer is the same block
         block_cls = nn.remat(_Block) if self.remat else _Block
         return block_cls(
             d_model=self.d_model, num_heads=self.num_heads,
@@ -346,14 +358,21 @@ class TransformerNet(nn.Module):
     def make_final_norm(self):
         return nn.LayerNorm()
 
+    @nn.nowrap
+    def layer_caches(self) -> Tuple[Tuple[int, int, int], ...]:
+        """Each layer's (memory_len, key/value heads, head size): the
+        shape of the cache it carries, [memory_len, B, heads, size], and
+        the band it attends within. Here every layer has the one."""
+        return (
+            (self.memory_len, self.num_heads, self.d_model // self.num_heads),
+        ) * self.num_layers
+
     def initial_state(self, batch_size: int) -> Tuple:
-        hd = self.d_model // self.num_heads
-        M = self.memory_len
         return tuple(
             (
-                jnp.zeros((M, batch_size, self.num_heads, hd), jnp.float32),
-                jnp.zeros((M, batch_size, self.num_heads, hd), jnp.float32),
+                jnp.zeros((M, batch_size, heads, hd), jnp.float32),
+                jnp.zeros((M, batch_size, heads, hd), jnp.float32),
                 jnp.zeros((M, batch_size), jnp.float32),
             )
-            for _ in range(self.num_layers)
+            for M, heads, hd in self.layer_caches()
         )

@@ -598,22 +598,50 @@ def dense_transformer_attend(q, k_all, v_all, mask, offsets, rel_bias):
     the Ulysses path below (which is exactly this on a head slice), so
     the two can never drift apart numerically.
 
-    q: [B, T, H, D]; k_all/v_all: [B, M+T, H, D] (cache prepended);
+    q: [B, T, H, D]; k_all/v_all: [B, M+T, Hkv, D] (cache prepended);
     mask: [B, T, M+T] bool; offsets: [T, M+T] int in [0, M];
     rel_bias: [H, M+1], or None for a family whose positions enter
     elsewhere (RoPE, models/olmoe.py). Scores and softmax run in f32;
     the combine runs in v's dtype.
+
+    Hkv may be a divisor of H (grouped-query heads, models/mellum2.py):
+    query head j reads key/value head j // (H // Hkv). The queries are
+    then contracted by group, [B, T, Hkv, G, D], and K and V are never
+    repeated.
     """
-    scale = q.shape[-1] ** -0.5
+    B, T, H, D = q.shape
+    Hkv = k_all.shape[2]
+    scale = D ** -0.5
+    if Hkv == H:
+        scores = (
+            jnp.einsum("bqhd,bkhd->bhqk", q, k_all).astype(jnp.float32)
+            * scale
+        )
+        if rel_bias is not None:
+            scores = scores + rel_bias[:, offsets][None]
+        scores = jnp.where(mask[:, None], scores, BIG_NEG)
+        weights = jax.nn.softmax(scores, axis=-1).astype(v_all.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", weights, v_all)
+    if H % Hkv:
+        raise ValueError(
+            f"{H} query heads do not divide over {Hkv} key/value heads"
+        )
+    G = H // Hkv
     scores = (
-        jnp.einsum("bqhd,bkhd->bhqk", q, k_all).astype(jnp.float32)
+        jnp.einsum(
+            "bqhgd,bkhd->bhgqk", q.reshape(B, T, Hkv, G, D), k_all
+        ).astype(jnp.float32)
         * scale
     )
     if rel_bias is not None:
-        scores = scores + rel_bias[:, offsets][None]
-    scores = jnp.where(mask[:, None], scores, BIG_NEG)
+        scores = scores + rel_bias[:, offsets].reshape(
+            (1, Hkv, G) + offsets.shape
+        )
+    scores = jnp.where(mask[:, None, None], scores, BIG_NEG)
     weights = jax.nn.softmax(scores, axis=-1).astype(v_all.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", weights, v_all)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", weights, v_all).reshape(
+        B, T, H, D
+    )
 
 
 def ulysses_attention(

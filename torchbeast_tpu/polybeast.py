@@ -2147,17 +2147,19 @@ def train(flags):
                 reg.gauge("learner.sample_reuse").set(replay_reuse)
                 reg.gauge("learner_queue.depth").set(learner_queue.size())
                 reg.gauge("inference.depth").set(serving_depth_fn())
-                if "moe_assignments" in stats_now:
-                    # What a dropless expert layer's router did in the
-                    # last fetched update (learner._moe_stats): every
-                    # assignment computed, and the fullest expert's
-                    # rows over the mean, worst layer.
-                    reg.gauge("moe.assignments").set(
-                        stats_now["moe_assignments"]
-                    )
-                    reg.gauge("moe.load_max_over_mean").set(
-                        stats_now["moe_load_max_over_mean"]
-                    )
+                # What a dropless expert layer's router did in the last
+                # fetched update (learner._moe_stats): every assignment
+                # computed, and the fullest expert's rows over the mean,
+                # worst layer; with --expert_share, the same two over
+                # the experts held here.
+                for name in (
+                    "assignments", "load_max_over_mean",
+                    "held_assignments", "held_load_max_over_mean",
+                ):
+                    if "moe_" + name in stats_now:
+                        reg.gauge("moe." + name).set(
+                            stats_now["moe_" + name]
+                        )
                 tele.write(extra={"step": now_step})
             means = timings.means()
             log.info(

@@ -74,7 +74,7 @@ def stages_for(model: str, use_lstm: bool) -> List[Stage]:
     if model == "deep":
         for i in range(3):
             stages.append(Stage(f"stage{i}", (False, "front", True)))
-    if model in ("transformer", "pipelined_transformer"):
+    if model in ("transformer", "pipelined_transformer", "mellum2"):
         stages.append(Stage("blocks", (False, True)))
     if use_lstm:
         stages.append(Stage("core", (False, True)))
@@ -88,7 +88,7 @@ def model_kwargs(model: str, assignment: Dict[str, Any]) -> Dict[str, Any]:
         kwargs["remat"] = tuple(
             assignment[f"stage{i}"] for i in range(3)
         )
-    if model in ("transformer", "pipelined_transformer"):
+    if model in ("transformer", "pipelined_transformer", "mellum2"):
         kwargs["remat"] = bool(assignment["blocks"])
     if "core" in assignment:
         kwargs["core_remat"] = bool(assignment["core"])
