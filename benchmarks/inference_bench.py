@@ -226,7 +226,7 @@ def main():
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--actors", type=int, default=32)
     parser.add_argument("--seconds", type=float, default=5.0)
-    parser.add_argument("--num_inference_threads", type=int, default=2)
+    parser.add_argument("--num_inference_threads", type=int, default=1)
     parser.add_argument("--max_batch_size", type=int, default=64)
     parser.add_argument("--model", default="shallow")
     parser.add_argument("--skip_hot_path", action="store_true",
@@ -322,12 +322,7 @@ def main():
             threading.Thread(
                 target=inference_loop,
                 args=(batcher, act_fn, args.max_batch_size),
-                # Pipelined dispatch is single-consumer-only (see
-                # runtime/inference.py); mirror polybeast's wiring.
-                kwargs={
-                    "lock": lock,
-                    "pipelined": args.num_inference_threads == 1,
-                },
+                kwargs={"lock": lock},
                 daemon=True,
             )
             for _ in range(args.num_inference_threads)

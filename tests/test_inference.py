@@ -1,15 +1,21 @@
 """inference_loop (runtime/inference.py): bucket padding, row routing,
-and the one-deep dispatch pipeline — replies must always arrive, and a
-single sparse request must be answered immediately (the pipeline may
-only hold a reply while another batch is in hand; anything else would
-deadlock actors blocked in compute())."""
+and the launcher/replier split — the launcher dispatches the next batch
+while an earlier reply is still outstanding, a reply goes out the
+instant its outputs land (a lone request is answered with nothing
+behind it), a failing fetch fails only its batch, and on every exit
+(batcher closed, poisoned table) outstanding replies are delivered or
+failed and the replier is joined."""
 
 import threading
+import time
 
 import numpy as np
 import pytest
 
+from torchbeast_tpu import telemetry
+from torchbeast_tpu.runtime.errors import StateTablePoisonedError
 from torchbeast_tpu.runtime.inference import (
+    _HANDOVER_DEPTH,
     bucket_size,
     default_buckets,
     inference_loop,
@@ -18,7 +24,9 @@ from torchbeast_tpu.runtime.inference import (
     pad_to,
     slice_to,
 )
-from torchbeast_tpu.runtime.queues import DynamicBatcher
+from torchbeast_tpu.runtime.queues import AsyncError, DynamicBatcher
+
+WAIT_S = 10  # every wait in this file is bounded; none should take 1 s
 
 
 def _act_fn(env_outputs, agent_state, batch_size):
@@ -38,85 +46,358 @@ def _request(i):
     }
 
 
-@pytest.mark.parametrize("pipelined", [False, True])
-def test_rows_route_back_to_their_producers(pipelined):
-    batcher = DynamicBatcher(
-        batch_dim=1, minimum_batch_size=1, maximum_batch_size=8,
+def _slot_request(i):
+    return {
+        "env": {"frame": np.full((1, 1, 3), i, np.float32)},
+        "slot": np.full((1, 1), i, np.int32),
+        "advance": np.full((1, 1), True, bool),
+    }
+
+
+class FakeTable:
+    """A state table on the host: `step` doubles the frames; `fetch`
+    can be held on an event (a device_get that has not landed), made
+    to raise for one batch, and `step` made to poison the table."""
+
+    trash_slot = 99
+
+    def __init__(self):
+        self.poisoned = False
+        self.steps = 0
+        self.fetch_gate = None  # threading.Event the fetch waits on
+        self.fetch_entered = threading.Event()
+        self.fail_fetch_of = set()  # first-row values whose fetch raises
+        self.poison_step = None  # the step (0-based) that poisons
+
+    def step(self, slots, advance, env_outputs, context=None):
+        if self.steps == self.poison_step:
+            self.poisoned = True
+            raise RuntimeError("donated buffer consumed")
+        self.steps += 1
+        return {"action": env_outputs["frame"] * 2}
+
+    def fetch(self, outputs, n):
+        self.fetch_entered.set()
+        if self.fetch_gate is not None:
+            assert self.fetch_gate.wait(WAIT_S)
+        if float(outputs["action"][0, 0, 0]) / 2 in self.fail_fetch_of:
+            raise RuntimeError("fetch failed")
+        return {"action": outputs["action"][:, :n]}
+
+
+def _batcher(max_batch=8):
+    return DynamicBatcher(
+        batch_dim=1, minimum_batch_size=1, maximum_batch_size=max_batch,
         timeout_ms=5,
     )
-    server = threading.Thread(
-        target=inference_loop,
-        args=(batcher, _act_fn, 8),
-        kwargs={"pipelined": pipelined},
-        daemon=True,
-    )
-    server.start()
 
-    results = {}
+
+class Serving:
+    """One inference_loop on a thread of its own, with its own series
+    (a prefix a test) and the error it ended with."""
+
+    def __init__(self, prefix, batcher, act_fn=None, state_table=None,
+                 max_batch=8):
+        self.prefix, self.batcher, self.error = prefix, batcher, None
+
+        def run():
+            try:
+                inference_loop(
+                    batcher, act_fn, max_batch, state_table=state_table,
+                    telemetry_prefix=prefix,
+                )
+            except BaseException as e:  # noqa: BLE001
+                self.error = e
+
+        self.thread = threading.Thread(
+            target=run, name=f"serving-{prefix}", daemon=True
+        )
+        self.thread.start()
+
+    def counter(self, name):
+        reg = telemetry.get_registry()
+        return reg.counter(f"{self.prefix}.{name}").value()
+
+    def repliers(self):
+        return [
+            t for t in threading.enumerate()
+            if t.name == f"serving-{self.prefix}-replier"
+        ]
+
+    def join(self):
+        self.thread.join(WAIT_S)
+        assert not self.thread.is_alive()
+        # The replier's life is the loop's.
+        assert self.repliers() == []
+
+
+class Producers:
+    """One thread a request, each blocked in compute() until its reply
+    (or its error) arrives."""
+
+    def __init__(self, batcher, make_request):
+        self.batcher, self.make_request = batcher, make_request
+        self.results, self.errors, self.threads = {}, {}, []
+
+    def send(self, i):
+        def run():
+            try:
+                self.results[i] = self.batcher.compute(self.make_request(i))
+            except Exception as e:  # noqa: BLE001
+                self.errors[i] = e
+
+        thread = threading.Thread(target=run, daemon=True)
+        thread.start()
+        self.threads.append(thread)
+        return thread
+
+    def join(self):
+        for thread in self.threads:
+            thread.join(WAIT_S)
+        assert not any(t.is_alive() for t in self.threads)
+
+
+def _wait_for(condition):
+    deadline = time.monotonic() + WAIT_S
+    while not condition():
+        assert time.monotonic() < deadline
+        time.sleep(0.001)
+
+
+@pytest.mark.parametrize("table", [False, True])
+def test_rows_route_back_with_several_batches_in_flight(table):
+    """16 producers against batches of at most 4 and a replier that
+    starts late: batches pile up in the hand-over (the launcher blocks
+    when it is full) and every row still reaches its own producer, on
+    the table path and the table-less one."""
+    batcher = _batcher(max_batch=4)
+    fake = FakeTable() if table else None
+    gate = threading.Event()
+    if table:
+        fake.fetch_gate = gate
+    serving = Serving(
+        f"route_{table}", batcher, None if table else _act_fn,
+        state_table=fake, max_batch=4,
+    )
+    producers = Producers(batcher, _slot_request if table else _request)
+    n = 16
+    for i in range(n):
+        producers.send(i)
+    if table:
+        # At least four batches exist and the first reply is held:
+        # the launcher runs on ahead of it, as far as the hand-over
+        # lets it.
+        _wait_for(lambda: fake.steps == _HANDOVER_DEPTH + 2)
+        assert not producers.results
+        gate.set()
+    producers.join()
+    assert not producers.errors, producers.errors
+    assert len(producers.results) == n
+    for i, out in producers.results.items():
+        np.testing.assert_array_equal(
+            out["outputs"]["action"], np.full((1, 1, 3), 2 * i, np.float32)
+        )
+        if table:
+            assert set(out) == {"outputs"}
+        else:
+            np.testing.assert_array_equal(
+                out["agent_state"]["h"],
+                np.full((1, 1, 2), 10 * i + 1, np.float32),
+            )
+    assert serving.counter("rows") == n
+    batcher.close()
+    serving.join()
+    assert serving.error is None
+
+
+def test_lone_request_is_answered_without_a_second_one():
+    """Nothing behind it: the reply must not wait for another batch."""
+    batcher = _batcher()
+    serving = Serving("lone", batcher, _act_fn)
+    producers = Producers(batcher, _request)
+    producers.send(3).join(WAIT_S)
+    np.testing.assert_array_equal(
+        producers.results[3]["outputs"]["action"],
+        np.full((1, 1, 3), 6, np.float32),
+    )
+    assert serving.counter("batches") == 1
+    assert serving.counter("overlapped_dispatches") == 0
+    batcher.close()
+    serving.join()
+
+
+def test_launcher_dispatches_batch_2_before_reply_1_is_released():
+    """With reply 1 held inside fetch (a device_get that has not
+    landed), the launcher takes and dispatches batch 2; the counter
+    that says the split engages counts that launch."""
+    batcher = _batcher()
+    table = FakeTable()
+    table.fetch_gate = threading.Event()
+    serving = Serving("overlap", batcher, state_table=table)
+    producers = Producers(batcher, _slot_request)
+    producers.send(1)
+    assert table.fetch_entered.wait(WAIT_S)  # reply 1 is in the replier
+    producers.send(2)
+    _wait_for(lambda: table.steps == 2)  # batch 2 dispatched meanwhile
+    assert not producers.results
+    assert serving.counter("overlapped_dispatches") == 1
+    table.fetch_gate.set()
+    producers.join()
+    assert sorted(producers.results) == [1, 2] and not producers.errors
+    assert serving.counter("batches") == 2
+    in_flight = telemetry.get_registry().histogram(
+        "overlap.replies_in_flight"
+    ).merged()
+    assert (in_flight.count, in_flight.total) == (2, 1)
+    batcher.close()
+    serving.join()
+
+
+def test_requests_one_at_a_time_overlap_nothing():
+    batcher = _batcher()
+    serving = Serving("serial", batcher, state_table=FakeTable())
+    producers = Producers(batcher, _slot_request)
+    for i in range(5):
+        producers.send(i).join(WAIT_S)
+        assert i in producers.results
+        # set_outputs wakes the producer a moment before the replier
+        # marks the entry done; the next launch must not see it.
+        time.sleep(0.05)
+    assert serving.counter("batches") == 5
+    assert serving.counter("overlapped_dispatches") == 0
+    batcher.close()
+    serving.join()
+
+
+def test_raising_fetch_fails_only_its_batch():
+    batcher = _batcher()
+    table = FakeTable()
+    table.fail_fetch_of = {2.0}
+    serving = Serving("bad_fetch", batcher, state_table=table)
+    producers = Producers(batcher, _slot_request)
+    for i in (1, 2, 3):
+        producers.send(i).join(WAIT_S)
+    assert sorted(producers.results) == [1, 3]
+    assert isinstance(producers.errors[2], AsyncError)
+    assert "fetch failed" in str(producers.errors[2])
+    assert serving.thread.is_alive() and len(serving.repliers()) == 1
+    batcher.close()
+    serving.join()
+    assert serving.error is None
+
+
+def test_poisoned_step_answers_outstanding_replies_then_raises():
+    """Batch 1 is dispatched and its reply held; batch 2's step poisons
+    the table. Batch 2 fails at once, the loop waits for the replier to
+    deliver batch 1, joins it and raises the typed error."""
+    batcher = _batcher()
+    table = FakeTable()
+    table.fetch_gate = threading.Event()
+    table.poison_step = 1
+    serving = Serving("poison", batcher, state_table=table)
+    producers = Producers(batcher, _slot_request)
+    first = producers.send(1)
+    assert table.fetch_entered.wait(WAIT_S)
+    producers.send(2).join(WAIT_S)
+    assert isinstance(producers.errors[2], AsyncError)
+    # The launcher is in its exit now, waiting on the replier.
+    assert first.is_alive() and serving.thread.is_alive()
+    table.fetch_gate.set()
+    producers.join()
+    np.testing.assert_array_equal(
+        producers.results[1]["outputs"]["action"],
+        np.full((1, 1, 3), 2, np.float32),
+    )
+    serving.join()
+    assert isinstance(serving.error, StateTablePoisonedError)
+    batcher.close()
+
+
+def test_closing_the_batcher_delivers_pending_replies_and_joins():
+    batcher = _batcher(max_batch=1)
+    table = FakeTable()
+    table.fetch_gate = threading.Event()
+    serving = Serving("close", batcher, state_table=table, max_batch=1)
+    producers = Producers(batcher, _slot_request)
+    for i in range(3):
+        producers.send(i)
+    _wait_for(lambda: table.steps == 3)  # all dispatched, none replied
+    batcher.close()
+    time.sleep(0.05)
+    assert serving.thread.is_alive()  # the loop waits for its replier
+    assert not producers.results
+    table.fetch_gate.set()
+    producers.join()
+    assert sorted(producers.results) == [0, 1, 2] and not producers.errors
+    serving.join()
+    assert serving.error is None
+
+
+def test_full_hand_over_blocks_the_launcher():
+    """The launcher cannot run further ahead than the hand-over holds:
+    one batch in the replier's hands, _HANDOVER_DEPTH handed over, one
+    dispatched and waiting to be; the rest stay in the batcher."""
+    batcher = _batcher(max_batch=1)
+    table = FakeTable()
+    table.fetch_gate = threading.Event()
+    serving = Serving("full", batcher, state_table=table, max_batch=1)
+    producers = Producers(batcher, _slot_request)
+    n = _HANDOVER_DEPTH + 5
+    for i in range(n):
+        producers.send(i)
+    _wait_for(lambda: table.steps == _HANDOVER_DEPTH + 2)
+    time.sleep(0.05)
+    assert table.steps == _HANDOVER_DEPTH + 2
+    table.fetch_gate.set()
+    producers.join()
+    assert len(producers.results) == n and not producers.errors
+    assert serving.counter("overlapped_dispatches") >= _HANDOVER_DEPTH + 1
+    batcher.close()
+    serving.join()
+
+
+def test_two_pairs_on_one_batcher_answer_every_request():
+    """--num_inference_threads 2: two launcher/replier pairs drain one
+    batcher; every producer gets its own rows, over many rounds, with
+    the interpreter switching threads as often as it can."""
+    import sys
+
+    batcher = _batcher(max_batch=4)
+    servings = [
+        Serving("pairs", batcher, _act_fn, max_batch=4) for _ in range(2)
+    ]
     errors = []
 
     def producer(i):
         try:
-            out = batcher.compute(_request(i))
-            results[i] = out
+            for step in range(50):
+                out = batcher.compute(_request(i + step))
+                assert out["outputs"]["action"][0, 0, 0] == 2 * (i + step)
+                assert out["agent_state"]["h"][0, 0, 0] == 10 * (i + step) + 1
         except Exception as e:  # noqa: BLE001
             errors.append(e)
 
-    n = 16  # > max bucket, so multiple batches form and the pipeline
-    # actually holds replies while later batches are in hand
-    threads = [
-        threading.Thread(target=producer, args=(i,)) for i in range(n)
-    ]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(timeout=30)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [
+            threading.Thread(target=producer, args=(i,), daemon=True)
+            for i in range(16)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(3 * WAIT_S)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
     assert not errors, errors
-    assert len(results) == n
-    for i, out in results.items():
-        np.testing.assert_array_equal(
-            out["outputs"]["action"], np.full((1, 1, 3), 2 * i, np.float32)
-        )
-        np.testing.assert_array_equal(
-            out["agent_state"]["h"],
-            np.full((1, 1, 2), 10 * i + 1, np.float32),
-        )
+    assert servings[0].counter("rows") == 16 * 50
     batcher.close()
-    server.join(timeout=10)
-    assert not server.is_alive()
-
-
-def test_sparse_single_request_not_held(sparse_timeout_s=10):
-    """One lone request with nothing behind it: the pipelined loop must
-    reply without waiting for a second batch."""
-    batcher = DynamicBatcher(
-        batch_dim=1, minimum_batch_size=1, maximum_batch_size=8,
-        timeout_ms=5,
-    )
-    server = threading.Thread(
-        target=inference_loop,
-        args=(batcher, _act_fn, 8),
-        kwargs={"pipelined": True},
-        daemon=True,
-    )
-    server.start()
-    done = threading.Event()
-    out_cell = {}
-
-    def producer():
-        out_cell["out"] = batcher.compute(_request(3))
-        done.set()
-
-    threading.Thread(target=producer, daemon=True).start()
-    assert done.wait(timeout=sparse_timeout_s), (
-        "pipelined inference_loop held the only pending reply"
-    )
-    np.testing.assert_array_equal(
-        out_cell["out"]["outputs"]["action"],
-        np.full((1, 1, 3), 6, np.float32),
-    )
-    batcher.close()
-    server.join(timeout=10)
+    for serving in servings:
+        serving.thread.join(WAIT_S)
+        assert not serving.thread.is_alive() and serving.error is None
+    assert servings[0].repliers() == []
 
 
 class TestBuckets:
@@ -179,6 +460,21 @@ class TestPadSlice:
         np.testing.assert_array_equal(
             back["nested"]["r"], tree["nested"]["r"]
         )
+
+    @pytest.mark.parametrize("batch_dim", [0, 1, 2])
+    @pytest.mark.parametrize("dtype", [np.uint8, np.float32, bool, np.int64])
+    def test_pad_matches_numpy_edge_padding(self, batch_dim, dtype):
+        """The written-out pad against np.pad(mode="edge"), the
+        reference it replaced: equal values, shape and dtype on any
+        axis."""
+        rng = np.random.default_rng(batch_dim)
+        arr = (rng.integers(0, 2, (3, 5, 4, 2)) * 7).astype(dtype)
+        width = [(0, 0)] * arr.ndim
+        width[batch_dim] = (0, 8 - arr.shape[batch_dim])
+        want = np.pad(arr, width, mode="edge")
+        got = pad_to({"x": arr}, 8, batch_dim)["x"]
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(got, want)
 
     def test_pad_to_exact_size_is_identity_object(self):
         tree = self._tree(4)

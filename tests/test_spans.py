@@ -315,10 +315,12 @@ class FakeBatcher:
         return 0
 
 
-def test_serving_loop_spans_tile_an_iteration():
-    """Every instant of the serving loop lies in one of its top-level
-    spans: wait_batch + prep + dispatch + reply add up to the loop's
-    wall time (within 5%)."""
+def test_serving_loop_spans_tile_each_role():
+    """Every instant of the launcher lies in one of its three spans:
+    wait_batch + prep + dispatch add up to the loop's wall time (within
+    5%). The replier's work on a batch is the reply span, beside them:
+    it costs the launcher nothing, so the four no longer sum to the
+    wall."""
     from torchbeast_tpu.runtime.inference import inference_loop
 
     delay = 0.004
@@ -339,18 +341,99 @@ def test_serving_loop_spans_tile_an_iteration():
         stage: reg.histogram(f"{prefix}.{stage}_s").merged().total
         for stage in ("wait_batch", "prep", "dispatch", "reply")
     }
+    # The loop returned, so its replier has answered every batch.
     assert all(batch.outputs is not None for batch in batcher.batches)
     assert reg.counter(f"{prefix}.batches").value() == 40
     # wait_batch saw the 40 batches and the batcher's end.
     assert reg.histogram(f"{prefix}.wait_batch_s").count == 41
+    assert reg.histogram(f"{prefix}.reply_s").count == 40
     for stage, total in totals.items():
         assert total >= 40 * delay, (stage, total)
-    assert sum(totals.values()) == pytest.approx(wall, rel=0.05)
+    launcher = sum(totals.values()) - totals["reply"]
+    assert launcher <= wall
+    assert launcher == pytest.approx(wall, rel=0.05)
+
+
+def test_serving_loop_span_tree_spans_two_threads():
+    """The span tree of a batch, now that two threads work on it:
+    `state_table.context` and `.call` lie inside the launcher's
+    dispatch span, `state_table.fetch` inside the replier's reply span,
+    each child on its parent's thread, and the two threads differ."""
+    import jax.numpy as jnp
+
+    from torchbeast_tpu.runtime.inference import inference_loop
+    from torchbeast_tpu.runtime.queues import DynamicBatcher
+    from torchbeast_tpu.runtime.state_table import DeviceStateTable
+
+    def act(ctx, env, state):
+        return {"out": env["frame"] + state["h"]}, {"h": state["h"] + 1.0}
+
+    tracer = telemetry.get_tracer()
+    recording = tracer.recording()
+    tracer.set_recording(True)
+    started_us = time.perf_counter() * 1e6
+    try:
+        table = DeviceStateTable(
+            {"h": jnp.zeros((1, 1, 4))}, num_slots=2, act_fn=act,
+            batch_dim=1,
+        )
+        batcher = DynamicBatcher(
+            batch_dim=1, minimum_batch_size=1, maximum_batch_size=1,
+            timeout_ms=5,
+        )
+        prefix = "tree_test"
+        server = threading.Thread(
+            target=inference_loop, args=(batcher, None, 1),
+            kwargs={"state_table": table, "telemetry_prefix": prefix},
+            daemon=True,
+        )
+        server.start()
+        for _ in range(3):
+            batcher.compute({
+                "env": {"frame": np.ones((1, 1, 4), np.float32)},
+                "slot": np.zeros((1, 1), np.int32),
+                "advance": np.ones((1, 1), bool),
+            })
+        batcher.close()
+        server.join(10)
+        assert not server.is_alive()
+        events = [
+            e for e in tracer.events()
+            if e["name"].startswith((prefix, "state_table."))
+            and e["ts"] >= started_us
+        ]
+    finally:
+        tracer.set_recording(recording)
+
+    def named(name):
+        return [e for e in events if e["name"] == name]
+
+    def inside(child, parents):
+        return any(
+            p["tid"] == child["tid"]
+            and p["ts"] <= child["ts"]
+            and child["ts"] + child["dur"] <= p["ts"] + p["dur"]
+            for p in parents
+        )
+
+    dispatches, replies = named(f"{prefix}.dispatch"), named(f"{prefix}.reply")
+    assert len(dispatches) == len(replies) == 3
+    for child in named("state_table.context") + named("state_table.call"):
+        assert inside(child, dispatches), child
+    fetches = named("state_table.fetch")
+    assert len(fetches) == 3
+    for child in fetches:
+        assert inside(child, replies), child
+    launcher_tids = {e["tid"] for e in dispatches}
+    replier_tids = {e["tid"] for e in replies}
+    assert len(launcher_tids) == len(replier_tids) == 1
+    assert launcher_tids != replier_tids
 
 
 def test_state_table_step_parts_lie_inside_dispatch():
-    """context + put + call are the children of the serving
-    loop's dispatch span; fetch is the child of reply."""
+    """context + call tile `step`, which is all of the serving loop's
+    dispatch span (the launcher's); fetch is the child of reply (the
+    replier's: the test above follows both through a loop)."""
     import jax.numpy as jnp
 
     from torchbeast_tpu.runtime.inference import (

@@ -610,6 +610,68 @@ class TestTransferGuard:
             with pytest.raises(Exception, match="host-to-device"):
                 legacy(host_state)
 
+    @pytest.mark.parametrize("leak", [False, True])
+    def test_guard_holds_on_the_launcher_and_the_replier(self, leak):
+        """A serving loop is two threads and `jax.transfer_guard(...)`
+        is per thread, so the guard is set process-wide here (a thread
+        that set none of its own reads the config's value). A whole
+        loop's traffic under "disallow": the launcher's hand-over is
+        the one allowed by name inside step, the replier's device_get
+        is explicit, and nothing else crosses. `leak` is the control
+        that the guard does reach the replier: a fetch that also ships
+        a host state leaf to the device fails its batch there."""
+        table = make_table(num_slots=4)
+        legacy = jax.jit(lambda state: state + 1)
+        legacy(jnp.zeros((1, 1, H)))  # compile outside the guard
+        host_state = np.zeros((1, 1, H), np.float32)
+        fetch = table.fetch
+        fetch_threads = set()
+
+        def watched_fetch(outputs, n):
+            fetch_threads.add(threading.current_thread().name)
+            if leak:
+                legacy(host_state)
+            return fetch(outputs, n)
+
+        table.fetch = watched_fetch
+        request = {
+            "env": {"frame": np.full((1, 1, H), 1.0, np.float32)},
+            "slot": np.full((1, 1), 0, np.int32),
+            "advance": np.full((1, 1), True, bool),
+        }
+        slots, advance = np.asarray([0], np.int32), np.ones(1, bool)
+        table.fetch(table.step(slots, advance, request["env"]), 1)  # compile
+        fetch_threads.clear()
+
+        batcher = DynamicBatcher(
+            batch_dim=1, minimum_batch_size=1, maximum_batch_size=1,
+            timeout_ms=5,
+        )
+        server = threading.Thread(
+            target=inference_loop, args=(batcher, None, 1),
+            kwargs={"state_table": table}, name="guarded", daemon=True,
+        )
+        before = jax.config.jax_transfer_guard
+        jax.config.update("jax_transfer_guard", "disallow")
+        try:
+            server.start()
+            if leak:
+                with pytest.raises(Exception, match="host-to-device"):
+                    batcher.compute(request)
+            else:
+                for step in range(1, 6):
+                    out = batcher.compute(request)
+                    np.testing.assert_array_equal(
+                        np.asarray(out["outputs"]["out"]),
+                        np.full((1, 1, H), 1.0 + step, np.float32),
+                    )
+            batcher.close()
+            server.join(timeout=10)
+        finally:
+            jax.config.update("jax_transfer_guard", before)
+        assert not server.is_alive()
+        assert fetch_threads == {"guarded-replier"}
+
     def test_pipelined_unroll_state_stays_on_device(self):
         """Lag-1 collector variant of the guard test: a device-side
         policy's recurrent state flows device -> device across a whole

@@ -251,7 +251,6 @@ def build_sebulba_serving(
     registry=None,
     admission=None,
     throttle_fn: Optional[Callable] = None,
-    pipelined: bool = False,
     batch_dim: int = 1,
     batcher_factory: Optional[Callable] = None,
 ) -> SebulbaServing:
@@ -351,7 +350,6 @@ def build_sebulba_serving(
                 max_batch_size,
                 batch_dim=batch_dim,
                 lock=None,
-                pipelined=pipelined,
                 state_table=table,
                 serving_hooks=hooks,
                 throttle_fn=throttle_fn,

@@ -71,7 +71,10 @@ def make_parser():
     parser.add_argument("--no_start_servers", dest="start_servers",
                         action="store_false",
                         help="Connect to externally-launched servers.")
-    parser.add_argument("--num_inference_threads", type=int, default=2)
+    # Serving loops per batcher, each a launcher/replier pair
+    # (runtime/inference.py). One: a second launcher only contends for
+    # the state table's lock and the GIL (PERF.md section 6, PR 33).
+    parser.add_argument("--num_inference_threads", type=int, default=1)
     # Tri-state: None (default) = native-first with a clean, logged
     # fallback to the Python pool when _tbt_core is absent/stale;
     # True (explicit --native_runtime) = native REQUIRED, unusable
@@ -1475,7 +1478,6 @@ def train(flags):
                     None if state_table is not None else _replica_act_fn,
                     flags.max_inference_batch_size,
                     lock=None,
-                    pipelined=False,
                     state_table=state_table,
                     serving_hooks=loop_hooks,
                     throttle_fn=throttle,
@@ -1498,10 +1500,10 @@ def train(flags):
         # (sharded) state table, so poison recovery must rebuild once
         # and restart every serving thread under one budget.
         if sebulba is not None:
-            # --num_inference_threads serving threads PER SLICE (same
-            # host-side overlap the central path gets): each slice's
-            # threads drain only that slice's batcher, so the pinned
-            # dispatch story is unchanged.
+            # --num_inference_threads serving loops (launcher/replier
+            # pairs) PER SLICE: each slice's loops drain only that
+            # slice's batcher, so the pinned dispatch story is
+            # unchanged.
             slice_loops = [
                 loop
                 for loop in sebulba.loop_fns
@@ -1520,17 +1522,11 @@ def train(flags):
             )
         else:
             def _serve_loop():
-                # Pipelined dispatch only with a single consumer
-                # thread: its held-reply optimization is unsafe with
-                # several threads draining one batcher
-                # (runtime/inference.py docstring); with >1 threads
-                # the overlap comes from the threads.
                 inference_loop(
                     inference_batcher,
                     act_fn,
                     flags.max_inference_batch_size,
                     lock=None,
-                    pipelined=flags.num_inference_threads == 1,
                     state_table=state_table,
                     throttle_fn=throttle,
                 )

@@ -26,8 +26,7 @@ Two schedules over the SAME data flow:
   fetched (one small explicit device_get); policy logits/baseline stay on
   device and the host materializes tick t-1's results while the envs step
   tick t (the pool's step_async/step_wait window), one dispatch behind
-  the device — the same one-deep pipeline runtime/inference.py uses for
-  batched replies. Agent state never crosses at all: it flows device →
+  the device. Agent state never crosses at all: it flows device →
   device between policy calls, and the learner consumes the on-device
   `initial_agent_state` directly (tests/test_state_table.py pins the
   zero-host-round-trips property with jax.transfer_guard). Batches are

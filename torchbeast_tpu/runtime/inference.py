@@ -20,11 +20,16 @@ Two state regimes:
   gathers/advances/scatters state entirely on device and the reply holds
   outputs only. Padding rows scatter to the table's trash slot so they
   can never race a real slot's update.
+
+A serving loop is two threads with one role each (inference_loop): the
+launcher takes batches and dispatches act programs, the replier it owns
+fetches each batch's outputs and answers its actors.
 """
 
 # beastlint: hot-module — every function here sits on the per-batch serving path.
 
 import logging
+import queue
 import threading
 from typing import Any, Callable, List
 
@@ -34,6 +39,16 @@ from torchbeast_tpu import nest
 from torchbeast_tpu import telemetry
 
 log = logging.getLogger(__name__)
+
+# Dispatched batches the launcher may hand over ahead of the replier
+# before it blocks: with one in the replier's hands, one handed over and
+# one dispatched and waiting to be, the device has a program queued
+# behind the one it runs, which is all that running ahead can buy. A
+# launcher further ahead than that only cuts the requests that wait
+# into smaller batches (a device slower than the launcher, as the CPU
+# backend is: every batch costs it a program). Where the host sets the
+# pace the hand-over is empty or holds one.
+_HANDOVER_DEPTH = 1
 
 
 def bucket_size(n: int, buckets: List[int]) -> int:
@@ -56,16 +71,27 @@ def default_buckets(max_batch_size: int) -> List[int]:
 def pad_to(tree: Any, size: int, batch_dim: int) -> Any:
     """Pad every leaf to `size` along batch_dim by repeating the edge row
     (valid data, so the padded forward can't produce NaNs that would
-    poison batch-norm-style reductions; pad rows are sliced off after)."""
+    poison batch-norm-style reductions; pad rows are sliced off after).
+    Written out, not `np.pad(mode="edge")`: that spends some 30 us of
+    Python a leaf whatever its size, in the launcher's prep, which the
+    replier waits out for the GIL (tests keep np.pad as the reference)."""
 
     def pad(arr):
         arr = np.asarray(arr)
         n = arr.shape[batch_dim]
         if n == size:
             return arr
-        pad_width = [(0, 0)] * arr.ndim
-        pad_width[batch_dim] = (0, size - n)
-        return np.pad(arr, pad_width, mode="edge")
+        shape = list(arr.shape)
+        shape[batch_dim] = size
+        out = np.empty(shape, arr.dtype)
+        rows = [slice(None)] * arr.ndim
+        rows[batch_dim] = slice(0, n)
+        out[tuple(rows)] = arr
+        rows[batch_dim] = slice(n - 1, n)
+        edge = arr[tuple(rows)]
+        rows[batch_dim] = slice(n, size)
+        out[tuple(rows)] = edge
+        return out
 
     return nest.map(pad, tree)
 
@@ -110,13 +136,29 @@ def inference_loop(
     max_batch_size: int,
     batch_dim: int = 1,
     lock: threading.Lock = None,
-    pipelined: bool = False,
     state_table=None,
     serving_hooks=None,
     throttle_fn: Callable = None,
     telemetry_prefix: str = "inference",
 ):
-    """Thread body (run num_inference_threads of these).
+    """One serving loop: a launcher/replier pair (polybeast runs
+    --num_inference_threads of these per batcher).
+
+    The CALLING thread is the launcher: wait for a batch, prep it (pad
+    to its bucket), dispatch the act program (`state_table.step` or
+    `act_fn`; asynchronous, returns device arrays) — and nothing else.
+    Each dispatched batch goes, in order, over a bounded hand-over
+    (`_HANDOVER_DEPTH`; a full one blocks the launcher) to the REPLIER,
+    a thread this call starts on entry and owns: fetch the outputs (the
+    `device_get` — the one blocking device round trip of a batch), slice
+    them to the true size, `annotate`, `set_outputs`. So a launch never
+    waits on a `device_get`, and a reply goes out the instant its
+    outputs land, whether or not another batch exists. The replier's
+    life is this call's: on EVERY exit (batcher closed, an exception,
+    a poisoned table) the launcher hands over a stop mark, the replier
+    answers what was dispatched before it, and the call joins it before
+    it returns or raises — no reply is dropped and no replier outlives
+    its loop.
 
     act_fn(env_outputs, agent_state, batch_size) ->
         (agent_outputs, new_agent_state)   # numpy or device arrays
@@ -135,33 +177,13 @@ def inference_loop(
     with lock=None calls run concurrently (safe for pure jitted act_fns —
     the device serializes execution anyway).
 
-    `pipelined` keeps a one-deep dispatch pipeline: when more requests
-    are already waiting, batch k's host fetch (`np.asarray`, a full
-    device round-trip) happens
-    AFTER batch k+1's act is dispatched, so the device always has a
-    queued program and never idles on the reply path. The reply to k is
-    only ever deferred while k+1 is in hand; when the batcher is empty
-    the fetch happens immediately. SINGLE-CONSUMER ONLY: the "more
-    requests waiting" check is a racy global size() — with several
-    threads draining one batcher, another thread can steal the waiting
-    request and leave this one parked on an empty batcher while holding
-    finished replies, stalling those actors until new traffic arrives.
-    Tail-latency cost: the held reply for batch k is only flushed once
-    the batcher YIELDS batch k+1 — if size() > 0 but that next batch is
-    still forming (waiting on stragglers to reach min batch size), the
-    deferred actors wait up to the batcher's formation timeout (default
-    100 ms) beyond the dispatch-side win. Worth it only when the reply
-    path is the bottleneck; otherwise the default (off) avoids the
-    tail.
-    Default OFF: only enable it for a single consumer thread
-    (polybeast wires pipelined=num_inference_threads==1; cross-thread
-    overlap already comes from the threads themselves).
-
     A failing act_fn fails only its batch (promises broken with the error
-    so producers wake immediately); the loop continues serving. Exception:
-    a failed STATE-TABLE step poisons the table (its buffer is donated
-    into the dispatch, so it may already be consumed) — the loop fails
-    the batch and re-raises to kill the thread rather than serve garbage.
+    so producers wake immediately), and so does a failing fetch on the
+    replier; the loop continues serving. Exception: a failed STATE-TABLE
+    step poisons the table (its buffer is donated into the dispatch, so
+    it may already be consumed) — the loop fails the batch, lets the
+    replier answer (or fail) the batches dispatched before it, joins it
+    and re-raises to end the pair rather than serve garbage.
 
     `serving_hooks` (serving/replica.ReplicaServingHooks, or anything
     with the same `begin_batch() -> (params, annotate)` shape, plus
@@ -188,11 +210,13 @@ def inference_loop(
     buckets = default_buckets(max_batch_size)
 
     # Stage attribution for the serving loop: batch-size distribution
-    # and four spans that tile an iteration — wait_batch (blocked in the
-    # batcher: no request ready), prep (host: inputs, bucket, padding),
-    # dispatch (async — the time to hand XLA the program, not device
-    # compute) and reply (the device fetch + row slicing actors actually
-    # wait on). Each is a histogram `<span>_s` and, on the profiler's
+    # and four spans. Three tile the launcher's iteration — wait_batch
+    # (blocked in the batcher: no request ready), prep (host: inputs,
+    # bucket, padding), dispatch (async — the time to hand XLA the
+    # program, not device compute) — and reply covers the replier's
+    # work on a batch (the device fetch + row slicing + set_outputs
+    # actors actually wait on; its wait for the next hand-over is in no
+    # span). Each is a histogram `<span>_s` and, on the profiler's
     # clock, `pb:<span>`; resolved once, used every batch.
     _reg = telemetry.get_registry()
     _tracer = telemetry.get_tracer()
@@ -208,6 +232,11 @@ def inference_loop(
     _h_batch = _reg.histogram(f"{telemetry_prefix}.batch_size")
     _c_batches = _reg.counter(f"{telemetry_prefix}.batches")
     _c_rows = _reg.counter(f"{telemetry_prefix}.rows")
+    # Whether the split engages: replies still outstanding (handed over
+    # or in the replier's hands) at the instant of each launch, and the
+    # launches that found at least one.
+    _h_in_flight = _reg.histogram(f"{telemetry_prefix}.replies_in_flight")
+    _c_overlapped = _reg.counter(f"{telemetry_prefix}.overlapped_dispatches")
     # A Python DynamicBatcher with a telemetry_name already observes
     # inference.batch_size per dequeued batch — observing here too
     # would double-count it. The loop keeps that role only for
@@ -241,106 +270,127 @@ def inference_loop(
                 log.exception("Inference reply failed; continuing")
                 batch.fail(e)
 
-    pending = None
-    batches = iter(inference_batcher)
-    while True:
-        # The stall gate runs BEFORE the blocking pull: a stalled chip
-        # does not pick work up, so queued requests age toward their
-        # deadline and the dequeue-side expiry gate sees the truth. A
-        # throttle placed after the pull would grab fresh requests and
-        # hold them un-expirable for the whole window.
-        if throttle_fn is not None:
-            throttle_fn()
-        with _sp_wait:
-            batch = next(batches, None)
-        if batch is None:
-            break
-        try:
-            with _sp_prep:
-                inputs = batch.get_inputs()
-                env_outputs = inputs["env"]
-                n = len(batch)
-                if _observe_sizes:
-                    _h_batch.observe(n)
-                _c_batches.inc()
-                _c_rows.inc(n)
-                padded = bucket_size(n, buckets)
-                env_padded = pad_to(env_outputs, padded, batch_dim)
-                # Replica mode: ONE atomic (snapshot ctx, lag
-                # annotation) pick per batch, so the lag stamped into
-                # the reply is the lag of the params this dispatch
-                # actually used.
-                ctx = annotate = None
-                if serving_hooks is not None:
-                    ctx, annotate = serving_hooks.begin_batch()
-                if state_table is not None:
-                    slots = pad_slots(
-                        inputs["slot"], padded, state_table.trash_slot
-                    )
-                    advance = pad_advance(inputs["advance"], padded)
-                else:
-                    state_padded = pad_to(
-                        inputs["agent_state"], padded, batch_dim
-                    )
-                    act_args = (env_padded, state_padded, padded)
-                    if serving_hooks is not None:
-                        # No table to own the rng chain: the hooks do.
-                        act_args += ((ctx, serving_hooks.next_key()),)
+    # The hand-over's own count of entries put and not yet marked done
+    # IS the number of replies outstanding, so nothing else is shared
+    # between the two threads.
+    handover = queue.Queue(maxsize=_HANDOVER_DEPTH)
 
-            # inference.dispatch_s times ONLY the act dispatch (the
-            # host handing XLA the program) — padding is prep and the
-            # lock wait has its own span; folding them in would
-            # double-count stages and misattribute a lock bottleneck
-            # to XLA.
-            if state_table is not None:
-                with _sp_dispatch:
-                    outputs = state_table.step(
-                        slots, advance, env_padded, context=ctx
-                    )
-                new_state = None
-            elif lock is not None:
-                with _sp_lock_wait:
-                    # beastlint: disable=LOCK-DISCIPLINE  the span closes on the acquire and the try/finally release follows at once
-                    lock.acquire()
-                try:
+    def reply_loop():
+        while True:
+            entry = handover.get()
+            if entry is None:
+                return
+            try:
+                flush(entry)
+            finally:
+                handover.task_done()
+
+    replier = threading.Thread(
+        target=reply_loop,
+        name=f"{threading.current_thread().name}-replier",
+        daemon=True,
+    )
+    replier.start()
+    batches = iter(inference_batcher)
+    try:
+        while True:
+            # The stall gate runs BEFORE the blocking pull: a stalled
+            # chip does not pick work up, so queued requests age toward
+            # their deadline and the dequeue-side expiry gate sees the
+            # truth. A throttle placed after the pull would grab fresh
+            # requests and hold them un-expirable for the whole window.
+            if throttle_fn is not None:
+                throttle_fn()
+            with _sp_wait:
+                batch = next(batches, None)
+            if batch is None:
+                break
+            try:
+                with _sp_prep:
+                    inputs = batch.get_inputs()
+                    env_outputs = inputs["env"]
+                    n = len(batch)
+                    if _observe_sizes:
+                        _h_batch.observe(n)
+                    _c_batches.inc()
+                    _c_rows.inc(n)
+                    padded = bucket_size(n, buckets)
+                    env_padded = pad_to(env_outputs, padded, batch_dim)
+                    # Replica mode: ONE atomic (snapshot ctx, lag
+                    # annotation) pick per batch, so the lag stamped
+                    # into the reply is the lag of the params this
+                    # dispatch actually used.
+                    ctx = annotate = None
+                    if serving_hooks is not None:
+                        ctx, annotate = serving_hooks.begin_batch()
+                    if state_table is not None:
+                        slots = pad_slots(
+                            inputs["slot"], padded, state_table.trash_slot
+                        )
+                        advance = pad_advance(inputs["advance"], padded)
+                    else:
+                        state_padded = pad_to(
+                            inputs["agent_state"], padded, batch_dim
+                        )
+                        act_args = (env_padded, state_padded, padded)
+                        if serving_hooks is not None:
+                            # No table to own the rng chain: the hooks do.
+                            act_args += ((ctx, serving_hooks.next_key()),)
+                    in_flight = handover.unfinished_tasks
+                    _h_in_flight.observe(in_flight)
+                    if in_flight:
+                        _c_overlapped.inc()
+
+                # inference.dispatch_s times ONLY the act dispatch (the
+                # host handing XLA the program) — padding is prep and
+                # the lock wait has its own span; folding them in would
+                # double-count stages and misattribute a lock
+                # bottleneck to XLA.
+                if state_table is not None:
+                    with _sp_dispatch:
+                        outputs = state_table.step(
+                            slots, advance, env_padded, context=ctx
+                        )
+                    new_state = None
+                elif lock is not None:
+                    with _sp_lock_wait:
+                        # beastlint: disable=LOCK-DISCIPLINE  the span closes on the acquire and the try/finally release follows at once
+                        lock.acquire()
+                    try:
+                        with _sp_dispatch:
+                            outputs, new_state = act_fn(*act_args)
+                    finally:
+                        lock.release()
+                else:
                     with _sp_dispatch:
                         outputs, new_state = act_fn(*act_args)
-                finally:
-                    lock.release()
-            else:
-                with _sp_dispatch:
-                    outputs, new_state = act_fn(*act_args)
-        except Exception as e:  # noqa: BLE001
-            batch.fail(e)
-            if pending is not None:
-                flush(pending)
-                pending = None
-            if state_table is not None and state_table.poisoned:
-                # The donated table buffer may already be consumed;
-                # per-batch retry would serve garbage state. Die loudly
-                # — with the TYPED error, so a supervising wrapper
-                # (resilience.InferenceSupervisor) can distinguish
-                # "rebuild the table and restart me" from a real
-                # serving bug that must stay fatal.
-                from torchbeast_tpu.runtime.errors import (
-                    StateTablePoisonedError,
-                )
+            except Exception as e:  # noqa: BLE001
+                batch.fail(e)
+                if state_table is not None and state_table.poisoned:
+                    # The donated table buffer may already be consumed;
+                    # per-batch retry would serve garbage state. Die
+                    # loudly — with the TYPED error, so a supervising
+                    # wrapper (resilience.InferenceSupervisor) can
+                    # distinguish "rebuild the table and restart me"
+                    # from a real serving bug that must stay fatal.
+                    from torchbeast_tpu.runtime.errors import (
+                        StateTablePoisonedError,
+                    )
 
-                log.exception("State table poisoned; inference thread exiting")
-                if isinstance(e, StateTablePoisonedError):
-                    raise
-                raise StateTablePoisonedError(
-                    f"state table poisoned by: {type(e).__name__}: {e}"
-                ) from e
-            log.exception("Inference batch failed; continuing")
-            continue
-        # This batch is dispatched (async); NOW reply to the previous one.
-        if pending is not None:
-            flush(pending)
-            pending = None
-        if pipelined and inference_batcher.size() > 0:
-            pending = (batch, outputs, new_state, n, annotate)
-        else:
-            flush((batch, outputs, new_state, n, annotate))
-    if pending is not None:  # batcher closed with a reply in flight
-        flush(pending)
+                    log.exception(
+                        "State table poisoned; serving loop exiting"
+                    )
+                    if isinstance(e, StateTablePoisonedError):
+                        raise
+                    raise StateTablePoisonedError(
+                        f"state table poisoned by: {type(e).__name__}: {e}"
+                    ) from e
+                log.exception("Inference batch failed; continuing")
+                continue
+            # Dispatched (async): the reply is the replier's from here.
+            handover.put((batch, outputs, new_state, n, annotate))
+    finally:
+        # Every exit: the replier answers (or fails) what was
+        # dispatched before the stop mark reaches it, then ends.
+        handover.put(None)
+        replier.join()

@@ -22,9 +22,10 @@ One runtime call per act batch: `step` hands its host inputs (slot ids,
 advance mask, env leaves) to the jitted program as the numpy arrays
 they are — the call itself transfers them — and the acting rng key
 lives in the donated carry beside the table, split INSIDE the program.
-So a serving thread enters the runtime (and lets go of the GIL) twice a
-batch: the launch, and `fetch`'s device_get. The table owns the key for
-every caller: contexts carry params only.
+So a serving loop enters the runtime (and lets go of the GIL) twice a
+batch, on a thread each (runtime/inference.py): the launch on its
+launcher, `fetch`'s device_get on its replier. The table owns the key
+for every caller: contexts carry params only.
 
 Layout/contract notes:
 
@@ -135,7 +136,8 @@ class DeviceStateTable:
         # device syncs to the acting hot path — pinned by the
         # transfer-guard test in tests/test_telemetry.py. The two
         # parts of step() are children of the serving loop's dispatch
-        # span; fetch is the child of its reply span.
+        # span (its launcher's); fetch is the child of its reply span
+        # (its replier's, another thread).
         self._tm_dispatches = telemetry.get_registry().counter(
             "state_table.dispatches"
         )
