@@ -165,12 +165,12 @@ FAMILY_FIELD_CASES = {
     "num_layers": (
         "3", 3, "olmoe", "pipelined_transformer",
         "--num_layers is a positive depth or window of --model "
-        "transformer or olmoe or mellum2",
+        "transformer or olmoe or mellum2 or ouro",
     ),
     "memory_len": (
         "9", 9, "transformer", "deep",
         "--memory_len is a positive depth or window of --model "
-        "transformer or olmoe or mellum2",
+        "transformer or olmoe or mellum2 or ouro",
     ),
     "num_experts": (
         "4", 4, "transformer", "olmoe",
@@ -232,17 +232,18 @@ def test_refusals_are_stated_on_the_class():
         "pipelined_transformer": ("num_layers", "memory_len"),
         "olmoe": ("num_experts", "attention_impl"),
         "mellum2": ("num_experts", "attention_impl"),
+        "ouro": ("num_experts", "attention_impl"),
     }
     kv_cache = [
         name for name in models.MODEL_NAMES
         if getattr(models._REGISTRY[name], "memory_is_kv_cache", False)
     ]
     assert kv_cache == [
-        "transformer", "pipelined_transformer", "olmoe", "mellum2",
+        "transformer", "pipelined_transformer", "olmoe", "mellum2", "ouro",
     ]
     for name in models.MODEL_NAMES:
-        # test_olmoe and test_mellum2 have theirs
-        if name in kv_cache and name not in ("olmoe", "mellum2"):
+        # test_olmoe, test_mellum2 and test_ouro have theirs
+        if name in kv_cache and name not in ("olmoe", "mellum2", "ouro"):
             with pytest.raises(ValueError, match="KV cache"):
                 models.create_model(name, num_actions=A, use_lstm=True)
         elif name not in kv_cache:

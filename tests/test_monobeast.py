@@ -537,3 +537,29 @@ def test_train_mellum2_through_main(tmp_path, monkeypatch, share):
     assert stats["moe_assignments"] == 2 * 6 * 2 * 4
     assert ("moe_held_assignments" in stats) == bool(share)
     assert (tmp_path / "smoke-mellum2" / "model.ckpt").exists()
+
+
+def test_train_ouro_through_main(tmp_path, monkeypatch):
+    """`--model ouro` on the normal path, the family's table shrunk (2
+    layers run 3 times): acting at T=1 through the 3 x 2 rolling caches,
+    unrolls of 5, updates with the blocks rematerialised, the
+    checkpoint; the update's stats carry the loop's counters."""
+    from torchbeast_tpu.models import ouro
+
+    monkeypatch.setattr(ouro, "PUBLISHED", dict(
+        ouro.PUBLISHED, d_model=32, num_heads=4, head_dim=8, mlp_width=48,
+        passes=3,
+    ))
+    stats = monobeast.main(make_flags(
+        tmp_path, xpid="smoke-ouro", model="ouro", num_layers=2,
+        memory_len=6, remat="all",
+    ))
+    assert stats["step"] >= 40
+    assert np.isfinite(stats["total_loss"])
+    assert stats["loop_passes"] == 3
+    assert stats["loop_block_applications"] == 6
+    # k, v [6, 4, 8] and a validity column, f32, for each of 6 caches.
+    assert stats["loop_cache_bytes_per_row"] == 6 * 4 * 6 * (2 * 32 + 1)
+    assert 1.0 <= stats["loop_expected_exit_pass"] <= 3.0
+    assert 0.0 <= stats["loop_exit_p_last"] <= 1.0
+    assert (tmp_path / "smoke-ouro" / "model.ckpt").exists()

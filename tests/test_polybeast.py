@@ -38,6 +38,30 @@ def test_polybeast_train_smoke(tmp_path):
     assert (tmp_path / "poly-smoke" / "logs.csv").exists()
 
 
+def test_polybeast_train_ouro(tmp_path, monkeypatch):
+    """`--model ouro` through the async driver, the family's table
+    shrunk (2 layers run 3 times): the state table's slots hold the
+    3 x 2 caches, an act step runs the three passes, the learner's
+    updates report the loop's counters."""
+    from torchbeast_tpu.models import ouro
+
+    monkeypatch.setattr(ouro, "PUBLISHED", dict(
+        ouro.PUBLISHED, d_model=32, num_heads=4, head_dim=8, mlp_width=48,
+        passes=3,
+    ))
+    flags = make_flags(
+        tmp_path, xpid="poly-ouro", model="ouro", num_layers=2,
+        memory_len=6, remat="all",
+    )
+    stats = polybeast.train(flags)
+    assert stats["step"] >= 60
+    assert np.isfinite(stats["total_loss"])
+    assert stats["loop_passes"] == 3
+    assert stats["loop_block_applications"] == 6
+    assert 1.0 <= stats["loop_expected_exit_pass"] <= 3.0
+    assert (tmp_path / "poly-ouro" / "model.ckpt").exists()
+
+
 @pytest.mark.slow
 def test_polybeast_train_lstm(tmp_path):
     flags = make_flags(tmp_path, xpid="poly-lstm", use_lstm=True)

@@ -529,13 +529,14 @@ def compute_loss(
         batch,
         initial_agent_state,
         sample_action=False,
-        mutable=["losses", "moe_stats"],
+        mutable=["losses", "moe_stats", "loop_stats"],
     )
     aux_loss = sum(
         jnp.sum(leaf)
         for leaf in jax.tree_util.tree_leaves(variables.get("losses", {}))
     )
     moe_stats = _moe_stats(variables.get("moe_stats", {}))
+    loop_stats = _loop_stats(variables.get("loop_stats", {}))
 
     bootstrap_value = learner_outputs.baseline[-1]
 
@@ -617,6 +618,7 @@ def compute_loss(
         "episode_returns_sum": episode_returns_sum,
         "episode_count": episode_count,
         **moe_stats,
+        **loop_stats,
     }
     return total_loss, stats
 
@@ -638,6 +640,30 @@ def _moe_stats(sown) -> Dict[str, Any]:
     for name in ("load_max_over_mean", "held_load_max_over_mean"):
         if name in by_name:
             stats["moe_" + name] = jnp.max(jnp.stack(by_name[name]))
+    return stats
+
+
+def _loop_stats(sown) -> Dict[str, Any]:
+    """What a looped trunk says of its passes (models/ouro.py): its
+    constants as they are (`passes`, `block_applications`, `cache_bytes_
+    per_row`), and from the passes' exit gates lambda_u the distribution
+    over exits p_u = lambda_u prod_{j<u} (1 - lambda_j), the last pass
+    taking the rest: the pass a token would leave after, counted from 1
+    and averaged over the batch, and the mass left to the last pass.
+    Empty for every other model."""
+    stats = {
+        "loop_" + path[-1]: leaf
+        for path, leaf in flax.traverse_util.flatten_dict(sown).items()
+    }
+    if stats:
+        gates = jnp.stack(stats.pop("loop_exit_gates"))  # [passes, ...]
+        # Not leaving at pass u, for every pass but the last; their
+        # running product is still being in after it.
+        stays = 1.0 - gates[:-1]
+        stats["loop_expected_exit_pass"] = 1.0 + jnp.mean(
+            jnp.sum(jnp.cumprod(stays, axis=0), axis=0)
+        )
+        stats["loop_exit_p_last"] = jnp.mean(jnp.prod(stays, axis=0))
     return stats
 
 

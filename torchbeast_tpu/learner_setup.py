@@ -170,17 +170,20 @@ def add_learner_arguments(parser, *, model_default,
                              "M to amortize it; the learner batch must "
                              "divide into M microbatches.")
     parser.add_argument("--num_layers", type=int, default=0,
-                        help="Depth of --model transformer, olmoe or "
-                             "mellum2 (0: the family's own, 2 and the "
-                             "published 16 and 28; mellum2 in whole "
-                             "periods of 4).")
+                        help="Depth of --model transformer, olmoe, "
+                             "mellum2 or ouro (0: the family's own, 2 "
+                             "and the published 16, 28 and 48; mellum2 "
+                             "in whole periods of 4; ouro runs the "
+                             "layers it has 4 times a step).")
     parser.add_argument("--memory_len", type=int, default=0,
-                        help="Steps of its own past a transformer, olmoe "
-                             "or mellum2 policy attends over, carried as "
-                             "the rolling KV cache (0: the family's own, "
-                             "64, 128 and 4095; mellum2: its full "
-                             "layers' cache, the sliding layers carry "
-                             "min(memory_len, 1023)).")
+                        help="Steps of its own past a transformer, "
+                             "olmoe, mellum2 or ouro policy attends "
+                             "over, carried as the rolling KV cache (0: "
+                             "the family's own, 64, 128, 4095 and 255; "
+                             "mellum2: its full layers' cache, the "
+                             "sliding layers carry min(memory_len, "
+                             "1023); ouro: every one of its 4 x "
+                             "num_layers caches).")
     parser.add_argument("--expert_share", default="",
                         help="--model mellum2: 'i/n' holds share i of "
                              "the n chips that divide each layer's 64 "
@@ -265,8 +268,11 @@ def add_learner_arguments(parser, *, model_default,
                         help="Rematerialization plan over the model's "
                              "remat-able stages (runtime/remat_plan.py: "
                              "the ResNet trunk's per-stage none/front/"
-                             "all, the transformer families' block "
-                             "remat, the LSTM scan): 'auto' picks the "
+                             "all, the block remat of the families "
+                             "whose class has the `blocks` lever "
+                             "(transformer, pipelined_transformer, "
+                             "mellum2, ouro; not olmoe), the LSTM "
+                             "scan): 'auto' picks the "
                              "minimum-recompute plan whose XLA-measured "
                              "peak fits --hbm_budget_gb; 'all'/'none' "
                              "force every stage; 'stage0=front,"

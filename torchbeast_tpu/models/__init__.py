@@ -10,9 +10,10 @@ import dataclasses
 from torchbeast_tpu.models.atari_net import AtariNet  # noqa: F401
 from torchbeast_tpu.models.cores import LSTMCore  # noqa: F401
 from torchbeast_tpu.models.mlp import MLPNet  # noqa: F401
-from torchbeast_tpu.models import mellum2, olmoe
+from torchbeast_tpu.models import mellum2, olmoe, ouro
 from torchbeast_tpu.models.mellum2 import Mellum2Net  # noqa: F401
 from torchbeast_tpu.models.olmoe import OLMoENet  # noqa: F401
+from torchbeast_tpu.models.ouro import OuroNet  # noqa: F401
 from torchbeast_tpu.models.pipelined import PipelinedMLPNet  # noqa: F401
 from torchbeast_tpu.models.resnet import ResNet  # noqa: F401
 from torchbeast_tpu.models.transformer import TransformerNet  # noqa: F401
@@ -31,11 +32,12 @@ _REGISTRY = {
     "pipelined_transformer": PipelinedTransformerNet,
     "olmoe": OLMoENet,
     "mellum2": Mellum2Net,
+    "ouro": OuroNet,
 }
 # A family whose widths are a published table (its module's `PUBLISHED`,
 # keyed by the class's fields): read when the model is built, so that a
 # test shrinks the family there.
-_PUBLISHED_TABLES = {OLMoENet: olmoe, Mellum2Net: mellum2}
+_PUBLISHED_TABLES = {OLMoENet: olmoe, Mellum2Net: mellum2, OuroNet: ouro}
 MODEL_NAMES = tuple(_REGISTRY)
 
 
@@ -48,6 +50,14 @@ def takes_flag(name: str, field: str) -> bool:
     return field not in getattr(cls, "flag_refused_fields", ()) and any(
         f.name == field for f in dataclasses.fields(cls)
     )
+
+
+def remat_lever(name: str):
+    """What family `name`'s class says `--remat` reaches in it beside
+    the LSTM scan (runtime/remat_plan.py): "blocks" where the `remat`
+    field rematerialises each block, None where nothing is said (the
+    deep ResNet's three stages are the planner's own)."""
+    return getattr(_REGISTRY.get(name), "remat_lever", None)
 
 
 def families_taking(field: str):

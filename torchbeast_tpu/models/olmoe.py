@@ -138,6 +138,8 @@ class OLMoENet(TransformerNet):
     # Fields the published table sets, or that the block does not read:
     # no flag reaches them (models/__init__.py `takes_flag`).
     flag_refused_fields = ("num_experts", "attention_impl")
+    # `make_block` below does not read `remat`: `--remat` has no lever.
+    remat_lever = None
 
     num_layers: int = PUBLISHED["num_layers"]
     d_model: int = PUBLISHED["d_model"]

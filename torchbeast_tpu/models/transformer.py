@@ -35,6 +35,7 @@ T=1) automatically use the dense path with the SAME parameters, so one
 model serves both.
 """
 
+import contextlib
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -69,7 +70,7 @@ class _Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, cache, mask, offsets, cache_mask=None, seg=None,
-                 cache_valid=None, no_done=None):
+                 cache_valid=None, no_done=None, **_):
         """x: [B, T, d]; cache: (k, v) with k/v [B, M, H, hd];
         mask: [B, T, M+T] (True = may attend); offsets: [T, M+T] relative
         distances query_time - key_time in [0, M]. cache_mask [B, T, M]
@@ -202,6 +203,10 @@ class TransformerNet(nn.Module):
     # The family's memory is its KV cache: --use_lstm does not apply
     # (models/__init__.py `create_model`).
     memory_is_kv_cache = True
+    # `--remat`'s lever here (runtime/remat_plan.py): `make_block` wraps
+    # each block in nn.remat when the `remat` field is set. A family
+    # whose `make_block` does not read the field says None.
+    remat_lever = "blocks"
 
     num_actions: int
     use_lstm: bool = False  # accepted for registry uniformity; unused
@@ -286,40 +291,61 @@ class TransformerNet(nn.Module):
                 # In-unroll mask: band-causal + same segment. [B, T, T]
                 geometry[M] = band, offsets, band[None, :, M:] & same
 
-        new_state = []
-        for layer, (M, _, _) in enumerate(self.layer_caches()):
-            band, offsets, seq_mask = geometry[M]
-            k_cache, v_cache, valid = core_state[layer]
-            # state convention [M, B, ...] -> model-internal [B, M, ...]
-            k_cache_b = k_cache.transpose(1, 0, 2, 3)
-            v_cache_b = v_cache.transpose(1, 0, 2, 3)
-            valid_b = valid.T  # [B, M]
-            cache_mask = (
-                band[None, :, :M]
-                & valid_b[:, None, :].astype(bool)
-                & no_done_yet[:, :, None]
-            )  # [B, T, M]
-            mask = jnp.concatenate([cache_mask, seq_mask], axis=-1)
-            x, k_new, v_new = self.make_block(f"block_{layer}", layer)(
-                x, (k_cache_b, v_cache_b), mask, offsets,
-                cache_mask=cache_mask, seg=seg,
-                cache_valid=valid_b, no_done=no_done_yet,
-            )
+        # The walk: `block_passes` says which block's weights serve each
+        # cache entry, in passes over the stack; the family's last norm
+        # follows every pass. A block that serves several entries (a
+        # looped family, models/ouro.py) is one module applied again.
+        final_norm = self.make_final_norm()
+        walk = self.block_passes()
+        caches = zip(core_state, self.layer_caches())
+        blocks, new_state = {}, []
+        for blocks_of_pass in walk:
+            with jax.named_scope("loop_pass") if len(walk) > 1 else (
+                contextlib.nullcontext()
+            ):
+                for layer in blocks_of_pass:
+                    if layer not in blocks:
+                        blocks[layer] = self.make_block(
+                            f"block_{layer}", layer
+                        )
+                    (k_cache, v_cache, valid), (M, _, _) = next(caches)
+                    band, offsets, seq_mask = geometry[M]
+                    # state convention [M, B, ...] -> model-internal
+                    # [B, M, ...]
+                    k_cache_b = k_cache.transpose(1, 0, 2, 3)
+                    v_cache_b = v_cache.transpose(1, 0, 2, 3)
+                    valid_b = valid.T  # [B, M]
+                    cache_mask = (
+                        band[None, :, :M]
+                        & valid_b[:, None, :].astype(bool)
+                        & no_done_yet[:, :, None]
+                    )  # [B, T, M]
+                    mask = jnp.concatenate([cache_mask, seq_mask], axis=-1)
+                    x, k_new, v_new = blocks[layer](
+                        x, (k_cache_b, v_cache_b), mask, offsets,
+                        cache_mask=cache_mask, seg=seg,
+                        cache_valid=valid_b, no_done=no_done_yet,
+                        # The cache as the state has it. A block that is
+                        # rematerialised and transposes this one itself
+                        # keeps the state's own buffer for its backward
+                        # pass, not a transposed copy (models/ouro.py).
+                        cache_state=(k_cache, v_cache),
+                    )
 
-            # Roll the cache: last M of [old cache; this unroll], validity
-            # restricted to the final segment (shared helper,
-            # ops/attention.py).
-            k_roll, v_roll, valid_roll = roll_kv_cache(
-                k_cache_b, v_cache_b, valid_b, k_new, v_new,
-                seg, no_done_yet,
-            )
-            new_state.append((
-                k_roll.transpose(1, 0, 2, 3),
-                v_roll.transpose(1, 0, 2, 3),
-                valid_roll.T,
-            ))
+                    # Roll the cache: last M of [old cache; this unroll],
+                    # validity restricted to the final segment (shared
+                    # helper, ops/attention.py).
+                    k_roll, v_roll, valid_roll = roll_kv_cache(
+                        k_cache_b, v_cache_b, valid_b, k_new, v_new,
+                        seg, no_done_yet,
+                    )
+                    new_state.append((
+                        k_roll.transpose(1, 0, 2, 3),
+                        v_roll.transpose(1, 0, 2, 3),
+                        valid_roll.T,
+                    ))
+                x = final_norm(x)
 
-        x = self.make_final_norm()(x)
         core_output = x.transpose(1, 0, 2)  # [T, B, d], the head's layout
 
         out, _ = RecurrentPolicyHead(
@@ -333,9 +359,10 @@ class TransformerNet(nn.Module):
         return out, tuple(new_state)
 
     # What a family built on this scaffolding replaces (models/olmoe.py,
-    # models/mellum2.py): its block, its last norm, and each layer's
-    # cache. Everything else — observation and extras projections,
-    # masks, cache roll, state convention, head — is this class's.
+    # models/mellum2.py, models/ouro.py): its block, its last norm, each
+    # cache entry's shape, and which block serves which entry. Everything
+    # else — observation and extras projections, masks, cache roll,
+    # state convention, head — is this class's.
     @nn.nowrap
     def make_block(self, name: str, layer: int):
         del layer  # every layer is the same block
@@ -360,12 +387,21 @@ class TransformerNet(nn.Module):
 
     @nn.nowrap
     def layer_caches(self) -> Tuple[Tuple[int, int, int], ...]:
-        """Each layer's (memory_len, key/value heads, head size): the
-        shape of the cache it carries, [memory_len, B, heads, size], and
-        the band it attends within. Here every layer has the one."""
+        """Each cache entry's (memory_len, key/value heads, head size):
+        the shape of the cache, [memory_len, B, heads, size], and the
+        band attended within. An entry a layer (`block_passes` says
+        which block serves which); here every layer has the one."""
         return (
             (self.memory_len, self.num_heads, self.d_model // self.num_heads),
         ) * self.num_layers
+
+    @nn.nowrap
+    def block_passes(self) -> Tuple[Tuple[int, ...], ...]:
+        """The walk over `layer_caches()`'s entries, as passes over the
+        stack: each pass names, in order, the block whose weights serve
+        the next entry, and `make_final_norm()`'s norm follows every
+        pass. Here one pass, block i on entry i."""
+        return (tuple(range(len(self.layer_caches()))),)
 
     def initial_state(self, batch_size: int) -> Tuple:
         return tuple(

@@ -119,6 +119,7 @@ class PipelinedTransformerNet(nn.Module):
     )
 
     memory_is_kv_cache = True  # --use_lstm does not apply
+    remat_lever = "blocks"  # --remat reaches `remat` (remat_plan.py)
     # Depth is --pipeline_stages' to set (it must divide over the pipe
     # mesh), and the window has no flag here: --num_layers and
     # --memory_len are refused (models/__init__.py `takes_flag`).

@@ -2143,19 +2143,30 @@ def train(flags):
                 reg.gauge("learner.sample_reuse").set(replay_reuse)
                 reg.gauge("learner_queue.depth").set(learner_queue.size())
                 reg.gauge("inference.depth").set(serving_depth_fn())
-                # What a dropless expert layer's router did in the last
-                # fetched update (learner._moe_stats): every assignment
-                # computed, and the fullest expert's rows over the mean,
-                # worst layer; with --expert_share, the same two over
-                # the experts held here.
-                for name in (
-                    "assignments", "load_max_over_mean",
-                    "held_assignments", "held_load_max_over_mean",
+                # From the last fetched update's own stats. What a
+                # dropless expert layer's router did (learner._moe_stats):
+                # every assignment computed, and the fullest expert's
+                # rows over the mean, worst layer; with --expert_share,
+                # the same two over the experts held here. A looped
+                # trunk's passes (learner._loop_stats): how many, the
+                # block applications and cache bytes a row they cost,
+                # and where its exit gates would let go.
+                for family, names in (
+                    ("moe", (
+                        "assignments", "load_max_over_mean",
+                        "held_assignments", "held_load_max_over_mean",
+                    )),
+                    ("loop", (
+                        "passes", "block_applications",
+                        "cache_bytes_per_row", "expected_exit_pass",
+                        "exit_p_last",
+                    )),
                 ):
-                    if "moe_" + name in stats_now:
-                        reg.gauge("moe." + name).set(
-                            stats_now["moe_" + name]
-                        )
+                    for name in names:
+                        if f"{family}_{name}" in stats_now:
+                            reg.gauge(family + "." + name).set(
+                                stats_now[f"{family}_{name}"]
+                            )
                 tele.write(extra={"step": now_step})
             means = timings.means()
             log.info(

@@ -74,11 +74,20 @@ def stages_for(model: str, use_lstm: bool) -> List[Stage]:
     if model == "deep":
         for i in range(3):
             stages.append(Stage(f"stage{i}", (False, "front", True)))
-    if model in ("transformer", "pipelined_transformer", "mellum2"):
+    if _has_blocks_lever(model):
         stages.append(Stage("blocks", (False, True)))
     if use_lstm:
         stages.append(Stage("core", (False, True)))
     return stages
+
+
+def _has_blocks_lever(model: str) -> bool:
+    """Whether the family's class says its `remat` field rematerialises
+    each block (`remat_lever = "blocks"`: the transformer families whose
+    `make_block` reads it)."""
+    from torchbeast_tpu import models
+
+    return models.remat_lever(model) == "blocks"
 
 
 def model_kwargs(model: str, assignment: Dict[str, Any]) -> Dict[str, Any]:
@@ -88,7 +97,7 @@ def model_kwargs(model: str, assignment: Dict[str, Any]) -> Dict[str, Any]:
         kwargs["remat"] = tuple(
             assignment[f"stage{i}"] for i in range(3)
         )
-    if model in ("transformer", "pipelined_transformer", "mellum2"):
+    if _has_blocks_lever(model):
         kwargs["remat"] = bool(assignment["blocks"])
     if "core" in assignment:
         kwargs["core_remat"] = bool(assignment["core"])
