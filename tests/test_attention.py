@@ -270,3 +270,201 @@ def test_dense_transformer_attend_by_group(kv_heads, with_bias):
     )
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+# --- cached_transformer_attend: the cache and the unroll as two legs -----
+
+
+def _two_leg_case(kv_heads, cache, seed=0, M=5, heads=H):
+    """A seeded case as models/transformer.py would hand it over: a
+    `done` inside the unroll (row 0, step 7: later queries see neither
+    the cache nor the steps before it), the band, and a cache that is
+    wholly invalid, valid in its newest slots, or full."""
+    from torchbeast_tpu.ops.attention import band_by_leg
+
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.standard_normal((B, T, heads, D)), jnp.float32)
+    k, v = (
+        jnp.asarray(rng.standard_normal((B, T, kv_heads, D)), jnp.float32)
+        for _ in range(2)
+    )
+    cache_k, cache_v = (
+        jnp.asarray(rng.standard_normal((M, B, kv_heads, D)), jnp.float32)
+        for _ in range(2)
+    )
+    done = np.zeros((T, B), bool)
+    done[7, 0] = True
+    seg = segment_ids_from_done(jnp.asarray(done)).T
+    no_done_yet = jnp.cumsum(jnp.asarray(done), axis=0).T == 0
+    valid = {
+        "invalid": np.zeros((B, M), bool),
+        "partly": np.arange(M)[None, :] >= np.array([2, 4])[:, None],
+        "full": np.ones((B, M), bool),
+    }[cache]
+    cache_band, seq_band = band_by_leg(T, M)
+    cache_mask = (
+        cache_band[None] & jnp.asarray(valid)[:, None, :]
+        & no_done_yet[:, :, None]
+    )
+    seq_mask = seq_band[None] & (seg[:, :, None] == seg[:, None, :])
+    return q, k, v, cache_k, cache_v, cache_mask, seq_mask
+
+
+def _dense_on_the_concatenation(q, k, v, cache_k, cache_v, cache_mask,
+                                seq_mask):
+    from torchbeast_tpu.ops.attention import dense_transformer_attend
+
+    return dense_transformer_attend(
+        q,
+        jnp.concatenate([cache_k.transpose(1, 0, 2, 3), k], axis=1),
+        jnp.concatenate([cache_v.transpose(1, 0, 2, 3), v], axis=1),
+        jnp.concatenate([cache_mask, seq_mask], axis=-1), None, None,
+    )
+
+
+@pytest.mark.parametrize("cache", ["invalid", "partly", "full"])
+@pytest.mark.parametrize(
+    "heads, kv_heads", [(H, H), (8, 2)], ids=["mha", "gqa-8-on-2"]
+)
+def test_cached_transformer_attend_is_the_dense_body(heads, kv_heads, cache):
+    """Two legs of one softmax against the dense body on `[cache; k]`,
+    `[cache; v]`: outputs, and the gradients with respect to q, k, v
+    AND the cache (asked for explicitly: the function is plain autodiff,
+    no rule of its own drops it)."""
+    from torchbeast_tpu.ops.attention import cached_transformer_attend
+
+    case = _two_leg_case(kv_heads, cache, seed=kv_heads, heads=heads)
+    two_legs = jax.jit(cached_transformer_attend)
+    dense = jax.jit(_dense_on_the_concatenation)
+    got, want = two_legs(*case), dense(*case)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    # A query after the `done` sees no slot and nothing before step 7.
+    assert not bool(case[5][0, 7:].any()) and not bool(case[6][0, 7:, :7].any())
+
+    def total(fn):
+        return lambda *operands: jnp.sum(jnp.sin(fn(*operands, *case[5:])))
+
+    operands = case[:5]
+    got = jax.grad(total(cached_transformer_attend), range(5))(*operands)
+    want = jax.grad(total(_dense_on_the_concatenation), range(5))(*operands)
+    for name, a, b in zip(("q", "k", "v", "cache_k", "cache_v"), got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5, err_msg=name)
+    if cache != "invalid":
+        assert float(jnp.abs(got[3]).max()) > 0 < float(jnp.abs(got[4]).max())
+
+
+def test_cached_transformer_attend_refuses_heads_that_do_not_divide():
+    from torchbeast_tpu.ops.attention import cached_transformer_attend
+
+    q, k, v, cache_k, cache_v, cache_mask, seq_mask = _two_leg_case(3, "full")
+    with pytest.raises(ValueError, match="do not divide"):
+        cached_transformer_attend(
+            q, k, v, cache_k, cache_v, cache_mask, seq_mask
+        )
+
+
+@pytest.mark.parametrize("t", [1, 3, 9], ids=["act", "short", "evicts-all"])
+def test_roll_kv_cache_in_the_states_layout(t):
+    """Rolled where the state lies (axis 0) = rolled batch-first and
+    transposed back, for an act step, an unroll shorter than the cache
+    and one that evicts all of it, a `done` inside."""
+    from torchbeast_tpu.ops.attention import roll_kv_cache
+
+    M = 5
+    rng = np.random.default_rng(t)
+    k_cache, v_cache = (
+        jnp.asarray(rng.standard_normal((B, M, H, D)), jnp.float32)
+        for _ in range(2)
+    )
+    k_new, v_new = (
+        jnp.asarray(rng.standard_normal((B, t, H, D)), jnp.float32)
+        for _ in range(2)
+    )
+    valid = jnp.asarray(rng.random((B, M)) < 0.7, jnp.float32)
+    done = np.zeros((t, B), bool)
+    done[t // 2, 1] = True
+    seg = segment_ids_from_done(jnp.asarray(done)).T
+    no_done = jnp.cumsum(jnp.asarray(done), axis=0).T == 0
+    want = roll_kv_cache(k_cache, v_cache, valid, k_new, v_new, seg, no_done)
+    to_state = lambda x: jnp.swapaxes(x, 0, 1)
+    got = roll_kv_cache(
+        *map(to_state, (k_cache, v_cache, valid, k_new, v_new, seg, no_done)),
+        axis=0,
+    )
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, to_state(b))
+
+
+def _arrays_of(jaxpr):
+    """(primitive, input shapes, output shapes) of every equation of a
+    jaxpr and of the jaxprs it holds (remat, pjit, custom rules)."""
+    for eqn in jaxpr.eqns:
+        yield (
+            eqn.primitive.name,
+            [getattr(x.aval, "shape", ()) for x in eqn.invars],
+            [x.aval.shape for x in eqn.outvars],
+            eqn.params,
+        )
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _arrays_of(sub)
+
+
+def test_ouro_update_gradient_builds_nothing_over_cache_and_unroll():
+    """The gradient of a tiny Ouro update with respect to its
+    parameters, as a jaxpr: no array has a key axis of M + T (no
+    `[cache; k]`, no joined scores or mask, forward or backward), and
+    no `[M, B, H, hd]` operand is transposed to `[B, M, H, hd]`; the
+    cache leg's backward pass is a query's alone: no dot_general puts
+    out an array of the cache's shape."""
+    from torchbeast_tpu import learner as learner_lib
+    from torchbeast_tpu import monobeast
+    from torchbeast_tpu.models import OuroNet
+
+    t, rows, slots, heads, size, actions = 6, 2, 5, 4, 16, 4
+    model = OuroNet(
+        num_actions=actions, memory_len=slots, d_model=64, num_heads=heads,
+        head_dim=size, mlp_width=96, num_layers=2, passes=3, remat=True,
+    )
+    rng = np.random.default_rng(0)
+    lead = (t, rows)
+    batch = {
+        "frame": jnp.asarray(rng.integers(0, 256, lead + (8, 8, 1)), jnp.uint8),
+        "reward": jnp.asarray(rng.standard_normal(lead), jnp.float32),
+        "done": jnp.zeros(lead, bool).at[3, 0].set(True),
+        "last_action": jnp.asarray(rng.integers(0, actions, lead)),
+        "episode_return": jnp.zeros(lead, jnp.float32),
+        "episode_step": jnp.zeros(lead, jnp.int32),
+        "action": jnp.asarray(rng.integers(0, actions, lead)),
+        "policy_logits": jnp.asarray(
+            rng.standard_normal(lead + (actions,)), jnp.float32
+        ),
+        "baseline": jnp.asarray(rng.standard_normal(lead), jnp.float32),
+    }
+    state = model.initial_state(rows)
+    params = model.init(
+        {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
+        {k: batch[k] for k in ("frame", "reward", "done", "last_action")},
+        state,
+    )
+    hp = monobeast.hparams_from_flags(monobeast.make_parser().parse_args([]))
+
+    def loss(params):
+        return learner_lib.compute_loss(model, params, batch, state, hp)[0]
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+    cache, cache_first = (slots, rows, heads, size), (rows, slots, heads, size)
+    joined, equations = slots + t, 0
+    assert joined not in (t, rows, slots, heads, size, actions, 64, 96)
+    for name, ins, outs, eqn_params in _arrays_of(jaxpr):
+        equations += 1
+        for shape in ins + outs:
+            assert joined not in shape, (name, ins, outs)
+        if name == "transpose":
+            assert not (ins[0] == cache and outs[0] == cache_first), (
+                name, eqn_params,
+            )
+        if name == "dot_general":
+            assert cache not in outs and cache_first not in outs, (ins, outs)
+    # The walk went inside the rematerialised blocks: 3 passes x 2
+    # layers, forward and again in the backward pass.
+    assert equations > 1000

@@ -238,7 +238,10 @@ def test_a_window_layer_ignores_the_key_a_full_layer_reads():
     window's cache of 3 slots is handed the last 3 and cannot tell what
     came before; a full layer's output moves when the key and value 4
     steps back (one past the window of 4) change."""
-    from torchbeast_tpu.ops.attention import band_relative_offsets
+    from torchbeast_tpu.ops.attention import (
+        band_by_leg,
+        band_relative_offsets,
+    )
 
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((B, 1, 48)), jnp.float32)
@@ -249,12 +252,16 @@ def test_a_window_layer_ignores_the_key_a_full_layer_reads():
     v_moved = v.at[:, M - 4].add(1.0)
 
     def run(kind, slots, k, v):
-        band, offsets = band_relative_offsets(1, slots)
-        mask = jnp.broadcast_to(band[None], (B, 1, slots + 1))
+        cache_band, seq_band = band_by_leg(1, slots)
+        masks = (
+            jnp.broadcast_to(cache_band[None], (B, 1, slots)),
+            jnp.broadcast_to(seq_band[None], (B, 1, 1)),
+        )
         block = _one_block(kind, slots)
-        cache = (k[:, -slots:], v[:, -slots:])
-        params = block.init(jax.random.PRNGKey(0), x, cache, mask, offsets)
-        return block.apply(params, x, cache, mask, offsets)[0]
+        # The block contract: the cache as the state holds it.
+        cache = tuple(c[:, -slots:].transpose(1, 0, 2, 3) for c in (k, v))
+        params = block.init(jax.random.PRNGKey(0), x, cache, *masks)
+        return block.apply(params, x, cache, *masks)[0]
 
     window = SMALL["sliding_window"] - 1
     np.testing.assert_array_equal(

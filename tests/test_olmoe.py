@@ -177,6 +177,36 @@ def _every_expert_masked(x, idx, gate, w_gate, w_up, w_down):
     return y
 
 
+@pytest.mark.parametrize("factor", [1.0, 1.25], ids=["plain", "yarn-factor"])
+def test_rope_in_the_states_layout_is_rope(factor):
+    """`rope_rotate_state` on a cache as the state holds it, [S, B, H,
+    D] at negative times, = `rope_rotate` on the same keys batch-first
+    to the last bit or two (jitted, as the models run them: the two
+    forms' multiply-adds contract differently), and both = the
+    reference's rotate-half."""
+    rng = np.random.default_rng(0)
+    keys = jnp.asarray(rng.standard_normal((5, 2, 4, 16)), jnp.float32)
+    times = jnp.arange(5) - 5
+    inv_freq = 10000.0 ** (-jnp.arange(8, dtype=jnp.float32) / 8)
+    rotate_state = jax.jit(olmoe.rope_rotate_state, static_argnums=3)
+    rotate = jax.jit(olmoe.rope_rotate, static_argnums=3)
+    in_state = rotate_state(keys, times, inv_freq, factor)
+    batch_first = rotate(keys.transpose(1, 0, 2, 3), times, inv_freq, factor)
+    np.testing.assert_allclose(
+        in_state, batch_first.transpose(1, 0, 2, 3), rtol=1e-5, atol=1e-6
+    )
+    if factor == 1.0:
+        np.testing.assert_allclose(
+            batch_first,
+            reference._rope(keys.transpose(1, 0, 2, 3), times, 10000.0),
+            rtol=1e-6, atol=1e-6,
+        )
+        np.testing.assert_allclose(
+            olmoe.rope_state(keys, times, 10000.0), in_state,
+            rtol=1e-5, atol=1e-6,
+        )
+
+
 def test_gates_are_not_renormalised():
     """With every expert the same matrix the layer's output is that
     expert's, times the SUM of the chosen probabilities (under one: the

@@ -529,7 +529,7 @@ def compute_loss(
         batch,
         initial_agent_state,
         sample_action=False,
-        mutable=["losses", "moe_stats", "loop_stats"],
+        mutable=["losses", "moe_stats", "loop_stats", "attention_stats"],
     )
     aux_loss = sum(
         jnp.sum(leaf)
@@ -537,6 +537,12 @@ def compute_loss(
     )
     moe_stats = _moe_stats(variables.get("moe_stats", {}))
     loop_stats = _loop_stats(variables.get("loop_stats", {}))
+    # Block applications traced through the two-leg attention (models/
+    # transformer.py `count_two_leg_application`); no key for a model
+    # that has none.
+    attention_stats = _sown_by_name(
+        "attention_", variables.get("attention_stats", {})
+    )
 
     bootstrap_value = learner_outputs.baseline[-1]
 
@@ -619,6 +625,7 @@ def compute_loss(
         "episode_count": episode_count,
         **moe_stats,
         **loop_stats,
+        **attention_stats,
     }
     return total_loss, stats
 
@@ -643,6 +650,14 @@ def _moe_stats(sown) -> Dict[str, Any]:
     return stats
 
 
+def _sown_by_name(prefix: str, sown) -> Dict[str, Any]:
+    """A sown collection's leaves under `prefix` + their own names."""
+    return {
+        prefix + path[-1]: leaf
+        for path, leaf in flax.traverse_util.flatten_dict(sown).items()
+    }
+
+
 def _loop_stats(sown) -> Dict[str, Any]:
     """What a looped trunk says of its passes (models/ouro.py): its
     constants as they are (`passes`, `block_applications`, `cache_bytes_
@@ -651,10 +666,7 @@ def _loop_stats(sown) -> Dict[str, Any]:
     taking the rest: the pass a token would leave after, counted from 1
     and averaged over the batch, and the mass left to the last pass.
     Empty for every other model."""
-    stats = {
-        "loop_" + path[-1]: leaf
-        for path, leaf in flax.traverse_util.flatten_dict(sown).items()
-    }
+    stats = _sown_by_name("loop_", sown)
     if stats:
         gates = jnp.stack(stats.pop("loop_exit_gates"))  # [passes, ...]
         # Not leaving at pass u, for every pass but the last; their

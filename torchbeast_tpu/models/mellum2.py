@@ -125,14 +125,24 @@ class _Mellum2Block(nn.Module):
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x, cache, mask, offsets, **_):
-        """TransformerNet's block contract: x [B, T, d]; cache (k, v)
-        [B, M, kv_heads, hd]; mask [B, T, M+T]. Returns (y, k, v) with
-        this unroll's un-rotated k and v [B, T, kv_heads, hd]."""
+    def __call__(self, x, cache_state, cache_mask, seq_mask, **_):
+        """TransformerNet's block contract: x [B, T, d]; cache_state
+        (k, v) [M, B, kv_heads, hd] as the state holds them; cache_mask
+        [B, T, M], seq_mask [B, T, T]. Returns (y, k, v) with this
+        unroll's un-rotated k and v [B, T, kv_heads, hd].
+
+        This block still attends over the concatenation `[cache; k]`,
+        `[cache; v]` through `dense_transformer_attend`, which it builds
+        here: tests/perfbench/test_perfbench_mellum2.py plants its
+        repeated-head fault on this module's name for that function and
+        its `k_all`, `v_all`, and a PR that changes the program may not
+        change the benchmark's files (PERF.md section 7)."""
         B, T, _ = x.shape
         M, H, Hkv, hd = (
             self.memory_len, self.num_heads, self.kv_heads, self.head_dim
         )
+        cache = tuple(c.transpose(1, 0, 2, 3) for c in cache_state)
+        mask = jnp.concatenate([cache_mask, seq_mask], axis=-1)
 
         def norm(name):
             return nn.RMSNorm(epsilon=self.rms_norm_eps, name=name)
@@ -168,7 +178,7 @@ class _Mellum2Block(nn.Module):
                 rope_rotate(k_all, key_time, inv_freq, factor).astype(
                     self.dtype
                 ),
-                v_all.astype(self.dtype), mask, offsets, None,
+                v_all.astype(self.dtype), mask, None, None,
             )
             x = x + proj("o", self.d_model)(
                 attended.reshape(B, T, H * hd)

@@ -8,7 +8,9 @@ published widths. Per layer:
 
     h = rmsnorm(x)
     q = rmsnorm_d(Wq h), k = rmsnorm_d(Wk h), v = Wv h      (no bias)
-    x = x + Wo attend(rope(q), rope(k), v)                  (16 heads)
+    x = x + Wo attend(rope(q), rope(cache.k) | rope(k), cache.v | v)
+                                  16 heads; the cache and the unroll are
+                                  two legs of one softmax
     x = x + moe(rmsnorm(x))       64 SwiGLU experts, top 8, dropless,
                                   gates not renormalised (models/moe.py)
 
@@ -31,8 +33,11 @@ import jax
 import jax.numpy as jnp
 
 from torchbeast_tpu.models.moe import DroplessMoE
-from torchbeast_tpu.models.transformer import TransformerNet
-from torchbeast_tpu.ops.attention import dense_transformer_attend
+from torchbeast_tpu.models.transformer import (
+    TransformerNet,
+    count_two_leg_application,
+)
+from torchbeast_tpu.ops.attention import cached_transformer_attend
 
 # https://huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct/blob/main/config.json
 # by the name of the field that carries each. `expert_width` is the
@@ -51,32 +56,96 @@ PUBLISHED = {
 }
 
 
+def _cos_sin(positions, inv_freq, factor):
+    """cos and sin [S, D/2] of RoPE's angles; `factor` scales both
+    (YaRN's attention factor, models/mellum2.py)."""
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    return cos, sin
+
+
 def rope_rotate(x, positions, inv_freq, factor=1.0):
     """Rotate-half RoPE at the given frequencies. x [B, S, H, D];
     positions [S] (may be negative: only differences between a query's
-    and a key's reach the scores); inv_freq [D/2]. `factor` scales cos
-    and sin (YaRN's attention factor, models/mellum2.py)."""
+    and a key's reach the scores); inv_freq [D/2].
+
+    x * [cos, cos] + [-x2, x1] * [sin, sin], a half at a time. Written
+    so, the chip's compiler keeps the halves apart and lets the scores
+    contract each (no rotated copy of x, no joined result): the fastest
+    of four forms for an unroll's queries and keys, and for Mellum2's
+    `[cache; k]` (172.4 ms an update for 176.7; PERF.md, PR 35)."""
     half = x.shape[-1] // 2
-    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    cos = jnp.tile(jnp.cos(angles), 2)[None, :, None, :]
-    sin = jnp.tile(jnp.sin(angles), 2)[None, :, None, :]
-    if factor != 1.0:
-        cos, sin = cos * factor, sin * factor
-    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-    return x * cos + rotated * sin
+    cos, sin = (
+        t[None, :, None, :] for t in _cos_sin(positions, inv_freq, factor)
+    )
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    )
+
+
+def rope_rotate_state(x, positions, inv_freq, factor=1.0):
+    """`rope_rotate` for a cache as the state holds it, x [S, B, H, D]:
+    the same products and sums as ONE expression over the halves as a
+    pair axis, [S, B, H, 2, D/2]: x * cos + (the pair swapped) *
+    [-sin, sin]. The compiler makes of it one pass that reads the cache
+    and writes the rotated keys whole, which the three matmuls a block
+    application makes on them then read at full width; from the form
+    above it makes two padded halves of a cache (811 ms an update of
+    the Ouro cell for 841; PERF.md, PR 35). For the unroll's own few
+    keys it is the other way round."""
+    half = x.shape[-1] // 2
+    cos, sin = (
+        t[:, None, None, None, :]
+        for t in _cos_sin(positions, inv_freq, factor)
+    )
+    pairs = x.reshape(x.shape[:-1] + (2, half))
+    signed_sin = jnp.concatenate([-sin, sin], axis=-2)
+    return (pairs * cos + jnp.flip(pairs, axis=-2) * signed_sin).reshape(
+        x.shape
+    )
+
+
+def _inv_freq(theta, dim):
+    """theta^(-2i/D), i < D/2."""
+    half = dim // 2
+    return theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
 
 
 def rope(x, positions, theta):
-    """Rotate-half RoPE at the frequencies theta^(-2i/D)."""
-    half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    return rope_rotate(x, positions, inv_freq)
+    """Rotate-half RoPE of x [B, S, H, D] at theta^(-2i/D)."""
+    return rope_rotate(x, positions, _inv_freq(theta, x.shape[-1]))
+
+
+def rope_state(x, positions, theta):
+    """`rope` for a cache as the state holds it, x [S, B, H, D]."""
+    return rope_rotate_state(x, positions, _inv_freq(theta, x.shape[-1]))
+
+
+def rope_cached_attend(q, k, v, cache_state, cache_mask, seq_mask, theta,
+                       dtype):
+    """A RoPE block's attention (this family's and models/ouro.py's):
+    un-rotated q, k, v [B, T, H, hd] of the unroll and the cache (k, v)
+    [M, B, H, hd] as the state holds it, un-rotated too, through
+    ops/attention.cached_transformer_attend. A key's position is its
+    time relative to the unroll's first step: step j is j, the cache's
+    slots come with theirs (slot m of M: m - M)."""
+    steps = jnp.arange(q.shape[1])
+    cache_k, cache_v = cache_state
+    return cached_transformer_attend(
+        rope(q, steps, theta).astype(dtype),
+        rope(k, steps, theta).astype(dtype),
+        v.astype(dtype),
+        cache_k.astype(dtype), cache_v.astype(dtype), cache_mask, seq_mask,
+        place_cache_keys=lambda keys, times: rope_state(keys, times, theta),
+    )
 
 
 class _OLMoEBlock(nn.Module):
     d_model: int
     num_heads: int
-    memory_len: int
     num_experts: int
     experts_per_token: int
     expert_width: int
@@ -86,12 +155,13 @@ class _OLMoEBlock(nn.Module):
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x, cache, mask, offsets, **_):
-        """TransformerNet's block contract: x [B, T, d]; cache (k, v)
-        [B, M, H, hd]; mask [B, T, M+T]. Returns (y, k, v) with this
-        unroll's un-rotated k and v [B, T, H, hd]."""
+    def __call__(self, x, cache_state, cache_mask, seq_mask, **_):
+        """TransformerNet's block contract: x [B, T, d]; cache_state
+        (k, v) [M, B, H, hd], read where the state holds them;
+        cache_mask [B, T, M], seq_mask [B, T, T]. Returns (y, k, v) with
+        this unroll's un-rotated k and v [B, T, H, hd]."""
         B, T, _ = x.shape
-        M, H = self.memory_len, self.num_heads
+        H = self.num_heads
         hd = self.d_model // H
 
         def norm(name):
@@ -110,14 +180,11 @@ class _OLMoEBlock(nn.Module):
             q = norm("q_norm")(proj("q")(h)).reshape(B, T, H, hd)
             k = norm("k_norm")(proj("k")(h)).reshape(B, T, H, hd)
             v = proj("v")(h).reshape(B, T, H, hd)
-            k_all = jnp.concatenate([cache[0].astype(k.dtype), k], axis=1)
-            v_all = jnp.concatenate([cache[1].astype(v.dtype), v], axis=1)
-            key_time = jnp.concatenate([jnp.arange(M) - M, jnp.arange(T)])
-            attended = dense_transformer_attend(
-                rope(q, jnp.arange(T), self.rope_theta).astype(self.dtype),
-                rope(k_all, key_time, self.rope_theta).astype(self.dtype),
-                v_all.astype(self.dtype), mask, offsets, None,
+            attended = rope_cached_attend(
+                q, k, v, cache_state, cache_mask, seq_mask,
+                self.rope_theta, self.dtype,
             )
+            count_two_leg_application(self)
             x = x + proj("o")(
                 attended.reshape(B, T, self.d_model)
             ).astype(jnp.float32)
@@ -167,7 +234,6 @@ class OLMoENet(TransformerNet):
         del layer  # every layer is the same block
         return _OLMoEBlock(
             d_model=self.d_model, num_heads=self.num_heads,
-            memory_len=self.memory_len,
             num_experts=self.num_experts,
             experts_per_token=self.experts_per_token,
             expert_width=self.expert_width,

@@ -12,8 +12,10 @@ stack of L layers is applied `passes` (the config's `total_ut_steps`,
       for layer l = 0 .. L - 1:
         h = n1_l(x)
         q, k, v = Wq_l h, Wk_l h, Wv_l h       16 heads of 128, no bias
-        a = Wo_l attend(rope(q), rope([cache[u][l].k ; k]),
-                        [cache[u][l].v ; v])    theta 1e6
+        a = Wo_l attend(rope(q), rope(cache[u][l].k) | rope(k),
+                        cache[u][l].v | v)      theta 1e6; the cache
+                                                and the unroll are two
+                                                legs of one softmax
         x = x + n2_l(a)                         RMSNorm on the branch's
         m = Wdown_l(silu(Wgate_l n3_l(x)) * Wup_l n3_l(x))    OUTPUT too
         x = x + n4_l(m)
@@ -47,9 +49,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from torchbeast_tpu.models.olmoe import rope
-from torchbeast_tpu.models.transformer import TransformerNet
-from torchbeast_tpu.ops.attention import dense_transformer_attend
+from torchbeast_tpu.models.olmoe import rope_cached_attend
+from torchbeast_tpu.models.transformer import (
+    TransformerNet,
+    count_two_leg_application,
+)
 
 # https://huggingface.co/ByteDance/Ouro-2.6B/blob/main/config.json by
 # the name of the field that carries each. `create_model("ouro")` reads
@@ -71,26 +75,22 @@ class _OuroBlock(nn.Module):
     num_heads: int
     head_dim: int
     mlp_width: int
-    memory_len: int
     rms_norm_eps: float
     rope_theta: float
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x, cache, mask, offsets, cache_state=None, **_):
-        """TransformerNet's block contract: x [B, T, d]; cache (k, v)
-        [B, M, H, hd]; mask [B, T, M+T]. Returns (y, k, v) with this
-        unroll's un-rotated k and v [B, T, H, hd]. The norms carry the
-        names of the model's public modeling file."""
-        if cache_state is not None:
-            # `cache` again, as the state holds it ([M, B, H, hd]), to
-            # be transposed in here: what a rematerialised block keeps
-            # for its backward pass is then the state's own buffer. Kept
-            # outside, the passes x L transposed copies all live until
-            # the backward pass: 3.98 GiB at the cell's sizes.
-            cache = tuple(c.transpose(1, 0, 2, 3) for c in cache_state)
+    def __call__(self, x, cache_state, cache_mask, seq_mask, **_):
+        """TransformerNet's block contract: x [B, T, d]; cache_state
+        (k, v) [M, B, H, hd], the state's own buffers, read where they
+        lie (what a rematerialised block keeps for its backward pass is
+        then the state's buffer too: transposed copies, kept, were 3.98
+        GiB at the cell's sizes); cache_mask [B, T, M], seq_mask
+        [B, T, T]. Returns (y, k, v) with this unroll's un-rotated k and
+        v [B, T, H, hd]. The norms carry the names of the model's
+        public modeling file."""
         B, T, _ = x.shape
-        M, H, hd = self.memory_len, self.num_heads, self.head_dim
+        H, hd = self.num_heads, self.head_dim
 
         def norm(name):
             return nn.RMSNorm(epsilon=self.rms_norm_eps, name=name)
@@ -105,13 +105,9 @@ class _OuroBlock(nn.Module):
             q = proj("q", H * hd)(h).reshape(B, T, H, hd)
             k = proj("k", H * hd)(h).reshape(B, T, H, hd)
             v = proj("v", H * hd)(h).reshape(B, T, H, hd)
-            k_all = jnp.concatenate([cache[0].astype(k.dtype), k], axis=1)
-            v_all = jnp.concatenate([cache[1].astype(v.dtype), v], axis=1)
-            key_time = jnp.concatenate([jnp.arange(M) - M, jnp.arange(T)])
-            attended = dense_transformer_attend(
-                rope(q, jnp.arange(T), self.rope_theta).astype(self.dtype),
-                rope(k_all, key_time, self.rope_theta).astype(self.dtype),
-                v_all.astype(self.dtype), mask, offsets, None,
+            attended = rope_cached_attend(
+                q, k, v, cache_state, cache_mask, seq_mask,
+                self.rope_theta, self.dtype,
             )
             x = x + norm("input_layernorm_2")(
                 proj("o", self.d_model)(
@@ -187,7 +183,6 @@ class OuroNet(TransformerNet):
         block = block_cls(
             d_model=self.d_model, num_heads=self.num_heads,
             head_dim=self.head_dim, mlp_width=self.mlp_width,
-            memory_len=self.memory_len,
             rms_norm_eps=self.rms_norm_eps, rope_theta=self.rope_theta,
             dtype=self.dtype, parent=None,
         )
@@ -207,6 +202,9 @@ class OuroNet(TransformerNet):
             # pass's weight gradients live until the last is there:
             # (passes - 1) x 1.53 GiB at the cell's sizes.
             weights, x = jax.lax.optimization_barrier((weights, x))
+            # Counted here: the block is applied apart from this module
+            # and what it sowed would be dropped.
+            count_two_leg_application(self)
             return block.apply({"params": weights}, x, *args, **kwargs)
 
         return apply

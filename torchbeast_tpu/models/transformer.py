@@ -13,7 +13,8 @@ across unrolls as the recurrent state (so acting at T=1 still sees up to
 - the cache written back keeps only entries from the final segment.
 
 Attention is windowed to the last `memory_len` steps via a band mask over
-the combined [cache; unroll] axis — EXACTLY the semantics of stepwise
+the cache's slots and the unroll's steps (built a leg at a time,
+ops/attention.band_by_leg) — EXACTLY the semantics of stepwise
 acting with rolling cache eviction, so the learner's batch forward and the
 actor's T=1 forwards agree bit-for-bit at any unroll length or cache fill
 (pinned by tests/test_transformer.py). Positions enter through a learned
@@ -22,7 +23,10 @@ cache consistency).
 
 The cache pytree uses the framework-wide state convention (batch on axis
 1: k/v [M, B, H, D], valid [M, B]), so the queues/batcher/collectors carry
-it exactly like LSTM state.
+it exactly like LSTM state. The walk hands a block its cache in that
+layout and rolls it in that layout (`roll_kv_cache(..., axis=0)`): no
+batch-first copy of a cache is made on the way in or out; a block that
+wants one (`_Block`, models/mellum2.py) makes it.
 
 Sequence parallelism: construct with `mesh=` (a jax Mesh with a `seq`
 axis) and unrolls whose T is divisible by the axis size run their
@@ -44,6 +48,7 @@ import jax.numpy as jnp
 
 from torchbeast_tpu.models.cores import RecurrentPolicyHead
 from torchbeast_tpu.ops.attention import (
+    band_by_leg,
     band_relative_offsets,
     dense_transformer_attend,
     ring_transformer_attention,
@@ -51,6 +56,20 @@ from torchbeast_tpu.ops.attention import (
     segment_ids_from_done,
     ulysses_transformer_attention,
 )
+
+
+def count_two_leg_application(module: nn.Module) -> None:
+    """One block application traced through ops/attention.
+    cached_transformer_attend, for the update's stats (`attention_two_
+    leg_applications`, learner.compute_loss; poly's gauge `attention.
+    two_leg_applications`): 32 in the Ouro cell, 2 in OLMoE's. The path
+    is compiled in, so the count is the trace's, not the device's."""
+    if not module.is_initializing():
+        module.sow(
+            "attention_stats", "two_leg_applications", jnp.float32(1.0),
+            init_fn=lambda: jnp.float32(0.0),
+            reduce_fn=lambda count, one: count + one,
+        )
 
 
 class _Block(nn.Module):
@@ -69,18 +88,36 @@ class _Block(nn.Module):
     moe_mesh: Any = None  # mesh with an `expert` axis -> expert parallel
 
     @nn.compact
-    def __call__(self, x, cache, mask, offsets, cache_mask=None, seg=None,
-                 cache_valid=None, no_done=None, **_):
-        """x: [B, T, d]; cache: (k, v) with k/v [B, M, H, hd];
-        mask: [B, T, M+T] (True = may attend); offsets: [T, M+T] relative
-        distances query_time - key_time in [0, M]. cache_mask [B, T, M]
-        and seg [B, T] feed the ring path (which rebuilds the in-unroll
-        band/segment mask per block instead of materializing [T, T]);
-        cache_valid [B, M] and no_done [B, T] feed the fused pallas
-        kernel (which rebuilds the whole mask in-kernel). Returns
-        (y, new_k, new_v) where new_k/new_v are this unroll's
-        [B, T, H, hd]."""
+    def __call__(self, x, cache_state, cache_mask, seq_mask, seg=None,
+                 cache_valid=None, no_done=None):
+        """The block contract of `TransformerNet`'s walk. x: [B, T, d];
+        cache_state: (k, v), the layer's cache AS THE STATE HOLDS IT,
+        [M, B, H, hd]; cache_mask [B, T, M] and seq_mask [B, T, T]
+        (True = may attend): the masks of the two legs, the cache and
+        the unroll, apart. seg [B, T] feeds the ring path (which
+        rebuilds the in-unroll band/segment mask per block instead of
+        materializing [T, T]); cache_valid [B, M] and no_done [B, T]
+        feed the fused pallas kernel (which rebuilds the whole mask
+        in-kernel). Returns (y, new_k, new_v) where new_k/new_v are
+        this unroll's [B, T, H, hd].
+
+        A family's block takes of these what it reads. The OLMoE and
+        Ouro blocks read the state and the two masks as they come
+        (ops/attention.cached_transformer_attend: two legs of one
+        softmax, nothing over M + T keys built). This one wants the
+        batch-first cache and, on its dense and Ulysses branches, one
+        mask over [cache; unroll] with the relative distances its bias
+        is indexed by: it builds them here."""
+        cache = tuple(c.transpose(1, 0, 2, 3) for c in cache_state)
         B, T, _ = x.shape
+
+        def over_cache_and_unroll():
+            """(mask [B, T, M+T], offsets [T, M+T]: distances query_time
+            - key_time clipped to [0, M]) for the two branches that
+            attend over the concatenation."""
+            _, offsets = band_relative_offsets(T, self.memory_len)
+            return jnp.concatenate([cache_mask, seq_mask], axis=-1), offsets
+
         H = self.num_heads
         hd = self.d_model // H
 
@@ -123,7 +160,7 @@ class _Block(nn.Module):
                 q, k, v,
                 cache[0].astype(k.dtype),
                 cache[1].astype(v.dtype),
-                mask, offsets, rel_bias,
+                *over_cache_and_unroll(), rel_bias,
                 self.mesh, self.seq_axis,
                 batch_axis=self.batch_axis,
             ).astype(v.dtype)
@@ -169,7 +206,7 @@ class _Block(nn.Module):
             # Shared body with the Ulysses path (ops/attention.py) so the
             # dense==ulysses parity invariant cannot drift.
             attended = dense_transformer_attend(
-                q, k_all, v_all, mask, offsets, rel_bias
+                q, k_all, v_all, *over_cache_and_unroll(), rel_bias
             )
         x = x + nn.DenseGeneral(
             self.d_model, axis=(-2, -1), name="out", dtype=self.dtype
@@ -287,9 +324,9 @@ class TransformerNet(nn.Module):
         geometry = {}
         for M, _, _ in self.layer_caches():
             if M not in geometry:
-                band, offsets = band_relative_offsets(T, M)
+                cache_band, seq_band = band_by_leg(T, M)
                 # In-unroll mask: band-causal + same segment. [B, T, T]
-                geometry[M] = band, offsets, band[None, :, M:] & same
+                geometry[M] = cache_band, seq_band[None] & same
 
         # The walk: `block_passes` says which block's weights serve each
         # cache entry, in passes over the stack; the family's last norm
@@ -309,40 +346,30 @@ class TransformerNet(nn.Module):
                             f"block_{layer}", layer
                         )
                     (k_cache, v_cache, valid), (M, _, _) = next(caches)
-                    band, offsets, seq_mask = geometry[M]
-                    # state convention [M, B, ...] -> model-internal
-                    # [B, M, ...]
-                    k_cache_b = k_cache.transpose(1, 0, 2, 3)
-                    v_cache_b = v_cache.transpose(1, 0, 2, 3)
+                    cache_band, seq_mask = geometry[M]
                     valid_b = valid.T  # [B, M]
                     cache_mask = (
-                        band[None, :, :M]
+                        cache_band[None]
                         & valid_b[:, None, :].astype(bool)
                         & no_done_yet[:, :, None]
                     )  # [B, T, M]
-                    mask = jnp.concatenate([cache_mask, seq_mask], axis=-1)
+                    # The cache goes in as the state has it, [M, B, ...]
+                    # (the block contract, `_Block.__call__`): a block
+                    # that is rematerialised keeps the state's own
+                    # buffer for its backward pass, not a copy.
                     x, k_new, v_new = blocks[layer](
-                        x, (k_cache_b, v_cache_b), mask, offsets,
-                        cache_mask=cache_mask, seg=seg,
-                        cache_valid=valid_b, no_done=no_done_yet,
-                        # The cache as the state has it. A block that is
-                        # rematerialised and transposes this one itself
-                        # keeps the state's own buffer for its backward
-                        # pass, not a transposed copy (models/ouro.py).
-                        cache_state=(k_cache, v_cache),
+                        x, (k_cache, v_cache), cache_mask, seq_mask,
+                        seg=seg, cache_valid=valid_b, no_done=no_done_yet,
                     )
 
-                    # Roll the cache: last M of [old cache; this unroll],
-                    # validity restricted to the final segment (shared
-                    # helper, ops/attention.py).
-                    k_roll, v_roll, valid_roll = roll_kv_cache(
-                        k_cache_b, v_cache_b, valid_b, k_new, v_new,
-                        seg, no_done_yet,
-                    )
-                    new_state.append((
-                        k_roll.transpose(1, 0, 2, 3),
-                        v_roll.transpose(1, 0, 2, 3),
-                        valid_roll.T,
+                    # Roll the cache where it lies: last M of [old
+                    # cache; this unroll] on axis 0, validity restricted
+                    # to the final segment (ops/attention.py).
+                    new_state.append(roll_kv_cache(
+                        k_cache, v_cache, valid,
+                        k_new.transpose(1, 0, 2, 3),
+                        v_new.transpose(1, 0, 2, 3),
+                        seg.T, no_done_yet.T, axis=0,
                     ))
                 x = final_norm(x)
 

@@ -17,6 +17,20 @@ op comes with a sequence-parallel formulation from the start:
 
 Equivalence of the two is pinned by tests/test_attention.py on the 8-device
 CPU mesh.
+
+The transformer policies attend over a rolling KV cache and over the
+unroll. Two bodies do that on one chip:
+
+- `cached_transformer_attend` (the OLMoE and Ouro blocks): the cache and
+  the unroll are TWO LEGS OF ONE SOFTMAX. The cache is read where the
+  state holds it ([M, B, Hkv, D]), its scores and the unroll's are never
+  joined on the key axis, and no `[cache; k]` / `[cache; v]` is built;
+  the cache is an input of its own, so the backward pass does a query's
+  work through it and none for its keys and values.
+- `dense_transformer_attend` on the concatenated `[cache; unroll]`:
+  kept for `models/transformer._Block` (learned relative bias), its
+  parity with the Ulysses path, `models/transformer_pp.py`, and the
+  Mellum2 block (tests/perfbench pins that name on it).
 """
 
 import functools
@@ -549,54 +563,172 @@ def _zigzag_transformer_ring(q, k, v, cache_k, cache_v, cache_mask,
     return constrain(jnp.take(out_z, inv_perm, axis=1), seq_sh)
 
 
-def band_relative_offsets(T: int, M: int):
-    """(band, offsets) over the combined [cache; unroll] key axis —
-    the ONE implementation of the transformer families' windowed-causal
-    time geometry (models/transformer.py and models/transformer_pp.py
-    both consume it, so the semantics cannot drift apart).
+def band_by_leg(T: int, M: int):
+    """The transformer families' windowed-causal time geometry, a leg
+    at a time: (cache_band [T, M], seq_band [T, T]) bool.
 
     Cache slot m (of M, oldest-first) has time m - M; in-unroll step j
-    has time j; query t may attend to times in [t - M, t]. Returns
-    band [T, M+T] bool and offsets [T, M+T] int clipped to [0, M]
-    (indices into the learned relative bias).
+    has time j; query t may attend to times in [t - M, t]. Slot m lies
+    t + M - m >= 1 steps back, so it is in the band iff m >= t.
     """
     q_time = jnp.arange(T)
+    cache_band = jnp.arange(M)[None, :] >= q_time[:, None]
+    back = q_time[:, None] - q_time[None, :]
+    return cache_band, (back >= 0) & (back <= M)
+
+
+def band_relative_offsets(T: int, M: int):
+    """(band, offsets) over the combined [cache; unroll] key axis —
+    `band_by_leg`'s two bands side by side, for the bodies that attend
+    over the concatenation (models/transformer.py `_Block`,
+    models/transformer_pp.py), and the offsets their learned relative
+    bias is indexed by. Returns band [T, M+T] bool and offsets
+    [T, M+T] int clipped to [0, M].
+    """
     key_time = jnp.concatenate([jnp.arange(M) - M, jnp.arange(T)])
-    offsets = q_time[:, None] - key_time[None, :]  # [T, M+T]
-    band = (offsets >= 0) & (offsets <= M)
+    offsets = jnp.arange(T)[:, None] - key_time[None, :]  # [T, M+T]
+    band = jnp.concatenate(band_by_leg(T, M), axis=-1)
     return band, jnp.clip(offsets, 0, M)
 
 
-def roll_kv_cache(k_cache, v_cache, valid, k_new, v_new, seg, no_done):
-    """Roll a per-layer KV cache across an unroll (batch-first layout):
-    keep the last M of [old cache; this unroll], with validity restricted
-    to the FINAL segment (an episode boundary inside the unroll evicts
-    everything before it). Shared by both transformer families — see
+def roll_kv_cache(k_cache, v_cache, valid, k_new, v_new, seg, no_done,
+                  axis: int = 1):
+    """Roll a per-layer KV cache across an unroll: keep the last M of
+    [old cache; this unroll], with validity restricted to the FINAL
+    segment (an episode boundary inside the unroll evicts everything
+    before it). Shared by both transformer families — see
     band_relative_offsets.
 
-    k_cache/v_cache: [B, M, H, hd]; valid: [B, M] (float or bool);
-    k_new/v_new: [B, T, H, hd]; seg/no_done: [B, T].
-    Returns (k, v, valid_f32) in the same batch-first layout.
+    `axis` is where every argument has its time (slot or step), the
+    batch on the other of the first two: 1 for the batch-first layout
+    (k_cache/v_cache [B, M, H, hd]; valid [B, M]; k_new/v_new
+    [B, T, H, hd]; seg/no_done [B, T]: models/transformer_pp.py's
+    carry), 0 for the state's own ([M, B, H, hd], [M, B], [T, B, H, hd],
+    [T, B]: models/transformer.py rolls the state where it lies, a slice
+    and a concatenation, no transposed copy of the cache in or out).
+    Returns (k, v, valid_f32) in the layout given.
     """
-    M = k_cache.shape[1]
-    final_seg = seg[:, -1:]
-    seq_valid = seg == final_seg  # [B, T]
-    old_valid = valid.astype(bool) & no_done[:, -1:]
-    k_cat = jnp.concatenate([k_cache, k_new], axis=1)
-    v_cat = jnp.concatenate([v_cache, v_new], axis=1)
-    valid_cat = jnp.concatenate([old_valid, seq_valid], axis=1)
+    M, T = k_cache.shape[axis], k_new.shape[axis]
+
+    def last(x, n):
+        size = x.shape[axis]
+        return jax.lax.slice_in_dim(x, size - n, size, axis=axis)
+
+    def rolled(old, new):
+        # The last M of [old; new], with nothing of M + T built: what
+        # the unroll leaves of the old slots, then the unroll's last.
+        kept = max(M - T, 0)
+        return jnp.concatenate(
+            [last(old, kept), last(new, M - kept)], axis=axis
+        )
+
+    seq_valid = seg == last(seg, 1)
+    old_valid = valid.astype(bool) & last(no_done, 1)
     return (
-        k_cat[:, -M:],
-        v_cat[:, -M:],
-        valid_cat[:, -M:].astype(jnp.float32),
+        rolled(k_cache, k_new),
+        rolled(v_cache, v_new),
+        rolled(old_valid, seq_valid).astype(jnp.float32),
     )
 
 
+@jax.custom_jvp
+def _queried_first(q, slot_times):
+    """The identity, with the cache slots' times tied to the queries.
+    The cache is there from the program's start, and so is whatever is
+    computed from it alone: left to itself the compiler rotates the
+    cached keys of many layers at once, ahead of their use, and keeps
+    the results (0.73 GiB more at the Ouro cell's sizes). With the
+    times behind this barrier a layer's keys are rotated when the
+    layer's queries are there. The times, not the cache: a barrier on
+    an argument of the program costs a copy of it."""
+    return jax.lax.optimization_barrier((q, slot_times))
+
+
+# The barrier's own rule makes a tangent of zeros for what has none;
+# the identity's is the identity.
+_queried_first.defjvp(
+    lambda primals, tangents: (_queried_first(*primals), tangents),
+    symbolic_zeros=True,
+)
+
+
+def cached_transformer_attend(q, k, v, cache_k, cache_v, cache_mask,
+                              seq_mask, place_cache_keys=None):
+    """Attention over a rolling cache and over the unroll as two legs
+    of one softmax, the cache read where the state holds it.
+
+    q: [B, T, H, D]; k, v: [B, T, Hkv, D], this unroll's; cache_k,
+    cache_v: [M, B, Hkv, D], THE STATE'S LAYOUT; cache_mask [B, T, M] and
+    seq_mask [B, T, T] bool, as models/transformer.py builds them apart.
+    q and k come with their positions applied; `place_cache_keys`, if
+    given, applies the cache's: called with cache_k and the slots'
+    times [M] (slot m is at m - M), it returns cache_k placed, where it
+    lies (RoPE: the state holds un-rotated keys). Returns [B, T, H, D].
+
+    What `dense_transformer_attend` computes on `[cache; k]`, `[cache;
+    v]` and `[cache_mask, seq_mask]`, in another order of summation:
+    scores of each leg in f32, masked with BIG_NEG; one maximum and one
+    denominator over both legs; the two combines added and divided by
+    the denominator on the [B, T, H, D] output. Every query sees its own
+    step, so the denominator is positive whatever the cache's validity,
+    and the program is the same for a full cache and an empty one.
+    Nothing of M + T keys is built, forward or backward, and the cache
+    takes part as plain inputs of autodiff: asked for, its gradient is
+    the dense path's; not asked for (the learner's case: the cache is
+    data), only dq is computed through the cache leg.
+
+    Hkv may be a divisor of H: query head j reads key/value head
+    j // (H // Hkv), contracted by group as in `dense_transformer_attend`.
+    """
+    B, T, H, D = q.shape
+    Hkv = k.shape[2]
+    if H % Hkv:
+        raise ValueError(
+            f"{H} query heads do not divide over {Hkv} key/value heads"
+        )
+    scale = D ** -0.5
+    q = q.reshape(B, T, Hkv, H // Hkv, D)
+    if place_cache_keys is not None:
+        # Slot m of M is at time m - M (`band_by_leg`).
+        M = cache_k.shape[0]
+        q, slot_times = _queried_first(q, jnp.arange(M) - M)
+        cache_k = place_cache_keys(cache_k, slot_times)
+
+    def scores(spec, keys, mask):
+        s = jnp.einsum(spec, q, keys).astype(jnp.float32) * scale
+        return jnp.where(mask[:, None, None], s, BIG_NEG)
+
+    with jax.named_scope("cache_leg"):
+        s_c = scores("bqhgd,mbhd->bhgqm", cache_k, cache_mask)
+    with jax.named_scope("unroll_leg"):
+        s_u = scores("bqhgd,bkhd->bhgqk", k, seq_mask)
+    # As jax.nn.softmax: no gradient through the maximum.
+    top = jax.lax.stop_gradient(
+        jnp.maximum(s_c.max(axis=-1), s_u.max(axis=-1))
+    )[..., None]
+    with jax.named_scope("cache_leg"):
+        p_c = jnp.exp(s_c - top)
+        out_c = jnp.einsum(
+            "bhgqm,mbhd->bqhgd", p_c.astype(cache_v.dtype), cache_v
+        )
+    with jax.named_scope("unroll_leg"):
+        p_u = jnp.exp(s_u - top)
+        out_u = jnp.einsum("bhgqk,bkhd->bqhgd", p_u.astype(v.dtype), v)
+    den = p_c.sum(axis=-1) + p_u.sum(axis=-1)  # [B, Hkv, G, T]
+    out = (out_c + out_u) / den.transpose(0, 3, 1, 2)[..., None].astype(
+        out_u.dtype
+    )
+    return out.reshape(B, T, H, D)
+
+
 def dense_transformer_attend(q, k_all, v_all, mask, offsets, rel_bias):
-    """The transformer policy's dense attention body — ONE implementation
-    shared by the model's dense branch (models/transformer.py _Block) and
-    the Ulysses path below (which is exactly this on a head slice), so
-    the two can never drift apart numerically.
+    """The transformer policy's dense attention body over `[cache;
+    unroll]` — ONE implementation shared by the model's dense branch
+    (models/transformer.py _Block) and the Ulysses path below (which is
+    exactly this on a head slice), so the two can never drift apart
+    numerically; models/transformer_pp.py and models/mellum2.py call it
+    too. The OLMoE and Ouro blocks do not: `cached_transformer_attend`
+    computes the same over the two legs apart.
 
     q: [B, T, H, D]; k_all/v_all: [B, M+T, Hkv, D] (cache prepended);
     mask: [B, T, M+T] bool; offsets: [T, M+T] int in [0, M];

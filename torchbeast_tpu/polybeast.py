@@ -2150,7 +2150,9 @@ def train(flags):
                 # the same two over the experts held here. A looped
                 # trunk's passes (learner._loop_stats): how many, the
                 # block applications and cache bytes a row they cost,
-                # and where its exit gates would let go.
+                # and where its exit gates would let go. Block
+                # applications traced through the two-leg attention
+                # (ops/attention.cached_transformer_attend).
                 for family, names in (
                     ("moe", (
                         "assignments", "load_max_over_mean",
@@ -2161,6 +2163,7 @@ def train(flags):
                         "cache_bytes_per_row", "expected_exit_pass",
                         "exit_p_last",
                     )),
+                    ("attention", ("two_leg_applications",)),
                 ):
                     for name in names:
                         if f"{family}_{name}" in stats_now:
