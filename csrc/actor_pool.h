@@ -23,6 +23,8 @@
 
 #pragma once
 
+#include <pthread.h>
+
 #include <atomic>
 #include <chrono>
 #include <cstring>
@@ -175,8 +177,12 @@ class ActorPool {
     for (size_t i = 0; i < addresses_.size(); ++i) {
       const std::string& address = addresses_[i];
       int64_t index = static_cast<int64_t>(i);
-      threads.emplace_back(
-          [this, index, address] { guarded_loop(index, address); });
+      threads.emplace_back([this, index, address] {
+        // The kernel's name of the task: what the host's thread ledger
+        // (telemetry/heartbeat.py) takes for the role `actors`.
+        pthread_setname_np(pthread_self(), "tbt-actor");
+        guarded_loop(index, address);
+      });
     }
     for (auto& t : threads) t.join();
     std::lock_guard<std::mutex> lock(error_mu_);

@@ -18,6 +18,11 @@
 #include <numpy/arrayobject.h>
 #include <numpy/arrayscalars.h>
 
+#include <atomic>
+#include <chrono>
+#include <cmath>
+#include <cstdint>
+#include <limits>
 #include <memory>
 #include <string>
 #include <vector>
@@ -113,12 +118,108 @@ bool npy_to_dtype(int npy, DType* out) {
   }
 }
 
+// ------------------------------------------- the interpreter lock's wait
+// How long a thread waited to (re-)take the GIL at each place this
+// module takes it: the re-acquire that ends every call_nogil, and every
+// PyGILState_Ensure. Samples of the lock's wait on the threads that
+// serve, at the instants they ask for it. One interval histogram a
+// site, in the registry's log buckets (csrc/queues.h
+// telemetry_bucket_index); runtime/native.py's NativeTelemetryFolder
+// folds them into `host.gil_wait_s.<site>`. A crossing pays two clock
+// reads and a few relaxed atomic adds: no lock, no allocation (a
+// HistAccum has both), since the observer may be an actor thread.
+enum GilSite : int {
+  kGilBatcherNext = 0,  // the launcher coming back with a batch
+  kGilGetInputs,        // Batch.get_inputs (never drops the lock today)
+  kGilSetOutputs,       // Batch.set_outputs (likewise: it clones and
+                        // fulfils the promises with the lock held)
+  kGilLearnerDequeue,   // BatchingQueue's blocking reads
+  kGilSlotHook,         // the actor threads' read_slot / reset
+  kGilBufferRelease,    // a borrowed numpy buffer let go off-thread
+  kGilEnvHook,          // the native env server's calls into the env
+  kGilOther,            // enqueue, compute, routers, run, stop, chaos
+  kGilSiteCount
+};
+const char* const kGilSiteNames[kGilSiteCount] = {
+    "batcher_next", "get_inputs", "set_outputs", "learner_dequeue",
+    "slot_hook",    "buffer_release", "env_hook", "other"};
+
+using GilClock = std::chrono::steady_clock;
+
+class GilWaitHist {
+ public:
+  // Bucket kBuckets - 1 starts at 1e-9 * 2^((kBuckets - 2) / 4) s, over
+  // an hour: nothing lands past it.
+  static constexpr int kBuckets = 176;
+
+  void observe(double seconds) {
+    int index = tbt::telemetry_bucket_index(seconds);
+    if (index >= kBuckets) index = kBuckets - 1;
+    buckets_[index].fetch_add(1, std::memory_order_relaxed);
+    add(total_, seconds);
+    add(total_sq_, seconds * seconds);
+    double seen = max_.load(std::memory_order_relaxed);
+    while (seconds > seen &&
+           !max_.compare_exchange_weak(seen, seconds,
+                                       std::memory_order_relaxed)) {
+    }
+    seen = min_.load(std::memory_order_relaxed);
+    while (seconds < seen &&
+           !min_.compare_exchange_weak(seen, seconds,
+                                       std::memory_order_relaxed)) {
+    }
+  }
+
+  // The interval since the last call. A sample landing while this
+  // runs may have its bucket in one interval and its moments in the
+  // next; the reader derives counts from buckets, like the registry.
+  tbt::HistSnapshot take() {
+    tbt::HistSnapshot out;
+    for (int i = 0; i < kBuckets; ++i) {
+      int64_t n = buckets_[i].exchange(0, std::memory_order_relaxed);
+      if (n > 0) {
+        out.buckets[i] = n;
+        out.count += n;
+      }
+    }
+    out.total = total_.exchange(0.0, std::memory_order_relaxed);
+    out.total_sq = total_sq_.exchange(0.0, std::memory_order_relaxed);
+    out.max = max_.exchange(0.0, std::memory_order_relaxed);
+    out.min = min_.exchange(kNone, std::memory_order_relaxed);
+    if (out.min == kNone) out.min = 0.0;
+    return out;
+  }
+
+ private:
+  static constexpr double kNone = std::numeric_limits<double>::infinity();
+
+  static void add(std::atomic<double>& cell, double value) {
+    double seen = cell.load(std::memory_order_relaxed);
+    while (!cell.compare_exchange_weak(seen, seen + value,
+                                       std::memory_order_relaxed)) {
+    }
+  }
+
+  std::atomic<int64_t> buckets_[kBuckets] = {};
+  std::atomic<double> total_{0.0}, total_sq_{0.0}, max_{0.0}, min_{kNone};
+};
+
+GilWaitHist gil_wait_hists[kGilSiteCount];
+
+// The wait that ended just now, asked for at `asked`.
+inline void gil_waited(GilSite site, GilClock::time_point asked) {
+  gil_wait_hists[site].observe(
+      std::chrono::duration<double>(GilClock::now() - asked).count());
+}
+
 // ------------------------------------------------- python -> C++ nest
 // Decref-under-GIL owner for buffers borrowed from numpy.
 std::shared_ptr<void> py_owner(PyObject* obj) {
   Py_INCREF(obj);
   return std::shared_ptr<void>(obj, [](void* p) {
+    auto asked = GilClock::now();
     PyGILState_STATE gil = PyGILState_Ensure();
+    gil_waited(kGilBufferRelease, asked);
     Py_DECREF(static_cast<PyObject*>(p));
     PyGILState_Release(gil);
   });
@@ -439,17 +540,21 @@ PyObject* value_to_py(const tbt::wire::ValueNest& nest) {
 // Run fn with the GIL released, catching C++ exceptions INSIDE the no-GIL
 // region (an exception unwinding past Py_END_ALLOW_THREADS would skip the
 // GIL re-acquire and corrupt the interpreter). Returns false with the
-// Python error set on failure.
+// Python error set on failure. The wait to take the GIL back is stamped
+// into `site`'s histogram.
 template <typename F>
-bool call_nogil(F&& fn) {
+bool call_nogil(GilSite site, F&& fn) {
   std::exception_ptr err;
+  GilClock::time_point asked;
   Py_BEGIN_ALLOW_THREADS
   try {
     fn();
   } catch (...) {
     err = std::current_exception();
   }
+  asked = GilClock::now();
   Py_END_ALLOW_THREADS
+  gil_waited(site, asked);
   if (err) {
     try {
       std::rethrow_exception(err);
@@ -579,7 +684,8 @@ PyObject* queue_enqueue(PyBatchingQueue* self, PyObject* arg) {
   ArrayNest nest;
   if (!nest_from_py(arg, &nest)) return nullptr;
   auto queue = self->queue;
-  if (!call_nogil([&] { queue->enqueue(std::move(nest), 0); }))
+  if (!call_nogil(kGilOther,
+                  [&] { queue->enqueue(std::move(nest), 0); }))
     return nullptr;
   Py_RETURN_NONE;
 }
@@ -587,7 +693,9 @@ PyObject* queue_enqueue(PyBatchingQueue* self, PyObject* arg) {
 PyObject* queue_dequeue_many(PyBatchingQueue* self, PyObject*) {
   std::pair<ArrayNest, std::vector<int>> result;
   auto queue = self->queue;
-  if (!call_nogil([&] { result = queue->dequeue_many(); })) return nullptr;
+  if (!call_nogil(kGilLearnerDequeue,
+                  [&] { result = queue->dequeue_many(); }))
+    return nullptr;
   PyObject* nest = nest_to_py(result.first);
   if (!nest) return nullptr;
   return Py_BuildValue("(Nn)", nest,
@@ -601,7 +709,9 @@ PyObject* queue_dequeue_many(PyBatchingQueue* self, PyObject*) {
 PyObject* queue_dequeue_item(PyBatchingQueue* self, PyObject*) {
   std::pair<ArrayNest, int64_t> result;
   auto queue = self->queue;
-  if (!call_nogil([&] { result = queue->dequeue_item(); })) return nullptr;
+  if (!call_nogil(kGilLearnerDequeue,
+                  [&] { result = queue->dequeue_item(); }))
+    return nullptr;
   PyObject* nest = nest_to_py(result.first);
   if (!nest) return nullptr;
   return Py_BuildValue("(NL)", nest,
@@ -651,7 +761,9 @@ PyObject* queue_iter(PyObject* self) {
 PyObject* queue_iternext(PyBatchingQueue* self) {
   std::pair<ArrayNest, std::vector<int>> result;
   auto queue = self->queue;
-  if (!call_nogil([&] { result = queue->dequeue_many(); })) return nullptr;
+  if (!call_nogil(kGilLearnerDequeue,
+                  [&] { result = queue->dequeue_many(); }))
+    return nullptr;
   return nest_to_py(result.first);
 }
 
@@ -803,7 +915,8 @@ PyObject* batcher_compute(PyDynamicBatcher* self, PyObject* arg) {
   if (!nest_from_py(arg, &nest)) return nullptr;
   ArrayNest result;
   auto batcher = self->batcher;
-  if (!call_nogil([&] { result = batcher->compute(std::move(nest)); }))
+  if (!call_nogil(kGilOther,
+                  [&] { result = batcher->compute(std::move(nest)); }))
     return nullptr;
   return nest_to_py(result);
 }
@@ -811,7 +924,8 @@ PyObject* batcher_compute(PyDynamicBatcher* self, PyObject* arg) {
 PyObject* batcher_iternext(PyDynamicBatcher* self) {
   std::unique_ptr<tbt::DynamicBatcher::Batch> batch;
   auto batcher = self->batcher;
-  if (!call_nogil([&] { batch = batcher->get_batch(); })) return nullptr;
+  if (!call_nogil(kGilBatcherNext, [&] { batch = batcher->get_batch(); }))
+    return nullptr;
   PyBatch* out =
       reinterpret_cast<PyBatch*>(PyBatchType.tp_alloc(&PyBatchType, 0));
   if (!out) return nullptr;
@@ -964,7 +1078,8 @@ PyObject* slice_router_compute(PySliceRouter* self, PyObject* arg) {
   if (!nest_from_py(arg, &nest)) return nullptr;
   ArrayNest result;
   auto router = self->router;
-  if (!call_nogil([&] { result = router->compute(std::move(nest)); }))
+  if (!call_nogil(kGilOther,
+                  [&] { result = router->compute(std::move(nest)); }))
     return nullptr;
   return nest_to_py(result);
 }
@@ -993,7 +1108,7 @@ PyObject* slice_router_n_slices(PySliceRouter* self, PyObject*) {
 
 PyObject* slice_router_close(PySliceRouter* self, PyObject*) {
   auto router = self->router;
-  if (!call_nogil([&] { router->close(); })) return nullptr;
+  if (!call_nogil(kGilOther, [&] { router->close(); })) return nullptr;
   Py_RETURN_NONE;
 }
 
@@ -1066,7 +1181,8 @@ PyObject* replica_router_compute(PyReplicaRouter* self, PyObject* arg) {
   if (!nest_from_py(arg, &nest)) return nullptr;
   ArrayNest result;
   auto router = self->router;
-  if (!call_nogil([&] { result = router->compute(std::move(nest)); }))
+  if (!call_nogil(kGilOther,
+                  [&] { result = router->compute(std::move(nest)); }))
     return nullptr;
   return nest_to_py(result);
 }
@@ -1092,7 +1208,7 @@ PyObject* replica_router_telemetry(PyReplicaRouter* self, PyObject*) {
 
 PyObject* replica_router_close(PyReplicaRouter* self, PyObject*) {
   auto router = self->router;
-  if (!call_nogil([&] { router->close(); })) return nullptr;
+  if (!call_nogil(kGilOther, [&] { router->close(); })) return nullptr;
   Py_RETURN_NONE;
 }
 
@@ -1152,7 +1268,9 @@ PyTypeObject PyReplicaRouterType = {
 
 tbt::ActorPool::SlotHook make_slot_reset(std::shared_ptr<void> table_ref) {
   return [table_ref](int64_t slot) -> ArrayNest {
+    auto asked = GilClock::now();
     PyGILState_STATE gil = PyGILState_Ensure();
+    gil_waited(kGilSlotHook, asked);
     ArrayNest out;
     try {
       PyObject* table = static_cast<PyObject*>(table_ref.get());
@@ -1181,7 +1299,9 @@ tbt::ActorPool::SlotHook make_slot_reset(std::shared_ptr<void> table_ref) {
 
 tbt::ActorPool::SlotHook make_slot_read(std::shared_ptr<void> table_ref) {
   return [table_ref](int64_t slot) -> ArrayNest {
+    auto asked = GilClock::now();
     PyGILState_STATE gil = PyGILState_Ensure();
+    gil_waited(kGilSlotHook, asked);
     ArrayNest out;
     try {
       PyObject* table = static_cast<PyObject*>(table_ref.get());
@@ -1295,7 +1415,7 @@ int pool_init(PyActorPool* self, PyObject* args, PyObject* kwargs) {
 
 PyObject* pool_run(PyActorPool* self, PyObject*) {
   auto pool = self->pool;
-  if (!call_nogil([&] { pool->run(); })) return nullptr;
+  if (!call_nogil(kGilOther, [&] { pool->run(); })) return nullptr;
   Py_RETURN_NONE;
 }
 
@@ -1350,7 +1470,8 @@ PyObject* pool_chaos_sever(PyActorPool* self, PyObject* arg) {
   tbt::FaultHooks* hooks = pool_hooks_or_raise(self);
   if (!hooks) return nullptr;
   bool ok = false;
-  if (!call_nogil([&] { ok = hooks->sever(actor); })) return nullptr;
+  if (!call_nogil(kGilOther, [&] { ok = hooks->sever(actor); }))
+    return nullptr;
   return PyBool_FromLong(ok);
 }
 
@@ -1377,9 +1498,9 @@ PyObject* pool_chaos_window(PyActorPool* self, PyObject* args,
   tbt::FaultHooks* hooks = pool_hooks_or_raise(self);
   if (!hooks) return nullptr;
   bool ok = false;
-  if (!call_nogil(
-          [&] { ok = hooks->arm_window(actor, is_delay, duration_s,
-                                       delay_s); }))
+  if (!call_nogil(kGilOther, [&] {
+        ok = hooks->arm_window(actor, is_delay, duration_s, delay_s);
+      }))
     return nullptr;
   return PyBool_FromLong(ok);
 }
@@ -1396,8 +1517,9 @@ PyObject* pool_chaos_corrupt_ring(PyActorPool* self, PyObject* args,
   tbt::FaultHooks* hooks = pool_hooks_or_raise(self);
   if (!hooks) return nullptr;
   bool ok = false;
-  if (!call_nogil(
-          [&] { ok = hooks->corrupt_recv_ring(actor, header != 0); }))
+  if (!call_nogil(kGilOther, [&] {
+        ok = hooks->corrupt_recv_ring(actor, header != 0);
+      }))
     return nullptr;
   return PyBool_FromLong(ok);
 }
@@ -1495,7 +1617,11 @@ namespace wire = tbt::wire;
 // RAII GIL for hook bodies running on C++ server threads.
 struct GILGuard {
   PyGILState_STATE state;
-  GILGuard() : state(PyGILState_Ensure()) {}
+  GILGuard() {
+    auto asked = GilClock::now();
+    state = PyGILState_Ensure();
+    gil_waited(kGilEnvHook, asked);
+  }
   ~GILGuard() { PyGILState_Release(state); }
 };
 
@@ -1733,16 +1859,17 @@ int env_server_init(PyEnvServer* self, PyObject* args, PyObject* kwargs) {
 
 PyObject* env_server_run(PyEnvServer* self, PyObject*) {
   auto server = self->server;
-  if (!call_nogil([&] { server->run(); })) return nullptr;
+  if (!call_nogil(kGilOther, [&] { server->run(); })) return nullptr;
   // run() returns after stop(); make sure stream threads are gone before
   // the caller proceeds to tear anything down.
-  if (!call_nogil([&] { server->join_all(); })) return nullptr;
+  if (!call_nogil(kGilOther, [&] { server->join_all(); }))
+    return nullptr;
   Py_RETURN_NONE;
 }
 
 PyObject* env_server_stop(PyEnvServer* self, PyObject*) {
   auto server = self->server;
-  if (!call_nogil([&] { server->stop(); })) return nullptr;
+  if (!call_nogil(kGilOther, [&] { server->stop(); })) return nullptr;
   Py_RETURN_NONE;
 }
 
@@ -1750,7 +1877,7 @@ void env_server_dealloc(PyEnvServer* self) {
   // EnvServer's destructor stops and JOINS stream threads, whose hooks
   // take the GIL — joining while holding it would deadlock.
   auto release = [&] { self->server.reset(); };
-  if (self->server) call_nogil(release);
+  if (self->server) call_nogil(kGilOther, release);
   self->server.~shared_ptr();
   Py_XDECREF(self->env_init);
   Py_TYPE(self)->tp_free(reinterpret_cast<PyObject*>(self));
@@ -1832,7 +1959,7 @@ PyObject* py_bench_client_rtt(PyObject*, PyObject* args, PyObject* kwargs) {
     return nullptr;
   long long iters = 0;
   double elapsed = 0.0;
-  bool ok = call_nogil([&] {
+  bool ok = call_nogil(kGilOther, [&] {
     auto t = tbt::shm::connect_transport(address, 30.0);
     t->recv();  // initial step
     tbt::wire::ValueNest::Dict action;
@@ -1924,8 +2051,29 @@ PyObject* py_slice_for_slot(PyObject*, PyObject* args, PyObject* kwargs) {
   }
 }
 
+// Interval aggregates (reset on read) of the wait for the GIL at each
+// site, keyed by site name: {"batcher_next": {...}, ...}; every site is
+// there, sampled or not.
+PyObject* py_gil_wait_histograms(PyObject*, PyObject*) {
+  PyObject* out = PyDict_New();
+  if (!out) return nullptr;
+  for (int site = 0; site < kGilSiteCount; ++site) {
+    PyObject* hist = hist_to_py(gil_wait_hists[site].take());
+    if (!hist || PyDict_SetItemString(out, kGilSiteNames[site], hist) < 0) {
+      Py_XDECREF(hist);
+      Py_DECREF(out);
+      return nullptr;
+    }
+    Py_DECREF(hist);
+  }
+  return out;
+}
+
 // ---------------------------------------------------------------- module
 PyMethodDef module_functions[] = {
+    {"gil_wait_histograms",
+     reinterpret_cast<PyCFunction>(py_gil_wait_histograms), METH_NOARGS,
+     nullptr},
     {"wire_encode", reinterpret_cast<PyCFunction>(py_wire_encode), METH_O,
      nullptr},
     {"wire_decode", reinterpret_cast<PyCFunction>(py_wire_decode), METH_O,
@@ -2075,9 +2223,9 @@ PyMODINIT_FUNC PyInit__tbt_core(void) {
   // Extension API generation (runtime/native.py REQUIRED_API_VERSION):
   // 1 = the ISSUE 14 shed protocol; 2 = the ISSUE 16 serving plane
   // (routers, continuous batching, record_policy_lag); 3 = ISSUE 25's
-  // ActorPool.stage_histograms. The default-on native runtime refuses
-  // stale builds instead of silently serving central-only without
-  // admission control.
-  PyModule_AddIntConstant(module, "API_VERSION", 3);
+  // ActorPool.stage_histograms; 4 = ISSUE 36's gil_wait_histograms.
+  // The default-on native runtime refuses stale builds instead of
+  // silently serving central-only without admission control.
+  PyModule_AddIntConstant(module, "API_VERSION", 4);
   return module;
 }

@@ -36,10 +36,10 @@ def time_limit():
     longer than this."""
 
     def expired(signum, frame):
-        raise TimeoutError("test exceeded its 60 s limit")
+        raise TimeoutError("test exceeded its 120 s limit")
 
     previous = signal.signal(signal.SIGALRM, expired)
-    signal.alarm(60)
+    signal.alarm(120)
     try:
         yield
     finally:
@@ -82,7 +82,7 @@ def _host_probe_server(address):
         server.stop()
 
 
-def _wait(condition, what, timeout_s=20.0):
+def _wait(condition, what, timeout_s=45.0):
     deadline = time.monotonic() + timeout_s
     while not condition():
         if time.monotonic() > deadline:
@@ -287,8 +287,17 @@ def test_a_stopped_server_leaves_nothing_behind(how):
             if how == "sigterm_group":
                 targets += sorted(children)
             for pid in targets:
-                os.kill(pid, signal.SIGTERM)
-        proc.join(15)
+                try:
+                    os.kill(pid, signal.SIGTERM)
+                except ProcessLookupError:
+                    # The listener got its signal first and, on a host
+                    # that keeps this process waiting between two
+                    # kills, has ended and collected this child
+                    # already (D16); `kill_group` passes over it too.
+                    assert pid != proc.pid
+        # Long enough for a host that six test workers load: the
+        # listener's own teardown is at most 4 s of grace.
+        proc.join(45)
         assert not proc.is_alive() and proc.exitcode == 0
         for pid in children:
             _wait(lambda pid=pid: _gone(pid), f"stream process {pid} to go")

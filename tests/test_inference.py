@@ -245,10 +245,8 @@ def test_launcher_dispatches_batch_2_before_reply_1_is_released():
     producers.join()
     assert sorted(producers.results) == [1, 2] and not producers.errors
     assert serving.counter("batches") == 2
-    in_flight = telemetry.get_registry().histogram(
-        "overlap.replies_in_flight"
-    ).merged()
-    assert (in_flight.count, in_flight.total) == (2, 1)
+    # One of the two launches found a reply outstanding, not both.
+    assert serving.counter("overlapped_dispatches") == 1
     batcher.close()
     serving.join()
 

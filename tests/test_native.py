@@ -851,6 +851,174 @@ def test_native_telemetry_fold():
 
 
 # ---------------------------------------------------------------------------
+# The wait for the GIL, stamped where _tbt_core takes it (ISSUE 36).
+
+GIL_SITES = {
+    "batcher_next", "get_inputs", "set_outputs", "learner_dequeue",
+    "slot_hook", "buffer_release", "env_hook", "other",
+}
+
+
+def test_gil_wait_histograms_name_every_site():
+    snaps = core.gil_wait_histograms()
+    assert set(snaps) == GIL_SITES
+    for snap in snaps.values():
+        assert set(snap) == {
+            "count", "total", "total_sq", "min", "max", "buckets",
+        }
+    # Reset on read: nothing crossed since.
+    assert all(s["count"] == 0 for s in core.gil_wait_histograms().values())
+
+
+def test_an_uncontended_crossing_waits_microseconds():
+    from torchbeast_tpu.telemetry.metrics import bucket_index
+
+    queue = core.BatchingQueue(batch_dim=0, minimum_batch_size=1)
+    core.gil_wait_histograms()
+    for _ in range(50):
+        queue.enqueue(np.zeros((1, 2), np.float32))
+        queue.dequeue_many()
+    snaps = core.gil_wait_histograms()
+    dequeue, enqueue = snaps["learner_dequeue"], snaps["other"]
+    assert dequeue["count"] == 50 and enqueue["count"] >= 50
+    assert sum(dequeue["buckets"].values()) == 50
+    # Some other test's sleeping thread may wake once; most crossings
+    # find the lock free.
+    assert 0.0 <= dequeue["min"] < 100e-6
+    assert dequeue["min"] <= dequeue["total"] / 50 <= dequeue["max"]
+    # The registry's own bucket geometry, sample for sample.
+    assert bucket_index(dequeue["max"]) == max(dequeue["buckets"])
+    assert bucket_index(dequeue["min"]) == min(dequeue["buckets"])
+    queue.close()
+
+
+def test_a_crossing_waits_for_a_thread_that_holds_the_lock():
+    """One thread sits in dequeue_many with the GIL dropped until the
+    queue's own timeout hands it the short batch; no other thread
+    touches Python then but this one, in a pure-Python loop, which
+    gives the GIL up only at the interpreter's switch interval. The
+    crossing asks for the lock twice: inside the GIL-free call, to let
+    go of the numpy buffer the item borrowed (`buffer_release`), and at
+    the call's end (`learner_dequeue`). Whichever comes while the loop
+    holds the lock waits; the other finds it just handed over."""
+    import sys
+
+    queue = core.BatchingQueue(
+        batch_dim=0, minimum_batch_size=2, timeout_ms=200
+    )
+    queue.enqueue(np.zeros((1, 2), np.float32))
+    crossed = threading.Event()
+
+    def cross():
+        queue.dequeue_many()  # one row of two: back at the timeout
+        crossed.set()
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(0.02)
+    try:
+        crosser = threading.Thread(target=cross, daemon=True)
+        crosser.start()
+        time.sleep(0.05)  # the crosser is inside dequeue_many
+        core.gil_wait_histograms()
+        deadline = time.monotonic() + 10.0
+        spins = 0
+        while not crossed.is_set() and time.monotonic() < deadline:
+            spins += 1  # holds the GIL but for forced switches
+    finally:
+        sys.setswitchinterval(interval)
+    crosser.join(5)
+    assert crossed.is_set()
+    snaps = core.gil_wait_histograms()
+    assert snaps["learner_dequeue"]["count"] == 1
+    assert snaps["buffer_release"]["count"] == 1
+    waited = max(snaps[s]["max"] for s in ("learner_dequeue", "buffer_release"))
+    assert 1e-3 <= waited < 5.0
+    queue.close()
+
+
+def test_the_folder_folds_gil_waits_and_the_thread_ledger():
+    from torchbeast_tpu.runtime.native import NativeTelemetryFolder
+    from torchbeast_tpu.telemetry.metrics import MetricsRegistry
+
+    class Ledger:
+        folds = 0
+        asked = None
+
+        def fold(self, min_interval_s=0.0):
+            self.folds += 1
+            self.asked = min_interval_s
+
+    queue = core.BatchingQueue(batch_dim=0, minimum_batch_size=1)
+    registry, ledger = MetricsRegistry(), Ledger()
+    folder = NativeTelemetryFolder(registry, queue=queue, ledger=ledger)
+    names = {f"host.gil_wait_s.{site}" for site in GIL_SITES}
+    assert names <= set(registry.instruments())
+    queue.enqueue(np.zeros((1, 2), np.float32))
+    queue.dequeue_many()
+    folder.tick()
+    assert ledger.folds == 1
+    dequeue = registry.histogram("host.gil_wait_s.learner_dequeue")
+    assert dequeue.count == 1 and 0.0 <= dequeue.mean < 1.0
+    # A site that never drops the lock holds no sample and reads 0.
+    held = registry.histogram("host.gil_wait_s.set_outputs").merged()
+    assert (held.count, held.total) == (0, 0.0)
+    assert ledger.asked == 0.0  # called by name: the account as of now
+    # The driver's periodic tick leaves the ledger its period.
+    folder.tick(ledger_min_interval_s=30.0)
+    assert dequeue.count == 1  # interval semantics: nothing twice
+    assert (ledger.folds, ledger.asked) == (2, 30.0)
+    # A folder with no native source (the fleet fold alone) leaves the
+    # extension's one set of stamps to the folder that has one.
+    bare = MetricsRegistry()
+    NativeTelemetryFolder(bare).tick()
+    assert not [n for n in bare.instruments() if "gil_wait" in n]
+    queue.close()
+
+
+def test_actor_threads_carry_their_name_in_the_kernel():
+    """pthread_setname_np where the pool starts its loops: the thread
+    ledger's role `actors` goes by this `comm`."""
+    from torchbeast_tpu.telemetry.heartbeat import thread_role
+
+    queue = core.BatchingQueue(batch_dim=0, minimum_batch_size=1)
+    batcher = core.DynamicBatcher(batch_dim=0)
+    pool = core.ActorPool(
+        unroll_length=2, learner_queue=queue, inference_batcher=batcher,
+        # Nobody listens: the loops dial until the timeout.
+        env_server_addresses=[f"unix:{tempfile.mkdtemp()}/none"] * 2,
+        initial_agent_state=(), connect_timeout_s=1.0, max_reconnects=0,
+    )
+
+    def run():
+        try:
+            pool.run()
+        except RuntimeError:  # WaitForConnected() timed out
+            pass
+
+    runner = threading.Thread(target=run, daemon=True)
+    runner.start()
+    mine = {t.native_id for t in threading.enumerate()}
+    deadline = time.monotonic() + 5.0
+    named = []
+    while len(named) < 2 and time.monotonic() < deadline:
+        named = []
+        for tid in os.listdir("/proc/self/task"):
+            try:
+                with open(f"/proc/self/task/{tid}/comm") as f:
+                    comm = f.read().strip()
+            except OSError:
+                continue
+            if comm == "tbt-actor":
+                named.append(int(tid))
+        time.sleep(0.01)
+    batcher.close()
+    queue.close()
+    runner.join(10)
+    assert len(named) == 2 and not set(named) & mine
+    assert thread_role(None, "tbt-actor") == "actors"
+
+
+# ---------------------------------------------------------------------------
 # Adaptive doorbell recheck (ISSUE 12): the C++ policy pinned through the
 # sim binding, and pinned BEHAVIORALLY against the Python policy (beastlint
 # ATOMIC-ORDER pins the constants; this pins the walk).

@@ -10,12 +10,14 @@ import os
 import subprocess
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
 
 from torchbeast_tpu import telemetry
 from torchbeast_tpu.telemetry import export as export_mod
+from torchbeast_tpu.telemetry import trace as trace_mod
 from torchbeast_tpu.telemetry.metrics import (
     BUCKET_GROWTH,
     MetricsRegistry,
@@ -314,6 +316,461 @@ class TestSpans:
         events = tr.events()
         assert len(events) == 10
         assert events[0]["name"] == "e90"  # oldest dropped
+
+
+class TestSpanThreadCpu:
+    """Every span observes `<name>_cpu_s`, the calling thread's own
+    CPU clock, beside `<name>_s` (ISSUE 36): read in one pass of
+    CPU_SAMPLE_EVERY, credited that many times over."""
+
+    EVERY = trace_mod.CPU_SAMPLE_EVERY
+
+    @staticmethod
+    def _span(name):
+        reg = MetricsRegistry()
+        tr = Tracer(registry=reg, record=False)
+        return tr.span(name), reg
+
+    def test_a_sleep_costs_wall_and_no_cpu(self):
+        span, reg = self._span("stage.sleep")
+        for _ in range(self.EVERY):
+            with span:
+                time.sleep(0.02)
+        wall = reg.histogram("stage.sleep_s").merged()
+        cpu = reg.histogram("stage.sleep_cpu_s").merged()
+        assert wall.count == cpu.count == self.EVERY
+        assert 0.02 * self.EVERY <= wall.total < 0.5 * self.EVERY
+        assert cpu.total < 0.005 * self.EVERY
+
+    def test_a_spin_costs_as_much_cpu_as_wall(self):
+        """The pass whose CPU is read spins until the thread's own
+        clock has moved 20 ms: the span reads those 20 ms, however long
+        a loaded host made the pass last, and never more than its
+        wall time; on a host with a core to spare the two agree."""
+        span, reg = self._span("stage.spin")
+        wall_h = reg.histogram("stage.spin_s")
+        cpu_h = reg.histogram("stage.spin_cpu_s")
+        ratios = []
+        for _ in range(3 * self.EVERY):
+            wall0, cpu0 = wall_h.merged().total, cpu_h.merged()
+            with span:
+                end = time.thread_time() + 0.02
+                while time.thread_time() < end:
+                    pass
+            cpu1 = cpu_h.merged()
+            if cpu1.count == cpu0.count:
+                continue  # not a pass that reads the clock
+            assert cpu1.count == cpu0.count + self.EVERY
+            read = (cpu1.total - cpu0.total) / self.EVERY
+            wall = wall_h.merged().total - wall0
+            assert 0.02 <= read < 0.03 and read <= wall + 1e-3
+            ratios.append(read / wall)
+        assert len(ratios) == 3
+        # Five other test workers may share this host's cores: only
+        # with one to spare does a spin's wall time equal its CPU time.
+        if os.getloadavg()[0] < len(os.sched_getaffinity(0)) - 1:
+            assert max(ratios) >= 0.8, ratios
+
+    def test_one_pass_in_sixteen_is_read_and_stands_for_sixteen(self):
+        span, reg = self._span("stage.pass")
+        for _ in range(3 * self.EVERY):
+            with span:
+                pass
+        wall = reg.histogram("stage.pass_s").merged()
+        cpu = reg.histogram("stage.pass_cpu_s").merged()
+        assert wall.count == cpu.count == 3 * self.EVERY
+        assert all(n % self.EVERY == 0 for n in cpu.buckets.values())
+        assert cpu.min <= cpu.total / cpu.count <= cpu.max < 0.01
+        # The span counts its passes, whichever thread makes them: a
+        # native actor thread's Python state lasts one slot hook, so
+        # here every pass is a new thread's first.
+        def one_pass():
+            with span:
+                pass
+
+        for _ in range(self.EVERY):
+            other = threading.Thread(target=one_pass)
+            other.start()
+            other.join()
+        assert reg.histogram("stage.pass_cpu_s").count == 4 * self.EVERY
+
+    def test_a_nested_span_and_its_parent_each_read_their_own_clock(self):
+        reg = MetricsRegistry()
+        tr = Tracer(registry=reg, record=False)
+        outer, inner = tr.span("stage.outer"), tr.span("stage.inner")
+        # Made one after the other, they read the clock on different
+        # passes: the parent's reading holds no child's clock reads.
+        assert outer._passes != inner._passes
+        for _ in range(self.EVERY):
+            with outer:
+                with inner:
+                    time.sleep(0.01)
+                end = time.thread_time() + 0.01
+                while time.thread_time() < end:
+                    pass
+        outer_cpu = reg.histogram("stage.outer_cpu_s").merged()
+        inner_cpu = reg.histogram("stage.inner_cpu_s").merged()
+        assert outer_cpu.count == inner_cpu.count == self.EVERY
+        assert inner_cpu.total / self.EVERY < 0.005
+        assert 0.01 <= outer_cpu.total / self.EVERY < 0.02
+        assert reg.histogram("stage.outer_s").merged().total >= (
+            0.02 * self.EVERY
+        )
+
+    def test_a_disabled_tracer_observes_neither(self):
+        tr = telemetry.get_tracer()
+        span = tr.span("gate_test.cpu_span")
+        reg = telemetry.get_registry()
+        wall = reg.histogram("gate_test.cpu_span_s")
+        cpu = reg.histogram("gate_test.cpu_span_cpu_s")
+        telemetry.set_enabled(False)
+        try:
+            for _ in range(self.EVERY):
+                with span:
+                    pass
+            assert (wall.count, cpu.count) == (0, 0)
+        finally:
+            telemetry.set_enabled(True)
+        for _ in range(self.EVERY):
+            with span:
+                pass
+        assert (wall.count, cpu.count) == (self.EVERY, self.EVERY)
+
+    def test_a_given_histogram_keeps_its_name_and_gains_the_cpu_one(self):
+        """utils/prof.Timings hands its sections' older names in."""
+        reg = MetricsRegistry()
+        tr = Tracer(registry=reg, record=False)
+        span = tr.span(
+            "learner.learn", histogram=reg.histogram("learner.learn")
+        )
+        for _ in range(self.EVERY):
+            with span:
+                pass
+        assert reg.histogram("learner.learn").count == self.EVERY
+        assert reg.histogram("learner.learn_cpu_s").count == self.EVERY
+        assert "learner.learn_s" not in reg.instruments()
+        # A tracer without a registry observes nothing of its own.
+        bare = Tracer(record=False).span("x.y")
+        assert bare.histogram is None and bare.cpu_histogram is None
+        with bare:
+            pass
+
+
+# /proc as a thread ledger reads it, recorded: six tasks of process
+# 4242 (clock ticks of 10 ms; schedstat: on-CPU ns, run-queue ns,
+# timeslices) and an env-server listener 500 with one forked child.
+_STAT = (
+    "{tid} ({comm}) S 1 4242 4242 0 -1 4194560 1 0 0 0 {utime} {stime} "
+    "0 0 20 0 6 0 100 1000000 100 18446744073709551615 0 0 0 0 0 0 0 0 "
+    "0 0 0 0 17 3 0 0 0 0 0 0 0 0 0 0 0 0 0\n"
+)
+# tid: (python name or None, comm, utime, stime, on_cpu_ns, wait_ns)
+_TASKS_T0 = {
+    4242: ("MainThread", "python3", 150, 50, 2_000_000_000, 40_000_000),
+    4250: ("inference-0", "python3", 300, 100, 4_000_000_000, 900_000_000),
+    4251: ("inference-0-replier", "python3", 200, 50, 2_500_000_000,
+           600_000_000),
+    4252: ("learner", "python3", 90, 10, 1_000_000_000, 10_000_000),
+    4260: (None, "tbt-actor", 40, 20, 600_000_000, 300_000_000),
+    4270: (None, "tf_XLATfrtCpuCl", 10, 0, 100_000_000, 5_000_000),
+}
+# 2.5 s later: the launcher ran 1.5 s and waited 0.5 s for a core, the
+# replier 0.75 / 0.25, the actor 0.1 / 0.2; the learner thread is gone
+# and tid 4280 (a new prefetch thread, 0.3 s of CPU) has appeared.
+_TASKS_T1 = {
+    4242: ("MainThread", "python3", 160, 50, 2_100_000_000, 45_000_000),
+    4250: ("inference-0", "python3", 420, 130, 5_500_000_000,
+           1_400_000_000),
+    4251: ("inference-0-replier", "python3", 260, 65, 3_250_000_000,
+           850_000_000),
+    4260: (None, "tbt-actor", 45, 25, 700_000_000, 500_000_000),
+    4270: (None, "tf_XLATfrtCpuCl", 10, 0, 100_000_000, 5_000_000),
+    4280: ("device-prefetch", "python3", 25, 5, 300_000_000, 1_000_000),
+}
+
+
+class _FakeThread:
+    def __init__(self, native_id, name):
+        self.native_id, self.name = native_id, name
+
+
+def _write_proc(root, pid, tasks, schedstat=True, children=None):
+    task_dir = root / str(pid) / "task"
+    if task_dir.exists():
+        import shutil
+
+        shutil.rmtree(task_dir)
+    for tid, (_, comm, utime, stime, on_cpu, waited) in tasks.items():
+        d = task_dir / str(tid)
+        d.mkdir(parents=True)
+        text = _STAT.format(tid=tid, comm=comm, utime=utime, stime=stime)
+        (d / "stat").write_text(text)
+        (d / "comm").write_text(comm + "\n")
+        if schedstat:
+            (d / "schedstat").write_text(f"{on_cpu} {waited} 1234\n")
+        if children is not None:
+            listed = children.get(tid, [])
+            (d / "children").write_text(" ".join(map(str, listed)))
+    (root / str(pid) / "stat").write_text(
+        _STAT.format(tid=pid, comm="python3", utime=0, stime=0)
+    )
+
+
+def _threads_of(tasks):
+    return lambda: [
+        _FakeThread(tid, name) for tid, (name, *_) in tasks.items()
+        if name is not None
+    ]
+
+
+class TestThreadLedger:
+    """telemetry/heartbeat.py ThreadLedger on recorded /proc text."""
+
+    def _counters(self, reg):
+        return {
+            name: inst.value() for name, inst in reg.instruments().items()
+        }
+
+    def test_roles(self):
+        from torchbeast_tpu.telemetry.heartbeat import thread_role
+
+        assert thread_role("inference-0", "python3") == "launcher"
+        assert thread_role("inference-0-replier", "python3") == "replier"
+        assert thread_role("inference.slice.1-0-replier", "x") == "replier"
+        assert thread_role("learner", "python3") == "learner"
+        assert thread_role("learner-watchdog", "python3") == "python_other"
+        assert thread_role("device-prefetch", "python3") == "prefetch"
+        assert thread_role("MainThread", "python3") == "python_other"
+        assert thread_role("telemetry-heartbeat", "x") == "python_other"
+        assert thread_role(None, "tbt-actor") == "actors"
+        assert thread_role(None, "tf_XLATfrtCpuCl") == "native_other"
+
+    def test_deltas_by_role_between_two_samples(self, tmp_path):
+        from torchbeast_tpu.telemetry.heartbeat import ThreadLedger
+
+        state = {"tasks": _TASKS_T0}
+        _write_proc(tmp_path, 4242, _TASKS_T0, children={})
+        reg = MetricsRegistry()
+        ledger = ThreadLedger(
+            reg, proc_root=str(tmp_path), pid=4242,
+            threads=lambda: _threads_of(state["tasks"])(),
+        )
+        assert ledger.has_schedstat
+        ledger.fold()
+        first = self._counters(reg)
+        # The first fold credits all a task has used since it began.
+        assert first["host.cpu_s.launcher"] == pytest.approx(4.0)
+        assert first["host.cpu_s.learner"] == pytest.approx(1.0)
+        assert first["host.cpu_s.prefetch"] == 0.0
+        assert first["host.run_delay_s.replier"] == pytest.approx(0.6)
+
+        state["tasks"] = _TASKS_T1
+        _write_proc(tmp_path, 4242, _TASKS_T1, children={})
+        ledger.fold()
+        now = self._counters(reg)
+        grew = {k: now[k] - first[k] for k in now}
+        want = {
+            "host.cpu_s.launcher": 1.5, "host.run_delay_s.launcher": 0.5,
+            "host.cpu_s.replier": 0.75, "host.run_delay_s.replier": 0.25,
+            "host.cpu_s.actors": 0.1, "host.run_delay_s.actors": 0.2,
+            "host.cpu_s.python_other": 0.1,
+            "host.run_delay_s.python_other": 0.005,
+            # Idle between the samples.
+            "host.cpu_s.native_other": 0.0,
+            "host.run_delay_s.native_other": 0.0,
+            # New since the last fold: all it has used.
+            "host.cpu_s.prefetch": 0.3,
+            "host.run_delay_s.prefetch": 0.001,
+            # Gone between the samples: nothing more, and no error.
+            "host.cpu_s.learner": 0.0, "host.run_delay_s.learner": 0.0,
+        }
+        assert set(grew) == set(want)
+        for name, value in want.items():
+            assert grew[name] == pytest.approx(value, abs=1e-9), name
+
+    def test_a_fold_too_soon_after_another_is_skipped(self, tmp_path):
+        from torchbeast_tpu.telemetry.heartbeat import ThreadLedger
+
+        _write_proc(tmp_path, 4242, _TASKS_T0, children={})
+        reg = MetricsRegistry()
+        ledger = ThreadLedger(
+            reg, proc_root=str(tmp_path), pid=4242,
+            threads=_threads_of(_TASKS_T1),
+        )
+        ledger.fold()
+        _write_proc(tmp_path, 4242, _TASKS_T1, children={})
+        ledger.fold(min_interval_s=60.0)  # DriverTelemetry.write's way
+        assert reg.counter("host.cpu_s.launcher").value() == (
+            pytest.approx(4.0)
+        )
+        ledger.fold()  # the native folder's: always
+        assert reg.counter("host.cpu_s.launcher").value() == (
+            pytest.approx(5.5)
+        )
+
+    def test_a_kernel_without_schedstat(self, tmp_path):
+        """CPU comes from stat's utime + stime, in clock ticks; the
+        run-delay counters are not registered at all."""
+        from torchbeast_tpu.telemetry.heartbeat import ThreadLedger
+
+        tick = 1.0 / os.sysconf("SC_CLK_TCK")
+        state = {"tasks": _TASKS_T0}
+        _write_proc(tmp_path, 4242, _TASKS_T0, schedstat=False, children={})
+        reg = MetricsRegistry()
+        ledger = ThreadLedger(
+            reg, proc_root=str(tmp_path), pid=4242,
+            threads=lambda: _threads_of(state["tasks"])(),
+        )
+        assert not ledger.has_schedstat
+        ledger.watch(lambda: [])
+        ledger.fold()
+        state["tasks"] = _TASKS_T1
+        _write_proc(tmp_path, 4242, _TASKS_T1, schedstat=False, children={})
+        ledger.fold()
+        counters = self._counters(reg)
+        assert not [k for k in counters if "run_delay" in k]
+        assert counters["host.cpu_s.launcher"] == pytest.approx(550 * tick)
+        assert counters["host.cpu_s.replier"] == pytest.approx(325 * tick)
+        assert counters["host.cpu_s.actors"] == pytest.approx(70 * tick)
+        assert counters["host.cpu_s.native_other"] == pytest.approx(
+            10 * tick
+        )
+        assert counters["host.cpu_s.env_servers"] == 0.0
+
+    @pytest.mark.parametrize("children_file", [True, False])
+    def test_watched_processes_and_what_they_forked(
+        self, tmp_path, children_file
+    ):
+        """The env servers: a listener (500) and the stream child it
+        forked (501, which has a thread of its own), found through
+        task/<tid>/children or, on a kernel without it, by ppid."""
+        from torchbeast_tpu.telemetry.heartbeat import ThreadLedger
+
+        def proc(pid, ppid, tasks, children):
+            _write_proc(
+                tmp_path, pid, tasks,
+                children=children if children_file else None,
+            )
+            stat = _STAT.format(tid=pid, comm="python3", utime=0, stime=0)
+            (tmp_path / str(pid) / "stat").write_text(
+                stat.replace(" S 1 ", f" S {ppid} ", 1)
+            )
+
+        me = {4242: ("MainThread", "python3", 0, 0, 0, 0)}
+        idle = ("x", "python3", 1, 0, 10_000_000, 1_000_000)
+        proc(4242, 1, me, {})
+        proc(500, 4242, {500: idle}, {500: [501]})
+        proc(501, 500, {
+            501: ("x", "python3", 100, 20, 1_200_000_000, 400_000_000),
+            502: ("x", "python3", 5, 0, 50_000_000, 0),
+        }, {})
+        # Not the supervisor's, nobody's child here: never counted.
+        proc(900, 1, {900: ("x", "python3", 999, 0, 9_000_000_000, 0)}, {})
+        reg = MetricsRegistry()
+        ledger = ThreadLedger(
+            reg, proc_root=str(tmp_path), pid=4242, threads=_threads_of(me),
+        )
+        ledger.watch(lambda: [500, None, 777])  # unstarted, and gone
+        ledger.fold()
+        assert reg.counter("host.cpu_s.env_servers").value() == (
+            pytest.approx(1.26)
+        )
+        assert reg.counter("host.run_delay_s.env_servers").value() == (
+            pytest.approx(0.401)
+        )
+        # The child ends; the listener goes on.
+        import shutil
+
+        shutil.rmtree(tmp_path / "501")
+        proc(500, 4242, {500: ("x", "python3", 2, 0, 20_000_000, 1_000_000)},
+             {500: []})
+        ledger.fold()
+        assert reg.counter("host.cpu_s.env_servers").value() == (
+            pytest.approx(1.27)
+        )
+
+    def test_on_this_process(self):
+        """The live /proc: a thread that spins is credited with about
+        what it spun, under the role its name gives it."""
+        from torchbeast_tpu.telemetry.heartbeat import ThreadLedger
+
+        reg = MetricsRegistry()
+        ledger = ThreadLedger(reg)
+        stop = threading.Event()
+
+        def spin():
+            while not stop.is_set():
+                pass
+
+        thread = threading.Thread(target=spin, name="learner", daemon=True)
+        thread.start()
+        try:
+            ledger.fold()
+            before = reg.counter("host.cpu_s.learner").value()
+            used, began = time.thread_time(), time.monotonic()
+            time.sleep(0.3)
+            ledger.fold()
+            lasted = time.monotonic() - began
+        finally:
+            stop.set()
+            thread.join()
+        grew = reg.counter("host.cpu_s.learner").value() - before
+        # It ran whenever this thread slept (and shared the GIL with
+        # nobody else): a loaded host may have kept it off a core, or
+        # this thread asleep for longer than it asked.
+        assert 0.02 <= grew <= lasted + 0.05
+        assert time.thread_time() - used < 0.1
+        assert reg.counter("host.cpu_s.python_other").value() > 0.0
+
+
+    def test_a_native_thread_that_ran_python_keeps_its_native_role(self):
+        """A foreign thread that has run Python code (an actor thread inside
+        a slot hook) stands in threading.enumerate() as a _DummyThread; the
+        ledger goes by its `comm` all the same."""
+        import ctypes
+
+        from torchbeast_tpu.telemetry.heartbeat import ThreadLedger
+        seen = {}
+        release = threading.Event()
+
+        @ctypes.CFUNCTYPE(ctypes.c_void_p, ctypes.c_void_p)
+        def body(_):
+            # Python code on a thread Python did not start.
+            seen["thread"] = threading.current_thread()
+            end = time.thread_time() + 0.05
+            while time.thread_time() < end:
+                pass
+            seen["spun"] = True
+            release.wait(10)
+            return None
+
+        libc = ctypes.CDLL(None, use_errno=True)
+        libc.pthread_create.argtypes = [
+            ctypes.POINTER(ctypes.c_ulong), ctypes.c_void_p, type(body),
+            ctypes.c_void_p,
+        ]
+        libc.pthread_create.restype = ctypes.c_int
+        libc.pthread_setname_np.argtypes = [ctypes.c_ulong, ctypes.c_char_p]
+        libc.pthread_setname_np.restype = ctypes.c_int
+        libc.pthread_join.argtypes = [ctypes.c_ulong, ctypes.c_void_p]
+        libc.pthread_join.restype = ctypes.c_int
+        handle = ctypes.c_ulong()
+        assert libc.pthread_create(
+            ctypes.byref(handle), None, body, None
+        ) == 0
+        try:
+            assert libc.pthread_setname_np(handle, b"tbt-actor") == 0
+            deadline = time.monotonic() + 10
+            while "spun" not in seen and time.monotonic() < deadline:
+                time.sleep(0.01)
+            assert isinstance(seen["thread"], threading._DummyThread)
+            assert seen["thread"] in threading.enumerate()
+            registry = MetricsRegistry()
+            ThreadLedger(registry).fold()
+            assert registry.counter("host.cpu_s.actors").value() >= 0.04
+        finally:
+            release.set()
+            libc.pthread_join(handle, None)
 
 
 class TestEnabledGate:

@@ -16,6 +16,7 @@ Run (combined, like the reference's polybeast.py launcher):
 """
 
 import argparse
+import functools
 import logging
 import os
 import queue as stdlib_queue
@@ -541,6 +542,12 @@ def train(flags):
             # the reap paths below always terminate the CURRENT group.
             server_procs = server_supervisor.processes
             server_supervisor.start_watch()
+            if tele.ledger is not None:
+                # The env stage's own CPU: the listeners and the stream
+                # children they fork, as host.cpu_s.env_servers.
+                tele.ledger.watch(
+                    lambda: [p.pid for p in server_supervisor.processes]
+                )
             if chaos is not None:
                 chaos.attach_servers(server_supervisor)
             time.sleep(0.5)
@@ -1629,13 +1636,15 @@ def train(flags):
                 # (only the lead receives heartbeats; the fold no-ops
                 # elsewhere).
                 folder_kwargs.update(fleet=fleet_coord)
-            tele.add_tick_callback(
-                NativeTelemetryFolder(
-                    reg, pool=actors, batcher=inference_batcher,
-                    queue=learner_queue, slo_target_s=slo_target_s,
-                    **folder_kwargs,
-                ).tick
+            folder = NativeTelemetryFolder(
+                reg, pool=actors, batcher=inference_batcher,
+                queue=learner_queue, slo_target_s=slo_target_s,
+                ledger=tele.ledger, **folder_kwargs,
             )
+            tele.add_tick_callback(functools.partial(
+                folder.tick,
+                ledger_min_interval_s=telemetry.LEDGER_PERIOD_S,
+            ))
         elif fleet_coord is not None and telemetry_on:
             # Python runtime: the folder runs for the fleet fold alone
             # (every native source None).

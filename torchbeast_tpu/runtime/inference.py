@@ -232,10 +232,8 @@ def inference_loop(
     _h_batch = _reg.histogram(f"{telemetry_prefix}.batch_size")
     _c_batches = _reg.counter(f"{telemetry_prefix}.batches")
     _c_rows = _reg.counter(f"{telemetry_prefix}.rows")
-    # Whether the split engages: replies still outstanding (handed over
-    # or in the replier's hands) at the instant of each launch, and the
-    # launches that found at least one.
-    _h_in_flight = _reg.histogram(f"{telemetry_prefix}.replies_in_flight")
+    # Whether the split engages: the launches made while a reply was
+    # still outstanding (handed over or in the replier's hands).
     _c_overlapped = _reg.counter(f"{telemetry_prefix}.overlapped_dispatches")
     # A Python DynamicBatcher with a telemetry_name already observes
     # inference.batch_size per dequeued batch — observing here too
@@ -336,9 +334,7 @@ def inference_loop(
                         if serving_hooks is not None:
                             # No table to own the rng chain: the hooks do.
                             act_args += ((ctx, serving_hooks.next_key()),)
-                    in_flight = handover.unfinished_tasks
-                    _h_in_flight.observe(in_flight)
-                    if in_flight:
+                    if handover.unfinished_tasks:
                         _c_overlapped.inc()
 
                 # inference.dispatch_s times ONLY the act dispatch (the

@@ -21,7 +21,12 @@ telemetry/metrics.py (csrc/queues.h telemetry_bucket_index) and
 snapshots reset per interval. Sampled per-request spans (ISSUE 12)
 fold the same way: 1-in-256 native computes record their stage stamps
 C++-side and land in the tracer as actor.request.* spans, closing the
-trace-schema gap for degraded-mode diagnosis.
+trace-schema gap for degraded-mode diagnosis. The same tick folds the
+extension's stamps of the wait for the interpreter lock
+(`host.gil_wait_s.<site>`, csrc/pymodule.cc GilSite) and, handed the
+driver's `telemetry.ThreadLedger`, the kernel's account of the
+process's threads (`host.cpu_s.<role>`, `host.run_delay_s.<role>`): a
+reader that forces a tick gets all of it as of that instant.
 
 Build: bash scripts/build_native.sh   (setup.py build_ext --inplace)
 """
@@ -63,8 +68,9 @@ def available() -> bool:
 # an older .so would silently serve central-only, so the default-on
 # runtime falls back to Python instead; 3 = ISSUE 25's
 # ActorPool.stage_histograms (actor.env_rtt_s), without which the fold
-# would leave that series silently empty.
-REQUIRED_API_VERSION = 3
+# would leave that series silently empty; 4 = ISSUE 36's
+# gil_wait_histograms (host.gil_wait_s.<site>), likewise.
+REQUIRED_API_VERSION = 4
 
 
 def gap_reason(core=None) -> Optional[str]:
@@ -100,7 +106,7 @@ class NativeTelemetryFolder:
     def __init__(self, registry, pool=None, batcher=None, queue=None,
                  tracer=None, slo_target_s=None, slice_batchers=None,
                  slice_router=None, replica_router=None,
-                 replica_batcher=None, fleet=None):
+                 replica_batcher=None, fleet=None, ledger=None):
         # ISSUE 17 fleet fold: with a FleetCoordinator attached, the
         # lead re-exports every remote host's heartbeat gauges
         # (inference.slice.<i>.* by construction — parallel.sebulba
@@ -109,6 +115,7 @@ class NativeTelemetryFolder:
         # fleet. Works with all native sources None — Python-runtime
         # fleet runs construct this folder for the fleet fold alone.
         self._fleet = fleet
+        self._ledger = ledger
         self._registry = registry
         self._fleet_gauges = {}  # name -> Gauge  # guarded-by: self._lock
         self._pool = pool
@@ -195,6 +202,19 @@ class NativeTelemetryFolder:
             "learner_queue.dequeue_wait_s"
         )
         self._h_queue_batch = registry.histogram("learner_queue.batch_size")
+        # The wait for the GIL where _tbt_core takes it, a histogram a
+        # site. The stamps are the extension's own (one set a process),
+        # so only a folder with a native source folds them.
+        core = None
+        if any(s is not None for s in (pool, batcher, queue)):
+            core = import_native()
+        self._gil_waits = getattr(core, "gil_wait_histograms", None)
+        self._h_gil_wait = {}
+        if self._gil_waits is not None:
+            self._h_gil_wait = {
+                site: registry.histogram(f"host.gil_wait_s.{site}")
+                for site in self._gil_waits()
+            }
 
     # beastlint: holds self._lock
     def _inc_delta(self, counter, key: str, value: int) -> None:
@@ -296,8 +316,16 @@ class NativeTelemetryFolder:
         self._fold_hist(self._h_queue_delay, delay)
         return True
 
-    def tick(self) -> None:
+    def tick(self, ledger_min_interval_s: float = 0.0) -> None:
+        """`ledger_min_interval_s`: the driver's periodic ticks pass
+        the ledger's period; a caller that wants the account as of now
+        (the benchmark at its window's ends) passes nothing."""
+        if self._ledger is not None:
+            self._ledger.fold(min_interval_s=ledger_min_interval_s)
         with self._lock:
+            if self._gil_waits is not None:
+                for site, snap in self._gil_waits().items():
+                    self._fold_hist(self._h_gil_wait[site], snap)
             if self._pool is not None:
                 p = self._pool.telemetry()
                 self._inc_delta(self._c_bytes_up, "bytes_up", p["bytes_up"])

@@ -49,7 +49,11 @@ from torchbeast_tpu.telemetry.export import (  # noqa: F401
     telemetry_block,
     validate_snapshot,
 )
-from torchbeast_tpu.telemetry.heartbeat import Heartbeat  # noqa: F401
+from torchbeast_tpu.telemetry.heartbeat import (  # noqa: F401
+    LEDGER_PERIOD_S,
+    Heartbeat,
+    ThreadLedger,
+)
 from torchbeast_tpu.telemetry.metrics import (  # noqa: F401
     Counter,
     Gauge,

@@ -6,20 +6,27 @@ once): `add_arguments` contributes the --telemetry/--no_telemetry/
 --telemetry_port/--trace_path stanza to a driver parser;
 `DriverTelemetry` owns the exporter, the optional Prometheus endpoint
 (bind failures DEGRADE to a warning — an observability port conflict
-must never abort a training run), the host heartbeat, the tracer's
+must never abort a training run), the host heartbeat and the ledger of
+the process's threads (folded before a snapshot, at most every
+`LEDGER_PERIOD_S`), the tracer's
 sinks (profiler annotations through the factory the driver hands in,
 Chrome events only with --trace_path) and the guarded shutdown writes.
 stdlib-only, like the rest of the package.
 """
 
 import logging
+import os
 from typing import Callable, Dict, Optional
 
 from torchbeast_tpu.telemetry.export import (
     JsonLinesExporter,
     PrometheusServer,
 )
-from torchbeast_tpu.telemetry.heartbeat import Heartbeat
+from torchbeast_tpu.telemetry.heartbeat import (
+    LEDGER_PERIOD_S,
+    Heartbeat,
+    ThreadLedger,
+)
 from torchbeast_tpu.telemetry.metrics import (
     MetricsRegistry,
     get_registry,
@@ -81,6 +88,7 @@ class DriverTelemetry:
         self.exporter: Optional[JsonLinesExporter] = None
         self.prometheus: Optional[PrometheusServer] = None
         self.heartbeat: Optional[Heartbeat] = None
+        self.ledger: Optional[ThreadLedger] = None
         self._trace_path = getattr(flags, "trace_path", None)
         self._tick_callbacks = []
         tracer = get_tracer()
@@ -92,6 +100,13 @@ class DriverTelemetry:
         if not self.enabled:
             return
         self.heartbeat = Heartbeat(self.registry).start()
+        self.ledger = ThreadLedger(self.registry)
+        log.info(
+            "Host ledger: %d cores; run-queue wait %s",
+            len(os.sched_getaffinity(0)),
+            "from schedstat" if self.ledger.has_schedstat
+            else "not kept by this kernel (host.run_delay_s.* absent)",
+        )
         self.exporter = JsonLinesExporter(
             jsonl_path, registry=self.registry, static={"driver": driver}
         )
@@ -139,6 +154,10 @@ class DriverTelemetry:
                 cb()
             except Exception:  # noqa: BLE001
                 log.exception("Telemetry tick callback failed")
+        try:
+            self.ledger.fold(min_interval_s=LEDGER_PERIOD_S)
+        except Exception:  # noqa: BLE001
+            log.exception("Host ledger fold failed")
         try:
             self.exporter.write(extra=extra)
         except Exception:  # noqa: BLE001

@@ -5,8 +5,14 @@ Two granularities:
 - `Tracer.span(name)` — THE way to time a stage of a thread's loop. It
   returns a reusable `Span`; call sites resolve it once and write
   `with span:` around the stage on every iteration. One block (i)
-  observes the stage's histogram `<name>_s` in the tracer's registry,
-  (ii) opens a profiler annotation `pb:<name>`, so the span lies on the
+  observes the stage's histogram `<name>_s` in the tracer's registry
+  and, beside it, `<name>_cpu_s`: the calling thread's own CPU clock
+  (`time.thread_time`) over the same block, read in one pass of
+  `CPU_SAMPLE_EVERY` through the span and credited that many times
+  over, so `<name>_s`
+  less `<name>_cpu_s` is what the thread spent off the CPU inside the
+  stage (asleep for the chip, waiting for the interpreter lock or for
+  a core), (ii) opens a profiler annotation `pb:<name>`, so the span lies on the
   device trace's clock and the benchmark's trace reduction names the
   device's idle gaps by it, and (iii) appends a Chrome "X" event while
   the tracer records (`--trace_path`; nesting renders as stacked bars
@@ -44,8 +50,17 @@ from torchbeast_tpu.telemetry.metrics import (
     _ENABLED,
     Histogram,
     MetricsRegistry,
+    bucket_index,
     get_registry,
 )
+
+_perf_counter = time.perf_counter
+_thread_time = time.thread_time
+
+# A thread reads its CPU clock in one of this many passes through a
+# span (see Span).
+CPU_SAMPLE_EVERY = 16
+_PHASES = itertools.count()
 
 # What the benchmark's trace reduction (perfbench/trace.py) takes for a
 # program span among the profiler's host events.
@@ -116,20 +131,37 @@ class Span:
     tracer's sinks. Reusable and re-entrant across threads and nesting
     (what is open lives on a per-thread stack), so a call site resolves
     its span once: the histogram and the annotation's name are looked
-    up here, not per call."""
+    up here, not per call.
+
+    The thread's CPU clock is a system call where the wall clock is
+    not, and on a sandboxed kernel a system call costs what a short
+    span lasts (17 us each on the benchmark's machine; two a span cost
+    the poly cell 5.8% of its frames: PERF.md section 6, PR 36). So a
+    span reads it in one of every `CPU_SAMPLE_EVERY` passes through it
+    and credits `<name>_cpu_s` with that sample `CPU_SAMPLE_EVERY`
+    times over: totals and means stay what they would be, from fewer
+    readings. The passes are counted on the span, not on the thread (a
+    native actor thread's Python state, and with it any thread-local
+    count, lasts one slot hook); two threads in one span may lose a
+    count between them, which moves a reading and loses none. Spans
+    made one after the other read on different passes, so a parent's
+    reading holds no child's clock reads."""
 
     __slots__ = ("_tracer", "name", "cat", "args", "histogram",
-                 "_annotation", "_local")
+                 "cpu_histogram", "_annotation", "_local", "_passes")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 histogram: Optional[Histogram], args: Optional[dict]):
+                 histogram: Optional[Histogram], args: Optional[dict],
+                 cpu_histogram: Optional[Histogram] = None):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
         self.histogram = histogram
+        self.cpu_histogram = cpu_histogram
         self._annotation = ANNOTATION_PREFIX + name
         self._local = threading.local()
+        self._passes = next(_PHASES) % CPU_SAMPLE_EVERY
 
     def __enter__(self):
         try:
@@ -145,19 +177,33 @@ class Span:
         if factory is not None and tracer._annotation_active():
             annotation = factory(self._annotation)
             annotation.__enter__()
-        stack.append((annotation, time.perf_counter()))
+        cpu_start = None
+        if self.cpu_histogram is not None:
+            self._passes = passes = self._passes + 1
+            if passes % CPU_SAMPLE_EVERY == 0:
+                cpu_start = _thread_time()
+        # The wall clock is read innermost: `_s` holds neither the
+        # span's bookkeeping nor the CPU clock's system calls.
+        stack.append((annotation, _perf_counter(), cpu_start))
         return self
 
     def __exit__(self, exc_type, exc, tb):
         entry = self._local.stack.pop()
         if entry is None:
             return False
-        annotation, start = entry
-        duration = time.perf_counter() - start
+        annotation, start, cpu_start = entry
+        duration = _perf_counter() - start
+        if cpu_start is not None:
+            cpu = _thread_time() - cpu_start
         if annotation is not None:
             annotation.__exit__(exc_type, exc, tb)
         if self.histogram is not None:
             self.histogram.observe(duration)
+        if cpu_start is not None:
+            n = CPU_SAMPLE_EVERY
+            self.cpu_histogram.observe_aggregate(
+                {bucket_index(cpu): n}, n * cpu, n * cpu * cpu, cpu, cpu
+            )
         tracer = self._tracer
         if tracer._record:
             tracer.add_complete(
@@ -167,9 +213,9 @@ class Span:
 
 
 class Tracer:
-    """`registry` is where spans find their `<name>_s` histograms
-    (None: spans observe none); `record` is whether Chrome events are
-    kept for `export_chrome`."""
+    """`registry` is where spans find their `<name>_s` and
+    `<name>_cpu_s` histograms (None: spans observe none); `record` is
+    whether Chrome events are kept for `export_chrome`."""
 
     def __init__(self, max_events: int = 32768, gated: bool = False,
                  registry: Optional[MetricsRegistry] = None,
@@ -283,10 +329,16 @@ class Tracer:
         """The stage `name` as a reusable context manager (see Span).
         Its histogram is `<name>_s` of the tracer's registry unless one
         is given (utils/prof.Timings keeps its sections' older names);
-        `args` ride on every Chrome event of the span."""
-        if histogram is None and self._registry is not None:
-            histogram = self._registry.histogram(name + "_s")
-        return Span(self, name, cat, histogram, args or None)
+        the thread-CPU histogram beside it is always `<name>_cpu_s` of
+        the tracer's registry. `args` ride on every Chrome event of the
+        span."""
+        cpu_histogram = None
+        if self._registry is not None:
+            if histogram is None:
+                histogram = self._registry.histogram(name + "_s")
+            cpu_histogram = self._registry.histogram(name + "_cpu_s")
+        return Span(self, name, cat, histogram, args or None,
+                    cpu_histogram)
 
     def stage(self, name: str, **args) -> Optional[StageTrace]:
         """A cross-thread request trace; None when nothing records, so
