@@ -275,7 +275,7 @@ def test_dense_transformer_attend_by_group(kv_heads, with_bias):
 # --- cached_transformer_attend: the cache and the unroll as two legs -----
 
 
-def _two_leg_case(kv_heads, cache, seed=0, M=5, heads=H):
+def _two_leg_case(kv_heads, cache, seed=0, M=5, heads=H, head_size=D):
     """A seeded case as models/transformer.py would hand it over: a
     `done` inside the unroll (row 0, step 7: later queries see neither
     the cache nor the steps before it), the band, and a cache that is
@@ -283,13 +283,13 @@ def _two_leg_case(kv_heads, cache, seed=0, M=5, heads=H):
     from torchbeast_tpu.ops.attention import band_by_leg
 
     rng = np.random.default_rng(seed)
-    q = jnp.asarray(rng.standard_normal((B, T, heads, D)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((B, T, heads, head_size)), jnp.float32)
     k, v = (
-        jnp.asarray(rng.standard_normal((B, T, kv_heads, D)), jnp.float32)
+        jnp.asarray(rng.standard_normal((B, T, kv_heads, head_size)), jnp.float32)
         for _ in range(2)
     )
     cache_k, cache_v = (
-        jnp.asarray(rng.standard_normal((M, B, kv_heads, D)), jnp.float32)
+        jnp.asarray(rng.standard_normal((M, B, kv_heads, head_size)), jnp.float32)
         for _ in range(2)
     )
     done = np.zeros((T, B), bool)
@@ -468,3 +468,219 @@ def test_ouro_update_gradient_builds_nothing_over_cache_and_unroll():
     # The walk went inside the rematerialised blocks: 3 passes x 2
     # layers, forward and again in the backward pass.
     assert equations > 1000
+
+
+# --- fused_attend: the dense body with its scores in VMEM -----------------
+
+
+def _fused_case(heads, kv_heads, cache, M, head_size=D):
+    """`_two_leg_case` as the Mellum2 block hands it over: `[cache; k]`,
+    `[cache; v]` and the two masks side by side."""
+    q, k, v, cache_k, cache_v, cache_mask, seq_mask = _two_leg_case(
+        kv_heads, cache, seed=heads + M, M=M, heads=heads,
+        head_size=head_size,
+    )
+    return (
+        q,
+        jnp.concatenate([cache_k.transpose(1, 0, 2, 3), k], axis=1),
+        jnp.concatenate([cache_v.transpose(1, 0, 2, 3), v], axis=1),
+        jnp.concatenate([cache_mask, seq_mask], axis=-1),
+    )
+
+
+def _dense_body(q, k_all, v_all, mask):
+    from torchbeast_tpu.ops.attention import dense_transformer_attend
+
+    return dense_transformer_attend(q, k_all, v_all, mask, None, None)
+
+
+def _gradients_of(fn, mask):
+    """Jitted gradients of a scalar of fn(q, k_all, v_all, mask) with
+    respect to its three operands."""
+    return jax.jit(jax.grad(
+        lambda *operands: jnp.sum(jnp.sin(fn(*operands, mask))), (0, 1, 2)
+    ))
+
+
+@pytest.mark.parametrize(
+    "heads, kv_heads, cache, M, blocks",
+    [
+        (4, 4, "full", 1776, (896, 256)),
+        (4, 4, "invalid", 1684, (896, 256)),
+        (8, 2, "partly", 684, (768, 384)),
+        (8, 2, "full", 5, (128, 128)),
+        (8, 1, "invalid", 1776, (896, 256)),
+        (8, 1, "partly", 1684, (896, 256)),
+    ],
+    ids=[
+        "mha-full-whole-blocks", "mha-empty-ragged", "gqa4-partly-ragged",
+        "gqa4-full-1-block", "gqa8-empty-whole-blocks", "gqa8-partly-ragged",
+    ],
+)
+def test_fused_attend_is_the_dense_body(heads, kv_heads, cache, M, blocks):
+    """The blockwise pass (interpreted here) against the dense body on
+    the same `[cache; unroll]`: the output and the gradients of q, k_all
+    and v_all, for equal and grouped heads (1, 4, 8 to a key/value
+    head), M + T = 1,792 keys (two whole blocks of 896 forward, seven of
+    256 backward), 1,700 (the last of each ragged), 700 (one ragged
+    block forward, two backward) and 21 (one block, mostly padding),
+    T = 16 steps (two sublane tiles) — and in every case an episode end
+    inside the unroll, whose first query (row 0, step 7) admits its own
+    step alone when the cache is empty."""
+    from torchbeast_tpu.ops import fused_attention
+    from torchbeast_tpu.ops.fused_attention import fused_attend, key_block
+
+    q, k_all, v_all, mask = _fused_case(heads, kv_heads, cache, M)
+    assert blocks == (
+        key_block(M + T, fused_attention._FORWARD_KEYS),
+        key_block(M + T, fused_attention._BACKWARD_KEYS),
+    )
+    if cache == "invalid":
+        assert int(mask[0, 7].sum()) == 1 and bool(mask[0, 7, M + 7])
+    fused, dense = jax.jit(fused_attend), jax.jit(_dense_body)
+    got, want = fused(q, k_all, v_all, mask), dense(q, k_all, v_all, mask)
+    assert got.dtype == want.dtype
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+    fused_grads = _gradients_of(fused_attend, mask)
+    dense_grads = _gradients_of(_dense_body, mask)
+    got, want = fused_grads(q, k_all, v_all), dense_grads(q, k_all, v_all)
+    for name, a, b in zip(("q", "k_all", "v_all"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5, err_msg=name)
+    if cache != "invalid":
+        # The cached keys take their gradient too: k_all is one operand.
+        assert float(jnp.abs(got[1][:, :M]).max()) > 0
+
+
+@pytest.mark.parametrize(
+    "M, no_grad_keys, regime",
+    [(1684, 1684, "fused"), (1684, 1536, "fused"), (1684, 100, "fused"),
+     (5, 5, "fused"), (684, 684, "dense"), (5, 5, "dense")],
+    ids=[
+        "fused-from-the-last-ragged-block", "fused-from-a-block's-edge",
+        "fused-from-inside-the-first-block", "fused-one-block",
+        "dense-700-keys", "dense-21-keys",
+    ],
+)
+def test_keys_told_to_take_no_gradient_take_zeros(
+    monkeypatch, M, no_grad_keys, regime
+):
+    """`no_grad_keys` (the Mellum2 block passes its cache's length M):
+    the output and dq are unchanged, dk_all and dv_all are zeros over
+    the first so many keys and unchanged over the rest, in both regimes
+    of `dense_transformer_attend`. In the fused one the backward kernel
+    writes dk, dv from the block that holds the first key that takes
+    them (of seven blocks of 256: the last and ragged one, the seventh
+    from its first key, the first)."""
+    from torchbeast_tpu.ops import attention
+
+    if regime == "fused":
+        monkeypatch.setattr(attention, "FUSED_SCORE_BYTES", 1)
+    q, k_all, v_all, mask = _fused_case(8, 2, "partly", M, head_size=128)
+
+    def grads(no_grad_keys):
+        def total(q, k_all, v_all):
+            return jnp.sum(jnp.sin(attention.dense_transformer_attend(
+                q, k_all, v_all, mask, None, None, no_grad_keys
+            )))
+
+        return jax.value_and_grad(total, (0, 1, 2))(q, k_all, v_all)
+
+    n = no_grad_keys
+    (value, (dq, dk, dv)), (value_0, (dq_0, dk_0, dv_0)) = grads(n), grads(0)
+    assert float(value) == float(value_0)
+    np.testing.assert_array_equal(dq, dq_0)
+    for got, whole in ((dk, dk_0), (dv, dv_0)):
+        assert float(jnp.abs(whole[:, :n]).max()) > 0
+        np.testing.assert_array_equal(got[:, :n], 0.0)
+        np.testing.assert_array_equal(got[:, n:], whole[:, n:])
+
+
+def test_fused_attend_under_remat():
+    """`--remat all` wraps the block: the rematerialised forward runs
+    the kernel again and the gradients are those without it."""
+    from torchbeast_tpu.ops.fused_attention import fused_attend
+
+    q, k_all, v_all, mask = _fused_case(8, 2, "partly", 1684)
+
+    plain_grads = _gradients_of(fused_attend, mask)
+    remat_grads = _gradients_of(jax.checkpoint(fused_attend), mask)
+    plain, remat = plain_grads(q, k_all, v_all), remat_grads(q, k_all, v_all)
+    for a, b in zip(remat, plain):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize(
+    "q_shape, keys, bias, fused",
+    [
+        ((32, 81, 32, 128), 4176, False, True),
+        ((32, 81, 32, 128), 1104, False, True),
+        ((32, 81, 32, 128), 4176, True, False),
+        ((32, 81, 16, 128), 209, False, False),
+        ((32, 81, 16, 128), 336, False, False),
+        ((64, 1, 32, 128), 4096, False, False),
+        ((2, 16, 4, 8), 21, False, False),
+        ((32, 81, 64, 64), 4176, False, False),
+    ],
+    ids=[
+        "mellum2-full", "mellum2-sliding", "a-learned-bias", "olmoe-sized",
+        "ouro-sized", "acting", "tier-1-toy", "heads-of-64",
+    ],
+)
+def test_fused_pass_applies_by_shapes_and_bias_alone(
+    q_shape, keys, bias, fused
+):
+    """The rule: 128 MiB of f32 scores or more, heads that fill the 128
+    lanes and no learned bias. The Mellum2 cell's two kinds of layer
+    are above it (1,385 and 366 MB); OLMoE- and Ouro-sized problems,
+    acting at T=1 against a full cache and everything tier-1 builds are
+    below it."""
+    from torchbeast_tpu.ops.attention import (
+        FUSED_SCORE_BYTES,
+        fused_pass_applies,
+    )
+
+    assert FUSED_SCORE_BYTES == 128 * 2 ** 20
+    k_shape = (q_shape[0], keys, 4, q_shape[3])
+    rel_bias = jnp.zeros((q_shape[2], keys)) if bias else None
+    assert fused_pass_applies(q_shape, k_shape, rel_bias) is fused
+
+
+@pytest.mark.parametrize(
+    "threshold, bias, fused",
+    [(None, False, False), (1, False, True), (1, True, False)],
+    ids=["small-problem", "above-the-threshold", "above-it-with-a-bias"],
+)
+def test_dense_transformer_attend_chooses_by_the_rule(
+    monkeypatch, threshold, bias, fused
+):
+    """A side of the rule each: the same call compiles the dense body
+    for a small problem, the kernel once the scores are over the
+    threshold (lowered here to reach it), the dense body again whenever
+    a learned bias comes with it — and gives the same values."""
+    from torchbeast_tpu.ops import attention
+
+    q, k_all, v_all, mask = _fused_case(8, 2, "partly", 193, head_size=128)
+    rng = np.random.default_rng(0)
+    offsets = jnp.asarray(rng.integers(0, 194, (T, 193 + T)))
+    rel_bias = (
+        jnp.asarray(rng.standard_normal((8, 194)), jnp.float32)
+        if bias else None
+    )
+    want = attention.dense_transformer_attend(
+        q, k_all, v_all, mask, offsets, rel_bias
+    )
+    if threshold is not None:
+        monkeypatch.setattr(attention, "FUSED_SCORE_BYTES", threshold)
+    # A function of its own: traces are cached by the function traced.
+    program = str(
+        jax.make_jaxpr(
+            lambda *operands: attention.dense_transformer_attend(*operands)
+        )(q, k_all, v_all, mask, offsets, rel_bias)
+    )
+    assert ("pallas_call" in program) is fused
+    got = attention.dense_transformer_attend(
+        q, k_all, v_all, mask, offsets, rel_bias
+    )
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)

@@ -239,6 +239,48 @@ def test_mellum2_share_of_the_experts_compiles_for_v5e(one_chip, monkeypatch):
     assert compiled.as_text().count("tpu_custom_call") >= 8
 
 
+@pytest.mark.parametrize("keys", [4176, 1104], ids=["full", "sliding"])
+def test_mellum2_fused_attention_compiles_for_v5e(one_chip, monkeypatch, keys):
+    """The Mellum2 cell's attention below `dense_transformer_attend`
+    (ops/fused_attention.py), forward and backward at the published
+    widths [32, 81, 32 on 4, 128] over a full layer's 4,176 keys and a
+    window layer's 1,104: the rule takes the fused pass, two Mosaic
+    kernels (704 rows x 384 keys a cell) fit the scoped VMEM they ask
+    for, and the compiled program holds no f32 array whose last
+    dimension is the keys: the scores' [.., 81, keys] (1.385 GB in the
+    full layer) are never built. As the block calls it: the cache's
+    keys take no gradient."""
+    import re
+
+    from torchbeast_tpu.ops import attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, t, h, hkv, d = B, T + 1, 32, 4, 128
+    assert attention.fused_pass_applies((b, t, h, d), (b, keys, hkv, d), None)
+
+    def loss(q, k_all, v_all, mask, dout):
+        return jnp.sum(
+            attention.dense_transformer_attend(
+                q, k_all, v_all, mask, None, None, keys - t
+            ) * dout
+        )
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        _struct(one_chip, (b, t, h, d)),
+        _struct(one_chip, (b, keys, hkv, d)),
+        _struct(one_chip, (b, keys, hkv, d)),
+        _struct(one_chip, (b, t, keys), jnp.bool_),
+        _struct(one_chip, (b, t, h, d)),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    padded = -(-keys // 384) * 384
+    over_keys = {
+        dims for dims in re.findall(r"f32\[([0-9,]+)\]", text)
+        if dims.endswith((f",{keys}", f",{padded}"))
+    }
+    assert not over_keys, over_keys
+
+
 def _ouro_loop_gradient_memory(chip):
     """Temp bytes of the gradient of ONE Ouro layer at the published
     widths run 4 times (the family's loop), rematerialised, over its 4

@@ -541,3 +541,43 @@ def test_rematerialised_blocks_give_the_same_loss_and_gradients():
     assert float(stats["moe_held_assignments"]) == float(
         stats_r["moe_held_assignments"]
     )
+
+
+def test_blocks_over_the_threshold_take_the_fused_pass_and_are_counted(
+    monkeypatch
+):
+    """Which body attends is chosen from the shapes (ops/attention.py
+    `fused_pass_applies`), so at these toy widths every block keeps the
+    dense body and the update's stats hold no `attention_fused_
+    applications`. With the threshold lowered to reach them the four
+    blocks take the fused pass (interpreted here; rematerialised, as
+    the cell runs them), the stats count 4, and the loss and the
+    gradients are the dense body's."""
+    from torchbeast_tpu.ops import attention
+
+    # Heads of 128: the fused pass reads a head as a block of lanes.
+    model, params = _model((1, 4), head_dim=128)
+    model = model.clone(remat=True)
+    state = _warm_state(model, params, seed=5)
+    batch = _learner_batch(9, done_steps=[(1, 1)])
+    hp = learner_lib.HParams(batch_size=B, unroll_length=T - 1)
+
+    def run():
+        # Jitted, and a function of its own each time: traces are
+        # cached by the function traced, the rule is read at the trace.
+        loss_and_grads = jax.jit(jax.value_and_grad(
+            lambda p: learner_lib.compute_loss(model, p, batch, state, hp),
+            has_aux=True,
+        ))
+        (loss, stats), grads = loss_and_grads(params)
+        return loss, stats, jax.flatten_util.ravel_pytree(grads)[0]
+
+    loss, stats, grads = run()
+    assert "attention_fused_applications" not in stats
+    monkeypatch.setattr(attention, "FUSED_SCORE_BYTES", 1)
+    loss_f, stats_f, grads_f = run()
+    assert float(stats_f["attention_fused_applications"]) == 4.0
+    assert float(loss_f) == pytest.approx(float(loss), rel=1e-6)
+    np.testing.assert_allclose(
+        grads_f, grads, rtol=0, atol=RTOL * float(jnp.max(jnp.abs(grads)))
+    )

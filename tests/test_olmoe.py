@@ -136,6 +136,11 @@ def test_family_agrees_with_the_reference(widths):
     )
     assert float(stats["moe_load_max_over_mean"]) >= 1.0
     assert float(stats["aux_loss"]) > 0
+    # Every block counts its own two-leg application: summed by name.
+    assert float(stats["attention_two_leg_applications"]) == (
+        widths["num_layers"]
+    )
+    assert "attention_fused_applications" not in stats
 
 
 def test_batch_forward_equals_stepwise_acting_across_an_episode_end():

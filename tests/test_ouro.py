@@ -159,6 +159,8 @@ def test_family_agrees_with_the_reference():
     np.testing.assert_allclose(p_exit.sum(axis=0), 1.0, 1e-6)
     assert float(stats["loop_passes"]) == 3
     assert float(stats["loop_block_applications"]) == 6
+    assert float(stats["attention_two_leg_applications"]) == 6
+    assert "attention_fused_applications" not in stats
     assert float(stats["loop_cache_bytes_per_row"]) == 6 * 4 * M * (
         2 * 4 * 16 + 1
     )

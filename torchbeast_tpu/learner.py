@@ -537,12 +537,16 @@ def compute_loss(
     )
     moe_stats = _moe_stats(variables.get("moe_stats", {}))
     loop_stats = _loop_stats(variables.get("loop_stats", {}))
-    # Block applications traced through the two-leg attention (models/
-    # transformer.py `count_two_leg_application`); no key for a model
-    # that has none.
-    attention_stats = _sown_by_name(
-        "attention_", variables.get("attention_stats", {})
-    )
+    # Block applications traced through the two-leg attention or the
+    # fused pass (models/transformer.py `count_two_leg_application`,
+    # `count_fused_application`), each module's count under its own
+    # scope: summed by name; no key for a model that has none.
+    attention_stats = {}
+    for path, count in flax.traverse_util.flatten_dict(
+        variables.get("attention_stats", {})
+    ).items():
+        name = "attention_" + path[-1]
+        attention_stats[name] = attention_stats.get(name, 0.0) + count
 
     bootstrap_value = learner_outputs.baseline[-1]
 

@@ -51,8 +51,14 @@ import numpy as np
 
 from torchbeast_tpu.models.moe import DroplessMoE
 from torchbeast_tpu.models.olmoe import rope_rotate
-from torchbeast_tpu.models.transformer import TransformerNet
-from torchbeast_tpu.ops.attention import dense_transformer_attend
+from torchbeast_tpu.models.transformer import (
+    TransformerNet,
+    count_fused_application,
+)
+from torchbeast_tpu.ops.attention import (
+    dense_transformer_attend,
+    fused_pass_applies,
+)
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -136,7 +142,16 @@ class _Mellum2Block(nn.Module):
         here: tests/perfbench/test_perfbench_mellum2.py plants its
         repeated-head fault on this module's name for that function and
         its `k_all`, `v_all`, and a PR that changes the program may not
-        change the benchmark's files (PERF.md section 7)."""
+        change the benchmark's files (PERF.md section 7). Since PR 37
+        that is cheap where it was not: below that name the learner's
+        shapes take the fused pass (ops/fused_attention.py), whose
+        scores stay in VMEM and whose key operands are time-major as
+        the state is, so the concatenation costs one cast (and
+        rotation) of the cache into `[M+T, B, 4, 128]` forward and one
+        rematerialised, no longer passes over [B, 4, 8, T, M+T]; the
+        body is told that the cache's M keys take no gradient (ROADMAP
+        S8 (i) for what is left). The application is counted
+        (`attention_fused_applications`) when the rule takes it."""
         B, T, _ = x.shape
         M, H, Hkv, hd = (
             self.memory_len, self.num_heads, self.kv_heads, self.head_dim
@@ -168,18 +183,31 @@ class _Mellum2Block(nn.Module):
             q = norm("q_norm")(proj("q", H * hd)(h).reshape(B, T, H, hd))
             k = norm("k_norm")(proj("k", Hkv * hd)(h).reshape(B, T, Hkv, hd))
             v = proj("v", Hkv * hd)(h).reshape(B, T, Hkv, hd)
-            k_all = jnp.concatenate([cache[0].astype(k.dtype), k], axis=1)
-            v_all = jnp.concatenate([cache[1].astype(v.dtype), v], axis=1)
-            key_time = jnp.concatenate([jnp.arange(M) - M, jnp.arange(T)])
-            attended = dense_transformer_attend(
-                rope_rotate(q, jnp.arange(T), inv_freq, factor).astype(
+
+            def rotate(x, times):
+                return rope_rotate(x, times, inv_freq, factor).astype(
                     self.dtype
-                ),
-                rope_rotate(k_all, key_time, inv_freq, factor).astype(
-                    self.dtype
-                ),
-                v_all.astype(self.dtype), mask, None, None,
+                )
+
+            # The cache and the unroll rotated apart, then joined: the
+            # backward pass then rotates 81 keys' gradient back, not
+            # M + 81 keys' of which the concatenation drops M.
+            k_all = jnp.concatenate(
+                [
+                    rotate(cache[0].astype(k.dtype), jnp.arange(M) - M),
+                    rotate(k, jnp.arange(T)),
+                ],
+                axis=1,
             )
+            v_all = jnp.concatenate([cache[1].astype(v.dtype), v], axis=1)
+            # The cache is the learner's data: its M keys take no
+            # gradient, and the fused pass then makes none for them.
+            attended = dense_transformer_attend(
+                rotate(q, jnp.arange(T)), k_all, v_all.astype(self.dtype),
+                mask, None, None, M,
+            )
+            if fused_pass_applies(q.shape, k_all.shape, None):
+                count_fused_application(self)
             x = x + proj("o", self.d_model)(
                 attended.reshape(B, T, H * hd)
             ).astype(jnp.float32)

@@ -58,18 +58,30 @@ from torchbeast_tpu.ops.attention import (
 )
 
 
+def _count_application(module: nn.Module, name: str) -> None:
+    if not module.is_initializing():
+        module.sow(
+            "attention_stats", name, jnp.float32(1.0),
+            init_fn=lambda: jnp.float32(0.0),
+            reduce_fn=lambda count, one: count + one,
+        )
+
+
 def count_two_leg_application(module: nn.Module) -> None:
     """One block application traced through ops/attention.
     cached_transformer_attend, for the update's stats (`attention_two_
     leg_applications`, learner.compute_loss; poly's gauge `attention.
     two_leg_applications`): 32 in the Ouro cell, 2 in OLMoE's. The path
     is compiled in, so the count is the trace's, not the device's."""
-    if not module.is_initializing():
-        module.sow(
-            "attention_stats", "two_leg_applications", jnp.float32(1.0),
-            init_fn=lambda: jnp.float32(0.0),
-            reduce_fn=lambda count, one: count + one,
-        )
+    _count_application(module, "two_leg_applications")
+
+
+def count_fused_application(module: nn.Module) -> None:
+    """One block application whose `dense_transformer_attend` took the
+    fused pass (ops/attention.py `fused_pass_applies`, which the block
+    asks with the shapes it hands over): `attention_fused_applications`,
+    4 in the Mellum2 cell, no such key where no block took it."""
+    _count_application(module, "fused_applications")
 
 
 class _Block(nn.Module):

@@ -30,7 +30,17 @@ unroll. Two bodies do that on one chip:
 - `dense_transformer_attend` on the concatenated `[cache; unroll]`:
   kept for `models/transformer._Block` (learned relative bias), its
   parity with the Ulysses path, `models/transformer_pp.py`, and the
-  Mellum2 block (tests/perfbench pins that name on it).
+  Mellum2 block (tests/perfbench pins that name on it). One function
+  in two regimes, chosen by `fused_pass_applies` from the operands'
+  shapes and `rel_bias is None`: the dense body, which builds the f32
+  scores [B, Hkv, G, T, M+T], where they are small; where they are
+  128 MiB or more (the Mellum2 learner step: 1.385 GB a full layer)
+  `ops/fused_attention.fused_attend`, a blockwise pass over the keys
+  with a running maximum and denominator, forward and backward, whose
+  scores never leave VMEM. Same mathematics and precision in both
+  (bfloat16 matmul operands with float32 sums on the chip, float32
+  softmax, every admitted key summed); a family with a learned bias
+  keeps the dense body whatever its size.
 """
 
 import functools
@@ -41,7 +51,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-BIG_NEG = -1e30
+from torchbeast_tpu.ops.fused_attention import BIG_NEG, fused_attend
+
+# f32 score bytes (B x H x T x K x 4) from which `dense_transformer_
+# attend` takes the fused pass: see `fused_pass_applies`.
+FUSED_SCORE_BYTES = 128 * 2 ** 20
 
 
 def segment_ids_from_done(done):
@@ -721,9 +735,56 @@ def cached_transformer_attend(q, k, v, cache_k, cache_v, cache_mask,
     return out.reshape(B, T, H, D)
 
 
-def dense_transformer_attend(q, k_all, v_all, mask, offsets, rel_bias):
-    """The transformer policy's dense attention body over `[cache;
-    unroll]` — ONE implementation shared by the model's dense branch
+def fused_pass_applies(q_shape, k_shape, rel_bias) -> bool:
+    """Whether `dense_transformer_attend` takes the fused pass for q
+    [B, T, H, D] and k_all [B, K, Hkv, D]: no learned bias, a head size
+    that fills the 128 lanes (the kernels read a head's keys as a
+    column block of [K, B * Hkv * D]), and f32 scores of `FUSED_SCORE_
+    BYTES` (128 MiB) or more. A function of the shapes and of `rel_bias
+    is None` alone: the body asks it, and a block asks it to count what
+    it compiled in (`attention_fused_applications`, models/
+    transformer.py `count_fused_application`).
+
+    Why 128 MiB: it lies between what was measured to gain and what
+    nothing measures. At the Mellum2 cell's sizes (366 MB a window
+    layer, 1,385 MB the full one) XLA's passes over the scores cost
+    10-45 ms a step and the fused pass a quarter of that (PERF.md
+    section 6, PR 37). Below it are acting at T=1 (one query row padded
+    to a tile of 8, 34 MB against a full cache), the toy families
+    (which on the CPU would run through the Pallas interpreter) and
+    OLMoE- and Ouro-sized problems (35 and 56 MB; equal heads, so 81
+    rows a matmul where Mellum2's groups give 648; they attend through
+    `cached_transformer_attend` anyway): none has been timed on the
+    chip through the fused pass in its own program."""
+    B, T, H, D = q_shape
+    return (
+        rel_bias is None
+        and D % 128 == 0
+        and B * H * T * k_shape[1] * 4 >= FUSED_SCORE_BYTES
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _no_gradient_before(keys, count):
+    """keys [B, K, ...] as they are, with zeros for a gradient in the
+    first `count`."""
+    return keys
+
+
+def _no_gradient_before_bwd(count, _, grad):
+    takes = jnp.arange(grad.shape[1]) >= count
+    return (jnp.where(takes[None, :, None, None], grad, 0),)
+
+
+_no_gradient_before.defvjp(
+    lambda keys, count: (keys, None), _no_gradient_before_bwd
+)
+
+
+def dense_transformer_attend(q, k_all, v_all, mask, offsets, rel_bias,
+                             no_grad_keys=0):
+    """The transformer policy's attention body over `[cache; unroll]` —
+    ONE implementation shared by the model's dense branch
     (models/transformer.py _Block) and the Ulysses path below (which is
     exactly this on a head slice), so the two can never drift apart
     numerically; models/transformer_pp.py and models/mellum2.py call it
@@ -740,9 +801,35 @@ def dense_transformer_attend(q, k_all, v_all, mask, offsets, rel_bias):
     query head j reads key/value head j // (H // Hkv). The queries are
     then contracted by group, [B, T, Hkv, G, D], and K and V are never
     repeated.
+
+    One function, two regimes, chosen by `fused_pass_applies` (no
+    flag): the dense body below, which builds the f32 scores
+    [B, Hkv, G, T, M+T] and leaves the rest to XLA, or `ops/
+    fused_attention.fused_attend`, the same blockwise over the keys
+    with the scores in VMEM, forward and backward. The precision is the
+    same in both: matmul operands bfloat16 with float32 sums on the
+    chip (XLA's default for a float32 einsum), scores, mask, maximum,
+    exponent and denominator float32, the weights cast to v's type for
+    the combine; only the order of summation over keys differs. Every
+    query must admit a key.
+
+    `no_grad_keys` (a Python int; the Mellum2 block passes its cache's
+    length) says that the first so many keys of k_all and v_all are
+    data: their part of the two gradients is zeros in both regimes, and
+    the fused pass does no work for it (most of its backward pass's
+    `dk`, `dv` when the cache is 4,095 of 4,176 keys).
     """
     B, T, H, D = q.shape
     Hkv = k_all.shape[2]
+    if H % Hkv:
+        raise ValueError(
+            f"{H} query heads do not divide over {Hkv} key/value heads"
+        )
+    if fused_pass_applies(q.shape, k_all.shape, rel_bias):
+        return fused_attend(q, k_all, v_all, mask, no_grad_keys)
+    if no_grad_keys:
+        k_all = _no_gradient_before(k_all, no_grad_keys)
+        v_all = _no_gradient_before(v_all, no_grad_keys)
     scale = D ** -0.5
     if Hkv == H:
         scores = (
@@ -754,10 +841,6 @@ def dense_transformer_attend(q, k_all, v_all, mask, offsets, rel_bias):
         scores = jnp.where(mask[:, None], scores, BIG_NEG)
         weights = jax.nn.softmax(scores, axis=-1).astype(v_all.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", weights, v_all)
-    if H % Hkv:
-        raise ValueError(
-            f"{H} query heads do not divide over {Hkv} key/value heads"
-        )
     G = H // Hkv
     scores = (
         jnp.einsum(

@@ -1,0 +1,411 @@
+"""Attention over `[cache; unroll]` whose scores live in VMEM.
+
+`ops/attention.dense_transformer_attend` computes softmax(mask(q k^T /
+sqrt(D))) v by building the f32 scores `[B, Hkv, G, T, K]` in HBM; at
+the Mellum2 cell's widths that tensor is 1.385 GB in the layer that
+reads its whole cache and every pass over it is a round trip through
+HBM (PERF.md, PR 37). `fused_attend` is the same function as a
+blockwise pass over the keys with a running maximum and denominator,
+forward and backward under one `jax.custom_vjp`: a grid cell is one
+batch row, one key/value head and one block of keys, its scores a
+`[G * T, block]` tile that never leaves the chip.
+
+**Same mathematics, same precision.** On the chip the operands of the
+matmuls are bfloat16 and their sums float32 — what XLA makes of a
+float32 einsum at JAX's default precision, so `q k^T`, `p v` and the
+backward pass's four products are computed as the dense body's are (and
+as models/moe.py `_gmm_call` states for the experts). Scores, the mask
+(`BIG_NEG`), maximum, exponent and denominator are float32; `p` is cast
+to the matmul's operand type for the combine. What differs from the
+dense body is the order of summation over key blocks, and that `p` is
+rounded before it is divided by the denominator, not after. Every key
+the mask admits is summed; no block is skipped, whatever the mask says
+(the mask is data: a program that skipped on it would be another
+program for a full cache than for an empty one). Off the chip the
+kernels are interpreted, in float32.
+
+**Grouped heads as rows.** A key/value head's G query heads x T steps
+are the rows of one matmul against a `[block, D]` tile of keys: K and V
+are read once a key/value head and never repeated. T is padded to the
+f32 sublane tile (8) so that a `[G * Tp, block]` tile of scores splits
+into `[G, Tp, block]` for the `[Tp, block]` slab of the mask without a
+relayout. Padded rows admit no key and are dropped; every real row
+must admit one (the transformer families always admit a query's own
+step): a row that admits none comes out as an average over the last
+block's padding too, not as the dense body's average over the K.
+
+**Keys where the state holds them.** The kernels read k_all and v_all
+time-major, `[K, B * Hkv * D]`, a cell's keys rows j of column block
+b * Hkv + h: the layout of a cache in the agent state (`[M, B, Hkv,
+D]`), so `[cache; k]` reaches a kernel by one pass over the cache (the
+cast to the operand type, a family's rotation fused into it) and one
+relayout of the result, with no transposed copy of the cache and no
+copy to pad it (first built batch-major and padded: 8 ms a step more in
+the Mellum2 cell, PERF.md section 6, PR 37). The keys are not padded to
+a whole number of blocks: the last block's tail is zeroed in the cell,
+and the mask, which is small, is padded with False instead.
+
+**Backward.** One kernel, one pass over the key blocks: all of a
+key/value head's query rows are in the cell, so a block's `dk` and `dv`
+are complete when the cell ends and only `dq` is carried across blocks.
+Saved from the forward pass: the operands as the kernel reads them, the
+output and the rows' log-sum-exp (lane-replicated, `[.., 128]`). Told
+that the first n keys take no gradient (`no_grad_keys`: a cache that is
+the learner's data), the kernel makes `dk`, `dv` from the block that
+holds key n on and writes no row before it.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+BIG_NEG = -1e30
+
+# The most keys a grid cell takes. A forward cell pays for its running
+# maximum and denominator (two reductions along the lanes, the rescaled
+# accumulator) once a block, so it takes long blocks: at the Mellum2
+# widths 1,408 keys a cell ran the full layer's forward in 4.9 ms where
+# 384 took 6.2 and 128 took 11.5 (PERF.md section 6, PR 37). A backward
+# cell has no reduction and four [rows, block] intermediates, and with
+# `no_grad_keys` it makes dk, dv for whole blocks: 384 keys a cell.
+_FORWARD_KEYS = 1536
+_BACKWARD_KEYS = 512
+_SUBLANES = 8  # rows of a float32 tile
+_LANES = 128
+# Scoped VMEM a cell may use. At the Mellum2 widths (704 rows) the
+# forward cell holds ~10 MB of [rows, 1408] intermediates, the backward
+# cell ~5 MB of [rows, 384] ones beside 5 MB of double-buffered
+# operands; the chip's default is 16 MiB of its 128.
+_VMEM_LIMIT = 48 * 1024 * 1024
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(
+        a, b, dims, preferred_element_type=jnp.float32
+    )
+
+
+def key_block(num_keys: int, most: int) -> int:
+    """Keys a grid cell for `num_keys` keys: the multiple of 128 from
+    256 to `most` that pads the keys the least, the largest on a tie
+    (4,176 keys: 1,408 forward, 384 backward, both to 4,224; 1,104:
+    1,152 and 384)."""
+    if num_keys <= _LANES:
+        return _LANES
+    return min(
+        range(2 * _LANES, most + 1, _LANES),
+        key=lambda block: (-(-num_keys // block) * block, -block),
+    )
+
+
+def _whole_keys(x, block_index, num_keys):
+    """A [block, D] tile of keys or values with the rows past the last
+    key zeroed: where the keys do not fill their last block the tile's
+    tail is whatever memory held, and 0 x NaN is NaN in a matmul."""
+    block = x.shape[0]
+    if num_keys % block == 0:
+        return x
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.where(row < num_keys - block_index * block, x, 0)
+
+
+def _scores(q, k, admitted, scale, groups):
+    """Masked f32 scores [G * Tp, block] of a cell: q [G * Tp, D],
+    k [block, D], admitted [Tp, block] int8 (the mask's slab, shared by
+    the G query heads of the group)."""
+    rows, block = q.shape[0], k.shape[0]
+    s = _dot(q, k, _NT) * scale
+    s = s.reshape(groups, rows // groups, block)
+    s = jnp.where((admitted != 0)[None], s, BIG_NEG)
+    return s.reshape(rows, block)
+
+
+def _forward_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref,
+                    top_ref, den_ref, acc_ref, *, scale, groups, num_keys):
+    block_index = pl.program_id(2)
+
+    @pl.when(block_index == 0)
+    def _():
+        # -inf, not BIG_NEG: a first block the mask excludes whole then
+        # weighs nothing once a later block admits a key.
+        top_ref[...] = jnp.full_like(top_ref, -jnp.inf)
+        den_ref[...] = jnp.zeros_like(den_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    k = _whole_keys(k_ref[...], block_index, num_keys)
+    v = _whole_keys(v_ref[...], block_index, num_keys)
+    s = _scores(q_ref[0, 0], k, mask_ref[0], scale, groups)
+    top = jnp.maximum(top_ref[...], s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - top)
+    shrink = jnp.exp(top_ref[...] - top)
+    den_ref[...] = shrink * den_ref[...] + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = shrink * acc_ref[...] + _dot(p.astype(v.dtype), v)
+    top_ref[...] = top
+
+    @pl.when(block_index == pl.num_programs(2) - 1)
+    def _():
+        out_ref[0, 0] = (acc_ref[...] / den_ref[...]).astype(out_ref.dtype)
+        lse_ref[0, 0] = jnp.broadcast_to(
+            top_ref[...] + jnp.log(den_ref[...]), lse_ref.shape[2:]
+        )
+
+
+def _backward_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref,
+                     dout_ref, dq_ref, dk_ref, dv_ref, dq_acc_ref,
+                     delta_ref, *, scale, groups, num_keys, first_block):
+    block_index = pl.program_id(2)
+
+    @pl.when(block_index == 0)
+    def _():
+        dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
+        # sum_k p (dp): the softmax's own term, from the output.
+        delta_ref[...] = jnp.sum(
+            out_ref[0, 0] * dout_ref[0, 0], axis=-1, keepdims=True
+        )
+
+    q = q_ref[0, 0]
+    k = _whole_keys(k_ref[...], block_index, num_keys)
+    v = _whole_keys(v_ref[...], block_index, num_keys)
+    dout = dout_ref[0, 0].astype(q.dtype)
+    s = _scores(q, k, mask_ref[0], scale, groups)
+    p = jnp.exp(s - lse_ref[0, 0][:, :1])
+    # The 1/sqrt(D) of the scores goes on the products, in f32: ds is
+    # rounded to the operand type once either way.
+    ds = (p * (_dot(dout, v, _NT) - delta_ref[...])).astype(q.dtype)
+    dq_acc_ref[...] += _dot(ds, k)
+
+    # dk, dv from the first block that holds a key that takes them: the
+    # blocks before it are not written (nor part of dk_ref, dv_ref).
+    @pl.when(block_index >= first_block)
+    def _():
+        dv_ref[...] = _dot(p.astype(q.dtype), dout, _TN)
+        dk_ref[...] = _dot(ds, q, _TN) * scale
+
+    @pl.when(block_index == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0, 0] = dq_acc_ref[...] * scale
+
+
+def _row_specs(rows, d, tp, block):
+    """Block specs of what a (batch row b, key/value head h, key block
+    j) cell reads by its rows: the row operands [B, Hkv, rows, D], the
+    mask [B, Tp, Kp] and the log-sum-exp [B, Hkv, rows, 128]."""
+    by_rows = pl.BlockSpec((1, 1, rows, d), lambda b, h, j: (b, h, 0, 0))
+    mask = pl.BlockSpec((1, tp, block), lambda b, h, j: (b, 0, j))
+    lse = pl.BlockSpec((1, 1, rows, _LANES), lambda b, h, j: (b, h, 0, 0))
+    return by_rows, mask, lse
+
+
+def _key_spec(d, hkv, block, first_block=0):
+    """Block spec of a key operand [K, B * Hkv * D]: a cell's keys are
+    a [block, D] tile, rows j of column block b * Hkv + h; with
+    `first_block`, of an array that starts at that block."""
+    return pl.BlockSpec(
+        (block, d),
+        lambda b, h, j: (jnp.maximum(j - first_block, 0), b * hkv + h),
+    )
+
+
+def _compiler_params(interpret):
+    if interpret:
+        return {}
+    return {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        )
+    }
+
+
+def _forward_call(q, k, v, mask, groups, interpret):
+    """q [B, Hkv, G * Tp, D]; k, v [K, B * Hkv * D]; mask [B, Tp, Kp]
+    int8 -> (out f32 like q, lse f32 [B, Hkv, G * Tp, 128])."""
+    b, hkv, rows, d = q.shape
+    num_keys = k.shape[0]
+    block = key_block(num_keys, _FORWARD_KEYS)
+    by_rows, mask_spec, lse_spec = _row_specs(rows, d, mask.shape[1], block)
+    by_keys = _key_spec(d, hkv, block)
+    return pl.pallas_call(
+        functools.partial(
+            _forward_kernel, scale=d ** -0.5, groups=groups,
+            num_keys=num_keys,
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct(q.shape, jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, rows, _LANES), jnp.float32),
+        ),
+        grid=(b, hkv, pl.cdiv(num_keys, block)),
+        in_specs=[by_rows, by_keys, by_keys, mask_spec],
+        out_specs=(by_rows, lse_spec),
+        scratch_shapes=[
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32),
+        ],
+        interpret=interpret,
+        name="fused_attend_forward",
+        **_compiler_params(interpret),
+    )(q, k, v, mask)
+
+
+def _backward_call(q, k, v, mask, out, lse, dout, groups, first_block,
+                   interpret):
+    """The forward's operands, its two results and dout like out ->
+    (dq like q, dk, dv like k from block `first_block` on), all f32."""
+    b, hkv, rows, d = q.shape
+    num_keys = k.shape[0]
+    block = key_block(num_keys, _BACKWARD_KEYS)
+    by_rows, mask_spec, lse_spec = _row_specs(rows, d, mask.shape[1], block)
+    by_keys = _key_spec(d, hkv, block)
+    by_later_keys = _key_spec(d, hkv, block, first_block)
+    grads = jax.ShapeDtypeStruct(
+        (num_keys - first_block * block, k.shape[1]), jnp.float32
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _backward_kernel, scale=d ** -0.5, groups=groups,
+            num_keys=num_keys, first_block=first_block,
+        ),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, jnp.float32), grads, grads),
+        grid=(b, hkv, pl.cdiv(num_keys, block)),
+        in_specs=[
+            by_rows, by_keys, by_keys, mask_spec, by_rows, lse_spec, by_rows
+        ],
+        out_specs=(by_rows, by_later_keys, by_later_keys),
+        scratch_shapes=[
+            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+        ],
+        interpret=interpret,
+        name="fused_attend_backward",
+        **_compiler_params(interpret),
+    )(q, k, v, mask, out, lse, dout)
+
+
+def _as_rows(x, hkv, tp):
+    """[B, T, H, D] -> [B, Hkv, G * Tp, D]: a key/value head's query
+    heads one after the other, each padded to Tp steps."""
+    b, t, h, d = x.shape
+    x = x.reshape(b, t, hkv, h // hkv, d).transpose(0, 2, 3, 1, 4)
+    x = jnp.pad(x, ((0, 0),) * 3 + ((0, tp - t), (0, 0)))
+    return x.reshape(b, hkv, -1, d)
+
+
+def _from_rows(x, t):
+    """`_as_rows` undone: [B, Hkv, G * Tp, D] -> [B, T, H, D]."""
+    b, hkv, rows, d = x.shape
+    tp = -(-t // _SUBLANES) * _SUBLANES
+    x = x.reshape(b, hkv, rows // tp, tp, d)[:, :, :, :t]
+    return x.transpose(0, 3, 1, 2, 4).reshape(b, t, -1, d)
+
+
+def _as_keys(x):
+    """[B, K, Hkv, D] -> [K, B * Hkv * D]: time-major, as the state
+    holds a cache, so that `[cache; k]` reaches the kernel by one pass
+    over the cache (a cast, the family's rotation fused into it) and
+    not by a transposed copy of it; not padded, for the same reason."""
+    return x.transpose(1, 0, 2, 3).reshape(x.shape[1], -1)
+
+
+def _from_keys(x, like, no_grad_keys):
+    """A gradient [K - first, B * Hkv * D] of the keys from `first` on
+    as one of all K like `like` [B, K, Hkv, 0]: zeros before
+    `no_grad_keys`."""
+    b, num_keys, hkv, _ = like.shape
+    first = num_keys - x.shape[0]
+    x = x.reshape(x.shape[0], b, hkv, -1).transpose(1, 0, 2, 3)
+    if no_grad_keys:
+        takes = jnp.arange(first, num_keys) >= no_grad_keys
+        x = jnp.where(takes[None, :, None, None], x, 0)
+    x = jnp.pad(x, ((0, 0), (first, 0), (0, 0), (0, 0)))
+    return x.astype(like.dtype)
+
+
+def _operands(q, k_all, v_all, mask, on_chip):
+    """The four operands as the kernels read them: the mask padded with
+    False to a whole number of either pass's blocks."""
+    t, hkv = q.shape[1], k_all.shape[2]
+    num_keys = k_all.shape[1]
+    tp = -(-t // _SUBLANES) * _SUBLANES
+    kp = max(
+        -(-num_keys // block) * block
+        for block in (
+            key_block(num_keys, _FORWARD_KEYS),
+            key_block(num_keys, _BACKWARD_KEYS),
+        )
+    )
+    operand = jnp.bfloat16 if on_chip else jnp.float32
+    return (
+        _as_rows(q.astype(operand), hkv, tp),
+        _as_keys(k_all.astype(operand)),
+        _as_keys(v_all.astype(operand)),
+        jnp.pad(
+            mask.astype(jnp.int8),
+            ((0, 0), (0, tp - t), (0, kp - num_keys)),
+        ),
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _fused_attend(q, k_all, v_all, mask, no_grad_keys, on_chip):
+    return _fused_attend_fwd(q, k_all, v_all, mask, no_grad_keys, on_chip)[0]
+
+
+def _fused_attend_fwd(q, k_all, v_all, mask, no_grad_keys, on_chip):
+    operands = _operands(q, k_all, v_all, mask, on_chip)
+    out, lse = _forward_call(
+        *operands, q.shape[2] // k_all.shape[2], not on_chip
+    )
+    result = _from_rows(out, q.shape[1]).astype(v_all.dtype)
+    # Empty carriers of what the gradients are shaped and typed like.
+    like = tuple(
+        jnp.zeros(x.shape[:-1] + (0,), x.dtype) for x in (q, k_all, v_all)
+    )
+    return result, (operands, out, lse, like)
+
+
+def _fused_attend_bwd(no_grad_keys, on_chip, residuals, dresult):
+    operands, out, lse, like = residuals
+    q_rows, keys, _, mask = operands
+    hkv, tp = q_rows.shape[1], mask.shape[1]
+    dq, dk, dv = _backward_call(
+        *operands, out, lse,
+        _as_rows(dresult.astype(jnp.float32), hkv, tp),
+        q_rows.shape[2] // tp,
+        no_grad_keys // key_block(keys.shape[0], _BACKWARD_KEYS),
+        not on_chip,
+    )
+    return (
+        _from_rows(dq, like[0].shape[1]).astype(like[0].dtype),
+        _from_keys(dk, like[1], no_grad_keys),
+        _from_keys(dv, like[2], no_grad_keys),
+        None,
+    )
+
+
+_fused_attend.defvjp(_fused_attend_fwd, _fused_attend_bwd)
+
+
+def fused_attend(q, k_all, v_all, mask, no_grad_keys=0):
+    """softmax(mask(q k^T / sqrt(D))) v with grouped heads, as
+    `ops/attention.dense_transformer_attend` with no `rel_bias`, the
+    scores never in HBM (see the module's header).
+
+    q: [B, T, H, D]; k_all, v_all: [B, K, Hkv, D] with Hkv a divisor of
+    H (query head j reads key/value head j // (H // Hkv)); mask:
+    [B, T, K] bool, True where the query admits the key, at least one
+    key a query. Returns [B, T, H, D] in v_all's dtype. Differentiable
+    in q, k_all and v_all; the first `no_grad_keys` (a Python int) of
+    k_all and v_all take zeros for a gradient, and the blocks that hold
+    no other key cost the backward pass neither their two products nor
+    the write of their rows.
+    """
+    return _fused_attend(
+        q, k_all, v_all, mask, no_grad_keys, jax.default_backend() == "tpu"
+    )

@@ -2161,7 +2161,8 @@ def train(flags):
                 # block applications and cache bytes a row they cost,
                 # and where its exit gates would let go. Block
                 # applications traced through the two-leg attention
-                # (ops/attention.cached_transformer_attend).
+                # (ops/attention.cached_transformer_attend) or through
+                # the fused pass (ops/fused_attention.fused_attend).
                 for family, names in (
                     ("moe", (
                         "assignments", "load_max_over_mean",
@@ -2172,7 +2173,9 @@ def train(flags):
                         "cache_bytes_per_row", "expected_exit_pass",
                         "exit_p_last",
                     )),
-                    ("attention", ("two_leg_applications",)),
+                    ("attention", (
+                        "two_leg_applications", "fused_applications",
+                    )),
                 ):
                     for name in names:
                         if f"{family}_{name}" in stats_now:
