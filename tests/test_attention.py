@@ -353,6 +353,95 @@ def test_cached_transformer_attend_is_the_dense_body(heads, kv_heads, cache):
         assert float(jnp.abs(got[3]).max()) > 0 < float(jnp.abs(got[4]).max())
 
 
+def _two_legs_as_at_pr_35(q, k, v, cache_k, cache_v, cache_mask, seq_mask):
+    """`cached_transformer_attend`'s body as PR 35 wrote it, kept here
+    word for word: PR 38 put `latent_cached_attend` beside it and may
+    not have moved it."""
+    from torchbeast_tpu.ops.attention import BIG_NEG
+
+    B, T, H, D = q.shape
+    Hkv = k.shape[2]
+    scale = D ** -0.5
+    q = q.reshape(B, T, Hkv, H // Hkv, D)
+
+    def scores(spec, keys, mask):
+        s = jnp.einsum(spec, q, keys).astype(jnp.float32) * scale
+        return jnp.where(mask[:, None, None], s, BIG_NEG)
+
+    s_c = scores("bqhgd,mbhd->bhgqm", cache_k, cache_mask)
+    s_u = scores("bqhgd,bkhd->bhgqk", k, seq_mask)
+    top = jax.lax.stop_gradient(
+        jnp.maximum(s_c.max(axis=-1), s_u.max(axis=-1))
+    )[..., None]
+    p_c = jnp.exp(s_c - top)
+    out_c = jnp.einsum(
+        "bhgqm,mbhd->bqhgd", p_c.astype(cache_v.dtype), cache_v
+    )
+    p_u = jnp.exp(s_u - top)
+    out_u = jnp.einsum("bhgqk,bkhd->bqhgd", p_u.astype(v.dtype), v)
+    den = p_c.sum(axis=-1) + p_u.sum(axis=-1)
+    out = (out_c + out_u) / den.transpose(0, 3, 1, 2)[..., None].astype(
+        out_u.dtype
+    )
+    return out.reshape(B, T, H, D)
+
+
+@pytest.mark.parametrize("cache", ["partly", "full"])
+@pytest.mark.parametrize(
+    "heads, kv_heads", [(H, H), (8, 2)], ids=["mha", "gqa-8-on-2"]
+)
+def test_cached_transformer_attend_is_what_it_was(heads, kv_heads, cache):
+    """The old call, bit for bit: outputs and all five gradients of the
+    function OLMoE and Ouro attend through equal its PR 35 body's."""
+    from torchbeast_tpu.ops.attention import cached_transformer_attend
+
+    case = _two_leg_case(kv_heads, cache, seed=3 + kv_heads, heads=heads)
+    now, then = jax.jit(cached_transformer_attend), jax.jit(_two_legs_as_at_pr_35)
+    np.testing.assert_array_equal(now(*case), then(*case))
+
+    def total(fn):
+        return jax.jit(jax.grad(
+            lambda *operands: jnp.sum(jnp.sin(fn(*operands, *case[5:]))),
+            range(5),
+        ))
+
+    for a, b in zip(
+        total(cached_transformer_attend)(*case[:5]),
+        total(_two_legs_as_at_pr_35)(*case[:5]),
+    ):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("cache", ["invalid", "partly", "full"])
+def test_latent_cached_attend_with_nothing_compressed_is_the_two_legs(cache):
+    """`latent_cached_attend` where the latent IS a head's key and
+    value (one head, the decompression matrices the identity, no rope
+    part worth the name): the absorbed cache leg is then the plain one,
+    and the function computes what `cached_transformer_attend` does."""
+    from torchbeast_tpu.ops.attention import (
+        cached_transformer_attend,
+        latent_cached_attend,
+    )
+
+    q, k, _, cache_k, _, cache_mask, seq_mask = _two_leg_case(
+        1, cache, seed=5, heads=1
+    )
+    size = q.shape[-1]
+    rope = jnp.zeros(q.shape[:-1] + (2,), jnp.float32)
+    got = latent_cached_attend(
+        q, rope, k, rope[:, :, :1], k, cache_k,
+        jnp.zeros(cache_k.shape[:-1] + (2,), jnp.float32),
+        jnp.eye(size)[:, None, :], jnp.eye(size)[:, None, :],
+        cache_mask, seq_mask,
+    )
+    # The two-leg body scales by D^-0.5, the latent one by (D + 2)^-0.5.
+    want = cached_transformer_attend(
+        q * (size / (size + 2)) ** 0.5, k, k, cache_k, cache_k,
+        cache_mask, seq_mask,
+    )
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
 def test_cached_transformer_attend_refuses_heads_that_do_not_divide():
     from torchbeast_tpu.ops.attention import cached_transformer_attend
 

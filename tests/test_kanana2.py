@@ -1,0 +1,797 @@
+"""The `kanana2` family (models/kanana2.py; the absorbed cache leg in
+ops/attention.latent_cached_attend; sigmoid routing, the selection bias
+and the shared expert in models/moe.py DroplessMoE; cache entries of
+two unequal leaves in models/transformer.py; the bias's step in
+learner.update_body): against the plain reference on seeded weights,
+absorbed against decompressed attention, batch forward against stepwise
+acting through the latent caches and through the state table, the
+shares of the routed experts adding up to the layer, and the bias
+moving by its rule and by nothing else."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.flatten_util
+import jax.numpy as jnp
+
+from perfbench.reference import kanana2_policy as reference
+from torchbeast_tpu import learner as learner_lib
+from torchbeast_tpu import monobeast, polybeast
+from torchbeast_tpu.models import Kanana2Net, create_model, kanana2, moe
+from torchbeast_tpu.ops import attention
+from torchbeast_tpu.runtime.state_table import DeviceStateTable
+
+T, B, A = 6, 2, 4
+FRAME = (8, 8, 1)
+# A shrunken `PUBLISHED`: 4 heads of 16 + 8 (values of 12) over a latent
+# of 24, a dense SwiGLU of 64, 16 routed experts of 20, top 3, two
+# shared experts (one SwiGLU of 40). The caches are `M` slots.
+SMALL = dict(
+    d_model=48, num_heads=4, latent_rank=24, nope_head_dim=16,
+    rope_head_dim=8, value_head_dim=12, mlp_width=64, num_experts=16,
+    experts_per_token=3, expert_width=20, shared_experts=2,
+)
+LAYERS = 3  # the dense layer and two MoE layers
+M = 9
+# As tests/test_olmoe.py: on the CPU both sides compute in float32 at
+# full precision and differ by the order of their sums.
+RTOL = ATOL = 1e-5
+
+
+def _inputs(seed, done_steps=(), t=T, rows=B):
+    rng = np.random.default_rng(seed)
+    done = np.zeros((t, rows), bool)
+    for step, row in done_steps:
+        done[step, row] = True
+    return {
+        "frame": jnp.asarray(
+            rng.integers(0, 256, (t, rows) + FRAME, dtype=np.uint8)
+        ),
+        "reward": jnp.asarray(rng.standard_normal((t, rows)), jnp.float32),
+        "done": jnp.asarray(done),
+        "last_action": jnp.asarray(rng.integers(0, A, (t, rows))),
+    }
+
+
+def _learner_batch(seed, done_steps):
+    rng = np.random.default_rng(seed + 100)
+    lead = (T, B)
+    return dict(
+        _inputs(seed, done_steps),
+        episode_return=jnp.asarray(rng.standard_normal(lead), jnp.float32),
+        episode_step=jnp.zeros(lead, jnp.int32),
+        action=jnp.asarray(rng.integers(0, A, lead)),
+        policy_logits=jnp.asarray(
+            rng.standard_normal(lead + (A,)), jnp.float32
+        ),
+        baseline=jnp.asarray(rng.standard_normal(lead), jnp.float32),
+    )
+
+
+def _model(share=(0, 1), seed=0, **overrides):
+    model = Kanana2Net(
+        num_actions=A, num_layers=LAYERS, memory_len=M, expert_share=share,
+        **dict(SMALL, **overrides),
+    )
+    params = model.init(
+        {"params": jax.random.PRNGKey(seed), "action": jax.random.PRNGKey(1)},
+        _inputs(0), model.initial_state(B),
+    )
+    # The family starts its side inputs' projection and its selection
+    # biases at zero: give both values, so that the comparisons cover
+    # those paths too.
+    inner = dict(params["params"])
+    assert not np.any(inner["extras"]["kernel"])
+    inner["extras"] = dict(inner["extras"], kernel=0.3 * jax.random.normal(
+        jax.random.PRNGKey(seed + 7), inner["extras"]["kernel"].shape
+    ))
+    for layer in range(1, LAYERS):
+        block = dict(inner[f"block_{layer}"])
+        assert not np.any(block["moe"]["e_score_correction_bias"])
+        block["moe"] = dict(
+            block["moe"],
+            e_score_correction_bias=0.1 * jax.random.normal(
+                jax.random.PRNGKey(seed + layer), (SMALL["num_experts"],)
+            ),
+        )
+        inner[f"block_{layer}"] = block
+    return model, {"params": inner}
+
+
+def _reference_config(share=(0, 1), **overrides):
+    widths = dict(SMALL, **overrides)
+    return {
+        "num_attention_heads": widths["num_heads"],
+        "kv_lora_rank": widths["latent_rank"], "q_lora_rank": None,
+        "qk_nope_head_dim": widths["nope_head_dim"],
+        "qk_rope_head_dim": widths["rope_head_dim"],
+        "qk_head_dim": widths["nope_head_dim"] + widths["rope_head_dim"],
+        "v_head_dim": widths["value_head_dim"],
+        "rope_interleave": True, "rope_scaling": None, "rope_theta": 1e6,
+        "rms_norm_eps": 1e-6, "num_hidden_layers": LAYERS,
+        "first_k_dense_replace": 1,
+        "published_n_routed_experts": widths["num_experts"],
+        "n_routed_experts": widths["num_experts"] // share[1],
+        "expert_share": list(share),
+        "num_experts_per_tok": widths["experts_per_token"],
+        "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+        "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+        "routed_scaling_factor": 2.448, "bias_update_rate": 0.001,
+        "memory_len": M, "num_actions": A,
+        "discounting": 0.99, "baseline_cost": 0.5, "entropy_cost": 0.0006,
+    }
+
+
+def _warm_state(model, params, seed, unrolls=1, rows=B):
+    """Caches an actor would hold: `unrolls` unrolls of 6 steps in, an
+    episode end in the first. After one the 9-slot caches are part
+    full; after two, full."""
+    state = model.initial_state(rows)
+    for i in range(unrolls):
+        _, state = model.apply(
+            params,
+            _inputs(seed + i, [(2, 1)] if i == 0 else (), rows=rows),
+            state, sample_action=False,
+        )
+    return state
+
+
+def _loss_and_grads(model, params, batch, state):
+    hp = learner_lib.HParams(batch_size=B, unroll_length=T - 1)
+    (loss, stats), grads = jax.value_and_grad(
+        lambda p: learner_lib.compute_loss(model, p, batch, state, hp),
+        has_aux=True,
+    )(params)
+    return loss, stats, grads
+
+
+@pytest.mark.parametrize(
+    "share", [(0, 1), (0, 8)], ids=["all-16-experts", "share-0-of-8"]
+)
+def test_family_agrees_with_the_reference(share):
+    model, params = _model(share)
+    config = _reference_config(share)
+    state = _warm_state(model, params, seed=5)
+    batch = _learner_batch(7, done_steps=[(3, 0)])
+
+    out, new_state = model.apply(params, batch, state, sample_action=False)
+    logits, baseline, ref_state, _ = reference.forward(
+        params, batch, state, config
+    )
+    np.testing.assert_allclose(out.policy_logits, logits, RTOL, ATOL)
+    np.testing.assert_allclose(out.baseline, baseline, RTOL, ATOL)
+    for got, want in zip(
+        jax.tree_util.tree_leaves(new_state),
+        jax.tree_util.tree_leaves(ref_state),
+    ):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, RTOL, ATOL)
+
+    loss, stats, grads = _loss_and_grads(model, params, batch, state)
+    ref_loss, ref_grads = jax.value_and_grad(reference.loss)(
+        params, batch, state, config
+    )
+    scale = float(reference.loss_and_scale(params, batch, state, config)[1])
+    assert abs(float(loss) - float(ref_loss)) <= RTOL * scale
+    flat, ref_flat = (
+        jax.flatten_util.ravel_pytree(g)[0] for g in (grads, ref_grads)
+    )
+    np.testing.assert_allclose(
+        flat, ref_flat, rtol=0, atol=RTOL * float(jnp.max(jnp.abs(ref_flat)))
+    )
+    # No auxiliary loss; the biases take no gradient on either side.
+    assert float(stats["aux_loss"]) == 0.0
+    for tree in (grads, ref_grads):
+        for layer in (1, 2):
+            assert not np.any(
+                tree["params"][f"block_{layer}"]["moe"][
+                    "e_score_correction_bias"
+                ]
+            )
+    # Routing is over all the experts whatever is held; the steps the
+    # layers sow for their biases are the reference's rule.
+    assert float(stats["moe_assignments"]) == 3 * T * B * 2
+    assert float(stats["moe_shared_applications"]) == 2
+    assert float(stats["attention_latent_applications"]) == LAYERS
+    assert float(stats["attention_latent_cache_bytes_per_row"]) == (
+        4 * LAYERS * M * (24 + 8 + 1)
+    )
+    assert 0.1 < float(stats["moe_bias_abs_max"]) < 0.4
+    steps = reference.bias_steps(params, batch, state, config)
+    for layer, want in zip((1, 2), steps):
+        got = stats[learner_lib.PARAM_STEPS_KEY][f"block_{layer}"]["moe"][
+            "e_score_correction_bias"
+        ]
+        np.testing.assert_array_equal(got, want)
+        assert set(np.unique(np.abs(want))) <= {0.0, np.float32(0.001)}
+    if share == (0, 1):
+        assert "moe_held_assignments" not in stats
+    else:
+        assert 0 < float(stats["moe_held_assignments"]) < 3 * T * B * 2
+
+
+def test_absorbed_equals_decompressed():
+    """`latent_cached_attend` against dense attention over `[cache;
+    unroll]` with `kv_b` applied to every cached latent: the same
+    values, and the same gradient for the decompression matrix, which
+    the absorbed leg reads in two halves and never multiplies a cached
+    latent by. A third of the cache is masked out."""
+    rng = np.random.default_rng(3)
+    rows, steps, slots, H, C, Dn, Dr, Dv = 2, 5, 7, 4, 24, 16, 8, 12
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    q_nope, q_rope = normal(rows, steps, H, Dn), normal(rows, steps, H, Dr)
+    c, k_r = normal(rows, steps, C), normal(rows, steps, 1, Dr)
+    cache_c, cache_r = normal(slots, rows, 1, C), normal(slots, rows, 1, Dr)
+    w_kvb = 0.3 * normal(C, H, Dn + Dv)
+    cache_mask = jnp.asarray(rng.random((rows, steps, slots)) < 0.67)
+    seq_mask = jnp.broadcast_to(
+        jnp.tril(jnp.ones((steps, steps), bool)), (rows, steps, steps)
+    )
+    theta = 1e6
+
+    def absorbed(w):
+        kv = jnp.einsum("btc,chd->bthd", c, w)
+        return attention.latent_cached_attend(
+            q_nope, kanana2.rope_pairs(q_rope, jnp.arange(steps), theta),
+            kv[..., :Dn], kanana2.rope_pairs(k_r, jnp.arange(steps), theta),
+            kv[..., Dn:], cache_c, cache_r, w[..., :Dn], w[..., Dn:],
+            cache_mask, seq_mask,
+            place_cache_keys=lambda keys, times: kanana2.rope_pairs(
+                keys, times, theta, time_axis=0
+            ),
+        )
+
+    def decompressed(w):
+        latents = jnp.concatenate(
+            [cache_c[:, :, 0].transpose(1, 0, 2), c], axis=1
+        )
+        rope_keys = jnp.concatenate(
+            [cache_r.transpose(1, 0, 2, 3), k_r], axis=1
+        )
+        times = jnp.concatenate([jnp.arange(slots) - slots, jnp.arange(steps)])
+        kv = jnp.einsum("bkc,chd->bkhd", latents, w)
+        keys = jnp.concatenate([
+            kv[..., :Dn],
+            jnp.repeat(kanana2.rope_pairs(rope_keys, times, theta), H, axis=2),
+        ], axis=-1)
+        queries = jnp.concatenate([
+            q_nope, kanana2.rope_pairs(q_rope, jnp.arange(steps), theta),
+        ], axis=-1)
+        # Values as wide as the keys for the dense body, then cut back.
+        values = jnp.pad(kv[..., Dn:], ((0, 0),) * 3 + ((0, Dn + Dr - Dv),))
+        return attention.dense_transformer_attend(
+            queries, keys, values,
+            jnp.concatenate([cache_mask, seq_mask], axis=-1), None, None,
+        )[..., :Dv]
+
+    np.testing.assert_allclose(
+        absorbed(w_kvb), decompressed(w_kvb), RTOL, ATOL
+    )
+    weight = normal(rows, steps, H, Dv)
+    grads = [
+        jax.grad(lambda w: jnp.sum(weight * f(w)))(w_kvb)
+        for f in (absorbed, decompressed)
+    ]
+    assert float(jnp.max(jnp.abs(grads[1]))) > 0.1
+    np.testing.assert_allclose(grads[0], grads[1], rtol=1e-4, atol=1e-5)
+    # The cache is data: asked for, its gradient is the dense path's
+    # too (the acting path never asks).
+    assert absorbed(w_kvb).shape == (rows, steps, H, Dv)
+
+
+def test_interleaved_rope_turns_neighbouring_pairs():
+    """`rope_interleave`: (x[2i], x[2i+1]) is the pair, not (x[i],
+    x[i + D/2]); the program's and the reference's agree, in both
+    layouts."""
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((2, 3, 1, 8)),
+                    jnp.float32)
+    times = jnp.asarray([-2, 0, 5])
+    got = kanana2.rope_pairs(x, times, 1e6)
+    for row in range(2):
+        np.testing.assert_allclose(
+            got[row], reference._rope_pairs(x[row], times, 1e6), RTOL, ATOL
+        )
+    np.testing.assert_allclose(
+        kanana2.rope_pairs(x.transpose(1, 0, 2, 3), times, 1e6, time_axis=0),
+        got.transpose(1, 0, 2, 3), RTOL, ATOL,
+    )
+    # Position 0 leaves x alone; the first pair turns by the position.
+    np.testing.assert_allclose(got[:, 1], x[:, 1], RTOL, ATOL)
+    np.testing.assert_allclose(
+        got[0, 2, 0, :2],
+        [x[0, 2, 0, 0] * np.cos(5) - x[0, 2, 0, 1] * np.sin(5),
+         x[0, 2, 0, 1] * np.cos(5) + x[0, 2, 0, 0] * np.sin(5)],
+        RTOL, ATOL,
+    )
+
+
+@pytest.mark.parametrize("unrolls", [0, 1, 2], ids=["empty", "part", "full"])
+def test_batch_forward_equals_stepwise_acting_through_the_latent_caches(
+    unrolls
+):
+    """The learner's [T, B] forward (the cache leg absorbed over M
+    slots, the unroll leg over T decompressed keys) and the actor's T=1
+    forwards through the rolling caches give the same logits and leave
+    the same latents and rope keys, from caches of any fill and across
+    an episode end."""
+    model, params = _model()
+    state = _warm_state(model, params, seed=2, unrolls=unrolls)
+    inputs = _inputs(3, done_steps=[(3, 1)])
+    full, full_state = model.apply(params, inputs, state, sample_action=False)
+    logits = []
+    for t in range(T):
+        step = {k: v[t : t + 1] for k, v in inputs.items()}
+        out, state = model.apply(params, step, state, sample_action=False)
+        logits.append(out.policy_logits[0])
+    np.testing.assert_allclose(
+        np.stack(logits), full.policy_logits, rtol=2e-4, atol=2e-5
+    )
+    for got, want in zip(
+        jax.tree_util.tree_leaves(state),
+        jax.tree_util.tree_leaves(full_state),
+    ):
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("via", ["reset", "rebuild"])
+def test_stepwise_acting_through_the_state_table_equals_the_batch_forward(
+    via
+):
+    """Three actors' slots in a `DeviceStateTable` whose rows hold the
+    three layers' (latent [M, 1, 1, 24], rope key [M, 1, 1, 8], valid):
+    entries of unequal leaves. The rows arrive in another order every
+    step and one episode ends on the way; every step's logits equal the
+    batch forward's and the table ends with what that forward leaves;
+    reset and rebuild bring back empty caches of both shapes."""
+    model, params = _model()
+    rows = 3
+    inputs = _inputs(4, done_steps=[(3, 2)], rows=rows)
+    full, full_state = model.apply(
+        params, inputs, model.initial_state(rows), sample_action=False
+    )
+
+    def act(ctx, env_outputs, agent_state):
+        out, new_state = model.apply(
+            params, env_outputs, agent_state, sample_action=False
+        )
+        return {"logits": out.policy_logits}, new_state
+
+    table = DeviceStateTable(
+        model.initial_state(1), num_slots=rows, act_fn=act, batch_dim=1
+    )
+    orders = [[0, 1, 2], [2, 0, 1], [1, 2, 0], [2, 1, 0], [0, 2, 1], [1, 0, 2]]
+    for t, order in enumerate(orders):
+        step = {
+            k: np.asarray(v[t : t + 1])[:, order] for k, v in inputs.items()
+        }
+        out = table.step(
+            np.asarray(order, np.int32), np.ones(rows, bool), step
+        )
+        np.testing.assert_allclose(
+            table.fetch(out, rows)["logits"][0],
+            np.asarray(full.policy_logits)[t][order],
+            rtol=2e-4, atol=2e-5,
+        )
+    for slot in range(rows):
+        for entry, held in enumerate(table.read_slot(slot)):
+            assert [np.shape(leaf) for leaf in held] == [
+                (M, 1, 1, 24), (M, 1, 1, 8), (M, 1)
+            ]
+            for got, want in zip(held, full_state[entry]):
+                np.testing.assert_allclose(
+                    got, np.asarray(want)[:, slot : slot + 1],
+                    rtol=2e-4, atol=2e-5,
+                )
+    if via == "reset":
+        table.reset([1])
+        assert all(np.any(e[0]) for e in table.read_slot(0))
+    else:
+        table.poison()
+        table.rebuild()
+    for latent, rope_key, valid in table.read_slot(1):
+        assert np.shape(latent) == (M, 1, 1, 24)
+        assert np.shape(rope_key) == (M, 1, 1, 8)
+        assert not np.any(latent) and not np.any(rope_key)
+        assert not np.any(valid)
+
+
+def _layer(held=None, tokens=40, seed=0, E=16, K=3, **overrides):
+    fields = dict(
+        d_ff=8, num_experts=E, top_k=K, aux_loss_weight=0.0,
+        renormalise=True, held=held, scoring="sigmoid", selection_bias=True,
+        bias_update_rate=0.001, routed_scaling=2.448, shared_width=12,
+    )
+    layer = moe.DroplessMoE(**dict(fields, **overrides))
+    x = jax.random.normal(jax.random.PRNGKey(seed), (tokens, 16))
+    params = layer.init(jax.random.PRNGKey(seed + 1), x)
+    return layer, x, params
+
+
+def _shared(x, p):
+    return (
+        jax.nn.silu(x @ p["shared_gate"]["kernel"])
+        * (x @ p["shared_up"]["kernel"])
+    ) @ p["shared_down"]["kernel"]
+
+
+def test_gates_sum_to_the_scaling_factor():
+    """With every expert the same matrix the routed sum is exactly
+    2.448 times that expert's output: the chosen sigmoid scores,
+    renormalised over the six, times `routed_scaling_factor`. The shared
+    expert comes on top, unscaled."""
+    layer, x, params = _layer()
+    p = params["params"]
+    same = {
+        k: jnp.broadcast_to(p[k][:1], p[k].shape)
+        for k in ("w_gate", "w_up", "w_down")
+    }
+    y = layer.apply({"params": dict(p, **same)}, x)
+    expert = (
+        jax.nn.silu(x @ p["w_gate"][0]) * (x @ p["w_up"][0])
+    ) @ p["w_down"][0]
+    np.testing.assert_allclose(
+        y, 2.448 * expert + _shared(x, p), RTOL, ATOL
+    )
+    # Sigmoid scores are not a distribution: as they come, the three
+    # chosen sum to more than one.
+    scores = jax.nn.sigmoid(x @ p["router"]["kernel"])
+    assert float(jax.lax.top_k(scores, 3)[0].sum(axis=-1).min()) > 1.2
+
+
+def test_the_bias_chooses_and_is_no_part_of_the_gate():
+    """A bias that lifts the fourth-best expert over the third flips a
+    token's last choice; the gates are still the chosen experts' own
+    scores (renormalised over the new three), on the program and on the
+    reference."""
+    E, K = 16, 3
+    layer, x, params = _layer(tokens=8, seed=2)
+    p = dict(params["params"])
+    scores = jax.nn.sigmoid(x @ p["router"]["kernel"])
+    ranked = np.argsort(-np.asarray(scores), axis=-1)
+    third, fourth = ranked[0, K - 1], ranked[0, K]
+    gap = float(scores[0, third] - scores[0, fourth])
+    assert gap > 0
+    bias = np.zeros(E, np.float32)
+    bias[fourth] = gap + 1e-3
+
+    def experts_of(expert):
+        return (
+            jax.nn.silu(x @ p["w_gate"][expert]) * (x @ p["w_up"][expert])
+        ) @ p["w_down"][expert]
+
+    def by_hand(chosen):
+        s = scores[0, np.asarray(chosen)]
+        gates = 2.448 * s / (s.sum() + 1e-20)
+        return sum(
+            g * experts_of(e)[0] for g, e in zip(gates, chosen)
+        ) + _shared(x, p)[0]
+
+    config = {
+        "published_n_routed_experts": E, "n_routed_experts": E,
+        "expert_share": [0, 1], "num_experts_per_tok": K,
+        "scoring_func": "sigmoid", "topk_method": "noaux_tc", "n_group": 1,
+        "topk_group": 1, "norm_topk_prob": True,
+        "routed_scaling_factor": 2.448,
+    }
+    for b, chosen in (
+        (np.zeros(E, np.float32), ranked[0, :K]),
+        (bias, list(ranked[0, : K - 1]) + [fourth]),
+    ):
+        weights = dict(p, e_score_correction_bias=jnp.asarray(b))
+        want = by_hand(list(chosen))
+        np.testing.assert_allclose(
+            layer.apply({"params": weights}, x)[0], want, RTOL, ATOL
+        )
+        np.testing.assert_allclose(
+            reference._experts(x, weights, config)[0], want, RTOL, ATOL
+        )
+    # A bias added into the gates would give something else.
+    s = scores[0, np.asarray(list(ranked[0, : K - 1]) + [fourth])]
+    wrong = s.at[-1].add(bias[fourth])
+    assert abs(float((wrong / wrong.sum())[-1] - (s / s.sum())[-1])) > 1e-4
+
+
+@pytest.mark.parametrize("side", ["program", "reference"])
+def test_the_eight_shares_add_up_to_the_uncut_layer(side):
+    """The test that ties the share to the model: 32 experts (the
+    interpreted grouped kernels are slow over 128), top 6, the same
+    router and biases; the routed parts of `held` (0, 4), (4, 4), ...
+    (28, 4), each holding its own eighth of the uncut layer's expert
+    weights, plus the shared expert COUNTED ONCE (every chip computes
+    it alike), add up to the uncut 32-expert layer's output. On the program (values and the gradient with respect to x)
+    and on the reference."""
+    E, K, tokens = 32, 6, 40
+    _, x, params = _layer(None, tokens=tokens, seed=4, E=E, K=K)
+    p = dict(params["params"])
+    p["e_score_correction_bias"] = 0.05 * jax.random.normal(
+        jax.random.PRNGKey(9), (E,)
+    )
+
+    def share_params(first, count):
+        return dict(p, **{
+            k: p[k][first : first + count]
+            for k in ("w_gate", "w_up", "w_down")
+        })
+
+    if side == "program":
+        def run(first, count, x):
+            held = None if count == E else (first, count)
+            layer, _, _ = _layer(held, tokens=tokens, E=E, K=K)
+            return layer.apply({"params": share_params(first, count)}, x)
+    else:
+        def run(first, count, x):
+            config = {
+                "published_n_routed_experts": E, "n_routed_experts": count,
+                "expert_share": [first // count, E // count],
+                "num_experts_per_tok": K, "scoring_func": "sigmoid",
+                "topk_method": "noaux_tc", "n_group": 1, "topk_group": 1,
+                "norm_topk_prob": True, "routed_scaling_factor": 2.448,
+            }
+            return reference._experts(x, share_params(first, count), config)
+
+    firsts = range(0, E, E // 8)
+    whole = run(0, E, x)
+    shared = _shared(x, p)
+    parts = [run(first, E // 8, x) - shared for first in firsts]
+    assert all(float(jnp.max(jnp.abs(part))) > 0 for part in parts)
+    np.testing.assert_allclose(sum(parts) + shared, whole, RTOL, ATOL)
+    # No share is the whole, and the shared expert counted eight times
+    # is not it either.
+    assert float(jnp.max(jnp.abs(parts[0] + shared - whole))) > 1e-3
+    assert float(jnp.max(jnp.abs(sum(parts) + 8 * shared - whole))) > 1e-3
+
+    grad_whole = jax.grad(lambda x: jnp.sum(jnp.sin(run(0, E, x))))(x)
+    weight = jnp.cos(whole)
+    grad_parts = sum(
+        jax.grad(lambda x, f=first: jnp.sum(
+            weight * (run(f, E // 8, x) - _shared(x, p))
+        ))(x)
+        for first in firsts
+    ) + jax.grad(lambda x: jnp.sum(weight * _shared(x, p)))(x)
+    np.testing.assert_allclose(grad_parts, grad_whole, rtol=1e-4, atol=1e-5)
+
+
+def test_a_share_visits_no_row_of_anothers_experts():
+    """A layer that holds experts 4..5 of 16: a token that chose
+    neither gets the shared expert's output and nothing else, exactly;
+    the held experts' weight gradients come from their own rows alone
+    (a token that chose neither contributes nothing)."""
+    layer, x, params = _layer((4, 2), tokens=64, seed=6)
+    p = params["params"]
+    assert p["w_gate"].shape == (2, 16, 8)
+    assert p["router"]["kernel"].shape == (16, 16)
+    scores = jax.nn.sigmoid(x @ p["router"]["kernel"])
+    _, chosen = jax.lax.top_k(scores, 3)
+    mine = np.isin(np.asarray(chosen), [4, 5]).any(axis=-1)
+    assert 0 < mine.sum() < len(mine)
+    y = layer.apply(params, x)
+    shared = _shared(x, p)
+    np.testing.assert_array_equal(
+        np.asarray(y)[~mine], np.asarray(shared)[~mine]
+    )
+    assert float(jnp.max(jnp.abs((y - shared)[mine]))) > 1e-3
+
+    def total(p, rows):
+        out = layer.apply({"params": p}, x)
+        return jnp.sum(jnp.where(rows[:, None], jnp.sin(out), 0.0))
+
+    others = jax.grad(total)(p, jnp.asarray(~mine))
+    for name in ("w_gate", "w_up", "w_down"):
+        assert not np.any(others[name])
+    assert np.any(jax.grad(total)(p, jnp.asarray(mine))["w_down"])
+
+
+def test_the_bias_moves_by_its_rule_and_by_nothing_else():
+    """Two updates through `learner.make_update_step` with RMSprop: the
+    selection biases end where the reference's rule puts them (u x
+    sign(mean load - load) of each update's own batch and weights,
+    every step -u, 0 or +u), the optimizer's second moment for them
+    stays zero, every other parameter moves, and the stats count the
+    leaves moved."""
+    model, params = _model((0, 8))
+    config = _reference_config((0, 8))
+    hp = learner_lib.HParams(
+        batch_size=B, unroll_length=T - 1, learning_rate=1e-3,
+        total_steps=100 * B * (T - 1),
+    )
+    optimizer = learner_lib.make_optimizer(hp)
+    opt_state = optimizer.init(params)
+    update = learner_lib.make_update_step(model, optimizer, hp, donate=False)
+    state = _warm_state(model, params, seed=5)
+
+    def biases(tree):
+        return [
+            np.asarray(
+                tree["params"][f"block_{layer}"]["moe"][
+                    "e_score_correction_bias"
+                ]
+            )
+            for layer in (1, 2)
+        ]
+
+    start = params
+    want = biases(params)
+    for i in range(2):
+        batch = _learner_batch(20 + i, done_steps=[(2, 0)])
+        steps = reference.bias_steps(params, batch, state, config)
+        assert all(
+            set(np.unique(np.asarray(s))) <= {
+                np.float32(-0.001), 0.0, np.float32(0.001)
+            } and np.any(s)
+            for s in steps
+        )
+        want = [w + np.asarray(s) for w, s in zip(want, steps)]
+        params, opt_state, stats = update(params, opt_state, batch, state)
+        assert float(stats["moe_bias_steps"]) == 2
+        assert learner_lib.PARAM_STEPS_KEY not in stats
+    for got, wanted in zip(biases(params), want):
+        np.testing.assert_allclose(got, wanted, rtol=0, atol=1e-7)
+    # RMSprop saw a zero gradient for them: nothing accumulated.
+    moments = [
+        leaf for path, leaf in jax.tree_util.tree_leaves_with_path(opt_state)
+        if "e_score_correction_bias" in jax.tree_util.keystr(path)
+    ]
+    assert moments and not any(np.any(m) for m in moments)
+    moved = jax.tree_util.tree_map(
+        lambda a, b: bool(np.any(a != b)), start, params
+    )
+    assert all(jax.tree_util.tree_leaves(moved))
+
+
+def test_a_sown_step_reaches_the_leaf_at_its_path_or_is_refused():
+    """`learner.add_param_steps`: the step lands on the parameter whose
+    path it was sown under and on nothing else; under a bf16-resident
+    optimizer (whose master copy it would not reach) it is refused."""
+    params = {"params": {
+        "block_1": {"moe": {"e_score_correction_bias": jnp.zeros(4),
+                            "router": {"kernel": jnp.ones((2, 4))}}},
+        "head": {"bias": jnp.ones(3)},
+    }}
+    steps = {"block_1": {"moe": {
+        "e_score_correction_bias": jnp.asarray([0.5, 0.0, -0.5, 0.5])
+    }}}
+    moved, count = learner_lib.add_param_steps(params, steps)
+    assert count == 1
+    np.testing.assert_array_equal(
+        moved["params"]["block_1"]["moe"]["e_score_correction_bias"],
+        [0.5, 0.0, -0.5, 0.5],
+    )
+    np.testing.assert_array_equal(moved["params"]["head"]["bias"], 1.0)
+    np.testing.assert_array_equal(
+        moved["params"]["block_1"]["moe"]["router"]["kernel"], 1.0
+    )
+    with pytest.raises(NotImplementedError, match="float32 resident"):
+        learner_lib.add_param_steps(
+            params, steps, learner_lib.MasterParamsState(None, None)
+        )
+
+
+def test_layer_zero_is_dense_and_the_rest_are_experts():
+    _, params = _model()
+    first, second = params["params"]["block_0"], params["params"]["block_1"]
+    assert "moe" not in first and first["gate"]["kernel"].shape == (48, 64)
+    assert first["down"]["kernel"].shape == (64, 48)
+    assert not {"gate", "up", "down"} & set(second)
+    assert second["moe"]["router"]["kernel"].shape == (48, 16)
+    assert second["moe"]["shared_gate"]["kernel"].shape == (48, 2 * 20)
+    assert second["moe"]["w_gate"].shape == (16, 48, 20)
+    for block in (first, second):
+        assert block["q"]["kernel"].shape == (48, 4 * (16 + 8))
+        assert block["kv_a"]["kernel"].shape == (48, 24 + 8)
+        assert block["kv_a_norm"]["scale"].shape == (24,)
+        assert block["kv_b"].shape == (24, 4 * (16 + 12))
+        assert block["o"]["kernel"].shape == (4 * 12, 48)
+    # A cache entry's two leaves differ; the other families' do not.
+    model = Kanana2Net(num_actions=A, num_layers=3, memory_len=M, **SMALL)
+    assert model.layer_caches() == ((M, 1, (24, 8)),) * 3
+    state = model.initial_state(5)
+    assert [leaf.shape for leaf in state[0]] == [
+        (M, 5, 1, 24), (M, 5, 1, 8), (M, 5)
+    ]
+    olmoe = create_model("olmoe", num_actions=6, num_layers=1)
+    assert olmoe.layer_caches() == ((128, 16, 128),)
+    assert [leaf.shape for leaf in olmoe.initial_state(2)[0]] == [
+        (128, 2, 16, 128), (128, 2, 16, 128), (128, 2)
+    ]
+
+
+def test_registry_builds_the_published_widths_and_refuses_lstm():
+    model = create_model("kanana2", num_actions=6, num_layers=5)
+    assert isinstance(model, Kanana2Net)
+    assert model.zero_init_extras and model.frame_range == (-1.0, 1.0)
+    assert (model.d_model, model.num_heads, model.latent_rank) == (
+        2048, 32, 512
+    )
+    assert (
+        model.nope_head_dim, model.rope_head_dim, model.value_head_dim
+    ) == (128, 64, 128)
+    assert (model.dense_layers, model.mlp_width) == (1, 6144)
+    assert (
+        model.num_experts, model.experts_per_token, model.expert_width,
+        model.shared_experts,
+    ) == (128, 6, 768, 2)
+    assert model.renormalise and model.routed_scaling == 2.448
+    assert (model.rms_norm_eps, model.rope_theta) == (1e-6, 1e6)
+    assert (model.memory_len, model.bias_update_rate) == (4095, 0.001)
+    assert model.layer_caches() == ((4095, 1, (512, 64)),) * 5
+    assert model.held_experts() is None
+    assert create_model("kanana2", num_actions=6).num_layers == 48
+    share = create_model(
+        "kanana2", num_actions=6, num_layers=5, expert_share=(7, 8)
+    )
+    assert share.held_experts() == (112, 16)
+    with pytest.raises(ValueError, match="use_lstm"):
+        create_model("kanana2", num_actions=6, use_lstm=True)
+    with pytest.raises(ValueError, match="at least one MoE layer"):
+        create_model("kanana2", num_actions=6, num_layers=1)
+    for bad in [(8, 8), (0, 3), (-1, 8)]:
+        with pytest.raises(ValueError, match="expert_share"):
+            create_model("kanana2", num_actions=6, expert_share=bad)
+    # The published heads are not the fused pass's (128 lanes a head):
+    # 192-wide unroll keys, one 576-wide cache key.
+    for q_width, keys in ((192, 81), (576, 4095)):
+        assert not attention.fused_pass_applies(
+            (32, 81, 32, q_width), (32, keys, 1, q_width), None
+        )
+
+
+@pytest.mark.parametrize("driver", [monobeast, polybeast], ids=["mono", "poly"])
+def test_parsers_take_the_family_and_its_flags(driver, monkeypatch):
+    parse = driver.make_parser().parse_args
+    flags = parse([
+        "--model", "kanana2", "--num_layers", "3", "--memory_len", "9",
+        "--expert_share", "1/8",
+    ])
+    assert (flags.model, flags.num_layers, flags.expert_share) == (
+        "kanana2", 3, "1/8"
+    )
+    monkeypatch.setattr(kanana2, "PUBLISHED", dict(kanana2.PUBLISHED, **SMALL))
+    model, _ = monobeast._init_model_and_params(
+        flags, A, B, FRAME, init_params=False
+    )
+    assert isinstance(model, Kanana2Net)
+    assert (model.num_layers, model.memory_len, model.d_model) == (3, 9, 48)
+    assert model.held_experts() == (2, 2)
+    with pytest.raises(ValueError, match="at least one MoE layer"):
+        monobeast._init_model_and_params(
+            parse(["--model", "kanana2", "--num_layers", "1"]),
+            A, B, FRAME, init_params=False,
+        )
+    with pytest.raises(ValueError, match="use_lstm"):
+        monobeast._init_model_and_params(
+            parse(["--model", "kanana2", "--use_lstm"]),
+            A, B, FRAME, init_params=False,
+        )
+    # --remat reaches the family's blocks.
+    model, _ = monobeast._init_model_and_params(
+        parse(["--model", "kanana2", "--num_layers", "3", "--remat", "all"]),
+        A, B, FRAME, init_params=False,
+    )
+    assert model.remat is True
+
+
+def test_rematerialised_blocks_give_the_same_loss_gradients_and_steps():
+    model, params = _model((1, 8))
+    remat = model.clone(remat=True)
+    state = _warm_state(model, params, seed=5)
+    batch = _learner_batch(9, done_steps=[(1, 1)])
+    loss, stats, grads = _loss_and_grads(model, params, batch, state)
+    loss_r, stats_r, grads_r = _loss_and_grads(remat, params, batch, state)
+    assert float(loss) == pytest.approx(float(loss_r), rel=1e-5)
+    flat, flat_r = (
+        jax.flatten_util.ravel_pytree(g)[0] for g in (grads, grads_r)
+    )
+    np.testing.assert_allclose(
+        flat, flat_r, rtol=0, atol=RTOL * float(jnp.max(jnp.abs(flat)))
+    )
+    for name in ("moe_held_assignments", "attention_latent_applications"):
+        assert float(stats[name]) == float(stats_r[name])
+    jax.tree_util.tree_map(
+        np.testing.assert_array_equal,
+        stats[learner_lib.PARAM_STEPS_KEY],
+        stats_r[learner_lib.PARAM_STEPS_KEY],
+    )

@@ -165,12 +165,12 @@ FAMILY_FIELD_CASES = {
     "num_layers": (
         "3", 3, "olmoe", "pipelined_transformer",
         "--num_layers is a positive depth or window of --model "
-        "transformer or olmoe or mellum2 or ouro",
+        "transformer or olmoe or mellum2 or ouro or kanana2",
     ),
     "memory_len": (
         "9", 9, "transformer", "deep",
         "--memory_len is a positive depth or window of --model "
-        "transformer or olmoe or mellum2 or ouro",
+        "transformer or olmoe or mellum2 or ouro or kanana2",
     ),
     "num_experts": (
         "4", 4, "transformer", "olmoe",
@@ -180,7 +180,7 @@ FAMILY_FIELD_CASES = {
     "expert_share": (
         "1/4", (1, 4), "mellum2", "olmoe",
         "--expert_share i/n (share i of the n chips that divide each "
-        "layer's experts) applies to --model mellum2 only",
+        "layer's experts) applies to --model mellum2 or kanana2 only",
     ),
     "trunk_channels": (
         "32,64,64", (32, 64, 64), "deep", "mlp",
@@ -220,6 +220,28 @@ def test_family_field_flag_follows_the_class(flag, monkeypatch):
     assert str(refused.value) == told
 
 
+@pytest.mark.parametrize("family", ["mellum2", "kanana2"])
+def test_expert_share_reaches_every_family_that_declares_it(family):
+    """`--expert_share` is no family's by name: a class that declares
+    the field takes the flag (`models.takes_flag`), and the refusal's
+    text lists the takers from the registry. PR 38 added a second taker
+    and edited neither `_FAMILY_FIELD_REFUSALS` nor the check."""
+    assert models.families_taking("expert_share") == ["mellum2", "kanana2"]
+    layers = {"mellum2": "4", "kanana2": "2"}[family]
+    model, _ = learner_setup.init_model_and_params(
+        monobeast.make_parser().parse_args([
+            "--model", family, "--num_layers", layers,
+            "--expert_share", "3/4",
+        ]),
+        A, B, FRAME, init_params=False,
+    )
+    assert model.expert_share == (3, 4)
+    quarter = model.num_experts // 4
+    assert model.held_experts() == (3 * quarter, quarter)
+    assert "{families}" in learner_setup._FAMILY_FIELD_REFUSALS["expert_share"]
+    assert family not in learner_setup._FAMILY_FIELD_REFUSALS["expert_share"]
+
+
 def test_refusals_are_stated_on_the_class():
     """A class that has the field and still refuses the flag says so
     itself; nothing else does."""
@@ -233,6 +255,7 @@ def test_refusals_are_stated_on_the_class():
         "olmoe": ("num_experts", "attention_impl"),
         "mellum2": ("num_experts", "attention_impl"),
         "ouro": ("num_experts", "attention_impl"),
+        "kanana2": ("num_experts", "attention_impl"),
     }
     kv_cache = [
         name for name in models.MODEL_NAMES
@@ -240,10 +263,13 @@ def test_refusals_are_stated_on_the_class():
     ]
     assert kv_cache == [
         "transformer", "pipelined_transformer", "olmoe", "mellum2", "ouro",
+        "kanana2",
     ]
     for name in models.MODEL_NAMES:
-        # test_olmoe, test_mellum2 and test_ouro have theirs
-        if name in kv_cache and name not in ("olmoe", "mellum2", "ouro"):
+        # test_olmoe, test_mellum2, test_ouro and test_kanana2 have theirs
+        if name in kv_cache and name not in (
+            "olmoe", "mellum2", "ouro", "kanana2"
+        ):
             with pytest.raises(ValueError, match="KV cache"):
                 models.create_model(name, num_actions=A, use_lstm=True)
         elif name not in kv_cache:

@@ -15,6 +15,7 @@ import jax.flatten_util
 import jax.numpy as jnp
 
 from perfbench.reference import mellum2_policy as reference
+from tests.seeded_pin import assert_seeded_outputs
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu import monobeast, polybeast
 from torchbeast_tpu.models import Mellum2Net, create_model, mellum2, moe
@@ -507,7 +508,7 @@ def test_parsers_take_the_family_and_its_flags(driver, monkeypatch):
         )
     # The share is refused for a family without experts to divide.
     for family in ("deep", "transformer", "olmoe"):
-        with pytest.raises(ValueError, match="--model mellum2 only"):
+        with pytest.raises(ValueError, match="--model mellum2 or kanana2 only"):
             monobeast._init_model_and_params(
                 parse(["--model", family, "--expert_share", "0/4"]),
                 A, B, FRAME, init_params=False,
@@ -580,4 +581,27 @@ def test_blocks_over_the_threshold_take_the_fused_pass_and_are_counted(
     assert float(loss_f) == pytest.approx(float(loss), rel=1e-6)
     np.testing.assert_allclose(
         grads_f, grads, rtol=0, atol=RTOL * float(jnp.max(jnp.abs(grads)))
+    )
+
+
+def test_seeded_logits_are_what_they_were_before_pr_38():
+    """PR 38 let a cache entry's two leaves differ (models/transformer.
+    py `layer_caches`, `initial_state`) and gave `DroplessMoE` a second
+    router: this family's tree, state and outputs at a seeded tiny size
+    are the numbers the parent commit gave (tests/seeded_pin.py, run on
+    both trees)."""
+    assert_seeded_outputs(
+        Mellum2Net(
+            num_actions=4, num_layers=4, memory_len=9, d_model=48,
+            num_heads=4, kv_heads=2, head_dim=16, sliding_window=4,
+            num_experts=8, experts_per_token=2, expert_width=24,
+        ),
+        params=153205,
+        logits=[
+            -0.5582820177078247, -0.5578451156616211, -0.16875900328159332,
+            0.3949725925922394,
+        ],
+        baseline=0.9923625588417053,
+        leaf_shapes=[[3, 2, 2, 16], [3, 2, 2, 16], [3, 2], [3, 2, 2, 16]],
+        state_sum=1876.7550048828125,
     )

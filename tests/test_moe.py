@@ -286,6 +286,76 @@ def _old_dropless_layer(p, x, top_k):
     )[0]
 
 
+@pytest.mark.parametrize("fields", [
+    {},
+    dict(scoring="softmax", selection_bias=False, bias_update_rate=0.0,
+         routed_scaling=1.0, shared_width=0),
+    dict(bias_update_rate=0.5),
+], ids=["unnamed", "named", "a-rate-without-a-bias"])
+def test_router_fields_at_their_defaults_are_the_old_layer(fields):
+    """PR 38's fields (sigmoid scores, a selection bias, a scaling of
+    the gates, a shared expert) at their defaults: the parameter tree,
+    the output bit for bit and the sown collections of the layer as it
+    was, with no `param_steps` and no new statistic."""
+    from torchbeast_tpu.models.moe import DroplessMoE
+
+    x = jax.random.normal(jax.random.PRNGKey(9), (24, D))
+    layer = DroplessMoE(d_ff=FF, num_experts=E, top_k=2, **fields)
+    params = layer.init(jax.random.PRNGKey(0), x)
+    assert sorted(params["params"]) == ["router", "w_down", "w_gate", "w_up"]
+    apply = jax.jit(lambda v, x: layer.apply(
+        v, x, mutable=["losses", "moe_stats", "param_steps"]
+    ))
+    old_layer = jax.jit(lambda p, x: _old_dropless_layer(p, x, 2))
+    y, sown = apply(params, x)
+    np.testing.assert_array_equal(y, old_layer(params["params"], x))
+    assert sorted(sown) == ["losses", "moe_stats"]
+    assert sorted(sown["moe_stats"]) == ["assignments", "load_max_over_mean"]
+    assert float(sown["losses"]["moe_load_balance"]) > 0
+
+
+@pytest.mark.parametrize("renormalise", [False, True])
+def test_sigmoid_router_by_hand(renormalise):
+    """`scoring="sigmoid"` with a scaling and a shared expert, no bias:
+    each token's two largest sigmoid scores gate its experts (over
+    their sum + 1e-20 if renormalised), times the scaling, and the
+    shared SwiGLU is added unscaled; with `aux_loss_weight` 0 nothing
+    is sown into `losses`."""
+    from torchbeast_tpu.models.moe import DroplessMoE
+
+    x = jax.random.normal(jax.random.PRNGKey(3), (12, D))
+    layer = DroplessMoE(
+        d_ff=FF, num_experts=E, top_k=2, aux_loss_weight=0.0,
+        renormalise=renormalise, scoring="sigmoid", routed_scaling=1.5,
+        shared_width=6,
+    )
+    params = layer.init(jax.random.PRNGKey(0), x)
+    p = params["params"]
+    assert p["shared_gate"]["kernel"].shape == (D, 6)
+    y, sown = layer.apply(params, x, mutable=["losses", "moe_stats"])
+    assert "losses" not in sown
+    assert float(sown["moe_stats"]["shared_applications"]) == 1.0
+    scores = np.asarray(jax.nn.sigmoid(x @ p["router"]["kernel"]))
+    want = np.zeros_like(np.asarray(y))
+    for t in range(12):
+        chosen = np.argsort(-scores[t])[:2]
+        gates = scores[t, chosen]
+        if renormalise:
+            gates = gates / (gates.sum() + 1e-20)
+        for g, e in zip(1.5 * gates, chosen):
+            hidden = jax.nn.silu(x[t] @ p["w_gate"][e]) * (x[t] @ p["w_up"][e])
+            want[t] += g * np.asarray(hidden @ p["w_down"][e])
+    shared = (
+        jax.nn.silu(x @ p["shared_gate"]["kernel"])
+        * (x @ p["shared_up"]["kernel"])
+    ) @ p["shared_down"]["kernel"]
+    np.testing.assert_allclose(y, want + shared, rtol=1e-5, atol=1e-6)
+    with pytest.raises(ValueError, match="Unknown scoring"):
+        DroplessMoE(
+            d_ff=FF, num_experts=E, top_k=2, scoring="tanh"
+        ).init(jax.random.PRNGKey(0), x)
+
+
 @pytest.mark.parametrize(
     "held", [None, (0, E), (0, 2), (2, 2)],
     ids=["all", "whole-range", "first-half", "second-half"],
@@ -343,3 +413,90 @@ def test_dropless_layer_with_its_experts_held(held):
         DroplessMoE(d_ff=FF, num_experts=E, top_k=2, held=(3, 2)).init(
             jax.random.PRNGKey(0), x
         )
+
+
+# --- the grouped matmul's passes, by the precision it is traced under -----
+
+
+@pytest.mark.parametrize("terms", [1, 2, 3])
+def test_bf16_terms_add_up_to_the_operand(terms):
+    """An operand cut into bfloat16 terms: the first is the plain cast,
+    each further term takes eight more bits of what the others left."""
+    from torchbeast_tpu.models import moe
+
+    x = jax.random.normal(jax.random.PRNGKey(3), (64, 48)) * 7.0
+    cut = jax.jit(lambda x: moe._bf16_terms(x, terms))
+    parts = cut(x)
+    assert len(parts) == terms
+    assert all(part.dtype == jnp.bfloat16 for part in parts)
+    np.testing.assert_array_equal(parts[0], x.astype(jnp.bfloat16))
+    total = sum(np.asarray(part, np.float64) for part in parts)
+    left = np.abs(total - np.asarray(x, np.float64))
+    assert np.all(left <= 2.0 ** (-8 * terms) * np.abs(x))
+    if terms < 3:
+        assert np.any(left > 2.0 ** (-8 * terms - 4) * np.abs(x))
+
+
+@pytest.mark.parametrize(
+    "precision, terms, passes, error",
+    [(None, 1, 1, 2.0**-8), ("default", 1, 1, 2.0**-8),
+     ("high", 2, 3, 2.0**-15), ("highest", 3, 6, 2.0**-21)],
+    ids=["unset", "default", "high", "highest"],
+)
+def test_grouped_matmul_passes_follow_the_traced_precision(
+    monkeypatch, precision, terms, passes, error
+):
+    """On the chip a grouped matmul traced under `high` is three calls
+    of the kernel on bfloat16 operands, under `highest` six, else the
+    one it always was: forward and in both gradients. The products
+    chosen are worth the precision's name (a stand-in kernel that
+    multiplies what it is given exactly)."""
+    from torchbeast_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, k, n = 128, 96, 40
+    lhs = jax.random.normal(jax.random.PRNGKey(0), (rows, k))
+    rhs = jax.random.normal(jax.random.PRNGKey(1), (1, k, n))
+    sizes = jnp.array([rows], jnp.int32)
+
+    calls = []
+    for name in ("gmm", "tgmm"):
+        kernel = getattr(moe._megablox, name)
+        monkeypatch.setattr(
+            moe._megablox, name,
+            lambda *a, _kernel=kernel, _name=name, **k: (
+                calls.append(_name), _kernel(*a, **k)
+            )[1],
+        )
+
+    def loss(lhs, rhs):
+        # As a model that sets its precision inside its own call: the
+        # gradient's kernels are traced after the context has ended.
+        with jax.default_matmul_precision(precision):
+            assert moe._terms_traced_under() == terms
+            y = moe.grouped_matmul(lhs, rhs, sizes)
+        return jnp.sum(jnp.sin(y))
+
+    jax.eval_shape(loss, lhs, rhs)
+    assert calls == ["gmm"] * passes
+    del calls[:]
+    jax.eval_shape(jax.grad(loss, argnums=(0, 1)), lhs, rhs)
+    assert sorted(calls) == ["gmm"] * 2 * passes + ["tgmm"] * passes
+    del calls[:]
+
+    def exact(lhs, rhs, sizes, dtype, tiling, interpret):
+        assert lhs.dtype == rhs.dtype == jnp.bfloat16 and not interpret
+        calls.append(1)
+        return jnp.dot(
+            lhs.astype(dtype), rhs[0].astype(dtype),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+
+    got = moe._gmm_call(exact, lhs, rhs, sizes, rows, terms)
+    want = np.asarray(lhs, np.float64) @ np.asarray(rhs[0], np.float64)
+    scale = np.abs(np.asarray(lhs)) @ np.abs(np.asarray(rhs[0]))
+    assert len(calls) == passes
+    worst = np.max(np.abs(np.asarray(got, np.float64) - want) / scale)
+    assert worst <= error
+    if terms < 3:
+        assert worst > error / 64

@@ -539,6 +539,42 @@ def test_train_mellum2_through_main(tmp_path, monkeypatch, share):
     assert (tmp_path / "smoke-mellum2" / "model.ckpt").exists()
 
 
+def test_train_kanana2_through_main(tmp_path, monkeypatch):
+    """`--model kanana2` on the normal path, the family's table shrunk
+    (a dense layer and two MoE layers, share 1 of 4): acting at T=1
+    through the rolling latent caches, unrolls of 5, updates with the
+    blocks rematerialised, the selection biases moved by the load after
+    every optimizer step, the checkpoint carrying them."""
+    from torchbeast_tpu.models import kanana2
+
+    monkeypatch.setattr(kanana2, "PUBLISHED", dict(
+        kanana2.PUBLISHED, d_model=32, num_heads=4, latent_rank=16,
+        nope_head_dim=8, rope_head_dim=4, value_head_dim=8, mlp_width=48,
+        num_experts=8, experts_per_token=2, expert_width=16,
+    ))
+    stats = monobeast.main(make_flags(
+        tmp_path, xpid="smoke-kanana2", model="kanana2", num_layers=3,
+        memory_len=6, expert_share="1/4", remat="all",
+    ))
+    assert stats["step"] >= 40
+    assert np.isfinite(stats["total_loss"])
+    assert stats["attention_latent_applications"] == 3
+    # A latent [6, 16], a rope key [6, 4] and a validity column, f32,
+    # for each of the 3 caches.
+    assert stats["attention_latent_cache_bytes_per_row"] == (
+        3 * 4 * 6 * (16 + 4 + 1)
+    )
+    assert stats["moe_bias_steps"] == 2
+    assert stats["moe_shared_applications"] == 2
+    # (The mock env's frames are all alike: the experts held may draw
+    # every row or none.)
+    assert 0 <= stats["moe_held_assignments"] <= stats["moe_assignments"]
+    # At least one update moved them: 0.001 a step from zero.
+    assert stats["moe_bias_abs_max"] >= 0.001 - 1e-9
+    assert stats["aux_loss"] == 0.0
+    assert (tmp_path / "smoke-kanana2" / "model.ckpt").exists()
+
+
 def test_train_ouro_through_main(tmp_path, monkeypatch):
     """`--model ouro` on the normal path, the family's table shrunk (2
     layers run 3 times): acting at T=1 through the 3 x 2 rolling caches,

@@ -14,6 +14,7 @@ import jax.flatten_util
 import jax.numpy as jnp
 
 from perfbench.reference import olmoe_policy as reference
+from tests.seeded_pin import assert_seeded_outputs
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu import monobeast, polybeast
 from torchbeast_tpu.models import OLMoENet, create_model, moe, olmoe
@@ -416,4 +417,26 @@ def test_tree_state_and_output_are_what_they_were_before_layer_caches(family):
     )
     assert jax.tree_util.tree_map(jnp.shape, new_state) == (
         jax.tree_util.tree_map(jnp.shape, state)
+    )
+
+
+def test_seeded_logits_are_what_they_were_before_pr_38():
+    """PR 38 let a cache entry's two leaves differ (models/transformer.
+    py `layer_caches`, `initial_state`) and gave `DroplessMoE` a second
+    router: this family's tree, state and outputs at a seeded tiny size
+    are the numbers the parent commit gave (tests/seeded_pin.py, run on
+    both trees)."""
+    assert_seeded_outputs(
+        OLMoENet(
+            num_actions=4, num_layers=2, memory_len=5, d_model=32,
+            num_heads=2, num_experts=4, experts_per_token=2, expert_width=16,
+        ),
+        params=23461,
+        logits=[
+            0.9347226619720459, 2.0615499019622803, 0.7423094511032104,
+            1.6697852611541748,
+        ],
+        baseline=-2.277128219604492,
+        leaf_shapes=[[5, 2, 2, 16], [5, 2, 2, 16], [5, 2], [5, 2, 2, 16]],
+        state_sum=1020.148193359375,
     )

@@ -13,6 +13,7 @@ import jax.flatten_util
 import jax.numpy as jnp
 
 from perfbench.reference import ouro_policy as reference
+from tests.seeded_pin import assert_seeded_outputs
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu import monobeast, polybeast
 from torchbeast_tpu.models import OLMoENet, OuroNet, create_model, ouro
@@ -475,3 +476,25 @@ def test_every_matmul_is_traced_at_the_familys_precision():
     )
     theirs = dots(plain, plain_params, plain.initial_state(B))
     assert theirs and not any("HIGH" in d for d in theirs if "HIGHEST" not in d)
+
+
+def test_seeded_logits_are_what_they_were_before_pr_38():
+    """PR 38 let a cache entry's two leaves differ (models/transformer.
+    py `layer_caches`, `initial_state`) and gave `DroplessMoE` a second
+    router: this family's tree, state and outputs at a seeded tiny size
+    are the numbers the parent commit gave (tests/seeded_pin.py, run on
+    both trees)."""
+    assert_seeded_outputs(
+        OuroNet(
+            num_actions=4, num_layers=2, memory_len=5, d_model=32,
+            num_heads=2, head_dim=16, mlp_width=48, passes=3,
+        ),
+        params=20166,
+        logits=[
+            -1.6723791360855103, -1.0398012399673462, -0.014137506484985352,
+            -0.9085246920585632,
+        ],
+        baseline=0.43291524052619934,
+        leaf_shapes=[[5, 2, 2, 16], [5, 2, 2, 16], [5, 2], [5, 2, 2, 16]],
+        state_sum=3152.189697265625,
+    )

@@ -62,6 +62,31 @@ def test_polybeast_train_ouro(tmp_path, monkeypatch):
     assert (tmp_path / "poly-ouro" / "model.ckpt").exists()
 
 
+def test_polybeast_train_kanana2(tmp_path, monkeypatch):
+    """`--model kanana2` through the async driver, the family's table
+    shrunk: the state table's slots hold the latent caches (entries of
+    two unequal leaves), the learner's updates move the selection
+    biases and report it."""
+    from torchbeast_tpu.models import kanana2
+
+    monkeypatch.setattr(kanana2, "PUBLISHED", dict(
+        kanana2.PUBLISHED, d_model=32, num_heads=4, latent_rank=16,
+        nope_head_dim=8, rope_head_dim=4, value_head_dim=8, mlp_width=48,
+        num_experts=8, experts_per_token=2, expert_width=16,
+    ))
+    flags = make_flags(
+        tmp_path, xpid="poly-kanana2", model="kanana2", num_layers=2,
+        memory_len=6, remat="all",
+    )
+    stats = polybeast.train(flags)
+    assert stats["step"] >= 60
+    assert np.isfinite(stats["total_loss"])
+    assert stats["attention_latent_applications"] == 2
+    assert stats["moe_bias_steps"] == 1
+    assert stats["moe_bias_abs_max"] >= 0.001 - 1e-9
+    assert (tmp_path / "poly-kanana2" / "model.ckpt").exists()
+
+
 @pytest.mark.slow
 def test_polybeast_train_lstm(tmp_path):
     flags = make_flags(tmp_path, xpid="poly-lstm", use_lstm=True)

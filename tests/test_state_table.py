@@ -294,6 +294,66 @@ class TestDeviceStateTable:
         table.step(np.asarray([0], np.int32), np.ones(1, bool), env(1))
         assert [int(v.sum()) for v in valid(0)] == [1, 1, 1, 1]
 
+    @pytest.mark.parametrize("via", ["reset", "rebuild"])
+    def test_an_entry_whose_two_leaves_differ(self, via):
+        """A cache entry of unequal leaves (kanana2: a latent [M, B, 1,
+        6] and a rope key [M, B, 1, 2] with one validity column), the
+        table knowing nothing of either: the slots step apart, both
+        leaves roll together, reset and rebuild bring back both at
+        their own widths."""
+        M = 4
+        initial = ((
+            np.zeros((M, 1, 1, 6), np.float32),
+            np.zeros((M, 1, 1, 2), np.float32),
+            np.zeros((M, 1), np.float32),
+        ),)
+
+        def act(ctx, env_outputs, agent_state):
+            (latent, rope_key, valid), = agent_state
+            frame = env_outputs["frame"]  # [1, B, H]
+            wide = jnp.broadcast_to(frame[:, :, None, :1], (1,) + latent.shape[1:])
+            new = (
+                jnp.concatenate([latent[1:], wide]),
+                jnp.concatenate([rope_key[1:], wide[..., :2] + 0.5]),
+                jnp.concatenate([valid[1:], jnp.ones_like(valid[:1])]),
+            )
+            return {"out": frame + latent.sum(axis=(0, 2, 3))[None, :, None]}, (new,)
+
+        table = DeviceStateTable(
+            initial, num_slots=3, act_fn=act, batch_dim=1
+        )
+        for value in (1.0, 2.0):
+            table.step(
+                np.asarray([0, 2], np.int32), np.ones(2, bool),
+                _env([value, 10 * value]),
+            )
+        (latent, rope_key, valid), = table.read_slot(2)
+        assert np.shape(latent) == (M, 1, 1, 6)
+        assert np.shape(rope_key) == (M, 1, 1, 2)
+        np.testing.assert_array_equal(
+            np.asarray(latent)[:, 0, 0, 0], [0.0, 0.0, 10.0, 20.0]
+        )
+        np.testing.assert_array_equal(
+            np.asarray(rope_key)[:, 0, 0, 1], [0.0, 0.0, 10.5, 20.5]
+        )
+        np.testing.assert_array_equal(np.asarray(valid)[:, 0], [0, 0, 1, 1])
+        assert not any(np.any(leaf) for leaf in table.read_slot(1)[0])
+        # The next step reads what the last one left: 6 x (10 + 20).
+        out = _step_out(table, [2], [True], _env([0.0]))
+        assert float(out[0, 0, 0]) == 6 * 30.0
+        if via == "reset":
+            table.reset([2])
+            assert np.any(table.read_slot(0)[0][0])
+        else:
+            table.poison()
+            table.rebuild()
+            assert not np.any(table.read_slot(0)[0][0])
+        (latent, rope_key, valid), = table.read_slot(2)
+        assert np.shape(latent) == (M, 1, 1, 6)
+        assert np.shape(rope_key) == (M, 1, 1, 2)
+        assert not np.any(latent) and not np.any(rope_key)
+        assert not np.any(valid)
+
     def test_read_slot_shape_matches_initial_state(self):
         table = make_table()
         piece = table.read_slot(3)

@@ -457,6 +457,10 @@ def make_optimizer(hp: HParams) -> optax.GradientTransformation:
 # the target network's bootstrap value.
 TARGET_LOGITS_KEY = "impact_target_logits"
 TARGET_BASELINE_KEY = "impact_target_baseline"
+# The collection a module sows into to move a parameter of its own by
+# something other than a gradient, under the parameter's name; the key
+# of `compute_loss`'s stats that carries the tree to `update_body`.
+PARAM_STEPS_KEY = "param_steps"
 
 
 def make_target_forward(model, superstep_k: int = 1):
@@ -529,7 +533,10 @@ def compute_loss(
         batch,
         initial_agent_state,
         sample_action=False,
-        mutable=["losses", "moe_stats", "loop_stats", "attention_stats"],
+        mutable=[
+            "losses", "moe_stats", "loop_stats", "attention_stats",
+            PARAM_STEPS_KEY,
+        ],
     )
     aux_loss = sum(
         jnp.sum(leaf)
@@ -631,6 +638,12 @@ def compute_loss(
         **loop_stats,
         **attention_stats,
     }
+    # What a model says its parameters move by beside their gradient
+    # (models/moe.py: a router's selection bias, by the batch's load),
+    # a tree under the parameters' own paths: `update_body` takes it
+    # out of the stats and adds it after the optimizer's step.
+    if variables.get(PARAM_STEPS_KEY):
+        stats[PARAM_STEPS_KEY] = variables[PARAM_STEPS_KEY]
     return total_loss, stats
 
 
@@ -639,16 +652,19 @@ def _moe_stats(sown) -> Dict[str, Any]:
     DroplessMoE), over the layers: every assignment computed (8 x tokens
     x layers for OLMoE: nothing dropped) and the fullest expert's rows
     over the mean, worst layer; for layers that hold a share of their
-    experts (mellum2), the same two over the experts held. Empty for
-    every other model."""
+    experts (mellum2, kanana2), the same two over the experts held; for
+    layers with a selection bias, its largest magnitude, and the shared
+    experts applied (kanana2). Empty for every other model."""
     by_name: Dict[str, list] = {}
     for path, leaf in flax.traverse_util.flatten_dict(sown).items():
         by_name.setdefault(path[-1], []).append(leaf)
     stats = {}
-    for name in ("assignments", "held_assignments"):
+    for name in ("assignments", "held_assignments", "shared_applications"):
         if name in by_name:
             stats["moe_" + name] = sum(by_name[name])
-    for name in ("load_max_over_mean", "held_load_max_over_mean"):
+    for name in (
+        "load_max_over_mean", "held_load_max_over_mean", "bias_abs_max",
+    ):
         if name in by_name:
             stats["moe_" + name] = jnp.max(jnp.stack(by_name[name]))
     return stats
@@ -681,6 +697,27 @@ def _loop_stats(sown) -> Dict[str, Any]:
         )
         stats["loop_exit_p_last"] = jnp.mean(jnp.prod(stays, axis=0))
     return stats
+
+
+def add_param_steps(params, steps, opt_state=None):
+    """`params` with every leaf of `steps` (the sown collection
+    `PARAM_STEPS_KEY`, a tree under the parameters' own paths) added to
+    the parameter at its path, and how many leaves that was. Such a
+    leaf's gradient is zero (nothing differentiable reads it), so the
+    optimizer leaves it where it is and this is all that moves it."""
+    if isinstance(opt_state, (MasterParamsState, FusedTailState)):
+        raise NotImplementedError(
+            "a model whose parameters move by what it sows (a router's "
+            "selection bias) trains with float32 resident parameters: "
+            "the bf16-resident optimizers keep a master this step does "
+            "not reach"
+        )
+    flat = flax.traverse_util.flatten_dict(params)
+    moved = flax.traverse_util.flatten_dict(steps)
+    for path, step in moved.items():
+        leaf = flat[("params",) + path]
+        flat[("params",) + path] = leaf + step.astype(leaf.dtype)
+    return flax.traverse_util.unflatten_dict(flat), len(moved)
 
 
 def donate_argnums_for(donate, donate_batch: bool = False) -> tuple:
@@ -767,6 +804,7 @@ def update_body(model, optimizer: optax.GradientTransformation, hp: HParams):
             has_aux=True,
         )
         grads, stats = grad_fn(params)
+        param_steps = stats.pop(PARAM_STEPS_KEY, None)
         with jax.named_scope("optimizer"):
             updates, new_opt_state = optimizer.update(
                 grads, opt_state, params
@@ -776,6 +814,12 @@ def update_body(model, optimizer: optax.GradientTransformation, hp: HParams):
             # the resident params are one narrowing cast; every other
             # optimizer takes the stock optax apply.
             params = apply_updates(params, updates, new_opt_state)
+        if param_steps is not None:
+            with jax.named_scope("load_moved"):
+                params, moved = add_param_steps(
+                    params, param_steps, new_opt_state
+                )
+            stats["moe_bias_steps"] = jnp.float32(moved)
         # f32 upcast before the norm reduction (no-op for f32 grads;
         # bf16-resident runs emit bf16 grad arrays).
         with jax.named_scope("grad_norm"):

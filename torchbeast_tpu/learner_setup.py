@@ -171,26 +171,31 @@ def add_learner_arguments(parser, *, model_default,
                              "divide into M microbatches.")
     parser.add_argument("--num_layers", type=int, default=0,
                         help="Depth of --model transformer, olmoe, "
-                             "mellum2 or ouro (0: the family's own, 2 "
-                             "and the published 16, 28 and 48; mellum2 "
-                             "in whole periods of 4; ouro runs the "
-                             "layers it has 4 times a step).")
+                             "mellum2, ouro or kanana2 (0: the family's "
+                             "own, 2 and the published 16, 28, 48 and "
+                             "48; mellum2 in whole periods of 4; ouro "
+                             "runs the layers it has 4 times a step; "
+                             "kanana2: its leading dense layer and the "
+                             "MoE layers after it, 2 or more).")
     parser.add_argument("--memory_len", type=int, default=0,
                         help="Steps of its own past a transformer, "
-                             "olmoe, mellum2 or ouro policy attends "
-                             "over, carried as the rolling KV cache (0: "
-                             "the family's own, 64, 128, 4095 and 255; "
-                             "mellum2: its full layers' cache, the "
-                             "sliding layers carry min(memory_len, "
-                             "1023); ouro: every one of its 4 x "
-                             "num_layers caches).")
+                             "olmoe, mellum2, ouro or kanana2 policy "
+                             "attends over, carried as the rolling KV "
+                             "cache (0: the family's own, 64, 128, 4095, "
+                             "255 and 4095; mellum2: its full layers' "
+                             "cache, the sliding layers carry "
+                             "min(memory_len, 1023); ouro: every one of "
+                             "its 4 x num_layers caches; kanana2: a "
+                             "latent and a rope key a slot).")
     parser.add_argument("--expert_share", default="",
-                        help="--model mellum2: 'i/n' holds share i of "
-                             "the n chips that divide each layer's 64 "
-                             "experts (0/4: experts 0..15). The layer "
-                             "routes over all 64 and adds its own "
-                             "experts' part of the sum; nothing stands "
-                             "in for the other chips. Empty: all.")
+                        help="--model mellum2 or kanana2: 'i/n' holds "
+                             "share i of the n chips that divide each "
+                             "layer's 64 (128 routed) experts (0/4: "
+                             "experts 0..15). The layer routes over all "
+                             "of them and adds its own experts' part of "
+                             "the sum (kanana2: and the shared expert); "
+                             "nothing stands in for the other chips. "
+                             "Empty: all.")
     parser.add_argument("--num_experts", type=int, default=0,
                         help="Replace the transformer's FFN with a top-2 "
                              "mixture of N experts (model=transformer "
@@ -271,8 +276,8 @@ def add_learner_arguments(parser, *, model_default,
                              "all, the block remat of the families "
                              "whose class has the `blocks` lever "
                              "(transformer, pipelined_transformer, "
-                             "mellum2, ouro; not olmoe), the LSTM "
-                             "scan): 'auto' picks the "
+                             "mellum2, ouro, kanana2; not olmoe), the "
+                             "LSTM scan): 'auto' picks the "
                              "minimum-recompute plan whose XLA-measured "
                              "peak fits --hbm_budget_gb; 'all'/'none' "
                              "force every stage; 'stage0=front,"

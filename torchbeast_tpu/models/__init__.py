@@ -10,7 +10,8 @@ import dataclasses
 from torchbeast_tpu.models.atari_net import AtariNet  # noqa: F401
 from torchbeast_tpu.models.cores import LSTMCore  # noqa: F401
 from torchbeast_tpu.models.mlp import MLPNet  # noqa: F401
-from torchbeast_tpu.models import mellum2, olmoe, ouro
+from torchbeast_tpu.models import kanana2, mellum2, olmoe, ouro
+from torchbeast_tpu.models.kanana2 import Kanana2Net  # noqa: F401
 from torchbeast_tpu.models.mellum2 import Mellum2Net  # noqa: F401
 from torchbeast_tpu.models.olmoe import OLMoENet  # noqa: F401
 from torchbeast_tpu.models.ouro import OuroNet  # noqa: F401
@@ -33,11 +34,14 @@ _REGISTRY = {
     "olmoe": OLMoENet,
     "mellum2": Mellum2Net,
     "ouro": OuroNet,
+    "kanana2": Kanana2Net,
 }
 # A family whose widths are a published table (its module's `PUBLISHED`,
 # keyed by the class's fields): read when the model is built, so that a
 # test shrinks the family there.
-_PUBLISHED_TABLES = {OLMoENet: olmoe, Mellum2Net: mellum2, OuroNet: ouro}
+_PUBLISHED_TABLES = {
+    OLMoENet: olmoe, Mellum2Net: mellum2, OuroNet: ouro, Kanana2Net: kanana2,
+}
 MODEL_NAMES = tuple(_REGISTRY)
 
 

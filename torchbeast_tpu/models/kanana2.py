@@ -1,0 +1,348 @@
+"""A Kanana-2-30B-A3B block as the policy trunk (`--model kanana2`).
+
+The family is `TransformerNet`'s scaffolding — observation and extras
+projections, band / segment / cache-validity masks, `roll_kv_cache`,
+the `[M, B, heads, D]` state convention, `RecurrentPolicyHead` — with
+the block of kanana-2-30b-a3b-instruct-2601 (config.json, `model_type`
+deepseek_v3) at its published widths. Per layer (no biases anywhere):
+
+    h = rmsnorm(x)
+    q = Wq h -> [32, 192], split q_nope (128) | q_rope (64)
+                                    no query bottleneck (q_lora_rank null)
+    Wkva h -> 576, split c (512) | k_r (64);  c = rmsnorm_512(c)
+                                    THE CACHE KEEPS c (normed) AND k_r
+                                    (un-rotated): 576 floats a slot
+    Wkvb c -> [32, 256], split k_nope (128) | v (128)
+                                    for this unroll's keys alone
+    RoPE theta 1e6 on q_rope and k_r (one key for all 32 heads), pairs
+    (2i, 2i+1) rotated (rope_interleave)
+    scores (q_nope . k_nope + q_rope . k_r) / sqrt(192), softmax, P v,
+    Wo (4096 -> 2048), residual
+    the cache leg ABSORBED (ops/attention.latent_cached_attend): with
+    Wkvb read per head as W_UK, W_UV [512, 32, 128], q_nope W_UK^T
+    scores against c itself, the weights combine c, and W_UV lifts the
+    result; nothing cached is decompressed
+    layer 0:     x = x + SwiGLU_6144(rmsnorm(x))   (first_k_dense_replace)
+    layers >= 1: s = sigmoid(Wr u) over 128; the 6 largest of s + b;
+                 g = 2.448 s / (sum of the 6 chosen s + 1e-20)
+                 x = x + sum g_e E_e(u) + SwiGLU_1536(u)
+                                    128 SwiGLU experts of 768 (models/
+                                    moe.py DroplessMoE), two shared
+                                    experts as one SwiGLU every token
+                                    takes; no auxiliary loss
+
+and one RMSNorm after the last layer. `b` (`e_score_correction_bias`)
+is a parameter that takes no gradient: after the optimizer's step it
+moves by `bias_update_rate` x sign(mean load - load), the batch's
+assignments over all 128 experts (DeepSeek-V3, arXiv:2412.19437,
+section 2.1.2; the layer sows the step, learner.update_body adds it).
+`n_group` 1 / `topk_group` 1: group-limited selection with one group is
+plain top-k, and is not written.
+
+As in models/olmoe.py, a key's position is its time relative to the
+unroll's first step and the cache holds un-rotated keys, so the
+learner's batch forward equals the actor's T=1 forwards through the
+rolling latent caches (tests/test_kanana2.py).
+
+A chip may hold a share of each layer's routed experts (`--expert_share
+i/n`, as models/mellum2.py): the layer routes over all 128, adds its own
+experts' part of the sum and the shared expert (whole on every chip),
+and nothing stands in for the other chips.
+
+The widths are constants of the family (`PUBLISHED`), not flags; a user
+cuts depth (`--num_layers`: the leading dense layer and the MoE layers
+after it), chooses the cache (`--memory_len`) and the share. What the
+config does not spell out is noted where it is used.
+"""
+
+from typing import Any, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from torchbeast_tpu.models.moe import DroplessMoE, held_experts
+from torchbeast_tpu.models.transformer import (
+    TransformerNet,
+    count_latent_application,
+)
+from torchbeast_tpu.ops.attention import latent_cached_attend
+
+# https://huggingface.co/kakaocorp/kanana-2-30b-a3b-instruct-2601/blob/main/config.json
+# by the name of the field that carries each. `create_model("kanana2")`
+# reads this table when it is called, so a test shrinks the family here.
+PUBLISHED = {
+    "d_model": 2048,  # hidden_size
+    "num_heads": 32,  # num_attention_heads
+    "latent_rank": 512,  # kv_lora_rank
+    "nope_head_dim": 128,  # qk_nope_head_dim
+    "rope_head_dim": 64,  # qk_rope_head_dim
+    "value_head_dim": 128,  # v_head_dim
+    "num_layers": 48,  # num_hidden_layers
+    "dense_layers": 1,  # first_k_dense_replace
+    "mlp_width": 6144,  # intermediate_size, the dense layers' SwiGLU
+    "num_experts": 128,  # n_routed_experts
+    "experts_per_token": 6,  # num_experts_per_tok
+    "expert_width": 768,  # moe_intermediate_size
+    "shared_experts": 2,  # n_shared_experts: one SwiGLU of 2 x 768
+    "renormalise": True,  # norm_topk_prob
+    "routed_scaling": 2.448,  # routed_scaling_factor
+    "rms_norm_eps": 1e-6,
+    "rope_theta": 1000000.0,
+}
+
+
+def rope_pairs(x, positions, theta, time_axis=1):
+    """Interleaved RoPE (`rope_interleave`): the pair (x[2i], x[2i+1])
+    turned by positions x theta^(-2i/D). x [..., D] with its time on
+    `time_axis` (1 for [B, S, H, D], 0 for a cache as the state holds
+    it, [S, B, H, D]); positions [S], which may be negative: only
+    differences between a query's and a key's reach the scores."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    along = [1] * x.ndim
+    along[time_axis], along[-1] = angles.shape
+    cos, sin = jnp.cos(angles).reshape(along), jnp.sin(angles).reshape(along)
+    pairs = x.reshape(x.shape[:-1] + (half, 2))
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    return jnp.stack(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).reshape(x.shape)
+
+
+class _Kanana2Block(nn.Module):
+    dense: bool  # a leading layer: SwiGLU `mlp_width`, no router
+    d_model: int
+    num_heads: int
+    latent_rank: int
+    nope_head_dim: int
+    rope_head_dim: int
+    value_head_dim: int
+    mlp_width: int
+    num_experts: int
+    held: Any  # (first, count) of the routed experts, or None for all
+    experts_per_token: int
+    expert_width: int
+    shared_width: int
+    renormalise: bool
+    routed_scaling: float
+    bias_update_rate: float
+    rms_norm_eps: float
+    rope_theta: float
+    cache_leg_precision: Any = None  # None: as the caller traces
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, cache_state, cache_mask, seq_mask, **_):
+        """TransformerNet's block contract: x [B, T, d]; cache_state
+        (latent [M, B, 1, 512], rope key [M, B, 1, 64]) as the state
+        holds them, read where they lie; cache_mask [B, T, M], seq_mask
+        [B, T, T]. Returns (y, c, k_r): this unroll's normed latent
+        [B, T, 1, 512] and un-rotated rope key [B, T, 1, 64]."""
+        B, T, _ = x.shape
+        H, C = self.num_heads, self.latent_rank
+        Dn, Dr, Dv = (
+            self.nope_head_dim, self.rope_head_dim, self.value_head_dim
+        )
+
+        def norm(name):
+            return nn.RMSNorm(epsilon=self.rms_norm_eps, name=name)
+
+        def proj(name, width):
+            return nn.Dense(
+                width, use_bias=False, dtype=self.dtype, name=name
+            )
+
+        with jax.named_scope("attention_latent"):
+            h = norm("attn_norm")(x)
+            q = proj("q", H * (Dn + Dr))(h).reshape(B, T, H, Dn + Dr)
+            compressed = proj("kv_a", C + Dr)(h)
+            c = norm("kv_a_norm")(compressed[..., :C])
+            k_r = compressed[..., None, C:]  # [B, T, 1, Dr]
+            # kv_b_proj's matrix, read a head at a time: the unroll's
+            # keys and values are decompressed by it, the cache leg
+            # takes its two halves as they are.
+            w_kvb = self.param(
+                "kv_b", nn.initializers.lecun_normal(), (C, H * (Dn + Dv))
+            ).astype(self.dtype).reshape(C, H, Dn + Dv)
+            kv = jnp.einsum("btc,chd->bthd", c.astype(self.dtype), w_kvb)
+
+            def rotate(keys, times, time_axis=1):
+                return rope_pairs(
+                    keys, times, self.rope_theta, time_axis
+                ).astype(self.dtype)
+
+            steps = jnp.arange(T)
+            attended = latent_cached_attend(
+                q[..., :Dn], rotate(q[..., Dn:], steps),
+                kv[..., :Dn], rotate(k_r, steps), kv[..., Dn:],
+                cache_state[0].astype(self.dtype),
+                cache_state[1].astype(self.dtype),
+                w_kvb[..., :Dn], w_kvb[..., Dn:], cache_mask, seq_mask,
+                place_cache_keys=lambda keys, times: rotate(keys, times, 0),
+                cache_precision=self.cache_leg_precision,
+            )
+            count_latent_application(self)
+            x = x + proj("o", self.d_model)(
+                attended.reshape(B, T, H * Dv)
+            ).astype(jnp.float32)
+
+        h = norm("mlp_norm")(x)
+        if self.dense:
+            with jax.named_scope("mlp"):
+                hidden = nn.silu(proj("gate", self.mlp_width)(h)) * proj(
+                    "up", self.mlp_width
+                )(h)
+                x = x + proj("down", self.d_model)(hidden).astype(
+                    jnp.float32
+                )
+        else:
+            y = DroplessMoE(
+                d_ff=self.expert_width,
+                num_experts=self.num_experts,
+                top_k=self.experts_per_token,
+                aux_loss_weight=0.0,  # topk_method noaux_tc
+                renormalise=self.renormalise,
+                held=self.held,
+                scoring="sigmoid",
+                selection_bias=True,
+                bias_update_rate=self.bias_update_rate,
+                routed_scaling=self.routed_scaling,
+                shared_width=self.shared_width,
+                dtype=self.dtype,
+                name="moe",
+            )(h.reshape(B * T, self.d_model))
+            x = x + y.reshape(B, T, self.d_model)
+        return (
+            x,
+            c[:, :, None, :].astype(jnp.float32),
+            k_r.astype(jnp.float32),
+        )
+
+
+class Kanana2Net(TransformerNet):
+    # Fields the published table sets, or that the block does not read:
+    # no flag reaches them (models/__init__.py `takes_flag`).
+    flag_refused_fields = ("num_experts", "attention_impl")
+
+    num_layers: int = PUBLISHED["num_layers"]
+    d_model: int = PUBLISHED["d_model"]
+    num_heads: int = PUBLISHED["num_heads"]
+    latent_rank: int = PUBLISHED["latent_rank"]
+    nope_head_dim: int = PUBLISHED["nope_head_dim"]
+    rope_head_dim: int = PUBLISHED["rope_head_dim"]
+    value_head_dim: int = PUBLISHED["value_head_dim"]
+    dense_layers: int = PUBLISHED["dense_layers"]
+    mlp_width: int = PUBLISHED["mlp_width"]
+    # Not the model's 32,768 positions: rolling caches of the policy's
+    # own past. 576 floats a slot whatever the head count, which is what
+    # lets 32 heads look 4,095 steps back at all (as keys and values of
+    # 32 heads a slot would be 10,240).
+    memory_len: int = 4095
+    num_experts: int = PUBLISHED["num_experts"]
+    experts_per_token: int = PUBLISHED["experts_per_token"]
+    expert_width: int = PUBLISHED["expert_width"]
+    shared_experts: int = PUBLISHED["shared_experts"]
+    renormalise: bool = PUBLISHED["renormalise"]
+    routed_scaling: float = PUBLISHED["routed_scaling"]
+    rms_norm_eps: float = PUBLISHED["rms_norm_eps"]
+    rope_theta: float = PUBLISHED["rope_theta"]
+    # (i, n): this chip is share i of the n that divide each layer's
+    # routed experts (`--expert_share i/n`). (0, 1): all are here.
+    expert_share: Tuple[int, int] = (0, 1)
+    # DeepSeek-V3's bias update speed (its `gamma`, 0.001 for most of
+    # its training); config.json has no key for it.
+    bias_update_rate: float = 0.001
+    # Frames to [-1, 1], for the reason models/olmoe.py gives.
+    frame_range: Tuple[float, float] = (-1.0, 1.0)
+    # For the reason models/mellum2.py gives: even seeded routing.
+    zero_init_extras: bool = True
+    # Every matmul of the family in three bf16 passes on the MXU (JAX
+    # precision `high`, as models/ouro.py and for its reason), the
+    # grouped expert matmuls among them (models/moe.py cuts their
+    # operands in two bfloat16 terms under `high`), but one kind. At
+    # JAX's default one pass the loss drifted 3.7e-3 (mean over 12
+    # seeded batches on the chip; 8.0e-3 the worst) of its scale from
+    # the float32 reference's, past the benchmark's 5e-3 in four seeds
+    # of twelve: the rounding of what feeds a router moves a token's
+    # sixth choice among 128 close scores, and the chosen experts' sum
+    # comes scaled by 2.448. No part alone carries it (the projection,
+    # attention or the SwiGLUs exact leave 6e-3 to 1.6e-2), and the
+    # experts at one pass with all else exact still leave 5.9e-3 (three
+    # seeds of 36 past 5e-3: what they round feeds the next layer's
+    # router). The kind left at one pass: the cache leg's two products
+    # over the M slots, most of the step's operations, whose sums run
+    # over keys (all 4,095 slots filled: 1.1e-3 the worst of 12 seeds,
+    # 5.0e-4 at three passes there, which cost 135 ms more an update
+    # on 388; empty, as the benchmark checks: 1.9e-3 the worst of 160
+    # either way). PERF.md, PR 38.
+    matmul_precision: str = "high"
+    cache_leg_precision: str = "default"
+
+    def __call__(self, inputs, core_state, **kwargs):
+        # Read when a dot is traced, and kept by its gradient's.
+        with jax.default_matmul_precision(self.matmul_precision):
+            return super().__call__(inputs, core_state, **kwargs)
+
+    def __post_init__(self):
+        if self.num_layers <= self.dense_layers:
+            raise ValueError(
+                f"--num_layers {self.num_layers}: --model kanana2 is its "
+                f"{self.dense_layers} leading dense layer(s) and at least "
+                "one MoE layer after"
+            )
+        self.held_experts()  # refuses a share that is none
+        super().__post_init__()
+
+    @nn.nowrap
+    def layer_caches(self):
+        """A layer's cache is a latent and a rope key for all heads
+        together: leaves [M, B, 1, 512] and [M, B, 1, 64]."""
+        return (
+            (self.memory_len, 1, (self.latent_rank, self.rope_head_dim)),
+        ) * self.num_layers
+
+    @nn.nowrap
+    def held_experts(self):
+        """(first, count) of the experts this chip holds, None for all."""
+        return held_experts(self.expert_share, self.num_experts)
+
+    @nn.nowrap
+    def make_block(self, name: str, layer: int):
+        block_cls = nn.remat(_Kanana2Block) if self.remat else _Kanana2Block
+        return block_cls(
+            dense=layer < self.dense_layers,
+            d_model=self.d_model, num_heads=self.num_heads,
+            latent_rank=self.latent_rank,
+            nope_head_dim=self.nope_head_dim,
+            rope_head_dim=self.rope_head_dim,
+            value_head_dim=self.value_head_dim,
+            mlp_width=self.mlp_width,
+            num_experts=self.num_experts, held=self.held_experts(),
+            experts_per_token=self.experts_per_token,
+            expert_width=self.expert_width,
+            shared_width=self.shared_experts * self.expert_width,
+            renormalise=self.renormalise,
+            routed_scaling=self.routed_scaling,
+            bias_update_rate=self.bias_update_rate,
+            rms_norm_eps=self.rms_norm_eps, rope_theta=self.rope_theta,
+            cache_leg_precision=self.cache_leg_precision,
+            dtype=self.dtype, name=name,
+        )
+
+    @nn.nowrap
+    def make_final_norm(self):
+        norm = nn.RMSNorm(epsilon=self.rms_norm_eps, name="final_norm")
+        if not self.is_initializing():
+            # The latent, the rope key and the validity column of every
+            # cache, float32: what a row of the batch carries.
+            self.sow(
+                "attention_stats", "latent_cache_bytes_per_row",
+                jnp.float32(
+                    4 * self.num_layers * self.memory_len
+                    * (self.latent_rank + self.rope_head_dim + 1)
+                ),
+                reduce_fn=lambda prev, new: new,
+            )
+        return norm

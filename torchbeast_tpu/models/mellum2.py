@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from torchbeast_tpu.models.moe import DroplessMoE
+from torchbeast_tpu.models.moe import DroplessMoE, held_experts
 from torchbeast_tpu.models.olmoe import rope_rotate
 from torchbeast_tpu.models.transformer import (
     TransformerNet,
@@ -275,12 +275,7 @@ class Mellum2Net(TransformerNet):
                 f"in whole periods of {period} layers "
                 f"({', '.join(self.layer_period)})"
             )
-        share, of = self.expert_share
-        if not 0 <= share < of or self.num_experts % of:
-            raise ValueError(
-                f"--expert_share {share}/{of}: share i of n takes "
-                f"0 <= i < n, and n divides the {self.num_experts} experts"
-            )
+        self.held_experts()  # refuses a share that is none
         super().__post_init__()
 
     @nn.nowrap
@@ -302,9 +297,7 @@ class Mellum2Net(TransformerNet):
     @nn.nowrap
     def held_experts(self):
         """(first, count) of the experts this chip holds, None for all."""
-        share, of = self.expert_share
-        count = self.num_experts // of
-        return None if of == 1 else (share * count, count)
+        return held_experts(self.expert_share, self.num_experts)
 
     @nn.nowrap
     def make_block(self, name: str, layer: int):

@@ -191,34 +191,83 @@ _permute.defvjp(_permute_fwd, _permute_bwd)
 _GMM_TILING = (256, 1024, 1024)
 
 
-def _gmm_call(kernel, lhs, rhs, sizes, rows, **kwargs):
-    """One call of a megablox kernel. On the chip its operands are
-    bfloat16 and its sums and result float32 — what XLA makes of a
-    float32 matmul at JAX's default precision, so the experts are
-    computed as every other matmul of the model is. Elsewhere the
-    kernel is interpreted, in float32."""
+# bfloat16 terms an operand is cut into, by the matmul precision the
+# caller traces under (JAX's names and their aliases); n terms make
+# n (n + 1) / 2 passes of the kernel.
+_TERMS = {"high": 2, "tensorfloat32": 2, "highest": 3, "float32": 3}
+
+
+def _terms_traced_under():
+    return _TERMS.get(jax.config.jax_default_matmul_precision, 1)
+
+
+def _bf16_terms(x, terms):
+    """x as a sum of `terms` bfloat16 arrays, the largest first."""
+    if terms == 1:
+        return [x.astype(jnp.bfloat16)]
+    out = []
+    for _ in range(terms):
+        # Not astype there and back: XLA takes that round trip for the
+        # identity on the chip, and the next term comes out as zeros.
+        head = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+        out.append(head.astype(jnp.bfloat16))
+        x = x - head
+    return out
+
+
+def _gmm_call(kernel, lhs, rhs, sizes, rows, terms, **kwargs):
+    """One product by a megablox kernel. On the chip its operands are
+    bfloat16 and its sums and result float32. With one term a side,
+    that is what XLA makes of a float32 matmul at JAX's default
+    precision; with two (a caller that traces under `high`, models/
+    kanana2.py) the three passes XLA makes there: head x head, head x
+    tail, tail x head. So the experts are computed as every other
+    matmul of the model is. Elsewhere the kernel is interpreted, in
+    float32."""
     on_chip = jax.default_backend() == "tpu"
-    if on_chip:
-        lhs, rhs = lhs.astype(jnp.bfloat16), rhs.astype(jnp.bfloat16)
     _, tk, tn = _GMM_TILING
-    return kernel(
-        lhs, rhs, sizes, jnp.float32, (rows, tk, tn),
-        interpret=not on_chip, **kwargs,
-    )
+
+    def call(lhs, rhs):
+        # The kernel's own dot is the plain one whatever the caller
+        # traces under: Mosaic refuses a bfloat16 operand at a float32
+        # contraction ("Bad lhs type").
+        with jax.default_matmul_precision(None):
+            return kernel(
+                lhs, rhs, sizes, jnp.float32, (rows, tk, tn),
+                interpret=not on_chip, **kwargs,
+            )
+
+    if not on_chip:
+        return call(lhs, rhs)
+    lhs, rhs = _bf16_terms(lhs, terms), _bf16_terms(rhs, terms)
+    # The smallest products first, so that they are not lost one by
+    # one beside the largest.
+    out = None
+    for order in reversed(range(terms)):
+        for i in range(order + 1):
+            part = call(lhs[i], rhs[order - i])
+            out = part if out is None else out + part
+    return out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def grouped_matmul(lhs, rhs, sizes, first=None):
     """lhs [m, k] in contiguous groups of `sizes` [E] rows, rhs
     [E, k, n] -> [m, n]: rows of group e times rhs[e]. The kernels are
     JAX's shipped megablox `gmm` / `tgmm`; this wrapper fixes their
-    operand and result types (above) and pads the rows to the kernel's
-    tile, the padding going to the last group as rows of zeros.
+    operand and result types (above), forward and backward by the
+    `jax.default_matmul_precision` this call is traced under, and pads
+    the rows to the kernel's tile, the padding going to the last group
+    as rows of zeros.
 
     With `first` (a Python int), rhs [C, k, n] holds groups first ..
     first + C - 1 of the E alone: the rows of the other groups are not
     visited, and come out as zeros (as do their gradients)."""
-    return _grouped_matmul_fwd(lhs, rhs, sizes, first)[0]
+    return _grouped_matmul(lhs, rhs, sizes, first, _terms_traced_under())
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _grouped_matmul(lhs, rhs, sizes, first, terms):
+    return _grouped_matmul_fwd(lhs, rhs, sizes, first, terms)[0]
 
 
 def _pad_rows(sizes, *matrices):
@@ -237,29 +286,30 @@ def _from_group(first):
     return {"group_offset": jnp.asarray(first, jnp.int32)}
 
 
-def _grouped_matmul_fwd(lhs, rhs, sizes, first):
+def _grouped_matmul_fwd(lhs, rhs, sizes, first, terms):
     tile, padded_sizes, (padded,) = _pad_rows(sizes, lhs)
     out = _gmm_call(
-        _megablox.gmm, padded, rhs, padded_sizes, tile, **_from_group(first)
+        _megablox.gmm, padded, rhs, padded_sizes, tile, terms,
+        **_from_group(first),
     )
     return out[: lhs.shape[0]], (lhs, rhs, sizes)
 
 
-def _grouped_matmul_bwd(first, residuals, grad):
+def _grouped_matmul_bwd(first, terms, residuals, grad):
     lhs, rhs, sizes = residuals
     tile, padded_sizes, (lhs_p, grad_p) = _pad_rows(sizes, lhs, grad)
     grad_lhs = _gmm_call(
-        _megablox.gmm, grad_p, rhs, padded_sizes, tile, transpose_rhs=True,
-        **_from_group(first),
+        _megablox.gmm, grad_p, rhs, padded_sizes, tile, terms,
+        transpose_rhs=True, **_from_group(first),
     )[: lhs.shape[0]]
     grad_rhs = _gmm_call(
         _megablox.tgmm, lhs_p.swapaxes(0, 1), grad_p, padded_sizes, tile,
-        num_actual_groups=rhs.shape[0], **_from_group(first),
+        terms, num_actual_groups=rhs.shape[0], **_from_group(first),
     )
     return grad_lhs.astype(lhs.dtype), grad_rhs.astype(rhs.dtype), None
 
 
-grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
 def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None):
@@ -297,25 +347,58 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None):
     return y, sizes
 
 
+def held_experts(expert_share, num_experts):
+    """(first, count) of the experts that share i of n holds
+    (`--expert_share i/n`: experts i * num_experts / n onward), None
+    for (0, 1): all of them. A share that is none of the n, or an n
+    that does not divide the experts, is refused."""
+    share, of = expert_share
+    if not 0 <= share < of or num_experts % of:
+        raise ValueError(
+            f"--expert_share {share}/{of}: share i of n takes "
+            f"0 <= i < n, and n divides the {num_experts} experts"
+        )
+    count = num_experts // of
+    return None if of == 1 else (share * count, count)
+
+
 class DroplessMoE(nn.Module):
-    """[tokens, d_model] -> [tokens, d_model]: softmax router, top-k
-    gates as the selected probabilities (OLMoE's layer: not
-    renormalised) or over their sum (`renormalise`, a config's
-    `norm_topk_prob`), SwiGLU experts without biases, no capacity.
+    """[tokens, d_model] -> [tokens, d_model]: a router over all the
+    experts, top-k gates, SwiGLU experts without biases, no capacity.
+
+    The router as the fields say. `scoring` "softmax" (OLMoE's and
+    Mellum2's layers): the gates are the selected probabilities as they
+    are, or over their sum (`renormalise`, a config's `norm_topk_prob`),
+    and the load-balance term is sown. `scoring` "sigmoid" (DeepSeek-V3's
+    router, models/kanana2.py): each expert's score is a sigmoid of its
+    own logit; with `selection_bias` the k experts are chosen by score +
+    bias (a parameter `e_score_correction_bias` [E] that takes no
+    gradient: it moves by the load, below) while the gates are the
+    chosen SCORES, bias left out. Either way the gates are then
+    multiplied by `routed_scaling`. `shared_width` > 0 adds one SwiGLU
+    of that width that every token takes, beside the routed sum.
 
     `held` = (first, count) of the `num_experts`: the layer routes over
     all of them, computes gates and the load-balance term over all of
     them, and holds `w_gate`/`w_up`/`w_down` for `count` alone; what it
-    returns is those experts' part of the sum, and nothing stands in
-    for the rest. None: all are held."""
+    returns is those experts' part of the sum (and the shared expert,
+    whole on every chip), and nothing stands in for the rest. None: all
+    are held."""
 
     d_ff: int  # width of one expert
     num_experts: int
     top_k: int
-    aux_loss_weight: float = 1e-2
+    aux_loss_weight: float = 1e-2  # 0: no load-balance term is sown
     dtype: Any = jnp.float32
     renormalise: bool = False
     held: Optional[Tuple[int, int]] = None
+    scoring: str = "softmax"  # or "sigmoid"
+    selection_bias: bool = False
+    # What the bias moves by after an update: `bias_update_rate` x
+    # sign(mean load - load) (DeepSeek-V3, arXiv:2412.19437, 2.1.2).
+    bias_update_rate: float = 0.0
+    routed_scaling: float = 1.0
+    shared_width: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -328,6 +411,8 @@ class DroplessMoE(nn.Module):
             raise ValueError(
                 f"held={self.held} is not a range of the {E} experts"
             )
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"Unknown scoring {self.scoring!r}")
 
         # f32 at the highest matmul precision: the logits decide WHICH
         # experts run, and a rounded logit picks another expert where
@@ -337,10 +422,26 @@ class DroplessMoE(nn.Module):
                 E, use_bias=False, name="router",
                 precision=jax.lax.Precision.HIGHEST,
             )(x.astype(jnp.float32))
-            probs = jax.nn.softmax(router_logits, axis=-1)  # [t, E]
-            gate, idx = jax.lax.top_k(probs, K)  # [t, K]
+            if self.scoring == "softmax":
+                probs = jax.nn.softmax(router_logits, axis=-1)  # [t, E]
+            else:
+                probs = nn.sigmoid(router_logits)
+            if self.selection_bias:
+                bias = self.param(
+                    "e_score_correction_bias", nn.initializers.zeros, (E,)
+                )
+                # The indices carry no gradient, so the bias has none.
+                _, idx = jax.lax.top_k(probs + bias, K)  # [t, K]
+                gate = jnp.take_along_axis(probs, idx, axis=-1)
+            else:
+                gate, idx = jax.lax.top_k(probs, K)  # [t, K]
             if self.renormalise:
-                gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+                total = jnp.sum(gate, axis=-1, keepdims=True)
+                if self.scoring == "sigmoid":
+                    total = total + 1e-20  # scores may all be zero
+                gate = gate / total
+            if self.routed_scaling != 1.0:
+                gate = gate * self.routed_scaling
 
         # fan-in is one expert's d (or f), not E times it.
         kernel_init = nn.initializers.lecun_normal(batch_axis=(0,))
@@ -353,16 +454,33 @@ class DroplessMoE(nn.Module):
             w_down.astype(self.dtype),
             **({} if count == E else {"first_of": (first, E)}),
         )
+        if self.shared_width:
+            with jax.named_scope("moe_shared"):
+                def proj(name, width):
+                    return nn.Dense(
+                        width, use_bias=False, dtype=self.dtype, name=name
+                    )
 
-        # Load balance: E x sum_e (share of the K*t assignments that
-        # went to e) x (mean router probability of e); 1.0 when uniform.
+                h = x.astype(self.dtype)
+                y = y + proj("shared_down", d)(
+                    nn.silu(proj("shared_gate", self.shared_width)(h))
+                    * proj("shared_up", self.shared_width)(h)
+                ).astype(jnp.float32)
+
         load = sizes.astype(jnp.float32)
-        aux = E * jnp.sum(load / (tokens * K) * probs.mean(axis=0))
+        if self.aux_loss_weight:
+            # Load balance: E x sum_e (share of the K*t assignments that
+            # went to e) x (mean router probability of e); 1.0 when
+            # uniform.
+            aux = E * jnp.sum(load / (tokens * K) * probs.mean(axis=0))
         if not self.is_initializing():
             # `losses` is added to the objective, `moe_stats` (what the
-            # router did) to the update's stats: learner.compute_loss.
+            # router did) to the update's stats, `param_steps` to the
+            # parameters after the optimizer's step: learner.py.
             sown = [
                 ("losses", "moe_load_balance", self.aux_loss_weight * aux),
+            ] if self.aux_loss_weight else []
+            sown += [
                 ("moe_stats", "assignments", jnp.sum(load)),
                 ("moe_stats", "load_max_over_mean",
                  jnp.max(load) * E / (tokens * K)),
@@ -376,6 +494,19 @@ class DroplessMoE(nn.Module):
                     ("moe_stats", "held_load_max_over_mean",
                      jnp.max(mine) * count / jnp.maximum(jnp.sum(mine), 1.0)),
                 ]
+            if self.selection_bias:
+                # Under the parameter's own name: the learner adds a
+                # sown step to the leaf of `params` at the same path.
+                sown += [
+                    ("param_steps", "e_score_correction_bias",
+                     self.bias_update_rate
+                     * jnp.sign(jnp.mean(load) - load)),
+                    ("moe_stats", "bias_abs_max", jnp.max(jnp.abs(bias))),
+                ]
+            if self.shared_width:
+                sown.append(
+                    ("moe_stats", "shared_applications", jnp.float32(1.0))
+                )
             for collection, name, value in sown:
                 self.sow(
                     collection, name, value,

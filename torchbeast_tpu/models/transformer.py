@@ -84,6 +84,13 @@ def count_fused_application(module: nn.Module) -> None:
     _count_application(module, "fused_applications")
 
 
+def count_latent_application(module: nn.Module) -> None:
+    """One block application traced through ops/attention.
+    latent_cached_attend: `attention_latent_applications`, 5 in the
+    Kanana-2 cell."""
+    _count_application(module, "latent_applications")
+
+
 class _Block(nn.Module):
     d_model: int
     num_heads: int
@@ -398,10 +405,10 @@ class TransformerNet(nn.Module):
         return out, tuple(new_state)
 
     # What a family built on this scaffolding replaces (models/olmoe.py,
-    # models/mellum2.py, models/ouro.py): its block, its last norm, each
-    # cache entry's shape, and which block serves which entry. Everything
-    # else — observation and extras projections, masks, cache roll,
-    # state convention, head — is this class's.
+    # models/mellum2.py, models/ouro.py, models/kanana2.py): its block,
+    # its last norm, each cache entry's shape, and which block serves
+    # which entry. Everything else — observation and extras projections,
+    # masks, cache roll, state convention, head — is this class's.
     @nn.nowrap
     def make_block(self, name: str, layer: int):
         del layer  # every layer is the same block
@@ -425,11 +432,15 @@ class TransformerNet(nn.Module):
         return nn.LayerNorm()
 
     @nn.nowrap
-    def layer_caches(self) -> Tuple[Tuple[int, int, int], ...]:
+    def layer_caches(self) -> Tuple[Tuple[int, int, Any], ...]:
         """Each cache entry's (memory_len, key/value heads, head size):
-        the shape of the cache, [memory_len, B, heads, size], and the
-        band attended within. An entry a layer (`block_passes` says
-        which block serves which); here every layer has the one."""
+        the shape of the cache's two leaves, [memory_len, B, heads,
+        size], and the band attended within. Where the leaves differ
+        `size` is the pair of their sizes (models/kanana2.py: a latent
+        and a RoPE key, not a key and a value); the walk rolls each
+        leaf as it is, and what the two hold is the block's business.
+        An entry a layer (`block_passes` says which block serves
+        which); here every layer has the one."""
         return (
             (self.memory_len, self.num_heads, self.d_model // self.num_heads),
         ) * self.num_layers
@@ -443,11 +454,12 @@ class TransformerNet(nn.Module):
         return (tuple(range(len(self.layer_caches()))),)
 
     def initial_state(self, batch_size: int) -> Tuple:
-        return tuple(
-            (
-                jnp.zeros((M, batch_size, heads, hd), jnp.float32),
-                jnp.zeros((M, batch_size, heads, hd), jnp.float32),
+        def entry(M, heads, size):
+            first, second = size if isinstance(size, tuple) else (size, size)
+            return (
+                jnp.zeros((M, batch_size, heads, first), jnp.float32),
+                jnp.zeros((M, batch_size, heads, second), jnp.float32),
                 jnp.zeros((M, batch_size), jnp.float32),
             )
-            for M, heads, hd in self.layer_caches()
-        )
+
+        return tuple(entry(*cache) for cache in self.layer_caches())

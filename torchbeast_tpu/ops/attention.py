@@ -19,7 +19,7 @@ Equivalence of the two is pinned by tests/test_attention.py on the 8-device
 CPU mesh.
 
 The transformer policies attend over a rolling KV cache and over the
-unroll. Two bodies do that on one chip:
+unroll. Three bodies do that on one chip:
 
 - `cached_transformer_attend` (the OLMoE and Ouro blocks): the cache and
   the unroll are TWO LEGS OF ONE SOFTMAX. The cache is read where the
@@ -27,6 +27,11 @@ unroll. Two bodies do that on one chip:
   joined on the key axis, and no `[cache; k]` / `[cache; v]` is built;
   the cache is an input of its own, so the backward pass does a query's
   work through it and none for its keys and values.
+- `latent_cached_attend` (the Kanana-2 block): the same two legs for
+  latent attention, whose cache is one compressed latent and one RoPE
+  key a slot for all heads: the cache leg in absorbed form (the
+  queries carried into the latent's space, the combine lifted out of
+  it), the unroll leg on decompressed heads.
 - `dense_transformer_attend` on the concatenated `[cache; unroll]`:
   kept for `models/transformer._Block` (learned relative bias), its
   parity with the Ulysses path, `models/transformer_pp.py`, and the
@@ -733,6 +738,95 @@ def cached_transformer_attend(q, k, v, cache_k, cache_v, cache_mask,
         out_u.dtype
     )
     return out.reshape(B, T, H, D)
+
+
+def latent_cached_attend(q_nope, q_rope, k_nope, k_rope, v, cache_latent,
+                         cache_rope, w_uk, w_uv, cache_mask, seq_mask,
+                         place_cache_keys=None, cache_precision=None):
+    """`cached_transformer_attend` for latent attention (MLA, models/
+    kanana2.py): the cache holds, for all heads together, a compressed
+    latent and one un-rotated RoPE key a slot, and its leg is computed
+    in ABSORBED form, with nothing decompressed.
+
+    q_nope, k_nope: [B, T, H, Dn]; q_rope [B, T, H, Dr] and k_rope
+    [B, T, 1, Dr] (one key for every head), both with their positions
+    applied; v [B, T, H, Dv]: this unroll's, decompressed. cache_latent
+    [M, B, 1, C] and cache_rope [M, B, 1, Dr], THE STATE'S LAYOUT;
+    w_uk [C, H, Dn] and w_uv [C, H, Dv], the two halves of the
+    decompression (a cached key's content part is latent @ w_uk[:, h],
+    its value latent @ w_uv[:, h]); the masks and `place_cache_keys`
+    (applied to cache_rope) as in `cached_transformer_attend`. Returns
+    [B, T, H, Dv].
+
+    The cache leg: q_nope . (latent @ w_uk) = (q_nope @ w_uk^T) .
+    latent, so the H query heads, carried into the latent's space once
+    (`latent_absorb`), all score against ONE key of C + Dr a slot; the
+    weights combine the latents themselves, and what comes out is
+    lifted to Dv a head by w_uv (`latent_lift`, linear, so before the
+    division by the denominator). The unroll leg is the plain one on
+    the T decompressed keys and values. One maximum and one denominator
+    over both legs, scores in f32 and scaled by (Dn + Dr)^-0.5: what
+    dense attention over `[cache; unroll]` decompressed computes, in
+    another order (tests/test_kanana2.py). Nothing of [M, H, Dn + Dv]
+    is built, forward or backward; the cache takes no gradient unless
+    asked, w_uk and w_uv take theirs through both legs.
+
+    `cache_precision`, if given, is the matmul precision of the cache
+    leg's two products over the M slots (and of their gradients'),
+    whatever `jax.default_matmul_precision` the caller traces under:
+    they are most of the step's operations at a long cache, and their
+    sums run over keys, where rounding averages out.
+    """
+    M = cache_latent.shape[0]
+    scale = (q_nope.shape[-1] + q_rope.shape[-1]) ** -0.5
+    with jax.named_scope("latent_absorb"):
+        q_cache = jnp.concatenate(
+            [jnp.einsum("bqhd,chd->bqhc", q_nope, w_uk), q_rope], axis=-1
+        )
+    if place_cache_keys is not None:
+        # As in `cached_transformer_attend`: the joined key of a layer
+        # is built when the layer's queries are there, not for every
+        # layer at the program's start.
+        q_cache, slot_times = _queried_first(q_cache, jnp.arange(M) - M)
+        cache_rope = place_cache_keys(cache_rope, slot_times)
+    latent, cache_rope = cache_latent[:, :, 0], cache_rope[:, :, 0]
+
+    def masked(s, mask):
+        return jnp.where(mask[:, None], s.astype(jnp.float32) * scale, BIG_NEG)
+
+    with jax.named_scope("cache_leg"):
+        s_c = masked(
+            jnp.einsum(
+                "bqhc,mbc->bhqm", q_cache,
+                jnp.concatenate([latent, cache_rope], axis=-1),
+                precision=cache_precision,
+            ),
+            cache_mask,
+        )
+    with jax.named_scope("unroll_leg"):
+        s_u = masked(
+            jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
+            + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope[:, :, 0]),
+            seq_mask,
+        )
+    top = jax.lax.stop_gradient(
+        jnp.maximum(s_c.max(axis=-1), s_u.max(axis=-1))
+    )[..., None]
+    with jax.named_scope("cache_leg"):
+        p_c = jnp.exp(s_c - top)
+        out_c = jnp.einsum(
+            "bhqm,mbc->bqhc", p_c.astype(latent.dtype), latent,
+            precision=cache_precision,
+        )
+    with jax.named_scope("latent_lift"):
+        out_c = jnp.einsum("bqhc,chd->bqhd", out_c, w_uv)
+    with jax.named_scope("unroll_leg"):
+        p_u = jnp.exp(s_u - top)
+        out_u = jnp.einsum("bhqk,bkhd->bqhd", p_u.astype(v.dtype), v)
+    den = p_c.sum(axis=-1) + p_u.sum(axis=-1)  # [B, H, T]
+    return (out_c + out_u) / den.transpose(0, 2, 1)[..., None].astype(
+        out_u.dtype
+    )
 
 
 def fused_pass_applies(q_shape, k_shape, rel_bias) -> bool:

@@ -2167,6 +2167,7 @@ def train(flags):
                     ("moe", (
                         "assignments", "load_max_over_mean",
                         "held_assignments", "held_load_max_over_mean",
+                        "bias_abs_max", "bias_steps", "shared_applications",
                     )),
                     ("loop", (
                         "passes", "block_applications",
@@ -2175,6 +2176,7 @@ def train(flags):
                     )),
                     ("attention", (
                         "two_leg_applications", "fused_applications",
+                        "latent_applications", "latent_cache_bytes_per_row",
                     )),
                 ):
                     for name in names:
