@@ -773,3 +773,346 @@ def test_dense_transformer_attend_chooses_by_the_rule(
         q, k_all, v_all, mask, offsets, rel_bias
     )
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+# --- fused_latent_leg: the absorbed cache leg with its scores in VMEM -----
+
+LATENT_NAMES = ("q_nope", "q_rope", "k_nope", "k_rope", "v", "w_uk", "w_uv")
+
+
+def _latent_cache_mask(cache, rng, shape):
+    """[B, T, M] bool: a cache that is full, a third masked (at random:
+    a row may admit slots in one block and none in the next) or WHOLLY
+    masked."""
+    return jnp.asarray({
+        "full": np.ones(shape, bool),
+        "third-masked": rng.random(shape) < 0.67,
+        "wholly-masked": np.zeros(shape, bool),
+    }[cache])
+
+
+def _latent_case(cache, steps, slots, seed=0, heads=4, latent=128):
+    """Operands of `latent_cached_attend` as the Kanana-2 block hands
+    them over, at toy head sizes (16 + 8, values of 12) over a latent
+    of whole lane tiles: the seven that take a gradient, and the cache,
+    the masks and a cotangent."""
+    rng = np.random.default_rng(seed)
+    rows, nope, rope, value = 2, 16, 8, 12
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    operands = dict(
+        q_nope=normal(rows, steps, heads, nope),
+        q_rope=normal(rows, steps, heads, rope),
+        k_nope=normal(rows, steps, heads, nope),
+        k_rope=normal(rows, steps, 1, rope),
+        v=normal(rows, steps, heads, value),
+        w_uk=0.1 * normal(latent, heads, nope),
+        w_uv=0.1 * normal(latent, heads, value),
+    )
+    fixed = dict(
+        cache_latent=normal(slots, rows, 1, latent),
+        cache_rope=normal(slots, rows, 1, rope),
+        cache_mask=_latent_cache_mask(cache, rng, (rows, steps, slots)),
+        seq_mask=jnp.broadcast_to(
+            jnp.tril(jnp.ones((steps, steps), bool)), (rows, steps, steps)
+        ),
+        dout=normal(rows, steps, heads, value),
+    )
+    return operands, fixed
+
+
+def _latent_attend(operands, fixed, cache_precision="default"):
+    from torchbeast_tpu.models import kanana2
+    from torchbeast_tpu.ops.attention import latent_cached_attend
+
+    return latent_cached_attend(
+        *(operands[name] for name in LATENT_NAMES[:5]),
+        fixed["cache_latent"], fixed["cache_rope"],
+        operands["w_uk"], operands["w_uv"],
+        fixed["cache_mask"], fixed["seq_mask"],
+        place_cache_keys=lambda keys, times: kanana2.rope_pairs(
+            keys, times, 1e6, time_axis=0
+        ),
+        cache_precision=cache_precision,
+    )
+
+
+def _latent_value_and_grads(operands, fixed):
+    """A fresh jitted function (traces are cached by the function
+    traced): the output and the gradients of the seven operands."""
+    def run(operands):
+        out, pull = jax.vjp(lambda o: _latent_attend(o, fixed), operands)
+        return out, pull(fixed["dout"])[0]
+
+    fresh = jax.jit(run)
+    return fresh(operands)
+
+
+@pytest.mark.parametrize(
+    "cache, steps, slots, blocks",
+    [
+        ("full", 16, 2048, (1024, 1024)),
+        ("full", 5, 1300, (768, 768)),
+        ("third-masked", 16, 1300, (768, 768)),
+        ("third-masked", 3, 300, (384, 384)),
+        ("wholly-masked", 5, 1300, (768, 768)),
+        ("wholly-masked", 8, 100, (128, 128)),
+    ],
+    ids=[
+        "full-whole-blocks", "full-ragged-5-steps", "third-masked-ragged",
+        "third-masked-1-block-3-steps", "wholly-masked-ragged",
+        "wholly-masked-1-block",
+    ],
+)
+def test_fused_latent_leg_is_the_xla_body(
+    monkeypatch, cache, steps, slots, blocks
+):
+    """`latent_cached_attend` with its cache leg as the blockwise pass
+    (interpreted here, f32) against its XLA body on the same operands:
+    the output and the gradients of q_nope, q_rope, w_uk, w_uv and the
+    unroll's k_nope, k_rope, v. 2,048 slots fill their two blocks of
+    1,024; 1,300 do not fill the last of two of 768; 300 and 100 are
+    one ragged block; 5 and 3 steps are no multiple of the sublane
+    tile. A
+    WHOLLY masked cache weighs nothing in the join: the result is the
+    unroll leg alone, and every gradient is finite (w_uk and w_uv,
+    which only the cache leg reads, take exact zeros)."""
+    from torchbeast_tpu.ops import attention, fused_attention
+    from torchbeast_tpu.ops.fused_attention import key_block
+
+    assert blocks == (
+        key_block(slots, fused_attention._LATENT_FORWARD_KEYS),
+        key_block(slots, fused_attention._LATENT_BACKWARD_KEYS),
+    )
+    operands, fixed = _latent_case(cache, steps, slots, seed=steps + slots)
+    want, want_grads = _latent_value_and_grads(operands, fixed)
+    assert "pallas_call" not in str(
+        jax.make_jaxpr(lambda o: _latent_attend(o, fixed))(operands)
+    )
+    monkeypatch.setattr(attention, "FUSED_SCORE_BYTES", 1)
+    assert "pallas_call" in str(
+        jax.make_jaxpr(lambda o: _latent_attend(o, fixed))(operands)
+    )
+    got, got_grads = _latent_value_and_grads(operands, fixed)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for name in LATENT_NAMES:
+        a, b = got_grads[name], want_grads[name]
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert bool(jnp.isfinite(a).all()), name
+        np.testing.assert_allclose(
+            a, b, rtol=1e-5, atol=1e-5 * max(1.0, float(jnp.abs(b).max())),
+            err_msg=name,
+        )
+    if cache == "wholly-masked":
+        for name in ("w_uk", "w_uv"):
+            np.testing.assert_array_equal(got_grads[name], 0.0)
+    else:
+        assert float(jnp.abs(got_grads["w_uk"]).max()) > 0
+
+
+@pytest.mark.parametrize("cache", ["full", "third-masked", "wholly-masked"])
+def test_fused_latent_leg_takes_a_cotangent_on_its_log_sum_exp(cache):
+    """The leg alone against the same in plain jnp: its output, the
+    rows' log-sum-exp, and the gradients of the queries' two parts when
+    BOTH results carry a cotangent (ds = p (dP - delta + dlse)); 8 heads
+    in two cells of 4 (`_LATENT_HEADS` lowered), 11 steps padded to 16
+    as the caller pads them. A row that admits no slot (the padded
+    steps always) reads a finite output (an average of the latents,
+    over the last block's padding too: the join weighs it by 0),
+    BIG_NEG for a log-sum-exp and a gradient of zeros, whatever its
+    cotangents."""
+    from torchbeast_tpu.ops import fused_attention
+    from torchbeast_tpu.ops.fused_attention import (
+        BIG_NEG,
+        fused_latent_leg,
+        padded_steps,
+    )
+
+    rng = np.random.default_rng(11)
+    rows, steps, heads, slots, latent, rope = 2, 11, 8, 700, 128, 8
+    tp = padded_steps(steps)
+    assert tp == 16
+    scale = 24 ** -0.5
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    def padded(x):
+        return jnp.pad(x, ((0, 0), (0, 0), (0, tp - steps), (0, 0)))
+
+    q_latent = padded(normal(heads, rows, steps, latent))
+    q_rope = padded(normal(heads, rows, steps, rope))
+    cache_latent, cache_rope = normal(slots, rows, latent), normal(slots, rows, rope)
+    mask = _latent_cache_mask(cache, rng, (rows, steps, slots))
+    dout, dlse = normal(heads, rows, tp, latent), normal(heads, rows, tp)
+
+    def plain(q_latent, q_rope):
+        s = scale * (
+            jnp.einsum("hbqc,mbc->hbqm", q_latent, cache_latent)
+            + jnp.einsum("hbqd,mbd->hbqm", q_rope, cache_rope)
+        )
+        admitted = jnp.pad(mask, ((0, 0), (0, tp - steps), (0, 0)))
+        s = jnp.where(admitted[None], s, BIG_NEG)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        out = jnp.einsum(
+            "hbqm,mbc->hbqc", jnp.exp(s - lse[..., None]), cache_latent
+        )
+        return out, lse
+
+    def fused(q_latent, q_rope):
+        return fused_latent_leg(
+            q_latent, q_rope, cache_latent, cache_rope, mask, scale
+        )
+
+    def results_and_grads(leg):
+        (out, lse), pull = jax.vjp(leg, q_latent, q_rope)
+        return (out, lse) + pull((dout, dlse))
+
+    of_fused = jax.jit(lambda: results_and_grads(fused))
+    of_plain = jax.jit(lambda: results_and_grads(plain))
+    saved = fused_attention._LATENT_HEADS
+    fused_attention._LATENT_HEADS = 4
+    try:
+        got = of_fused()
+    finally:
+        fused_attention._LATENT_HEADS = saved
+        # The calls are jitted of their own: no later trace at these
+        # shapes may find this one's cells.
+        fused_attention._latent_forward_call.clear_cache()
+        fused_attention._latent_backward_call.clear_cache()
+    want = of_plain()
+    dead = np.ones((heads, rows, tp), bool)
+    if cache != "wholly-masked":
+        dead[:, :, :steps] = ~np.asarray(mask).any(axis=-1)[None]
+    assert dead[:, :, steps:].all() and (
+        dead[:, :, :steps].all() == (cache == "wholly-masked")
+    )
+    for name, a, b in zip(("out", "lse", "dq_latent", "dq_rope"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert bool(jnp.isfinite(a).all()), name
+        np.testing.assert_allclose(
+            a[~dead], b[~dead], rtol=2e-5, atol=2e-5, err_msg=name
+        )
+    # BIG_NEG + log(slots) is BIG_NEG in f32.
+    np.testing.assert_array_equal(got[1][dead], np.float32(BIG_NEG))
+    for grad in got[2:]:
+        np.testing.assert_array_equal(grad[dead], 0.0)
+
+
+def test_a_latent_cache_is_data_in_both_regimes(monkeypatch):
+    """The cache takes no gradient from `latent_cached_attend`, fused
+    leg or XLA body: the contract is one (the fused backward makes dq
+    alone, and must not differ from the other regime in silence)."""
+    from torchbeast_tpu.ops import attention
+
+    operands, fixed = _latent_case("third-masked", 5, 300, seed=2)
+
+    def cache_grads():
+        return jax.grad(
+            lambda latent, rope: jnp.sum(jnp.sin(_latent_attend(
+                operands, dict(fixed, cache_latent=latent, cache_rope=rope)
+            ))),
+            (0, 1),
+        )(fixed["cache_latent"], fixed["cache_rope"])
+
+    for threshold in (attention.FUSED_SCORE_BYTES, 1):
+        monkeypatch.setattr(attention, "FUSED_SCORE_BYTES", threshold)
+        for grad in cache_grads():
+            np.testing.assert_array_equal(grad, 0.0)
+
+
+def test_fused_latent_leg_under_remat(monkeypatch):
+    """`--remat all` wraps the block: the rematerialised forward runs
+    the kernel again and the gradients are those without it."""
+    from torchbeast_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "FUSED_SCORE_BYTES", 1)
+    operands, fixed = _latent_case("third-masked", 5, 300, seed=4)
+
+    def grads(attend):
+        of = jax.jit(jax.grad(lambda o: jnp.sum(jnp.sin(attend(o)))))
+        return of(operands)
+
+    plain = grads(lambda o: _latent_attend(o, fixed))
+    remat = grads(jax.checkpoint(lambda o: _latent_attend(o, fixed)))
+    for name in LATENT_NAMES:
+        np.testing.assert_array_equal(remat[name], plain[name], err_msg=name)
+
+
+@pytest.mark.parametrize(
+    "q_shape, slots, latent, precision, ambient, fused",
+    [
+        ((32, 81, 32, 576), 4095, 512, "default", "high", True),
+        ((32, 81, 32, 576), 4095, 512, None, None, True),
+        ((32, 81, 32, 576), 4095, 512, "high", None, False),
+        ((32, 81, 32, 576), 4095, 512, "highest", "high", False),
+        ((32, 81, 32, 576), 4095, 512, None, "high", False),
+        ((32, 1, 32, 576), 4095, 512, "default", "high", False),
+        ((64, 1, 32, 576), 4095, 512, "default", "high", False),
+        ((2, 6, 4, 32), 9, 24, "default", "high", False),
+        ((32, 81, 32, 576), 4095, 576, "default", "high", False),
+        ((4, 81, 32, 576), 4095, 512, "default", "high", True),
+        ((4, 81, 32, 576), 255, 512, "default", "high", False),
+    ],
+    ids=[
+        "kanana2-published", "one-pass-by-the-caller's-trace",
+        "leg-at-high", "leg-at-highest", "high-by-the-caller's-trace",
+        "acting-32-rows", "acting-64-rows", "tier-1-toy",
+        "a-latent-of-4.5-lane-tiles", "a-chip's-4-rows", "a-short-cache",
+    ],
+)
+def test_fused_latent_leg_applies_by_shapes_and_precision_alone(
+    q_shape, slots, latent, precision, ambient, fused
+):
+    """The rule: 128 MiB of f32 scores or more in the leg, a latent of
+    whole lane tiles, the leg's products at one bf16 pass (what the
+    kernels compute). Kanana-2's learner step at the published widths is
+    above it (1,359 MB a layer; 170 MB at a chip's 4 rows); acting at
+    T=1, everything tier-1 builds and a leg asked for (or traced) at
+    `high` are not."""
+    from torchbeast_tpu.ops.attention import fused_latent_leg_applies
+
+    with jax.default_matmul_precision(ambient or "default"):
+        if ambient is None:
+            jax.config.update("jax_default_matmul_precision", None)
+        assert fused_latent_leg_applies(
+            q_shape, slots, latent, precision
+        ) is fused
+
+
+@pytest.mark.parametrize(
+    "steps, precision, fused",
+    [(81, "default", True), (1, "default", False), (81, "high", False)],
+    ids=["learner-step", "act-step", "leg-at-high"],
+)
+def test_latent_cached_attend_chooses_by_the_rule(steps, precision, fused):
+    """At Kanana-2's published widths (traced on shapes alone, nothing
+    computed): the learner's [81, 32] step compiles the kernels in, a
+    T=1 act step and a leg asked for at `high` keep the einsums."""
+    from torchbeast_tpu.ops.attention import latent_cached_attend
+
+    B_, H_, C_, Dn, Dr, Dv, M_ = 32, 32, 512, 128, 64, 128, 4095
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    def mask(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bool_)
+
+    program = str(jax.make_jaxpr(
+        lambda *operands: latent_cached_attend(
+            *operands, cache_precision=precision
+        )
+    )(
+        f32(B_, steps, H_, Dn), f32(B_, steps, H_, Dr),
+        f32(B_, steps, H_, Dn), f32(B_, steps, 1, Dr),
+        f32(B_, steps, H_, Dv), f32(M_, B_, 1, C_), f32(M_, B_, 1, Dr),
+        f32(C_, H_, Dn), f32(C_, H_, Dv),
+        mask(B_, steps, M_), mask(B_, steps, steps),
+    ))
+    assert ("pallas_call" in program) is fused
+    # The leg's f32 scores are an array of the program in one regime only.
+    assert (f"f32[{B_},{H_},{steps},{M_}]" in program) is not fused

@@ -281,6 +281,54 @@ def test_mellum2_fused_attention_compiles_for_v5e(one_chip, monkeypatch, keys):
     assert not over_keys, over_keys
 
 
+def test_kanana2_fused_latent_leg_compiles_for_v5e(one_chip, monkeypatch):
+    """The Kanana-2 cell's cache leg (ops/fused_attention.py `fused_
+    latent_leg`), forward and backward at the published widths: 32
+    heads' absorbed queries, head-major and their 81 steps padded to 88
+    ([32 heads, 32, 88, 512] and [.., 64], f32), against ONE joined key a
+    slot over 4,095 slots, a cotangent on both of its results. The rule
+    takes it, two Mosaic kernels fit the scoped VMEM they ask for, and
+    the compiled program holds no f32 array whose last dimension is the
+    slots: the scores' [32, 32, 81, 4095] (1.36 GB) are never built."""
+    import re
+
+    from torchbeast_tpu.ops import attention
+    from torchbeast_tpu.ops.fused_attention import (
+        fused_latent_leg,
+        padded_steps,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, t, h, latent, rope, slots = B, T + 1, 32, 512, 64, 4095
+    tp = padded_steps(t)
+    assert tp == 88
+    assert attention.fused_latent_leg_applies(
+        (b, t, h, rope), slots, latent, "default"
+    )
+
+    def loss(q_latent, q_rope, cache_latent, cache_rope, mask, dout, dlse):
+        out, lse = fused_latent_leg(
+            q_latent, q_rope, cache_latent, cache_rope, mask, 192 ** -0.5
+        )
+        return jnp.sum(out * dout) + jnp.sum(lse * dlse)
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(
+        _struct(one_chip, (h, b, tp, latent)),
+        _struct(one_chip, (h, b, tp, rope)),
+        _struct(one_chip, (slots, b, latent)),
+        _struct(one_chip, (slots, b, rope)),
+        _struct(one_chip, (b, t, slots), jnp.bool_),
+        _struct(one_chip, (h, b, tp, latent)),
+        _struct(one_chip, (h, b, tp)),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    over_slots = {
+        dims for dims in re.findall(r"f32\[([0-9,]+)\]", text)
+        if dims.endswith((f",{slots}", f",{slots + 1}"))
+    }
+    assert not over_slots, over_slots
+
+
 def _ouro_loop_gradient_memory(chip):
     """Temp bytes of the gradient of ONE Ouro layer at the published
     widths run 4 times (the family's loop), rematerialised, over its 4
@@ -337,10 +385,13 @@ def test_kanana2_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     configuration's own argv: 5 layers, 4,095-slot latent caches, share
     0/8, blocks rematerialised, [81, 32] batch), whole, for a described
     v5e: its bytes, read before the cell's first chip run (PR 38: 11.4
-    GiB; the driver keeps a 2.12 GiB copy of the weights beside it), and
-    the absorbed form seen in the program: the f32 scores [32, 32, 81,
-    4095] are there, no array of decompressed cached keys or values
-    (4,095 slots x 32 heads of 128, 192 or 256) is."""
+    GiB; PR 41: 10.42, the score-sized temporaries gone; the driver
+    keeps a 2.12 GiB copy of the weights beside it), and
+    the absorbed form seen in the program: no array of decompressed
+    cached keys or values (4,095 slots x 32 heads of 128, 192 or 256)
+    is there, and since PR 41 no f32 array over the slots at all: the
+    cache leg's scores [32, 32, 81, 4095] live in the VMEM of `fused_
+    latent_leg`'s two kernels."""
     import json
     import re
 
@@ -391,18 +442,30 @@ def test_kanana2_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     # 15.75 GiB a chip, less the driver's copy of the weights.
     assert total < 15.75 * 2**30 - weights, memory
     assert total > 8 * 2**30, memory  # the cell fills the chip
+    # The family's `update_compiler_options` reached the compiler: the
+    # blocks' shared parts compiled once (416 MB of program without).
+    assert memory.generated_code_size_in_bytes < 200 * 2**20, memory
     shapes = {
         tuple(int(d) for d in dims.split(","))
         for dims in re.findall(r"f32\[([0-9,]+)\]", compiled.as_text())
     }
-    assert (B, 32, T + 1, 4095) in shapes  # the cache leg's scores
+    # The cache leg's scores, at their own size or a padded one.
+    scores = {s for s in shapes if s[-1] in (4095, 4096) and len(s) >= 4}
+    assert not scores, scores
+    # (Rank 4 or more: [32, 4095, 128] is the cached rope keys placed,
+    # padded to a lane tile and laid batch-major for the kernels.)
     decompressed = {
-        s for s in shapes if 4095 in s and s[-1] in (128, 192, 256, 320)
+        s for s in shapes
+        if 4095 in s and s[-1] in (128, 192, 256, 320) and len(s) >= 4
     }
     assert not decompressed, decompressed
     # The grouped expert matmuls at the family's three passes: four MoE
-    # layers x (3 forward, 3 rematerialised, 6 backward) x 3.
-    assert compiled.as_text().count("tpu_custom_call") == 144
+    # layers x (3 forward, 3 rematerialised, 6 backward) x 3; and the
+    # cache leg's kernels: five layers x (forward, rematerialised,
+    # backward).
+    assert compiled.as_text().count("tpu_custom_call") == 144 + 15
+    assert compiled.as_text().count("fused_latent_leg_forward") >= 10
+    assert compiled.as_text().count("fused_latent_leg_backward") >= 5
 
 
 def test_flagship_act_step_compiles_for_v5e(one_chip, monkeypatch):

@@ -861,7 +861,19 @@ def make_update_step(
     return jax.jit(
         update_body(one_device_model(model), optimizer, hp),
         donate_argnums=donate_argnums_for(donate, donate_batch),
+        compiler_options=update_compiler_options(model),
     )
+
+
+def update_compiler_options(model):
+    """The XLA options a family names for its update step (a tuple of
+    pairs, `update_compiler_options`: models/kanana2.py), on the chip
+    alone (they are the TPU compiler's; the CPU's refuses what it does
+    not know); None for a family that names none."""
+    options = getattr(model, "update_compiler_options", ())
+    if options and jax.default_backend() == "tpu":
+        return dict(options)
+    return None
 
 
 # beastlint: hot

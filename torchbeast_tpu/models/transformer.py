@@ -91,6 +91,15 @@ def count_latent_application(module: nn.Module) -> None:
     _count_application(module, "latent_applications")
 
 
+def count_latent_fused_application(module: nn.Module) -> None:
+    """One block application whose `latent_cached_attend` computed its
+    cache leg by the fused pass (ops/attention.py `fused_latent_leg_
+    applies`, asked by the block with the shapes it hands over):
+    `attention_latent_fused_applications`, 5 in the Kanana-2 cell, no
+    such key where no block took it."""
+    _count_application(module, "latent_fused_applications")
+
+
 class _Block(nn.Module):
     d_model: int
     num_heads: int

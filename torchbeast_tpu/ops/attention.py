@@ -31,7 +31,15 @@ unroll. Three bodies do that on one chip:
   latent attention, whose cache is one compressed latent and one RoPE
   key a slot for all heads: the cache leg in absorbed form (the
   queries carried into the latent's space, the combine lifted out of
-  it), the unroll leg on decompressed heads.
+  it), the unroll leg on decompressed heads. One function in two
+  regimes, chosen by `fused_latent_leg_applies` from the operands'
+  shapes and the leg's precision: plain einsums with the leg's f32
+  scores [B, H, T, M] in HBM where they are small; where they are
+  128 MiB or more and the leg is at one bf16 pass (the Kanana-2
+  learner step: 1.36 GB a layer) `ops/fused_attention.fused_latent_
+  leg`, all heads of a group against ONE joined key a slot blockwise
+  over the slots, forward and backward, whose scores never leave
+  VMEM; the two legs are then joined by their log-sum-exps.
 - `dense_transformer_attend` on the concatenated `[cache; unroll]`:
   kept for `models/transformer._Block` (learned relative bias), its
   parity with the Ulysses path, `models/transformer_pp.py`, and the
@@ -56,7 +64,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from torchbeast_tpu.ops.fused_attention import BIG_NEG, fused_attend
+from torchbeast_tpu.ops.fused_attention import (
+    BIG_NEG,
+    fused_attend,
+    fused_latent_leg,
+    padded_steps,
+)
 
 # f32 score bytes (B x H x T x K x 4) from which `dense_transformer_
 # attend` takes the fused pass: see `fused_pass_applies`.
@@ -768,64 +781,166 @@ def latent_cached_attend(q_nope, q_rope, k_nope, k_rope, v, cache_latent,
     over both legs, scores in f32 and scaled by (Dn + Dr)^-0.5: what
     dense attention over `[cache; unroll]` decompressed computes, in
     another order (tests/test_kanana2.py). Nothing of [M, H, Dn + Dv]
-    is built, forward or backward; the cache takes no gradient unless
-    asked, w_uk and w_uv take theirs through both legs.
+    is built, forward or backward. THE CACHE IS DATA: cache_latent and
+    cache_rope take no gradient (zeros, in both regimes below; no
+    caller asks, and the fused leg makes none); w_uk and w_uv take
+    theirs through both legs.
 
     `cache_precision`, if given, is the matmul precision of the cache
     leg's two products over the M slots (and of their gradients'),
     whatever `jax.default_matmul_precision` the caller traces under:
     they are most of the step's operations at a long cache, and their
     sums run over keys, where rounding averages out.
+
+    One function, two regimes, chosen by `fused_latent_leg_applies` (no
+    flag). Below 128 MiB of f32 scores in the leg (acting, toy widths),
+    or with the leg above one bf16 pass: the einsums below, the scores
+    [B, H, T, M] left to XLA. At or over it (the learner's step at the
+    published widths): `ops/fused_attention.fused_latent_leg`, which
+    returns the leg's output normalised within the leg and the rows'
+    log-sum-exp, and the two legs are joined by `top = max(lse_c, max
+    s_u)`, `den = exp(lse_c - top) + sum exp(s_u - top)`, `out =
+    (exp(lse_c - top) lift(out_c) + p_u v) / den`: the same sums in
+    another order, the same precision (bf16 operands, f32 sums and
+    softmax on the chip), every admitted slot summed and no block
+    skipped on the mask. There the queries are made head-major with
+    their steps padded to whole sublane tiles BEFORE the absorb, so
+    that the absorb writes what the kernel reads and the lift reads
+    what it writes. A row that admits no slot weighs the leg by
+    exp(BIG_NEG - top) = 0: the unroll leg alone, finite gradients.
     """
     M = cache_latent.shape[0]
     scale = (q_nope.shape[-1] + q_rope.shape[-1]) ** -0.5
-    with jax.named_scope("latent_absorb"):
-        q_cache = jnp.concatenate(
-            [jnp.einsum("bqhd,chd->bqhc", q_nope, w_uk), q_rope], axis=-1
-        )
-    if place_cache_keys is not None:
+    # The cache is data, in both regimes.
+    cache_latent = jax.lax.stop_gradient(cache_latent)
+    cache_rope = jax.lax.stop_gradient(cache_rope)
+    fused = fused_latent_leg_applies(
+        q_rope.shape, M, cache_latent.shape[-1], cache_precision
+    )
+
+    def placed(queries):
         # As in `cached_transformer_attend`: the joined key of a layer
         # is built when the layer's queries are there, not for every
         # layer at the program's start.
-        q_cache, slot_times = _queried_first(q_cache, jnp.arange(M) - M)
-        cache_rope = place_cache_keys(cache_rope, slot_times)
-    latent, cache_rope = cache_latent[:, :, 0], cache_rope[:, :, 0]
+        # The cache follows, one key a slot for all heads: [M, B, .].
+        rope = cache_rope
+        if place_cache_keys is not None:
+            queries, slot_times = _queried_first(queries, jnp.arange(M) - M)
+            rope = place_cache_keys(rope, slot_times)
+        return queries, cache_latent[:, :, 0], rope[:, :, 0]
 
     def masked(s, mask):
         return jnp.where(mask[:, None], s.astype(jnp.float32) * scale, BIG_NEG)
 
-    with jax.named_scope("cache_leg"):
-        s_c = masked(
-            jnp.einsum(
-                "bqhc,mbc->bhqm", q_cache,
-                jnp.concatenate([latent, cache_rope], axis=-1),
+    def unroll_scores():
+        with jax.named_scope("unroll_leg"):
+            return masked(
+                jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
+                + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope[:, :, 0]),
+                seq_mask,
+            )
+
+    if fused:
+        # The queries head-major ([H, B, Tp, .]: where a matmul
+        # batched over heads writes them), their steps padded to whole
+        # sublane tiles where they are still 128 and 64 wide: the
+        # absorb's output is then what the kernel reads, its output
+        # what the lift reads, and nothing latent-sized is relaid.
+        T = q_nope.shape[1]
+        steps = ((0, 0), (0, padded_steps(T) - T), (0, 0), (0, 0))
+        q_nope, latent, cache_rope = placed(q_nope)
+        with jax.named_scope("latent_absorb"):
+            q_latent = jnp.einsum(
+                "bqhd,chd->hbqc", jnp.pad(q_nope, steps), w_uk
+            )
+        with jax.named_scope("cache_leg"):
+            out_c, lse_c = fused_latent_leg(
+                q_latent, jnp.pad(q_rope, steps).transpose(2, 0, 1, 3),
+                latent, cache_rope, cache_mask, scale,
+            )
+            lse_c = lse_c[:, :, :T].transpose(1, 0, 2)  # [B, H, T]
+        s_u = unroll_scores()
+        top = jax.lax.stop_gradient(jnp.maximum(lse_c, s_u.max(axis=-1)))
+        # The leg's share of the one denominator: its own, rescaled.
+        den_c = jnp.exp(lse_c - top)
+        with jax.named_scope("latent_lift"):
+            out_c = jnp.einsum(
+                "hbqc,chd->hbqd", out_c.astype(w_uv.dtype), w_uv
+            )[:, :, :T].transpose(1, 2, 0, 3)
+        out_c = out_c * den_c.transpose(0, 2, 1)[..., None].astype(
+            out_c.dtype
+        )
+        top = top[..., None]
+    else:
+        with jax.named_scope("latent_absorb"):
+            q_cache = jnp.concatenate(
+                [jnp.einsum("bqhd,chd->bqhc", q_nope, w_uk), q_rope],
+                axis=-1,
+            )
+        q_cache, latent, cache_rope = placed(q_cache)
+        with jax.named_scope("cache_leg"):
+            s_c = masked(
+                jnp.einsum(
+                    "bqhc,mbc->bhqm", q_cache,
+                    jnp.concatenate([latent, cache_rope], axis=-1),
+                    precision=cache_precision,
+                ),
+                cache_mask,
+            )
+        s_u = unroll_scores()
+        top = jax.lax.stop_gradient(
+            jnp.maximum(s_c.max(axis=-1), s_u.max(axis=-1))
+        )[..., None]
+        with jax.named_scope("cache_leg"):
+            p_c = jnp.exp(s_c - top)
+            out_c = jnp.einsum(
+                "bhqm,mbc->bqhc", p_c.astype(latent.dtype), latent,
                 precision=cache_precision,
-            ),
-            cache_mask,
-        )
-    with jax.named_scope("unroll_leg"):
-        s_u = masked(
-            jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
-            + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope[:, :, 0]),
-            seq_mask,
-        )
-    top = jax.lax.stop_gradient(
-        jnp.maximum(s_c.max(axis=-1), s_u.max(axis=-1))
-    )[..., None]
-    with jax.named_scope("cache_leg"):
-        p_c = jnp.exp(s_c - top)
-        out_c = jnp.einsum(
-            "bhqm,mbc->bqhc", p_c.astype(latent.dtype), latent,
-            precision=cache_precision,
-        )
-    with jax.named_scope("latent_lift"):
-        out_c = jnp.einsum("bqhc,chd->bqhd", out_c, w_uv)
+            )
+        with jax.named_scope("latent_lift"):
+            out_c = jnp.einsum("bqhc,chd->bqhd", out_c, w_uv)
     with jax.named_scope("unroll_leg"):
         p_u = jnp.exp(s_u - top)
         out_u = jnp.einsum("bhqk,bkhd->bqhd", p_u.astype(v.dtype), v)
-    den = p_c.sum(axis=-1) + p_u.sum(axis=-1)  # [B, H, T]
+    # [B, H, T]; the XLA body's sum where it always stood in the program.
+    den = (den_c if fused else p_c.sum(axis=-1)) + p_u.sum(axis=-1)
     return (out_c + out_u) / den.transpose(0, 2, 1)[..., None].astype(
         out_u.dtype
+    )
+
+
+def _one_bf16_pass(precision) -> bool:
+    """Whether a float32 matmul traced now at `precision` (None: as the
+    caller traces) is one bfloat16 pass on the chip."""
+    if precision is None:
+        precision = jax.config.jax_default_matmul_precision
+    try:
+        return jax.lax.Precision(precision) == jax.lax.Precision.DEFAULT
+    except ValueError:  # an algorithm's name, a pair: not read here
+        return False
+
+
+def fused_latent_leg_applies(q_shape, num_slots, latent_rank,
+                             cache_precision) -> bool:
+    """Whether `latent_cached_attend` computes its cache leg by `ops/
+    fused_attention.fused_latent_leg` for absorbed queries [B, T, H, *]
+    against `num_slots` cached slots: f32 scores of the leg of `FUSED_
+    SCORE_BYTES` (128 MiB) or more, a latent whose width is whole lane
+    tiles (the combine reads the joined key's first `latent_rank`
+    columns), and the leg's two products at one bfloat16 pass, which is
+    what the kernels compute: a `cache_precision` of `high` / `highest`,
+    or None under a caller that traces so, keeps the XLA body. The same
+    kind of rule as `fused_pass_applies`, and for its reasons: the body
+    asks it, and a block asks it to count what it compiled in
+    (`attention_latent_fused_applications`). Kanana-2's learner step is
+    above it (32 x 32 x 81 x 4,095 x 4 = 1,359 MB a layer); acting at
+    T=1 (16.8 MB), tier-1's toy widths and tests/perfbench's tiny cell
+    are below."""
+    B, T, H = q_shape[:3]
+    return (
+        latent_rank % 128 == 0
+        and B * H * T * num_slots * 4 >= FUSED_SCORE_BYTES
+        and _one_bf16_pass(cache_precision)
     )
 
 
@@ -838,6 +953,10 @@ def fused_pass_applies(q_shape, k_shape, rel_bias) -> bool:
     is None` alone: the body asks it, and a block asks it to count what
     it compiled in (`attention_fused_applications`, models/
     transformer.py `count_fused_application`).
+
+    Kanana-2's heads are not these (192-wide unroll keys, one 576-wide
+    cache key for all heads): its cache leg has a rule and a pass of
+    its own, `fused_latent_leg_applies`.
 
     Why 128 MiB: it lies between what was measured to gain and what
     nothing measures. At the Mellum2 cell's sizes (366 MB a window

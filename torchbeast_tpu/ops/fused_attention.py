@@ -53,6 +53,15 @@ output and the rows' log-sum-exp (lane-replicated, `[.., 128]`). Told
 that the first n keys take no gradient (`no_grad_keys`: a cache that is
 the learner's data), the kernel makes `dk`, `dv` from the block that
 holds key n on and writes no row before it.
+
+**The latent leg** (PR 41). `fused_latent_leg` is the same pass for the
+cache leg of latent attention (`ops/attention.latent_cached_attend`,
+the Kanana-2 cell): a group of heads' absorbed queries against ONE
+joined key a slot, whose first columns are also the values; it returns
+the leg's output and the rows' log-sum-exp, both differentiable, for
+the caller to join with the unroll leg. Its own section, below, says
+what differs: no `dk`, `dv` at all, rows that admit no slot, operands
+head-major as the einsums around it make and read them.
 """
 
 import functools
@@ -85,9 +94,10 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
-def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+def _dot(a, b, dims=(((1,), (0,)), ((), ())), precision=None):
     return jax.lax.dot_general(
-        a, b, dims, preferred_element_type=jnp.float32
+        a, b, dims, precision=precision,
+        preferred_element_type=jnp.float32,
     )
 
 
@@ -104,6 +114,23 @@ def key_block(num_keys: int, most: int) -> int:
     )
 
 
+def padded_steps(steps: int) -> int:
+    """T in whole sublane tiles: the steps of a head's query rows."""
+    return -(-steps // _SUBLANES) * _SUBLANES
+
+
+def _padded_keys(num_keys: int, forward_most: int, backward_most: int) -> int:
+    """The keys in whole blocks of either pass: what a mask is padded
+    to."""
+    return max(
+        -(-num_keys // block) * block
+        for block in (
+            key_block(num_keys, forward_most),
+            key_block(num_keys, backward_most),
+        )
+    )
+
+
 def _whole_keys(x, block_index, num_keys):
     """A [block, D] tile of keys or values with the rows past the last
     key zeroed: where the keys do not fill their last block the tile's
@@ -115,12 +142,12 @@ def _whole_keys(x, block_index, num_keys):
     return jnp.where(row < num_keys - block_index * block, x, 0)
 
 
-def _scores(q, k, admitted, scale, groups):
+def _scores(q, k, admitted, scale, groups, precision=None):
     """Masked f32 scores [G * Tp, block] of a cell: q [G * Tp, D],
     k [block, D], admitted [Tp, block] int8 (the mask's slab, shared by
     the G query heads of the group)."""
     rows, block = q.shape[0], k.shape[0]
-    s = _dot(q, k, _NT) * scale
+    s = _dot(q, k, _NT, precision) * scale
     s = s.reshape(groups, rows // groups, block)
     s = jnp.where((admitted != 0)[None], s, BIG_NEG)
     return s.reshape(rows, block)
@@ -212,13 +239,13 @@ def _key_spec(d, hkv, block, first_block=0):
     )
 
 
-def _compiler_params(interpret):
+def _compiler_params(interpret, vmem_limit=None):
     if interpret:
         return {}
     return {
         "compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT,
+            vmem_limit_bytes=vmem_limit or _VMEM_LIMIT,
         )
     }
 
@@ -300,7 +327,7 @@ def _as_rows(x, hkv, tp):
 def _from_rows(x, t):
     """`_as_rows` undone: [B, Hkv, G * Tp, D] -> [B, T, H, D]."""
     b, hkv, rows, d = x.shape
-    tp = -(-t // _SUBLANES) * _SUBLANES
+    tp = padded_steps(t)
     x = x.reshape(b, hkv, rows // tp, tp, d)[:, :, :, :t]
     return x.transpose(0, 3, 1, 2, 4).reshape(b, t, -1, d)
 
@@ -332,14 +359,8 @@ def _operands(q, k_all, v_all, mask, on_chip):
     False to a whole number of either pass's blocks."""
     t, hkv = q.shape[1], k_all.shape[2]
     num_keys = k_all.shape[1]
-    tp = -(-t // _SUBLANES) * _SUBLANES
-    kp = max(
-        -(-num_keys // block) * block
-        for block in (
-            key_block(num_keys, _FORWARD_KEYS),
-            key_block(num_keys, _BACKWARD_KEYS),
-        )
-    )
+    tp = padded_steps(t)
+    kp = _padded_keys(num_keys, _FORWARD_KEYS, _BACKWARD_KEYS)
     operand = jnp.bfloat16 if on_chip else jnp.float32
     return (
         _as_rows(q.astype(operand), hkv, tp),
@@ -408,4 +429,360 @@ def fused_attend(q, k_all, v_all, mask, no_grad_keys=0):
     """
     return _fused_attend(
         q, k_all, v_all, mask, no_grad_keys, jax.default_backend() == "tpu"
+    )
+
+
+# --- The latent leg: H heads against ONE key a slot -----------------------
+#
+# Latent attention (ops/attention.latent_cached_attend) reads its cache
+# in absorbed form: every head's query, carried into the latent's space,
+# scores against the same `C + Dr`-wide key a slot (the latent and the
+# placed rope key side by side), and the weights combine the latents
+# themselves, the key's first C columns. So a cell takes a group of
+# heads x Tp steps as the rows of one matmul against a `[block, C + Dr]`
+# tile, and the combine reads the SAME tile: one read of the cache a
+# block. The rope part is padded to whole lane tiles with zero columns
+# (64 -> 128: a 640-wide key for Kanana-2's 576); a contraction of 576
+# costs the MXU what 640 does. The key's two parts reach a cell apart
+# and in float32, as [block, .] tiles of the cache batch-major ([B, M,
+# C]: ONE transposing copy of the latents by XLA), and the cell casts
+# them side by side into one operand. Cast, joined and laid out
+# time-major ([M, B * 640], PR 37's layout) by XLA they cost five
+# passes over the cache a call, 3 ms beside 12 of kernels: a float32
+# cache of [M, B, 1, C] is rows of 128 in the state, and tiles of 8
+# slots x 128 have to be made of it either way.
+#
+# Operands as their producers hold them (PERF.md section 6, PR 41: the
+# kernels ran at 85-94% of the MXU's pace from the first, and the XLA
+# passes that cast, padded and relaid their operands cost as much
+# again). The queries come HEAD-major and in float32, [H, B, Tp, .],
+# the steps padded to the sublane tile by their producer: the latent
+# part is the absorb einsum's own output (a matmul batched over heads
+# puts the heads first), the rope part a small array beside it, and a
+# cell casts and joins the two in VMEM once. The output leaves the
+# same way, which is how the lift (batched over heads again) reads it,
+# and so do its cotangent and the queries' gradients, two outputs. The
+# softmax's own term (delta = sum out dout) is made in the cell.
+#
+# The leg is one of two of a softmax, so the forward returns its output
+# normalised WITHIN the leg and the rows' log-sum-exp, and both take a
+# cotangent. A row that admits no slot (every row of a learner cell's
+# empty caches, and the padded steps) comes out finite: an average of
+# the latents and a log-sum-exp of BIG_NEG, which the join weighs by
+# exp(BIG_NEG - top) = 0; its scores are the mask's constant, so its
+# gradient is zeros whatever its cotangents (the cell drops them: p is
+# 1 there, not 0). The cache is data: the backward kernel makes the
+# queries' gradients alone.
+
+# Heads a cell, keys a forward / backward cell (PERF.md section 6, PR 41).
+_LATENT_HEADS = 16
+_LATENT_FORWARD_KEYS = 1024
+_LATENT_BACKWARD_KEYS = 1024
+_LATENT_VMEM_LIMIT = 96 * 1024 * 1024
+# One bf16 pass whatever the caller traces under: a family that traces
+# at `high` (models/kanana2.py) keeps this leg at the default.
+_ONE_PASS = jax.lax.Precision.DEFAULT
+
+
+def _join_queries(q_ref, q_latent_ref, q_rope_ref):
+    """The cell's queries [heads, Tp, .] in two float32 parts as ONE
+    [heads * Tp, C + Wr] operand of the matmuls' type."""
+    rows, latent = q_ref.shape[0], q_latent_ref.shape[-1]
+    q_ref[:, :latent] = q_latent_ref[...].reshape(rows, latent).astype(
+        q_ref.dtype
+    )
+    q_ref[:, latent:] = q_rope_ref[...].reshape(rows, -1).astype(q_ref.dtype)
+
+
+def _join_keys(k_ref, k_latent_ref, k_rope_ref, block_index, num_keys):
+    """The block's keys [block, .] in two float32 parts as ONE [block,
+    C + Wr] operand of the matmuls' type, the rows past the last slot
+    zeroed (`_whole_keys`)."""
+    latent = k_latent_ref.shape[-1]
+    for part, columns in (
+        (k_latent_ref, slice(0, latent)), (k_rope_ref, slice(latent, None))
+    ):
+        k_ref[:, columns] = _whole_keys(
+            part[...], block_index, num_keys
+        ).astype(k_ref.dtype)
+    return k_ref[...]
+
+
+def _latent_forward_kernel(q_latent_ref, q_rope_ref, k_latent_ref,
+                           k_rope_ref, mask_ref, out_ref, lse_ref, q_ref,
+                           k_ref, top_ref, den_ref, acc_ref, *, scale,
+                           groups, num_keys):
+    block_index = pl.program_id(2)
+    latent = out_ref.shape[-1]
+
+    @pl.when(block_index == 0)
+    def _():
+        _join_queries(q_ref, q_latent_ref, q_rope_ref)
+        top_ref[...] = jnp.full_like(top_ref, -jnp.inf)
+        den_ref[...] = jnp.zeros_like(den_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    k = _join_keys(k_ref, k_latent_ref, k_rope_ref, block_index, num_keys)
+    s = _scores(q_ref[...], k, mask_ref[0], scale, groups, _ONE_PASS)
+    top = jnp.maximum(top_ref[...], s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - top)
+    shrink = jnp.exp(top_ref[...] - top)
+    den_ref[...] = shrink * den_ref[...] + p.sum(axis=-1, keepdims=True)
+    # The latent is both key and value.
+    acc_ref[...] = shrink * acc_ref[...] + _dot(
+        p.astype(k.dtype), k[:, :latent], precision=_ONE_PASS
+    )
+    top_ref[...] = top
+
+    @pl.when(block_index == pl.num_programs(2) - 1)
+    def _():
+        out_ref[...] = (acc_ref[...] / den_ref[...]).reshape(out_ref.shape)
+        lse_ref[...] = jnp.broadcast_to(
+            top_ref[...] + jnp.log(den_ref[...]), (q_ref.shape[0], _LANES)
+        ).reshape(lse_ref.shape)
+
+
+def _latent_backward_kernel(q_latent_ref, q_rope_ref, k_latent_ref,
+                            k_rope_ref, mask_ref, lse_ref, dlse_ref, out_ref,
+                            dout_ref, dq_latent_ref, dq_rope_ref, q_ref,
+                            k_ref, dout_cast_ref, lse_row_ref, shift_ref,
+                            dq_ref, *, scale, groups, num_keys):
+    block_index = pl.program_id(2)
+    rows, latent = dout_cast_ref.shape
+
+    @pl.when(block_index == 0)
+    def _():
+        _join_queries(q_ref, q_latent_ref, q_rope_ref)
+        # ds = p (dP - delta + dlse): `shift` is delta - dlse, a row
+        # each, delta = sum_k p dP from the output. A row that admitted
+        # no slot takes no gradient: its cotangents are dropped here.
+        lse = lse_ref[...].reshape(rows, _LANES)[:, :1]
+        dlse = dlse_ref[...].reshape(rows, _LANES)[:, :1]
+        live = lse > BIG_NEG / 2
+        dout = dout_ref[...].reshape(rows, latent)
+        delta = jnp.sum(
+            out_ref[...].reshape(rows, latent) * dout, axis=-1, keepdims=True
+        )
+        lse_row_ref[...] = lse
+        shift_ref[...] = jnp.where(live, delta - dlse, 0.0)
+        dout_cast_ref[...] = jnp.where(live, dout, 0.0).astype(
+            dout_cast_ref.dtype
+        )
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+
+    q = q_ref[...]
+    k = _join_keys(k_ref, k_latent_ref, k_rope_ref, block_index, num_keys)
+    s = _scores(q, k, mask_ref[0], scale, groups, _ONE_PASS)
+    p = jnp.exp(s - lse_row_ref[...])
+    dp = _dot(dout_cast_ref[...], k[:, :latent], _NT, _ONE_PASS)
+    ds = (p * (dp - shift_ref[...])).astype(q.dtype)
+    dq_ref[...] += _dot(ds, k, precision=_ONE_PASS)
+
+    @pl.when(block_index == pl.num_programs(2) - 1)
+    def _():
+        dq = dq_ref[...] * scale
+        dq_latent_ref[...] = dq[:, :latent].reshape(dq_latent_ref.shape)
+        dq_rope_ref[...] = dq[:, latent:].reshape(dq_rope_ref.shape)
+
+
+def _latent_cells(q_latent, q_rope, k_latent, most_keys):
+    """How a pass divides q_latent [H, B, Tp, C], q_rope [.., Wr] and
+    the keys [B, M, .] into (batch row b, head group g, key block j)
+    cells: (heads a cell, its rows, keys a block, the grid, block
+    specs). The specs, in order: what is shaped like the queries'
+    latent part (a cell's is [heads, Tp, C]), like their rope part, the
+    key's two parts (a cell's are rows j of batch row b), the mask
+    [B, Tp, Kp] and a row statistic [H, B, Tp, 128]."""
+    h, b, tp, latent = q_latent.shape
+    rope, num_keys = q_rope.shape[-1], k_latent.shape[1]
+    # The largest divisor of the heads up to `_LATENT_HEADS`.
+    heads = max(
+        n for n in range(1, min(h, _LATENT_HEADS) + 1) if h % n == 0
+    )
+    block = key_block(num_keys, most_keys)
+
+    def by_rows(d):
+        return pl.BlockSpec(
+            (heads, None, tp, d), lambda b, g, j: (g, b, 0, 0)
+        )
+
+    def by_keys(d):
+        return pl.BlockSpec((None, block, d), lambda b, g, j: (b, j, 0))
+
+    specs = (
+        by_rows(latent), by_rows(rope), by_keys(latent), by_keys(rope),
+        pl.BlockSpec((1, tp, block), lambda b, g, j: (b, 0, j)),
+        by_rows(_LANES),
+    )
+    grid = (b, h // heads, pl.cdiv(num_keys, block))
+    return heads, heads * tp, block, grid, specs
+
+
+# Jitted of their own: a model's layers call them with the same shapes,
+# and JAX then traces and lowers each kernel once a program, not once a
+# layer and pass (15 call sites in the Kanana-2 update: a second of
+# every start's set-up on the chip's host).
+_KERNEL_CALL = functools.partial(
+    jax.jit, static_argnames=("scale", "operand", "interpret")
+)
+
+
+@_KERNEL_CALL
+def _latent_forward_call(q_latent, q_rope, k_latent, k_rope, mask, scale,
+                         operand, interpret):
+    """q_latent [H, B, Tp, C] and q_rope [.., Wr]; k_latent [B, M, C]
+    and k_rope [B, M, Wr]; all f32, cast to `operand` in the cells;
+    mask [B, Tp, Kp] int8 -> (out f32 like q_latent, lse f32 [.., 128])."""
+    heads, rows, block, grid, specs = _latent_cells(
+        q_latent, q_rope, k_latent, _LATENT_FORWARD_KEYS
+    )
+    by_latent, by_rope, keys_latent, keys_rope, mask_spec, by_stat = specs
+    latent, rope = q_latent.shape[-1], q_rope.shape[-1]
+    return pl.pallas_call(
+        functools.partial(
+            _latent_forward_kernel, scale=scale, groups=heads,
+            num_keys=k_latent.shape[1],
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct(q_latent.shape, jnp.float32),
+            jax.ShapeDtypeStruct(
+                q_latent.shape[:-1] + (_LANES,), jnp.float32
+            ),
+        ),
+        grid=grid,
+        in_specs=[by_latent, by_rope, keys_latent, keys_rope, mask_spec],
+        out_specs=(by_latent, by_stat),
+        scratch_shapes=[
+            pltpu.VMEM((rows, latent + rope), operand),
+            pltpu.VMEM((block, latent + rope), operand),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, latent), jnp.float32),
+        ],
+        interpret=interpret,
+        name="fused_latent_leg_forward",
+        **_compiler_params(interpret, _LATENT_VMEM_LIMIT),
+    )(q_latent, q_rope, k_latent, k_rope, mask)
+
+
+@_KERNEL_CALL
+def _latent_backward_call(q_latent, q_rope, k_latent, k_rope, mask, lse,
+                          dlse, out, dout, scale, operand, interpret):
+    """The forward's operands and results, dlse like lse and dout like
+    out -> (dq_latent, dq_rope), f32 like the queries' two parts."""
+    heads, rows, block, grid, specs = _latent_cells(
+        q_latent, q_rope, k_latent, _LATENT_BACKWARD_KEYS
+    )
+    by_latent, by_rope, keys_latent, keys_rope, mask_spec, by_stat = specs
+    latent, rope = q_latent.shape[-1], q_rope.shape[-1]
+    return pl.pallas_call(
+        functools.partial(
+            _latent_backward_kernel, scale=scale, groups=heads,
+            num_keys=k_latent.shape[1],
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct(q_latent.shape, jnp.float32),
+            jax.ShapeDtypeStruct(q_rope.shape, jnp.float32),
+        ),
+        grid=grid,
+        in_specs=[
+            by_latent, by_rope, keys_latent, keys_rope, mask_spec, by_stat,
+            by_stat, by_latent, by_latent,
+        ],
+        out_specs=(by_latent, by_rope),
+        scratch_shapes=[
+            pltpu.VMEM((rows, latent + rope), operand),
+            pltpu.VMEM((block, latent + rope), operand),
+            pltpu.VMEM((rows, latent), operand),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, latent + rope), jnp.float32),
+        ],
+        interpret=interpret,
+        name="fused_latent_leg_backward",
+        **_compiler_params(interpret, _LATENT_VMEM_LIMIT),
+    )(q_latent, q_rope, k_latent, k_rope, mask, lse, dlse, out, dout)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _fused_latent_leg(q_latent, q_rope, k_latent, k_rope, mask, scale,
+                      on_chip):
+    return _fused_latent_leg_fwd(
+        q_latent, q_rope, k_latent, k_rope, mask, scale, on_chip
+    )[0]
+
+
+def _latent_operand(on_chip):
+    return (jnp.bfloat16 if on_chip else jnp.float32), not on_chip
+
+
+def _fused_latent_leg_fwd(q_latent, q_rope, k_latent, k_rope, mask, scale,
+                          on_chip):
+    tp, num_keys = q_latent.shape[2], k_latent.shape[1]
+    kp = _padded_keys(
+        num_keys, _LATENT_FORWARD_KEYS, _LATENT_BACKWARD_KEYS
+    )
+    operands = tuple(
+        x.astype(jnp.float32) for x in (q_latent, q_rope, k_latent, k_rope)
+    ) + (
+        jnp.pad(
+            mask.astype(jnp.int8),
+            ((0, 0), (0, tp - mask.shape[1]), (0, kp - num_keys)),
+        ),
+    )
+    out, lse = _latent_forward_call(
+        *operands, scale, *_latent_operand(on_chip)
+    )
+    # Empty carriers of what the gradients are typed like.
+    like = (jnp.zeros((0,), q_latent.dtype), jnp.zeros((0,), q_rope.dtype))
+    return (out, lse[..., 0]), (operands, out, lse, like)
+
+
+def _fused_latent_leg_bwd(scale, on_chip, residuals, cotangents):
+    operands, out, lse, like = residuals
+    dout, dlse = cotangents
+    dq_latent, dq_rope = _latent_backward_call(
+        *operands, lse,
+        jnp.broadcast_to(dlse.astype(jnp.float32)[..., None], lse.shape),
+        out, dout.astype(jnp.float32), scale, *_latent_operand(on_chip),
+    )
+    return (
+        dq_latent.astype(like[0].dtype), dq_rope.astype(like[1].dtype),
+        None, None, None,
+    )
+
+
+_fused_latent_leg.defvjp(_fused_latent_leg_fwd, _fused_latent_leg_bwd)
+
+
+def fused_latent_leg(q_latent, q_rope, latent, rope, mask, scale):
+    """The cache leg of latent attention with its scores in VMEM.
+
+    The absorbed queries HEAD-major, their steps padded to `padded_
+    steps(T)` (zeros) by whoever makes them: q_latent [H, B, Tp, C], the
+    part that scores against the latents, and q_rope [H, B, Tp, Dr];
+    latent [M, B, C] and rope [M, B, Dr], a cache AS THE STATE HOLDS IT
+    (time-major), the rope keys placed; mask [B, T, M] bool; scale, a
+    Python float, on the scores. Returns (out [H, B, Tp, C] f32, the
+    softmax over the M slots alone applied to the latents, and lse
+    [H, B, Tp] f32, the log-sum-exp of the rows' masked, scaled
+    scores): what a second leg needs to join this one in one softmax.
+    Differentiable in the queries, through both results; the cache is
+    data and takes no gradient. A row that admits no slot (the padded
+    steps among them) gets a finite output, BIG_NEG for a log-sum-exp
+    and zeros for a gradient.
+
+    The cache reaches the kernels in float32 and batch-major, as
+    `[block, C]` and `[block, Wr]` tiles of `[B, M, C]` and `[B, M, Wr]`
+    (Wr: Dr padded with zero columns to whole lane tiles): ONE
+    transposing copy of the latents by XLA, no cast, join or padded
+    copy of them; a cell casts a block's two parts to the matmuls' type
+    (bfloat16 on the chip) side by side in VMEM.
+    """
+    pad = ((0, 0),) * (rope.ndim - 1) + ((0, -rope.shape[-1] % _LANES),)
+    return _fused_latent_leg(
+        q_latent, jnp.pad(q_rope, ((0, 0),) + pad),
+        jax.lax.stop_gradient(latent.transpose(1, 0, 2)),
+        jax.lax.stop_gradient(jnp.pad(rope, pad).transpose(1, 0, 2)),
+        mask, float(scale), jax.default_backend() == "tpu",
     )

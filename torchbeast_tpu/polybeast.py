@@ -2176,7 +2176,8 @@ def train(flags):
                     )),
                     ("attention", (
                         "two_leg_applications", "fused_applications",
-                        "latent_applications", "latent_cache_bytes_per_row",
+                        "latent_applications", "latent_fused_applications",
+                        "latent_cache_bytes_per_row",
                     )),
                 ):
                     for name in names:
