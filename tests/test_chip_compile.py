@@ -547,6 +547,47 @@ def test_nemotron3_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     assert {s for s in shapes if s == (8 * tokens, 2688)}
 
 
+def test_nemotron3_dispatch_moves_the_windows_rows_alone_on_v5e(
+    one_chip, monkeypatch
+):
+    """`jax.grad` through `dropless_experts` at the Nemotron-3 cell's
+    shapes (4,096 tokens, 22 of 512 a token, 8 held, relu^2 experts of
+    2,688 in a latent of 1,024, traced under `high`), for a described
+    v5e: with fewer experts held than a token chooses, rows are moved
+    tokens x 8 at a time. No f32 array of the tokens x 22 sorted rows
+    (90,112) nor of those and the window's (122,880) is in the
+    program, and its temporaries are no more than the parent's, which
+    built both (PR 43: 2,121,320,960 bytes there, 1,811,172,864
+    here)."""
+    from torchbeast_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tokens, top_k, experts, held, latent, width = 4096, 22, 512, 8, 1024, 2688
+
+    def loss(x, gate, w_up, w_down, idx):
+        with jax.default_matmul_precision("high"):
+            y, _ = moe.dropless_experts(
+                x, idx, gate, None, w_up, w_down, first_of=(0, experts),
+                activation="relu2",
+            )
+        return jnp.sum(jnp.sin(y))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        _struct(one_chip, (tokens, latent)),
+        _struct(one_chip, (tokens, top_k)),
+        _struct(one_chip, (held, latent, width)),
+        _struct(one_chip, (held, width, latent)),
+        _struct(one_chip, (tokens, top_k), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert f"f32[{tokens * top_k},{latent}]" not in text
+    assert f"f32[{tokens * (top_k + held)},{latent}]" not in text
+    assert f"f32[{tokens * held},{latent}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2_121_320_960
+    # Two forward kernels and four backward, three passes each.
+    assert text.count("tpu_custom_call") >= 18
+
+
 def test_flagship_act_step_compiles_for_v5e(one_chip, monkeypatch):
     """The acting program at the largest inference bucket."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")

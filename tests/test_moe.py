@@ -5,6 +5,8 @@ must equal a single dense FFN (renormalized gates sum to 1); the
 expert-sharded run must match the unsharded run bitwise-close; capacity
 overflow must drop, not corrupt."""
 
+import re
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -471,19 +473,39 @@ def test_ungated_relu2_experts_in_a_latent_by_hand():
         layer.clone(activation="gelu").init(jax.random.PRNGKey(0), x)
 
 
-@pytest.mark.parametrize("first", [0, 3, 6], ids=["first", "middle", "last"])
-def test_fewer_experts_held_than_chosen_see_a_window_of_the_rows(first):
+def _routed_to(kernel, x, first, column, sign):
+    """The router's kernel with held expert `first + column` moved to
+    every token's top (sign +1) or bottom (-1): x is positive there."""
+    assert float(jnp.min(x)) > 0
+    return kernel.at[:, first + column].set(sign * 3.0)
+
+
+@pytest.mark.parametrize(
+    "first, routing",
+    [(0, None), (3, None), (6, None), (3, "both"), (6, "never")],
+    ids=["first", "middle", "last", "window-full", "one-never-chosen"],
+)
+def test_fewer_experts_held_than_chosen_see_a_window_of_the_rows(
+    first, routing
+):
     """Two of eight experts held under five a token: a token lands on
     each held expert at most once, so the grouped matmuls see a window
     of tokens x 2 of the tokens x 5 sorted rows (PR 42: 8 of 512 held
     under 22 a token would else push 90,112 rows of which 1,408 are
     the chip's through every kernel). Same values and gradients as the
     experts by hand, wherever the window lies, the sorted rows' end
-    among it; and the rows the kernels are handed are the window's."""
+    among it; with every token on BOTH held experts (the window full)
+    and with a held expert that no token chooses (no row in its column
+    of the slots). The rows the kernels are handed are the window's,
+    and (PR 43) so is every row that is moved: no array of tokens x 5
+    rows, nor of those and the window's, forward or backward, and no
+    scatter-add of rows."""
     from torchbeast_tpu.models.moe import DroplessMoE
 
     tokens, experts, top_k = 24, 8, 5
     x = jax.random.normal(jax.random.PRNGKey(first), (tokens, D))
+    if routing:
+        x = jnp.abs(x) + 0.5
     layer = DroplessMoE(
         d_ff=FF, num_experts=experts, top_k=top_k, aux_loss_weight=0.0,
         renormalise=True, scoring="sigmoid", routed_scaling=5.0,
@@ -491,11 +513,29 @@ def test_fewer_experts_held_than_chosen_see_a_window_of_the_rows(first):
         held=(first, 2),
     )
     params = layer.init(jax.random.PRNGKey(1), x)
+    if routing:
+        kernel = params["params"]["router"]["kernel"]
+        sign = -1 if routing == "never" else 1
+        kernel = _routed_to(kernel, x, first, 1, sign)
+        if routing == "both":
+            kernel = _routed_to(kernel, x, first, 0, 1)
+        params = {"params": dict(
+            params["params"], router={"kernel": kernel}
+        )}
     y, sown = layer.apply(params, x, mutable=["moe_stats"])
     want = _latent_by_hand(x, params["params"], top_k, 5.0, held=(first, 2))
     np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-6)
     assert float(sown["moe_stats"]["assignments"]) == tokens * top_k
-    assert 0 < float(sown["moe_stats"]["held_assignments"]) <= tokens * 2
+    held_rows = float(sown["moe_stats"]["held_assignments"])
+    assert 0 < held_rows <= tokens * 2
+    _, chosen = jax.lax.top_k(
+        jax.nn.sigmoid(x @ params["params"]["router"]["kernel"]), top_k
+    )
+    if routing == "both":
+        assert held_rows == tokens * 2
+    if routing == "never":
+        assert not np.any(np.asarray(chosen) == first + 1)
+        assert held_rows == np.sum(np.asarray(chosen) == first)
 
     def by_rows(params, x):
         return jnp.sum(jnp.sin(layer.apply(params, x)))
@@ -532,11 +572,135 @@ def test_fewer_experts_held_than_chosen_see_a_window_of_the_rows(first):
     wide = jax.random.normal(jax.random.PRNGKey(2), (64, D))
     text = str(jax.make_jaxpr(lambda x: layer.apply(params, x))(wide))
     assert "f32[128,5]" in text and "f32[512,5]" not in text
+    # Nor is any array of the latent's width 64 x 5 = 320 rows long, or
+    # 320 + the window's 128, forward or backward; and no gradient of a
+    # gather is left to JAX, whose scatter-add the chip serialises.
+    backward = str(jax.make_jaxpr(jax.grad(by_rows, argnums=(0, 1)))(
+        params, wide
+    ))
+    for program in (text, backward):
+        assert "f32[320,5]" not in program and "f32[448,5]" not in program
+        assert not re.search(r"f32\[\d+,5\] = scatter-add", program)
+    assert re.search(r"f32\[\d+,8\] = scatter-add", backward)  # the router's
     whole = layer.clone(held=None)
     text = str(jax.make_jaxpr(lambda x: whole.apply(
         whole.init(jax.random.PRNGKey(1), wide), x
     ))(wide))
     assert "f32[512,5]" in text and "f32[128,5]" not in text
+    assert "f32[320,5]" in text
+
+
+def _window_by_hand(idx, first, held):
+    """The window's geometry in numpy: (order, live, slot)."""
+    tokens, K = idx.shape
+    flat = np.asarray(idx).reshape(-1)
+    order = np.argsort(flat, kind="stable")
+    start = int(np.sum(flat < first))
+    live = int(np.sum((flat >= first) & (flat < first + held)))
+    window = tokens * held
+    order_w = np.concatenate([order, np.zeros(window, order.dtype)])[
+        start : start + window
+    ]
+    slot = np.full((tokens, held), window)
+    for row in range(live):
+        slot[order_w[row] // K, flat[order_w[row]] - first] = row
+    return order_w, live, slot
+
+
+@pytest.mark.parametrize(
+    "tokens, K, E, held, first",
+    [(16, 3, 8, 2, 0), (16, 3, 8, 2, 3), (16, 3, 8, 2, 6),
+     (12, 6, 12, 2, 5), (12, 6, 12, 1, 11), (8, 9, 12, 4, 8),
+     (20, 4, 6, 3, 3)],
+    ids=["start", "middle", "past-the-end", "K-far-over-held",
+         "one-held-last", "top-9-of-12-past-the-end", "half-the-experts"],
+)
+def test_window_dispatch_and_combine_against_plain_gathers(
+    tokens, K, E, held, first
+):
+    """`_window_dispatch` and `_window_combine` on their own, against
+    the obvious formulation left to JAX's autodiff: `x[order // K]`,
+    whose gradient is the scatter-add the helper avoids, and a plain
+    weighted sum of the kernels' rows; values, and the gradients with
+    respect to x, the kernels' output and the gates."""
+    from torchbeast_tpu.models import moe
+
+    width = 7
+    keys = jax.random.split(jax.random.PRNGKey(tokens * K + first), 5)
+    x = jax.random.normal(keys[0], (tokens, width))
+    gate, idx = jax.lax.top_k(jax.random.uniform(keys[1], (tokens, E)), K)
+    window = tokens * held
+    out = jax.random.normal(keys[2], (window, width))
+    weights = jax.random.normal(keys[3], (window, width))
+    tangent = jax.random.normal(keys[4], (tokens, width))
+    order_w, live, slot = _window_by_hand(idx, first, held)
+    assert 0 < live <= window
+    if first + held == E:
+        assert int(np.sum(np.asarray(idx) < first)) + window > tokens * K
+
+    def indices(idx):
+        flat = idx.reshape(-1)
+        order = jnp.argsort(flat, stable=True)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(tokens * K, dtype=order.dtype)
+        )
+        sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+        return order, inverse, sizes
+
+    def dispatch(x):
+        return moe._window_dispatch(x, idx, *indices(idx), first, held)
+
+    rows, groups, at = dispatch(x)
+    np.testing.assert_array_equal(at.order[:live], order_w[:live])
+    np.testing.assert_array_equal(at.token, order_w // K)
+    np.testing.assert_array_equal(at.slot, slot)
+    assert int(at.live) == live
+    assert int(jnp.sum(groups)) == window
+    np.testing.assert_array_equal(
+        groups[:held], np.bincount(np.asarray(idx).reshape(-1), minlength=E)[
+            first : first + held
+        ],
+    )
+    np.testing.assert_array_equal(rows, np.asarray(x)[order_w // K])
+    # The kernels visit the held experts' rows alone: so does the loss.
+    weights = weights * (jnp.arange(window) < live)[:, None]
+    got = jax.grad(lambda x: jnp.sum(dispatch(x)[0] * weights))(x)
+    want = jax.grad(lambda x: jnp.sum(x[order_w // K] * weights))(x)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+    hit = slot < window
+    chose = np.asarray(idx)[:, :, None] == first + np.arange(held)
+
+    def plain(out, gate):
+        gate_held = jnp.sum(jnp.where(chose, gate[:, :, None], 0.0), axis=1)
+        picked = jnp.where(
+            hit[:, :, None], out[np.minimum(slot, window - 1)], 0.0
+        )
+        return jnp.sum(picked * gate_held[:, :, None], axis=1)
+
+    def combine(out, gate):
+        return moe._window_combine(out, gate, at)
+
+    np.testing.assert_allclose(
+        combine(out, gate), plain(out, gate), rtol=1e-6, atol=1e-6
+    )
+    for argument in (0, 1):
+        got, want = (
+            jax.grad(
+                lambda *a, f=f: jnp.sum(f(*a) * tangent), argnums=argument
+            )(out, gate)
+            for f in (combine, plain)
+        )
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    # Backward too, rows move by gathers alone.
+    program = str(jax.make_jaxpr(jax.grad(
+        lambda x, out, gate: jnp.sum(
+            dispatch(x)[0] * weights
+        ) + jnp.sum(combine(out, gate) * tangent),
+        argnums=(0, 1, 2),
+    ))(x, out, gate))
+    assert "scatter-add" in program  # the bincount's, of integers
+    assert not re.search(r"f32\[[\d,]*\] = scatter-add", program)
 
 
 # --- the grouped matmul's passes, by the precision it is traced under -----

@@ -41,7 +41,7 @@ other experts are never visited (the kernels' `group_offset`).
 
 import functools
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -184,6 +184,116 @@ def _permute_bwd(residuals, grad):
 
 
 _permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _rows_at(rows, slot):
+    """rows[slot] for slot [tokens, held], zeros where a slot is past
+    the rows' end (the sign of a token that chose no such expert)."""
+    return rows.at[slot].get(mode="fill", fill_value=0)
+
+
+@jax.custom_vjp
+def _window_rows(x, token, slot):
+    """x[token]: the window's rows [window, d], window row i the row of
+    token[i]. With slot[t, c] the window row where token t meets held
+    expert c (or the window's size: nowhere), the gradient is a gather
+    too, `sum_c grad[slot[t, c]]`, and not the scatter-add of a
+    gather's. It counts the held experts' rows alone: the kernels
+    visit no other row of the window, whose gradients are zeros."""
+    del slot
+    return x[token]
+
+
+def _window_rows_fwd(x, token, slot):
+    return x[token], slot
+
+
+def _window_rows_bwd(slot, grad):
+    return _rows_at(grad, slot).sum(axis=1), None, None
+
+
+_window_rows.defvjp(_window_rows_fwd, _window_rows_bwd)
+
+
+@jax.custom_vjp
+def _window_sum(out, gate_held, token, slot, gate_rows):
+    """sum_c gate_held[t, c] * out[slot[t, c]] -> [tokens, d]: a token's
+    sum over the held experts it chose, out [window, d] the kernels'
+    rows, `token` and `slot` as `_window_rows` takes them. `gate_rows`
+    [window] is `gate_held` again, laid by window row with zeros at the
+    rows that are no held expert's; the backward pass alone reads it
+    (`grad[token] * gate_rows` is the gradient of `out`, a gather
+    again), and the gates' gradient goes to `gate_held`."""
+    del token, gate_rows
+    return jnp.einsum("tcd,tc->td", _rows_at(out, slot), gate_held)
+
+
+def _window_sum_fwd(out, gate_held, token, slot, gate_rows):
+    y = _window_sum(out, gate_held, token, slot, gate_rows)
+    return y, (out, token, slot, gate_rows)
+
+
+def _window_sum_bwd(residuals, grad):
+    out, token, slot, gate_rows = residuals
+    grad_out = grad[token] * gate_rows[:, None]
+    grad_gate = jnp.einsum("tcd,td->tc", _rows_at(out, slot), grad)
+    return grad_out.astype(out.dtype), grad_gate, None, None, None
+
+
+_window_sum.defvjp(_window_sum_fwd, _window_sum_bwd)
+
+
+class _Window(NamedTuple):
+    """Which assignments the window of tokens x held sorted rows holds."""
+
+    order: jax.Array  # [window] the (token, rank) assignment of row i
+    token: jax.Array  # [window] its token: order // K
+    chose: jax.Array  # [tokens, K, held] token t's rank k is held expert c
+    slot: jax.Array  # [tokens, held] the row where t meets c, else `window`
+    live: jax.Array  # [] how many rows, the first, are held experts' rows
+
+
+def _window_dispatch(x, idx, order, inverse, sizes, first, held):
+    """The window of the sorted rows that starts at expert `first`'s,
+    tokens x held long: (rows [window, d], the kernels' group sizes
+    [held + 1], the `_Window`). One gather of the window's rows from x;
+    `order`, `inverse`, `sizes` as `dropless_experts` has them."""
+    tokens, K = idx.shape
+    window = tokens * held
+    start = jnp.sum(sizes[:first])
+    mine = sizes[first : first + held]
+    live = jnp.sum(mine)
+    # The INDICES are cut, not the rows; behind the sorted rows' end
+    # assignment 0 again, so that every entry names a token.
+    order = jax.lax.dynamic_slice_in_dim(
+        jnp.pad(order, (0, window)), start, window
+    )
+    chose = idx[:, :, None] == first + jnp.arange(held)
+    slot = jnp.where(
+        chose.any(axis=1),
+        jnp.sum(
+            jnp.where(chose, inverse.reshape(tokens, K, 1) - start, 0), axis=1
+        ),
+        window,
+    )
+    at = _Window(order, order // K, chose, slot, live)
+    # The held experts' groups, and one more of whatever else the
+    # window took in, which no expert visits.
+    groups = jnp.append(mine, window - live)
+    return _window_rows(x, at.token, at.slot), groups, at
+
+
+def _window_combine(out, gate, at):
+    """sum over the held experts c that token t chose of its gate there
+    times the kernels' row for it: out [window, d], gate [tokens, K] ->
+    [tokens, d]. One gather of tokens x held rows, a sum over held."""
+    # Linear in the gates: the router's gradient is what it was.
+    gate_held = jnp.sum(jnp.where(at.chose, gate[:, :, None], 0.0), axis=1)
+    gate_rows = jnp.where(
+        jnp.arange(at.order.shape[0]) < at.live,
+        gate.reshape(-1)[at.order], 0.0,
+    )
+    return _window_sum(out, gate_held, at.token, at.slot, gate_rows)
 
 
 # The grouped matmul's tiles (rows of a group, contracted, output
@@ -333,6 +443,13 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None,
     `first_of` = (first, E) when the weights are those of experts first
     .. first + C - 1 of E ([C, d, f], [C, f, d]): the sum then runs over
     the assignments to those alone, the sizes are still all E experts'.
+    With C >= K (or all experts held) every one of the t x K sorted
+    rows is moved: x repeated K times and permuted, the kernels' rows
+    permuted back and summed K a token. With C < K the held experts'
+    rows lie within t x C of them, and those alone are moved: t x C
+    rows gathered from x, t x C gathered from the kernels' output and
+    summed C a token (`_window_dispatch`, `_window_combine`), backward
+    by gathers of as many; no array of t x K rows is built.
     """
     tokens, K = idx.shape
     first, E = first_of or (None, w_up.shape[0])
@@ -340,8 +457,10 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None,
     # Fewer experts held than a token chooses (models/nemotron3.py: 8
     # of 512 under 22 a token): a token lands on each expert at most
     # once, so at most tokens x held of the tokens x K sorted rows are
-    # these experts', one contiguous run. The experts then see that
-    # window of the rows alone, not all of them.
+    # these experts', one contiguous run. That window is cut out of the
+    # sorted INDICES, and rows are gathered by them alone: tokens x
+    # held from x for the kernels, as many from the kernels' output for
+    # the sum. The shapes decide, once, at trace time.
     held = w_up.shape[0]
     window = tokens * held if first is not None and held < K else None
     with jax.named_scope("moe_dispatch"):
@@ -352,20 +471,14 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None,
             jnp.arange(tokens * K, dtype=order.dtype), unique_indices=True
         )
         sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
-        rows = _permute(jnp.repeat(x, K, axis=0), order, inverse)
-        groups, first_group = sizes, first
         if window:
-            start = jnp.sum(sizes[:first])
-            mine = sizes[first : first + held]
-            # Zero rows behind the last, so that the window never
-            # reaches past the end and is moved.
-            rows = jax.lax.dynamic_slice_in_dim(
-                jnp.pad(rows, ((0, window), (0, 0))), start, window
+            rows, groups, at = _window_dispatch(
+                x, idx, order, inverse, sizes, first, held
             )
-            # The held experts' groups, and one more of whatever else
-            # the window took in, which no expert visits.
-            groups = jnp.append(mine, window - jnp.sum(mine))
             first_group = 0
+        else:
+            rows = _permute(jnp.repeat(x, K, axis=0), order, inverse)
+            groups, first_group = sizes, first
     with jax.named_scope("moe_experts"):
         if w_gate is None:
             hidden = act(grouped_matmul(rows, w_up, groups, first_group))
@@ -376,14 +489,15 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None,
         out = grouped_matmul(hidden, w_down, groups, first_group)
     with jax.named_scope("moe_combine"):
         if window:
-            out = jax.lax.dynamic_update_slice_in_dim(
-                jnp.zeros((tokens * K + window, out.shape[1]), out.dtype),
-                out, start, 0,
-            )[: tokens * K]
-        out = _permute(out, inverse, order).reshape(tokens, K, -1)
-        y = jnp.einsum(
-            "tkd,tk->td", out.astype(jnp.float32), gate.astype(jnp.float32)
-        )
+            y = _window_combine(
+                out.astype(jnp.float32), gate.astype(jnp.float32), at
+            )
+        else:
+            out = _permute(out, inverse, order).reshape(tokens, K, -1)
+            y = jnp.einsum(
+                "tkd,tk->td", out.astype(jnp.float32),
+                gate.astype(jnp.float32),
+            )
     return y, sizes
 
 
