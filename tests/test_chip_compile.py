@@ -476,8 +476,9 @@ def test_nemotron3_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     weights (ISSUE 42's rule: under 15.0 GiB with it); the attention
     layer's scores over 4,351 keys live in `fused_attend`'s VMEM (no
     f32 array over the keys is in the program); the experts' kernels
-    see the window of the sorted rows that 8 held experts can draw
-    (tokens x 8), not all tokens x 22."""
+    see one rung at a time of the window of the sorted rows that 8
+    held experts can draw (PR 44: 2,816 rows, twice an even load's),
+    not all tokens x 22 (PR 42) nor the whole window's tokens x 8."""
     import json
     import re
 
@@ -541,10 +542,17 @@ def test_nemotron3_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     assert text.count("fused_attend_forward") >= 2  # and rematerialised
     assert text.count("fused_attend_backward") >= 1
     # The sorted rows of all the assignments are never an operand of a
-    # kernel: 22 a token; the window is 8 a token.
+    # kernel: 22 a token; nor is the window's 8 a token, which is swept
+    # a rung at a time.
+    from torchbeast_tpu.models import moe
+
     tokens = (steps + 1) * rows
-    assert not {s for s in shapes if s[0] == 22 * tokens and s[-1] == 2688}
-    assert {s for s in shapes if s == (8 * tokens, 2688)}
+    rung, window = moe.window_rungs(tokens, 22, 8, 512)
+    assert (rung, window) == (2816, 8 * tokens)
+    assert not {
+        s for s in shapes if s[0] in (22 * tokens, window) and s[-1] == 2688
+    }
+    assert {s for s in shapes if s == (rung, 2688)}
 
 
 def test_nemotron3_dispatch_moves_the_windows_rows_alone_on_v5e(
@@ -556,9 +564,13 @@ def test_nemotron3_dispatch_moves_the_windows_rows_alone_on_v5e(
     v5e: with fewer experts held than a token chooses, rows are moved
     tokens x 8 at a time. No f32 array of the tokens x 22 sorted rows
     (90,112) nor of those and the window's (122,880) is in the
-    program, and its temporaries are no more than the parent's, which
-    built both (PR 43: 2,121,320,960 bytes there, 1,811,172,864
-    here)."""
+    program (PR 43: 2,121,320,960 bytes of temporaries before it,
+    1,811,172,864 after). And (PR 44) the kernels sweep that window a
+    rung of 2,816 rows at a time, in a loop on the device of as many
+    turns as the step's rows fill, forward and backward: no array of
+    the experts' width is as long as the whole window, zeros or
+    otherwise, each loop holds one copy of the kernels, and the
+    temporaries are 507,526,144 bytes."""
     from torchbeast_tpu.models import moe
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -582,10 +594,17 @@ def test_nemotron3_dispatch_moves_the_windows_rows_alone_on_v5e(
     text = compiled.as_text()
     assert f"f32[{tokens * top_k},{latent}]" not in text
     assert f"f32[{tokens * (top_k + held)},{latent}]" not in text
-    assert f"f32[{tokens * held},{latent}]" in text
-    assert compiled.memory_analysis().temp_size_in_bytes <= 2_121_320_960
-    # Two forward kernels and four backward, three passes each.
-    assert text.count("tpu_custom_call") >= 18
+    assert f"f32[{tokens * held},{latent}]" in text  # the gathers by slot
+    rung, window = moe.window_rungs(tokens, top_k, held, experts)
+    assert (rung, window) == (2816, tokens * held)
+    assert f"f32[{rung},{width}]" in text and f"f32[{rung},{latent}]" in text
+    assert f"[{window},{width}]" not in text
+    assert "/jvp()/while/body/moe_experts" in text  # the forward loop
+    assert "/transpose(jvp())/while/body/jvp(moe_experts)" in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 507_526_144
+    # Two forward kernels in the forward loop; those and four backward
+    # in the backward loop; three passes each, and no second copy.
+    assert text.count("tpu_custom_call") == 24
 
 
 def test_flagship_act_step_compiles_for_v5e(one_chip, monkeypatch):

@@ -176,6 +176,9 @@ def test_family_agrees_with_the_reference(share):
     else:
         assert 0 < float(stats["moe_held_assignments"]) < 2 * T * B * 4
         assert float(stats["moe_held_load_max_over_mean"]) >= 1.0
+    # As many held as chosen, or more: no window of the sorted rows.
+    assert "moe_window_rows" not in stats
+    assert "moe_window_short_applications" not in stats
 
 
 @pytest.mark.parametrize("unrolls", [0, 1, 2], ids=["empty", "part", "full"])

@@ -5,6 +5,7 @@ must equal a single dense FFN (renormalized gates sum to 1); the
 expert-sharded run must match the unsharded run bitwise-close; capacity
 overflow must drop, not corrupt."""
 
+import functools
 import re
 
 import flax.linen as nn
@@ -701,6 +702,188 @@ def test_window_dispatch_and_combine_against_plain_gathers(
     ))(x, out, gate))
     assert "scatter-add" in program  # the bincount's, of integers
     assert not re.search(r"f32\[[\d,]*\] = scatter-add", program)
+
+
+# --- a window as long as its live rows: the rungs ---------------------------
+
+RUNG_TOKENS, RUNG_K, RUNG_E, RUNG_HELD, RUNG_FIRST = 512, 5, 64, 2, 7
+
+
+def _routed_with(live, seed):
+    """idx [tokens, K], distinct experts a token, `live` of the
+    assignments on the RUNG_HELD experts from RUNG_FIRST on."""
+    held = RUNG_HELD
+    rng = np.random.default_rng(seed)
+    others = [
+        e for e in range(RUNG_E) if not RUNG_FIRST <= e < RUNG_FIRST + held
+    ]
+    idx = np.stack([
+        rng.choice(others, RUNG_K, replace=False) for _ in range(RUNG_TOKENS)
+    ])
+    cells = [(t, c) for t in range(RUNG_TOKENS) for c in range(held)]
+    rng.shuffle(cells)
+    for t, c in cells[:live]:
+        idx[t, c] = RUNG_FIRST + c
+    return jnp.asarray(idx, jnp.int32)
+
+
+@functools.lru_cache(maxsize=None)
+def _rung_programs(gated):
+    """(ours, plain): jitted value and gradients of the held experts'
+    part of the sum by `dropless_experts` and written out, an expert at
+    a time over ALL the tokens; traced once for every case."""
+    from torchbeast_tpu.models import moe
+
+    def ours(x, gate, w_gate, w_up, w_down, idx):
+        return moe.dropless_experts(
+            x, idx, gate, w_gate if gated else None, w_up, w_down,
+            first_of=(RUNG_FIRST, RUNG_E), activation="relu2",
+        )[0]
+
+    def plain(x, gate, w_gate, w_up, w_down, idx):
+        y = 0.0
+        for c in range(RUNG_HELD):
+            mine = jnp.sum(
+                jnp.where(idx == RUNG_FIRST + c, gate, 0.0), axis=1,
+                keepdims=True,
+            )
+            hidden = moe.relu2(x @ (w_gate if gated else w_up)[c])
+            if gated:
+                hidden = hidden * (x @ w_up[c])
+            y = y + mine * (hidden @ w_down[c])
+        return y
+
+    def program(f):
+        return jax.jit(jax.value_and_grad(
+            lambda tangent, idx, *a: jnp.sum(f(*a, idx) * tangent),
+            argnums=(2, 3, 4, 5, 6),
+        ))
+
+    return program(ours), program(plain)
+
+
+@pytest.mark.parametrize(
+    "live, gated",
+    [(100, False), (256, True), (257, False), (1024, False), (0, False)],
+    ids=["under-a-rung", "exactly-a-rung-gated", "one-row-over-a-rung",
+         "collapsed-onto-the-held-experts", "no-row"],
+)
+def test_window_is_swept_as_far_as_its_live_rows_reach(live, gated):
+    """Two of 64 experts held under five a token, 512 tokens: the
+    window of tokens x 2 = 1,024 sorted rows is swept 256 rows at a
+    time (twice an even load's 80, in row tiles), as many rungs as the
+    step's own sizes fill, counted on the device; every assignment to a
+    held expert is computed however many that is. Values and the
+    gradients of x, the gates and every weight against the sum over the
+    held experts written out, with the rows under one rung, exactly
+    filling it (SwiGLU experts there), one over it (two rungs), all
+    1,024 (every token on both held experts: four) and none."""
+    from torchbeast_tpu.models import moe
+
+    rungs = moe.window_rungs(RUNG_TOKENS, RUNG_K, RUNG_HELD, RUNG_E)
+    assert rungs == (256, RUNG_TOKENS * RUNG_HELD)
+    d, f = 8, 16
+    keys = jax.random.split(jax.random.PRNGKey(live), 6)
+    x = jax.random.normal(keys[0], (RUNG_TOKENS, d))
+    gate = jax.random.uniform(keys[1], (RUNG_TOKENS, RUNG_K))
+    w_gate, w_up = (
+        jax.random.normal(k, (RUNG_HELD, d, f)) / 3 for k in keys[2:4]
+    )
+    w_down = jax.random.normal(keys[4], (RUNG_HELD, f, d)) / 4
+    tangent = jax.random.normal(keys[5], (RUNG_TOKENS, d))
+    idx = _routed_with(live, seed=live)
+    mine = jnp.bincount(idx.reshape(-1), length=RUNG_E)[
+        RUNG_FIRST : RUNG_FIRST + RUNG_HELD
+    ]
+    assert int(jnp.sum(mine)) == live
+    assert int(moe.window_sweeps(rungs, mine)) == -(-live // 256)
+    ours, plain = _rung_programs(gated)
+    (got, got_grads), (want, want_grads) = (
+        program(tangent, idx, x, gate, w_gate, w_up, w_down)
+        for program in (ours, plain)
+    )
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for name, a, b in zip(
+        ("x", "gate", "w_gate", "w_up", "w_down"), got_grads, want_grads
+    ):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5, err_msg=name)
+    if live:
+        assert np.any(got_grads[3]) and np.any(got_grads[0])
+    else:
+        assert not np.any(got) and not np.any(got_grads[4])
+
+
+@pytest.mark.parametrize(
+    "tokens, top_k, held, experts, want",
+    [(4096, 22, 8, 512, (2816, 32768)),  # the Nemotron-3 cell's layer
+     (1024, 22, 8, 512, (768, 8192)),  # and its check's four rows
+     (24, 5, 2, 8, (48, 48)),  # no room under half: one rung, as it was
+     (512, 5, 2, 8, (1024, 1024)),  # an even load fills more than half
+     (4096, 8, 16, 64, ()), (4096, 6, 16, 128, ()), (64, 5, 5, 8, ())],
+    ids=["nemotron3-cell", "nemotron3-check", "toy", "dense-routing",
+         "mellum2", "kanana2", "held-equals-chosen"],
+)
+def test_window_rungs_follow_from_shapes_alone(
+    tokens, top_k, held, experts, want
+):
+    from torchbeast_tpu.models import moe
+
+    assert moe.window_rungs(tokens, top_k, held, experts) == want
+    if want:
+        rung, window = want
+
+        def sweeps(*mine):
+            return int(moe.window_sweeps(want, jnp.asarray(mine)))
+
+        assert sweeps(rung, 0) == 1 and sweeps(*[tokens] * held) == (
+            -(-window // rung)
+        )
+        if rung < window:
+            assert sweeps(0, 0) == 0 and sweeps(rung, 1) == 2
+
+
+def test_as_many_held_as_chosen_trace_the_program_they_traced():
+    """Five of eight experts held under five a token (`held >= K`:
+    Mellum2's 16 under 8, Kanana-2's 16 under 6, OLMoE's all): no
+    window, no rung, no loop; the t x K sorted rows permuted as before
+    PR 44, whose jaxpr of value and gradients this is letter for letter
+    (3,217 lines, 29 arrays of the 320 sorted rows at the experts' two
+    widths; the parent commit's text hashed the same)."""
+    from torchbeast_tpu.models import moe
+
+    tokens, top_k, experts, held, d, f = 64, 5, 8, 5, 8, 16
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    x = jax.random.normal(keys[0], (tokens, d))
+    gate, idx = jax.lax.top_k(
+        jax.random.uniform(keys[1], (tokens, experts)), top_k
+    )
+    w_up = jax.random.normal(keys[3], (held, d, f))
+    w_down = jax.random.normal(keys[4], (held, f, d))
+
+    def loss(x, gate, w_up, w_down, first_of):
+        y, _ = moe.dropless_experts(
+            x, idx, gate, None, w_up, w_down, first_of=first_of,
+            activation="relu2",
+        )
+        return jnp.sum(jnp.sin(y))
+
+    def program(first_of, w_up, w_down):
+        return str(jax.make_jaxpr(jax.value_and_grad(
+            functools.partial(loss, first_of=first_of), argnums=(0, 1, 2, 3)
+        ))(x, gate, w_up, w_down))
+
+    text = program((1, experts), w_up, w_down)
+    assert moe.window_rungs(tokens, top_k, held, experts) == ()
+    assert "while[" not in text
+    assert len(text.splitlines()) == 3217
+    rows = tokens * top_k
+    assert text.count(f"f32[{rows},{d}]") + text.count(
+        f"f32[{rows},{f}]"
+    ) == 29
+    # Four held under five chosen: a window, of one rung at 64 tokens.
+    windowed = program((1, experts), w_up[:4], w_down[:4])
+    assert f"f32[{rows},{d}]" not in windowed
+    assert f"f32[{tokens * 4},{d}]" in windowed and "while[" not in windowed
 
 
 # --- the grouped matmul's passes, by the precision it is traced under -----

@@ -246,8 +246,53 @@ def test_family_agrees_with_the_reference(expert_share, mixer_share):
     assert "attention_fused_applications" not in stats  # toy widths
     if expert_share == (0, 1):
         assert "moe_held_assignments" not in stats
+        assert "moe_window_rows" not in stats
     else:
         assert 0 < float(stats["moe_held_assignments"]) < 3 * T * B
+        # Two held under three chosen: a window, of one rung at 22
+        # tokens (a rung is never under the kernels' 256-row tile).
+        assert float(stats["moe_window_rows"]) == 2 * T * B
+        assert float(stats["moe_window_short_applications"]) == 0
+
+
+@pytest.mark.parametrize(
+    "to_held, sweeps", [(None, 1), (0, 0), (3.0, 3)],
+    ids=["as-routed", "no-row", "every-token-on-both-held-experts"],
+)
+def test_update_stats_say_how_far_the_window_was_swept(to_held, sweeps):
+    """Two of 32 experts held under three a token, 352 tokens: the
+    window of 704 sorted rows has rungs of 256 (`moe.window_rungs`),
+    and the update's stats carry the rows the kernels swept and the
+    layers that needed one rung alone, as the held experts' sizes
+    imply: one rung as initialised, none with the held experts at
+    every token's bottom, three (`moe_window_short_applications` 0)
+    with both at every token's top."""
+    from torchbeast_tpu.models import moe
+
+    rows = 32
+    model, params = _model((1, 16), num_experts=32)
+    assert moe.window_rungs(T * rows, 3, 2, 32) == (256, 2 * T * rows)
+    bias = 0.1 * jax.random.normal(jax.random.PRNGKey(1), (32,))
+    if to_held is not None:
+        bias = bias.at[2:4].set(to_held - 1.5)
+    inner = dict(params["params"])
+    block = dict(inner["block_1"])
+    block["moe"] = dict(block["moe"], e_score_correction_bias=bias)
+    params = {"params": dict(inner, block_1=block)}
+    batch = {
+        k: jnp.concatenate([v] * (rows // B), axis=1)
+        for k, v in _learner_batch(3, done_steps=ENDS).items()
+    }
+    hp = learner_lib.HParams(batch_size=rows, unroll_length=T - 1)
+    jitted = jax.jit(lambda p: learner_lib.compute_loss(
+        model, p, batch, model.initial_state(rows), hp
+    ))
+    _, stats = jitted(params)
+    held = float(stats["moe_held_assignments"])
+    assert held == {None: held, 0: 0, 3.0: 2 * T * rows}[to_held]
+    assert float(stats["moe_window_rows"]) == 256 * sweeps
+    assert sweeps == -(-held // 256)
+    assert float(stats["moe_window_short_applications"]) == (sweeps <= 1)
 
 
 def _recurrence(x, dt, A, B_in, C_in, state, done):

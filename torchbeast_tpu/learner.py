@@ -656,13 +656,15 @@ def _moe_stats(sown) -> Dict[str, Any]:
     experts (mellum2, kanana2), the same two over the experts held; for
     layers with a selection bias, its largest magnitude, and the shared
     experts applied (kanana2); for layers whose experts live in a
-    latent, the layers that project into one (nemotron3). Empty for
-    every other model."""
+    latent, the layers that project into one, and for layers that hold
+    fewer experts than a token chooses, the rows of the window of
+    sorted rows their kernels swept and the layers for which one short
+    rung of it was enough (nemotron3). Empty for every other model."""
     by_name = _sown_leaves_by_name(sown)
     stats = {}
     for name in (
         "assignments", "held_assignments", "shared_applications",
-        "latent_applications",
+        "latent_applications", "window_rows", "window_short_applications",
     ):
         if name in by_name:
             stats["moe_" + name] = sum(by_name[name])

@@ -244,42 +244,47 @@ _window_sum.defvjp(_window_sum_fwd, _window_sum_bwd)
 
 
 class _Window(NamedTuple):
-    """Which assignments the window of tokens x held sorted rows holds."""
+    """Which assignments a rung of the window of sorted rows holds."""
 
-    order: jax.Array  # [window] the (token, rank) assignment of row i
-    token: jax.Array  # [window] its token: order // K
+    order: jax.Array  # [rung] the (token, rank) assignment of row i
+    token: jax.Array  # [rung] its token: order // K
     chose: jax.Array  # [tokens, K, held] token t's rank k is held expert c
-    slot: jax.Array  # [tokens, held] the row where t meets c, else `window`
+    slot: jax.Array  # [tokens, held] the row where t meets c, else `rung`
     live: jax.Array  # [] how many rows, the first, are held experts' rows
 
 
-def _window_dispatch(x, idx, order, inverse, sizes, first, held):
-    """The window of the sorted rows that starts at expert `first`'s,
-    tokens x held long: (rows [window, d], the kernels' group sizes
-    [held + 1], the `_Window`). One gather of the window's rows from x;
-    `order`, `inverse`, `sizes` as `dropless_experts` has them."""
+def _window_dispatch(x, idx, order, inverse, sizes, first, held, rung=None,
+                     at_row=0):
+    """`rung` rows (unless told, all tokens x held that the held
+    experts can draw) of the window of the sorted rows that starts at
+    expert `first`'s, from the window's row `at_row` on: (rows [rung,
+    d], the kernels' group sizes [held + 1], the `_Window`). One gather
+    of the rung's rows from x; `order`, `inverse`, `sizes` as
+    `dropless_experts` has them."""
     tokens, K = idx.shape
-    window = tokens * held
-    start = jnp.sum(sizes[:first])
-    mine = sizes[first : first + held]
+    rung = rung or tokens * held
+    start = jnp.sum(sizes[:first]) + at_row
+    # What of each held expert's rows, one run after another from the
+    # window's row 0, lies within this rung.
+    ends = jnp.cumsum(sizes[first : first + held])
+    mine = jnp.diff(jnp.clip(ends, at_row, at_row + rung), prepend=at_row)
     live = jnp.sum(mine)
     # The INDICES are cut, not the rows; behind the sorted rows' end
     # assignment 0 again, so that every entry names a token.
     order = jax.lax.dynamic_slice_in_dim(
-        jnp.pad(order, (0, window)), start, window
+        jnp.pad(order, (0, rung)), start, rung
     )
     chose = idx[:, :, None] == first + jnp.arange(held)
+    row = jnp.sum(
+        jnp.where(chose, inverse.reshape(tokens, K, 1) - start, 0), axis=1
+    )
     slot = jnp.where(
-        chose.any(axis=1),
-        jnp.sum(
-            jnp.where(chose, inverse.reshape(tokens, K, 1) - start, 0), axis=1
-        ),
-        window,
+        chose.any(axis=1) & (row >= 0) & (row < rung), row, rung
     )
     at = _Window(order, order // K, chose, slot, live)
-    # The held experts' groups, and one more of whatever else the
-    # window took in, which no expert visits.
-    groups = jnp.append(mine, window - live)
+    # The held experts' groups, and one more of whatever else the rung
+    # took in, which no expert visits.
+    groups = jnp.append(mine, rung - live)
     return _window_rows(x, at.token, at.slot), groups, at
 
 
@@ -430,6 +435,133 @@ def relu2(x):
 _ACTIVATIONS = {"silu": nn.silu, "relu2": relu2}
 
 
+# A rung of the window over the rows an even load sends the held experts.
+_RUNG_HEADROOM = 2.0
+
+
+def window_rungs(tokens, top_k, held, experts):
+    """(rung, window) of the held experts' sorted rows, from shapes
+    alone; () where no window is cut (`held >= top_k`). The window is
+    tokens x held rows, the most the routing can send to `held`
+    experts. It is swept `rung` rows at a time: the rows an even load
+    sends there (tokens x top_k x held / experts) with `_RUNG_HEADROOM`,
+    in whole row tiles of the grouped kernels, where that is under
+    half the window; else the window is its one rung."""
+    if held >= top_k:
+        return ()
+    window, tile = tokens * held, _GMM_TILING[0]
+    even = tokens * top_k * held / experts
+    rung = -(-math.ceil(_RUNG_HEADROOM * even) // tile) * tile
+    return (rung if 2 * rung < window else window, window)
+
+
+def window_sweeps(rungs, mine):
+    """How many rungs of the window a step takes, on the device: as
+    many as hold a row of the held experts, `mine` [held] their group
+    sizes; one, whatever they are, where the window is one rung."""
+    rung, window = rungs
+    if rung == window:
+        return jnp.int32(1)
+    return (jnp.sum(mine).astype(jnp.int32) + rung - 1) // rung
+
+
+class _Experts(NamedTuple):
+    """What a pass over the window is traced by (hashable: the static
+    argument of `_swept_experts`)."""
+
+    first: int
+    held: int
+    activation: str
+    terms: int  # bfloat16 terms a side of the kernels' products
+    rungs: Tuple[int, int]
+
+
+def _experts_on_rows(rows, w_gate, w_up, w_down, groups, first, activation,
+                     terms):
+    """The experts on rows sorted into `groups`: `w_down act(w_up x)`,
+    or with `w_gate` the SwiGLU's `w_down (act(w_gate x) * w_up x)`;
+    `first` and `terms` as `_grouped_matmul` takes them."""
+    act = _ACTIVATIONS[activation]
+    with jax.named_scope("moe_experts"):
+        hidden = act(_grouped_matmul(
+            rows, w_up if w_gate is None else w_gate, groups, first, terms
+        ))
+        if w_gate is not None:
+            hidden = hidden * _grouped_matmul(
+                rows, w_up, groups, first, terms
+            )
+        return _grouped_matmul(hidden, w_down, groups, first, terms)
+
+
+def _window_experts(how, at_row, x, gate, w_gate, w_up, w_down, idx, order,
+                    inverse, sizes):
+    """The held experts' part of the sum over one rung of the window,
+    its rows `at_row` onward: rows gathered, the grouped matmuls, the
+    sum a token."""
+    with jax.named_scope("moe_dispatch"):
+        rows, groups, at = _window_dispatch(
+            x, idx, order, inverse, sizes, how.first, how.held,
+            how.rungs[0], at_row,
+        )
+    out = _experts_on_rows(
+        rows, w_gate, w_up, w_down, groups, 0, how.activation, how.terms
+    )
+    with jax.named_scope("moe_combine"):
+        return _window_combine(
+            out.astype(jnp.float32), gate.astype(jnp.float32), at
+        )
+
+
+def _sweep(how, sizes, rung_at, start):
+    """`rung_at(at_row, carry)` over the rungs this step's window
+    takes, one compiled body however many they are."""
+    mine = sizes[how.first : how.first + how.held]
+    return jax.lax.fori_loop(
+        0, window_sweeps(how.rungs, mine),
+        lambda i, carry: rung_at(i * how.rungs[0], carry), start,
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _swept_experts(how, weights, indices):
+    """`_window_experts` summed over the rungs that hold a row of the
+    held experts: one in a step whose routing is near even, all of the
+    window if every token chose every held expert; no assignment is
+    left out. Differentiated as a whole: the rungs are a loop of as
+    many turns as the step's sizes say, which JAX does not reverse, and
+    the backward pass is the same loop over each rung's own."""
+    return _swept_experts_fwd(how, weights, indices)[0]
+
+
+def _swept_experts_fwd(how, weights, indices):
+    y = _sweep(
+        how, indices[-1],
+        lambda at_row, y: y + _window_experts(
+            how, at_row, *weights, *indices
+        ),
+        jnp.zeros(weights[0].shape, jnp.float32),
+    )
+    return y, (weights, indices)
+
+
+def _swept_experts_bwd(how, residuals, grad):
+    weights, indices = residuals
+
+    def rung_at(at_row, grads):
+        _, pull_back = jax.vjp(
+            lambda *w: _window_experts(how, at_row, *w, *indices), *weights
+        )
+        return jax.tree_util.tree_map(jnp.add, grads, pull_back(grad))
+
+    return _sweep(
+        how, indices[-1], rung_at,
+        jax.tree_util.tree_map(jnp.zeros_like, weights),
+    ), None
+
+
+_swept_experts.defvjp(_swept_experts_fwd, _swept_experts_bwd)
+
+
 def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None,
                      activation="silu"):
     """sum_k gate[t, k] * expert_{idx[t, k]}(x[t]), every assignment
@@ -446,23 +578,28 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None,
     With C >= K (or all experts held) every one of the t x K sorted
     rows is moved: x repeated K times and permuted, the kernels' rows
     permuted back and summed K a token. With C < K the held experts'
-    rows lie within t x C of them, and those alone are moved: t x C
-    rows gathered from x, t x C gathered from the kernels' output and
-    summed C a token (`_window_dispatch`, `_window_combine`), backward
-    by gathers of as many; no array of t x K rows is built.
+    rows lie within t x C of them, and a window of the sorted rows is
+    moved alone: its rows gathered from x, t x C gathered from the
+    kernels' output and summed C a token (`_window_dispatch`,
+    `_window_combine`), backward by gathers of as many; no array of
+    t x K rows is built. The window is t x C rows long and, where the
+    shapes leave room for it (`window_rungs`), swept a rung at a time
+    only as far as the step's own rows reach, counted on the device.
     """
     tokens, K = idx.shape
     first, E = first_of or (None, w_up.shape[0])
-    act = _ACTIVATIONS[activation]
+    terms = _terms_traced_under()
     # Fewer experts held than a token chooses (models/nemotron3.py: 8
     # of 512 under 22 a token): a token lands on each expert at most
     # once, so at most tokens x held of the tokens x K sorted rows are
     # these experts', one contiguous run. That window is cut out of the
-    # sorted INDICES, and rows are gathered by them alone: tokens x
-    # held from x for the kernels, as many from the kernels' output for
-    # the sum. The shapes decide, once, at trace time.
+    # sorted INDICES, and rows are gathered by them alone: the window's
+    # from x for the kernels, tokens x held from the kernels' output
+    # for the sum. The shapes decide, once, at trace time, whether
+    # there is a window and how long a rung of it is; the step's own
+    # sizes, on the device, how many rungs it takes.
     held = w_up.shape[0]
-    window = tokens * held if first is not None and held < K else None
+    rungs = () if first is None else window_rungs(tokens, K, held, E)
     with jax.named_scope("moe_dispatch"):
         flat = idx.reshape(tokens * K)
         # order[i]: which (token, rank) assignment sits in sorted row i.
@@ -471,33 +608,23 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None,
             jnp.arange(tokens * K, dtype=order.dtype), unique_indices=True
         )
         sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
-        if window:
-            rows, groups, at = _window_dispatch(
-                x, idx, order, inverse, sizes, first, held
-            )
-            first_group = 0
-        else:
-            rows = _permute(jnp.repeat(x, K, axis=0), order, inverse)
-            groups, first_group = sizes, first
-    with jax.named_scope("moe_experts"):
-        if w_gate is None:
-            hidden = act(grouped_matmul(rows, w_up, groups, first_group))
-        else:
-            hidden = act(
-                grouped_matmul(rows, w_gate, groups, first_group)
-            ) * grouped_matmul(rows, w_up, groups, first_group)
-        out = grouped_matmul(hidden, w_down, groups, first_group)
+    if rungs:
+        how = _Experts(first, held, activation, terms, rungs)
+        weights = (x, gate, w_gate, w_up, w_down)
+        indices = (idx, order, inverse, sizes)
+        if rungs[0] == rungs[1]:
+            return _window_experts(how, 0, *weights, *indices), sizes
+        return _swept_experts(how, weights, indices), sizes
+    with jax.named_scope("moe_dispatch"):
+        rows = _permute(jnp.repeat(x, K, axis=0), order, inverse)
+    out = _experts_on_rows(
+        rows, w_gate, w_up, w_down, sizes, first, activation, terms
+    )
     with jax.named_scope("moe_combine"):
-        if window:
-            y = _window_combine(
-                out.astype(jnp.float32), gate.astype(jnp.float32), at
-            )
-        else:
-            out = _permute(out, inverse, order).reshape(tokens, K, -1)
-            y = jnp.einsum(
-                "tkd,tk->td", out.astype(jnp.float32),
-                gate.astype(jnp.float32),
-            )
+        out = _permute(out, inverse, order).reshape(tokens, K, -1)
+        y = jnp.einsum(
+            "tkd,tk->td", out.astype(jnp.float32), gate.astype(jnp.float32)
+        )
     return y, sizes
 
 
@@ -684,6 +811,17 @@ class DroplessMoE(nn.Module):
                     ("moe_stats", "held_load_max_over_mean",
                      jnp.max(mine) * count / jnp.maximum(jnp.sum(mine), 1.0)),
                 ]
+                rungs = window_rungs(tokens, K, count, E)
+                if rungs:
+                    # Fewer held than chosen: the rows of the window
+                    # the kernels swept, and whether one rung short of
+                    # the whole window held them all.
+                    swept = window_sweeps(rungs, mine).astype(jnp.float32)
+                    sown += [
+                        ("moe_stats", "window_rows", swept * rungs[0]),
+                        ("moe_stats", "window_short_applications",
+                         (swept <= 1.0) * jnp.float32(rungs[0] < rungs[1])),
+                    ]
             if self.selection_bias:
                 # Under the parameter's own name: the learner adds a
                 # sown step to the leaf of `params` at the same path.

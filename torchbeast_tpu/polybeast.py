@@ -2156,7 +2156,9 @@ def train(flags):
                 # dropless expert layer's router did (learner._moe_stats):
                 # every assignment computed, and the fullest expert's
                 # rows over the mean, worst layer; with --expert_share,
-                # the same two over the experts held here. A looped
+                # the same two over the experts held here, and where
+                # those are fewer than a token chooses the rows of the
+                # window their kernels swept. A looped
                 # trunk's passes (learner._loop_stats): how many, the
                 # block applications and cache bytes a row they cost,
                 # and where its exit gates would let go. Block
@@ -2171,7 +2173,8 @@ def train(flags):
                         "assignments", "load_max_over_mean",
                         "held_assignments", "held_load_max_over_mean",
                         "bias_abs_max", "bias_steps", "shared_applications",
-                        "latent_applications",
+                        "latent_applications", "window_rows",
+                        "window_short_applications",
                     )),
                     ("ssm", (
                         "applications", "chunks", "resets_per_row",
