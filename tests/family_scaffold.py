@@ -1,0 +1,569 @@
+"""The one scaffold under the policy families' tier-1 tests: a toy
+family built and compiled ONCE a process.
+
+What every family's file needs is here and nowhere else: the toy batch
+(`inputs`, `learner_batch`), `build(family, **overrides) -> (model,
+params)`, `warm_state`, `reference_config`, and jitted callables for
+the four programs the cases run again and again (`forward`,
+`loss_and_grads`, `reference_forward`, `reference_loss_and_grads`),
+with the three comparisons several families make in the same words
+(`assert_agrees_with_the_reference`, `assert_stepwise_acting_equals_
+the_batch_forward`, `assert_state_table_acting_equals_the_batch_
+forward`). `build` and the callables are memoised for the life of the
+process (a flax module hashes by its fields), and `build` runs
+`jax.jit(model.init)`: run eagerly, a toy family is 1,300 XLA compiles
+of one op each, 79% of a case (ISSUE 45). So:
+
+- Memoised parameters are READ-ONLY: a case that alters them builds a
+  new tree around the leaves it replaces (`dict(inner, ...)`), never
+  writes into one.
+- A callable is traced once a model and input shape. A case whose trace
+  must be its own (it monkeypatches a rule that is read at trace time)
+  asks for `loss_and_grads.__wrapped__(model)`; a case that must run
+  eagerly (it reads a sown intermediate, or hands `DeviceStateTable` an
+  `act_fn` the table jits itself) calls `model.apply` and says so.
+
+THE RULE FOR THE NEXT FAMILY (a `model_config` PR): one `Family` entry
+below (its class, its `SMALL` table, its reference, and `perturb`: the
+values for what the family starts at zero or one); one file
+`tests/test_<family>.py` with the cases that are the family's own, on
+this scaffold; one file `tests/test_chip_compile_<family>.py` with its
+whole-cell compile (fixtures: `tests/chip_fixtures.py`); one id in each
+parametrised test of `tests/test_families.py`, `tests/test_monobeast.py
+::test_train_family_through_main` and `tests/test_polybeast.py::test_
+polybeast_train_family`. No edit to another family's file.
+"""
+
+import dataclasses
+import functools
+from types import ModuleType
+from typing import Any, Callable, Optional
+
+import jax
+import jax.flatten_util
+import jax.numpy as jnp
+import numpy as np
+
+from perfbench.reference import (
+    kanana2_policy,
+    mellum2_policy,
+    nemotron3_policy,
+    olmoe_policy,
+    ouro_policy,
+)
+from torchbeast_tpu import learner as learner_lib
+from torchbeast_tpu.models import (
+    Kanana2Net,
+    Mellum2Net,
+    Nemotron3Net,
+    OLMoENet,
+    OuroNet,
+    kanana2,
+    mellum2,
+    nemotron3,
+    olmoe,
+    ouro,
+)
+from torchbeast_tpu.runtime.state_table import DeviceStateTable
+
+B, A = 2, 4
+FRAME = (8, 8, 1)
+_LOSS_COSTS = {
+    "discounting": 0.99, "baseline_cost": 0.5, "entropy_cost": 0.0006,
+}
+
+
+def inputs(seed, done_steps=(), t=6, rows=B):
+    rng = np.random.default_rng(seed)
+    done = np.zeros((t, rows), bool)
+    for step, row in done_steps:
+        done[step, row] = True
+    return {
+        "frame": jnp.asarray(
+            rng.integers(0, 256, (t, rows) + FRAME, dtype=np.uint8)
+        ),
+        "reward": jnp.asarray(rng.standard_normal((t, rows)), jnp.float32),
+        "done": jnp.asarray(done),
+        "last_action": jnp.asarray(rng.integers(0, A, (t, rows))),
+    }
+
+
+def learner_batch(seed, done_steps, t=6):
+    rng = np.random.default_rng(seed + 100)
+    lead = (t, B)
+    return dict(
+        inputs(seed, done_steps, t=t),
+        episode_return=jnp.asarray(rng.standard_normal(lead), jnp.float32),
+        episode_step=jnp.zeros(lead, jnp.int32),
+        action=jnp.asarray(rng.integers(0, A, lead)),
+        policy_logits=jnp.asarray(
+            rng.standard_normal(lead + (A,)), jnp.float32
+        ),
+        baseline=jnp.asarray(rng.standard_normal(lead), jnp.float32),
+    )
+
+
+def _normal(seed, shape, scale):
+    return scale * jax.random.normal(jax.random.PRNGKey(seed), shape)
+
+
+def _with_extras(inner):
+    """The side inputs' projection, which the family starts at zero."""
+    assert not np.any(inner["extras"]["kernel"])
+    kernel = _normal(7, inner["extras"]["kernel"].shape, 0.3)
+    return dict(inner, extras=dict(inner["extras"], kernel=kernel))
+
+
+def _with_selection_bias(block, seed):
+    assert not np.any(block["moe"]["e_score_correction_bias"])
+    bias = _normal(
+        seed, block["moe"]["e_score_correction_bias"].shape, 0.1
+    )
+    return dict(block, moe=dict(block["moe"], e_score_correction_bias=bias))
+
+
+def _perturb_mellum2(model, params):
+    return {"params": _with_extras(params["params"])}
+
+
+def _perturb_ouro(model, params):
+    # Norm scales start at one and the gate's bias at zero: move them, so
+    # that a norm left out or applied twice, or a gate misread, shows.
+    flat, unravel = jax.flatten_util.ravel_pytree(params)
+    return unravel(flat + _normal(7, flat.shape, 0.2))
+
+
+def _perturb_kanana2(model, params):
+    inner = _with_extras(params["params"])
+    for layer in range(1, model.num_layers):
+        name = f"block_{layer}"
+        inner[name] = _with_selection_bias(inner[name], layer)
+    return {"params": inner}
+
+
+def _perturb_nemotron3(model, params):
+    # And D and the norms, which start at one.
+    inner = _with_extras(params["params"])
+    for layer, letter in enumerate(model.pattern()):
+        name = f"block_{layer}"
+        if letter == "E":
+            inner[name] = _with_selection_bias(inner[name], layer)
+        elif letter == "M":
+            block = dict(inner[name])
+            for i, leaf in enumerate(("D", "gate_norm")):
+                block[leaf] = block[leaf] + _normal(
+                    10 * layer + i, block[leaf].shape, 0.3
+                )
+            inner[name] = block
+    return {"params": inner}
+
+
+def _config_olmoe(model):
+    return {
+        "num_attention_heads": model.num_heads,
+        "num_experts": model.num_experts,
+        "num_experts_per_tok": model.experts_per_token,
+        "num_hidden_layers": model.num_layers,
+        "rms_norm_eps": 1e-5, "rope_theta": 10000,
+        "load_balance_weight": 0.01,
+    }
+
+
+YARN_CONFIG = {
+    "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+    "original_max_position_embeddings": 8192, "beta_fast": 32,
+    "beta_slow": 1, "attention_factor": 1.2772588722239782,
+}
+
+
+def _config_mellum2(model):
+    return {
+        "num_attention_heads": model.num_heads,
+        "num_key_value_heads": model.kv_heads,
+        "head_dim": model.head_dim,
+        "sliding_window": model.sliding_window,
+        "layer_types": list(mellum2.PUBLISHED["layer_period"]) * 7,
+        "num_hidden_layers": model.num_layers,
+        "published_num_experts": model.num_experts,
+        "num_experts": model.num_experts // model.expert_share[1],
+        "expert_share": list(model.expert_share),
+        "num_experts_per_tok": model.experts_per_token,
+        "norm_topk_prob": True,
+        "rms_norm_eps": 1e-6,
+        "rope_parameters": {
+            "full_attention": YARN_CONFIG,
+            "sliding_attention": {
+                "rope_type": "default", "rope_theta": 500000,
+            },
+        },
+        "load_balance_weight": 0.001,
+    }
+
+
+def _config_ouro(model):
+    return {
+        "num_attention_heads": model.num_heads,
+        "head_dim": model.head_dim,
+        "num_hidden_layers": model.num_layers,
+        "total_ut_steps": model.passes,
+        "rms_norm_eps": 1e-6, "rope_theta": 1000000.0,
+    }
+
+
+def _config_kanana2(model):
+    return {
+        "num_attention_heads": model.num_heads,
+        "kv_lora_rank": model.latent_rank, "q_lora_rank": None,
+        "qk_nope_head_dim": model.nope_head_dim,
+        "qk_rope_head_dim": model.rope_head_dim,
+        "qk_head_dim": model.nope_head_dim + model.rope_head_dim,
+        "v_head_dim": model.value_head_dim,
+        "rope_interleave": True, "rope_scaling": None, "rope_theta": 1e6,
+        "rms_norm_eps": 1e-6, "num_hidden_layers": model.num_layers,
+        "first_k_dense_replace": 1,
+        "published_n_routed_experts": model.num_experts,
+        "n_routed_experts": model.num_experts // model.expert_share[1],
+        "expert_share": list(model.expert_share),
+        "num_experts_per_tok": model.experts_per_token,
+        "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+        "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+        "routed_scaling_factor": 2.448, "bias_update_rate": 0.001,
+    }
+
+
+def _config_nemotron3(model):
+    heads, groups, query_heads, kv_heads = model.held_mixers()
+    held = model.held_experts()
+    return {
+        "hybrid_override_pattern": model.pattern(),
+        "num_hidden_layers": model.num_layers,
+        "mamba_num_heads": heads, "mamba_head_dim": model.mamba_head_dim,
+        "n_groups": groups, "ssm_state_size": model.state_size,
+        "conv_kernel": model.conv_kernel, "use_conv_bias": True,
+        "mamba_proj_bias": False, "mamba_hidden_act": "silu",
+        "num_attention_heads": query_heads,
+        "num_key_value_heads": kv_heads, "head_dim": model.head_dim,
+        "attention_bias": False,
+        "published_n_routed_experts": model.num_experts,
+        "n_routed_experts": held[1] if held else model.num_experts,
+        "expert_share": list(model.expert_share),
+        "mixer_share": list(model.mixer_share),
+        "num_experts_per_tok": model.experts_per_token,
+        "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+        "routed_scaling_factor": 5.0, "n_shared_experts": 1,
+        "mlp_hidden_act": "relu2", "mlp_bias": False,
+        "bias_update_rate": 0.001, "layer_norm_epsilon": 1e-5,
+    }
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """One policy family at toy size: `net(num_actions=A, **small)` is
+    the model every case of the family starts from (`small` is also
+    what a case lays over the family's `PUBLISHED` table), `reference`
+    the plain implementation it is held to, `perturb(model, params)`
+    the values for what the family starts at zero or one."""
+
+    net: type
+    module: ModuleType  # holds `PUBLISHED`
+    reference: ModuleType
+    small: dict  # with num_layers and memory_len
+    config: Callable  # model -> the reference's config, less the costs
+    perturb: Optional[Callable] = None
+    t: int = 6
+    # The episode end inside warm_state's first unroll.
+    warm_end: Any = (2, 1)
+
+
+FAMILIES = {
+    # 8 experts of 32, top 2, over a 4-slot cache (T=6 evicts).
+    "olmoe": Family(
+        OLMoENet, olmoe, olmoe_policy,
+        dict(
+            d_model=64, num_heads=4, num_layers=2, num_experts=8,
+            experts_per_token=2, expert_width=32, memory_len=4,
+        ),
+        _config_olmoe,
+    ),
+    # 4 query heads on 2 key/value heads of 16, a window of 4 keys (3
+    # slots), 8 experts of 24, top 2; the full layer's cache is 9 slots.
+    "mellum2": Family(
+        Mellum2Net, mellum2, mellum2_policy,
+        dict(
+            d_model=48, num_heads=4, kv_heads=2, head_dim=16,
+            sliding_window=4, num_experts=8, experts_per_token=2,
+            expert_width=24, num_layers=4, memory_len=9,
+        ),
+        _config_mellum2, _perturb_mellum2,
+    ),
+    # 4 heads of 16, a SwiGLU of 96, 2 layers run 3 times over 5-slot
+    # caches (T=6 evicts on the way).
+    "ouro": Family(
+        OuroNet, ouro, ouro_policy,
+        dict(
+            d_model=64, num_heads=4, head_dim=16, mlp_width=96,
+            num_layers=2, passes=3, memory_len=5,
+        ),
+        _config_ouro, _perturb_ouro, warm_end=(4, 1),
+    ),
+    # 4 heads of 16 + 8 (values of 12) over a latent of 24, a dense
+    # SwiGLU of 64, 16 routed experts of 20, top 3, two shared experts
+    # (one SwiGLU of 40); the dense layer and two MoE layers, 9 slots.
+    "kanana2": Family(
+        Kanana2Net, kanana2, kanana2_policy,
+        dict(
+            d_model=48, num_heads=4, latent_rank=24, nope_head_dim=16,
+            rope_head_dim=8, value_head_dim=12, mlp_width=64,
+            num_experts=16, experts_per_token=3, expert_width=20,
+            shared_experts=2, num_layers=3, memory_len=9,
+        ),
+        _config_kanana2, _perturb_kanana2,
+    ),
+    # One attention layer of 4 query heads of 8 on 2 key/value heads,
+    # one latent MoE layer (16 experts of 10 in a latent of 12, top 3, a
+    # shared expert of 20), one Mamba-2 layer of 8 heads of 4 in 4
+    # groups over a state of 6, scanned in chunks of 4 steps: the 11
+    # steps of an unroll are two whole chunks and one padded.
+    "nemotron3": Family(
+        Nemotron3Net, nemotron3, nemotron3_policy,
+        dict(
+            d_model=32, num_heads=4, kv_heads=2, head_dim=8, mamba_heads=8,
+            mamba_head_dim=4, mamba_groups=4, state_size=6, chunk_size=4,
+            num_experts=16, experts_per_token=3, expert_width=10,
+            latent_width=12, shared_width=20, layer_period="*EM",
+            layer_pattern="MEM*EMM", num_layers=3, memory_len=5,
+        ),
+        _config_nemotron3, _perturb_nemotron3, t=11,
+    ),
+}
+
+
+def family_of(model) -> Family:
+    return next(f for f in FAMILIES.values() if isinstance(model, f.net))
+
+
+def init_params(model, batch, jit=True):
+    """`model.init` as one program (eagerly it is some hundreds of
+    compiles of one op each), from the keys every case uses."""
+    init = jax.jit(model.init) if jit else model.init
+    rows = batch["done"].shape[1]
+    return init(
+        {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
+        batch, model.initial_state(rows),
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _build(family, overrides):
+    toy = FAMILIES[family]
+    model = toy.net(num_actions=A, **dict(toy.small, **dict(overrides)))
+    params = init_params(model, inputs(0, t=toy.t))
+    if toy.perturb is not None:
+        params = toy.perturb(model, params)
+    return model, params
+
+
+def build(family, **overrides):
+    """(model, params) of the toy `family` with `overrides` laid over
+    its `small` table: built once a process, the parameters read-only."""
+    return _build(family, tuple(sorted(overrides.items())))
+
+
+@functools.lru_cache(maxsize=None)
+def forward(model, jit=True):
+    """Jitted `(params, inputs, state) -> (outputs, new state)`, for a
+    [T, B] batch and for a T=1 act step alike (a trace a shape).
+    `jit=False`: the same callable eager, for the case that says why."""
+
+    def run(params, batch, state):
+        return model.apply(params, batch, state, sample_action=False)
+
+    return jax.jit(run) if jit else run
+
+
+@functools.lru_cache(maxsize=None)
+def loss_and_grads(model, jit=True):
+    """Jitted `(params, batch, state) -> (loss, stats, grads)` of
+    `learner.compute_loss`, `PARAM_STEPS_KEY` still among the stats."""
+
+    def run(params, batch, state):
+        t, rows = batch["done"].shape
+        hp = learner_lib.HParams(batch_size=rows, unroll_length=t - 1)
+        (loss, stats), grads = jax.value_and_grad(
+            lambda p: learner_lib.compute_loss(model, p, batch, state, hp),
+            has_aux=True,
+        )(params)
+        return loss, stats, grads
+
+    return jax.jit(run) if jit else run
+
+
+@functools.lru_cache(maxsize=None)
+def reference_bias_steps(model):
+    """Jitted `(params, batch, state) -> the steps the reference's rule
+    gives the selection biases`, a layer an item (kanana2, nemotron3)."""
+    reference, config = family_of(model).reference, reference_config(model)
+    return jax.jit(lambda params, batch, state: reference.bias_steps(
+        params, batch, state, config
+    ))
+
+
+def reference_config(model):
+    """What `perfbench/reference/<family>_policy.py` reads of `model`."""
+    return dict(
+        family_of(model).config(model), memory_len=model.memory_len,
+        num_actions=A, **_LOSS_COSTS,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def reference_forward(model):
+    """Jitted `(params, batch, state) -> (logits, baseline, new state,
+    the family's fourth)` of the plain reference, its config closed
+    over."""
+    reference, config = family_of(model).reference, reference_config(model)
+    return jax.jit(lambda params, batch, state: reference.forward(
+        params, batch, state, config
+    ))
+
+
+@functools.lru_cache(maxsize=None)
+def reference_loss_and_grads(model):
+    """Jitted `(params, batch, state) -> (loss, scale, grads)` of the
+    plain reference (`loss` is `loss_and_scale(...)[0]` in every one)."""
+    reference, config = family_of(model).reference, reference_config(model)
+
+    def run(params, batch, state):
+        (loss, scale), grads = jax.value_and_grad(
+            lambda p: reference.loss_and_scale(p, batch, state, config),
+            has_aux=True,
+        )(params)
+        return loss, scale, grads
+
+    return jax.jit(run)
+
+
+def warm_state(model, params, seed, unrolls=1, rows=B):
+    """What an actor would hold `unrolls` unrolls in, an episode end in
+    the first (`Family.warm_end`)."""
+    toy = family_of(model)
+    state = model.initial_state(rows)
+    for i in range(unrolls):
+        ends = [toy.warm_end] if i == 0 else ()
+        _, state = forward(model)(
+            params, inputs(seed + i, ends, t=toy.t, rows=rows), state
+        )
+    return state
+
+
+def flat(tree):
+    return jax.flatten_util.ravel_pytree(tree)[0]
+
+
+def assert_agrees_with_the_reference(
+    model, params, state, batch, rtol, atol
+):
+    """Outputs, new state, the loss (within `rtol` of its scale) and
+    its gradients (within `rtol` of the largest) against the plain
+    reference's; hands back what the family's own assertions read:
+    (stats, grads, the reference's grads, the reference's fourth)."""
+    out, new_state = forward(model)(params, batch, state)
+    logits, baseline, ref_state, fourth = reference_forward(model)(
+        params, batch, state
+    )
+    np.testing.assert_allclose(out.policy_logits, logits, rtol, atol)
+    np.testing.assert_allclose(out.baseline, baseline, rtol, atol)
+    leaves, ref_leaves = (
+        jax.tree_util.tree_leaves(s) for s in (new_state, ref_state)
+    )
+    assert len(leaves) == len(ref_leaves)
+    for got, want in zip(leaves, ref_leaves):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, rtol, atol)
+
+    loss, stats, grads = loss_and_grads(model)(params, batch, state)
+    ref_loss, scale, ref_grads = reference_loss_and_grads(model)(
+        params, batch, state
+    )
+    assert abs(float(loss) - float(ref_loss)) <= rtol * float(scale)
+    np.testing.assert_allclose(
+        flat(grads), flat(ref_grads), rtol=0,
+        atol=rtol * float(jnp.max(jnp.abs(flat(ref_grads)))),
+    )
+    return stats, grads, ref_grads, fourth
+
+
+def assert_stepwise_acting_equals_the_batch_forward(
+    model, params, state, batch
+):
+    """The learner's [T, B] forward and the actor's T=1 forwards from
+    `state` give the same logits and leave the same state; hands back
+    that state. Tolerance: a softmax over another number of masked
+    keys, f32."""
+    full, full_state = forward(model)(params, batch, state)
+    logits = []
+    for t in range(batch["done"].shape[0]):
+        step = {k: v[t : t + 1] for k, v in batch.items()}
+        out, state = forward(model)(params, step, state)
+        logits.append(out.policy_logits[0])
+    np.testing.assert_allclose(
+        np.stack(logits), full.policy_logits, rtol=2e-4, atol=2e-5
+    )
+    for got, want in zip(
+        jax.tree_util.tree_leaves(state),
+        jax.tree_util.tree_leaves(full_state),
+    ):
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    return full_state
+
+
+def assert_state_table_acting_equals_the_batch_forward(
+    model, params, batch, shapes=None
+):
+    """Three actors' slots in a `DeviceStateTable` whose rows hold the
+    family's state (a pytree the table knows nothing of); the rows
+    arrive in another order every step. Every step's logits equal the
+    batch forward's from an empty state over the same inputs, and what
+    the table holds at the end is what that forward leaves (every
+    slot's items of `shapes`, where given). Hands back the table.
+    `act` is eager: the table jits it itself."""
+    rows = 3
+    full, full_state = forward(model)(
+        params, batch, model.initial_state(rows)
+    )
+
+    def act(ctx, env_outputs, agent_state):
+        out, new_state = model.apply(
+            params, env_outputs, agent_state, sample_action=False
+        )
+        return {"logits": out.policy_logits}, new_state
+
+    table = DeviceStateTable(
+        model.initial_state(1), num_slots=rows, act_fn=act, batch_dim=1
+    )
+    orders = [[0, 1, 2], [2, 0, 1], [1, 2, 0], [2, 1, 0], [0, 2, 1], [1, 0, 2]]
+    for t, order in enumerate(orders):
+        step = {
+            k: np.asarray(v[t : t + 1])[:, order] for k, v in batch.items()
+        }
+        out = table.step(
+            np.asarray(order, np.int32), np.ones(rows, bool), step
+        )
+        np.testing.assert_allclose(
+            table.fetch(out, rows)["logits"][0],
+            np.asarray(full.policy_logits)[t][order],
+            rtol=2e-4, atol=2e-5,
+        )
+    for slot in range(rows):
+        held = table.read_slot(slot)
+        if shapes is not None:
+            assert [
+                [np.shape(leaf) for leaf in item] for item in held
+            ] == shapes
+        for item, want_item in zip(held, full_state):
+            for got, want in zip(item, want_item):
+                np.testing.assert_allclose(
+                    got, np.asarray(want)[:, slot : slot + 1],
+                    rtol=2e-4, atol=2e-5,
+                )
+    return table

@@ -9,71 +9,40 @@ device. These tests compile every Pallas kernel a driver can select at
 the flagship learner's shapes (unroll 80, batch 32, so the trunk pools
 see N = 81 * 32 = 2592 rows), plus the flagship act step at the largest
 inference bucket and the flagship update over the four chips against
-its one-chip quarter; the whole one-chip update step is the `slow` case. Nothing
-runs: a compile that passes says nothing about results or times.
+its one-chip quarter; the whole one-chip update step is the `slow`
+case. The families' whole-cell compiles have a file each (`tests/test_
+chip_compile_<family>.py`); the described chip all of them share is
+`tests/chip_fixtures.py`. Nothing runs: a compile that passes says
+nothing about results or times.
 
 Code that asks `jax.default_backend()` still sees the CPU here, so the
 kernels get `interpret=False` explicitly and the whole-step cases patch
-the backend name for the duration of the trace. The persistent compile
-cache is off around these compiles — an executable for a described
-device is written but cannot be read back without a chip.
+the backend name for the duration of the trace.
 """
 
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
-import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
-from jax.experimental import topologies  # noqa: E402
-from jax.experimental.compilation_cache import (  # noqa: E402
-    compilation_cache,
-)
-from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 import __graft_entry__  # noqa: E402
+from tests.chip_fixtures import (  # noqa: E402, F401
+    B,
+    NUM_ACTIONS,
+    T,
+    on as _on,
+    one_chip,
+    struct as _struct,
+    topo,
+)
 from torchbeast_tpu import learner as learner_lib  # noqa: E402
 
-T, B, NUM_ACTIONS = 80, 32, 6
 POOL_N = (T + 1) * B
 MAX_INFERENCE_BATCH = 64  # polybeast --max_inference_batch_size default
-
-
-@pytest.fixture(scope="module")
-def topo():
-    try:
-        described = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-    except Exception as e:  # noqa: BLE001 — no libtpu, no description
-        pytest.skip(f"cannot describe a v5e topology here: {e}")
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield described
-    jax.config.update("jax_enable_compilation_cache", True)
-    compilation_cache.reset_cache()
-
-
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    return SingleDeviceSharding(topo.devices[0])
-
-
-def _on(sharding, tree):
-    """ShapeDtypeStructs of `tree` placed on the described chip."""
-    return jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(
-            np.shape(x), jnp.result_type(x), sharding=sharding
-        ),
-        tree,
-    )
-
-
-def _struct(sharding, shape, dtype=jnp.float32):
-    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
 def _compile_vtrace(chip):
@@ -327,232 +296,6 @@ def test_kanana2_fused_latent_leg_compiles_for_v5e(one_chip, monkeypatch):
         if dims.endswith((f",{slots}", f",{slots + 1}"))
     }
     assert not over_slots, over_slots
-
-
-def _ouro_loop_gradient_memory(chip):
-    """Temp bytes of the gradient of ONE Ouro layer at the published
-    widths run 4 times (the family's loop), rematerialised, over its 4
-    caches of 255 slots, on the cell's [81, 32] tokens; the observation
-    projection shrunk to an 8x8x1 frame."""
-    from torchbeast_tpu.models import create_model
-
-    model = create_model("ouro", num_actions=NUM_ACTIONS, num_layers=1,
-                         remat=True)
-    inputs = {
-        "frame": np.zeros((T + 1, B, 8, 8, 1), np.uint8),
-        "reward": np.zeros((T + 1, B), np.float32),
-        "done": np.zeros((T + 1, B), bool),
-        "last_action": np.zeros((T + 1, B), np.int32),
-    }
-    state = jax.eval_shape(lambda: model.initial_state(B))
-    params = jax.eval_shape(
-        lambda: model.init(
-            {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
-            jax.tree_util.tree_map(lambda x: x[:1], inputs), model.initial_state(B),
-        )
-    )
-
-    def loss(params, inputs, state):
-        out, _ = model.apply(params, inputs, state, sample_action=False)
-        return jnp.sum(out.policy_logits) + jnp.sum(out.baseline)
-
-    return jax.jit(jax.grad(loss)).lower(
-        _on(chip, params), _on(chip, inputs), _on(chip, state)
-    ).compile().memory_analysis().temp_size_in_bytes
-
-
-def test_ouro_loop_sums_weight_gradients_pass_by_pass_on_v5e(
-    one_chip, monkeypatch
-):
-    """A looped block's weight gradient is the sum over its passes.
-    `OuroNet.make_block` ties the weights to the hidden state between
-    two applications (an optimization barrier, whose transpose is one),
-    so that the chip's compiler adds a pass's part to the running sum
-    before it enters the pass before; without the tie the adds fuse
-    into the gradients' consumer and every pass's part lives to the
-    end: (passes - 1) x 196 MiB more here, 4.6 GiB at the cell's 8
-    layers, which then does not fit beside the driver's copy."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    tied = _ouro_loop_gradient_memory(one_chip)
-    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
-    untied = _ouro_loop_gradient_memory(one_chip)
-    layer = 4 * (4 * 2048 * 2048 + 3 * 2048 * 5632)  # one layer, f32
-    assert untied - tied > layer, (tied, untied, layer)
-
-
-def test_kanana2_cell_update_compiles_for_v5e(one_chip, monkeypatch):
-    """`kanana2_policy.learner`'s update as the benchmark builds it (the
-    configuration's own argv: 5 layers, 4,095-slot latent caches, share
-    0/8, blocks rematerialised, [81, 32] batch), whole, for a described
-    v5e: its bytes, read before the cell's first chip run (PR 38: 11.4
-    GiB; PR 41: 10.42, the score-sized temporaries gone; the driver
-    keeps a 2.12 GiB copy of the weights beside it), and
-    the absorbed form seen in the program: no array of decompressed
-    cached keys or values (4,095 slots x 32 heads of 128, 192 or 256)
-    is there, and since PR 41 no f32 array over the slots at all: the
-    cache leg's scores [32, 32, 81, 4095] live in the VMEM of `fused_
-    latent_leg`'s two kernels."""
-    import json
-    import re
-
-    from perfbench import manifest
-    from perfbench.drivers import learner as learner_driver
-    from torchbeast_tpu import monobeast
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with open(os.path.join(
-        manifest.HERE, "configs", "kanana2_30b_policy.json"
-    )) as f:
-        config = json.load(f)
-    flags = monobeast.make_parser().parse_args(
-        config["program_argv"]
-        + ["--unroll_length", str(T), "--batch_size", str(B)]
-    )
-    hp = monobeast.hparams_from_flags(flags)
-    frame = tuple(config["frame_shape"])
-    model, _ = monobeast._init_model_and_params(
-        flags, NUM_ACTIONS, B, frame, init_params=False
-    )
-    optimizer = learner_lib.make_optimizer(hp)
-    params = jax.eval_shape(lambda: model.init(
-        {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
-        monobeast.dummy_env_outputs(1, B, frame, np.uint8),
-        model.initial_state(B),
-    ))
-    batch, state = jax.eval_shape(lambda: (
-        learner_driver._make_batch(
-            jax.random.PRNGKey(0), T + 1, B, NUM_ACTIONS, frame
-        ),
-        model.initial_state(B),
-    ))
-    compiled = learner_lib.make_update_step(model, optimizer, hp).lower(
-        _on(one_chip, params),
-        _on(one_chip, jax.eval_shape(optimizer.init, params)),
-        _on(one_chip, batch), _on(one_chip, state),
-    ).compile()
-    memory = compiled.memory_analysis()
-    total = (
-        memory.temp_size_in_bytes + memory.argument_size_in_bytes
-        + memory.output_size_in_bytes - memory.alias_size_in_bytes
-    )
-    weights = 4 * sum(
-        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)
-    )
-    assert weights == 4 * 568_124_423
-    # 15.75 GiB a chip, less the driver's copy of the weights.
-    assert total < 15.75 * 2**30 - weights, memory
-    assert total > 8 * 2**30, memory  # the cell fills the chip
-    # The family's `update_compiler_options` reached the compiler: the
-    # blocks' shared parts compiled once (416 MB of program without).
-    assert memory.generated_code_size_in_bytes < 200 * 2**20, memory
-    shapes = {
-        tuple(int(d) for d in dims.split(","))
-        for dims in re.findall(r"f32\[([0-9,]+)\]", compiled.as_text())
-    }
-    # The cache leg's scores, at their own size or a padded one.
-    scores = {s for s in shapes if s[-1] in (4095, 4096) and len(s) >= 4}
-    assert not scores, scores
-    # (Rank 4 or more: [32, 4095, 128] is the cached rope keys placed,
-    # padded to a lane tile and laid batch-major for the kernels.)
-    decompressed = {
-        s for s in shapes
-        if 4095 in s and s[-1] in (128, 192, 256, 320) and len(s) >= 4
-    }
-    assert not decompressed, decompressed
-    # The grouped expert matmuls at the family's three passes: four MoE
-    # layers x (3 forward, 3 rematerialised, 6 backward) x 3; and the
-    # cache leg's kernels: five layers x (forward, rematerialised,
-    # backward).
-    assert compiled.as_text().count("tpu_custom_call") == 144 + 15
-    assert compiled.as_text().count("fused_latent_leg_forward") >= 10
-    assert compiled.as_text().count("fused_latent_leg_backward") >= 5
-
-
-def test_nemotron3_cell_update_compiles_for_v5e(one_chip, monkeypatch):
-    """`nemotron3_policy.learner`'s update as the benchmark builds it
-    (the configuration's own argv and sizes: one period of 11 layers,
-    mixers 0/4, experts 0/64, blocks rematerialised, a [256, B] batch),
-    whole, for a described v5e: it fits beside the driver's copy of the
-    weights (ISSUE 42's rule: under 15.0 GiB with it); the attention
-    layer's scores over 4,351 keys live in `fused_attend`'s VMEM (no
-    f32 array over the keys is in the program); the experts' kernels
-    see one rung at a time of the window of the sorted rows that 8
-    held experts can draw (PR 44: 2,816 rows, twice an even load's),
-    not all tokens x 22 (PR 42) nor the whole window's tokens x 8."""
-    import json
-    import re
-
-    from perfbench import manifest
-    from perfbench.drivers import learner as learner_driver
-    from torchbeast_tpu import monobeast
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with open(os.path.join(
-        manifest.HERE, "configs", "nemotron3_super_policy.json"
-    )) as f:
-        config = json.load(f)
-    steps, rows = config["unroll_length"], config["batch_size"]
-    flags = monobeast.make_parser().parse_args(
-        config["program_argv"]
-        + ["--unroll_length", str(steps), "--batch_size", str(rows)]
-    )
-    hp = monobeast.hparams_from_flags(flags)
-    frame = tuple(config["frame_shape"])
-    model, _ = monobeast._init_model_and_params(
-        flags, NUM_ACTIONS, rows, frame, init_params=False
-    )
-    optimizer = learner_lib.make_optimizer(hp)
-    params = jax.eval_shape(lambda: model.init(
-        {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
-        monobeast.dummy_env_outputs(1, rows, frame, np.uint8),
-        model.initial_state(rows),
-    ))
-    batch, state = jax.eval_shape(lambda: (
-        learner_driver._make_batch(
-            jax.random.PRNGKey(0), steps + 1, rows, NUM_ACTIONS, frame
-        ),
-        model.initial_state(rows),
-    ))
-    compiled = learner_lib.make_update_step(model, optimizer, hp).lower(
-        _on(one_chip, params),
-        _on(one_chip, jax.eval_shape(optimizer.init, params)),
-        _on(one_chip, batch), _on(one_chip, state),
-    ).compile()
-    memory = compiled.memory_analysis()
-    total = (
-        memory.temp_size_in_bytes + memory.argument_size_in_bytes
-        + memory.output_size_in_bytes - memory.alias_size_in_bytes
-    )
-    weights = 4 * sum(
-        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)
-    )
-    assert weights == 4 * 755_035_623
-    assert total + weights < 15.0 * 2**30, memory
-    assert total > 8 * 2**30, memory  # the cell fills the chip
-    text = compiled.as_text()
-    shapes = {
-        tuple(int(d) for d in dims.split(","))
-        for dims in re.findall(r"f32\[([0-9,]+)\]", text)
-    }
-    # (4,096 is the hidden width: keys are 4,095 + 256, or padded.)
-    scores = {
-        s for s in shapes if len(s) >= 3 and s[-1] in (4095, 4351, 4352)
-    }
-    assert not scores, scores
-    assert text.count("fused_attend_forward") >= 2  # and rematerialised
-    assert text.count("fused_attend_backward") >= 1
-    # The sorted rows of all the assignments are never an operand of a
-    # kernel: 22 a token; nor is the window's 8 a token, which is swept
-    # a rung at a time.
-    from torchbeast_tpu.models import moe
-
-    tokens = (steps + 1) * rows
-    rung, window = moe.window_rungs(tokens, 22, 8, 512)
-    assert (rung, window) == (2816, 8 * tokens)
-    assert not {
-        s for s in shapes if s[0] in (22 * tokens, window) and s[-1] == 2688
-    }
-    assert {s for s in shapes if s == (rung, 2688)}
 
 
 def test_nemotron3_dispatch_moves_the_windows_rows_alone_on_v5e(

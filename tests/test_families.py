@@ -1,0 +1,606 @@
+"""What every policy family is held to in the same words, once: a case
+a family of ONE parametrised test each (tests/family_scaffold.py has
+the rule for the next family: one id here, no edit to another family's
+file). What is a family's own is in `tests/test_<family>.py`."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tests import family_scaffold as scaffold
+from torchbeast_tpu import learner as learner_lib
+from torchbeast_tpu import monobeast, polybeast
+from torchbeast_tpu.models import create_model
+from torchbeast_tpu.models import stats as model_stats
+from torchbeast_tpu.models.transformer import Recurrent
+from torchbeast_tpu.ops import attention
+
+A, B, FRAME = scaffold.A, scaffold.B, scaffold.FRAME
+NAMES = list(scaffold.FAMILIES)
+
+
+def _refused(match, **kwargs):
+    return match, kwargs
+
+
+# --- the registry ---------------------------------------------------------
+
+
+def _published_olmoe(model):
+    assert (model.d_model, model.num_heads, model.num_layers) == (2048, 16, 2)
+    assert (model.num_experts, model.experts_per_token) == (64, 8)
+    assert (model.expert_width, model.memory_len) == (1024, 128)
+    # Centred frames are these families'; the d128 transformer keeps
+    # [0, 1].
+    assert create_model("transformer", num_actions=6).frame_range == (0.0, 1.0)
+    k, v, valid = model.initial_state(3)[1]
+    assert k.shape == v.shape == (128, 3, 16, 128)
+    assert valid.shape == (128, 3)
+
+
+def _published_mellum2(model):
+    # The side inputs start at zero in this family (and the later ones).
+    assert model.zero_init_extras
+    assert not create_model("olmoe", num_actions=6).zero_init_extras
+    assert not create_model("transformer", num_actions=6).zero_init_extras
+    assert (model.d_model, model.num_heads, model.kv_heads) == (2304, 32, 4)
+    assert (model.head_dim, model.sliding_window) == (128, 1024)
+    assert (model.num_experts, model.experts_per_token) == (64, 8)
+    assert (model.expert_width, model.memory_len) == (896, 4095)
+    assert model.renormalise and model.rms_norm_eps == 1e-6
+    assert model.held_experts() is None
+    share = create_model(
+        "mellum2", num_actions=6, num_layers=4, expert_share=(3, 4)
+    )
+    assert share.held_experts() == (48, 16)
+
+
+def _published_ouro(model):
+    assert (model.d_model, model.num_heads, model.head_dim) == (2048, 16, 128)
+    assert (model.mlp_width, model.passes, model.memory_len) == (5632, 4, 255)
+    assert (model.rms_norm_eps, model.rope_theta) == (1e-6, 1e6)
+    assert not model.zero_init_extras
+    assert model.matmul_precision == "high"
+    # 4 x 8 caches of [255, B, 16, 128] over 8 blocks, pass-major.
+    assert model.layer_caches() == ((255, 16, 128),) * 32
+    assert model.block_passes() == (tuple(range(8)),) * 4
+    state = jax.eval_shape(lambda: model.initial_state(3))
+    assert len(state) == 32 and state[31][0].shape == (255, 3, 16, 128)
+
+
+def _published_kanana2(model):
+    assert model.zero_init_extras
+    assert (model.d_model, model.num_heads, model.latent_rank) == (
+        2048, 32, 512
+    )
+    assert (
+        model.nope_head_dim, model.rope_head_dim, model.value_head_dim
+    ) == (128, 64, 128)
+    assert (model.dense_layers, model.mlp_width) == (1, 6144)
+    assert (
+        model.num_experts, model.experts_per_token, model.expert_width,
+        model.shared_experts,
+    ) == (128, 6, 768, 2)
+    assert model.renormalise and model.routed_scaling == 2.448
+    assert (model.rms_norm_eps, model.rope_theta) == (1e-6, 1e6)
+    assert (model.memory_len, model.bias_update_rate) == (4095, 0.001)
+    assert model.layer_caches() == ((4095, 1, (512, 64)),) * 5
+    assert model.held_experts() is None
+    share = create_model(
+        "kanana2", num_actions=6, num_layers=5, expert_share=(7, 8)
+    )
+    assert share.held_experts() == (112, 16)
+    # The published heads are not `fused_attend`'s (128 lanes a head):
+    # 192-wide unroll keys, one 576-wide cache key. The cache leg has a
+    # fused pass of its own, which the learner's shapes take at the
+    # family's one bf16 pass and a T=1 act step does not.
+    for q_width, keys in ((192, 81), (576, 4095)):
+        assert not attention.fused_pass_applies(
+            (32, 81, 32, q_width), (32, keys, 1, q_width), None
+        )
+    assert model.cache_leg_precision == "default"
+    for steps, fused in ((81, True), (1, False)):
+        assert attention.fused_latent_leg_applies(
+            (32, steps, 32, 576), 4095, 512, model.cache_leg_precision
+        ) is fused
+
+
+def _published_nemotron3(model):
+    assert model.zero_init_extras
+    assert (model.d_model, model.num_heads, model.kv_heads, model.head_dim) == (
+        4096, 32, 2, 128
+    )
+    assert (
+        model.mamba_heads, model.mamba_head_dim, model.mamba_groups,
+        model.state_size, model.conv_kernel, model.chunk_size,
+    ) == (128, 64, 8, 128, 4, 128)
+    assert model.mamba_heads * model.mamba_head_dim == 2 * model.d_model
+    assert (
+        model.num_experts, model.experts_per_token, model.expert_width,
+        model.latent_width, model.shared_width,
+    ) == (512, 22, 2688, 1024, 5376)
+    assert model.renormalise and model.routed_scaling == 5.0
+    assert (model.rms_norm_eps, model.memory_len) == (1e-5, 4095)
+    assert model.pattern() == "*EMEMEMEMEM"
+    assert model.held_mixers() == (32, 2, 8, 1)
+    assert model.held_experts() == (0, 8)
+    window, nothing, carried = model.layer_caches()[:3]
+    assert window == (4095, 1, 128) and nothing is None
+    assert carried == Recurrent(((32, 64, 128), (3, 2048 + 2 * 2 * 128)))
+    whole = create_model("nemotron3", num_actions=6)
+    assert whole.num_layers == 88 == len(whole.pattern())
+    assert whole.pattern()[25:36] == model.pattern()
+    assert [whole.pattern().count(c) for c in "M*E"] == [40, 8, 40]
+    assert whole.held_mixers() == (128, 8, 32, 2)
+    # Two chips a layer halve the key/value heads; eight hold one each.
+    assert create_model(
+        "nemotron3", num_actions=6, mixer_share=(1, 2)
+    ).held_mixers() == (64, 4, 16, 1)
+    assert create_model(
+        "nemotron3", num_actions=6, mixer_share=(7, 8)
+    ).held_mixers() == (16, 1, 4, 1)
+    # The cell's attention layer (8 query heads of 128 on one key/value
+    # head over 4,095 + 256 keys, 570 MB of f32 scores at B=16) is
+    # `fused_attend`'s; a T=1 act step is not.
+    assert attention.fused_pass_applies(
+        (16, 256, 8, 128), (16, 4351, 1, 128), None
+    )
+    assert not attention.fused_pass_applies(
+        (16, 1, 8, 128), (16, 4096, 1, 128), None
+    )
+
+
+# family: how the cell builds it, the depth of the published model, its
+# own assertions, and what the registry refuses beside `use_lstm`.
+REGISTRY = {
+    "olmoe": (dict(num_layers=2), 16, _published_olmoe, []),
+    "mellum2": (dict(num_layers=4), 28, _published_mellum2, [
+        _refused("whole periods of 4", num_layers=3),
+        *(_refused("expert_share", expert_share=bad)
+          for bad in [(4, 4), (0, 3), (-1, 4)]),
+    ]),
+    "ouro": (dict(num_layers=8), 48, _published_ouro, []),
+    "kanana2": (dict(num_layers=5), 48, _published_kanana2, [
+        _refused("at least one MoE layer", num_layers=1),
+        *(_refused("expert_share", expert_share=bad)
+          for bad in [(8, 8), (0, 3), (-1, 8)]),
+    ]),
+    "nemotron3": (
+        dict(num_layers=11, mixer_share=(0, 4), expert_share=(0, 64)), 88,
+        _published_nemotron3, [
+            _refused("whole periods of 11", num_layers=5),
+            *(_refused("mixer_share", mixer_share=bad)
+              for bad in [(4, 4), (0, 3), (-1, 8), (0, 16)]),
+            _refused("expert_share", expert_share=(0, 7)),
+        ],
+    ),
+}
+
+
+@pytest.mark.parametrize("family", NAMES)
+def test_registry_builds_the_published_widths_and_refuses_lstm(family):
+    kwargs, depth, published, refusals = REGISTRY[family]
+    model = create_model(family, num_actions=6, **kwargs)
+    assert isinstance(model, scaffold.FAMILIES[family].net)
+    assert model.frame_range == (-1.0, 1.0)
+    assert create_model(family, num_actions=6).num_layers == depth
+    published(model)
+    with pytest.raises(ValueError, match="use_lstm"):
+        create_model(family, num_actions=6, use_lstm=True)
+    for match, bad in refusals:
+        with pytest.raises(ValueError, match=match):
+            create_model(family, num_actions=6, **bad)
+
+
+# --- the parsers -----------------------------------------------------------
+
+
+def _flags_olmoe(parse, build):
+    flags = parse(["--model", "olmoe", "--num_layers", "3", "--memory_len", "9"])
+    assert (flags.model, flags.num_layers, flags.memory_len) == ("olmoe", 3, 9)
+    model = build(flags)
+    assert (model.num_layers, model.memory_len, model.d_model) == (3, 9, 64)
+    # The flags are the transformer families' alone.
+    with pytest.raises(ValueError, match="num_layers"):
+        build(parse(["--model", "mlp", "--num_layers", "3"]))
+    model = build(parse(
+        ["--model", "transformer", "--num_layers", "1", "--memory_len", "7"]
+    ))
+    assert (model.num_layers, model.memory_len) == (1, 7)
+    return build(flags), None
+
+
+def _flags_mellum2(parse, build):
+    flags = parse([
+        "--model", "mellum2", "--num_layers", "8", "--memory_len", "9",
+        "--expert_share", "1/4",
+    ])
+    assert (flags.model, flags.num_layers, flags.expert_share) == (
+        "mellum2", 8, "1/4"
+    )
+    model = build(flags)
+    assert (model.num_layers, model.memory_len, model.d_model) == (8, 9, 48)
+    assert model.held_experts() == (2, 2)
+    # --num_layers 3 is refused, and says why.
+    with pytest.raises(ValueError, match="whole periods of 4"):
+        build(parse(["--model", "mellum2", "--num_layers", "3"]))
+    with pytest.raises(ValueError, match="'i/n'"):
+        build(parse(["--model", "mellum2", "--expert_share", "quarter"]))
+    # The share is refused for a family without experts to divide.
+    for other in ("deep", "transformer", "olmoe"):
+        with pytest.raises(
+            ValueError, match="--model mellum2 or kanana2 or nemotron3 only"
+        ):
+            build(parse(["--model", other, "--expert_share", "0/4"]))
+    return model, ["--model", "mellum2", "--num_layers", "4"]
+
+
+def _flags_ouro(parse, build):
+    model = build(parse([
+        "--model", "ouro", "--num_layers", "3", "--memory_len", "9",
+        "--remat", "all",
+    ]))
+    assert (model.num_layers, model.memory_len, model.d_model) == (3, 9, 64)
+    # `passes` has no flag: the (shrunken) table's.
+    assert model.passes == 3 and len(model.layer_caches()) == 9
+    for flag, value in (("--num_experts", "4"), ("--expert_share", "0/4")):
+        with pytest.raises(ValueError):
+            build(parse(["--model", "ouro", flag, value]))
+    return model, ["--model", "ouro", "--num_layers", "3"]
+
+
+def _flags_kanana2(parse, build):
+    flags = parse([
+        "--model", "kanana2", "--num_layers", "3", "--memory_len", "9",
+        "--expert_share", "1/8",
+    ])
+    assert (flags.model, flags.num_layers, flags.expert_share) == (
+        "kanana2", 3, "1/8"
+    )
+    model = build(flags)
+    assert (model.num_layers, model.memory_len, model.d_model) == (3, 9, 48)
+    assert model.held_experts() == (2, 2)
+    with pytest.raises(ValueError, match="at least one MoE layer"):
+        build(parse(["--model", "kanana2", "--num_layers", "1"]))
+    with pytest.raises(ValueError, match="use_lstm"):
+        build(parse(["--model", "kanana2", "--use_lstm"]))
+    return model, ["--model", "kanana2", "--num_layers", "3"]
+
+
+def _flags_nemotron3(parse, build):
+    flags = parse([
+        "--model", "nemotron3", "--num_layers", "3", "--memory_len", "9",
+        "--expert_share", "1/8", "--mixer_share", "1/2",
+    ])
+    assert (flags.model, flags.expert_share, flags.mixer_share) == (
+        "nemotron3", "1/8", "1/2"
+    )
+    model = build(flags)
+    assert (model.num_layers, model.memory_len, model.d_model) == (3, 9, 32)
+    assert model.held_experts() == (2, 2)
+    assert model.held_mixers() == (4, 2, 2, 1)
+    with pytest.raises(ValueError, match="whole periods of 3"):
+        build(parse(["--model", "nemotron3", "--num_layers", "2"]))
+    with pytest.raises(ValueError, match="must be 'i/n'"):
+        build(parse([
+            "--model", "nemotron3", "--num_layers", "3",
+            "--mixer_share", "half",
+        ]))
+    with pytest.raises(ValueError, match="mixer_share .* nemotron3 only"):
+        build(parse(["--model", "kanana2", "--mixer_share", "0/2"]))
+    with pytest.raises(ValueError, match="use_lstm"):
+        build(parse(["--model", "nemotron3", "--use_lstm"]))
+    return model, ["--model", "nemotron3", "--num_layers", "3"]
+
+
+FLAGS = {
+    "olmoe": _flags_olmoe, "mellum2": _flags_mellum2, "ouro": _flags_ouro,
+    "kanana2": _flags_kanana2, "nemotron3": _flags_nemotron3,
+}
+
+
+@pytest.mark.parametrize("family", NAMES)
+@pytest.mark.parametrize("driver", [monobeast, polybeast], ids=["mono", "poly"])
+def test_parsers_take_the_family_and_its_flags(driver, family, monkeypatch):
+    """Both parsers take `--model <family>` and the family's flags, the
+    model built from them is the family's at the (shrunken) table's
+    widths, what the family cannot do is refused with its reason, and
+    `--remat all` reaches the blocks of a family whose class says so."""
+    toy = scaffold.FAMILIES[family]
+    widths = {
+        k: v for k, v in toy.small.items()
+        if k not in ("num_layers", "memory_len")
+    }
+    monkeypatch.setattr(
+        toy.module, "PUBLISHED", dict(toy.module.PUBLISHED, **widths)
+    )
+
+    def build(flags):
+        return monobeast._init_model_and_params(
+            flags, A, B, FRAME, init_params=False
+        )[0]
+
+    parse = driver.make_parser().parse_args
+    model, remat_flags = FLAGS[family](parse, build)
+    assert isinstance(model, toy.net)
+    if remat_flags:
+        assert build(parse(remat_flags + ["--remat", "all"])).remat is True
+
+
+# --- rematerialised blocks ---------------------------------------------------
+
+_ENDS = [(4, 0), (7, 0), (8, 0), (10, 0), (0, 1), (5, 1)]
+# family: the model's overrides, the batch's episode ends, how close the
+# loss and the gradients stay (rtol, atol; an atol of None: 1e-5 (2e-5:
+# nemotron3) of the largest gradient, the family's tolerance against
+# its reference), the stats that are equal and those equal to 1e-6.
+# ouro runs EAGERLY: its perturbed norm scales put keys and logits at
+# 4-5, and two XLA programs (with and without remat) round 2e-5 apart
+# where the same ops one by one agree to its 1e-6.
+REMAT = {
+    "mellum2": (
+        dict(expert_share=(1, 4)), [(1, 1)], 1e-6, (1e-5, 1e-6),
+        ["moe_held_assignments"], [],
+    ),
+    "ouro": ({}, [(1, 1)], 1e-6, (1e-5, 1e-6), [], ["loop_exit_p_last"]),
+    "kanana2": (
+        dict(expert_share=(1, 8)), [(1, 1)], 1e-5, (0, 1e-5),
+        ["moe_held_assignments", "attention_latent_applications"], [],
+    ),
+    "nemotron3": (
+        dict(expert_share=(1, 8), mixer_share=(1, 2)), _ENDS, 1e-5,
+        (0, 2e-5),
+        ["moe_held_assignments", "ssm_applications", "ssm_chunks",
+         "ssm_resets_per_row", "moe_latent_applications"], [],
+    ),
+}
+
+
+@pytest.mark.parametrize("family", list(REMAT))
+def test_rematerialised_blocks_give_the_same_loss_gradients_and_steps(family):
+    """`--remat all`: nn.remat around the family's blocks (one applied
+    `passes` times: ouro) changes no value, no statistic and no sown
+    step of a selection bias."""
+    overrides, ends, loss_rel, (rtol, atol), equal, close = REMAT[family]
+    model, params = scaffold.build(family, **overrides)
+    state = scaffold.warm_state(model, params, seed=5)
+    batch = scaffold.learner_batch(9, ends, t=scaffold.FAMILIES[family].t)
+    jit = family != "ouro"
+    loss, stats, grads = scaffold.loss_and_grads(model, jit)(
+        params, batch, state
+    )
+    loss_r, stats_r, grads_r = scaffold.loss_and_grads(
+        model.clone(remat=True), jit
+    )(params, batch, state)
+    assert float(loss) == pytest.approx(float(loss_r), rel=loss_rel)
+    flat, flat_r = scaffold.flat(grads), scaffold.flat(grads_r)
+    if rtol == 0:
+        atol = atol * float(jnp.max(jnp.abs(flat)))
+    np.testing.assert_allclose(flat, flat_r, rtol=rtol, atol=atol)
+    for name in equal:
+        assert float(stats[name]) == float(stats_r[name])
+    for name in close:
+        assert float(stats[name]) == pytest.approx(
+            float(stats_r[name]), rel=1e-6
+        )
+    jax.tree_util.tree_map(
+        np.testing.assert_array_equal,
+        stats.get(learner_lib.PARAM_STEPS_KEY, {}),
+        stats_r.get(learner_lib.PARAM_STEPS_KEY, {}),
+    )
+
+
+# --- seeded outputs ----------------------------------------------------------
+
+SEEDED = {
+    "olmoe": (
+        dict(
+            num_layers=2, memory_len=5, d_model=32, num_heads=2,
+            num_experts=4, experts_per_token=2, expert_width=16,
+        ),
+        dict(
+            params=23461,
+            logits=[
+                0.9347226619720459, 2.0615499019622803, 0.7423094511032104,
+                1.6697852611541748,
+            ],
+            baseline=-2.277128219604492,
+            leaf_shapes=[[5, 2, 2, 16], [5, 2, 2, 16], [5, 2], [5, 2, 2, 16]],
+            state_sum=1020.148193359375,
+        ),
+    ),
+    "mellum2": (
+        dict(
+            num_layers=4, memory_len=9, d_model=48, num_heads=4, kv_heads=2,
+            head_dim=16, sliding_window=4, num_experts=8,
+            experts_per_token=2, expert_width=24,
+        ),
+        dict(
+            params=153205,
+            logits=[
+                -0.5582820177078247, -0.5578451156616211,
+                -0.16875900328159332, 0.3949725925922394,
+            ],
+            baseline=0.9923625588417053,
+            leaf_shapes=[[3, 2, 2, 16], [3, 2, 2, 16], [3, 2], [3, 2, 2, 16]],
+            state_sum=1876.7550048828125,
+        ),
+    ),
+    "ouro": (
+        dict(
+            num_layers=2, memory_len=5, d_model=32, num_heads=2, head_dim=16,
+            mlp_width=48, passes=3,
+        ),
+        dict(
+            params=20166,
+            logits=[
+                -1.6723791360855103, -1.0398012399673462,
+                -0.014137506484985352, -0.9085246920585632,
+            ],
+            baseline=0.43291524052619934,
+            leaf_shapes=[[5, 2, 2, 16], [5, 2, 2, 16], [5, 2], [5, 2, 2, 16]],
+            state_sum=3152.189697265625,
+        ),
+    ),
+}
+
+
+@pytest.mark.parametrize("family", list(SEEDED))
+def test_seeded_logits_are_what_they_were_before_pr_38(family):
+    """PR 38 let a cache entry's two leaves differ (models/transformer.
+    py `layer_caches`, `initial_state`) and gave `DroplessMoE` a second
+    router: the tree, state and outputs of the families that were there
+    before it, at a seeded tiny size, are the numbers its parent commit
+    gave. The model (4 actions) is initialised from fixed keys, warmed
+    by one unroll and run on a second: its parameter count, the last
+    step's logits of row 0 and baseline of row 1, the first four state
+    leaves' shapes and the sum of the new state's magnitudes."""
+    widths, pinned = SEEDED[family]
+    model = scaffold.FAMILIES[family].net(num_actions=4, **widths)
+    batches = [scaffold.inputs(seed, [(3, 1)]) for seed in range(3)]
+    # ouro EAGERLY, as the numbers were read: as one program its logits
+    # round 3e-6 from them, past the pin's 1e-6.
+    jit = family != "ouro"
+    tree = scaffold.init_params(model, batches[0], jit=jit)
+    assert (
+        sum(x.size for x in jax.tree_util.tree_leaves(tree))
+        == pinned["params"]
+    )
+    _, state = scaffold.forward(model, jit)(
+        tree, batches[1], model.initial_state(2)
+    )
+    out, new_state = scaffold.forward(model, jit)(tree, batches[2], state)
+    np.testing.assert_allclose(
+        out.policy_logits[-1, 0], pinned["logits"], rtol=1e-5, atol=1e-6
+    )
+    np.testing.assert_allclose(
+        out.baseline[-1, 1], pinned["baseline"], rtol=1e-5, atol=1e-6
+    )
+    leaves = jax.tree_util.tree_leaves(new_state)
+    assert [list(x.shape) for x in leaves[:4]] == pinned["leaf_shapes"]
+    np.testing.assert_allclose(
+        sum(float(jnp.sum(jnp.abs(x))) for x in leaves),
+        pinned["state_sum"], rtol=1e-5,
+    )
+
+
+# --- the update's stats and their gauges -------------------------------------
+
+_LOSSES = [
+    "aux_loss", "baseline_loss", "entropy_loss", "episode_count",
+    "episode_returns_sum", "grad_norm", "pg_loss", "total_loss",
+]
+# The keys of the update's stats at PR 44 (the parent of the PR that
+# moved the folds to where a counter is sown), beside `_LOSSES`: every
+# family with a share of its experts (and mixers) held, so that the
+# `held` and `window` counters are there; `deep` sows nothing.
+STATS_AT_PR_44 = {
+    "deep": [],
+    "olmoe": [
+        "attention_two_leg_applications", "moe_assignments",
+        "moe_load_max_over_mean",
+    ],
+    "mellum2": [
+        "moe_assignments", "moe_held_assignments",
+        "moe_held_load_max_over_mean", "moe_load_max_over_mean",
+    ],
+    "ouro": [
+        "attention_two_leg_applications", "loop_block_applications",
+        "loop_cache_bytes_per_row", "loop_exit_p_last",
+        "loop_expected_exit_pass", "loop_passes",
+    ],
+    "kanana2": [
+        "attention_latent_applications",
+        "attention_latent_cache_bytes_per_row", "moe_assignments",
+        "moe_bias_abs_max", "moe_bias_steps", "moe_held_assignments",
+        "moe_held_load_max_over_mean", "moe_load_max_over_mean",
+        "moe_shared_applications", "moe_window_rows",
+        "moe_window_short_applications",
+    ],
+    "nemotron3": [
+        "moe_assignments", "moe_bias_abs_max", "moe_bias_steps",
+        "moe_held_assignments", "moe_held_load_max_over_mean",
+        "moe_latent_applications", "moe_load_max_over_mean",
+        "moe_shared_applications", "moe_window_rows",
+        "moe_window_short_applications", "ssm_applications", "ssm_chunks",
+        "ssm_resets_per_row", "ssm_state_bytes_per_row",
+    ],
+}
+_HELD = {
+    "mellum2": dict(expert_share=(1, 4)),
+    "kanana2": dict(expert_share=(0, 8)),
+    "nemotron3": dict(expert_share=(1, 8), mixer_share=(1, 2)),
+}
+
+
+@pytest.mark.parametrize("family", list(STATS_AT_PR_44))
+def test_update_stats_and_gauges_are_the_parents(family):
+    """The update's stats carry the keys they carried when `learner.py`
+    folded four collections by name, and polybeast's monitor makes the
+    gauges it made from its list of 24: `<family>.<name>` for every
+    counter, none for a loss. The keys alone: nothing is computed."""
+    if family == "deep":
+        model = create_model("deep", num_actions=A, use_lstm=True)
+        batch = scaffold.learner_batch(0, [])
+        batch["frame"] = jnp.zeros((6, B, 84, 84, 4), jnp.uint8)
+        params = jax.eval_shape(
+            model.init,
+            {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
+            batch, model.initial_state(B),
+        )
+    else:
+        model, params = scaffold.build(family, **_HELD.get(family, {}))
+        batch = scaffold.learner_batch(0, [], t=scaffold.FAMILIES[family].t)
+    hp = learner_lib.HParams(
+        batch_size=B, unroll_length=batch["done"].shape[0] - 1
+    )
+    optimizer = learner_lib.make_optimizer(hp)
+    _, _, stats = jax.eval_shape(
+        learner_lib.update_body(model, optimizer, hp),
+        params, jax.eval_shape(optimizer.init, params), batch,
+        model.initial_state(B),
+    )
+    counters = STATS_AT_PR_44[family]
+    assert sorted(stats) == sorted(_LOSSES + counters)
+    gauges = {model_stats.gauge_name(key) for key in stats} - {None}
+    assert sorted(gauges) == sorted(
+        name.replace("_", ".", 1) for name in counters
+    )
+
+
+def test_the_three_folds_on_two_layers_of_each_kind():
+    """Two periods of the toy nemotron3 (`*EM*EM`: two expert layers,
+    two Mamba layers): `moe_assignments` is the two layers' SUM,
+    `moe_load_max_over_mean` the worse layer's (MAX), `ssm_chunks` what
+    both say alike (the SAME: 3, not 6), read against what each layer
+    sowed (eager: the sown leaves are the point)."""
+    model, params = scaffold.build("nemotron3", num_layers=6)
+    t = scaffold.FAMILIES["nemotron3"].t
+    _, sown = model.apply(
+        params, scaffold.inputs(3, _ENDS, t=t), model.initial_state(B),
+        sample_action=False, mutable=model_stats.COLLECTIONS,
+    )
+    assert sorted(sown) == ["stats_max", "stats_same", "stats_sum"]
+
+    def of_layers(layers, fold, name):
+        return [
+            float(sown["stats_" + fold][f"block_{layer}"][name])
+            if inner is None
+            else float(sown["stats_" + fold][f"block_{layer}"][inner][name])
+            for layer, inner in layers
+        ]
+
+    stats = {k: float(v) for k, v in model_stats.folded(sown).items()}
+    experts, mixers = [(1, "moe"), (4, "moe")], [(2, None), (5, None)]
+    rows = of_layers(experts, "sum", "moe_assignments")
+    assert rows == [3.0 * t * B] * 2
+    assert stats["moe_assignments"] == sum(rows)
+    loads = of_layers(experts, "max", "moe_load_max_over_mean")
+    assert loads[0] != loads[1]
+    assert stats["moe_load_max_over_mean"] == max(loads)
+    assert of_layers(mixers, "same", "ssm_chunks") == [3.0, 3.0]
+    assert stats["ssm_chunks"] == 3.0
+    assert stats["ssm_applications"] == sum(
+        of_layers(mixers, "sum", "ssm_applications")
+    ) == 2.0

@@ -11,162 +11,33 @@ import numpy as np
 import pytest
 
 import jax
-import jax.flatten_util
 import jax.numpy as jnp
 
 from perfbench.reference import mellum2_policy as reference
-from tests.seeded_pin import assert_seeded_outputs
-from torchbeast_tpu import learner as learner_lib
-from torchbeast_tpu import monobeast, polybeast
+from tests import family_scaffold as scaffold
+from tests.family_scaffold import YARN_CONFIG
 from torchbeast_tpu.models import Mellum2Net, create_model, mellum2, moe
 from torchbeast_tpu.ops.attention import dense_transformer_attend
 
-T, B, A = 6, 2, 4
-FRAME = (8, 8, 1)
-# A shrunken `PUBLISHED`: 4 query heads on 2 key/value heads of 16, a
-# window of 4 keys (3 slots), 8 experts of 24, top 2. The full layers'
-# cache is `M` slots.
-SMALL = dict(
-    d_model=48, num_heads=4, kv_heads=2, head_dim=16, sliding_window=4,
-    num_experts=8, experts_per_token=2, expert_width=24,
-)
-M = 9
+T, B, A = 6, scaffold.B, scaffold.A
+# The shrunken `PUBLISHED` (tests/family_scaffold.py): the window layers'
+# caches are 3 slots, the full layer's `M`.
+SMALL = scaffold.FAMILIES["mellum2"].small
+M = SMALL["memory_len"]
 # As tests/test_olmoe.py: on the CPU both sides compute in float32 at
 # full precision and differ by the order of their sums.
 RTOL = ATOL = 1e-5
-
-YARN_CONFIG = {
-    "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
-    "original_max_position_embeddings": 8192, "beta_fast": 32,
-    "beta_slow": 1, "attention_factor": 1.2772588722239782,
-}
-
-
-def _inputs(seed, done_steps=(), t=T):
-    rng = np.random.default_rng(seed)
-    done = np.zeros((t, B), bool)
-    for step, row in done_steps:
-        done[step, row] = True
-    return {
-        "frame": jnp.asarray(
-            rng.integers(0, 256, (t, B) + FRAME, dtype=np.uint8)
-        ),
-        "reward": jnp.asarray(rng.standard_normal((t, B)), jnp.float32),
-        "done": jnp.asarray(done),
-        "last_action": jnp.asarray(rng.integers(0, A, (t, B))),
-    }
-
-
-def _learner_batch(seed, done_steps):
-    rng = np.random.default_rng(seed + 100)
-    lead = (T, B)
-    return dict(
-        _inputs(seed, done_steps),
-        episode_return=jnp.asarray(rng.standard_normal(lead), jnp.float32),
-        episode_step=jnp.zeros(lead, jnp.int32),
-        action=jnp.asarray(rng.integers(0, A, lead)),
-        policy_logits=jnp.asarray(
-            rng.standard_normal(lead + (A,)), jnp.float32
-        ),
-        baseline=jnp.asarray(rng.standard_normal(lead), jnp.float32),
-    )
-
-
-def _model(share=(0, 1), seed=0, **overrides):
-    model = Mellum2Net(
-        num_actions=A, num_layers=4, memory_len=M, expert_share=share,
-        **dict(SMALL, **overrides),
-    )
-    params = model.init(
-        {"params": jax.random.PRNGKey(seed), "action": jax.random.PRNGKey(1)},
-        _inputs(0), model.initial_state(B),
-    )
-    # The family starts its side inputs' projection at zero (below):
-    # give it weights, so that the comparisons cover that path too.
-    inner = dict(params["params"])
-    assert not np.any(inner["extras"]["kernel"])
-    inner["extras"] = dict(inner["extras"], kernel=0.3 * jax.random.normal(
-        jax.random.PRNGKey(seed + 7), inner["extras"]["kernel"].shape
-    ))
-    return model, {"params": inner}
-
-
-def _reference_config(share=(0, 1), **overrides):
-    widths = dict(SMALL, **overrides)
-    return {
-        "num_attention_heads": widths["num_heads"],
-        "num_key_value_heads": widths["kv_heads"],
-        "head_dim": widths["head_dim"],
-        "sliding_window": widths["sliding_window"],
-        "layer_types": list(mellum2.PUBLISHED["layer_period"]) * 7,
-        "num_hidden_layers": 4,
-        "published_num_experts": widths["num_experts"],
-        "num_experts": widths["num_experts"] // share[1],
-        "expert_share": list(share),
-        "num_experts_per_tok": widths["experts_per_token"],
-        "norm_topk_prob": True,
-        "rms_norm_eps": 1e-6,
-        "rope_parameters": {
-            "full_attention": YARN_CONFIG,
-            "sliding_attention": {
-                "rope_type": "default", "rope_theta": 500000,
-            },
-        },
-        "memory_len": M, "load_balance_weight": 0.001, "num_actions": A,
-        "discounting": 0.99, "baseline_cost": 0.5, "entropy_cost": 0.0006,
-    }
-
-
-def _warm_state(model, params, seed, unrolls=1):
-    """Caches an actor would hold: `unrolls` unrolls in, an episode end
-    in the first. After one, the 3-slot window caches are full and the
-    9-slot full cache is not; after two, both are."""
-    state = model.initial_state(B)
-    for i in range(unrolls):
-        _, state = model.apply(
-            params, _inputs(seed + i, done_steps=[(2, 1)] if i == 0 else ()),
-            state, sample_action=False,
-        )
-    return state
 
 
 @pytest.mark.parametrize(
     "share", [(0, 1), (1, 4)], ids=["all-8-experts", "share-1-of-4"]
 )
 def test_family_agrees_with_the_reference(share):
-    model, params = _model(share)
-    config = _reference_config(share)
-    state = _warm_state(model, params, seed=5)
-    batch = _learner_batch(7, done_steps=[(3, 0)])
-
-    out, new_state = model.apply(params, batch, state, sample_action=False)
-    logits, baseline, ref_state, _ = reference.forward(
-        params, batch, state, config
-    )
-    np.testing.assert_allclose(out.policy_logits, logits, RTOL, ATOL)
-    np.testing.assert_allclose(out.baseline, baseline, RTOL, ATOL)
-    for got, want in zip(
-        jax.tree_util.tree_leaves(new_state),
-        jax.tree_util.tree_leaves(ref_state),
-    ):
-        assert got.shape == want.shape
-        np.testing.assert_allclose(got, want, RTOL, ATOL)
-
-    hp = learner_lib.HParams(batch_size=B, unroll_length=T - 1)
-    (loss, stats), grads = jax.value_and_grad(
-        lambda p: learner_lib.compute_loss(model, p, batch, state, hp),
-        has_aux=True,
-    )(params)
-    ref_loss, ref_grads = jax.value_and_grad(reference.loss)(
-        params, batch, state, config
-    )
-    scale = float(reference.loss_and_scale(params, batch, state, config)[1])
-    assert abs(float(loss) - float(ref_loss)) <= RTOL * scale
-    flat, ref_flat = (
-        jax.flatten_util.ravel_pytree(g)[0] for g in (grads, ref_grads)
-    )
-    np.testing.assert_allclose(
-        flat, ref_flat, rtol=0, atol=RTOL * float(jnp.max(jnp.abs(ref_flat)))
+    model, params = scaffold.build("mellum2", expert_share=share)
+    state = scaffold.warm_state(model, params, seed=5)
+    batch = scaffold.learner_batch(7, done_steps=[(3, 0)])
+    stats, _, _, _ = scaffold.assert_agrees_with_the_reference(
+        model, params, state, batch, RTOL, ATOL
     )
     # Routing is over all the experts whatever is held.
     assert float(stats["moe_assignments"]) == 2 * T * B * 4
@@ -186,28 +57,18 @@ def test_batch_forward_equals_stepwise_acting_through_both_caches(unrolls):
     """The learner's [T, B] forward and the actor's T=1 forwards through
     the rolling caches (window layers 3 slots, the full layer 9; T=6
     evicts from the first on the way) give the same logits and leave the
-    same caches, from caches of any fill and across an episode end."""
-    model, params = _model()
-    state = _warm_state(model, params, seed=2, unrolls=unrolls)
-    inputs = _inputs(3, done_steps=[(3, 1)])
-    full, full_state = model.apply(params, inputs, state, sample_action=False)
-    logits = []
-    for t in range(T):
-        step = {k: v[t : t + 1] for k, v in inputs.items()}
-        out, state = model.apply(params, step, state, sample_action=False)
-        logits.append(out.policy_logits[0])
-    np.testing.assert_allclose(
-        np.stack(logits), full.policy_logits, rtol=2e-4, atol=2e-5
+    same caches, from caches of any fill (after one unroll the 3-slot
+    window caches are full and the 9-slot full cache is not; after two,
+    both are) and across an episode end."""
+    model, params = scaffold.build("mellum2")
+    state = scaffold.warm_state(model, params, seed=2, unrolls=unrolls)
+    scaffold.assert_stepwise_acting_equals_the_batch_forward(
+        model, params, state, scaffold.inputs(3, done_steps=[(3, 1)])
     )
-    for got, want in zip(
-        jax.tree_util.tree_leaves(state),
-        jax.tree_util.tree_leaves(full_state),
-    ):
-        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
 def test_layers_carry_caches_of_their_kind():
-    model, _ = _model()
+    model, _ = scaffold.build("mellum2")
     assert [model.layer_kind(i) for i in range(4)] == [
         mellum2.SLIDING, mellum2.SLIDING, mellum2.SLIDING, mellum2.FULL,
     ]
@@ -217,7 +78,7 @@ def test_layers_carry_caches_of_their_kind():
     )
     assert [layer[2].shape for layer in state] == [(3, 3)] * 3 + [(M, 3)]
     # A full cache shorter than the window: every layer carries it.
-    short = Mellum2Net(num_actions=A, num_layers=4, memory_len=2, **SMALL)
+    short = Mellum2Net(num_actions=A, **dict(SMALL, memory_len=2))
     assert [m for m, _, _ in short.layer_caches()] == [2, 2, 2, 2]
     published = create_model("mellum2", num_actions=6, num_layers=8)
     assert [m for m, _, _ in published.layer_caches()] == (
@@ -453,100 +314,6 @@ def test_grouped_query_heads_equal_repeated_keys():
         dense_transformer_attend(q, k[:, :, :1].repeat(3, 2), v, mask, offsets, None)
 
 
-def test_registry_builds_the_published_widths_and_refuses_lstm():
-    model = create_model("mellum2", num_actions=6, num_layers=4)
-    # The side inputs start at zero in this family alone.
-    assert model.zero_init_extras
-    assert not create_model("olmoe", num_actions=6).zero_init_extras
-    assert not create_model("transformer", num_actions=6).zero_init_extras
-    assert isinstance(model, Mellum2Net)
-    assert (model.d_model, model.num_heads, model.kv_heads) == (2304, 32, 4)
-    assert (model.head_dim, model.sliding_window) == (128, 1024)
-    assert (model.num_experts, model.experts_per_token) == (64, 8)
-    assert (model.expert_width, model.memory_len) == (896, 4095)
-    assert model.renormalise and model.rms_norm_eps == 1e-6
-    assert model.frame_range == (-1.0, 1.0)
-    assert model.held_experts() is None
-    assert create_model("mellum2", num_actions=6).num_layers == 28
-    share = create_model(
-        "mellum2", num_actions=6, num_layers=4, expert_share=(3, 4)
-    )
-    assert share.held_experts() == (48, 16)
-    with pytest.raises(ValueError, match="use_lstm"):
-        create_model("mellum2", num_actions=6, use_lstm=True)
-    with pytest.raises(ValueError, match="whole periods of 4"):
-        create_model("mellum2", num_actions=6, num_layers=3)
-    for bad in [(4, 4), (0, 3), (-1, 4)]:
-        with pytest.raises(ValueError, match="expert_share"):
-            create_model("mellum2", num_actions=6, expert_share=bad)
-
-
-@pytest.mark.parametrize("driver", [monobeast, polybeast], ids=["mono", "poly"])
-def test_parsers_take_the_family_and_its_flags(driver, monkeypatch):
-    parse = driver.make_parser().parse_args
-    flags = parse([
-        "--model", "mellum2", "--num_layers", "8", "--memory_len", "9",
-        "--expert_share", "1/4",
-    ])
-    assert (flags.model, flags.num_layers, flags.expert_share) == (
-        "mellum2", 8, "1/4"
-    )
-    monkeypatch.setattr(mellum2, "PUBLISHED", dict(mellum2.PUBLISHED, **SMALL))
-    model, _ = monobeast._init_model_and_params(
-        flags, A, B, FRAME, init_params=False
-    )
-    assert isinstance(model, Mellum2Net)
-    assert (model.num_layers, model.memory_len, model.d_model) == (8, 9, 48)
-    assert model.held_experts() == (2, 2)
-    # --num_layers 3 is refused, and says why.
-    with pytest.raises(ValueError, match="whole periods of 4"):
-        monobeast._init_model_and_params(
-            parse(["--model", "mellum2", "--num_layers", "3"]),
-            A, B, FRAME, init_params=False,
-        )
-    with pytest.raises(ValueError, match="'i/n'"):
-        monobeast._init_model_and_params(
-            parse(["--model", "mellum2", "--expert_share", "quarter"]),
-            A, B, FRAME, init_params=False,
-        )
-    # The share is refused for a family without experts to divide.
-    for family in ("deep", "transformer", "olmoe"):
-        with pytest.raises(ValueError, match="--model mellum2 or kanana2 or nemotron3 only"):
-            monobeast._init_model_and_params(
-                parse(["--model", family, "--expert_share", "0/4"]),
-                A, B, FRAME, init_params=False,
-            )
-    # --remat reaches the family's blocks.
-    model, _ = monobeast._init_model_and_params(
-        parse(["--model", "mellum2", "--num_layers", "4", "--remat", "all"]),
-        A, B, FRAME, init_params=False,
-    )
-    assert model.remat is True
-
-
-def test_rematerialised_blocks_give_the_same_loss_and_gradients():
-    model, params = _model((1, 4))
-    remat = model.clone(remat=True)
-    state = _warm_state(model, params, seed=5)
-    batch = _learner_batch(9, done_steps=[(1, 1)])
-    hp = learner_lib.HParams(batch_size=B, unroll_length=T - 1)
-
-    def run(net):
-        (loss, stats), grads = jax.value_and_grad(
-            lambda p: learner_lib.compute_loss(net, p, batch, state, hp),
-            has_aux=True,
-        )(params)
-        return loss, stats, jax.flatten_util.ravel_pytree(grads)[0]
-
-    loss, stats, grads = run(model)
-    loss_r, stats_r, grads_r = run(remat)
-    assert float(loss) == pytest.approx(float(loss_r), rel=1e-6)
-    np.testing.assert_allclose(grads, grads_r, rtol=1e-5, atol=1e-6)
-    assert float(stats["moe_held_assignments"]) == float(
-        stats_r["moe_held_assignments"]
-    )
-
-
 def test_blocks_over_the_threshold_take_the_fused_pass_and_are_counted(
     monkeypatch
 ):
@@ -560,21 +327,21 @@ def test_blocks_over_the_threshold_take_the_fused_pass_and_are_counted(
     from torchbeast_tpu.ops import attention
 
     # Heads of 128: the fused pass reads a head as a block of lanes.
-    model, params = _model((1, 4), head_dim=128)
+    model, params = scaffold.build(
+        "mellum2", expert_share=(1, 4), head_dim=128
+    )
     model = model.clone(remat=True)
-    state = _warm_state(model, params, seed=5)
-    batch = _learner_batch(9, done_steps=[(1, 1)])
-    hp = learner_lib.HParams(batch_size=B, unroll_length=T - 1)
+    state = scaffold.warm_state(model, params, seed=5)
+    batch = scaffold.learner_batch(9, done_steps=[(1, 1)])
 
     def run():
-        # Jitted, and a function of its own each time: traces are
-        # cached by the function traced, the rule is read at the trace.
-        loss_and_grads = jax.jit(jax.value_and_grad(
-            lambda p: learner_lib.compute_loss(model, p, batch, state, hp),
-            has_aux=True,
-        ))
-        (loss, stats), grads = loss_and_grads(params)
-        return loss, stats, jax.flatten_util.ravel_pytree(grads)[0]
+        # A trace of its own each time (`__wrapped__`: not the
+        # scaffold's memoised one): traces are cached by the function
+        # traced, the rule is read at the trace.
+        loss, stats, grads = scaffold.loss_and_grads.__wrapped__(model)(
+            params, batch, state
+        )
+        return loss, stats, scaffold.flat(grads)
 
     loss, stats, grads = run()
     assert "attention_fused_applications" not in stats
@@ -584,27 +351,4 @@ def test_blocks_over_the_threshold_take_the_fused_pass_and_are_counted(
     assert float(loss_f) == pytest.approx(float(loss), rel=1e-6)
     np.testing.assert_allclose(
         grads_f, grads, rtol=0, atol=RTOL * float(jnp.max(jnp.abs(grads)))
-    )
-
-
-def test_seeded_logits_are_what_they_were_before_pr_38():
-    """PR 38 let a cache entry's two leaves differ (models/transformer.
-    py `layer_caches`, `initial_state`) and gave `DroplessMoE` a second
-    router: this family's tree, state and outputs at a seeded tiny size
-    are the numbers the parent commit gave (tests/seeded_pin.py, run on
-    both trees)."""
-    assert_seeded_outputs(
-        Mellum2Net(
-            num_actions=4, num_layers=4, memory_len=9, d_model=48,
-            num_heads=4, kv_heads=2, head_dim=16, sliding_window=4,
-            num_experts=8, experts_per_token=2, expert_width=24,
-        ),
-        params=153205,
-        logits=[
-            -0.5582820177078247, -0.5578451156616211, -0.16875900328159332,
-            0.3949725925922394,
-        ],
-        baseline=0.9923625588417053,
-        leaf_shapes=[[3, 2, 2, 16], [3, 2, 2, 16], [3, 2], [3, 2, 2, 16]],
-        state_sum=1876.7550048828125,
     )

@@ -16,7 +16,7 @@ import pytest
 from jax.sharding import Mesh
 
 from torchbeast_tpu import learner as learner_lib
-from torchbeast_tpu.models import create_model
+from torchbeast_tpu.models import create_model, stats
 from torchbeast_tpu.models.moe import MoEFFN
 from torchbeast_tpu.parallel.ep import (
     expert_param_shardings,
@@ -309,13 +309,15 @@ def test_router_fields_at_their_defaults_are_the_old_layer(fields):
     params = layer.init(jax.random.PRNGKey(0), x)
     assert sorted(params["params"]) == ["router", "w_down", "w_gate", "w_up"]
     apply = jax.jit(lambda v, x: layer.apply(
-        v, x, mutable=["losses", "moe_stats", "param_steps"]
+        v, x, mutable=("losses", "param_steps") + stats.COLLECTIONS
     ))
     old_layer = jax.jit(lambda p, x: _old_dropless_layer(p, x, 2))
     y, sown = apply(params, x)
     np.testing.assert_array_equal(y, old_layer(params["params"], x))
-    assert sorted(sown) == ["losses", "moe_stats"]
-    assert sorted(sown["moe_stats"]) == ["assignments", "load_max_over_mean"]
+    assert sorted(sown) == ["losses", "stats_max", "stats_sum"]
+    assert sorted(stats.folded(sown)) == [
+        "moe_assignments", "moe_load_max_over_mean",
+    ]
     assert float(sown["losses"]["moe_load_balance"]) > 0
 
 
@@ -337,9 +339,11 @@ def test_sigmoid_router_by_hand(renormalise):
     params = layer.init(jax.random.PRNGKey(0), x)
     p = params["params"]
     assert p["shared_gate"]["kernel"].shape == (D, 6)
-    y, sown = layer.apply(params, x, mutable=["losses", "moe_stats"])
+    y, sown = layer.apply(
+        params, x, mutable=("losses",) + stats.COLLECTIONS
+    )
     assert "losses" not in sown
-    assert float(sown["moe_stats"]["shared_applications"]) == 1.0
+    assert float(stats.folded(sown)["moe_shared_applications"]) == 1.0
     scores = np.asarray(jax.nn.sigmoid(x @ p["router"]["kernel"]))
     want = np.zeros_like(np.asarray(y))
     for t in range(12):
@@ -386,21 +390,25 @@ def test_dropless_layer_with_its_experts_held(held):
     )
     assert shapes == jax.tree_util.tree_map(jnp.shape, mine)
     apply = jax.jit(
-        lambda v, x: layer.apply(v, x, mutable=["losses", "moe_stats"])
+        lambda v, x: layer.apply(
+            v, x, mutable=("losses",) + stats.COLLECTIONS
+        )
     )
     as_it_was = jax.jit(lambda p, x: _old_dropless_layer(p, x, 2))
     (y, sown), old = apply(mine, x), as_it_was(p, x)
     y_whole, sown_whole = whole.apply(
-        params, x, mutable=["losses", "moe_stats"]
+        params, x, mutable=("losses",) + stats.COLLECTIONS
     )
     assert float(sown["losses"]["moe_load_balance"]) == float(
         sown_whole["losses"]["moe_load_balance"]
     )
-    assert float(sown["moe_stats"]["assignments"]) == 48.0
+    assert float(stats.folded(sown)["moe_assignments"]) == 48.0
     if count == E:
         np.testing.assert_array_equal(y, old)
         np.testing.assert_array_equal(y, y_whole)
-        assert ("held_assignments" in sown["moe_stats"]) == (held is not None)
+        assert ("moe_held_assignments" in stats.folded(sown)) == (
+            held is not None
+        )
     else:
         other = DroplessMoE(
             d_ff=FF, num_experts=E, top_k=2, held=(2 - first, 2)
@@ -412,7 +420,7 @@ def test_dropless_layer_with_its_experts_held(held):
         np.testing.assert_allclose(
             y + other.apply(theirs, x), old, rtol=1e-5, atol=1e-6
         )
-        held_rows = float(sown["moe_stats"]["held_assignments"])
+        held_rows = float(stats.folded(sown)["moe_held_assignments"])
         assert 0 < held_rows < 48
     with pytest.raises(ValueError, match="not a range"):
         DroplessMoE(d_ff=FF, num_experts=E, top_k=2, held=(3, 2)).init(
@@ -462,8 +470,10 @@ def test_ungated_relu2_experts_in_a_latent_by_hand():
         "w_up": (E, 5, FF), "w_down": (E, FF, 5),
         "shared_up": {"kernel": (D, 6)}, "shared_down": {"kernel": (6, D)},
     }
-    y, sown = layer.apply(params, x, mutable=["losses", "moe_stats"])
-    assert float(sown["moe_stats"]["latent_applications"]) == 1.0
+    y, sown = layer.apply(
+        params, x, mutable=("losses",) + stats.COLLECTIONS
+    )
+    assert float(stats.folded(sown)["moe_latent_applications"]) == 1.0
     np.testing.assert_allclose(
         y, _latent_by_hand(x, p, 2, 5.0), rtol=1e-5, atol=1e-6
     )
@@ -523,11 +533,11 @@ def test_fewer_experts_held_than_chosen_see_a_window_of_the_rows(
         params = {"params": dict(
             params["params"], router={"kernel": kernel}
         )}
-    y, sown = layer.apply(params, x, mutable=["moe_stats"])
+    y, sown = layer.apply(params, x, mutable=stats.COLLECTIONS)
     want = _latent_by_hand(x, params["params"], top_k, 5.0, held=(first, 2))
     np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-6)
-    assert float(sown["moe_stats"]["assignments"]) == tokens * top_k
-    held_rows = float(sown["moe_stats"]["held_assignments"])
+    assert float(stats.folded(sown)["moe_assignments"]) == tokens * top_k
+    held_rows = float(stats.folded(sown)["moe_held_assignments"])
     assert 0 < held_rows <= tokens * 2
     _, chosen = jax.lax.top_k(
         jax.nn.sigmoid(x @ params["params"]["router"]["kernel"]), top_k

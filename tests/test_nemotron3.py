@@ -12,157 +12,23 @@ import numpy as np
 import pytest
 
 import jax
-import jax.flatten_util
 import jax.numpy as jnp
 
 from perfbench.reference import nemotron3_policy as reference
+from tests import family_scaffold as scaffold
 from torchbeast_tpu import learner as learner_lib
-from torchbeast_tpu import monobeast, polybeast
-from torchbeast_tpu.models import Nemotron3Net, create_model, moe, nemotron3
+from torchbeast_tpu.models import Nemotron3Net, moe, nemotron3
 from torchbeast_tpu.models.transformer import Recurrent
-from torchbeast_tpu.ops import attention
-from torchbeast_tpu.runtime.state_table import DeviceStateTable
 
-T, B, A = 11, 2, 4
-FRAME = (8, 8, 1)
-# A shrunken `PUBLISHED`: one attention layer of 4 query heads of 8 on 2
-# key/value heads, one latent MoE layer (16 experts of 10 in a latent of
-# 12, top 3, a shared expert of 20), one Mamba-2 layer of 8 heads of 4
-# in 4 groups over a state of 6, scanned in chunks of 4 steps: the 11
-# steps of an unroll are two whole chunks and one padded.
-SMALL = dict(
-    d_model=32, num_heads=4, kv_heads=2, head_dim=8, mamba_heads=8,
-    mamba_head_dim=4, mamba_groups=4, state_size=6, chunk_size=4,
-    num_experts=16, experts_per_token=3, expert_width=10, latent_width=12,
-    shared_width=20, layer_period="*EM", layer_pattern="MEM*EMM",
-)
-LAYERS = 3
-M = 5
+T, B, A = scaffold.FAMILIES["nemotron3"].t, scaffold.B, scaffold.A
+# The shrunken `PUBLISHED` (tests/family_scaffold.py): one attention
+# layer, one latent MoE layer, one Mamba-2 layer scanned in chunks of 4
+# steps: the 11 steps of an unroll are two whole chunks and one padded.
+SMALL = scaffold.FAMILIES["nemotron3"].small
+M = SMALL["memory_len"]
 # On the CPU both sides compute in float32 at full precision and differ
 # by the order of their sums.
 RTOL = ATOL = 2e-5
-
-
-def _inputs(seed, done_steps=(), t=T, rows=B):
-    rng = np.random.default_rng(seed)
-    done = np.zeros((t, rows), bool)
-    for step, row in done_steps:
-        done[step, row] = True
-    return {
-        "frame": jnp.asarray(
-            rng.integers(0, 256, (t, rows) + FRAME, dtype=np.uint8)
-        ),
-        "reward": jnp.asarray(rng.standard_normal((t, rows)), jnp.float32),
-        "done": jnp.asarray(done),
-        "last_action": jnp.asarray(rng.integers(0, A, (t, rows))),
-    }
-
-
-def _learner_batch(seed, done_steps):
-    rng = np.random.default_rng(seed + 100)
-    lead = (T, B)
-    return dict(
-        _inputs(seed, done_steps),
-        episode_return=jnp.asarray(rng.standard_normal(lead), jnp.float32),
-        episode_step=jnp.zeros(lead, jnp.int32),
-        action=jnp.asarray(rng.integers(0, A, lead)),
-        policy_logits=jnp.asarray(
-            rng.standard_normal(lead + (A,)), jnp.float32
-        ),
-        baseline=jnp.asarray(rng.standard_normal(lead), jnp.float32),
-    )
-
-
-def _model(expert_share=(0, 1), mixer_share=(0, 1), seed=0, **overrides):
-    model = Nemotron3Net(
-        num_actions=A, memory_len=M,
-        expert_share=expert_share, mixer_share=mixer_share,
-        **{**SMALL, "num_layers": LAYERS, **overrides},
-    )
-    params = model.init(
-        {"params": jax.random.PRNGKey(seed), "action": jax.random.PRNGKey(1)},
-        _inputs(0), model.initial_state(B),
-    )
-    # The family starts its side inputs' projection and its selection
-    # biases at zero, and D and the norms at one: give them values, so
-    # that the comparisons cover those paths too.
-    inner = dict(params["params"])
-    assert not np.any(inner["extras"]["kernel"])
-    inner["extras"] = dict(inner["extras"], kernel=0.3 * jax.random.normal(
-        jax.random.PRNGKey(seed + 7), inner["extras"]["kernel"].shape
-    ))
-    for layer, letter in enumerate(model.pattern()):
-        block = dict(inner[f"block_{layer}"])
-        if letter == "E":
-            assert not np.any(block["moe"]["e_score_correction_bias"])
-            block["moe"] = dict(
-                block["moe"],
-                e_score_correction_bias=0.1 * jax.random.normal(
-                    jax.random.PRNGKey(seed + layer),
-                    (SMALL["num_experts"],),
-                ),
-            )
-        elif letter == "M":
-            for i, name in enumerate(("D", "gate_norm")):
-                block[name] = block[name] + 0.3 * jax.random.normal(
-                    jax.random.PRNGKey(seed + 10 * layer + i),
-                    block[name].shape,
-                )
-        inner[f"block_{layer}"] = block
-    return model, {"params": inner}
-
-
-def _reference_config(model):
-    heads, groups, query_heads, kv_heads = model.held_mixers()
-    held = model.held_experts()
-    return {
-        "hybrid_override_pattern": model.pattern(),
-        "num_hidden_layers": model.num_layers,
-        "mamba_num_heads": heads, "mamba_head_dim": model.mamba_head_dim,
-        "n_groups": groups, "ssm_state_size": model.state_size,
-        "conv_kernel": model.conv_kernel, "use_conv_bias": True,
-        "mamba_proj_bias": False, "mamba_hidden_act": "silu",
-        "num_attention_heads": query_heads,
-        "num_key_value_heads": kv_heads, "head_dim": model.head_dim,
-        "attention_bias": False,
-        "published_n_routed_experts": model.num_experts,
-        "n_routed_experts": held[1] if held else model.num_experts,
-        "expert_share": list(model.expert_share),
-        "mixer_share": list(model.mixer_share),
-        "num_experts_per_tok": model.experts_per_token,
-        "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
-        "routed_scaling_factor": 5.0, "n_shared_experts": 1,
-        "mlp_hidden_act": "relu2", "mlp_bias": False,
-        "bias_update_rate": 0.001, "layer_norm_epsilon": 1e-5,
-        "memory_len": M, "num_actions": A,
-        "discounting": 0.99, "baseline_cost": 0.5, "entropy_cost": 0.0006,
-    }
-
-
-def _warm_state(model, params, seed, unrolls=1, rows=B):
-    """What an actor would hold `unrolls` unrolls of 11 steps in, an
-    episode end in the first: Mamba states and conv tails that are not
-    zeros, an attention cache that is full."""
-    state = model.initial_state(rows)
-    apply = jax.jit(lambda x, s: model.apply(
-        params, x, s, sample_action=False
-    )[1])
-    for i in range(unrolls):
-        state = apply(
-            _inputs(seed + i, [(2, 1)] if i == 0 else (), rows=rows), state
-        )
-    return state
-
-
-def _loss_and_grads(model, params, batch, state):
-    hp = learner_lib.HParams(batch_size=B, unroll_length=T - 1)
-    jitted = jax.jit(jax.value_and_grad(
-        lambda p: learner_lib.compute_loss(model, p, batch, state, hp),
-        has_aux=True,
-    ))
-    (loss, stats), grads = jitted(params)
-    return loss, stats, grads
-
 
 # Episode ends at a chunk's first step (4), at its last (7), and twice in
 # one chunk (8 and 10), in one row; the other row ends one on step 0,
@@ -176,43 +42,15 @@ ENDS = [(4, 0), (7, 0), (8, 0), (10, 0), (0, 1), (5, 1)]
     ids=["everything-held", "experts-1-of-8-mixers-3-of-4"],
 )
 def test_family_agrees_with_the_reference(expert_share, mixer_share):
-    model, params = _model(expert_share, mixer_share)
-    config = _reference_config(model)
-    state = _warm_state(model, params, seed=5)
+    model, params = scaffold.build(
+        "nemotron3", expert_share=expert_share, mixer_share=mixer_share
+    )
+    state = scaffold.warm_state(model, params, seed=5)
     assert all(np.any(leaf) for leaf in jax.tree_util.tree_leaves(state))
-    batch = _learner_batch(7, done_steps=ENDS[:4] + [(5, 1)])
-
-    jitted = jax.jit(lambda p, b, s: model.apply(
-        p, b, s, sample_action=False
-    ))
-    out, new_state = jitted(params, batch, state)
-    jitted = jax.jit(
-        lambda p, b, s: reference.forward(p, b, s, config)
-    )
-    logits, baseline, ref_state, _ = jitted(params, batch, state)
-    np.testing.assert_allclose(out.policy_logits, logits, RTOL, ATOL)
-    np.testing.assert_allclose(out.baseline, baseline, RTOL, ATOL)
-    leaves, ref_leaves = (
-        jax.tree_util.tree_leaves(s) for s in (new_state, ref_state)
-    )
-    assert len(leaves) == len(ref_leaves) == 5
-    for got, want in zip(leaves, ref_leaves):
-        assert got.shape == want.shape
-        np.testing.assert_allclose(got, want, RTOL, ATOL)
-
-    loss, stats, grads = _loss_and_grads(model, params, batch, state)
-    jitted = jax.jit(jax.value_and_grad(
-        lambda p: reference.loss_and_scale(p, batch, state, config),
-        has_aux=True,
-    ))
-    (ref_loss, scale), ref_grads = jitted(params)
-    scale = float(scale)
-    assert abs(float(loss) - float(ref_loss)) <= RTOL * scale
-    flat, ref_flat = (
-        jax.flatten_util.ravel_pytree(g)[0] for g in (grads, ref_grads)
-    )
-    np.testing.assert_allclose(
-        flat, ref_flat, rtol=0, atol=RTOL * float(jnp.max(jnp.abs(ref_flat)))
+    assert len(jax.tree_util.tree_leaves(state)) == 5
+    batch = scaffold.learner_batch(7, ENDS[:4] + [(5, 1)], t=T)
+    stats, grads, ref_grads, _ = scaffold.assert_agrees_with_the_reference(
+        model, params, state, batch, RTOL, ATOL
     )
     # Every parameter of the Mamba layer takes a gradient.
     for name, leaf in grads["params"]["block_2"].items():
@@ -224,10 +62,7 @@ def test_family_agrees_with_the_reference(expert_share, mixer_share):
         assert not np.any(
             tree["params"]["block_1"]["moe"]["e_score_correction_bias"]
         )
-    jitted = jax.jit(
-        lambda p: reference.bias_steps(p, batch, state, config)
-    )
-    (want,) = jitted(params)
+    (want,) = scaffold.reference_bias_steps(model)(params, batch, state)
     got = stats[learner_lib.PARAM_STEPS_KEY]["block_1"]["moe"][
         "e_score_correction_bias"
     ]
@@ -267,10 +102,10 @@ def test_update_stats_say_how_far_the_window_was_swept(to_held, sweeps):
     imply: one rung as initialised, none with the held experts at
     every token's bottom, three (`moe_window_short_applications` 0)
     with both at every token's top."""
-    from torchbeast_tpu.models import moe
-
     rows = 32
-    model, params = _model((1, 16), num_experts=32)
+    model, params = scaffold.build(
+        "nemotron3", expert_share=(1, 16), num_experts=32
+    )
     assert moe.window_rungs(T * rows, 3, 2, 32) == (256, 2 * T * rows)
     bias = 0.1 * jax.random.normal(jax.random.PRNGKey(1), (32,))
     if to_held is not None:
@@ -281,9 +116,10 @@ def test_update_stats_say_how_far_the_window_was_swept(to_held, sweeps):
     params = {"params": dict(inner, block_1=block)}
     batch = {
         k: jnp.concatenate([v] * (rows // B), axis=1)
-        for k, v in _learner_batch(3, done_steps=ENDS).items()
+        for k, v in scaffold.learner_batch(3, ENDS, t=T).items()
     }
     hp = learner_lib.HParams(batch_size=rows, unroll_length=T - 1)
+    # The forward alone: 352 tokens through the interpreted kernels.
     jitted = jax.jit(lambda p: learner_lib.compute_loss(
         model, p, batch, model.initial_state(rows), hp
     ))
@@ -380,26 +216,11 @@ def test_batch_forward_equals_stepwise_acting_through_the_carried_states(
     unroll]) and the actor's T=1 forwards through the Mamba state, the
     conv tail and the rolling cache give the same logits and leave the
     same states, across episode ends inside a chunk."""
-    model, params = _model()
-    state = _warm_state(model, params, seed=2, unrolls=unrolls)
-    inputs = _inputs(3, done_steps=ENDS)
-    apply = jax.jit(lambda x, s: model.apply(
-        params, x, s, sample_action=False
-    ))
-    full, full_state = apply(inputs, state)
-    logits = []
-    for t in range(T):
-        step = {k: v[t : t + 1] for k, v in inputs.items()}
-        out, state = apply(step, state)
-        logits.append(out.policy_logits[0])
-    np.testing.assert_allclose(
-        np.stack(logits), full.policy_logits, rtol=2e-4, atol=2e-5
+    model, params = scaffold.build("nemotron3")
+    state = scaffold.warm_state(model, params, seed=2, unrolls=unrolls)
+    scaffold.assert_stepwise_acting_equals_the_batch_forward(
+        model, params, state, scaffold.inputs(3, ENDS, t=T)
     )
-    for got, want in zip(
-        jax.tree_util.tree_leaves(state),
-        jax.tree_util.tree_leaves(full_state),
-    ):
-        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
 @pytest.mark.parametrize("via", ["reset", "rebuild"])
@@ -413,48 +234,15 @@ def test_stepwise_acting_through_the_state_table_equals_the_batch_forward(
     order every step and episodes end on the way; every step's logits
     equal the batch forward's and the table ends with what that forward
     leaves; reset and rebuild bring back zeros of every shape."""
-    model, params = _model()
-    rows, steps = 3, 6
-    inputs = _inputs(4, done_steps=[(3, 2), (4, 2), (1, 0)], t=steps, rows=rows)
-    jitted = jax.jit(lambda x, s: model.apply(
-        params, x, s, sample_action=False
-    ))
-    full, full_state = jitted(inputs, model.initial_state(rows))
-
-    def act(ctx, env_outputs, agent_state):
-        out, new_state = model.apply(
-            params, env_outputs, agent_state, sample_action=False
-        )
-        return {"logits": out.policy_logits}, new_state
-
-    table = DeviceStateTable(
-        model.initial_state(1), num_slots=rows, act_fn=act, batch_dim=1
-    )
-    orders = [[0, 1, 2], [2, 0, 1], [1, 2, 0], [2, 1, 0], [0, 2, 1], [1, 0, 2]]
-    for t, order in enumerate(orders):
-        step = {
-            k: np.asarray(v[t : t + 1])[:, order] for k, v in inputs.items()
-        }
-        out = table.step(
-            np.asarray(order, np.int32), np.ones(rows, bool), step
-        )
-        np.testing.assert_allclose(
-            table.fetch(out, rows)["logits"][0],
-            np.asarray(full.policy_logits)[t][order],
-            rtol=2e-4, atol=2e-5,
-        )
+    model, params = scaffold.build("nemotron3")
     shapes = [
         [(M, 1, 2, 8), (M, 1, 2, 8), (M, 1)], [(8, 1, 4, 6), (3, 1, 80)],
     ]
-    for slot in range(rows):
-        held = table.read_slot(slot)
-        assert [[np.shape(leaf) for leaf in item] for item in held] == shapes
-        for item, want_item in zip(held, full_state):
-            for got, want in zip(item, want_item):
-                np.testing.assert_allclose(
-                    got, np.asarray(want)[:, slot : slot + 1],
-                    rtol=2e-4, atol=2e-5,
-                )
+    table = scaffold.assert_state_table_acting_equals_the_batch_forward(
+        model, params,
+        scaffold.inputs(4, [(3, 2), (4, 2), (1, 0)], t=6, rows=3),
+        shapes=shapes,
+    )
     if via == "reset":
         table.reset([1])
         assert all(
@@ -526,13 +314,12 @@ def test_the_four_mixer_shares_add_up_to_the_uncut_mixers(side):
     gives its part of `out_proj` / `o`; the four parts add up to the
     uncut layer's. Warm states, episode ends inside a chunk; on the
     program's blocks and on the reference's functions."""
-    model, params = _model()
-    state = _warm_state(model, params, seed=3)
-    inputs = _inputs(6, done_steps=ENDS)
-    done = inputs["done"]
+    model, params = scaffold.build("nemotron3")
+    state = scaffold.warm_state(model, params, seed=3)
+    done = scaffold.inputs(6, ENDS, t=T)["done"]
     x = jax.random.normal(jax.random.PRNGKey(2), (B, T, 32))
     p = params["params"]
-    config = _reference_config(model)
+    config = scaffold.reference_config(model)
     window, carried = state
     valid = window[2]
     allowed = reference._may_attend(done, valid, M)
@@ -692,7 +479,7 @@ def test_the_gates_sum_to_five_and_the_experts_are_relu_squared():
 
 
 def test_layers_follow_the_pattern_and_the_state_holds_what_they_carry():
-    model, params = _model()
+    model, params = scaffold.build("nemotron3")
     assert model.pattern() == "*EM"
     assert model.layer_caches() == (
         (M, 2, 8), None, Recurrent(((8, 4, 6), (3, 8 * 4 + 2 * 4 * 6))),
@@ -725,7 +512,7 @@ def test_layers_follow_the_pattern_and_the_state_holds_what_they_carry():
         num_actions=A, **dict(SMALL, num_layers=6)
     ).pattern() == "*EM*EM"
     whole = Nemotron3Net(
-        num_actions=A, memory_len=M, **dict(SMALL, num_layers=7)
+        num_actions=A, **dict(SMALL, num_layers=7)
     )
     assert whole.pattern() == "MEM*EMM"
     assert [type(entry) for entry in whole.layer_caches()] == [
@@ -737,143 +524,9 @@ def test_layers_follow_the_pattern_and_the_state_holds_what_they_carry():
         Nemotron3Net(num_actions=A, **dict(SMALL, num_layers=4))
 
 
-def test_registry_builds_the_published_widths_and_refuses_lstm():
-    model = create_model(
-        "nemotron3", num_actions=6, num_layers=11, mixer_share=(0, 4),
-        expert_share=(0, 64),
-    )
-    assert isinstance(model, Nemotron3Net)
-    assert model.zero_init_extras and model.frame_range == (-1.0, 1.0)
-    assert (model.d_model, model.num_heads, model.kv_heads, model.head_dim) == (
-        4096, 32, 2, 128
-    )
-    assert (
-        model.mamba_heads, model.mamba_head_dim, model.mamba_groups,
-        model.state_size, model.conv_kernel, model.chunk_size,
-    ) == (128, 64, 8, 128, 4, 128)
-    assert model.mamba_heads * model.mamba_head_dim == 2 * model.d_model
-    assert (
-        model.num_experts, model.experts_per_token, model.expert_width,
-        model.latent_width, model.shared_width,
-    ) == (512, 22, 2688, 1024, 5376)
-    assert model.renormalise and model.routed_scaling == 5.0
-    assert (model.rms_norm_eps, model.memory_len) == (1e-5, 4095)
-    assert model.pattern() == "*EMEMEMEMEM"
-    assert model.held_mixers() == (32, 2, 8, 1)
-    assert model.held_experts() == (0, 8)
-    window, nothing, carried = model.layer_caches()[:3]
-    assert window == (4095, 1, 128) and nothing is None
-    assert carried == Recurrent(((32, 64, 128), (3, 2048 + 2 * 2 * 128)))
-    whole = create_model("nemotron3", num_actions=6)
-    assert whole.num_layers == 88 == len(whole.pattern())
-    assert whole.pattern()[25:36] == model.pattern()
-    assert [whole.pattern().count(c) for c in "M*E"] == [40, 8, 40]
-    assert whole.held_mixers() == (128, 8, 32, 2)
-    # Two chips a layer halve the key/value heads; eight hold one each.
-    assert create_model(
-        "nemotron3", num_actions=6, mixer_share=(1, 2)
-    ).held_mixers() == (64, 4, 16, 1)
-    assert create_model(
-        "nemotron3", num_actions=6, mixer_share=(7, 8)
-    ).held_mixers() == (16, 1, 4, 1)
-    with pytest.raises(ValueError, match="use_lstm"):
-        create_model("nemotron3", num_actions=6, use_lstm=True)
-    with pytest.raises(ValueError, match="whole periods of 11"):
-        create_model("nemotron3", num_actions=6, num_layers=5)
-    for bad in [(4, 4), (0, 3), (-1, 8), (0, 16)]:
-        with pytest.raises(ValueError, match="mixer_share"):
-            create_model("nemotron3", num_actions=6, mixer_share=bad)
-    with pytest.raises(ValueError, match="expert_share"):
-        create_model("nemotron3", num_actions=6, expert_share=(0, 7))
-    # The cell's attention layer (8 query heads of 128 on one key/value
-    # head over 4,095 + 256 keys, 570 MB of f32 scores at B=16) is
-    # `fused_attend`'s; a T=1 act step is not.
-    assert attention.fused_pass_applies(
-        (16, 256, 8, 128), (16, 4351, 1, 128), None
-    )
-    assert not attention.fused_pass_applies(
-        (16, 1, 8, 128), (16, 4096, 1, 128), None
-    )
-
-
-@pytest.mark.parametrize("driver", [monobeast, polybeast], ids=["mono", "poly"])
-def test_parsers_take_the_family_and_its_flags(driver, monkeypatch):
-    parse = driver.make_parser().parse_args
-    flags = parse([
-        "--model", "nemotron3", "--num_layers", "3", "--memory_len", "9",
-        "--expert_share", "1/8", "--mixer_share", "1/2",
-    ])
-    assert (flags.model, flags.expert_share, flags.mixer_share) == (
-        "nemotron3", "1/8", "1/2"
-    )
-    monkeypatch.setattr(
-        nemotron3, "PUBLISHED", dict(nemotron3.PUBLISHED, **SMALL)
-    )
-    model, _ = monobeast._init_model_and_params(
-        flags, A, B, FRAME, init_params=False
-    )
-    assert isinstance(model, Nemotron3Net)
-    assert (model.num_layers, model.memory_len, model.d_model) == (3, 9, 32)
-    assert model.held_experts() == (2, 2)
-    assert model.held_mixers() == (4, 2, 2, 1)
-    with pytest.raises(ValueError, match="whole periods of 3"):
-        monobeast._init_model_and_params(
-            parse(["--model", "nemotron3", "--num_layers", "2"]),
-            A, B, FRAME, init_params=False,
-        )
-    with pytest.raises(ValueError, match="must be 'i/n'"):
-        monobeast._init_model_and_params(
-            parse(["--model", "nemotron3", "--num_layers", "3",
-                   "--mixer_share", "half"]),
-            A, B, FRAME, init_params=False,
-        )
-    with pytest.raises(ValueError, match="mixer_share .* nemotron3 only"):
-        monobeast._init_model_and_params(
-            parse(["--model", "kanana2", "--mixer_share", "0/2"]),
-            A, B, FRAME, init_params=False,
-        )
-    with pytest.raises(ValueError, match="use_lstm"):
-        monobeast._init_model_and_params(
-            parse(["--model", "nemotron3", "--use_lstm"]),
-            A, B, FRAME, init_params=False,
-        )
-    # --remat reaches the family's blocks.
-    model, _ = monobeast._init_model_and_params(
-        parse(["--model", "nemotron3", "--num_layers", "3", "--remat", "all"]),
-        A, B, FRAME, init_params=False,
-    )
-    assert model.remat is True
-
-
-def test_rematerialised_blocks_give_the_same_loss_gradients_and_steps():
-    model, params = _model((1, 8), (1, 2))
-    remat = model.clone(remat=True)
-    state = _warm_state(model, params, seed=5)
-    batch = _learner_batch(9, done_steps=ENDS)
-    loss, stats, grads = _loss_and_grads(model, params, batch, state)
-    loss_r, stats_r, grads_r = _loss_and_grads(remat, params, batch, state)
-    assert float(loss) == pytest.approx(float(loss_r), rel=1e-5)
-    flat, flat_r = (
-        jax.flatten_util.ravel_pytree(g)[0] for g in (grads, grads_r)
-    )
-    np.testing.assert_allclose(
-        flat, flat_r, rtol=0, atol=RTOL * float(jnp.max(jnp.abs(flat)))
-    )
-    for name in (
-        "moe_held_assignments", "ssm_applications", "ssm_chunks",
-        "ssm_resets_per_row", "moe_latent_applications",
-    ):
-        assert float(stats[name]) == float(stats_r[name])
-    np.testing.assert_array_equal(
-        *(s[learner_lib.PARAM_STEPS_KEY]["block_1"]["moe"][
-            "e_score_correction_bias"
-        ] for s in (stats, stats_r))
-    )
-
-
 def test_the_new_scopes_are_in_the_lowered_update():
-    model, params = _model()
-    batch = _learner_batch(1, done_steps=ENDS)
+    model, params = scaffold.build("nemotron3")
+    batch = scaffold.learner_batch(1, ENDS, t=T)
     hp = learner_lib.HParams(batch_size=B, unroll_length=T - 1)
     text = jax.jit(jax.grad(
         lambda p: learner_lib.compute_loss(

@@ -10,22 +10,15 @@ import numpy as np
 import pytest
 
 import jax
-import jax.flatten_util
 import jax.numpy as jnp
 
 from perfbench.reference import olmoe_policy as reference
-from tests.seeded_pin import assert_seeded_outputs
-from torchbeast_tpu import learner as learner_lib
-from torchbeast_tpu import monobeast, polybeast
-from torchbeast_tpu.models import OLMoENet, create_model, moe, olmoe
+from tests import family_scaffold as scaffold
+from torchbeast_tpu.models import OLMoENet, moe, olmoe
+from torchbeast_tpu.models import stats as model_stats
 
-T, B, A, M = 6, 2, 4, 4
-FRAME = (8, 8, 1)
-SMALL = dict(
-    d_model=64, num_heads=4, num_layers=2, num_experts=8,
-    experts_per_token=2, expert_width=32,
-)
-WIDE_ROUTER = dict(SMALL, num_experts=64, experts_per_token=8)
+T, B = 6, scaffold.B
+WIDE_ROUTER = dict(num_experts=64, experts_per_token=8)
 # On the CPU the program and the reference both compute in float32 at
 # full precision, so they differ only by the order of their sums: the
 # program adds each token's experts as sorted rows, the reference adds
@@ -34,112 +27,25 @@ WIDE_ROUTER = dict(SMALL, num_experts=64, experts_per_token=8)
 RTOL = ATOL = 1e-5
 
 
-def _inputs(seed, done_steps=(), t=T):
-    rng = np.random.default_rng(seed)
-    done = np.zeros((t, B), bool)
-    for step, row in done_steps:
-        done[step, row] = True
-    return {
-        "frame": jnp.asarray(
-            rng.integers(0, 256, (t, B) + FRAME, dtype=np.uint8)
-        ),
-        "reward": jnp.asarray(rng.standard_normal((t, B)), jnp.float32),
-        "done": jnp.asarray(done),
-        "last_action": jnp.asarray(rng.integers(0, A, (t, B))),
-    }
-
-
-def _learner_batch(seed, done_steps):
-    rng = np.random.default_rng(seed + 100)
-    lead = (T, B)
-    return dict(
-        _inputs(seed, done_steps),
-        episode_return=jnp.asarray(rng.standard_normal(lead), jnp.float32),
-        episode_step=jnp.zeros(lead, jnp.int32),
-        action=jnp.asarray(rng.integers(0, A, lead)),
-        policy_logits=jnp.asarray(
-            rng.standard_normal(lead + (A,)), jnp.float32
-        ),
-        baseline=jnp.asarray(rng.standard_normal(lead), jnp.float32),
-    )
-
-
-def _model(widths, seed=0):
-    model = OLMoENet(num_actions=A, memory_len=M, **widths)
-    params = model.init(
-        {"params": jax.random.PRNGKey(seed), "action": jax.random.PRNGKey(1)},
-        _inputs(0), model.initial_state(B),
-    )
-    return model, params
-
-
-def _reference_config(widths):
-    return {
-        "num_attention_heads": widths["num_heads"],
-        "num_experts": widths["num_experts"],
-        "num_experts_per_tok": widths["experts_per_token"],
-        "num_hidden_layers": widths["num_layers"],
-        "rms_norm_eps": 1e-5, "rope_theta": 10000, "memory_len": M,
-        "load_balance_weight": 0.01, "num_actions": A,
-        "discounting": 0.99, "baseline_cost": 0.5, "entropy_cost": 0.0006,
-    }
-
-
-def _warm_state(model, params, seed):
-    """A cache an actor would hold: one unroll in, an episode end in it."""
-    _, state = model.apply(
-        params, _inputs(seed, done_steps=[(2, 1)]), model.initial_state(B),
-        sample_action=False,
-    )
-    return state
-
-
 @pytest.mark.parametrize(
-    "widths", [SMALL, WIDE_ROUTER], ids=["8-experts-top-2", "64-top-8"]
+    "widths", [{}, WIDE_ROUTER], ids=["8-experts-top-2", "64-top-8"]
 )
 def test_family_agrees_with_the_reference(widths):
-    model, params = _model(widths)
-    config = _reference_config(widths)
-    state = _warm_state(model, params, seed=5)
-    batch = _learner_batch(7, done_steps=[(3, 0)])
-
-    out, new_state = model.apply(params, batch, state, sample_action=False)
-    logits, baseline, ref_state, _ = reference.forward(
-        params, batch, state, config
-    )
-    np.testing.assert_allclose(out.policy_logits, logits, RTOL, ATOL)
-    np.testing.assert_allclose(out.baseline, baseline, RTOL, ATOL)
-    for got, want in zip(
-        jax.tree_util.tree_leaves(new_state),
-        jax.tree_util.tree_leaves(ref_state),
-    ):
-        np.testing.assert_allclose(got, want, RTOL, ATOL)
-
-    hp = learner_lib.HParams(batch_size=B, unroll_length=T - 1)
-    (loss, stats), grads = jax.value_and_grad(
-        lambda p: learner_lib.compute_loss(model, p, batch, state, hp),
-        has_aux=True,
-    )(params)
-    ref_loss, ref_grads = jax.value_and_grad(reference.loss)(
-        params, batch, state, config
-    )
-    scale = float(reference.loss_and_scale(params, batch, state, config)[1])
-    assert abs(float(loss) - float(ref_loss)) <= RTOL * scale
-    flat, ref_flat = (
-        jax.flatten_util.ravel_pytree(g)[0] for g in (grads, ref_grads)
-    )
-    np.testing.assert_allclose(
-        flat, ref_flat, rtol=0, atol=RTOL * float(jnp.max(jnp.abs(ref_flat)))
+    model, params = scaffold.build("olmoe", **widths)
+    state = scaffold.warm_state(model, params, seed=5)
+    batch = scaffold.learner_batch(7, done_steps=[(3, 0)])
+    stats, _, _, _ = scaffold.assert_agrees_with_the_reference(
+        model, params, state, batch, RTOL, ATOL
     )
     tokens = T * B
     assert float(stats["moe_assignments"]) == (
-        widths["experts_per_token"] * tokens * widths["num_layers"]
+        model.experts_per_token * tokens * model.num_layers
     )
     assert float(stats["moe_load_max_over_mean"]) >= 1.0
     assert float(stats["aux_loss"]) > 0
     # Every block counts its own two-leg application: summed by name.
     assert float(stats["attention_two_leg_applications"]) == (
-        widths["num_layers"]
+        model.num_layers
     )
     assert "attention_fused_applications" not in stats
 
@@ -149,23 +55,11 @@ def test_batch_forward_equals_stepwise_acting_across_an_episode_end():
     the rolling cache (M=4 < T: slots are evicted on the way) give the
     same logits and leave the same cache, with an episode ending
     mid-unroll in one row: RoPE sees time differences alone."""
-    model, params = _model(SMALL)
-    state = _warm_state(model, params, seed=2)
-    inputs = _inputs(3, done_steps=[(3, 1)])
-    full, full_state = model.apply(params, inputs, state, sample_action=False)
-    logits = []
-    for t in range(T):
-        step = {k: v[t : t + 1] for k, v in inputs.items()}
-        out, state = model.apply(params, step, state, sample_action=False)
-        logits.append(out.policy_logits[0])
-    np.testing.assert_allclose(
-        np.stack(logits), full.policy_logits, rtol=2e-4, atol=2e-5
+    model, params = scaffold.build("olmoe")
+    state = scaffold.warm_state(model, params, seed=2)
+    scaffold.assert_stepwise_acting_equals_the_batch_forward(
+        model, params, state, scaffold.inputs(3, done_steps=[(3, 1)])
     )
-    for got, want in zip(
-        jax.tree_util.tree_leaves(state),
-        jax.tree_util.tree_leaves(full_state),
-    ):
-        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
 def _layer(num_experts=8, top_k=2, d=16, width=8, tokens=12, seed=0):
@@ -246,10 +140,12 @@ def test_a_router_forced_onto_one_expert_drops_nothing():
     params["params"]["router"]["kernel"] = forced.at[0].set(bias_row)
     assert math.ceil(K * tokens / E * 1.25) < tokens
 
-    y, sown = layer.apply(params, x, mutable=["losses", "moe_stats"])
-    stats = sown["moe_stats"]
-    assert float(stats["assignments"]) == K * tokens
-    assert float(stats["load_max_over_mean"]) == pytest.approx(E / K)
+    y, sown = layer.apply(
+        params, x, mutable=("losses",) + model_stats.COLLECTIONS
+    )
+    stats = model_stats.folded(sown)
+    assert float(stats["moe_assignments"]) == K * tokens
+    assert float(stats["moe_load_max_over_mean"]) == pytest.approx(E / K)
     p = params["params"]
     gate, idx = jax.lax.top_k(jax.nn.softmax(x @ p["router"]["kernel"]), K)
     assert set(np.asarray(idx).ravel()) == {3, 5}
@@ -286,48 +182,6 @@ def test_dropless_dispatch_equals_the_every_expert_masked_sum(tokens):
     )
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
-
-
-def test_registry_builds_the_published_widths_and_refuses_lstm():
-    model = create_model("olmoe", num_actions=6, num_layers=2)
-    assert isinstance(model, OLMoENet)
-    assert (model.d_model, model.num_heads, model.num_layers) == (2048, 16, 2)
-    assert (model.num_experts, model.experts_per_token) == (64, 8)
-    assert (model.expert_width, model.memory_len) == (1024, 128)
-    # Centred frames are this family's; the d128 transformer keeps [0, 1].
-    assert model.frame_range == (-1.0, 1.0)
-    assert create_model("transformer", num_actions=6).frame_range == (0.0, 1.0)
-    assert create_model("olmoe", num_actions=6).num_layers == 16
-    k, v, valid = model.initial_state(3)[1]
-    assert k.shape == v.shape == (128, 3, 16, 128)
-    assert valid.shape == (128, 3)
-    with pytest.raises(ValueError, match="use_lstm"):
-        create_model("olmoe", num_actions=6, use_lstm=True)
-
-
-@pytest.mark.parametrize("driver", [monobeast, polybeast], ids=["mono", "poly"])
-def test_parsers_take_the_family_and_its_two_flags(driver, monkeypatch):
-    flags = driver.make_parser().parse_args(
-        ["--model", "olmoe", "--num_layers", "3", "--memory_len", "9"]
-    )
-    assert (flags.model, flags.num_layers, flags.memory_len) == ("olmoe", 3, 9)
-    monkeypatch.setattr(olmoe, "PUBLISHED", dict(olmoe.PUBLISHED, **SMALL))
-    model, _ = monobeast._init_model_and_params(
-        flags, A, B, FRAME, init_params=False
-    )
-    assert isinstance(model, OLMoENet)
-    assert (model.num_layers, model.memory_len, model.d_model) == (3, 9, 64)
-    # The flags are the transformer families' alone.
-    flags = driver.make_parser().parse_args(["--model", "mlp", "--num_layers", "3"])
-    with pytest.raises(ValueError, match="num_layers"):
-        monobeast._init_model_and_params(flags, A, B, FRAME, init_params=False)
-    flags = driver.make_parser().parse_args(
-        ["--model", "transformer", "--num_layers", "1", "--memory_len", "7"]
-    )
-    model, _ = monobeast._init_model_and_params(
-        flags, A, B, FRAME, init_params=False
-    )
-    assert (model.num_layers, model.memory_len) == (1, 7)
 
 
 def _tree_shapes(tree):
@@ -397,10 +251,10 @@ def test_tree_state_and_output_are_what_they_were_before_layer_caches(family):
     assert [tuple(x.shape for x in layer) for layer in state] == [
         ((5, rows, 2, 16), (5, rows, 2, 16), (5, rows))
     ] * 2
-    params = model.init(
-        {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
-        inputs, state,
-    )
+    # The d128 transformer EAGERLY, as its numbers were read: as one
+    # program its first logit rounds 4e-5 from the pin's 2e-6.
+    jit = family == "olmoe"
+    params = scaffold.init_params(model, inputs, jit=jit)
     want = dict(_SCAFFOLDING, **last)
     for layer in range(2):
         want.update({f"block_{layer}/{k}": v for k, v in block.items()})
@@ -408,35 +262,12 @@ def test_tree_state_and_output_are_what_they_were_before_layer_caches(family):
     frames = np.random.default_rng(0).integers(
         0, 256, (steps, rows, 8, 8, 1), dtype=np.uint8
     )
-    out, new_state = model.apply(
-        params, dict(inputs, frame=jnp.asarray(frames)), state,
-        sample_action=False,
+    out, new_state = scaffold.forward(model, jit)(
+        params, dict(inputs, frame=jnp.asarray(frames)), state
     )
     np.testing.assert_allclose(
         np.asarray(out.policy_logits).ravel()[:3], pinned, rtol=2e-6
     )
     assert jax.tree_util.tree_map(jnp.shape, new_state) == (
         jax.tree_util.tree_map(jnp.shape, state)
-    )
-
-
-def test_seeded_logits_are_what_they_were_before_pr_38():
-    """PR 38 let a cache entry's two leaves differ (models/transformer.
-    py `layer_caches`, `initial_state`) and gave `DroplessMoE` a second
-    router: this family's tree, state and outputs at a seeded tiny size
-    are the numbers the parent commit gave (tests/seeded_pin.py, run on
-    both trees)."""
-    assert_seeded_outputs(
-        OLMoENet(
-            num_actions=4, num_layers=2, memory_len=5, d_model=32,
-            num_heads=2, num_experts=4, experts_per_token=2, expert_width=16,
-        ),
-        params=23461,
-        logits=[
-            0.9347226619720459, 2.0615499019622803, 0.7423094511032104,
-            1.6697852611541748,
-        ],
-        baseline=-2.277128219604492,
-        leaf_shapes=[[5, 2, 2, 16], [5, 2, 2, 16], [5, 2], [5, 2, 2, 16]],
-        state_sum=1020.148193359375,
     )

@@ -38,80 +38,80 @@ def test_polybeast_train_smoke(tmp_path):
     assert (tmp_path / "poly-smoke" / "logs.csv").exists()
 
 
-def test_polybeast_train_ouro(tmp_path, monkeypatch):
-    """`--model ouro` through the async driver, the family's table
-    shrunk (2 layers run 3 times): the state table's slots hold the
-    3 x 2 caches, an act step runs the three passes, the learner's
-    updates report the loop's counters."""
-    from torchbeast_tpu.models import ouro
-
-    monkeypatch.setattr(ouro, "PUBLISHED", dict(
-        ouro.PUBLISHED, d_model=32, num_heads=4, head_dim=8, mlp_width=48,
-        passes=3,
-    ))
-    flags = make_flags(
-        tmp_path, xpid="poly-ouro", model="ouro", num_layers=2,
-        memory_len=6, remat="all",
-    )
-    stats = polybeast.train(flags)
-    assert stats["step"] >= 60
-    assert np.isfinite(stats["total_loss"])
+def _ouro_trained(stats):
     assert stats["loop_passes"] == 3
     assert stats["loop_block_applications"] == 6
     assert 1.0 <= stats["loop_expected_exit_pass"] <= 3.0
-    assert (tmp_path / "poly-ouro" / "model.ckpt").exists()
 
 
-def test_polybeast_train_kanana2(tmp_path, monkeypatch):
-    """`--model kanana2` through the async driver, the family's table
-    shrunk: the state table's slots hold the latent caches (entries of
-    two unequal leaves), the learner's updates move the selection
-    biases and report it."""
-    from torchbeast_tpu.models import kanana2
-
-    monkeypatch.setattr(kanana2, "PUBLISHED", dict(
-        kanana2.PUBLISHED, d_model=32, num_heads=4, latent_rank=16,
-        nope_head_dim=8, rope_head_dim=4, value_head_dim=8, mlp_width=48,
-        num_experts=8, experts_per_token=2, expert_width=16,
-    ))
-    flags = make_flags(
-        tmp_path, xpid="poly-kanana2", model="kanana2", num_layers=2,
-        memory_len=6, remat="all",
-    )
-    stats = polybeast.train(flags)
-    assert stats["step"] >= 60
-    assert np.isfinite(stats["total_loss"])
+def _kanana2_trained(stats):
     assert stats["attention_latent_applications"] == 2
     assert stats["moe_bias_steps"] == 1
     assert stats["moe_bias_abs_max"] >= 0.001 - 1e-9
-    assert (tmp_path / "poly-kanana2" / "model.ckpt").exists()
 
 
-def test_polybeast_train_nemotron3(tmp_path, monkeypatch):
-    """`--model nemotron3` through the async driver, the family's table
-    shrunk: the state table's slots hold both kinds of state (the
-    attention layer's window, the Mamba layer's state and conv tail;
-    the MoE layer has none), the learner's updates scan in chunks and
-    report it."""
-    from torchbeast_tpu.models import nemotron3
-
-    monkeypatch.setattr(nemotron3, "PUBLISHED", dict(
-        nemotron3.PUBLISHED, d_model=32, num_heads=4, kv_heads=2, head_dim=8,
-        mamba_heads=8, mamba_head_dim=4, mamba_groups=4, state_size=6,
-        chunk_size=4, num_experts=8, experts_per_token=3, expert_width=10,
-        latent_width=12, shared_width=20, layer_period="*EM",
-    ))
-    flags = make_flags(
-        tmp_path, xpid="poly-nemotron3", model="nemotron3", num_layers=3,
-        memory_len=6, remat="all",
-    )
-    stats = polybeast.train(flags)
-    assert stats["step"] >= 60
-    assert np.isfinite(stats["total_loss"])
+def _nemotron3_trained(stats):
     assert stats["ssm_applications"] == 1
     assert stats["moe_latent_applications"] == 1
     assert stats["moe_bias_steps"] == 1
-    assert (tmp_path / "poly-nemotron3" / "model.ckpt").exists()
+
+
+# A family a row (a `model_config` PR adds one: tests/family_scaffold.py):
+# what its `PUBLISHED` table is shrunk to, its depth, and what the last
+# update's stats must say.
+#  ouro: 2 layers run 3 times; the state table's slots hold the 3 x 2
+#   caches, an act step runs the three passes.
+#  kanana2: the slots hold the latent caches (entries of two unequal
+#   leaves), the learner's updates move the selection biases.
+#  nemotron3: the slots hold both kinds of state (the attention layer's
+#   window, the Mamba layer's state and conv tail; the MoE layer has
+#   none), the learner's updates scan in chunks.
+FAMILIES = {
+    "ouro": (
+        dict(d_model=32, num_heads=4, head_dim=8, mlp_width=48, passes=3),
+        2, _ouro_trained,
+    ),
+    "kanana2": (
+        dict(
+            d_model=32, num_heads=4, latent_rank=16, nope_head_dim=8,
+            rope_head_dim=4, value_head_dim=8, mlp_width=48, num_experts=8,
+            experts_per_token=2, expert_width=16,
+        ),
+        2, _kanana2_trained,
+    ),
+    "nemotron3": (
+        dict(
+            d_model=32, num_heads=4, kv_heads=2, head_dim=8, mamba_heads=8,
+            mamba_head_dim=4, mamba_groups=4, state_size=6, chunk_size=4,
+            num_experts=8, experts_per_token=3, expert_width=10,
+            latent_width=12, shared_width=20, layer_period="*EM",
+        ),
+        3, _nemotron3_trained,
+    ),
+}
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_polybeast_train_family(tmp_path, monkeypatch, family):
+    """`--model <family>` through the async driver, the family's table
+    shrunk: the state table's slots hold what the family carries, the
+    blocks are rematerialised, the learner's updates report the
+    family's counters."""
+    import importlib
+
+    widths, layers, check = FAMILIES[family]
+    module = importlib.import_module(f"torchbeast_tpu.models.{family}")
+    monkeypatch.setattr(
+        module, "PUBLISHED", dict(module.PUBLISHED, **widths)
+    )
+    stats = polybeast.train(make_flags(
+        tmp_path, xpid=f"poly-{family}", model=family, num_layers=layers,
+        memory_len=6, remat="all",
+    ))
+    assert stats["step"] >= 60
+    assert np.isfinite(stats["total_loss"])
+    check(stats)
+    assert (tmp_path / f"poly-{family}" / "model.ckpt").exists()
 
 
 @pytest.mark.slow

@@ -54,6 +54,9 @@ SKIP_DIRS = {
     ".eggs",
     ".pytest_cache",
     "node_modules",
+    # A builder's copies of the tree for chip comparisons (.gitignore):
+    # never linted as the tree.
+    ".scratch",
 }
 
 _DISABLE_RE = re.compile(

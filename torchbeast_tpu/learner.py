@@ -26,7 +26,7 @@ import optax
 
 from torchbeast_tpu import precision as precision_lib
 from torchbeast_tpu import telemetry
-
+from torchbeast_tpu.models import stats as model_stats
 from torchbeast_tpu.ops import (
     compute_entropy_loss,
     impact_policy_losses,
@@ -533,28 +533,12 @@ def compute_loss(
         batch,
         initial_agent_state,
         sample_action=False,
-        mutable=[
-            "losses", "moe_stats", "loop_stats", "attention_stats",
-            "ssm_stats", PARAM_STEPS_KEY,
-        ],
+        mutable=("losses", PARAM_STEPS_KEY) + model_stats.COLLECTIONS,
     )
     aux_loss = sum(
         jnp.sum(leaf)
         for leaf in jax.tree_util.tree_leaves(variables.get("losses", {}))
     )
-    moe_stats = _moe_stats(variables.get("moe_stats", {}))
-    loop_stats = _loop_stats(variables.get("loop_stats", {}))
-    # Block applications traced through the two-leg attention or the
-    # fused pass (models/transformer.py `count_two_leg_application`,
-    # `count_fused_application`), each module's count under its own
-    # scope: summed by name; no key for a model that has none.
-    attention_stats = {}
-    for path, count in flax.traverse_util.flatten_dict(
-        variables.get("attention_stats", {})
-    ).items():
-        name = "attention_" + path[-1]
-        attention_stats[name] = attention_stats.get(name, 0.0) + count
-
     bootstrap_value = learner_outputs.baseline[-1]
 
     # Shift: env/behavior fields drop slot 0, learner outputs drop slot T
@@ -634,10 +618,10 @@ def compute_loss(
         "aux_loss": jnp.asarray(aux_loss, jnp.float32),
         "episode_returns_sum": episode_returns_sum,
         "episode_count": episode_count,
-        **moe_stats,
-        **loop_stats,
-        **attention_stats,
-        **_ssm_stats(variables.get("ssm_stats", {})),
+        # What the model's layers sowed of themselves, each name
+        # folded over the layers by the rule it was sown with
+        # (models/stats.py); nothing for a model that sows none.
+        **model_stats.folded(variables),
     }
     # What a model says its parameters move by beside their gradient
     # (models/moe.py: a router's selection bias, by the batch's load),
@@ -646,90 +630,6 @@ def compute_loss(
     if variables.get(PARAM_STEPS_KEY):
         stats[PARAM_STEPS_KEY] = variables[PARAM_STEPS_KEY]
     return total_loss, stats
-
-
-def _moe_stats(sown) -> Dict[str, Any]:
-    """What a dropless expert layer says its router did (models/moe.py
-    DroplessMoE), over the layers: every assignment computed (8 x tokens
-    x layers for OLMoE: nothing dropped) and the fullest expert's rows
-    over the mean, worst layer; for layers that hold a share of their
-    experts (mellum2, kanana2), the same two over the experts held; for
-    layers with a selection bias, its largest magnitude, and the shared
-    experts applied (kanana2); for layers whose experts live in a
-    latent, the layers that project into one, and for layers that hold
-    fewer experts than a token chooses, the rows of the window of
-    sorted rows their kernels swept and the layers for which one short
-    rung of it was enough (nemotron3). Empty for every other model."""
-    by_name = _sown_leaves_by_name(sown)
-    stats = {}
-    for name in (
-        "assignments", "held_assignments", "shared_applications",
-        "latent_applications", "window_rows", "window_short_applications",
-    ):
-        if name in by_name:
-            stats["moe_" + name] = sum(by_name[name])
-    for name in (
-        "load_max_over_mean", "held_load_max_over_mean", "bias_abs_max",
-    ):
-        if name in by_name:
-            stats["moe_" + name] = jnp.max(jnp.stack(by_name[name]))
-    return stats
-
-
-def _ssm_stats(sown) -> Dict[str, Any]:
-    """What the layers that carry a recurrent state say (models/
-    nemotron3.py `_MambaBlock`): how many there are and the bytes of
-    state a row of the batch carries through them, summed; the chunks
-    the unroll's scan was cut into and the episode ends a row had in
-    the unroll, which every layer says alike. Empty for every other
-    model."""
-    by_name = _sown_leaves_by_name(sown)
-    stats = {}
-    for name in ("applications", "state_bytes_per_row"):
-        if name in by_name:
-            stats["ssm_" + name] = sum(by_name[name])
-    for name in ("chunks", "resets_per_row"):
-        if name in by_name:
-            stats["ssm_" + name] = by_name[name][0]
-    return stats
-
-
-def _sown_leaves_by_name(sown) -> Dict[str, list]:
-    """A sown collection's leaves, every layer's under the leaf's own
-    name, in the layers' order."""
-    by_name: Dict[str, list] = {}
-    for path, leaf in flax.traverse_util.flatten_dict(sown).items():
-        by_name.setdefault(path[-1], []).append(leaf)
-    return by_name
-
-
-def _sown_by_name(prefix: str, sown) -> Dict[str, Any]:
-    """A sown collection's leaves under `prefix` + their own names."""
-    return {
-        prefix + path[-1]: leaf
-        for path, leaf in flax.traverse_util.flatten_dict(sown).items()
-    }
-
-
-def _loop_stats(sown) -> Dict[str, Any]:
-    """What a looped trunk says of its passes (models/ouro.py): its
-    constants as they are (`passes`, `block_applications`, `cache_bytes_
-    per_row`), and from the passes' exit gates lambda_u the distribution
-    over exits p_u = lambda_u prod_{j<u} (1 - lambda_j), the last pass
-    taking the rest: the pass a token would leave after, counted from 1
-    and averaged over the batch, and the mass left to the last pass.
-    Empty for every other model."""
-    stats = _sown_by_name("loop_", sown)
-    if stats:
-        gates = jnp.stack(stats.pop("loop_exit_gates"))  # [passes, ...]
-        # Not leaving at pass u, for every pass but the last; their
-        # running product is still being in after it.
-        stays = 1.0 - gates[:-1]
-        stats["loop_expected_exit_pass"] = 1.0 + jnp.mean(
-            jnp.sum(jnp.cumprod(stays, axis=0), axis=0)
-        )
-        stats["loop_exit_p_last"] = jnp.mean(jnp.prod(stays, axis=0))
-    return stats
 
 
 def add_param_steps(params, steps, opt_state=None):

@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 
 from torchbeast_tpu.models.moe import DroplessMoE, held_experts
+from torchbeast_tpu.models.stats import sow_stat
 from torchbeast_tpu.models.transformer import (
     TransformerNet,
     count_latent_application,
@@ -358,15 +359,12 @@ class Kanana2Net(TransformerNet):
     @nn.nowrap
     def make_final_norm(self):
         norm = nn.RMSNorm(epsilon=self.rms_norm_eps, name="final_norm")
-        if not self.is_initializing():
-            # The latent, the rope key and the validity column of every
-            # cache, float32: what a row of the batch carries.
-            self.sow(
-                "attention_stats", "latent_cache_bytes_per_row",
-                jnp.float32(
-                    4 * self.num_layers * self.memory_len
-                    * (self.latent_rank + self.rope_head_dim + 1)
-                ),
-                reduce_fn=lambda prev, new: new,
-            )
+        # The latent, the rope key and the validity column of every
+        # cache, float32: what a row of the batch carries.
+        sow_stat(
+            self, "attention_latent_cache_bytes_per_row",
+            4 * self.num_layers * self.memory_len
+            * (self.latent_rank + self.rope_head_dim + 1),
+            "same",
+        )
         return norm

@@ -49,6 +49,8 @@ import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu.megablox.ops import backend as _megablox
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from torchbeast_tpu.models.stats import sow_stat
+
 
 def _constrain(x, mesh, spec):
     if mesh is None:
@@ -791,57 +793,57 @@ class DroplessMoE(nn.Module):
             # uniform.
             aux = E * jnp.sum(load / (tokens * K) * probs.mean(axis=0))
         if not self.is_initializing():
-            # `losses` is added to the objective, `moe_stats` (what the
-            # router did) to the update's stats, `param_steps` to the
-            # parameters after the optimizer's step: learner.py.
-            sown = [
-                ("losses", "moe_load_balance", self.aux_loss_weight * aux),
-            ] if self.aux_loss_weight else []
-            sown += [
-                ("moe_stats", "assignments", jnp.sum(load)),
-                ("moe_stats", "load_max_over_mean",
-                 jnp.max(load) * E / (tokens * K)),
-            ]
+            # `losses` is added to the objective, a stat (what the router
+            # did) to the update's stats by its fold over the layers
+            # (models/stats.py), `param_steps` to the parameters after
+            # the optimizer's step: learner.py.
+            if self.aux_loss_weight:
+                self.sow(
+                    "losses", "moe_load_balance", self.aux_loss_weight * aux,
+                    reduce_fn=lambda prev, new: new,
+                )
+            sow_stat(self, "moe_assignments", jnp.sum(load), "sum")
+            sow_stat(
+                self, "moe_load_max_over_mean",
+                jnp.max(load) * E / (tokens * K), "max",
+            )
             if self.held is not None:
                 # The part of those this layer computed, and how uneven
                 # its own experts' rows are.
                 mine = load[first : first + count]
-                sown += [
-                    ("moe_stats", "held_assignments", jnp.sum(mine)),
-                    ("moe_stats", "held_load_max_over_mean",
-                     jnp.max(mine) * count / jnp.maximum(jnp.sum(mine), 1.0)),
-                ]
+                sow_stat(self, "moe_held_assignments", jnp.sum(mine), "sum")
+                sow_stat(
+                    self, "moe_held_load_max_over_mean",
+                    jnp.max(mine) * count / jnp.maximum(jnp.sum(mine), 1.0),
+                    "max",
+                )
                 rungs = window_rungs(tokens, K, count, E)
                 if rungs:
                     # Fewer held than chosen: the rows of the window
                     # the kernels swept, and whether one rung short of
                     # the whole window held them all.
                     swept = window_sweeps(rungs, mine).astype(jnp.float32)
-                    sown += [
-                        ("moe_stats", "window_rows", swept * rungs[0]),
-                        ("moe_stats", "window_short_applications",
-                         (swept <= 1.0) * jnp.float32(rungs[0] < rungs[1])),
-                    ]
+                    sow_stat(
+                        self, "moe_window_rows", swept * rungs[0], "sum"
+                    )
+                    sow_stat(
+                        self, "moe_window_short_applications",
+                        (swept <= 1.0) * jnp.float32(rungs[0] < rungs[1]),
+                        "sum",
+                    )
             if self.selection_bias:
                 # Under the parameter's own name: the learner adds a
                 # sown step to the leaf of `params` at the same path.
-                sown += [
-                    ("param_steps", "e_score_correction_bias",
-                     self.bias_update_rate
-                     * jnp.sign(jnp.mean(load) - load)),
-                    ("moe_stats", "bias_abs_max", jnp.max(jnp.abs(bias))),
-                ]
-            if self.shared_width:
-                sown.append(
-                    ("moe_stats", "shared_applications", jnp.float32(1.0))
-                )
-            if self.latent_width:
-                sown.append(
-                    ("moe_stats", "latent_applications", jnp.float32(1.0))
-                )
-            for collection, name, value in sown:
                 self.sow(
-                    collection, name, value,
+                    "param_steps", "e_score_correction_bias",
+                    self.bias_update_rate * jnp.sign(jnp.mean(load) - load),
                     reduce_fn=lambda prev, new: new,
                 )
+                sow_stat(
+                    self, "moe_bias_abs_max", jnp.max(jnp.abs(bias)), "max"
+                )
+            if self.shared_width:
+                sow_stat(self, "moe_shared_applications", 1.0, "sum")
+            if self.latent_width:
+                sow_stat(self, "moe_latent_applications", 1.0, "sum")
         return y.astype(jnp.float32)

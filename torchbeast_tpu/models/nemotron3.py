@@ -79,6 +79,7 @@ import jax
 import jax.numpy as jnp
 
 from torchbeast_tpu.models.moe import DroplessMoE, held_experts
+from torchbeast_tpu.models.stats import sow_stat
 from torchbeast_tpu.models.transformer import (
     Recurrent,
     TransformerNet,
@@ -368,18 +369,21 @@ class _MambaBlock(nn.Module):
             ).astype(jnp.float32)
 
         if not self.is_initializing():
-            for name, value in (
-                ("applications", 1.0),
-                ("chunks", -(-steps // min(self.chunk_size, steps))),
-                ("resets_per_row",
-                 jnp.mean(jnp.sum(done.astype(jnp.float32), axis=1))),
-                ("state_bytes_per_row",
-                 4 * (H * P * N + (K - 1) * channels)),
+            # How many such layers and the bytes of state a row carries
+            # through them; the chunks the unroll's scan was cut into
+            # and the episode ends a row had, which every layer says
+            # alike.
+            for name, value, fold in (
+                ("ssm_applications", 1.0, "sum"),
+                ("ssm_state_bytes_per_row",
+                 4 * (H * P * N + (K - 1) * channels), "sum"),
+                ("ssm_chunks", -(-steps // min(self.chunk_size, steps)),
+                 "same"),
+                ("ssm_resets_per_row",
+                 jnp.mean(jnp.sum(done.astype(jnp.float32), axis=1)),
+                 "same"),
             ):
-                self.sow(
-                    "ssm_stats", name, jnp.float32(value),
-                    reduce_fn=lambda prev, new: new,
-                )
+                sow_stat(self, name, value, fold)
         return x, (new_carried.transpose(1, 0, 2, 3), new_tail)
 
 

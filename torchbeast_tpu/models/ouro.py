@@ -33,7 +33,8 @@ un-rotated keys, so the learner's batch forward equals the actor's T=1
 forwards through the `passes x L` rolling caches (tests/test_ouro.py).
 
 `early_exit_threshold` is 1.0: no pass is skipped. The exit gate is
-computed and its distribution over the passes logged (`loop_stats`);
+computed and its distribution over the passes logged (`loop_expected_
+exit_pass`, `loop_exit_p_last` of the update's stats: `end_pass`);
 no gradient reaches it here (Ouro trains it with an expected loss over
 the exits, which an IMPALA loss on the last pass's output does not
 carry over).
@@ -50,6 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from torchbeast_tpu.models.olmoe import rope_cached_attend
+from torchbeast_tpu.models.stats import sow_stat
 from torchbeast_tpu.models.transformer import (
     TransformerNet,
     count_two_leg_application,
@@ -212,9 +214,13 @@ class OuroNet(TransformerNet):
     @nn.nowrap
     def make_final_norm(self):
         """What follows every pass: the one norm, then the exit gate on
-        its output. The gate is a logged statistic (`loop_stats`, which
-        learner.compute_loss collects): its input is cut from the
-        gradient."""
+        its output. The gate is a logged statistic (models/stats.py):
+        its input is cut from the gradient. From the passes' gates
+        lambda_u the distribution over exits is p_u = lambda_u
+        prod_{j<u} (1 - lambda_j), the last pass taking the rest: the
+        last pass sows the pass a token would leave after, counted from
+        1 and averaged over the batch, and the mass left to the last
+        pass."""
         norm = nn.RMSNorm(epsilon=self.rms_norm_eps, name="final_norm")
         gate = nn.Dense(1, name="exit_gate")
         caches = self.passes * self.num_layers
@@ -223,25 +229,33 @@ class OuroNet(TransformerNet):
             2 * self.num_heads * self.head_dim + 1
         )
 
-        statics = (
-            ("passes", self.passes),
-            ("block_applications", caches),
-            ("cache_bytes_per_row", cache_bytes),
-        )
-        if not self.is_initializing():
-            for name, value in statics:
-                self.sow(
-                    "loop_stats", name, jnp.float32(value),
-                    reduce_fn=lambda prev, new: new,
-                )
+        for name, value in (
+            ("loop_passes", self.passes),
+            ("loop_block_applications", caches),
+            ("loop_cache_bytes_per_row", cache_bytes),
+        ):
+            sow_stat(self, name, value, "same")
+        gates = []  # one a pass, in order
 
         def end_pass(x):
             with jax.named_scope("pass_norm"):
                 x = norm(x)
-            exit_now = nn.sigmoid(gate(jax.lax.stop_gradient(x)))[..., 0]
-            if not self.is_initializing():
-                # One gate a pass: the collection holds them in order.
-                self.sow("loop_stats", "exit_gates", exit_now)
+            gates.append(nn.sigmoid(gate(jax.lax.stop_gradient(x)))[..., 0])
+            if len(gates) == self.passes and not self.is_initializing():
+                # Not leaving at pass u, for every pass but the last;
+                # their running product is still being in after it.
+                stays = 1.0 - jnp.stack(gates)[:-1]
+                sow_stat(
+                    self, "loop_expected_exit_pass",
+                    1.0 + jnp.mean(
+                        jnp.sum(jnp.cumprod(stays, axis=0), axis=0)
+                    ),
+                    "same",
+                )
+                sow_stat(
+                    self, "loop_exit_p_last",
+                    jnp.mean(jnp.prod(stays, axis=0)), "same",
+                )
             return x
 
         return end_pass

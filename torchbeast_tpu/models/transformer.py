@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from torchbeast_tpu.models.cores import RecurrentPolicyHead
+from torchbeast_tpu.models.stats import sow_stat
 from torchbeast_tpu.ops.attention import (
     band_by_leg,
     band_relative_offsets,
@@ -59,12 +60,7 @@ from torchbeast_tpu.ops.attention import (
 
 
 def _count_application(module: nn.Module, name: str) -> None:
-    if not module.is_initializing():
-        module.sow(
-            "attention_stats", name, jnp.float32(1.0),
-            init_fn=lambda: jnp.float32(0.0),
-            reduce_fn=lambda count, one: count + one,
-        )
+    sow_stat(module, "attention_" + name, 1.0, "sum")
 
 
 def count_two_leg_application(module: nn.Module) -> None:

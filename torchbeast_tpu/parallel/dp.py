@@ -100,7 +100,7 @@ def fleet_strategy(backend: Optional[str] = None) -> str:
 
 
 def make_parallel_update_step(
-    model, optimizer, hp: learner_lib.HParams, mesh, donate=True,
+    model, optimizer, hp: "learner_lib.HParams", mesh, donate=True,
     param_shardings: Optional[Any] = None,
     opt_shardings: Optional[Any] = None,
     donate_batch: bool = False,

@@ -37,6 +37,7 @@ from torchbeast_tpu.learner_setup import (
     hparams_from_flags,
     init_model_and_params,
 )
+from torchbeast_tpu.models import stats as model_stats
 from torchbeast_tpu.runtime import wire
 from torchbeast_tpu.runtime.actor_pool import ActorPool
 from torchbeast_tpu.runtime.inference import default_buckets, inference_loop
@@ -2152,50 +2153,17 @@ def train(flags):
                 reg.gauge("learner.sample_reuse").set(replay_reuse)
                 reg.gauge("learner_queue.depth").set(learner_queue.size())
                 reg.gauge("inference.depth").set(serving_depth_fn())
-                # From the last fetched update's own stats. What a
-                # dropless expert layer's router did (learner._moe_stats):
-                # every assignment computed, and the fullest expert's
-                # rows over the mean, worst layer; with --expert_share,
-                # the same two over the experts held here, and where
-                # those are fewer than a token chooses the rows of the
-                # window their kernels swept. A looped
-                # trunk's passes (learner._loop_stats): how many, the
-                # block applications and cache bytes a row they cost,
-                # and where its exit gates would let go. Block
-                # applications traced through the two-leg attention
-                # (ops/attention.cached_transformer_attend) or through
-                # the fused pass (ops/fused_attention.fused_attend).
-                # The layers that carry a recurrent state (learner.
-                # _ssm_stats): how many, the chunks their scan was cut
-                # into, episode ends and bytes of state a row.
-                for family, names in (
-                    ("moe", (
-                        "assignments", "load_max_over_mean",
-                        "held_assignments", "held_load_max_over_mean",
-                        "bias_abs_max", "bias_steps", "shared_applications",
-                        "latent_applications", "window_rows",
-                        "window_short_applications",
-                    )),
-                    ("ssm", (
-                        "applications", "chunks", "resets_per_row",
-                        "state_bytes_per_row",
-                    )),
-                    ("loop", (
-                        "passes", "block_applications",
-                        "cache_bytes_per_row", "expected_exit_pass",
-                        "exit_p_last",
-                    )),
-                    ("attention", (
-                        "two_leg_applications", "fused_applications",
-                        "latent_applications", "latent_fused_applications",
-                        "latent_cache_bytes_per_row",
-                    )),
-                ):
-                    for name in names:
-                        if f"{family}_{name}" in stats_now:
-                            reg.gauge(family + "." + name).set(
-                                stats_now[f"{family}_{name}"]
-                            )
+                # From the last fetched update's own stats: a gauge
+                # `<family>.<name>` for every `<family>_<name>` a model's
+                # layers sowed of themselves (models/stats.py: what the
+                # routers did, a looped trunk's passes, which attention
+                # path a block was traced through, the recurrent
+                # layers' state) or the update counted (`moe_bias_
+                # steps`).
+                for key, value in stats_now.items():
+                    gauge = model_stats.gauge_name(key)
+                    if gauge:
+                        reg.gauge(gauge).set(value)
                 tele.write(extra={"step": now_step})
             means = timings.means()
             log.info(
