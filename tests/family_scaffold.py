@@ -50,6 +50,7 @@ from perfbench.reference import (
     nemotron3_policy,
     olmoe_policy,
     ouro_policy,
+    qwen3next_policy,
 )
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import (
@@ -58,11 +59,13 @@ from torchbeast_tpu.models import (
     Nemotron3Net,
     OLMoENet,
     OuroNet,
+    Qwen3NextNet,
     kanana2,
     mellum2,
     nemotron3,
     olmoe,
     ouro,
+    qwen3next,
 )
 from torchbeast_tpu.runtime.state_table import DeviceStateTable
 
@@ -155,6 +158,29 @@ def _perturb_nemotron3(model, params):
                     10 * layer + i, block[leaf].shape, 0.3
                 )
             inner[name] = block
+    return {"params": inner}
+
+
+def _perturb_qwen3next(model, params):
+    # And the zero-centred norms' scales, which start at zero, and the
+    # gated norm's, which starts at one; a seed a leaf moved.
+    inner = _with_extras(params["params"])
+    seeds = iter(range(1, 1000))
+
+    def moved(tree):
+        out = {}
+        for name, leaf in sorted(tree.items()):
+            if isinstance(leaf, dict):
+                out[name] = leaf if name == "moe" else moved(leaf)
+            elif name in ("scale", "gate_norm"):
+                out[name] = leaf + _normal(next(seeds), leaf.shape, 0.3)
+            else:
+                out[name] = leaf
+        return out
+
+    for name in sorted(inner):
+        if name.startswith("block_") or name == "final_norm":
+            inner[name] = moved(inner[name])
     return {"params": inner}
 
 
@@ -256,6 +282,30 @@ def _config_nemotron3(model):
     }
 
 
+def _config_qwen3next(model):
+    held = model.held_experts()
+    return {
+        "num_hidden_layers": model.num_layers,
+        "full_attention_interval": model.attention_interval,
+        "num_attention_heads": model.num_heads,
+        "num_key_value_heads": model.kv_heads, "head_dim": model.head_dim,
+        "partial_rotary_factor": model.rotary_factor,
+        "rope_theta": model.rope_theta,
+        "linear_num_key_heads": model.delta_key_heads,
+        "linear_num_value_heads": model.delta_value_heads,
+        "linear_key_head_dim": model.delta_key_dim,
+        "linear_value_head_dim": model.delta_value_dim,
+        "linear_conv_kernel_dim": model.conv_kernel,
+        "published_num_experts": model.num_experts,
+        "num_experts": held[1] if held else model.num_experts,
+        "expert_share": list(model.expert_share),
+        "num_experts_per_tok": model.experts_per_token,
+        "norm_topk_prob": True, "hidden_act": "silu",
+        "decoder_sparse_step": 1, "mlp_only_layers": [],
+        "rms_norm_eps": 1e-6, "router_aux_loss_coef": 0.001,
+    }
+
+
 @dataclasses.dataclass(frozen=True)
 class Family:
     """One policy family at toy size: `net(num_actions=A, **small)` is
@@ -334,6 +384,22 @@ FAMILIES = {
             layer_pattern="MEM*EMM", num_layers=3, memory_len=5,
         ),
         _config_nemotron3, _perturb_nemotron3, t=11,
+    ),
+    # One Gated DeltaNet layer of 4 value heads of 5 on 2 key heads of 6,
+    # scanned in chunks of 4 steps (the 11 steps of an unroll are two
+    # whole chunks and one padded), one gated attention layer of 4 query
+    # heads of 16 on 2 key/value heads, RoPE on a head's first 4
+    # columns; 16 experts of 10, top 3, a gated shared expert of 12.
+    "qwen3next": Family(
+        Qwen3NextNet, qwen3next, qwen3next_policy,
+        dict(
+            d_model=32, attention_interval=2, num_heads=4, kv_heads=2,
+            head_dim=16, delta_key_heads=2, delta_value_heads=4,
+            delta_key_dim=6, delta_value_dim=5, chunk_size=4,
+            num_experts=16, experts_per_token=3, expert_width=10,
+            shared_width=12, num_layers=2, memory_len=5,
+        ),
+        _config_qwen3next, _perturb_qwen3next, t=11,
     ),
 }
 

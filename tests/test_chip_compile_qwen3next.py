@@ -1,0 +1,95 @@
+"""Compile for the chip, without the chip (tests/chip_fixtures.py):
+`qwen3next_policy.learner`'s whole update, one AOT compile of the real
+cell. A file of its own: tests/chip_fixtures.py says why.
+"""
+
+import json
+import os
+import re
+
+import numpy as np
+
+import jax
+
+from tests.chip_fixtures import (  # noqa: F401 (fixtures)
+    NUM_ACTIONS,
+    on as _on,
+    one_chip,
+    topo,
+)
+from torchbeast_tpu import learner as learner_lib
+
+
+def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
+    """`qwen3next_policy.learner`'s update as the benchmark builds it
+    (the configuration's own argv and sizes: one period `DDDA`, experts
+    0/16, blocks rematerialised, a [256, B] batch), whole, for a
+    described v5e: it fits beside the driver's copy of the weights
+    (under 15.0 GiB with it) and fills the chip; the attention layer's
+    scores over 4,351 keys of heads of 256 live in `fused_attend`'s
+    VMEM (no f32 array over the keys is in the program: the fused pass
+    compiles at a head size it had never run); the three DeltaNet
+    layers' matrix states [16, 32, 128, 128] are in it, and no array of
+    the chunked scan is larger than a chunk's [64, 64] a head."""
+    from perfbench import manifest
+    from perfbench.drivers import learner as learner_driver
+    from torchbeast_tpu import monobeast
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(
+        manifest.HERE, "configs", "qwen3next_80b_policy.json"
+    )) as f:
+        config = json.load(f)
+    steps, rows = config["unroll_length"], config["batch_size"]
+    flags = monobeast.make_parser().parse_args(
+        config["program_argv"]
+        + ["--unroll_length", str(steps), "--batch_size", str(rows)]
+    )
+    hp = monobeast.hparams_from_flags(flags)
+    frame = tuple(config["frame_shape"])
+    model, _ = monobeast._init_model_and_params(
+        flags, NUM_ACTIONS, rows, frame, init_params=False
+    )
+    optimizer = learner_lib.make_optimizer(hp)
+    params = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
+        monobeast.dummy_env_outputs(1, rows, frame, np.uint8),
+        model.initial_state(rows),
+    ))
+    batch, state = jax.eval_shape(lambda: (
+        learner_driver._make_batch(
+            jax.random.PRNGKey(0), steps + 1, rows, NUM_ACTIONS, frame
+        ),
+        model.initial_state(rows),
+    ))
+    compiled = learner_lib.make_update_step(model, optimizer, hp).lower(
+        _on(one_chip, params),
+        _on(one_chip, jax.eval_shape(optimizer.init, params)),
+        _on(one_chip, batch), _on(one_chip, state),
+    ).compile()
+    memory = compiled.memory_analysis()
+    total = (
+        memory.temp_size_in_bytes + memory.argument_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    weights = 4 * sum(
+        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)
+    )
+    assert weights == 4 * config["param_count"] == 4 * 605_711_431
+    print("memory", memory, "total GiB", total / 2**30,
+          "with the copy", (total + weights) / 2**30)
+    assert total + weights < 15.0 * 2**30, memory
+    assert total > 4 * 2**30, memory  # the cell fills the chip
+    text = compiled.as_text()
+    shapes = {
+        tuple(int(d) for d in dims.split(","))
+        for dims in re.findall(r"f32\[([0-9,]+)\]", text)
+    }
+    scores = {
+        s for s in shapes if len(s) >= 3 and s[-1] in (4095, 4351, 4352)
+    }
+    assert not scores, scores
+    assert text.count("fused_attend_forward") >= 2  # and rematerialised
+    assert text.count("fused_attend_backward") >= 1
+    # The carried matrix states are the program's arguments.
+    assert (32, rows, 128, 128) in shapes

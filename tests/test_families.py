@@ -151,6 +151,53 @@ def _published_nemotron3(model):
     )
 
 
+def _published_qwen3next(model):
+    assert model.zero_init_extras
+    assert (model.d_model, model.num_heads, model.kv_heads, model.head_dim) == (
+        2048, 16, 2, 256
+    )
+    assert (model.rotary_factor, model.rope_theta) == (0.25, 1e7)
+    assert (
+        model.delta_key_heads, model.delta_value_heads, model.delta_key_dim,
+        model.delta_value_dim, model.conv_kernel, model.chunk_size,
+    ) == (16, 32, 128, 128, 4, 64)
+    assert (
+        model.num_experts, model.experts_per_token, model.expert_width,
+        model.shared_width,
+    ) == (512, 10, 512, 512)
+    assert model.renormalise and model.aux_loss_weight == 0.001
+    assert (model.rms_norm_eps, model.memory_len) == (1e-6, 4095)
+    assert model.matmul_precision == "high"
+    assert model.held_experts() == (0, 32)
+    # One period: three matrix states with their conv tails, one window.
+    carried = Recurrent(((32, 128, 128), (3, 2 * 2048 + 4096)))
+    assert model.layer_caches() == (carried, None) * 3 + (
+        (4095, 2, 256), None,
+    )
+    state = jax.eval_shape(lambda: model.initial_state(16))
+    assert [leaf.shape for leaf in state[0]] == [
+        (32, 16, 128, 128), (3, 16, 8192),
+    ]
+    assert 4 * sum(
+        int(np.prod(leaf.shape[:1] + leaf.shape[2:]))
+        for item in state[:3] for leaf in item
+    ) == 6_586_368
+    whole = create_model("qwen3next", num_actions=6)
+    assert [type(e) is tuple for e in whole.layer_caches()[::2]] == (
+        [False, False, False, True] * 12
+    )
+    assert whole.held_experts() is None
+    # The cell's attention layer (8 query heads of 256 a key/value head
+    # over 4,095 + 256 keys, 1.14 GB of f32 scores at B=16) is
+    # `fused_attend`'s at a head of two lane tiles; a T=1 act step is not.
+    assert attention.fused_pass_applies(
+        (16, 256, 16, 256), (16, 4351, 2, 256), None
+    )
+    assert not attention.fused_pass_applies(
+        (16, 1, 16, 256), (16, 4096, 2, 256), None
+    )
+
+
 # family: how the cell builds it, the depth of the published model, its
 # own assertions, and what the registry refuses beside `use_lstm`.
 REGISTRY = {
@@ -173,6 +220,13 @@ REGISTRY = {
             *(_refused("mixer_share", mixer_share=bad)
               for bad in [(4, 4), (0, 3), (-1, 8), (0, 16)]),
             _refused("expert_share", expert_share=(0, 7)),
+        ],
+    ),
+    "qwen3next": (
+        dict(num_layers=4, expert_share=(0, 16)), 48, _published_qwen3next, [
+            _refused("whole periods of 4", num_layers=6),
+            *(_refused("expert_share", expert_share=bad)
+              for bad in [(16, 16), (0, 3), (-1, 16)]),
         ],
     ),
 }
@@ -230,7 +284,8 @@ def _flags_mellum2(parse, build):
     # The share is refused for a family without experts to divide.
     for other in ("deep", "transformer", "olmoe"):
         with pytest.raises(
-            ValueError, match="--model mellum2 or kanana2 or nemotron3 only"
+            ValueError,
+            match="--model mellum2 or kanana2 or nemotron3 or qwen3next only",
         ):
             build(parse(["--model", other, "--expert_share", "0/4"]))
     return model, ["--model", "mellum2", "--num_layers", "4"]
@@ -294,9 +349,35 @@ def _flags_nemotron3(parse, build):
     return model, ["--model", "nemotron3", "--num_layers", "3"]
 
 
+def _flags_qwen3next(parse, build):
+    flags = parse([
+        "--model", "qwen3next", "--num_layers", "4", "--memory_len", "9",
+        "--expert_share", "1/4",
+    ])
+    assert (flags.model, flags.num_layers, flags.expert_share) == (
+        "qwen3next", 4, "1/4"
+    )
+    model = build(flags)
+    assert (model.num_layers, model.memory_len, model.d_model) == (4, 9, 32)
+    assert model.held_experts() == (4, 4)
+    # The (shrunken) table's interval: a period of two.
+    assert [type(e) is tuple for e in model.layer_caches()[::2]] == [
+        False, True, False, True,
+    ]
+    with pytest.raises(ValueError, match="whole periods of 2"):
+        build(parse(["--model", "qwen3next", "--num_layers", "3"]))
+    # The mixers are whole on every chip: no share of them to take.
+    with pytest.raises(ValueError, match="mixer_share .* nemotron3 only"):
+        build(parse(["--model", "qwen3next", "--mixer_share", "0/2"]))
+    with pytest.raises(ValueError, match="use_lstm"):
+        build(parse(["--model", "qwen3next", "--use_lstm"]))
+    return model, ["--model", "qwen3next", "--num_layers", "2"]
+
+
 FLAGS = {
     "olmoe": _flags_olmoe, "mellum2": _flags_mellum2, "ouro": _flags_ouro,
     "kanana2": _flags_kanana2, "nemotron3": _flags_nemotron3,
+    "qwen3next": _flags_qwen3next,
 }
 
 
@@ -353,6 +434,11 @@ REMAT = {
         (0, 2e-5),
         ["moe_held_assignments", "ssm_applications", "ssm_chunks",
          "ssm_resets_per_row", "moe_latent_applications"], [],
+    ),
+    "qwen3next": (
+        dict(expert_share=(1, 4)), _ENDS, 1e-5, (0, 2e-5),
+        ["moe_held_assignments", "delta_applications", "delta_chunks",
+         "delta_resets_per_row", "attention_gated_applications"], [],
     ),
 }
 
@@ -526,11 +612,21 @@ STATS_AT_PR_44 = {
         "moe_window_short_applications", "ssm_applications", "ssm_chunks",
         "ssm_resets_per_row", "ssm_state_bytes_per_row",
     ],
+    # Not a parent's: the family of PR 46, as it came (four of sixteen
+    # held under three chosen: no window).
+    "qwen3next": [
+        "attention_gated_applications", "delta_applications", "delta_chunks",
+        "delta_resets_per_row", "delta_state_bytes_per_row",
+        "moe_assignments", "moe_held_assignments",
+        "moe_held_load_max_over_mean", "moe_load_max_over_mean",
+        "moe_shared_applications",
+    ],
 }
 _HELD = {
     "mellum2": dict(expert_share=(1, 4)),
     "kanana2": dict(expert_share=(0, 8)),
     "nemotron3": dict(expert_share=(1, 8), mixer_share=(1, 2)),
+    "qwen3next": dict(expert_share=(1, 4)),
 }
 
 

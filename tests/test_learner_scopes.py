@@ -68,3 +68,43 @@ def test_the_drivers_optimizer_keeps_three_scopes():
     names = _op_names(learner_lib.make_optimizer(learner_lib.HParams()))
     for scope in ("vtrace", "loss_terms", "optimizer"):
         assert any(_in_scope(n, scope) for n in names), scope
+
+
+# --- a family's own scopes (PR 46: `--model qwen3next`) -----------------------
+
+QWEN3NEXT_SCOPES = (
+    "deltanet_in_proj", "deltanet_conv", "delta_scan", "delta_intra",
+    "delta_solve", "delta_states", "delta_inter", "deltanet_gate_norm",
+    "deltanet_out_proj", "attention_full", "attention_gate",
+    "moe_shared_gate",
+)
+
+
+@pytest.fixture(scope="module")
+def qwen3next_op_names():
+    """The toy family's whole update, compiled (tests/family_scaffold.
+    py): what a device trace of the cell is split by."""
+    from tests import family_scaffold as scaffold
+
+    model, params = scaffold.build("qwen3next")
+    t = scaffold.FAMILIES["qwen3next"].t
+    batch = scaffold.learner_batch(1, [(4, 0), (5, 1)], t=t)
+    hp = learner_lib.HParams(batch_size=scaffold.B, unroll_length=t - 1)
+    optimizer = optax.sgd(0.1)
+    compiled = learner_lib.make_update_step(
+        model, optimizer, hp, donate=False
+    ).lower(
+        params, optimizer.init(params), batch,
+        model.initial_state(scaffold.B),
+    ).compile()
+    return re.findall(r'op_name="([^"]+)"', compiled.as_text())
+
+
+@pytest.mark.parametrize("scope", QWEN3NEXT_SCOPES)
+def test_family_scope_reaches_the_compiled_hlo(qwen3next_op_names, scope):
+    inside = [n for n in qwen3next_op_names if _in_scope(n, scope)]
+    assert inside, f"no compiled op carries the scope {scope!r}"
+    if scope in ("delta_intra", "delta_states", "delta_inter"):
+        assert all(_in_scope(n, "delta_scan") for n in inside)
+    if scope == "delta_solve":
+        assert all(_in_scope(n, "delta_intra") for n in inside)

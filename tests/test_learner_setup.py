@@ -165,12 +165,14 @@ FAMILY_FIELD_CASES = {
     "num_layers": (
         "3", 3, "olmoe", "pipelined_transformer",
         "--num_layers is a positive depth or window of --model "
-        "transformer or olmoe or mellum2 or ouro or kanana2 or nemotron3",
+        "transformer or olmoe or mellum2 or ouro or kanana2 or nemotron3 "
+        "or qwen3next",
     ),
     "memory_len": (
         "9", 9, "transformer", "deep",
         "--memory_len is a positive depth or window of --model "
-        "transformer or olmoe or mellum2 or ouro or kanana2 or nemotron3",
+        "transformer or olmoe or mellum2 or ouro or kanana2 or nemotron3 "
+        "or qwen3next",
     ),
     "num_experts": (
         "4", 4, "transformer", "olmoe",
@@ -181,7 +183,7 @@ FAMILY_FIELD_CASES = {
         "1/4", (1, 4), "mellum2", "olmoe",
         "--expert_share i/n (share i of the n chips that divide each "
         "layer's experts) applies to --model mellum2 or kanana2 or "
-        "nemotron3 only",
+        "nemotron3 or qwen3next only",
     ),
     "mixer_share": (
         "1/2", (1, 2), "nemotron3", "kanana2",
@@ -226,18 +228,23 @@ def test_family_field_flag_follows_the_class(flag, monkeypatch):
     assert str(refused.value) == told
 
 
-@pytest.mark.parametrize("family", ["mellum2", "kanana2", "nemotron3"])
+@pytest.mark.parametrize(
+    "family", ["mellum2", "kanana2", "nemotron3", "qwen3next"]
+)
 def test_expert_share_reaches_every_family_that_declares_it(family):
     """`--expert_share` is no family's by name: a class that declares
     the field takes the flag (`models.takes_flag`), and the refusal's
     text lists the takers from the registry. PR 38 added a second taker
     and edited neither `_FAMILY_FIELD_REFUSALS` nor the check; PR 42 a
-    third, and its sibling `--mixer_share` with a refusal of its own."""
+    third, and its sibling `--mixer_share` with a refusal of its own; PR
+    46 a fourth."""
     assert models.families_taking("expert_share") == [
-        "mellum2", "kanana2", "nemotron3"
+        "mellum2", "kanana2", "nemotron3", "qwen3next"
     ]
     assert models.families_taking("mixer_share") == ["nemotron3"]
-    layers = {"mellum2": "4", "kanana2": "2", "nemotron3": "11"}[family]
+    layers = {
+        "mellum2": "4", "kanana2": "2", "nemotron3": "11", "qwen3next": "4",
+    }[family]
     model, _ = learner_setup.init_model_and_params(
         monobeast.make_parser().parse_args([
             "--model", family, "--num_layers", layers,
@@ -267,6 +274,7 @@ def test_refusals_are_stated_on_the_class():
         "ouro": ("num_experts", "attention_impl"),
         "kanana2": ("num_experts", "attention_impl"),
         "nemotron3": ("num_experts", "attention_impl"),
+        "qwen3next": ("num_experts", "attention_impl"),
     }
     kv_cache = [
         name for name in models.MODEL_NAMES
@@ -274,13 +282,12 @@ def test_refusals_are_stated_on_the_class():
     ]
     assert kv_cache == [
         "transformer", "pipelined_transformer", "olmoe", "mellum2", "ouro",
-        "kanana2", "nemotron3",
+        "kanana2", "nemotron3", "qwen3next",
     ]
     for name in models.MODEL_NAMES:
-        # test_olmoe, test_mellum2, test_ouro, test_kanana2 and
-        # test_nemotron3 have theirs
+        # test_families has the published families'
         if name in kv_cache and name not in (
-            "olmoe", "mellum2", "ouro", "kanana2", "nemotron3"
+            "olmoe", "mellum2", "ouro", "kanana2", "nemotron3", "qwen3next"
         ):
             with pytest.raises(ValueError, match="KV cache"):
                 models.create_model(name, num_actions=A, use_lstm=True)

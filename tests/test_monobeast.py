@@ -550,6 +550,19 @@ def _nemotron3_through_main(stats):
     assert stats["aux_loss"] == 0.0
 
 
+def _qwen3next_through_main(stats):
+    assert stats["delta_applications"] == 1
+    assert stats["delta_chunks"] == 2  # 6 steps in chunks of 4
+    # 4 value heads' [6, 5] matrix states and a tail of 3 inputs over
+    # 2 x 2 x 6 + 4 x 5 channels, f32.
+    assert stats["delta_state_bytes_per_row"] == 4 * (4 * 6 * 5 + 3 * 44)
+    assert stats["delta_resets_per_row"] >= 0
+    assert stats["attention_gated_applications"] == 1
+    assert stats["moe_shared_applications"] == 2
+    assert 0 <= stats["moe_held_assignments"] <= stats["moe_assignments"]
+    assert stats["aux_loss"] >= 0.001 * 2 * 0.99  # two layers' balance
+
+
 def _ouro_through_main(stats):
     assert stats["loop_passes"] == 3
     assert stats["loop_block_applications"] == 6
@@ -574,6 +587,10 @@ _MELLUM2_WIDTHS = dict(
 #  nemotron3: one period of attention, latent MoE and Mamba-2, half of
 #   each mixer's heads and a quarter of the experts: acting through the
 #   rolling cache AND the Mamba state with its conv tail, unrolls of 5
+#   scanned in chunks of 4.
+#  qwen3next: one Gated DeltaNet layer and one gated attention layer, a
+#   quarter of the experts: acting through the MATRIX state with its
+#   conv tail AND the rolling cache of un-rotated keys, unrolls of 5
 #   scanned in chunks of 4.
 #  ouro: 2 layers run 3 times, through 3 x 2 rolling caches.
 THROUGH_MAIN = {
@@ -607,6 +624,17 @@ THROUGH_MAIN = {
             num_layers=3, expert_share="1/4", mixer_share="1/2", remat="all",
         ),
         _nemotron3_through_main,
+    ),
+    "qwen3next": (
+        "qwen3next",
+        dict(
+            d_model=32, attention_interval=2, num_heads=4, kv_heads=2,
+            head_dim=16, delta_key_heads=2, delta_value_heads=4,
+            delta_key_dim=6, delta_value_dim=5, chunk_size=4, num_experts=8,
+            experts_per_token=2, expert_width=10, shared_width=12,
+        ),
+        dict(num_layers=2, expert_share="1/4", remat="all"),
+        _qwen3next_through_main,
     ),
     "ouro": (
         "ouro",

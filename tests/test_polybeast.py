@@ -56,6 +56,12 @@ def _nemotron3_trained(stats):
     assert stats["moe_bias_steps"] == 1
 
 
+def _qwen3next_trained(stats):
+    assert stats["delta_applications"] == 1
+    assert stats["attention_gated_applications"] == 1
+    assert stats["moe_shared_applications"] == 2
+
+
 # A family a row (a `model_config` PR adds one: tests/family_scaffold.py):
 # what its `PUBLISHED` table is shrunk to, its depth, and what the last
 # update's stats must say.
@@ -66,6 +72,8 @@ def _nemotron3_trained(stats):
 #  nemotron3: the slots hold both kinds of state (the attention layer's
 #   window, the Mamba layer's state and conv tail; the MoE layer has
 #   none), the learner's updates scan in chunks.
+#  qwen3next: the slots hold a matrix state with its conv tail beside
+#   the attention layer's window; the delta rule runs in chunks.
 FAMILIES = {
     "ouro": (
         dict(d_model=32, num_heads=4, head_dim=8, mlp_width=48, passes=3),
@@ -87,6 +95,15 @@ FAMILIES = {
             latent_width=12, shared_width=20, layer_period="*EM",
         ),
         3, _nemotron3_trained,
+    ),
+    "qwen3next": (
+        dict(
+            d_model=32, attention_interval=2, num_heads=4, kv_heads=2,
+            head_dim=16, delta_key_heads=2, delta_value_heads=4,
+            delta_key_dim=6, delta_value_dim=5, chunk_size=4, num_experts=8,
+            experts_per_token=2, expert_width=10, shared_width=12,
+        ),
+        2, _qwen3next_trained,
     ),
 }
 

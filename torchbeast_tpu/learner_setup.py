@@ -175,34 +175,42 @@ def add_learner_arguments(parser, *, model_default,
                              "divide into M microbatches.")
     parser.add_argument("--num_layers", type=int, default=0,
                         help="Depth of --model transformer, olmoe, "
-                             "mellum2, ouro, kanana2 or nemotron3 (0: "
+                             "mellum2, ouro, kanana2, nemotron3 or "
+                             "qwen3next (0: "
                              "the family's own, 2 and the published 16, "
-                             "28, 48, 48 and 88; mellum2 in whole "
+                             "28, 48, 48, 88 and 48; mellum2 in whole "
                              "periods of 4; ouro runs the layers it has "
                              "4 times a step; kanana2: its leading "
                              "dense layer and the MoE layers after it, "
                              "2 or more; nemotron3 in whole periods of "
-                             "11, *EMEMEMEMEM).")
+                             "11, *EMEMEMEMEM; qwen3next in whole "
+                             "periods of 4, three Gated DeltaNet layers "
+                             "and one gated attention layer).")
     parser.add_argument("--memory_len", type=int, default=0,
                         help="Steps of its own past a transformer, "
-                             "olmoe, mellum2, ouro, kanana2 or nemotron3 "
+                             "olmoe, mellum2, ouro, kanana2, nemotron3 "
+                             "or qwen3next "
                              "policy attends over, carried as the "
                              "rolling KV cache (0: the family's own, 64, "
-                             "128, 4095, 255, 4095 and 4095; mellum2: "
+                             "128, 4095, 255, 4095, 4095 and 4095; mellum2: "
                              "its full layers' cache, the sliding "
                              "layers carry min(memory_len, 1023); ouro: "
                              "every one of its 4 x num_layers caches; "
                              "kanana2: a latent and a rope key a slot; "
                              "nemotron3: its attention layers', the "
-                             "Mamba-2 layers carry a state instead).")
+                             "Mamba-2 layers carry a state instead; "
+                             "qwen3next: its attention layers', the "
+                             "DeltaNet layers carry a matrix state).")
     parser.add_argument("--expert_share", default="",
-                        help="--model mellum2, kanana2 or nemotron3: "
+                        help="--model mellum2, kanana2, nemotron3 or "
+                             "qwen3next: "
                              "'i/n' holds share i of the n chips that "
-                             "divide each layer's 64 (128, 512 routed) "
+                             "divide each layer's 64 (128, 512, 512 routed) "
                              "experts (0/4: experts 0..15). The layer "
                              "routes over all of them and adds its own "
                              "experts' part of the sum (kanana2, "
-                             "nemotron3: and the shared expert); "
+                             "nemotron3, qwen3next: and the shared "
+                             "expert); "
                              "nothing stands in for the other chips. "
                              "Empty: all.")
     parser.add_argument("--mixer_share", default="",
@@ -294,7 +302,8 @@ def add_learner_arguments(parser, *, model_default,
                              "all, the block remat of the families "
                              "whose class has the `blocks` lever "
                              "(transformer, pipelined_transformer, "
-                             "mellum2, ouro, kanana2; not olmoe), the "
+                             "mellum2, ouro, kanana2, nemotron3, "
+                             "qwen3next; not olmoe), the "
                              "LSTM scan): 'auto' picks the "
                              "minimum-recompute plan whose XLA-measured "
                              "peak fits --hbm_budget_gb; 'all'/'none' "

@@ -10,13 +10,21 @@ import dataclasses
 from torchbeast_tpu.models.atari_net import AtariNet  # noqa: F401
 from torchbeast_tpu.models.cores import LSTMCore  # noqa: F401
 from torchbeast_tpu.models.mlp import MLPNet  # noqa: F401
-from torchbeast_tpu.models import kanana2, mellum2, nemotron3, olmoe, ouro
+from torchbeast_tpu.models import (
+    kanana2,
+    mellum2,
+    nemotron3,
+    olmoe,
+    ouro,
+    qwen3next,
+)
 from torchbeast_tpu.models.kanana2 import Kanana2Net  # noqa: F401
 from torchbeast_tpu.models.mellum2 import Mellum2Net  # noqa: F401
 from torchbeast_tpu.models.nemotron3 import Nemotron3Net  # noqa: F401
 from torchbeast_tpu.models.olmoe import OLMoENet  # noqa: F401
 from torchbeast_tpu.models.ouro import OuroNet  # noqa: F401
 from torchbeast_tpu.models.pipelined import PipelinedMLPNet  # noqa: F401
+from torchbeast_tpu.models.qwen3next import Qwen3NextNet  # noqa: F401
 from torchbeast_tpu.models.resnet import ResNet  # noqa: F401
 from torchbeast_tpu.models.transformer import TransformerNet  # noqa: F401
 from torchbeast_tpu.models.transformer_pp import (  # noqa: F401
@@ -37,13 +45,14 @@ _REGISTRY = {
     "ouro": OuroNet,
     "kanana2": Kanana2Net,
     "nemotron3": Nemotron3Net,
+    "qwen3next": Qwen3NextNet,
 }
 # A family whose widths are a published table (its module's `PUBLISHED`,
 # keyed by the class's fields): read when the model is built, so that a
 # test shrinks the family there.
 _PUBLISHED_TABLES = {
     OLMoENet: olmoe, Mellum2Net: mellum2, OuroNet: ouro, Kanana2Net: kanana2,
-    Nemotron3Net: nemotron3,
+    Nemotron3Net: nemotron3, Qwen3NextNet: qwen3next,
 }
 MODEL_NAMES = tuple(_REGISTRY)
 

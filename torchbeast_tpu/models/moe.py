@@ -660,7 +660,8 @@ class DroplessMoE(nn.Module):
     chosen SCORES, bias left out. Either way the gates are then
     multiplied by `routed_scaling`. `shared_width` > 0 adds one expert
     of that width that every token takes, beside the routed sum,
-    unscaled.
+    unscaled, or with `shared_token_gate` (models/qwen3next.py) scaled
+    a token by sigmoid(`shared_expert_gate` x), d -> 1 without bias.
 
     The experts as the fields say. `gated` (every family's but one):
     SwiGLUs, three matrices. Not gated (models/nemotron3.py): two,
@@ -693,6 +694,7 @@ class DroplessMoE(nn.Module):
     bias_update_rate: float = 0.0
     routed_scaling: float = 1.0
     shared_width: int = 0
+    shared_token_gate: bool = False
     gated: bool = True
     activation: str = "silu"  # or "relu2"
     latent_width: int = 0
@@ -784,7 +786,13 @@ class DroplessMoE(nn.Module):
                 ) if self.gated else act(
                     proj("shared_up", self.shared_width)(h)
                 )
-                y = y + proj("shared_down", d)(hidden).astype(jnp.float32)
+                shared = proj("shared_down", d)(hidden).astype(jnp.float32)
+                if self.shared_token_gate:
+                    with jax.named_scope("moe_shared_gate"):
+                        shared = shared * nn.sigmoid(
+                            proj("shared_expert_gate", 1)(h)
+                        ).astype(jnp.float32)
+                y = y + shared
 
         load = sizes.astype(jnp.float32)
         if self.aux_loss_weight:

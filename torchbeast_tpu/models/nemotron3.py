@@ -128,6 +128,39 @@ PUBLISHED = {
 }
 
 
+def chunk_plan(steps, chunk):
+    """(steps a chunk, padded steps, chunks) of an unroll cut into chunks
+    of `chunk` steps: one chunk of the unroll's own length where it is
+    the shorter (T = 1: a chunk of one step, the recurrence)."""
+    Q = min(chunk, steps)
+    pad = -steps % Q
+    return Q, pad, (steps + pad) // Q
+
+
+def in_chunks(a, Q, pad):
+    """a [B, T, ...] -> [B, c, Q, ...], float32 (`done` stays bool), the
+    last chunk padded with zeros: steps that pass a state on as it is
+    (this module's scan and models/qwen3next.py's)."""
+    a = a.astype(jnp.float32) if a.dtype != bool else a
+    a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+    return a.reshape((a.shape[0], a.shape[1] // Q, Q) + a.shape[2:])
+
+
+def ends_in_chunks(done, Q, pad):
+    """Episodes ended in the chunk up to and including each step:
+    [B, c, Q] of done [B, T]."""
+    return jnp.cumsum(in_chunks(done, Q, pad).astype(jnp.int32), axis=2)
+
+
+def reaches(ends):
+    """[B, c, Q, Q]: source step j reaches step i of its chunk, j <= i
+    and no episode end in (j, i] (their counts of ends are equal)."""
+    Q = ends.shape[-1]
+    return jnp.tril(jnp.ones((Q, Q), bool)) & (
+        ends[:, :, :, None] == ends[:, :, None, :]
+    )
+
+
 def ssd_scan(x, dt, A, B_in, C_in, state, done, chunk):
     """The Mamba-2 recurrence over an unroll, in chunks, with episode
     ends inside them.
@@ -150,20 +183,15 @@ def ssd_scan(x, dt, A, B_in, C_in, state, done, chunk):
     rows, steps, H, P = x.shape
     G, N = B_in.shape[2:]
     per = H // G
-    Q = min(chunk, steps)
-    pad = -steps % Q
-    nc = (steps + pad) // Q
+    Q, pad, nc = chunk_plan(steps, chunk)
 
     def chunks(a):
-        a = a.astype(jnp.float32) if a.dtype != bool else a
-        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-        return a.reshape((rows, nc, Q) + a.shape[2:])
+        return in_chunks(a, Q, pad)
 
     x = chunks(x).reshape(rows, nc, Q, G, per, P)
     dt = chunks(dt).reshape(rows, nc, Q, G, per)
     B_in, C_in = chunks(B_in), chunks(C_in)
-    # Episodes ended in the chunk up to and including each step.
-    ends = jnp.cumsum(chunks(done).astype(jnp.int32), axis=2)  # [B, c, Q]
+    ends = ends_in_chunks(done, Q, pad)  # [B, c, Q]
     # [B, c, G, per, Q]: steps last, the lanes' axis.
     dt_last = dt.transpose(0, 1, 3, 4, 2)
     cs = jnp.cumsum(dt_last * A.reshape(G, per, 1), axis=-1)
@@ -172,13 +200,9 @@ def ssd_scan(x, dt, A, B_in, C_in, state, done, chunk):
         return mask[:, :, None, None]
 
     with jax.named_scope("ssd_intra"):
-        # Source j reaches step i: j <= i and no episode end in (j, i].
-        reaches = jnp.tril(jnp.ones((Q, Q), bool)) & (
-            ends[:, :, :, None] == ends[:, :, None, :]
-        )
         decay = jnp.exp(jnp.where(
-            along_heads(reaches), cs[..., :, None] - cs[..., None, :],
-            -jnp.inf,
+            along_heads(reaches(ends)),
+            cs[..., :, None] - cs[..., None, :], -jnp.inf,
         ))  # [B, c, G, per, Q, Q]
         scores = jnp.einsum("bcign,bcjgn->bcgij", C_in, B_in)
         weights = scores[:, :, :, None] * decay * dt_last[..., None, :]
@@ -223,7 +247,8 @@ def conv_over_episodes(inputs, tail, done, taps, bias):
 
     inputs [B, T, C]; tail [K - 1, B, C], the K - 1 inputs before the
     unroll as the state holds them; done [B, T]; taps [K, C] (the last
-    is the step's own); bias [C]. A tap is read only where no episode
+    is the step's own); bias [C], or None for a convolution without one
+    (models/qwen3next.py). A tap is read only where no episode
     ended between its step and the step it is read at. Returns (the
     convolution [B, T, C] in float32, before the silu; the tail the
     next unroll starts from, cut at the last episode end)."""
@@ -238,7 +263,7 @@ def conv_over_episodes(inputs, tail, done, taps, bias):
     inputs = jnp.concatenate(
         [tail.transpose(1, 0, 2), inputs.astype(jnp.float32)], axis=1
     )  # [B, K - 1 + T, C]: times -(K - 1) .. T - 1
-    conv = bias.astype(jnp.float32)
+    conv = 0.0 if bias is None else bias.astype(jnp.float32)
     for k in range(K):
         conv = conv + taps[k] * jnp.where(
             (ends_of[:, k : k + steps] == ends)[..., None],
@@ -261,7 +286,7 @@ def gated_group_norm(y, z, scale, groups, eps):
     return gated.reshape(y.shape) * scale
 
 
-def _dt_bias_init(low, high, floor):
+def dt_bias_init(low, high, floor):
     """The bias whose softplus is log-uniform in [low, high] (clipped
     at `floor`): what the config's `time_step_*` keys seed."""
     def init(key, shape, dtype=jnp.float32):
@@ -275,7 +300,7 @@ def _dt_bias_init(low, high, floor):
     return init
 
 
-def _uniform_between(low, high, transform=lambda value: value):
+def uniform_between(low, high, transform=lambda value: value):
     def init(key, shape, dtype=jnp.float32):
         return transform(jax.random.uniform(key, shape, dtype, low, high))
 
@@ -327,11 +352,11 @@ class _MambaBlock(nn.Module):
             xBC, new_tail = conv_over_episodes(
                 xBC, tail, done,
                 self.param(
-                    "conv_kernel", _uniform_between(-bound, bound),
+                    "conv_kernel", uniform_between(-bound, bound),
                     (K, channels),
                 ),
                 self.param(
-                    "conv_bias", _uniform_between(-bound, bound), (channels,)
+                    "conv_bias", uniform_between(-bound, bound), (channels,)
                 ),
             )
             xBC = nn.silu(xBC)
@@ -340,11 +365,11 @@ class _MambaBlock(nn.Module):
             dt = nn.softplus(
                 dt.astype(jnp.float32)
                 + self.param(
-                    "dt_bias", _dt_bias_init(*self.time_step), (H,)
+                    "dt_bias", dt_bias_init(*self.time_step), (H,)
                 )
             )
             A = -jnp.exp(self.param(
-                "A_log", _uniform_between(1.0, 16.0, jnp.log), (H,)
+                "A_log", uniform_between(1.0, 16.0, jnp.log), (H,)
             ))
             heads_x = xBC[..., :inner].reshape(rows, steps, H, P)
             y, new_carried = ssd_scan(

@@ -103,6 +103,16 @@ _LANES = 128
 # operands; the chip's default is 16 MiB of its 128.
 _VMEM_LIMIT = 48 * 1024 * 1024
 
+
+def _vmem_limit(d):
+    """A cell's scoped VMEM for heads of `d`: the row operands (q, out,
+    dout, dq, the accumulator: [rows, d]) and the key tiles grow with
+    the head, the [rows, block] intermediates do not. Heads of 256
+    (models/qwen3next.py: 2,048 rows a cell, float32 operands) needed
+    48.4 MB in the backward cell; the chip has 128 MiB."""
+    return _VMEM_LIMIT * max(1, d // _LANES)
+
+
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
@@ -320,7 +330,7 @@ def _forward_call(q, k, v, mask, groups, interpret, precise=False):
         ],
         interpret=interpret,
         name="fused_attend_forward",
-        **_compiler_params(interpret),
+        **_compiler_params(interpret, _vmem_limit(d)),
     ))(q, k, v, mask)
 
 
@@ -355,7 +365,7 @@ def _backward_call(q, k, v, mask, out, lse, dout, groups, first_block,
         ],
         interpret=interpret,
         name="fused_attend_backward",
-        **_compiler_params(interpret),
+        **_compiler_params(interpret, _vmem_limit(d)),
     ))(q, k, v, mask, out, lse, dout)
 
 
