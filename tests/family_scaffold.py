@@ -106,6 +106,23 @@ def learner_batch(seed, done_steps, t=6):
     )
 
 
+def forward_stats(model, params, rows, done_steps, t):
+    """The update's stats from the forward alone (`learner.compute_
+    loss`, no gradient) on the toy learner batch side by side until it
+    is `rows` wide, from empty states: enough tokens for a window of
+    the sorted rows to have rungs, through the interpreted kernels."""
+    batch = {
+        k: jnp.concatenate([v] * (rows // B), axis=1)
+        for k, v in learner_batch(3, done_steps, t=t).items()
+    }
+    hp = learner_lib.HParams(batch_size=rows, unroll_length=t - 1)
+    jitted = jax.jit(lambda p: learner_lib.compute_loss(
+        model, p, batch, model.initial_state(rows), hp
+    ))
+    _, stats = jitted(params)
+    return stats
+
+
 def _normal(seed, shape, scale):
     return scale * jax.random.normal(jax.random.PRNGKey(seed), shape)
 

@@ -30,7 +30,19 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     VMEM (no f32 array over the keys is in the program: the fused pass
     compiles at a head size it had never run); the three DeltaNet
     layers' matrix states [16, 32, 128, 128] are in it, and no array of
-    the chunked scan is larger than a chunk's [64, 64] a head."""
+    the chunked scan is larger than a chunk's [64, 64] a head. The
+    experts' kernels see one rung at a time of the sorted rows (PR 47:
+    5,120 rows, twice an even load's, of the 40,960 that 32 held under
+    10 chosen can draw): no f32 array of 40,960 rows is left at the
+    experts' hidden width, at the model's only the three gathers by
+    `slot` a layer from a rung's table (the combine's sum, its gates'
+    gradient, the dispatch's backward), and the sweep's loops are in
+    the program. By
+    `memory_analysis` 11.078 GiB, 13.335 with the copy (10.347 / 12.604
+    before the sweep): it counts the backward loops' carried gradients,
+    3 x [32, 2048, 512] f32 a layer, apart from the heap they share
+    with everything else by the TPU backend's own account ("Total hbm
+    usage" 10.58G against 10.62G), which is what the chip reads."""
     from perfbench import manifest
     from perfbench.drivers import learner as learner_driver
     from torchbeast_tpu import monobeast
@@ -93,3 +105,21 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     assert text.count("fused_attend_backward") >= 1
     # The carried matrix states are the program's arguments.
     assert (32, rows, 128, 128) in shapes
+    # The sorted rows of all the assignments are never an operand of a
+    # kernel, a cut or the activation: they see a rung of them.
+    from torchbeast_tpu.models import moe
+
+    tokens = (steps + 1) * rows
+    rung, window = moe.window_rungs(tokens, 10, 32, 512)
+    assert (rung, window) == (5120, 10 * tokens)
+    assert not {s for s in shapes if s[0] == window and s[-1] == 512}
+    assert {(rung, 2048), (rung, 512)} <= shapes
+    long_rows = re.findall(
+        r"^\s+%%\S+ = \(?f32\[%d,2048\][^\n]*" % window, text, re.M
+    )
+    assert 0 < len(long_rows) <= 12, len(long_rows)
+    assert all('/gather"' in line for line in long_rows)
+    # A forward and a backward loop a MoE part, their turns counted on
+    # the device from the step's own group sizes.
+    sweeps = re.findall(r"while\([^\n]*op_name=\"[^\"]*/moe/while\"", text)
+    assert len(sweeps) >= 8, len(sweeps)

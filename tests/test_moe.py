@@ -716,48 +716,56 @@ def test_window_dispatch_and_combine_against_plain_gathers(
 
 # --- a window as long as its live rows: the rungs ---------------------------
 
-RUNG_TOKENS, RUNG_K, RUNG_E, RUNG_HELD, RUNG_FIRST = 512, 5, 64, 2, 7
+RUNG_TOKENS, RUNG_E, RUNG_FIRST = 512, 64, 7
+# (K, held): fewer held than chosen (Nemotron-3's 8 under 22), and as
+# many or more (Qwen3-Next's 32 under 10).
+FEWER_HELD, MORE_HELD = (5, 2), (3, 4)
 
 
-def _routed_with(live, seed):
+def _routed_with(live, seed, top_k, held, whole=0):
     """idx [tokens, K], distinct experts a token, `live` of the
-    assignments on the RUNG_HELD experts from RUNG_FIRST on."""
-    held = RUNG_HELD
+    assignments on the `held` experts from RUNG_FIRST on; `whole`
+    tokens have every one of their min(K, held) on them."""
     rng = np.random.default_rng(seed)
     others = [
         e for e in range(RUNG_E) if not RUNG_FIRST <= e < RUNG_FIRST + held
     ]
     idx = np.stack([
-        rng.choice(others, RUNG_K, replace=False) for _ in range(RUNG_TOKENS)
+        rng.choice(others, top_k, replace=False) for _ in range(RUNG_TOKENS)
     ])
-    cells = [(t, c) for t in range(RUNG_TOKENS) for c in range(held)]
+    slots = min(top_k, held)
+    cells = [(t, c) for t in range(whole, RUNG_TOKENS) for c in range(slots)]
     rng.shuffle(cells)
+    cells = [(t, c) for t in range(whole) for c in range(slots)] + cells
+    turn = rng.integers(held, size=RUNG_TOKENS)  # which expert a rank meets
     for t, c in cells[:live]:
-        idx[t, c] = RUNG_FIRST + c
+        idx[t, c] = RUNG_FIRST + (c + turn[t]) % held
     return jnp.asarray(idx, jnp.int32)
 
 
 @functools.lru_cache(maxsize=None)
-def _rung_programs(gated):
+def _rung_programs(gated, held, activation):
     """(ours, plain): jitted value and gradients of the held experts'
     part of the sum by `dropless_experts` and written out, an expert at
     a time over ALL the tokens; traced once for every case."""
     from torchbeast_tpu.models import moe
 
+    act = moe._ACTIVATIONS[activation]
+
     def ours(x, gate, w_gate, w_up, w_down, idx):
         return moe.dropless_experts(
             x, idx, gate, w_gate if gated else None, w_up, w_down,
-            first_of=(RUNG_FIRST, RUNG_E), activation="relu2",
+            first_of=(RUNG_FIRST, RUNG_E), activation=activation,
         )[0]
 
     def plain(x, gate, w_gate, w_up, w_down, idx):
         y = 0.0
-        for c in range(RUNG_HELD):
+        for c in range(held):
             mine = jnp.sum(
                 jnp.where(idx == RUNG_FIRST + c, gate, 0.0), axis=1,
                 keepdims=True,
             )
-            hidden = moe.relu2(x @ (w_gate if gated else w_up)[c])
+            hidden = act(x @ (w_gate if gated else w_up)[c])
             if gated:
                 hidden = hidden * (x @ w_up[c])
             y = y + mine * (hidden @ w_down[c])
@@ -770,6 +778,49 @@ def _rung_programs(gated):
         ))
 
     return program(ours), program(plain)
+
+
+def _swept_against_the_experts_written_out(
+    shape, live, gated, activation, whole=0
+):
+    """Values and the gradients of x, the gates and every weight, by
+    the sweep and by the sum over the held experts written out; the
+    rungs taken are those the live rows fill."""
+    from torchbeast_tpu.models import moe
+
+    top_k, held = shape
+    rungs = moe.window_rungs(RUNG_TOKENS, top_k, held, RUNG_E)
+    assert rungs == (256, RUNG_TOKENS * min(top_k, held))
+    d, f = 8, 16
+    keys = jax.random.split(jax.random.PRNGKey(live), 6)
+    x = jax.random.normal(keys[0], (RUNG_TOKENS, d))
+    gate = jax.random.uniform(keys[1], (RUNG_TOKENS, top_k))
+    w_gate, w_up = (
+        jax.random.normal(k, (held, d, f)) / 3 for k in keys[2:4]
+    )
+    w_down = jax.random.normal(keys[4], (held, f, d)) / 4
+    tangent = jax.random.normal(keys[5], (RUNG_TOKENS, d))
+    idx = _routed_with(live, live, top_k, held, whole)
+    mine = jnp.bincount(idx.reshape(-1), length=RUNG_E)[
+        RUNG_FIRST : RUNG_FIRST + held
+    ]
+    assert int(jnp.sum(mine)) == live
+    assert int(moe.window_sweeps(rungs, mine)) == -(-live // 256)
+    ours, plain = _rung_programs(gated, held, activation)
+    (got, got_grads), (want, want_grads) = (
+        program(tangent, idx, x, gate, w_gate, w_up, w_down)
+        for program in (ours, plain)
+    )
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for name, a, b in zip(
+        ("x", "gate", "w_gate", "w_up", "w_down"), got_grads, want_grads
+    ):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5, err_msg=name)
+    if live:
+        assert np.any(got_grads[3]) and np.any(got_grads[0])
+    else:
+        assert not np.any(got) and not np.any(got_grads[4])
+    return idx
 
 
 @pytest.mark.parametrize(
@@ -788,39 +839,33 @@ def test_window_is_swept_as_far_as_its_live_rows_reach(live, gated):
     held experts written out, with the rows under one rung, exactly
     filling it (SwiGLU experts there), one over it (two rungs), all
     1,024 (every token on both held experts: four) and none."""
-    from torchbeast_tpu.models import moe
+    _swept_against_the_experts_written_out(FEWER_HELD, live, gated, "relu2")
 
-    rungs = moe.window_rungs(RUNG_TOKENS, RUNG_K, RUNG_HELD, RUNG_E)
-    assert rungs == (256, RUNG_TOKENS * RUNG_HELD)
-    d, f = 8, 16
-    keys = jax.random.split(jax.random.PRNGKey(live), 6)
-    x = jax.random.normal(keys[0], (RUNG_TOKENS, d))
-    gate = jax.random.uniform(keys[1], (RUNG_TOKENS, RUNG_K))
-    w_gate, w_up = (
-        jax.random.normal(k, (RUNG_HELD, d, f)) / 3 for k in keys[2:4]
+
+@pytest.mark.parametrize(
+    "live, gated, whole",
+    [(100, False, 0), (256, True, 0), (257, False, 0), (300, True, 60),
+     (1536, True, 512), (0, False, 0)],
+    ids=["under-a-rung", "exactly-a-rung-gated", "one-row-over-a-rung",
+         "tokens-on-K-held-experts-at-once",
+         "collapsed-onto-the-held-experts", "no-row"],
+)
+def test_window_is_swept_where_as_many_are_held_as_chosen(live, gated, whole):
+    """Four of 64 experts held under THREE a token (`held >= K`:
+    Qwen3-Next's 32 of 512 under 10), 512 tokens: a token may land on
+    three held experts at once, so the window is all tokens x 3 = 1,536
+    sorted rows and a token reads its rows back by RANK, three slots,
+    not four; the sweep is the same loop, 256 rows a rung (twice an
+    even load's 96). Against the held experts written out (silu, and
+    SwiGLUs where gated): under a rung, exactly one, one row over, 60
+    tokens with all three ranks on held experts (what `held < K` cannot
+    have), every assignment on them (all six rungs: nothing is dropped
+    at any load) and none."""
+    idx = _swept_against_the_experts_written_out(
+        MORE_HELD, live, gated, "silu", whole
     )
-    w_down = jax.random.normal(keys[4], (RUNG_HELD, f, d)) / 4
-    tangent = jax.random.normal(keys[5], (RUNG_TOKENS, d))
-    idx = _routed_with(live, seed=live)
-    mine = jnp.bincount(idx.reshape(-1), length=RUNG_E)[
-        RUNG_FIRST : RUNG_FIRST + RUNG_HELD
-    ]
-    assert int(jnp.sum(mine)) == live
-    assert int(moe.window_sweeps(rungs, mine)) == -(-live // 256)
-    ours, plain = _rung_programs(gated)
-    (got, got_grads), (want, want_grads) = (
-        program(tangent, idx, x, gate, w_gate, w_up, w_down)
-        for program in (ours, plain)
-    )
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    for name, a, b in zip(
-        ("x", "gate", "w_gate", "w_up", "w_down"), got_grads, want_grads
-    ):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5, err_msg=name)
-    if live:
-        assert np.any(got_grads[3]) and np.any(got_grads[0])
-    else:
-        assert not np.any(got) and not np.any(got_grads[4])
+    on_held = (idx >= RUNG_FIRST) & (idx < RUNG_FIRST + MORE_HELD[1])
+    assert int(jnp.sum(jnp.all(on_held, axis=1))) >= whole
 
 
 @pytest.mark.parametrize(
@@ -829,9 +874,13 @@ def test_window_is_swept_as_far_as_its_live_rows_reach(live, gated):
      (1024, 22, 8, 512, (768, 8192)),  # and its check's four rows
      (24, 5, 2, 8, (48, 48)),  # no room under half: one rung, as it was
      (512, 5, 2, 8, (1024, 1024)),  # an even load fills more than half
-     (4096, 8, 16, 64, ()), (4096, 6, 16, 128, ()), (64, 5, 5, 8, ())],
+     (4096, 10, 32, 512, (5120, 40960)),  # the Qwen3-Next cell's layer
+     (1024, 10, 32, 512, (1280, 10240)),  # and its check's four rows
+     (2592, 8, 16, 64, ()),  # twice the rung is over the window
+     (2592, 6, 16, 128, (4096, 15552)), (64, 5, 5, 8, ())],
     ids=["nemotron3-cell", "nemotron3-check", "toy", "dense-routing",
-         "mellum2", "kanana2", "held-equals-chosen"],
+         "qwen3next-cell", "qwen3next-check", "mellum2", "kanana2",
+         "held-equals-chosen"],
 )
 def test_window_rungs_follow_from_shapes_alone(
     tokens, top_k, held, experts, want
@@ -845,20 +894,22 @@ def test_window_rungs_follow_from_shapes_alone(
         def sweeps(*mine):
             return int(moe.window_sweeps(want, jnp.asarray(mine)))
 
-        assert sweeps(rung, 0) == 1 and sweeps(*[tokens] * held) == (
-            -(-window // rung)
-        )
+        assert sweeps(rung, 0) == 1
+        # Every token on as many held experts as it can choose.
+        assert sweeps(window - 1, 1) == -(-window // rung)
         if rung < window:
             assert sweeps(0, 0) == 0 and sweeps(rung, 1) == 2
 
 
 def test_as_many_held_as_chosen_trace_the_program_they_traced():
-    """Five of eight experts held under five a token (`held >= K`:
-    Mellum2's 16 under 8, Kanana-2's 16 under 6, OLMoE's all): no
-    window, no rung, no loop; the t x K sorted rows permuted as before
-    PR 44, whose jaxpr of value and gradients this is letter for letter
-    (3,217 lines, 29 arrays of the 320 sorted rows at the experts' two
-    widths; the parent commit's text hashed the same)."""
+    """Five of eight experts held under five a token (`held >= K`) at
+    64 tokens, where a rung of 256 rows is not under half the 320
+    sorted rows (as Mellum2's 16 of 64 under 8 at its cell's shapes,
+    and OLMoE's all): no window, no rung, no loop; the t x K sorted
+    rows permuted as before PRs 44 and 47, whose jaxpr of value and
+    gradients this is letter for letter (3,217 lines, 29 arrays of the
+    320 sorted rows at the experts' two widths; the parent commit's
+    text hashed the same)."""
     from torchbeast_tpu.models import moe
 
     tokens, top_k, experts, held, d, f = 64, 5, 8, 5, 8, 16

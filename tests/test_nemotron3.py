@@ -114,16 +114,7 @@ def test_update_stats_say_how_far_the_window_was_swept(to_held, sweeps):
     block = dict(inner["block_1"])
     block["moe"] = dict(block["moe"], e_score_correction_bias=bias)
     params = {"params": dict(inner, block_1=block)}
-    batch = {
-        k: jnp.concatenate([v] * (rows // B), axis=1)
-        for k, v in scaffold.learner_batch(3, ENDS, t=T).items()
-    }
-    hp = learner_lib.HParams(batch_size=rows, unroll_length=T - 1)
-    # The forward alone: 352 tokens through the interpreted kernels.
-    jitted = jax.jit(lambda p: learner_lib.compute_loss(
-        model, p, batch, model.initial_state(rows), hp
-    ))
-    _, stats = jitted(params)
+    stats = scaffold.forward_stats(model, params, rows, ENDS, T)
     held = float(stats["moe_held_assignments"])
     assert held == {None: held, 0: 0, 3.0: 2 * T * rows}[to_held]
     assert float(stats["moe_window_rows"]) == 256 * sweeps

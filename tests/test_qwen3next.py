@@ -47,8 +47,9 @@ ENDS = [(4, 0), (7, 0), (8, 0), (9, 0), (0, 1), (5, 1)]
 def test_family_agrees_with_the_reference(expert_share, ends):
     """Logits, baseline, the states handed on, the loss and every
     gradient, from states an actor carried, with and without episode
-    ends in the batch; 4 of 16 experts held is the cell's path (as many
-    held as a token chooses, or more), 2 of 16 the window's."""
+    ends in the batch; with 4 of 16 experts held (as many as a token
+    chooses, or more) 22 tokens leave no room for a rung and all the
+    sorted rows are permuted, 2 of 16 see a window of one rung."""
     model, params = scaffold.build("qwen3next", expert_share=expert_share)
     state = scaffold.warm_state(model, params, seed=5)
     assert all(np.any(leaf) for leaf in jax.tree_util.tree_leaves(state))
@@ -80,8 +81,51 @@ def test_family_agrees_with_the_reference(expert_share, ends):
         assert "moe_held_assignments" not in stats
     else:
         assert 0 < float(stats["moe_held_assignments"]) < 2 * 3 * T * B
-        # Four held are no fewer than the three chosen: no window.
+        # Four held are no fewer than the three chosen, and 66 sorted
+        # rows are under a rung: no window.
         assert ("moe_window_rows" in stats) == (expert_share == (1, 8))
+
+
+@pytest.mark.parametrize(
+    "expert_share, even_router, sweeps",
+    [((1, 16), False, 1), ((1, 16), True, 0), ((0, 16), True, 5)],
+    ids=["as-routed", "no-row", "every-token-on-three-held-experts"],
+)
+def test_update_stats_say_how_far_the_window_was_swept(
+    expert_share, even_router, sweeps
+):
+    """Four of 64 experts held under three a token (`held >= K`, the
+    cell's side of `moe.window_rungs`), 352 tokens: the window is all
+    1,056 sorted rows, its rungs 256, and the update's stats carry the
+    rows the kernels swept and the layers that needed one rung alone,
+    as the held experts' sizes imply, summed over the two MoE parts:
+    one rung each as initialised; with a router of zeros every token's
+    three are experts 0, 1, 2 (ties go to the first), so none with
+    experts 4-7 held, and with 0-3 held all five rungs, every one of
+    the 1,056 assignments computed (`moe_window_short_applications`
+    0)."""
+    rows = 32
+    model, params = scaffold.build(
+        "qwen3next", expert_share=expert_share, num_experts=64
+    )
+    assert moe.window_rungs(T * rows, 3, 4, 64) == (256, 3 * T * rows)
+    if even_router:
+        inner = dict(params["params"])
+        for name in ("block_1", "block_3"):
+            block = dict(inner[name])
+            router = jax.tree_util.tree_map(
+                jnp.zeros_like, block["moe"]["router"]
+            )
+            block["moe"] = dict(block["moe"], router=router)
+            inner[name] = block
+        params = {"params": inner}
+    stats = scaffold.forward_stats(model, params, rows, ENDS, T)
+    held = float(stats["moe_held_assignments"]) / 2  # a layer
+    if even_router:
+        assert held == (3 * T * rows if sweeps else 0)
+    assert sweeps == -(-held // 256)
+    assert float(stats["moe_window_rows"]) == 2 * 256 * sweeps
+    assert float(stats["moe_window_short_applications"]) == 2 * (sweeps <= 1)
 
 
 def _recurrence(q, k, v, g, beta, state, done):

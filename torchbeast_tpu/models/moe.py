@@ -36,7 +36,10 @@ A `DroplessMoE` may hold a share of its experts (`held`: the chip's
 part of a layer that several chips divide, models/mellum2.py): it
 routes over all of them, has weights for its own alone, and returns
 the part of the sum that its own experts give. The sorted rows of the
-other experts are never visited (the kernels' `group_offset`).
+other experts are never visited (the kernels' `group_offset`), and
+where the shapes leave room (`window_rungs`) never moved either: the
+held experts' rows are one run of the sorted rows, swept a rung at a
+time as far as the step's own rows reach.
 """
 
 import functools
@@ -189,7 +192,7 @@ _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
 def _rows_at(rows, slot):
-    """rows[slot] for slot [tokens, held], zeros where a slot is past
+    """rows[slot] for slot [tokens, slots], zeros where a slot is past
     the rows' end (the sign of a token that chose no such expert)."""
     return rows.at[slot].get(mode="fill", fill_value=0)
 
@@ -197,9 +200,9 @@ def _rows_at(rows, slot):
 @jax.custom_vjp
 def _window_rows(x, token, slot):
     """x[token]: the window's rows [window, d], window row i the row of
-    token[i]. With slot[t, c] the window row where token t meets held
-    expert c (or the window's size: nowhere), the gradient is a gather
-    too, `sum_c grad[slot[t, c]]`, and not the scatter-add of a
+    token[i]. With slot[t, c] the window row of token t's c-th slot
+    (`_Window`; or the window's size: nowhere), the gradient is a
+    gather too, `sum_c grad[slot[t, c]]`, and not the scatter-add of a
     gather's. It counts the held experts' rows alone: the kernels
     visit no other row of the window, whose gradients are zeros."""
     del slot
@@ -220,8 +223,9 @@ _window_rows.defvjp(_window_rows_fwd, _window_rows_bwd)
 @jax.custom_vjp
 def _window_sum(out, gate_held, token, slot, gate_rows):
     """sum_c gate_held[t, c] * out[slot[t, c]] -> [tokens, d]: a token's
-    sum over the held experts it chose, out [window, d] the kernels'
-    rows, `token` and `slot` as `_window_rows` takes them. `gate_rows`
+    sum over the held experts it chose, in the slots' order (by rank
+    that of `tkd,tk->td`); out [window, d] the kernels' rows, `token`
+    and `slot` as `_window_rows` takes them. `gate_rows`
     [window] is `gate_held` again, laid by window row with zeros at the
     rows that are no held expert's; the backward pass alone reads it
     (`grad[token] * gate_rows` is the gradient of `out`, a gather
@@ -246,25 +250,30 @@ _window_sum.defvjp(_window_sum_fwd, _window_sum_bwd)
 
 
 class _Window(NamedTuple):
-    """Which assignments a rung of the window of sorted rows holds."""
+    """Which assignments a rung of the window of sorted rows holds. A
+    token reads its rows back from min(held, K) slots: with `held < K`
+    one a held expert (it lands on each at most once), else one a rank
+    (it may land on K held experts at once, never on more)."""
 
     order: jax.Array  # [rung] the (token, rank) assignment of row i
     token: jax.Array  # [rung] its token: order // K
-    chose: jax.Array  # [tokens, K, held] token t's rank k is held expert c
-    slot: jax.Array  # [tokens, held] the row where t meets c, else `rung`
+    # held < K: [tokens, K, held], token t's rank k is held expert c;
+    # else [tokens, K], token t's rank k is a held expert.
+    chose: jax.Array
+    slot: jax.Array  # [tokens, slots] the row of t's slot c, else `rung`
     live: jax.Array  # [] how many rows, the first, are held experts' rows
 
 
 def _window_dispatch(x, idx, order, inverse, sizes, first, held, rung=None,
                      at_row=0):
-    """`rung` rows (unless told, all tokens x held that the held
+    """`rung` rows (unless told, all tokens x min(held, K) that the held
     experts can draw) of the window of the sorted rows that starts at
     expert `first`'s, from the window's row `at_row` on: (rows [rung,
     d], the kernels' group sizes [held + 1], the `_Window`). One gather
     of the rung's rows from x; `order`, `inverse`, `sizes` as
     `dropless_experts` has them."""
     tokens, K = idx.shape
-    rung = rung or tokens * held
+    rung = rung or tokens * min(held, K)
     start = jnp.sum(sizes[:first]) + at_row
     # What of each held expert's rows, one run after another from the
     # window's row 0, lies within this rung.
@@ -276,13 +285,16 @@ def _window_dispatch(x, idx, order, inverse, sizes, first, held, rung=None,
     order = jax.lax.dynamic_slice_in_dim(
         jnp.pad(order, (0, rung)), start, rung
     )
-    chose = idx[:, :, None] == first + jnp.arange(held)
-    row = jnp.sum(
-        jnp.where(chose, inverse.reshape(tokens, K, 1) - start, 0), axis=1
-    )
-    slot = jnp.where(
-        chose.any(axis=1) & (row >= 0) & (row < rung), row, rung
-    )
+    if held < K:
+        chose = idx[:, :, None] == first + jnp.arange(held)
+        row = jnp.sum(
+            jnp.where(chose, inverse.reshape(tokens, K, 1) - start, 0), axis=1
+        )
+        hit = chose.any(axis=1)
+    else:
+        chose = hit = (idx >= first) & (idx < first + held)
+        row = inverse.reshape(tokens, K) - start
+    slot = jnp.where(hit & (row >= 0) & (row < rung), row, rung)
     at = _Window(order, order // K, chose, slot, live)
     # The held experts' groups, and one more of whatever else the rung
     # took in, which no expert visits.
@@ -291,11 +303,16 @@ def _window_dispatch(x, idx, order, inverse, sizes, first, held, rung=None,
 
 
 def _window_combine(out, gate, at):
-    """sum over the held experts c that token t chose of its gate there
+    """sum over the held experts that token t chose of its gate there
     times the kernels' row for it: out [window, d], gate [tokens, K] ->
-    [tokens, d]. One gather of tokens x held rows, a sum over held."""
+    [tokens, d]. One gather of tokens x slots rows, a sum over slots."""
     # Linear in the gates: the router's gradient is what it was.
-    gate_held = jnp.sum(jnp.where(at.chose, gate[:, :, None], 0.0), axis=1)
+    if at.chose.ndim == 3:
+        gate_held = jnp.sum(
+            jnp.where(at.chose, gate[:, :, None], 0.0), axis=1
+        )
+    else:
+        gate_held = jnp.where(at.chose, gate, 0.0)
     gate_rows = jnp.where(
         jnp.arange(at.order.shape[0]) < at.live,
         gate.reshape(-1)[at.order], 0.0,
@@ -443,18 +460,20 @@ _RUNG_HEADROOM = 2.0
 
 def window_rungs(tokens, top_k, held, experts):
     """(rung, window) of the held experts' sorted rows, from shapes
-    alone; () where no window is cut (`held >= top_k`). The window is
-    tokens x held rows, the most the routing can send to `held`
-    experts. It is swept `rung` rows at a time: the rows an even load
-    sends there (tokens x top_k x held / experts) with `_RUNG_HEADROOM`,
-    in whole row tiles of the grouped kernels, where that is under
-    half the window; else the window is its one rung."""
-    if held >= top_k:
-        return ()
-    window, tile = tokens * held, _GMM_TILING[0]
+    alone; () where no window is cut. The window is tokens x min(held,
+    top_k) rows, the most the routing can send to `held` experts. It
+    is swept `rung` rows at a time: the rows an even load sends there
+    (tokens x top_k x held / experts) with `_RUNG_HEADROOM`, in whole
+    row tiles of the grouped kernels, where that is under half the
+    window. Else, with `held < top_k`, the window is its one rung;
+    with `held >= top_k` the window is all the sorted rows, one rung of
+    it cuts nothing, and none is cut."""
+    window, tile = tokens * min(held, top_k), _GMM_TILING[0]
     even = tokens * top_k * held / experts
     rung = -(-math.ceil(_RUNG_HEADROOM * even) // tile) * tile
-    return (rung if 2 * rung < window else window, window)
+    if 2 * rung < window:
+        return (rung, window)
+    return (window, window) if held < top_k else ()
 
 
 def window_sweeps(rungs, mine):
@@ -577,29 +596,30 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None,
     `first_of` = (first, E) when the weights are those of experts first
     .. first + C - 1 of E ([C, d, f], [C, f, d]): the sum then runs over
     the assignments to those alone, the sizes are still all E experts'.
-    With C >= K (or all experts held) every one of the t x K sorted
-    rows is moved: x repeated K times and permuted, the kernels' rows
-    permuted back and summed K a token. With C < K the held experts'
-    rows lie within t x C of them, and a window of the sorted rows is
-    moved alone: its rows gathered from x, t x C gathered from the
-    kernels' output and summed C a token (`_window_dispatch`,
-    `_window_combine`), backward by gathers of as many; no array of
-    t x K rows is built. The window is t x C rows long and, where the
-    shapes leave room for it (`window_rungs`), swept a rung at a time
-    only as far as the step's own rows reach, counted on the device.
-    """
+    Those experts' rows are one run of the sorted rows, within a window
+    of t x min(C, K) of them. Where `window_rungs` cuts it, that window
+    is moved alone: a rung's rows gathered from x, t x min(C, K)
+    gathered from the kernels' output and summed as many a token
+    (`_window_dispatch`, `_window_combine`), backward by gathers of as
+    many; where the shapes leave room for a rung short of the window,
+    it is swept a rung at a time only as far as the step's own rows
+    reach, counted on the device. Elsewhere (all experts held, or C >=
+    K and no room) every one of the t x K sorted rows is moved: x
+    repeated K times and permuted, the kernels' rows permuted back and
+    summed K a token."""
     tokens, K = idx.shape
     first, E = first_of or (None, w_up.shape[0])
     terms = _terms_traced_under()
-    # Fewer experts held than a token chooses (models/nemotron3.py: 8
-    # of 512 under 22 a token): a token lands on each expert at most
-    # once, so at most tokens x held of the tokens x K sorted rows are
-    # these experts', one contiguous run. That window is cut out of the
-    # sorted INDICES, and rows are gathered by them alone: the window's
-    # from x for the kernels, tokens x held from the kernels' output
-    # for the sum. The shapes decide, once, at trace time, whether
-    # there is a window and how long a rung of it is; the step's own
-    # sizes, on the device, how many rungs it takes.
+    # A share of the experts held (models/nemotron3.py: 8 of 512 under
+    # 22 a token; models/qwen3next.py: 32 of 512 under 10): a token
+    # lands on each expert at most once, so at most tokens x min(held,
+    # K) of the tokens x K sorted rows are these experts', one
+    # contiguous run. That window is cut out of the sorted INDICES, and
+    # rows are gathered by them alone: a rung's from x for the kernels,
+    # tokens x min(held, K) from the kernels' output for the sum. The
+    # shapes decide, once, at trace time, whether there is a window and
+    # how long a rung of it is; the step's own sizes, on the device,
+    # how many rungs it takes.
     held = w_up.shape[0]
     rungs = () if first is None else window_rungs(tokens, K, held, E)
     with jax.named_scope("moe_dispatch"):
@@ -827,9 +847,9 @@ class DroplessMoE(nn.Module):
                 )
                 rungs = window_rungs(tokens, K, count, E)
                 if rungs:
-                    # Fewer held than chosen: the rows of the window
-                    # the kernels swept, and whether one rung short of
-                    # the whole window held them all.
+                    # A window is cut: the rows of it the kernels
+                    # swept, and whether one rung short of the whole
+                    # window held them all.
                     swept = window_sweeps(rungs, mine).astype(jnp.float32)
                     sow_stat(
                         self, "moe_window_rows", swept * rungs[0], "sum"
