@@ -37,12 +37,17 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     experts' hidden width, at the model's only the three gathers by
     `slot` a layer from a rung's table (the combine's sum, its gates'
     gradient, the dispatch's backward), and the sweep's loops are in
-    the program. By
-    `memory_analysis` 11.078 GiB, 13.335 with the copy (10.347 / 12.604
-    before the sweep): it counts the backward loops' carried gradients,
-    3 x [32, 2048, 512] f32 a layer, apart from the heap they share
-    with everything else by the TPU backend's own account ("Total hbm
-    usage" 10.58G against 10.62G), which is what the chip reads."""
+    the program. The triangular solve is 36 products at the highest
+    in the whole update (PR 48): a layer's ten forward and the two of
+    its closed-form backward, the rematerialised block solving no
+    second time (120 when JAX differentiated the doubling). By
+    `memory_analysis` 9.888 GiB, 12.144 with the copy (11.078 / 13.335
+    with the solve's levels kept for autodiff; 10.347 / 12.604 before
+    the sweep): it counts the backward loops' carried gradients, 3 x
+    [32, 2048, 512] f32 a layer, apart from the heap they share with
+    everything else by the TPU backend's own account ("Total hbm usage"
+    9.95G; 10.58G before PR 48, 10.62G before the sweep), which is
+    what the chip reads."""
     from perfbench import manifest
     from perfbench.drivers import learner as learner_driver
     from torchbeast_tpu import monobeast
@@ -119,6 +124,15 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     )
     assert 0 < len(long_rows) <= 12, len(long_rows)
     assert all('/gather"' in line for line in long_rows)
+    # A DeltaNet layer solves once: ten products forward, two backward.
+    solves = [
+        line for line in text.splitlines()
+        if " convolution(" in line and "/delta_solve/" in line
+    ]
+    assert len(solves) == 3 * (10 + 2), len(solves)
+    assert all(
+        "operand_precision={highest,highest}" in line for line in solves
+    )
     # A forward and a backward loop a MoE part, their turns counted on
     # the device from the step's own group sizes.
     sweeps = re.findall(r"while\([^\n]*op_name=\"[^\"]*/moe/while\"", text)

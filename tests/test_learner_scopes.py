@@ -108,3 +108,16 @@ def test_family_scope_reaches_the_compiled_hlo(qwen3next_op_names, scope):
         assert all(_in_scope(n, "delta_scan") for n in inside)
     if scope == "delta_solve":
         assert all(_in_scope(n, "delta_intra") for n in inside)
+
+
+def test_the_solves_own_backward_is_under_its_calls_scopes(qwen3next_op_names):
+    """`unit_lower_inverse` is a `custom_vjp`: its backward rule's two
+    products carry the scopes of the call they are the backward of, so
+    a trace split by scope charges them to `delta_solve`."""
+    backward = [
+        n for n in qwen3next_op_names
+        if _in_scope(n, "delta_solve") and "transpose(" in n
+        and n.endswith("dot_general")
+    ]
+    assert backward
+    assert all(_in_scope(n, "delta_intra") for n in backward)

@@ -242,6 +242,128 @@ def test_the_solve_is_the_inverse_and_keeps_exact_zeros(size):
     )
 
 
+# The solve as JAX differentiates it when left alone: the block doubling
+# that `unit_lower_inverse` runs forward, every level's two products
+# kept and transposed. What the closed form is held to.
+_doubling_by_autodiff = qwen3next._block_doubling
+
+
+def _products(jaxpr, both_shaped=None):
+    """The `dot_general`s of a jaxpr and of every jaxpr inside it (a
+    rematerialised block's, a loop's); with `both_shaped`, those whose
+    two operands both end in that shape."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and (
+            both_shaped is None or all(
+                v.aval.shape[-len(both_shaped):] == both_shaped
+                for v in eqn.invars
+            )
+        ):
+            found += 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    found += _products(inner, both_shaped)
+    return found
+
+
+@pytest.mark.parametrize("ended", [False, True], ids=["whole", "episode-end"])
+@pytest.mark.parametrize("size", [1, 2, 8, 64])
+def test_the_solves_closed_form_gradient_is_the_doublings_by_autodiff(
+    size, ended
+):
+    """`unit_lower_inverse`'s backward (-T^T T_bar T^T below the
+    diagonal, T the one residual) against JAX's own of the ten
+    products, on the six axes `delta_scan` hands it ([B, c, Hk, per, Q,
+    Q]), to 1e-5 of the gradient's scale; with a block of exact zeros
+    below the diagonal (an episode end between its steps); and an input
+    that has entries ON and ABOVE the diagonal, which the solve does
+    not read: the value is the same and their gradient zeros."""
+    rng = np.random.default_rng(size + ended)
+    shape = (2, 3, 2, 2, size, size)
+    L = np.tril(rng.uniform(-1, 1, shape), -1).astype(np.float32)
+    cut = size // 2
+    if ended:
+        L[..., cut:, :cut] = 0.0
+    weight = jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+
+    def gradient(solve):
+        return jax.jit(jax.value_and_grad(
+            lambda L: jnp.sum(jnp.sin(solve(L)) * weight)
+        ))
+
+    closed_form = gradient(qwen3next.unit_lower_inverse)
+    value, got = closed_form(L)
+    want_value, want = gradient(_doubling_by_autodiff)(L)
+    assert float(value) == float(want_value)  # the same forward
+    scale = max(float(jnp.max(jnp.abs(want))), 1e-30)
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-5 * scale)
+    assert got.shape == shape and (size == 1 or np.any(got))
+    assert not np.any(np.triu(got))
+    full = L + np.triu(rng.uniform(-1, 1, shape)).astype(np.float32)
+    value_full, got_full = closed_form(full)
+    assert float(value_full) == float(value)
+    assert not np.any(np.triu(got_full))
+    np.testing.assert_array_equal(got_full, got)
+
+
+def test_the_solves_backward_is_two_products_and_one_residual():
+    """What pins the mechanism: the gradient of a scalar of the solve
+    on a [64, 64] system is 12 `dot_general`s (the ten of the forward
+    and the closed form's two; JAX's of the doubling 30), all at the
+    highest precision, and the forward alone the doubling's ten."""
+    L = jnp.zeros((64, 64), jnp.float32)
+
+    def gradient_of(solve):
+        return jax.make_jaxpr(
+            jax.grad(lambda L: jnp.sum(jnp.sin(solve(L))))
+        )(L).jaxpr
+
+    ours = gradient_of(qwen3next.unit_lower_inverse)
+    assert _products(ours) == 12
+    assert _products(gradient_of(_doubling_by_autodiff)) == 30
+    assert _products(
+        jax.make_jaxpr(qwen3next.unit_lower_inverse)(L).jaxpr
+    ) == 10
+    text = str(ours)
+    assert text.count("Precision.HIGHEST") >= 12
+    assert "Precision.HIGH," not in text and "DEFAULT" not in text
+
+
+def test_a_rematerialised_deltanet_block_solves_once():
+    """`--remat all` on the toy family (chunks of 4: one level of the
+    doubling, two [4, 4] products a solve): the update's gradient holds
+    the solve's forward products ONCE and the closed form's two, as the
+    program without rematerialisation does: the block's second forward
+    reads the inverse it kept (`delta_solved`, the one name the block's
+    policy saves) and does not solve again, which would be two more;
+    and the counter says what is kept, 4 bytes x rows x chunks x value
+    heads x 4 x 4."""
+    model, params = scaffold.build("qwen3next")
+    batch = scaffold.learner_batch(1, ENDS, t=T)
+    state = model.initial_state(B)
+
+    def solves(model):
+        jaxpr, (_, stats, _) = jax.make_jaxpr(
+            scaffold.loss_and_grads.__wrapped__(model, jit=False),
+            return_shape=True,
+        )(params, batch, state)
+        return _products(jaxpr.jaxpr, both_shaped=(4, 4)), stats
+
+    plain, stats = solves(model)
+    kept, _ = solves(model.clone(remat=True))
+    assert plain == kept == 2 + 2
+    assert "delta_solved_bytes_kept" not in stats
+    stats_kept = scaffold.forward_stats(
+        model.clone(remat=True), params, B, ENDS, T
+    )
+    assert float(stats_kept["delta_solved_bytes_kept"]) == (
+        4 * B * 3 * 4 * 4 * 4
+    )
+
+
 @pytest.mark.parametrize("unrolls", [0, 2], ids=["empty", "warm"])
 def test_batch_forward_equals_stepwise_acting_through_the_carried_states(
     unrolls

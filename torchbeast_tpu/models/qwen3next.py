@@ -73,8 +73,12 @@ blocks below the diagonal: ten [64, 64] matmuls a chunk and head,
 every intermediate a true inverse of a part of I + L (bounded: the
 Neumann product (I - L)(I + L^2)... is the same in exact arithmetic and
 is not, its powers of L cancel), and no step-by-step substitution. The
-backward pass is JAX's of this form. T = 1 is a chunk of one step: the
-recurrence.
+solve is differentiated as one unit: W (I + L) = I gives dW = -W dL W,
+so <W_bar, dW> = <-W^T W_bar W^T, dL>: the cotangent of L is that
+product's part below the diagonal, two matmuls with W the one residual
+(JAX's own of the doubling is twenty and keeps every level), and a
+rematerialised block keeps W and does not solve again (`make_block`).
+T = 1 is a chunk of one step: the recurrence.
 
 A chip may hold a share of each layer's routed experts (`--expert_share
 i/n`, as models/mellum2.py); mixers, router and the shared expert are
@@ -92,6 +96,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from torchbeast_tpu.models.moe import DroplessMoE, held_experts
 from torchbeast_tpu.models.nemotron3 import (
@@ -145,7 +150,7 @@ PUBLISHED = {
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def unit_lower_inverse(L):
+def _block_doubling(L):
     """(I + L)^-1 for L [..., C, C] strictly lower triangular, by block
     doubling: with X the inverses of I + L's diagonal blocks of size s
     (zeros elsewhere) and C its blocks below them inside the diagonal
@@ -176,6 +181,45 @@ def unit_lower_inverse(L):
         )
         size *= 2
     return inverse
+
+
+# What a rematerialised DeltaNet block keeps of its forward pass: the
+# solve's result, which is all its backward reads (`make_block`).
+SOLVED = "delta_solved"
+
+
+@jax.custom_vjp
+def unit_lower_inverse(L):
+    """(I + L)^-1 for L [..., C, C], of which the part strictly below
+    the diagonal is read (`_block_doubling`), differentiated as ONE
+    unit: the inverse is the only residual and its cotangent two
+    products (the module's header), where JAX's of the doubling is
+    twenty and keeps every level."""
+    return _block_doubling(L)
+
+
+def _solve_forward(L):
+    solved = checkpoint_name(_block_doubling(L), SOLVED)
+    return solved, solved
+
+
+def _solve_backward(solved, cotangent):
+    # Traced under the name stack of the call it is the backward of:
+    # `delta_scan`'s scopes name these two products too.
+    C = solved.shape[-1]
+    transposed = jnp.swapaxes(solved, -1, -2)
+    return (jnp.where(
+        np.tril(np.ones((C, C), bool), -1),
+        -jnp.matmul(
+            transposed,
+            jnp.matmul(cotangent, transposed, precision=_HIGHEST),
+            precision=_HIGHEST,
+        ),
+        0.0,
+    ),)
+
+
+unit_lower_inverse.defvjp(_solve_forward, _solve_backward)
 
 
 def delta_scan(q, k, v, g, beta, state, done, chunk):
@@ -345,6 +389,9 @@ class _DeltaNetBlock(nn.Module):
     conv_kernel: int
     chunk_size: int
     rms_norm_eps: float
+    # Whether this block is rematerialised under the policy that keeps
+    # its solves' results (`make_block`): what the counter says.
+    keeps_solved: bool = False
     dtype: Any = jnp.float32
 
     @nn.compact
@@ -433,17 +480,22 @@ class _DeltaNetBlock(nn.Module):
             # through them; the chunks the unroll's scan was cut into
             # and the episode ends a row had, which every layer says
             # alike.
+            Q, _, chunks = chunk_plan(steps, self.chunk_size)
             for name, value, fold in (
                 ("delta_applications", 1.0, "sum"),
                 ("delta_state_bytes_per_row",
                  4 * (Hv * Dk * Dv + (K - 1) * channels), "sum"),
-                ("delta_chunks", chunk_plan(steps, self.chunk_size)[2],
-                 "same"),
+                ("delta_chunks", chunks, "same"),
                 ("delta_resets_per_row",
                  jnp.mean(jnp.sum(done.astype(jnp.float32), axis=1)),
                  "same"),
             ):
                 sow_stat(self, name, value, fold)
+            if self.keeps_solved:
+                sow_stat(
+                    self, "delta_solved_bytes_kept",
+                    4 * rows * chunks * Hv * Q * Q, "sum",
+                )
         return x, (new_carried.transpose(1, 0, 2, 3), new_tail)
 
 
@@ -629,6 +681,7 @@ class Qwen3NextNet(TransformerNet):
             d_model=self.d_model, rms_norm_eps=self.rms_norm_eps,
             dtype=self.dtype, name=name,
         )
+        kept = None  # what a rematerialised block keeps of its forward
         if layer % 2:
             cls, fields = _MoEBlock, dict(
                 num_experts=self.num_experts, held=self.held_experts(),
@@ -651,8 +704,15 @@ class Qwen3NextNet(TransformerNet):
                 value_heads=self.delta_value_heads,
                 key_dim=self.delta_key_dim, value_dim=self.delta_value_dim,
                 conv_kernel=self.conv_kernel, chunk_size=self.chunk_size,
+                keeps_solved=self.remat,
             )
-        return (nn.remat(cls) if self.remat else cls)(**fields, **shared)
+            # Its second forward does not solve again: the inverses (4
+            # Hv Q^2 bytes a row and chunk) are all the solve's backward
+            # reads, and cost less to keep than to make at that peak.
+            kept = jax.checkpoint_policies.save_only_these_names(SOLVED)
+        return (nn.remat(cls, policy=kept) if self.remat else cls)(
+            **fields, **shared
+        )
 
     @nn.nowrap
     def make_final_norm(self):
