@@ -26,7 +26,12 @@ import jax  # noqa: E402
 
 from torchbeast_tpu.utils.xla_cache import use_compile_cache  # noqa: E402
 
-# Persistent compilation cache: repeat suite runs skip XLA recompiles.
+# Persistent compilation cache. The driver's checkout starts cold every
+# time, so what it does there is share each compile of 0.5 s or more
+# between the six workers of ONE run; a one-op program is under that
+# and is compiled again in every worker, which is why the tests trace
+# whole modules (tests/family_scaffold.py). On a builder's machine a
+# repeat run also skips what the last one compiled.
 use_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 

@@ -66,7 +66,8 @@ def make_batch():
 model = create_model("shallow", num_actions=A, use_lstm=True)
 batch = make_batch()
 state = model.initial_state(B)
-params = model.init(
+params_fn = jax.jit(model.init)
+params = params_fn(
     {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
     batch,
     state,
@@ -133,7 +134,8 @@ batch2 = {
     "baseline": rng2.standard_normal((T2 + 1, B)).astype(np.float32),
 }
 state2 = model2_single.initial_state(B)
-params2 = model2_single.init(
+params2_fn = jax.jit(model2_single.init)
+params2 = params2_fn(
     {"params": jax.random.PRNGKey(2), "action": jax.random.PRNGKey(3)},
     batch2,
     state2,

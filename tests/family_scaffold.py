@@ -1,37 +1,59 @@
-"""The one scaffold under the policy families' tier-1 tests: a toy
-family built and compiled ONCE a process.
+"""The one scaffold under tier-1's tests of flax modules: a module is
+traced, never run op by op, and a toy family is built and compiled ONCE
+a process.
 
-What every family's file needs is here and nowhere else: the toy batch
-(`inputs`, `learner_batch`), `build(family, **overrides) -> (model,
-params)`, `warm_state`, `reference_config`, and jitted callables for
-the four programs the cases run again and again (`forward`,
-`loss_and_grads`, `reference_forward`, `reference_loss_and_grads`),
-with the three comparisons several families make in the same words
-(`assert_agrees_with_the_reference`, `assert_stepwise_acting_equals_
-the_batch_forward`, `assert_state_table_acting_equals_the_batch_
-forward`). `build` and the callables are memoised for the life of the
-process (a flax module hashes by its fields), and `build` runs
-`jax.jit(model.init)`: run eagerly, a toy family is 1,300 XLA compiles
-of one op each, 79% of a case (ISSUE 45). So:
+THE RULE (ISSUE 45 found it, ISSUE 49 made it every file's): run
+eagerly, a flax module is one XLA compile an op (a toy family 1,300 of
+them, 79% of a case), and the suite's persistent cache keeps no compile
+under 0.5 s. So every `init`, `apply` and gradient of a module goes
+through `jax.jit`:
 
+- `init(module, rngs, *args)` and `apply(module, **static)` serve ANY
+  flax module (a layer, a block, a whole net), memoised for the life of
+  the process by the module's fields and traced once an input shape. A
+  gradient is `jax.jit(jax.grad(...))` around `module.apply` in the
+  case itself. A plain `jnp` function under test is called through
+  `jax.jit` too, once a shape.
+- For the six policy families: the toy batch (`inputs`,
+  `learner_batch`), `build(family, **overrides) -> (model, params)`,
+  `expert_layer(family, held)`, `warm_state`, `reference_config`, and
+  jitted callables for the four programs the cases run again and again
+  (`forward`, `loss_and_grads`, `reference_forward`,
+  `reference_loss_and_grads`), with the three comparisons several
+  families make in the same words (`assert_agrees_with_the_reference`,
+  `assert_stepwise_acting_equals_the_batch_forward`,
+  `assert_state_table_acting_equals_the_batch_forward`). They are
+  callers of `init` and `apply`, not a second copy.
 - Memoised parameters are READ-ONLY: a case that alters them builds a
   new tree around the leaves it replaces (`dict(inner, ...)`), never
   writes into one.
-- A callable is traced once a model and input shape. A case whose trace
-  must be its own (it monkeypatches a rule that is read at trace time)
-  asks for `loss_and_grads.__wrapped__(model)`; a case that must run
-  eagerly (it reads a sown intermediate, or hands `DeviceStateTable` an
-  `act_fn` the table jits itself) calls `model.apply` and says so.
+- A case whose trace must be its own (it monkeypatches a rule that is
+  read at trace time) jits a function of its own, or asks for
+  `loss_and_grads.__wrapped__(model)`; a case that must run eagerly (it
+  hands `DeviceStateTable` an `act_fn` the table jits itself, or two
+  XLA programs round further apart than its tolerance: ouro's remat
+  case) calls `model.apply` or passes `jit=False`, and says so in one
+  line.
 
 THE RULE FOR THE NEXT FAMILY (a `model_config` PR): one `Family` entry
-below (its class, its `SMALL` table, its reference, and `perturb`: the
-values for what the family starts at zero or one); one file
-`tests/test_<family>.py` with the cases that are the family's own, on
-this scaffold; one file `tests/test_chip_compile_<family>.py` with its
+below (its class, its `SMALL` table, its reference, `perturb`: the
+values for what the family starts at zero or one, and `experts` if it
+can hold a share of a layer's experts: the shares test is then an id of
+`tests/test_families_shares.py::test_the_expert_shares_add_up_to_the_
+uncut_layer`, not a copy); one file `tests/test_<family>.py` with the
+cases that are the family's own, on this scaffold, building no layer by
+hand; one file `tests/test_chip_compile_<family>.py` with its
 whole-cell compile (fixtures: `tests/chip_fixtures.py`); one id in each
-parametrised test of `tests/test_families.py`, `tests/test_monobeast.py
-::test_train_family_through_main` and `tests/test_polybeast.py::test_
-polybeast_train_family`. No edit to another family's file.
+parametrised test of `tests/test_families.py`, `tests/test_families_
+remat.py`, `tests/test_families_shares.py`, `tests/test_monobeast_
+families.py::test_train_family_through_main` and `tests/test_
+polybeast_families.py::test_polybeast_train_family`. No edit to
+another family's file. WHAT A FAMILY COSTS
+tier-1 (CPU-s in the driver's command, the builder's junit file, PR
+49; Qwen3-Next, the dearest): 706, where it was 927: its own files 288
+(215 + 73), its whole-cell compile 110, its ids in the three
+`test_families*` files 52, its case through `main` 13 and through
+`train` 78, and `tests/perfbench/`'s file 165 (a `benchmark` issue's).
 """
 
 import dataclasses
@@ -62,6 +84,7 @@ from torchbeast_tpu.models import (
     Qwen3NextNet,
     kanana2,
     mellum2,
+    moe,
     nemotron3,
     olmoe,
     ouro,
@@ -323,6 +346,60 @@ def _config_qwen3next(model):
     }
 
 
+def _swiglu_shared(x, p):
+    return (
+        jax.nn.silu(x @ p["shared_gate"]["kernel"])
+        * (x @ p["shared_up"]["kernel"])
+    ) @ p["shared_down"]["kernel"]
+
+
+def _relu2_shared(x, p):
+    return jnp.square(jax.nn.relu(x @ p["shared_up"]["kernel"])) @ (
+        p["shared_down"]["kernel"]
+    )
+
+
+def _token_gated_shared(x, p):
+    return jax.nn.sigmoid(
+        x @ p["shared_expert_gate"]["kernel"]
+    ) * _swiglu_shared(x, p)
+
+
+@dataclasses.dataclass(frozen=True)
+class Experts:
+    """A family's expert layer alone, at toy size (`expert_layer`):
+    `fields` are `moe.DroplessMoE`'s, less `held`; `uncut` is laid over
+    them for the layer that `shares` shares add up to
+    (`test_families_shares.py`); `config(E, K, first, count)` is what the
+    reference's `_experts` reads of the share that holds `count` of `E`
+    experts from `first`; `shared(x, params)` the shared expert by
+    hand, which every chip computes alike; `leaves` the layer's
+    parameters by name; `tol` the family's rtol and atol."""
+
+    fields: dict
+    shares: int
+    config: Callable
+    leaves: tuple
+    tol: float
+    uncut: Any = dataclasses.field(default_factory=dict)
+    shared: Optional[Callable] = None
+
+
+_SWIGLU_EXPERTS = ("router", "w_down", "w_gate", "w_up")
+_SWIGLU_SHARED = ("shared_down", "shared_gate", "shared_up")
+
+
+def _experts_config(published, held, **rest):
+    def config(E, K, first, count):
+        return {
+            published: E, held: count,
+            "expert_share": [first // count, E // count],
+            "num_experts_per_tok": K, "norm_topk_prob": True, **rest,
+        }
+
+    return config
+
+
 @dataclasses.dataclass(frozen=True)
 class Family:
     """One policy family at toy size: `net(num_actions=A, **small)` is
@@ -340,6 +417,8 @@ class Family:
     t: int = 6
     # The episode end inside warm_state's first unroll.
     warm_end: Any = (2, 1)
+    # Of a family that can hold a share of each layer's experts.
+    experts: Optional[Experts] = None
 
 
 FAMILIES = {
@@ -362,6 +441,18 @@ FAMILIES = {
             expert_width=24, num_layers=4, memory_len=9,
         ),
         _config_mellum2, _perturb_mellum2,
+        # The shares: 64 experts, top 8, the published counts; held
+        # (0, 16), (16, 16), (32, 16), (48, 16).
+        experts=Experts(
+            dict(d_ff=8, num_experts=8, top_k=2, renormalise=True),
+            shares=4,
+            config=_experts_config(
+                "published_num_experts", "num_experts",
+                load_balance_weight=0.001,
+            ),
+            leaves=_SWIGLU_EXPERTS, tol=1e-5,
+            uncut=dict(num_experts=64, top_k=8),
+        ),
     ),
     # 4 heads of 16, a SwiGLU of 96, 2 layers run 3 times over 5-slot
     # caches (T=6 evicts on the way).
@@ -385,6 +476,29 @@ FAMILIES = {
             shared_experts=2, num_layers=3, memory_len=9,
         ),
         _config_kanana2, _perturb_kanana2,
+        # The shares: 32 experts (the interpreted grouped kernels are
+        # slow over 128), top 6, the same router and biases; held (0, 4),
+        # (4, 4), ... (28, 4), the shared expert COUNTED ONCE.
+        experts=Experts(
+            dict(
+                d_ff=8, num_experts=16, top_k=3, aux_loss_weight=0.0,
+                renormalise=True, scoring="sigmoid", selection_bias=True,
+                bias_update_rate=0.001, routed_scaling=2.448,
+                shared_width=12,
+            ),
+            shares=8,
+            config=_experts_config(
+                "published_n_routed_experts", "n_routed_experts",
+                scoring_func="sigmoid", topk_method="noaux_tc", n_group=1,
+                topk_group=1, routed_scaling_factor=2.448,
+            ),
+            leaves=tuple(sorted(
+                _SWIGLU_EXPERTS + _SWIGLU_SHARED
+                + ("e_score_correction_bias",)
+            )),
+            tol=1e-5, uncut=dict(num_experts=32, top_k=6),
+            shared=_swiglu_shared,
+        ),
     ),
     # One attention layer of 4 query heads of 8 on 2 key/value heads,
     # one latent MoE layer (16 experts of 10 in a latent of 12, top 3, a
@@ -401,6 +515,33 @@ FAMILIES = {
             layer_pattern="MEM*EMM", num_layers=3, memory_len=5,
         ),
         _config_nemotron3, _perturb_nemotron3, t=11,
+        # The shares: eight of 16 experts (the interpreted grouped
+        # kernels are slow over 512; two held under three a token, so a
+        # share's experts see the window of the sorted rows that can be
+        # theirs, models/moe.py), each LIFTED OUT OF THE LATENT by the
+        # one `latent_up` every chip holds, the shared expert COUNTED
+        # ONCE; `latent_down` is applied on every chip alike and is no
+        # part of the sum.
+        experts=Experts(
+            dict(
+                d_ff=8, num_experts=16, top_k=3, aux_loss_weight=0.0,
+                renormalise=True, scoring="sigmoid", selection_bias=True,
+                bias_update_rate=0.001, routed_scaling=5.0,
+                shared_width=12, gated=False, activation="relu2",
+                latent_width=10,
+            ),
+            shares=8,
+            config=_experts_config(
+                "published_n_routed_experts", "n_routed_experts",
+                n_group=1, topk_group=1, routed_scaling_factor=5.0,
+                n_shared_experts=1, mlp_hidden_act="relu2", mlp_bias=False,
+            ),
+            leaves=(
+                "e_score_correction_bias", "latent_down", "latent_up",
+                "router", "shared_down", "shared_up", "w_down", "w_up",
+            ),
+            tol=2e-5, shared=_relu2_shared,
+        ),
     ),
     # One Gated DeltaNet layer of 4 value heads of 5 on 2 key heads of 6,
     # scanned in chunks of 4 steps (the 11 steps of an unroll are two
@@ -417,6 +558,24 @@ FAMILIES = {
             shared_width=12, num_layers=2, memory_len=5,
         ),
         _config_qwen3next, _perturb_qwen3next, t=11,
+        # The shares: four of 16 experts (four held, no fewer than the
+        # three a token chooses: the cell's path), the shared expert
+        # UNDER ITS TOKEN GATE, COUNTED ONCE.
+        experts=Experts(
+            dict(
+                d_ff=8, num_experts=16, top_k=3, aux_loss_weight=0.001,
+                renormalise=True, shared_width=12, shared_token_gate=True,
+            ),
+            shares=4,
+            config=_experts_config(
+                "published_num_experts", "num_experts",
+                hidden_act="silu", router_aux_loss_coef=0.001,
+            ),
+            leaves=tuple(sorted(
+                _SWIGLU_EXPERTS + _SWIGLU_SHARED + ("shared_expert_gate",)
+            )),
+            tol=2e-5, shared=_token_gated_shared,
+        ),
     ),
 }
 
@@ -425,14 +584,59 @@ def family_of(model) -> Family:
     return next(f for f in FAMILIES.values() if isinstance(model, f.net))
 
 
+@functools.lru_cache(maxsize=None)
+def _init(module, jit):
+    return jax.jit(module.init) if jit else module.init
+
+
+def init(module, rngs, *args, jit=True):
+    """`module.init(rngs, *args)` of ANY flax module as one traced
+    program, traced once a module and shape of `args` (a second call
+    runs the compiled program and gives the same tree: read-only, as
+    `build`'s is)."""
+    return _init(module, jit)(rngs, *args)
+
+
+def compile_requests(monkeypatch):
+    """A list that grows by one with every program handed to XLA (what
+    the persistent cache then answers is counted too): what the pins of
+    the rule read (tests/test_family_scaffold.py, test_learner_setup.py)."""
+    from jax._src import compiler
+
+    seen, compile_or_get_cached = [], compiler.compile_or_get_cached
+
+    def counted(*args, **kwargs):
+        seen.append(1)
+        return compile_or_get_cached(*args, **kwargs)
+
+    monkeypatch.setattr(compiler, "compile_or_get_cached", counted)
+    return seen
+
+
+@functools.lru_cache(maxsize=None)
+def _apply(module, jit, static):
+    def run(variables, *args, rngs=None):
+        return module.apply(variables, *args, rngs=rngs, **dict(static))
+
+    return jax.jit(run) if jit else run
+
+
+def apply(module, jit=True, **static):
+    """Jitted `(variables, *args, rngs=None) -> module.apply(variables,
+    *args, rngs=rngs, **static)` of ANY flax module, made once a module
+    and `static` (hashable: `mutable=("losses",)`, a tuple) and traced
+    once an input shape; `jit=False` the same callable eager, for the
+    case that says why."""
+    return _apply(module, jit, tuple(sorted(static.items())))
+
+
 def init_params(model, batch, jit=True):
-    """`model.init` as one program (eagerly it is some hundreds of
-    compiles of one op each), from the keys every case uses."""
-    init = jax.jit(model.init) if jit else model.init
+    """The family's parameters from the keys every case uses."""
     rows = batch["done"].shape[1]
     return init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
-        batch, model.initial_state(rows),
+        batch, model.initial_state(rows), jit=jit,
     )
 
 
@@ -452,16 +656,23 @@ def build(family, **overrides):
     return _build(family, tuple(sorted(overrides.items())))
 
 
-@functools.lru_cache(maxsize=None)
+def expert_layer(family, held=None, tokens=40, seed=0, **overrides):
+    """(layer, x, params): the family's expert layer alone
+    (`Family.experts`) holding `held` (first, count) of its experts,
+    `tokens` rows of 16 columns and the layer's first parameters."""
+    spec = FAMILIES[family].experts
+    layer = moe.DroplessMoE(**dict(spec.fields, held=held, **overrides))
+    x = jax.random.normal(jax.random.PRNGKey(seed), (tokens, 16))
+    params = init(layer, jax.random.PRNGKey(seed + 1), x)
+    assert tuple(sorted(params["params"])) == spec.leaves
+    return layer, x, params
+
+
 def forward(model, jit=True):
     """Jitted `(params, inputs, state) -> (outputs, new state)`, for a
     [T, B] batch and for a T=1 act step alike (a trace a shape).
     `jit=False`: the same callable eager, for the case that says why."""
-
-    def run(params, batch, state):
-        return model.apply(params, batch, state, sample_action=False)
-
-    return jax.jit(run) if jit else run
+    return apply(model, jit, sample_action=False)
 
 
 @functools.lru_cache(maxsize=None)

@@ -2,6 +2,8 @@
 with and without segment (episode-boundary) masking, odd head dims, and
 gradient equivalence."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -15,8 +17,14 @@ from torchbeast_tpu.ops.attention import (
     segment_ids_from_done,
 )
 from torchbeast_tpu.parallel import create_mesh
+from tests import family_scaffold as scaffold
 
 B, T, H, D = 2, 16, 4, 8  # T divisible by the 8-way ring
+
+# The functions under test through `jax.jit`, traced once a shape (run
+# op by op they are an XLA compile an op: tests/family_scaffold.py).
+_causal = jax.jit(causal_attention)
+_ring = jax.jit(ring_attention, static_argnames=("mesh", "axis", "schedule"))
 
 
 def make_qkv(seed=0, t=T):
@@ -36,10 +44,10 @@ def seq_sharded(mesh, x):
 
 def test_causal_attention_is_causal():
     q, k, v = make_qkv()
-    out1 = causal_attention(q, k, v)
+    out1 = _causal(q, k, v)
     # Changing the future must not change the past.
     v2 = v.at[:, -1].set(123.0)
-    out2 = causal_attention(q, k, v2)
+    out2 = _causal(q, k, v2)
     np.testing.assert_allclose(out1[:, :-1], out2[:, :-1], rtol=1e-6)
     assert not np.allclose(out1[:, -1], out2[:, -1])
 
@@ -49,10 +57,10 @@ def test_segment_mask_blocks_cross_episode():
     done = np.zeros((T, B), bool)
     done[T // 2] = True  # episode boundary mid-sequence
     seg = segment_ids_from_done(jnp.asarray(done)).T  # [B, T]
-    out = causal_attention(q, k, v, segment_ids=seg)
+    out = _causal(q, k, v, segment_ids=seg)
     # Changing pre-boundary values must not affect post-boundary outputs.
     v2 = v.at[:, 0].set(55.0)
-    out2 = causal_attention(q, k, v2, segment_ids=seg)
+    out2 = _causal(q, k, v2, segment_ids=seg)
     np.testing.assert_allclose(
         out[:, T // 2 :], out2[:, T // 2 :], rtol=1e-6
     )
@@ -69,13 +77,13 @@ def test_ring_matches_dense(with_segments):
         done[11, 0] = True
         seg = segment_ids_from_done(jnp.asarray(done)).T
 
-    dense = causal_attention(q, k, v, segment_ids=seg)
+    dense = _causal(q, k, v, segment_ids=seg)
 
     qs, ks, vs = (seq_sharded(mesh, x) for x in (q, k, v))
     segs = None
     if seg is not None:
         segs = jax.device_put(seg, NamedSharding(mesh, P(None, "data")))
-    ring = ring_attention(qs, ks, vs, mesh, axis="data", segment_ids=segs)
+    ring = _ring(qs, ks, vs, mesh=mesh, axis="data", segment_ids=segs)
 
     np.testing.assert_allclose(
         np.asarray(ring), np.asarray(dense), rtol=2e-4, atol=2e-5
@@ -88,14 +96,16 @@ def test_ring_gradients_match_dense():
     q, k, v = make_qkv(seed=3)
 
     def dense_loss(q, k, v):
-        return jnp.sum(causal_attention(q, k, v) ** 2)
+        return jnp.sum(_causal(q, k, v) ** 2)
 
     def ring_loss(q, k, v):
-        return jnp.sum(ring_attention(q, k, v, mesh, axis="data") ** 2)
+        return jnp.sum(_ring(q, k, v, mesh=mesh, axis="data") ** 2)
 
-    g_dense = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
+    g_dense_fn = jax.jit(jax.grad(dense_loss, argnums=(0, 1, 2)))
+    g_dense = g_dense_fn(q, k, v)
     qs, ks, vs = (seq_sharded(mesh, x) for x in (q, k, v))
-    g_ring = jax.grad(ring_loss, argnums=(0, 1, 2))(qs, ks, vs)
+    g_ring_fn = jax.jit(jax.grad(ring_loss, argnums=(0, 1, 2)))
+    g_ring = g_ring_fn(qs, ks, vs)
     for gd, gr in zip(g_dense, g_ring):
         np.testing.assert_allclose(
             np.asarray(gr), np.asarray(gd), rtol=2e-3, atol=2e-4
@@ -107,9 +117,9 @@ def test_ring_long_sequence():
     # materialization per device.
     mesh = create_mesh(8)
     q, k, v = make_qkv(seed=4, t=512)
-    dense = causal_attention(q, k, v)
+    dense = _causal(q, k, v)
     qs, ks, vs = (seq_sharded(mesh, x) for x in (q, k, v))
-    ring = ring_attention(qs, ks, vs, mesh, axis="data")
+    ring = _ring(qs, ks, vs, mesh=mesh, axis="data")
     np.testing.assert_allclose(
         np.asarray(ring), np.asarray(dense), rtol=2e-4, atol=2e-5
     )
@@ -126,13 +136,14 @@ def test_zigzag_ring_matches_dense(with_segments):
         done[11, 0] = True
         seg = segment_ids_from_done(jnp.asarray(done)).T
 
-    dense = causal_attention(q, k, v, segment_ids=seg)
+    dense = _causal(q, k, v, segment_ids=seg)
     qs, ks, vs = (seq_sharded(mesh, x) for x in (q, k, v))
     segs = None
     if seg is not None:
         segs = jax.device_put(seg, NamedSharding(mesh, P(None, "data")))
-    zig = ring_attention(
-        qs, ks, vs, mesh, axis="data", segment_ids=segs, schedule="zigzag"
+    zig = _ring(
+        qs, ks, vs, mesh=mesh, axis="data", segment_ids=segs,
+        schedule="zigzag",
     )
     np.testing.assert_allclose(
         np.asarray(zig), np.asarray(dense), rtol=2e-4, atol=2e-5
@@ -145,17 +156,19 @@ def test_zigzag_ring_gradients_match_dense():
     q, k, v = make_qkv(seed=6)
 
     def dense_loss(q, k, v):
-        return jnp.sum(causal_attention(q, k, v) ** 2)
+        return jnp.sum(_causal(q, k, v) ** 2)
 
     def zig_loss(q, k, v):
         return jnp.sum(
-            ring_attention(q, k, v, mesh, axis="data", schedule="zigzag")
+            _ring(q, k, v, mesh=mesh, axis="data", schedule="zigzag")
             ** 2
         )
 
-    g_dense = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
+    g_dense_fn = jax.jit(jax.grad(dense_loss, argnums=(0, 1, 2)))
+    g_dense = g_dense_fn(q, k, v)
     qs, ks, vs = (seq_sharded(mesh, x) for x in (q, k, v))
-    g_zig = jax.grad(zig_loss, argnums=(0, 1, 2))(qs, ks, vs)
+    g_zig_fn = jax.jit(jax.grad(zig_loss, argnums=(0, 1, 2)))
+    g_zig = g_zig_fn(qs, ks, vs)
     for gd, gz in zip(g_dense, g_zig):
         np.testing.assert_allclose(
             np.asarray(gz), np.asarray(gd), rtol=2e-3, atol=2e-4
@@ -177,13 +190,14 @@ def test_zigzag_ring_long_sequence(with_segments):
         done[200, 0] = True
         done[470] = True
         seg = segment_ids_from_done(jnp.asarray(done)).T
-    dense = causal_attention(q, k, v, segment_ids=seg)
+    dense = _causal(q, k, v, segment_ids=seg)
     qs, ks, vs = (seq_sharded(mesh, x) for x in (q, k, v))
     segs = None
     if seg is not None:
         segs = jax.device_put(seg, NamedSharding(mesh, P(None, "data")))
-    zig = ring_attention(
-        qs, ks, vs, mesh, axis="data", segment_ids=segs, schedule="zigzag"
+    zig = _ring(
+        qs, ks, vs, mesh=mesh, axis="data", segment_ids=segs,
+        schedule="zigzag",
     )
     np.testing.assert_allclose(
         np.asarray(zig), np.asarray(dense), rtol=2e-4, atol=2e-5
@@ -197,14 +211,14 @@ def test_zigzag_rejects_indivisible_t():
     mesh = create_mesh(8)
     q, k, v = make_qkv(seed=8, t=24)  # 24 % 16 != 0
     with pytest.raises(ValueError, match="divisible"):
-        ring_attention(q, k, v, mesh, axis="data", schedule="zigzag")
+        _ring(q, k, v, mesh=mesh, axis="data", schedule="zigzag")
 
 
 def test_unknown_schedule_rejected():
     mesh = create_mesh(8)
     q, k, v = make_qkv(seed=9)
     with pytest.raises(ValueError, match="schedule"):
-        ring_attention(q, k, v, mesh, axis="data", schedule="spiral")
+        _ring(q, k, v, mesh=mesh, axis="data", schedule="spiral")
 
 
 # --- dense_transformer_attend: equal and grouped heads -------------------
@@ -262,12 +276,14 @@ def test_dense_transformer_attend_by_group(kv_heads, with_bias):
             jnp.sin(fn(q, rep(k), rep(v), mask, offsets, bias))
         )
 
-    got = jax.grad(total(dense_transformer_attend, lambda x: x), (0, 1, 2))(
-        q, k, v
-    )
-    want = jax.grad(total(_old_dense_transformer_attend, repeat), (0, 1, 2))(
-        q, k, v
-    )
+    got_fn = jax.jit(jax.grad(
+        total(dense_transformer_attend, lambda x: x), (0, 1, 2)
+    ))
+    got = got_fn(q, k, v)
+    want_fn = jax.jit(jax.grad(
+        total(_old_dense_transformer_attend, repeat), (0, 1, 2)
+    ))
+    want = want_fn(q, k, v)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
@@ -275,13 +291,23 @@ def test_dense_transformer_attend_by_group(kv_heads, with_bias):
 # --- cached_transformer_attend: the cache and the unroll as two legs -----
 
 
+@functools.partial(jax.jit, static_argnames="M")
+def _masks_by_leg(done, valid, M):
+    from torchbeast_tpu.ops.attention import band_by_leg
+
+    seg = segment_ids_from_done(done).T
+    no_done_yet = jnp.cumsum(done, axis=0).T == 0
+    cache_band, seq_band = band_by_leg(T, M)
+    cache_mask = cache_band[None] & valid[:, None, :] & no_done_yet[:, :, None]
+    seq_mask = seq_band[None] & (seg[:, :, None] == seg[:, None, :])
+    return cache_mask, seq_mask
+
+
 def _two_leg_case(kv_heads, cache, seed=0, M=5, heads=H, head_size=D):
     """A seeded case as models/transformer.py would hand it over: a
     `done` inside the unroll (row 0, step 7: later queries see neither
     the cache nor the steps before it), the band, and a cache that is
     wholly invalid, valid in its newest slots, or full."""
-    from torchbeast_tpu.ops.attention import band_by_leg
-
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.standard_normal((B, T, heads, head_size)), jnp.float32)
     k, v = (
@@ -294,19 +320,12 @@ def _two_leg_case(kv_heads, cache, seed=0, M=5, heads=H, head_size=D):
     )
     done = np.zeros((T, B), bool)
     done[7, 0] = True
-    seg = segment_ids_from_done(jnp.asarray(done)).T
-    no_done_yet = jnp.cumsum(jnp.asarray(done), axis=0).T == 0
     valid = {
         "invalid": np.zeros((B, M), bool),
         "partly": np.arange(M)[None, :] >= np.array([2, 4])[:, None],
         "full": np.ones((B, M), bool),
     }[cache]
-    cache_band, seq_band = band_by_leg(T, M)
-    cache_mask = (
-        cache_band[None] & jnp.asarray(valid)[:, None, :]
-        & no_done_yet[:, :, None]
-    )
-    seq_mask = seq_band[None] & (seg[:, :, None] == seg[:, None, :])
+    cache_mask, seq_mask = _masks_by_leg(done, valid, M=M)
     return q, k, v, cache_k, cache_v, cache_mask, seq_mask
 
 
@@ -345,8 +364,14 @@ def test_cached_transformer_attend_is_the_dense_body(heads, kv_heads, cache):
         return lambda *operands: jnp.sum(jnp.sin(fn(*operands, *case[5:])))
 
     operands = case[:5]
-    got = jax.grad(total(cached_transformer_attend), range(5))(*operands)
-    want = jax.grad(total(_dense_on_the_concatenation), range(5))(*operands)
+    got_fn = jax.jit(
+        jax.grad(total(cached_transformer_attend), range(5))
+    )
+    got = got_fn(*operands)
+    want_fn = jax.jit(
+        jax.grad(total(_dense_on_the_concatenation), range(5))
+    )
+    want = want_fn(*operands)
     for name, a, b in zip(("q", "k", "v", "cache_k", "cache_v"), got, want):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5, err_msg=name)
     if cache != "invalid":
@@ -428,14 +453,16 @@ def test_latent_cached_attend_with_nothing_compressed_is_the_two_legs(cache):
     )
     size = q.shape[-1]
     rope = jnp.zeros(q.shape[:-1] + (2,), jnp.float32)
-    got = latent_cached_attend(
+    got_fn = jax.jit(latent_cached_attend)
+    got = got_fn(
         q, rope, k, rope[:, :, :1], k, cache_k,
         jnp.zeros(cache_k.shape[:-1] + (2,), jnp.float32),
         jnp.eye(size)[:, None, :], jnp.eye(size)[:, None, :],
         cache_mask, seq_mask,
     )
     # The two-leg body scales by D^-0.5, the latent one by (D + 2)^-0.5.
-    want = cached_transformer_attend(
+    want_fn = jax.jit(cached_transformer_attend)
+    want = want_fn(
         q * (size / (size + 2)) ** 0.5, k, k, cache_k, cache_k,
         cache_mask, seq_mask,
     )
@@ -474,9 +501,10 @@ def test_roll_kv_cache_in_the_states_layout(t):
     done[t // 2, 1] = True
     seg = segment_ids_from_done(jnp.asarray(done)).T
     no_done = jnp.cumsum(jnp.asarray(done), axis=0).T == 0
-    want = roll_kv_cache(k_cache, v_cache, valid, k_new, v_new, seg, no_done)
+    roll = jax.jit(roll_kv_cache, static_argnames="axis")
+    want = roll(k_cache, v_cache, valid, k_new, v_new, seg, no_done)
     to_state = lambda x: jnp.swapaxes(x, 0, 1)
-    got = roll_kv_cache(
+    got = roll(
         *map(to_state, (k_cache, v_cache, valid, k_new, v_new, seg, no_done)),
         axis=0,
     )
@@ -530,7 +558,8 @@ def test_ouro_update_gradient_builds_nothing_over_cache_and_unroll():
         "baseline": jnp.asarray(rng.standard_normal(lead), jnp.float32),
     }
     state = model.initial_state(rows)
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         {k: batch[k] for k in ("frame", "reward", "done", "last_action")},
         state,
@@ -562,19 +591,23 @@ def test_ouro_update_gradient_builds_nothing_over_cache_and_unroll():
 # --- fused_attend: the dense body with its scores in VMEM -----------------
 
 
-def _fused_case(heads, kv_heads, cache, M, head_size=D):
-    """`_two_leg_case` as the Mellum2 block hands it over: `[cache; k]`,
-    `[cache; v]` and the two masks side by side."""
-    q, k, v, cache_k, cache_v, cache_mask, seq_mask = _two_leg_case(
-        kv_heads, cache, seed=heads + M, M=M, heads=heads,
-        head_size=head_size,
-    )
+@jax.jit
+def _side_by_side(q, k, v, cache_k, cache_v, cache_mask, seq_mask):
     return (
         q,
         jnp.concatenate([cache_k.transpose(1, 0, 2, 3), k], axis=1),
         jnp.concatenate([cache_v.transpose(1, 0, 2, 3), v], axis=1),
         jnp.concatenate([cache_mask, seq_mask], axis=-1),
     )
+
+
+def _fused_case(heads, kv_heads, cache, M, head_size=D):
+    """`_two_leg_case` as the Mellum2 block hands it over: `[cache; k]`,
+    `[cache; v]` and the two masks side by side."""
+    return _side_by_side(*_two_leg_case(
+        kv_heads, cache, seed=heads + M, M=M, heads=heads,
+        head_size=head_size,
+    ))
 
 
 def _dense_body(q, k_all, v_all, mask):
@@ -674,7 +707,8 @@ def test_keys_told_to_take_no_gradient_take_zeros(
                 q, k_all, v_all, mask, None, None, no_grad_keys
             )))
 
-        return jax.value_and_grad(total, (0, 1, 2))(q, k_all, v_all)
+        traced = jax.jit(jax.value_and_grad(total, (0, 1, 2)))
+        return traced(q, k_all, v_all)
 
     n = no_grad_keys
     (value, (dq, dk, dv)), (value_0, (dq_0, dk_0, dv_0)) = grads(n), grads(0)
@@ -739,11 +773,18 @@ def test_the_fused_pass_follows_the_precision_its_caller_traces_at(
         ),
     )
     q, k_all, v_all, mask = _fused_case(8, 2, "partly", 5, head_size=128)
+    # Traced on shapes alone: what `fused_attend` is handed is a
+    # Python-side effect of the trace, and nothing is computed.
     with (
         jax.default_matmul_precision(traced_at) if traced_at
         else contextlib.nullcontext()
     ):
-        attention.dense_transformer_attend(q, k_all, v_all, mask, None, None)
+        jax.eval_shape(
+            lambda *operands: attention.dense_transformer_attend(
+                *operands, None, None
+            ),
+            q, k_all, v_all, mask,
+        )
     assert seen == [precise]
 
 
@@ -818,21 +859,25 @@ def test_dense_transformer_attend_chooses_by_the_rule(
         jnp.asarray(rng.standard_normal((8, 194)), jnp.float32)
         if bias else None
     )
-    want = attention.dense_transformer_attend(
-        q, k_all, v_all, mask, offsets, rel_bias
+    # A function of its own each time: traces are cached by the function
+    # traced, and the rule is read at trace time.
+    operands = (q, k_all, v_all, mask, offsets, rel_bias)
+    want_fn = jax.jit(
+        lambda *operands: attention.dense_transformer_attend(*operands)
     )
+    want = want_fn(*operands)
     if threshold is not None:
         monkeypatch.setattr(attention, "FUSED_SCORE_BYTES", threshold)
-    # A function of its own: traces are cached by the function traced.
     program = str(
         jax.make_jaxpr(
             lambda *operands: attention.dense_transformer_attend(*operands)
-        )(q, k_all, v_all, mask, offsets, rel_bias)
+        )(*operands)
     )
     assert ("pallas_call" in program) is fused
-    got = attention.dense_transformer_attend(
-        q, k_all, v_all, mask, offsets, rel_bias
+    got_fn = jax.jit(
+        lambda *operands: attention.dense_transformer_attend(*operands)
     )
+    got = got_fn(*operands)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
@@ -860,8 +905,10 @@ def _latent_case(cache, steps, slots, seed=0, heads=4, latent=128):
     rng = np.random.default_rng(seed)
     rows, nope, rope, value = 2, 16, 8, 12
 
-    def normal(*shape):
-        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    def normal(*shape, scale=1.0):
+        return jnp.asarray(
+            np.float32(scale) * rng.standard_normal(shape).astype(np.float32)
+        )
 
     operands = dict(
         q_nope=normal(rows, steps, heads, nope),
@@ -869,16 +916,16 @@ def _latent_case(cache, steps, slots, seed=0, heads=4, latent=128):
         k_nope=normal(rows, steps, heads, nope),
         k_rope=normal(rows, steps, 1, rope),
         v=normal(rows, steps, heads, value),
-        w_uk=0.1 * normal(latent, heads, nope),
-        w_uv=0.1 * normal(latent, heads, value),
+        w_uk=normal(latent, heads, nope, scale=0.1),
+        w_uv=normal(latent, heads, value, scale=0.1),
     )
     fixed = dict(
         cache_latent=normal(slots, rows, 1, latent),
         cache_rope=normal(slots, rows, 1, rope),
         cache_mask=_latent_cache_mask(cache, rng, (rows, steps, slots)),
-        seq_mask=jnp.broadcast_to(
-            jnp.tril(jnp.ones((steps, steps), bool)), (rows, steps, steps)
-        ),
+        seq_mask=jnp.asarray(np.broadcast_to(
+            np.tril(np.ones((steps, steps), bool)), (rows, steps, steps)
+        )),
         dout=normal(rows, steps, heads, value),
     )
     return operands, fixed
@@ -1072,12 +1119,14 @@ def test_a_latent_cache_is_data_in_both_regimes(monkeypatch):
     operands, fixed = _latent_case("third-masked", 5, 300, seed=2)
 
     def cache_grads():
-        return jax.grad(
+        # Jitted anew a regime: the rule is read at trace time.
+        traced = jax.jit(jax.grad(
             lambda latent, rope: jnp.sum(jnp.sin(_latent_attend(
                 operands, dict(fixed, cache_latent=latent, cache_rope=rope)
             ))),
             (0, 1),
-        )(fixed["cache_latent"], fixed["cache_rope"])
+        ))
+        return traced(fixed["cache_latent"], fixed["cache_rope"])
 
     for threshold in (attention.FUSED_SCORE_BYTES, 1):
         monkeypatch.setattr(attention, "FUSED_SCORE_BYTES", threshold)

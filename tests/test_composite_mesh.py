@@ -7,6 +7,7 @@ import jax
 import numpy as np
 import pytest
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import create_model
 from torchbeast_tpu.parallel import (
@@ -58,7 +59,8 @@ def test_dp_x_ep_update_matches_single_device():
 
     batch = _batch()
     state = single.initial_state(B)
-    params = single.init(
+    params = scaffold.init(
+        single,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         batch,
         state,
@@ -126,7 +128,8 @@ def test_dp_x_sp_update_matches_single_device():
 
     batch = _batch(seed=1, t=T_)
     state = single.initial_state(B)
-    params = single.init(
+    params = scaffold.init(
+        single,
         {"params": jax.random.PRNGKey(2), "action": jax.random.PRNGKey(3)},
         batch,
         state,
@@ -194,7 +197,8 @@ def test_dp_x_sp_x_ep_update_matches_single_device():
 
     batch = _batch(seed=2, t=T_)
     state = single.initial_state(B)
-    params = single.init(
+    params = scaffold.init(
+        single,
         {"params": jax.random.PRNGKey(4), "action": jax.random.PRNGKey(5)},
         batch,
         state,
@@ -274,7 +278,8 @@ def test_dp_x_tp_x_ep_update_matches_single_device():
     single = create_model("transformer", **kwargs)
     batch = _batch(seed=3)
     state = single.initial_state(B)
-    params = single.init(
+    params = scaffold.init(
+        single,
         {"params": jax.random.PRNGKey(6), "action": jax.random.PRNGKey(7)},
         batch,
         state,
@@ -361,7 +366,8 @@ def test_dp_x_pp_update_matches_single_device():
         )
         batch = _batch(seed=7)
         state = state_fn(single)
-        params = single.init(
+        params = scaffold.init(
+            single,
             {
                 "params": jax.random.PRNGKey(8),
                 "action": jax.random.PRNGKey(9),

@@ -7,6 +7,7 @@ import jax
 import numpy as np
 import pytest
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu.models import create_model
 from torchbeast_tpu.utils.convert import (
     pipelined_to_transformer,
@@ -30,7 +31,8 @@ def _inputs(seed=0):
 
 
 def _init(model, seed=0):
-    return model.init(
+    return scaffold.init(
+        model,
         {
             "params": jax.random.PRNGKey(seed),
             "action": jax.random.PRNGKey(seed + 1),
@@ -69,8 +71,8 @@ def test_transformer_to_pipelined_same_outputs():
         converted
     ) == jax.tree_util.tree_structure(ref)
     inputs, state = _inputs(seed=3), seq.initial_state(B)
-    out_s, st_s = seq.apply(params, inputs, state, sample_action=False)
-    out_p, st_p = pipe.apply(converted, inputs, state, sample_action=False)
+    out_s, st_s = scaffold.forward(seq)(params, inputs, state)
+    out_p, st_p = scaffold.forward(pipe)(converted, inputs, state)
     _assert_same_outputs(out_s, st_s, out_p, st_p)
 
 
@@ -80,8 +82,8 @@ def test_pipelined_to_transformer_roundtrip():
     params = _init(pipe, seed=20)
     converted = pipelined_to_transformer(params)
     inputs, state = _inputs(seed=4), pipe.initial_state(B)
-    out_p, st_p = pipe.apply(params, inputs, state, sample_action=False)
-    out_s, st_s = seq.apply(converted, inputs, state, sample_action=False)
+    out_p, st_p = scaffold.forward(pipe)(params, inputs, state)
+    out_s, st_s = scaffold.forward(seq)(converted, inputs, state)
     _assert_same_outputs(out_p, st_p, out_s, st_s)
     # Round trip is the identity.
     back = transformer_to_pipelined(converted)

@@ -20,6 +20,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import create_model
 from torchbeast_tpu.ops import impact_policy_losses, vtrace_policy_losses
@@ -85,8 +86,10 @@ class TestOpsGradientEquivalence:
             return pg + bl
 
         args = (jnp.asarray(x["learner_logits"]), jnp.asarray(x["values"]))
-        g_vt = jax.grad(vtrace_total, argnums=(0, 1))(*args)
-        g_im = jax.grad(impact_total, argnums=(0, 1))(*args)
+        g_vt_fn = jax.jit(jax.grad(vtrace_total, argnums=(0, 1)))
+        g_vt = g_vt_fn(*args)
+        g_im_fn = jax.jit(jax.grad(impact_total, argnums=(0, 1)))
+        g_im = g_im_fn(*args)
         for a, b in zip(g_vt, g_im):
             np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
@@ -160,7 +163,8 @@ class TestOpsGradientEquivalence:
             )
             return pg + bl
 
-        grads = jax.grad(total, argnums=(0, 1, 2))(
+        grads_fn = jax.jit(jax.grad(total, argnums=(0, 1, 2)))
+        grads = grads_fn(
             jnp.asarray(x["learner_logits"]),
             jnp.asarray(x["values"]),
             jnp.asarray(x["behavior_logits"]),
@@ -190,7 +194,8 @@ def _batch(seed=0, t=T, b=B):
 def model_and_params():
     model = create_model("shallow", num_actions=A)
     batch = _batch()
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         batch,
         (),
@@ -220,17 +225,19 @@ class TestComputeLossEquivalence:
         hp_vt = learner_lib.HParams()
         hp_im = learner_lib.HParams(loss="impact")
 
-        g_vt, _ = jax.grad(
+        traced = jax.jit(jax.grad(
             lambda p: learner_lib.compute_loss(model, p, batch, (), hp_vt),
             has_aux=True,
-        )(params)
+        ))
+        g_vt, _ = traced(params)
         merged = _with_target(model, params, batch)
-        g_im, _ = jax.grad(
+        traced = jax.jit(jax.grad(
             lambda p: learner_lib.compute_loss(
                 model, p, merged, (), hp_im
             ),
             has_aux=True,
-        )(params)
+        ))
+        g_im, _ = traced(params)
         for a, b in zip(
             jax.tree_util.tree_leaves(g_vt),
             jax.tree_util.tree_leaves(g_im),

@@ -3,10 +3,11 @@ ops/attention.latent_cached_attend; sigmoid routing, the selection bias
 and the shared expert in models/moe.py DroplessMoE; cache entries of
 two unequal leaves in models/transformer.py; the bias's step in
 learner.update_body): against the plain reference on seeded weights,
-absorbed against decompressed attention, batch forward against stepwise
-acting through the latent caches and through the state table, the
-shares of the routed experts adding up to the layer, and the bias
-moving by its rule and by nothing else."""
+batch forward against stepwise acting through the latent caches and
+through the state table, and the bias moving by its rule and by nothing
+else. The latent attention on its own: tests/test_kanana2_attention.py;
+the shares of the routed experts adding up to the layer: an id of
+tests/test_families_shares.py."""
 
 import numpy as np
 import pytest
@@ -17,8 +18,7 @@ import jax.numpy as jnp
 from perfbench.reference import kanana2_policy as reference
 from tests import family_scaffold as scaffold
 from torchbeast_tpu import learner as learner_lib
-from torchbeast_tpu.models import Kanana2Net, create_model, kanana2, moe
-from torchbeast_tpu.ops import attention
+from torchbeast_tpu.models import Kanana2Net, create_model
 
 T, B, A = 6, scaffold.B, scaffold.A
 # The shrunken `PUBLISHED` (tests/family_scaffold.py): the dense layer
@@ -28,6 +28,7 @@ LAYERS, M = SMALL["num_layers"], SMALL["memory_len"]
 # As tests/test_olmoe.py: on the CPU both sides compute in float32 at
 # full precision and differ by the order of their sums.
 RTOL = ATOL = 1e-5
+SHARED = scaffold.FAMILIES["kanana2"].experts.shared
 
 
 @pytest.mark.parametrize(
@@ -71,107 +72,6 @@ def test_family_agrees_with_the_reference(share):
         assert "moe_held_assignments" not in stats
     else:
         assert 0 < float(stats["moe_held_assignments"]) < 3 * T * B * 2
-
-
-def test_absorbed_equals_decompressed():
-    """`latent_cached_attend` against dense attention over `[cache;
-    unroll]` with `kv_b` applied to every cached latent: the same
-    values, and the same gradient for the decompression matrix, which
-    the absorbed leg reads in two halves and never multiplies a cached
-    latent by. A third of the cache is masked out."""
-    rng = np.random.default_rng(3)
-    rows, steps, slots, H, C, Dn, Dr, Dv = 2, 5, 7, 4, 24, 16, 8, 12
-
-    def normal(*shape):
-        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
-
-    q_nope, q_rope = normal(rows, steps, H, Dn), normal(rows, steps, H, Dr)
-    c, k_r = normal(rows, steps, C), normal(rows, steps, 1, Dr)
-    cache_c, cache_r = normal(slots, rows, 1, C), normal(slots, rows, 1, Dr)
-    w_kvb = 0.3 * normal(C, H, Dn + Dv)
-    cache_mask = jnp.asarray(rng.random((rows, steps, slots)) < 0.67)
-    seq_mask = jnp.broadcast_to(
-        jnp.tril(jnp.ones((steps, steps), bool)), (rows, steps, steps)
-    )
-    theta = 1e6
-
-    @jax.jit
-    def absorbed(w):
-        kv = jnp.einsum("btc,chd->bthd", c, w)
-        return attention.latent_cached_attend(
-            q_nope, kanana2.rope_pairs(q_rope, jnp.arange(steps), theta),
-            kv[..., :Dn], kanana2.rope_pairs(k_r, jnp.arange(steps), theta),
-            kv[..., Dn:], cache_c, cache_r, w[..., :Dn], w[..., Dn:],
-            cache_mask, seq_mask,
-            place_cache_keys=lambda keys, times: kanana2.rope_pairs(
-                keys, times, theta, time_axis=0
-            ),
-        )
-
-    @jax.jit
-    def decompressed(w):
-        latents = jnp.concatenate(
-            [cache_c[:, :, 0].transpose(1, 0, 2), c], axis=1
-        )
-        rope_keys = jnp.concatenate(
-            [cache_r.transpose(1, 0, 2, 3), k_r], axis=1
-        )
-        times = jnp.concatenate([jnp.arange(slots) - slots, jnp.arange(steps)])
-        kv = jnp.einsum("bkc,chd->bkhd", latents, w)
-        keys = jnp.concatenate([
-            kv[..., :Dn],
-            jnp.repeat(kanana2.rope_pairs(rope_keys, times, theta), H, axis=2),
-        ], axis=-1)
-        queries = jnp.concatenate([
-            q_nope, kanana2.rope_pairs(q_rope, jnp.arange(steps), theta),
-        ], axis=-1)
-        # Values as wide as the keys for the dense body, then cut back.
-        values = jnp.pad(kv[..., Dn:], ((0, 0),) * 3 + ((0, Dn + Dr - Dv),))
-        return attention.dense_transformer_attend(
-            queries, keys, values,
-            jnp.concatenate([cache_mask, seq_mask], axis=-1), None, None,
-        )[..., :Dv]
-
-    np.testing.assert_allclose(
-        absorbed(w_kvb), decompressed(w_kvb), RTOL, ATOL
-    )
-    weight = normal(rows, steps, H, Dv)
-    grad = jax.jit(
-        lambda w, f: jax.grad(lambda w: jnp.sum(weight * f(w)))(w),
-        static_argnums=1,
-    )
-    grads = [grad(w_kvb, f) for f in (absorbed, decompressed)]
-    assert float(jnp.max(jnp.abs(grads[1]))) > 0.1
-    np.testing.assert_allclose(grads[0], grads[1], rtol=1e-4, atol=1e-5)
-    # The cache is data: it takes no gradient from the absorbed form,
-    # in either regime of its cache leg (tests/test_attention.py).
-    assert absorbed(w_kvb).shape == (rows, steps, H, Dv)
-
-
-def test_interleaved_rope_turns_neighbouring_pairs():
-    """`rope_interleave`: (x[2i], x[2i+1]) is the pair, not (x[i],
-    x[i + D/2]); the program's and the reference's agree, in both
-    layouts."""
-    x = jnp.asarray(np.random.default_rng(0).standard_normal((2, 3, 1, 8)),
-                    jnp.float32)
-    times = jnp.asarray([-2, 0, 5])
-    got = kanana2.rope_pairs(x, times, 1e6)
-    for row in range(2):
-        np.testing.assert_allclose(
-            got[row], reference._rope_pairs(x[row], times, 1e6), RTOL, ATOL
-        )
-    np.testing.assert_allclose(
-        kanana2.rope_pairs(x.transpose(1, 0, 2, 3), times, 1e6, time_axis=0),
-        got.transpose(1, 0, 2, 3), RTOL, ATOL,
-    )
-    # Position 0 leaves x alone; the first pair turns by the position.
-    np.testing.assert_allclose(got[:, 1], x[:, 1], RTOL, ATOL)
-    np.testing.assert_allclose(
-        got[0, 2, 0, :2],
-        [x[0, 2, 0, 0] * np.cos(5) - x[0, 2, 0, 1] * np.sin(5),
-         x[0, 2, 0, 1] * np.cos(5) + x[0, 2, 0, 0] * np.sin(5)],
-        RTOL, ATOL,
-    )
 
 
 @pytest.mark.parametrize("unrolls", [0, 1, 2], ids=["empty", "part", "full"])
@@ -218,42 +118,23 @@ def test_stepwise_acting_through_the_state_table_equals_the_batch_forward(
         assert not np.any(valid)
 
 
-def _layer(held=None, tokens=40, seed=0, E=16, K=3, **overrides):
-    fields = dict(
-        d_ff=8, num_experts=E, top_k=K, aux_loss_weight=0.0,
-        renormalise=True, held=held, scoring="sigmoid", selection_bias=True,
-        bias_update_rate=0.001, routed_scaling=2.448, shared_width=12,
-    )
-    layer = moe.DroplessMoE(**dict(fields, **overrides))
-    x = jax.random.normal(jax.random.PRNGKey(seed), (tokens, 16))
-    params = layer.init(jax.random.PRNGKey(seed + 1), x)
-    return layer, x, params
-
-
-def _shared(x, p):
-    return (
-        jax.nn.silu(x @ p["shared_gate"]["kernel"])
-        * (x @ p["shared_up"]["kernel"])
-    ) @ p["shared_down"]["kernel"]
-
-
 def test_gates_sum_to_the_scaling_factor():
     """With every expert the same matrix the routed sum is exactly
     2.448 times that expert's output: the chosen sigmoid scores,
     renormalised over the six, times `routed_scaling_factor`. The shared
     expert comes on top, unscaled."""
-    layer, x, params = _layer()
+    layer, x, params = scaffold.expert_layer("kanana2")
     p = params["params"]
     same = {
         k: jnp.broadcast_to(p[k][:1], p[k].shape)
         for k in ("w_gate", "w_up", "w_down")
     }
-    y = layer.apply({"params": dict(p, **same)}, x)
+    y = scaffold.apply(layer)({"params": dict(p, **same)}, x)
     expert = (
         jax.nn.silu(x @ p["w_gate"][0]) * (x @ p["w_up"][0])
     ) @ p["w_down"][0]
     np.testing.assert_allclose(
-        y, 2.448 * expert + _shared(x, p), RTOL, ATOL
+        y, 2.448 * expert + SHARED(x, p), RTOL, ATOL
     )
     # Sigmoid scores are not a distribution: as they come, the three
     # chosen sum to more than one.
@@ -267,7 +148,7 @@ def test_the_bias_chooses_and_is_no_part_of_the_gate():
     scores (renormalised over the new three), on the program and on the
     reference."""
     E, K = 16, 3
-    layer, x, params = _layer(tokens=8, seed=2)
+    layer, x, params = scaffold.expert_layer("kanana2", tokens=8, seed=2)
     p = dict(params["params"])
     scores = jax.nn.sigmoid(x @ p["router"]["kernel"])
     ranked = np.argsort(-np.asarray(scores), axis=-1)
@@ -287,7 +168,7 @@ def test_the_bias_chooses_and_is_no_part_of_the_gate():
         gates = 2.448 * s / (s.sum() + 1e-20)
         return sum(
             g * experts_of(e)[0] for g, e in zip(gates, chosen)
-        ) + _shared(x, p)[0]
+        ) + SHARED(x, p)[0]
 
     config = {
         "published_n_routed_experts": E, "n_routed_experts": E,
@@ -303,7 +184,8 @@ def test_the_bias_chooses_and_is_no_part_of_the_gate():
         weights = dict(p, e_score_correction_bias=jnp.asarray(b))
         want = by_hand(list(chosen))
         np.testing.assert_allclose(
-            layer.apply({"params": weights}, x)[0], want, RTOL, ATOL
+            scaffold.apply(layer)({"params": weights}, x)[0],
+            want, RTOL, ATOL,
         )
         np.testing.assert_allclose(
             reference._experts(x, weights, config)[0], want, RTOL, ATOL
@@ -314,72 +196,14 @@ def test_the_bias_chooses_and_is_no_part_of_the_gate():
     assert abs(float((wrong / wrong.sum())[-1] - (s / s.sum())[-1])) > 1e-4
 
 
-@pytest.mark.parametrize("side", ["program", "reference"])
-def test_the_eight_shares_add_up_to_the_uncut_layer(side):
-    """The test that ties the share to the model: 32 experts (the
-    interpreted grouped kernels are slow over 128), top 6, the same
-    router and biases; the routed parts of `held` (0, 4), (4, 4), ...
-    (28, 4), each holding its own eighth of the uncut layer's expert
-    weights, plus the shared expert COUNTED ONCE (every chip computes
-    it alike), add up to the uncut 32-expert layer's output. On the program (values and the gradient with respect to x)
-    and on the reference."""
-    E, K, tokens = 32, 6, 40
-    _, x, params = _layer(None, tokens=tokens, seed=4, E=E, K=K)
-    p = dict(params["params"])
-    p["e_score_correction_bias"] = 0.05 * jax.random.normal(
-        jax.random.PRNGKey(9), (E,)
-    )
-
-    def share_params(first, count):
-        return dict(p, **{
-            k: p[k][first : first + count]
-            for k in ("w_gate", "w_up", "w_down")
-        })
-
-    if side == "program":
-        def run(first, count, x):
-            held = None if count == E else (first, count)
-            layer, _, _ = _layer(held, tokens=tokens, E=E, K=K)
-            return layer.apply({"params": share_params(first, count)}, x)
-    else:
-        def run(first, count, x):
-            config = {
-                "published_n_routed_experts": E, "n_routed_experts": count,
-                "expert_share": [first // count, E // count],
-                "num_experts_per_tok": K, "scoring_func": "sigmoid",
-                "topk_method": "noaux_tc", "n_group": 1, "topk_group": 1,
-                "norm_topk_prob": True, "routed_scaling_factor": 2.448,
-            }
-            return reference._experts(x, share_params(first, count), config)
-
-    firsts = range(0, E, E // 8)
-    whole = run(0, E, x)
-    shared = _shared(x, p)
-    parts = [run(first, E // 8, x) - shared for first in firsts]
-    assert all(float(jnp.max(jnp.abs(part))) > 0 for part in parts)
-    np.testing.assert_allclose(sum(parts) + shared, whole, RTOL, ATOL)
-    # No share is the whole, and the shared expert counted eight times
-    # is not it either.
-    assert float(jnp.max(jnp.abs(parts[0] + shared - whole))) > 1e-3
-    assert float(jnp.max(jnp.abs(sum(parts) + 8 * shared - whole))) > 1e-3
-
-    grad_whole = jax.grad(lambda x: jnp.sum(jnp.sin(run(0, E, x))))(x)
-    weight = jnp.cos(whole)
-    grad_parts = sum(
-        jax.grad(lambda x, f=first: jnp.sum(
-            weight * (run(f, E // 8, x) - _shared(x, p))
-        ))(x)
-        for first in firsts
-    ) + jax.grad(lambda x: jnp.sum(weight * _shared(x, p)))(x)
-    np.testing.assert_allclose(grad_parts, grad_whole, rtol=1e-4, atol=1e-5)
-
-
 def test_a_share_visits_no_row_of_anothers_experts():
     """A layer that holds experts 4..5 of 16: a token that chose
     neither gets the shared expert's output and nothing else, exactly;
     the held experts' weight gradients come from their own rows alone
     (a token that chose neither contributes nothing)."""
-    layer, x, params = _layer((4, 2), tokens=64, seed=6)
+    layer, x, params = scaffold.expert_layer(
+        "kanana2", (4, 2), tokens=64, seed=6
+    )
     p = params["params"]
     assert p["w_gate"].shape == (2, 16, 8)
     assert p["router"]["kernel"].shape == (16, 16)
@@ -387,8 +211,8 @@ def test_a_share_visits_no_row_of_anothers_experts():
     _, chosen = jax.lax.top_k(scores, 3)
     mine = np.isin(np.asarray(chosen), [4, 5]).any(axis=-1)
     assert 0 < mine.sum() < len(mine)
-    y = layer.apply(params, x)
-    shared = _shared(x, p)
+    y = scaffold.apply(layer)(params, x)
+    shared = SHARED(x, p)
     np.testing.assert_array_equal(
         np.asarray(y)[~mine], np.asarray(shared)[~mine]
     )
@@ -398,10 +222,11 @@ def test_a_share_visits_no_row_of_anothers_experts():
         out = layer.apply({"params": p}, x)
         return jnp.sum(jnp.where(rows[:, None], jnp.sin(out), 0.0))
 
-    others = jax.grad(total)(p, jnp.asarray(~mine))
+    grad = jax.jit(jax.grad(total))
+    others = grad(p, jnp.asarray(~mine))
     for name in ("w_gate", "w_up", "w_down"):
         assert not np.any(others[name])
-    assert np.any(jax.grad(total)(p, jnp.asarray(mine))["w_down"])
+    assert np.any(grad(p, jnp.asarray(mine))["w_down"])
 
 
 def test_the_bias_moves_by_its_rule_and_by_nothing_else():
@@ -515,49 +340,6 @@ def test_layer_zero_is_dense_and_the_rest_are_experts():
     assert [leaf.shape for leaf in olmoe.initial_state(2)[0]] == [
         (128, 2, 16, 128), (128, 2, 16, 128), (128, 2)
     ]
-
-
-def test_family_with_the_fused_leg_agrees_with_the_xla_body(monkeypatch):
-    """Five layers over a latent of whole lane tiles (128), caches an
-    actor warmed: with the threshold of `fused_latent_leg_applies`
-    lowered every layer's cache leg is the blockwise pass (interpreted
-    here) and is counted,
-    `attention_latent_fused_applications` 5 beside `attention_latent_
-    applications` 5; the loss and the gradients are those of the XLA
-    body; a leg at `high` keeps the XLA body and the key is absent."""
-    layers = 5
-    model, params = scaffold.build(
-        "kanana2", num_layers=layers, latent_rank=128
-    )
-    state = scaffold.warm_state(model, params, seed=5, unrolls=2)
-    batch = scaffold.learner_batch(9, done_steps=[(1, 1)])
-    loss, stats, grads = scaffold.loss_and_grads(model)(params, batch, state)
-    assert float(stats["attention_latent_applications"]) == layers
-    assert "attention_latent_fused_applications" not in stats
-
-    monkeypatch.setattr(attention, "FUSED_SCORE_BYTES", 1)
-    # A trace of its own (`__wrapped__`: not the scaffold's memoised
-    # one): the rule is read at the trace.
-    loss_f, stats_f, grads_f = scaffold.loss_and_grads.__wrapped__(model)(
-        params, batch, state
-    )
-    assert float(stats_f["attention_latent_applications"]) == layers
-    assert float(stats_f["attention_latent_fused_applications"]) == layers
-    assert float(loss_f) == pytest.approx(float(loss), rel=1e-4)
-    flat, flat_f = scaffold.flat(grads), scaffold.flat(grads_f)
-    np.testing.assert_allclose(
-        flat_f, flat, rtol=0, atol=RTOL * float(jnp.max(jnp.abs(flat)))
-    )
-    # The stats' keys alone, nothing computed.
-    hp = learner_lib.HParams(batch_size=B, unroll_length=T - 1)
-    stats_h = jax.eval_shape(
-        lambda p: learner_lib.compute_loss(
-            model.clone(cache_leg_precision="high"), p, batch, state, hp
-        )[1],
-        params,
-    )
-    assert "attention_latent_applications" in stats_h
-    assert "attention_latent_fused_applications" not in stats_h
 
 
 def test_the_family_names_its_updates_compiler_options(monkeypatch):

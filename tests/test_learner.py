@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import create_model
 
@@ -35,7 +36,8 @@ def make_batch(rng_seed=0, t=T, b=B):
 def model_and_params():
     model = create_model("shallow", num_actions=A)
     batch = make_batch()
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         batch,
         (),
@@ -52,10 +54,11 @@ def test_update_step_matches_manual_sgd(model_and_params):
     opt_state = optimizer.init(params)
     batch = make_batch()
 
-    grads, _ = jax.grad(
+    traced = jax.jit(jax.grad(
         lambda p: learner_lib.compute_loss(model, p, batch, (), hp),
         has_aux=True,
-    )(params)
+    ))
+    grads, _ = traced(params)
     expected = jax.tree_util.tree_map(lambda p, g: p - lr * g, params, grads)
 
     update_step = learner_lib.make_update_step(model, optimizer, hp)

@@ -8,6 +8,7 @@ import jax
 import optax
 import pytest
 
+from tests import family_scaffold as scaffold
 from tests.test_learner import make_batch
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import create_model
@@ -24,7 +25,8 @@ def _in_scope(op_name, scope):
 def _op_names(optimizer):
     model = create_model("shallow", num_actions=3)
     batch = make_batch()
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         batch, (),
     )
@@ -84,8 +86,6 @@ QWEN3NEXT_SCOPES = (
 def qwen3next_op_names():
     """The toy family's whole update, compiled (tests/family_scaffold.
     py): what a device trace of the cell is split by."""
-    from tests import family_scaffold as scaffold
-
     model, params = scaffold.build("qwen3next")
     t = scaffold.FAMILIES["qwen3next"].t
     batch = scaffold.learner_batch(1, [(4, 0), (5, 1)], t=t)

@@ -15,8 +15,11 @@ import os
 import subprocess
 import sys
 
+import jax
+import numpy as np
 import pytest
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu import anakin, learner_setup, models, monobeast, polybeast
 from torchbeast_tpu import learner as learner_lib
 
@@ -379,3 +382,90 @@ def test_drivers_take_the_builder_from_the_module():
         and isinstance(node.value, ast.Name) and node.value.id == "monobeast"
     }
     assert uses == {"test"}
+
+
+# (g) a flax module is traced, never run op by op
+
+
+def _assert_the_eager_trees_leaves(traced, eager):
+    """The same tree, every leaf within 1 ulp: to the bit on the
+    builder's CPU (PR 49), the ulp for one whose fused multiply rounds
+    an initialiser's scale otherwise."""
+    flat, eager_flat = (
+        dict(jax.tree_util.tree_flatten_with_path(tree)[0])
+        for tree in (traced, eager)
+    )
+    assert sorted(map(str, flat)) == sorted(map(str, eager_flat))
+    for path, leaf in flat.items():
+        want = eager_flat[path]
+        assert leaf.dtype == want.dtype and leaf.shape == want.shape, path
+        np.testing.assert_array_max_ulp(
+            np.asarray(leaf), np.asarray(want), maxulp=1
+        )
+
+
+def test_first_parameters_are_one_traced_program(monkeypatch):
+    """`init_model_and_params` of the toy Qwen3-Next family hands XLA
+    its `init` as one program (8 requests with the two keys' and the
+    empty state's small ones; op by op it was 421), and the parameters
+    are the eager `model.init`'s."""
+    from tests.test_monobeast_families import THROUGH_MAIN
+
+    family, widths, flags, _ = THROUGH_MAIN["qwen3next"]
+    monkeypatch.setattr(
+        models.qwen3next, "PUBLISHED",
+        dict(models.qwen3next.PUBLISHED, **widths),
+    )
+    argv = ["--model", family, "--memory_len", "6"]
+    for flag, value in flags.items():
+        argv += [f"--{flag}", str(value)]
+    parsed = monobeast.make_parser().parse_args(argv)
+    requests = scaffold.compile_requests(monkeypatch)
+    model, params = learner_setup.init_model_and_params(parsed, A, B, FRAME)
+    assert len(requests) <= 10
+    del requests[:]
+    eager = model.init(
+        {
+            "params": jax.random.PRNGKey(parsed.seed),
+            "action": jax.random.PRNGKey(parsed.seed + 1),
+        },
+        learner_setup.dummy_env_outputs(1, B, FRAME, np.uint8),
+        model.initial_state(B),
+    )
+    assert len(requests) > 400
+    _assert_the_eager_trees_leaves(params, eager)
+
+
+def test_anakins_first_parameters_are_the_same_traced_program(monkeypatch):
+    """`anakin.initial_carry` makes its parameters by the same function,
+    from its own keys and the environments' first outputs, and primes
+    the boundary output through the jitted act step: 38 requests, all
+    but two of them the keys' and the environments' first steps' (with
+    the MLP run op by op it was 84)."""
+    from torchbeast_tpu.envs.jax_env import create_jax_env
+
+    flags = anakin.make_parser().parse_args([])
+    env = create_jax_env(flags.env)
+    model, _ = learner_setup.init_model_and_params(
+        flags, env.num_actions, B, env.frame_shape, init_params=False
+    )
+    requests = scaffold.compile_requests(monkeypatch)
+    made = []
+    initial_params = learner_setup.initial_params
+
+    def spy(*args):
+        before = len(requests)
+        made.append(args)
+        params = initial_params(*args)
+        assert len(requests) - before == 1
+        return params
+
+    monkeypatch.setattr(anakin, "initial_params", spy)
+    params, carry = anakin.initial_carry(env, model, B, jax.random.PRNGKey(3))
+    assert len(requests) <= 45
+    ((module, rngs, env_outputs, agent_state),) = made
+    assert module is model
+    _assert_the_eager_trees_leaves(
+        params, model.init(rngs, env_outputs, agent_state)
+    )
+    assert carry.agent_out["action"].shape == (B,)

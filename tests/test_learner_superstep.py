@@ -12,6 +12,7 @@ import pytest
 
 import jax
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import create_model
 
@@ -51,7 +52,8 @@ def _setup(use_lstm, entropy_anneal, seed=0):
     state = model.initial_state(B)
     rng = np.random.default_rng(seed)
     dummy = make_batch(rng, t=0)
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(seed),
          "action": jax.random.PRNGKey(seed + 1)},
         dummy,

@@ -3,7 +3,8 @@ ops/attention.py; the share of the experts in models/moe.py DroplessMoE;
 per-layer caches in models/transformer.py): against the plain reference
 on seeded weights, batch forward against stepwise acting through the two
 rolling caches, what a window layer cannot see and a full layer can,
-YaRN by hand, and the shares of the experts adding up to the layer."""
+and YaRN by hand (the shares of the experts adding up to the layer: an
+id of tests/test_families_shares.py)."""
 
 import math
 
@@ -125,8 +126,8 @@ def test_a_window_layer_ignores_the_key_a_full_layer_reads():
         block = _one_block(kind, slots)
         # The block contract: the cache as the state holds it.
         cache = tuple(c[:, -slots:].transpose(1, 0, 2, 3) for c in (k, v))
-        params = block.init(jax.random.PRNGKey(0), x, cache, *masks)
-        return block.apply(params, x, cache, *masks)[0]
+        params = scaffold.init(block, jax.random.PRNGKey(0), x, cache, *masks)
+        return scaffold.apply(block)(params, x, cache, *masks)[0]
 
     window = SMALL["sliding_window"] - 1
     np.testing.assert_array_equal(
@@ -179,29 +180,17 @@ def test_yarn_by_hand():
     np.testing.assert_allclose(rotated, attention * x, 1e-6)
 
 
-def _layer(held=None, renormalise=True, tokens=40, seed=0, E=8, K=2):
-    count = E if held is None else held[1]
-    layer = moe.DroplessMoE(
-        d_ff=8, num_experts=E, top_k=K, renormalise=renormalise, held=held,
-    )
-    x = jax.random.normal(jax.random.PRNGKey(seed), (tokens, 16))
-    params = layer.init(jax.random.PRNGKey(seed + 1), x)
-    assert params["params"]["w_gate"].shape == (count, 16, 8)
-    assert params["params"]["router"]["kernel"].shape == (16, E)
-    return layer, x, params
-
-
 def test_renormalised_gates_sum_to_one():
     """With every expert the same matrix the layer's output is exactly
     that expert's: the chosen gates sum to one (OLMoE's, as they are,
     sum to less: tests/test_olmoe.py)."""
-    layer, x, params = _layer()
+    layer, x, params = scaffold.expert_layer("mellum2")
     p = params["params"]
     same = {
         k: jnp.broadcast_to(p[k][:1], p[k].shape)
         for k in ("w_gate", "w_up", "w_down")
     }
-    y = layer.apply({"params": dict(p, **same)}, x)
+    y = scaffold.apply(layer)({"params": dict(p, **same)}, x)
     expert = (
         jax.nn.silu(x @ p["w_gate"][0]) * (x @ p["w_up"][0])
     ) @ p["w_down"][0]
@@ -209,64 +198,6 @@ def test_renormalised_gates_sum_to_one():
     probs = jax.nn.softmax(x @ p["router"]["kernel"])
     gate, _ = jax.lax.top_k(probs, 2)
     assert float(gate.sum(axis=-1).max()) < 0.95
-
-
-@pytest.mark.parametrize("side", ["program", "reference"])
-def test_the_four_shares_add_up_to_the_uncut_layer(side):
-    """The test that ties the share to the model: 64 experts, top 8, the
-    same router; the layer outputs of `held` (0, 16), (16, 16), (32, 16),
-    (48, 16), each holding its own quarter of the uncut layer's expert
-    weights, add up to the uncut 64-expert layer's output. On the
-    program (values and the gradient with respect to x) and on the
-    reference."""
-    E, K, tokens, d = 64, 8, 40, 16
-    uncut, x, params = _layer(None, tokens=tokens, seed=4, E=E, K=K)
-    p = params["params"]
-
-    def share_params(first, count):
-        return {"params": dict(
-            router=p["router"],
-            **{k: p[k][first : first + count]
-               for k in ("w_gate", "w_up", "w_down")},
-        )}
-
-    if side == "program":
-        def run(first, count, x):
-            held = None if count == E else (first, count)
-            layer = moe.DroplessMoE(
-                d_ff=8, num_experts=E, top_k=K, renormalise=True, held=held,
-            )
-            return layer.apply(share_params(first, count), x)
-    else:
-        def run(first, count, x):
-            config = {
-                "published_num_experts": E, "num_experts": count,
-                "expert_share": [first // count, E // count],
-                "num_experts_per_tok": K, "norm_topk_prob": True,
-                "load_balance_weight": 0.001,
-            }
-            return reference._experts(
-                x, share_params(first, count)["params"], config
-            )[0]
-
-    whole = run(0, E, x)
-    parts = [run(first, 16, x) for first in (0, 16, 32, 48)]
-    assert all(float(jnp.max(jnp.abs(part))) > 0 for part in parts)
-    np.testing.assert_allclose(sum(parts), whole, RTOL, ATOL)
-    # No share is the whole.
-    assert float(jnp.max(jnp.abs(parts[0] - whole))) > 1e-3
-
-    def total(first, count):
-        return lambda x: jnp.sum(jnp.sin(run(first, count, x)))
-
-    grad_whole = jax.grad(total(0, E))(x)
-    grad_parts = sum(
-        jax.grad(
-            lambda x, f=first: jnp.sum(jnp.cos(whole) * run(f, 16, x))
-        )(x)
-        for first in (0, 16, 32, 48)
-    )
-    np.testing.assert_allclose(grad_parts, grad_whole, rtol=1e-4, atol=1e-5)
 
 
 def test_a_share_costs_no_rows_of_the_other_experts():
@@ -286,8 +217,9 @@ def test_a_share_costs_no_rows_of_the_other_experts():
     loss = lambda r, w, first: jnp.sum(
         jnp.sin(moe.grouped_matmul(r, w, sizes, first))[3:10]
     )
-    d_rows, d_held = jax.grad(loss, argnums=(0, 1))(rows, held, 2)
-    w_rows, w_all = jax.grad(loss, argnums=(0, 1))(rows, weights, None)
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1)), static_argnums=2)
+    d_rows, d_held = grad(rows, held, 2)
+    w_rows, w_all = grad(rows, weights, None)
     np.testing.assert_allclose(d_rows[3:10], w_rows[3:10], RTOL, ATOL)
     assert not np.any(d_rows[:3]) and not np.any(d_rows[10:])
     np.testing.assert_allclose(d_held, w_all[2:4], RTOL, ATOL)

@@ -10,6 +10,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import AtariNet, LSTMCore, ResNet, create_model
 from torchbeast_tpu.types import AgentOutput
@@ -36,12 +37,13 @@ def test_forward_shapes(model_cls, use_lstm):
     model = model_cls(num_actions=NUM_ACTIONS, use_lstm=use_lstm)
     inputs = make_inputs()
     core_state = model.initial_state(B)
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         inputs,
         core_state,
     )
-    out, new_state = model.apply(
+    out, new_state = scaffold.apply(model)(
         params, inputs, core_state, rngs={"action": jax.random.PRNGKey(2)}
     )
     assert isinstance(out, AgentOutput)
@@ -71,15 +73,17 @@ def test_initial_state_shapes():
 def test_argmax_is_deterministic_and_sampling_varies():
     model = AtariNet(num_actions=NUM_ACTIONS)
     inputs = make_inputs()
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         inputs,
         (),
     )
     # Greedy path needs no action rng and is reproducible (reference eval
     # path, monobeast.py:621-623).
-    out1, _ = model.apply(params, inputs, (), sample_action=False)
-    out2, _ = model.apply(params, inputs, (), sample_action=False)
+    greedy = scaffold.forward(model)
+    out1, _ = greedy(params, inputs, ())
+    out2, _ = greedy(params, inputs, ())
     np.testing.assert_array_equal(out1.action, out2.action)
     np.testing.assert_array_equal(
         out1.action, jnp.argmax(out1.policy_logits, axis=-1)
@@ -87,10 +91,10 @@ def test_argmax_is_deterministic_and_sampling_varies():
     # Sampling path: different rng keys must give different action sequences
     # (with T*B=8 draws from 6 near-uniform actions, a collision across all
     # draws is astronomically unlikely).
-    s1, _ = model.apply(
+    s1, _ = scaffold.apply(model)(
         params, inputs, (), rngs={"action": jax.random.PRNGKey(10)}
     )
-    s2, _ = model.apply(
+    s2, _ = scaffold.apply(model)(
         params, inputs, (), rngs={"action": jax.random.PRNGKey(11)}
     )
     assert not np.array_equal(s1.action, s2.action)
@@ -103,13 +107,13 @@ def test_lstm_core_done_resets_state():
     inp = jnp.broadcast_to(jnp.arange(5.0), (6, 3, 5))
     notdone = jnp.zeros((6, 3))
     state = core.initial_state(3)
-    params = core.init(jax.random.PRNGKey(0), inp, notdone, state)
-    out, _ = core.apply(params, inp, notdone, state)
+    params = scaffold.init(core, jax.random.PRNGKey(0), inp, notdone, state)
+    out, _ = scaffold.apply(core)(params, inp, notdone, state)
     for t in range(1, 6):
         np.testing.assert_allclose(out[t], out[0], rtol=1e-6)
 
     # Without dones the state carries: outputs at t>0 differ from t=0.
-    out2, _ = core.apply(params, inp, jnp.ones((6, 3)), state)
+    out2, _ = scaffold.apply(core)(params, inp, jnp.ones((6, 3)), state)
     assert not np.allclose(out2[1], out2[0])
 
 
@@ -120,14 +124,14 @@ def test_lstm_core_scan_matches_stepwise():
     inp = jnp.asarray(rng.standard_normal((5, 2, 3)).astype(np.float32))
     notdone = jnp.asarray((rng.random((5, 2)) > 0.3).astype(np.float32))
     state = core.initial_state(2)
-    params = core.init(jax.random.PRNGKey(0), inp, notdone, state)
+    params = scaffold.init(core, jax.random.PRNGKey(0), inp, notdone, state)
 
-    full_out, full_state = core.apply(params, inp, notdone, state)
+    full_out, full_state = scaffold.apply(core)(params, inp, notdone, state)
 
     step_state = state
     outs = []
     for t in range(5):
-        o, step_state = core.apply(
+        o, step_state = scaffold.apply(core)(
             params, inp[t : t + 1], notdone[t : t + 1], step_state
         )
         outs.append(o[0])
@@ -148,7 +152,8 @@ def test_resnet_feature_size():
     # hard-coded nn.Linear(3872, 256) (polybeast_learner.py:195).
     model = ResNet(num_actions=NUM_ACTIONS)
     inputs = make_inputs()
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         inputs,
         (),
@@ -170,7 +175,8 @@ def test_resnet_remat_variants_identical(remat):
     for flag in (False, remat):
         model = ResNet(num_actions=NUM_ACTIONS, use_lstm=True, remat=flag)
         state = model.initial_state(2)
-        params = model.init(
+        params = scaffold.init(
+            model,
             {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
             inputs,
             state,
@@ -201,7 +207,8 @@ def test_resnet_trunk_channels_variant():
     )
     inputs = make_inputs(t=2, b=2)
     state = model.initial_state(2)
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         inputs,
         state,
@@ -210,7 +217,7 @@ def test_resnet_trunk_channels_variant():
     assert trunk["feat_conv_0"]["kernel"].shape[-1] == 32
     assert trunk["feat_conv_2"]["kernel"].shape[-1] == 64
     assert trunk["fc"]["kernel"].shape == (11 * 11 * 64, 256)
-    out, _ = model.apply(params, inputs, state, sample_action=False)
+    out, _ = scaffold.forward(model)(params, inputs, state)
     assert out.policy_logits.shape == (2, 2, NUM_ACTIONS)
 
 
@@ -218,7 +225,8 @@ def test_resnet_remat_length_validated():
     model = ResNet(num_actions=NUM_ACTIONS, remat=(True, False))
     inputs = make_inputs(t=2, b=1)
     with pytest.raises(ValueError, match="one flag per stage"):
-        model.init(
+        scaffold.init(
+            model,
             {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
             inputs,
             (),
@@ -359,7 +367,8 @@ def test_forward_matches_time_major_formulation(family, one_device, t,
         ),
         model.initial_state(b),
     )
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         inputs,
         state,
@@ -373,10 +382,19 @@ def test_forward_matches_time_major_formulation(family, one_device, t,
         return categorical(key, logits, axis=axis)
 
     monkeypatch.setattr(jax.random, "categorical", spy)
-    out, new_state = model.apply(
-        params, inputs, state, rngs={"action": jax.random.PRNGKey(2)}
-    )
-    want_logits, want_baseline, want_state = _time_major_forward(
+
+    # A trace of the case's own (the spy is read at trace time), which
+    # hands out what the spy saw of it.
+    def run(params, inputs, state):
+        out, new_state = model.apply(
+            params, inputs, state, rngs={"action": jax.random.PRNGKey(2)}
+        )
+        return out, new_state, tuple(drawn)
+
+    traced = jax.jit(run)
+    out, new_state, drawn = traced(params, inputs, state)
+    time_major = jax.jit(_time_major_forward, static_argnums=0)
+    want_logits, want_baseline, want_state = time_major(
         family, params, inputs, state
     )
 

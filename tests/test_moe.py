@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import create_model, stats
 from torchbeast_tpu.models.moe import MoEFFN
@@ -28,7 +29,7 @@ D, FF, E = 8, 16, 4
 
 def _init(key, moe, tokens=16):
     x = jax.random.normal(jax.random.PRNGKey(9), (tokens, D))
-    params = moe.init(key, x)
+    params = scaffold.init(moe, key, x)
     return params, x
 
 
@@ -46,7 +47,7 @@ def test_identical_experts_equal_dense_ffn():
         w_out=jnp.broadcast_to(p["w_out"][:1], p["w_out"].shape),
         b_out=jnp.broadcast_to(p["b_out"][:1], p["b_out"].shape),
     )
-    y = moe.apply({"params": p}, x)
+    y = scaffold.apply(moe)({"params": p}, x)
     dense = (
         nn.gelu(x @ p["w_in"][0] + p["b_in"][0]) @ p["w_out"][0]
         + p["b_out"][0]
@@ -68,7 +69,7 @@ def test_capacity_overflow_drops_tokens():
     router[:, 0] = 0.0  # uniform logits -> top_k ties resolve to expert 0
     p["router"] = {"kernel": jnp.asarray(router)}
     # capacity = ceil(1 * 8 / 4 * 1.0) = 2
-    y = moe.apply({"params": p}, x)
+    y = scaffold.apply(moe)({"params": p}, x)
     nonzero_rows = np.flatnonzero(np.abs(np.asarray(y)).sum(axis=1) > 1e-9)
     assert len(nonzero_rows) == 2, nonzero_rows
     np.testing.assert_array_equal(nonzero_rows, [0, 1])  # token order wins
@@ -82,7 +83,7 @@ def test_expert_parallel_matches_unsharded():
         d_model=D, d_ff=FF, num_experts=n_dev, top_k=2, mesh=mesh
     )
     params, x = _init(jax.random.PRNGKey(2), moe_plain, tokens=32)
-    y_plain = moe_plain.apply(params, x)
+    y_plain = scaffold.apply(moe_plain)(params, x)
 
     placed = {
         "params": place_expert_params(mesh, params["params"])
@@ -90,8 +91,7 @@ def test_expert_parallel_matches_unsharded():
     shardings = expert_param_shardings(mesh, params["params"])
     assert not shardings["w_in"].is_fully_replicated
     assert shardings["router"]["kernel"].is_fully_replicated
-    apply_ep = jax.jit(moe_ep.apply)
-    y_ep = apply_ep(placed, x)
+    y_ep = scaffold.apply(moe_ep)(placed, x)
     np.testing.assert_allclose(y_ep, y_plain, rtol=1e-5, atol=1e-5)
 
 
@@ -100,7 +100,7 @@ def test_aux_loss_sown_and_balanced_floor():
         d_model=D, d_ff=FF, num_experts=E, top_k=2, aux_loss_weight=1.0
     )
     params, x = _init(jax.random.PRNGKey(3), moe, tokens=64)
-    _, variables = moe.apply(params, x, mutable=["losses"])
+    _, variables = scaffold.apply(moe, mutable=("losses",))(params, x)
     assert "losses" not in params  # init() must not materialize it
     aux = variables["losses"]["moe_load_balance"]
     # E * sum(f_e * p_e) >= 1 with equality iff perfectly uniform.
@@ -131,7 +131,8 @@ def test_transformer_moe_trains_and_aux_flows():
         "baseline": rng.standard_normal((T + 1, B)).astype(np.float32),
     }
     state = model.initial_state(B)
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(5), "action": jax.random.PRNGKey(6)},
         batch,
         state,
@@ -165,7 +166,8 @@ def test_acting_path_unaffected_by_sow():
         "last_action": np.zeros((1, B), np.int32),
     }
     state = model.initial_state(B)
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(7), "action": jax.random.PRNGKey(8)},
         dict(inputs, episode_return=np.zeros((1, B), np.float32),
              episode_step=np.zeros((1, B), np.int32),
@@ -174,7 +176,7 @@ def test_acting_path_unaffected_by_sow():
              baseline=np.zeros((1, B), np.float32)),
         state,
     )
-    out, new_state = model.apply(
+    out, new_state = scaffold.apply(model)(
         params, inputs, state, rngs={"action": jax.random.PRNGKey(9)}
     )
     assert out.action.shape == (1, B)
@@ -189,7 +191,8 @@ def _init_model_params(model, A, frame_shape=(4, 4, 1), B=2):
         "last_action": np.zeros((1, B), np.int32),
     }
     state = model.initial_state(B)
-    return model.init(
+    return scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(11), "action": jax.random.PRNGKey(12)},
         dummy,
         state,
@@ -306,11 +309,11 @@ def test_router_fields_at_their_defaults_are_the_old_layer(fields):
 
     x = jax.random.normal(jax.random.PRNGKey(9), (24, D))
     layer = DroplessMoE(d_ff=FF, num_experts=E, top_k=2, **fields)
-    params = layer.init(jax.random.PRNGKey(0), x)
+    params = scaffold.init(layer, jax.random.PRNGKey(0), x)
     assert sorted(params["params"]) == ["router", "w_down", "w_gate", "w_up"]
-    apply = jax.jit(lambda v, x: layer.apply(
-        v, x, mutable=("losses", "param_steps") + stats.COLLECTIONS
-    ))
+    apply = scaffold.apply(
+        layer, mutable=("losses", "param_steps") + stats.COLLECTIONS
+    )
     old_layer = jax.jit(lambda p, x: _old_dropless_layer(p, x, 2))
     y, sown = apply(params, x)
     np.testing.assert_array_equal(y, old_layer(params["params"], x))
@@ -336,12 +339,12 @@ def test_sigmoid_router_by_hand(renormalise):
         renormalise=renormalise, scoring="sigmoid", routed_scaling=1.5,
         shared_width=6,
     )
-    params = layer.init(jax.random.PRNGKey(0), x)
+    params = scaffold.init(layer, jax.random.PRNGKey(0), x)
     p = params["params"]
     assert p["shared_gate"]["kernel"].shape == (D, 6)
-    y, sown = layer.apply(
-        params, x, mutable=("losses",) + stats.COLLECTIONS
-    )
+    y, sown = scaffold.apply(
+        layer, mutable=("losses",) + stats.COLLECTIONS
+    )(params, x)
     assert "losses" not in sown
     assert float(stats.folded(sown)["moe_shared_applications"]) == 1.0
     scores = np.asarray(jax.nn.sigmoid(x @ p["router"]["kernel"]))
@@ -360,9 +363,10 @@ def test_sigmoid_router_by_hand(renormalise):
     ) @ p["shared_down"]["kernel"]
     np.testing.assert_allclose(y, want + shared, rtol=1e-5, atol=1e-6)
     with pytest.raises(ValueError, match="Unknown scoring"):
-        DroplessMoE(
-            d_ff=FF, num_experts=E, top_k=2, scoring="tanh"
-        ).init(jax.random.PRNGKey(0), x)
+        scaffold.init(
+            DroplessMoE(d_ff=FF, num_experts=E, top_k=2, scoring="tanh"),
+            jax.random.PRNGKey(0), x,
+        )
 
 
 @pytest.mark.parametrize(
@@ -378,7 +382,7 @@ def test_dropless_layer_with_its_experts_held(held):
 
     x = jax.random.normal(jax.random.PRNGKey(9), (24, D))
     whole = DroplessMoE(d_ff=FF, num_experts=E, top_k=2)
-    params = whole.init(jax.random.PRNGKey(0), x)
+    params = scaffold.init(whole, jax.random.PRNGKey(0), x)
     p = params["params"]
     layer = DroplessMoE(d_ff=FF, num_experts=E, top_k=2, held=held)
     first, count = held or (0, E)
@@ -386,19 +390,15 @@ def test_dropless_layer_with_its_experts_held(held):
         k: p[k][first : first + count] for k in ("w_gate", "w_up", "w_down")
     })}
     shapes = jax.tree_util.tree_map(
-        jnp.shape, layer.init(jax.random.PRNGKey(0), x)
+        jnp.shape, scaffold.init(layer, jax.random.PRNGKey(0), x)
     )
     assert shapes == jax.tree_util.tree_map(jnp.shape, mine)
-    apply = jax.jit(
-        lambda v, x: layer.apply(
-            v, x, mutable=("losses",) + stats.COLLECTIONS
-        )
-    )
+    mutable = ("losses",) + stats.COLLECTIONS
     as_it_was = jax.jit(lambda p, x: _old_dropless_layer(p, x, 2))
-    (y, sown), old = apply(mine, x), as_it_was(p, x)
-    y_whole, sown_whole = whole.apply(
-        params, x, mutable=("losses",) + stats.COLLECTIONS
+    (y, sown), old = (
+        scaffold.apply(layer, mutable=mutable)(mine, x), as_it_was(p, x)
     )
+    y_whole, sown_whole = scaffold.apply(whole, mutable=mutable)(params, x)
     assert float(sown["losses"]["moe_load_balance"]) == float(
         sown_whole["losses"]["moe_load_balance"]
     )
@@ -418,13 +418,14 @@ def test_dropless_layer_with_its_experts_held(held):
             for k in ("w_gate", "w_up", "w_down")
         })}
         np.testing.assert_allclose(
-            y + other.apply(theirs, x), old, rtol=1e-5, atol=1e-6
+            y + scaffold.apply(other)(theirs, x), old, rtol=1e-5, atol=1e-6
         )
         held_rows = float(stats.folded(sown)["moe_held_assignments"])
         assert 0 < held_rows < 48
     with pytest.raises(ValueError, match="not a range"):
-        DroplessMoE(d_ff=FF, num_experts=E, top_k=2, held=(3, 2)).init(
-            jax.random.PRNGKey(0), x
+        scaffold.init(
+            DroplessMoE(d_ff=FF, num_experts=E, top_k=2, held=(3, 2)),
+            jax.random.PRNGKey(0), x,
         )
 
 
@@ -462,7 +463,7 @@ def test_ungated_relu2_experts_in_a_latent_by_hand():
         renormalise=True, scoring="sigmoid", routed_scaling=5.0,
         shared_width=6, gated=False, activation="relu2", latent_width=5,
     )
-    params = layer.init(jax.random.PRNGKey(0), x)
+    params = scaffold.init(layer, jax.random.PRNGKey(0), x)
     p = params["params"]
     assert jax.tree_util.tree_map(jnp.shape, p) == {
         "router": {"kernel": (D, E)},
@@ -470,18 +471,20 @@ def test_ungated_relu2_experts_in_a_latent_by_hand():
         "w_up": (E, 5, FF), "w_down": (E, FF, 5),
         "shared_up": {"kernel": (D, 6)}, "shared_down": {"kernel": (6, D)},
     }
-    y, sown = layer.apply(
-        params, x, mutable=("losses",) + stats.COLLECTIONS
-    )
+    y, sown = scaffold.apply(
+        layer, mutable=("losses",) + stats.COLLECTIONS
+    )(params, x)
     assert float(stats.folded(sown)["moe_latent_applications"]) == 1.0
     np.testing.assert_allclose(
         y, _latent_by_hand(x, p, 2, 5.0), rtol=1e-5, atol=1e-6
     )
     # relu^2 is not relu, and the activation is the shared expert's too.
-    relu = layer.clone(activation="silu").apply(params, x)
+    relu = scaffold.apply(layer.clone(activation="silu"))(params, x)
     assert float(jnp.max(jnp.abs(relu - y))) > 1e-3
     with pytest.raises(ValueError, match="Unknown activation"):
-        layer.clone(activation="gelu").init(jax.random.PRNGKey(0), x)
+        scaffold.init(
+            layer.clone(activation="gelu"), jax.random.PRNGKey(0), x
+        )
 
 
 def _routed_to(kernel, x, first, column, sign):
@@ -523,7 +526,7 @@ def test_fewer_experts_held_than_chosen_see_a_window_of_the_rows(
         shared_width=6, gated=False, activation="relu2", latent_width=5,
         held=(first, 2),
     )
-    params = layer.init(jax.random.PRNGKey(1), x)
+    params = scaffold.init(layer, jax.random.PRNGKey(1), x)
     if routing:
         kernel = params["params"]["router"]["kernel"]
         sign = -1 if routing == "never" else 1
@@ -533,7 +536,7 @@ def test_fewer_experts_held_than_chosen_see_a_window_of_the_rows(
         params = {"params": dict(
             params["params"], router={"kernel": kernel}
         )}
-    y, sown = layer.apply(params, x, mutable=stats.COLLECTIONS)
+    y, sown = scaffold.apply(layer, mutable=stats.COLLECTIONS)(params, x)
     want = _latent_by_hand(x, params["params"], top_k, 5.0, held=(first, 2))
     np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-6)
     assert float(stats.folded(sown)["moe_assignments"]) == tokens * top_k
@@ -572,8 +575,10 @@ def test_fewer_experts_held_than_chosen_see_a_window_of_the_rows(
             ) @ p["shared_down"]["kernel"]
         ))
 
-    got = jax.grad(by_rows, argnums=(0, 1))(params, x)
-    ref = jax.grad(by_hand, argnums=(0, 1))(params, x)
+    got_fn = jax.jit(jax.grad(by_rows, argnums=(0, 1)))
+    got = got_fn(params, x)
+    ref_fn = jax.jit(jax.grad(by_hand, argnums=(0, 1)))
+    ref = ref_fn(params, x)
     for a, b in zip(
         jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(ref)
     ):
@@ -661,7 +666,8 @@ def test_window_dispatch_and_combine_against_plain_gathers(
     def dispatch(x):
         return moe._window_dispatch(x, idx, *indices(idx), first, held)
 
-    rows, groups, at = dispatch(x)
+    dispatched = jax.jit(dispatch)
+    rows, groups, at = dispatched(x)
     np.testing.assert_array_equal(at.order[:live], order_w[:live])
     np.testing.assert_array_equal(at.token, order_w // K)
     np.testing.assert_array_equal(at.slot, slot)
@@ -675,8 +681,12 @@ def test_window_dispatch_and_combine_against_plain_gathers(
     np.testing.assert_array_equal(rows, np.asarray(x)[order_w // K])
     # The kernels visit the held experts' rows alone: so does the loss.
     weights = weights * (jnp.arange(window) < live)[:, None]
-    got = jax.grad(lambda x: jnp.sum(dispatch(x)[0] * weights))(x)
-    want = jax.grad(lambda x: jnp.sum(x[order_w // K] * weights))(x)
+    got_fn = jax.jit(jax.grad(lambda x: jnp.sum(dispatch(x)[0] * weights)))
+    got = got_fn(x)
+    want_fn = jax.jit(
+        jax.grad(lambda x: jnp.sum(x[order_w // K] * weights))
+    )
+    want = want_fn(x)
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
     hit = slot < window
@@ -692,15 +702,21 @@ def test_window_dispatch_and_combine_against_plain_gathers(
     def combine(out, gate):
         return moe._window_combine(out, gate, at)
 
+    def traced(f, argument=None):
+        """f, or its gradient against `tangent`, as one program."""
+        if argument is None:
+            return jax.jit(f)
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(f(*a) * tangent), argnums=argument
+        ))
+
     np.testing.assert_allclose(
-        combine(out, gate), plain(out, gate), rtol=1e-6, atol=1e-6
+        traced(combine)(out, gate), traced(plain)(out, gate),
+        rtol=1e-6, atol=1e-6,
     )
     for argument in (0, 1):
         got, want = (
-            jax.grad(
-                lambda *a, f=f: jnp.sum(f(*a) * tangent), argnums=argument
-            )(out, gate)
-            for f in (combine, plain)
+            traced(f, argument)(out, gate) for f in (combine, plain)
         )
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
     # Backward too, rows move by gathers alone.
@@ -891,8 +907,10 @@ def test_window_rungs_follow_from_shapes_alone(
     if want:
         rung, window = want
 
+        swept = jax.jit(functools.partial(moe.window_sweeps, want))
+
         def sweeps(*mine):
-            return int(moe.window_sweeps(want, jnp.asarray(mine)))
+            return int(swept(jnp.asarray(mine)))
 
         assert sweeps(rung, 0) == 1
         # Every token on as many held experts as it can choose.
@@ -1024,7 +1042,11 @@ def test_grouped_matmul_passes_follow_the_traced_precision(
             precision=jax.lax.Precision.HIGHEST,
         )
 
-    got = moe._gmm_call(exact, lhs, rhs, sizes, rows, terms)
+    # One trace: the stand-in counts its calls as the trace makes them.
+    got_fn = jax.jit(
+        lambda lhs, rhs: moe._gmm_call(exact, lhs, rhs, sizes, rows, terms)
+    )
+    got = got_fn(lhs, rhs)
     want = np.asarray(lhs, np.float64) @ np.asarray(rhs[0], np.float64)
     scale = np.abs(np.asarray(lhs)) @ np.abs(np.asarray(rhs[0]))
     assert len(calls) == passes

@@ -510,158 +510,3 @@ def test_superstep_divisibility_rejected(tmp_path):
     )
     with pytest.raises(ValueError, match="superstep_k"):
         monobeast.train(flags)
-
-
-def _mellum2_through_main(stats, share=True):
-    # 2 rows x 6 steps x top 2 x 4 layers, over all 8 experts.
-    assert stats["moe_assignments"] == 2 * 6 * 2 * 4
-    assert ("moe_held_assignments" in stats) == share
-
-
-def _kanana2_through_main(stats):
-    assert stats["attention_latent_applications"] == 3
-    # A latent [6, 16], a rope key [6, 4] and a validity column, f32,
-    # for each of the 3 caches.
-    assert stats["attention_latent_cache_bytes_per_row"] == (
-        3 * 4 * 6 * (16 + 4 + 1)
-    )
-    assert stats["moe_bias_steps"] == 2
-    assert stats["moe_shared_applications"] == 2
-    # (The mock env's frames are all alike: the experts held may draw
-    # every row or none.)
-    assert 0 <= stats["moe_held_assignments"] <= stats["moe_assignments"]
-    # At least one update moved them: 0.001 a step from zero.
-    assert stats["moe_bias_abs_max"] >= 0.001 - 1e-9
-    assert stats["aux_loss"] == 0.0
-
-
-def _nemotron3_through_main(stats):
-    assert stats["ssm_applications"] == 1
-    assert stats["ssm_chunks"] == 2  # 6 steps in chunks of 4
-    # The held half: 4 heads' [4, 6] states and a tail of 3 inputs over
-    # 4 x 4 + 2 x 2 x 6 channels, f32.
-    assert stats["ssm_state_bytes_per_row"] == 4 * (4 * 4 * 6 + 3 * 40)
-    assert stats["ssm_resets_per_row"] >= 0
-    assert stats["moe_latent_applications"] == 1
-    assert stats["moe_shared_applications"] == 1
-    assert stats["moe_bias_steps"] == 1
-    assert 0 <= stats["moe_held_assignments"] <= stats["moe_assignments"]
-    assert stats["moe_bias_abs_max"] >= 0.001 - 1e-9
-    assert stats["aux_loss"] == 0.0
-
-
-def _qwen3next_through_main(stats):
-    assert stats["delta_applications"] == 1
-    assert stats["delta_chunks"] == 2  # 6 steps in chunks of 4
-    # 4 value heads' [6, 5] matrix states and a tail of 3 inputs over
-    # 2 x 2 x 6 + 4 x 5 channels, f32.
-    assert stats["delta_state_bytes_per_row"] == 4 * (4 * 6 * 5 + 3 * 44)
-    assert stats["delta_resets_per_row"] >= 0
-    assert stats["attention_gated_applications"] == 1
-    assert stats["moe_shared_applications"] == 2
-    assert 0 <= stats["moe_held_assignments"] <= stats["moe_assignments"]
-    assert stats["aux_loss"] >= 0.001 * 2 * 0.99  # two layers' balance
-
-
-def _ouro_through_main(stats):
-    assert stats["loop_passes"] == 3
-    assert stats["loop_block_applications"] == 6
-    # k, v [6, 4, 8] and a validity column, f32, for each of 6 caches.
-    assert stats["loop_cache_bytes_per_row"] == 6 * 4 * 6 * (2 * 32 + 1)
-    assert 1.0 <= stats["loop_expected_exit_pass"] <= 3.0
-    assert 0.0 <= stats["loop_exit_p_last"] <= 1.0
-
-
-_MELLUM2_WIDTHS = dict(
-    d_model=32, num_heads=4, kv_heads=2, head_dim=8, sliding_window=4,
-    num_experts=8, experts_per_token=2, expert_width=16,
-)
-# A family a row (a `model_config` PR adds one: tests/family_scaffold.py):
-# what its `PUBLISHED` table is shrunk to, its flags, and what the last
-# update's stats must say.
-#  mellum2: window 4, so the sliding layers carry 3 slots and the full
-#   layer 6; with and without a share of the experts.
-#  kanana2: a dense layer and two MoE layers, share 1 of 4, the blocks
-#   rematerialised, the selection biases moved by the load after every
-#   optimizer step and carried by the checkpoint.
-#  nemotron3: one period of attention, latent MoE and Mamba-2, half of
-#   each mixer's heads and a quarter of the experts: acting through the
-#   rolling cache AND the Mamba state with its conv tail, unrolls of 5
-#   scanned in chunks of 4.
-#  qwen3next: one Gated DeltaNet layer and one gated attention layer, a
-#   quarter of the experts: acting through the MATRIX state with its
-#   conv tail AND the rolling cache of un-rotated keys, unrolls of 5
-#   scanned in chunks of 4.
-#  ouro: 2 layers run 3 times, through 3 x 2 rolling caches.
-THROUGH_MAIN = {
-    "mellum2-all-experts": (
-        "mellum2", _MELLUM2_WIDTHS, dict(num_layers=4),
-        lambda stats: _mellum2_through_main(stats, share=False),
-    ),
-    "mellum2-share-1-of-4": (
-        "mellum2", _MELLUM2_WIDTHS, dict(num_layers=4, expert_share="1/4"),
-        _mellum2_through_main,
-    ),
-    "kanana2": (
-        "kanana2",
-        dict(
-            d_model=32, num_heads=4, latent_rank=16, nope_head_dim=8,
-            rope_head_dim=4, value_head_dim=8, mlp_width=48, num_experts=8,
-            experts_per_token=2, expert_width=16,
-        ),
-        dict(num_layers=3, expert_share="1/4", remat="all"),
-        _kanana2_through_main,
-    ),
-    "nemotron3": (
-        "nemotron3",
-        dict(
-            d_model=32, num_heads=4, kv_heads=2, head_dim=8, mamba_heads=8,
-            mamba_head_dim=4, mamba_groups=4, state_size=6, chunk_size=4,
-            num_experts=8, experts_per_token=3, expert_width=10,
-            latent_width=12, shared_width=20, layer_period="*EM",
-        ),
-        dict(
-            num_layers=3, expert_share="1/4", mixer_share="1/2", remat="all",
-        ),
-        _nemotron3_through_main,
-    ),
-    "qwen3next": (
-        "qwen3next",
-        dict(
-            d_model=32, attention_interval=2, num_heads=4, kv_heads=2,
-            head_dim=16, delta_key_heads=2, delta_value_heads=4,
-            delta_key_dim=6, delta_value_dim=5, chunk_size=4, num_experts=8,
-            experts_per_token=2, expert_width=10, shared_width=12,
-        ),
-        dict(num_layers=2, expert_share="1/4", remat="all"),
-        _qwen3next_through_main,
-    ),
-    "ouro": (
-        "ouro",
-        dict(d_model=32, num_heads=4, head_dim=8, mlp_width=48, passes=3),
-        dict(num_layers=2, remat="all"),
-        _ouro_through_main,
-    ),
-}
-
-
-@pytest.mark.parametrize("case", list(THROUGH_MAIN))
-def test_train_family_through_main(tmp_path, monkeypatch, case):
-    """`--model <family>` on the normal path, the family's table
-    shrunk: acting at T=1 through what the family carries (6-slot
-    caches), unrolls of 5, updates, the checkpoint; the last update's
-    stats carry the family's counters."""
-    import importlib
-
-    family, widths, flags, check = THROUGH_MAIN[case]
-    module = importlib.import_module(f"torchbeast_tpu.models.{family}")
-    monkeypatch.setattr(
-        module, "PUBLISHED", dict(module.PUBLISHED, **widths)
-    )
-    stats = monobeast.main(make_flags(
-        tmp_path, xpid=f"smoke-{family}", model=family, memory_len=6, **flags
-    ))
-    assert stats["step"] >= 40
-    assert np.isfinite(stats["total_loss"])
-    check(stats)
-    assert (tmp_path / f"smoke-{family}" / "model.ckpt").exists()

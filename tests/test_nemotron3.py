@@ -5,8 +5,9 @@ latent in models/moe.py DroplessMoE): against the plain reference on
 seeded weights (loss, gradients, new states), the chunked scan against
 the step-by-step recurrence with episode ends inside a chunk, batch
 forward against stepwise acting through the carried states and through
-the state table, and the shares of the mixers' heads and of the routed
-experts adding up to the uncut layers."""
+the state table, and the shares of the mixers' heads adding up to the
+uncut layer (those of the routed experts: an id of
+tests/test_families_shares.py)."""
 
 import numpy as np
 import pytest
@@ -372,86 +373,12 @@ def test_the_four_mixer_shares_add_up_to_the_uncut_mixers(side):
     assert float(jnp.max(jnp.abs(parts[0] - whole))) > 1e-3
 
 
-def _latent_layer(held=None, tokens=40, seed=0, E=16, K=3):
-    layer = moe.DroplessMoE(
-        d_ff=8, num_experts=E, top_k=K, aux_loss_weight=0.0,
-        renormalise=True, held=held, scoring="sigmoid", selection_bias=True,
-        bias_update_rate=0.001, routed_scaling=5.0, shared_width=12,
-        gated=False, activation="relu2", latent_width=10,
-    )
-    x = jax.random.normal(jax.random.PRNGKey(seed), (tokens, 16))
-    return layer, x, layer.init(jax.random.PRNGKey(seed + 1), x)
-
-
-@pytest.mark.parametrize("side", ["program", "reference"])
-def test_the_expert_shares_add_up_to_the_uncut_latent_layer(side):
-    """The routed parts of eight shares of 16 experts (the interpreted
-    grouped kernels are slow over 512; two held under three a token, so
-    a share's experts see the window of the sorted rows that can be
-    theirs, models/moe.py), each with its own eighth of the
-    uncut layer's expert weights, LIFTED OUT OF THE LATENT by the one
-    `latent_up` every chip holds, plus the shared expert COUNTED ONCE,
-    add up to the uncut layer's output; `latent_down` is applied on
-    every chip alike and is no part of the sum. Program (values and the
-    gradient with respect to x) and reference."""
-    E, K, tokens, shares = 16, 3, 40, 8
-    _, x, params = _latent_layer(tokens=tokens, seed=4)
-    p = dict(params["params"])
-    assert "w_gate" not in p and "shared_gate" not in p
-    p["e_score_correction_bias"] = 0.05 * jax.random.normal(
-        jax.random.PRNGKey(9), (E,)
-    )
-
-    def shared(x):
-        return jnp.square(jax.nn.relu(x @ p["shared_up"]["kernel"])) @ (
-            p["shared_down"]["kernel"]
-        )
-
-    def run(first, count, x):
-        cut = dict(p, **{
-            k: p[k][first : first + count] for k in ("w_up", "w_down")
-        })
-        if side == "program":
-            held = None if count == E else (first, count)
-            return _latent_layer(held, tokens=tokens)[0].apply(
-                {"params": cut}, x
-            )
-        return reference._experts(x, cut, {
-            "published_n_routed_experts": E, "n_routed_experts": count,
-            "expert_share": [first // count, E // count],
-            "num_experts_per_tok": K, "n_group": 1, "topk_group": 1,
-            "norm_topk_prob": True, "routed_scaling_factor": 5.0,
-            "n_shared_experts": 1, "mlp_hidden_act": "relu2",
-            "mlp_bias": False,
-        })
-
-    firsts = range(0, E, E // shares)
-    whole = run(0, E, x)
-    parts = [run(first, E // shares, x) - shared(x) for first in firsts]
-    assert all(float(jnp.max(jnp.abs(part))) > 0 for part in parts)
-    np.testing.assert_allclose(sum(parts) + shared(x), whole, RTOL, ATOL)
-    assert float(jnp.max(jnp.abs(parts[0] + shared(x) - whole))) > 1e-3
-    assert float(
-        jnp.max(jnp.abs(sum(parts) + shares * shared(x) - whole))
-    ) > 1e-3
-
-    grad_whole = jax.grad(lambda x: jnp.sum(jnp.sin(run(0, E, x))))(x)
-    weight = jnp.cos(whole)
-    grad_parts = sum(
-        jax.grad(lambda x, f=first: jnp.sum(
-            weight * (run(f, E // shares, x) - shared(x))
-        ))(x)
-        for first in firsts
-    ) + jax.grad(lambda x: jnp.sum(weight * shared(x)))(x)
-    np.testing.assert_allclose(grad_parts, grad_whole, rtol=1e-4, atol=1e-5)
-
-
 def test_the_gates_sum_to_five_and_the_experts_are_relu_squared():
     """One token, by hand: 22-of-512's rule at 3 of 16. The gates are
     the chosen sigmoid scores over their sum, times 5; an expert is
     W2 relu(W1 l)^2 on the latent l = W_down u; the shared expert reads
     u itself and is not scaled."""
-    layer, x, params = _latent_layer(tokens=1, seed=3)
+    layer, x, params = scaffold.expert_layer("nemotron3", tokens=1, seed=3)
     p = params["params"]
     u = x[0]
     scores = jax.nn.sigmoid(u @ p["router"]["kernel"])
@@ -466,7 +393,9 @@ def test_the_gates_sum_to_five_and_the_experts_are_relu_squared():
     want = routed @ p["latent_up"]["kernel"] + jnp.square(
         jax.nn.relu(u @ p["shared_up"]["kernel"])
     ) @ p["shared_down"]["kernel"]
-    np.testing.assert_allclose(layer.apply(params, x)[0], want, RTOL, ATOL)
+    np.testing.assert_allclose(
+        scaffold.apply(layer)(params, x)[0], want, RTOL, ATOL
+    )
 
 
 def test_layers_follow_the_pattern_and_the_state_holds_what_they_carry():

@@ -65,7 +65,7 @@ def test_batch_forward_equals_stepwise_acting_across_an_episode_end():
 def _layer(num_experts=8, top_k=2, d=16, width=8, tokens=12, seed=0):
     layer = moe.DroplessMoE(d_ff=width, num_experts=num_experts, top_k=top_k)
     x = jax.random.normal(jax.random.PRNGKey(seed), (tokens, d))
-    return layer, x, layer.init(jax.random.PRNGKey(seed + 1), x)
+    return layer, x, scaffold.init(layer, jax.random.PRNGKey(seed + 1), x)
 
 
 def _every_expert_masked(x, idx, gate, w_gate, w_up, w_down):
@@ -117,7 +117,7 @@ def test_gates_are_not_renormalised():
         k: jnp.broadcast_to(p[k][:1], p[k].shape)
         for k in ("w_gate", "w_up", "w_down")
     }
-    y = layer.apply({"params": dict(p, **same)}, x)
+    y = scaffold.apply(layer)({"params": dict(p, **same)}, x)
     probs = jax.nn.softmax(x @ p["router"]["kernel"])
     chosen = jnp.sort(probs, axis=-1)[:, -2:].sum(axis=-1)
     expert = (
@@ -140,9 +140,9 @@ def test_a_router_forced_onto_one_expert_drops_nothing():
     params["params"]["router"]["kernel"] = forced.at[0].set(bias_row)
     assert math.ceil(K * tokens / E * 1.25) < tokens
 
-    y, sown = layer.apply(
-        params, x, mutable=("losses",) + model_stats.COLLECTIONS
-    )
+    y, sown = scaffold.apply(
+        layer, mutable=("losses",) + model_stats.COLLECTIONS
+    )(params, x)
     stats = model_stats.folded(sown)
     assert float(stats["moe_assignments"]) == K * tokens
     assert float(stats["moe_load_max_over_mean"]) == pytest.approx(E / K)
@@ -174,12 +174,11 @@ def test_dropless_dispatch_equals_the_every_expert_masked_sum(tokens):
         y, _every_expert_masked(x, idx, gate, *weights), RTOL, ATOL
     )
     assert int(sizes.sum()) == 2 * tokens
-    got = jax.grad(total, argnums=(1, 2, 3, 4, 5))(
-        moe.dropless_experts, x, gate, *weights
+    grad = jax.jit(
+        jax.grad(total, argnums=(1, 2, 3, 4, 5)), static_argnums=0
     )
-    want = jax.grad(total, argnums=(1, 2, 3, 4, 5))(
-        _every_expert_masked, x, gate, *weights
-    )
+    got = grad(moe.dropless_experts, x, gate, *weights)
+    want = grad(_every_expert_masked, x, gate, *weights)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 

@@ -8,6 +8,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu.models import TransformerNet
 from torchbeast_tpu.ops.pallas_attention import (
     _reference,
@@ -66,8 +67,10 @@ def test_gradients_flow_and_match_reference():
             _reference(q, k, v, seg, valid, nodone, bias, M) ** 2
         )
 
-    g_ours = jax.grad(ours, argnums=(0, 1, 2, 3))(q, k, v, bias)
-    g_ref = jax.grad(ref, argnums=(0, 1, 2, 3))(q, k, v, bias)
+    g_ours_fn = jax.jit(jax.grad(ours, argnums=(0, 1, 2, 3)))
+    g_ours = g_ours_fn(q, k, v, bias)
+    g_ref_fn = jax.jit(jax.grad(ref, argnums=(0, 1, 2, 3)))
+    g_ref = g_ref_fn(q, k, v, bias)
     for a, b in zip(g_ours, g_ref):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-4
@@ -114,15 +117,14 @@ def test_model_pallas_matches_dense():
     inputs = make_model_inputs(seed=12, t=t, done=done)
 
     state0 = dense.initial_state(B)
-    params = dense.init(
+    params = scaffold.init(
+        dense,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         warm, state0,
     )
-    _, cache = dense.apply(params, warm, state0, sample_action=False)
-    out_d, state_d = dense.apply(params, inputs, cache,
-                                 sample_action=False)
-    out_p, state_p = palls.apply(params, inputs, cache,
-                                 sample_action=False)
+    _, cache = scaffold.forward(dense)(params, warm, state0)
+    out_d, state_d = scaffold.forward(dense)(params, inputs, cache)
+    out_p, state_p = scaffold.forward(palls)(params, inputs, cache)
     np.testing.assert_allclose(
         np.asarray(out_p.policy_logits), np.asarray(out_d.policy_logits),
         rtol=2e-4, atol=2e-5,
@@ -145,12 +147,13 @@ def test_model_pallas_stepwise_T1():
     dense = TransformerNet(num_actions=A)
     inputs = make_model_inputs(seed=21, t=1)
     state = dense.initial_state(B)
-    params = dense.init(
+    params = scaffold.init(
+        dense,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         inputs, state,
     )
-    out_d, _ = dense.apply(params, inputs, state, sample_action=False)
-    out_p, _ = palls.apply(params, inputs, state, sample_action=False)
+    out_d, _ = scaffold.forward(dense)(params, inputs, state)
+    out_p, _ = scaffold.forward(palls)(params, inputs, state)
     np.testing.assert_allclose(
         np.asarray(out_p.policy_logits), np.asarray(out_d.policy_logits),
         rtol=2e-4, atol=2e-5,

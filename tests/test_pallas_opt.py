@@ -32,6 +32,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu import precision as precision_lib
 from torchbeast_tpu.models import create_model
@@ -85,7 +86,8 @@ def _setup(precision, use_lstm, clip, momentum=0.0):
         ),
         pol.batch_dtype,
     )
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         make_batch(t=0),
         model.initial_state(B),
@@ -218,7 +220,8 @@ def test_entropy_anneal_reads_fused_count():
     )
     optimizer = learner_lib.make_optimizer(hp)
     model = create_model("mlp", num_actions=A)
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         make_batch(t=0),
         (),

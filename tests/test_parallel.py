@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import create_model
 from torchbeast_tpu.parallel import (
@@ -42,7 +43,8 @@ def setup():
     model = create_model("shallow", num_actions=A, use_lstm=True)
     batch = make_batch()
     state = model.initial_state(B)
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         batch,
         state,
@@ -109,7 +111,8 @@ def test_dp_plus_tp_update_matches_single_device(setup):
 
     model = create_model("mlp", num_actions=A)
     batch = make_batch()
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         batch,
         (),
@@ -183,7 +186,8 @@ def test_transformer_megatron_tp_matches_single_device():
     model = create_model("transformer", **kwargs)
     batch = make_batch(rng_seed=3)
     state = model.initial_state(B)
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(6), "action": jax.random.PRNGKey(7)},
         batch,
         state,
@@ -255,7 +259,8 @@ def test_transformer_tp_rejects_indivisible_heads():
         num_heads=2, memory_len=4,
     )
     batch = make_batch(rng_seed=4)
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(8), "action": jax.random.PRNGKey(9)},
         batch,
         model.initial_state(B),

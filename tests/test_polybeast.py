@@ -38,99 +38,6 @@ def test_polybeast_train_smoke(tmp_path):
     assert (tmp_path / "poly-smoke" / "logs.csv").exists()
 
 
-def _ouro_trained(stats):
-    assert stats["loop_passes"] == 3
-    assert stats["loop_block_applications"] == 6
-    assert 1.0 <= stats["loop_expected_exit_pass"] <= 3.0
-
-
-def _kanana2_trained(stats):
-    assert stats["attention_latent_applications"] == 2
-    assert stats["moe_bias_steps"] == 1
-    assert stats["moe_bias_abs_max"] >= 0.001 - 1e-9
-
-
-def _nemotron3_trained(stats):
-    assert stats["ssm_applications"] == 1
-    assert stats["moe_latent_applications"] == 1
-    assert stats["moe_bias_steps"] == 1
-
-
-def _qwen3next_trained(stats):
-    assert stats["delta_applications"] == 1
-    assert stats["attention_gated_applications"] == 1
-    assert stats["moe_shared_applications"] == 2
-
-
-# A family a row (a `model_config` PR adds one: tests/family_scaffold.py):
-# what its `PUBLISHED` table is shrunk to, its depth, and what the last
-# update's stats must say.
-#  ouro: 2 layers run 3 times; the state table's slots hold the 3 x 2
-#   caches, an act step runs the three passes.
-#  kanana2: the slots hold the latent caches (entries of two unequal
-#   leaves), the learner's updates move the selection biases.
-#  nemotron3: the slots hold both kinds of state (the attention layer's
-#   window, the Mamba layer's state and conv tail; the MoE layer has
-#   none), the learner's updates scan in chunks.
-#  qwen3next: the slots hold a matrix state with its conv tail beside
-#   the attention layer's window; the delta rule runs in chunks.
-FAMILIES = {
-    "ouro": (
-        dict(d_model=32, num_heads=4, head_dim=8, mlp_width=48, passes=3),
-        2, _ouro_trained,
-    ),
-    "kanana2": (
-        dict(
-            d_model=32, num_heads=4, latent_rank=16, nope_head_dim=8,
-            rope_head_dim=4, value_head_dim=8, mlp_width=48, num_experts=8,
-            experts_per_token=2, expert_width=16,
-        ),
-        2, _kanana2_trained,
-    ),
-    "nemotron3": (
-        dict(
-            d_model=32, num_heads=4, kv_heads=2, head_dim=8, mamba_heads=8,
-            mamba_head_dim=4, mamba_groups=4, state_size=6, chunk_size=4,
-            num_experts=8, experts_per_token=3, expert_width=10,
-            latent_width=12, shared_width=20, layer_period="*EM",
-        ),
-        3, _nemotron3_trained,
-    ),
-    "qwen3next": (
-        dict(
-            d_model=32, attention_interval=2, num_heads=4, kv_heads=2,
-            head_dim=16, delta_key_heads=2, delta_value_heads=4,
-            delta_key_dim=6, delta_value_dim=5, chunk_size=4, num_experts=8,
-            experts_per_token=2, expert_width=10, shared_width=12,
-        ),
-        2, _qwen3next_trained,
-    ),
-}
-
-
-@pytest.mark.parametrize("family", list(FAMILIES))
-def test_polybeast_train_family(tmp_path, monkeypatch, family):
-    """`--model <family>` through the async driver, the family's table
-    shrunk: the state table's slots hold what the family carries, the
-    blocks are rematerialised, the learner's updates report the
-    family's counters."""
-    import importlib
-
-    widths, layers, check = FAMILIES[family]
-    module = importlib.import_module(f"torchbeast_tpu.models.{family}")
-    monkeypatch.setattr(
-        module, "PUBLISHED", dict(module.PUBLISHED, **widths)
-    )
-    stats = polybeast.train(make_flags(
-        tmp_path, xpid=f"poly-{family}", model=family, num_layers=layers,
-        memory_len=6, remat="all",
-    ))
-    assert stats["step"] >= 60
-    assert np.isfinite(stats["total_loss"])
-    check(stats)
-    assert (tmp_path / f"poly-{family}" / "model.ckpt").exists()
-
-
 @pytest.mark.slow
 def test_polybeast_train_lstm(tmp_path):
     flags = make_flags(tmp_path, xpid="poly-lstm", use_lstm=True)

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import create_model
 from torchbeast_tpu.parallel.pp import stage_param_shardings
@@ -48,13 +49,14 @@ def test_pipelined_model_matches_sequential():
     seq, pipe, _ = _models()
     batch = _batch()
     state = seq.initial_state(B)
-    params = seq.init(
+    params = scaffold.init(
+        seq,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         batch,
         state,
     )
-    out_seq, _ = seq.apply(params, batch, state, sample_action=False)
-    out_pipe, _ = pipe.apply(params, batch, state, sample_action=False)
+    out_seq, _ = scaffold.forward(seq)(params, batch, state)
+    out_pipe, _ = scaffold.forward(pipe)(params, batch, state)
     np.testing.assert_allclose(
         out_pipe.policy_logits, out_seq.policy_logits, rtol=1e-5, atol=1e-5
     )
@@ -70,7 +72,8 @@ def test_pipelined_model_update_step_matches_sequential():
     seq, pipe, mesh = _models()
     batch = _batch(seed=1)
     state = seq.initial_state(B)
-    params = seq.init(
+    params = scaffold.init(
+        seq,
         {"params": jax.random.PRNGKey(2), "action": jax.random.PRNGKey(3)},
         batch,
         state,
@@ -131,13 +134,14 @@ def test_pipelined_model_with_lstm_head():
     batch = _batch(seed=2)
     state = seq.initial_state(B)
     assert len(state) == 2  # (h, c)
-    params = seq.init(
+    params = scaffold.init(
+        seq,
         {"params": jax.random.PRNGKey(4), "action": jax.random.PRNGKey(5)},
         batch,
         state,
     )
-    out_seq, st_seq = seq.apply(params, batch, state, sample_action=False)
-    out_pipe, st_pipe = pipe.apply(params, batch, state, sample_action=False)
+    out_seq, st_seq = scaffold.forward(seq)(params, batch, state)
+    out_pipe, st_pipe = scaffold.forward(pipe)(params, batch, state)
     np.testing.assert_allclose(
         out_pipe.policy_logits, out_seq.policy_logits, rtol=1e-5, atol=1e-5
     )
@@ -160,13 +164,14 @@ def test_pipelined_model_microbatch_count():
         "pipelined_mlp", mesh=mesh, n_microbatches=8, **kwargs
     )
     batch = _batch(seed=3)
-    params = seq.init(
+    params = scaffold.init(
+        seq,
         {"params": jax.random.PRNGKey(6), "action": jax.random.PRNGKey(7)},
         batch,
         (),
     )
-    out_seq, _ = seq.apply(params, batch, (), sample_action=False)
-    out_pipe, _ = pipe.apply(params, batch, (), sample_action=False)
+    out_seq, _ = scaffold.forward(seq)(params, batch, ())
+    out_pipe, _ = scaffold.forward(pipe)(params, batch, ())
     np.testing.assert_allclose(
         out_pipe.policy_logits, out_seq.policy_logits, rtol=1e-5, atol=1e-5
     )
@@ -181,13 +186,14 @@ def test_pipelined_model_more_stages_than_devices():
     seq = create_model("pipelined_mlp", **kwargs)
     pipe = create_model("pipelined_mlp", mesh=mesh, **kwargs)
     batch = _batch(seed=9)
-    params = seq.init(
+    params = scaffold.init(
+        seq,
         {"params": jax.random.PRNGKey(30), "action": jax.random.PRNGKey(31)},
         batch,
         (),
     )
-    out_seq, _ = seq.apply(params, batch, (), sample_action=False)
-    out_pipe, _ = pipe.apply(params, batch, (), sample_action=False)
+    out_seq, _ = scaffold.forward(seq)(params, batch, ())
+    out_pipe, _ = scaffold.forward(pipe)(params, batch, ())
     np.testing.assert_allclose(
         out_pipe.policy_logits, out_seq.policy_logits, rtol=1e-5, atol=1e-5
     )
@@ -218,13 +224,14 @@ def test_pipelined_transformer_matches_sequential_with_cache():
     seq, pipe, _ = _tf_models()
     b1, b2 = _batch(seed=4), _batch(seed=5)
     state0 = seq.initial_state(B)
-    params = seq.init(
+    params = scaffold.init(
+        seq,
         {"params": jax.random.PRNGKey(8), "action": jax.random.PRNGKey(9)},
         b1,
         state0,
     )
-    out_s1, st_s = seq.apply(params, b1, state0, sample_action=False)
-    out_p1, st_p = pipe.apply(params, b1, state0, sample_action=False)
+    out_s1, st_s = scaffold.forward(seq)(params, b1, state0)
+    out_p1, st_p = scaffold.forward(pipe)(params, b1, state0)
     np.testing.assert_allclose(
         out_p1.policy_logits, out_s1.policy_logits, rtol=1e-5, atol=1e-5
     )
@@ -236,8 +243,8 @@ def test_pipelined_transformer_matches_sequential_with_cache():
         st_s,
     )
     # Second unroll from the carried (non-zero) cache.
-    out_s2, _ = seq.apply(params, b2, st_s, sample_action=False)
-    out_p2, _ = pipe.apply(params, b2, st_p, sample_action=False)
+    out_s2, _ = scaffold.forward(seq)(params, b2, st_s)
+    out_p2, _ = scaffold.forward(pipe)(params, b2, st_p)
     np.testing.assert_allclose(
         out_p2.policy_logits, out_s2.policy_logits, rtol=1e-5, atol=1e-5
     )
@@ -254,7 +261,8 @@ def test_pipelined_transformer_update_step_matches_sequential():
     seq, pipe, mesh = _tf_models()
     batch = _batch(seed=6)
     state = seq.initial_state(B)
-    params = seq.init(
+    params = scaffold.init(
+        seq,
         {"params": jax.random.PRNGKey(10), "action": jax.random.PRNGKey(11)},
         batch,
         state,
@@ -304,13 +312,14 @@ def test_pipelined_transformer_looped_and_microbatched():
     seq, pipe, _ = _tf_models(n_dev=4, num_layers=8, n_microbatches=8)
     batch = _batch(seed=7)
     state = seq.initial_state(B)
-    params = seq.init(
+    params = scaffold.init(
+        seq,
         {"params": jax.random.PRNGKey(12), "action": jax.random.PRNGKey(13)},
         batch,
         state,
     )
-    out_seq, _ = seq.apply(params, batch, state, sample_action=False)
-    out_pipe, _ = pipe.apply(params, batch, state, sample_action=False)
+    out_seq, _ = scaffold.forward(seq)(params, batch, state)
+    out_pipe, _ = scaffold.forward(pipe)(params, batch, state)
     np.testing.assert_allclose(
         out_pipe.policy_logits, out_seq.policy_logits, rtol=1e-5, atol=1e-5
     )
@@ -330,13 +339,14 @@ def test_pipelined_transformer_acting_fallback():
     }
     state = seq.initial_state(1)
     batch = _batch(seed=9)
-    params = seq.init(
+    params = scaffold.init(
+        seq,
         {"params": jax.random.PRNGKey(14), "action": jax.random.PRNGKey(15)},
         batch,
         seq.initial_state(B),
     )
-    out_s, st_s = seq.apply(params, inputs, state, sample_action=False)
-    out_p, st_p = pipe.apply(params, inputs, state, sample_action=False)
+    out_s, st_s = scaffold.forward(seq)(params, inputs, state)
+    out_p, st_p = scaffold.forward(pipe)(params, inputs, state)
     np.testing.assert_allclose(
         out_p.policy_logits, out_s.policy_logits, rtol=1e-5, atol=1e-5
     )
@@ -365,14 +375,15 @@ def test_pipelined_transformer_remat_matches():
     )
     batch = _batch(seed=11)
     state = plain.initial_state(B)
-    params = plain.init(
+    params = scaffold.init(
+        plain,
         {"params": jax.random.PRNGKey(42), "action": jax.random.PRNGKey(43)},
         batch,
         state,
     )
-    out_plain, _ = plain.apply(params, batch, state, sample_action=False)
-    out_rs, _ = remat_seq.apply(params, batch, state, sample_action=False)
-    out_rp, _ = remat_pipe.apply(params, batch, state, sample_action=False)
+    out_plain, _ = scaffold.forward(plain)(params, batch, state)
+    out_rs, _ = scaffold.forward(remat_seq)(params, batch, state)
+    out_rp, _ = scaffold.forward(remat_pipe)(params, batch, state)
     np.testing.assert_allclose(
         out_rs.policy_logits, out_plain.policy_logits, rtol=1e-5, atol=1e-6
     )

@@ -13,6 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu import precision as precision_lib
 from torchbeast_tpu.models import create_model
@@ -58,7 +59,8 @@ def _build(precision, use_lstm=False, **hp_kw):
         dtype=pol.compute_dtype, head_dtype=pol.head_dtype,
     )
     rng = np.random.default_rng(0)
-    params = model.init(
+    params = scaffold.init(
+        model,
         {
             "params": jax.random.PRNGKey(0),
             "action": jax.random.PRNGKey(1),
@@ -188,11 +190,17 @@ class TestOptimizerState:
         p32, p16 = params, params
         import optax
 
+        def stepped(optimizer):
+            def step(p, s):
+                u, s = optimizer.update(grads, s, p)
+                return optax.apply_updates(p, u), s
+
+            return jax.jit(step)
+
+        step32, step16 = stepped(o32), stepped(o16)
         for _ in range(5):
-            u32, s32 = o32.update(grads, s32, p32)
-            p32 = optax.apply_updates(p32, u32)
-            u16, s16 = o16.update(grads, s16, p16)
-            p16 = optax.apply_updates(p16, u16)
+            p32, s32 = step32(p32, s32)
+            p16, s16 = step16(p16, s16)
         np.testing.assert_allclose(
             p16["w"], p32["w"], rtol=2e-2, atol=1e-4
         )
@@ -238,12 +246,15 @@ class TestOptimizerState:
                 jnp.square(p["b"] + 2.0)
             )
 
+        @jax.jit
+        def step(p, state):
+            u, state = opt.update(jax.grad(loss)(p), state, p)
+            return optax.apply_updates(p, u), state
+
         p = params
         before = float(loss(p))
         for _ in range(20):
-            g = jax.grad(loss)(p)
-            u, state = opt.update(g, state, p)
-            p = optax.apply_updates(p, u)
+            p, state = step(p, state)
         assert float(loss(p)) < before
 
     def test_bf16_resident_master_round_trip(self):
@@ -356,10 +367,12 @@ class TestFusedLoss:
             )
             return pg + 0.5 * base
 
-        v1, g1 = jax.value_and_grad(composed, argnums=(0, 1))(
+        traced = jax.jit(jax.value_and_grad(composed, argnums=(0, 1)))
+        v1, g1 = traced(
             target, values
         )
-        v2, g2 = jax.value_and_grad(fused, argnums=(0, 1))(
+        traced = jax.jit(jax.value_and_grad(fused, argnums=(0, 1)))
+        v2, g2 = traced(
             target, values
         )
         np.testing.assert_allclose(float(v1), float(v2), rtol=1e-6)
@@ -462,7 +475,8 @@ class TestTransformerBF16Head:
         rng = np.random.default_rng(0)
         batch = self._tiny_transformer_batch(rng)
         state = model.initial_state(2)
-        params = model.init(
+        params = scaffold.init(
+            model,
             {
                 "params": jax.random.PRNGKey(0),
                 "action": jax.random.PRNGKey(1),
@@ -470,10 +484,9 @@ class TestTransformerBF16Head:
             batch,
             state,
         )
-        (out, _), _ = model.apply(
-            params, batch, state, sample_action=False,
-            mutable=["losses"],
-        )
+        (out, _), _ = scaffold.apply(
+            model, sample_action=False, mutable=("losses",)
+        )(params, batch, state)
         # The head boundary contract: compute bf16, outputs f32 (the
         # loss side, wire schema, and sampling never see bf16).
         assert out.policy_logits.dtype == jnp.float32

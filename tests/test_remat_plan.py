@@ -16,6 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu.runtime import remat_plan as rp
 
 # ---------------------------------------------------------------------------
@@ -198,7 +199,8 @@ def test_lstm_core_remat_is_numerically_transparent():
             "mlp", num_actions=a, use_lstm=True, core_remat=remat
         )
         state = model.initial_state(b)
-        params = model.init(
+        params = scaffold.init(
+            model,
             {
                 "params": jax.random.PRNGKey(0),
                 "action": jax.random.PRNGKey(1),
@@ -216,7 +218,8 @@ def test_lstm_core_remat_is_numerically_transparent():
                 jnp.sum(out.policy_logits ** 2) + jnp.sum(out.baseline)
             )
 
-        value, grads = jax.value_and_grad(loss)(params)
+        # beastlint: disable=JIT-HAZARD  per-config closure compared once each; one-shot compile by design
+        value, grads = jax.jit(jax.value_and_grad(loss))(params)
         outs[remat] = (value, grads)
     # Same params tree either way (nn.remat must not rescope), same
     # forward, same grads to reassociation tolerance.

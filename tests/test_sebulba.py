@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from tests import jax_caps
+from tests import family_scaffold as scaffold
 from torchbeast_tpu.runtime.placement import (
     DeviceSplit,
     parse_device_split,
@@ -478,7 +479,8 @@ class TestSplitSuperstepAccounting:
         batches = [make_batch(i) for i in range(K)]
         hp = learner_lib.HParams(batch_size=B, unroll_length=T)
         optimizer = learner_lib.make_optimizer(hp)
-        init = model.init(
+        init = scaffold.init(
+            model,
             {"params": jax.random.PRNGKey(0),
              "action": jax.random.PRNGKey(1)},
             batches[0],

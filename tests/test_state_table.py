@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu.runtime.inference import (
     inference_loop,
     pad_advance,
@@ -240,11 +241,13 @@ class TestDeviceStateTable:
                 "last_action": np.zeros((1, rows), np.int32),
             }
 
-        params = model.init(
+        params = scaffold.init(
+            model,
             {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
             env(1), model.initial_state(1),
         )
 
+        # Eager: the table jits `act` itself.
         def act(ctx, env_outputs, agent_state):
             out, new_state = model.apply(
                 params, env_outputs, agent_state, sample_action=False

@@ -8,6 +8,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu.models import TransformerNet, create_model
 
 T, B, A = 6, 2, 4
@@ -32,7 +33,8 @@ def init_model(**kwargs):
     model = TransformerNet(num_actions=A, **kwargs)
     inputs = make_inputs()
     state = model.initial_state(B)
-    params = model.init(
+    params = scaffold.init(
+        model,
         {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
         inputs,
         state,
@@ -44,7 +46,7 @@ def test_shapes_and_state():
     model, params = init_model()
     inputs = make_inputs()
     state = model.initial_state(B)
-    out, new_state = model.apply(params, inputs, state, sample_action=False)
+    out, new_state = scaffold.forward(model)(params, inputs, state)
     assert out.policy_logits.shape == (T, B, A)
     assert out.baseline.shape == (T, B)
     assert len(new_state) == model.num_layers
@@ -60,7 +62,7 @@ def _stepwise_logits(model, params, inputs, state, t_total):
     logits = []
     for t in range(t_total):
         sub = {k: v[t : t + 1] for k, v in inputs.items()}
-        out, state = model.apply(params, sub, state, sample_action=False)
+        out, state = scaffold.forward(model)(params, sub, state)
         logits.append(out.policy_logits[0])
     return np.stack(logits), state
 
@@ -71,7 +73,7 @@ def test_batch_forward_matches_stepwise_with_cache():
     model, params = init_model()
     inputs = make_inputs(seed=3)
     state = model.initial_state(B)
-    full, _ = model.apply(params, inputs, state, sample_action=False)
+    full, _ = scaffold.forward(model)(params, inputs, state)
     logits, _ = _stepwise_logits(model, params, inputs, state, T)
     np.testing.assert_allclose(
         logits, np.asarray(full.policy_logits), rtol=2e-4, atol=2e-5
@@ -88,10 +90,10 @@ def test_batch_matches_stepwise_with_small_memory_and_full_cache():
 
     state0 = model.initial_state(B)
     # Fill the cache with a warmup unroll (both paths identically).
-    _, batch_state = model.apply(params, warmup, state0, sample_action=False)
-    full, _ = model.apply(params, inputs, batch_state, sample_action=False)
+    _, batch_state = scaffold.forward(model)(params, warmup, state0)
+    full, _ = scaffold.forward(model)(params, inputs, batch_state)
 
-    _, step_state = model.apply(params, warmup, state0, sample_action=False)
+    _, step_state = scaffold.forward(model)(params, warmup, state0)
     logits, _ = _stepwise_logits(model, params, inputs, step_state, T)
     np.testing.assert_allclose(
         logits, np.asarray(full.policy_logits), rtol=2e-4, atol=2e-5
@@ -104,11 +106,11 @@ def test_stepwise_state_equals_batch_state():
     model, params = init_model(memory_len=4)
     inputs = make_inputs(seed=13)
     state0 = model.initial_state(B)
-    _, batch_state = model.apply(params, inputs, state0, sample_action=False)
+    _, batch_state = scaffold.forward(model)(params, inputs, state0)
     s = state0
     for t in range(T):
         sub = {k: v[t : t + 1] for k, v in inputs.items()}
-        _, s = model.apply(params, sub, s, sample_action=False)
+        _, s = scaffold.forward(model)(params, sub, s)
     for (bk, bv, bval), (sk, sv, sval) in zip(batch_state, s):
         np.testing.assert_allclose(
             np.asarray(bk), np.asarray(sk), rtol=2e-4, atol=2e-5
@@ -126,14 +128,14 @@ def test_episode_boundary_isolates_past():
     done[d] = True
     inputs = make_inputs(seed=5, done=done)
     state = model.initial_state(B)
-    out1, _ = model.apply(params, inputs, state, sample_action=False)
+    out1, _ = scaffold.forward(model)(params, inputs, state)
 
     # Perturb pre-boundary frames: post-boundary outputs must not move.
     frames2 = np.asarray(inputs["frame"]).copy()
     frames2[0] = 0
     frames2[1] = 255
     inputs2 = {**inputs, "frame": jnp.asarray(frames2)}
-    out2, _ = model.apply(params, inputs2, state, sample_action=False)
+    out2, _ = scaffold.forward(model)(params, inputs2, state)
     np.testing.assert_allclose(
         np.asarray(out1.policy_logits)[d:],
         np.asarray(out2.policy_logits)[d:],
@@ -152,15 +154,15 @@ def test_cache_invalidated_by_done():
     # Unroll 1 fills the cache (distinct content per variant).
     u1a = make_inputs(seed=7)
     u1b = make_inputs(seed=8)
-    _, state_a = model.apply(params, u1a, state, sample_action=False)
-    _, state_b = model.apply(params, u1b, state, sample_action=False)
+    _, state_a = scaffold.forward(model)(params, u1a, state)
+    _, state_b = scaffold.forward(model)(params, u1b, state)
 
     # Unroll 2 starts with done at slot 0: the old cache is invisible.
     done = np.zeros((T, B), bool)
     done[0] = True
     u2 = make_inputs(seed=9, done=done)
-    out_a, _ = model.apply(params, u2, state_a, sample_action=False)
-    out_b, _ = model.apply(params, u2, state_b, sample_action=False)
+    out_a, _ = scaffold.forward(model)(params, u2, state_a)
+    out_b, _ = scaffold.forward(model)(params, u2, state_b)
     np.testing.assert_allclose(
         np.asarray(out_a.policy_logits),
         np.asarray(out_b.policy_logits),
@@ -208,14 +210,11 @@ def test_ring_path_matches_dense_forward_and_state():
     inputs = make_inputs(seed=22, t=t, done=done)
 
     state0 = model.initial_state(B)
-    _, cache = model.apply(params, warm, state0, sample_action=False)
-    dense_out, dense_state = model.apply(
-        params, inputs, cache, sample_action=False
-    )
+    _, cache = scaffold.forward(model)(params, warm, state0)
+    dense_out, dense_state = scaffold.forward(model)(params, inputs, cache)
 
     ring = _ring_model(model)
-    ring_out, ring_state = ring.apply(params, inputs, cache,
-                                      sample_action=False)
+    ring_out, ring_state = scaffold.forward(ring)(params, inputs, cache)
 
     np.testing.assert_allclose(
         np.asarray(ring_out.policy_logits),
@@ -251,8 +250,10 @@ def test_ring_path_gradients_match_dense():
             )
         return f
 
-    g_dense = jax.grad(loss(model))(params)
-    g_ring = jax.grad(loss(ring))(params)
+    g_dense_fn = jax.jit(jax.grad(loss(model)))
+    g_dense = g_dense_fn(params)
+    g_ring_fn = jax.jit(jax.grad(loss(ring)))
+    g_ring = g_ring_fn(params)
     flat_d, _ = jax.tree_util.tree_flatten(g_dense)
     flat_r, _ = jax.tree_util.tree_flatten(g_ring)
     for gd, gr in zip(flat_d, flat_r):
@@ -268,8 +269,8 @@ def test_ring_path_falls_back_to_dense_for_short_t():
     ring = _ring_model(model)
     inputs = make_inputs(seed=41, t=1)
     state = model.initial_state(B)
-    out_d, _ = model.apply(params, inputs, state, sample_action=False)
-    out_r, _ = ring.apply(params, inputs, state, sample_action=False)
+    out_d, _ = scaffold.forward(model)(params, inputs, state)
+    out_r, _ = scaffold.forward(ring)(params, inputs, state)
     np.testing.assert_allclose(
         np.asarray(out_r.policy_logits), np.asarray(out_d.policy_logits),
         rtol=1e-6,
@@ -290,10 +291,8 @@ def test_zigzag_ring_path_matches_dense():
     inputs = make_inputs(seed=52, t=t, done=done)
 
     state0 = model.initial_state(B)
-    _, cache = model.apply(params, warm, state0, sample_action=False)
-    dense_out, dense_state = model.apply(
-        params, inputs, cache, sample_action=False
-    )
+    _, cache = scaffold.forward(model)(params, warm, state0)
+    dense_out, dense_state = scaffold.forward(model)(params, inputs, cache)
 
     zig = TransformerNet(
         num_actions=model.num_actions,
@@ -304,8 +303,7 @@ def test_zigzag_ring_path_matches_dense():
         mesh=_seq_mesh(8),
         ring_schedule="zigzag",
     )
-    zig_out, zig_state = zig.apply(params, inputs, cache,
-                                   sample_action=False)
+    zig_out, zig_state = scaffold.forward(zig)(params, inputs, cache)
     np.testing.assert_allclose(
         np.asarray(zig_out.policy_logits),
         np.asarray(dense_out.policy_logits),
@@ -343,8 +341,10 @@ def test_zigzag_ring_path_gradients_match_dense():
             )
         return f
 
-    g_dense = jax.grad(loss(model))(params)
-    g_zig = jax.grad(loss(zig))(params)
+    g_dense_fn = jax.jit(jax.grad(loss(model)))
+    g_dense = g_dense_fn(params)
+    g_zig_fn = jax.jit(jax.grad(loss(zig)))
+    g_zig = g_zig_fn(params)
     flat_d, _ = jax.tree_util.tree_flatten(g_dense)
     flat_z, _ = jax.tree_util.tree_flatten(g_zig)
     for gd, gz in zip(flat_d, flat_z):
@@ -387,7 +387,8 @@ def test_remat_update_matches_non_remat():
     plain = create_model("transformer", **kwargs)
     remat = create_model("transformer", remat=True, **kwargs)
     state = plain.initial_state(B)
-    params = plain.init(
+    params = scaffold.init(
+        plain,
         {"params": jax.random.PRNGKey(40), "action": jax.random.PRNGKey(41)},
         batch,
         state,
@@ -398,7 +399,8 @@ def test_remat_update_matches_non_remat():
             np.asarray(a), np.asarray(b)
         ),
         params,
-        remat.init(
+        scaffold.init(
+            remat,
             {"params": jax.random.PRNGKey(40),
              "action": jax.random.PRNGKey(41)},
             batch,

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
+from tests import family_scaffold as scaffold
 from torchbeast_tpu.models import create_model
 from torchbeast_tpu.ops.attention import (
     causal_attention,
@@ -16,6 +17,10 @@ from torchbeast_tpu.ops.attention import (
 )
 
 B, T, H, D = 2, 16, 8, 4
+
+# The functions under test through `jax.jit`, traced once a shape.
+_causal = jax.jit(causal_attention)
+_ulysses = jax.jit(ulysses_attention, static_argnums=3)
 
 
 def _mesh(n):
@@ -32,8 +37,8 @@ def _qkv(key):
 @pytest.mark.parametrize("n_dev", [4, 8])
 def test_ulysses_matches_dense(n_dev):
     q, k, v = _qkv(jax.random.PRNGKey(0))
-    dense = causal_attention(q, k, v)
-    out = ulysses_attention(q, k, v, _mesh(n_dev))
+    dense = _causal(q, k, v)
+    out = _ulysses(q, k, v, _mesh(n_dev))
     np.testing.assert_allclose(out, dense, rtol=1e-5, atol=1e-5)
 
 
@@ -41,8 +46,8 @@ def test_ulysses_with_segments_matches_dense():
     q, k, v = _qkv(jax.random.PRNGKey(1))
     done = jax.random.bernoulli(jax.random.PRNGKey(2), 0.2, (T, B))
     seg = segment_ids_from_done(done).T  # [B, T]
-    dense = causal_attention(q, k, v, seg)
-    out = ulysses_attention(q, k, v, _mesh(4), segment_ids=seg)
+    dense = _causal(q, k, v, seg)
+    out = _ulysses(q, k, v, _mesh(4), segment_ids=seg)
     np.testing.assert_allclose(out, dense, rtol=1e-5, atol=1e-5)
 
 
@@ -52,13 +57,15 @@ def test_ulysses_gradients_match_dense():
     mesh = _mesh(4)
 
     def loss_dense(q, k, v):
-        return jnp.sum(causal_attention(q, k, v) ** 2)
+        return jnp.sum(_causal(q, k, v) ** 2)
 
     def loss_uly(q, k, v):
-        return jnp.sum(ulysses_attention(q, k, v, mesh) ** 2)
+        return jnp.sum(_ulysses(q, k, v, mesh) ** 2)
 
-    g_dense = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    g_uly = jax.grad(loss_uly, argnums=(0, 1, 2))(q, k, v)
+    g_dense_fn = jax.jit(jax.grad(loss_dense, argnums=(0, 1, 2)))
+    g_dense = g_dense_fn(q, k, v)
+    g_uly_fn = jax.jit(jax.grad(loss_uly, argnums=(0, 1, 2)))
+    g_uly = g_uly_fn(q, k, v)
     for a, b in zip(g_uly, g_dense):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
@@ -67,7 +74,7 @@ def test_ulysses_rejects_bad_shapes():
     q, k, v = _qkv(jax.random.PRNGKey(4))
     with pytest.raises(ValueError, match=r"H \(6\) divisible"):
         # T=16 divides over 4 devices but H=6 does not.
-        ulysses_attention(
+        _ulysses(
             q[:, :, :6], k[:, :, :6], v[:, :, :6], _mesh(4)
         )
 
@@ -111,15 +118,16 @@ def test_ulysses_transformer_matches_dense():
     batch = _transformer_batch(T_, A)
     state = dense.initial_state(B)
     # Non-trivial cache: run one unroll with the dense model first.
-    params = dense.init(
+    params = scaffold.init(
+        dense,
         {"params": jax.random.PRNGKey(6), "action": jax.random.PRNGKey(7)},
         batch,
         state,
     )
-    _, state = dense.apply(params, batch, state, sample_action=False)
+    _, state = scaffold.forward(dense)(params, batch, state)
 
-    out_d, st_d = dense.apply(params, batch, state, sample_action=False)
-    out_u, st_u = uly.apply(params, batch, state, sample_action=False)
+    out_d, st_d = scaffold.forward(dense)(params, batch, state)
+    out_u, st_u = scaffold.forward(uly)(params, batch, state)
     np.testing.assert_allclose(
         out_u.policy_logits, out_d.policy_logits, rtol=1e-5, atol=1e-5
     )
@@ -149,12 +157,13 @@ def test_ulysses_transformer_acting_falls_back_to_dense():
     )
     batch = _transformer_batch(0, A)
     state = uly.initial_state(B)
-    params = uly.init(
+    params = scaffold.init(
+        uly,
         {"params": jax.random.PRNGKey(8), "action": jax.random.PRNGKey(9)},
         batch,
         state,
     )
-    out, _ = uly.apply(
+    out, _ = scaffold.apply(uly)(
         params,
         {k: batch[k][:1] for k in
          ("frame", "reward", "done", "last_action")},

@@ -34,6 +34,7 @@ from torchbeast_tpu.learner_setup import (
     add_learner_arguments,
     hparams_from_flags,
     init_model_and_params,
+    initial_params,
 )
 from torchbeast_tpu.models import MODEL_NAMES
 from torchbeast_tpu.utils import (
@@ -209,13 +210,14 @@ def initial_carry(env, model, batch_size: int, rng):
         k: env_out[k]
         for k in ("frame", "reward", "done", "last_action")
     }
-    params = model.init(
+    params = initial_params(
+        model,
         {"params": init_key, "action": action_key},
         {k: v[None] for k, v in model_inputs.items()},
         agent_state,
     )
-    out, _ = learner_lib.act_body(
-        model, params, prime_key, model_inputs, agent_state
+    out, _ = learner_lib.make_act_step(model)(
+        params, prime_key, model_inputs, agent_state
     )
     agent_out = _agent_out_dict(out)
     carry = ActorCarry(

@@ -454,6 +454,14 @@ def dummy_env_outputs(t, batch_size, frame_shape, frame_dtype):
     }
 
 
+def initial_params(model, rngs, env_outputs, agent_state):
+    """`model.init` as ONE traced program: run op by op, a flax module
+    is an XLA compile an op (some hundreds for a transformer family).
+    Every driver's first parameters come from here."""
+    init = jax.jit(model.init)
+    return init(rngs, env_outputs, agent_state)
+
+
 def _make_1d_mesh(n: int, axis: str, flag_name: str):
     """A 1-D device mesh over the first n devices, with the consistent
     too-few-devices error every parallelism flag shares."""
@@ -809,15 +817,14 @@ def init_model_and_params(flags, num_actions, batch_size, frame_shape,
             f"({model.num_heads}) divisible by --sequence_parallel "
             f"{seq_par} (heads are the sharded resource)"
         )
-    dummy = dummy_env_outputs(1, batch_size, frame_shape, frame_dtype)
-    state = model.initial_state(batch_size)
-    params = model.init(
+    params = initial_params(
+        model,
         {
             "params": jax.random.PRNGKey(flags.seed),
             "action": jax.random.PRNGKey(flags.seed + 1),
         },
-        dummy,
-        state,
+        dummy_env_outputs(1, batch_size, frame_shape, frame_dtype),
+        model.initial_state(batch_size),
     )
     # bf16_train: params are bf16-RESIDENT from here on — every
     # consumer (acting, learner, checkpoint templates) sees bf16; the
