@@ -21,6 +21,7 @@ the backend name for the duration of the trace.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -208,6 +209,60 @@ def test_mellum2_share_of_the_experts_compiles_for_v5e(one_chip, monkeypatch):
     assert compiled.as_text().count("tpu_custom_call") >= 8
 
 
+# A rung of the window's rows, its width, the experts held and theirs:
+# what `moe.window_rungs` hands the kernels in the three cells that
+# trace under `high`.
+CUT_IN_VMEM_CELLS = {
+    "qwen3next": (5120, 2048, 32, 512, True),
+    "kanana2": (4096, 2048, 16, 768, True),
+    "nemotron3": (2816, 1024, 8, 2688, False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CUT_IN_VMEM_CELLS))
+@pytest.mark.parametrize("precision", ["high", "highest", "default"])
+def test_experts_cut_in_vmem_compile_for_v5e(
+    one_chip, monkeypatch, cell, precision
+):
+    """A rung's grouped matmuls as the three `high` cells call them
+    (ops/grouped_matmul.py: `gmm`, `gmm` on transposed weights, `tgmm`,
+    each at the up and at the down projection's shape), forward and
+    backward, under the 16 MiB of VMEM a kernel is given unasked: one
+    kernel call a product at two terms a side and at three; at one
+    term the shipped kernels and none of ours."""
+    from torchbeast_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rung, d, held, width, gated = CUT_IN_VMEM_CELLS[cell]
+
+    def loss(x, w_gate, w_up, w_down, sizes):
+        with jax.default_matmul_precision(precision):
+            hidden = moe._experts_on_rows(
+                x, w_gate if gated else None, w_up, w_down, sizes, 0,
+                "silu", moe._terms_traced_under(),
+            )
+        return jnp.sum(hidden)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        _struct(one_chip, (rung, d)),
+        _struct(one_chip, (held, d, width)),
+        _struct(one_chip, (held, d, width)),
+        _struct(one_chip, (held, width, d)),
+        _struct(one_chip, (held + 1,), jnp.int32),
+    ).compile().as_text()
+    # Forward, and a product's two gradients (the sum needs no forward
+    # of the last).
+    calls_owed = 3 * (3 if gated else 2) - 1
+    ours = len(re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+                          r"gmm_cut_in_vmem", text))
+    calls = text.count("custom_call_target=\"tpu_custom_call\"")
+    if precision == "default":
+        assert ours == 0 and calls == calls_owed
+    else:
+        assert ours == calls == calls_owed
+    assert "vmem_limit_bytes" not in text
+
+
 @pytest.mark.parametrize("keys", [4176, 1104], ids=["full", "sliding"])
 def test_mellum2_fused_attention_compiles_for_v5e(one_chip, monkeypatch, keys):
     """The Mellum2 cell's attention below `dense_transformer_attend`
@@ -346,8 +401,11 @@ def test_nemotron3_dispatch_moves_the_windows_rows_alone_on_v5e(
     assert "/transpose(jvp())/while/body/jvp(moe_experts)" in text
     assert compiled.memory_analysis().temp_size_in_bytes <= 507_526_144
     # Two forward kernels in the forward loop; those and four backward
-    # in the backward loop; three passes each, and no second copy.
-    assert text.count("tpu_custom_call") == 24
+    # in the backward loop; ONE call a product since PR 50 (the kernels
+    # cut their operands in VMEM; three passes each, 24, before), and
+    # no second copy.
+    assert text.count("tpu_custom_call") == 8
+    assert text.count("gmm_cut_in_vmem") >= 8
 
 
 def test_flagship_act_step_compiles_for_v5e(one_chip, monkeypatch):

@@ -99,10 +99,16 @@ def test_kanana2_cell_update_compiles_for_v5e(one_chip, monkeypatch):
         if 4095 in s and s[-1] in (128, 192, 256, 320) and len(s) >= 4
     }
     assert not decompressed, decompressed
-    # The grouped expert matmuls at the family's three passes: four MoE
-    # layers x (3 forward, 3 rematerialised, 6 backward) x 3; and the
-    # cache leg's kernels: five layers x (forward, rematerialised,
-    # backward).
-    assert compiled.as_text().count("tpu_custom_call") == 144 + 15
+    # The grouped expert matmuls, ONE kernel call a product at the
+    # family's two terms a side (PR 50: ops/grouped_matmul.py cuts the
+    # float32 tiles in VMEM; three calls a product, 144, before): four
+    # MoE layers x (3 forward, 3 the backward loop's second forward, 6
+    # backward); and the cache leg's kernels: five layers x (forward,
+    # rematerialised, backward).
+    assert compiled.as_text().count("tpu_custom_call") == 48 + 15
+    assert len(re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem',
+        compiled.as_text(),
+    )) == 48
     assert compiled.as_text().count("fused_latent_leg_forward") >= 10
     assert compiled.as_text().count("fused_latent_leg_backward") >= 5

@@ -103,3 +103,13 @@ def test_nemotron3_cell_update_compiles_for_v5e(one_chip, monkeypatch):
         s for s in shapes if s[0] in (22 * tokens, window) and s[-1] == 2688
     }
     assert {s for s in shapes if s == (rung, 2688)}
+    # The experts' products are ONE kernel call each at the family's
+    # two terms a side (PR 50: ops/grouped_matmul.py; 150 calls of the
+    # shipped kernels before): five MoE layers x (2 forward, 2 the
+    # backward loop's second forward, 4 backward, and the two forward
+    # again in the rematerialised block's); beside them the attention
+    # layer's three.
+    assert len(re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
+    )) == 50
+    assert text.count("tpu_custom_call") == 50 + 3

@@ -133,6 +133,15 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     assert all(
         "operand_precision={highest,highest}" in line for line in solves
     )
+    # The experts' products are ONE kernel call each at the family's
+    # two terms a side (PR 50: ops/grouped_matmul.py; 144 calls of the
+    # shipped kernels before): four MoE parts x (3 forward, 3 the
+    # backward loop's second forward, 6 backward); beside them the
+    # attention layer's three.
+    assert len(re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
+    )) == 48
+    assert text.count("tpu_custom_call") == 48 + 3
     # A forward and a backward loop a MoE part, their turns counted on
     # the device from the step's own group sizes.
     sweeps = re.findall(r"while\([^\n]*op_name=\"[^\"]*/moe/while\"", text)

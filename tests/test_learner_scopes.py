@@ -121,3 +121,49 @@ def test_the_solves_own_backward_is_under_its_calls_scopes(qwen3next_op_names):
     ]
     assert backward
     assert all(_in_scope(n, "delta_intra") for n in backward)
+
+
+def _kernel_calls(jaxpr, under=""):
+    """(name stack, kernel name) of every Pallas kernel call in a
+    jaxpr, calls inside calls too: what the compiled module's
+    `op_name` is made of."""
+    for eqn in jaxpr.eqns:
+        stack = f"{under}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "pallas_call":
+            yield stack, stack.rsplit("/", 1)[-1]
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _kernel_calls(inner, stack)
+
+
+def test_the_experts_own_kernels_are_under_their_scope(monkeypatch):
+    """The grouped matmuls that cut their operands in VMEM (PR 50) are
+    kernels of this repo's, called from a `custom_vjp`: the forward's
+    three a SwiGLU and the backward rule's six carry `moe_experts`, so
+    a trace split by scope charges all nine to it."""
+    import jax.numpy as jnp
+
+    from torchbeast_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, d, width, held = 256, 128, 128, 2
+
+    def loss(x, w_gate, w_up, w_down, sizes):
+        with jax.default_matmul_precision("high"):
+            y = moe._experts_on_rows(
+                x, w_gate, w_up, w_down, sizes, 0, "silu",
+                moe._terms_traced_under(),
+            )
+        return jnp.sum(jnp.sin(y))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3)))(
+        jnp.ones((rows, d)), jnp.ones((held, d, width)),
+        jnp.ones((held, d, width)), jnp.ones((held, width, d)),
+        jnp.array([100, 100, 56], jnp.int32),
+    )
+    calls = list(_kernel_calls(jaxpr.jaxpr))
+    assert sorted(name for _, name in calls) == (
+        ["gmm_cut_in_vmem"] * 6 + ["tgmm_cut_in_vmem"] * 3
+    )
+    assert all(_in_scope(stack, "moe_experts") for stack, _ in calls)
+    backward = [stack for stack, _ in calls if "transpose(" in stack]
+    assert len(backward) == 6

@@ -53,6 +53,7 @@ from jax.experimental.pallas.ops.tpu.megablox.ops import backend as _megablox
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from torchbeast_tpu.models.stats import sow_stat
+from torchbeast_tpu.ops import grouped_matmul as _cut_in_vmem
 
 
 def _constrain(x, mesh, spec):
@@ -349,17 +350,37 @@ def _bf16_terms(x, terms):
     return out
 
 
-def _gmm_call(kernel, lhs, rhs, sizes, rows, terms, **kwargs):
-    """One product by a megablox kernel. On the chip its operands are
-    bfloat16 and its sums and result float32. With one term a side,
-    that is what XLA makes of a float32 matmul at JAX's default
-    precision; with two (a caller that traces under `high`, models/
-    kanana2.py) the three passes XLA makes there: head x head, head x
-    tail, tail x head. So the experts are computed as every other
-    matmul of the model is. Elsewhere the kernel is interpreted, in
-    float32."""
+def _cut_in_kernel(terms):
+    """Whether a product at `terms` terms a side is one call of the
+    kernel that cuts its operands itself: on the chip, at more than
+    one."""
+    return jax.default_backend() == "tpu" and terms > 1
+
+
+def _gmm_call(name, lhs, rhs, sizes, rows, terms, **kwargs):
+    """One product by a grouped kernel, "gmm" or "tgmm" (lhs [m, k]
+    for both). On the chip its operands are bfloat16 and its sums and
+    result float32. With one term a side, that is what XLA makes of a
+    float32 matmul at JAX's default precision: the shipped megablox
+    kernel on one cast of each operand (its groups of thousands of rows
+    visit a weight tile many times, so one bfloat16 copy made once is
+    the cheaper read). With two (a caller that traces under `high`,
+    models/kanana2.py) the three passes XLA makes there, head x head,
+    head x tail, tail x head, and with three `highest`'s six: ONE call
+    of ops/grouped_matmul.py's kernel, which reads the float32 operands
+    and cuts each tile in VMEM. So the experts are computed as every
+    other matmul of the model is. Elsewhere the shipped kernel is
+    interpreted, in float32."""
+    if _cut_in_kernel(terms):
+        return getattr(_cut_in_vmem, name)(
+            lhs.astype(jnp.float32), rhs.astype(jnp.float32), sizes,
+            terms=terms, tm=rows, **kwargs,
+        )
     on_chip = jax.default_backend() == "tpu"
+    kernel = getattr(_megablox, name)
     _, tk, tn = _GMM_TILING
+    if name == "tgmm":
+        lhs = lhs.swapaxes(0, 1)  # the shipped one's rows are columns
 
     def call(lhs, rhs):
         # The kernel's own dot is the plain one whatever the caller
@@ -373,21 +394,14 @@ def _gmm_call(kernel, lhs, rhs, sizes, rows, terms, **kwargs):
 
     if not on_chip:
         return call(lhs, rhs)
-    lhs, rhs = _bf16_terms(lhs, terms), _bf16_terms(rhs, terms)
-    # The smallest products first, so that they are not lost one by
-    # one beside the largest.
-    out = None
-    for order in reversed(range(terms)):
-        for i in range(order + 1):
-            part = call(lhs[i], rhs[order - i])
-            out = part if out is None else out + part
-    return out
+    return call(*_bf16_terms(lhs, 1), *_bf16_terms(rhs, 1))
 
 
 def grouped_matmul(lhs, rhs, sizes, first=None):
     """lhs [m, k] in contiguous groups of `sizes` [E] rows, rhs
     [E, k, n] -> [m, n]: rows of group e times rhs[e]. The kernels are
-    JAX's shipped megablox `gmm` / `tgmm`; this wrapper fixes their
+    JAX's shipped megablox `gmm` / `tgmm` or, at more than one term,
+    their forks in ops/grouped_matmul.py; this wrapper fixes their
     operand and result types (above), forward and backward by the
     `jax.default_matmul_precision` this call is traced under, and pads
     the rows to the kernel's tile, the padding going to the last group
@@ -404,9 +418,22 @@ def _grouped_matmul(lhs, rhs, sizes, first, terms):
     return _grouped_matmul_fwd(lhs, rhs, sizes, first, terms)[0]
 
 
-def _pad_rows(sizes, *matrices):
+# Rows a held group (all the rows over the groups held) under which the
+# kernels that cut in VMEM tile the rows by 128: such a kernel visits a
+# row tile once for every group with rows in it, and at 160 / 256 / 352
+# rows a group, half of them live (a rung in the Qwen3-Next / Kanana-2 /
+# Nemotron-3 cells), a product took 0.39-0.42 / 0.37-0.40 / 0.36-0.40 ms
+# at 128 against 0.46-0.52 / 0.38-0.44 / 0.37-0.44 at 256 (PERF.md, PR
+# 50). Longer groups are not measured and keep the shipped kernels' tile.
+_SHORT_ROWS_UNDER = 512
+
+
+def _pad_rows(held, terms, sizes, *matrices):
     m = matrices[0].shape[0]
-    tile = min(_GMM_TILING[0], -(-m // 128) * 128)
+    tile = _GMM_TILING[0]
+    if _cut_in_kernel(terms) and m < _SHORT_ROWS_UNDER * held:
+        tile //= 2
+    tile = min(tile, -(-m // 128) * 128)
     pad = -m % tile
     if pad:
         sizes = sizes.at[-1].add(pad)
@@ -421,9 +448,11 @@ def _from_group(first):
 
 
 def _grouped_matmul_fwd(lhs, rhs, sizes, first, terms):
-    tile, padded_sizes, (padded,) = _pad_rows(sizes, lhs)
+    tile, padded_sizes, (padded,) = _pad_rows(
+        rhs.shape[0], terms, sizes, lhs
+    )
     out = _gmm_call(
-        _megablox.gmm, padded, rhs, padded_sizes, tile, terms,
+        "gmm", padded, rhs, padded_sizes, tile, terms,
         **_from_group(first),
     )
     return out[: lhs.shape[0]], (lhs, rhs, sizes)
@@ -431,14 +460,16 @@ def _grouped_matmul_fwd(lhs, rhs, sizes, first, terms):
 
 def _grouped_matmul_bwd(first, terms, residuals, grad):
     lhs, rhs, sizes = residuals
-    tile, padded_sizes, (lhs_p, grad_p) = _pad_rows(sizes, lhs, grad)
+    tile, padded_sizes, (lhs_p, grad_p) = _pad_rows(
+        rhs.shape[0], terms, sizes, lhs, grad
+    )
     grad_lhs = _gmm_call(
-        _megablox.gmm, grad_p, rhs, padded_sizes, tile, terms,
+        "gmm", grad_p, rhs, padded_sizes, tile, terms,
         transpose_rhs=True, **_from_group(first),
     )[: lhs.shape[0]]
     grad_rhs = _gmm_call(
-        _megablox.tgmm, lhs_p.swapaxes(0, 1), grad_p, padded_sizes, tile,
-        terms, num_actual_groups=rhs.shape[0], **_from_group(first),
+        "tgmm", lhs_p, grad_p, padded_sizes, tile, terms,
+        num_actual_groups=rhs.shape[0], **_from_group(first),
     )
     return grad_lhs.astype(lhs.dtype), grad_rhs.astype(rhs.dtype), None
 
@@ -859,6 +890,15 @@ class DroplessMoE(nn.Module):
                         (swept <= 1.0) * jnp.float32(rungs[0] < rungs[1]),
                         "sum",
                     )
+            if _cut_in_kernel(_terms_traced_under()):
+                # The grouped products of this layer's forward pass
+                # whose operands are cut in the kernel. Not sown where
+                # none is: a sown zero would be one more output of an
+                # update that this leaves as it was.
+                sow_stat(
+                    self, "moe_products_cut_in_kernel",
+                    3.0 if self.gated else 2.0, "sum",
+                )
             if self.selection_bias:
                 # Under the parameter's own name: the learner adds a
                 # sown step to the leaf of `params` at the same path.
