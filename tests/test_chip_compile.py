@@ -397,8 +397,12 @@ def test_nemotron3_dispatch_moves_the_windows_rows_alone_on_v5e(
     assert (rung, window) == (2816, tokens * held)
     assert f"f32[{rung},{width}]" in text and f"f32[{rung},{latent}]" in text
     assert f"[{window},{width}]" not in text
-    assert "/jvp()/while/body/moe_experts" in text  # the forward loop
-    assert "/transpose(jvp())/while/body/jvp(moe_experts)" in text
+    # The forward loop and the backward's, under the sweep's own name
+    # (PR 51: `moe_sweep`; a rung's parts enter their scopes inside).
+    assert "/jvp(moe_sweep)/while/body/moe_experts" in text
+    assert (
+        "/transpose(jvp(moe_sweep))/while/body/jvp(moe_experts)" in text
+    )
     assert compiled.memory_analysis().temp_size_in_bytes <= 507_526_144
     # Two forward kernels in the forward loop; those and four backward
     # in the backward loop; ONE call a product since PR 50 (the kernels

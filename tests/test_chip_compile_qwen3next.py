@@ -144,5 +144,7 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     assert text.count("tpu_custom_call") == 48 + 3
     # A forward and a backward loop a MoE part, their turns counted on
     # the device from the step's own group sizes.
-    sweeps = re.findall(r"while\([^\n]*op_name=\"[^\"]*/moe/while\"", text)
+    sweeps = re.findall(
+        r"while\([^\n]*op_name=\"[^\"]*/moe/moe_sweep/while\"", text
+    )
     assert len(sweeps) >= 8, len(sweeps)

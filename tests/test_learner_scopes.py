@@ -1,6 +1,7 @@
 """The update step's parts that no Flax module names carry
-`jax.named_scope`s, so a device trace can be split by them: the names
-reach the compiled HLO's `op_name` metadata (ISSUE 25)."""
+`telemetry.device_scope`s, so a device trace can be split by them: the
+names reach the compiled HLO's `op_name` metadata (ISSUE 25) and the
+by-scope account's set of known scopes (ISSUE 51)."""
 
 import re
 
@@ -11,15 +12,22 @@ import pytest
 from tests import family_scaffold as scaffold
 from tests.test_learner import make_batch
 from torchbeast_tpu import learner as learner_lib
+from torchbeast_tpu import telemetry
 from torchbeast_tpu.models import create_model
+from torchbeast_tpu.telemetry import device_scopes
 
 SCOPES = ("vtrace", "loss_terms", "optimizer", "grad_norm")
 
 
 def _in_scope(op_name, scope):
     """Under jax.grad a scope shows as `jvp(<scope>)` and
-    `transpose(jvp(<scope>))`; outside it, bare."""
-    return re.search(rf"[/(]{scope}[/)]", op_name + "/") is not None
+    `transpose(jvp(<scope>))`; outside it, bare. Read as the by-scope
+    account reads a path, the candidates the names `device_scope`
+    noted while the program was traced: an expectation that holds
+    says the helper knows the name too."""
+    return scope in device_scopes.scopes_of(
+        op_name, telemetry.known_device_scopes()
+    )
 
 
 def _op_names(optimizer):
@@ -70,6 +78,42 @@ def test_the_drivers_optimizer_keeps_three_scopes():
     names = _op_names(learner_lib.make_optimizer(learner_lib.HParams()))
     for scope in ("vtrace", "loss_terms", "optimizer"):
         assert any(_in_scope(n, scope) for n in names), scope
+
+
+# --- the conv family's scopes (PR 51: `--model deep --use_lstm`) -----------------
+
+DEEP_SCOPES = (
+    "trunk_input", "trunk_stage_0", "trunk_stage_1", "trunk_stage_2",
+    "trunk_fc", "lstm_core", "policy_head",
+)
+
+
+@pytest.fixture(scope="module")
+def deep_op_names():
+    """The flagship net's update at a toy size: without these names
+    all of a conv cell's step but the loss and the optimizer would
+    read `unscoped`."""
+    model = create_model("deep", num_actions=3, use_lstm=True)
+    batch = make_batch(t=3, b=2)
+    state = model.initial_state(2)
+    params = scaffold.init(
+        model,
+        {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
+        batch, state,
+    )
+    optimizer = optax.sgd(0.1)
+    compiled = learner_lib.make_update_step(
+        model, optimizer, learner_lib.HParams(), donate=False
+    ).lower(params, optimizer.init(params), batch, state).compile()
+    return re.findall(r'op_name="([^"]+)"', compiled.as_text())
+
+
+@pytest.mark.parametrize("scope", DEEP_SCOPES)
+def test_conv_scope_reaches_the_compiled_hlo(deep_op_names, scope):
+    inside = [n for n in deep_op_names if _in_scope(n, scope)]
+    assert inside, f"no compiled op carries the scope {scope!r}"
+    if scope == "lstm_core":
+        assert any("/while/body/" in n for n in inside)
 
 
 # --- a family's own scopes (PR 46: `--model qwen3next`) -----------------------
@@ -167,3 +211,49 @@ def test_the_experts_own_kernels_are_under_their_scope(monkeypatch):
     assert all(_in_scope(stack, "moe_experts") for stack, _ in calls)
     backward = [stack for stack, _ in calls if "transpose(" in stack]
     assert len(backward) == 6
+
+
+def _loops(jaxpr, under=""):
+    """The name stack of every `while` in a jaxpr, calls inside calls
+    too."""
+    for eqn in jaxpr.eqns:
+        stack = f"{under}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "while":
+            yield stack
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _loops(inner, stack)
+
+
+def test_the_sweeps_loop_has_a_name_of_its_own():
+    """The swept experts' loop holds a rung's dispatch, experts and
+    combine, each under its scope; the loop's own work (the backward's
+    carried sums of the weights' gradients, 7.3 ms a step in the
+    Qwen3-Next cell) is `moe_sweep`'s, forward and backward."""
+    import jax.numpy as jnp
+
+    from torchbeast_tpu.models import moe
+
+    tokens, top_k, held, experts, d, width = 512, 2, 2, 16, 8, 16
+    assert moe.window_rungs(tokens, top_k, held, experts) == (256, 1024)
+    idx = jnp.stack(
+        [jnp.arange(tokens) % experts, (jnp.arange(tokens) + 1) % experts],
+        axis=1,
+    )
+
+    def loss(x, w_gate, w_up, w_down):
+        y, _ = moe.dropless_experts(
+            x, idx, jnp.ones((tokens, top_k)), w_gate, w_up, w_down,
+            first_of=(0, experts),
+        )
+        return jnp.sum(y)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3)))(
+        jnp.ones((tokens, d)), jnp.ones((held, d, width)),
+        jnp.ones((held, d, width)), jnp.ones((held, width, d)),
+    )
+    sweeps = [
+        stack for stack in _loops(jaxpr.jaxpr)
+        if "searchsorted" not in stack
+    ]
+    assert len(sweeps) == 2  # the forward's and the backward's
+    assert all(_in_scope(stack, "moe_sweep") for stack in sweeps)

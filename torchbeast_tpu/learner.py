@@ -559,7 +559,7 @@ def compute_loss(
     # compiled HLO's op_name metadata, so a device trace can be split
     # by them (vtrace, loss_terms; optimizer and grad_norm in
     # update_body).
-    with jax.named_scope("vtrace"):
+    with telemetry.device_scope("vtrace"):
         if hp.loss == "impact":
             if target_net_logits_full is None:
                 raise ValueError(
@@ -590,7 +590,7 @@ def compute_loss(
                 bootstrap_value=bootstrap_value,
                 scan_impl=hp.vtrace_impl,
             )
-    with jax.named_scope("loss_terms"):
+    with telemetry.device_scope("loss_terms"):
         baseline_loss = hp.baseline_cost * baseline_loss
         # entropy_cost may be a traced scalar (the annealed schedule
         # from make_update_step); None = the constant from hp.
@@ -738,7 +738,7 @@ def update_body(model, optimizer: optax.GradientTransformation, hp: HParams):
         )
         grads, stats = grad_fn(params)
         param_steps = stats.pop(PARAM_STEPS_KEY, None)
-        with jax.named_scope("optimizer"):
+        with telemetry.device_scope("optimizer"):
             updates, new_opt_state = optimizer.update(
                 grads, opt_state, params
             )
@@ -748,14 +748,14 @@ def update_body(model, optimizer: optax.GradientTransformation, hp: HParams):
             # optimizer takes the stock optax apply.
             params = apply_updates(params, updates, new_opt_state)
         if param_steps is not None:
-            with jax.named_scope("load_moved"):
+            with telemetry.device_scope("load_moved"):
                 params, moved = add_param_steps(
                     params, param_steps, new_opt_state
                 )
             stats["moe_bias_steps"] = jnp.float32(moved)
         # f32 upcast before the norm reduction (no-op for f32 grads;
         # bf16-resident runs emit bf16 grad arrays).
-        with jax.named_scope("grad_norm"):
+        with telemetry.device_scope("grad_norm"):
             stats["grad_norm"] = optax.global_norm(
                 jax.tree_util.tree_map(
                     lambda g: g.astype(jnp.float32), grads
@@ -974,6 +974,11 @@ def instrument_update_step(update_step, registry=None, superstep_k=1):
       (lowering is compile-free but traces the net), and only when the
       inner jitted step is reachable (.lower).
 
+    - `wrapped.compiled_text()`: the text of the program the first
+      dispatch compiled (jit answers from its cache; None before a
+      dispatch or without `.lower`): what `--profile_dir`'s account
+      joins a device trace's ops on (telemetry/device_scopes.py).
+
     Signature-transparent: drivers swap `update_step =
     instrument_update_step(update_step, superstep_k=k)` and nothing
     else changes.
@@ -990,6 +995,7 @@ def instrument_update_step(update_step, registry=None, superstep_k=1):
     reg.gauge("learner.superstep_k").set(superstep_k)
     g_hbm = reg.gauge("learner.hbm_bytes_per_update")
     hbm_pending = [getattr(update_step, "lower", None) is not None]
+    first_signature = []
 
     def wrapped(params, opt_state, batch, initial_agent_state):
         nbytes = sum(
@@ -1002,11 +1008,11 @@ def instrument_update_step(update_step, registry=None, superstep_k=1):
             # Single-consumer hot path (the learner thread): the flag
             # flip is ordinary sequential code, no lock needed.
             hbm_pending[0] = False
-            precision_lib.hbm_gauge_async(
-                update_step,
-                (params, opt_state, batch, initial_agent_state),
-                g_hbm,
+            args = (params, opt_state, batch, initial_agent_state)
+            first_signature.append(
+                precision_lib.shape_structs(args, placed=True)
             )
+            precision_lib.hbm_gauge_async(update_step, args, g_hbm)
         with sp_dispatch:
             out = update_step(
                 params, opt_state, batch, initial_agent_state
@@ -1016,7 +1022,13 @@ def instrument_update_step(update_step, registry=None, superstep_k=1):
         h_per_dispatch.observe(superstep_k)
         return out
 
+    def compiled_text():
+        if not first_signature:
+            return None
+        return update_step.lower(*first_signature[0]).compile().as_text()
+
     wrapped.count_host_sync = lambda: c_host_syncs.inc()
+    wrapped.compiled_text = compiled_text
     return wrapped
 
 

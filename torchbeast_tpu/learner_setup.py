@@ -15,7 +15,9 @@ passed when the class declares the field and refused otherwise, and
 "memory is a KV cache" is an attribute of the class.
 """
 
+import glob
 import logging
+import os
 
 import jax
 import numpy as np
@@ -23,6 +25,10 @@ import numpy as np
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu import models
 from torchbeast_tpu import precision as precision_lib
+from torchbeast_tpu.models import stats as model_stats
+from torchbeast_tpu.telemetry import device_scopes
+
+log = logging.getLogger(__name__)
 
 # Flags that set a field of the family's module, each with what a family
 # that does not take it is told. A family takes one when its class
@@ -412,6 +418,50 @@ def add_learner_arguments(parser, *, model_default,
         raise ValueError(f"not learner flags: {sorted(unknown)}")
 
 
+def device_time_account(profile_dir, texts=(), stats=None, strict=True):
+    """The by-scope account (telemetry/device_scopes.py) of the newest
+    trace `jax.profiler` left under `profile_dir`: one account a
+    program in it, `texts` the compiled programs' whose ops it joins
+    on, and the sown counters among an update's `stats`."""
+    found = sorted(glob.glob(os.path.join(
+        profile_dir, "plugins", "profile", "*", "*.xplane.pb"
+    )))
+    if not found:
+        raise FileNotFoundError(f"no .xplane.pb under {profile_dir}")
+    profile = jax.profiler.ProfileData.from_file(found[-1])
+    counters = {
+        name: float(value) for name, value in (stats or {}).items()
+        if model_stats.gauge_name(name) and np.ndim(value) == 0
+    }
+    return device_scopes.account(
+        device_scopes.plain_trace(profile),
+        [device_scopes.read_program_text(text) for text in texts if text],
+        counters=counters, strict=strict,
+    )
+
+
+def stop_profile(flags, tele, update_step, stats):
+    """What `--profile_dir` ends a run with, for both drivers: the
+    trace stopped, its account logged as a table, a program a table,
+    and on the run's last telemetry line (`device_scopes`). A
+    profiling run is a short run: the flag traces all of it. Never
+    raises: a run's shutdown goes on without its account."""
+    try:
+        jax.profiler.stop_trace()
+    except RuntimeError:
+        return  # start_trace itself failed; don't mask the cause
+    try:
+        text = getattr(update_step, "compiled_text", lambda: None)()
+        report = device_time_account(
+            flags.profile_dir, [text], stats, strict=False
+        )
+    except Exception:  # noqa: BLE001
+        log.exception("No device time account of %s", flags.profile_dir)
+        return
+    log.info("Device time by scope:\n%s", device_scopes.render(report))
+    tele.set_static("device_scopes", report)
+
+
 def hparams_from_flags(flags) -> learner_lib.HParams:
     policy = precision_lib.resolve_flags(flags)
     return learner_lib.HParams(
@@ -703,7 +753,7 @@ def init_model_and_params(flags, num_actions, batch_size, frame_shape,
         n_stages = getattr(flags, "pipeline_stages", 0)
         if n_stages:
             extra[stage_kwarg] = n_stages
-        logging.getLogger(__name__).info(
+        log.info(
             "--model %s without --pipeline_parallel: the stage tower "
             "runs sequentially on one device", flags.model,
         )

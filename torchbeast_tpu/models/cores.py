@@ -16,6 +16,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from torchbeast_tpu.telemetry import device_scope
 from torchbeast_tpu.types import AgentOutput
 
 
@@ -197,29 +198,31 @@ class RecurrentPolicyHead(nn.Module):
         core_output = core_input.astype(self.dtype)
         if self.use_lstm:
             notdone = 1.0 - done.astype(jnp.float32)
-            core_output, core_state = LSTMCore(
-                hidden_size=self.hidden_size,
-                num_layers=self.num_layers,
-                dtype=self.dtype,
-                remat=self.remat,
-                name="core",
-            )(core_output, notdone, core_state)
+            with device_scope("lstm_core"):
+                core_output, core_state = LSTMCore(
+                    hidden_size=self.hidden_size,
+                    num_layers=self.num_layers,
+                    dtype=self.dtype,
+                    remat=self.remat,
+                    name="core",
+                )(core_output, notdone, core_state)
         else:
             core_state = ()
 
-        policy_logits = nn.Dense(
-            self.num_actions, dtype=self.dtype, name="policy"
-        )(core_output).astype(jnp.float32)
-        baseline = nn.Dense(
-            1, dtype=self.dtype, name="baseline"
-        )(core_output).astype(jnp.float32)
+        with device_scope("policy_head"):
+            policy_logits = nn.Dense(
+                self.num_actions, dtype=self.dtype, name="policy"
+            )(core_output).astype(jnp.float32)
+            baseline = nn.Dense(
+                1, dtype=self.dtype, name="baseline"
+            )(core_output).astype(jnp.float32)
 
-        if sample_action:
-            action = jax.random.categorical(
-                self.make_rng("action"), policy_logits, axis=-1
-            )
-        else:
-            action = jnp.argmax(policy_logits, axis=-1)
+            if sample_action:
+                action = jax.random.categorical(
+                    self.make_rng("action"), policy_logits, axis=-1
+                )
+            else:
+                action = jnp.argmax(policy_logits, axis=-1)
 
         return (
             AgentOutput(

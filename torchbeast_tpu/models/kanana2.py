@@ -77,6 +77,7 @@ from torchbeast_tpu.ops.attention import (
     fused_latent_leg_applies,
     latent_cached_attend,
 )
+from torchbeast_tpu.telemetry import device_scope
 
 # https://huggingface.co/kakaocorp/kanana-2-30b-a3b-instruct-2601/blob/main/config.json
 # by the name of the field that carries each. `create_model("kanana2")`
@@ -164,7 +165,7 @@ class _Kanana2Block(nn.Module):
                 width, use_bias=False, dtype=self.dtype, name=name
             )
 
-        with jax.named_scope("attention_latent"):
+        with device_scope("attention_latent"):
             h = norm("attn_norm")(x)
             q = proj("q", H * (Dn + Dr))(h).reshape(B, T, H, Dn + Dr)
             compressed = proj("kv_a", C + Dr)(h)
@@ -205,7 +206,7 @@ class _Kanana2Block(nn.Module):
 
         h = norm("mlp_norm")(x)
         if self.dense:
-            with jax.named_scope("mlp"):
+            with device_scope("mlp"):
                 hidden = nn.silu(proj("gate", self.mlp_width)(h)) * proj(
                     "up", self.mlp_width
                 )(h)

@@ -54,6 +54,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from torchbeast_tpu.models.stats import sow_stat
 from torchbeast_tpu.ops import grouped_matmul as _cut_in_vmem
+from torchbeast_tpu.telemetry import device_scope
 
 
 def _constrain(x, mesh, spec):
@@ -534,7 +535,7 @@ def _experts_on_rows(rows, w_gate, w_up, w_down, groups, first, activation,
     or with `w_gate` the SwiGLU's `w_down (act(w_gate x) * w_up x)`;
     `first` and `terms` as `_grouped_matmul` takes them."""
     act = _ACTIVATIONS[activation]
-    with jax.named_scope("moe_experts"):
+    with device_scope("moe_experts"):
         hidden = act(_grouped_matmul(
             rows, w_up if w_gate is None else w_gate, groups, first, terms
         ))
@@ -550,7 +551,7 @@ def _window_experts(how, at_row, x, gate, w_gate, w_up, w_down, idx, order,
     """The held experts' part of the sum over one rung of the window,
     its rows `at_row` onward: rows gathered, the grouped matmuls, the
     sum a token."""
-    with jax.named_scope("moe_dispatch"):
+    with device_scope("moe_dispatch"):
         rows, groups, at = _window_dispatch(
             x, idx, order, inverse, sizes, how.first, how.held,
             how.rungs[0], at_row,
@@ -558,7 +559,7 @@ def _window_experts(how, at_row, x, gate, w_gate, w_up, w_down, idx, order,
     out = _experts_on_rows(
         rows, w_gate, w_up, w_down, groups, 0, how.activation, how.terms
     )
-    with jax.named_scope("moe_combine"):
+    with device_scope("moe_combine"):
         return _window_combine(
             out.astype(jnp.float32), gate.astype(jnp.float32), at
         )
@@ -568,10 +569,13 @@ def _sweep(how, sizes, rung_at, start):
     """`rung_at(at_row, carry)` over the rungs this step's window
     takes, one compiled body however many they are."""
     mine = sizes[how.first : how.first + how.held]
-    return jax.lax.fori_loop(
-        0, window_sweeps(how.rungs, mine),
-        lambda i, carry: rung_at(i * how.rungs[0], carry), start,
-    )
+    # The loop's own work (the carried sums, their zeros and copies)
+    # under a name: a rung's parts enter their scopes inside it.
+    with device_scope("moe_sweep"):
+        return jax.lax.fori_loop(
+            0, window_sweeps(how.rungs, mine),
+            lambda i, carry: rung_at(i * how.rungs[0], carry), start,
+        )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -653,7 +657,7 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None,
     # how many rungs it takes.
     held = w_up.shape[0]
     rungs = () if first is None else window_rungs(tokens, K, held, E)
-    with jax.named_scope("moe_dispatch"):
+    with device_scope("moe_dispatch"):
         flat = idx.reshape(tokens * K)
         # order[i]: which (token, rank) assignment sits in sorted row i.
         order = jnp.argsort(flat, stable=True)
@@ -668,12 +672,12 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None,
         if rungs[0] == rungs[1]:
             return _window_experts(how, 0, *weights, *indices), sizes
         return _swept_experts(how, weights, indices), sizes
-    with jax.named_scope("moe_dispatch"):
+    with device_scope("moe_dispatch"):
         rows = _permute(jnp.repeat(x, K, axis=0), order, inverse)
     out = _experts_on_rows(
         rows, w_gate, w_up, w_down, sizes, first, activation, terms
     )
-    with jax.named_scope("moe_combine"):
+    with device_scope("moe_combine"):
         out = _permute(out, inverse, order).reshape(tokens, K, -1)
         y = jnp.einsum(
             "tkd,tk->td", out.astype(jnp.float32), gate.astype(jnp.float32)
@@ -769,7 +773,7 @@ class DroplessMoE(nn.Module):
         # f32 at the highest matmul precision: the logits decide WHICH
         # experts run, and a rounded logit picks another expert where
         # the eighth and ninth are close.
-        with jax.named_scope("moe_route"):
+        with device_scope("moe_route"):
             router_logits = nn.Dense(
                 E, use_bias=False, name="router",
                 precision=jax.lax.Precision.HIGHEST,
@@ -804,7 +808,7 @@ class DroplessMoE(nn.Module):
         routed_in, width = x.astype(self.dtype), d
         if self.latent_width:
             width = self.latent_width
-            with jax.named_scope("moe_latent_down"):
+            with device_scope("moe_latent_down"):
                 routed_in = proj("latent_down", width)(routed_in)
         # fan-in is one expert's d (or f), not E times it.
         kernel_init = nn.initializers.lecun_normal(batch_axis=(0,))
@@ -825,12 +829,12 @@ class DroplessMoE(nn.Module):
             w_down.astype(self.dtype), **extras,
         )
         if self.latent_width:
-            with jax.named_scope("moe_latent_up"):
+            with device_scope("moe_latent_up"):
                 y = proj("latent_up", d)(y.astype(self.dtype)).astype(
                     jnp.float32
                 )
         if self.shared_width:
-            with jax.named_scope("moe_shared"):
+            with device_scope("moe_shared"):
                 h = x.astype(self.dtype)
                 hidden = act(proj("shared_gate", self.shared_width)(h)) * (
                     proj("shared_up", self.shared_width)(h)
@@ -839,7 +843,7 @@ class DroplessMoE(nn.Module):
                 )
                 shared = proj("shared_down", d)(hidden).astype(jnp.float32)
                 if self.shared_token_gate:
-                    with jax.named_scope("moe_shared_gate"):
+                    with device_scope("moe_shared_gate"):
                         shared = shared * nn.sigmoid(
                             proj("shared_expert_gate", 1)(h)
                         ).astype(jnp.float32)

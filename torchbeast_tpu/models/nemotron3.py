@@ -89,6 +89,7 @@ from torchbeast_tpu.ops.attention import (
     dense_transformer_attend,
     fused_pass_applies,
 )
+from torchbeast_tpu.telemetry import device_scope
 
 MAMBA, ATTENTION, EXPERTS = "M", "*", "E"
 
@@ -199,7 +200,7 @@ def ssd_scan(x, dt, A, B_in, C_in, state, done, chunk):
     def along_heads(mask):  # [B, c, ...] -> [B, c, 1, 1, ...]
         return mask[:, :, None, None]
 
-    with jax.named_scope("ssd_intra"):
+    with device_scope("ssd_intra"):
         decay = jnp.exp(jnp.where(
             along_heads(reaches(ends)),
             cs[..., :, None] - cs[..., None, :], -jnp.inf,
@@ -207,7 +208,7 @@ def ssd_scan(x, dt, A, B_in, C_in, state, done, chunk):
         scores = jnp.einsum("bcign,bcjgn->bcgij", C_in, B_in)
         weights = scores[:, :, :, None] * decay * dt_last[..., None, :]
         y = jnp.einsum("bcghij,bcjghp->bcighp", weights, x)
-    with jax.named_scope("ssd_states"):
+    with device_scope("ssd_states"):
         # What the chunk's own steps leave in the state at its end, and
         # what it hands on of the state it was given.
         to_end = jnp.exp(jnp.where(
@@ -221,7 +222,7 @@ def ssd_scan(x, dt, A, B_in, C_in, state, done, chunk):
         handed_on = jnp.where(
             along_heads(ends[:, :, -1] == 0), jnp.exp(cs[..., -1]), 0.0
         )  # [B, c, G, per]
-    with jax.named_scope("ssd_inter"):
+    with device_scope("ssd_inter"):
         def pass_on(entering, chunk_parts):
             left_c, handed_on_c = chunk_parts
             leaving = handed_on_c[..., None, None] * entering + left_c
@@ -340,14 +341,14 @@ class _MambaBlock(nn.Module):
         channels = inner + 2 * G * N
         carried, tail = state
 
-        with jax.named_scope("mamba_in_proj"):
+        with device_scope("mamba_in_proj"):
             h = _norm("norm", self.rms_norm_eps)(x)
             joined = _proj("in_proj", 2 * inner + 2 * G * N + H, self.dtype)(h)
             z = joined[..., :inner]
             xBC = joined[..., inner : inner + channels]
             dt = joined[..., inner + channels :]
 
-        with jax.named_scope("mamba_conv"):
+        with device_scope("mamba_conv"):
             bound = 1.0 / math.sqrt(K)
             xBC, new_tail = conv_over_episodes(
                 xBC, tail, done,
@@ -361,7 +362,7 @@ class _MambaBlock(nn.Module):
             )
             xBC = nn.silu(xBC)
 
-        with jax.named_scope("ssd_scan"):
+        with device_scope("ssd_scan"):
             dt = nn.softplus(
                 dt.astype(jnp.float32)
                 + self.param(
@@ -382,13 +383,13 @@ class _MambaBlock(nn.Module):
                 :, None
             ] * heads_x
 
-        with jax.named_scope("mamba_gate_norm"):
+        with device_scope("mamba_gate_norm"):
             y = gated_group_norm(
                 y.reshape(rows, steps, inner), z.astype(jnp.float32),
                 self.param("gate_norm", nn.initializers.ones, (inner,)),
                 G, self.rms_norm_eps,
             )
-        with jax.named_scope("mamba_out_proj"):
+        with device_scope("mamba_out_proj"):
             x = x + _proj("out_proj", self.d_model, self.dtype)(
                 y.astype(self.dtype)
             ).astype(jnp.float32)
@@ -432,7 +433,7 @@ class _AttentionBlock(nn.Module):
         H, Hkv, hd = self.num_heads, self.kv_heads, self.head_dim
         cache = tuple(c.transpose(1, 0, 2, 3) for c in cache_state)
         mask = jnp.concatenate([cache_mask, seq_mask], axis=-1)
-        with jax.named_scope("attention_full"):
+        with device_scope("attention_full"):
             h = _norm("norm", self.rms_norm_eps)(x)
             q = _proj("q", H * hd, self.dtype)(h).reshape(rows, steps, H, hd)
             k, v = (

@@ -38,6 +38,7 @@ from torchbeast_tpu.models.transformer import (
     count_two_leg_application,
 )
 from torchbeast_tpu.ops.attention import cached_transformer_attend
+from torchbeast_tpu.telemetry import device_scope
 
 # https://huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct/blob/main/config.json
 # by the name of the field that carries each. `expert_width` is the
@@ -172,7 +173,7 @@ class _OLMoEBlock(nn.Module):
                 self.d_model, use_bias=False, dtype=self.dtype, name=name
             )
 
-        with jax.named_scope("attention"):
+        with device_scope("attention"):
             h = norm("attn_norm")(x)
             # q/k norm over the whole projected width before the split
             # into heads: OLMoE's block has it, its config.json does not

@@ -56,6 +56,7 @@ from torchbeast_tpu.models.transformer import (
     TransformerNet,
     count_two_leg_application,
 )
+from torchbeast_tpu.telemetry import device_scope
 
 # https://huggingface.co/ByteDance/Ouro-2.6B/blob/main/config.json by
 # the name of the field that carries each. `create_model("ouro")` reads
@@ -102,7 +103,7 @@ class _OuroBlock(nn.Module):
                 width, use_bias=False, dtype=self.dtype, name=name
             )
 
-        with jax.named_scope("attention"):
+        with device_scope("attention"):
             h = norm("input_layernorm")(x)
             q = proj("q", H * hd)(h).reshape(B, T, H, hd)
             k = proj("k", H * hd)(h).reshape(B, T, H, hd)
@@ -116,7 +117,7 @@ class _OuroBlock(nn.Module):
                     attended.reshape(B, T, H * hd)
                 ).astype(jnp.float32)
             )
-        with jax.named_scope("mlp"):
+        with device_scope("mlp"):
             h = norm("post_attention_layernorm")(x)
             hidden = nn.silu(proj("gate", self.mlp_width)(h)) * proj(
                 "up", self.mlp_width
@@ -238,7 +239,7 @@ class OuroNet(TransformerNet):
         gates = []  # one a pass, in order
 
         def end_pass(x):
-            with jax.named_scope("pass_norm"):
+            with device_scope("pass_norm"):
                 x = norm(x)
             gates.append(nn.sigmoid(gate(jax.lax.stop_gradient(x)))[..., 0])
             if len(gates) == self.passes and not self.is_initializing():

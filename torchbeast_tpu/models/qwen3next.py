@@ -119,6 +119,7 @@ from torchbeast_tpu.ops.attention import (
     dense_transformer_attend,
     fused_pass_applies,
 )
+from torchbeast_tpu.telemetry import device_scope
 
 # https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct/blob/main/config.json
 # by the name of the field that carries each. `create_model("qwen3next")`
@@ -254,7 +255,7 @@ def delta_scan(q, k, v, g, beta, state, done, chunk):
     def along_heads(mask):  # [B, c, ...] -> [B, c, 1, 1, ...]
         return mask[:, :, None, None]
 
-    with jax.named_scope("delta_intra"):
+    with device_scope("delta_intra"):
         decay = jnp.exp(jnp.where(
             along_heads(reaches(ends)), G[..., :, None] - G[..., None, :],
             -jnp.inf,
@@ -262,7 +263,7 @@ def delta_scan(q, k, v, g, beta, state, done, chunk):
         # What step i still sees of the state that entered the chunk.
         from_start = jnp.where(along_heads(ends == 0), jnp.exp(G), 0.0)
         between_keys = jnp.einsum("bcihd,bcjhd->bchij", k, k)
-        with jax.named_scope("delta_solve"):
+        with device_scope("delta_solve"):
             solved = unit_lower_inverse(jnp.where(
                 np.tril(np.ones((Q, Q), bool), -1),
                 beta[..., :, None] * between_keys[:, :, :, None] * decay,
@@ -276,7 +277,7 @@ def delta_scan(q, k, v, g, beta, state, done, chunk):
         weights = jnp.einsum(
             "bcihd,bcjhd->bchij", q, k
         )[:, :, :, None] * decay
-    with jax.named_scope("delta_states"):
+    with device_scope("delta_states"):
         # What the chunk's own steps leave in the state at its end, and
         # what it makes of the state it was given: both linear in it.
         to_end = jnp.exp(jnp.where(
@@ -287,7 +288,7 @@ def delta_scan(q, k, v, g, beta, state, done, chunk):
         handed_on = from_start[..., -1, None, None] * jnp.eye(Dk) - (
             jnp.einsum("bchpjd,bchpje->bchpde", keys_left, keys_seen)
         )  # [B, c, Hk, per, Dk, Dk]
-    with jax.named_scope("delta_inter"):
+    with device_scope("delta_inter"):
         def pass_on(entering, chunk_parts):
             handed_on_c, left_c = chunk_parts
             leaving = jnp.einsum(
@@ -408,7 +409,7 @@ class _DeltaNetBlock(nn.Module):
         channels = 2 * keys + inner
         carried, tail = state
 
-        with jax.named_scope("deltanet_in_proj"):
+        with device_scope("deltanet_in_proj"):
             h = RMSNorm0(self.rms_norm_eps, name="norm")(x)
             # The published layout: a key head's q, k, its value heads'
             # v and z side by side; likewise b and a.
@@ -427,7 +428,7 @@ class _DeltaNetBlock(nn.Module):
                 )
             ], axis=-1)
 
-        with jax.named_scope("deltanet_conv"):
+        with device_scope("deltanet_conv"):
             bound = K ** -0.5
             joined, new_tail = conv_over_episodes(
                 joined, tail, done,
@@ -439,7 +440,7 @@ class _DeltaNetBlock(nn.Module):
             )
             joined = nn.silu(joined)
 
-        with jax.named_scope("delta_scan"):
+        with device_scope("delta_scan"):
             beta = nn.sigmoid(
                 ba[..., :per].reshape(rows, steps, Hv).astype(jnp.float32)
             )
@@ -464,13 +465,13 @@ class _DeltaNetBlock(nn.Module):
                 g, beta, carried.transpose(1, 0, 2, 3), done, self.chunk_size,
             )
 
-        with jax.named_scope("deltanet_gate_norm"):
+        with device_scope("deltanet_gate_norm"):
             y = normed_then_gated(
                 o, z.astype(jnp.float32),
                 self.param("gate_norm", nn.initializers.ones, (Dv,)),
                 self.rms_norm_eps,
             )
-        with jax.named_scope("deltanet_out_proj"):
+        with device_scope("deltanet_out_proj"):
             x = x + _proj("out_proj", self.d_model, self.dtype)(
                 y.reshape(rows, steps, inner).astype(self.dtype)
             ).astype(jnp.float32)
@@ -539,7 +540,7 @@ class _GatedAttentionBlock(nn.Module):
                 x[..., rotary:],
             ], axis=-1).astype(self.dtype)
 
-        with jax.named_scope("attention_full"):
+        with device_scope("attention_full"):
             h = RMSNorm0(self.rms_norm_eps, name="norm")(x)
             # A head's query and gate side by side (the published layout).
             q_gate = _proj("q", H * 2 * hd, self.dtype)(h).reshape(
@@ -567,7 +568,7 @@ class _GatedAttentionBlock(nn.Module):
             )
             if fused_pass_applies(q.shape, k_all.shape, None):
                 count_fused_application(self)
-            with jax.named_scope("attention_gate"):
+            with device_scope("attention_gate"):
                 attended = attended * nn.sigmoid(
                     q_gate[..., hd:].astype(attended.dtype)
                 )

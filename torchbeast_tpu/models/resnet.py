@@ -21,6 +21,7 @@ from torchbeast_tpu.models.cores import (
     split_time_batch,
 )
 from torchbeast_tpu.ops.pool import max_pool2d
+from torchbeast_tpu.telemetry import device_scope
 
 
 class ResNetBase(nn.Module):
@@ -78,8 +79,9 @@ class ResNetBase(nn.Module):
     @nn.compact
     def __call__(self, frame):
         T, B = frame.shape[:2]
-        x = merge_time_batch(frame, self.time_major_merge)
-        x = x.astype(self.dtype) / 255.0
+        with device_scope("trunk_input"):
+            x = merge_time_batch(frame, self.time_major_merge)
+            x = x.astype(self.dtype) / 255.0
 
         # Rematerialize stages in the backward pass: at the reference's
         # T=80 x B=32 the stage-0 activations alone are ~1.1 GB f32 each
@@ -106,19 +108,21 @@ class ResNetBase(nn.Module):
         whole = nn.remat(ResNetBase._stage, static_argnums=(2,))
         front = nn.remat(ResNetBase._stage_front, static_argnums=(2,))
         for i, flag in enumerate(flags):
-            if flag == "front":
-                x = self._stage_rest(front(self, x, i), i)
-            elif flag:
-                x = whole(self, x, i)
-            else:
-                x = ResNetBase._stage(self, x, i)
+            with device_scope(f"trunk_stage_{i}"):
+                if flag == "front":
+                    x = self._stage_rest(front(self, x, i), i)
+                elif flag:
+                    x = whole(self, x, i)
+                else:
+                    x = ResNetBase._stage(self, x, i)
 
-        x = nn.relu(x)
-        x = x.reshape((B * T, -1))  # 11*11*32 = 3872 for 84x84 input
-        x = nn.relu(nn.Dense(256, dtype=self.dtype, name="fc")(x))
-        return split_time_batch(
-            x.astype(self.out_dtype), T, B, self.time_major_merge
-        )
+        with device_scope("trunk_fc"):
+            x = nn.relu(x)
+            x = x.reshape((B * T, -1))  # 11*11*32 = 3872 for 84x84 input
+            x = nn.relu(nn.Dense(256, dtype=self.dtype, name="fc")(x))
+            return split_time_batch(
+                x.astype(self.out_dtype), T, B, self.time_major_merge
+            )
 
 
 class ResNet(nn.Module):

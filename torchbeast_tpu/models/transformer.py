@@ -57,6 +57,7 @@ from torchbeast_tpu.ops.attention import (
     segment_ids_from_done,
     ulysses_transformer_attention,
 )
+from torchbeast_tpu.telemetry import device_scope
 
 
 def _count_application(module: nn.Module, name: str) -> None:
@@ -321,7 +322,7 @@ class TransformerNet(nn.Module):
         frame = inputs["frame"]  # [T, B, ...]
         T, B = frame.shape[:2]
 
-        with jax.named_scope("obs_embed"):
+        with device_scope("obs_embed"):
             x = frame.reshape((T * B, -1)).astype(self.dtype) / 255.0
             low, high = self.frame_range
             if (low, high) != (0.0, 1.0):
@@ -379,7 +380,7 @@ class TransformerNet(nn.Module):
         entries, carried = iter(self.layer_caches()), iter(core_state)
         blocks, new_state = {}, []
         for blocks_of_pass in walk:
-            with jax.named_scope("loop_pass") if len(walk) > 1 else (
+            with device_scope("loop_pass") if len(walk) > 1 else (
                 contextlib.nullcontext()
             ):
                 for layer in blocks_of_pass:

@@ -36,6 +36,7 @@ from torchbeast_tpu.learner_setup import (  # noqa: F401
     dummy_env_outputs,
     hparams_from_flags,
     init_model_and_params as _init_model_and_params,
+    stop_profile,
 )
 from torchbeast_tpu.rollout import (
     PipelinedRolloutCollector,
@@ -711,7 +712,7 @@ def train(flags):
             pending = None
         g_dispatch_q.set(0)  # everything flushed (or abandoned) now
         if flags.profile_dir:
-            jax.profiler.stop_trace()
+            stop_profile(flags, tele, update_step, stats)
         save_checkpoint(
             checkpoint_path,
             params=latest_params,

@@ -70,6 +70,7 @@ from torchbeast_tpu.ops.fused_attention import (
     fused_latent_leg,
     padded_steps,
 )
+from torchbeast_tpu.telemetry import device_scope
 
 # f32 score bytes (B x H x T x K x 4) from which `dense_transformer_
 # attend` takes the fused pass: see `fused_pass_applies`.
@@ -730,20 +731,20 @@ def cached_transformer_attend(q, k, v, cache_k, cache_v, cache_mask,
         s = jnp.einsum(spec, q, keys).astype(jnp.float32) * scale
         return jnp.where(mask[:, None, None], s, BIG_NEG)
 
-    with jax.named_scope("cache_leg"):
+    with device_scope("cache_leg"):
         s_c = scores("bqhgd,mbhd->bhgqm", cache_k, cache_mask)
-    with jax.named_scope("unroll_leg"):
+    with device_scope("unroll_leg"):
         s_u = scores("bqhgd,bkhd->bhgqk", k, seq_mask)
     # As jax.nn.softmax: no gradient through the maximum.
     top = jax.lax.stop_gradient(
         jnp.maximum(s_c.max(axis=-1), s_u.max(axis=-1))
     )[..., None]
-    with jax.named_scope("cache_leg"):
+    with device_scope("cache_leg"):
         p_c = jnp.exp(s_c - top)
         out_c = jnp.einsum(
             "bhgqm,mbhd->bqhgd", p_c.astype(cache_v.dtype), cache_v
         )
-    with jax.named_scope("unroll_leg"):
+    with device_scope("unroll_leg"):
         p_u = jnp.exp(s_u - top)
         out_u = jnp.einsum("bhgqk,bkhd->bqhgd", p_u.astype(v.dtype), v)
     den = p_c.sum(axis=-1) + p_u.sum(axis=-1)  # [B, Hkv, G, T]
@@ -833,7 +834,7 @@ def latent_cached_attend(q_nope, q_rope, k_nope, k_rope, v, cache_latent,
         return jnp.where(mask[:, None], s.astype(jnp.float32) * scale, BIG_NEG)
 
     def unroll_scores():
-        with jax.named_scope("unroll_leg"):
+        with device_scope("unroll_leg"):
             return masked(
                 jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
                 + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope[:, :, 0]),
@@ -849,11 +850,11 @@ def latent_cached_attend(q_nope, q_rope, k_nope, k_rope, v, cache_latent,
         T = q_nope.shape[1]
         steps = ((0, 0), (0, padded_steps(T) - T), (0, 0), (0, 0))
         q_nope, latent, cache_rope = placed(q_nope)
-        with jax.named_scope("latent_absorb"):
+        with device_scope("latent_absorb"):
             q_latent = jnp.einsum(
                 "bqhd,chd->hbqc", jnp.pad(q_nope, steps), w_uk
             )
-        with jax.named_scope("cache_leg"):
+        with device_scope("cache_leg"):
             out_c, lse_c = fused_latent_leg(
                 q_latent, jnp.pad(q_rope, steps).transpose(2, 0, 1, 3),
                 latent, cache_rope, cache_mask, scale,
@@ -863,7 +864,7 @@ def latent_cached_attend(q_nope, q_rope, k_nope, k_rope, v, cache_latent,
         top = jax.lax.stop_gradient(jnp.maximum(lse_c, s_u.max(axis=-1)))
         # The leg's share of the one denominator: its own, rescaled.
         den_c = jnp.exp(lse_c - top)
-        with jax.named_scope("latent_lift"):
+        with device_scope("latent_lift"):
             out_c = jnp.einsum(
                 "hbqc,chd->hbqd", out_c.astype(w_uv.dtype), w_uv
             )[:, :, :T].transpose(1, 2, 0, 3)
@@ -872,13 +873,13 @@ def latent_cached_attend(q_nope, q_rope, k_nope, k_rope, v, cache_latent,
         )
         top = top[..., None]
     else:
-        with jax.named_scope("latent_absorb"):
+        with device_scope("latent_absorb"):
             q_cache = jnp.concatenate(
                 [jnp.einsum("bqhd,chd->bqhc", q_nope, w_uk), q_rope],
                 axis=-1,
             )
         q_cache, latent, cache_rope = placed(q_cache)
-        with jax.named_scope("cache_leg"):
+        with device_scope("cache_leg"):
             s_c = masked(
                 jnp.einsum(
                     "bqhc,mbc->bhqm", q_cache,
@@ -891,15 +892,15 @@ def latent_cached_attend(q_nope, q_rope, k_nope, k_rope, v, cache_latent,
         top = jax.lax.stop_gradient(
             jnp.maximum(s_c.max(axis=-1), s_u.max(axis=-1))
         )[..., None]
-        with jax.named_scope("cache_leg"):
+        with device_scope("cache_leg"):
             p_c = jnp.exp(s_c - top)
             out_c = jnp.einsum(
                 "bhqm,mbc->bqhc", p_c.astype(latent.dtype), latent,
                 precision=cache_precision,
             )
-        with jax.named_scope("latent_lift"):
+        with device_scope("latent_lift"):
             out_c = jnp.einsum("bqhc,chd->bqhd", out_c, w_uv)
-    with jax.named_scope("unroll_leg"):
+    with device_scope("unroll_leg"):
         p_u = jnp.exp(s_u - top)
         out_u = jnp.einsum("bhqk,bkhd->bqhd", p_u.astype(v.dtype), v)
     # [B, H, T]; the XLA body's sum where it always stood in the program.

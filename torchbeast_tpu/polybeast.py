@@ -36,6 +36,7 @@ from torchbeast_tpu.learner_setup import (
     dummy_env_outputs,
     hparams_from_flags,
     init_model_and_params,
+    stop_profile,
 )
 from torchbeast_tpu.models import stats as model_stats
 from torchbeast_tpu.runtime import wire
@@ -2203,10 +2204,9 @@ def train(flags):
             tele.set_static("chaos", chaos.summary())
         watchdog.stop()
         if flags.profile_dir:
-            try:
-                jax.profiler.stop_trace()
-            except RuntimeError:
-                pass  # start_trace itself failed; don't mask the cause
+            stop_profile(
+                flags, tele, update_step, state["stats"]
+            )
         # Shutdown ordering mirrors the reference (polybeast_learner.py:
         # 587-593): close batcher + queue, join actors, join threads.
         # The replica batcher (when armed) closes alongside the central

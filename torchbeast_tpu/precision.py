@@ -233,13 +233,15 @@ def memory_stats(jittable, *args, compiled: bool = True) -> MemoryStats:
     )
 
 
-def shape_structs(tree):
+def shape_structs(tree, placed=False):
     """Concrete arrays -> ShapeDtypeStructs (lowering fodder that holds
-    no buffers)."""
+    no buffers); `placed`, each with its array's sharding, so that a
+    jitted function lowers to the program the arrays' call compiled."""
     return jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(
             np.shape(a), jnp.asarray(a).dtype if not hasattr(a, "dtype")
-            else a.dtype
+            else a.dtype,
+            sharding=getattr(a, "sharding", None) if placed else None,
         ),
         tree,
     )

@@ -1,7 +1,7 @@
 """Process-wide observability: metrics registry, pipeline span tracing,
 and exporters (ISSUE 2 tentpole).
 
-Four modules, stdlib-only (no jax/numpy — instrumentation inside the
+Five modules, stdlib-only (no jax/numpy — instrumentation inside the
 acting hot path must never trigger a device sync or heavyweight import;
 pinned by tests/test_telemetry.py):
 
@@ -12,6 +12,9 @@ pinned by tests/test_telemetry.py):
   exportable as Chrome trace-event JSON (chrome://tracing / Perfetto).
 - heartbeat: how late a 5 ms sleeper wakes (GIL pressure), and stalls
   with the CPU time that passed in them.
+- device_scopes: `device_scope(name)`, the one way to name a part of a
+  device program (`jax.named_scope` that notes the name), and the
+  account of a profiler trace's device time by those scopes.
 - export:  snapshot / delta / merge, the JSON-lines exporter FileWriter
   hosts (`{xpid}/telemetry.jsonl`), a Prometheus-text HTTP endpoint
   (--telemetry_port), and a `--selftest` CLI.
@@ -33,6 +36,10 @@ global-registry instrument and the global tracer into no-ops; private
 MetricsRegistry()/Tracer() instances ignore the gate.
 """
 
+from torchbeast_tpu.telemetry.device_scopes import (  # noqa: F401
+    device_scope,
+    known_device_scopes,
+)
 from torchbeast_tpu.telemetry.driver import (  # noqa: F401
     DriverTelemetry,
     add_arguments,
