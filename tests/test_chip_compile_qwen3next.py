@@ -108,6 +108,33 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     assert not scores, scores
     assert text.count("fused_attend_forward") >= 2  # and rematerialised
     assert text.count("fused_attend_backward") >= 1
+    # The frames enter `Dense_0` as the bfloat16 integers they are (PR
+    # 52: models/transformer.py `frame_projection`): no float32 copy of
+    # the batch's frames anywhere in the program (the parent wrote three,
+    # 462 MB each), and under `obs_embed` nothing 28,224 wide but the
+    # kernel [28224, d] (its gradient, its two bf16 terms) and the bf16
+    # operand: ONE cast of the uint8 frames (116 MB in, 231 out), which
+    # both products read.
+    frames = (steps + 1) * rows * int(np.prod(frame))
+    assert not {s for s in shapes if int(np.prod(s)) >= frames}
+    wide = {
+        (kind, tuple(int(d) for d in dims.split(",")))
+        for line in text.splitlines() if "obs_embed" in line
+        for kind, dims in re.findall(
+            r"= \(?(\w+)\[([0-9,]+)\]", line.split(" metadata=")[0]
+        )
+        if "28224" in dims.split(",")
+    }
+    kernel = {(28224, 2048), (28224, 2048, 1)}
+    assert {s for kind, s in wide if kind == "f32"} <= kernel, wide
+    assert {kind for kind, _ in wide} <= {"f32", "bf16"}, wide
+    operands = [
+        line for line in text[text.index("\nENTRY "):].splitlines()
+        if "obs_embed" in line and re.search(
+            r"= bf16\[%d,%d,84,84,4\]\S* fusion\(" % (rows, steps + 1), line
+        )
+    ]
+    assert len(operands) == 1, operands
     # The carried matrix states are the program's arguments.
     assert (32, rows, 128, 128) in shapes
     # The sorted rows of all the assignments are never an operand of a

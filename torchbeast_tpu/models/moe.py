@@ -54,6 +54,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from torchbeast_tpu.models.stats import sow_stat
 from torchbeast_tpu.ops import grouped_matmul as _cut_in_vmem
+from torchbeast_tpu.ops.bf16_terms import (
+    bf16_terms as _bf16_terms,
+    terms_traced_under as _terms_traced_under,
+)
 from torchbeast_tpu.telemetry import device_scope
 
 
@@ -325,30 +329,6 @@ def _window_combine(out, gate, at):
 # The grouped matmul's tiles (rows of a group, contracted, output
 # columns), tuned on the v5e at the OLMoE cell's shapes (PERF.md, PR 27).
 _GMM_TILING = (256, 1024, 1024)
-
-
-# bfloat16 terms an operand is cut into, by the matmul precision the
-# caller traces under (JAX's names and their aliases); n terms make
-# n (n + 1) / 2 passes of the kernel.
-_TERMS = {"high": 2, "tensorfloat32": 2, "highest": 3, "float32": 3}
-
-
-def _terms_traced_under():
-    return _TERMS.get(jax.config.jax_default_matmul_precision, 1)
-
-
-def _bf16_terms(x, terms):
-    """x as a sum of `terms` bfloat16 arrays, the largest first."""
-    if terms == 1:
-        return [x.astype(jnp.bfloat16)]
-    out = []
-    for _ in range(terms):
-        # Not astype there and back: XLA takes that round trip for the
-        # identity on the chip, and the next term comes out as zeros.
-        head = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
-        out.append(head.astype(jnp.bfloat16))
-        x = x - head
-    return out
 
 
 def _cut_in_kernel(terms):

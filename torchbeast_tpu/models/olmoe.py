@@ -224,7 +224,11 @@ class OLMoENet(TransformerNet):
     # that all tokens share, and the frames' mean of 0.5 put one on
     # every token larger than what tells them apart, so every token's
     # router saw much the same input (one expert took every token at
-    # seeded weights; a token embedding has no such offset).
+    # seeded weights; a token embedding has no such offset). On the
+    # chip a uint8 frame is multiplied as the integers 2u - 255 and the
+    # scale 1/255 lands on the projection's result (models/
+    # transformer.py `frame_projection`); a symmetric range needs no
+    # shift there.
     frame_range: Tuple[float, float] = (-1.0, 1.0)
     # OLMoE's paper trains with a load-balance weight of 0.01 (and a
     # router z-loss, left out here); neither is in config.json.

@@ -40,6 +40,7 @@ model serves both.
 """
 
 import contextlib
+import functools
 from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
@@ -48,6 +49,7 @@ import jax.numpy as jnp
 
 from torchbeast_tpu.models.cores import RecurrentPolicyHead
 from torchbeast_tpu.models.stats import sow_stat
+from torchbeast_tpu.ops.bf16_terms import bf16_terms, terms_traced_under
 from torchbeast_tpu.ops.attention import (
     band_by_leg,
     band_relative_offsets,
@@ -95,6 +97,128 @@ def count_latent_fused_application(module: nn.Module) -> None:
     `attention_latent_fused_applications`, 5 in the Kanana-2 cell, no
     such key where no block took it."""
     _count_application(module, "latent_fused_applications")
+
+
+def scaled_frames(frame, frame_range, dtype):
+    """Frames [T, B, ...] as the [T * B, F] values of `frame_range` in
+    `dtype`, merged time-major: the float expression's left operand."""
+    T, B = frame.shape[:2]
+    x = frame.reshape((T * B, -1)).astype(dtype) / 255.0
+    low, high = frame_range
+    if (low, high) != (0.0, 1.0):
+        x = low + (high - low) * x
+    return x
+
+
+def _symmetric(frame_range):
+    low, high = frame_range
+    return low + high == 0
+
+
+def integers_to_range(frame_range):
+    """(scale, shift): uint8 frames scaled to `frame_range` are `scale
+    * frame_integers(frame) + shift`."""
+    low, high = frame_range
+    if _symmetric(frame_range):
+        return (high - low) / 510.0, 0.0
+    return (high - low) / 255.0, float(low)
+
+
+def frame_integers(frame, frame_range):
+    """uint8 frames [rows, steps, ...] as the [rows, steps, F] integers
+    the product multiplies, which bfloat16 holds exactly. Where the
+    range is symmetric they are 2u - 255, the odd integers of [-255,
+    255] (eight significant bits, as 0..255 has), and nothing is
+    shifted after: the operand is then 255 times the very value the
+    float expression multiplies, so the weights' terms are rounded
+    against magnitudes no larger than the float path's and nothing is
+    added back that the product must cancel. Any other range takes u
+    itself and a shift of `low`, which reaches the result as `low *
+    colsum(W)` (nothing where low is 0)."""
+    u = frame.reshape(frame.shape[:2] + (-1,))
+    if _symmetric(frame_range):
+        u = 2 * u.astype(jnp.int32) - 255
+    return u.astype(jnp.bfloat16)
+
+
+def _one_pass(spec, lhs, rhs):
+    """bfloat16 x bfloat16 -> float32: one pass of the MXU whatever
+    `jax.default_matmul_precision` the caller traces under."""
+    return jnp.einsum(
+        spec, lhs, rhs, precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _sum_of_passes(spec, operand, cut):
+    """`operand` against each bfloat16 term of `cut`, the smallest
+    term's product first, summed in float32."""
+    passes = [_one_pass(spec, operand, term) for term in reversed(cut)]
+    return functools.reduce(jnp.add, passes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _integer_frames_times(frame, kernel, frame_range, terms):
+    """The integers of `frame` [T, B, ...] (`frame_integers`) times the
+    float32 `kernel` [F, d] -> [B, T, d] float32. The kernel is cut in
+    `terms` bfloat16 terms (ops/bf16_terms.py) and the frames are ONE,
+    exact: `terms` passes of the MXU, where a float32 operand that no
+    longer looks like an integer costs three under `high` and six under
+    `highest`.
+    The columns are contracted with batch and time BOTH FREE (the
+    uint8 frames read batch-first: a layout for the compiler to choose,
+    the cast to bfloat16 part of it), so no [T * B] merge fixes an
+    order and nothing is transposed after; on the v5e that beats either
+    merge at every cell's shapes (PERF.md section 6, PR 52: merged
+    time-major, the order the frames are held in, it is twice as slow
+    at 4,096 rows).
+
+    Differentiated as one unit: the kernel's cotangent is the same
+    integers against the result's cotangent cut in `terms` terms (by
+    autodiff the cotangent would go through dots at the caller's
+    precision, three passes under `high`), the frames get none, and the
+    rule's one residual is the uint8 frame, an input of the step."""
+    operand = frame_integers(frame.swapaxes(0, 1), frame_range)
+    return _sum_of_passes("btf,fd->btd", operand, bf16_terms(kernel, terms))
+
+
+def _integer_frames_times_fwd(frame, kernel, frame_range, terms):
+    return _integer_frames_times(frame, kernel, frame_range, terms), frame
+
+
+def _integer_frames_times_bwd(frame_range, terms, frame, dy):
+    # The same expression as the forward's, on the residual uint8 frame:
+    # XLA shares the forward's bfloat16 operand (2 bytes a frame's byte
+    # held through the step, where the float path held 4). Making it
+    # again behind an `optimization_barrier` was tried: 0.2 GiB lower at
+    # 4,096 rows and 1.3-2.4 ms a step slower in every cell (PERF.md
+    # section 6, PR 52).
+    operand = frame_integers(frame.swapaxes(0, 1), frame_range)
+    return None, _sum_of_passes("btf,btd->fd", operand, bf16_terms(dy, terms))
+
+
+_integer_frames_times.defvjp(
+    _integer_frames_times_fwd, _integer_frames_times_bwd
+)
+
+
+def frame_projection(frame, kernel, bias, frame_range, terms):
+    """`Dense_0` on uint8 frames as the chip computes it: frame [T, B,
+    ...] uint8, kernel [F, d], bias [d] -> [B, T, d] float32, equal to
+    `scaled(frame) @ kernel + bias` at `terms` bfloat16 terms of the
+    kernel (1 at JAX's default matmul precision, 2 under `high`, 3
+    under `highest`). The frame enters the product as the integers it
+    is; the scale and the shift of `frame_range` are linear, `(a u + b)
+    W = a (u W) + b colsum(W)`, and are applied to the [B, T, d]
+    result, in float32: no float32 copy of the frames is made."""
+    scale, shift = integers_to_range(frame_range)
+    kernel = kernel.astype(jnp.float32)
+    offset = bias.astype(jnp.float32)
+    if shift:
+        offset = offset + shift * jnp.sum(kernel, axis=0)
+    return scale * _integer_frames_times(
+        frame, kernel, frame_range, terms
+    ) + offset
 
 
 class Recurrent(NamedTuple):
@@ -310,8 +434,11 @@ class TransformerNet(nn.Module):
     # models/cores.RecurrentPolicyHead). Closes the "transformer
     # families stay bf16-trunk-only" gap PR 8 logged.
     head_dtype: Any = jnp.float32
-    # What the uint8 frame is scaled to before the projection (models/
-    # olmoe.py centres it).
+    # What the uint8 frame is scaled to for the projection (models/
+    # olmoe.py centres it). A float frame is scaled before `Dense_0`; a
+    # uint8 one on the chip goes in as its integers, and the range's
+    # scale and shift are applied to the [B, T, d] result
+    # (`frame_projection`).
     frame_range: Tuple[float, float] = (0.0, 1.0)
     # The projection of reward and last action starts at zero (models/
     # mellum2.py says why); a family's choice, not a flag.
@@ -323,25 +450,43 @@ class TransformerNet(nn.Module):
         T, B = frame.shape[:2]
 
         with device_scope("obs_embed"):
-            x = frame.reshape((T * B, -1)).astype(self.dtype) / 255.0
-            low, high = self.frame_range
-            if (low, high) != (0.0, 1.0):
-                x = low + (high - low) * x
-            x = nn.Dense(self.d_model, dtype=self.dtype)(x)
-            one_hot = jax.nn.one_hot(
-                inputs["last_action"].reshape(T * B), self.num_actions
-            )
-            reward = jnp.clip(
-                inputs["reward"].astype(jnp.float32), -1, 1
-            ).reshape(T * B, 1)
+            # Dense_0 [F, d]: the parent's module, so its parameters,
+            # their initialisers and their RNG path are what they were.
+            project = nn.Dense(self.d_model, dtype=self.dtype)
+            # A uint8 frame enters the projection as the integers it is
+            # (`frame_projection`), at the terms the traced precision
+            # states; half-width compute reads the kernel in one. Off
+            # the chip, for a float frame and at init (which makes the
+            # parameters), the float expression stands.
+            if (
+                frame.dtype == jnp.uint8
+                and jax.default_backend() == "tpu"
+                and not self.is_initializing()
+            ):
+                terms = (
+                    terms_traced_under() if self.dtype == jnp.float32 else 1
+                )
+                sow_stat(self, "obs_integer_applications", 1.0, "sum")
+                sow_stat(self, "obs_weight_terms", terms, "same")
+                weights = project.variables["params"]
+                x = frame_projection(
+                    frame, weights["kernel"], weights["bias"],
+                    self.frame_range, terms,
+                )
+            else:
+                x = scaled_frames(frame, self.frame_range, self.dtype)
+                x = project(x).astype(jnp.float32)
+                x = x.reshape(T, B, self.d_model).transpose(1, 0, 2)
+            # [B, T, d] from here on: the side inputs follow into it.
+            one_hot = jax.nn.one_hot(inputs["last_action"].T, self.num_actions)
+            reward = jnp.clip(inputs["reward"].astype(jnp.float32), -1, 1).T
             side_init = (
                 {"kernel_init": nn.initializers.zeros}
                 if self.zero_init_extras else {}
             )
-            x = x.astype(jnp.float32) + nn.Dense(
-                self.d_model, name="extras", **side_init
-            )(jnp.concatenate([reward, one_hot], axis=-1))
-            x = x.reshape(T, B, self.d_model).transpose(1, 0, 2)  # [B, T, d]
+            x = x + nn.Dense(self.d_model, name="extras", **side_init)(
+                jnp.concatenate([reward[..., None], one_hot], axis=-1)
+            )
 
         done = inputs["done"]  # [T, B]
         seg = segment_ids_from_done(done).T  # [B, T]

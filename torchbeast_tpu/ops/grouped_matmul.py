@@ -16,7 +16,7 @@ that read the FLOAT32 operands and cut every tile after it is loaded:
 one call a product, the operands read once a tile visit, the result
 written once, no term ever in HBM.
 
-**Same arithmetic.** The terms are `models/moe.py` `_bf16_terms`'s, the
+**Same arithmetic.** The terms are `ops/bf16_terms.py` `bf16_terms`'s, the
 products the same `terms (terms + 1) / 2`, the smallest added first,
 every sum float32. What differs is the order of summation: a tile's
 products are added to one another before they are added to the running

@@ -21,8 +21,11 @@ The compiled program is pinned by
 tests/test_parallel.py::test_data_parallel_update_divides_the_model and,
 for the described v5e:2x2, tests/test_chip_compile.py: all-reduces only,
 a chip's FLOPs those of the one-chip program at B / n. (The transformer
-families' frame projection still merges time-major, models/
-transformer.py `obs_embed`: no cell runs it across chips, PERF.md §7.)
+families' frame projection merges nothing on the chip: uint8 frames are
+contracted with batch and time both free, models/transformer.py
+`frame_projection`, so a sharded batch axis stays whole rows; the float
+expression that float frames and the CPU keep merges time-major. No
+cell runs either across chips, PERF.md §7.)
 
 Multi-host: call `initialize_distributed()` first (jax.distributed over
 DCN), then build the mesh over `jax.devices()` (global). Each host feeds
