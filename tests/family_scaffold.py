@@ -14,7 +14,7 @@ through `jax.jit`:
   gradient is `jax.jit(jax.grad(...))` around `module.apply` in the
   case itself. A plain `jnp` function under test is called through
   `jax.jit` too, once a shape.
-- For the six policy families: the toy batch (`inputs`,
+- For the seven policy families: the toy batch (`inputs`,
   `learner_batch`), `build(family, **overrides) -> (model, params)`,
   `expert_layer(family, held)`, `warm_state`, `reference_config`, and
   jitted callables for the four programs the cases run again and again
@@ -68,6 +68,7 @@ import numpy as np
 
 from perfbench.reference import (
     kanana2_policy,
+    lfm2_policy,
     mellum2_policy,
     nemotron3_policy,
     olmoe_policy,
@@ -77,12 +78,14 @@ from perfbench.reference import (
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import (
     Kanana2Net,
+    Lfm2Net,
     Mellum2Net,
     Nemotron3Net,
     OLMoENet,
     OuroNet,
     Qwen3NextNet,
     kanana2,
+    lfm2,
     mellum2,
     moe,
     nemotron3,
@@ -224,6 +227,28 @@ def _perturb_qwen3next(model, params):
     return {"params": inner}
 
 
+def _perturb_lfm2(model, params):
+    # And every norm's scale (a layer's two, q_norm and k_norm, the
+    # final one), which starts at one; a seed a leaf moved.
+    inner = _with_extras(params["params"])
+    seeds = iter(range(1, 1000))
+
+    def moved(norm):
+        scale = norm["scale"]
+        return {"scale": scale + _normal(next(seeds), scale.shape, 0.3)}
+
+    inner["final_norm"] = moved(inner["final_norm"])
+    for name in sorted(n for n in inner if n.startswith("block_")):
+        block = dict(inner[name])
+        if "moe" in block:
+            block = _with_selection_bias(block, next(seeds))
+        for leaf in sorted(block):
+            if leaf.endswith("norm"):
+                block[leaf] = moved(block[leaf])
+        inner[name] = block
+    return {"params": inner}
+
+
 def _config_olmoe(model):
     return {
         "num_attention_heads": model.num_heads,
@@ -343,6 +368,30 @@ def _config_qwen3next(model):
         "norm_topk_prob": True, "hidden_act": "silu",
         "decoder_sparse_step": 1, "mlp_only_layers": [],
         "rms_norm_eps": 1e-6, "router_aux_loss_coef": 0.001,
+    }
+
+
+def _config_lfm2(model):
+    held = model.held_experts()
+    kinds = model.layers()
+    return {
+        # The layers as the toy runs them: its cut IS its model.
+        "layer_types": [kind for kind, _ in kinds],
+        "num_dense_layers": sum(dense for _, dense in kinds),
+        "layers_run": list(range(len(kinds))),
+        "num_hidden_layers": model.num_layers,
+        "conv_L_cache": model.conv_kernel, "conv_bias": model.conv_bias,
+        "num_attention_heads": model.num_heads,
+        "num_key_value_heads": model.kv_heads,
+        "rope_theta": model.rope_theta, "norm_eps": model.norm_eps,
+        "published_num_experts": model.num_experts,
+        "num_experts": held[1] if held else model.num_experts,
+        "expert_share": list(model.expert_share),
+        "num_experts_per_tok": model.experts_per_token,
+        "norm_topk_prob": model.renormalise,
+        "use_expert_bias": model.use_expert_bias,
+        "routed_scaling_factor": model.routed_scaling,
+        "bias_update_rate": model.bias_update_rate,
     }
 
 
@@ -575,6 +624,38 @@ FAMILIES = {
                 _SWIGLU_EXPERTS + _SWIGLU_SHARED + ("shared_expert_gate",)
             )),
             tol=2e-5, shared=_token_gated_shared,
+        ),
+    ),
+    # The leading dense layer (a gated short convolution of 3 taps over a
+    # SwiGLU of 48), then one period cut to `A c`: 4 query heads of 8 on
+    # 2 key/value heads over a cache of 5 slots, a conv layer; 16
+    # experts of 10, top 3, chosen under a bias.
+    "lfm2": Family(
+        Lfm2Net, lfm2, lfm2_policy,
+        dict(
+            d_model=32, num_heads=4, kv_heads=2, head_dim=8, dense_width=48,
+            expert_width=10, num_experts=16, experts_per_token=3,
+            layer_period=("full_attention", "conv"), num_layers=3,
+            memory_len=5,
+        ),
+        _config_lfm2, _perturb_lfm2,
+        # The shares: 32 experts, top 4, the published counts; held (0, 8),
+        # (8, 8), (16, 8), (24, 8): a quarter each, no shared expert.
+        experts=Experts(
+            dict(
+                d_ff=8, num_experts=16, top_k=3, aux_loss_weight=0.0,
+                renormalise=True, gate_sum_floor=1e-6, scoring="sigmoid",
+                selection_bias=True, bias_update_rate=0.001,
+            ),
+            shares=4,
+            config=_experts_config(
+                "published_num_experts", "num_experts",
+                use_expert_bias=True, routed_scaling_factor=1.0,
+            ),
+            leaves=tuple(sorted(
+                _SWIGLU_EXPERTS + ("e_score_correction_bias",)
+            )),
+            tol=1e-5, uncut=dict(num_experts=32, top_k=4),
         ),
     ),
 }

@@ -812,18 +812,23 @@ def test_fused_attend_under_remat():
         ((32, 81, 16, 128), 336, False, False),
         ((64, 1, 32, 128), 4096, False, False),
         ((2, 16, 4, 8), 21, False, False),
-        ((32, 81, 64, 64), 4176, False, False),
+        ((32, 81, 64, 64), 4176, False, True),
+        ((32, 81, 64, 96), 4176, False, False),
+        ((32, 81, 64, 32), 4176, False, False),
     ],
     ids=[
         "mellum2-full", "mellum2-sliding", "a-learned-bias", "olmoe-sized",
-        "ouro-sized", "acting", "tier-1-toy", "heads-of-64",
+        "ouro-sized", "acting", "tier-1-toy", "heads-of-64", "heads-of-96",
+        "heads-of-32",
     ],
 )
 def test_fused_pass_applies_by_shapes_and_bias_alone(
     q_shape, keys, bias, fused
 ):
     """The rule: 128 MiB of f32 scores or more, heads that fill the 128
-    lanes and no learned bias. The Mellum2 cell's two kinds of layer
+    lanes or are half of them (64, since PR 53: `fused_attend` pads such
+    a head with zero columns; no other width under a tile) and no
+    learned bias. The Mellum2 cell's two kinds of layer
     are above it (1,385 and 366 MB); OLMoE- and Ouro-sized problems,
     acting at T=1 against a full cache and everything tier-1 builds are
     below it."""

@@ -1,0 +1,167 @@
+"""Compile for the chip, without the chip (tests/chip_fixtures.py):
+`lfm2_policy.learner`'s whole update, one AOT compile of the real cell,
+and the fused attention pass alone at heads of 64. A file of its own:
+tests/chip_fixtures.py says why.
+"""
+
+import json
+import os
+import re
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from tests.chip_fixtures import (  # noqa: F401 (fixtures)
+    NUM_ACTIONS,
+    on as _on,
+    one_chip,
+    struct as _struct,
+    topo,
+)
+from torchbeast_tpu import learner as learner_lib
+from torchbeast_tpu.ops import attention, fused_attention
+
+
+def test_fused_pass_lowers_at_heads_of_64_for_v5e(one_chip, monkeypatch):
+    """The check interpret mode cannot make: the fused pass, forward
+    and backward, at the cell's attention shapes (16 rows x 256 steps,
+    32 query heads on 8 key/value heads of 64, 4,095 + 256 keys, the
+    cache taking no gradient) compiles for the chip's compiler at both
+    precisions, a head padded to the 128 lanes with zero columns: the
+    kernels' key tiles are [block, 128] and the result is 64 wide."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, steps, M, H, Hkv, hd = 16, 256, 4095, 32, 8, 64
+    assert attention.fused_pass_applies(
+        (rows, steps, H, hd), (rows, M + steps, Hkv, hd), None
+    )
+    # A T=1 act step (34 MB of scores) and a head of 32 or 96 are not it.
+    assert not attention.fused_pass_applies(
+        (rows, 1, H, hd), (rows, M + 1, Hkv, hd), None
+    )
+    for narrow in (32, 96):
+        assert not attention.fused_pass_applies(
+            (rows, steps, H, narrow), (rows, M + steps, Hkv, narrow), None
+        )
+
+    def loss(q, k_all, v_all, mask, precise):
+        return jnp.sum(fused_attention.fused_attend(
+            q, k_all, v_all, mask, M, precise=precise
+        ) ** 2)
+
+    traced = jax.jit(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)), static_argnums=4
+    )
+    for precise in (True, False):
+        text = traced.lower(
+            _struct(one_chip, (rows, steps, H, hd)),
+            _struct(one_chip, (rows, M + steps, Hkv, hd)),
+            _struct(one_chip, (rows, M + steps, Hkv, hd)),
+            _struct(one_chip, (rows, steps, M + steps), jnp.bool_),
+            precise,
+        ).compile().as_text()
+        assert text.count("fused_attend_forward") >= 1
+        assert text.count("fused_attend_backward") >= 1
+        # No f32 array over the keys a query row: the scores stay in VMEM.
+        shapes = {
+            tuple(int(d) for d in dims.split(","))
+            for dims in re.findall(r"f32\[([0-9,]+)\]", text)
+        }
+        assert not {
+            s for s in shapes
+            if len(s) >= 3 and s[-1] in (4095, 4351, 4352) and s[-2] >= steps
+        }
+
+
+def test_lfm2_cell_update_compiles_for_v5e(one_chip, monkeypatch):
+    """`lfm2_policy.learner`'s update as the benchmark builds it (the
+    configuration's own argv and sizes: the dense layer and one period
+    `A c c c`, experts 0/4, blocks rematerialised, a [256, B] batch),
+    whole, for a described v5e: it fits beside the driver's copy of the
+    weights (under the rule's 15.0 GiB with it) and fills the chip; the
+    attention layer's scores over 4,351 keys of heads of 64 live in
+    `fused_attend`'s VMEM (no f32 array over the keys is in the
+    program); the four conv layers' tails [2, B, 2048] are its
+    arguments; the frames enter `Dense_0` as bfloat16 integers (PR 52);
+    the experts' products are one kernel call each at the family's two
+    terms a side (PR 50)."""
+    from perfbench import manifest
+    from perfbench.drivers import learner as learner_driver
+    from torchbeast_tpu import monobeast
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(
+        manifest.HERE, "configs", "lfm2_8b_policy.json"
+    )) as f:
+        config = json.load(f)
+    steps, rows = config["unroll_length"], config["batch_size"]
+    flags = monobeast.make_parser().parse_args(
+        config["program_argv"]
+        + ["--unroll_length", str(steps), "--batch_size", str(rows)]
+    )
+    hp = monobeast.hparams_from_flags(flags)
+    frame = tuple(config["frame_shape"])
+    model, _ = monobeast._init_model_and_params(
+        flags, NUM_ACTIONS, rows, frame, init_params=False
+    )
+    optimizer = learner_lib.make_optimizer(hp)
+    params = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
+        monobeast.dummy_env_outputs(1, rows, frame, np.uint8),
+        model.initial_state(rows),
+    ))
+    batch, state = jax.eval_shape(lambda: (
+        learner_driver._make_batch(
+            jax.random.PRNGKey(0), steps + 1, rows, NUM_ACTIONS, frame
+        ),
+        model.initial_state(rows),
+    ))
+    compiled = learner_lib.make_update_step(model, optimizer, hp).lower(
+        _on(one_chip, params),
+        _on(one_chip, jax.eval_shape(optimizer.init, params)),
+        _on(one_chip, batch), _on(one_chip, state),
+    ).compile()
+    memory = compiled.memory_analysis()
+    total = (
+        memory.temp_size_in_bytes + memory.argument_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    weights = 4 * sum(
+        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)
+    )
+    assert weights == 4 * config["param_count"] == 4 * 532_101_383
+    print("memory", memory, "total GiB", total / 2**30,
+          "with the copy", (total + weights) / 2**30)
+    assert total + weights < 15.0 * 2**30, memory
+    assert total > 4 * 2**30, memory  # the cell fills the chip
+    text = compiled.as_text()
+    shapes = {
+        tuple(int(d) for d in dims.split(","))
+        for dims in re.findall(r"f32\[([0-9,]+)\]", text)
+    }
+    scores = {
+        s for s in shapes if len(s) >= 3 and s[-1] in (4095, 4351, 4352)
+    }
+    assert not scores, scores
+    assert text.count("fused_attend_forward") >= 2  # and rematerialised
+    assert text.count("fused_attend_backward") >= 1
+    # No float32 copy of the batch's frames anywhere in the program.
+    frames = (steps + 1) * rows * int(np.prod(frame))
+    assert not {s for s in shapes if int(np.prod(s)) >= frames}
+    # The carried tails are the program's arguments.
+    assert (2, rows, 2048) in shapes
+    # Four MoE layers x (3 forward, 3 the rematerialised forward, 6
+    # backward) grouped products, one kernel call each; beside them the
+    # attention layer's three.
+    assert len(re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
+    )) == 48
+    assert text.count("tpu_custom_call") == 48 + 3
+    # The family's scopes reach the compiled program.
+    for scope in (
+        "conv_operator/conv_in_proj", "conv_operator/conv_gate_taps",
+        "conv_operator/conv_out_proj", "/attention/", "dense_mlp",
+        "moe_route", "moe_experts",
+    ):
+        assert scope in text, scope

@@ -198,6 +198,52 @@ def _published_qwen3next(model):
     )
 
 
+def _published_lfm2(model):
+    assert model.zero_init_extras
+    assert (model.d_model, model.num_heads, model.kv_heads, model.head_dim) == (
+        2048, 32, 8, 64
+    )
+    assert model.num_heads * model.head_dim == model.d_model
+    assert (model.conv_kernel, model.conv_bias) == (3, False)
+    assert (
+        model.dense_width, model.expert_width, model.num_experts,
+        model.experts_per_token, model.num_dense_layers,
+    ) == (7168, 1792, 32, 4, 2)
+    assert model.renormalise and model.use_expert_bias
+    assert (model.routed_scaling, model.gate_sum_floor) == (1.0, 1e-6)
+    assert (model.norm_eps, model.rope_theta) == (1e-5, 1e6)
+    assert (model.memory_len, model.bias_update_rate) == (4095, 0.001)
+    assert model.matmul_precision == "high"
+    assert model.held_experts() == (0, 8)
+    # The cut: published layer 1 (a conv operator over the dense SwiGLU),
+    # then one period `A c c c`: four two-step tails and one window.
+    tail = Recurrent(((2, 2048),))
+    assert model.layers() == (
+        ("conv", True), ("full_attention", False),
+    ) + (("conv", False),) * 3
+    assert model.layer_caches() == (tail, (4095, 8, 64), tail, tail, tail)
+    state = jax.eval_shape(lambda: model.initial_state(16))
+    assert [leaf.shape for leaf in state[0]] == [(2, 16, 2048)]
+    # 16 KB a row and conv layer.
+    assert 4 * 2 * 2048 == 16_384
+    whole = create_model("lfm2", num_actions=6)
+    assert "".join(
+        "A" if kind == "full_attention" else "c" for kind, _ in whole.layers()
+    ) == "ccAcccAcccAcccAcccAccAcc"
+    assert [dense for _, dense in whole.layers()] == [True] * 2 + [False] * 22
+    assert whole.layers()[1:6] == model.layers()
+    assert whole.held_experts() is None
+    # The cell's attention layer (4 query heads of 64 a key/value head
+    # over 4,095 + 256 keys, 2.28 GB of f32 scores at B=16) is
+    # `fused_attend`'s at HALF a lane tile a head; a T=1 act step is not.
+    assert attention.fused_pass_applies(
+        (16, 256, 32, 64), (16, 4351, 8, 64), None
+    )
+    assert not attention.fused_pass_applies(
+        (16, 1, 32, 64), (16, 4096, 8, 64), None
+    )
+
+
 # family: how the cell builds it, the depth of the published model, its
 # own assertions, and what the registry refuses beside `use_lstm`.
 REGISTRY = {
@@ -227,6 +273,14 @@ REGISTRY = {
             _refused("whole periods of 4", num_layers=6),
             *(_refused("expert_share", expert_share=bad)
               for bad in [(16, 16), (0, 3), (-1, 16)]),
+        ],
+    ),
+    "lfm2": (
+        dict(num_layers=5, expert_share=(0, 4)), 24, _published_lfm2, [
+            *(_refused(r"1 \+ 4k layers, or is all 24", num_layers=bad)
+              for bad in [4, 1, 8]),
+            *(_refused("expert_share", expert_share=bad)
+              for bad in [(4, 4), (0, 3), (-1, 4)]),
         ],
     ),
 }
@@ -285,7 +339,8 @@ def _flags_mellum2(parse, build):
     for other in ("deep", "transformer", "olmoe"):
         with pytest.raises(
             ValueError,
-            match="--model mellum2 or kanana2 or nemotron3 or qwen3next only",
+            match="--model mellum2 or kanana2 or nemotron3 or qwen3next or "
+                  "lfm2 only",
         ):
             build(parse(["--model", other, "--expert_share", "0/4"]))
     return model, ["--model", "mellum2", "--num_layers", "4"]
@@ -374,10 +429,36 @@ def _flags_qwen3next(parse, build):
     return model, ["--model", "qwen3next", "--num_layers", "2"]
 
 
+def _flags_lfm2(parse, build):
+    flags = parse([
+        "--model", "lfm2", "--num_layers", "5", "--memory_len", "9",
+        "--expert_share", "1/4",
+    ])
+    assert (flags.model, flags.num_layers, flags.expert_share) == (
+        "lfm2", 5, "1/4"
+    )
+    model = build(flags)
+    assert (model.num_layers, model.memory_len, model.d_model) == (5, 9, 32)
+    assert model.held_experts() == (4, 4)
+    # The (shrunken) table's period of two: the dense conv layer, `A c`
+    # twice.
+    assert [type(e) is tuple for e in model.layer_caches()] == [
+        False, True, False, True, False,
+    ]
+    with pytest.raises(ValueError, match=r"1 \+ 2k layers, or is all 24"):
+        build(parse(["--model", "lfm2", "--num_layers", "4"]))
+    # The operators are whole on every chip: no share of them to take.
+    with pytest.raises(ValueError, match="mixer_share .* nemotron3 only"):
+        build(parse(["--model", "lfm2", "--mixer_share", "0/2"]))
+    with pytest.raises(ValueError, match="use_lstm"):
+        build(parse(["--model", "lfm2", "--use_lstm"]))
+    return model, ["--model", "lfm2", "--num_layers", "3"]
+
+
 FLAGS = {
     "olmoe": _flags_olmoe, "mellum2": _flags_mellum2, "ouro": _flags_ouro,
     "kanana2": _flags_kanana2, "nemotron3": _flags_nemotron3,
-    "qwen3next": _flags_qwen3next,
+    "qwen3next": _flags_qwen3next, "lfm2": _flags_lfm2,
 }
 
 
@@ -553,12 +634,21 @@ STATS_AT_PR_44 = {
         "moe_held_load_max_over_mean", "moe_load_max_over_mean",
         "moe_shared_applications",
     ],
+    # The family of PR 53, as it came (four of sixteen held under three
+    # chosen: no window).
+    "lfm2": [
+        "conv_layers", "conv_resets_per_row", "conv_state_bytes_per_row",
+        "moe_assignments", "moe_bias_abs_max", "moe_bias_steps",
+        "moe_held_assignments", "moe_held_load_max_over_mean",
+        "moe_load_max_over_mean",
+    ],
 }
 _HELD = {
     "mellum2": dict(expert_share=(1, 4)),
     "kanana2": dict(expert_share=(0, 8)),
     "nemotron3": dict(expert_share=(1, 8), mixer_share=(1, 2)),
     "qwen3next": dict(expert_share=(1, 4)),
+    "lfm2": dict(expert_share=(1, 4)),
 }
 
 

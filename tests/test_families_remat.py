@@ -41,6 +41,12 @@ REMAT = {
         ["moe_held_assignments", "delta_applications", "delta_chunks",
          "delta_resets_per_row", "attention_gated_applications"], [],
     ),
+    "lfm2": (
+        dict(expert_share=(1, 4)), [(2, 0), (3, 0), (0, 1), (4, 1)], 1e-5,
+        (0, 2e-5),
+        ["moe_held_assignments", "conv_layers", "conv_resets_per_row",
+         "conv_state_bytes_per_row"], [],
+    ),
 }
 
 

@@ -167,6 +167,43 @@ def test_the_solves_own_backward_is_under_its_calls_scopes(qwen3next_op_names):
     assert all(_in_scope(n, "delta_intra") for n in backward)
 
 
+# --- a family's own scopes (PR 53: `--model lfm2`) ---------------------------
+
+LFM2_SCOPES = (
+    "conv_operator", "conv_in_proj", "conv_gate_taps", "conv_out_proj",
+    "attention", "dense_mlp", "moe_route", "moe_dispatch", "moe_experts",
+    "moe_combine",
+)
+
+
+@pytest.fixture(scope="module")
+def lfm2_op_names():
+    """The toy family's whole update, compiled (tests/family_scaffold.
+    py): what a device trace of the cell is split by."""
+    model, params = scaffold.build("lfm2")
+    t = scaffold.FAMILIES["lfm2"].t
+    batch = scaffold.learner_batch(1, [(2, 0), (4, 1)], t=t)
+    hp = learner_lib.HParams(batch_size=scaffold.B, unroll_length=t - 1)
+    optimizer = optax.sgd(0.1)
+    compiled = learner_lib.make_update_step(
+        model, optimizer, hp, donate=False
+    ).lower(
+        params, optimizer.init(params), batch,
+        model.initial_state(scaffold.B),
+    ).compile()
+    return re.findall(r'op_name="([^"]+)"', compiled.as_text())
+
+
+@pytest.mark.parametrize("scope", LFM2_SCOPES)
+def test_lfm2_scope_reaches_the_compiled_hlo(lfm2_op_names, scope):
+    inside = [n for n in lfm2_op_names if _in_scope(n, scope)]
+    assert inside, f"no compiled op carries the scope {scope!r}"
+    if scope in ("conv_in_proj", "conv_gate_taps", "conv_out_proj"):
+        assert all(_in_scope(n, "conv_operator") for n in inside)
+    # The account knows the names from the lines that enter them.
+    assert scope in device_scopes.known_device_scopes()
+
+
 def _kernel_calls(jaxpr, under=""):
     """(name stack, kernel name) of every Pallas kernel call in a
     jaxpr, calls inside calls too: what the compiled module's

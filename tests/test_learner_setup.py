@@ -169,13 +169,13 @@ FAMILY_FIELD_CASES = {
         "3", 3, "olmoe", "pipelined_transformer",
         "--num_layers is a positive depth or window of --model "
         "transformer or olmoe or mellum2 or ouro or kanana2 or nemotron3 "
-        "or qwen3next",
+        "or qwen3next or lfm2",
     ),
     "memory_len": (
         "9", 9, "transformer", "deep",
         "--memory_len is a positive depth or window of --model "
         "transformer or olmoe or mellum2 or ouro or kanana2 or nemotron3 "
-        "or qwen3next",
+        "or qwen3next or lfm2",
     ),
     "num_experts": (
         "4", 4, "transformer", "olmoe",
@@ -186,7 +186,7 @@ FAMILY_FIELD_CASES = {
         "1/4", (1, 4), "mellum2", "olmoe",
         "--expert_share i/n (share i of the n chips that divide each "
         "layer's experts) applies to --model mellum2 or kanana2 or "
-        "nemotron3 or qwen3next only",
+        "nemotron3 or qwen3next or lfm2 only",
     ),
     "mixer_share": (
         "1/2", (1, 2), "nemotron3", "kanana2",
@@ -232,7 +232,7 @@ def test_family_field_flag_follows_the_class(flag, monkeypatch):
 
 
 @pytest.mark.parametrize(
-    "family", ["mellum2", "kanana2", "nemotron3", "qwen3next"]
+    "family", ["mellum2", "kanana2", "nemotron3", "qwen3next", "lfm2"]
 )
 def test_expert_share_reaches_every_family_that_declares_it(family):
     """`--expert_share` is no family's by name: a class that declares
@@ -240,13 +240,14 @@ def test_expert_share_reaches_every_family_that_declares_it(family):
     text lists the takers from the registry. PR 38 added a second taker
     and edited neither `_FAMILY_FIELD_REFUSALS` nor the check; PR 42 a
     third, and its sibling `--mixer_share` with a refusal of its own; PR
-    46 a fourth."""
+    46 a fourth; PR 53 a fifth."""
     assert models.families_taking("expert_share") == [
-        "mellum2", "kanana2", "nemotron3", "qwen3next"
+        "mellum2", "kanana2", "nemotron3", "qwen3next", "lfm2"
     ]
     assert models.families_taking("mixer_share") == ["nemotron3"]
     layers = {
         "mellum2": "4", "kanana2": "2", "nemotron3": "11", "qwen3next": "4",
+        "lfm2": "5",
     }[family]
     model, _ = learner_setup.init_model_and_params(
         monobeast.make_parser().parse_args([
@@ -278,6 +279,7 @@ def test_refusals_are_stated_on_the_class():
         "kanana2": ("num_experts", "attention_impl"),
         "nemotron3": ("num_experts", "attention_impl"),
         "qwen3next": ("num_experts", "attention_impl"),
+        "lfm2": ("num_experts", "attention_impl"),
     }
     kv_cache = [
         name for name in models.MODEL_NAMES
@@ -285,12 +287,13 @@ def test_refusals_are_stated_on_the_class():
     ]
     assert kv_cache == [
         "transformer", "pipelined_transformer", "olmoe", "mellum2", "ouro",
-        "kanana2", "nemotron3", "qwen3next",
+        "kanana2", "nemotron3", "qwen3next", "lfm2",
     ]
     for name in models.MODEL_NAMES:
         # test_families has the published families'
         if name in kv_cache and name not in (
-            "olmoe", "mellum2", "ouro", "kanana2", "nemotron3", "qwen3next"
+            "olmoe", "mellum2", "ouro", "kanana2", "nemotron3", "qwen3next",
+            "lfm2",
         ):
             with pytest.raises(ValueError, match="KV cache"):
                 models.create_model(name, num_actions=A, use_lstm=True)

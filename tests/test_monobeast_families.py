@@ -61,6 +61,17 @@ def _qwen3next_through_main(stats):
     assert stats["aux_loss"] >= 0.001 * 2 * 0.99  # two layers' balance
 
 
+def _lfm2_through_main(stats):
+    assert stats["conv_layers"] == 2
+    # Two conv layers' tails of 2 products over 32 channels, f32.
+    assert stats["conv_state_bytes_per_row"] == 2 * 4 * 2 * 32
+    assert stats["conv_resets_per_row"] >= 0
+    assert stats["moe_bias_steps"] == 2
+    assert 0 <= stats["moe_held_assignments"] <= stats["moe_assignments"]
+    assert stats["moe_bias_abs_max"] >= 0.001 - 1e-9
+    assert stats["aux_loss"] == 0.0
+
+
 def _ouro_through_main(stats):
     assert stats["loop_passes"] == 3
     assert stats["loop_block_applications"] == 6
@@ -90,6 +101,10 @@ _MELLUM2_WIDTHS = dict(
 #   quarter of the experts: acting through the MATRIX state with its
 #   conv tail AND the rolling cache of un-rotated keys, unrolls of 5
 #   scanned in chunks of 4.
+#  lfm2: the dense conv layer and a period cut to `A c`, a quarter of
+#   the experts: acting through the two-step tails (entries of ONE
+#   leaf) AND the rolling cache of un-rotated keys, the biases moved by
+#   the load after every optimizer step.
 #  ouro: 2 layers run 3 times, through 3 x 2 rolling caches.
 THROUGH_MAIN = {
     "mellum2-all-experts": (
@@ -133,6 +148,16 @@ THROUGH_MAIN = {
         ),
         dict(num_layers=2, expert_share="1/4", remat="all"),
         _qwen3next_through_main,
+    ),
+    "lfm2": (
+        "lfm2",
+        dict(
+            d_model=32, num_heads=4, kv_heads=2, head_dim=8, dense_width=48,
+            expert_width=10, num_experts=8, experts_per_token=2,
+            layer_period=("full_attention", "conv"),
+        ),
+        dict(num_layers=3, expert_share="1/4", remat="all"),
+        _lfm2_through_main,
     ),
     "ouro": (
         "ouro",

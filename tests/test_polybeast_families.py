@@ -33,6 +33,12 @@ def _qwen3next_trained(stats):
     assert stats["moe_shared_applications"] == 2
 
 
+def _lfm2_trained(stats):
+    assert stats["conv_layers"] == 2
+    assert stats["moe_bias_steps"] == 2
+    assert stats["moe_bias_abs_max"] >= 0.001 - 1e-9
+
+
 # A family a row (a `model_config` PR adds one: tests/family_scaffold.py):
 # what its `PUBLISHED` table is shrunk to, its depth, and what the last
 # update's stats must say.
@@ -45,6 +51,8 @@ def _qwen3next_trained(stats):
 #   none), the learner's updates scan in chunks.
 #  qwen3next: the slots hold a matrix state with its conv tail beside
 #   the attention layer's window; the delta rule runs in chunks.
+#  lfm2: the slots hold two conv layers' two-step tails (entries of one
+#   leaf) beside the attention layer's window.
 FAMILIES = {
     "ouro": (
         dict(d_model=32, num_heads=4, head_dim=8, mlp_width=48, passes=3),
@@ -75,6 +83,14 @@ FAMILIES = {
             experts_per_token=2, expert_width=10, shared_width=12,
         ),
         2, _qwen3next_trained,
+    ),
+    "lfm2": (
+        dict(
+            d_model=32, num_heads=4, kv_heads=2, head_dim=8, dense_width=48,
+            expert_width=10, num_experts=8, experts_per_token=2,
+            layer_period=("full_attention", "conv"),
+        ),
+        3, _lfm2_trained,
     ),
 }
 

@@ -181,24 +181,28 @@ def add_learner_arguments(parser, *, model_default,
                              "divide into M microbatches.")
     parser.add_argument("--num_layers", type=int, default=0,
                         help="Depth of --model transformer, olmoe, "
-                             "mellum2, ouro, kanana2, nemotron3 or "
-                             "qwen3next (0: "
+                             "mellum2, ouro, kanana2, nemotron3, "
+                             "qwen3next or lfm2 (0: "
                              "the family's own, 2 and the published 16, "
-                             "28, 48, 48, 88 and 48; mellum2 in whole "
+                             "28, 48, 48, 88, 48 and 24; mellum2 in whole "
                              "periods of 4; ouro runs the layers it has "
                              "4 times a step; kanana2: its leading "
                              "dense layer and the MoE layers after it, "
                              "2 or more; nemotron3 in whole periods of "
                              "11, *EMEMEMEMEM; qwen3next in whole "
                              "periods of 4, three Gated DeltaNet layers "
-                             "and one gated attention layer).")
+                             "and one gated attention layer; lfm2 as 1 + "
+                             "4k: its last leading dense layer, then "
+                             "whole periods of one attention layer and "
+                             "three gated short convolutions).")
     parser.add_argument("--memory_len", type=int, default=0,
                         help="Steps of its own past a transformer, "
-                             "olmoe, mellum2, ouro, kanana2, nemotron3 "
-                             "or qwen3next "
+                             "olmoe, mellum2, ouro, kanana2, nemotron3, "
+                             "qwen3next or lfm2 "
                              "policy attends over, carried as the "
                              "rolling KV cache (0: the family's own, 64, "
-                             "128, 4095, 255, 4095, 4095 and 4095; mellum2: "
+                             "128, 4095, 255, 4095, 4095, 4095 and 4095; "
+                             "mellum2: "
                              "its full layers' cache, the sliding "
                              "layers carry min(memory_len, 1023); ouro: "
                              "every one of its 4 x num_layers caches; "
@@ -206,12 +210,15 @@ def add_learner_arguments(parser, *, model_default,
                              "nemotron3: its attention layers', the "
                              "Mamba-2 layers carry a state instead; "
                              "qwen3next: its attention layers', the "
-                             "DeltaNet layers carry a matrix state).")
+                             "DeltaNet layers carry a matrix state; "
+                             "lfm2: its attention layers', the conv "
+                             "layers carry two values).")
     parser.add_argument("--expert_share", default="",
-                        help="--model mellum2, kanana2, nemotron3 or "
-                             "qwen3next: "
+                        help="--model mellum2, kanana2, nemotron3, "
+                             "qwen3next or lfm2: "
                              "'i/n' holds share i of the n chips that "
-                             "divide each layer's 64 (128, 512, 512 routed) "
+                             "divide each layer's 64 (128, 512, 512, 32 "
+                             "routed) "
                              "experts (0/4: experts 0..15). The layer "
                              "routes over all of them and adds its own "
                              "experts' part of the sum (kanana2, "
@@ -309,7 +316,7 @@ def add_learner_arguments(parser, *, model_default,
                              "whose class has the `blocks` lever "
                              "(transformer, pipelined_transformer, "
                              "mellum2, ouro, kanana2, nemotron3, "
-                             "qwen3next; not olmoe), the "
+                             "qwen3next, lfm2; not olmoe), the "
                              "LSTM scan): 'auto' picks the "
                              "minimum-recompute plan whose XLA-measured "
                              "peak fits --hbm_budget_gb; 'all'/'none' "

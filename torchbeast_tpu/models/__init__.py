@@ -12,6 +12,7 @@ from torchbeast_tpu.models.cores import LSTMCore  # noqa: F401
 from torchbeast_tpu.models.mlp import MLPNet  # noqa: F401
 from torchbeast_tpu.models import (
     kanana2,
+    lfm2,
     mellum2,
     nemotron3,
     olmoe,
@@ -19,6 +20,7 @@ from torchbeast_tpu.models import (
     qwen3next,
 )
 from torchbeast_tpu.models.kanana2 import Kanana2Net  # noqa: F401
+from torchbeast_tpu.models.lfm2 import Lfm2Net  # noqa: F401
 from torchbeast_tpu.models.mellum2 import Mellum2Net  # noqa: F401
 from torchbeast_tpu.models.nemotron3 import Nemotron3Net  # noqa: F401
 from torchbeast_tpu.models.olmoe import OLMoENet  # noqa: F401
@@ -46,13 +48,14 @@ _REGISTRY = {
     "kanana2": Kanana2Net,
     "nemotron3": Nemotron3Net,
     "qwen3next": Qwen3NextNet,
+    "lfm2": Lfm2Net,
 }
 # A family whose widths are a published table (its module's `PUBLISHED`,
 # keyed by the class's fields): read when the model is built, so that a
 # test shrinks the family there.
 _PUBLISHED_TABLES = {
     OLMoENet: olmoe, Mellum2Net: mellum2, OuroNet: ouro, Kanana2Net: kanana2,
-    Nemotron3Net: nemotron3, Qwen3NextNet: qwen3next,
+    Nemotron3Net: nemotron3, Qwen3NextNet: qwen3next, Lfm2Net: lfm2,
 }
 MODEL_NAMES = tuple(_REGISTRY)
 

@@ -686,7 +686,8 @@ class DroplessMoE(nn.Module):
 
     The router as the fields say. `scoring` "softmax" (OLMoE's and
     Mellum2's layers): the gates are the selected probabilities as they
-    are, or over their sum (`renormalise`, a config's `norm_topk_prob`),
+    are, or over their sum (`renormalise`, a config's `norm_topk_prob`;
+    over their sum + `gate_sum_floor` where a family states one),
     and the load-balance term is sown. `scoring` "sigmoid" (DeepSeek-V3's
     router, models/kanana2.py): each expert's score is a sigmoid of its
     own logit; with `selection_bias` the k experts are chosen by score +
@@ -721,6 +722,11 @@ class DroplessMoE(nn.Module):
     aux_loss_weight: float = 1e-2  # 0: no load-balance term is sown
     dtype: Any = jnp.float32
     renormalise: bool = False
+    # What the chosen scores' sum is raised by before the gates are
+    # divided by it, where a family's reference implementation states
+    # one (models/lfm2.py: 1e-6). None: nothing under softmax, 1e-20
+    # under sigmoid.
+    gate_sum_floor: Optional[float] = None
     held: Optional[Tuple[int, int]] = None
     scoring: str = "softmax"  # or "sigmoid"
     selection_bias: bool = False
@@ -773,8 +779,11 @@ class DroplessMoE(nn.Module):
                 gate, idx = jax.lax.top_k(probs, K)  # [t, K]
             if self.renormalise:
                 total = jnp.sum(gate, axis=-1, keepdims=True)
-                if self.scoring == "sigmoid":
-                    total = total + 1e-20  # scores may all be zero
+                floor = self.gate_sum_floor
+                if floor is None and self.scoring == "sigmoid":
+                    floor = 1e-20  # scores may all be zero
+                if floor:
+                    total = total + floor
                 gate = gate / total
             if self.routed_scaling != 1.0:
                 gate = gate * self.routed_scaling

@@ -949,7 +949,9 @@ def fused_pass_applies(q_shape, k_shape, rel_bias) -> bool:
     """Whether `dense_transformer_attend` takes the fused pass for q
     [B, T, H, D] and k_all [B, K, Hkv, D]: no learned bias, a head size
     that fills the 128 lanes (the kernels read a head's keys as a
-    column block of [K, B * Hkv * D]), and f32 scores of `FUSED_SCORE_
+    column block of [K, B * Hkv * D]) or is half of them (models/
+    lfm2.py: 64, which `fused_attend` pads with zero columns), and f32
+    scores of `FUSED_SCORE_
     BYTES` (128 MiB) or more. A function of the shapes and of `rel_bias
     is None` alone: the body asks it, and a block asks it to count what
     it compiled in (`attention_fused_applications`, models/
@@ -973,7 +975,7 @@ def fused_pass_applies(q_shape, k_shape, rel_bias) -> bool:
     B, T, H, D = q_shape
     return (
         rel_bias is None
-        and D % 128 == 0
+        and (D % 128 == 0 or D == 64)
         and B * H * T * k_shape[1] * 4 >= FUSED_SCORE_BYTES
     )
 

@@ -303,9 +303,11 @@ def _at_one_pass(kernel_call):
     return call
 
 
-def _forward_call(q, k, v, mask, groups, interpret, precise=False):
+def _forward_call(q, k, v, mask, groups, interpret, precise, scale):
     """q [B, Hkv, G * Tp, D]; k, v [K, B * Hkv * D]; mask [B, Tp, Kp]
-    int8 -> (out f32 like q, lse f32 [B, Hkv, G * Tp, 128])."""
+    int8 -> (out f32 like q, lse f32 [B, Hkv, G * Tp, 128]). `scale`:
+    what the scores are multiplied by, the true head's D^-0.5
+    (`fused_attend` pads a narrow one)."""
     b, hkv, rows, d = q.shape
     num_keys = k.shape[0]
     block = key_block(num_keys, _most_keys(precise)[0])
@@ -313,7 +315,7 @@ def _forward_call(q, k, v, mask, groups, interpret, precise=False):
     by_keys = _key_spec(d, hkv, block)
     return _at_one_pass(pl.pallas_call(
         functools.partial(
-            _forward_kernel, scale=d ** -0.5, groups=groups,
+            _forward_kernel, scale=scale, groups=groups,
             num_keys=num_keys, precision=_precision(precise),
         ),
         out_shape=(
@@ -335,7 +337,7 @@ def _forward_call(q, k, v, mask, groups, interpret, precise=False):
 
 
 def _backward_call(q, k, v, mask, out, lse, dout, groups, first_block,
-                   interpret, precise=False):
+                   interpret, precise, scale):
     """The forward's operands, its two results and dout like out ->
     (dq like q, dk, dv like k from block `first_block` on), all f32."""
     b, hkv, rows, d = q.shape
@@ -349,7 +351,7 @@ def _backward_call(q, k, v, mask, out, lse, dout, groups, first_block,
     )
     return _at_one_pass(pl.pallas_call(
         functools.partial(
-            _backward_kernel, scale=d ** -0.5, groups=groups,
+            _backward_kernel, scale=scale, groups=groups,
             num_keys=num_keys, first_block=first_block,
             precision=_precision(precise),
         ),
@@ -427,17 +429,19 @@ def _operands(q, k_all, v_all, mask, on_chip, precise=False):
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _fused_attend(q, k_all, v_all, mask, no_grad_keys, on_chip, precise):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _fused_attend(q, k_all, v_all, mask, no_grad_keys, on_chip, precise,
+                  scale):
     return _fused_attend_fwd(
-        q, k_all, v_all, mask, no_grad_keys, on_chip, precise
+        q, k_all, v_all, mask, no_grad_keys, on_chip, precise, scale
     )[0]
 
 
-def _fused_attend_fwd(q, k_all, v_all, mask, no_grad_keys, on_chip, precise):
+def _fused_attend_fwd(q, k_all, v_all, mask, no_grad_keys, on_chip, precise,
+                      scale):
     operands = _operands(q, k_all, v_all, mask, on_chip, precise)
     out, lse = _forward_call(
-        *operands, q.shape[2] // k_all.shape[2], not on_chip, precise
+        *operands, q.shape[2] // k_all.shape[2], not on_chip, precise, scale
     )
     result = _from_rows(out, q.shape[1]).astype(v_all.dtype)
     # Empty carriers of what the gradients are shaped and typed like.
@@ -447,7 +451,8 @@ def _fused_attend_fwd(q, k_all, v_all, mask, no_grad_keys, on_chip, precise):
     return result, (operands, out, lse, like)
 
 
-def _fused_attend_bwd(no_grad_keys, on_chip, precise, residuals, dresult):
+def _fused_attend_bwd(no_grad_keys, on_chip, precise, scale, residuals,
+                      dresult):
     operands, out, lse, like = residuals
     q_rows, keys, _, mask = operands
     hkv, tp = q_rows.shape[1], mask.shape[1]
@@ -456,7 +461,7 @@ def _fused_attend_bwd(no_grad_keys, on_chip, precise, residuals, dresult):
         _as_rows(dresult.astype(jnp.float32), hkv, tp),
         q_rows.shape[2] // tp,
         no_grad_keys // key_block(keys.shape[0], _most_keys(precise)[1]),
-        not on_chip, precise,
+        not on_chip, precise, scale,
     )
     return (
         _from_rows(dq, like[0].shape[1]).astype(like[0].dtype),
@@ -484,11 +489,26 @@ def fused_attend(q, k_all, v_all, mask, no_grad_keys=0, precise=False):
     the write of their rows. `precise` (a Python bool): float32
     operands and every product at `highest`, forward and backward,
     where the default is bfloat16 operands (see the module's header).
+
+    A head narrower than the 128 lanes (models/lfm2.py: 64) is padded
+    to them with zero columns, which add nothing to a score and come
+    out of the combine as zeros that are dropped; the scores keep the
+    true D^-0.5. The MXU contracts over 64 at the cost of 128 either
+    way; what the padding costs is the operands' second half in VMEM
+    and in the keys' one copy.
     """
-    return _fused_attend(
+    d = q.shape[-1]
+    narrow = -d % _LANES if d < _LANES else 0
+    if narrow:
+        q, k_all, v_all = (
+            jnp.pad(x, ((0, 0),) * 3 + ((0, narrow),))
+            for x in (q, k_all, v_all)
+        )
+    out = _fused_attend(
         q, k_all, v_all, mask, no_grad_keys, jax.default_backend() == "tpu",
-        bool(precise),
+        bool(precise), d ** -0.5,
     )
+    return out[..., :d] if narrow else out
 
 
 # --- The latent leg: H heads against ONE key a slot -----------------------
