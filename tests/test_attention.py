@@ -720,46 +720,117 @@ def test_keys_told_to_take_no_gradient_take_zeros(
         np.testing.assert_array_equal(got[:, n:], whole[:, n:])
 
 
+# What two and three bfloat16 terms an operand leave of a product, of
+# its largest entry: 2^-16 and 2^-24 a product, a few products deep.
+_CUT_TOLERANCE = {2: 1e-4, 3: 5e-6}
+
+
+@pytest.mark.parametrize("terms", [2, 3], ids=["high", "highest"])
 @pytest.mark.parametrize(
-    "heads, kv_heads, cache, M, block",
-    [(8, 2, "partly", 684, 384), (8, 1, "invalid", 1776, 256)],
+    "heads, kv_heads, cache, M, blocks, no_grad_keys",
+    [(8, 2, "partly", 684, (384, 256), 684),
+     (8, 1, "invalid", 1776, (384, 256), 300)],
     ids=["gqa4-partly-ragged", "gqa8-empty-whole-blocks"],
 )
-def test_precise_fused_attend_is_the_dense_body(
-    heads, kv_heads, cache, M, block
+def test_fused_attend_at_its_callers_terms_is_the_dense_body(
+    heads, kv_heads, cache, M, blocks, no_grad_keys, terms
 ):
-    """`precise` (float32 operands at the kernels' `highest`; blocks of
-    at most 512 keys both ways) is the same function, value and
-    gradients; interpreted here, where both modes are float32, so this
-    holds the block arithmetic and the plumbing of the flag."""
+    """At two and at three terms an operand (what a caller at `high`
+    and at `highest` hands over) the kernels read float32 tiles, cut
+    them into bfloat16 terms and make the product's three or six passes
+    themselves; interpreted here, against the dense body traced at that
+    precision (float32 on this backend): the output and dq, dk, dv
+    with a cache that takes no gradient, within what the terms leave
+    out, and three terms closer than two."""
     import functools
 
-    from torchbeast_tpu.ops import fused_attention
-    from torchbeast_tpu.ops.fused_attention import fused_attend, key_block
+    from torchbeast_tpu.ops import attention, fused_attention
+    from torchbeast_tpu.ops.fused_attention import fused_attend
 
     q, k_all, v_all, mask = _fused_case(heads, kv_heads, cache, M)
-    assert block == key_block(M + T, fused_attention._PRECISE_KEYS)
-    precise = functools.partial(fused_attend, precise=True)
-    fused, dense = jax.jit(precise), jax.jit(_dense_body)
-    got, want = fused(q, k_all, v_all, mask), dense(q, k_all, v_all, mask)
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    got = _gradients_of(precise, mask)(q, k_all, v_all)
-    want = _gradients_of(_dense_body, mask)(q, k_all, v_all)
-    for name, a, b in zip(("q", "k_all", "v_all"), got, want):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5, err_msg=name)
+    # 700 keys: two ragged blocks of 384 forward (512 would pad them to
+    # 1,024), three of 256 backward; 1,792: five 384s forward (to 1,920
+    # where 512s pad to 2,048), seven whole 256s backward, dk and dv
+    # from the second on. The cells' 4,351 keys: 512 and 256.
+    assert fused_attention._key_blocks(4351, terms) == (512, 256)
+    assert blocks == fused_attention._key_blocks(M + T, terms)
+    cut = functools.partial(
+        fused_attend, no_grad_keys=no_grad_keys, terms=terms
+    )
+
+    def dense(q, k_all, v_all, mask):
+        with jax.default_matmul_precision(
+            {2: "high", 3: "highest"}[terms]
+        ):
+            return attention.dense_transformer_attend(
+                q, k_all, v_all, mask, None, None, no_grad_keys
+            )
+
+    def worst(got, want):
+        return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+    fused, plain = jax.jit(cut), jax.jit(dense)
+    errors = [worst(fused(q, k_all, v_all, mask), plain(q, k_all, v_all, mask))]
+    got = _gradients_of(cut, mask)(q, k_all, v_all)
+    want = _gradients_of(dense, mask)(q, k_all, v_all)
+    for a, b in zip(got[1:], want[1:]):
+        np.testing.assert_array_equal(a[:, :no_grad_keys], 0.0)
+        assert float(jnp.abs(b[:, no_grad_keys:]).max()) > 0
+    errors += [worst(a, b) for a, b in zip(got, want)]
+    assert max(errors) < _CUT_TOLERANCE[terms], errors
+    if terms == 2:
+        # The tail is cut and multiplied, not folded away: two terms
+        # leave more than float32's rounding, and far less than one
+        # term's 2^-9.
+        assert max(errors) > 1e-6, errors
+
+
+def test_two_terms_are_not_one():
+    """Values whose bfloat16 head is the same 1.0 at every key and
+    whose tail alone tells them apart: through one term they are all
+    1.0, every output exactly the softmax's sum and dq zeros; through
+    two terms the tail is cut in the kernel and multiplied, and the
+    output and dq are the dense body's."""
+    import functools
+
+    from torchbeast_tpu.ops.fused_attention import fused_attend
+
+    q, k_all, v_all, mask = _fused_case(8, 2, "partly", 684)
+    tail = 2.0 ** -10 * jnp.tanh(v_all)
+    values = 1.0 + tail
+    head = values.astype(jnp.bfloat16).astype(jnp.float32)
+    np.testing.assert_array_equal(head, 1.0)
+
+    two = functools.partial(fused_attend, terms=2)
+    dense, at_two, at_one = map(jax.jit, (_dense_body, two, fused_attend))
+    want = dense(q, k_all, values, mask)
+    signal = float(jnp.max(jnp.abs(want - 1.0)))
+    assert signal > 1e-5
+    got = at_two(q, k_all, values, mask)
+    np.testing.assert_allclose(got - 1.0, want - 1.0, atol=0.02 * signal)
+    one = at_one(q, k_all, head, mask)
+    assert float(jnp.max(jnp.abs(one - 1.0))) < 0.02 * signal
+
+    dq = _gradients_of(two, mask)(q, k_all, values)[0]
+    want_dq = _gradients_of(_dense_body, mask)(q, k_all, values)[0]
+    signal = float(jnp.max(jnp.abs(want_dq)))
+    np.testing.assert_allclose(dq, want_dq, atol=0.02 * signal)
+    dq_one = _gradients_of(fused_attend, mask)(q, k_all, head)[0]
+    assert float(jnp.max(jnp.abs(dq_one))) < 0.02 * signal
 
 
 @pytest.mark.parametrize(
-    "traced_at, precise",
-    [(None, False), ("default", False), ("high", True), ("highest", True)],
+    "traced_at, terms",
+    [(None, 1), ("default", 1), ("high", 2), ("highest", 3)],
 )
 def test_the_fused_pass_follows_the_precision_its_caller_traces_at(
-    monkeypatch, traced_at, precise
+    monkeypatch, traced_at, terms
 ):
-    """`dense_transformer_attend` hands `fused_attend` `precise` when
-    its caller traces at more than one bfloat16 pass (models/
-    nemotron3.py under `high`), and not at the default (Mellum2's
-    program is what it was)."""
+    """`dense_transformer_attend` hands `fused_attend` the number of
+    bfloat16 terms its caller's traced precision states: two at `high`
+    (models/nemotron3.py, models/qwen3next.py, models/lfm2.py), three
+    at `highest`, one at the default (Mellum2's program is what it
+    was)."""
     import contextlib
 
     from torchbeast_tpu.ops import attention
@@ -768,8 +839,8 @@ def test_the_fused_pass_follows_the_precision_its_caller_traces_at(
     monkeypatch.setattr(attention, "FUSED_SCORE_BYTES", 1)
     monkeypatch.setattr(
         attention, "fused_attend",
-        lambda q, k, v, mask, no_grad_keys, precise: (
-            seen.append(precise) or q
+        lambda q, k, v, mask, no_grad_keys, terms: (
+            seen.append(terms) or q
         ),
     )
     q, k_all, v_all, mask = _fused_case(8, 2, "partly", 5, head_size=128)
@@ -785,7 +856,7 @@ def test_the_fused_pass_follows_the_precision_its_caller_traces_at(
             ),
             q, k_all, v_all, mask,
         )
-    assert seen == [precise]
+    assert seen == [terms]
 
 
 def test_fused_attend_under_remat():

@@ -28,9 +28,12 @@ def test_fused_pass_lowers_at_heads_of_64_for_v5e(one_chip, monkeypatch):
     """The check interpret mode cannot make: the fused pass, forward
     and backward, at the cell's attention shapes (16 rows x 256 steps,
     32 query heads on 8 key/value heads of 64, 4,095 + 256 keys, the
-    cache taking no gradient) compiles for the chip's compiler at both
-    precisions, a head padded to the 128 lanes with zero columns: the
-    kernels' key tiles are [block, 128] and the result is 64 wide."""
+    cache taking no gradient) compiles for the chip's compiler at one
+    term an operand and at the family's two (float32 tiles cut in VMEM,
+    three one-pass dots a product and no dot at Mosaic's `highest` in
+    the kernels' text), a head padded to the 128 lanes with zero
+    columns: the kernels' key tiles are [block, 128] and the result is
+    64 wide."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     rows, steps, M, H, Hkv, hd = 16, 256, 4095, 32, 8, 64
     assert attention.fused_pass_applies(
@@ -45,22 +48,29 @@ def test_fused_pass_lowers_at_heads_of_64_for_v5e(one_chip, monkeypatch):
             (rows, steps, H, narrow), (rows, M + steps, Hkv, narrow), None
         )
 
-    def loss(q, k_all, v_all, mask, precise):
+    def loss(q, k_all, v_all, mask, terms):
         return jnp.sum(fused_attention.fused_attend(
-            q, k_all, v_all, mask, M, precise=precise
+            q, k_all, v_all, mask, M, terms=terms
         ) ** 2)
 
     traced = jax.jit(
         jax.value_and_grad(loss, argnums=(0, 1, 2)), static_argnums=4
     )
-    for precise in (True, False):
-        text = traced.lower(
-            _struct(one_chip, (rows, steps, H, hd)),
-            _struct(one_chip, (rows, M + steps, Hkv, hd)),
-            _struct(one_chip, (rows, M + steps, Hkv, hd)),
-            _struct(one_chip, (rows, steps, M + steps), jnp.bool_),
-            precise,
-        ).compile().as_text()
+    operands = (
+        _struct(one_chip, (rows, steps, H, hd)),
+        _struct(one_chip, (rows, M + steps, Hkv, hd)),
+        _struct(one_chip, (rows, M + steps, Hkv, hd)),
+        _struct(one_chip, (rows, steps, M + steps), jnp.bool_),
+    )
+    for terms in (2, 1):
+        # The kernels' bodies as traced: every dot one pass, on bfloat16
+        # operands; at two terms three a product (two products forward,
+        # five backward).
+        kernels = str(traced.trace(*operands, terms).jaxpr)
+        assert "fused_attend_forward" in kernels
+        assert "HIGHEST" not in kernels and "HIGH" not in kernels
+        assert kernels.count("dot_general") == 7 * (3 if terms > 1 else 1)
+        text = traced.lower(*operands, terms).compile().as_text()
         assert text.count("fused_attend_forward") >= 1
         assert text.count("fused_attend_backward") >= 1
         # No f32 array over the keys a query row: the scores stay in VMEM.

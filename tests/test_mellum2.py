@@ -280,6 +280,9 @@ def test_blocks_over_the_threshold_take_the_fused_pass_and_are_counted(
     monkeypatch.setattr(attention, "FUSED_SCORE_BYTES", 1)
     loss_f, stats_f, grads_f = run()
     assert float(stats_f["attention_fused_applications"]) == 4.0
+    # One term an operand: no product is cut in a kernel, and no zero
+    # is sown to say so.
+    assert "attention_products_cut_in_kernel" not in stats_f
     assert float(loss_f) == pytest.approx(float(loss), rel=1e-6)
     np.testing.assert_allclose(
         grads_f, grads, rtol=0, atol=RTOL * float(jnp.max(jnp.abs(grads)))

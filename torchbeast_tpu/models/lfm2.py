@@ -331,8 +331,10 @@ class Lfm2Net(TransformerNet):
     zero_init_extras: bool = True
     # Every matmul of the family in three bf16 passes on the MXU (JAX
     # precision `high`), the grouped expert matmuls and the attention
-    # layer's fused pass (`precise`) among them, as models/kanana2.py,
-    # models/nemotron3.py and models/qwen3next.py and for their reason:
+    # layer's fused pass among them (both make the three passes
+    # themselves, from float32 tiles cut into two bf16 terms in VMEM),
+    # as models/kanana2.py, models/nemotron3.py and models/qwen3next.py
+    # and for their reason:
     # what feeds a router is rounded, and the fourth choice among 32
     # close scores decides. PERF.md section 6 (PR 53) has the readings.
     matmul_precision: str = "high"

@@ -570,8 +570,9 @@ class Nemotron3Net(TransformerNet):
     # scores decides. PERF.md section 6 (PR 42) has the readings.
     matmul_precision: str = "high"
     # The attention layer's two products over the keys follow it: in
-    # `dense_transformer_attend`'s fused regime the kernels then take
-    # float32 operands at their `highest` (`fused_attend`'s `precise`).
+    # `dense_transformer_attend`'s fused regime the kernels then read
+    # float32 operands, cut them into two bf16 terms in VMEM and make
+    # the same three passes (`fused_attend`'s `terms`, 2 under `high`).
     # At one bfloat16 pass the layer, first of the period, rounds what
     # all five routers read to 1e-3, and one seed in 90 read 7.9e-3 of
     # the loss's scale on the chip (PERF.md section 6).

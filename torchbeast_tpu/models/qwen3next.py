@@ -621,11 +621,13 @@ class Qwen3NextNet(TransformerNet):
     zero_init_extras: bool = True
     # Every matmul of the family in three bf16 passes on the MXU (JAX
     # precision `high`), the grouped expert matmuls and the attention
-    # layer's fused pass (`precise`) among them, as models/kanana2.py
-    # and models/nemotron3.py and for their reason: what feeds a router
-    # is rounded, and the tenth choice among 512 close probabilities
-    # decides. The scan's decays are float32 and its solve at the
-    # highest. PERF.md section 6 (PR 46) has the readings.
+    # layer's fused pass among them (both make the three passes
+    # themselves, from float32 tiles cut into two bf16 terms in VMEM),
+    # as models/kanana2.py and models/nemotron3.py and for their
+    # reason: what feeds a router is rounded, and the tenth choice
+    # among 512 close probabilities decides. The scan's decays are
+    # float32 and its solve at the highest. PERF.md section 6 (PR 46)
+    # has the readings.
     matmul_precision: str = "high"
 
     def __call__(self, inputs, core_state, **kwargs):

@@ -79,8 +79,16 @@ def count_fused_application(module: nn.Module) -> None:
     """One block application whose `dense_transformer_attend` took the
     fused pass (ops/attention.py `fused_pass_applies`, which the block
     asks with the shapes it hands over): `attention_fused_applications`,
-    4 in the Mellum2 cell, no such key where no block took it."""
+    4 in the Mellum2 cell, no such key where no block took it. Beside
+    it `attention_products_cut_in_kernel`: those of them whose products
+    the kernels make from float32 tiles cut into bfloat16 terms in VMEM,
+    which is under a caller that traces at more than one term (`high`,
+    `highest`: 1 in the LFM2, Qwen3-Next and Nemotron-3 cells). Not
+    sown where there is none: a sown zero would be one more output of
+    Mellum2's update, which this leaves as it was."""
     _count_application(module, "fused_applications")
+    if terms_traced_under() > 1:
+        _count_application(module, "products_cut_in_kernel")
 
 
 def count_latent_application(module: nn.Module) -> None:

@@ -64,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from torchbeast_tpu.ops.bf16_terms import terms_traced_under
 from torchbeast_tpu.ops.fused_attention import (
     BIG_NEG,
     fused_attend,
@@ -1028,9 +1029,12 @@ def dense_transformer_attend(q, k_all, v_all, mask, offsets, rel_bias,
     exponent and denominator float32, the weights cast to v's type for
     the combine; only the order of summation over keys differs. Under
     a caller that traces at `high` or `highest` the dense body's
-    einsums follow, and the fused pass takes float32 operands at the
-    kernels' `highest` (`fused_attend`'s `precise`; models/
-    nemotron3.py). Every query must admit a key.
+    einsums follow, three or six passes over bfloat16 terms of the
+    float32 operands, and so does the fused pass, which is handed the
+    number of terms the trace states (`bf16_terms.terms_traced_under`:
+    2, 3) and makes the same passes from float32 tiles cut in VMEM
+    (models/nemotron3.py, models/qwen3next.py, models/lfm2.py). Every
+    query must admit a key.
 
     `no_grad_keys` (a Python int; the Mellum2 block passes its cache's
     length) says that the first so many keys of k_all and v_all are
@@ -1046,8 +1050,7 @@ def dense_transformer_attend(q, k_all, v_all, mask, offsets, rel_bias,
         )
     if fused_pass_applies(q.shape, k_all.shape, rel_bias):
         return fused_attend(
-            q, k_all, v_all, mask, no_grad_keys,
-            precise=not _one_bf16_pass(None),
+            q, k_all, v_all, mask, no_grad_keys, terms=terms_traced_under()
         )
     if no_grad_keys:
         k_all = _no_gradient_before(k_all, no_grad_keys)

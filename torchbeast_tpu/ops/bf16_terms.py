@@ -5,11 +5,14 @@ On the MXU a float32 matmul is passes over bfloat16 pieces of its
 operands: one at JAX's default precision, three under `high` (each
 operand a head and a tail: head x head, head x tail, tail x head), six
 under `highest` (three terms a side). Code that makes those passes
-itself, because a kernel wants bfloat16 operands (models/moe.py
-`_gmm_call`, ops/grouped_matmul.py) or because one operand is exact in
+itself cuts its operands here, at the count the caller's
+`jax.default_matmul_precision` states: because a kernel wants bfloat16
+operands (models/moe.py `_gmm_call`), because one operand is exact in
 bfloat16 and needs no tail (models/transformer.py `frame_projection`:
-a uint8 frame), cuts its operands here, at the count the caller's
-`jax.default_matmul_precision` states.
+a uint8 frame), or because it IS a kernel, which reads float32 tiles
+and cuts them after they are loaded, so that no term is ever in HBM
+(ops/grouped_matmul.py, ops/fused_attention.py: `cut_in_kernel`,
+`product_of_terms`, the same terms by the only cast Mosaic lowers).
 """
 
 import jax
@@ -37,3 +40,44 @@ def bf16_terms(x, terms):
         out.append(head.astype(jnp.bfloat16))
         x = x - head
     return out
+
+
+def cut_in_kernel(x, terms):
+    """A float32 tile as `terms` bfloat16 tiles, the largest first:
+    `bf16_terms` inside a Mosaic kernel, where `lax.reduce_precision`
+    has no lowering and a cast there and back, which XLA folds to the
+    identity outside one, is not folded."""
+    out = []
+    for term in range(terms):
+        head = x.astype(jnp.bfloat16)
+        out.append(head)
+        if term + 1 < terms:
+            x = x - head.astype(jnp.float32)
+    return out
+
+
+def product_of_terms(lhs, rhs, dims):
+    """lhs x rhs over `dims`, each the list of its n bfloat16 terms, as
+    the n (n + 1) / 2 one-pass products that a float32 matmul at that
+    many terms is, the smallest first, so that they are not lost one by
+    one beside the largest; float32."""
+    out = None
+    for order in reversed(range(len(lhs))):
+        for i in range(order + 1):
+            part = jax.lax.dot_general(
+                lhs[i], rhs[order - i], dims,
+                # Whatever the caller traces under: Mosaic refuses a
+                # bfloat16 operand at a float32 contraction.
+                precision=jax.lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32,
+            )
+            out = part if out is None else out + part
+    return out
+
+
+def product_in_kernel(lhs, rhs, terms, dims):
+    """Two float32 tiles' product over `dims` at `terms` terms a side:
+    each cut, then `product_of_terms`."""
+    return product_of_terms(
+        cut_in_kernel(lhs, terms), cut_in_kernel(rhs, terms), dims
+    )

@@ -24,15 +24,26 @@ the mask admits is summed; no block is skipped, whatever the mask says
 program for a full cache than for an empty one). Off the chip the
 kernels are interpreted, in float32.
 
-**Or the caller's precision** (`precise`, PR 42). The dense body's
+**Or the caller's precision** (`terms`; PR 42, PR 54). The dense body's
 einsums follow the precision their caller traces under; so does this
-pass. A caller that traces at `high` or `highest` (models/nemotron3.py:
-what its attention layer writes feeds five routers' 22nd choice among
-512) gets the kernels with float32 operands and every product at
-Mosaic's `highest` (it has no `high`), `p` not rounded at all; blocks
-of 512 keys both ways, since a cell's operands are twice as wide.
-`dense_transformer_attend` asks `ops/attention._one_bf16_pass` when it
-calls, and a caller at the default gets the program it always got.
+pass. A float32 matmul on the MXU is passes over bfloat16 terms of its
+operands (ops/bf16_terms.py): one term a side and one pass at the
+default, two and three under `high`, three and six under `highest`.
+`dense_transformer_attend` hands over the number of terms its caller's
+trace states (`bf16_terms.terms_traced_under`), and at more than one
+(models/nemotron3.py, models/qwen3next.py, models/lfm2.py trace at
+`high`: what their attention layer writes feeds routers whose last
+choice among close scores decides) the kernels read FLOAT32 tiles and
+make every product themselves, from terms cut in VMEM (`cut_in_kernel`,
+`product_of_terms`: ops/grouped_matmul.py's way): three passes under
+`high`, as XLA's dense body would make (a dot at Mosaic's own
+`highest`, which this pass asked for until PR 54, is six passes on
+three terms cut again inside every dot). Each operand is cut ONCE: a
+block's keys, values, `p` and `ds` when they are made, and the row
+operands, which are the same over a cell's key blocks (`q`; backward
+`dout` too), at the cell's first block into VMEM scratch. `p` is then
+two (three) terms as any float32 operand under that precision is. A
+caller at the default gets the program it always got.
 
 **Grouped heads as rows.** A key/value head's G query heads x T steps
 are the rows of one matmul against a `[block, D]` tile of keys: K and V
@@ -81,6 +92,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from torchbeast_tpu.ops.bf16_terms import cut_in_kernel, product_of_terms
+
 BIG_NEG = -1e30
 
 # The most keys a grid cell takes. A forward cell pays for its running
@@ -92,9 +105,19 @@ BIG_NEG = -1e30
 # `no_grad_keys` it makes dk, dv for whole blocks: 384 keys a cell.
 _FORWARD_KEYS = 1536
 _BACKWARD_KEYS = 512
-# With float32 operands at `highest` (`precise`): a cell's [rows, block]
-# intermediates are split into bfloat16 terms beside themselves.
-_PRECISE_KEYS = 512
+# With float32 operands cut into terms in the cell (1,024 to 2,048
+# rows, 4,351 keys, `high`; PERF.md section 6, PR 54, has the sweep): a
+# forward cell of 512 keys, or of 384 where that pads them less. At
+# heads of 128 the once-a-block work on [rows, 1] and [rows, D] costs a
+# cell what 250 keys do, so blocks of 256, which pad 4,351 keys the
+# least, ran 7.8 ms where 512 ran 6.2 and 768 to 1,536 6.3 to 7.1; at
+# heads of 256 the MXU paces the cell and the padding is all that
+# shows (5.2 at 256, 5.4 at 512). The backward cell, which makes dk,
+# dv for whole blocks from `no_grad_keys` on, 256: 8.5 / 7.7 ms where
+# 512 ran 9.4 / 8.6.
+_CUT_FEWEST_FORWARD_KEYS = 384
+_CUT_FORWARD_KEYS = 512
+_CUT_BACKWARD_KEYS = 256
 _SUBLANES = 8  # rows of a float32 tile
 _LANES = 128
 # Scoped VMEM a cell may use. At the Mellum2 widths (704 rows) the
@@ -113,37 +136,68 @@ def _vmem_limit(d):
     return _VMEM_LIMIT * max(1, d // _LANES)
 
 
+_NN = (((1,), (0,)), ((), ()))  # a @ b
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
-def _dot(a, b, dims=(((1,), (0,)), ((), ())), precision=None):
+def _dot(a, b, dims=_NN, precision=None):
     return jax.lax.dot_general(
         a, b, dims, precision=precision,
         preferred_element_type=jnp.float32,
     )
 
 
-def _most_keys(precise):
-    """(forward, backward): the most keys a cell of either pass takes."""
-    if precise:
-        return _PRECISE_KEYS, _PRECISE_KEYS
-    return _FORWARD_KEYS, _BACKWARD_KEYS
+def _key_blocks(num_keys, terms):
+    """(forward, backward): the keys a cell of either pass takes."""
+    if terms > 1:
+        return (
+            key_block(
+                num_keys, _CUT_FORWARD_KEYS, _CUT_FEWEST_FORWARD_KEYS
+            ),
+            key_block(num_keys, _CUT_BACKWARD_KEYS),
+        )
+    return (
+        key_block(num_keys, _FORWARD_KEYS),
+        key_block(num_keys, _BACKWARD_KEYS),
+    )
 
 
-def _precision(precise):
-    return jax.lax.Precision.HIGHEST if precise else None
+def _operand(x, terms):
+    """A tile as `_matmul` reads it: itself at one term (cast outside
+    the kernel, to bfloat16 on the chip), else the bfloat16 terms of
+    the float32 tile."""
+    return x if terms == 1 else cut_in_kernel(x, terms)
 
 
-def key_block(num_keys: int, most: int) -> int:
+def _matmul(a, b, terms, dims=_NN):
+    """a x b over `dims`, each as `_operand` gives it: one pass, or the
+    passes of its terms."""
+    if terms == 1:
+        return _dot(a, b, dims)
+    return product_of_terms(a, b, dims)
+
+
+def _keep_terms(terms_ref, x):
+    """The bfloat16 terms of the float32 tile x into scratch [terms,
+    ...]: a row operand, cut once a cell."""
+    for i, term in enumerate(cut_in_kernel(x, terms_ref.shape[0])):
+        terms_ref[i] = term
+
+
+def _kept_terms(terms_ref):
+    return [terms_ref[i] for i in range(terms_ref.shape[0])]
+
+
+def key_block(num_keys: int, most: int, fewest: int = 2 * _LANES) -> int:
     """Keys a grid cell for `num_keys` keys: the multiple of 128 from
-    256 to `most` that pads the keys the least, the largest on a tie
-    (4,176 keys: 1,408 forward, 384 backward, both to 4,224; 1,104:
-    1,152 and 384)."""
+    `fewest` (256) to `most` that pads the keys the least, the largest
+    on a tie (4,176 keys: 1,408 forward, 384 backward, both to 4,224;
+    1,104: 1,152 and 384)."""
     if num_keys <= _LANES:
         return _LANES
     return min(
-        range(2 * _LANES, most + 1, _LANES),
+        range(fewest, most + 1, _LANES),
         key=lambda block: (-(-num_keys // block) * block, -block),
     )
 
@@ -153,16 +207,10 @@ def padded_steps(steps: int) -> int:
     return -(-steps // _SUBLANES) * _SUBLANES
 
 
-def _padded_keys(num_keys: int, forward_most: int, backward_most: int) -> int:
-    """The keys in whole blocks of either pass: what a mask is padded
-    to."""
-    return max(
-        -(-num_keys // block) * block
-        for block in (
-            key_block(num_keys, forward_most),
-            key_block(num_keys, backward_most),
-        )
-    )
+def _padded_keys(num_keys: int, blocks) -> int:
+    """The keys in whole blocks of either pass (`blocks`: forward,
+    backward): what a mask is padded to."""
+    return max(-(-num_keys // block) * block for block in blocks)
 
 
 def _whole_keys(x, block_index, num_keys):
@@ -176,20 +224,27 @@ def _whole_keys(x, block_index, num_keys):
     return jnp.where(row < num_keys - block_index * block, x, 0)
 
 
-def _scores(q, k, admitted, scale, groups, precision=None):
-    """Masked f32 scores [G * Tp, block] of a cell: q [G * Tp, D],
-    k [block, D], admitted [Tp, block] int8 (the mask's slab, shared by
-    the G query heads of the group)."""
-    rows, block = q.shape[0], k.shape[0]
-    s = _dot(q, k, _NT, precision) * scale
+def _masked(s, admitted, scale, groups):
+    """Masked f32 scores [G * Tp, block] of a cell from the product
+    q k^T: admitted [Tp, block] int8 (the mask's slab, shared by the G
+    query heads of the group)."""
+    rows, block = s.shape
+    s = s * scale
     s = s.reshape(groups, rows // groups, block)
     s = jnp.where((admitted != 0)[None], s, BIG_NEG)
     return s.reshape(rows, block)
 
 
+def _scores(q, k, admitted, scale, groups, precision=None):
+    """`_masked` scores of q [G * Tp, D] against k [block, D]."""
+    return _masked(_dot(q, k, _NT, precision), admitted, scale, groups)
+
+
 def _forward_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref,
-                    top_ref, den_ref, acc_ref, *, scale, groups, num_keys,
-                    precision=None):
+                    top_ref, den_ref, acc_ref, *kept, scale, groups,
+                    num_keys, terms):
+    """`kept`: at more than one term, scratch [terms, G * Tp, D] for
+    the terms of q."""
     block_index = pl.program_id(2)
 
     @pl.when(block_index == 0)
@@ -199,16 +254,20 @@ def _forward_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref,
         top_ref[...] = jnp.full_like(top_ref, -jnp.inf)
         den_ref[...] = jnp.zeros_like(den_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        if kept:
+            _keep_terms(kept[0], q_ref[0, 0])
 
-    k = _whole_keys(k_ref[...], block_index, num_keys)
-    v = _whole_keys(v_ref[...], block_index, num_keys)
-    s = _scores(q_ref[0, 0], k, mask_ref[0], scale, groups, precision)
+    k = _operand(_whole_keys(k_ref[...], block_index, num_keys), terms)
+    v = _operand(_whole_keys(v_ref[...], block_index, num_keys), terms)
+    q = _kept_terms(kept[0]) if kept else q_ref[0, 0]
+    admitted = mask_ref[0]
+    s = _masked(_matmul(q, k, terms, _NT), admitted, scale, groups)
     top = jnp.maximum(top_ref[...], s.max(axis=-1, keepdims=True))
     p = jnp.exp(s - top)
     shrink = jnp.exp(top_ref[...] - top)
     den_ref[...] = shrink * den_ref[...] + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = shrink * acc_ref[...] + _dot(
-        p.astype(v.dtype), v, precision=precision
+    acc_ref[...] = shrink * acc_ref[...] + _matmul(
+        _operand(p.astype(v_ref.dtype), terms), v, terms
     )
     top_ref[...] = top
 
@@ -222,9 +281,12 @@ def _forward_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref,
 
 def _backward_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref,
                      dout_ref, dq_ref, dk_ref, dv_ref, dq_acc_ref,
-                     delta_ref, *, scale, groups, num_keys, first_block,
-                     precision=None):
+                     delta_ref, *kept, scale, groups, num_keys,
+                     first_block, terms):
+    """`kept`: at more than one term, scratch [terms, G * Tp, D] for
+    the terms of q and for those of dout."""
     block_index = pl.program_id(2)
+    operand = q_ref.dtype
 
     @pl.when(block_index == 0)
     def _():
@@ -233,26 +295,32 @@ def _backward_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, lse_ref,
         delta_ref[...] = jnp.sum(
             out_ref[0, 0] * dout_ref[0, 0], axis=-1, keepdims=True
         )
+        for terms_ref, x_ref in zip(kept, (q_ref, dout_ref)):
+            _keep_terms(terms_ref, x_ref[0, 0])
 
-    q = q_ref[0, 0]
-    k = _whole_keys(k_ref[...], block_index, num_keys)
-    v = _whole_keys(v_ref[...], block_index, num_keys)
-    dout = dout_ref[0, 0].astype(q.dtype)
-    s = _scores(q, k, mask_ref[0], scale, groups, precision)
+    q = _kept_terms(kept[0]) if kept else q_ref[0, 0]
+    k = _operand(_whole_keys(k_ref[...], block_index, num_keys), terms)
+    v = _operand(_whole_keys(v_ref[...], block_index, num_keys), terms)
+    dout = _kept_terms(kept[1]) if kept else dout_ref[0, 0].astype(operand)
+    admitted = mask_ref[0]
+    s = _masked(_matmul(q, k, terms, _NT), admitted, scale, groups)
     p = jnp.exp(s - lse_ref[0, 0][:, :1])
     # The 1/sqrt(D) of the scores goes on the products, in f32: ds is
     # rounded to the operand type once either way.
-    ds = (
-        p * (_dot(dout, v, _NT, precision) - delta_ref[...])
-    ).astype(q.dtype)
-    dq_acc_ref[...] += _dot(ds, k, precision=precision)
+    ds = _operand(
+        (p * (_matmul(dout, v, terms, _NT) - delta_ref[...])).astype(operand),
+        terms,
+    )
+    dq_acc_ref[...] += _matmul(ds, k, terms)
 
     # dk, dv from the first block that holds a key that takes them: the
     # blocks before it are not written (nor part of dk_ref, dv_ref).
     @pl.when(block_index >= first_block)
     def _():
-        dv_ref[...] = _dot(p.astype(q.dtype), dout, _TN, precision)
-        dk_ref[...] = _dot(ds, q, _TN, precision) * scale
+        dv_ref[...] = _matmul(
+            _operand(p.astype(operand), terms), dout, terms, _TN
+        )
+        dk_ref[...] = _matmul(ds, q, terms, _TN) * scale
 
     @pl.when(block_index == pl.num_programs(2) - 1)
     def _():
@@ -292,7 +360,8 @@ def _compiler_params(interpret, vmem_limit=None):
 
 def _at_one_pass(kernel_call):
     """`kernel_call` traced under no matmul precision, whatever its
-    caller traces under: the kernels' operands are bfloat16 on the chip
+    caller traces under: the kernels' dots are on bfloat16 operands on
+    the chip, one pass each (three passes are three such dots, `_matmul`),
     and Mosaic refuses a dot at `high` (models/nemotron3.py traces
     under it; a rematerialised block's forward rule is traced outside
     whatever narrower context its block set)."""
@@ -303,20 +372,28 @@ def _at_one_pass(kernel_call):
     return call
 
 
-def _forward_call(q, k, v, mask, groups, interpret, precise, scale):
+def _kept_rows(rows, d, terms, operands):
+    """Scratch for the bfloat16 terms of `operands` row operands
+    [rows, d], cut once a cell; none at one term."""
+    if terms == 1:
+        return []
+    return [pltpu.VMEM((terms, rows, d), jnp.bfloat16)] * operands
+
+
+def _forward_call(q, k, v, mask, groups, interpret, terms, scale):
     """q [B, Hkv, G * Tp, D]; k, v [K, B * Hkv * D]; mask [B, Tp, Kp]
     int8 -> (out f32 like q, lse f32 [B, Hkv, G * Tp, 128]). `scale`:
     what the scores are multiplied by, the true head's D^-0.5
     (`fused_attend` pads a narrow one)."""
     b, hkv, rows, d = q.shape
     num_keys = k.shape[0]
-    block = key_block(num_keys, _most_keys(precise)[0])
+    block = _key_blocks(num_keys, terms)[0]
     by_rows, mask_spec, lse_spec = _row_specs(rows, d, mask.shape[1], block)
     by_keys = _key_spec(d, hkv, block)
     return _at_one_pass(pl.pallas_call(
         functools.partial(
             _forward_kernel, scale=scale, groups=groups,
-            num_keys=num_keys, precision=_precision(precise),
+            num_keys=num_keys, terms=terms,
         ),
         out_shape=(
             jax.ShapeDtypeStruct(q.shape, jnp.float32),
@@ -329,7 +406,7 @@ def _forward_call(q, k, v, mask, groups, interpret, precise, scale):
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, d), jnp.float32),
-        ],
+        ] + _kept_rows(rows, d, terms, 1),
         interpret=interpret,
         name="fused_attend_forward",
         **_compiler_params(interpret, _vmem_limit(d)),
@@ -337,12 +414,12 @@ def _forward_call(q, k, v, mask, groups, interpret, precise, scale):
 
 
 def _backward_call(q, k, v, mask, out, lse, dout, groups, first_block,
-                   interpret, precise, scale):
+                   interpret, terms, scale):
     """The forward's operands, its two results and dout like out ->
     (dq like q, dk, dv like k from block `first_block` on), all f32."""
     b, hkv, rows, d = q.shape
     num_keys = k.shape[0]
-    block = key_block(num_keys, _most_keys(precise)[1])
+    block = _key_blocks(num_keys, terms)[1]
     by_rows, mask_spec, lse_spec = _row_specs(rows, d, mask.shape[1], block)
     by_keys = _key_spec(d, hkv, block)
     by_later_keys = _key_spec(d, hkv, block, first_block)
@@ -352,8 +429,7 @@ def _backward_call(q, k, v, mask, out, lse, dout, groups, first_block,
     return _at_one_pass(pl.pallas_call(
         functools.partial(
             _backward_kernel, scale=scale, groups=groups,
-            num_keys=num_keys, first_block=first_block,
-            precision=_precision(precise),
+            num_keys=num_keys, first_block=first_block, terms=terms,
         ),
         out_shape=(jax.ShapeDtypeStruct(q.shape, jnp.float32), grads, grads),
         grid=(b, hkv, pl.cdiv(num_keys, block)),
@@ -364,7 +440,7 @@ def _backward_call(q, k, v, mask, out, lse, dout, groups, first_block,
         scratch_shapes=[
             pltpu.VMEM((rows, d), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
-        ],
+        ] + _kept_rows(rows, d, terms, 2),
         interpret=interpret,
         name="fused_attend_backward",
         **_compiler_params(interpret, _vmem_limit(d)),
@@ -410,14 +486,16 @@ def _from_keys(x, like, no_grad_keys):
     return x.astype(like.dtype)
 
 
-def _operands(q, k_all, v_all, mask, on_chip, precise=False):
-    """The four operands as the kernels read them: the mask padded with
-    False to a whole number of either pass's blocks."""
+def _operands(q, k_all, v_all, mask, on_chip, terms):
+    """The four operands as the kernels read them: bfloat16 at one term
+    on the chip, else float32 (for the cells to cut, or interpreted);
+    the mask padded with False to a whole number of either pass's
+    blocks."""
     t, hkv = q.shape[1], k_all.shape[2]
     num_keys = k_all.shape[1]
     tp = padded_steps(t)
-    kp = _padded_keys(num_keys, *_most_keys(precise))
-    operand = jnp.bfloat16 if on_chip and not precise else jnp.float32
+    kp = _padded_keys(num_keys, _key_blocks(num_keys, terms))
+    operand = jnp.bfloat16 if on_chip and terms == 1 else jnp.float32
     return (
         _as_rows(q.astype(operand), hkv, tp),
         _as_keys(k_all.astype(operand)),
@@ -430,18 +508,18 @@ def _operands(q, k_all, v_all, mask, on_chip, precise=False):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _fused_attend(q, k_all, v_all, mask, no_grad_keys, on_chip, precise,
+def _fused_attend(q, k_all, v_all, mask, no_grad_keys, on_chip, terms,
                   scale):
     return _fused_attend_fwd(
-        q, k_all, v_all, mask, no_grad_keys, on_chip, precise, scale
+        q, k_all, v_all, mask, no_grad_keys, on_chip, terms, scale
     )[0]
 
 
-def _fused_attend_fwd(q, k_all, v_all, mask, no_grad_keys, on_chip, precise,
+def _fused_attend_fwd(q, k_all, v_all, mask, no_grad_keys, on_chip, terms,
                       scale):
-    operands = _operands(q, k_all, v_all, mask, on_chip, precise)
+    operands = _operands(q, k_all, v_all, mask, on_chip, terms)
     out, lse = _forward_call(
-        *operands, q.shape[2] // k_all.shape[2], not on_chip, precise, scale
+        *operands, q.shape[2] // k_all.shape[2], not on_chip, terms, scale
     )
     result = _from_rows(out, q.shape[1]).astype(v_all.dtype)
     # Empty carriers of what the gradients are shaped and typed like.
@@ -451,7 +529,7 @@ def _fused_attend_fwd(q, k_all, v_all, mask, no_grad_keys, on_chip, precise,
     return result, (operands, out, lse, like)
 
 
-def _fused_attend_bwd(no_grad_keys, on_chip, precise, scale, residuals,
+def _fused_attend_bwd(no_grad_keys, on_chip, terms, scale, residuals,
                       dresult):
     operands, out, lse, like = residuals
     q_rows, keys, _, mask = operands
@@ -460,8 +538,8 @@ def _fused_attend_bwd(no_grad_keys, on_chip, precise, scale, residuals,
         *operands, out, lse,
         _as_rows(dresult.astype(jnp.float32), hkv, tp),
         q_rows.shape[2] // tp,
-        no_grad_keys // key_block(keys.shape[0], _most_keys(precise)[1]),
-        not on_chip, precise, scale,
+        no_grad_keys // _key_blocks(keys.shape[0], terms)[1],
+        not on_chip, terms, scale,
     )
     return (
         _from_rows(dq, like[0].shape[1]).astype(like[0].dtype),
@@ -474,7 +552,7 @@ def _fused_attend_bwd(no_grad_keys, on_chip, precise, scale, residuals,
 _fused_attend.defvjp(_fused_attend_fwd, _fused_attend_bwd)
 
 
-def fused_attend(q, k_all, v_all, mask, no_grad_keys=0, precise=False):
+def fused_attend(q, k_all, v_all, mask, no_grad_keys=0, terms=1):
     """softmax(mask(q k^T / sqrt(D))) v with grouped heads, as
     `ops/attention.dense_transformer_attend` with no `rel_bias`, the
     scores never in HBM (see the module's header).
@@ -486,9 +564,12 @@ def fused_attend(q, k_all, v_all, mask, no_grad_keys=0, precise=False):
     in q, k_all and v_all; the first `no_grad_keys` (a Python int) of
     k_all and v_all take zeros for a gradient, and the blocks that hold
     no other key cost the backward pass neither their two products nor
-    the write of their rows. `precise` (a Python bool): float32
-    operands and every product at `highest`, forward and backward,
-    where the default is bfloat16 operands (see the module's header).
+    the write of their rows. `terms` (a Python int): the bfloat16 terms
+    a float32 operand of the products is, forward and backward, as
+    `bf16_terms.terms_traced_under` counts them: 1, one pass on
+    operands cast to bfloat16 outside the kernels; 2 (`high`) or 3
+    (`highest`), float32 operands cut in VMEM and a product's 3 or 6
+    passes made from the terms (see the module's header).
 
     A head narrower than the 128 lanes (models/lfm2.py: 64) is padded
     to them with zero columns, which add nothing to a score and come
@@ -506,7 +587,7 @@ def fused_attend(q, k_all, v_all, mask, no_grad_keys=0, precise=False):
         )
     out = _fused_attend(
         q, k_all, v_all, mask, no_grad_keys, jax.default_backend() == "tpu",
-        bool(precise), d ** -0.5,
+        int(terms), d ** -0.5,
     )
     return out[..., :d] if narrow else out
 
@@ -798,9 +879,10 @@ def _latent_operand(on_chip):
 def _fused_latent_leg_fwd(q_latent, q_rope, k_latent, k_rope, mask, scale,
                           on_chip):
     tp, num_keys = q_latent.shape[2], k_latent.shape[1]
-    kp = _padded_keys(
-        num_keys, _LATENT_FORWARD_KEYS, _LATENT_BACKWARD_KEYS
-    )
+    kp = _padded_keys(num_keys, (
+        key_block(num_keys, _LATENT_FORWARD_KEYS),
+        key_block(num_keys, _LATENT_BACKWARD_KEYS),
+    ))
     operands = tuple(
         x.astype(jnp.float32) for x in (q_latent, q_rope, k_latent, k_rope)
     ) + (

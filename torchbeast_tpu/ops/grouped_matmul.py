@@ -16,11 +16,12 @@ that read the FLOAT32 operands and cut every tile after it is loaded:
 one call a product, the operands read once a tile visit, the result
 written once, no term ever in HBM.
 
-**Same arithmetic.** The terms are `ops/bf16_terms.py` `bf16_terms`'s, the
-products the same `terms (terms + 1) / 2`, the smallest added first,
-every sum float32. What differs is the order of summation: a tile's
-products are added to one another before they are added to the running
-sum over the contracted tiles, where three calls each summed alone.
+**Same arithmetic.** The terms are `ops/bf16_terms.py` `bf16_terms`'s
+(`cut_in_kernel` beside it), the products the same `terms (terms + 1)
+/ 2` (`product_in_kernel`), the smallest added first, every sum
+float32. What differs is the order of summation: a tile's products are
+added to one another before they are added to the running sum over the
+contracted tiles, where three calls each summed alone.
 
 **The cut is a cast there and back.** `lax.reduce_precision` has no
 Mosaic lowering; `x.astype(bfloat16).astype(float32)`, which XLA folds
@@ -44,6 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.pallas.ops.tpu.megablox.gmm import make_group_metadata
+
+from torchbeast_tpu.ops.bf16_terms import product_in_kernel
 
 # What a call's tiles may take of the 16 MiB of VMEM a kernel is given
 # unasked: the operands' and the result's double buffers, the running
@@ -84,36 +87,6 @@ def tiles(tm, k, n, terms, over_rows=False):
             if _tile_bytes(operands, result, terms) <= _VMEM_BUDGET:
                 return tk, tn
     return _divisors(k)[-1], _divisors(n)[-1]
-
-
-def _cut(x, terms):
-    """A float32 tile as `terms` bfloat16 tiles, the largest first."""
-    out = []
-    for term in range(terms):
-        head = x.astype(jnp.bfloat16)
-        out.append(head)
-        if term + 1 < terms:
-            x = x - head.astype(jnp.float32)
-    return out
-
-
-def _product(lhs, rhs, terms, dims):
-    """lhs x rhs over `dims` as the passes of `terms` terms a side, the
-    smallest first, so that they are not lost one by one beside the
-    largest; float32."""
-    lhs, rhs = _cut(lhs, terms), _cut(rhs, terms)
-    out = None
-    for order in reversed(range(terms)):
-        for i in range(order + 1):
-            part = jax.lax.dot_general(
-                lhs[i], rhs[order - i], dims,
-                # Whatever the caller traces under: Mosaic refuses a
-                # bfloat16 operand at a float32 contraction.
-                precision=jax.lax.Precision.DEFAULT,
-                preferred_element_type=jnp.float32,
-            )
-            out = part if out is None else out + part
-    return out
 
 
 def _rows_of_group(grid_id, metadata, tm, width):
@@ -197,7 +170,7 @@ def gmm(lhs, rhs, group_sizes, *, terms, tm, tiling=None, group_offset=None,
         def _zero():
             acc[...] = jnp.zeros_like(acc)
 
-        acc[...] += _product(lhs[...], rhs[...], terms, dims)
+        acc[...] += product_in_kernel(lhs[...], rhs[...], terms, dims)
 
         @pl.when(k_i == tiles_k - 1)
         def _store():
@@ -299,7 +272,7 @@ def tgmm(lhs, rhs, group_sizes, *, terms, tm, tiling=None, group_offset=None,
         # An empty group is visited once, for its zeros alone.
         @pl.when(group_offsets[group + 1] > group_offsets[group])
         def _accumulate():
-            acc[...] += _product(
+            acc[...] += product_in_kernel(
                 jnp.where(
                     _rows_of_group(grid_id, metadata, tm, tk), lhs[...], 0.0
                 ),
