@@ -14,7 +14,7 @@ through `jax.jit`:
   gradient is `jax.jit(jax.grad(...))` around `module.apply` in the
   case itself. A plain `jnp` function under test is called through
   `jax.jit` too, once a shape.
-- For the seven policy families: the toy batch (`inputs`,
+- For the eight policy families: the toy batch (`inputs`,
   `learner_batch`), `build(family, **overrides) -> (model, params)`,
   `expert_layer(family, held)`, `warm_state`, `reference_config`, and
   jitted callables for the four programs the cases run again and again
@@ -73,6 +73,7 @@ from perfbench.reference import (
     nemotron3_policy,
     olmoe_policy,
     ouro_policy,
+    phi4flash_policy,
     qwen3next_policy,
 )
 from torchbeast_tpu import learner as learner_lib
@@ -83,6 +84,7 @@ from torchbeast_tpu.models import (
     Nemotron3Net,
     OLMoENet,
     OuroNet,
+    Phi4FlashNet,
     Qwen3NextNet,
     kanana2,
     lfm2,
@@ -91,6 +93,7 @@ from torchbeast_tpu.models import (
     nemotron3,
     olmoe,
     ouro,
+    phi4flash,
     qwen3next,
 )
 from torchbeast_tpu.runtime.state_table import DeviceStateTable
@@ -249,6 +252,27 @@ def _perturb_lfm2(model, params):
     return {"params": inner}
 
 
+def _perturb_phi4flash(model, params):
+    # And everything else the family starts at zero or one: every bias
+    # of a projection, every norm's scale and bias, the scan's skip `D`;
+    # a seed a leaf moved.
+    inner = _with_extras(params["params"])
+    seeds = iter(range(1, 1000))
+
+    def moved(tree):
+        return {
+            name: moved(leaf) if isinstance(leaf, dict)
+            else leaf + _normal(next(seeds), leaf.shape, 0.3)
+            if name in ("bias", "scale", "D") else leaf
+            for name, leaf in sorted(tree.items())
+        }
+
+    for name in sorted(inner):
+        if name.startswith("block_") or name == "final_norm":
+            inner[name] = moved(inner[name])
+    return {"params": inner}
+
+
 def _config_olmoe(model):
     return {
         "num_attention_heads": model.num_heads,
@@ -392,6 +416,23 @@ def _config_lfm2(model):
         "use_expert_bias": model.use_expert_bias,
         "routed_scaling_factor": model.routed_scaling,
         "bias_update_rate": model.bias_update_rate,
+    }
+
+
+def _config_phi4flash(model):
+    return {
+        "hidden_size": model.d_model,
+        "num_attention_heads": model.num_heads,
+        "num_key_value_heads": model.num_key_value_heads,
+        "intermediate_size": model.intermediate_size,
+        "layer_norm_eps": model.layer_norm_eps,
+        "sliding_window": model.sliding_window,
+        "mlp_bias": model.mlp_bias,
+        "published_num_hidden_layers": model.published_layers,
+        "layers_run": list(model.published_indices()),
+        "num_hidden_layers": model.num_layers,
+        "d_state": model.d_state, "d_conv": model.d_conv,
+        "expand": model.expand, "dt_rank": model.dt_rank,
     }
 
 
@@ -657,6 +698,19 @@ FAMILIES = {
             )),
             tol=1e-5, uncut=dict(num_experts=32, top_k=4),
         ),
+    ),
+    # Published layers 14-19, one pair of each stage: 8 query heads of 4
+    # on 4 key heads (4 query pairs, 2 key pairs, values of 8); a
+    # sliding window of 4 keys (3 slots) and a full cache of 5; Mamba-1
+    # states of [4, 64] (T=6: one chunk of the `lax.scan`).
+    "phi4flash": Family(
+        Phi4FlashNet, phi4flash, phi4flash_policy,
+        dict(
+            d_model=32, num_heads=8, num_key_value_heads=4,
+            intermediate_size=48, sliding_window=4, d_state=4, dt_rank=2,
+            num_layers=6, memory_len=5,
+        ),
+        _config_phi4flash, _perturb_phi4flash,
     ),
 }
 

@@ -244,6 +244,53 @@ def _published_lfm2(model):
     )
 
 
+def _published_phi4flash(model):
+    assert model.zero_init_extras
+    assert (
+        model.d_model, model.num_heads, model.num_key_value_heads,
+        model.intermediate_size, model.sliding_window, model.mb_per_layer,
+    ) == (2560, 40, 20, 10240, 512, 2)
+    assert model.layer_norm_eps == 1e-5
+    assert not model.mlp_bias
+    assert (model.d_state, model.d_conv, model.expand, model.dt_rank) == (
+        16, 4, 2, 160
+    )
+    assert model.dt_rank == -(-model.d_model // 16)
+    assert model.memory_len == 4095
+    assert model.matmul_precision == "high"
+    # The cut: published layers 14-19, one pair of each stage.
+    assert model.published_indices() == (14, 15, 16, 17, 18, 19)
+    assert model.kinds() == (
+        "mamba", "sliding", "mamba", "full", "memory", "cross"
+    )
+    carried = Recurrent(((16, 5120), (3, 5120)))
+    assert model.layer_caches() == (
+        carried, (511, 20, 64), carried, (4095, 20, 64), None, None
+    )
+    assert model.layer_shares() == (
+        ((), ()), ((), ()), (("memory",), ()), (("keys_values",), ()),
+        ((), ("memory",)), ((), ("keys_values",)),
+    )
+    whole = create_model("phi4flash", num_actions=6)
+    kinds = whole.kinds()
+    assert len(kinds) == 32 and whole.published_indices() == tuple(range(32))
+    assert [kinds.count(k) for k in (
+        "mamba", "sliding", "full", "memory", "cross"
+    )] == [9, 8, 1, 7, 7]
+    assert kinds[14:20] == model.kinds()
+    # The cell's three attentions as ONE grouped attention of 40 query
+    # heads on 10 key/value heads of 128 (2.85 GB of f32 scores over the
+    # full window's 4,351 keys at B=16, 0.50 over the sliding layer's
+    # 767) are `fused_attend`'s; a T=1 act step is not.
+    for keys in (4351, 767):
+        assert attention.fused_pass_applies(
+            (16, 256, 40, 128), (16, keys, 10, 128), None
+        )
+    assert not attention.fused_pass_applies(
+        (16, 1, 40, 128), (16, 4096, 10, 128), None
+    )
+
+
 # family: how the cell builds it, the depth of the published model, its
 # own assertions, and what the registry refuses beside `use_lstm`.
 REGISTRY = {
@@ -281,6 +328,12 @@ REGISTRY = {
               for bad in [4, 1, 8]),
             *(_refused("expert_share", expert_share=bad)
               for bad in [(4, 4), (0, 3), (-1, 4)]),
+        ],
+    ),
+    "phi4flash": (
+        dict(num_layers=6), 32, _published_phi4flash, [
+            *(_refused("whole pairs of layers around", num_layers=bad)
+              for bad in [4, 7, 34]),
         ],
     ),
 }
@@ -455,10 +508,39 @@ def _flags_lfm2(parse, build):
     return model, ["--model", "lfm2", "--num_layers", "3"]
 
 
+def _flags_phi4flash(parse, build):
+    flags = parse([
+        "--model", "phi4flash", "--num_layers", "8", "--memory_len", "9",
+    ])
+    assert (flags.model, flags.num_layers, flags.memory_len) == (
+        "phi4flash", 8, 9
+    )
+    model = build(flags)
+    assert (model.num_layers, model.memory_len, model.d_model) == (8, 9, 32)
+    # Published layers 12-19: two pairs before the boundary's, one after;
+    # the sliding layers carry the (shrunken) window less one.
+    assert model.published_indices() == tuple(range(12, 20))
+    assert [
+        None if e is None else type(e) is tuple for e in model.layer_caches()
+    ] == [False, True, False, True, False, True, None, None]
+    assert [e[0] for e in model.layer_caches()[1:6:2]] == [3, 3, 9]
+    with pytest.raises(ValueError, match="an even number from 6 to 32"):
+        build(parse(["--model", "phi4flash", "--num_layers", "5"]))
+    # A dense model whose layers are whole on a chip: no share to take.
+    for flag, value in (("--expert_share", "0/4"), ("--mixer_share", "0/2"),
+                        ("--num_experts", "4")):
+        with pytest.raises(ValueError):
+            build(parse(["--model", "phi4flash", flag, value]))
+    with pytest.raises(ValueError, match="use_lstm"):
+        build(parse(["--model", "phi4flash", "--use_lstm"]))
+    return model, ["--model", "phi4flash", "--num_layers", "6"]
+
+
 FLAGS = {
     "olmoe": _flags_olmoe, "mellum2": _flags_mellum2, "ouro": _flags_ouro,
     "kanana2": _flags_kanana2, "nemotron3": _flags_nemotron3,
     "qwen3next": _flags_qwen3next, "lfm2": _flags_lfm2,
+    "phi4flash": _flags_phi4flash,
 }
 
 
@@ -641,6 +723,12 @@ STATS_AT_PR_44 = {
         "moe_assignments", "moe_bias_abs_max", "moe_bias_steps",
         "moe_held_assignments", "moe_held_load_max_over_mean",
         "moe_load_max_over_mean",
+    ],
+    # The family of PR 55, as it came.
+    "phi4flash": [
+        "attention_differential_applications", "shared_bytes_per_row",
+        "shared_kv_readers", "shared_memory_readers", "ssm_applications",
+        "ssm_chunks", "ssm_resets_per_row", "ssm_state_bytes_per_row",
     ],
 }
 _HELD = {

@@ -47,6 +47,14 @@ REMAT = {
         ["moe_held_assignments", "conv_layers", "conv_resets_per_row",
          "conv_state_bytes_per_row"], [],
     ),
+    # The values handed on are inputs and outputs of the rematerialised
+    # blocks; ends inside the unroll and on step 0.
+    "phi4flash": (
+        {}, [(2, 0), (4, 0), (0, 1), (3, 1)], 1e-5, (0, 2e-5),
+        ["ssm_applications", "ssm_chunks", "ssm_resets_per_row",
+         "shared_memory_readers", "shared_kv_readers",
+         "shared_bytes_per_row", "attention_differential_applications"], [],
+    ),
 }
 
 

@@ -204,6 +204,73 @@ def test_lfm2_scope_reaches_the_compiled_hlo(lfm2_op_names, scope):
     assert scope in device_scopes.known_device_scopes()
 
 
+# --- a family's own scopes (PR 55: `--model phi4flash`) ----------------------
+
+PHI4FLASH_SCOPES = (
+    "mamba1_in_proj", "mamba1_conv", "mamba1_x_proj", "selective_scan",
+    "mamba1_out_proj", "attention_sliding", "attention_full",
+    "attention_cross", "attention_difference", "memory_unit", "mlp",
+)
+
+
+@pytest.fixture(scope="module")
+def phi4flash_compiled():
+    """The toy family's whole update, compiled (tests/family_scaffold.
+    py): (the `op_name`s a device trace of the cell is split by, the
+    update's stats from the same program's shapes)."""
+    model, params = scaffold.build("phi4flash")
+    t = scaffold.FAMILIES["phi4flash"].t
+    batch = scaffold.learner_batch(1, [(2, 0), (4, 1)], t=t)
+    hp = learner_lib.HParams(batch_size=scaffold.B, unroll_length=t - 1)
+    optimizer = optax.sgd(0.1)
+    update_step = learner_lib.make_update_step(
+        model, optimizer, hp, donate=False
+    )
+    operands = (
+        params, optimizer.init(params), batch,
+        model.initial_state(scaffold.B),
+    )
+    compiled = update_step.lower(*operands).compile()
+    stats = jax.eval_shape(update_step, *operands)[2]
+    return re.findall(r'op_name="([^"]+)"', compiled.as_text()), stats
+
+
+@pytest.mark.parametrize("scope", PHI4FLASH_SCOPES)
+def test_phi4flash_scope_reaches_the_compiled_hlo(phi4flash_compiled, scope):
+    op_names, _ = phi4flash_compiled
+    inside = [n for n in op_names if _in_scope(n, scope)]
+    assert inside, f"no compiled op carries the scope {scope!r}"
+    if scope == "attention_difference":
+        # Inside each of the three layers that attend, and nowhere else.
+        for outer in ("attention_sliding", "attention_full",
+                      "attention_cross"):
+            assert any(_in_scope(n, outer) for n in inside), outer
+        assert all(
+            any(_in_scope(n, outer) for outer in PHI4FLASH_SCOPES[5:8])
+            for n in inside
+        )
+    # The account knows the names from the lines that enter them.
+    assert scope in device_scopes.known_device_scopes()
+
+
+def test_phi4flash_counters_are_the_updates_stats(phi4flash_compiled):
+    """What the layers sow reaches the update's stats, a gauge each:
+    `ssm_*` as Nemotron-3's Mamba-2 layers sow them, the readers of the
+    two handed-on values, the bytes a row hands on, the differential
+    attentions."""
+    from torchbeast_tpu.models import stats as model_stats
+
+    _, stats = phi4flash_compiled
+    for name in (
+        "ssm_applications", "ssm_chunks", "ssm_resets_per_row",
+        "ssm_state_bytes_per_row", "shared_memory_readers",
+        "shared_kv_readers", "shared_bytes_per_row",
+        "attention_differential_applications",
+    ):
+        assert name in stats, name
+        assert model_stats.gauge_name(name) == name.replace("_", ".", 1)
+
+
 def _kernel_calls(jaxpr, under=""):
     """(name stack, kernel name) of every Pallas kernel call in a
     jaxpr, calls inside calls too: what the compiled module's

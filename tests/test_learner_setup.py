@@ -169,13 +169,13 @@ FAMILY_FIELD_CASES = {
         "3", 3, "olmoe", "pipelined_transformer",
         "--num_layers is a positive depth or window of --model "
         "transformer or olmoe or mellum2 or ouro or kanana2 or nemotron3 "
-        "or qwen3next or lfm2",
+        "or qwen3next or lfm2 or phi4flash",
     ),
     "memory_len": (
         "9", 9, "transformer", "deep",
         "--memory_len is a positive depth or window of --model "
         "transformer or olmoe or mellum2 or ouro or kanana2 or nemotron3 "
-        "or qwen3next or lfm2",
+        "or qwen3next or lfm2 or phi4flash",
     ),
     "num_experts": (
         "4", 4, "transformer", "olmoe",
@@ -280,6 +280,7 @@ def test_refusals_are_stated_on_the_class():
         "nemotron3": ("num_experts", "attention_impl"),
         "qwen3next": ("num_experts", "attention_impl"),
         "lfm2": ("num_experts", "attention_impl"),
+        "phi4flash": ("num_experts", "attention_impl"),
     }
     kv_cache = [
         name for name in models.MODEL_NAMES
@@ -287,13 +288,13 @@ def test_refusals_are_stated_on_the_class():
     ]
     assert kv_cache == [
         "transformer", "pipelined_transformer", "olmoe", "mellum2", "ouro",
-        "kanana2", "nemotron3", "qwen3next", "lfm2",
+        "kanana2", "nemotron3", "qwen3next", "lfm2", "phi4flash",
     ]
     for name in models.MODEL_NAMES:
         # test_families has the published families'
         if name in kv_cache and name not in (
             "olmoe", "mellum2", "ouro", "kanana2", "nemotron3", "qwen3next",
-            "lfm2",
+            "lfm2", "phi4flash",
         ):
             with pytest.raises(ValueError, match="KV cache"):
                 models.create_model(name, num_actions=A, use_lstm=True)

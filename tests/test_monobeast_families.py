@@ -72,6 +72,17 @@ def _lfm2_through_main(stats):
     assert stats["aux_loss"] == 0.0
 
 
+def _phi4flash_through_main(stats):
+    assert stats["ssm_applications"] == 2
+    # Two Mamba layers' states [4, 64] and tails of 3 over 64 channels.
+    assert stats["ssm_state_bytes_per_row"] == 2 * 4 * (4 + 3) * 64
+    assert stats["ssm_resets_per_row"] >= 0
+    assert stats["shared_memory_readers"] == 1
+    assert stats["shared_kv_readers"] == 1
+    assert stats["attention_differential_applications"] == 3
+    assert stats["aux_loss"] == 0.0
+
+
 def _ouro_through_main(stats):
     assert stats["loop_passes"] == 3
     assert stats["loop_block_applications"] == 6
@@ -105,6 +116,10 @@ _MELLUM2_WIDTHS = dict(
 #   the experts: acting through the two-step tails (entries of ONE
 #   leaf) AND the rolling cache of un-rotated keys, the biases moved by
 #   the load after every optimizer step.
+#  phi4flash: published layers 14-19: acting through two Mamba-1 states
+#   with their tails and two windows of different lengths, the memory
+#   and the full layer's keys and values handed on inside every act
+#   step; the learner's updates scan in chunks, blocks rematerialised.
 #  ouro: 2 layers run 3 times, through 3 x 2 rolling caches.
 THROUGH_MAIN = {
     "mellum2-all-experts": (
@@ -158,6 +173,15 @@ THROUGH_MAIN = {
         ),
         dict(num_layers=3, expert_share="1/4", remat="all"),
         _lfm2_through_main,
+    ),
+    "phi4flash": (
+        "phi4flash",
+        dict(
+            d_model=32, num_heads=8, num_key_value_heads=4,
+            intermediate_size=48, sliding_window=4, d_state=4, dt_rank=2,
+        ),
+        dict(num_layers=6, remat="all"),
+        _phi4flash_through_main,
     ),
     "ouro": (
         "ouro",

@@ -39,6 +39,12 @@ def _lfm2_trained(stats):
     assert stats["moe_bias_abs_max"] >= 0.001 - 1e-9
 
 
+def _phi4flash_trained(stats):
+    assert stats["ssm_applications"] == 2
+    assert stats["shared_memory_readers"] == 1
+    assert stats["shared_kv_readers"] == 1
+
+
 # A family a row (a `model_config` PR adds one: tests/family_scaffold.py):
 # what its `PUBLISHED` table is shrunk to, its depth, and what the last
 # update's stats must say.
@@ -53,7 +59,17 @@ def _lfm2_trained(stats):
 #   the attention layer's window; the delta rule runs in chunks.
 #  lfm2: the slots hold two conv layers' two-step tails (entries of one
 #   leaf) beside the attention layer's window.
+#  phi4flash: the slots hold two Mamba-1 states with their tails and two
+#   windows, and nothing for the two layers that read another layer's
+#   values: the act step hands those on inside itself.
 FAMILIES = {
+    "phi4flash": (
+        dict(
+            d_model=32, num_heads=8, num_key_value_heads=4,
+            intermediate_size=48, sliding_window=4, d_state=4, dt_rank=2,
+        ),
+        6, _phi4flash_trained,
+    ),
     "ouro": (
         dict(d_model=32, num_heads=4, head_dim=8, mlp_width=48, passes=3),
         2, _ouro_trained,

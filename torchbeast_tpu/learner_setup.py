@@ -182,9 +182,9 @@ def add_learner_arguments(parser, *, model_default,
     parser.add_argument("--num_layers", type=int, default=0,
                         help="Depth of --model transformer, olmoe, "
                              "mellum2, ouro, kanana2, nemotron3, "
-                             "qwen3next or lfm2 (0: "
+                             "qwen3next, lfm2 or phi4flash (0: "
                              "the family's own, 2 and the published 16, "
-                             "28, 48, 48, 88, 48 and 24; mellum2 in whole "
+                             "28, 48, 48, 88, 48, 24 and 32; mellum2 in whole "
                              "periods of 4; ouro runs the layers it has "
                              "4 times a step; kanana2: its leading "
                              "dense layer and the MoE layers after it, "
@@ -194,14 +194,18 @@ def add_learner_arguments(parser, *, model_default,
                              "and one gated attention layer; lfm2 as 1 + "
                              "4k: its last leading dense layer, then "
                              "whole periods of one attention layer and "
-                             "three gated short convolutions).")
+                             "three gated short convolutions; phi4flash "
+                             "as whole pairs around its stage boundary, "
+                             "an even number from 6: 6 is published "
+                             "layers 14-19).")
     parser.add_argument("--memory_len", type=int, default=0,
                         help="Steps of its own past a transformer, "
                              "olmoe, mellum2, ouro, kanana2, nemotron3, "
-                             "qwen3next or lfm2 "
+                             "qwen3next, lfm2 or phi4flash "
                              "policy attends over, carried as the "
                              "rolling KV cache (0: the family's own, 64, "
-                             "128, 4095, 255, 4095, 4095, 4095 and 4095; "
+                             "128, 4095, 255, 4095, 4095, 4095, 4095 and "
+                             "4095; "
                              "mellum2: "
                              "its full layers' cache, the sliding "
                              "layers carry min(memory_len, 1023); ouro: "
@@ -212,7 +216,10 @@ def add_learner_arguments(parser, *, model_default,
                              "qwen3next: its attention layers', the "
                              "DeltaNet layers carry a matrix state; "
                              "lfm2: its attention layers', the conv "
-                             "layers carry two values).")
+                             "layers carry two values; phi4flash: its "
+                             "full layer's, which the cross layers read "
+                             "too; a sliding layer carries min(memory_len, "
+                             "511), a Mamba layer a state).")
     parser.add_argument("--expert_share", default="",
                         help="--model mellum2, kanana2, nemotron3, "
                              "qwen3next or lfm2: "
@@ -316,7 +323,7 @@ def add_learner_arguments(parser, *, model_default,
                              "whose class has the `blocks` lever "
                              "(transformer, pipelined_transformer, "
                              "mellum2, ouro, kanana2, nemotron3, "
-                             "qwen3next, lfm2; not olmoe), the "
+                             "qwen3next, lfm2, phi4flash; not olmoe), the "
                              "LSTM scan): 'auto' picks the "
                              "minimum-recompute plan whose XLA-measured "
                              "peak fits --hbm_budget_gb; 'all'/'none' "

@@ -17,6 +17,7 @@ from torchbeast_tpu.models import (
     nemotron3,
     olmoe,
     ouro,
+    phi4flash,
     qwen3next,
 )
 from torchbeast_tpu.models.kanana2 import Kanana2Net  # noqa: F401
@@ -25,6 +26,7 @@ from torchbeast_tpu.models.mellum2 import Mellum2Net  # noqa: F401
 from torchbeast_tpu.models.nemotron3 import Nemotron3Net  # noqa: F401
 from torchbeast_tpu.models.olmoe import OLMoENet  # noqa: F401
 from torchbeast_tpu.models.ouro import OuroNet  # noqa: F401
+from torchbeast_tpu.models.phi4flash import Phi4FlashNet  # noqa: F401
 from torchbeast_tpu.models.pipelined import PipelinedMLPNet  # noqa: F401
 from torchbeast_tpu.models.qwen3next import Qwen3NextNet  # noqa: F401
 from torchbeast_tpu.models.resnet import ResNet  # noqa: F401
@@ -49,6 +51,7 @@ _REGISTRY = {
     "nemotron3": Nemotron3Net,
     "qwen3next": Qwen3NextNet,
     "lfm2": Lfm2Net,
+    "phi4flash": Phi4FlashNet,
 }
 # A family whose widths are a published table (its module's `PUBLISHED`,
 # keyed by the class's fields): read when the model is built, so that a
@@ -56,6 +59,7 @@ _REGISTRY = {
 _PUBLISHED_TABLES = {
     OLMoENet: olmoe, Mellum2Net: mellum2, OuroNet: ouro, Kanana2Net: kanana2,
     Nemotron3Net: nemotron3, Qwen3NextNet: qwen3next, Lfm2Net: lfm2,
+    Phi4FlashNet: phi4flash,
 }
 MODEL_NAMES = tuple(_REGISTRY)
 

@@ -21,7 +21,9 @@ FOLDS = {
 }
 COLLECTIONS = tuple("stats_" + fold for fold in FOLDS)
 # A stat is `<family>_<name>`, its gauge `<family>.<name>`.
-FAMILIES = ("moe", "ssm", "delta", "conv", "loop", "attention", "obs")
+FAMILIES = (
+    "moe", "ssm", "delta", "conv", "loop", "attention", "shared", "obs"
+)
 
 
 def sow_stat(module, name: str, value, fold: str) -> None:

@@ -531,7 +531,8 @@ class TransformerNet(nn.Module):
         final_norm = self.make_final_norm()
         walk = self.block_passes()
         entries, carried = iter(self.layer_caches()), iter(core_state)
-        blocks, new_state = {}, []
+        shares = iter(self.layer_shares())
+        blocks, new_state, handed = {}, [], {}
         for blocks_of_pass in walk:
             with device_scope("loop_pass") if len(walk) > 1 else (
                 contextlib.nullcontext()
@@ -542,16 +543,22 @@ class TransformerNet(nn.Module):
                             f"block_{layer}", layer
                         )
                     entry = next(entries)
+                    # What earlier blocks handed on for this one, by
+                    # name (`layer_shares`): nothing in a family whose
+                    # layers read x and their own state alone.
+                    gives, takes = next(shares)
+                    received = {name: handed[name] for name in takes}
                     if entry is None:
                         # A layer that carries nothing (a feed-forward
                         # part alone, models/nemotron3.py).
-                        x = blocks[layer](x)
+                        x = blocks[layer](x, **received)
                         continue
                     if isinstance(entry, Recurrent):
-                        x, leaves = blocks[layer](
-                            x, next(carried), done=done.T
+                        x, leaves, *given = blocks[layer](
+                            x, next(carried), done=done.T, **received
                         )
                         new_state.append(tuple(leaves))
+                        handed.update(zip(gives, given, strict=True))
                         continue
                     k_cache, v_cache, valid = next(carried)
                     cache_band, seq_mask = geometry[entry[0]]
@@ -568,7 +575,16 @@ class TransformerNet(nn.Module):
                     x, k_new, v_new = blocks[layer](
                         x, (k_cache, v_cache), cache_mask, seq_mask,
                         seg=seg, cache_valid=valid_b, no_done=no_done_yet,
+                        **received,
                     )
+                    for name in gives:
+                        # What the layer attended over: its cache
+                        # BEFORE the roll, this unroll's keys and
+                        # values, the two legs' masks.
+                        handed[name] = (
+                            (k_cache, v_cache), k_new, v_new, cache_mask,
+                            seq_mask,
+                        )
 
                     # Roll the cache where it lies: last M of [old
                     # cache; this unroll] on axis 0, validity restricted
@@ -647,6 +663,25 @@ class TransformerNet(nn.Module):
         the next entry, and `make_final_norm()`'s norm follows every
         pass. Here one pass, block i on entry i."""
         return (tuple(range(len(self.layer_caches()))),)
+
+    @nn.nowrap
+    def layer_shares(self) -> Tuple[Tuple[Tuple[str, ...], ...], ...]:
+        """(gives, takes) a `layer_caches()` entry: the names of the
+        values the entry's block hands on to the blocks after it, and of
+        those it receives from blocks before it as keyword arguments
+        (models/phi4flash.py: layers that read one earlier layer's
+        state-space output, or attend over one earlier layer's keys and
+        values). A `Recurrent` entry's block returns a value a name
+        after its leaves; for a window entry that gives (one name) the
+        walk hands on what the block attended over, `((k, v) of its
+        cache as the state held it, this unroll's k, v, cache_mask,
+        seq_mask)`. The values are of this unroll and are never carried:
+        the T=1 act step hands them on as an unroll does. Handed through
+        the blocks' arguments and results, they are inputs and outputs
+        of a rematerialised block and their gradients sum over their
+        readers. Here, and in every family but that one, no layer gives
+        or takes."""
+        return (((), ()),) * len(self.layer_caches())
 
     def initial_state(self, batch_size: int) -> Tuple:
         def window(M, heads, size):
