@@ -152,6 +152,20 @@ def forward_stats(model, params, rows, done_steps, t):
     return stats
 
 
+def with_zeroed(params, blocks, leaves=("router",)):
+    """`params` with the named leaves of each named block's `moe` at
+    zero: a router of zeros sends every token to experts 0 .. K-1
+    (ties go to the first)."""
+    inner = dict(params["params"])
+    for name in blocks:
+        block = dict(inner[name])
+        block["moe"] = dict(block["moe"], **jax.tree_util.tree_map(
+            jnp.zeros_like, {leaf: block["moe"][leaf] for leaf in leaves}
+        ))
+        inner[name] = block
+    return {"params": inner}
+
+
 def _normal(seed, shape, scale):
     return scale * jax.random.normal(jax.random.PRNGKey(seed), shape)
 

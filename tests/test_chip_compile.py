@@ -412,6 +412,70 @@ def test_nemotron3_dispatch_moves_the_windows_rows_alone_on_v5e(
     assert text.count("gmm_cut_in_vmem") >= 8
 
 
+# tokens, top_k, experts, held, d, width, the precision the family traces
+# under, the rung: the two cells that hold a QUARTER of their experts.
+QUARTER_SHARE_CELLS = {
+    "lfm2": (4096, 4, 32, 8, 2048, 1792, "high", 5120),
+    "mellum2": (2592, 8, 64, 16, 2304, 896, "default", 6656),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(QUARTER_SHARE_CELLS))
+def test_quarter_share_sweeps_a_rung_on_v5e(one_chip, monkeypatch, cell):
+    """`jax.grad` through `dropless_experts` at the LFM2 and Mellum2
+    cells' layer shapes (8 of 32 held under 4 a token at `high`: the
+    kernels that cut in VMEM; 16 of 64 under 8 at one bf16 pass: the
+    shipped kernels), for a described v5e. Since PR 56 a quarter share
+    with `held >= top_k` sweeps a rung of 1.25 times the even load
+    (5,120 of 16,384 sorted rows; 6,656 of 20,736): the kernels, the
+    activation and the operand casts see the rung's rows, no array of
+    the experts' width is as long as all the sorted rows, and the only
+    arrays of the model's width that long are the two gathers by
+    `slot` (the forward's sum and the dispatch's gradient; the gates'
+    gradient reads scalars back). Each loop holds one copy of the
+    kernels: three products forward, those and six backward."""
+    from torchbeast_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tokens, top_k, experts, held, d, width, precision, rung = (
+        QUARTER_SHARE_CELLS[cell]
+    )
+    rows = tokens * top_k
+    assert moe.window_rungs(tokens, top_k, held, experts) == (rung, rows)
+
+    def loss(x, gate, w_gate, w_up, w_down, idx):
+        with jax.default_matmul_precision(precision):
+            y, _ = moe.dropless_experts(
+                x, idx, gate, w_gate, w_up, w_down, first_of=(held, experts)
+            )
+        return jnp.sum(jnp.sin(y))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        _struct(one_chip, (tokens, d)),
+        _struct(one_chip, (tokens, top_k)),
+        _struct(one_chip, (held, d, width)),
+        _struct(one_chip, (held, d, width)),
+        _struct(one_chip, (held, width, d)),
+        _struct(one_chip, (tokens, top_k), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert f"[{rows},{width}]" not in text
+    assert f"f32[{rung},{width}]" in text and f"f32[{rung},{d}]" in text
+    assert "/jvp(moe_sweep)/while/body/moe_experts" in text
+    assert (
+        "/transpose(jvp(moe_sweep))/while/body/jvp(moe_experts)" in text
+    )
+    assert text.count("tpu_custom_call") == 12
+    # What is written as long as all the sorted rows at the model's
+    # width: the two gathers by `slot`, [tokens, K, d] or flat.
+    long_rows = rf"= f32\[({tokens},{top_k},{d}|{rows},{d})\]"
+    long_gathers = [
+        line for line in text.splitlines()
+        if re.search(long_rows, line) and "gather" in line.split("=")[0]
+    ]
+    assert len(long_gathers) == 2, long_gathers
+
+
 def test_flagship_act_step_compiles_for_v5e(one_chip, monkeypatch):
     """The acting program at the largest inference bucket."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")

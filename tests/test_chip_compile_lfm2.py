@@ -95,7 +95,14 @@ def test_lfm2_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     program); the four conv layers' tails [2, B, 2048] are its
     arguments; the frames enter `Dense_0` as bfloat16 integers (PR 52);
     the experts' products are one kernel call each at the family's two
-    terms a side (PR 50)."""
+    terms a side (PR 50). Since PR 56 the experts sweep a rung of 5,120
+    of the 16,384 sorted rows in a loop body, forward and backward. The
+    compiler's account reads 8.82 GiB since (10.80 with the copy; 7.57
+    and 9.55 before): it counts the backward loops' carried weight
+    gradients, 352 MB a layer, beside the kernels' results they are
+    added to. On the chip the cell's peak did not move (10.248 GiB
+    against the parent's 10.245, PR 56's runs): the bound below is the
+    rule's, and this reading is the compiler's, not the chip's."""
     from perfbench import manifest
     from perfbench.drivers import learner as learner_driver
     from torchbeast_tpu import monobeast
@@ -161,17 +168,22 @@ def test_lfm2_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     assert not {s for s in shapes if int(np.prod(s)) >= frames}
     # The carried tails are the program's arguments.
     assert (2, rows, 2048) in shapes
-    # Four MoE layers x (3 forward, 3 the rematerialised forward, 6
-    # backward) grouped products, one kernel call each; beside them the
-    # attention layer's three.
+    # Four MoE layers x 12 grouped products, one kernel call each: 3
+    # in the forward sweep's loop body and 9 in the backward's (the
+    # rung's forward again, 6 backward); the rematerialised block's
+    # sweep is dead, its value unused. Until PR 56 the same 12 stood
+    # inline (3 forward, 3 rematerialised, 6 backward) over all 16,384
+    # rows. Beside them the attention layer's three.
     assert len(re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
     )) == 48
     assert text.count("tpu_custom_call") == 48 + 3
+    # The kernels and the SwiGLU's elementwise passes see a rung.
+    assert "f32[5120,1792]" in text and "[16384,1792]" not in text
     # The family's scopes reach the compiled program.
     for scope in (
         "conv_operator/conv_in_proj", "conv_operator/conv_gate_taps",
         "conv_operator/conv_out_proj", "/attention/", "dense_mlp",
-        "moe_route", "moe_experts",
+        "moe_route", "moe_experts", "moe_sweep",
     ):
         assert scope in text, scope

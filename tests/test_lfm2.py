@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from tests import family_scaffold as scaffold
 from tests.test_attention import _dense_body, _fused_case, _gradients_of
 from torchbeast_tpu import learner as learner_lib
-from torchbeast_tpu.models import Lfm2Net, lfm2
+from torchbeast_tpu.models import Lfm2Net, lfm2, moe
 from torchbeast_tpu.models.transformer import Recurrent
 from torchbeast_tpu.ops import attention
 
@@ -88,7 +88,49 @@ def test_family_agrees_with_the_reference(expert_share, ends):
         assert "moe_held_assignments" not in stats
     else:
         assert 0 < float(stats["moe_held_assignments"]) < 2 * 3 * T * B
+        # Two held under three chosen are a window of one rung; four
+        # held are no fewer than the three chosen, and at these 12
+        # tokens a rung of whole 256-row tiles (1.25 times the even
+        # load since PR 56, as twice it before) is over the 36 sorted
+        # rows: no window, the pin as it was. The quarter share SWEEPS
+        # from 192 tokens on: the next test.
         assert ("moe_window_rows" in stats) == (expert_share == (1, 8))
+
+
+@pytest.mark.parametrize(
+    "expert_share, even_router, sweeps",
+    [((1, 4), False, 1), ((1, 4), True, 0), ((0, 4), True, 3)],
+    ids=["as-routed", "no-row", "every-token-on-three-held-experts"],
+)
+def test_update_stats_say_how_far_the_quarter_share_was_swept(
+    expert_share, even_router, sweeps
+):
+    """PR 56: four of 16 experts held under three a token (a quarter
+    with `held >= K`, the cell's 8 of 32 under 4), 192 tokens. Twice
+    the even load's 144 rows in row tiles is 512, not under half the
+    576 sorted rows, and until PR 56 they were all permuted; 1.25 times
+    it is a rung of 256. The update's stats carry what the sweep took,
+    summed over the two MoE layers: one rung each as initialised; with
+    a router of zeros every token's three are experts 0, 1, 2 (ties go
+    to the first, the bias zeroed too), so none with experts 4-7 held, and
+    with 0-3 held all three rungs of the window, every one of the 576
+    assignments computed."""
+    rows = 32
+    model, params = scaffold.build("lfm2", expert_share=expert_share)
+    assert moe.window_rungs(T * rows, 3, 4, 16) == (256, 3 * T * rows)
+    if even_router:
+        # The router, and the bias the scaffold moved off zero.
+        params = scaffold.with_zeroed(
+            params, ("block_1", "block_2"),
+            ("router", "e_score_correction_bias"),
+        )
+    stats = scaffold.forward_stats(model, params, rows, ENDS, T)
+    held = float(stats["moe_held_assignments"]) / 2  # a layer
+    if even_router:
+        assert held == (3 * T * rows if sweeps else 0)
+    assert sweeps == -(-held // 256)
+    assert float(stats["moe_window_rows"]) == 2 * 256 * sweeps
+    assert float(stats["moe_window_short_applications"]) == 2 * (sweeps <= 1)
 
 
 @pytest.mark.parametrize("unrolls", [0, 2], ids=["empty", "warm"])

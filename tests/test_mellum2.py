@@ -48,9 +48,47 @@ def test_family_agrees_with_the_reference(share):
     else:
         assert 0 < float(stats["moe_held_assignments"]) < 2 * T * B * 4
         assert float(stats["moe_held_load_max_over_mean"]) >= 1.0
-    # As many held as chosen, or more: no window of the sorted rows.
+    # As many held as chosen, and at these 12 tokens a rung of whole
+    # 256-row tiles (1.25 times the even load since PR 56, as twice it
+    # before) is over the 24 sorted rows: no window, the pin as it was.
+    # The quarter share SWEEPS from 384 tokens on: the next test.
     assert "moe_window_rows" not in stats
     assert "moe_window_short_applications" not in stats
+
+
+@pytest.mark.parametrize(
+    "expert_share, even_router, sweeps",
+    [((1, 4), False, 1), ((1, 4), True, 0), ((0, 4), True, 3)],
+    ids=["as-routed", "no-row", "every-token-on-both-held-experts"],
+)
+def test_update_stats_say_how_far_the_quarter_share_was_swept(
+    expert_share, even_router, sweeps
+):
+    """PR 56: two of 8 experts held under two a token (a quarter with
+    `held >= K`, the cell's 16 of 64 under 8), 384 tokens. Twice the
+    even load's 192 rows in row tiles is 512, not under half the 768
+    sorted rows, and until PR 56 they were all permuted; 1.25 times it
+    is a rung of 256. The update's stats carry what the sweep took,
+    summed over the four layers: one rung each as initialised; with a
+    router of zeros every token's two are experts 0 and 1 (ties go to
+    the first), so none with experts 2-3 held, and with 0-1 held all
+    three rungs of the window, every one of the 768 assignments
+    computed."""
+    rows = 64
+    model, params = scaffold.build("mellum2", expert_share=expert_share)
+    assert moe.window_rungs(T * rows, 2, 2, 8) == (256, 2 * T * rows)
+    if even_router:
+        params = scaffold.with_zeroed(
+            params, ("block_0", "block_1", "block_2", "block_3")
+        )
+    stats = scaffold.forward_stats(model, params, rows, [(3, 1)], T)
+    held = float(stats["moe_held_assignments"]) / 4  # a layer
+    if even_router:
+        assert held == (2 * T * rows if sweeps else 0)
+    else:
+        assert 0 < held <= 256
+    assert float(stats["moe_window_rows"]) == 4 * 256 * sweeps
+    assert float(stats["moe_window_short_applications"]) == 4 * (sweeps <= 1)
 
 
 @pytest.mark.parametrize("unrolls", [0, 1, 2], ids=["empty", "part", "full"])

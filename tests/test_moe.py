@@ -7,6 +7,7 @@ overflow must drop, not corrupt."""
 
 import functools
 import re
+from unittest import mock
 
 import flax.linen as nn
 import jax
@@ -738,24 +739,23 @@ RUNG_TOKENS, RUNG_E, RUNG_FIRST = 512, 64, 7
 FEWER_HELD, MORE_HELD = (5, 2), (3, 4)
 
 
-def _routed_with(live, seed, top_k, held, whole=0):
+def _routed_with(live, seed, top_k, held, whole=0, tokens=RUNG_TOKENS,
+                 experts=RUNG_E, first=RUNG_FIRST):
     """idx [tokens, K], distinct experts a token, `live` of the
-    assignments on the `held` experts from RUNG_FIRST on; `whole`
+    assignments on the `held` experts from `first` on; `whole`
     tokens have every one of their min(K, held) on them."""
     rng = np.random.default_rng(seed)
-    others = [
-        e for e in range(RUNG_E) if not RUNG_FIRST <= e < RUNG_FIRST + held
-    ]
+    others = [e for e in range(experts) if not first <= e < first + held]
     idx = np.stack([
-        rng.choice(others, top_k, replace=False) for _ in range(RUNG_TOKENS)
+        rng.choice(others, top_k, replace=False) for _ in range(tokens)
     ])
     slots = min(top_k, held)
-    cells = [(t, c) for t in range(whole, RUNG_TOKENS) for c in range(slots)]
+    cells = [(t, c) for t in range(whole, tokens) for c in range(slots)]
     rng.shuffle(cells)
     cells = [(t, c) for t in range(whole) for c in range(slots)] + cells
-    turn = rng.integers(held, size=RUNG_TOKENS)  # which expert a rank meets
+    turn = rng.integers(held, size=tokens)  # which expert a rank meets
     for t, c in cells[:live]:
-        idx[t, c] = RUNG_FIRST + (c + turn[t]) % held
+        idx[t, c] = first + (c + turn[t]) % held
     return jnp.asarray(idx, jnp.int32)
 
 
@@ -796,6 +796,20 @@ def _rung_programs(gated, held, activation):
     return program(ours), program(plain)
 
 
+def _expert_operands(seed, tokens, top_k, held, d=8, f=16):
+    """(tangent, x, gate, w_gate, w_up, w_down) of `held` seeded
+    experts d -> f -> d, as `_rung_programs`' callables take them."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x = jax.random.normal(keys[0], (tokens, d))
+    gate = jax.random.uniform(keys[1], (tokens, top_k))
+    w_gate, w_up = (
+        jax.random.normal(k, (held, d, f)) / 3 for k in keys[2:4]
+    )
+    w_down = jax.random.normal(keys[4], (held, f, d)) / 4
+    tangent = jax.random.normal(keys[5], (tokens, d))
+    return tangent, x, gate, w_gate, w_up, w_down
+
+
 def _swept_against_the_experts_written_out(
     shape, live, gated, activation, whole=0
 ):
@@ -807,15 +821,9 @@ def _swept_against_the_experts_written_out(
     top_k, held = shape
     rungs = moe.window_rungs(RUNG_TOKENS, top_k, held, RUNG_E)
     assert rungs == (256, RUNG_TOKENS * min(top_k, held))
-    d, f = 8, 16
-    keys = jax.random.split(jax.random.PRNGKey(live), 6)
-    x = jax.random.normal(keys[0], (RUNG_TOKENS, d))
-    gate = jax.random.uniform(keys[1], (RUNG_TOKENS, top_k))
-    w_gate, w_up = (
-        jax.random.normal(k, (held, d, f)) / 3 for k in keys[2:4]
+    tangent, x, gate, w_gate, w_up, w_down = _expert_operands(
+        live, RUNG_TOKENS, top_k, held
     )
-    w_down = jax.random.normal(keys[4], (held, f, d)) / 4
-    tangent = jax.random.normal(keys[5], (RUNG_TOKENS, d))
     idx = _routed_with(live, live, top_k, held, whole)
     mine = jnp.bincount(idx.reshape(-1), length=RUNG_E)[
         RUNG_FIRST : RUNG_FIRST + held
@@ -884,6 +892,205 @@ def test_window_is_swept_where_as_many_are_held_as_chosen(live, gated, whole):
     assert int(jnp.sum(jnp.all(on_held, axis=1))) >= whole
 
 
+# A quarter of the experts held, as many as a token chooses or more
+# (LFM2's 8 of 32 under 4 at a quarter of its cell's tokens): twice the
+# even load is half the window, 1.25 times it a rung of 1,280 of 4,096.
+QUARTER_TOKENS, QUARTER_K, QUARTER_HELD, QUARTER_E, QUARTER_FIRST = (
+    1024, 4, 8, 32, 16
+)
+
+
+@functools.lru_cache(maxsize=None)
+def _quarter_programs(gated):
+    """(swept, permuted): jitted value and gradients of `dropless_
+    experts` at the quarter share as `window_rungs` has it, and the
+    program it traced before PR 56: with no window (`window_rungs`
+    answering () while THAT one is traced), every one of the tokens x K
+    sorted rows permuted and the other experts' rows skipped by the
+    kernels' `group_offset`."""
+    from torchbeast_tpu.models import moe
+
+    def program(rungs_rule):
+        def experts(x, gate, w_gate, w_up, w_down, idx):
+            with mock.patch.object(moe, "window_rungs", rungs_rule):
+                return moe.dropless_experts(
+                    x, idx, gate, w_gate if gated else None, w_up, w_down,
+                    first_of=(QUARTER_FIRST, QUARTER_E),
+                )[0]
+
+        return jax.jit(jax.value_and_grad(
+            lambda tangent, idx, *a: jnp.sum(experts(*a, idx) * tangent),
+            argnums=(2, 3, 4, 5, 6),
+        ))
+
+    return program(moe.window_rungs), program(lambda *shape: ())
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["swiglu", "two-matrix"])
+@pytest.mark.parametrize(
+    "live, rungs_swept",
+    [(None, 1), (1280, 1), (1281, 2), (2400, 2), (4096, 4), (0, 0)],
+    ids=["near-even-routing", "exactly-a-rung", "one-row-over-a-rung",
+         "pushed-onto-the-held-two-rungs", "every-rung", "no-row"],
+)
+def test_a_quarter_share_is_swept_and_is_the_full_permute(
+    live, rungs_swept, gated
+):
+    """PR 56: 8 of 32 experts held under 4 a token, 1,024 tokens. The
+    window is all 4,096 sorted rows and is swept 1,280 at a time; value
+    and the gradients of x, the gates and every weight (five with the
+    SwiGLU's `w_gate`, four without) equal the full permute's, the
+    program these shapes traced before: at a routing drawn as a router
+    draws it (top 4 of uniform scores: about 1,024 rows, one rung), at
+    exactly a rung and one row over, pushed onto the held experts (two
+    rungs), with EVERY assignment on them (all four rungs: nothing is
+    dropped at any load) and with none."""
+    from torchbeast_tpu.models import moe
+
+    tokens, top_k, held = QUARTER_TOKENS, QUARTER_K, QUARTER_HELD
+    rungs = moe.window_rungs(tokens, top_k, held, QUARTER_E)
+    assert rungs == (1280, tokens * top_k)
+    tangent, x, gate, w_gate, w_up, w_down = _expert_operands(
+        live or 7, tokens, top_k, held
+    )
+    if live is None:
+        _, idx = jax.lax.top_k(jax.random.uniform(
+            jax.random.PRNGKey(56), (tokens, QUARTER_E)
+        ), top_k)
+    else:
+        idx = _routed_with(
+            live, live, top_k, held, tokens=tokens, experts=QUARTER_E,
+            first=QUARTER_FIRST,
+        )
+    mine = jnp.bincount(idx.reshape(-1), length=QUARTER_E)[
+        QUARTER_FIRST : QUARTER_FIRST + held
+    ]
+    if live is not None:
+        assert int(jnp.sum(mine)) == live
+    assert int(moe.window_sweeps(rungs, mine)) == rungs_swept
+    swept, permuted = _quarter_programs(gated)
+    (got, got_grads), (want, want_grads) = (
+        program(tangent, idx, x, gate, w_gate, w_up, w_down)
+        for program in (swept, permuted)
+    )
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    names = ("x", "gate", "w_gate", "w_up", "w_down")
+    for name, a, b in zip(names, got_grads, want_grads):
+        if name == "w_gate" and not gated:
+            assert not np.any(a) and not np.any(b)
+            continue
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5, err_msg=name)
+        assert np.any(b) == bool(rungs_swept), name
+
+
+def test_a_quarter_share_moves_a_rung_of_rows_and_the_permute_all():
+    """What each of the two programs moves at the quarter share, in
+    the text XLA compiles (a `custom_vjp`'s unused forward value is
+    still in the jaxpr): swept, the kernels and the activation see the
+    rung's [1280, .] and no array of all 4,096 sorted rows at the
+    experts' hidden width exists, forward or backward; the gathers as
+    long as all the sorted rows are TWO (the forward's sum and the
+    dispatch's gradient; the gates' gradient reads [4096] scalars
+    back), the rung's three (dispatch forward, again backward, and
+    `grad[token]`). The full permute gathers all the rows four times
+    and holds the hidden width at 4,096 rows."""
+    tokens, top_k, held = QUARTER_TOKENS, QUARTER_K, QUARTER_HELD
+    d, f = 8, 16
+    rows = tokens * top_k
+    operands = (
+        jnp.zeros((tokens, d)), jnp.zeros((tokens, d)),
+        jnp.zeros((tokens, top_k)), jnp.zeros((held, d, f)),
+        jnp.zeros((held, d, f)), jnp.zeros((held, f, d)),
+    )
+    idx = jnp.zeros((tokens, top_k), jnp.int32)
+    swept, permuted = (
+        program.lower(operands[0], idx, *operands[1:]).compile().as_text()
+        for program in _quarter_programs(True)
+    )
+
+    def gathered(text):
+        """Elements of every f32 gather's result, most first."""
+        return sorted((
+            int(np.prod([int(n) for n in dims.split(",")]))
+            for dims in re.findall(r"f32\[([\d,]+)\][^=\n]* gather\(", text)
+        ), reverse=True)
+
+    assert "moe_sweep)/while" in swept and "moe_sweep" not in permuted
+    assert f"f32[1280,{f}]" in swept and f"f32[{rows},{f}]" not in swept
+    assert f"f32[{rows},{f}]" in permuted
+    assert gathered(swept)[:6] == [rows * d] * 2 + [1280 * d] * 3 + [rows]
+    assert gathered(permuted)[:4] == [rows * d] * 4
+    assert gathered(permuted)[4] < rows
+    for program in (swept, permuted):
+        # No rows are scatter-added (the kernels' own group metadata is
+        # a vector of a few floats).
+        assert not re.search(r"f32\[\d+,[\d,]+\][^=\n]* scatter\(", program)
+
+
+@pytest.mark.parametrize(
+    "live", [14, 0, 20], ids=["some-slots-fill", "all-fill", "none-fill"]
+)
+def test_window_sum_gradients_against_the_einsum_form(live):
+    """`_window_sum`'s backward (PR 56: one gather `g = grad[token]`,
+    the gates' gradient `sum(out * g, -1)` read back by `slot` as
+    scalars) against what it replaces, `einsum("tcd,td->tc", out[slot],
+    grad)` on a second gather of [tokens, slots, d], and against JAX's
+    own gradient of the sum written plainly (a scatter-add into `out`);
+    with slots that name no row (the fill: a token that chose fewer
+    held experts than it has slots), with none that does, and with
+    all."""
+    from torchbeast_tpu.models import moe
+
+    tokens, slots, window, d = 12, 3, 20, 7
+    rng = np.random.default_rng(live)
+    cells = [(t, c) for t in range(tokens) for c in range(slots)]
+    rng.shuffle(cells)
+    slot = np.full((tokens, slots), window)
+    token = np.zeros(window, np.int32)
+    for row, (t, c) in enumerate(cells[:live]):
+        slot[t, c], token[row] = row, t
+    keys = jax.random.split(jax.random.PRNGKey(live), 3)
+    out = jax.random.normal(keys[0], (window, d))
+    gate_held = jax.random.uniform(keys[1], (tokens, slots))
+    grad = jax.random.normal(keys[2], (tokens, d))
+    hit = slot < window
+    gate_rows = np.zeros(window, np.float32)
+    gate_rows[slot[hit]] = np.asarray(gate_held)[hit]
+    slot, token = jnp.asarray(slot), jnp.asarray(token)
+
+    def ours(out, gate_held):
+        return moe._window_sum(out, gate_held, token, slot, gate_rows)
+
+    def plain(out, gate_held):
+        picked = jnp.where(
+            hit[:, :, None], out[jnp.minimum(slot, window - 1)], 0.0
+        )
+        return jnp.sum(picked * gate_held[:, :, None], axis=1)
+
+    def pulled_back(f):
+        return jax.jit(lambda out, gate_held: jax.vjp(f, out, gate_held)[1](
+            grad
+        ))
+
+    got_out, got_gate = pulled_back(ours)(out, gate_held)
+    want_out, want_gate = pulled_back(plain)(out, gate_held)
+    replaced = jnp.einsum("tcd,td->tc", moe._rows_at(out, slot), grad)
+    np.testing.assert_allclose(got_gate, replaced, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(got_gate, want_gate, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(got_out, want_out, rtol=1e-6, atol=1e-6)
+    assert not np.any(np.asarray(got_gate)[~hit])
+    assert not np.any(np.asarray(got_out)[live:])
+    assert np.any(got_gate) == bool(live)
+    # The backward gathers the window's rows of `grad` once and reads
+    # [window] scalars back: no array of tokens x slots x d.
+    text = str(jax.make_jaxpr(moe._window_sum_bwd)(
+        (out, token, slot, gate_rows), grad
+    ))
+    assert f"f32[{tokens},{slots},{d}]" not in text
+    assert f"f32[{tokens},{slots}] = gather" in text
+    assert text.count(" = gather") == 2
+
+
 @pytest.mark.parametrize(
     "tokens, top_k, held, experts, want",
     [(4096, 22, 8, 512, (2816, 32768)),  # the Nemotron-3 cell's layer
@@ -892,15 +1099,27 @@ def test_window_is_swept_where_as_many_are_held_as_chosen(live, gated, whole):
      (512, 5, 2, 8, (1024, 1024)),  # an even load fills more than half
      (4096, 10, 32, 512, (5120, 40960)),  # the Qwen3-Next cell's layer
      (1024, 10, 32, 512, (1280, 10240)),  # and its check's four rows
-     (2592, 8, 16, 64, ()),  # twice the rung is over the window
-     (2592, 6, 16, 128, (4096, 15552)), (64, 5, 5, 8, ())],
+     # A quarter of the experts, as many held as chosen: twice the
+     # even load is half the window, 1.25 times it is a rung (PR 56).
+     (2592, 8, 16, 64, (6656, 20736)),  # the Mellum2 cell's layer
+     (2592, 6, 16, 128, (4096, 15552)), (64, 5, 5, 8, ()),
+     (4096, 4, 8, 32, (5120, 16384)),  # the LFM2 cell's layer
+     (1024, 4, 8, 32, (1280, 4096)),  # and its check's four rows
+     (4096, 4, 16, 32, ()),  # half the experts: no room for either rung
+     (4096, 8, 64, 64, ())],  # as many rows as OLMoE's, were they a share
     ids=["nemotron3-cell", "nemotron3-check", "toy", "dense-routing",
          "qwen3next-cell", "qwen3next-check", "mellum2", "kanana2",
-         "held-equals-chosen"],
+         "held-equals-chosen", "lfm2-cell", "lfm2-check", "half-held",
+         "all-held"],
 )
 def test_window_rungs_follow_from_shapes_alone(
     tokens, top_k, held, experts, want
 ):
+    """The rung of every cell that holds a share, by the one rule: the
+    tuples of the Qwen3-Next, Kanana-2 and Nemotron-3 cells as they
+    were; since PR 56 LFM2's and Mellum2's quarter shares take a rung
+    of 1.25 times the even load, where twice it left no room and all
+    tokens x K rows were permuted."""
     from torchbeast_tpu.models import moe
 
     assert moe.window_rungs(tokens, top_k, held, experts) == want

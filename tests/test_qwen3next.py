@@ -109,15 +109,7 @@ def test_update_stats_say_how_far_the_window_was_swept(
     )
     assert moe.window_rungs(T * rows, 3, 4, 64) == (256, 3 * T * rows)
     if even_router:
-        inner = dict(params["params"])
-        for name in ("block_1", "block_3"):
-            block = dict(inner[name])
-            router = jax.tree_util.tree_map(
-                jnp.zeros_like, block["moe"]["router"]
-            )
-            block["moe"] = dict(block["moe"], router=router)
-            inner[name] = block
-        params = {"params": inner}
+        params = scaffold.with_zeroed(params, ("block_1", "block_3"))
     stats = scaffold.forward_stats(model, params, rows, ENDS, T)
     held = float(stats["moe_held_assignments"]) / 2  # a layer
     if even_router:

@@ -39,7 +39,15 @@ the part of the sum that its own experts give. The sorted rows of the
 other experts are never visited (the kernels' `group_offset`), and
 where the shapes leave room (`window_rungs`) never moved either: the
 held experts' rows are one run of the sorted rows, swept a rung at a
-time as far as the step's own rows reach.
+time as far as the step's own rows reach. A rung is twice the rows an
+even load sends the held experts where that is under half the window
+(a small share of many experts, whose load swings); where as many are
+held as a token chooses and twice leaves no room (a quarter of the
+experts: LFM2's 8 of 32, Mellum2's 16 of 64), a rung of 1.25 times the
+even load, if that is under half the window; else, and with every
+expert held, all the t x K sorted rows are permuted. Whatever the rung,
+the sweep takes as many as the step's own sizes fill, so no assignment
+is dropped at any routing.
 """
 
 import functools
@@ -233,9 +241,15 @@ def _window_sum(out, gate_held, token, slot, gate_rows):
     that of `tkd,tk->td`); out [window, d] the kernels' rows, `token`
     and `slot` as `_window_rows` takes them. `gate_rows`
     [window] is `gate_held` again, laid by window row with zeros at the
-    rows that are no held expert's; the backward pass alone reads it
-    (`grad[token] * gate_rows` is the gradient of `out`, a gather
-    again), and the gates' gradient goes to `gate_held`."""
+    rows that are no held expert's; the backward pass alone reads it.
+
+    Backward, ONE gather of the window's rows, `g = grad[token]`: the
+    gradient of `out` is `g * gate_rows`, and the gates' gradient (to
+    `gate_held`) is the row-wise dot `sum(out * g, -1)`, a [window]
+    vector of float32 sums over d read back by `slot` as SCALARS (zero
+    where a slot is no row). No [tokens, slots, d] array is gathered
+    from `out` again: with `held >= top_k` that one is as long as all
+    the sorted rows, whatever the rung."""
     del token, gate_rows
     return jnp.einsum("tcd,tc->td", _rows_at(out, slot), gate_held)
 
@@ -247,8 +261,9 @@ def _window_sum_fwd(out, gate_held, token, slot, gate_rows):
 
 def _window_sum_bwd(residuals, grad):
     out, token, slot, gate_rows = residuals
-    grad_out = grad[token] * gate_rows[:, None]
-    grad_gate = jnp.einsum("tcd,td->tc", _rows_at(out, slot), grad)
+    g = grad[token]
+    grad_out = g * gate_rows[:, None]
+    grad_gate = _rows_at(jnp.sum(out * g, axis=-1), slot)
     return grad_out.astype(out.dtype), grad_gate, None, None, None
 
 
@@ -468,6 +483,9 @@ _ACTIVATIONS = {"silu": nn.silu, "relu2": relu2}
 
 # A rung of the window over the rows an even load sends the held experts.
 _RUNG_HEADROOM = 2.0
+# The same where as many experts are held as a token chooses and
+# `_RUNG_HEADROOM` leaves no room under half the window.
+_LARGE_SHARE_RUNG_HEADROOM = 1.25
 
 
 def window_rungs(tokens, top_k, held, experts):
@@ -477,15 +495,37 @@ def window_rungs(tokens, top_k, held, experts):
     is swept `rung` rows at a time: the rows an even load sends there
     (tokens x top_k x held / experts) with `_RUNG_HEADROOM`, in whole
     row tiles of the grouped kernels, where that is under half the
-    window. Else, with `held < top_k`, the window is its one rung;
-    with `held >= top_k` the window is all the sorted rows, one rung of
-    it cuts nothing, and none is cut."""
+    window (Nemotron-3's 2,816 of 32,768, Qwen3-Next's 5,120 of 40,960,
+    Kanana-2's 4,096 of 15,552). Else, with `held < top_k`, the window
+    is its one rung. With `held >= top_k` the window is all the sorted
+    rows and one rung of it cuts nothing; there a rung with
+    `_LARGE_SHARE_RUNG_HEADROOM`, where THAT is under half the window
+    (a quarter of the experts: LFM2's 5,120 of 16,384, Mellum2's 6,656
+    of 20,736), and else none is cut.
+
+    Why 1.25 there and not 2.0: the headroom is what keeps a step on
+    one rung, and what it must cover is how far the held experts' rows
+    stray from the even load. Twice was sized for 8 of 512 experts,
+    whose load swings by 3.8 x (PERF.md section 7, C15); what a quarter
+    of the experts draw of 4,096 x 4 assignments strays by tens of rows
+    (16,414 against 16,384 over LFM2's four layers), and twice the even
+    load of a quarter share is half the window exactly, which cuts
+    nothing worth a loop. A step that draws more than a rung takes a
+    second (`window_sweeps`): the headroom sets a step's cost, never
+    its result."""
     window, tile = tokens * min(held, top_k), _GMM_TILING[0]
     even = tokens * top_k * held / experts
-    rung = -(-math.ceil(_RUNG_HEADROOM * even) // tile) * tile
+
+    def rung_with(headroom):
+        return -(-math.ceil(headroom * even) // tile) * tile
+
+    rung = rung_with(_RUNG_HEADROOM)
     if 2 * rung < window:
         return (rung, window)
-    return (window, window) if held < top_k else ()
+    if held < top_k:
+        return (window, window)
+    rung = rung_with(_LARGE_SHARE_RUNG_HEADROOM)
+    return (rung, window) if 2 * rung < window else ()
 
 
 def window_sweeps(rungs, mine):
@@ -615,18 +655,21 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None,
     of t x min(C, K) of them. Where `window_rungs` cuts it, that window
     is moved alone: a rung's rows gathered from x, t x min(C, K)
     gathered from the kernels' output and summed as many a token
-    (`_window_dispatch`, `_window_combine`), backward by gathers of as
-    many; where the shapes leave room for a rung short of the window,
-    it is swept a rung at a time only as far as the step's own rows
-    reach, counted on the device. Elsewhere (all experts held, or C >=
-    K and no room) every one of the t x K sorted rows is moved: x
-    repeated K times and permuted, the kernels' rows permuted back and
-    summed K a token."""
+    (`_window_dispatch`, `_window_combine`), backward by a gather of
+    as many for x and of a rung's for the rest; where the shapes leave room for a rung short of the window
+    (twice the even load's rows, or 1.25 times them where C >= K and
+    twice leaves none: `window_rungs`), it is swept a rung at a time
+    only as far as the step's own rows reach, counted on the device, so
+    any routing is computed whole. Elsewhere (all experts held, or C >=
+    K and no room for either rung: more than two fifths of the experts)
+    every one of the t x K sorted rows is moved: x repeated K times and
+    permuted, the kernels' rows permuted back and summed K a token."""
     tokens, K = idx.shape
     first, E = first_of or (None, w_up.shape[0])
     terms = _terms_traced_under()
     # A share of the experts held (models/nemotron3.py: 8 of 512 under
-    # 22 a token; models/qwen3next.py: 32 of 512 under 10): a token
+    # 22 a token; models/qwen3next.py: 32 of 512 under 10;
+    # models/lfm2.py: 8 of 32 under 4): a token
     # lands on each expert at most once, so at most tokens x min(held,
     # K) of the tokens x K sorted rows are these experts', one
     # contiguous run. That window is cut out of the sorted INDICES, and
