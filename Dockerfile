@@ -19,7 +19,7 @@ COPY csrc/ csrc/
 COPY torchbeast_tpu/ torchbeast_tpu/
 COPY tests/ tests/
 COPY benchmarks/ benchmarks/
-COPY bench.py chip_smoke.py __graft_entry__.py ./
+COPY chip_smoke.py __graft_entry__.py ./
 
 RUN bash scripts/build_native.sh
 

@@ -42,7 +42,7 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-T, B = 80, 32  # the flagship unroll and batch (bench.py, BASELINE.md)
+T, B = 80, 32  # the flagship unroll and batch (BASELINE.md)
 ENV = "tbt/MiniAtari-v0"
 FLAGSHIP_ARGV = ["--env", ENV, "--model", "deep", "--use_lstm"]
 

@@ -32,18 +32,17 @@ disjoint from the killed servers' actors, staggered triggers), so the
 10x acceptance run (scale 10 on the 16-actor/8-server base = 160/80)
 exercises the same exact accounting as the CI selftest.
 
-`--selftest` is the CPU CI gate (Mock env, short run, schema-pinned in
-tests/test_bench_scripts.py; scripts/check.sh runs it at --scale 2);
-the default mode is the Catch acceptance run whose artifact is
-committed under benchmarks/artifacts/.
+`--selftest` is the CPU CI gate (Mock env, short run; scripts/check.sh
+runs it at --scale 2); the default mode is the Catch acceptance run,
+whose verdict goes where `--out` says.
 
 Usage:
   python scripts/chaos_run.py --selftest
   python scripts/chaos_run.py --selftest --scale 2
-  python scripts/chaos_run.py --out benchmarks/artifacts/chaos_run.json
+  python scripts/chaos_run.py --out logs/chaos_run.json
   python scripts/chaos_run.py --native --scale 10 --num_servers 8 \\
       --num_actors 16 --batch_size 16 --request_deadline_ms 2000 \\
-      --out benchmarks/artifacts/chaos_run_10x.json
+      --out logs/chaos_run_10x.json
 """
 
 import argparse
@@ -172,7 +171,7 @@ def parse_args(argv=None):
                    help="Induced-scheduler-pressure mode (ROADMAP "
                         "metastability debt): run the CHAOS leg with N "
                         "spinner subprocesses competing for every core "
-                        "(capacity_bench's pressure trick) and record "
+                        "and record "
                         "the ring.doorbell_waits / "
                         "ring.recheck_wakeups contrast between the "
                         "unpressured baseline leg and the pressured "
@@ -327,10 +326,9 @@ def _live_children():
 
 class _SchedulerPressure:
     """Spinner subprocesses competing for every core while the chaos
-    leg runs — the same induced-pressure contrast as
-    benchmarks/capacity_bench.py, here paired with the ring-wait
-    counters so the verdict carries a pressured-vs-unpressured
-    baseline for the doorbell metastability investigation. n=0 is a
+    leg runs, paired with the ring-wait counters so the verdict
+    carries a pressured-vs-unpressured baseline for the doorbell
+    metastability investigation. n=0 is a
     no-op (spawns nothing), so the harness can wrap the leg
     unconditionally."""
 

@@ -95,82 +95,13 @@ print("impact-smoke: PASS (return", ret, "env_sps", round(env_sps, 1),
       "learn_sps", round(learn_sps, 1), ")")
 EOF
 
-    echo "== check: Sebulba split smoke (2 forced host devices, inf=1,learn=rest)"
-    # The async driver end to end with the device split on a forced
-    # 2-device CPU topology (ISSUE 15): per-slice serving + the
-    # DP-pinned learner mesh must train a short Mock run to completion.
-    python benchmarks/tpu_e2e_async.py \
-        --device_split inf=1,learn=rest --xla_device_count 2 \
-        --model mlp --use_lstm --num_servers 2 --num_actors 4 \
-        --batch_size 4 --unroll_length 10 --total_steps 4000 \
-        --timeout_s 240 --out /tmp/tbt_split_smoke.log \
-        > /tmp/tbt_split_smoke.json
-    python - <<'EOF'
-import json
-summary = json.load(open("/tmp/tbt_split_smoke.json"))
-assert "error" not in summary, summary
-snap = summary["telemetry"]["snapshot"]
-assert snap["device_split"]["inference_slices"] == 1, snap["device_split"]
-assert snap["learner.mesh_shape"] == {"data": 1, "model": 1}
-assert "inference.slice.0.depth" in snap["gauges"]
-print("sebulba-smoke: PASS (steady sps", summary["steady_sps_mean"], ")")
-EOF
-
-    echo "== check: multi-host fleet smoke (2 forced-CPU hosts, wire-composed learner)"
-    # Two polybeast processes composed into one fleet over a loopback
-    # coord port (ISSUE 17): the lead must report the cross-host
-    # learner mesh and the remote's folded slice gauges; the remote
-    # must serve from wire-delivered snapshots (its store never
-    # publishes past v0, so any version > 0 IS wire delivery) with
-    # non-zero policy lag observed on the serving path.
-    python benchmarks/tpu_e2e_async.py --fleet_hosts 2 \
-        --device_split inf=1,learn=rest --xla_device_count 2 \
-        --model mlp --use_lstm --num_servers 2 --num_actors 4 \
-        --batch_size 4 --unroll_length 10 --total_steps 4000 \
-        --timeout_s 300 --out /tmp/tbt_fleet_smoke.log \
-        > /tmp/tbt_fleet_smoke.json
-    python - <<'EOF'
-import json
-summary = json.load(open("/tmp/tbt_fleet_smoke.json"))
-assert "error" not in summary, summary
-snap = summary["telemetry"]["snapshot"]
-assert snap["host_rank"] == 0 and snap["fleet_size"] == 2, snap
-assert snap["learner.mesh_shape"] == {"data": 2, "model": 1}, \
-    snap["learner.mesh_shape"]
-assert "host1.inference.slice.0.depth" in snap["gauges"], \
-    sorted(k for k in snap["gauges"] if k.startswith("host1."))
-remote = summary["remote_hosts"]["1"]
-assert remote["rc"] == 0, remote
-rsnap = remote["snapshot"]
-assert rsnap["host_rank"] == 1 and rsnap["fleet_size"] == 2, rsnap
-assert rsnap["gauges"]["serving.snapshot_version"] > 0, \
-    rsnap["gauges"].get("serving.snapshot_version")
-assert rsnap["counters"]["fleet.snapshots_received"] > 0
-lag = rsnap["histograms"]["serving.policy_lag"]
-assert lag["count"] > 0 and lag["max"] > 0, lag
-print("fleet-smoke: PASS (remote served", int(lag["count"]),
-      "batches at snapshot v%d," % rsnap["gauges"]["serving.snapshot_version"],
-      "max policy lag", lag["max"], ")")
-EOF
-
-    echo "== check: native capacity smoke (C++ slice+replica routing, admission armed)"
-    # The NATIVE serving plane end to end, scaled down (ISSUE 16): one
-    # tiny split+replica run per admission family (continuous vs
-    # depth-gated) over shm rings, with the capacity-row schema —
-    # per-slice request counters on BOTH slices, live admitted
-    # accounting, ring-wait counters, policy-lag stamps — asserted by
-    # the bench's own selftest verdict.
-    JAX_PLATFORMS=cpu python benchmarks/capacity_bench.py --selftest \
-        > /tmp/tbt_capacity_smoke.json
-    python - <<'EOF'
-import json
-out = json.loads(open("/tmp/tbt_capacity_smoke.json").read().strip().splitlines()[-1])
-assert out["selftest"]["ok"] is True, out["selftest"]
-rows = {r["family"]: r for r in out["rows"]}
-print("capacity-smoke: PASS (admitted/s continuous",
-      rows["continuous"]["admitted_per_s"],
-      "depth_gated", rows["depth_gated"]["admitted_per_s"], ")")
-EOF
+    # The device split, the two-host fleet and the native serving plane
+    # have no smoke here: tier-1 drives them end to end
+    # (tests/test_sebulba.py::test_polybeast_device_split_e2e: polybeast
+    # under `--device_split inf=1,learn=rest` on forced host devices;
+    # tests/test_fleet.py: the control plane and wire-delivered
+    # snapshots; tests/test_native_routing.py: the C++ slice and replica
+    # routing with admission armed).
 fi
 
 echo "== check: PASS"

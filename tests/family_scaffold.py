@@ -960,6 +960,44 @@ def assert_stepwise_acting_equals_the_batch_forward(
     return full_state
 
 
+class _SameTree:
+    """A tree as a cache key: that object, not an equal one."""
+
+    def __init__(self, tree):
+        self.tree = tree
+
+    def __hash__(self):
+        return id(self.tree)
+
+    def __eq__(self, other):
+        return self.tree is other.tree
+
+
+@functools.lru_cache(maxsize=None)
+def _state_table(model, params, rows):
+    """A `DeviceStateTable` of `rows` slots around `model` and the tree
+    of `params`, made once: the table jits `act` itself, so a second
+    table would be a second trace of the family's act step. `act` is
+    eager for that reason."""
+
+    def act(ctx, env_outputs, agent_state):
+        out, new_state = model.apply(
+            params.tree, env_outputs, agent_state, sample_action=False
+        )
+        return {"logits": out.policy_logits}, new_state
+
+    return DeviceStateTable(
+        model.initial_state(1), num_slots=rows, act_fn=act, batch_dim=1
+    )
+
+
+def state_table(model, params, rows):
+    """The one `DeviceStateTable` of `rows` slots a model and tree of
+    parameters (that tree: hand in the same object again). It keeps
+    what its last user left: reset the slots a case reads."""
+    return _state_table(model, _SameTree(params), rows)
+
+
 def assert_state_table_acting_equals_the_batch_forward(
     model, params, batch, shapes=None
 ):
@@ -968,22 +1006,15 @@ def assert_state_table_acting_equals_the_batch_forward(
     arrive in another order every step. Every step's logits equal the
     batch forward's from an empty state over the same inputs, and what
     the table holds at the end is what that forward leaves (every
-    slot's items of `shapes`, where given). Hands back the table.
-    `act` is eager: the table jits it itself."""
+    slot's items of `shapes`, where given). Hands back the table: one a
+    model and tree of parameters, every slot reset before the first
+    step, whatever an earlier case left in it."""
     rows = 3
     full, full_state = forward(model)(
         params, batch, model.initial_state(rows)
     )
-
-    def act(ctx, env_outputs, agent_state):
-        out, new_state = model.apply(
-            params, env_outputs, agent_state, sample_action=False
-        )
-        return {"logits": out.policy_logits}, new_state
-
-    table = DeviceStateTable(
-        model.initial_state(1), num_slots=rows, act_fn=act, batch_dim=1
-    )
+    table = state_table(model, params, rows)
+    table.reset(list(range(rows)))
     orders = [[0, 1, 2], [2, 0, 1], [1, 2, 0], [2, 1, 0], [0, 2, 1], [1, 0, 2]]
     for t, order in enumerate(orders):
         step = {

@@ -1,4 +1,4 @@
-"""Compile for the chip, without the chip.
+"""Compile for the chip, without the chip: the flagship's programs.
 
 The TPU compiler is installed here and compiles for a v5e that is
 described, not attached (`jax.experimental.topologies`). Interpret-mode
@@ -10,10 +10,12 @@ the flagship learner's shapes (unroll 80, batch 32, so the trunk pools
 see N = 81 * 32 = 2592 rows), plus the flagship act step at the largest
 inference bucket and the flagship update over the four chips against
 its one-chip quarter; the whole one-chip update step is the `slow`
-case. The families' whole-cell compiles have a file each (`tests/test_
-chip_compile_<family>.py`); the described chip all of them share is
-`tests/chip_fixtures.py`. Nothing runs: a compile that passes says
-nothing about results or times.
+case. The routed families' grouped matmuls and windows are `tests/test_
+chip_compile_moe.py`, the fused attention kernels `tests/test_chip_
+compile_attention.py`, and the families' whole-cell compiles have a
+file each (`tests/test_chip_compile_<family>.py`); the described chip
+all of them share is `tests/chip_fixtures.py`. Nothing runs: a compile
+that passes says nothing about results or times.
 
 Code that asks `jax.default_backend()` still sees the CPU here, so the
 kernels get `interpret=False` explicitly and the whole-step cases patch
@@ -21,7 +23,6 @@ the backend name for the duration of the trace.
 """
 
 import os
-import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -150,330 +151,6 @@ KERNELS = {
 def test_kernel_compiles_for_v5e(one_chip, name):
     compiled = KERNELS[name](one_chip)
     assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.parametrize(
-    "rows", [81 * 32 * 8, 8 * 8], ids=["learner-81x32x8", "act-8x8"]
-)
-def test_olmoe_experts_compile_for_v5e(one_chip, monkeypatch, rows):
-    """The OLMoE cell's grouped expert matmuls (models/moe.py on the
-    shipped megablox kernels) at the published widths, forward and
-    backward: the learner's 20,736 sorted rows, and an act batch of 8
-    whose 64 rows are padded to one tile. A contracted tile of 2048
-    overflowed VMEM in `tgmm` here before the chip ever saw it."""
-    from torchbeast_tpu.models import moe
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    d, width, experts = 2048, 1024, 64
-
-    def loss(x, w_gate, w_up, w_down, sizes):
-        hidden = jax.nn.silu(
-            moe.grouped_matmul(x, w_gate, sizes)
-        ) * moe.grouped_matmul(x, w_up, sizes)
-        return jnp.sum(moe.grouped_matmul(hidden, w_down, sizes))
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
-        _struct(one_chip, (rows, d)),
-        _struct(one_chip, (experts, d, width)),
-        _struct(one_chip, (experts, d, width)),
-        _struct(one_chip, (experts, width, d)),
-        _struct(one_chip, (experts,), jnp.int32),
-    ).compile()
-    # Two forward kernels (the sum needs no third), six backward.
-    assert compiled.as_text().count("tpu_custom_call") >= 8
-
-
-def test_mellum2_share_of_the_experts_compiles_for_v5e(one_chip, monkeypatch):
-    """The Mellum2 cell's grouped matmuls: the learner's 20,736 sorted
-    rows over all 64 groups, the weights of experts 16..31 alone
-    (megablox's `group_offset`), at the published 2304 -> 896 -> 2304,
-    forward and backward."""
-    from torchbeast_tpu.models import moe
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    rows, d, width, experts, held = 81 * 32 * 8, 2304, 896, 64, 16
-
-    def loss(x, w_gate, w_up, w_down, sizes):
-        hidden = jax.nn.silu(
-            moe.grouped_matmul(x, w_gate, sizes, 16)
-        ) * moe.grouped_matmul(x, w_up, sizes, 16)
-        return jnp.sum(moe.grouped_matmul(hidden, w_down, sizes, 16))
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
-        _struct(one_chip, (rows, d)),
-        _struct(one_chip, (held, d, width)),
-        _struct(one_chip, (held, d, width)),
-        _struct(one_chip, (held, width, d)),
-        _struct(one_chip, (experts,), jnp.int32),
-    ).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 8
-
-
-# A rung of the window's rows, its width, the experts held and theirs:
-# what `moe.window_rungs` hands the kernels in the three cells that
-# trace under `high`.
-CUT_IN_VMEM_CELLS = {
-    "qwen3next": (5120, 2048, 32, 512, True),
-    "kanana2": (4096, 2048, 16, 768, True),
-    "nemotron3": (2816, 1024, 8, 2688, False),
-}
-
-
-@pytest.mark.parametrize("cell", sorted(CUT_IN_VMEM_CELLS))
-@pytest.mark.parametrize("precision", ["high", "highest", "default"])
-def test_experts_cut_in_vmem_compile_for_v5e(
-    one_chip, monkeypatch, cell, precision
-):
-    """A rung's grouped matmuls as the three `high` cells call them
-    (ops/grouped_matmul.py: `gmm`, `gmm` on transposed weights, `tgmm`,
-    each at the up and at the down projection's shape), forward and
-    backward, under the 16 MiB of VMEM a kernel is given unasked: one
-    kernel call a product at two terms a side and at three; at one
-    term the shipped kernels and none of ours."""
-    from torchbeast_tpu.models import moe
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    rung, d, held, width, gated = CUT_IN_VMEM_CELLS[cell]
-
-    def loss(x, w_gate, w_up, w_down, sizes):
-        with jax.default_matmul_precision(precision):
-            hidden = moe._experts_on_rows(
-                x, w_gate if gated else None, w_up, w_down, sizes, 0,
-                "silu", moe._terms_traced_under(),
-            )
-        return jnp.sum(hidden)
-
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
-        _struct(one_chip, (rung, d)),
-        _struct(one_chip, (held, d, width)),
-        _struct(one_chip, (held, d, width)),
-        _struct(one_chip, (held, width, d)),
-        _struct(one_chip, (held + 1,), jnp.int32),
-    ).compile().as_text()
-    # Forward, and a product's two gradients (the sum needs no forward
-    # of the last).
-    calls_owed = 3 * (3 if gated else 2) - 1
-    ours = len(re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*"
-                          r"gmm_cut_in_vmem", text))
-    calls = text.count("custom_call_target=\"tpu_custom_call\"")
-    if precision == "default":
-        assert ours == 0 and calls == calls_owed
-    else:
-        assert ours == calls == calls_owed
-    assert "vmem_limit_bytes" not in text
-
-
-@pytest.mark.parametrize("keys", [4176, 1104], ids=["full", "sliding"])
-def test_mellum2_fused_attention_compiles_for_v5e(one_chip, monkeypatch, keys):
-    """The Mellum2 cell's attention below `dense_transformer_attend`
-    (ops/fused_attention.py), forward and backward at the published
-    widths [32, 81, 32 on 4, 128] over a full layer's 4,176 keys and a
-    window layer's 1,104: the rule takes the fused pass, two Mosaic
-    kernels (704 rows x 384 keys a cell) fit the scoped VMEM they ask
-    for, and the compiled program holds no f32 array whose last
-    dimension is the keys: the scores' [.., 81, keys] (1.385 GB in the
-    full layer) are never built. As the block calls it: the cache's
-    keys take no gradient."""
-    import re
-
-    from torchbeast_tpu.ops import attention
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    b, t, h, hkv, d = B, T + 1, 32, 4, 128
-    assert attention.fused_pass_applies((b, t, h, d), (b, keys, hkv, d), None)
-
-    def loss(q, k_all, v_all, mask, dout):
-        return jnp.sum(
-            attention.dense_transformer_attend(
-                q, k_all, v_all, mask, None, None, keys - t
-            ) * dout
-        )
-
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        _struct(one_chip, (b, t, h, d)),
-        _struct(one_chip, (b, keys, hkv, d)),
-        _struct(one_chip, (b, keys, hkv, d)),
-        _struct(one_chip, (b, t, keys), jnp.bool_),
-        _struct(one_chip, (b, t, h, d)),
-    ).compile().as_text()
-    assert text.count("tpu_custom_call") == 2
-    padded = -(-keys // 384) * 384
-    over_keys = {
-        dims for dims in re.findall(r"f32\[([0-9,]+)\]", text)
-        if dims.endswith((f",{keys}", f",{padded}"))
-    }
-    assert not over_keys, over_keys
-
-
-def test_kanana2_fused_latent_leg_compiles_for_v5e(one_chip, monkeypatch):
-    """The Kanana-2 cell's cache leg (ops/fused_attention.py `fused_
-    latent_leg`), forward and backward at the published widths: 32
-    heads' absorbed queries, head-major and their 81 steps padded to 88
-    ([32 heads, 32, 88, 512] and [.., 64], f32), against ONE joined key a
-    slot over 4,095 slots, a cotangent on both of its results. The rule
-    takes it, two Mosaic kernels fit the scoped VMEM they ask for, and
-    the compiled program holds no f32 array whose last dimension is the
-    slots: the scores' [32, 32, 81, 4095] (1.36 GB) are never built."""
-    import re
-
-    from torchbeast_tpu.ops import attention
-    from torchbeast_tpu.ops.fused_attention import (
-        fused_latent_leg,
-        padded_steps,
-    )
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    b, t, h, latent, rope, slots = B, T + 1, 32, 512, 64, 4095
-    tp = padded_steps(t)
-    assert tp == 88
-    assert attention.fused_latent_leg_applies(
-        (b, t, h, rope), slots, latent, "default"
-    )
-
-    def loss(q_latent, q_rope, cache_latent, cache_rope, mask, dout, dlse):
-        out, lse = fused_latent_leg(
-            q_latent, q_rope, cache_latent, cache_rope, mask, 192 ** -0.5
-        )
-        return jnp.sum(out * dout) + jnp.sum(lse * dlse)
-
-    text = jax.jit(jax.grad(loss, (0, 1))).lower(
-        _struct(one_chip, (h, b, tp, latent)),
-        _struct(one_chip, (h, b, tp, rope)),
-        _struct(one_chip, (slots, b, latent)),
-        _struct(one_chip, (slots, b, rope)),
-        _struct(one_chip, (b, t, slots), jnp.bool_),
-        _struct(one_chip, (h, b, tp, latent)),
-        _struct(one_chip, (h, b, tp)),
-    ).compile().as_text()
-    assert text.count("tpu_custom_call") == 2
-    over_slots = {
-        dims for dims in re.findall(r"f32\[([0-9,]+)\]", text)
-        if dims.endswith((f",{slots}", f",{slots + 1}"))
-    }
-    assert not over_slots, over_slots
-
-
-def test_nemotron3_dispatch_moves_the_windows_rows_alone_on_v5e(
-    one_chip, monkeypatch
-):
-    """`jax.grad` through `dropless_experts` at the Nemotron-3 cell's
-    shapes (4,096 tokens, 22 of 512 a token, 8 held, relu^2 experts of
-    2,688 in a latent of 1,024, traced under `high`), for a described
-    v5e: with fewer experts held than a token chooses, rows are moved
-    tokens x 8 at a time. No f32 array of the tokens x 22 sorted rows
-    (90,112) nor of those and the window's (122,880) is in the
-    program (PR 43: 2,121,320,960 bytes of temporaries before it,
-    1,811,172,864 after). And (PR 44) the kernels sweep that window a
-    rung of 2,816 rows at a time, in a loop on the device of as many
-    turns as the step's rows fill, forward and backward: no array of
-    the experts' width is as long as the whole window, zeros or
-    otherwise, each loop holds one copy of the kernels, and the
-    temporaries are 507,526,144 bytes."""
-    from torchbeast_tpu.models import moe
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    tokens, top_k, experts, held, latent, width = 4096, 22, 512, 8, 1024, 2688
-
-    def loss(x, gate, w_up, w_down, idx):
-        with jax.default_matmul_precision("high"):
-            y, _ = moe.dropless_experts(
-                x, idx, gate, None, w_up, w_down, first_of=(0, experts),
-                activation="relu2",
-            )
-        return jnp.sum(jnp.sin(y))
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
-        _struct(one_chip, (tokens, latent)),
-        _struct(one_chip, (tokens, top_k)),
-        _struct(one_chip, (held, latent, width)),
-        _struct(one_chip, (held, width, latent)),
-        _struct(one_chip, (tokens, top_k), jnp.int32),
-    ).compile()
-    text = compiled.as_text()
-    assert f"f32[{tokens * top_k},{latent}]" not in text
-    assert f"f32[{tokens * (top_k + held)},{latent}]" not in text
-    assert f"f32[{tokens * held},{latent}]" in text  # the gathers by slot
-    rung, window = moe.window_rungs(tokens, top_k, held, experts)
-    assert (rung, window) == (2816, tokens * held)
-    assert f"f32[{rung},{width}]" in text and f"f32[{rung},{latent}]" in text
-    assert f"[{window},{width}]" not in text
-    # The forward loop and the backward's, under the sweep's own name
-    # (PR 51: `moe_sweep`; a rung's parts enter their scopes inside).
-    assert "/jvp(moe_sweep)/while/body/moe_experts" in text
-    assert (
-        "/transpose(jvp(moe_sweep))/while/body/jvp(moe_experts)" in text
-    )
-    assert compiled.memory_analysis().temp_size_in_bytes <= 507_526_144
-    # Two forward kernels in the forward loop; those and four backward
-    # in the backward loop; ONE call a product since PR 50 (the kernels
-    # cut their operands in VMEM; three passes each, 24, before), and
-    # no second copy.
-    assert text.count("tpu_custom_call") == 8
-    assert text.count("gmm_cut_in_vmem") >= 8
-
-
-# tokens, top_k, experts, held, d, width, the precision the family traces
-# under, the rung: the two cells that hold a QUARTER of their experts.
-QUARTER_SHARE_CELLS = {
-    "lfm2": (4096, 4, 32, 8, 2048, 1792, "high", 5120),
-    "mellum2": (2592, 8, 64, 16, 2304, 896, "default", 6656),
-}
-
-
-@pytest.mark.parametrize("cell", sorted(QUARTER_SHARE_CELLS))
-def test_quarter_share_sweeps_a_rung_on_v5e(one_chip, monkeypatch, cell):
-    """`jax.grad` through `dropless_experts` at the LFM2 and Mellum2
-    cells' layer shapes (8 of 32 held under 4 a token at `high`: the
-    kernels that cut in VMEM; 16 of 64 under 8 at one bf16 pass: the
-    shipped kernels), for a described v5e. Since PR 56 a quarter share
-    with `held >= top_k` sweeps a rung of 1.25 times the even load
-    (5,120 of 16,384 sorted rows; 6,656 of 20,736): the kernels, the
-    activation and the operand casts see the rung's rows, no array of
-    the experts' width is as long as all the sorted rows, and the only
-    arrays of the model's width that long are the two gathers by
-    `slot` (the forward's sum and the dispatch's gradient; the gates'
-    gradient reads scalars back). Each loop holds one copy of the
-    kernels: three products forward, those and six backward."""
-    from torchbeast_tpu.models import moe
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    tokens, top_k, experts, held, d, width, precision, rung = (
-        QUARTER_SHARE_CELLS[cell]
-    )
-    rows = tokens * top_k
-    assert moe.window_rungs(tokens, top_k, held, experts) == (rung, rows)
-
-    def loss(x, gate, w_gate, w_up, w_down, idx):
-        with jax.default_matmul_precision(precision):
-            y, _ = moe.dropless_experts(
-                x, idx, gate, w_gate, w_up, w_down, first_of=(held, experts)
-            )
-        return jnp.sum(jnp.sin(y))
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        _struct(one_chip, (tokens, d)),
-        _struct(one_chip, (tokens, top_k)),
-        _struct(one_chip, (held, d, width)),
-        _struct(one_chip, (held, d, width)),
-        _struct(one_chip, (held, width, d)),
-        _struct(one_chip, (tokens, top_k), jnp.int32),
-    ).compile()
-    text = compiled.as_text()
-    assert f"[{rows},{width}]" not in text
-    assert f"f32[{rung},{width}]" in text and f"f32[{rung},{d}]" in text
-    assert "/jvp(moe_sweep)/while/body/moe_experts" in text
-    assert (
-        "/transpose(jvp(moe_sweep))/while/body/jvp(moe_experts)" in text
-    )
-    assert text.count("tpu_custom_call") == 12
-    # What is written as long as all the sorted rows at the model's
-    # width: the two gathers by `slot`, [tokens, K, d] or flat.
-    long_rows = rf"= f32\[({tokens},{top_k},{d}|{rows},{d})\]"
-    long_gathers = [
-        line for line in text.splitlines()
-        if re.search(long_rows, line) and "gather" in line.split("=")[0]
-    ]
-    assert len(long_gathers) == 2, long_gathers
 
 
 def test_flagship_act_step_compiles_for_v5e(one_chip, monkeypatch):

@@ -10,6 +10,7 @@ that a family's flags are read from its class.
 
 import argparse
 import ast
+import importlib
 import json
 import os
 import subprocess
@@ -20,6 +21,7 @@ import numpy as np
 import pytest
 
 from tests import family_scaffold as scaffold
+from tests.test_monobeast_families import THROUGH_MAIN
 from torchbeast_tpu import anakin, learner_setup, models, monobeast, polybeast
 from torchbeast_tpu import learner as learner_lib
 
@@ -408,17 +410,18 @@ def _assert_the_eager_trees_leaves(traced, eager):
         )
 
 
-def test_first_parameters_are_one_traced_program(monkeypatch):
-    """`init_model_and_params` of the toy Qwen3-Next family hands XLA
-    its `init` as one program (8 requests with the two keys' and the
-    empty state's small ones; op by op it was 421), and the parameters
-    are the eager `model.init`'s."""
-    from tests.test_monobeast_families import THROUGH_MAIN
-
-    family, widths, flags, _ = THROUGH_MAIN["qwen3next"]
+@pytest.mark.parametrize("case", list(THROUGH_MAIN))
+def test_first_parameters_are_one_traced_program(monkeypatch, case):
+    """`init_model_and_params` of every toy family that trains through
+    `main` hands XLA its `init` as one program (at most 10 requests
+    with the two keys' and the empty state's small ones; op by op
+    Qwen3-Next's was 421, minutes of a cell's `setup_s`), and the
+    parameters are `model.init`'s from the flags' seeds over one dummy
+    step and the empty state."""
+    family, widths, flags, _ = THROUGH_MAIN[case]
+    module = importlib.import_module(f"torchbeast_tpu.models.{family}")
     monkeypatch.setattr(
-        models.qwen3next, "PUBLISHED",
-        dict(models.qwen3next.PUBLISHED, **widths),
+        module, "PUBLISHED", dict(module.PUBLISHED, **widths)
     )
     argv = ["--model", family, "--memory_len", "6"]
     for flag, value in flags.items():
@@ -427,17 +430,15 @@ def test_first_parameters_are_one_traced_program(monkeypatch):
     requests = scaffold.compile_requests(monkeypatch)
     model, params = learner_setup.init_model_and_params(parsed, A, B, FRAME)
     assert len(requests) <= 10
-    del requests[:]
-    eager = model.init(
+    _assert_the_eager_trees_leaves(params, scaffold.init(
+        model,
         {
             "params": jax.random.PRNGKey(parsed.seed),
             "action": jax.random.PRNGKey(parsed.seed + 1),
         },
         learner_setup.dummy_env_outputs(1, B, FRAME, np.uint8),
         model.initial_state(B),
-    )
-    assert len(requests) > 400
-    _assert_the_eager_trees_leaves(params, eager)
+    ))
 
 
 def test_anakins_first_parameters_are_the_same_traced_program(monkeypatch):

@@ -1,5 +1,5 @@
 """Fused Pallas optimizer tail (--opt_impl pallas, ops/pallas_opt.py):
-parity against the optax chain and the ISSUE 13 bytes-accessed gates.
+parity against the optax chain.
 
 The parity matrix runs REAL update steps ({MLP, LSTM} x
 {f32, bf16_train} x clip active/inactive x momentum) and compares the
@@ -9,22 +9,7 @@ bf16_train — the master round-trip invariant (resident ==
 bf16(master) exactly, the same contract learner._bf16_resident_params
 pins). The kernel runs the identical f32 math in the identical order,
 so tolerances are one-f32-rounding tight.
-
-The bytes gates lower the flagship T=80/B=32 update for the TPU target
-(compiled kernel, not the CPU interpreter — learner_bench's
-_pallas_compile_env) and compare XLA's pre-opt bytes-accessed against
-the COMMITTED PR 8 baseline rows (benchmarks/artifacts/
-learner_bench.json, bytes.update, opt_impl=xla): the LSTM — whose
-optimizer tail is ~34% of its update — and the mlp+lstm combined
-figure must shrink >= 1.15x (the ISSUE floor); the tiny MLP's tail is
-only ~8% of its update, so its full-update ceiling is ~1.08x even at
-perfect fusion — gated at 1.03x so a fusion regression still fails
-while physics does not.
 """
-
-import importlib.util
-import json
-import os
 
 import numpy as np
 import pytest
@@ -37,11 +22,6 @@ from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu import precision as precision_lib
 from torchbeast_tpu.models import create_model
 from torchbeast_tpu.ops.pallas_opt import FusedTailState
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ARTIFACT = os.path.join(
-    REPO, "benchmarks", "artifacts", "learner_bench.json"
-)
 
 T, B, A = 6, 4, 4
 FRAME = (4, 4, 1)
@@ -229,71 +209,3 @@ def test_entropy_anneal_reads_fused_count():
     opt_state = optimizer.init(params)
     cost_at = learner_lib.entropy_schedule(hp)
     assert float(cost_at(opt_state)) == pytest.approx(0.01)
-
-
-def _load_learner_bench():
-    spec = importlib.util.spec_from_file_location(
-        "learner_bench",
-        os.path.join(REPO, "benchmarks", "learner_bench.py"),
-    )
-    lb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(lb)
-    return lb
-
-
-def _committed_baseline(config):
-    with open(ARTIFACT) as f:
-        art = json.load(f)
-    row = next(
-        r for r in art["results"]["bytes"]["update"]
-        if r["config"] == config and r["k"] == 1
-        and r["precision"] == "bf16_train"
-    )
-    return float(row["bytes_accessed"])
-
-
-def _pallas_update_bytes(lb, config):
-    pol = precision_lib.get("bf16_train")
-    hp, model, optimizer, params, rng = lb.build_config(
-        lb.CONFIGS[config]["use_lstm"], precision="bf16_train",
-        t=lb.BYTES_T, b=lb.BYTES_B, opt_impl="pallas",
-    )
-    batch = precision_lib.cast_batch(
-        lb.make_batch(rng, t=lb.BYTES_T, b=lb.BYTES_B), pol.batch_dtype
-    )
-    state = precision_lib.cast_batch(
-        jax.tree_util.tree_map(
-            np.asarray, model.initial_state(lb.BYTES_B)
-        ),
-        pol.batch_dtype,
-    )
-    upd = learner_lib.make_update_step(
-        model, optimizer, hp, donate=False
-    )
-    with lb._pallas_compile_env():
-        value = lb._bytes_of(lb._lower_for_tpu(
-            upd, params, optimizer.init(params), batch, state
-        ))
-    assert value is not None, "cost analysis unavailable"
-    return float(value)
-
-
-def test_fused_tail_bytes_vs_committed_baseline():
-    """The ISSUE 13 acceptance gate on the lowered-HLO accounting at
-    the flagship T=80/B=32 shapes under bf16_train, vs the PR 8
-    committed baseline (docstring has the per-config floor
-    rationale)."""
-    lb = _load_learner_bench()
-    got = {}
-    for config in ("mlp", "lstm"):
-        got[config] = (
-            _committed_baseline(config), _pallas_update_bytes(lb, config)
-        )
-    lstm_red = got["lstm"][0] / got["lstm"][1]
-    mlp_red = got["mlp"][0] / got["mlp"][1]
-    combined = (got["mlp"][0] + got["lstm"][0]) / (
-        got["mlp"][1] + got["lstm"][1]
-    )
-    assert lstm_red >= 1.15, got
-    assert combined >= 1.15, got
-    assert mlp_red >= 1.03, got

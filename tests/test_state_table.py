@@ -11,6 +11,7 @@ the chip, so tier-1 covers the whole device-resident path without TPU
 access.
 """
 
+import functools
 import threading
 
 import jax
@@ -47,6 +48,35 @@ def make_table(num_slots=4, context_fn=None):
         context_fn=context_fn,
         batch_dim=1,
     )
+
+
+def _mellum2_env(rows):
+    return {
+        "frame": np.full((1, rows, 4, 4, 1), 7, np.uint8),
+        "reward": np.zeros((1, rows), np.float32),
+        "done": np.zeros((1, rows), bool),
+        "last_action": np.zeros((1, rows), np.int32),
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def _mellum2_table():
+    """(a toy Mellum2, the scaffold's table of three slots around it),
+    made once: a table jits its `act` itself, so a second one would
+    trace the family's act step a second time."""
+    from torchbeast_tpu.models import Mellum2Net
+
+    model = Mellum2Net(
+        num_actions=3, num_layers=4, memory_len=9, d_model=16,
+        num_heads=2, kv_heads=1, head_dim=8, sliding_window=4,
+        num_experts=4, experts_per_token=2, expert_width=8,
+    )
+    params = scaffold.init(
+        model,
+        {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
+        _mellum2_env(1), model.initial_state(1),
+    )
+    return model, scaffold.state_table(model, params, 3)
 
 
 def _env(values):
@@ -223,45 +253,18 @@ class TestDeviceStateTable:
         slots step independently, a stepped slot's caches fill from the
         back at each layer's own length, and reset / rebuild bring back
         the empty caches."""
-        from torchbeast_tpu.models import Mellum2Net
-
-        model = Mellum2Net(
-            num_actions=3, num_layers=4, memory_len=9, d_model=16,
-            num_heads=2, kv_heads=1, head_dim=8, sliding_window=4,
-            num_experts=4, experts_per_token=2, expert_width=8,
-        )
+        model, table = _mellum2_table()
         lengths = [m for m, _, _ in model.layer_caches()]
         assert lengths == [3, 3, 3, 9]
-
-        def env(rows):
-            return {
-                "frame": np.full((1, rows, 4, 4, 1), 7, np.uint8),
-                "reward": np.zeros((1, rows), np.float32),
-                "done": np.zeros((1, rows), bool),
-                "last_action": np.zeros((1, rows), np.int32),
-            }
-
-        params = scaffold.init(
-            model,
-            {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
-            env(1), model.initial_state(1),
-        )
-
-        # Eager: the table jits `act` itself.
-        def act(ctx, env_outputs, agent_state):
-            out, new_state = model.apply(
-                params, env_outputs, agent_state, sample_action=False
-            )
-            return {"logits": out.policy_logits}, new_state
-
-        table = DeviceStateTable(
-            model.initial_state(1), num_slots=3, act_fn=act, batch_dim=1
-        )
+        table.reset([0, 1, 2])  # whatever the other id left
         for _ in range(5):
             table.step(
-                np.asarray([0, 2], np.int32), np.ones(2, bool), env(2)
+                np.asarray([0, 2], np.int32), np.ones(2, bool),
+                _mellum2_env(2),
             )
-        table.step(np.asarray([2], np.int32), np.ones(1, bool), env(1))
+        table.step(
+            np.asarray([2], np.int32), np.ones(1, bool), _mellum2_env(1)
+        )
 
         def valid(slot):
             return [
@@ -294,7 +297,9 @@ class TestDeviceStateTable:
             assert np.shape(k) == (length, 1, 1, 8)
             assert not np.any(k) and not np.any(mask)
         # And it steps on from there.
-        table.step(np.asarray([0], np.int32), np.ones(1, bool), env(1))
+        table.step(
+            np.asarray([0], np.int32), np.ones(1, bool), _mellum2_env(1)
+        )
         assert [int(v.sum()) for v in valid(0)] == [1, 1, 1, 1]
 
     @pytest.mark.parametrize("via", ["reset", "rebuild"])

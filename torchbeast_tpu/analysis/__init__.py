@@ -17,7 +17,7 @@ offending file:line. The rules encode the repo's real runtime contracts:
     WIRE-PARITY      runtime/wire.py == csrc/{wire,array,client}.h on the
                      dtype table, frame tags, and kMaxFrameBytes
     FLAG-PARITY      flags a script re-declares beside polybeast's
-                     (polybeast_env, chaos_run, capacity_bench) agree
+                     (polybeast_env, chaos_run) agree
                      on default and type
 
 Whole-program concurrency rules (ISSUE 7) ride the module -> call ->

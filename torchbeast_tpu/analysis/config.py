@@ -153,9 +153,6 @@ FLAG_PARITY_GROUPS = tuple(
         # programmatically; the flags it re-declares for itself must
         # not silently drift from the driver's meaning.
         "scripts/chaos_run.py",
-        # The capacity bench re-declares the driver flags its
-        # subprocess rows forward (ISSUE 16).
-        "benchmarks/capacity_bench.py",
     )
     for anchor in _POLYBEAST_PARSER
 )

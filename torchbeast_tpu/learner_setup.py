@@ -156,8 +156,7 @@ def add_learner_arguments(parser, *, model_default,
                              "16/32/32. A 16-channel conv fills 16 of an "
                              "MXU tile's 128 output lanes — wider trunks "
                              "buy capacity at far under proportional "
-                             "step-time (benchmarks/mfu_ablation.py "
-                             "measures the scaling). Deep model only.")
+                             "step-time. Deep model only.")
     parser.add_argument("--sequence_parallel", type=int, default=0,
                         help="Shard the transformer's unroll (time) axis "
                              "over N devices: in-unroll attention runs as "

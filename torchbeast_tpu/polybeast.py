@@ -1278,9 +1278,9 @@ def train(flags):
 
         # Per-env-step wire accounting for the acting path. Exported as
         # telemetry gauges + a static `acting_path` block on every
-        # telemetry.jsonl line (benchmarks/tpu_e2e_async.py consumes the
-        # structured snapshot, not log scraping; the cumulative actual
-        # traffic is the actor pool's wire.bytes_up/down counters). The
+        # telemetry.jsonl line (a reader takes the structured snapshot,
+        # not scraped logs; the cumulative actual traffic is the actor
+        # pool's wire.bytes_up/down counters). The
         # state table's whole point is making the state term vanish
         # from both directions.
         env_up = (
@@ -1312,7 +1312,7 @@ def train(flags):
         # synchronized, so concurrent threads overlap their host-side pad/
         # dispatch/device-sync work. Measured on 32 actors x 2 threads:
         # +27% steps/s (python runtime) / +18% (native), p99 latency -20-35%
-        # (benchmarks/inference_bench.py, artifacts/inference_lock_decision.md).
+        # (benchmarks/artifacts/inference_lock_decision.md).
         if flags.prewarm_inference:
             t0 = time.time()
             buckets = default_buckets(flags.max_inference_batch_size)

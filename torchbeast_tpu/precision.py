@@ -30,9 +30,8 @@ analysis off the LOWERED (pre-optimization) HLO, where every tensor
 still carries its semantic dtype. The CPU backend widens bf16 matmuls
 to f32 during optimization, so COMPILED cost analysis on this container
 reports the CPU emulation, not the policy — the lowered module is the
-platform-neutral accounting both learner_bench.py and the
-`learner.hbm_bytes_per_update` gauge report, and the chip-side compiled
-number is one `bench.py` run on the chip away.
+platform-neutral accounting the `learner.hbm_bytes_per_update` gauge
+reports.
 """
 
 import logging
@@ -258,8 +257,7 @@ def hbm_gauge_async(update_fn, args, gauge):
     The figure needs NO division by superstep_k: the lowered HLO counts
     a lax.scan body once, so a K-update superstep program's
     bytes-accessed is already one update's compute (plus the K-stack
-    staging operands) — the same semantics learner_bench.py documents,
-    and what its committed artifact shows (K=8 total ~= K=1 total)."""
+    staging operands)."""
     structs = tuple(shape_structs(a) for a in args)
 
     def run():

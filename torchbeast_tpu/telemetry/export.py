@@ -1,7 +1,7 @@
 """Snapshot/delta/merge + exporters for the telemetry registry.
 
-Snapshot schema (SCHEMA_VERSION bumps on any breaking change; the
-bench artifacts and tests/test_telemetry.py validate against it):
+Snapshot schema (SCHEMA_VERSION bumps on any breaking change;
+tests/test_telemetry.py validates against it):
 
     {
       "schema": 1,
@@ -190,10 +190,10 @@ def telemetry_block(
     prev: Optional[Dict] = None,
     registry: Optional[MetricsRegistry] = None,
 ) -> Dict:
-    """The `telemetry` block bench artifacts embed: the current
-    snapshot (or the delta since `prev`) plus the enabled flag. ONE
-    shared constructor so every artifact drifts together — and the
-    tier-1 schema test validates this exact shape."""
+    """The `telemetry` block a verdict embeds (scripts/chaos_run.py):
+    the current snapshot (or the delta since `prev`) plus the enabled
+    flag. ONE shared constructor so every verdict drifts together — and
+    the tier-1 schema test validates this exact shape."""
     from torchbeast_tpu.telemetry.metrics import is_enabled
 
     snap = snapshot(registry)

@@ -6,8 +6,7 @@ the program can place the cache. Otherwise the cache is one fixed
 directory inside the checkout. The directory is part of what makes an
 entry findable again, so it never depends on the host, the user's home,
 a pid or a temp name — every entry point of one checkout (drivers,
-bench.py, chip_smoke.py, the tests, the benchmark scripts) shares one
-set of compiles.
+chip_smoke.py, the tests, the benchmark) shares one set of compiles.
 
 The directory is listed in .gitignore, .dockerignore and
 .chiprunignore: a copied checkout starts cold instead of loading XLA:CPU
