@@ -75,10 +75,14 @@ def test_kanana2_cell_update_compiles_for_v5e(one_chip, monkeypatch):
         memory.temp_size_in_bytes + memory.argument_size_in_bytes
         + memory.output_size_in_bytes - memory.alias_size_in_bytes
     )
+    # `temp_size_in_bytes` 5,196,932,096 with the sweep's loops started from
+    # zeros, 5,105,227,264 with each first rung before its loop (PR 58: the
+    # zeros of a layer's five sums are gone).
     weights = 4 * sum(
         int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)
     )
     assert weights == 4 * 568_124_423
+    print("memory", memory, "total GiB", total / 2**30)
     # 15.75 GiB a chip, less the driver's copy of the weights.
     assert total < 15.75 * 2**30 - weights, memory
     assert total > 8 * 2**30, memory  # the cell fills the chip
@@ -102,13 +106,17 @@ def test_kanana2_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     # The grouped expert matmuls, ONE kernel call a product at the
     # family's two terms a side (PR 50: ops/grouped_matmul.py cuts the
     # float32 tiles in VMEM; three calls a product, 144, before): four
-    # MoE layers x (3 forward, 3 the backward loop's second forward, 6
-    # backward); and the cache leg's kernels: five layers x (forward,
+    # MoE layers x (3 forward, 3 the backward sweep's second forward, 6
+    # backward), each rung compiled twice since PR 58, the first before
+    # the loop and the loop's body (48 while the loop started from
+    # zeros); and the cache leg's kernels: five layers x (forward,
     # rematerialised, backward).
-    assert compiled.as_text().count("tpu_custom_call") == 48 + 15
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 96 + 15
     assert len(re.findall(
-        r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem',
-        compiled.as_text(),
-    )) == 48
+        r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
+    )) == 96
+    assert "/moe/moe_sweep/jit(_rung)/moe_experts" in text
+    assert "/moe/moe_sweep/while/body/jit(_rung)/moe_experts" in text
     assert compiled.as_text().count("fused_latent_leg_forward") >= 10
     assert compiled.as_text().count("fused_latent_leg_backward") >= 5

@@ -144,6 +144,9 @@ def test_lfm2_cell_update_compiles_for_v5e(one_chip, monkeypatch):
         memory.temp_size_in_bytes + memory.argument_size_in_bytes
         + memory.output_size_in_bytes - memory.alias_size_in_bytes
     )
+    # `temp_size_in_bytes` 4,825,537,024 with the sweep's loops started from
+    # zeros, 4,613,905,920 with each first rung before its loop (PR 58: the
+    # zeros of a layer's five sums are gone).
     weights = 4 * sum(
         int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)
     )
@@ -168,16 +171,25 @@ def test_lfm2_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     assert not {s for s in shapes if int(np.prod(s)) >= frames}
     # The carried tails are the program's arguments.
     assert (2, rows, 2048) in shapes
-    # Four MoE layers x 12 grouped products, one kernel call each: 3
-    # in the forward sweep's loop body and 9 in the backward's (the
+    # Four MoE layers x 12 grouped products a rung, one kernel call
+    # each: 3 in the forward sweep's and 9 in the backward's (the
     # rung's forward again, 6 backward); the rematerialised block's
     # sweep is dead, its value unused. Until PR 56 the same 12 stood
     # inline (3 forward, 3 rematerialised, 6 backward) over all 16,384
-    # rows. Beside them the attention layer's three.
+    # rows. Since PR 58 a rung is compiled twice, the first before the
+    # loop and the loop's body (48 while the loop started from zeros),
+    # and no zeros of a weight's shape are broadcast under a sweep.
+    # Beside them the attention layer's three.
     assert len(re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
-    )) == 48
-    assert text.count("tpu_custom_call") == 48 + 3
+    )) == 96
+    assert text.count("tpu_custom_call") == 96 + 3
+    assert "/moe/moe_sweep/jit(_rung)/moe_experts" in text
+    assert "/moe/moe_sweep/while/body/jit(_rung)/moe_experts" in text
+    assert not re.search(
+        r"f32\[8,(2048,1792|1792,2048)\][^=\n]* broadcast\([^\n]*moe_sweep",
+        text,
+    )
     # The kernels and the SwiGLU's elementwise passes see a rung.
     assert "f32[5120,1792]" in text and "[16384,1792]" not in text
     # The family's scopes reach the compiled program.

@@ -138,6 +138,22 @@ def test_experts_cut_in_vmem_compile_for_v5e(
     assert "vmem_limit_bytes" not in text
 
 
+def _a_rung_stands_before_each_loop_and_in_it(text):
+    """The scope paths of a sweep's compiled text: the experts of the
+    first rung directly under `moe_sweep`, those of the rungs from the
+    second on under its `while/body`, forward and backward (a rung is
+    a jitted function, traced once for its two places)."""
+    for sweep, experts in (
+        ("/jvp(moe_sweep)", "jit(_rung)/moe_experts"),
+        (
+            "/transpose(jvp(moe_sweep))",
+            "jit(_rung_gradients)/jvp(moe_experts)",
+        ),
+    ):
+        assert f"{sweep}/{experts}" in text, sweep
+        assert f"{sweep}/while/body/{experts}" in text, sweep
+
+
 def test_nemotron3_dispatch_moves_the_windows_rows_alone_on_v5e(
     one_chip, monkeypatch
 ):
@@ -152,8 +168,14 @@ def test_nemotron3_dispatch_moves_the_windows_rows_alone_on_v5e(
     rung of 2,816 rows at a time, in a loop on the device of as many
     turns as the step's rows fill, forward and backward: no array of
     the experts' width is as long as the whole window, zeros or
-    otherwise, each loop holds one copy of the kernels, and the
-    temporaries are 507,526,144 bytes."""
+    otherwise, and the temporaries were 507,526,144 bytes. And (PR 58)
+    the first rung stands outside each loop, its results the loop's
+    starting sums: a rung's kernels are in the program twice, once
+    before each loop and once as its body, no zeros of a weight's shape
+    are broadcast for the loop to add to, and the temporaries are
+    282,201,600 bytes (190,986,240 at the parent, whose bound stood at
+    PR 44's figure: two more prefetches of `w_down` stand in the
+    program, one a first rung)."""
     from torchbeast_tpu.models import moe
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -182,19 +204,21 @@ def test_nemotron3_dispatch_moves_the_windows_rows_alone_on_v5e(
     assert (rung, window) == (2816, tokens * held)
     assert f"f32[{rung},{width}]" in text and f"f32[{rung},{latent}]" in text
     assert f"[{window},{width}]" not in text
-    # The forward loop and the backward's, under the sweep's own name
-    # (PR 51: `moe_sweep`; a rung's parts enter their scopes inside).
-    assert "/jvp(moe_sweep)/while/body/moe_experts" in text
-    assert (
-        "/transpose(jvp(moe_sweep))/while/body/jvp(moe_experts)" in text
-    )
-    assert compiled.memory_analysis().temp_size_in_bytes <= 507_526_144
-    # Two forward kernels in the forward loop; those and four backward
-    # in the backward loop; ONE call a product since PR 50 (the kernels
-    # cut their operands in VMEM; three passes each, 24, before), and
-    # no second copy.
-    assert text.count("tpu_custom_call") == 8
-    assert text.count("gmm_cut_in_vmem") >= 8
+    # The forward sweep and the backward's, under the sweep's own name
+    # (PR 51: `moe_sweep`; a rung's parts enter their scopes inside):
+    # the first rung before the loop, the further ones its body (PR 58).
+    _a_rung_stands_before_each_loop_and_in_it(text)
+    assert f"f32[{held},{latent},{width}]" in text
+    weight = rf"f32\[{held},({latent},{width}|{width},{latent})\]"
+    assert not re.search(weight + r"[^=\n]* broadcast\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 282_201_600
+    # Two forward kernels in the forward sweep's rung; those and four
+    # backward in the backward's; ONE call a product since PR 50 (the
+    # kernels cut their operands in VMEM; three passes each, 24,
+    # before). Since PR 58 a rung is compiled twice, the first and the
+    # loop's body: 16 where one copy was 8.
+    assert text.count("tpu_custom_call") == 16
+    assert text.count("gmm_cut_in_vmem") >= 16
 
 
 # tokens, top_k, experts, held, d, width, the precision the family traces
@@ -217,8 +241,9 @@ def test_quarter_share_sweeps_a_rung_on_v5e(one_chip, monkeypatch, cell):
     the experts' width is as long as all the sorted rows, and the only
     arrays of the model's width that long are the two gathers by
     `slot` (the forward's sum and the dispatch's gradient; the gates'
-    gradient reads scalars back). Each loop holds one copy of the
-    kernels: three products forward, those and six backward."""
+    gradient reads scalars back), each once in the first rung and once
+    in the loop that takes the rungs from the second on (PR 58). A
+    rung holds three products forward, those and six backward."""
     from torchbeast_tpu.models import moe
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -246,16 +271,21 @@ def test_quarter_share_sweeps_a_rung_on_v5e(one_chip, monkeypatch, cell):
     text = compiled.as_text()
     assert f"[{rows},{width}]" not in text
     assert f"f32[{rung},{width}]" in text and f"f32[{rung},{d}]" in text
-    assert "/jvp(moe_sweep)/while/body/moe_experts" in text
-    assert (
-        "/transpose(jvp(moe_sweep))/while/body/jvp(moe_experts)" in text
+    _a_rung_stands_before_each_loop_and_in_it(text)
+    # 12 a rung, the first rung and the loop's body (PR 58; one copy,
+    # 12, while the loop started from zeros).
+    assert text.count("tpu_custom_call") == 24
+    # No zeros of a weight's shape for the backward's sums to start
+    # from: the first rung's `tgmm`s write them.
+    assert not re.search(
+        rf"f32\[{held},({d},{width}|{width},{d})\][^=\n]* broadcast\(", text
     )
-    assert text.count("tpu_custom_call") == 12
     # What is written as long as all the sorted rows at the model's
-    # width: the two gathers by `slot`, [tokens, K, d] or flat.
+    # width: the two gathers by `slot`, [tokens, K, d] or flat, of the
+    # first rung and of the loop's body.
     long_rows = rf"= f32\[({tokens},{top_k},{d}|{rows},{d})\]"
     long_gathers = [
         line for line in text.splitlines()
         if re.search(long_rows, line) and "gather" in line.split("=")[0]
     ]
-    assert len(long_gathers) == 2, long_gathers
+    assert len(long_gathers) == 4, long_gathers

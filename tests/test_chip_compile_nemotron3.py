@@ -73,10 +73,14 @@ def test_nemotron3_cell_update_compiles_for_v5e(one_chip, monkeypatch):
         memory.temp_size_in_bytes + memory.argument_size_in_bytes
         + memory.output_size_in_bytes - memory.alias_size_in_bytes
     )
+    # `temp_size_in_bytes` 4,192,582,656 with the sweep's loops started from
+    # zeros, 4,199,352,832 with each first rung before its loop (PR 58: +6.8
+    # MB, 0.05% of the cell's 12.76 GiB on the chip).
     weights = 4 * sum(
         int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)
     )
     assert weights == 4 * 755_035_623
+    print("memory", memory, "total GiB", total / 2**30)
     assert total + weights < 15.0 * 2**30, memory
     assert total > 8 * 2**30, memory  # the cell fills the chip
     text = compiled.as_text()
@@ -106,10 +110,16 @@ def test_nemotron3_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     # The experts' products are ONE kernel call each at the family's
     # two terms a side (PR 50: ops/grouped_matmul.py; 150 calls of the
     # shipped kernels before): five MoE layers x (2 forward, 2 the
-    # backward loop's second forward, 4 backward, and the two forward
-    # again in the rematerialised block's); beside them the attention
+    # backward sweep's second forward, 4 backward), each rung compiled
+    # twice since PR 58, the first before the loop and the loop's body,
+    # and the two forward of the rematerialised block's loop: its first
+    # rung's value is unused and gone, and the loop that is left takes
+    # no turn on a step of one rung (50 while the loops started from
+    # zeros, that one's turn among them); beside them the attention
     # layer's three.
     assert len(re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
-    )) == 50
-    assert text.count("tpu_custom_call") == 50 + 3
+    )) == 90
+    assert "/moe/moe_sweep/jit(_rung)/moe_experts" in text
+    assert "/moe/moe_sweep/while/body/jit(_rung)/moe_experts" in text
+    assert text.count("tpu_custom_call") == 90 + 3

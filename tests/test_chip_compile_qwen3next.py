@@ -89,6 +89,9 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
         memory.temp_size_in_bytes + memory.argument_size_in_bytes
         + memory.output_size_in_bytes - memory.alias_size_in_bytes
     )
+    # `temp_size_in_bytes` 6,472,551,424 with the sweep's loops started from
+    # zeros, 6,439,727,616 with each first rung before its loop (PR 58: the
+    # zeros of a part's five sums are gone).
     weights = 4 * sum(
         int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)
     )
@@ -149,7 +152,9 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     long_rows = re.findall(
         r"^\s+%%\S+ = \(?f32\[%d,2048\][^\n]*" % window, text, re.M
     )
-    assert 0 < len(long_rows) <= 12, len(long_rows)
+    # Two a MoE part (PR 56), in the first rung and in the loop's body
+    # (PR 58: 8 while one copy of a rung stood in the program).
+    assert 0 < len(long_rows) <= 16, len(long_rows)
     assert all('/gather"' in line for line in long_rows)
     # A DeltaNet layer solves once: ten products forward, two backward.
     solves = [
@@ -163,12 +168,16 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     # The experts' products are ONE kernel call each at the family's
     # two terms a side (PR 50: ops/grouped_matmul.py; 144 calls of the
     # shipped kernels before): four MoE parts x (3 forward, 3 the
-    # backward loop's second forward, 6 backward); beside them the
-    # attention layer's three.
+    # backward sweep's second forward, 6 backward), each rung compiled
+    # twice since PR 58, the first before the loop and the loop's body
+    # (48 while the loop started from zeros); beside them the attention
+    # layer's three.
     assert len(re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
-    )) == 48
-    assert text.count("tpu_custom_call") == 48 + 3
+    )) == 96
+    assert text.count("tpu_custom_call") == 96 + 3
+    assert "/moe/moe_sweep/jit(_rung)/moe_experts" in text
+    assert "/moe/moe_sweep/while/body/jit(_rung)/moe_experts" in text
     # A forward and a backward loop a MoE part, their turns counted on
     # the device from the step's own group sizes.
     sweeps = re.findall(

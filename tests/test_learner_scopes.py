@@ -318,21 +318,25 @@ def test_the_experts_own_kernels_are_under_their_scope(monkeypatch):
 
 
 def _loops(jaxpr, under=""):
-    """The name stack of every `while` in a jaxpr, calls inside calls
-    too."""
+    """(name stack, body) of every `while` in a jaxpr, calls inside
+    calls too."""
     for eqn in jaxpr.eqns:
         stack = f"{under}/{eqn.source_info.name_stack}"
         if eqn.primitive.name == "while":
-            yield stack
+            yield stack, eqn.params["body_jaxpr"].jaxpr
         for inner in jax.core.jaxprs_in_params(eqn.params):
             yield from _loops(inner, stack)
 
 
 def test_the_sweeps_loop_has_a_name_of_its_own():
     """The swept experts' loop holds a rung's dispatch, experts and
-    combine, each under its scope; the loop's own work (the backward's
-    carried sums of the weights' gradients, 7.3 ms a step in the
-    Qwen3-Next cell) is `moe_sweep`'s, forward and backward."""
+    combine, each under its scope; the loop's own work (a second
+    rung's sums into the weights' gradients; until PR 58 every step's,
+    from zeros: 7.3 ms a step in the Qwen3-Next cell) is `moe_sweep`'s,
+    forward and backward. The first rung stands before the loop under
+    the same name (PR 58): its kernels are `moe_sweep` >
+    `moe_experts`, the further rungs' `moe_sweep` > `while` >
+    `moe_experts`."""
     import jax.numpy as jnp
 
     from torchbeast_tpu.models import moe
@@ -356,8 +360,17 @@ def test_the_sweeps_loop_has_a_name_of_its_own():
         jnp.ones((held, d, width)), jnp.ones((held, width, d)),
     )
     sweeps = [
-        stack for stack in _loops(jaxpr.jaxpr)
+        (stack, body) for stack, body in _loops(jaxpr.jaxpr)
         if "searchsorted" not in stack
     ]
     assert len(sweeps) == 2  # the forward's and the backward's
-    assert all(_in_scope(stack, "moe_sweep") for stack in sweeps)
+    assert all(_in_scope(stack, "moe_sweep") for stack, _ in sweeps)
+    # A rung's kernels (3 forward; those and 6 backward) stand twice,
+    # before each loop and as its body, all under both names.
+    kernels = [stack for stack, _ in _kernel_calls(jaxpr.jaxpr)]
+    assert len(kernels) == 2 * (3 + 9)
+    assert all(
+        _in_scope(stack, "moe_sweep") and _in_scope(stack, "moe_experts")
+        for stack in kernels
+    )
+    assert [len(list(_kernel_calls(body))) for _, body in sweeps] == [3, 9]

@@ -156,6 +156,19 @@ def _window_by_hand(idx, first, held):
     return order_w, live, slot
 
 
+def _sorted_indices(idx, experts):
+    """(order, inverse, sizes) of idx [tokens, K] as `dropless_experts`
+    makes them: the assignments sorted by expert, the permutation back,
+    every expert's count."""
+    flat = idx.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(flat.shape[0], dtype=order.dtype)
+    )
+    sizes = jnp.bincount(flat, length=experts).astype(jnp.int32)
+    return order, inverse, sizes
+
+
 @pytest.mark.parametrize(
     "tokens, K, E, held, first",
     [(16, 3, 8, 2, 0), (16, 3, 8, 2, 3), (16, 3, 8, 2, 6),
@@ -187,17 +200,10 @@ def test_window_dispatch_and_combine_against_plain_gathers(
     if first + held == E:
         assert int(np.sum(np.asarray(idx) < first)) + window > tokens * K
 
-    def indices(idx):
-        flat = idx.reshape(-1)
-        order = jnp.argsort(flat, stable=True)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(tokens * K, dtype=order.dtype)
-        )
-        sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
-        return order, inverse, sizes
-
     def dispatch(x):
-        return moe._window_dispatch(x, idx, *indices(idx), first, held)
+        return moe._window_dispatch(
+            x, idx, *_sorted_indices(idx, E), first, held
+        )
 
     dispatched = jax.jit(dispatch)
     rows, groups, at = dispatched(x)
@@ -526,8 +532,9 @@ def test_a_quarter_share_moves_a_rung_of_rows_and_the_permute_all():
     long as all the sorted rows are TWO (the forward's sum and the
     dispatch's gradient; the gates' gradient reads [2048] scalars
     back), the rung's three (dispatch forward, again backward, and
-    `grad[token]`). The full permute gathers all the rows four times
-    and holds the hidden width at 2,048 rows."""
+    `grad[token]`), each once in the first rung and once in the loop's
+    body. The full permute gathers all the rows four times and holds
+    the hidden width at 2,048 rows."""
     tokens, top_k, held = QUARTER_TOKENS, QUARTER_K, QUARTER_HELD
     d, f = 8, 16
     rows, rung = tokens * top_k, QUARTER_RUNG
@@ -552,7 +559,13 @@ def test_a_quarter_share_moves_a_rung_of_rows_and_the_permute_all():
     assert "moe_sweep)/while" in swept and "moe_sweep" not in permuted
     assert f"f32[{rung},{f}]" in swept and f"f32[{rows},{f}]" not in swept
     assert f"f32[{rows},{f}]" in permuted
-    assert gathered(swept)[:6] == [rows * d] * 2 + [rung * d] * 3 + [rows]
+    # PR 58: a rung is traced twice, the first outside the loop and the
+    # loop's body (2 + 3 + 1 before it). The first rung's dispatch is ONE
+    # gather for the forward pass and the backward's: outside a loop the
+    # compiler sees they are the same.
+    assert gathered(swept)[:11] == (
+        [rows * d] * 4 + [rung * d] * 5 + [rows] * 2
+    )
     assert gathered(permuted)[:4] == [rows * d] * 4
     assert gathered(permuted)[4] < rows
     for program in (swept, permuted):

@@ -47,7 +47,10 @@ experts: LFM2's 8 of 32, Mellum2's 16 of 64), a rung of 1.25 times the
 even load, if that is under half the window; else, and with every
 expert held, all the t x K sorted rows are permuted. Whatever the rung,
 the sweep takes as many as the step's own sizes fill, so no assignment
-is dropped at any routing.
+is dropped at any routing. The first rung is taken outside the loop
+that takes the rest (`_sweep`): near an even load it is the only one,
+and its kernels then write the layer's sums, the weights' gradients
+among them, once, with nothing to add them to.
 """
 
 import functools
@@ -585,17 +588,49 @@ def _window_experts(how, at_row, x, gate, w_gate, w_up, w_down, idx, order,
         )
 
 
-def _sweep(how, sizes, rung_at, start):
-    """`rung_at(at_row, carry)` over the rungs this step's window
-    takes, one compiled body however many they are."""
+def _sweep(how, sizes, rung_at):
+    """The sum of `rung_at(at_row)` over the rungs this step's window
+    takes. The first rung is taken outside the loop and its results
+    ARE the loop's starting sums: a step on one rung (every layer of
+    every cell, PERF.md section 5) writes each sum once, by the kernel
+    that makes it, where a loop from zeros wrote the zeros, had the
+    kernels write beside them and added the two into a third (6-7
+    passes of HBM over a layer's weight gradients, PR 58). The loop
+    adds the rungs from the second on, one compiled body however many
+    they are. A step with no row of the held experts takes the first
+    rung all the same: it visits no row, and the grouped kernels give
+    zeros for groups without rows (`grouped_matmul`). `rung_at` is
+    handed an int32 scalar both times, so that a jitted rung is traced
+    and lowered once for its two places."""
     mine = sizes[how.first : how.first + how.held]
-    # The loop's own work (the carried sums, their zeros and copies)
-    # under a name: a rung's parts enter their scopes inside it.
+    # The loop's own work (the carried sums and their copies) under a
+    # name: a rung's parts enter their scopes inside it.
     with device_scope("moe_sweep"):
         return jax.lax.fori_loop(
-            0, window_sweeps(how.rungs, mine),
-            lambda i, carry: rung_at(i * how.rungs[0], carry), start,
+            1, window_sweeps(how.rungs, mine),
+            lambda i, sums: jax.tree_util.tree_map(
+                jnp.add, sums, rung_at(i * how.rungs[0])
+            ),
+            rung_at(jnp.int32(0)),
         )
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _rung(how, at_row, weights, indices):
+    """`_window_experts` at one rung, jitted so that the first rung
+    and the loop's body are one trace (a rung traced twice cost the
+    LFM2 cell's update 3 s of every start, PR 58)."""
+    return _window_experts(how, at_row, *weights, *indices)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _rung_gradients(how, at_row, weights, indices, grad):
+    """`grad` pulled back through `_rung` to the weights: the rung's
+    forward again, then its backward."""
+    _, pull_back = jax.vjp(
+        lambda *w: _window_experts(how, at_row, *w, *indices), *weights
+    )
+    return pull_back(grad)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -603,35 +638,27 @@ def _swept_experts(how, weights, indices):
     """`_window_experts` summed over the rungs that hold a row of the
     held experts: one in a step whose routing is near even, all of the
     window if every token chose every held expert; no assignment is
-    left out. Differentiated as a whole: the rungs are a loop of as
-    many turns as the step's sizes say, which JAX does not reverse, and
-    the backward pass is the same loop over each rung's own."""
+    left out. Differentiated as a whole: the rungs past the first are
+    a loop of as many turns as the step's sizes say, which JAX does
+    not reverse, and the backward pass is the same sweep over each
+    rung's own: the first rung's gradients as its kernels write them,
+    a further rung's added to those (`_sweep`)."""
     return _swept_experts_fwd(how, weights, indices)[0]
 
 
 def _swept_experts_fwd(how, weights, indices):
     y = _sweep(
         how, indices[-1],
-        lambda at_row, y: y + _window_experts(
-            how, at_row, *weights, *indices
-        ),
-        jnp.zeros(weights[0].shape, jnp.float32),
+        lambda at_row: _rung(how, at_row, weights, indices),
     )
     return y, (weights, indices)
 
 
 def _swept_experts_bwd(how, residuals, grad):
     weights, indices = residuals
-
-    def rung_at(at_row, grads):
-        _, pull_back = jax.vjp(
-            lambda *w: _window_experts(how, at_row, *w, *indices), *weights
-        )
-        return jax.tree_util.tree_map(jnp.add, grads, pull_back(grad))
-
     return _sweep(
-        how, indices[-1], rung_at,
-        jax.tree_util.tree_map(jnp.zeros_like, weights),
+        how, indices[-1],
+        lambda at_row: _rung_gradients(how, at_row, weights, indices, grad),
     ), None
 
 
@@ -656,14 +683,17 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None,
     is moved alone: a rung's rows gathered from x, t x min(C, K)
     gathered from the kernels' output and summed as many a token
     (`_window_dispatch`, `_window_combine`), backward by a gather of
-    as many for x and of a rung's for the rest; where the shapes leave room for a rung short of the window
-    (twice the even load's rows, or 1.25 times them where C >= K and
-    twice leaves none: `window_rungs`), it is swept a rung at a time
-    only as far as the step's own rows reach, counted on the device, so
-    any routing is computed whole. Elsewhere (all experts held, or C >=
-    K and no room for either rung: more than two fifths of the experts)
-    every one of the t x K sorted rows is moved: x repeated K times and
-    permuted, the kernels' rows permuted back and summed K a token."""
+    as many for x and of a rung's for the rest; where the shapes leave
+    room for a rung short of the window (twice the even load's rows,
+    or 1.25 times them where C >= K and twice leaves none:
+    `window_rungs`), it is swept a rung at a time only as far as the
+    step's own rows reach, counted on the device, so any routing is
+    computed whole; the first rung outside the loop, so that a step on
+    one rung adds nothing to anything (`_sweep`). Elsewhere (all
+    experts held, or C >= K and no room for either rung: more than two
+    fifths of the experts) every one of the t x K sorted rows is
+    moved: x repeated K times and permuted, the kernels' rows permuted
+    back and summed K a token."""
     tokens, K = idx.shape
     first, E = first_of or (None, w_up.shape[0])
     terms = _terms_traced_under()
