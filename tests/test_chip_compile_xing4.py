@@ -29,7 +29,9 @@ def test_xing4_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     v5e: where the fit is settled before any chip time. Its bytes with
     the driver's copy of the weights stay under the rule's 15.0 GiB (no
     fallback of the configuration's `fit` taken); the streams cross the
-    blocks as [4, 32, 81, 3584], never with the 4 on a tile's rows; the
+    blocks a token a row, [4, 2592, 3584], never with the 4 on a tile's
+    rows nor with an unroll's 81 steps on them (88 a tile, and laid out
+    again at every kernel: ops/stream_mix.py); the
     cache leg's scores stay in `fused_latent_leg`'s kernels; 8 held at
     top 4 sweeps a rung of the window (`moe.window_rungs`)."""
     from perfbench import flops_xing4, manifest
@@ -90,10 +92,12 @@ def test_xing4_cell_update_compiles_for_v5e(one_chip, monkeypatch):
         tuple(int(d) for d in dims.split(","))
         for dims in re.findall(r"f32\[([0-9,]+)\]", text)
     }
-    # The streams, stream-major; never with the 4 on a tile's rows
-    # ([2592, 4, 3584] is the combine's tokens x top 4, tiled by 4).
-    assert (4, 32, 81, 3584) in shapes
+    # The streams, stream-major and a token a row; never with the 4 on
+    # a tile's rows ([2592, 4, 3584] is the combine's tokens x top 4,
+    # tiled by 4), never [4, B, T, d].
+    assert (4, 2592, 3584) in shapes
     assert (32, 81, 4, 3584) not in shapes
+    assert (4, 32, 81, 3584) not in shapes
     # No f32 array over the slots of rank 4 or more: the scores.
     scores = {s for s in shapes if s[-1] in (1023, 1024) and len(s) >= 4}
     assert not scores, scores
@@ -107,3 +111,18 @@ def test_xing4_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     assert len(re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
     )) == 108
+    # The residual path's kernels (ops/stream_mix.py), ten sublayers:
+    # the maps + pre-sum forward and rematerialised, their backward and
+    # the mix's.
+    calls = {
+        kernel: len(re.findall(
+            r'custom_call_target="tpu_custom_call"[^\n]*' + kernel, text
+        ))
+        for kernel in ("stream_maps_forward", "stream_maps_backward",
+                       "stream_mix_backward")
+    }
+    print("stream kernels", calls)
+    assert calls == {
+        "stream_maps_forward": 20, "stream_maps_backward": 10,
+        "stream_mix_backward": 10,
+    }

@@ -316,8 +316,11 @@ def _published_xing4(model):
     # The cut: ONE leading dense layer; the streams between the blocks.
     assert model.leading_dense_layers() == 1
     x = jax.ShapeDtypeStruct((32, 81, 3584), jnp.float32)
+    # A token a row, the batch's shape beside them (PR 60): the layout
+    # ops/stream_mix.py's kernels take, held from block to block.
     streams = jax.eval_shape(model.into_streams, x)
-    assert streams.shape == (4, 32, 81, 3584)
+    assert streams.x.shape == (4, 32 * 81, 3584)
+    assert (streams.rows, streams.steps) == (32, 81)
     assert jax.eval_shape(model.out_of_streams, streams).shape == x.shape
     # 57 KB a token between blocks, in float32.
     assert 4 * 4 * 3584 == 57_344
@@ -814,11 +817,12 @@ STATS_AT_PR_44 = {
         "ssm_chunks", "ssm_resets_per_row", "ssm_state_bytes_per_row",
     ],
     # The family of PR 59, as it came: Kanana-2's and the residual
-    # path's three.
+    # path's three; PR 60's count of the sublayers its kernels ran.
     "xing4": [
         "attention_latent_applications",
         "attention_latent_cache_bytes_per_row", "hc_bytes_per_row",
-        "hc_post_mean", "hc_res_row_error_max", "moe_assignments",
+        "hc_fused_applications", "hc_post_mean", "hc_res_row_error_max",
+        "moe_assignments",
         "moe_bias_abs_max", "moe_bias_steps", "moe_held_assignments",
         "moe_held_load_max_over_mean", "moe_load_max_over_mean",
         "moe_shared_applications", "moe_window_rows",

@@ -64,6 +64,25 @@ def test_family_agrees_with_the_reference(share):
     assert len(steps) == LAYERS - 1
 
 
+def test_family_through_the_stream_kernels_agrees_with_the_reference():
+    """At a width of whole lane tiles (128) every sublayer's maps,
+    pre-sum and mix run ops/stream_mix.py's kernels (interpreted here;
+    `hc_fused_applications` counts them: two a layer), and outputs,
+    caches, loss and gradients are still the reference's, the maps
+    moved as above."""
+    model, params = scaffold.build("xing4", d_model=128)
+    state = scaffold.warm_state(model, params, seed=5)
+    batch = scaffold.learner_batch(7, done_steps=[(3, 0)])
+    stats, grads, _, _ = scaffold.assert_agrees_with_the_reference(
+        model, params, state, batch, RTOL, ATOL
+    )
+    assert float(stats["hc_fused_applications"]) == 2 * LAYERS
+    block = grads["params"]["block_1"]
+    for leaf in (block["attn_hc"]["phi"], block["mlp_hc"]["a"],
+                 block["mlp_hc"]["b"]):
+        assert np.any(leaf)
+
+
 @pytest.mark.parametrize("fault", ["bf16_maps", "nineteen_steps"])
 def test_the_tolerance_sees_a_rounded_map_and_a_skipped_step(
     fault, monkeypatch
@@ -142,7 +161,7 @@ def test_h_res_is_doubly_stochastic_after_twenty_steps():
     hold at ANY `a` (the last step is theirs), the rows as near as
     twenty steps bring them, which `hc_res_row_error_max` reports."""
     maps, streams, params = _maps([0.01, 0.01, 0.01])
-    h_pre, h_post, h_res = scaffold.apply(maps)(params, streams)
+    _, _, (h_pre, h_post, h_res) = scaffold.apply(maps)(params, streams)
     assert h_pre.shape == h_post.shape == (4, 3, 5)
     assert h_res.shape == (4, 4, 3, 5)
     np.testing.assert_allclose(h_res.sum(axis=1), 1.0, atol=1e-5)
@@ -155,7 +174,9 @@ def test_h_res_is_doubly_stochastic_after_twenty_steps():
         np.moveaxis(np.asarray(h_res), (0, 1), (-2, -1)),
         np.broadcast_to(np.eye(4), (3, 5, 4, 4)), atol=1e-3,
     )
-    _, _, moved = scaffold.apply(maps)(_maps([1.0, 1.0, 1.0])[2], streams)
+    _, _, (_, _, moved) = scaffold.apply(maps)(
+        _maps([1.0, 1.0, 1.0])[2], streams
+    )
     np.testing.assert_allclose(moved.sum(axis=0), 1.0, atol=1e-5)
     assert 1e-4 < float(jnp.max(jnp.abs(moved.sum(axis=1) - 1.0))) < 0.1
 
@@ -168,7 +189,7 @@ def test_h_res_gradient_is_finite_at_the_clips_edges(edge):
     maps, streams, params = _maps([0.5, 0.4, 0.3], b_res_edge=edge)
 
     def read(params, streams):
-        h_pre, h_post, h_res = maps.apply(params, streams)
+        _, _, (h_pre, h_post, h_res) = maps.apply(params, streams)
         weights = jnp.arange(16.0).reshape(4, 4, 1, 1)
         return jnp.sum(h_res * weights) + jnp.sum(h_pre * h_post)
 
@@ -177,6 +198,12 @@ def test_h_res_gradient_is_finite_at_the_clips_edges(edge):
     assert np.isfinite(float(value))
     for leaf in jax.tree_util.tree_leaves(grads):
         assert np.all(np.isfinite(leaf))
+
+
+def _as_the_net_hands_them(streams):
+    """[4, B, T, d] -> `xing4.Streams`, a token a row."""
+    n, rows, steps, d = streams.shape
+    return xing4.Streams(streams.reshape(n, rows * steps, d), rows, steps)
 
 
 def _layer(model, layer, params):
@@ -212,8 +239,8 @@ def test_maps_at_zero_are_a_plain_pre_norm_residual_block():
     x = jax.random.normal(jax.random.PRNGKey(4), (B, T, 48))
     got, c, k_r = scaffold.apply(streamed)(
         {"params": dict(weights, attn_hc=at_zero, mlp_hc=at_zero)},
-        jnp.broadcast_to(x[None], (4, B, T, 48)), cache, cache_mask,
-        seq_mask,
+        _as_the_net_hands_them(jnp.broadcast_to(x[None], (4, B, T, 48))),
+        cache, cache_mask, seq_mask,
     )
     want, want_c, want_k_r = scaffold.apply(plain)(
         {"params": {
@@ -221,8 +248,10 @@ def test_maps_at_zero_are_a_plain_pre_norm_residual_block():
         }},
         x, cache, cache_mask, seq_mask,
     )
-    for stream in got:
-        np.testing.assert_allclose(stream, want, rtol=1e-5, atol=1e-5)
+    for stream in got.x:
+        np.testing.assert_allclose(
+            stream.reshape(want.shape), want, rtol=1e-5, atol=1e-5
+        )
     np.testing.assert_allclose(c, want_c, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(k_r, want_k_r, rtol=1e-5, atol=1e-5)
 
@@ -247,8 +276,9 @@ def test_the_eight_shares_add_up_to_the_uncut_references_layer():
 
     def output(block, weights):
         return scaffold.apply(block)(
-            {"params": weights}, streams_in, cache, cache_mask, seq_mask
-        )[0]
+            {"params": weights}, _as_the_net_hands_them(streams_in), cache,
+            cache_mask, seq_mask,
+        )[0].x.reshape(streams_in.shape)
 
     stacked = ("w_gate", "w_up", "w_down")
 

@@ -38,14 +38,25 @@ dense layer a SwiGLU of 9216; the others sigmoid scores over 64, the 4
 largest of score + bias, renormalised, scaled by 2, one shared expert,
 the bias moved by load as Kanana-2's.
 
-The streams are held [4, B, T, 3584] float32, the stream axis FIRST: a
-stream is then a contiguous [B, T, d] like every other activation of
-the walk, where [B, T, 4, d] would pad its 4 rows to a tile's 8. The
-maps are small ([4, B, T], [4, 4, B, T]: tokens on the minor axes) and
+The streams are held [4, B T, 3584] float32 (`Streams`), the stream
+axis FIRST and a token a row: a stream is then a contiguous [B T, d],
+where [B, T, 4, d] would pad its 4 rows to a tile's 8 and [4, B, T, d]
+an unroll of 81 steps to 88. The maps are small ([4, B T], [4, 4, B T]:
+tokens on the minor axis) and
 are computed in float32 with `Phi`'s product at the highest precision
 whatever the family's matmuls run at: a 4 x 4 matrix from a 14,336-long
-sum feeds every later layer, as a router's logits do. Plain
-`jax.numpy`: XLA fuses the mixes; a kernel is a later lever.
+sum feeds every later layer, as a router's logits do. At a width of
+whole lane tiles (the published 3584) a sublayer reads its streams once
+a stage and direction, in ops/stream_mix.py's kernels: the products,
+the flat RMS and the pre-sum from one load of a token's [4, d] row
+(forward; backward dX written once, dPhi summed over the token blocks),
+and the mix's backward (dX, dy, the sixteen dH_res and four dH_post
+from one read of dX' and X); the forward mix, the sigmoids, the clipped
+exponent and the Sinkhorn steps are `jax.numpy` (XLA's fusions over
+small arrays, and one pass for the mix). Chosen by shape
+(`stream_mix.kernels_apply`; update stat `hc_fused_applications`): at
+another width (tier-1's 48) the `jax.numpy` body below runs whole, the
+kernels' reference.
 
 What config.json does not give, each ASSUMED (perfbench/configs/
 xing4_29b_policy.json `assumed`): no learned scale in the flat RMS;
@@ -63,6 +74,7 @@ import math
 from typing import Tuple
 
 import flax.linen as nn
+from flax import struct
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -70,6 +82,7 @@ import numpy as np
 from torchbeast_tpu.models.kanana2 import Kanana2Net, _Kanana2Block
 from torchbeast_tpu.models.mellum2 import rope_yarn
 from torchbeast_tpu.models.stats import sow_stat
+from torchbeast_tpu.ops import stream_mix
 from torchbeast_tpu.telemetry import device_scope
 
 # https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B/blob/main/config.json
@@ -126,24 +139,47 @@ def sinkhorn(s, iters, eps):
 
 
 def stream_products(streams, kernel):
-    """vec(X) Phi for every token: streams [n, B, T, d] against kernel
-    [n, d, columns] -> [columns, B, T], float32 at the highest
+    """vec(X) Phi for every token: streams [n, ..., d] against kernel
+    [n, d, columns] -> [columns, ...], float32 at the highest
     precision whatever the caller traces under. A product a stream,
     summed: as one contraction over (n, d) XLA first lays the streams
-    out again as [B, T, n, d], a copy of them with the 4 on a tile's 8
+    out again as [..., n, d], a copy of them with the 4 on a tile's 8
     rows."""
     return sum(
         jnp.einsum(
-            "btd,dc->cbt", stream, columns,
+            "...d,dc->c...", stream, columns,
             precision=jax.lax.Precision.HIGHEST,
         )
         for stream, columns in zip(streams, kernel)
     )
 
 
+@struct.dataclass
+class Streams:
+    """What the net's blocks hand each other: the streams a token a
+    row, x [n, B T, d] (a row of the batch after the other), and the
+    batch's `rows`, `steps` = B, T. Held so from the first block to the
+    last, and through `nn.remat`: [n, B, T, d] with T = 81 is 88 rows a
+    tile on the chip, and ops/stream_mix.py's kernels, which take a
+    token a row, would have it laid out again at every block's edge
+    (20 ms a step: PERF.md section 6, PR 60)."""
+
+    x: jax.Array
+    rows: int = struct.field(pytree_node=False)
+    steps: int = struct.field(pytree_node=False)
+
+
 class _StreamMaps(nn.Module):
-    """One sublayer's maps from the streams X [n, B, T, d] float32:
-    (H_pre [n, B, T], H_post [n, B, T], H_res [n, n, B, T])."""
+    """One sublayer's maps and its pre-sum from the streams X [n, ...,
+    d] float32, a token for every index of the dots (the net's blocks
+    hold [n, B T, d]): (X, u [..., d] = sum_i H_pre[i] X[i], (H_pre [n,
+    ...], H_post [n, ...], H_res [n, n, ...])). Where ops/stream_mix.py
+    `kernels_apply` (a width of whole lane tiles) the products, the
+    flat RMS and the pre-sum are one kernel's one read of the streams,
+    and the X handed back is the kernel's own: mixed in X's place it
+    brings the mix's cotangent into the backward kernel, which then
+    writes dX once. Elsewhere (tier-1's toy widths) the `jax.numpy`
+    body below, the kernels' reference."""
 
     rms_norm_eps: float
     sinkhorn_iters: int
@@ -152,7 +188,7 @@ class _StreamMaps(nn.Module):
 
     @nn.compact
     def __call__(self, streams):
-        n, _, _, d = streams.shape
+        n, *lead, d = streams.shape
         widths = (n, n, n * n)
 
         def biases(key, shape, dtype=jnp.float32):
@@ -175,32 +211,41 @@ class _StreamMaps(nn.Module):
             "a", nn.initializers.constant(MAP_SCALE_INIT), (len(widths),)
         )
         b = self.param("b", biases, (sum(widths),))
+        scale = a.astype(jnp.float32)[
+            np.repeat(np.arange(len(widths)), widths)
+        ]
+        bias = b.astype(jnp.float32)
+        kernel = phi.astype(jnp.float32).reshape(n, d, sum(widths))
+        fused = stream_mix.kernels_apply(n, d, streams.dtype)
         with device_scope("hc_maps"):
-            x = streams.astype(jnp.float32)
-            # The flat RMS over all n x d of a token (no learned scale),
-            # applied to the 24 products and not to the 14,336 inputs:
-            # (x / r) Phi = (x Phi) / r.
-            inv_rms = jax.lax.rsqrt(
-                jnp.mean(jnp.square(x), axis=(0, 3)) + self.rms_norm_eps
-            )
-            m = stream_products(
-                x, phi.astype(jnp.float32).reshape(n, d, sum(widths))
-            ) * inv_rms
-            scale = a.astype(jnp.float32)[
-                np.repeat(np.arange(len(widths)), widths)
-            ]
-            logits = (
-                scale[:, None, None] * m
-                + b.astype(jnp.float32)[:, None, None]
-            )
+            if fused:
+                # One pass over the streams: the products, the flat RMS
+                # and the pre-sum from one load of a token's row.
+                streams, u, m = stream_mix.maps_and_pre(
+                    streams, kernel, scale, bias, self.rms_norm_eps
+                )
+            else:
+                x = streams.astype(jnp.float32)
+                # The flat RMS over all n x d of a token (no learned
+                # scale), applied to the 24 products and not to the
+                # 14,336 inputs: (x / r) Phi = (x Phi) / r.
+                inv_rms = jax.lax.rsqrt(
+                    jnp.mean(jnp.square(x), axis=(0, -1)) + self.rms_norm_eps
+                )
+                m = stream_products(x, kernel) * inv_rms
+            by_map = (-1,) + (1,) * len(lead)
+            logits = scale.reshape(by_map) * m + bias.reshape(by_map)
             h_pre = jax.nn.sigmoid(logits[:n])
             h_post = 2.0 * jax.nn.sigmoid(logits[n : 2 * n])
             s = jnp.exp(
                 jnp.clip(logits[2 * n :], *self.res_clamp)
             ).reshape((n, n) + logits.shape[1:])
+        if not fused:
+            with device_scope("hc_pre"):
+                u = sum(h_pre[i][..., None] * streams[i] for i in range(n))
         with device_scope("hc_sinkhorn"):
             h_res = sinkhorn(s, self.sinkhorn_iters, self.hc_eps)
-        return h_pre, h_post, h_res
+        return streams, u, (h_pre, h_post, h_res)
 
 
 class _Xing4Block(_Kanana2Block):
@@ -213,23 +258,22 @@ class _Xing4Block(_Kanana2Block):
     sublayers: int = 2
 
     @nn.nowrap
-    def _mixed(self, name, streams, part):
-        """One sublayer: X [n, B, T, d] -> (X', whatever `part` returns
-        after its [B, T, d])."""
-        h_pre, h_post, h_res = _StreamMaps(
+    def _mixed(self, name, streams, steps, part):
+        """One sublayer: X [n, ..., d] -> (X', whatever `part` returns
+        after its [..., d]); `steps` of the tokens are a row's."""
+        n, *_, d = streams.shape
+        streams, u, (_, h_post, h_res) = _StreamMaps(
             rms_norm_eps=self.rms_norm_eps,
             sinkhorn_iters=self.sinkhorn_iters, hc_eps=self.hc_eps,
             res_clamp=self.res_clamp, name=name,
         )(streams)
-        n = streams.shape[0]
-        with device_scope("hc_pre"):
-            u = sum(h_pre[i][..., None] * streams[i] for i in range(n))
         y, *rest = part(u)
+        fused = stream_mix.kernels_apply(n, d, streams.dtype) and (
+            y.dtype == streams.dtype
+        )
         with device_scope("hc_post"):
-            mixed = sum(
-                h_res[:, j][..., None] * streams[j][None] for j in range(n)
-            )
-            streams = mixed + h_post[..., None] * y[None]
+            mix = stream_mix.mix if fused else stream_mix.plain_mix
+            streams = mix(streams, y, h_res, h_post)
         # Does the constraint hold at run time: the worst row of H_res
         # after the last step (its columns are 1 by construction).
         sow_stat(
@@ -239,28 +283,38 @@ class _Xing4Block(_Kanana2Block):
         sow_stat(
             self, "hc_post_mean", jnp.mean(h_post) / self.sublayers, "sum"
         )
+        # Sublayers whose maps, pre-sum and mix ran ops/stream_mix.py's
+        # kernels (0 where the `jax.numpy` body ran).
+        sow_stat(self, "hc_fused_applications", int(fused), "sum")
         # The streams a row of the batch hands this sublayer, float32.
-        sow_stat(
-            self, "hc_bytes_per_row",
-            4 * streams.shape[0] * streams.shape[2] * streams.shape[3],
-            "sum",
-        )
+        sow_stat(self, "hc_bytes_per_row", 4 * n * steps * d, "sum")
         return streams, rest
 
     @nn.compact
     def __call__(self, streams, cache_state, cache_mask, seq_mask, **_):
-        """TransformerNet's block contract with the streams [n, B, T, d]
-        in x's place (models/xing4.py `into_streams`)."""
-        streams, (c, k_r) = self._mixed(
-            "attn_hc", streams,
-            lambda u: self.attention_part(
+        """TransformerNet's block contract with `Streams` in x's place
+        (`into_streams`)."""
+        rows, steps = streams.rows, streams.steps
+
+        def over_the_batch(part):
+            # The parts are Kanana-2's, on [B, T, d].
+            def run(u):
+                y, *rest = part(u.reshape(rows, steps, u.shape[-1]))
+                return (y.reshape(u.shape), *rest)
+
+            return run
+
+        x, (c, k_r) = self._mixed(
+            "attn_hc", streams.x, steps,
+            over_the_batch(lambda u: self.attention_part(
                 u, cache_state, cache_mask, seq_mask
-            ),
+            )),
         )
-        streams, _ = self._mixed(
-            "mlp_hc", streams, lambda u: (self.feed_forward_part(u),)
+        x, _ = self._mixed(
+            "mlp_hc", x, steps,
+            over_the_batch(lambda u: (self.feed_forward_part(u),)),
         )
-        return (streams,) + self.for_the_cache(c, k_r)
+        return (streams.replace(x=x),) + self.for_the_cache(c, k_r)
 
 
 class Xing4Net(Kanana2Net):
@@ -318,12 +372,21 @@ class Xing4Net(Kanana2Net):
     @nn.nowrap
     def into_streams(self, x):
         # ASSUMED: every stream starts as the encoder's output.
-        return jnp.broadcast_to(x[None], (self.streams,) + x.shape)
+        rows, steps, d = x.shape
+        return Streams(
+            jnp.broadcast_to(
+                x.reshape(1, rows * steps, d),
+                (self.streams, rows * steps, d),
+            ),
+            rows, steps,
+        )
 
     @nn.nowrap
     def out_of_streams(self, streams):
         # ASSUMED: the streams are summed before the last norm.
-        return jnp.sum(streams, axis=0)
+        return jnp.sum(streams.x, axis=0).reshape(
+            streams.rows, streams.steps, -1
+        )
 
     @nn.nowrap
     def make_block(self, name: str, layer: int):
