@@ -1,6 +1,8 @@
 """Compile for the chip, without the chip (tests/chip_fixtures.py):
 `qwen3next_policy.learner`'s whole update, one AOT compile of the real
-cell. A file of its own: tests/chip_fixtures.py says why.
+cell, and the delta rule's two kernels alone at the cell's shapes and
+at others they take. A file of its own: tests/chip_fixtures.py says
+why.
 """
 
 import json
@@ -8,16 +10,95 @@ import os
 import re
 
 import numpy as np
+import pytest
 
 import jax
+import jax.numpy as jnp
 
 from tests.chip_fixtures import (  # noqa: F401 (fixtures)
     NUM_ACTIONS,
     on as _on,
     one_chip,
+    struct as _struct,
     topo,
 )
 from torchbeast_tpu import learner as learner_lib
+from torchbeast_tpu.models import qwen3next
+from torchbeast_tpu.ops import delta_rule
+
+
+def _shapes(text, under=""):
+    """The f32 shapes a compiled program names, on lines that hold
+    `under`."""
+    return {
+        tuple(int(d) for d in dims.split(","))
+        for line in text.splitlines() if under in line
+        for dims in re.findall(r"f32\[([0-9,]+)\]", line)
+    }
+
+
+def _states_of_every_cell(shapes, cells):
+    """Those of `shapes` that are a [128, 128] matrix a (row, chunk,
+    value head) cell: `left`, `handed_on`, `entering` of the `jax.numpy`
+    form, and their cotangents."""
+    return {
+        s for s in shapes
+        if s[-2:] == (128, 128) and int(np.prod(s[:-2])) >= cells
+    }
+
+
+@pytest.mark.parametrize(
+    "rows, steps, Hk, per, chunk, Dk, Dv, precision",
+    [
+        (16, 256, 16, 2, 64, 128, 128, "high"),  # the cell's
+        (2, 200, 16, 2, 64, 128, 128, "highest"),  # six passes, padded
+        (2, 64, 2, 2, 64, 128, 128, "high"),  # one chunk: no turn remakes
+        (2, 32, 1, 2, 16, 128, 256, "high"),
+        (2, 384, 3, 1, 128, 256, 128, None),
+    ],
+)
+def test_delta_rule_kernels_lower_for_v5e(
+    one_chip, monkeypatch, rows, steps, Hk, per, chunk, Dk, Dv, precision
+):
+    """The check interpret mode cannot make: `delta_scan` with its
+    chunk-to-chunk pass in ops/delta_rule.py's kernels, value and every
+    gradient, compiles for the chip's compiler at the cell's shapes
+    (16 rows x 256 steps in 4 chunks of 64, 16 key heads x 2 value
+    heads of 128 x 128, three passes) and at others `kernels_apply`
+    admits (six passes and one; one chunk; one key head a cell; chunks
+    of 16 and 128; widths of 256); each kernel is one Mosaic call and
+    the program around them holds no [128, 128] matrix a (row, chunk,
+    value head)."""
+    Hv = Hk * per
+    assert delta_rule.kernels_apply(steps, min(chunk, steps), Dk, Dv)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(q, k, v, g, beta, state, done):
+        with jax.default_matmul_precision(precision):
+            o, last = qwen3next.delta_scan(
+                q, k, v, g, beta, state, done, chunk
+            )
+        return jnp.sum(o * o) + jnp.sum(last)
+
+    traced = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6))))
+    text = traced.lower(
+        _struct(one_chip, (rows, steps, Hk, Dk)),
+        _struct(one_chip, (rows, steps, Hk, Dk)),
+        _struct(one_chip, (rows, steps, Hv, Dv)),
+        _struct(one_chip, (rows, steps, Hv)),
+        _struct(one_chip, (rows, steps, Hv)),
+        _struct(one_chip, (rows, Hv, Dk, Dv)),
+        _struct(one_chip, (rows, steps), jnp.bool_),
+    ).compile().as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*', text)
+    assert len(calls) == 2, len(calls)
+    assert sum("delta_rule_forward" in call for call in calls) == 1
+    assert sum("delta_rule_backward" in call for call in calls) == 1
+    chunks = -(-steps // chunk)
+    if (Dk, Dv) == (128, 128) and chunks > 1:
+        assert not _states_of_every_cell(
+            _shapes(text), rows * chunks * Hv
+        )
 
 
 def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
@@ -30,7 +111,9 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     VMEM (no f32 array over the keys is in the program: the fused pass
     compiles at a head size it had never run); the three DeltaNet
     layers' matrix states [16, 32, 128, 128] are in it, and no array of
-    the chunked scan is larger than a chunk's [64, 64] a head. The
+    the chunked scan is a [128, 128] matrix a chunk and head (PR 61:
+    the state goes from chunk to chunk in ops/delta_rule.py's kernels,
+    six forward calls and three backward). The
     experts' kernels see one rung at a time of the sorted rows (PR 47:
     5,120 rows, twice an even load's, of the 40,960 that 32 held under
     10 chosen can draw): no f32 array of 40,960 rows is left at the
@@ -91,7 +174,8 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     )
     # `temp_size_in_bytes` 6,472,551,424 with the sweep's loops started from
     # zeros, 6,439,727,616 with each first rung before its loop (PR 58: the
-    # zeros of a part's five sums are gone).
+    # zeros of a part's five sums are gone), 5,954,033,664 with the delta
+    # rule's states in VMEM (PR 61: 10.514 GiB in all where it was 10.967).
     weights = 4 * sum(
         int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)
     )
@@ -101,10 +185,7 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     assert total + weights < 15.0 * 2**30, memory
     assert total > 4 * 2**30, memory  # the cell fills the chip
     text = compiled.as_text()
-    shapes = {
-        tuple(int(d) for d in dims.split(","))
-        for dims in re.findall(r"f32\[([0-9,]+)\]", text)
-    }
+    shapes = _shapes(text)
     scores = {
         s for s in shapes if len(s) >= 3 and s[-1] in (4095, 4351, 4352)
     }
@@ -170,12 +251,27 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     # shipped kernels before): four MoE parts x (3 forward, 3 the
     # backward sweep's second forward, 6 backward), each rung compiled
     # twice since PR 58, the first before the loop and the loop's body
-    # (48 while the loop started from zeros); beside them the attention
-    # layer's three.
+    # (48 while the loop started from zeros).
     assert len(re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
     )) == 96
-    assert text.count("tpu_custom_call") == 96 + 3
+    # Beside them the attention layer's three and, since PR 61, the delta
+    # rule's chunk-to-chunk pass: a forward kernel a DeltaNet layer,
+    # again rematerialised, and one backward.
+    assert text.count("tpu_custom_call") == 96 + 3 + 9
+    for kernel, count in (
+        ("delta_rule_forward", 6), ("delta_rule_backward", 3),
+    ):
+        assert len(re.findall(
+            r'custom_call_target="tpu_custom_call"[^\n]*' + kernel, text
+        )) == count, kernel
+    # The mechanism's own witness: the state goes from chunk to chunk
+    # in VMEM, so no [128, 128] matrix a (row, chunk, value head) is
+    # under `delta_scan` (the `jax.numpy` form's `left`, `handed_on`,
+    # `entering` and their cotangents: 134 MB each a layer).
+    assert not _states_of_every_cell(
+        _shapes(text, "/delta_scan/"), rows * 4 * 32
+    )
     assert "/moe/moe_sweep/jit(_rung)/moe_experts" in text
     assert "/moe/moe_sweep/while/body/jit(_rung)/moe_experts" in text
     # A forward and a backward loop a MoE part, their turns counted on

@@ -794,9 +794,11 @@ STATS_AT_PR_44 = {
         "ssm_resets_per_row", "ssm_state_bytes_per_row",
     ],
     # Not a parent's: the family of PR 46, as it came (four of sixteen
-    # held under three chosen: no window).
+    # held under three chosen: no window); PR 61's count of the layers
+    # whose chunk-to-chunk pass its kernels ran.
     "qwen3next": [
         "attention_gated_applications", "delta_applications", "delta_chunks",
+        "delta_kernel_applications",
         "delta_resets_per_row", "delta_state_bytes_per_row",
         "moe_assignments", "moe_held_assignments",
         "moe_held_load_max_over_mean", "moe_load_max_over_mean",

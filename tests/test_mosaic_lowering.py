@@ -128,3 +128,44 @@ def test_auto_block_n_stays_under_what_the_compiler_refused():
     )
     # A row too big for the budget still gets a block of one.
     assert _auto_block_n(210, 210 * 64, 212, 212 * 64, f32) == 1
+
+
+@pytest.mark.parametrize(
+    "rows, chunks, Hk, per, Q, Dk, Dv, terms",
+    [
+        (16, 4, 16, 2, 64, 128, 128, 2),  # `qwen3next_policy.learner`'s
+        (2, 1, 2, 2, 64, 128, 128, 3),  # one chunk, six passes
+        (2, 3, 3, 1, 16, 128, 256, 1),  # one key head a turn, one pass
+    ],
+)
+def test_delta_rule_kernels_lower_for_tpu(
+    rows, chunks, Hk, per, Q, Dk, Dv, terms
+):
+    """ops/delta_rule.py's forward and backward kernels lower to Mosaic
+    at the cell's shapes and at others `kernels_apply` admits (the
+    chip's compiler has them in tests/test_chip_compile_qwen3next.py)."""
+    from torchbeast_tpu.ops import delta_rule
+
+    assert delta_rule.kernels_apply(chunks * Q, Q, Dk, Dv)
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    q = f32(rows, chunks, Hk, Q, Dk)
+    operands = (
+        q, q, f32(rows, chunks, Hk, 2 * per, Q),
+        f32(rows, chunks, Hk, per, Q, Q), f32(rows, chunks, Hk, per, Q, Dv),
+        f32(rows, chunks, Hk, per, Q, Dk), f32(rows, Hk, per, Dk, Dv),
+    )
+    jax.export.export(
+        jax.jit(lambda *a: delta_rule._forward(
+            *a, terms=terms, interpret=False
+        )),
+        platforms=["tpu"],
+    )(*operands)
+    jax.export.export(
+        jax.jit(lambda *a: delta_rule._backward(
+            *a, terms=terms, interpret=False
+        )),
+        platforms=["tpu"],
+    )(*operands, operands[4], operands[6])

@@ -38,8 +38,7 @@ def _recurrence(q, k, v, g, beta, state, done):
     return jnp.swapaxes(o, 0, 1), S
 
 
-def _scan_inputs(steps, ends):
-    rows, Hk, Hv, Dk, Dv = 2, 2, 4, 6, 5
+def _scan_inputs(steps, ends, rows=2, Hk=2, Hv=4, Dk=6, Dv=5):
     keys = jax.random.split(jax.random.PRNGKey(steps), 6)
     q = qwen3next.l2_normalise(
         jax.random.normal(keys[0], (rows, steps, Hk, Dk))

@@ -61,11 +61,28 @@ and including i, else 0:
 
 for the state S that enters the chunk: the recurrence above term for
 term (u_i = v'_i), because `done` at step t zeroes what step t reads of
-the state before it, which is D and e. S_next is linear in S, so a
-chunk's part of it is made for all chunks at once (`delta_states`: the
-[128, 128] matrix e_C I - (D_C. K)^T Kd and the offset (D_C. K)^T U)
-and the chunk-to-chunk pass (`delta_inter`) is one small matmul a
-chunk; O follows for all chunks from the states that entered them.
+the state before it, which is D and e. W, U, Kd and (Q K^T) D are made
+for all chunks at once (`delta_intra`); the last three lines, which
+need S, are the chunk-to-chunk pass (`delta_inter`), in one of two
+forms chosen by the shapes alone (`ops/delta_rule.kernels_apply`):
+
+  - an unroll whose chunks are whole sublane tiles at key and value
+    widths of whole lane tiles (the learner's [256, B] at the published
+    128 x 128) runs ops/delta_rule.py's kernels: a (row, key head)'s
+    states stay in VMEM from chunk to chunk, forward and backward, and
+    no [128, 128] matrix a chunk ever crosses HBM (the `jax.numpy`
+    form's five of them a chunk and value head were 35 of the cell's
+    300 ms for 2 ms of arithmetic; PERF.md section 6, PR 61);
+  - anything else (acting at T = 1, a chunk of one step; tier-1's toy
+    widths) runs `_pass_in_hbm`: S_next is linear in S, so a chunk's
+    part of it is made for all chunks at once (`delta_states`: the
+    [128, 128] matrix e_C I - (D_C. K)^T Kd and the offset (D_C. K)^T
+    U), the pass is one small matmul a chunk, and O follows for all
+    chunks from the states that entered them. It is also what the
+    kernels are held to (tests/test_delta_rule_kernel.py).
+
+Both make every product at the precision the family states, three
+bfloat16 passes; the kernels cut their float32 tiles in VMEM.
 Every exponential is of a difference inside one episode or of a masked
 -inf. W by block doubling (`unit_lower_inverse`): the inverse of the
 diagonal blocks of size s gives that of size 2s as X - X C X, C the
@@ -115,10 +132,12 @@ from torchbeast_tpu.models.transformer import (
     TransformerNet,
     count_fused_application,
 )
+from torchbeast_tpu.ops import delta_rule
 from torchbeast_tpu.ops.attention import (
     dense_transformer_attend,
     fused_pass_applies,
 )
+from torchbeast_tpu.ops.bf16_terms import terms_traced_under
 from torchbeast_tpu.telemetry import device_scope
 
 # https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct/blob/main/config.json
@@ -223,6 +242,45 @@ def _solve_backward(solved, cotangent):
 unit_lower_inverse.defvjp(_solve_forward, _solve_backward)
 
 
+def _pass_in_hbm(q, k, from_start, to_end, weights, values, keys_seen, state):
+    """`delta_scan`'s chunk-to-chunk pass as `jax.numpy`: S_next is linear
+    in S, so a chunk's part of it is made for all chunks at once
+    (`delta_states`), the pass itself (`delta_inter`) is one small matmul
+    a chunk, and O follows for all chunks from the states that entered
+    them. What `ops/delta_rule.py`'s kernels are held to, and what runs
+    where they do not apply. q, k [B, c, Q, Hk, Dk]; the rest and the
+    results as `delta_rule.chunk_pass`'s."""
+    Dk = k.shape[-1]
+    with device_scope("delta_states"):
+        # What the chunk's own steps leave in the state at its end, and
+        # what it makes of the state it was given: both linear in it.
+        keys_left = jnp.einsum("bchpj,bcjhd->bchpjd", to_end, k)
+        left = jnp.einsum("bchpjd,bchpjv->bchpdv", keys_left, values)
+        handed_on = from_start[..., -1, None, None] * jnp.eye(Dk) - (
+            jnp.einsum("bchpjd,bchpje->bchpde", keys_left, keys_seen)
+        )  # [B, c, Hk, per, Dk, Dk]
+    with device_scope("delta_inter"):
+        def pass_on(entering, chunk_parts):
+            handed_on_c, left_c = chunk_parts
+            leaving = jnp.einsum(
+                "bhpde,bhpev->bhpdv", handed_on_c, entering
+            ) + left_c
+            return leaving, entering
+
+        last, entering = jax.lax.scan(
+            pass_on, state, (handed_on.swapaxes(0, 1), left.swapaxes(0, 1)),
+        )
+        corrected = values - jnp.einsum(
+            "bchpid,cbhpdv->bchpiv", keys_seen, entering
+        )  # V'
+        o = jnp.einsum(
+            "bcihd,cbhpdv->bchpiv", q, entering
+        ) * from_start[..., None] + jnp.einsum(
+            "bchpij,bchpjv->bchpiv", weights, corrected
+        )
+    return o, last
+
+
 def delta_scan(q, k, v, g, beta, state, done, chunk):
     """The gated delta rule over an unroll, in chunks, with episode ends
     inside them (the module's header has the algebra).
@@ -235,7 +293,13 @@ def delta_scan(q, k, v, g, beta, state, done, chunk):
 
     Everything in float32. The last chunk is padded with steps of g = 0,
     beta = 0 and k = 0, which pass the state on as it is. A chunk of one
-    step (T = 1) is the recurrence."""
+    step (T = 1) is the recurrence. What needs no entering state
+    (`delta_intra`, the solve) is made for all chunks at once; the pass
+    from chunk to chunk is ops/delta_rule.py's kernels where `kernels_
+    apply(steps, Q, Dk, Dv)` holds (the states in VMEM; episode ends are
+    the zeros in `from_start`, `to_end` and `decay` that they multiply
+    by) and `_pass_in_hbm` elsewhere: a function of the shapes, no
+    flag."""
     rows, steps, Hk, Dk = q.shape
     Hv, Dv = v.shape[2:]
     per = Hv // Hk
@@ -277,36 +341,23 @@ def delta_scan(q, k, v, g, beta, state, done, chunk):
         weights = jnp.einsum(
             "bcihd,bcjhd->bchij", q, k
         )[:, :, :, None] * decay
+    entering = state.reshape(rows, Hk, per, Dk, Dv).astype(jnp.float32)
     with device_scope("delta_states"):
-        # What the chunk's own steps leave in the state at its end, and
-        # what it makes of the state it was given: both linear in it.
+        # What the chunk's end still sees of each of its steps.
         to_end = jnp.exp(jnp.where(
             along_heads(ends[:, :, -1:] == ends), G[..., -1:] - G, -jnp.inf
         ))  # [B, c, Hk, per, Q]
-        keys_left = jnp.einsum("bchpj,bcjhd->bchpjd", to_end, k)
-        left = jnp.einsum("bchpjd,bchpjv->bchpdv", keys_left, values)
-        handed_on = from_start[..., -1, None, None] * jnp.eye(Dk) - (
-            jnp.einsum("bchpjd,bchpje->bchpde", keys_left, keys_seen)
-        )  # [B, c, Hk, per, Dk, Dk]
-    with device_scope("delta_inter"):
-        def pass_on(entering, chunk_parts):
-            handed_on_c, left_c = chunk_parts
-            leaving = jnp.einsum(
-                "bhpde,bhpev->bhpdv", handed_on_c, entering
-            ) + left_c
-            return leaving, entering
-
-        last, entering = jax.lax.scan(
-            pass_on, state.reshape(rows, Hk, per, Dk, Dv).astype(jnp.float32),
-            (handed_on.swapaxes(0, 1), left.swapaxes(0, 1)),
-        )
-        corrected = values - jnp.einsum(
-            "bchpid,cbhpdv->bchpiv", keys_seen, entering
-        )  # V'
-        o = jnp.einsum(
-            "bcihd,cbhpdv->bchpiv", q, entering
-        ) * from_start[..., None] + jnp.einsum(
-            "bchpij,bchpjv->bchpiv", weights, corrected
+    if delta_rule.kernels_apply(steps, Q, Dk, Dv):
+        # The state from chunk to chunk in VMEM (ops/delta_rule.py).
+        with device_scope("delta_inter"):
+            o, last = delta_rule.chunk_pass(
+                q.transpose(0, 1, 3, 2, 4), k.transpose(0, 1, 3, 2, 4),
+                from_start, to_end, weights, values, keys_seen, entering,
+                terms_traced_under(),
+            )
+    else:
+        o, last = _pass_in_hbm(
+            q, k, from_start, to_end, weights, values, keys_seen, entering
         )
     o = o.transpose(0, 1, 4, 2, 3, 5).reshape(rows, nc * Q, Hv, Dv)
     return o[:, :steps], last.reshape(rows, Hv, Dk, Dv)
@@ -484,6 +535,10 @@ class _DeltaNetBlock(nn.Module):
             Q, _, chunks = chunk_plan(steps, self.chunk_size)
             for name, value, fold in (
                 ("delta_applications", 1.0, "sum"),
+                # Those whose chunk-to-chunk pass is ops/delta_rule.py's
+                # kernels: the learner's unroll, not a step of acting.
+                ("delta_kernel_applications",
+                 float(delta_rule.kernels_apply(steps, Q, Dk, Dv)), "sum"),
                 ("delta_state_bytes_per_row",
                  4 * (Hv * Dk * Dv + (K - 1) * channels), "sum"),
                 ("delta_chunks", chunks, "same"),
