@@ -164,7 +164,7 @@ def test_lfm2_cell_update_compiles_for_v5e(one_chip, monkeypatch):
         s for s in shapes if len(s) >= 3 and s[-1] in (4095, 4351, 4352)
     }
     assert not scores, scores
-    assert text.count("fused_attend_forward") >= 2  # and rematerialised
+    assert text.count("fused_attend_forward") >= 1
     assert text.count("fused_attend_backward") >= 1
     # No float32 copy of the batch's frames anywhere in the program.
     frames = (steps + 1) * rows * int(np.prod(frame))
@@ -179,11 +179,12 @@ def test_lfm2_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     # rows. Since PR 58 a rung is compiled twice, the first before the
     # loop and the loop's body (48 while the loop started from zeros),
     # and no zeros of a weight's shape are broadcast under a sweep.
-    # Beside them the attention layer's three.
+    # Beside them the attention layer's two: one forward since PR 63
+    # (the rematerialised block keeps its results), one backward.
     assert len(re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
     )) == 96
-    assert text.count("tpu_custom_call") == 96 + 3
+    assert text.count("tpu_custom_call") == 96 + 2
     assert "/moe/moe_sweep/jit(_rung)/moe_experts" in text
     assert "/moe/moe_sweep/while/body/jit(_rung)/moe_experts" in text
     assert not re.search(

@@ -93,7 +93,7 @@ def test_nemotron3_cell_update_compiles_for_v5e(one_chip, monkeypatch):
         s for s in shapes if len(s) >= 3 and s[-1] in (4095, 4351, 4352)
     }
     assert not scores, scores
-    assert text.count("fused_attend_forward") >= 2  # and rematerialised
+    assert text.count("fused_attend_forward") >= 1
     assert text.count("fused_attend_backward") >= 1
     # The sorted rows of all the assignments are never an operand of a
     # kernel: 22 a token; nor is the window's 8 a token, which is swept
@@ -116,10 +116,11 @@ def test_nemotron3_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     # rung's value is unused and gone, and the loop that is left takes
     # no turn on a step of one rung (50 while the loops started from
     # zeros, that one's turn among them); beside them the attention
-    # layer's three.
+    # layer's two (one forward since PR 63: the rematerialised block
+    # keeps its results).
     assert len(re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
     )) == 90
     assert "/moe/moe_sweep/jit(_rung)/moe_experts" in text
     assert "/moe/moe_sweep/while/body/jit(_rung)/moe_experts" in text
-    assert text.count("tpu_custom_call") == 90 + 3
+    assert text.count("tpu_custom_call") == 90 + 2

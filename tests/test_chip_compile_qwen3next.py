@@ -190,7 +190,7 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
         s for s in shapes if len(s) >= 3 and s[-1] in (4095, 4351, 4352)
     }
     assert not scores, scores
-    assert text.count("fused_attend_forward") >= 2  # and rematerialised
+    assert text.count("fused_attend_forward") >= 1
     assert text.count("fused_attend_backward") >= 1
     # The frames enter `Dense_0` as the bfloat16 integers they are (PR
     # 52: models/transformer.py `frame_projection`): no float32 copy of
@@ -255,10 +255,11 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     assert len(re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
     )) == 96
-    # Beside them the attention layer's three and, since PR 61, the delta
-    # rule's chunk-to-chunk pass: a forward kernel a DeltaNet layer,
-    # again rematerialised, and one backward.
-    assert text.count("tpu_custom_call") == 96 + 3 + 9
+    # Beside them the attention layer's two (one forward since PR 63:
+    # the rematerialised block keeps its results) and, since PR 61, the
+    # delta rule's chunk-to-chunk pass: a forward kernel a DeltaNet
+    # layer, again rematerialised, and one backward.
+    assert text.count("tpu_custom_call") == 96 + 2 + 9
     for kernel, count in (
         ("delta_rule_forward", 6), ("delta_rule_backward", 3),
     ):

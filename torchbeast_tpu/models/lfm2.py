@@ -77,6 +77,7 @@ from torchbeast_tpu.models.transformer import (
     Recurrent,
     TransformerNet,
     count_fused_application,
+    rematerialised,
 )
 from torchbeast_tpu.ops.attention import (
     dense_transformer_attend,
@@ -420,7 +421,9 @@ class Lfm2Net(TransformerNet):
             cls, fields = _ConvBlock, dict(conv_kernel=self.conv_kernel)
         else:
             raise ValueError(f"layer_types: unknown operator {kind!r}")
-        return (nn.remat(cls) if self.remat else cls)(**fields, **shared)
+        return (rematerialised(cls) if self.remat else cls)(
+            **fields, **shared
+        )
 
     @nn.nowrap
     def make_final_norm(self):

@@ -54,6 +54,7 @@ from torchbeast_tpu.models.olmoe import rope_rotate
 from torchbeast_tpu.models.transformer import (
     TransformerNet,
     count_fused_application,
+    rematerialised,
 )
 from torchbeast_tpu.ops.attention import (
     dense_transformer_attend,
@@ -303,7 +304,9 @@ class Mellum2Net(TransformerNet):
 
     @nn.nowrap
     def make_block(self, name: str, layer: int):
-        block_cls = nn.remat(_Mellum2Block) if self.remat else _Mellum2Block
+        block_cls = (
+            rematerialised(_Mellum2Block) if self.remat else _Mellum2Block
+        )
         return block_cls(
             kind=self.layer_kind(layer),
             d_model=self.d_model, num_heads=self.num_heads,

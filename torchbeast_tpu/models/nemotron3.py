@@ -84,6 +84,7 @@ from torchbeast_tpu.models.transformer import (
     Recurrent,
     TransformerNet,
     count_fused_application,
+    rematerialised,
 )
 from torchbeast_tpu.ops.attention import (
     dense_transformer_attend,
@@ -667,7 +668,9 @@ class Nemotron3Net(TransformerNet):
                 routed_scaling=self.routed_scaling,
                 bias_update_rate=self.bias_update_rate,
             )
-        return (nn.remat(cls) if self.remat else cls)(**fields, **shared)
+        return (rematerialised(cls) if self.remat else cls)(
+            **fields, **shared
+        )
 
     @nn.nowrap
     def make_final_norm(self):

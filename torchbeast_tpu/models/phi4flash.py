@@ -84,6 +84,7 @@ from torchbeast_tpu.models.transformer import (
     Recurrent,
     TransformerNet,
     count_fused_application,
+    rematerialised,
 )
 from torchbeast_tpu.ops.attention import (
     dense_transformer_attend,
@@ -634,7 +635,9 @@ class Phi4FlashNet(TransformerNet):
             )
             if kind != CROSS:
                 fields["hands_on"] = kind == FULL
-        return (nn.remat(cls) if self.remat else cls)(**fields, **shared)
+        return (rematerialised(cls) if self.remat else cls)(
+            **fields, **shared
+        )
 
     @nn.nowrap
     def make_final_norm(self):

@@ -131,6 +131,7 @@ from torchbeast_tpu.models.transformer import (
     Recurrent,
     TransformerNet,
     count_fused_application,
+    rematerialised,
 )
 from torchbeast_tpu.ops import delta_rule
 from torchbeast_tpu.ops.attention import (
@@ -739,7 +740,11 @@ class Qwen3NextNet(TransformerNet):
             d_model=self.d_model, rms_norm_eps=self.rms_norm_eps,
             dtype=self.dtype, name=name,
         )
-        kept = None  # what a rematerialised block keeps of its forward
+        # What a rematerialised block keeps of its forward BESIDE what
+        # every family's does (models/transformer.py `rematerialised`:
+        # the fused attention pass's two results, this family's
+        # attention block's among them).
+        also_kept = ()
         if layer % 2:
             cls, fields = _MoEBlock, dict(
                 num_experts=self.num_experts, held=self.held_experts(),
@@ -767,8 +772,8 @@ class Qwen3NextNet(TransformerNet):
             # Its second forward does not solve again: the inverses (4
             # Hv Q^2 bytes a row and chunk) are all the solve's backward
             # reads, and cost less to keep than to make at that peak.
-            kept = jax.checkpoint_policies.save_only_these_names(SOLVED)
-        return (nn.remat(cls, policy=kept) if self.remat else cls)(
+            also_kept = (SOLVED,)
+        return (rematerialised(cls, *also_kept) if self.remat else cls)(
             **fields, **shared
         )
 

@@ -59,7 +59,38 @@ from torchbeast_tpu.ops.attention import (
     segment_ids_from_done,
     ulysses_transformer_attention,
 )
+from torchbeast_tpu.ops.fused_attention import KEPT_FORWARD
 from torchbeast_tpu.telemetry import device_scope
+
+
+@functools.lru_cache(maxsize=None)
+def _keeping(*also_kept):
+    """ONE policy object a set of names: jax shares a block's inner
+    functions across blocks (and the lowering emits them once) only
+    under the same policy object, as it does under `nn.remat`'s None."""
+    return jax.checkpoint_policies.save_only_these_names(
+        *KEPT_FORWARD, *also_kept
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def rematerialised(block_cls, *also_kept):
+    """`block_cls` as a family's `make_block` instantiates it under
+    `--remat all`: `nn.remat` around it under the policy that keeps, of
+    a block's forward pass, the fused attention pass's two results
+    (ops/fused_attention.py `KEPT_FORWARD`: the output as the backward
+    kernel reads it and a float a row of log-sum-exp, 46 MB a layer in
+    the Trinity cell, so the block's second forward does not call the
+    forward kernel again, 4.5 to 8.1 ms a call there) and whatever the
+    family names besides (`also_kept`: models/qwen3next.py's solves).
+    A block that takes the dense body names nothing and is
+    rematerialised whole, as under no policy. The class is a subclass
+    that says so of itself (`keeps_forward_results`, read by
+    `count_fused_application`); one a block class and set of names."""
+    kept = type(
+        block_cls.__name__, (block_cls,), {"keeps_forward_results": True}
+    )
+    return nn.remat(kept, policy=_keeping(*also_kept))
 
 
 def _count_application(module: nn.Module, name: str) -> None:
@@ -85,10 +116,15 @@ def count_fused_application(module: nn.Module) -> None:
     which is under a caller that traces at more than one term (`high`,
     `highest`: 1 in the LFM2, Qwen3-Next and Nemotron-3 cells). Not
     sown where there is none: a sown zero would be one more output of
-    Mellum2's update, which this leaves as it was."""
+    Mellum2's update, which this leaves as it was. And `attention_
+    forward_results_kept`: those whose block is `rematerialised` under
+    the policy that keeps the pass's forward results, which is all of
+    them under `--remat all` (5 in the Trinity cell) and none without."""
     _count_application(module, "fused_applications")
     if terms_traced_under() > 1:
         _count_application(module, "products_cut_in_kernel")
+    if getattr(module, "keeps_forward_results", False):
+        _count_application(module, "forward_results_kept")
 
 
 def count_latent_application(module: nn.Module) -> None:

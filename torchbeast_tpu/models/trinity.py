@@ -69,6 +69,7 @@ from torchbeast_tpu.models.stats import sow_stat
 from torchbeast_tpu.models.transformer import (
     TransformerNet,
     count_fused_application,
+    rematerialised,
 )
 from torchbeast_tpu.ops.attention import (
     dense_transformer_attend,
@@ -362,7 +363,9 @@ class TrinityNet(TransformerNet):
 
     @nn.nowrap
     def make_block(self, name: str, layer: int):
-        block_cls = nn.remat(_TrinityBlock) if self.remat else _TrinityBlock
+        block_cls = (
+            rematerialised(_TrinityBlock) if self.remat else _TrinityBlock
+        )
         return block_cls(
             kind=self.layer_kind(layer),
             dense=layer < self.leading_dense_layers(),

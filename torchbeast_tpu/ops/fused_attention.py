@@ -70,7 +70,18 @@ and the mask, which is small, is padded with False instead.
 key/value head's query rows are in the cell, so a block's `dk` and `dv`
 are complete when the cell ends and only `dq` is carried across blocks.
 Saved from the forward pass: the operands as the kernel reads them, the
-output and the rows' log-sum-exp (lane-replicated, `[.., 128]`). Told
+output and the rows' log-sum-exp. A REMATERIALISED caller keeps the
+last two and nothing else (`KEPT_FORWARD`, the names `_fused_attend_fwd`
+gives them; models/transformer.py `rematerialised` hands `nn.remat` the
+policy that keeps them): its second forward then rebuilds the operands,
+which the backward kernel reads, and does not call the forward kernel,
+whose two results are all a block's backward pass wants of it and small
+beside what making them costs (46 MB a layer in the Trinity cell
+against 4.5 to 8.1 ms a call; PERF.md section 6, PR 63). The
+log-sum-exp is kept NARROW, a float a row `[B, Hkv, G * Tp]`: the
+kernel writes it lane-replicated, `[.., 128]`, as large as the output
+at heads of 128, and the backward pass broadcasts the column to the
+lanes again where it hands it to its kernel. Told
 that the first n keys take no gradient (`no_grad_keys`: a cache that is
 the learner's data), the kernel makes `dk`, `dv` from the block that
 holds key n on and writes no row before it.
@@ -89,12 +100,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from torchbeast_tpu.ops.bf16_terms import cut_in_kernel, product_of_terms
 
 BIG_NEG = -1e30
+
+# What a rematerialised caller keeps of `fused_attend`'s forward pass
+# (the module's header, "Backward"): the output as the backward kernel
+# reads it, and the rows' log-sum-exp, one float a row.
+KEPT_FORWARD = ("fused_attend_out", "fused_attend_lse")
 
 # The most keys a grid cell takes. A forward cell pays for its running
 # maximum and denominator (two reductions along the lanes, the rescaled
@@ -521,6 +538,17 @@ def _fused_attend_fwd(q, k_all, v_all, mask, no_grad_keys, on_chip, terms,
     out, lse = _forward_call(
         *operands, q.shape[2] // k_all.shape[2], not on_chip, terms, scale
     )
+    # Named BEFORE anything reads them: under a policy that keeps the
+    # names the second forward of a rematerialised caller then has no
+    # use for the kernel. The log-sum-exp as the one float a row it is
+    # ([B, Hkv, G * Tp]: a last axis of 1 would be padded to the lanes
+    # again in HBM), and WHEN the column is cut left to the scheduler:
+    # an `optimization_barrier` that ties the cut to `out` or to the
+    # result cost the Trinity cell 0.2% / 1.3% of its rate and 50 / 59
+    # MB at the peak on the chip, though the compile for a described
+    # v5e read 0.3 GiB better with it (PERF.md section 6, PR 63).
+    out = checkpoint_name(out, KEPT_FORWARD[0])
+    lse = checkpoint_name(lse[..., 0], KEPT_FORWARD[1])
     result = _from_rows(out, q.shape[1]).astype(v_all.dtype)
     # Empty carriers of what the gradients are shaped and typed like.
     like = tuple(
@@ -535,7 +563,8 @@ def _fused_attend_bwd(no_grad_keys, on_chip, terms, scale, residuals,
     q_rows, keys, _, mask = operands
     hkv, tp = q_rows.shape[1], mask.shape[1]
     dq, dk, dv = _backward_call(
-        *operands, out, lse,
+        *operands, out,
+        jnp.broadcast_to(lse[..., None], lse.shape + (_LANES,)),
         _as_rows(dresult.astype(jnp.float32), hkv, tp),
         q_rows.shape[2] // tp,
         no_grad_keys // _key_blocks(keys.shape[0], terms)[1],
@@ -570,6 +599,13 @@ def fused_attend(q, k_all, v_all, mask, no_grad_keys=0, terms=1):
     operands cast to bfloat16 outside the kernels; 2 (`high`) or 3
     (`highest`), float32 operands cut in VMEM and a product's 3 or 6
     passes made from the terms (see the module's header).
+
+    Differentiated inside `jax.checkpoint` under a policy that saves
+    the names `KEPT_FORWARD` (`jax.checkpoint_policies.save_only_these_
+    names`), the forward kernel runs once: its output and the rows'
+    log-sum-exp (one float a row) are kept for the backward kernel and
+    the second forward makes the operands alone. Under no such policy
+    the names do nothing.
 
     A head narrower than the 128 lanes (models/lfm2.py: 64) is padded
     to them with zero columns, which add nothing to a score and come
