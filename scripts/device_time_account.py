@@ -12,7 +12,7 @@ belongs to), beside the counters the update's layers sowed.
 It builds the cell through the benchmark's own `perfbench.drivers.
 learner.build`, runs three warm and `--steps` traced steps, and prints
 one JSON line `{"account": ...}` and the same as a table; `--out` also
-writes the JSON there. It serves the nine learner cells (`deep_lstm.
+writes the JSON there. It serves every learner cell (`deep_lstm.
 poly`'s update is `deep_lstm.learner`'s program); a whole run's trace,
 act step and all, is what a driver's `--profile_dir` accounts for at
 its end. Without a TPU it exits 1 as the benchmark does; tier-1 runs

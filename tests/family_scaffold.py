@@ -14,7 +14,7 @@ through `jax.jit`:
   gradient is `jax.jit(jax.grad(...))` around `module.apply` in the
   case itself. A plain `jnp` function under test is called through
   `jax.jit` too, once a shape.
-- For the ten policy families: the toy batch (`inputs`,
+- For the eleven policy families: the toy batch (`inputs`,
   `learner_batch`), `build(family, **overrides) -> (model, params)`,
   `expert_layer(family, held)`, `warm_state`, `reference_config`, and
   jitted callables for the four programs the cases run again and again
@@ -67,6 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from perfbench.reference import (
+    granite4_policy,
     kanana2_policy,
     lfm2_policy,
     mellum2_policy,
@@ -80,6 +81,7 @@ from perfbench.reference import (
 )
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import (
+    Granite4Net,
     Kanana2Net,
     Lfm2Net,
     Mellum2Net,
@@ -90,6 +92,7 @@ from torchbeast_tpu.models import (
     Qwen3NextNet,
     TrinityNet,
     Xing4Net,
+    granite4,
     kanana2,
     lfm2,
     mellum2,
@@ -339,6 +342,28 @@ def _perturb_xing4(model, params):
 _perturb_trinity = _perturb_lfm2
 
 
+def _perturb_granite4(model, params):
+    # The side inputs, and what starts at one: every norm's scale (a
+    # layer's two, the final one), the gated norm's and the skip `D`; a
+    # seed a leaf moved. A norm left out, or a multiplier, then shows.
+    inner = _with_extras(params["params"])
+    seeds = iter(range(1, 1000))
+
+    def moved(leaf):
+        return leaf + _normal(next(seeds), leaf.shape, 0.3)
+
+    inner["final_norm"] = {"scale": moved(inner["final_norm"]["scale"])}
+    for name in sorted(n for n in inner if n.startswith("block_")):
+        block = dict(inner[name])
+        for leaf in sorted(block):
+            if leaf in ("D", "gate_norm"):
+                block[leaf] = moved(block[leaf])
+            elif leaf.endswith("norm"):
+                block[leaf] = {"scale": moved(block[leaf]["scale"])}
+        inner[name] = block
+    return {"params": inner}
+
+
 def _config_olmoe(model):
     return {
         "num_attention_heads": model.num_heads,
@@ -479,6 +504,30 @@ def _config_nemotron3(model):
         "routed_scaling_factor": 5.0, "n_shared_experts": 1,
         "mlp_hidden_act": "relu2", "mlp_bias": False,
         "bias_update_rate": 0.001, "layer_norm_epsilon": 1e-5,
+    }
+
+
+def _config_granite4(model):
+    return {
+        "hidden_size": model.d_model,
+        "layer_types": list(model.pattern()),
+        "num_hidden_layers": model.num_layers,
+        "mamba_n_heads": model.mamba_heads,
+        "mamba_d_head": model.mamba_head_dim,
+        "mamba_n_groups": model.mamba_groups,
+        "mamba_d_state": model.state_size,
+        "mamba_d_conv": model.conv_kernel, "mamba_expand": 2,
+        "mamba_conv_bias": True, "mamba_proj_bias": False,
+        "num_attention_heads": model.num_heads,
+        "num_key_value_heads": model.kv_heads, "attention_bias": False,
+        "position_embedding_type": "nope",
+        "shared_intermediate_size": model.mlp_width, "hidden_act": "silu",
+        "num_local_experts": 0, "normalization_function": "rmsnorm",
+        "embedding_multiplier": model.input_scale,
+        "attention_multiplier": model.attention_multiplier,
+        "residual_multiplier": model.residual_multiplier,
+        "logits_scaling": 1.0 / model.logits_scale,
+        "rms_norm_eps": model.rms_norm_eps,
     }
 
 
@@ -901,6 +950,25 @@ FAMILIES = {
             tol=1e-5, uncut=dict(num_experts=64, top_k=8),
             shared=_swiglu_shared,
         ),
+    ),
+    # Two Mamba-2 layers of 8 heads of 8 on ONE B/C group over a state
+    # of 6, scanned in chunks of 4 steps (the 11 steps of an unroll are
+    # two whole chunks and one padded), around one attention layer of 4
+    # query heads of 8 on 2 key/value heads without positions, its
+    # scores times 1/16 (not 8^-0.5); a SwiGLU of 48 after every mixer;
+    # multipliers 3, 1/16, 0.3, 1/4.
+    "granite4": Family(
+        Granite4Net, granite4, granite4_policy,
+        dict(
+            d_model=32, num_heads=4, kv_heads=2, head_dim=8, mamba_heads=8,
+            mamba_head_dim=8, state_size=6, chunk_size=4, mlp_width=48,
+            input_scale=3.0, attention_multiplier=1 / 16,
+            residual_multiplier=0.3, logits_scale=0.25,
+            layer_period=("mamba", "attention", "mamba"),
+            layer_types=("mamba", "attention", "mamba") * 2,
+            num_layers=3, memory_len=5,
+        ),
+        _config_granite4, _perturb_granite4, t=11,
     ),
 }
 

@@ -839,7 +839,7 @@ def test_the_fused_pass_follows_the_precision_its_caller_traces_at(
     monkeypatch.setattr(attention, "FUSED_SCORE_BYTES", 1)
     monkeypatch.setattr(
         attention, "fused_attend",
-        lambda q, k, v, mask, no_grad_keys, terms: (
+        lambda q, k, v, mask, no_grad_keys, terms, scale: (
             seen.append(terms) or q
         ),
     )

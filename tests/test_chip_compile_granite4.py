@@ -1,0 +1,105 @@
+"""Compile for the chip, without the chip (tests/chip_fixtures.py):
+`granite4_policy.learner`'s whole update, one AOT compile of the real
+cell. A file of its own: tests/chip_fixtures.py says why.
+"""
+
+import json
+import os
+import re
+
+import numpy as np
+
+import jax
+
+from tests.chip_fixtures import (  # noqa: F401 (fixtures)
+    NUM_ACTIONS,
+    on as _on,
+    one_chip,
+    topo,
+)
+from torchbeast_tpu import learner as learner_lib
+
+
+def test_granite4_cell_update_compiles_for_v5e(one_chip, monkeypatch):
+    """`granite4_policy.learner`'s update as the benchmark builds it
+    (the configuration's own argv: one period of ten layers, nine
+    Mamba-2 mixers whole and one attention layer over a 4,095-slot
+    cache, blocks rematerialised, [512, 8] batch), whole, for a
+    described v5e: where the fit is settled before any chip time. Its
+    bytes with the driver's copy of the weights stay under the rule's
+    15.0 GiB (rung 1 of the configuration's `fit`: nothing cut); the
+    attention layer's scores over 4,607 keys stay in the fused pass's
+    kernels (heads of 64 padded to the lanes, the scale the config's
+    1/64), its forward kernel called once."""
+    from perfbench import flops_granite4, manifest
+    from perfbench.drivers import learner as learner_driver
+    from torchbeast_tpu import monobeast
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(
+        manifest.HERE, "configs", "granite4_h_micro_policy.json"
+    )) as f:
+        config = json.load(f)
+    steps, rows = config["unroll_length"], config["batch_size"]
+    flags = monobeast.make_parser().parse_args(
+        config["program_argv"]
+        + ["--unroll_length", str(steps), "--batch_size", str(rows)]
+    )
+    hp = monobeast.hparams_from_flags(flags)
+    frame = tuple(config["frame_shape"])
+    model, _ = monobeast._init_model_and_params(
+        flags, NUM_ACTIONS, rows, frame, init_params=False
+    )
+    optimizer = learner_lib.make_optimizer(hp)
+    params = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0), "action": jax.random.PRNGKey(1)},
+        monobeast.dummy_env_outputs(1, rows, frame, np.uint8),
+        model.initial_state(rows),
+    ))
+    batch, state = jax.eval_shape(lambda: (
+        learner_driver._make_batch(
+            jax.random.PRNGKey(0), steps + 1, rows, NUM_ACTIONS, frame
+        ),
+        model.initial_state(rows),
+    ))
+    compiled = learner_lib.make_update_step(model, optimizer, hp).lower(
+        _on(one_chip, params),
+        _on(one_chip, jax.eval_shape(optimizer.init, params)),
+        _on(one_chip, batch), _on(one_chip, state),
+    ).compile()
+    memory = compiled.memory_analysis()
+    total = (
+        memory.temp_size_in_bytes + memory.argument_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    weights = 4 * sum(
+        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)
+    )
+    # The count the configuration's `reduced_why` and `flops_granite4`
+    # state.
+    assert weights == 4 * flops_granite4.param_count(config) == (
+        4 * 804_305_863
+    )
+    print("memory", memory, "total GiB", total / 2**30)
+    # The rule's 15.0 GiB of the chip's 15.75, with the driver's copy
+    # of the weights beside the update.
+    assert total + weights < 15.0 * 2**30, memory
+    assert total > 8 * 2**30, memory  # the cell fills the chip
+    text = compiled.as_text()
+    shapes = {
+        tuple(int(d) for d in dims.split(","))
+        for dims in re.findall(r"f32\[([0-9,]+)\]", text)
+    }
+    # No f32 array over the 4,095 + 512 keys: the attention layer's
+    # scores stay in the fused pass, forward, rematerialised and
+    # backward.
+    scores = {
+        s for s in shapes if len(s) >= 3 and s[-1] in (4095, 4607, 4608)
+    }
+    assert not scores, scores
+    # One forward call: the second forward of the rematerialised block
+    # reads the kept results.
+    for kernel in ("fused_attend_forward", "fused_attend_backward"):
+        assert len(re.findall(
+            r'custom_call_target="tpu_custom_call".*' + kernel, text
+        )) == 1, kernel

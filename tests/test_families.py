@@ -401,6 +401,54 @@ def _published_trinity(model):
     )
 
 
+def _published_granite4(model):
+    assert model.zero_init_extras
+    assert (model.d_model, model.num_heads, model.kv_heads, model.head_dim) == (
+        2048, 32, 8, 64
+    )
+    assert (
+        model.mamba_heads, model.mamba_head_dim, model.mamba_groups,
+        model.state_size, model.conv_kernel, model.chunk_size,
+    ) == (64, 64, 1, 128, 4, 256)
+    assert (model.mlp_width, model.rms_norm_eps, model.memory_len) == (
+        8192, 1e-5, 4095
+    )
+    # The config's four multipliers; 1/64 is NOT head_dim^-0.5.
+    assert (
+        model.input_scale, model.attention_multiplier,
+        model.residual_multiplier, model.logits_scale,
+    ) == (12.0, 0.015625, 0.22, 0.125)
+    assert model.attention_multiplier != model.head_dim ** -0.5
+    assert create_model("nemotron3", num_actions=6).logits_scale == 1.0
+    assert model.matmul_precision == "high"
+    # One period: `MMMMM*MMMM`, nine carried states and one window.
+    assert model.pattern() == ("mamba",) * 5 + ("attention",) + (
+        "mamba",
+    ) * 4
+    state = jax.eval_shape(lambda: model.initial_state(8))
+    assert [item[0].shape for item in state] == (
+        [(64, 8, 64, 128)] * 5 + [(4095, 8, 8, 64)] + [(64, 8, 64, 128)] * 4
+    )
+    assert state[0][1].shape == (3, 8, 4352)
+    # 19,344,384 bytes of Mamba state a row, float32: nine layers'.
+    assert 9 * 4 * (64 * 64 * 128 + 3 * 4352) == 19_344_384
+    whole = create_model("granite4", num_actions=6)
+    assert [
+        layer for layer, kind in enumerate(whole.pattern())
+        if kind == "attention"
+    ] == [5, 15, 25, 35]
+    # The cell's attention layer (32 query heads of 64 on 8 key/value
+    # heads over 4,095 + 512 keys: 2.4 GB of f32 scores at B=8) is
+    # `fused_attend`'s, its heads padded to the lanes; a T=1 act step is
+    # not.
+    assert attention.fused_pass_applies(
+        (8, 512, 32, 64), (8, 4607, 8, 64), None
+    )
+    assert not attention.fused_pass_applies(
+        (8, 1, 32, 64), (8, 4096, 8, 64), None
+    )
+
+
 # family: how the cell builds it, the depth of the published model, its
 # own assertions, and what the registry refuses beside `use_lstm`.
 REGISTRY = {
@@ -460,6 +508,12 @@ REGISTRY = {
               for bad in [6, 4, 1, 36]),
             *(_refused("expert_share", expert_share=bad)
               for bad in [(8, 8), (0, 3), (-1, 8)]),
+        ],
+    ),
+    "granite4": (
+        dict(num_layers=10), 40, _published_granite4, [
+            *(_refused("whole periods of 10", num_layers=bad)
+              for bad in [5, 11, 45]),
         ],
     ),
 }
@@ -715,12 +769,36 @@ def _flags_trinity(parse, build):
     return model, ["--model", "trinity", "--num_layers", "3"]
 
 
+def _flags_granite4(parse, build):
+    flags = parse([
+        "--model", "granite4", "--num_layers", "6", "--memory_len", "9",
+    ])
+    assert (flags.model, flags.num_layers) == ("granite4", 6)
+    model = build(flags)
+    assert (model.num_layers, model.memory_len, model.d_model) == (6, 9, 32)
+    # The (shrunken) table's period of three, twice; the multipliers
+    # have no flag: the table's.
+    assert model.pattern() == ("mamba", "attention", "mamba") * 2
+    assert (model.input_scale, model.logits_scale) == (3.0, 0.25)
+    # --num_layers 4 is refused, and says why.
+    with pytest.raises(ValueError, match="whole periods of 3"):
+        build(parse(["--model", "granite4", "--num_layers", "4"]))
+    # Dense and whole on a chip: no share of either kind.
+    with pytest.raises(ValueError, match="mixer_share .* nemotron3 only"):
+        build(parse(["--model", "granite4", "--mixer_share", "0/2"]))
+    with pytest.raises(ValueError, match="expert_share"):
+        build(parse(["--model", "granite4", "--expert_share", "0/2"]))
+    with pytest.raises(ValueError, match="use_lstm"):
+        build(parse(["--model", "granite4", "--use_lstm"]))
+    return model, ["--model", "granite4", "--num_layers", "3"]
+
+
 FLAGS = {
     "olmoe": _flags_olmoe, "mellum2": _flags_mellum2, "ouro": _flags_ouro,
     "kanana2": _flags_kanana2, "nemotron3": _flags_nemotron3,
     "qwen3next": _flags_qwen3next, "lfm2": _flags_lfm2,
     "phi4flash": _flags_phi4flash, "xing4": _flags_xing4,
-    "trinity": _flags_trinity,
+    "trinity": _flags_trinity, "granite4": _flags_granite4,
 }
 
 
@@ -932,6 +1010,13 @@ STATS_AT_PR_44 = {
         "moe_held_assignments", "moe_held_load_max_over_mean",
         "moe_load_max_over_mean", "moe_shared_applications",
         "moe_window_rows", "moe_window_short_applications",
+    ],
+    # The family of PR 64, as it came: Nemotron-3's four of the mixer,
+    # and the sublayers that follow every mixer.
+    "granite4": [
+        "attention_unrotated_applications", "mlp_applications",
+        "ssm_applications", "ssm_chunks", "ssm_resets_per_row",
+        "ssm_state_bytes_per_row",
     ],
 }
 _HELD = {

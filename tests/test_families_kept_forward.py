@@ -33,6 +33,9 @@ KEPT = {
     "phi4flash": (dict(d_model=256, num_heads=4, num_key_value_heads=2), 3),
     "nemotron3": (dict(head_dim=128), 1),
     "qwen3next": (dict(head_dim=128), 1),
+    # Heads of 64 padded to the lanes, the scores times the config's
+    # constant and not 64^-0.5.
+    "granite4": (dict(head_dim=64), 1),
 }
 
 

@@ -70,6 +70,13 @@ REMAT = {
         ["moe_held_assignments", "attention_gated_applications",
          "attention_unrotated_applications", "moe_shared_applications"], [],
     ),
+    # Mixer and SwiGLU, each behind its multiplier, inside ONE
+    # rematerialised block a layer; ends inside a chunk and on step 0.
+    "granite4": (
+        {}, _ENDS, 1e-5, (0, 2e-5),
+        ["ssm_applications", "ssm_chunks", "ssm_resets_per_row",
+         "mlp_applications", "attention_unrotated_applications"], [],
+    ),
 }
 
 

@@ -404,6 +404,67 @@ def test_trinity_counters_are_the_updates_stats(trinity_compiled):
         assert model_stats.gauge_name(name) == name.replace("_", ".", 1)
 
 
+# --- a family's own scopes (PR 64: `--model granite4`) -----------------------
+
+GRANITE4_SCOPES = (
+    "mamba_in_proj", "mamba_conv", "ssd_scan", "ssd_intra", "ssd_states",
+    "ssd_inter", "mamba_gate_norm", "mamba_out_proj", "attention_full",
+    "dense_mlp",
+)
+
+
+@pytest.fixture(scope="module")
+def granite4_compiled():
+    """As `trinity_compiled`, of the toy granite4."""
+    model, params = scaffold.build("granite4")
+    t = scaffold.FAMILIES["granite4"].t
+    batch = scaffold.learner_batch(1, [(2, 0), (4, 1)], t=t)
+    hp = learner_lib.HParams(batch_size=scaffold.B, unroll_length=t - 1)
+    optimizer = optax.sgd(0.1)
+    update_step = learner_lib.make_update_step(
+        model, optimizer, hp, donate=False
+    )
+    operands = (
+        params, optimizer.init(params), batch,
+        model.initial_state(scaffold.B),
+    )
+    compiled = update_step.lower(*operands).compile()
+    stats = jax.eval_shape(update_step, *operands)[2]
+    return re.findall(r'op_name="([^"]+)"', compiled.as_text()), stats
+
+
+@pytest.mark.parametrize("scope", GRANITE4_SCOPES)
+def test_granite4_scope_reaches_the_compiled_hlo(granite4_compiled, scope):
+    """Nemotron-3's names for the mixer's parts (one account compares
+    the two cells), with the layer's SwiGLU outside every one of them."""
+    op_names, _ = granite4_compiled
+    inside = [n for n in op_names if _in_scope(n, scope)]
+    assert inside, f"no compiled op carries the scope {scope!r}"
+    if scope.startswith("ssd_") and scope != "ssd_scan":
+        assert all(_in_scope(n, "ssd_scan") for n in inside)
+    if scope == "dense_mlp":
+        assert not any(
+            _in_scope(n, other) for n in inside
+            for other in GRANITE4_SCOPES if other != "dense_mlp"
+        )
+    assert scope in device_scopes.known_device_scopes()
+
+
+def test_granite4_counters_are_the_updates_stats(granite4_compiled):
+    """What the layers sow reaches the update's stats, a gauge each
+    (`ssm.applications`, `mlp.applications`, ...)."""
+    from torchbeast_tpu.models import stats as model_stats
+
+    _, stats = granite4_compiled
+    for name in (
+        "ssm_applications", "ssm_chunks", "ssm_resets_per_row",
+        "ssm_state_bytes_per_row", "mlp_applications",
+        "attention_unrotated_applications",
+    ):
+        assert name in stats, name
+        assert model_stats.gauge_name(name) == name.replace("_", ".", 1)
+
+
 def _kernel_calls(jaxpr, under=""):
     """(name stack, kernel name) of every Pallas kernel call in a
     jaxpr, calls inside calls too: what the compiled module's

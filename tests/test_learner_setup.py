@@ -171,13 +171,15 @@ FAMILY_FIELD_CASES = {
         "3", 3, "olmoe", "pipelined_transformer",
         "--num_layers is a positive depth or window of --model "
         "transformer or olmoe or mellum2 or ouro or kanana2 or nemotron3 "
-        "or qwen3next or lfm2 or phi4flash or xing4 or trinity",
+        "or qwen3next or lfm2 or phi4flash or xing4 or trinity or "
+        "granite4",
     ),
     "memory_len": (
         "9", 9, "transformer", "deep",
         "--memory_len is a positive depth or window of --model "
         "transformer or olmoe or mellum2 or ouro or kanana2 or nemotron3 "
-        "or qwen3next or lfm2 or phi4flash or xing4 or trinity",
+        "or qwen3next or lfm2 or phi4flash or xing4 or trinity or "
+        "granite4",
     ),
     "num_experts": (
         "4", 4, "transformer", "olmoe",
@@ -288,6 +290,7 @@ def test_refusals_are_stated_on_the_class():
         "phi4flash": ("num_experts", "attention_impl"),
         "xing4": ("num_experts", "attention_impl"),
         "trinity": ("num_experts", "attention_impl"),
+        "granite4": ("num_experts", "attention_impl"),
     }
     kv_cache = [
         name for name in models.MODEL_NAMES
@@ -296,13 +299,13 @@ def test_refusals_are_stated_on_the_class():
     assert kv_cache == [
         "transformer", "pipelined_transformer", "olmoe", "mellum2", "ouro",
         "kanana2", "nemotron3", "qwen3next", "lfm2", "phi4flash", "xing4",
-        "trinity",
+        "trinity", "granite4",
     ]
     for name in models.MODEL_NAMES:
         # test_families has the published families'
         if name in kv_cache and name not in (
             "olmoe", "mellum2", "ouro", "kanana2", "nemotron3", "qwen3next",
-            "lfm2", "phi4flash", "xing4", "trinity",
+            "lfm2", "phi4flash", "xing4", "trinity", "granite4",
         ):
             with pytest.raises(ValueError, match="KV cache"):
                 models.create_model(name, num_actions=A, use_lstm=True)

@@ -205,7 +205,8 @@ def test_heads_of_64_through_the_fused_pass_are_the_dense_body(
     seen = []
     fused_attend = attention.fused_attend
 
-    def counted(q, k_all, v_all, mask, no_grad_keys, terms):
+    def counted(q, k_all, v_all, mask, no_grad_keys, terms, scale):
+        assert scale is None  # LFM2 names none: head_dim^-0.5
         seen.append((q.shape[-1], terms))
         return fused_attend(q, k_all, v_all, mask, no_grad_keys, terms)
 

@@ -57,6 +57,20 @@ def _trinity_through_main(stats):
     assert stats["aux_loss"] == 0.0
 
 
+def _granite4_through_main(stats):
+    # A period cut to `M * M`: two mixers whole around the attention
+    # layer, a SwiGLU after each of the three.
+    assert stats["ssm_applications"] == 2
+    assert stats["ssm_chunks"] == 2  # 6 steps in chunks of 4
+    # Two layers' [8, 8, 6] states and tails of 3 inputs over 8 x 8 +
+    # 2 x 6 channels, f32.
+    assert stats["ssm_state_bytes_per_row"] == 2 * 4 * (8 * 8 * 6 + 3 * 76)
+    assert stats["ssm_resets_per_row"] >= 0
+    assert stats["mlp_applications"] == 3
+    assert stats["attention_unrotated_applications"] == 1
+    assert stats["aux_loss"] == 0.0
+
+
 def _nemotron3_through_main(stats):
     assert stats["ssm_applications"] == 1
     assert stats["ssm_chunks"] == 2  # 6 steps in chunks of 4
@@ -152,6 +166,11 @@ _MELLUM2_WIDTHS = dict(
 #   scored) and a full one of 6 (read as it lies), the gate and the four
 #   norms inside
 #   every step, the blocks rematerialised, the biases moved by the load.
+#  granite4: a period cut to `M * M`: acting at T=1 through two Mamba-2
+#   states on ONE B/C group with their tails and a rolling cache of
+#   keys without positions, a SwiGLU after every mixer, the four
+#   multipliers inside every step, the blocks rematerialised; the
+#   learner's updates scan in chunks.
 #  ouro: 2 layers run 3 times, through 3 x 2 rolling caches.
 THROUGH_MAIN = {
     "mellum2-all-experts": (
@@ -236,6 +255,17 @@ THROUGH_MAIN = {
         ),
         dict(num_layers=3, expert_share="1/4", remat="all"),
         _trinity_through_main,
+    ),
+    "granite4": (
+        "granite4",
+        dict(
+            d_model=32, num_heads=4, kv_heads=2, head_dim=8, mamba_heads=8,
+            mamba_head_dim=8, state_size=6, chunk_size=4, mlp_width=48,
+            layer_period=("mamba", "attention", "mamba"),
+            layer_types=("mamba", "attention", "mamba") * 2,
+        ),
+        dict(num_layers=3, remat="all"),
+        _granite4_through_main,
     ),
     "ouro": (
         "ouro",

@@ -52,6 +52,12 @@ def _xing4_trained(stats):
     assert stats["moe_bias_steps"] == 1
 
 
+def _granite4_trained(stats):
+    assert stats["ssm_applications"] == 2
+    assert stats["mlp_applications"] == 3
+    assert stats["attention_unrotated_applications"] == 1
+
+
 def _trinity_trained(stats):
     assert stats["attention_gated_applications"] == 3
     assert stats["attention_unrotated_applications"] == 1
@@ -82,7 +88,19 @@ def _trinity_trained(stats):
 #  trinity: the slots hold two caches of 3 slots and one of 6; an act
 #   step rotates the sliding layers' and reads the full layer's as it
 #   lies.
+#  granite4: the slots hold two Mamba-2 states with their conv tails
+#   around the attention layer's window; the learner's updates scan in
+#   chunks of 4.
 FAMILIES = {
+    "granite4": (
+        dict(
+            d_model=32, num_heads=4, kv_heads=2, head_dim=8, mamba_heads=8,
+            mamba_head_dim=8, state_size=6, chunk_size=4, mlp_width=48,
+            layer_period=("mamba", "attention", "mamba"),
+            layer_types=("mamba", "attention", "mamba") * 2,
+        ),
+        3, _granite4_trained,
+    ),
     "trinity": (
         dict(
             d_model=32, num_heads=4, kv_heads=2, head_dim=8,

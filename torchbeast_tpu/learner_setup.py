@@ -181,10 +181,10 @@ def add_learner_arguments(parser, *, model_default,
     parser.add_argument("--num_layers", type=int, default=0,
                         help="Depth of --model transformer, olmoe, "
                              "mellum2, ouro, kanana2, nemotron3, "
-                             "qwen3next, lfm2, phi4flash, xing4 or "
-                             "trinity (0: "
+                             "qwen3next, lfm2, phi4flash, xing4, "
+                             "trinity or granite4 (0: "
                              "the family's own, 2 and the published 16, "
-                             "28, 48, 48, 88, 48, 24, 32, 40 and 32; "
+                             "28, 48, 48, 88, 48, 24, 32, 40, 32 and 40; "
                              "mellum2 in whole "
                              "periods of 4; ouro runs the layers it has "
                              "4 times a step; kanana2: its leading "
@@ -204,15 +204,18 @@ def add_learner_arguments(parser, *, model_default,
                              "trinity as 1 + 4k: one leading dense "
                              "sliding layer, then whole periods of three "
                              "sliding layers and one full, or all 32 "
-                             "with both dense layers).")
+                             "with both dense layers; granite4 in whole "
+                             "periods of 10, MMMMM*MMMM: nine Mamba-2 "
+                             "layers and one attention layer).")
     parser.add_argument("--memory_len", type=int, default=0,
                         help="Steps of its own past a transformer, "
                              "olmoe, mellum2, ouro, kanana2, nemotron3, "
-                             "qwen3next, lfm2, phi4flash, xing4 or trinity "
+                             "qwen3next, lfm2, phi4flash, xing4, trinity "
+                             "or granite4 "
                              "policy attends over, carried as the "
                              "rolling KV cache (0: the family's own, 64, "
                              "128, 4095, 255, 4095, 4095, 4095, 4095, 4095, "
-                             "4095 and 4095; "
+                             "4095, 4095 and 4095; "
                              "mellum2, trinity: "
                              "its full layers' cache, the sliding "
                              "layers carry min(memory_len, 1023; "
@@ -220,8 +223,8 @@ def add_learner_arguments(parser, *, model_default,
                              "every one of its 4 x num_layers caches; "
                              "kanana2, xing4: a latent and a rope key a "
                              "slot; "
-                             "nemotron3: its attention layers', the "
-                             "Mamba-2 layers carry a state instead; "
+                             "nemotron3, granite4: its attention layers', "
+                             "the Mamba-2 layers carry a state instead; "
                              "qwen3next: its attention layers', the "
                              "DeltaNet layers carry a matrix state; "
                              "lfm2: its attention layers', the conv "
@@ -333,8 +336,8 @@ def add_learner_arguments(parser, *, model_default,
                              "whose class has the `blocks` lever "
                              "(transformer, pipelined_transformer, "
                              "mellum2, ouro, kanana2, nemotron3, "
-                             "qwen3next, lfm2, phi4flash, xing4, trinity; "
-                             "not olmoe), the "
+                             "qwen3next, lfm2, phi4flash, xing4, trinity, "
+                             "granite4; not olmoe), the "
                              "LSTM scan): 'auto' picks the "
                              "minimum-recompute plan whose XLA-measured "
                              "peak fits --hbm_budget_gb; 'all'/'none' "

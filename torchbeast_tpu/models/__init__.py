@@ -11,6 +11,7 @@ from torchbeast_tpu.models.atari_net import AtariNet  # noqa: F401
 from torchbeast_tpu.models.cores import LSTMCore  # noqa: F401
 from torchbeast_tpu.models.mlp import MLPNet  # noqa: F401
 from torchbeast_tpu.models import (
+    granite4,
     kanana2,
     lfm2,
     mellum2,
@@ -22,6 +23,7 @@ from torchbeast_tpu.models import (
     trinity,
     xing4,
 )
+from torchbeast_tpu.models.granite4 import Granite4Net  # noqa: F401
 from torchbeast_tpu.models.kanana2 import Kanana2Net  # noqa: F401
 from torchbeast_tpu.models.lfm2 import Lfm2Net  # noqa: F401
 from torchbeast_tpu.models.mellum2 import Mellum2Net  # noqa: F401
@@ -58,6 +60,7 @@ _REGISTRY = {
     "phi4flash": Phi4FlashNet,
     "xing4": Xing4Net,
     "trinity": TrinityNet,
+    "granite4": Granite4Net,
 }
 # A family whose widths are a published table (its module's `PUBLISHED`,
 # keyed by the class's fields): read when the model is built, so that a
@@ -66,6 +69,7 @@ _PUBLISHED_TABLES = {
     OLMoENet: olmoe, Mellum2Net: mellum2, OuroNet: ouro, Kanana2Net: kanana2,
     Nemotron3Net: nemotron3, Qwen3NextNet: qwen3next, Lfm2Net: lfm2,
     Phi4FlashNet: phi4flash, Xing4Net: xing4, TrinityNet: trinity,
+    Granite4Net: granite4,
 }
 MODEL_NAMES = tuple(_REGISTRY)
 

@@ -192,6 +192,10 @@ class RecurrentPolicyHead(nn.Module):
     num_layers: int
     dtype: Any = jnp.float32
     remat: bool = False
+    # What the policy logits (not the baseline) are multiplied by, for a
+    # family whose config states one (models/granite4.py: 1 / `logits_
+    # scaling`); at 1 nothing is multiplied.
+    logits_scale: float = 1.0
 
     @nn.compact
     def __call__(self, core_input, done, core_state, sample_action):
@@ -213,6 +217,8 @@ class RecurrentPolicyHead(nn.Module):
             policy_logits = nn.Dense(
                 self.num_actions, dtype=self.dtype, name="policy"
             )(core_output).astype(jnp.float32)
+            if self.logits_scale != 1.0:
+                policy_logits = policy_logits * self.logits_scale
             baseline = nn.Dense(
                 1, dtype=self.dtype, name="baseline"
             )(core_output).astype(jnp.float32)

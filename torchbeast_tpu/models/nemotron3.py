@@ -317,6 +317,104 @@ def _proj(name, width, dtype):
     return nn.Dense(width, use_bias=False, dtype=dtype, name=name)
 
 
+def mamba_mixer(module, h, state, done):
+    """A Mamba-2 mixer from `in_proj` to `out_proj`, in the compact
+    method of `module`, which holds the mixer's parameters beside its
+    own and states its sizes (`d_model`, `heads`, `head_dim`, `groups`,
+    `state_size`, `conv_kernel`, `chunk_size`, `rms_norm_eps`,
+    `time_step` (min, max, floor), `dtype`): `_MambaBlock` below, and a
+    layer of models/granite4.py.
+
+    h [B, T, d], ALREADY NORMED; state (h [H, B, P, N], the
+    convolution's last conv_kernel - 1 inputs [K - 1, B, C]) as the
+    state holds them; done [B, T]. Returns (the mixer's branch [B, T,
+    d] in float32, what the caller adds to its residual stream as its
+    layer says; (h, tail) to start the next unroll from)."""
+    rows, steps, _ = h.shape
+    H, P, G, N = (
+        module.heads, module.head_dim, module.groups, module.state_size
+    )
+    K = module.conv_kernel
+    inner = H * P
+    channels = inner + 2 * G * N
+    carried, tail = state
+
+    with device_scope("mamba_in_proj"):
+        joined = _proj("in_proj", 2 * inner + 2 * G * N + H, module.dtype)(h)
+        z = joined[..., :inner]
+        xBC = joined[..., inner : inner + channels]
+        dt = joined[..., inner + channels :]
+
+    with device_scope("mamba_conv"):
+        bound = 1.0 / math.sqrt(K)
+        xBC, new_tail = conv_over_episodes(
+            xBC, tail, done,
+            module.param(
+                "conv_kernel", uniform_between(-bound, bound), (K, channels)
+            ),
+            module.param(
+                "conv_bias", uniform_between(-bound, bound), (channels,)
+            ),
+        )
+        xBC = nn.silu(xBC)
+
+    with device_scope("ssd_scan"):
+        dt = nn.softplus(
+            dt.astype(jnp.float32)
+            + module.param("dt_bias", dt_bias_init(*module.time_step), (H,))
+        )
+        A = -jnp.exp(module.param(
+            "A_log", uniform_between(1.0, 16.0, jnp.log), (H,)
+        ))
+        heads_x = xBC[..., :inner].reshape(rows, steps, H, P)
+        y, new_carried = ssd_scan(
+            heads_x, dt, A,
+            xBC[..., inner : inner + G * N].reshape(rows, steps, G, N),
+            xBC[..., inner + G * N :].reshape(rows, steps, G, N),
+            carried.transpose(1, 0, 2, 3), done, module.chunk_size,
+        )
+        y = y + module.param("D", nn.initializers.ones, (H,))[
+            :, None
+        ] * heads_x
+
+    with device_scope("mamba_gate_norm"):
+        y = gated_group_norm(
+            y.reshape(rows, steps, inner), z.astype(jnp.float32),
+            module.param("gate_norm", nn.initializers.ones, (inner,)),
+            G, module.rms_norm_eps,
+        )
+    with device_scope("mamba_out_proj"):
+        branch = _proj("out_proj", module.d_model, module.dtype)(
+            y.astype(module.dtype)
+        ).astype(jnp.float32)
+
+    return branch, (new_carried.transpose(1, 0, 2, 3), new_tail)
+
+
+def count_mamba_application(module, done):
+    """One `mamba_mixer` application of `module` over done [B, T], for
+    the update's stats: how many such layers and the bytes of state a
+    row carries through them; the chunks the unroll's scan was cut into
+    and the episode ends a row had, which every layer says alike. Its
+    caller says so once its branch has joined the stream."""
+    if module.is_initializing():
+        return
+    H, P, G, N = (
+        module.heads, module.head_dim, module.groups, module.state_size
+    )
+    steps = done.shape[1]
+    for name, value, fold in (
+        ("ssm_applications", 1.0, "sum"),
+        ("ssm_state_bytes_per_row",
+         4 * (H * P * N + (module.conv_kernel - 1) * (H * P + 2 * G * N)),
+         "sum"),
+        ("ssm_chunks", -(-steps // min(module.chunk_size, steps)), "same"),
+        ("ssm_resets_per_row",
+         jnp.mean(jnp.sum(done.astype(jnp.float32), axis=1)), "same"),
+    ):
+        sow_stat(module, name, value, fold)
+
+
 class _MambaBlock(nn.Module):
     d_model: int
     heads: int  # held here
@@ -331,87 +429,57 @@ class _MambaBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, state, done):
-        """x [B, T, d]; state (h [H, B, P, N], the convolution's last
-        conv_kernel - 1 inputs [K - 1, B, C]) as the state holds them;
-        done [B, T]. Returns (y, (h, tail)) to start the next unroll
-        from."""
-        rows, steps, _ = x.shape
-        H, P, G, N = self.heads, self.head_dim, self.groups, self.state_size
-        K = self.conv_kernel
-        inner = H * P
-        channels = inner + 2 * G * N
-        carried, tail = state
-
+        """x [B, T, d]; state and done as `mamba_mixer` takes them.
+        Returns (y, (h, tail)) to start the next unroll from."""
         with device_scope("mamba_in_proj"):
             h = _norm("norm", self.rms_norm_eps)(x)
-            joined = _proj("in_proj", 2 * inner + 2 * G * N + H, self.dtype)(h)
-            z = joined[..., :inner]
-            xBC = joined[..., inner : inner + channels]
-            dt = joined[..., inner + channels :]
-
-        with device_scope("mamba_conv"):
-            bound = 1.0 / math.sqrt(K)
-            xBC, new_tail = conv_over_episodes(
-                xBC, tail, done,
-                self.param(
-                    "conv_kernel", uniform_between(-bound, bound),
-                    (K, channels),
-                ),
-                self.param(
-                    "conv_bias", uniform_between(-bound, bound), (channels,)
-                ),
-            )
-            xBC = nn.silu(xBC)
-
-        with device_scope("ssd_scan"):
-            dt = nn.softplus(
-                dt.astype(jnp.float32)
-                + self.param(
-                    "dt_bias", dt_bias_init(*self.time_step), (H,)
-                )
-            )
-            A = -jnp.exp(self.param(
-                "A_log", uniform_between(1.0, 16.0, jnp.log), (H,)
-            ))
-            heads_x = xBC[..., :inner].reshape(rows, steps, H, P)
-            y, new_carried = ssd_scan(
-                heads_x, dt, A,
-                xBC[..., inner : inner + G * N].reshape(rows, steps, G, N),
-                xBC[..., inner + G * N :].reshape(rows, steps, G, N),
-                carried.transpose(1, 0, 2, 3), done, self.chunk_size,
-            )
-            y = y + self.param("D", nn.initializers.ones, (H,))[
-                :, None
-            ] * heads_x
-
-        with device_scope("mamba_gate_norm"):
-            y = gated_group_norm(
-                y.reshape(rows, steps, inner), z.astype(jnp.float32),
-                self.param("gate_norm", nn.initializers.ones, (inner,)),
-                G, self.rms_norm_eps,
-            )
+        branch, new_state = mamba_mixer(self, h, state, done)
         with device_scope("mamba_out_proj"):
-            x = x + _proj("out_proj", self.d_model, self.dtype)(
-                y.astype(self.dtype)
-            ).astype(jnp.float32)
+            x = x + branch
+        count_mamba_application(self, done)
+        return x, new_state
 
-        if not self.is_initializing():
-            # How many such layers and the bytes of state a row carries
-            # through them; the chunks the unroll's scan was cut into
-            # and the episode ends a row had, which every layer says
-            # alike.
-            for name, value, fold in (
-                ("ssm_applications", 1.0, "sum"),
-                ("ssm_state_bytes_per_row",
-                 4 * (H * P * N + (K - 1) * channels), "sum"),
-                ("ssm_chunks", -(-steps // min(self.chunk_size, steps)),
-                 "same"),
-                ("ssm_resets_per_row",
-                 jnp.mean(jnp.sum(done.astype(jnp.float32), axis=1)),
-                 "same"),
-            ):
-                sow_stat(self, name, value, fold)
-        return x, (new_carried.transpose(1, 0, 2, 3), new_tail)
+
+def cache_and_mask(cache_state, cache_mask, seq_mask):
+    """What `attention_mixer` attends over, of the block contract's
+    arguments: the cache (k, v) batch-first, [B, M, kv_heads, hd], and
+    one mask over [cache; unroll], [B, T, M + T]."""
+    cache = tuple(c.transpose(1, 0, 2, 3) for c in cache_state)
+    return cache, jnp.concatenate([cache_mask, seq_mask], axis=-1)
+
+
+def attention_mixer(module, h, cache, mask, scale=None):
+    """A position-free grouped-query attention from its projections to
+    `o`, in the compact method of `module`, which holds the parameters
+    beside its own and states `d_model`, `num_heads`, `kv_heads`,
+    `head_dim`, `memory_len`, `dtype`: `_AttentionBlock` below, and a
+    layer of models/granite4.py (which also names `scale`, what the
+    scores are multiplied by; None is head_dim^-0.5).
+
+    h [B, T, d], ALREADY NORMED; cache and mask as `cache_and_mask`
+    makes them. Returns (the branch [B, T, d] in float32, k, v: this
+    unroll's keys and values [B, T, kv_heads, hd]). No positional
+    embedding: a key is what it was when cached."""
+    rows, steps, _ = h.shape
+    H, Hkv, hd = module.num_heads, module.kv_heads, module.head_dim
+    q = _proj("q", H * hd, module.dtype)(h).reshape(rows, steps, H, hd)
+    k, v = (
+        _proj(name, Hkv * hd, module.dtype)(h).reshape(rows, steps, Hkv, hd)
+        for name in ("k", "v")
+    )
+    k_all = jnp.concatenate([cache[0].astype(k.dtype), k], axis=1)
+    v_all = jnp.concatenate([cache[1].astype(v.dtype), v], axis=1)
+    # The cache is the learner's data: its M keys take no gradient (as
+    # models/mellum2.py).
+    attended = dense_transformer_attend(
+        q, k_all, v_all, mask, None, None, module.memory_len, scale=scale
+    )
+    if fused_pass_applies(q.shape, k_all.shape, None):
+        count_fused_application(module)
+    branch = _proj("o", module.d_model, module.dtype)(
+        attended.reshape(rows, steps, H * hd)
+    ).astype(jnp.float32)
+    return branch, k.astype(jnp.float32), v.astype(jnp.float32)
 
 
 class _AttentionBlock(nn.Module):
@@ -428,34 +496,14 @@ class _AttentionBlock(nn.Module):
         """TransformerNet's block contract for a window entry: x
         [B, T, d]; cache_state (k, v) [M, B, kv_heads, hd] as the state
         holds them; cache_mask [B, T, M], seq_mask [B, T, T]. Returns
-        (y, k, v), this unroll's keys and values [B, T, kv_heads, hd].
-        No positional embedding: a key is what it was when cached."""
-        rows, steps, _ = x.shape
-        H, Hkv, hd = self.num_heads, self.kv_heads, self.head_dim
-        cache = tuple(c.transpose(1, 0, 2, 3) for c in cache_state)
-        mask = jnp.concatenate([cache_mask, seq_mask], axis=-1)
+        (y, k, v), this unroll's keys and values [B, T, kv_heads, hd]."""
+        cache, mask = cache_and_mask(cache_state, cache_mask, seq_mask)
         with device_scope("attention_full"):
-            h = _norm("norm", self.rms_norm_eps)(x)
-            q = _proj("q", H * hd, self.dtype)(h).reshape(rows, steps, H, hd)
-            k, v = (
-                _proj(name, Hkv * hd, self.dtype)(h).reshape(
-                    rows, steps, Hkv, hd
-                )
-                for name in ("k", "v")
+            branch, k, v = attention_mixer(
+                self, _norm("norm", self.rms_norm_eps)(x), cache, mask
             )
-            k_all = jnp.concatenate([cache[0].astype(k.dtype), k], axis=1)
-            v_all = jnp.concatenate([cache[1].astype(v.dtype), v], axis=1)
-            # The cache is the learner's data: its M keys take no
-            # gradient (as models/mellum2.py).
-            attended = dense_transformer_attend(
-                q, k_all, v_all, mask, None, None, self.memory_len
-            )
-            if fused_pass_applies(q.shape, k_all.shape, None):
-                count_fused_application(self)
-            x = x + _proj("o", self.d_model, self.dtype)(
-                attended.reshape(rows, steps, H * hd)
-            ).astype(jnp.float32)
-        return x, k.astype(jnp.float32), v.astype(jnp.float32)
+            x = x + branch
+        return x, k, v
 
 
 class _LatentMoEBlock(nn.Module):

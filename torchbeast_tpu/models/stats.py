@@ -23,7 +23,7 @@ COLLECTIONS = tuple("stats_" + fold for fold in FOLDS)
 # A stat is `<family>_<name>`, its gauge `<family>.<name>`.
 FAMILIES = (
     "moe", "ssm", "delta", "conv", "loop", "attention", "shared", "hc",
-    "obs",
+    "obs", "mlp",
 )
 
 

@@ -493,6 +493,10 @@ class TransformerNet(nn.Module):
     # family's choice, not a flag. At 1 nothing is multiplied and
     # `Dense_0` is made as it always was.
     input_scale: float = 1.0
+    # What the head's policy logits are multiplied by (models/cores.py
+    # `RecurrentPolicyHead.logits_scale`; models/granite4.py: a config's
+    # `logits_scaling`); a family's choice, not a flag.
+    logits_scale: float = 1.0
 
     @nn.compact
     def __call__(self, inputs, core_state, *, sample_action: bool = True):
@@ -658,6 +662,7 @@ class TransformerNet(nn.Module):
             hidden_size=self.d_model,
             num_layers=1,
             dtype=self.head_dtype,
+            logits_scale=self.logits_scale,
             name="head",
         )(core_output, done, (), sample_action)
         return out, tuple(new_state)

@@ -999,7 +999,7 @@ _no_gradient_before.defvjp(
 
 
 def dense_transformer_attend(q, k_all, v_all, mask, offsets, rel_bias,
-                             no_grad_keys=0):
+                             no_grad_keys=0, scale=None):
     """The transformer policy's attention body over `[cache; unroll]` —
     ONE implementation shared by the model's dense branch
     (models/transformer.py _Block) and the Ulysses path below (which is
@@ -1012,7 +1012,10 @@ def dense_transformer_attend(q, k_all, v_all, mask, offsets, rel_bias,
     mask: [B, T, M+T] bool; offsets: [T, M+T] int in [0, M];
     rel_bias: [H, M+1], or None for a family whose positions enter
     elsewhere (RoPE, models/olmoe.py). Scores and softmax run in f32;
-    the combine runs in v's dtype.
+    the combine runs in v's dtype: softmax(mask(q k^T * scale + bias))
+    v, where `scale` (a Python float, static) is D^-0.5 when None, the
+    default, and otherwise what the family's config states (models/
+    granite4.py: `attention_multiplier`), in both regimes below.
 
     Hkv may be a divisor of H (grouped-query heads, models/mellum2.py):
     query head j reads key/value head j // (H // Hkv). The queries are
@@ -1050,12 +1053,13 @@ def dense_transformer_attend(q, k_all, v_all, mask, offsets, rel_bias,
         )
     if fused_pass_applies(q.shape, k_all.shape, rel_bias):
         return fused_attend(
-            q, k_all, v_all, mask, no_grad_keys, terms=terms_traced_under()
+            q, k_all, v_all, mask, no_grad_keys, terms=terms_traced_under(),
+            scale=scale,
         )
     if no_grad_keys:
         k_all = _no_gradient_before(k_all, no_grad_keys)
         v_all = _no_gradient_before(v_all, no_grad_keys)
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else float(scale)
     if Hkv == H:
         scores = (
             jnp.einsum("bqhd,bkhd->bhqk", q, k_all).astype(jnp.float32)

@@ -581,8 +581,8 @@ def _fused_attend_bwd(no_grad_keys, on_chip, terms, scale, residuals,
 _fused_attend.defvjp(_fused_attend_fwd, _fused_attend_bwd)
 
 
-def fused_attend(q, k_all, v_all, mask, no_grad_keys=0, terms=1):
-    """softmax(mask(q k^T / sqrt(D))) v with grouped heads, as
+def fused_attend(q, k_all, v_all, mask, no_grad_keys=0, terms=1, scale=None):
+    """softmax(mask(q k^T * scale)) v with grouped heads, as
     `ops/attention.dense_transformer_attend` with no `rel_bias`, the
     scores never in HBM (see the module's header).
 
@@ -598,22 +598,22 @@ def fused_attend(q, k_all, v_all, mask, no_grad_keys=0, terms=1):
     `bf16_terms.terms_traced_under` counts them: 1, one pass on
     operands cast to bfloat16 outside the kernels; 2 (`high`) or 3
     (`highest`), float32 operands cut in VMEM and a product's 3 or 6
-    passes made from the terms (see the module's header).
+    passes made from the terms (see the module's header). `scale` (a
+    Python float, static): None, the default, is D^-0.5; else what the
+    family's config states (models/granite4.py: 1/64 on heads of 64).
 
     Differentiated inside `jax.checkpoint` under a policy that saves
-    the names `KEPT_FORWARD` (`jax.checkpoint_policies.save_only_these_
-    names`), the forward kernel runs once: its output and the rows'
-    log-sum-exp (one float a row) are kept for the backward kernel and
-    the second forward makes the operands alone. Under no such policy
-    the names do nothing.
+    the names `KEPT_FORWARD` (`save_only_these_names`), the forward
+    kernel runs once: its output and the rows' log-sum-exp (one float a
+    row) are kept for the backward kernel and the second forward makes
+    the operands alone. Under no such policy the names do nothing.
 
     A head narrower than the 128 lanes (models/lfm2.py: 64) is padded
     to them with zero columns, which add nothing to a score and come
     out of the combine as zeros that are dropped; the scores keep the
-    true D^-0.5. The MXU contracts over 64 at the cost of 128 either
-    way; what the padding costs is the operands' second half in VMEM
-    and in the keys' one copy.
-    """
+    caller's `scale` (by default the true D^-0.5, not the padded
+    width's). The MXU contracts over 64 at the cost of 128 either way;
+    the padding costs half the operands' VMEM and keys' one copy."""
     d = q.shape[-1]
     narrow = -d % _LANES if d < _LANES else 0
     if narrow:
@@ -623,7 +623,7 @@ def fused_attend(q, k_all, v_all, mask, no_grad_keys=0, terms=1):
         )
     out = _fused_attend(
         q, k_all, v_all, mask, no_grad_keys, jax.default_backend() == "tpu",
-        int(terms), d ** -0.5,
+        int(terms), d ** -0.5 if scale is None else float(scale),
     )
     return out[..., :d] if narrow else out
 
