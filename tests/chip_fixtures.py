@@ -17,6 +17,7 @@ without a chip.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -67,3 +68,25 @@ def on(sharding, tree):
 
 def struct(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def assert_scan_kernels(text, shapes, mixers, chunks, state_elements):
+    """A cell's compiled `text` holds ops/ssd_scan.py's kernels for its
+    `mixers` Mamba-2 layers (PR 65): the forward kernel twice a layer
+    (the rematerialised block's second forward calls it again), the
+    backward's once; and, among `shapes` (the text's float32 arrays'),
+    none of a state a chunk ([.., c, H, P, N]: the `jax.numpy` form's
+    `left` and `entering`) beside the carried states' own
+    `state_elements` a layer."""
+    for kernel, calls in (
+        ("ssd_scan_forward", 2 * mixers), ("ssd_scan_backward", mixers),
+    ):
+        assert len(re.findall(
+            r'custom_call_target="tpu_custom_call"[^\n]*' + kernel, text
+        )) == calls, kernel
+    states = {
+        s for s in shapes
+        if len(s) >= 4 and s[-1] == 128 and chunks in s[:-1]
+        and int(np.prod(s)) == chunks * state_elements
+    }
+    assert not states, states

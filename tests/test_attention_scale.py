@@ -117,14 +117,16 @@ def test_the_fused_pass_under_the_body_takes_the_callers_scale(monkeypatch):
 # functions their `scale` and cut `mamba_mixer` / `attention_mixer` out
 # of models/nemotron3.py's blocks: made by `_lowered_update` below in a
 # `git archive` of that commit. A later PR that changes one of these
-# programs on purpose computes its own.
+# programs on purpose computes its own: Nemotron-3's two are PR 65's,
+# whose mixers sow one more stat (`ssm_kernel_applications`) and norm
+# the gate's groups as slices (`gated_group_norm`).
 PARENTS = {
     # Nemotron-3's blocks call the two mixers; the dense body.
-    ("nemotron3", False): "ddd2195c9365f825",
+    ("nemotron3", False): "087c85731cee531b",
     # Heads of 64 through the fused pass, rematerialised: LFM2's.
     ("lfm2", True): "4c5c6bbe544f47dc",
     # Heads of 128 through the fused pass, the mixers' caller.
-    ("nemotron3", True): "79549e9893d8e7f1",
+    ("nemotron3", True): "58ee18004b2effdd",
 }
 _FUSED = {"lfm2": dict(head_dim=64), "nemotron3": dict(head_dim=128)}
 
@@ -156,7 +158,8 @@ def test_lowered_updates_are_the_parents(family, fused, monkeypatch):
     byte for byte: Nemotron-3 through the mixers cut out of its blocks
     (its parameter tree is then the parent's too: the text lists every
     leaf's shape in the tree's order), and a fused-pass family with the
-    argument absent."""
+    argument absent (Nemotron-3's two programs as PR 65 left them:
+    `PARENTS`)."""
     if fused:
         monkeypatch.setattr(attention, "FUSED_SCORE_BYTES", 1)
     text = _lowered_update(family, fused)

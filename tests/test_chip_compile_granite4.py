@@ -1,6 +1,8 @@
 """Compile for the chip, without the chip (tests/chip_fixtures.py):
 `granite4_policy.learner`'s whole update, one AOT compile of the real
-cell. A file of its own: tests/chip_fixtures.py says why.
+cell, and the Mamba-2 scan's two kernels alone at shapes they admit
+beside the two cells'. A file of its own: tests/chip_fixtures.py says
+why.
 """
 
 import json
@@ -8,16 +10,75 @@ import os
 import re
 
 import numpy as np
+import pytest
 
 import jax
+import jax.numpy as jnp
 
 from tests.chip_fixtures import (  # noqa: F401 (fixtures)
     NUM_ACTIONS,
+    assert_scan_kernels,
     on as _on,
     one_chip,
+    struct as _struct,
     topo,
 )
 from torchbeast_tpu import learner as learner_lib
+from torchbeast_tpu.models import nemotron3
+from torchbeast_tpu.ops import ssd_scan
+
+
+@pytest.mark.parametrize(
+    "rows, steps, H, P, G, N, chunk, precision",
+    [
+        (2, 160, 4, 64, 1, 128, 80, "high"),  # --unroll_length 80
+        (2, 40, 8, 32, 2, 128, 16, "highest"),  # four heads a tile, padded
+        (2, 32, 16, 16, 1, 128, 16, None),  # eight heads a tile, one pass
+        (2, 96, 2, 128, 1, 256, 48, "high"),  # a head a tile, a wide state
+        (2, 416, 4, 64, 2, 256, 208, "high"),  # a chunk of 1.6 lane tiles
+        (2, 512, 4, 64, 1, 384, 256, "high"),
+        # All that `kernels_apply` lets the backward kernel hold: four
+        # chunks of 128 heads' [64, 256] states, 32 MB of VMEM.
+        (1, 1024, 128, 64, 1, 256, 256, "high"),
+    ],
+)
+def test_ssd_scan_kernels_compile_for_v5e(
+    one_chip, monkeypatch, rows, steps, H, P, G, N, chunk, precision
+):
+    """The check interpret mode cannot make, at shapes
+    `ssd_scan.kernels_apply` admits and no cell has (the two cells'
+    are in the whole updates, here and in
+    tests/test_chip_compile_nemotron3.py): `ssd_scan`, value and every
+    gradient, compiles for the chip's compiler with chunks that are no
+    whole lane tiles (80, 48, 208 steps: [80, 80] scratch, products 80
+    deep, `rows[0:1, sources]` on part of a tile), heads of 16, 32 and
+    128, a state of two and three lane tiles, one, three and six
+    passes, and the most entering states the backward kernel is let
+    hold; each kernel is one Mosaic call."""
+    assert ssd_scan.kernels_apply(steps, chunk, H, P, G, N)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(x, dt, A, B_in, C_in, state, done):
+        with jax.default_matmul_precision(precision):
+            y, last = nemotron3.ssd_scan(
+                x, dt, A, B_in, C_in, state, done, chunk
+            )
+        return jnp.sum(y * y) + jnp.sum(last)
+
+    traced = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6))))
+    text = traced.lower(
+        _struct(one_chip, (rows, steps, H, P)),
+        _struct(one_chip, (rows, steps, H)),
+        _struct(one_chip, (H,)),
+        _struct(one_chip, (rows, steps, G, N)),
+        _struct(one_chip, (rows, steps, G, N)),
+        _struct(one_chip, (rows, H, P, N)),
+        _struct(one_chip, (rows, steps), jnp.bool_),
+    ).compile().as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*', text)
+    assert len(calls) == 2, len(calls)
+    assert sum("ssd_scan_forward" in call for call in calls) == 1
+    assert sum("ssd_scan_backward" in call for call in calls) == 1
 
 
 def test_granite4_cell_update_compiles_for_v5e(one_chip, monkeypatch):
@@ -30,7 +91,9 @@ def test_granite4_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     15.0 GiB (rung 1 of the configuration's `fit`: nothing cut); the
     attention layer's scores over 4,607 keys stay in the fused pass's
     kernels (heads of 64 padded to the lanes, the scale the config's
-    1/64), its forward kernel called once."""
+    1/64), its forward kernel called once; every mixer's scan is
+    ops/ssd_scan.py's kernels (PR 65), no state but the carried one an
+    array."""
     from perfbench import flops_granite4, manifest
     from perfbench.drivers import learner as learner_driver
     from torchbeast_tpu import monobeast
@@ -103,3 +166,5 @@ def test_granite4_cell_update_compiles_for_v5e(one_chip, monkeypatch):
         assert len(re.findall(
             r'custom_call_target="tpu_custom_call".*' + kernel, text
         )) == 1, kernel
+    # The nine mixers' scans, their states [64, 8, 64, 128] a layer.
+    assert_scan_kernels(text, shapes, 9, 2, rows * 64 * 64 * 128)

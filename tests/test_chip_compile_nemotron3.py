@@ -11,6 +11,7 @@ import jax
 
 from tests.chip_fixtures import (  # noqa: F401 (fixtures)
     NUM_ACTIONS,
+    assert_scan_kernels,
     on as _on,
     one_chip,
     topo,
@@ -63,6 +64,18 @@ def test_nemotron3_cell_update_compiles_for_v5e(one_chip, monkeypatch):
         ),
         model.initial_state(rows),
     ))
+    # Traced afresh, as the benchmark's own process does: the count of
+    # the experts' calls below rests on XLA merging two calls whose
+    # bodies are equal, and a body's source locations are those of the
+    # trace that made it. jit's tracing cache is keyed by the matmul
+    # precision among others, so the forward (traced under `high`) can
+    # take a `gmm` of these shapes that an earlier test of this process
+    # traced (tests/test_chip_compile_moe.py, `high-nemotron3`) where
+    # the backward (traced outside it) makes its own: the same kernel
+    # from two call stacks, not merged, ten calls more (PR 65, seen in
+    # a whole run: the file after the other in one worker; so at the
+    # parent commit).
+    jax.clear_caches()
     compiled = learner_lib.make_update_step(model, optimizer, hp).lower(
         _on(one_chip, params),
         _on(one_chip, jax.eval_shape(optimizer.init, params)),
@@ -112,15 +125,25 @@ def test_nemotron3_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     # shipped kernels before): five MoE layers x (2 forward, 2 the
     # backward sweep's second forward, 4 backward), each rung compiled
     # twice since PR 58, the first before the loop and the loop's body,
-    # and the two forward of the rematerialised block's loop: its first
-    # rung's value is unused and gone, and the loop that is left takes
-    # no turn on a step of one rung (50 while the loops started from
-    # zeros, that one's turn among them); beside them the attention
+    # and the two forward of the rematerialised block's loop. That
+    # block's FIRST rung is live (the latent's up-projection reads the
+    # sweep's sum for its weight gradient) and costs no call: it is the
+    # product on the operands that the backward sweep's first rung
+    # makes again, and XLA merges the two (the loop that is left takes
+    # no turn on a step of one rung; 50 while the loops started from
+    # zeros, that one's turn among them). Beside them the attention
     # layer's two (one forward since PR 63: the rematerialised block
-    # keeps its results).
+    # keeps its results). XLA merges two kernel calls only if their
+    # serialised bodies are equal to the byte, and a body carries the
+    # source locations of the trace that made it: see `jax.clear_caches`
+    # above (100 with a `gmm` of these shapes traced earlier in the
+    # process, PR 65).
     assert len(re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
     )) == 90
     assert "/moe/moe_sweep/jit(_rung)/moe_experts" in text
     assert "/moe/moe_sweep/while/body/jit(_rung)/moe_experts" in text
-    assert text.count("tpu_custom_call") == 90 + 2
+    # The five mixers' scans (PR 65), their states [32, 16, 64, 128] a
+    # layer: three more kernel calls a mixer.
+    assert_scan_kernels(text, shapes, 5, 2, rows * 32 * 64 * 128)
+    assert text.count("tpu_custom_call") == 90 + 2 + 3 * 5

@@ -963,7 +963,8 @@ STATS_AT_PR_44 = {
         "moe_latent_applications", "moe_load_max_over_mean",
         "moe_shared_applications", "moe_window_rows",
         "moe_window_short_applications", "ssm_applications", "ssm_chunks",
-        "ssm_resets_per_row", "ssm_state_bytes_per_row",
+        "ssm_kernel_applications", "ssm_resets_per_row",
+        "ssm_state_bytes_per_row",
     ],
     # Not a parent's: the family of PR 46, as it came (four of sixteen
     # held under three chosen: no window); PR 61's count of the layers
@@ -1012,11 +1013,12 @@ STATS_AT_PR_44 = {
         "moe_window_rows", "moe_window_short_applications",
     ],
     # The family of PR 64, as it came: Nemotron-3's four of the mixer,
-    # and the sublayers that follow every mixer.
+    # and the sublayers that follow every mixer; PR 65's count of the
+    # mixers whose scan its kernels ran (Nemotron-3's list too).
     "granite4": [
         "attention_unrotated_applications", "mlp_applications",
-        "ssm_applications", "ssm_chunks", "ssm_resets_per_row",
-        "ssm_state_bytes_per_row",
+        "ssm_applications", "ssm_chunks", "ssm_kernel_applications",
+        "ssm_resets_per_row", "ssm_state_bytes_per_row",
     ],
 }
 _HELD = {

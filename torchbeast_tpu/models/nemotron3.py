@@ -86,10 +86,12 @@ from torchbeast_tpu.models.transformer import (
     count_fused_application,
     rematerialised,
 )
+from torchbeast_tpu.ops import ssd_scan as scan_kernels
 from torchbeast_tpu.ops.attention import (
     dense_transformer_attend,
     fused_pass_applies,
 )
+from torchbeast_tpu.ops.bf16_terms import terms_traced_under
 from torchbeast_tpu.telemetry import device_scope
 
 MAMBA, ATTENTION, EXPERTS = "M", "*", "E"
@@ -181,11 +183,22 @@ def ssd_scan(x, dt, A, B_in, C_in, state, done, chunk):
     episode end: where one lies between two steps the comparison of
     their counts of ends says 0, and the difference is not read).
     The last chunk is padded with steps of dt = 0, which pass the state
-    on as it is. A chunk of one step (T = 1) is the recurrence."""
+    on as it is. A chunk of one step (T = 1) is the recurrence.
+
+    Two forms, chosen by the shapes alone (`ops/ssd_scan.kernels_apply`):
+    a learner's unroll at published widths runs ops/ssd_scan.py's two
+    Mosaic kernels (a head block's state in VMEM from chunk to chunk, x,
+    B, C and y read and written as [B, T, H P] and [B, T, G N]); acting
+    at T = 1 and toy widths run the `jax.numpy` form below, which is
+    what the kernels are held to (tests/test_ssd_scan_kernel.py)."""
     rows, steps, H, P = x.shape
     G, N = B_in.shape[2:]
     per = H // G
     Q, pad, nc = chunk_plan(steps, chunk)
+    if scan_kernels.kernels_apply(steps, Q, H, P, G, N):
+        return scan_kernels.scan(
+            x, dt, A, B_in, C_in, state, done, Q, terms_traced_under()
+        )
 
     def chunks(a):
         return in_chunks(a, Q, pad)
@@ -280,12 +293,22 @@ def conv_over_episodes(inputs, tail, done, taps, bias):
 
 def gated_group_norm(y, z, scale, groups, eps):
     """rmsnorm_per_group(y * silu(z)) * scale: the gate first, then the
-    norm inside each of the `groups` equal parts of the last axis."""
-    gated = (y * nn.silu(z)).reshape(y.shape[:-1] + (groups, -1))
-    gated = gated * jax.lax.rsqrt(
-        jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + eps
-    )
-    return gated.reshape(y.shape) * scale
+    norm inside each of the `groups` equal parts of the last axis, each
+    cut out as a slice: y is then never [.., groups, width], which the
+    chip lays out apart from [.., groups * width] (a copy in and a copy
+    out of every mixer, 3.3 ms of Nemotron-3's step once the scan's
+    kernels hand y over flat; PERF.md section 6, PR 65)."""
+    gated = y * nn.silu(z)
+    width = y.shape[-1] // groups
+    parts = [
+        gated[..., g * width : (g + 1) * width] for g in range(groups)
+    ]
+    return jnp.concatenate([
+        part * jax.lax.rsqrt(
+            jnp.mean(jnp.square(part), axis=-1, keepdims=True) + eps
+        )
+        for part in parts
+    ], axis=-1) * scale
 
 
 def dt_bias_init(low, high, floor):
@@ -403,12 +426,16 @@ def count_mamba_application(module, done):
         module.heads, module.head_dim, module.groups, module.state_size
     )
     steps = done.shape[1]
+    Q = min(module.chunk_size, steps)
     for name, value, fold in (
         ("ssm_applications", 1.0, "sum"),
+        # Those whose scan is ops/ssd_scan.py's kernels.
+        ("ssm_kernel_applications",
+         float(scan_kernels.kernels_apply(steps, Q, H, P, G, N)), "sum"),
         ("ssm_state_bytes_per_row",
          4 * (H * P * N + (module.conv_kernel - 1) * (H * P + 2 * G * N)),
          "sum"),
-        ("ssm_chunks", -(-steps // min(module.chunk_size, steps)), "same"),
+        ("ssm_chunks", -(-steps // Q), "same"),
         ("ssm_resets_per_row",
          jnp.mean(jnp.sum(done.astype(jnp.float32), axis=1)), "same"),
     ):
