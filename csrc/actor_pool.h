@@ -26,7 +26,6 @@
 #include <pthread.h>
 
 #include <atomic>
-#include <chrono>
 #include <cstring>
 #include <exception>
 #include <functional>
@@ -39,6 +38,7 @@
 #include "backoff.h"
 #include "chaos.h"
 #include "client.h"
+#include "clock.h"
 #include "queues.h"
 #include "shm.h"
 #include "wire.h"
@@ -89,6 +89,10 @@ class ActorPool {
     // ring_wait_counters — cumulative like the fields above).
     int64_t ring_doorbell_waits = 0;
     int64_t ring_recheck_wakeups = 0;
+    // Streams whose server does not read this machine's monotonic
+    // clock (a server on another host), each counted once: they
+    // observe actor.env_step_s alone.
+    int64_t env_clock_unshared = 0;
   };
 
   // `inference_batcher` is any InferenceClient: a plain DynamicBatcher
@@ -161,14 +165,50 @@ class ActorPool {
         shm::ring_wait_counters().doorbell_waits.load();
     t.ring_recheck_wakeups =
         shm::ring_wait_counters().recheck_wakeups.load();
+    t.env_clock_unshared = env_clock_unshared_.load();
     return t;
   }
 
-  // Interval aggregate (resets on read, like the batcher's histograms)
-  // of actor.env_rtt_s: send(action) to the return of recv_step, i.e.
-  // the wire both ways plus the env server's step. With
-  // actor.request_rtt_s it makes up an actor's whole step.
-  HistSnapshot env_rtt_snapshot() { return env_rtt_s_.snapshot(true); }
+  // One actor cycle, every term stamped where it happens, all on
+  // monotonic_ns() (ISSUE 66). An iteration of loop() is
+  //
+  //   top -> [enqueue] -> request_rtt -> reply_wake -> own (to the send)
+  //       -> env_rtt -> own (push, every unroll_length-th time the
+  //       rollout's enqueue and the slot read) -> top
+  //
+  // so cycle = request_rtt + reply_wake + own + env_rtt, less the few
+  // microseconds from the loop's top to the batcher's enqueue stamp
+  // (actor.request_rtt_s is the batcher's, queues.h: it ends when
+  // set_outputs is ENTERED). env_rtt is cut further by the two stamps
+  // the server puts on the step message:
+  //
+  //   env_rtt = env_wire_down + env_step + env_wire_up
+  //
+  // exactly, for a stream whose server shares this machine's clock;
+  // any other stream observes env_step alone.
+  struct StageHistograms {
+    HistAccum env_rtt_s;        // send(action) -> recv_step returned
+    HistAccum env_wire_down_s;  // send(action) -> the server's receipt
+    HistAccum env_step_s;       // the server's receipt -> its env stepped
+    HistAccum env_wire_up_s;    // env stepped -> recv_step returned
+    HistAccum reply_wake_s;     // set_outputs entered -> compute returned
+    HistAccum own_s;            // the actor thread's own two stretches
+    HistAccum cycle_s;          // loop top -> loop top
+  };
+
+  // Interval aggregates (reset on read, like the batcher's histograms),
+  // by the registry series each folds into.
+  std::vector<std::pair<const char*, HistSnapshot>> stage_snapshots() {
+    return {
+        {"actor.env_rtt_s", stages_.env_rtt_s.snapshot(true)},
+        {"actor.env_wire_down_s", stages_.env_wire_down_s.snapshot(true)},
+        {"actor.env_step_s", stages_.env_step_s.snapshot(true)},
+        {"actor.env_wire_up_s", stages_.env_wire_up_s.snapshot(true)},
+        {"actor.reply_wake_s", stages_.reply_wake_s.snapshot(true)},
+        {"actor.own_s", stages_.own_s.snapshot(true)},
+        {"actor.cycle_s", stages_.cycle_s.snapshot(true)},
+    };
+  }
 
   // Blocks until every loop exits; rethrows the first error.
   void run() {
@@ -352,14 +392,38 @@ class ActorPool {
     return a;
   }
 
-  ArrayNest recv_step(Transport* t) {
+  // A step as received: the env outputs, and the two instants the
+  // server stamped on the message (monotonic_ns on ITS machine): the
+  // action's receipt (the initial Step has none) and its env's return
+  // from step (the initial Step: from initial). 0 where the message
+  // carries none (a server from before ISSUE 66).
+  struct Step {
+    ArrayNest env;
+    int64_t server_recv_ns = 0;
+    int64_t server_stepped_ns = 0;
+  };
+
+  static int64_t int_field(const wire::ValueNest& msg, const char* key) {
+    auto it = msg.dict().find(key);
+    if (it == msg.dict().end() || !it->second.is_leaf() ||
+        it->second.leaf().kind != wire::Value::Kind::kInt)
+      return 0;
+    return it->second.leaf().i;
+  }
+
+  Step recv_step(Transport* t) {
     auto [msg, nbytes] = t->recv_sized();
     bytes_up_.fetch_add(static_cast<int64_t>(nbytes));
-    return env_outputs_from(msg);
+    Step step;
+    step.env = env_outputs_from(msg);  // throws unless msg is a dict
+    step.server_recv_ns = int_field(msg, "server_recv_ns");
+    step.server_stepped_ns = int_field(msg, "server_stepped_ns");
+    return step;
   }
 
   void loop(int64_t index, const std::string& address, int64_t* progress,
             bool* reconnect_pending) {
+    const int64_t connecting_ns = monotonic_ns();
     std::unique_ptr<Transport> sock =
         shm::connect_transport(address, connect_timeout_s_, max_frame_bytes_);
     if (fault_hooks_) {
@@ -386,7 +450,24 @@ class ActorPool {
     ArrayNest initial_agent_state =
         use_slots_ ? slot_reset_(index) : initial_agent_state_;
 
-    ArrayNest env_outputs = recv_step(sock.get());
+    Step step = recv_step(sock.get());
+    // The shared clock is checked, not assumed. The server stamped the
+    // initial Step after the handshake this thread began at
+    // `connecting_ns` and before this receipt, so on one clock its
+    // reading lies between the two; a reading after the receipt, or
+    // more than a second before the connect began, is another
+    // machine's (a server across TCP). Such a stream counts once and
+    // never observes a wire term: a difference of two clocks is no
+    // duration. (The second is slack, not need. It is taken from the
+    // connect's beginning and not from the receipt because slot_reset_
+    // above, which takes the GIL, lies between the two.)
+    bool wire_terms = false;
+    if (step.server_stepped_ns != 0) {
+      wire_terms = step.server_stepped_ns <= monotonic_ns() &&
+                   step.server_stepped_ns >= connecting_ns - kNsPerSecond;
+      if (!wire_terms) env_clock_unshared_.fetch_add(1);
+    }
+    ArrayNest env_outputs = std::move(step.env);
     // The stream is re-established AND delivering: a granted reconnect
     // retry counts as a completed recovery now — not at grant time, so
     // attempts that die before streaming (a stale socket file, a
@@ -406,10 +487,11 @@ class ActorPool {
     // time, making the resubmitted == shed + expired audit exact.
     Backoff shed_backoff(0.05, 1.0);
     auto abort_shed = [this] { return shutting_down(); };
-    auto shed_compute = [&](ArrayNest inputs) {
+    auto shed_compute = [&](ArrayNest inputs, int64_t* replied_ns) {
       while (true) {
         try {
-          ArrayNest result = inference_batcher_->compute(inputs);
+          ArrayNest result =
+              inference_batcher_->compute(inputs, 600, replied_ns);
           shed_backoff.reset();
           return result;
         } catch (const ShedError&) {
@@ -423,7 +505,7 @@ class ActorPool {
 
     auto compute = [this, index, &shed_compute](
                        const ArrayNest& env, ArrayNest* state,
-                       bool advance) {
+                       bool advance, int64_t* replied_ns) {
       ArrayNest::Dict inputs;
       inputs.emplace("env", env);
       if (use_slots_) {
@@ -431,11 +513,11 @@ class ActorPool {
                                    DType::kI32, static_cast<int32_t>(index))));
         inputs.emplace("advance", ArrayNest(scalar_array<uint8_t>(
                                       DType::kBool, advance ? 1 : 0)));
-        ArrayNest result = shed_compute(ArrayNest(inputs));
+        ArrayNest result = shed_compute(ArrayNest(inputs), replied_ns);
         return normalize_lag(result.dict().at("outputs"));
       }
       inputs.emplace("agent_state", *state);
-      ArrayNest result = shed_compute(ArrayNest(inputs));
+      ArrayNest result = shed_compute(ArrayNest(inputs), replied_ns);
       const auto& d = result.dict();
       if (advance) *state = d.at("agent_state");
       return normalize_lag(d.at("outputs"));
@@ -444,14 +526,18 @@ class ActorPool {
     // Prime the boundary agent output (state advance discarded — the
     // first in-rollout compute re-consumes this env output for real).
     ArrayNest agent_outputs = compute(env_outputs, &agent_state,
-                                      /*advance=*/false);
+                                      /*advance=*/false, nullptr);
 
     std::vector<StepPair> rollout;
     rollout.push_back({env_outputs, agent_outputs});
     ArrayNest rollout_initial_state = initial_agent_state;
 
+    int64_t top_ns = monotonic_ns();
     while (true) {
-      agent_outputs = compute(env_outputs, &agent_state, /*advance=*/true);
+      int64_t replied_ns = 0;
+      agent_outputs = compute(env_outputs, &agent_state, /*advance=*/true,
+                              &replied_ns);
+      const int64_t computed_ns = monotonic_ns();
 
       // Extract the scalar action from outputs["action"] ([1,1]).
       const Array& action_arr =
@@ -463,14 +549,32 @@ class ActorPool {
                          wire::ValueNest(wire::Value::of_string("action")));
       action_msg.emplace("action",
                          wire::ValueNest(wire::Value::of_int(action)));
-      auto sent_at = std::chrono::steady_clock::now();
+      const int64_t sent_ns = monotonic_ns();
       bytes_down_.fetch_add(
           static_cast<int64_t>(sock->send(wire::ValueNest(std::move(action_msg)))));
 
-      env_outputs = recv_step(sock.get());
-      env_rtt_s_.observe(std::chrono::duration<double>(
-                             std::chrono::steady_clock::now() - sent_at)
-                             .count());
+      step = recv_step(sock.get());
+      const int64_t received_ns = monotonic_ns();
+      env_outputs = std::move(step.env);
+      stages_.env_rtt_s.observe(seconds(received_ns - sent_ns));
+      if (step.server_recv_ns != 0 && step.server_stepped_ns != 0) {
+        // A difference of the server's own stamps is good on any clock.
+        stages_.env_step_s.observe(
+            seconds(step.server_stepped_ns - step.server_recv_ns));
+        if (wire_terms && (step.server_recv_ns < sent_ns ||
+                           step.server_stepped_ns > received_ns)) {
+          // Stamps out of order on a clock the initial Step passed for
+          // this machine's: it is not, and no negative term is observed.
+          wire_terms = false;
+          env_clock_unshared_.fetch_add(1);
+        }
+        if (wire_terms) {
+          stages_.env_wire_down_s.observe(
+              seconds(step.server_recv_ns - sent_ns));
+          stages_.env_wire_up_s.observe(
+              seconds(received_ns - step.server_stepped_ns));
+        }
+      }
       ++(*progress);
       count_.fetch_add(1);
       rollout.push_back({env_outputs, agent_outputs});
@@ -484,8 +588,19 @@ class ActorPool {
         // from the last reply.
         rollout_initial_state = use_slots_ ? slot_read_(index) : agent_state;
       }
+
+      const int64_t next_top_ns = monotonic_ns();
+      if (replied_ns != 0)  // 0: a client that hands no instant back
+        stages_.reply_wake_s.observe(seconds(computed_ns - replied_ns));
+      stages_.own_s.observe(seconds((sent_ns - computed_ns) +
+                                    (next_top_ns - received_ns)));
+      stages_.cycle_s.observe(seconds(next_top_ns - top_ns));
+      top_ns = next_top_ns;
     }
   }
+
+  static constexpr int64_t kNsPerSecond = 1000000000;
+  static double seconds(int64_t ns) { return static_cast<double>(ns) * 1e-9; }
 
   // The Python pool's _normalize_lag (runtime/actor_pool.py): central
   // replies carry no policy_lag leaf (their params rebind every update
@@ -557,7 +672,8 @@ class ActorPool {
   std::atomic<int64_t> dead_{0};  // retired actor loops (live_actors())
   std::atomic<int64_t> bytes_up_{0};
   std::atomic<int64_t> bytes_down_{0};
-  HistAccum env_rtt_s_;  // send(action) -> recv_step returned
+  std::atomic<int64_t> env_clock_unshared_{0};
+  StageHistograms stages_;
   std::unique_ptr<FaultHooks> fault_hooks_;  // non-null only when armed
   mutable std::mutex error_mu_;
   std::exception_ptr first_error_;  // guarded-by: error_mu_

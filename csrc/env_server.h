@@ -27,6 +27,7 @@
 #include <vector>
 
 #include "client.h"
+#include "clock.h"
 #include "shm.h"
 #include "wire.h"
 
@@ -173,10 +174,18 @@ class EnvServer {
       }
       hooks = hook_factory_();
       have_hooks = true;
-      sock->send(hooks.initial());
+      // The server's half of an actor's cycle rides on the step message
+      // (ISSUE 66; the same two keys as runtime/env_server.py): when the
+      // action arrived and when the env's step returned, on the
+      // machine's monotonic clock, which the actor pool reads too. The
+      // initial Step carries the second alone: the pool checks by it
+      // that the two clocks are one (actor_pool.h).
+      sock->send(stamped(hooks.initial(), 0, monotonic_ns()));
       while (true) {
         wire::ValueNest action = sock->recv();
-        sock->send(hooks.step(action));
+        const int64_t recv_ns = monotonic_ns();
+        wire::ValueNest step = hooks.step(action);
+        sock->send(stamped(std::move(step), recv_ns, monotonic_ns()));
       }
     } catch (const SocketError&) {
       // client hung up / stop(): normal end of stream
@@ -202,6 +211,18 @@ class EnvServer {
         break;
       }
     }
+  }
+
+  static wire::ValueNest stamped(wire::ValueNest msg, int64_t recv_ns,
+                                 int64_t stepped_ns) {
+    if (!msg.is_dict()) return msg;
+    if (recv_ns != 0)
+      msg.dict().insert_or_assign(
+          "server_recv_ns", wire::ValueNest(wire::Value::of_int(recv_ns)));
+    msg.dict().insert_or_assign(
+        "server_stepped_ns",
+        wire::ValueNest(wire::Value::of_int(stepped_ns)));
+    return msg;
   }
 
   // Join threads whose streams already ended so the vector stays

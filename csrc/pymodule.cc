@@ -1529,7 +1529,7 @@ PyObject* pool_chaos_corrupt_ring(PyActorPool* self, PyObject* args,
 PyObject* pool_telemetry(PyActorPool* self, PyObject*) {
   tbt::ActorPool::Telemetry t = self->pool->telemetry();
   return Py_BuildValue(
-      "{s:L,s:L,s:L,s:L,s:L,s:L,s:L,s:L,s:L}", "env_steps",
+      "{s:L,s:L,s:L,s:L,s:L,s:L,s:L,s:L,s:L,s:L}", "env_steps",
       static_cast<long long>(t.env_steps), "connects",
       static_cast<long long>(t.connects), "reconnects",
       static_cast<long long>(t.reconnects), "batch_retries",
@@ -1538,16 +1538,26 @@ PyObject* pool_telemetry(PyActorPool* self, PyObject*) {
       static_cast<long long>(t.bytes_up), "bytes_down",
       static_cast<long long>(t.bytes_down), "ring_doorbell_waits",
       static_cast<long long>(t.ring_doorbell_waits), "ring_recheck_wakeups",
-      static_cast<long long>(t.ring_recheck_wakeups));
+      static_cast<long long>(t.ring_recheck_wakeups), "env_clock_unshared",
+      static_cast<long long>(t.env_clock_unshared));
 }
 
 // Interval histograms of the actor loops' own stages, keyed by the
 // registry series they fold into (NativeTelemetryFolder). Kept apart
 // from telemetry(), whose values are all cumulative scalars.
 PyObject* pool_stage_histograms(PyActorPool* self, PyObject*) {
-  PyObject* env_rtt = hist_to_py(self->pool->env_rtt_snapshot());
-  if (!env_rtt) return nullptr;
-  return Py_BuildValue("{s:N}", "actor.env_rtt_s", env_rtt);
+  PyObject* out = PyDict_New();
+  if (!out) return nullptr;
+  for (const auto& [name, snapshot] : self->pool->stage_snapshots()) {
+    PyObject* hist = hist_to_py(snapshot);
+    if (!hist || PyDict_SetItemString(out, name, hist) < 0) {
+      Py_XDECREF(hist);
+      Py_DECREF(out);
+      return nullptr;
+    }
+    Py_DECREF(hist);
+  }
+  return out;
 }
 
 PyObject* pool_first_error_message(PyActorPool* self, PyObject*) {
@@ -2223,9 +2233,10 @@ PyMODINIT_FUNC PyInit__tbt_core(void) {
   // Extension API generation (runtime/native.py REQUIRED_API_VERSION):
   // 1 = the ISSUE 14 shed protocol; 2 = the ISSUE 16 serving plane
   // (routers, continuous batching, record_policy_lag); 3 = ISSUE 25's
-  // ActorPool.stage_histograms; 4 = ISSUE 36's gil_wait_histograms.
+  // ActorPool.stage_histograms; 4 = ISSUE 36's gil_wait_histograms;
+  // 5 = ISSUE 66's actor cycle (stage_histograms holds its seven terms).
   // The default-on native runtime refuses stale builds instead of
   // silently serving central-only without admission control.
-  PyModule_AddIntConstant(module, "API_VERSION", 4);
+  PyModule_AddIntConstant(module, "API_VERSION", 5);
   return module;
 }

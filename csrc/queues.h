@@ -22,6 +22,7 @@
 #include <vector>
 
 #include "array.h"
+#include "clock.h"
 #include "nest.h"
 
 namespace tbt {
@@ -98,7 +99,9 @@ struct BatcherTelemetry {
   std::atomic<int64_t> rows{0};
   HistAccum batch_size;
   HistAccum request_wait_s;  // enqueue -> picked into a batch
-  HistAccum request_rtt_s;   // enqueue -> outputs distributed
+  // enqueue -> set_outputs ENTERED: before any row is sliced or any
+  // promise set (the actor's wake from there is actor.reply_wake_s).
+  HistAccum request_rtt_s;
   // Admission-gate accounting (ISSUE 14): same semantics as the Python
   // serving/admission.py series the driver folds these into —
   // admitted (accepted at enqueue), shed (rejected at the depth
@@ -164,7 +167,12 @@ inline ArrayNest batch_nests(const std::vector<ArrayNest>& nests,
 class InferenceClient {
  public:
   virtual ~InferenceClient() = default;
-  virtual ArrayNest compute(ArrayNest inputs, int64_t timeout_s = 600) = 0;
+  // `replied_ns`, where given, receives the instant (monotonic_ns) at
+  // which the batch that served this request entered set_outputs: where
+  // actor.request_rtt_s ends, and where the caller's own account of the
+  // reply (actor.reply_wake_s) starts.
+  virtual ArrayNest compute(ArrayNest inputs, int64_t timeout_s = 600,
+                            int64_t* replied_ns = nullptr) = 0;
   virtual int64_t size() const = 0;
   virtual bool is_closed() const = 0;
   virtual void close() = 0;
@@ -387,8 +395,15 @@ class BatchingQueue {
 
 class DynamicBatcher : public InferenceClient {
  public:
+  // What a request's promise is fulfilled with: its rows of the
+  // outputs, and the instant set_outputs was entered.
+  struct Reply {
+    ArrayNest outputs;
+    int64_t replied_ns = 0;
+  };
+
   struct Request {
-    std::shared_ptr<std::promise<ArrayNest>> promise;
+    std::shared_ptr<std::promise<Reply>> promise;
     int64_t rows;
     // Stage stamps (enqueue -> batch -> reply): set at compute(), read
     // when the batch forms and when outputs are distributed.
@@ -445,6 +460,11 @@ class DynamicBatcher : public InferenceClient {
       if (!any) throw std::invalid_argument("empty output");
       outputs_set_ = true;
       auto now = std::chrono::steady_clock::now();
+      // The same instant on the actors' clock: request_rtt_s ends at
+      // `now`, BEFORE any row is sliced or any promise set; what a row
+      // waits from here until its actor runs again is the actor's to
+      // measure (actor_pool.h, actor.reply_wake_s).
+      const int64_t now_ns = monotonic_ns();
       int64_t offset = 0;
       for (Request& r : requests_) {
         int64_t start = offset, count = r.rows;
@@ -471,7 +491,7 @@ class DynamicBatcher : public InferenceClient {
                   {to_s(r.enqueued_at), to_s(r.batched_at), to_s(now)});
           }
         }
-        r.promise->set_value(std::move(mine));
+        r.promise->set_value(Reply{std::move(mine), now_ns});
         offset += count;
       }
     }
@@ -535,7 +555,8 @@ class DynamicBatcher : public InferenceClient {
   std::shared_ptr<BatcherTelemetry> telemetry() { return telemetry_; }
 
   ArrayNest compute(ArrayNest inputs,
-                    int64_t timeout_s = 600 /* reference: 10 min */) override {
+                    int64_t timeout_s = 600 /* reference: 10 min */,
+                    int64_t* replied_ns = nullptr) override {
     int64_t rows = inputs.front().dim(batch_dim_);
     if (rows > queue_.max_batch_size())
       throw std::invalid_argument("compute() exceeds maximum_batch_size");
@@ -554,7 +575,7 @@ class DynamicBatcher : public InferenceClient {
     // AdmissionController (disarmed runs report no serving.* series).
     if (shed_max_queue_depth_ || deadline_ms_)
       telemetry_->admitted.fetch_add(1);
-    Request req{std::make_shared<std::promise<ArrayNest>>(), rows,
+    Request req{std::make_shared<std::promise<Reply>>(), rows,
                 std::chrono::steady_clock::now()};
     if (deadline_ms_)
       req.deadline = req.enqueued_at +
@@ -571,7 +592,9 @@ class DynamicBatcher : public InferenceClient {
         std::future_status::timeout) {
       throw std::runtime_error("Compute response not ready after timeout");
     }
-    return future.get();
+    Reply reply = future.get();
+    if (replied_ns) *replied_ns = reply.replied_ns;
+    return std::move(reply.outputs);
   }
 
   // Blocks; throws QueueStopped when closed.

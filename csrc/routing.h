@@ -108,12 +108,13 @@ class SliceRouter : public InferenceClient {
     return out;
   }
 
-  ArrayNest compute(ArrayNest inputs, int64_t timeout_s = 600) override {
+  ArrayNest compute(ArrayNest inputs, int64_t timeout_s = 600,
+                    int64_t* replied_ns = nullptr) override {
     size_t idx = route(inputs);
     // Counted at routing time like the Python router: the series
     // answers "where is traffic going", sheds included.
     requests_[idx].fetch_add(1);
-    return slices_[idx]->compute(std::move(inputs), timeout_s);
+    return slices_[idx]->compute(std::move(inputs), timeout_s, replied_ns);
   }
 
   int64_t size() const override {
@@ -181,12 +182,13 @@ class ReplicaRouter : public InferenceClient {
   int64_t replica_requests() const { return replica_requests_.load(); }
   int64_t central_requests() const { return central_requests_.load(); }
 
-  ArrayNest compute(ArrayNest inputs, int64_t timeout_s = 600) override {
+  ArrayNest compute(ArrayNest inputs, int64_t timeout_s = 600,
+                    int64_t* replied_ns = nullptr) override {
     if (serving_ok_.load() && !replica_->is_closed()) {
       try {
         // `inputs` stays intact for the fallback leg: nest copies are
         // shallow (leaves share buffers), so this costs pointers.
-        ArrayNest out = replica_->compute(inputs, timeout_s);
+        ArrayNest out = replica_->compute(inputs, timeout_s, replied_ns);
         // Counted on SUCCESS only: a fallen-back request must land in
         // exactly one routing series, or the two sum past the total —
         // the Python router's accounting contract.
@@ -201,7 +203,7 @@ class ReplicaRouter : public InferenceClient {
       }
     }
     central_requests_.fetch_add(1);
-    return central_->compute(std::move(inputs), timeout_s);
+    return central_->compute(std::move(inputs), timeout_s, replied_ns);
   }
 
   int64_t size() const override {

@@ -13,8 +13,10 @@
 
 #include <sys/socket.h>
 
+#include "actor_pool.h"
 #include "array.h"
 #include "client.h"
+#include "clock.h"
 #include "env_server.h"
 #include "nest.h"
 #include "queues.h"
@@ -409,6 +411,43 @@ static void test_batcher_telemetry() {
   CHECK(telemetry_bucket_index(0.0) == 0);
   batcher.close();
   std::printf("batcher telemetry ok\n");
+}
+
+// compute() hands its caller the instant set_outputs was ENTERED
+// (ISSUE 66): where request_rtt_s ends and the caller's account of the
+// reply starts. Through a router it is the serving batcher's.
+static void test_batcher_reply_instant() {
+  auto batcher = std::make_shared<DynamicBatcher>(0, 1, 64, 20);
+  SliceRouter router({batcher});
+  for (InferenceClient* client :
+       {static_cast<InferenceClient*>(batcher.get()),
+        static_cast<InferenceClient*>(&router)}) {
+    int64_t replied_ns = 0, returned_ns = 0;
+    const int64_t before_ns = monotonic_ns();
+    std::thread producer([&] {
+      client->compute(ArrayNest(make_array(DType::kI64, {1, 2}, 3)), 600,
+                      &replied_ns);
+      returned_ns = monotonic_ns();
+    });
+    auto batch = batcher->get_batch();
+    const int64_t entering_ns = monotonic_ns();
+    batch->set_outputs(batch->inputs());
+    const int64_t left_ns = monotonic_ns();
+    producer.join();
+    CHECK(before_ns <= entering_ns);
+    CHECK(entering_ns <= replied_ns);
+    CHECK(replied_ns <= left_ns);
+    CHECK(replied_ns <= returned_ns);
+  }
+  // A caller that asks for none gets none (the Python binding's path).
+  std::thread producer([&batcher] {
+    batcher->compute(ArrayNest(make_array(DType::kI64, {1, 2}, 3)));
+  });
+  auto batch = batcher->get_batch();
+  batch->set_outputs(batch->inputs());
+  producer.join();
+  batcher->close();
+  std::printf("batcher reply instant ok\n");
 }
 
 // splitmix64 slice hash (ISSUE 16): the well-known finalizer vector for
@@ -946,6 +985,142 @@ void test_env_server() {
   std::printf("env server ok\n");
 }
 
+// A step message as an env server's hooks make it: the env's six keys.
+static wire::ValueNest env_step_message(int64_t t) {
+  wire::ValueNest::Dict d;
+  d.emplace("type", wire::ValueNest(wire::Value::of_string("step")));
+  auto put = [&d](const char* key, Array a) {
+    d.emplace(key, wire::ValueNest(wire::Value::of(std::move(a))));
+  };
+  put("frame", make_array(DType::kU8, {4, 4, 1}, t % 255));
+  put("reward", make_array(DType::kF32, {}, 0));
+  put("done", make_array(DType::kBool, {}, 0));
+  put("episode_step", make_array(DType::kI32, {}, 0));
+  put("episode_return", make_array(DType::kF32, {}, 0));
+  put("last_action", make_array(DType::kI32, {}, 0));
+  return wire::ValueNest(std::move(d));
+}
+
+// An InferenceClient that holds its caller back after the reply: what a
+// late wake or a busy core does to an actor thread.
+class HeldBackClient : public InferenceClient {
+ public:
+  HeldBackClient(std::shared_ptr<InferenceClient> inner, int hold_ms)
+      : inner_(std::move(inner)), hold_ms_(hold_ms) {}
+  ArrayNest compute(ArrayNest inputs, int64_t timeout_s = 600,
+                    int64_t* replied_ns = nullptr) override {
+    ArrayNest out = inner_->compute(std::move(inputs), timeout_s, replied_ns);
+    std::this_thread::sleep_for(std::chrono::milliseconds(hold_ms_));
+    return out;
+  }
+  int64_t size() const override { return inner_->size(); }
+  bool is_closed() const override { return inner_->is_closed(); }
+  void close() override { inner_->close(); }
+
+ private:
+  std::shared_ptr<InferenceClient> inner_;
+  const int hold_ms_;
+};
+
+// One actor cycle (ISSUE 66): eight actor loops against the C++ env
+// server whose step takes 3 ms, their threads held back 2 ms after each
+// reply. The seven stage histograms cover the same iterations; the
+// env's three terms sum to env_rtt_s; reply_wake_s sees the hold and
+// request_rtt_s does not; the terms add up to the cycle; a snapshot
+// starts a fresh interval.
+void test_actor_cycle() {
+  static constexpr int kActors = 8, kRounds = 5, kStepMs = 3, kHoldMs = 2;
+  std::string address = "unix:/tmp/tbt_test_actor_cycle";
+  auto factory = [] {
+    auto t = std::make_shared<int64_t>(0);
+    StreamHooks hooks;
+    hooks.initial = [t] { return env_step_message(*t); };
+    hooks.step = [t](const wire::ValueNest&) {
+      std::this_thread::sleep_for(std::chrono::milliseconds(kStepMs));
+      return env_step_message(++*t);
+    };
+    hooks.close = [] {};
+    return hooks;
+  };
+  EnvServer server(address, factory);
+  std::thread server_thread([&server] { server.run(); });
+
+  auto batcher = std::make_shared<DynamicBatcher>(1, kActors, kActors,
+                                                  std::nullopt);
+  auto learner_queue = std::make_shared<ActorPool::LearnerQueue>(
+      1, 1, 1, std::nullopt, std::nullopt, false);
+  ActorPool pool(/*unroll_length=*/1000, learner_queue,
+                 std::make_shared<HeldBackClient>(batcher, kHoldMs),
+                 std::vector<std::string>(kActors, address),
+                 ArrayNest(make_array(DType::kI64, {1, 1}, 0)),
+                 /*connect_timeout_s=*/10);
+  std::thread pool_thread([&pool] { pool.run(); });
+
+  auto answer = [&batcher] {
+    auto batch = batcher->get_batch();  // blocks for all eight rows
+    CHECK(batch->size() == kActors);
+    ArrayNest::Dict outputs;
+    outputs.emplace("action",
+                    ArrayNest(make_array(DType::kI32, {1, kActors}, 0)));
+    ArrayNest::Dict reply;
+    reply.emplace("outputs", ArrayNest(std::move(outputs)));
+    reply.emplace("agent_state",
+                  ArrayNest(make_array(DType::kI64, {1, kActors}, 0)));
+    batch->set_outputs(ArrayNest(std::move(reply)));
+  };
+  auto all_waiting = [&batcher] {
+    while (batcher->size() < kActors)
+      std::this_thread::sleep_for(std::chrono::milliseconds(1));
+  };
+  answer();  // the priming requests: in no cycle
+  all_waiting();
+  batcher->telemetry()->request_rtt_s.snapshot(true);
+  for (const auto& [name, h] : pool.stage_snapshots()) CHECK(h.count == 0);
+  for (int i = 0; i < kRounds; ++i) {
+    answer();
+    all_waiting();  // every actor has finished cycle i
+  }
+
+  std::map<std::string, HistSnapshot> stages;
+  for (auto& [name, h] : pool.stage_snapshots()) stages.emplace(name, h);
+  CHECK(stages.size() == 7);
+  for (const auto& [name, h] : stages) CHECK(h.count == kActors * kRounds);
+  HistSnapshot rtt = batcher->telemetry()->request_rtt_s.snapshot(true);
+  CHECK(rtt.count == kActors * kRounds);
+  // snapshot(reset) started a fresh interval in every accumulator.
+  for (const auto& [name, h] : pool.stage_snapshots()) {
+    CHECK(h.count == 0 && h.total == 0.0 && h.buckets.empty());
+  }
+
+  const HistSnapshot& env_rtt = stages.at("actor.env_rtt_s");
+  const HistSnapshot& step = stages.at("actor.env_step_s");
+  const HistSnapshot& down = stages.at("actor.env_wire_down_s");
+  const HistSnapshot& up = stages.at("actor.env_wire_up_s");
+  CHECK(step.min >= kStepMs * 1e-3);
+  CHECK(down.min > 0.0 && up.min > 0.0);
+  double parts = down.total + step.total + up.total;
+  CHECK(std::abs(parts - env_rtt.total) <= 1e-6 * env_rtt.total);
+
+  const HistSnapshot& wake = stages.at("actor.reply_wake_s");
+  const HistSnapshot& own = stages.at("actor.own_s");
+  const HistSnapshot& cycle = stages.at("actor.cycle_s");
+  CHECK(wake.min >= kHoldMs * 1e-3);
+  // request_rtt_s ended before the hold: a cycle is the step, the hold
+  // and little else, and the sum below would pass it otherwise.
+  double sum = rtt.total + wake.total + own.total + env_rtt.total;
+  CHECK(sum <= cycle.total);
+  CHECK(cycle.total - sum <= 0.01 * cycle.total);
+  CHECK(pool.telemetry().env_clock_unshared == 0);
+
+  batcher->close();
+  learner_queue->close();
+  pool_thread.join();
+  server.stop();
+  server_thread.join();
+  server.join_all();
+  std::printf("actor cycle ok\n");
+}
+
 int main(int argc, char** argv) {
   // Optional substring filter (argv[1]): run only matching tests. Lets
   // the sanitizer smoke tests exercise the codec/queue paths in
@@ -966,6 +1141,7 @@ int main(int argc, char** argv) {
   if (want("queue_stress")) { test_queue_stress(); ++ran; }
   if (want("dynamic_batcher")) { test_dynamic_batcher(); ++ran; }
   if (want("batcher_telemetry")) { test_batcher_telemetry(); ++ran; }
+  if (want("batcher_reply_instant")) { test_batcher_reply_instant(); ++ran; }
   if (want("routing_hash")) { test_routing_hash(); ++ran; }
   if (want("routing_slice")) { test_slice_router(); ++ran; }
   if (want("routing_replica")) { test_replica_router(); ++ran; }
@@ -978,6 +1154,7 @@ int main(int argc, char** argv) {
   if (want("shm_ring_transport")) { test_shm_ring_transport(); ++ran; }
   if (want("shm_ring_stress")) { test_shm_ring_stress(); ++ran; }
   if (want("env_server")) { test_env_server(); ++ran; }
+  if (want("actor_cycle")) { test_actor_cycle(); ++ran; }
   if (ran == 0) {
     std::fprintf(stderr, "no tests match filter '%s'\n", filter);
     return 1;

@@ -9,8 +9,10 @@ kernel's account of every thread role.
 
 It runs the cell through the benchmark's own driver (`perfbench.run`'s
 steps, `--trace 1`) and prints, after the driver's lines, one JSON line
-`{"account": ...}` and the same as a table; `--out` also writes the
-JSON there. `--cpu_rehearsal` runs a tiny version on the CPU, to see
+`{"account": ...}` and the same as a table, under it one actor cycle
+term by term (ISSUE 66: the measured cycle, the four terms that make it
+up, what each is made of, and what is left over); `--out` also writes
+the JSON there. `--cpu_rehearsal` runs a tiny version on the CPU, to see
 that every instrument reports; its numbers mean nothing.
 """
 
@@ -94,6 +96,92 @@ def gil_account(facts):
     return out
 
 
+# One actor cycle, term by term: (term, histogram, its parts). The
+# serving pair's spans are means a BATCH, which every row of the batch
+# waits out; the actors' terms are means a frame.
+CYCLE_TERMS = (
+    ("request_rtt", "actor.request_rtt_s", (
+        ("queue wait", "inference.request_wait_s"),
+        ("prep", "inference.prep_s"),
+        ("dispatch", "inference.dispatch_s"),
+        ("hand-over wait", "inference.handover_wait_s"),
+        ("reply", "inference.reply_s"),
+    )),
+    ("reply_wake", "actor.reply_wake_s", ()),
+    ("own", "actor.own_s", ()),
+    ("env_rtt", "actor.env_rtt_s", (
+        ("wire down", "actor.env_wire_down_s"),
+        ("env step", "actor.env_step_s"),
+        ("wire up", "actor.env_wire_up_s"),
+    )),
+)
+
+
+def cycle_account(facts, actors):
+    """An actor's cycle from the window's histograms, in ms a frame:
+    the cycle as measured (loop top to loop top) and as the rate has it
+    (actors over env frames a second), its four terms with the
+    remainder (the enqueue's own microseconds), and what each term is
+    made of with its own `rest` (request_rtt's: the gaps between the
+    serving pair's spans, less the end of `reply` that lies after
+    set_outputs is entered, where request_rtt ends; env_rtt's: 0 when
+    every stream shares the machine's clock). None where the run has no
+    `actor.cycle_s` (an extension from before ISSUE 66)."""
+    hists = facts["histograms"]
+
+    def mean_ms(name):
+        hist = hists.get(name)
+        if not hist or not hist["count"]:
+            return None
+        return 1e3 * hist["total"] / hist["count"]
+
+    cycle = mean_ms("actor.cycle_s")
+    if cycle is None:
+        return None
+    rows, accounted = [], 0.0
+    for term, name, parts in CYCLE_TERMS:
+        whole = mean_ms(name)
+        rows.append({"term": term, "ms": whole, "indent": 0})
+        accounted += whole or 0.0
+        known = [(part, mean_ms(hist)) for part, hist in parts]
+        rows.extend(
+            {"term": part, "ms": ms, "indent": 1} for part, ms in known
+        )
+        if whole is not None and any(ms is not None for _, ms in known):
+            rest = whole - sum(ms or 0.0 for _, ms in known)
+            rows.append({"term": "rest", "ms": rest, "indent": 1})
+    frames_per_s = (
+        facts["counters"]["pool.env_steps"] / facts["values"]["window_s"]
+    )
+    return {
+        "cycle_ms": cycle,
+        "cycle_by_rate_ms": 1e3 * actors / frames_per_s,
+        "frames_per_s": frames_per_s,
+        "terms": rows,
+        "remainder_ms": cycle - accounted,
+        "env_clock_unshared": facts["counters"].get(
+            "actor.env_clock_unshared"
+        ),
+    }
+
+
+def render_cycle(cycle):
+    lines = [
+        "actor cycle, ms a frame        "
+        f"{cycle['cycle_ms']:>9.4f}   ({cycle['cycle_by_rate_ms']:.4f} by "
+        f"the rate, {cycle['frames_per_s']:.1f} frames/s)"
+    ]
+    for row in cycle["terms"]:
+        value = "        -" if row["ms"] is None else f"{row['ms']:>9.4f}"
+        name = "  " * (1 + row["indent"]) + row["term"]
+        lines.append(f"{name:<30} {value}")
+    lines.append(f"{'  remainder':<30} {cycle['remainder_ms']:>9.4f}")
+    lines.append(
+        f"{'  env_clock_unshared':<30} {cycle['env_clock_unshared']!s:>9}"
+    )
+    return "\n".join(lines)
+
+
 def render(account):
     lines = ["span                       count   wall ms    cpu ms  "
              "gil-wait ms   rest ms"]
@@ -118,6 +206,8 @@ def render(account):
             f"{name:<16} {r['count']:>7} {r['mean_us']:>10.1f} "
             f"{r['total_s']:>10.3f}"
         )
+    if account.get("cycle"):
+        lines.append(render_cycle(account["cycle"]))
     return "\n".join(lines)
 
 
@@ -175,6 +265,7 @@ def main(argv=None) -> int:
         "spans": span_account(facts),
         "roles": role_account(facts),
         "gil_waits": gil_account(facts),
+        "cycle": cycle_account(facts, int(cell.traffic["num_actors"])),
     }
     print(json.dumps({"account": account}), flush=True)
     print(render(account), flush=True)

@@ -354,6 +354,48 @@ def test_full_hand_over_blocks_the_launcher():
     serving.join()
 
 
+def test_hand_over_wait_is_the_batches_behind_a_held_replier():
+    """A replier held in flush (a device_get that has not landed) with
+    two batches behind it, one handed over and one whose launcher
+    blocks on the full hand-over: both wait out the hold, which
+    handover_wait_s sees (the entry's stamp is taken before the put)
+    and their own reply_s does not. The hold is 30 ms and not the 3 ms
+    the replier spends on a batch, to stand clear of a loaded host's
+    scheduling."""
+    hold_s = 0.03
+    batcher = _batcher(max_batch=1)
+    table = FakeTable()
+    table.fetch_gate = threading.Event()
+    serving = Serving("handover", batcher, state_table=table, max_batch=1)
+    producers = Producers(batcher, _slot_request)
+    producers.send(0)
+    assert table.fetch_entered.wait(WAIT_S)  # reply 0 is in the replier
+    producers.send(1)
+    _wait_for(lambda: table.steps == 2)
+    producers.send(2)
+    _wait_for(lambda: table.steps == 3)  # both dispatched and stamped
+    time.sleep(hold_s)
+    table.fetch_gate.set()
+    producers.join()
+    assert len(producers.results) == 3 and not producers.errors
+    reg = telemetry.get_registry()
+    # A producer wakes inside set_outputs, a moment before its reply's
+    # span closes.
+    _wait_for(lambda: reg.histogram("handover.reply_s").count == 3)
+    waited = reg.histogram("handover.handover_wait_s").merged()
+    replied = reg.histogram("handover.reply_s").merged()
+    assert waited.count == replied.count == 3
+    # Batches 1 and 2 each waited out the hold; batch 0 found the
+    # replier idle.
+    assert waited.total >= 2 * hold_s
+    assert waited.min < hold_s / 3
+    # The hold is inside reply 0 (its fetch) and in no other reply.
+    assert replied.max >= hold_s
+    assert replied.total - replied.max < hold_s
+    batcher.close()
+    serving.join()
+
+
 def test_two_pairs_on_one_batcher_answer_every_request():
     """--num_inference_threads 2: two launcher/replier pairs drain one
     batcher; every producer gets its own rows, over many rounds, with

@@ -31,7 +31,6 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from torchbeast_tpu import telemetry
 from torchbeast_tpu.envs.environment import Environment
 from torchbeast_tpu.runtime import transport as transport_lib
 from torchbeast_tpu.runtime import wire
@@ -65,10 +64,23 @@ def _die_with_parent() -> None:
         raise OSError(ctypes.get_errno(), "prctl(PR_SET_PDEATHSIG)")
 
 
-def _step_to_message(step) -> dict:
+def _step_to_message(step, stepped_ns: int, recv_ns: int = 0) -> dict:
+    """`stepped_ns`, `recv_ns`: the server's half of an actor's cycle,
+    carried back on the message as two plain integers (ISSUE 66):
+    `time.monotonic_ns()` when the action arrived (the initial Step has
+    none) and when the env returned from `step` (or `initial`). On Linux
+    that clock is the machine's, the same number in every process, so
+    the native actor pool subtracts its own stamps from these
+    (csrc/actor_pool.h: actor.env_wire_down_s, env_step_s,
+    env_wire_up_s) with nothing to synchronise; a client that knows
+    neither key takes the env's keys and ignores them."""
     # 0-d arrays (not python scalars) so dtypes survive the wire exactly:
     # reward stays float32, done bool, counters int32.
-    return {"type": "step", **{k: np.asarray(v) for k, v in step.items()}}
+    msg = {"type": "step", **{k: np.asarray(v) for k, v in step.items()}}
+    msg["server_stepped_ns"] = stepped_ns
+    if recv_ns:
+        msg["server_recv_ns"] = recv_ns
+    return msg
 
 
 class EnvServer:
@@ -122,17 +134,10 @@ class EnvServer:
         self._ring_names = {}  # guarded-by: self._conns_lock
         self._conns_lock = threading.Lock()
         self._running = False  # guarded-by: self._conns_lock
-        # NB: env servers usually run as separate processes, so these
-        # land in each server's OWN process registry (the learner-side
-        # mirror lives in ActorPool's wire.bytes_* counters); a stream
-        # child's bytes and step times land in its own copy.
-        reg = telemetry.get_registry()
-        self._tm_conns = reg.gauge("env_server.connections")
-        self._tm_children = reg.gauge("env_server.stream_processes")
-        self._tm_child_exits = reg.counter("env_server.stream_exits")
-        self._tm_bytes_in = reg.counter("env_server.bytes_in")
-        self._tm_bytes_out = reg.counter("env_server.bytes_out")
-        self._tm_step_s = reg.histogram("env_server.env_step_s")
+        # No instrument of its own: a server's registry (a listener's,
+        # a stream child's) is written nowhere. Its step time rides back
+        # on the step message (_step_to_message) and its bytes are the
+        # client's wire.bytes_up / bytes_down.
 
     def run(self):
         """Bind and serve until stop() (reference Server.run blocks too,
@@ -223,8 +228,6 @@ class EnvServer:
                 self._children.add(pid)
                 if rings is not None:
                     self._ring_names[pid] = tuple(r.name for r in rings)
-                self._tm_children.set(len(self._children))
-                self._tm_conns.set(len(self._children))
             for ring in rings or ():
                 ring.detach()  # the child's to unlink now
         except OSError:
@@ -276,9 +279,6 @@ class EnvServer:
             with self._conns_lock:
                 self._children.discard(pid)
                 names = self._ring_names.pop(pid, ())
-                self._tm_children.set(len(self._children))
-                self._tm_conns.set(len(self._children))
-            self._tm_child_exits.inc()
             for name in names:
                 if transport_lib.unlink_segment(name):
                     log.warning(
@@ -407,22 +407,21 @@ class EnvServer:
             # env deps on the learner host).
             from torchbeast_tpu.envs import num_actions_of
 
-            initial = _step_to_message(env.initial())
+            step = env.initial()
+            initial = _step_to_message(step, time.monotonic_ns())
             initial["num_actions"] = num_actions_of(raw_env)
-            with self._conns_lock:
-                self._tm_conns.set(len(self._conns))
-            self._tm_bytes_out.inc(stream.send(initial))
+            stream.send(initial)
             while True:
-                msg, nbytes = stream.recv_sized()
+                msg = stream.recv()
+                recv_ns = time.monotonic_ns()
                 if msg is None:
                     break  # client hung up
-                self._tm_bytes_in.inc(nbytes)
                 if msg.get("type") != "action":
                     raise wire.WireError(f"Expected action, got {msg!r}")
-                t0 = time.perf_counter()
                 step = env.step(int(msg["action"]))
-                self._tm_step_s.observe(time.perf_counter() - t0)
-                self._tm_bytes_out.inc(stream.send(_step_to_message(step)))
+                stream.send(
+                    _step_to_message(step, time.monotonic_ns(), recv_ns)
+                )
         except (wire.WireError, ConnectionError, BrokenPipeError,
                 TimeoutError) as e:
             log.debug("Stream ended: %s", e)
@@ -450,7 +449,6 @@ class EnvServer:
                 # stream.close() unlinked the rings; drop them from the
                 # stop() sweep's ledger.
                 self._ring_names.pop(conn, None)
-                self._tm_conns.set(len(self._conns))
 
 
 def serve_once(env_init: Callable, address: str):

@@ -31,6 +31,7 @@ fetches each batch's outputs and answers its actors.
 import logging
 import queue
 import threading
+import time
 from typing import Any, Callable, List
 
 import numpy as np
@@ -215,9 +216,12 @@ def inference_loop(
     # bucket, padding), dispatch (async — the time to hand XLA the
     # program, not device compute) — and reply covers the replier's
     # work on a batch (the device fetch + row slicing + set_outputs
-    # actors actually wait on; its wait for the next hand-over is in no
-    # span). Each is a histogram `<span>_s` and, on the profiler's
-    # clock, `pb:<span>`; resolved once, used every batch.
+    # actors actually wait on). Each is a histogram `<span>_s` and, on
+    # the profiler's clock, `pb:<span>`; resolved once, used every
+    # batch. Between dispatch and reply a batch waits in the hand-over
+    # (a full one, and a replier still answering the batch before):
+    # the histogram handover_wait_s, no span, since the reply span opens
+    # right after and names the gap already.
     _reg = telemetry.get_registry()
     _tracer = telemetry.get_tracer()
 
@@ -235,6 +239,7 @@ def inference_loop(
     # Whether the split engages: the launches made while a reply was
     # still outstanding (handed over or in the replier's hands).
     _c_overlapped = _reg.counter(f"{telemetry_prefix}.overlapped_dispatches")
+    _h_handover = _reg.histogram(f"{telemetry_prefix}.handover_wait_s")
     # A Python DynamicBatcher with a telemetry_name already observes
     # inference.batch_size per dequeued batch — observing here too
     # would double-count it. The loop keeps that role only for
@@ -278,8 +283,10 @@ def inference_loop(
             entry = handover.get()
             if entry is None:
                 return
+            handed_at, dispatched = entry
+            _h_handover.observe(time.perf_counter() - handed_at)
             try:
-                flush(entry)
+                flush(dispatched)
             finally:
                 handover.task_done()
 
@@ -384,7 +391,9 @@ def inference_loop(
                 log.exception("Inference batch failed; continuing")
                 continue
             # Dispatched (async): the reply is the replier's from here.
-            handover.put((batch, outputs, new_state, n, annotate))
+            handover.put(
+                (time.perf_counter(), (batch, outputs, new_state, n, annotate))
+            )
     finally:
         # Every exit: the replier answers (or fails) what was
         # dispatched before the stop mark reaches it, then ends.

@@ -12,10 +12,14 @@ core stamps enqueue->batch->reply per request and counts wire bytes /
 env steps / queue intake in-process; each driver monitor tick folds that
 interval's aggregates into the process-wide telemetry registry under the
 SAME series names the Python runtime writes (wire.bytes_up/down,
-actor.env_steps/connects/request_rtt_s/env_rtt_s, recovery.actor_reconnects/
+actor.env_steps/connects/request_rtt_s, recovery.actor_reconnects/
 batch_retries, inference.request_wait_s, learner_queue.items_in/
 dequeue_wait_s/batch_size) — so native runs emit a telemetry.jsonl
-indistinguishable in schema from Python-runtime runs. Histogram folds
+indistinguishable in schema from Python-runtime runs, and beside them
+the terms of an actor's cycle that only the C++ pool stamps
+(actor.env_rtt_s cut into env_wire_down_s + env_step_s + env_wire_up_s
+by the env server's two stamps on the step message, actor.reply_wake_s,
+actor.own_s, actor.cycle_s; csrc/actor_pool.h StageHistograms). Histogram folds
 are exact: the C++ side accumulates into the same log-bucket geometry as
 telemetry/metrics.py (csrc/queues.h telemetry_bucket_index) and
 snapshots reset per interval. Sampled per-request spans (ISSUE 12)
@@ -69,8 +73,10 @@ def available() -> bool:
 # runtime falls back to Python instead; 3 = ISSUE 25's
 # ActorPool.stage_histograms (actor.env_rtt_s), without which the fold
 # would leave that series silently empty; 4 = ISSUE 36's
-# gil_wait_histograms (host.gil_wait_s.<site>), likewise.
-REQUIRED_API_VERSION = 4
+# gil_wait_histograms (host.gil_wait_s.<site>), likewise; 5 = ISSUE
+# 66's actor cycle (stage_histograms holds the cycle's seven terms and
+# telemetry() env_clock_unshared), likewise.
+REQUIRED_API_VERSION = 5
 
 
 def gap_reason(core=None) -> Optional[str]:
@@ -179,10 +185,8 @@ class NativeTelemetryFolder:
         # _ring_instruments), so mixed-runtime runs aggregate.
         self._c_ring_waits = registry.counter("ring.doorbell_waits")
         self._c_ring_rechecks = registry.counter("ring.recheck_wakeups")
+        self._c_clock_unshared = registry.counter("actor.env_clock_unshared")
         self._h_rtt = registry.histogram("actor.request_rtt_s")
-        # The actor loops' own stage (pool.stage_histograms): the rest
-        # of an actor's step beside actor.request_rtt_s.
-        self._h_env_rtt = registry.histogram("actor.env_rtt_s")
         self._h_request_wait = registry.histogram("inference.request_wait_s")
         # Serving-tier fold (ISSUE 14): the C++ batcher gates admission
         # and deadline expiry in-process; its counters land on the SAME
@@ -356,10 +360,15 @@ class NativeTelemetryFolder:
                     self._c_resubmits, "shed_resubmits",
                     p.get("shed_resubmits", 0),
                 )
-                self._fold_hist(
-                    self._h_env_rtt,
-                    self._pool.stage_histograms()["actor.env_rtt_s"],
+                self._inc_delta(
+                    self._c_clock_unshared, "env_clock_unshared",
+                    p.get("env_clock_unshared", 0),
                 )
+                # The actor loops' own stages, by series name: with
+                # actor.request_rtt_s the terms of an actor's cycle
+                # (csrc/actor_pool.h StageHistograms).
+                for name, snap in self._pool.stage_histograms().items():
+                    self._fold_hist(self._registry.histogram(name), snap)
             folded_delay = False
             for key, b_obj in self._batcher_sources():
                 folded_delay |= self._fold_batcher(key, b_obj)
