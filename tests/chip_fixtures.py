@@ -70,6 +70,19 @@ def struct(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def assert_conv_kernels(text, layers):
+    """A cell's compiled `text` holds ops/short_conv.py's kernels for
+    the `layers` that call `conv_over_episodes` (PR 67): the forward
+    kernel twice a layer (the rematerialised block's second forward
+    calls it again), the backward's once."""
+    for kernel, calls in (
+        ("short_conv_forward", 2 * layers), ("short_conv_backward", layers),
+    ):
+        assert len(re.findall(
+            r'custom_call_target="tpu_custom_call"[^\n]*' + kernel, text
+        )) == calls, kernel
+
+
 def assert_scan_kernels(text, shapes, mixers, chunks, state_elements):
     """A cell's compiled `text` holds ops/ssd_scan.py's kernels for its
     `mixers` Mamba-2 layers (PR 65): the forward kernel twice a layer

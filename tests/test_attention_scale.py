@@ -119,14 +119,18 @@ def test_the_fused_pass_under_the_body_takes_the_callers_scale(monkeypatch):
 # `git archive` of that commit. A later PR that changes one of these
 # programs on purpose computes its own: Nemotron-3's two are PR 65's,
 # whose mixers sow one more stat (`ssm_kernel_applications`) and norm
-# the gate's groups as slices (`gated_group_norm`).
+# the gate's groups as slices (`gated_group_norm`). All three are PR
+# 67's, whose layers that call `conv_over_episodes` sow one more stat
+# (`conv_kernel_applications`) and nothing else: with those four sows
+# taken out the three programs hash to PR 65's (087c85731cee531b,
+# 4c5c6bbe544f47dc, 58ee18004b2effdd).
 PARENTS = {
     # Nemotron-3's blocks call the two mixers; the dense body.
-    ("nemotron3", False): "087c85731cee531b",
+    ("nemotron3", False): "83b176739ada4716",
     # Heads of 64 through the fused pass, rematerialised: LFM2's.
-    ("lfm2", True): "4c5c6bbe544f47dc",
+    ("lfm2", True): "f9b65d9d7f691c19",
     # Heads of 128 through the fused pass, the mixers' caller.
-    ("nemotron3", True): "58ee18004b2effdd",
+    ("nemotron3", True): "3c43e1f27612f5bd",
 }
 _FUSED = {"lfm2": dict(head_dim=64), "nemotron3": dict(head_dim=128)}
 
@@ -158,7 +162,7 @@ def test_lowered_updates_are_the_parents(family, fused, monkeypatch):
     byte for byte: Nemotron-3 through the mixers cut out of its blocks
     (its parameter tree is then the parent's too: the text lists every
     leaf's shape in the tree's order), and a fused-pass family with the
-    argument absent (Nemotron-3's two programs as PR 65 left them:
+    argument absent (the three programs as PRs 65 and 67 left them:
     `PARENTS`)."""
     if fused:
         monkeypatch.setattr(attention, "FUSED_SCORE_BYTES", 1)

@@ -1,8 +1,8 @@
 """Compile for the chip, without the chip (tests/chip_fixtures.py):
 `granite4_policy.learner`'s whole update, one AOT compile of the real
-cell, and the Mamba-2 scan's two kernels alone at shapes they admit
-beside the two cells'. A file of its own: tests/chip_fixtures.py says
-why.
+cell, and the Mamba-2 scan's two kernels and the short convolution's
+two alone at shapes they admit beside the cells'. A file of its own:
+tests/chip_fixtures.py says why.
 """
 
 import json
@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from tests.chip_fixtures import (  # noqa: F401 (fixtures)
     NUM_ACTIONS,
+    assert_conv_kernels,
     assert_scan_kernels,
     on as _on,
     one_chip,
@@ -25,7 +26,7 @@ from tests.chip_fixtures import (  # noqa: F401 (fixtures)
 )
 from torchbeast_tpu import learner as learner_lib
 from torchbeast_tpu.models import nemotron3
-from torchbeast_tpu.ops import ssd_scan
+from torchbeast_tpu.ops import short_conv, ssd_scan
 
 
 @pytest.mark.parametrize(
@@ -79,6 +80,50 @@ def test_ssd_scan_kernels_compile_for_v5e(
     assert len(calls) == 2, len(calls)
     assert sum("ssd_scan_forward" in call for call in calls) == 1
     assert sum("ssd_scan_backward" in call for call in calls) == 1
+
+
+@pytest.mark.parametrize(
+    "rows, steps, channels, taps, bias",
+    [
+        (2, 8, 128, 2, True),  # one sublane tile, one lane tile, one turn
+        (2, 80, 384, 3, False),  # --unroll_length 80: turns of 16 steps
+        (1, 1000, 128, 4, True),  # turns of 8 steps
+        (2, 24, 17 * 128, 8, True),  # a tail of seven steps: all the tile
+        (1, 8192, 256, 4, False),  # a lane tile of a row is a cell's bytes
+        (8, 512, 4352, 4, True),  # Granite's cell
+        (16, 256, 8192, 4, False),  # Qwen3-Next's
+    ],
+)
+def test_short_conv_kernels_compile_for_v5e(
+    one_chip, monkeypatch, rows, steps, channels, taps, bias
+):
+    """The check interpret mode cannot make, at shapes `short_conv.
+    kernels_apply` admits (the five cells' are in the whole updates):
+    `conv_over_episodes`, value, new tail and every gradient, compiles
+    for the chip's compiler with unrolls of one sublane tile and of
+    turns of 8 and 16 steps, two to eight taps, one lane tile a cell
+    and thirty-two; each kernel is one Mosaic call."""
+    assert short_conv.kernels_apply(steps, channels, taps)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(inputs, tail, weights, offset, done):
+        conv, new_tail = nemotron3.conv_over_episodes(
+            inputs, tail, done, weights, offset if bias else None
+        )
+        return jnp.sum(conv * conv) + jnp.sum(new_tail)
+
+    traced = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)))
+    text = traced.lower(
+        _struct(one_chip, (rows, steps, channels)),
+        _struct(one_chip, (taps - 1, rows, channels)),
+        _struct(one_chip, (taps, channels)),
+        _struct(one_chip, (channels,)),
+        _struct(one_chip, (rows, steps), jnp.bool_),
+    ).compile().as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*', text)
+    assert len(calls) == 2, len(calls)
+    assert sum("short_conv_forward" in call for call in calls) == 1
+    assert sum("short_conv_backward" in call for call in calls) == 1
 
 
 def test_granite4_cell_update_compiles_for_v5e(one_chip, monkeypatch):
@@ -168,3 +213,5 @@ def test_granite4_cell_update_compiles_for_v5e(one_chip, monkeypatch):
         )) == 1, kernel
     # The nine mixers' scans, their states [64, 8, 64, 128] a layer.
     assert_scan_kernels(text, shapes, 9, 2, rows * 64 * 64 * 128)
+    # And their convolutions over [512, 4352], ops/short_conv.py's.
+    assert_conv_kernels(text, 9)

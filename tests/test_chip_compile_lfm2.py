@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from tests.chip_fixtures import (  # noqa: F401 (fixtures)
     NUM_ACTIONS,
+    assert_conv_kernels,
     on as _on,
     one_chip,
     struct as _struct,
@@ -184,7 +185,10 @@ def test_lfm2_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     assert len(re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*gmm_cut_in_vmem', text
     )) == 96
-    assert text.count("tpu_custom_call") == 96 + 2
+    # And, since PR 67, the four conv layers' taps (ops/short_conv.py):
+    # a forward kernel a layer, again rematerialised, and one backward.
+    assert_conv_kernels(text, 4)
+    assert text.count("tpu_custom_call") == 96 + 2 + 3 * 4
     assert "/moe/moe_sweep/jit(_rung)/moe_experts" in text
     assert "/moe/moe_sweep/while/body/jit(_rung)/moe_experts" in text
     assert not re.search(

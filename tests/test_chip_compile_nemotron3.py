@@ -11,6 +11,7 @@ import jax
 
 from tests.chip_fixtures import (  # noqa: F401 (fixtures)
     NUM_ACTIONS,
+    assert_conv_kernels,
     assert_scan_kernels,
     on as _on,
     one_chip,
@@ -146,4 +147,6 @@ def test_nemotron3_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     # The five mixers' scans (PR 65), their states [32, 16, 64, 128] a
     # layer: three more kernel calls a mixer.
     assert_scan_kernels(text, shapes, 5, 2, rows * 32 * 64 * 128)
-    assert text.count("tpu_custom_call") == 90 + 2 + 3 * 5
+    # And their convolutions (PR 67: ops/short_conv.py), three again.
+    assert_conv_kernels(text, 5)
+    assert text.count("tpu_custom_call") == 90 + 2 + 3 * 5 + 3 * 5

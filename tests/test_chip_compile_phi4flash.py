@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from tests.chip_fixtures import (  # noqa: F401 (fixtures)
     NUM_ACTIONS,
+    assert_conv_kernels,
     on as _on,
     one_chip,
     struct as _struct,
@@ -165,6 +166,9 @@ def test_phi4flash_cell_update_compiles_for_v5e(one_chip, monkeypatch):
         assert len(re.findall(
             r'custom_call_target="tpu_custom_call"[^\n]*' + kernel, text
         )) == 3, kernel
+    # The two Mamba-1 layers' convolutions are ops/short_conv.py's
+    # kernels (PR 67).
+    assert_conv_kernels(text, 2)
     # No float32 copy of the batch's frames anywhere in the program.
     frames = (steps + 1) * rows * int(np.prod(frame))
     assert not {

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from tests.chip_fixtures import (  # noqa: F401 (fixtures)
     NUM_ACTIONS,
+    assert_conv_kernels,
     on as _on,
     one_chip,
     struct as _struct,
@@ -259,7 +260,10 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     # the rematerialised block keeps its results) and, since PR 61, the
     # delta rule's chunk-to-chunk pass: a forward kernel a DeltaNet
     # layer, again rematerialised, and one backward.
-    assert text.count("tpu_custom_call") == 96 + 2 + 9
+    # Since PR 67 the layer's short convolution likewise
+    # (ops/short_conv.py).
+    assert text.count("tpu_custom_call") == 96 + 2 + 9 + 9
+    assert_conv_kernels(text, 3)
     for kernel, count in (
         ("delta_rule_forward", 6), ("delta_rule_backward", 3),
     ):

@@ -957,7 +957,10 @@ STATS_AT_PR_44 = {
         "moe_shared_applications", "moe_window_rows",
         "moe_window_short_applications",
     ],
+    # PR 67's count of the layers whose short convolution its kernels
+    # ran, here and in the four families below that call it.
     "nemotron3": [
+        "conv_kernel_applications",
         "moe_assignments", "moe_bias_abs_max", "moe_bias_steps",
         "moe_held_assignments", "moe_held_load_max_over_mean",
         "moe_latent_applications", "moe_load_max_over_mean",
@@ -970,8 +973,8 @@ STATS_AT_PR_44 = {
     # held under three chosen: no window); PR 61's count of the layers
     # whose chunk-to-chunk pass its kernels ran.
     "qwen3next": [
-        "attention_gated_applications", "delta_applications", "delta_chunks",
-        "delta_kernel_applications",
+        "attention_gated_applications", "conv_kernel_applications",
+        "delta_applications", "delta_chunks", "delta_kernel_applications",
         "delta_resets_per_row", "delta_state_bytes_per_row",
         "moe_assignments", "moe_held_assignments",
         "moe_held_load_max_over_mean", "moe_load_max_over_mean",
@@ -980,14 +983,16 @@ STATS_AT_PR_44 = {
     # The family of PR 53, as it came (four of sixteen held under three
     # chosen: no window).
     "lfm2": [
-        "conv_layers", "conv_resets_per_row", "conv_state_bytes_per_row",
+        "conv_kernel_applications", "conv_layers", "conv_resets_per_row",
+        "conv_state_bytes_per_row",
         "moe_assignments", "moe_bias_abs_max", "moe_bias_steps",
         "moe_held_assignments", "moe_held_load_max_over_mean",
         "moe_load_max_over_mean",
     ],
     # The family of PR 55, as it came.
     "phi4flash": [
-        "attention_differential_applications", "shared_bytes_per_row",
+        "attention_differential_applications", "conv_kernel_applications",
+        "shared_bytes_per_row",
         "shared_kv_readers", "shared_memory_readers", "ssm_applications",
         "ssm_chunks", "ssm_resets_per_row", "ssm_state_bytes_per_row",
     ],
@@ -1016,7 +1021,8 @@ STATS_AT_PR_44 = {
     # and the sublayers that follow every mixer; PR 65's count of the
     # mixers whose scan its kernels ran (Nemotron-3's list too).
     "granite4": [
-        "attention_unrotated_applications", "mlp_applications",
+        "attention_unrotated_applications", "conv_kernel_applications",
+        "mlp_applications",
         "ssm_applications", "ssm_chunks", "ssm_kernel_applications",
         "ssm_resets_per_row", "ssm_state_bytes_per_row",
     ],

@@ -263,7 +263,8 @@ def test_phi4flash_counters_are_the_updates_stats(phi4flash_compiled):
     _, stats = phi4flash_compiled
     for name in (
         "ssm_applications", "ssm_chunks", "ssm_resets_per_row",
-        "ssm_state_bytes_per_row", "shared_memory_readers",
+        "ssm_state_bytes_per_row", "conv_kernel_applications",
+        "shared_memory_readers",
         "shared_kv_readers", "shared_bytes_per_row",
         "attention_differential_applications",
     ):
@@ -458,7 +459,8 @@ def test_granite4_counters_are_the_updates_stats(granite4_compiled):
     _, stats = granite4_compiled
     for name in (
         "ssm_applications", "ssm_chunks", "ssm_resets_per_row",
-        "ssm_state_bytes_per_row", "mlp_applications",
+        "ssm_state_bytes_per_row", "conv_kernel_applications",
+        "mlp_applications",
         "attention_unrotated_applications",
     ):
         assert name in stats, name

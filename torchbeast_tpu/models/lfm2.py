@@ -79,6 +79,7 @@ from torchbeast_tpu.models.transformer import (
     count_fused_application,
     rematerialised,
 )
+from torchbeast_tpu.ops import short_conv
 from torchbeast_tpu.ops.attention import (
     dense_transformer_attend,
     fused_pass_applies,
@@ -214,6 +215,9 @@ class _ConvBlock(_Layer):
             # layer says alike.
             for name, value, fold in (
                 ("conv_layers", 1.0, "sum"),
+                # Those whose taps are ops/short_conv.py's kernels.
+                ("conv_kernel_applications",
+                 float(short_conv.kernels_apply(x.shape[1], d, K)), "sum"),
                 ("conv_state_bytes_per_row", 4 * (K - 1) * d, "sum"),
                 ("conv_resets_per_row",
                  jnp.mean(jnp.sum(done.astype(jnp.float32), axis=1)),

@@ -86,6 +86,7 @@ from torchbeast_tpu.models.transformer import (
     count_fused_application,
     rematerialised,
 )
+from torchbeast_tpu.ops import short_conv
 from torchbeast_tpu.ops import ssd_scan as scan_kernels
 from torchbeast_tpu.ops.attention import (
     dense_transformer_attend,
@@ -266,9 +267,28 @@ def conv_over_episodes(inputs, tail, done, taps, bias):
     (models/qwen3next.py). A tap is read only where no episode
     ended between its step and the step it is read at. Returns (the
     convolution [B, T, C] in float32, before the silu; the tail the
-    next unroll starts from, cut at the last episode end)."""
-    rows, steps, _ = inputs.shape
+    next unroll starts from, cut at the last episode end).
+
+    Two forms, chosen by the shapes alone (`ops/short_conv.kernels_
+    apply`): a learner's unroll at published widths runs ops/short_conv.
+    py's two Mosaic kernels, one pass over the array a direction;
+    acting at T = 1 and toy widths run the `jax.numpy` form below, which
+    is what the kernels are held to (tests/test_short_conv.py)."""
+    rows, steps, channels = inputs.shape
     K = taps.shape[0]
+    if short_conv.kernels_apply(steps, channels, K):
+        may = short_conv.reach(done, K)
+        # The K - 1 steps before the next unroll's first, each kept
+        # where no episode ended after it.
+        recent = jnp.concatenate(
+            [tail.transpose(1, 0, 2), inputs[:, 1 - K :].astype(jnp.float32)],
+            axis=1,
+        )[:, 1 - K :]
+        kept = may[:, -1:] >= jnp.arange(K - 2, -1, -1)
+        return (
+            short_conv.short_conv(inputs, tail, may, taps, bias),
+            jnp.where(kept[..., None], recent, 0.0).transpose(1, 0, 2),
+        )
     # Episodes ended up to and including each step; the carried tail
     # lies before all of them.
     ends = jnp.cumsum(done.astype(jnp.int32), axis=1)  # [B, T]
@@ -432,6 +452,11 @@ def count_mamba_application(module, done):
         # Those whose scan is ops/ssd_scan.py's kernels.
         ("ssm_kernel_applications",
          float(scan_kernels.kernels_apply(steps, Q, H, P, G, N)), "sum"),
+        # Those whose convolution is ops/short_conv.py's kernels.
+        ("conv_kernel_applications",
+         float(short_conv.kernels_apply(
+             steps, H * P + 2 * G * N, module.conv_kernel
+         )), "sum"),
         ("ssm_state_bytes_per_row",
          4 * (H * P * N + (module.conv_kernel - 1) * (H * P + 2 * G * N)),
          "sum"),

@@ -86,6 +86,7 @@ from torchbeast_tpu.models.transformer import (
     count_fused_application,
     rematerialised,
 )
+from torchbeast_tpu.ops import short_conv
 from torchbeast_tpu.ops.attention import (
     dense_transformer_attend,
     fused_pass_applies,
@@ -316,6 +317,9 @@ class _MambaBlock(_Layer):
             steps = x.shape[1]
             for name, value, fold in (
                 ("ssm_applications", 1.0, "sum"),
+                # Those whose convolution is ops/short_conv.py's kernels.
+                ("conv_kernel_applications",
+                 float(short_conv.kernels_apply(steps, D, K)), "sum"),
                 ("ssm_state_bytes_per_row", 4 * (N * D + (K - 1) * D), "sum"),
                 ("ssm_chunks", pieces, "same"),
                 ("ssm_resets_per_row",

@@ -133,7 +133,7 @@ from torchbeast_tpu.models.transformer import (
     count_fused_application,
     rematerialised,
 )
-from torchbeast_tpu.ops import delta_rule
+from torchbeast_tpu.ops import delta_rule, short_conv
 from torchbeast_tpu.ops.attention import (
     dense_transformer_attend,
     fused_pass_applies,
@@ -540,6 +540,9 @@ class _DeltaNetBlock(nn.Module):
                 # kernels: the learner's unroll, not a step of acting.
                 ("delta_kernel_applications",
                  float(delta_rule.kernels_apply(steps, Q, Dk, Dv)), "sum"),
+                # Those whose convolution is ops/short_conv.py's.
+                ("conv_kernel_applications",
+                 float(short_conv.kernels_apply(steps, channels, K)), "sum"),
                 ("delta_state_bytes_per_row",
                  4 * (Hv * Dk * Dv + (K - 1) * channels), "sum"),
                 ("delta_chunks", chunks, "same"),
