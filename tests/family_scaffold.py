@@ -14,7 +14,7 @@ through `jax.jit`:
   gradient is `jax.jit(jax.grad(...))` around `module.apply` in the
   case itself. A plain `jnp` function under test is called through
   `jax.jit` too, once a shape.
-- For the eleven policy families: the toy batch (`inputs`,
+- For the twelve policy families: the toy batch (`inputs`,
   `learner_batch`), `build(family, **overrides) -> (model, params)`,
   `expert_layer(family, held)`, `warm_state`, `reference_config`, and
   jitted callables for the four programs the cases run again and again
@@ -70,6 +70,7 @@ from perfbench.reference import (
     granite4_policy,
     kanana2_policy,
     lfm2_policy,
+    ling3_policy,
     mellum2_policy,
     nemotron3_policy,
     olmoe_policy,
@@ -84,6 +85,7 @@ from torchbeast_tpu.models import (
     Granite4Net,
     Kanana2Net,
     Lfm2Net,
+    Ling3Net,
     Mellum2Net,
     Nemotron3Net,
     OLMoENet,
@@ -95,6 +97,7 @@ from torchbeast_tpu.models import (
     granite4,
     kanana2,
     lfm2,
+    ling3,
     mellum2,
     moe,
     nemotron3,
@@ -342,6 +345,42 @@ def _perturb_xing4(model, params):
 _perturb_trinity = _perturb_lfm2
 
 
+def _perturb_ling3(model, params):
+    # The side inputs, the selection biases, every norm's scale (a
+    # layer's two, the latent's, the KDA output norm's, the final one),
+    # which start at one; and a KDA layer's `dt_bias` up by 4, so that
+    # its log-decays spread over (-5, 0) and some sit at the floor (at
+    # its init nearly every channel's is ~0 and a decay misapplied
+    # would not show); a seed a leaf moved.
+    inner = _with_extras(params["params"])
+    seeds = iter(range(1, 1000))
+
+    def moved(tree):
+        out = {}
+        for name, leaf in sorted(tree.items()):
+            if name == "moe":
+                out[name] = dict(
+                    leaf, e_score_correction_bias=_normal(
+                        next(seeds), leaf["e_score_correction_bias"].shape,
+                        0.1,
+                    ),
+                )
+            elif isinstance(leaf, dict):
+                out[name] = moved(leaf)
+            elif name in ("scale", "gate_norm"):
+                out[name] = leaf + _normal(next(seeds), leaf.shape, 0.3)
+            elif name == "dt_bias":
+                out[name] = leaf + 4.0 + _normal(next(seeds), leaf.shape, 1.0)
+            else:
+                out[name] = leaf
+        return out
+
+    for name in sorted(inner):
+        if name.startswith("block_") or name == "final_norm":
+            inner[name] = moved(inner[name])
+    return {"params": inner}
+
+
 def _perturb_granite4(model, params):
     # The side inputs, and what starts at one: every norm's scale (a
     # layer's two, the final one), the gated norm's and the skip `D`; a
@@ -528,6 +567,44 @@ def _config_granite4(model):
         "residual_multiplier": model.residual_multiplier,
         "logits_scaling": 1.0 / model.logits_scale,
         "rms_norm_eps": model.rms_norm_eps,
+    }
+
+
+def _config_ling3(model):
+    held = model.held_experts()
+    whole = model.num_layers == model.published_layers
+    return {
+        "num_hidden_layers": model.num_layers,
+        # A cut: the last leading dense layer (published layer 1),
+        # then whole periods from a period's first layer (6 on).
+        "layers_run": list(range(model.num_layers)) if whole else [
+            model.dense_layers - 1
+        ] + [
+            model.layer_group_size + i for i in range(model.num_layers - 1)
+        ],
+        "layer_group_size": model.layer_group_size,
+        "first_k_dense_replace": model.dense_layers,
+        "num_attention_heads": model.num_heads, "head_dim": model.head_dim,
+        "short_conv_kernel_size": model.conv_kernel,
+        "kda_safe_gate": model.safe_gate,
+        "kda_lower_bound": model.gate_lower_bound,
+        "num_kv_heads_for_linear_attn": 0, "linear_silu": True,
+        "group_norm_size": 1,
+        "kv_lora_rank": model.latent_rank, "q_lora_rank": None,
+        "qk_nope_head_dim": model.nope_head_dim,
+        "qk_rope_head_dim": model.rope_head_dim,
+        "rotary_dim": model.rope_head_dim,
+        "v_head_dim": model.value_head_dim, "rope_theta": model.rope_theta,
+        "gated_attention_proj_granularity_type": "head_wise",
+        "published_num_experts": model.num_experts,
+        "num_experts": held[1] if held else model.num_experts,
+        "expert_share": list(model.expert_share),
+        "num_experts_per_tok": model.experts_per_token,
+        "n_group": model.n_group, "topk_group": model.topk_group,
+        "norm_topk_prob": True, "score_function": "sigmoid",
+        "moe_router_enable_expert_bias": True,
+        "routed_scaling_factor": model.routed_scaling,
+        "bias_update_rate": model.bias_update_rate, "rms_norm_eps": 1e-6,
     }
 
 
@@ -969,6 +1046,52 @@ FAMILIES = {
             num_layers=3, memory_len=5,
         ),
         _config_granite4, _perturb_granite4, t=11,
+    ),
+    # The leading dense layer (KDA over a SwiGLU of 48; ONE such layer
+    # published, so that it is published layer 0, KDA under a period of
+    # two) and one period cut to `K M`: KDA of 4 heads of 8 scanned in chunks of 4 steps
+    # built from sub-blocks of 2 (the 11 steps of an unroll are two
+    # whole chunks and one padded), the latent layer Kanana-2's toy with
+    # a gate a head over a cache of 5 slots; 16 experts of 10 in 4
+    # groups of 4, top 3 among the 2 best groups', a shared expert of 12.
+    "ling3": Family(
+        Ling3Net, ling3, ling3_policy,
+        dict(
+            d_model=32, layer_group_size=2, dense_layers=1, num_heads=4,
+            head_dim=8, chunk_size=4, sub_chunk=2, latent_rank=12, nope_head_dim=8,
+            rope_head_dim=4, value_head_dim=6, mlp_width=48,
+            num_experts=16, experts_per_token=3, expert_width=10,
+            shared_width=12, n_group=4, topk_group=2, num_layers=3,
+            memory_len=5,
+        ),
+        _config_ling3, _perturb_ling3, t=11,
+        # The shares: 64 experts in 8 groups of 8, top 8 among the 4
+        # best groups', the published counts but for the experts (the
+        # interpreted grouped kernels are slow over 512); held (0, 4),
+        # (4, 4), ... (60, 4): a group is two shares, the shared expert
+        # COUNTED ONCE.
+        experts=Experts(
+            dict(
+                d_ff=8, num_experts=16, top_k=3, aux_loss_weight=0.0,
+                renormalise=True, scoring="sigmoid", selection_bias=True,
+                bias_update_rate=0.001, routed_scaling=2.5, n_group=4,
+                topk_group=2, shared_width=12,
+            ),
+            shares=16,
+            config=_experts_config(
+                "published_num_experts", "num_experts",
+                score_function="sigmoid",
+                moe_router_enable_expert_bias=True,
+                routed_scaling_factor=2.5, n_group=8, topk_group=4,
+            ),
+            leaves=tuple(sorted(
+                _SWIGLU_EXPERTS + _SWIGLU_SHARED
+                + ("e_score_correction_bias",)
+            )),
+            tol=1e-5,
+            uncut=dict(num_experts=64, top_k=8, n_group=8, topk_group=4),
+            shared=_swiglu_shared,
+        ),
     ),
 }
 

@@ -401,6 +401,65 @@ def _published_trinity(model):
     )
 
 
+def _published_ling3(model):
+    assert model.zero_init_extras
+    assert (model.d_model, model.num_heads, model.head_dim) == (2560, 32, 128)
+    assert (model.layer_group_size, model.dense_layers, model.mlp_width) == (
+        6, 2, 6144
+    )
+    assert (
+        model.conv_kernel, model.chunk_size, model.sub_chunk,
+        model.safe_gate, model.gate_lower_bound,
+    ) == (4, 64, 16, True, -5.0)
+    # 16 steps at the floor stay inside float32; a chunk's 64 do not.
+    assert 16 * 5.0 < 88 < 64 * 5.0
+    assert (
+        model.latent_rank, model.nope_head_dim, model.rope_head_dim,
+        model.value_head_dim, model.rope_theta,
+    ) == (512, 128, 64, 128, 6e6)
+    assert (
+        model.num_experts, model.experts_per_token, model.expert_width,
+        model.shared_width, model.n_group, model.topk_group,
+    ) == (512, 8, 768, 768, 8, 4)
+    assert model.renormalise and model.routed_scaling == 2.5
+    assert (model.rms_norm_eps, model.memory_len, model.bias_update_rate) == (
+        1e-6, 1023, 0.001
+    )
+    assert (model.matmul_precision, model.cache_leg_precision) == (
+        "high", "default"
+    )
+    # Share 0 of 64: eight experts, all inside group 0 of eight.
+    assert model.held_experts() == (0, 8)
+    # The cut: published layer 1 (dense, KDA), then one group `KKKKKM`;
+    # six carried states and one latent window.
+    assert model.leading_dense_layers() == 1
+    assert [model.is_latent(layer) for layer in range(7)] == (
+        [False] * 6 + [True]
+    )
+    state = jax.eval_shape(lambda: model.initial_state(8))
+    assert [item[0].shape for item in state] == (
+        [(32, 8, 128, 128)] * 6 + [(1023, 8, 1, 512)]
+    )
+    assert state[0][1].shape == (3, 8, 12288)
+    assert [leaf.shape for leaf in state[6]] == [
+        (1023, 8, 1, 512), (1023, 8, 1, 64), (1023, 8)
+    ]
+    # 13,467,648 bytes of KDA state a row, float32: six layers'.
+    assert 6 * 4 * (32 * 128 * 128 + 3 * 12288) == 13_467_648
+    whole = create_model("ling3", num_actions=6)
+    assert whole.leading_dense_layers() == 2 and whole.held_experts() is None
+    assert [
+        layer for layer in range(42) if whole.is_latent(layer)
+    ] == [5, 11, 17, 23, 29, 35, 41]
+    # The cell's latent layer over 1,023 slots at 256 steps is the fused
+    # latent leg (268 MB of f32 scores at one bf16 pass); a T=1 act step
+    # is not.
+    for steps, fused in ((256, True), (1, False)):
+        assert attention.fused_latent_leg_applies(
+            (8, steps, 32, 576), 1023, 512, model.cache_leg_precision
+        ) is fused
+
+
 def _published_granite4(model):
     assert model.zero_init_extras
     assert (model.d_model, model.num_heads, model.kv_heads, model.head_dim) == (
@@ -516,6 +575,14 @@ REGISTRY = {
               for bad in [5, 11, 45]),
         ],
     ),
+    "ling3": (
+        dict(num_layers=7, expert_share=(0, 64)), 42, _published_ling3, [
+            *(_refused(r"1 \+ 6k layers, or is all 42", num_layers=bad)
+              for bad in [6, 8, 1, 48]),
+            *(_refused("expert_share", expert_share=bad)
+              for bad in [(64, 64), (0, 3), (-1, 8)]),
+        ],
+    ),
 }
 
 
@@ -573,7 +640,7 @@ def _flags_mellum2(parse, build):
         with pytest.raises(
             ValueError,
             match="--model mellum2 or kanana2 or nemotron3 or qwen3next or "
-                  "lfm2 or xing4 or trinity only",
+                  "lfm2 or xing4 or trinity or ling3 only",
         ):
             build(parse(["--model", other, "--expert_share", "0/4"]))
     return model, ["--model", "mellum2", "--num_layers", "4"]
@@ -793,12 +860,43 @@ def _flags_granite4(parse, build):
     return model, ["--model", "granite4", "--num_layers", "3"]
 
 
+def _flags_ling3(parse, build):
+    flags = parse([
+        "--model", "ling3", "--num_layers", "5", "--memory_len", "9",
+        "--expert_share", "1/8",
+    ])
+    assert (flags.model, flags.num_layers, flags.expert_share) == (
+        "ling3", 5, "1/8"
+    )
+    model = build(flags)
+    assert (model.num_layers, model.memory_len, model.d_model) == (5, 9, 32)
+    # Half of group 0 of the (shrunken) table's four groups of four.
+    assert model.held_experts() == (2, 2)
+    assert model.leading_dense_layers() == 1
+    # The (shrunken) table's group of two: the dense layer, `K M` twice.
+    assert [model.is_latent(layer) for layer in range(5)] == [
+        False, False, True, False, True
+    ]
+    # --num_layers 4 is refused, and says why; so is a share that cuts
+    # a group unevenly.
+    with pytest.raises(ValueError, match=r"1 \+ 2k layers, or is all 42"):
+        build(parse(["--model", "ling3", "--num_layers", "4"]))
+    with pytest.raises(ValueError, match="expert_share"):
+        build(parse(["--model", "ling3", "--expert_share", "0/3"]))
+    with pytest.raises(ValueError, match="mixer_share .* nemotron3 only"):
+        build(parse(["--model", "ling3", "--mixer_share", "0/2"]))
+    with pytest.raises(ValueError, match="use_lstm"):
+        build(parse(["--model", "ling3", "--use_lstm"]))
+    return model, ["--model", "ling3", "--num_layers", "3"]
+
+
 FLAGS = {
     "olmoe": _flags_olmoe, "mellum2": _flags_mellum2, "ouro": _flags_ouro,
     "kanana2": _flags_kanana2, "nemotron3": _flags_nemotron3,
     "qwen3next": _flags_qwen3next, "lfm2": _flags_lfm2,
     "phi4flash": _flags_phi4flash, "xing4": _flags_xing4,
     "trinity": _flags_trinity, "granite4": _flags_granite4,
+    "ling3": _flags_ling3,
 }
 
 
@@ -1026,8 +1124,25 @@ STATS_AT_PR_44 = {
         "ssm_applications", "ssm_chunks", "ssm_kernel_applications",
         "ssm_resets_per_row", "ssm_state_bytes_per_row",
     ],
+    # The family of PR 68, as it came (two of sixteen held under three
+    # chosen: a window of one rung; the KDA layers', the latent
+    # layer's, the router's groups').
+    "ling3": [
+        "attention_latent_applications", "conv_kernel_applications",
+        "experts_held_rows_mean", "kda_applications", "kda_chunks",
+        "kda_gate_at_floor_share", "kda_kernel_applications",
+        "kda_log_decay_mean",
+        "kda_log_decay_min", "kda_resets_per_row",
+        "kda_state_bytes_per_row", "kda_sub_blocks",
+        "moe_assignments", "moe_bias_abs_max", "moe_bias_steps",
+        "moe_held_assignments", "moe_held_load_max_over_mean",
+        "moe_load_max_over_mean", "moe_shared_applications",
+        "moe_window_rows", "moe_window_short_applications",
+        "router_group_load_max_share",
+    ],
 }
 _HELD = {
+    "ling3": dict(expert_share=(0, 8)),
     "trinity": dict(expert_share=(0, 8)),
     "xing4": dict(expert_share=(0, 8)),
     "mellum2": dict(expert_share=(1, 4)),

@@ -77,6 +77,20 @@ REMAT = {
         ["ssm_applications", "ssm_chunks", "ssm_resets_per_row",
          "mlp_applications", "attention_unrotated_applications"], [],
     ),
+    # A KDA mixer (its solve's result kept across the rematerialisation),
+    # the latent mixer and a feed-forward part are each a rematerialised
+    # block of their own; ends inside a chunk, a sub-block and on step 0.
+    # 5e-5 of the largest gradient: a sub-block's columns are measured
+    # from its first step (e^5 on a row's e^-5 at the toy's floor), and
+    # two XLA programs round that product apart (one entry of 29,705 by
+    # 2.4e-5).
+    "ling3": (
+        dict(expert_share=(1, 8)), _ENDS, 1e-5, (0, 5e-5),
+        ["moe_held_assignments", "kda_applications", "kda_chunks",
+         "kda_resets_per_row", "attention_latent_applications",
+         "router_group_load_max_share"],
+        ["kda_log_decay_mean", "kda_gate_at_floor_share"],
+    ),
 }
 
 

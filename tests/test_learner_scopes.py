@@ -570,3 +570,71 @@ def test_the_sweeps_loop_has_a_name_of_its_own():
         for stack in kernels
     )
     assert [len(list(_kernel_calls(body))) for _, body in sweeps] == [3, 9]
+
+
+# --- a family's own scopes (PR 68: `--model ling3`) --------------------------
+
+LING3_SCOPES = (
+    "kda_in_proj", "kda_conv", "kda_gate", "kda_scan", "kda_intra",
+    "kda_solve", "kda_states", "kda_inter", "kda_out", "attention_latent",
+    "latent_head_gate", "router_groups", "moe_route", "mlp",
+)
+
+
+@pytest.fixture(scope="module")
+def ling3_compiled():
+    """As `granite4_compiled`, of the toy ling3."""
+    model, params = scaffold.build("ling3")
+    t = scaffold.FAMILIES["ling3"].t
+    batch = scaffold.learner_batch(1, [(2, 0), (4, 1)], t=t)
+    hp = learner_lib.HParams(batch_size=scaffold.B, unroll_length=t - 1)
+    optimizer = optax.sgd(0.1)
+    update_step = learner_lib.make_update_step(
+        model, optimizer, hp, donate=False
+    )
+    operands = (
+        params, optimizer.init(params), batch,
+        model.initial_state(scaffold.B),
+    )
+    compiled = update_step.lower(*operands).compile()
+    stats = jax.eval_shape(update_step, *operands)[2]
+    return re.findall(r'op_name="([^"]+)"', compiled.as_text()), stats
+
+
+@pytest.mark.parametrize("scope", LING3_SCOPES)
+def test_ling3_scope_reaches_the_compiled_hlo(ling3_compiled, scope):
+    """The family's own names (a decay a channel is no `delta_*` scope:
+    one account tells Qwen3-Next's scan from this one), the chunk's
+    parts inside `kda_scan`, the solve inside `kda_intra`, the head gate
+    inside the latent block's scope and the groups inside the router's."""
+    op_names, _ = ling3_compiled
+    inside = [n for n in op_names if _in_scope(n, scope)]
+    assert inside, f"no compiled op carries the scope {scope!r}"
+    for inner, outer in (
+        ("kda_intra", "kda_scan"), ("kda_states", "kda_scan"),
+        ("kda_inter", "kda_scan"), ("kda_solve", "kda_intra"),
+        ("latent_head_gate", "attention_latent"),
+        ("router_groups", "moe_route"),
+    ):
+        if scope == inner:
+            assert all(_in_scope(n, outer) for n in inside)
+    assert not any(_in_scope(n, "delta_scan") for n in inside)
+    assert scope in device_scopes.known_device_scopes()
+
+
+def test_ling3_counters_are_the_updates_stats(ling3_compiled):
+    """What the layers sow reaches the update's stats, a gauge each
+    (`kda.log_decay_min`, `router.group_load_max_share`, ...)."""
+    from torchbeast_tpu.models import stats as model_stats
+
+    _, stats = ling3_compiled
+    for name in (
+        "kda_applications", "kda_chunks", "kda_sub_blocks",
+        "kda_resets_per_row", "kda_state_bytes_per_row",
+        "kda_log_decay_min", "kda_log_decay_mean",
+        "kda_gate_at_floor_share", "conv_kernel_applications",
+        "attention_latent_applications", "router_group_load_max_share",
+        "experts_held_rows_mean", "moe_bias_steps",
+    ):
+        assert name in stats, name
+        assert model_stats.gauge_name(name) == name.replace("_", ".", 1)

@@ -172,14 +172,14 @@ FAMILY_FIELD_CASES = {
         "--num_layers is a positive depth or window of --model "
         "transformer or olmoe or mellum2 or ouro or kanana2 or nemotron3 "
         "or qwen3next or lfm2 or phi4flash or xing4 or trinity or "
-        "granite4",
+        "granite4 or ling3",
     ),
     "memory_len": (
         "9", 9, "transformer", "deep",
         "--memory_len is a positive depth or window of --model "
         "transformer or olmoe or mellum2 or ouro or kanana2 or nemotron3 "
         "or qwen3next or lfm2 or phi4flash or xing4 or trinity or "
-        "granite4",
+        "granite4 or ling3",
     ),
     "num_experts": (
         "4", 4, "transformer", "olmoe",
@@ -190,7 +190,8 @@ FAMILY_FIELD_CASES = {
         "1/4", (1, 4), "mellum2", "olmoe",
         "--expert_share i/n (share i of the n chips that divide each "
         "layer's experts) applies to --model mellum2 or kanana2 or "
-        "nemotron3 or qwen3next or lfm2 or xing4 or trinity only",
+        "nemotron3 or qwen3next or lfm2 or xing4 or trinity or ling3 "
+        "only",
     ),
     "mixer_share": (
         "1/2", (1, 2), "nemotron3", "kanana2",
@@ -238,7 +239,7 @@ def test_family_field_flag_follows_the_class(flag, monkeypatch):
 @pytest.mark.parametrize(
     "family",
     ["mellum2", "kanana2", "nemotron3", "qwen3next", "lfm2", "xing4",
-     "trinity"],
+     "trinity", "ling3"],
 )
 def test_expert_share_reaches_every_family_that_declares_it(family):
     """`--expert_share` is no family's by name: a class that declares
@@ -246,15 +247,16 @@ def test_expert_share_reaches_every_family_that_declares_it(family):
     text lists the takers from the registry. PR 38 added a second taker
     and edited neither `_FAMILY_FIELD_REFUSALS` nor the check; PR 42 a
     third, and its sibling `--mixer_share` with a refusal of its own; PR
-    46 a fourth; PR 53 a fifth; PR 59 a sixth; PR 62 a seventh."""
+    46 a fourth; PR 53 a fifth; PR 59 a sixth; PR 62 a seventh; PR 68
+    an eighth, whose router's groups a quarter share keeps whole."""
     assert models.families_taking("expert_share") == [
         "mellum2", "kanana2", "nemotron3", "qwen3next", "lfm2", "xing4",
-        "trinity",
+        "trinity", "ling3",
     ]
     assert models.families_taking("mixer_share") == ["nemotron3"]
     layers = {
         "mellum2": "4", "kanana2": "2", "nemotron3": "11", "qwen3next": "4",
-        "lfm2": "5", "xing4": "2", "trinity": "5",
+        "lfm2": "5", "xing4": "2", "trinity": "5", "ling3": "7",
     }[family]
     model, _ = learner_setup.init_model_and_params(
         monobeast.make_parser().parse_args([
@@ -291,6 +293,7 @@ def test_refusals_are_stated_on_the_class():
         "xing4": ("num_experts", "attention_impl"),
         "trinity": ("num_experts", "attention_impl"),
         "granite4": ("num_experts", "attention_impl"),
+        "ling3": ("num_experts", "attention_impl"),
     }
     kv_cache = [
         name for name in models.MODEL_NAMES
@@ -299,13 +302,13 @@ def test_refusals_are_stated_on_the_class():
     assert kv_cache == [
         "transformer", "pipelined_transformer", "olmoe", "mellum2", "ouro",
         "kanana2", "nemotron3", "qwen3next", "lfm2", "phi4flash", "xing4",
-        "trinity", "granite4",
+        "trinity", "granite4", "ling3",
     ]
     for name in models.MODEL_NAMES:
         # test_families has the published families'
         if name in kv_cache and name not in (
             "olmoe", "mellum2", "ouro", "kanana2", "nemotron3", "qwen3next",
-            "lfm2", "phi4flash", "xing4", "trinity", "granite4",
+            "lfm2", "phi4flash", "xing4", "trinity", "granite4", "ling3",
         ):
             with pytest.raises(ValueError, match="KV cache"):
                 models.create_model(name, num_actions=A, use_lstm=True)

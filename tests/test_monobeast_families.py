@@ -71,6 +71,25 @@ def _granite4_through_main(stats):
     assert stats["aux_loss"] == 0.0
 
 
+def _ling3_through_main(stats):
+    # The dense layer and a period cut to `K M`: two KDA mixers, one
+    # latent layer, the router's four groups.
+    assert stats["kda_applications"] == 2
+    assert stats["kda_chunks"] == 2  # 6 steps in chunks of 4
+    assert stats["kda_sub_blocks"] == 2
+    # Two layers' [4, 8, 8] states and tails of 3 inputs over 3 x 4 x 8
+    # channels, f32.
+    assert stats["kda_state_bytes_per_row"] == 2 * 4 * (4 * 8 * 8 + 3 * 96)
+    assert stats["kda_resets_per_row"] >= 0
+    assert -5.0 <= stats["kda_log_decay_min"] <= 0.0
+    assert stats["attention_latent_applications"] == 1
+    assert stats["moe_bias_steps"] == 2
+    assert stats["moe_shared_applications"] == 2
+    assert 0.25 <= stats["router_group_load_max_share"] <= 1.0
+    assert 0 <= stats["moe_held_assignments"] <= stats["moe_assignments"]
+    assert stats["aux_loss"] == 0.0
+
+
 def _nemotron3_through_main(stats):
     assert stats["ssm_applications"] == 1
     assert stats["ssm_chunks"] == 2  # 6 steps in chunks of 4
@@ -171,6 +190,12 @@ _MELLUM2_WIDTHS = dict(
 #   keys without positions, a SwiGLU after every mixer, the four
 #   multipliers inside every step, the blocks rematerialised; the
 #   learner's updates scan in chunks.
+#  ling3: the dense layer and a period cut to `K M`, share 1 of 4 (one
+#   of the router's four groups): acting at T=1 through two KDA matrix
+#   states with their tails (a chunk of one step: the recurrence, a
+#   decay a channel) and a rolling latent cache under a gate a head;
+#   the learner's updates scan in chunks of 4 from sub-blocks of 2, the
+#   blocks rematerialised, the biases moved by the load.
 #  ouro: 2 layers run 3 times, through 3 x 2 rolling caches.
 THROUGH_MAIN = {
     "mellum2-all-experts": (
@@ -266,6 +291,18 @@ THROUGH_MAIN = {
         ),
         dict(num_layers=3, remat="all"),
         _granite4_through_main,
+    ),
+    "ling3": (
+        "ling3",
+        dict(
+            d_model=32, layer_group_size=2, dense_layers=1, num_heads=4,
+            head_dim=8, chunk_size=4, sub_chunk=2, latent_rank=12,
+            nope_head_dim=8, rope_head_dim=4, value_head_dim=6, mlp_width=48,
+            num_experts=16, experts_per_token=3, expert_width=10,
+            shared_width=12, n_group=4, topk_group=2,
+        ),
+        dict(num_layers=3, expert_share="1/4", remat="all"),
+        _ling3_through_main,
     ),
     "ouro": (
         "ouro",

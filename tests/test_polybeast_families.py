@@ -58,6 +58,13 @@ def _granite4_trained(stats):
     assert stats["attention_unrotated_applications"] == 1
 
 
+def _ling3_trained(stats):
+    assert stats["kda_applications"] == 2
+    assert stats["attention_latent_applications"] == 1
+    assert stats["moe_bias_steps"] == 2
+    assert 0.25 <= stats["router_group_load_max_share"] <= 1.0
+
+
 def _trinity_trained(stats):
     assert stats["attention_gated_applications"] == 3
     assert stats["attention_unrotated_applications"] == 1
@@ -91,7 +98,21 @@ def _trinity_trained(stats):
 #  granite4: the slots hold two Mamba-2 states with their conv tails
 #   around the attention layer's window; the learner's updates scan in
 #   chunks of 4.
+#  ling3: the slots hold two KDA matrix states with their conv tails
+#   beside the latent layer's window (entries of two unequal leaves);
+#   an act step is a chunk of one step, the learner's updates scan in
+#   chunks of 4 from sub-blocks of 2.
 FAMILIES = {
+    "ling3": (
+        dict(
+            d_model=32, layer_group_size=2, dense_layers=1, num_heads=4,
+            head_dim=8, chunk_size=4, sub_chunk=2, latent_rank=12,
+            nope_head_dim=8, rope_head_dim=4, value_head_dim=6, mlp_width=48,
+            num_experts=16, experts_per_token=3, expert_width=10,
+            shared_width=12, n_group=4, topk_group=2,
+        ),
+        3, _ling3_trained,
+    ),
     "granite4": (
         dict(
             d_model=32, num_heads=4, kv_heads=2, head_dim=8, mamba_heads=8,

@@ -182,9 +182,10 @@ def add_learner_arguments(parser, *, model_default,
                         help="Depth of --model transformer, olmoe, "
                              "mellum2, ouro, kanana2, nemotron3, "
                              "qwen3next, lfm2, phi4flash, xing4, "
-                             "trinity or granite4 (0: "
+                             "trinity, granite4 or ling3 (0: "
                              "the family's own, 2 and the published 16, "
-                             "28, 48, 48, 88, 48, 24, 32, 40, 32 and 40; "
+                             "28, 48, 48, 88, 48, 24, 32, 40, 32, 40 and "
+                             "42; "
                              "mellum2 in whole "
                              "periods of 4; ouro runs the layers it has "
                              "4 times a step; kanana2: its leading "
@@ -206,23 +207,29 @@ def add_learner_arguments(parser, *, model_default,
                              "sliding layers and one full, or all 32 "
                              "with both dense layers; granite4 in whole "
                              "periods of 10, MMMMM*MMMM: nine Mamba-2 "
-                             "layers and one attention layer).")
+                             "layers and one attention layer; ling3 as "
+                             "1 + 6k: one leading dense layer, then whole "
+                             "periods of five Kimi Delta Attention "
+                             "layers and one latent attention layer, or "
+                             "all 42 with both dense layers).")
     parser.add_argument("--memory_len", type=int, default=0,
                         help="Steps of its own past a transformer, "
                              "olmoe, mellum2, ouro, kanana2, nemotron3, "
-                             "qwen3next, lfm2, phi4flash, xing4, trinity "
-                             "or granite4 "
+                             "qwen3next, lfm2, phi4flash, xing4, trinity, "
+                             "granite4 or ling3 "
                              "policy attends over, carried as the "
                              "rolling KV cache (0: the family's own, 64, "
                              "128, 4095, 255, 4095, 4095, 4095, 4095, 4095, "
-                             "4095, 4095 and 4095; "
+                             "4095, 4095, 4095 and 1023; "
                              "mellum2, trinity: "
                              "its full layers' cache, the sliding "
                              "layers carry min(memory_len, 1023; "
                              "trinity: 2047); ouro: "
                              "every one of its 4 x num_layers caches; "
                              "kanana2, xing4: a latent and a rope key a "
-                             "slot; "
+                             "slot; ling3: its latent layers' likewise, "
+                             "the Kimi Delta Attention layers carry a "
+                             "matrix state; "
                              "nemotron3, granite4: its attention layers', "
                              "the Mamba-2 layers carry a state instead; "
                              "qwen3next: its attention layers', the "
@@ -234,18 +241,23 @@ def add_learner_arguments(parser, *, model_default,
                              "511), a Mamba layer a state).")
     parser.add_argument("--expert_share", default="",
                         help="--model mellum2, kanana2, nemotron3, "
-                             "qwen3next, lfm2, xing4 or trinity: "
+                             "qwen3next, lfm2, xing4, trinity or ling3: "
                              "'i/n' holds share i of the n chips that "
                              "divide each layer's 64 (128, 512, 512, 32, "
-                             "64, 128 routed) "
+                             "64, 128, 512 routed) "
                              "experts (0/4: experts 0..15). The layer "
                              "routes over all of them and adds its own "
                              "experts' part of the sum (kanana2, "
-                             "nemotron3, qwen3next, xing4, trinity: and the "
-                             "shared "
+                             "nemotron3, qwen3next, xing4, trinity, "
+                             "ling3: and the shared "
                              "expert); "
                              "nothing stands in for the other chips. "
-                             "Empty: all.")
+                             "Where the router chooses by groups (ling3: "
+                             "8 groups of 64, a token's 8 among its 4 "
+                             "best groups') it does so over all the "
+                             "experts, and a share is whole groups or a "
+                             "group is whole shares (0/64: eight of "
+                             "group 0's). Empty: all.")
     parser.add_argument("--mixer_share", default="",
                         help="--model nemotron3: 'i/n' holds share i of "
                              "the n chips that divide each mixer's "
@@ -337,7 +349,7 @@ def add_learner_arguments(parser, *, model_default,
                              "(transformer, pipelined_transformer, "
                              "mellum2, ouro, kanana2, nemotron3, "
                              "qwen3next, lfm2, phi4flash, xing4, trinity, "
-                             "granite4; not olmoe), the "
+                             "granite4, ling3; not olmoe), the "
                              "LSTM scan): 'auto' picks the "
                              "minimum-recompute plan whose XLA-measured "
                              "peak fits --hbm_budget_gb; 'all'/'none' "

@@ -14,6 +14,7 @@ from torchbeast_tpu.models import (
     granite4,
     kanana2,
     lfm2,
+    ling3,
     mellum2,
     nemotron3,
     olmoe,
@@ -26,6 +27,7 @@ from torchbeast_tpu.models import (
 from torchbeast_tpu.models.granite4 import Granite4Net  # noqa: F401
 from torchbeast_tpu.models.kanana2 import Kanana2Net  # noqa: F401
 from torchbeast_tpu.models.lfm2 import Lfm2Net  # noqa: F401
+from torchbeast_tpu.models.ling3 import Ling3Net  # noqa: F401
 from torchbeast_tpu.models.mellum2 import Mellum2Net  # noqa: F401
 from torchbeast_tpu.models.nemotron3 import Nemotron3Net  # noqa: F401
 from torchbeast_tpu.models.olmoe import OLMoENet  # noqa: F401
@@ -61,6 +63,7 @@ _REGISTRY = {
     "xing4": Xing4Net,
     "trinity": TrinityNet,
     "granite4": Granite4Net,
+    "ling3": Ling3Net,
 }
 # A family whose widths are a published table (its module's `PUBLISHED`,
 # keyed by the class's fields): read when the model is built, so that a
@@ -69,7 +72,7 @@ _PUBLISHED_TABLES = {
     OLMoENet: olmoe, Mellum2Net: mellum2, OuroNet: ouro, Kanana2Net: kanana2,
     Nemotron3Net: nemotron3, Qwen3NextNet: qwen3next, Lfm2Net: lfm2,
     Phi4FlashNet: phi4flash, Xing4Net: xing4, TrinityNet: trinity,
-    Granite4Net: granite4,
+    Granite4Net: granite4, Ling3Net: ling3,
 }
 MODEL_NAMES = tuple(_REGISTRY)
 

@@ -67,9 +67,12 @@ left the lowered update and act step of the toy family equal to the
 byte: PERF.md section 6, PR 59). What the split left here: the parts,
 `rope_pairs` (now also at given frequencies), `for_the_cache`, and on
 `Kanana2Net` `latent_block_fields` and `leading_dense_layers`, which the
-other family's net reads. Three fields are the other family's and are
+other family's net reads. Four fields are other families' and are
 no-ops at their defaults: `query_rank` (None), `rope_inv_freq` (None:
-theta^(-2i/D)), `score_scale` (1).
+theta^(-2i/D)), `score_scale` (1), all models/xing4.py's, and
+`head_gate` (False; models/ling3.py: the attended values of a head
+times sigmoid of one number a head, `head_gate` d -> heads, before
+`o`).
 
 The widths are constants of the family (`PUBLISHED`), not flags; a user
 cuts depth (`--num_layers`: the leading dense layer and the MoE layers
@@ -185,6 +188,9 @@ class _Kanana2Block(nn.Module):
     # What the softmax scale (nope + rope)^-0.5 is multiplied by (YaRN's
     # mscale, squared); the queries carry it into both legs.
     score_scale: float = 1.0
+    # `gated_attention_proj_granularity_type` head_wise (models/ling3.py):
+    # attended_h * sigmoid(W_g h)_h before `o`, whichever leg made it.
+    head_gate: bool = False
 
     @nn.nowrap
     def _norm(self, name):
@@ -260,6 +266,11 @@ class _Kanana2Block(nn.Module):
                 self.cache_leg_precision,
             ):
                 count_latent_fused_application(self)
+            if self.head_gate:
+                with device_scope("latent_head_gate"):
+                    attended = attended * nn.sigmoid(
+                        proj("head_gate", H)(h)
+                    )[..., None].astype(attended.dtype)
             added = proj("o", self.d_model)(
                 attended.reshape(B, T, H * Dv)
             ).astype(jnp.float32)

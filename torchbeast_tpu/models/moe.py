@@ -738,11 +738,16 @@ def dropless_experts(x, idx, gate, w_gate, w_up, w_down, first_of=None,
     return y, sizes
 
 
-def held_experts(expert_share, num_experts):
+def held_experts(expert_share, num_experts, n_group=1):
     """(first, count) of the experts that share i of n holds
     (`--expert_share i/n`: experts i * num_experts / n onward), None
     for (0, 1): all of them. A share that is none of the n, or an n
-    that does not divide the experts, is refused."""
+    that does not divide the experts, is refused. Where the router
+    chooses by groups (`n_group` > 1: a group is num_experts / n_group
+    experts side by side), a share is whole groups or a group is whole
+    shares, so that no share holds parts of two groups: 64 shares of 8
+    cut each of 8 groups of 64 into eight and are taken, 3 shares of 4
+    over groups of 6 are refused."""
     share, of = expert_share
     if not 0 <= share < of or num_experts % of:
         raise ValueError(
@@ -750,7 +755,33 @@ def held_experts(expert_share, num_experts):
             f"0 <= i < n, and n divides the {num_experts} experts"
         )
     count = num_experts // of
+    group = num_experts // n_group
+    if count % group and group % count:
+        raise ValueError(
+            f"--expert_share {share}/{of}: a share of {count} experts "
+            f"splits the router's {n_group} groups of {group} unevenly "
+            "(a share is whole groups, or a group is whole shares)"
+        )
     return None if of == 1 else (share * count, count)
+
+
+def within_best_groups(choice, n_group, topk_group):
+    """Group-limited selection (DeepSeek-V3's router, arXiv:2412.19437,
+    `n_group` / `topk_group`): `choice` [t, E], what the experts are
+    chosen by (score + bias), with every expert outside its token's
+    `topk_group` best groups at -inf. A group is E / n_group experts
+    side by side and its score the sum of its two largest entries of
+    `choice`; ties go to the first group."""
+    tokens, E = choice.shape
+    grouped = choice.reshape(tokens, n_group, E // n_group)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, best = jax.lax.top_k(group_score, topk_group)  # [t, topk_group]
+    chosen = jnp.any(
+        best[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1
+    )  # [t, n_group]
+    return jnp.where(chosen[:, :, None], grouped, -jnp.inf).reshape(
+        tokens, E
+    )
 
 
 class DroplessMoE(nn.Module):
@@ -766,7 +797,11 @@ class DroplessMoE(nn.Module):
     own logit; with `selection_bias` the k experts are chosen by score +
     bias (a parameter `e_score_correction_bias` [E] that takes no
     gradient: it moves by the load, below) while the gates are the
-    chosen SCORES, bias left out. Either way the gates are then
+    chosen SCORES, bias left out. `n_group` > 1 (models/ling3.py): the
+    k experts are chosen inside the token's `topk_group` best groups
+    alone (`within_best_groups`: a group's score the sum of its two
+    largest score + bias); one group is plain top-k and traces nothing.
+    Either way the gates are then
     multiplied by `routed_scaling`. `shared_width` > 0 adds one expert
     of that width that every token takes, beside the routed sum,
     unscaled, or with `shared_token_gate` (models/qwen3next.py) scaled
@@ -807,6 +842,10 @@ class DroplessMoE(nn.Module):
     # sign(mean load - load) (DeepSeek-V3, arXiv:2412.19437, 2.1.2).
     bias_update_rate: float = 0.0
     routed_scaling: float = 1.0
+    # Group-limited selection: the experts in `n_group` equal groups, a
+    # token's k chosen among its `topk_group` best groups'.
+    n_group: int = 1
+    topk_group: int = 1
     shared_width: int = 0
     shared_token_gate: bool = False
     gated: bool = True
@@ -828,6 +867,16 @@ class DroplessMoE(nn.Module):
             raise ValueError(f"Unknown scoring {self.scoring!r}")
         if self.activation not in _ACTIVATIONS:
             raise ValueError(f"Unknown activation {self.activation!r}")
+        groups = self.n_group
+        if groups > 1 and (
+            E % groups or not 1 <= self.topk_group <= groups
+            or self.topk_group * (E // groups) < K or E // groups < 2
+        ):
+            raise ValueError(
+                f"n_group={groups}, topk_group={self.topk_group}: the "
+                f"{E} experts are cut into equal groups of two or more, "
+                f"and the chosen groups hold at least top_k={K}"
+            )
 
         # f32 at the highest matmul precision: the logits decide WHICH
         # experts run, and a rounded logit picks another expert where
@@ -845,8 +894,17 @@ class DroplessMoE(nn.Module):
                 bias = self.param(
                     "e_score_correction_bias", nn.initializers.zeros, (E,)
                 )
+                choice = probs + bias
+            else:
+                choice = probs
+            if groups > 1:
+                with device_scope("router_groups"):
+                    choice = within_best_groups(
+                        choice, groups, self.topk_group
+                    )
+            if self.selection_bias or groups > 1:
                 # The indices carry no gradient, so the bias has none.
-                _, idx = jax.lax.top_k(probs + bias, K)  # [t, K]
+                _, idx = jax.lax.top_k(choice, K)  # [t, K]
                 gate = jnp.take_along_axis(probs, idx, axis=-1)
             else:
                 gate, idx = jax.lax.top_k(probs, K)  # [t, K]
@@ -975,6 +1033,19 @@ class DroplessMoE(nn.Module):
                 )
                 sow_stat(
                     self, "moe_bias_abs_max", jnp.max(jnp.abs(bias)), "max"
+                )
+            if groups > 1:
+                # The fullest group's part of the batch's assignments
+                # (1 / n_group where even), and the rows an expert held
+                # here computed, on average.
+                by_group = jnp.sum(load.reshape(groups, -1), axis=-1)
+                sow_stat(
+                    self, "router_group_load_max_share",
+                    jnp.max(by_group) / (tokens * K), "max",
+                )
+                sow_stat(
+                    self, "experts_held_rows_mean",
+                    jnp.mean(load[first : first + count]), "max",
                 )
             if self.shared_width:
                 sow_stat(self, "moe_shared_applications", 1.0, "sum")

@@ -97,6 +97,19 @@ product's part below the diagonal, two matmuls with W the one residual
 rematerialised block keeps W and does not solve again (`make_block`).
 T = 1 is a chunk of one step: the recurrence.
 
+The delta rule with a decay for every KEY CHANNEL of a head (Kimi Delta
+Attention) lives in models/ling3.py `kda_scan`: there the decay sits
+inside the key contraction, D cannot be laid over K K^T from outside,
+and a chunk's matrices are built from sub-blocks so that no exponential
+leaves float32. This scalar form is kept as it is and not folded into
+that one: ONE decay a head and step is all this family's row publishes,
+it needs no sub-blocks and no [Q, Dk] factors (a [Q, Q] matrix of
+differences of one cumulative sum), and the cell's compiled update is
+what PRs 47-67 measured. What the two share is imported from here
+(`unit_lower_inverse`, `SOLVED`, `l2_normalise`) and from
+models/nemotron3.py; ops/delta_rule.py's kernels serve both, this one
+under the chunk's last f, that one under `hand_on`.
+
 A chip may hold a share of each layer's routed experts (`--expert_share
 i/n`, as models/mellum2.py); mixers, router and the shared expert are
 whole on every chip. Multi-token prediction is not run: a policy trunk

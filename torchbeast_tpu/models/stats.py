@@ -3,7 +3,8 @@
 A layer calls `sow_stat(self, "moe_assignments", value, "sum")`: the
 name is the key the update's stats carry, and `fold` is how the value
 joins the other layers' under that name: `sum` (counts: assignments,
-applications, bytes a row), `max` (the worst layer: a load's unevenness)
+applications, bytes a row), `max` (the worst layer: a load's unevenness),
+`min` (the strongest of the layers' log-decays, models/ling3.py)
 or `same` (what every layer says alike: the chunks an unroll's scan was
 cut into). `learner.compute_loss` collects `COLLECTIONS` and calls
 `folded`; polybeast makes the gauge `gauge_name` says of every such key.
@@ -17,13 +18,14 @@ import jax.numpy as jnp
 FOLDS = {
     "sum": jnp.add,
     "max": jnp.maximum,
+    "min": jnp.minimum,
     "same": lambda first, again: first,
 }
 COLLECTIONS = tuple("stats_" + fold for fold in FOLDS)
 # A stat is `<family>_<name>`, its gauge `<family>.<name>`.
 FAMILIES = (
     "moe", "ssm", "delta", "conv", "loop", "attention", "shared", "hc",
-    "obs", "mlp",
+    "obs", "mlp", "kda", "router", "experts",
 )
 
 

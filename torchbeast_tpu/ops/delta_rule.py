@@ -35,6 +35,21 @@ scratch. While the states are made the blocks that only the reverse
 walk touches stay on the last chunk's, so nothing is fetched or written
 twice.
 
+**A hand-on a key channel** (`chunk_pass(..., hand_on=)`, PR 68).
+models/ling3.py's Kimi Delta Attention decays every key channel of a
+head by its own factor: the decay sits INSIDE the key contraction, f
+and e become [Q, Dk] and are folded into q and Kl by the caller (they
+come here as ones), and what is left for the pass is the hand-on,
+S_next = Diag(d) S + Kl^T V' with d [Dk] a chunk and value head, a row
+scale where f_Q is one number. Given `hand_on`, the kernels read d as a
+row [per, Dk] a key head, turn it to a column as they turn f and e,
+multiply S (forward, the states made again, and dS = d . dS_next + ...
+backward) by it and return dd = rowsum(dS_next . S) in place of f_Q's
+<dS_next, S>. Without it (models/qwen3next.py: ONE decay a head and
+step, a scalar outside the contraction, which is all its row
+publishes and the cheaper form: a [Q, Q] decay matrix laid over K K^T,
+no sub-blocks) the kernels trace to what they were, op for op.
+
 **Same arithmetic.** Every product is made from float32 tiles cut into
 bfloat16 terms after they are loaded (ops/bf16_terms.py), at the count
 the caller traces under: three passes at the family's `high`, six at
@@ -174,7 +189,9 @@ def _last(row):
 
 def _advance(S, k, e_column, f_last, u, kd, terms, q_terms=None):
     """(V' in terms, q S or None, the state after the chunk) for one
-    value head: S [Dk, Dv] entering, k [Q, Dk], u [Q, Dv], kd [Q, Dk]."""
+    value head: S [Dk, Dv] entering, k [Q, Dk], u [Q, Dv], kd [Q, Dk];
+    `f_last` what the state is handed on under, [1, 1] (one decay a
+    head) or a column [Dk, 1] (one a key channel: `hand_on`)."""
     Q = k.shape[0]
     S_terms = _cut(S, terms)
     if q_terms is None:
@@ -195,7 +212,11 @@ def _advance(S, k, e_column, f_last, u, kd, terms, q_terms=None):
 
 
 def _forward_kernel(q_ref, k_ref, steps_ref, a_ref, u_ref, kd_ref, s0_ref,
-                    o_ref, last_ref, state, *, terms):
+                    *rest, terms):
+    # `rest`: with a hand-on a key channel its rows [heads, per, Dk]
+    # first; then the two results and the scratch.
+    hand_ref = rest[0] if len(rest) == 4 else None
+    o_ref, last_ref, state = rest[-3:]
     heads, per = state.shape[:2]
 
     @pl.when(pl.program_id(2) == 0)
@@ -210,7 +231,9 @@ def _forward_kernel(q_ref, k_ref, steps_ref, a_ref, u_ref, kd_ref, s0_ref,
             f = steps[p : p + 1]
             corrected, read, leaving = _advance(
                 state[h, p], k, _column(steps[per + p : per + p + 1]),
-                _last(f), u_ref[h, p], kd_ref[h, p], terms, q_terms,
+                _last(f) if hand_ref is None
+                else _column(hand_ref[h][p : p + 1]),
+                u_ref[h, p], kd_ref[h, p], terms, q_terms,
             )
             o_ref[h, p] = _column(f) * read + product_of_terms(
                 _cut(a_ref[h, p], terms), corrected, _NN
@@ -223,10 +246,22 @@ def _forward_kernel(q_ref, k_ref, steps_ref, a_ref, u_ref, kd_ref, s0_ref,
 
 
 def _backward_kernel(q_ref, k_ref, steps_ref, a_ref, u_ref, kd_ref, s0_ref,
-                     do_ref, dlast_ref, dq_ref, dk_ref, dsteps_ref, da_ref,
-                     du_ref, dkd_ref, ds0_ref, entering, cotangent, *, terms):
+                     *rest, terms):
+    # `rest`: with a hand-on a key channel its rows first and, last of
+    # the results, their cotangent's.
+    per_channel = len(rest) == 13
+    hand_ref = rest[0] if per_channel else None
+    (do_ref, dlast_ref, dq_ref, dk_ref, dsteps_ref, da_ref, du_ref, dkd_ref,
+     ds0_ref) = rest[per_channel : per_channel + 9]
+    dhand_ref = rest[-3] if per_channel else None
+    entering, cotangent = rest[-2:]
     chunks, heads, per = entering.shape[:3]
     turn = pl.program_id(2)
+
+    def handed_on_under(h, p, steps):
+        if hand_ref is None:
+            return _last(steps[p : p + 1])
+        return _column(hand_ref[h][p : p + 1])
 
     @pl.when(turn == 0)
     def _():
@@ -243,7 +278,7 @@ def _backward_kernel(q_ref, k_ref, steps_ref, a_ref, u_ref, kd_ref, s0_ref,
                 _, _, leaving = _advance(
                     entering[turn, h, p], k,
                     _column(steps[per + p : per + p + 1]),
-                    _last(steps[p : p + 1]), u_ref[h, p], kd_ref[h, p],
+                    handed_on_under(h, p, steps), u_ref[h, p], kd_ref[h, p],
                     terms,
                 )
                 entering[turn + 1, h, p] = leaving
@@ -261,9 +296,11 @@ def _backward_kernel(q_ref, k_ref, steps_ref, a_ref, u_ref, kd_ref, s0_ref,
             steps = steps_ref[h]
             dq = dk = None
             rows = [None] * (2 * per)
+            hand_rows = [None] * per
             for p in range(per):
                 f, e = steps[p : p + 1], steps[per + p : per + p + 1]
-                f_column, e_column, f_last = _column(f), _column(e), _last(f)
+                f_column, e_column = _column(f), _column(e)
+                f_last = handed_on_under(h, p, steps)
                 S, dS_next = entering[chunk, h, p], cotangent[h, p]
                 kd, dO = kd_ref[h, p], do_ref[h, p]
                 S_terms = _cut(S, terms)
@@ -296,17 +333,18 @@ def _backward_kernel(q_ref, k_ref, steps_ref, a_ref, u_ref, kd_ref, s0_ref,
                 d_left = product_of_terms(corrected_terms, dS_terms, _NT)
                 dk_p = e_column * d_left
                 dk = dk_p if dk is None else dk + dk_p
-                lane = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1)
-                rows[p] = _row(
-                    jnp.sum(dO * read, axis=1, keepdims=True)
-                ) + jnp.where(
-                    lane == Q - 1,
-                    jnp.sum(
-                        jnp.sum(dS_next * S, axis=1, keepdims=True),
-                        axis=0, keepdims=True,
-                    ),
-                    0.0,
-                )
+                # What the hand-on's factor is owed: <dS_next, S>, whole
+                # (the chunk's last f) or a key channel.
+                through_hand_on = jnp.sum(dS_next * S, axis=1, keepdims=True)
+                rows[p] = _row(jnp.sum(dO * read, axis=1, keepdims=True))
+                if hand_ref is None:
+                    lane = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1)
+                    rows[p] = rows[p] + jnp.where(
+                        lane == Q - 1,
+                        jnp.sum(through_hand_on, axis=0, keepdims=True), 0.0,
+                    )
+                else:
+                    hand_rows[p] = _row(through_hand_on)
                 rows[per + p] = _row(
                     jnp.sum(d_left * k, axis=1, keepdims=True)
                 )
@@ -316,6 +354,8 @@ def _backward_kernel(q_ref, k_ref, steps_ref, a_ref, u_ref, kd_ref, s0_ref,
             dq_ref[h] = dq
             dk_ref[h] = dk
             dsteps_ref[h] = jnp.concatenate(rows, axis=0)
+            if hand_ref is not None:
+                dhand_ref[h] = jnp.concatenate(hand_rows, axis=0)
 
         _over_heads(heads, head)
 
@@ -357,6 +397,8 @@ def _specs(shapes, heads, chunk_of, late):
         late_k=by_chunk(late, Q, Dk),
         late_steps=by_chunk(late, 2 * per, Q),
         late_kd=by_chunk(late, per, Q, Dk),
+        hand=by_chunk(chunk_of, per, Dk),
+        late_hand=by_chunk(late, per, Dk),
         state=pl.BlockSpec(
             (None, heads, per, Dk, Dv), lambda b, g, t: (b, g, 0, 0, 0)
         ),
@@ -367,12 +409,13 @@ def _specs(shapes, heads, chunk_of, late):
 # three layers, forward, rematerialised and backward, trace and lower a
 # kernel's body once.
 @functools.partial(jax.jit, static_argnames=("terms", "interpret"))
-def _forward(q, k, steps, a, u, kd, s0, *, terms, interpret):
+def _forward(q, k, steps, a, u, kd, s0, hand=None, *, terms, interpret):
     rows, chunks, Hk, Q, Dk = q.shape
     per, Dv = u.shape[3], u.shape[5]
     heads = _heads_a_cell(Hk, per, chunks, Dk, Dv)
     spec = _specs((Q, Dk, Dv, per), heads, lambda t: t, lambda t: t)
     f32 = jnp.float32
+    handed = () if hand is None else (hand,)
     return pl.pallas_call(
         functools.partial(_forward_kernel, terms=terms),
         out_shape=(
@@ -383,17 +426,18 @@ def _forward(q, k, steps, a, u, kd, s0, *, terms, interpret):
         in_specs=[
             spec["q"], spec["k"], spec["steps"], spec["a"], spec["u"],
             spec["kd"], spec["state"],
-        ],
+        ] + [spec["hand"]] * len(handed),
         out_specs=(spec["o"], spec["state"]),
         scratch_shapes=[pltpu.VMEM((heads, per, Dk, Dv), f32)],
         interpret=interpret,
         name="delta_rule_forward",
         **_compiler_params(interpret),
-    )(q, k, steps, a, u, kd, s0)
+    )(q, k, steps, a, u, kd, s0, *handed)
 
 
 @functools.partial(jax.jit, static_argnames=("terms", "interpret"))
-def _backward(q, k, steps, a, u, kd, s0, dO, dlast, *, terms, interpret):
+def _backward(q, k, steps, a, u, kd, s0, dO, dlast, hand=None, *, terms,
+              interpret):
     rows, chunks, Hk, Q, Dk = q.shape
     per, Dv = u.shape[3], u.shape[5]
     heads = _heads_a_cell(Hk, per, chunks, Dk, Dv)
@@ -407,6 +451,7 @@ def _backward(q, k, steps, a, u, kd, s0, dO, dlast, *, terms, interpret):
 
     spec = _specs((Q, Dk, Dv, per), heads, chunk_of, late)
     f32 = jnp.float32
+    handed = () if hand is None else (hand,)
     return pl.pallas_call(
         functools.partial(_backward_kernel, terms=terms),
         out_shape=(
@@ -417,16 +462,16 @@ def _backward(q, k, steps, a, u, kd, s0, dO, dlast, *, terms, interpret):
             jax.ShapeDtypeStruct(u.shape, f32),
             jax.ShapeDtypeStruct(kd.shape, f32),
             jax.ShapeDtypeStruct(s0.shape, f32),
-        ),
+        ) + tuple(jax.ShapeDtypeStruct(x.shape, f32) for x in handed),
         grid=(rows, Hk // heads, 2 * chunks - 1),
         in_specs=[
             spec["q"], spec["k"], spec["steps"], spec["a"], spec["u"],
-            spec["kd"], spec["state"], spec["o"], spec["state"],
-        ],
+            spec["kd"], spec["state"],
+        ] + [spec["hand"]] * len(handed) + [spec["o"], spec["state"]],
         out_specs=(
             spec["q"], spec["late_k"], spec["late_steps"], spec["a"],
             spec["o"], spec["late_kd"], spec["state"],
-        ),
+        ) + (spec["late_hand"],) * len(handed),
         scratch_shapes=[
             pltpu.VMEM((chunks, heads, per, Dk, Dv), f32),
             pltpu.VMEM((heads, per, Dk, Dv), f32),
@@ -434,32 +479,34 @@ def _backward(q, k, steps, a, u, kd, s0, dO, dlast, *, terms, interpret):
         interpret=interpret,
         name="delta_rule_backward",
         **_compiler_params(interpret),
-    )(q, k, steps, a, u, kd, s0, dO, dlast)
+    )(q, k, steps, a, u, kd, s0, *handed, dO, dlast)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _pass(q, k, steps, a, u, kd, s0, terms, interpret):
-    return _pass_fwd(q, k, steps, a, u, kd, s0, terms, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _pass(q, k, steps, a, u, kd, s0, hand, terms, interpret):
+    return _pass_fwd(q, k, steps, a, u, kd, s0, hand, terms, interpret)[0]
 
 
-def _pass_fwd(q, k, steps, a, u, kd, s0, terms, interpret):
-    operands = (q, k, steps, a, u, kd, s0)
+def _pass_fwd(q, k, steps, a, u, kd, s0, hand, terms, interpret):
+    operands = (q, k, steps, a, u, kd, s0, hand)
     return _forward(*operands, terms=terms, interpret=interpret), operands
 
 
 def _pass_bwd(terms, interpret, residuals, cotangents):
     dO, dlast = cotangents
-    return _backward(
-        *residuals, dO.astype(jnp.float32), dlast.astype(jnp.float32),
-        terms=terms, interpret=interpret,
+    grads = _backward(
+        *residuals[:-1], dO.astype(jnp.float32), dlast.astype(jnp.float32),
+        residuals[-1], terms=terms, interpret=interpret,
     )
+    # One a decay a head: no hand-on was given, and none is owed.
+    return tuple(grads) + (None,) * (residuals[-1] is None)
 
 
 _pass.defvjp(_pass_fwd, _pass_bwd)
 
 
 def chunk_pass(q, k, from_start, to_end, weights, values, keys_seen, state,
-               terms):
+               terms, hand_on=None):
     """`delta_scan`'s chunk-to-chunk pass by the kernels (the module's
     header): q, k [B, c, Hk, Q, Dk]; from_start, to_end [B, c, Hk, per,
     Q]; weights [B, c, Hk, per, Q, Q]; values [B, c, Hk, per, Q, Dv];
@@ -468,7 +515,15 @@ def chunk_pass(q, k, from_start, to_end, weights, values, keys_seen, state,
     differentiable in all of them. `terms`: the bfloat16 terms a side of
     every product (`ops/bf16_terms.terms_traced_under()` where the caller
     is traced: the backward kernel is traced after it and makes the
-    same). The shapes must be `kernels_apply`'s."""
+    same). The shapes must be `kernels_apply`'s.
+
+    `hand_on` [B, c, Hk, per, Dk], if given, is what the state that
+    entered a chunk is handed on under, a KEY CHANNEL each (S_next =
+    Diag(hand_on) S + Kl^T V': models/ling3.py `kda_scan`, whose decays
+    sit inside the key contraction, so its f and e are folded into q
+    and k and come here as ones) in place of the chunk's last f, one
+    number a head; differentiable too. None (models/qwen3next.py)
+    traces the kernels as they were."""
     rows, chunks, Hk, Q, Dk = q.shape
     Dv = values.shape[-1]
     if not kernels_apply(chunks * Q, Q, Dk, Dv):
@@ -478,5 +533,5 @@ def chunk_pass(q, k, from_start, to_end, weights, values, keys_seen, state,
         )
     return _pass(
         q, k, jnp.concatenate([from_start, to_end], axis=3), weights, values,
-        keys_seen, state, terms, jax.default_backend() != "tpu",
+        keys_seen, state, hand_on, terms, jax.default_backend() != "tpu",
     )

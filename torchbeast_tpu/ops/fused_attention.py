@@ -672,6 +672,12 @@ def fused_attend(q, k_all, v_all, mask, no_grad_keys=0, terms=1, scale=None):
 
 # Heads a cell, keys a forward / backward cell (PERF.md section 6, PR 41).
 _LATENT_HEADS = 16
+# And query rows (heads x padded steps) a cell, at most: an unroll of
+# 256 steps (models/ling3.py) at 16 heads is 4,096 rows, whose four
+# double-buffered [rows, 512] float32 blocks beside the scores are 99.75
+# MB of the backward cell's 96; at 8 heads they fit. An unroll of 81
+# steps (96 padded: models/kanana2.py, models/xing4.py) keeps its 16.
+_LATENT_ROWS = 2048
 _LATENT_FORWARD_KEYS = 1024
 _LATENT_BACKWARD_KEYS = 1024
 _LATENT_VMEM_LIMIT = 96 * 1024 * 1024
@@ -791,9 +797,11 @@ def _latent_cells(q_latent, q_rope, k_latent, most_keys):
     [B, Tp, Kp] and a row statistic [H, B, Tp, 128]."""
     h, b, tp, latent = q_latent.shape
     rope, num_keys = q_rope.shape[-1], k_latent.shape[1]
-    # The largest divisor of the heads up to `_LATENT_HEADS`.
+    # The largest divisor of the heads up to `_LATENT_HEADS` whose rows
+    # are `_LATENT_ROWS` at most.
     heads = max(
-        n for n in range(1, min(h, _LATENT_HEADS) + 1) if h % n == 0
+        n for n in range(1, min(h, _LATENT_HEADS) + 1)
+        if h % n == 0 and (n == 1 or n * tp <= _LATENT_ROWS)
     )
     block = key_block(num_keys, most_keys)
 
