@@ -92,9 +92,20 @@ def test_delta_rule_kernels_lower_for_v5e(
         _struct(one_chip, (rows, steps), jnp.bool_),
     ).compile().as_text()
     calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*', text)
-    assert len(calls) == 2, len(calls)
-    assert sum("delta_rule_forward" in call for call in calls) == 1
-    assert sum("delta_rule_backward" in call for call in calls) == 1
+    # Where two systems fill a lane tile (chunks of 64, a key head's two
+    # value heads) what a chunk owes before its state enters it is three
+    # cells more (PR 69: W; U, Kd, A; their backward), and no product
+    # of the scan is XLA's.
+    sides = delta_rule.sides_apply(steps, min(chunk, steps), Dk, Dv, per)
+    assert sides is (chunk == 64)
+    assert len(calls) == 2 + 3 * sides, len(calls)
+    for kernel, count in (
+        ("delta_rule_forward", 1), ("delta_rule_backward", 1),
+        ("delta_sides_solve", sides), ("delta_sides_apply", sides),
+        ("delta_sides_backward", sides),
+    ):
+        assert sum(kernel in call for call in calls) == count, kernel
+    assert (" convolution(" in text) is not sides
     chunks = -(-steps // chunk)
     if (Dk, Dv) == (128, 128) and chunks > 1:
         assert not _states_of_every_cell(
@@ -238,15 +249,26 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     # (PR 58: 8 while one copy of a rung stood in the program).
     assert 0 < len(long_rows) <= 16, len(long_rows)
     assert all('/gather"' in line for line in long_rows)
-    # A DeltaNet layer solves once: ten products forward, two backward.
-    solves = [
+    # A DeltaNet layer solves once, in ops/delta_rule.py's cell (PR 69;
+    # ten products forward and two backward at the highest were XLA's,
+    # 36 in the update): no product is left under `delta_solve` or
+    # `delta_intra`, and the only [64, 64] matrices a (row, chunk,
+    # value head) are A and its cotangent, the kernels' own: L, K K^T,
+    # D and the doubling's levels exist in VMEM alone.
+    assert not [
         line for line in text.splitlines()
-        if " convolution(" in line and "/delta_solve/" in line
+        if " convolution(" in line
+        and ("/delta_solve/" in line or "/delta_intra/" in line)
     ]
-    assert len(solves) == 3 * (10 + 2), len(solves)
-    assert all(
-        "operand_precision={highest,highest}" in line for line in solves
-    )
+    levels = [
+        line for line in text.splitlines()
+        if re.search(r"= f32\[%d,4,16,2,64,64\]" % rows, line)
+        and "/delta_scan/" in line
+    ]
+    assert levels and all(
+        " custom-call(" in line or " get-tuple-element(" in line
+        or " bitcast(" in line for line in levels
+    ), levels
     # The experts' products are ONE kernel call each at the family's
     # two terms a side (PR 50: ops/grouped_matmul.py; 144 calls of the
     # shipped kernels before): four MoE parts x (3 forward, 3 the
@@ -262,10 +284,15 @@ def test_qwen3next_cell_update_compiles_for_v5e(one_chip, monkeypatch):
     # layer, again rematerialised, and one backward.
     # Since PR 67 the layer's short convolution likewise
     # (ops/short_conv.py).
-    assert text.count("tpu_custom_call") == 96 + 2 + 9 + 9
+    # Since PR 69 what a chunk owes before its state enters it too: the
+    # solve's cell once a layer (the rematerialised block keeps W), the
+    # apply's again rematerialised, one backward.
+    assert text.count("tpu_custom_call") == 96 + 2 + 9 + 9 + 12
     assert_conv_kernels(text, 3)
     for kernel, count in (
         ("delta_rule_forward", 6), ("delta_rule_backward", 3),
+        ("delta_sides_solve", 3), ("delta_sides_apply", 6),
+        ("delta_sides_backward", 3),
     ):
         assert len(re.findall(
             r'custom_call_target="tpu_custom_call"[^\n]*' + kernel, text

@@ -529,6 +529,11 @@ PARENTS = {
     ("xing4", (1, 8)): "d384c83c0deea8be",
     ("trinity", (0, 8)): "073ef5a2f7a92866",
     ("lfm2", (1, 4)): "b48c7c682ce88d84",
+    # This family's own at commit e7a654d, PR 68's: the parent of the PR
+    # that gave ops/delta_rule.py the cells of W, U, Kd and A, which
+    # Qwen3-Next's toy widths do not take and this family not at all
+    # (PERF.md section 6, PR 69): both as they were, op for op.
+    ("ling3", (0, 8)): "24b5e154d419546c",
 }
 
 

@@ -61,10 +61,18 @@ and including i, else 0:
 
 for the state S that enters the chunk: the recurrence above term for
 term (u_i = v'_i), because `done` at step t zeroes what step t reads of
-the state before it, which is D and e. W, U, Kd and (Q K^T) D are made
-for all chunks at once (`delta_intra`); the last three lines, which
-need S, are the chunk-to-chunk pass (`delta_inter`), in one of two
-forms chosen by the shapes alone (`ops/delta_rule.kernels_apply`):
+the state before it, which is D and e. W, U, Kd and (Q K^T) D need no
+entering state and are made for all chunks at once (`delta_intra`):
+where chunks are 64 steps and a key head has two value heads (`ops/
+delta_rule.sides_apply`: the learner's unroll) by ops/delta_rule.py's
+three cells, two value heads' [64, 64] systems side by side in a lane
+tile, L, K K^T, D and the doubling's levels in VMEM alone and W the one
+array kept (PR 69: XLA's ~30 ops a layer and direction were 22.9 of the
+cell's 264 ms); elsewhere (acting, tier-1's toy widths) by the `jax.
+numpy` lines below, which are also what the cells are held to. The
+last three lines, which need S, are the chunk-to-chunk pass (`delta_
+inter`), in one of two forms chosen by the shapes alone (`ops/
+delta_rule.kernels_apply`):
 
   - an unroll whose chunks are whole sublane tiles at key and value
     widths of whole lane tiles (the learner's [256, B] at the published
@@ -219,7 +227,7 @@ def _block_doubling(L):
 
 # What a rematerialised DeltaNet block keeps of its forward pass: the
 # solve's result, which is all its backward reads (`make_block`).
-SOLVED = "delta_solved"
+SOLVED = delta_rule.SOLVED
 
 
 @jax.custom_vjp
@@ -308,12 +316,13 @@ def delta_scan(q, k, v, g, beta, state, done, chunk):
     Everything in float32. The last chunk is padded with steps of g = 0,
     beta = 0 and k = 0, which pass the state on as it is. A chunk of one
     step (T = 1) is the recurrence. What needs no entering state
-    (`delta_intra`, the solve) is made for all chunks at once; the pass
-    from chunk to chunk is ops/delta_rule.py's kernels where `kernels_
-    apply(steps, Q, Dk, Dv)` holds (the states in VMEM; episode ends are
-    the zeros in `from_start`, `to_end` and `decay` that they multiply
-    by) and `_pass_in_hbm` elsewhere: a function of the shapes, no
-    flag."""
+    (`delta_intra`, the solve) is made for all chunks at once, by
+    ops/delta_rule.py's cells where `sides_apply` holds and by the
+    `jax.numpy` lines elsewhere; the pass from chunk to chunk is
+    ops/delta_rule.py's kernels where `kernels_apply(steps, Q, Dk,
+    Dv)` holds (the states in VMEM; episode ends are the zeros in
+    `from_start`, `to_end` and `decay` that they multiply by) and
+    `_pass_in_hbm` elsewhere: functions of the shapes, no flag."""
     rows, steps, Hk, Dk = q.shape
     Hv, Dv = v.shape[2:]
     per = Hv // Hk
@@ -333,41 +342,54 @@ def delta_scan(q, k, v, g, beta, state, done, chunk):
     def along_heads(mask):  # [B, c, ...] -> [B, c, 1, 1, ...]
         return mask[:, :, None, None]
 
+    terms = terms_traced_under()
+    in_kernels = delta_rule.kernels_apply(steps, Q, Dk, Dv)
+    in_cells = delta_rule.sides_apply(steps, Q, Dk, Dv, per)
+    if in_kernels:
+        heads_first_q = q.transpose(0, 1, 3, 2, 4)  # [B, c, Hk, Q, Dk]
+        heads_first_k = k.transpose(0, 1, 3, 2, 4)
     with device_scope("delta_intra"):
-        decay = jnp.exp(jnp.where(
-            along_heads(reaches(ends)), G[..., :, None] - G[..., None, :],
-            -jnp.inf,
-        ))  # [B, c, Hk, per, Q, Q]: D, its diagonal ones
+        if not in_cells:
+            decay = jnp.exp(jnp.where(
+                along_heads(reaches(ends)),
+                G[..., :, None] - G[..., None, :], -jnp.inf,
+            ))  # [B, c, Hk, per, Q, Q]: D, its diagonal ones
         # What step i still sees of the state that entered the chunk.
         from_start = jnp.where(along_heads(ends == 0), jnp.exp(G), 0.0)
-        between_keys = jnp.einsum("bcihd,bcjhd->bchij", k, k)
-        with device_scope("delta_solve"):
-            solved = unit_lower_inverse(jnp.where(
-                np.tril(np.ones((Q, Q), bool), -1),
-                beta[..., :, None] * between_keys[:, :, :, None] * decay,
-                0.0,
-            ))  # W
-        by_beta = solved * beta[..., None, :]
-        values = jnp.einsum("bchpij,bcjhpv->bchpiv", by_beta, v)  # U
-        keys_seen = jnp.einsum(
-            "bchpij,bcjhd->bchpid", by_beta * from_start[..., None, :], k
-        )  # Kd
-        weights = jnp.einsum(
-            "bcihd,bcjhd->bchij", q, k
-        )[:, :, :, None] * decay
+        if in_cells:
+            # In VMEM too (ops/delta_rule.py's cells): L, K K^T and D
+            # are never arrays, and W is kept side by side.
+            weights, values, keys_seen = delta_rule.sides_before_the_state(
+                heads_first_q, heads_first_k, v, beta, G, ends, terms
+            )
+        else:
+            between_keys = jnp.einsum("bcihd,bcjhd->bchij", k, k)
+            with device_scope("delta_solve"):
+                solved = unit_lower_inverse(jnp.where(
+                    np.tril(np.ones((Q, Q), bool), -1),
+                    beta[..., :, None] * between_keys[:, :, :, None] * decay,
+                    0.0,
+                ))  # W
+            by_beta = solved * beta[..., None, :]
+            values = jnp.einsum("bchpij,bcjhpv->bchpiv", by_beta, v)  # U
+            keys_seen = jnp.einsum(
+                "bchpij,bcjhd->bchpid", by_beta * from_start[..., None, :], k
+            )  # Kd
+            weights = jnp.einsum(
+                "bcihd,bcjhd->bchij", q, k
+            )[:, :, :, None] * decay
     entering = state.reshape(rows, Hk, per, Dk, Dv).astype(jnp.float32)
     with device_scope("delta_states"):
         # What the chunk's end still sees of each of its steps.
         to_end = jnp.exp(jnp.where(
             along_heads(ends[:, :, -1:] == ends), G[..., -1:] - G, -jnp.inf
         ))  # [B, c, Hk, per, Q]
-    if delta_rule.kernels_apply(steps, Q, Dk, Dv):
+    if in_kernels:
         # The state from chunk to chunk in VMEM (ops/delta_rule.py).
         with device_scope("delta_inter"):
             o, last = delta_rule.chunk_pass(
-                q.transpose(0, 1, 3, 2, 4), k.transpose(0, 1, 3, 2, 4),
-                from_start, to_end, weights, values, keys_seen, entering,
-                terms_traced_under(),
+                heads_first_q, heads_first_k, from_start, to_end, weights,
+                values, keys_seen, entering, terms,
             )
     else:
         o, last = _pass_in_hbm(
@@ -564,6 +586,12 @@ class _DeltaNetBlock(nn.Module):
                  "same"),
             ):
                 sow_stat(self, name, value, fold)
+            if delta_rule.sides_apply(steps, Q, Dk, Dv, per):
+                # Those whose W, U, Kd and A are ops/delta_rule.py's
+                # cells' too: nothing a chunk owes is XLA's.
+                sow_stat(
+                    self, "delta_sides_in_kernel_applications", 1.0, "sum"
+                )
             if self.keeps_solved:
                 sow_stat(
                     self, "delta_solved_bytes_kept",
