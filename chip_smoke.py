@@ -1,6 +1,6 @@
 """Does the system start, compile and step on the attached TPU?
 
-    python chip_smoke.py              # one chip: seven phases, in order
+    python chip_smoke.py              # one chip: six phases, in order
     python chip_smoke.py --chips 4    # four chips: the two multi-chip phases only
 
 One process holds the chip: this script initialises the JAX backend and
@@ -27,7 +27,6 @@ arguments so tests/test_chip_smoke.py can run them tiny on the CPU.
 """
 
 import argparse
-import importlib.util
 import json
 import os
 import shutil
@@ -51,13 +50,6 @@ FLAGSHIP_ARGV = ["--env", ENV, "--model", "deep", "--use_lstm"]
 # agrees with the CPU to bf16 rounding accumulated over the net, not to
 # f32 rounding.
 CPU_PARITY_RTOL = {"f32": 2e-2, "bf16_train": 5e-2}
-# Pallas V-trace + fused optimizer tail vs the XLA paths, same params
-# and batch: the loss after ONE update. The two programs fuse — and so
-# reassociate — differently, and on a fixed random batch the first
-# RMSprop steps swing the loss through zero, which amplifies that by
-# 10x per step (measured on the v5e: 8e-4, 2e-3, 6e-3), so later steps
-# are reported and not held to a tolerance.
-PALLAS_VS_XLA_RTOL = 5e-3
 # DP over four chips vs one chip, same params and batch. The loss and
 # the global gradient norm are big sums that only reassociate (the norm
 # is what a wrong all-reduce would move). The parameter UPDATE is held
@@ -122,15 +114,6 @@ def _check(cond, message):
     # Not `assert`: the checks must hold under `python -O` too.
     if not cond:
         raise RuntimeError(message)
-
-
-def _load_pallas_smoke():
-    spec = importlib.util.spec_from_file_location(
-        "pallas_smoke", os.path.join(REPO, "benchmarks", "pallas_smoke.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _final_counters(savedir, xpid, before):
@@ -215,23 +198,6 @@ def phase_native_build(out):
     }
 
 
-def phase_kernels(interpret=False, t=T, b=B, attention_shapes=None):
-    """Every Pallas kernel a driver can select, against its plain
-    reference at the flagship shapes, with `interpret` passed to each
-    kernel explicitly (benchmarks/pallas_smoke.py has the cases)."""
-    smoke = _load_pallas_smoke()
-    kwargs = {}
-    if attention_shapes is not None:
-        kwargs["attention_shapes"] = attention_shapes
-    checked = []
-    for name, case in smoke.flagship_cases(interpret, t=t, b=b, **kwargs):
-        result = case()
-        result["case"] = name
-        _check(result["ok"], f"kernel case failed: {result}")
-        checked.append(result)
-    return {"interpret": interpret, "cases": checked}
-
-
 def build_flagship_learner(argv, t, b, seed=0):
     """The flagship learner the way the drivers build it — monobeast's
     parser, hparams, precision and model plumbing — from extra `argv`.
@@ -279,9 +245,8 @@ def _learner_variant(argv, t, b, steps, seed):
     import jax
 
     from torchbeast_tpu import learner as learner_lib
-    from torchbeast_tpu.utils.backend import describe_backend
 
-    flags, hp, model, params, optimizer, staged = build_flagship_learner(
+    _, hp, model, params, optimizer, staged = build_flagship_learner(
         argv, t, b, seed
     )
     params_host = jax.device_get(params)
@@ -325,7 +290,6 @@ def _learner_variant(argv, t, b, steps, seed):
         "report": {
             "losses": losses,
             "max_param_change": moved,
-            "pallas": describe_backend(flags)["pallas"],
             "memory_analysis": {
                 "temp_bytes": memory.temp_size_in_bytes,
                 "argument_bytes": memory.argument_size_in_bytes,
@@ -347,13 +311,10 @@ def _first_step_loss(run, device, t, b):
     return float(stats["total_loss"])
 
 
-def phase_learner(t=T, b=B, steps=4, ref_t=8, ref_b=4, seed=0,
-                  pallas_mode="compiled"):
-    """learner.make_update_step at the flagship batch: f32, bf16_train,
-    and f32 with the Pallas V-trace + optimizer-tail kernels selected.
-    The XLA variants' first-step loss is compared with the same step on
-    the CPU backend at a batch the CPU can take; the Pallas variant is
-    compared with the f32 variant's loss before and after one update."""
+def phase_learner(t=T, b=B, steps=4, ref_t=8, ref_b=4, seed=0):
+    """learner.make_update_step at the flagship batch, f32 and
+    bf16_train: each variant's first-step loss is compared with the
+    same step on the CPU backend at a batch the CPU can take."""
     import jax
 
     cpu = jax.devices("cpu")[0]
@@ -375,27 +336,6 @@ def phase_learner(t=T, b=B, steps=4, ref_t=8, ref_b=4, seed=0,
             "rtol": CPU_PARITY_RTOL[name],
         }
         checked[name] = run["report"]
-        if name == "f32":
-            xla_losses = run["losses"]
-
-    run = _learner_variant(
-        ["--opt_impl", "pallas", "--vtrace_impl", "pallas"],
-        t, b, steps, seed,
-    )
-    modes = run["report"]["pallas"]
-    _check(
-        modes == {"vtrace": pallas_mode, "opt_tail": pallas_mode},
-        f"Pallas kernels not {pallas_mode}: {modes}",
-    )
-    rel = [
-        abs(p - x) / abs(x) for p, x in zip(run["losses"], xla_losses)
-    ]
-    _check(
-        rel[0] == 0 and rel[1] <= PALLAS_VS_XLA_RTOL,
-        f"Pallas losses {run['losses']} vs XLA {xla_losses}",
-    )
-    run["report"]["vs_xla_rel_diff_per_step"] = rel
-    checked["f32_pallas"] = run["report"]
     return checked
 
 
@@ -732,7 +672,6 @@ def phases_for(chips, out, seed):
         ]
     return [
         device, native,
-        ("kernels", phase_kernels),
         ("learner", lambda: phase_learner(seed=seed)),
         ("mono", lambda: phase_mono(out, seed=seed)),
         ("poly", lambda: phase_poly(out, seed=seed)),

@@ -7,20 +7,21 @@ them at import, and nothing here touches a backend.
 The native runtime is built here too, so that what the suite counts
 does not depend on what an earlier build left in the tree.
 
-The order is set here as well (ROADMAP D22): under `--dist loadfile` a
-file is what a worker takes. xdist hands files out by their number of
-cases, most first, and that rule is KEPT: the driver cuts the run at
-its time limit and counts the cases that finished, so what is still
-running at the cut must be the files with the fewest cases (replayed
-from a whole run's junit file, a run 10% over the limit counts 2,094 of
-2,096 so, and 1,110 with the files longest first). What xdist leaves to
-the order of collection is the ties, and there the one-case whole-cell
-compiles (70-310 s each) sit in the alphabet's order: `pytest_configure`
-turns xdist's own reorder off and `pytest_collection_modifyitems`
-makes the same order with the ties longest first, by
-`tests/file_seconds.json` (the files' sums in a whole run's junit
-file; a file it does not know counts as long). The cases, and their
-order inside a file, are what they were.
+The order is set here as well (ROADMAP D22): under the driver's
+`--dist load` a worker takes CASES, in the order of collection, so a
+file's cases spread over the workers and the collection's order is the
+run's. The files go by their number of cases, most first: the driver
+cuts the run at its time limit and counts the cases that finished, so
+what is still running at the cut must be the files with the fewest
+cases (replayed from a whole run's junit file under `--dist loadfile`,
+whose rule this was, a run 10% over the limit counts 2,094 of 2,096 so,
+and 1,110 with the files longest first). Among files of as many cases
+the one-case whole-cell compiles (70-310 s each) would sit in the
+alphabet's order: `pytest_configure` turns xdist's own reorder off and
+`pytest_collection_modifyitems` makes the order with the ties longest
+first, by `tests/file_seconds.json` (the files' sums in a whole run's
+junit file; a file it does not know counts as long). The cases, and
+their order inside a file, are what they were.
 """
 
 import collections

@@ -961,16 +961,16 @@ class TestFlagParity:
             assert all(f.path == "scripts/s.py" for f in found)
 
     def test_issue13_flags_present_and_drift_caught(self):
-        """The three ISSUE 13 shared flags (--remat, --opt_impl,
+        """Three shared learner flags (--remat, --superstep_k,
         --hbm_budget_gb)."""
         self._assert_declared_once_and_drift_caught({
             "--remat": (
                 '"--remat", default=None',
                 '"--remat", default="all"',
             ),
-            "--opt_impl": (
-                '"--opt_impl", default="xla"',
-                '"--opt_impl", default="pallas"',
+            "--superstep_k": (
+                '"--superstep_k", type=int, default=1',
+                '"--superstep_k", type=int, default=4',
             ),
             "--hbm_budget_gb": (
                 '"--hbm_budget_gb", type=float, default=0.0',

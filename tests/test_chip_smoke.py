@@ -1,7 +1,6 @@
 """chip_smoke.py on the CPU: it must fail without a chip, its last line
 and phase selection are pinned, and every phase function runs here at a
-tiny size with interpreted kernels — called directly, so the script
-needs no rehearsal switch. The phases that compile the deep ResNet+LSTM
+tiny size — called directly, so the script needs no rehearsal switch. The phases that compile the deep ResNet+LSTM
 or build the native extension take tens of seconds each on the CPU and
 are `slow`; the chip run itself is their real test.
 """
@@ -22,7 +21,6 @@ sys.path.insert(0, REPO)
 import chip_smoke  # noqa: E402
 
 TINY = dict(t=3, b=2)
-TINY_ATTENTION = ((2, 12, 4, 16, 8), (1, 1, 4, 16, 8))
 
 
 def test_without_a_chip_the_script_fails_and_says_ok_false():
@@ -87,8 +85,7 @@ def test_phase_selection(tmp_path):
         n for n, _ in chip_smoke.phases_for(chips, str(tmp_path), 0)
     ]
     assert names(1) == [
-        "device", "native_build", "kernels", "learner", "mono", "poly",
-        "anakin",
+        "device", "native_build", "learner", "mono", "poly", "anakin",
     ]
     # Four chips: the multi-chip paths and what they need, nothing else.
     assert names(4) == ["device", "native_build", "dp4", "split"]
@@ -97,38 +94,6 @@ def test_phase_selection(tmp_path):
 def test_device_phase_refuses_the_cpu():
     with pytest.raises(RuntimeError, match="no TPU"):
         chip_smoke.phase_device(1)
-
-
-def test_kernel_cases_cover_every_selectable_kernel():
-    """The case list chip_smoke runs on the chip — every kernel, the
-    flagship's N = (T+1)*B pool rows — with the two cheapest cases run
-    here interpreted; the whole phase is the slow test below."""
-    smoke = chip_smoke._load_pallas_smoke()
-    cases = dict(smoke.flagship_cases(True, **TINY))
-    assert list(cases) == [
-        "vtrace-T3-B2", "opt-f32", "opt-bf16_train",
-        "attn-8x20x4x64x40", "attn-1x1x4x64x40",
-        "pool-8x84x84x16", "pool-8x42x42x32", "pool-8x21x21x32",
-    ]
-    assert [n for n, _ in smoke.flagship_cases(False)][-3:] == [
-        "pool-2592x84x84x16", "pool-2592x42x42x32", "pool-2592x21x21x32",
-    ]
-    for name in ("vtrace-T3-B2", "pool-8x21x21x32"):
-        result = cases[name]()
-        assert result["ok"], result
-
-
-@pytest.mark.slow
-def test_kernels_phase_tiny_interpreted():
-    checked = chip_smoke.phase_kernels(
-        interpret=True, attention_shapes=TINY_ATTENTION, **TINY
-    )
-    kernels = {c["kernel"] for c in checked["cases"]}
-    assert kernels == {
-        "vtrace_targets", "fused_opt_tail", "transformer_attention",
-        "pool_bwd",
-    }
-    assert len(checked["cases"]) == 8 and checked["interpret"] is True
 
 
 def test_anakin_phase_tiny(tmp_path):
@@ -202,13 +167,10 @@ def test_native_build_phase(tmp_path, monkeypatch):
 @pytest.mark.slow
 def test_learner_phase_tiny():
     checked = chip_smoke.phase_learner(
-        steps=3, ref_t=3, ref_b=2, pallas_mode="interpreted", **TINY
+        steps=3, ref_t=3, ref_b=2, **TINY
     )
-    assert set(checked) == {"f32", "bf16_train", "f32_pallas"}
+    assert set(checked) == {"f32", "bf16_train"}
     assert checked["f32"]["cpu_parity"]["rel_diff"] == 0.0
-    assert checked["f32_pallas"]["pallas"] == {
-        "vtrace": "interpreted", "opt_tail": "interpreted",
-    }
 
 
 @pytest.mark.slow

@@ -49,18 +49,6 @@ def test_train_with_lstm(tmp_path):
     assert np.isfinite(stats["total_loss"])
 
 
-def test_train_associative_vtrace(tmp_path):
-    """--vtrace_impl associative (log-depth suffix solve) trains through
-    the same driver path; numerics parity with the sequential scan is
-    pinned in tests/test_vtrace.py."""
-    flags = make_flags(
-        tmp_path, xpid="smoke-assoc", vtrace_impl="associative"
-    )
-    stats = monobeast.train(flags)
-    assert stats["step"] >= 40
-    assert np.isfinite(stats["total_loss"])
-
-
 def test_test_mode(tmp_path):
     flags = make_flags(tmp_path)
     monobeast.train(flags)

@@ -353,7 +353,7 @@ def train(flags):
 
 def main(flags):
     configure_logging()
-    log_backend(log, flags)
+    log_backend(log)
     return train(flags)
 
 

@@ -32,7 +32,6 @@ from torchbeast_tpu.ops import (
     impact_policy_losses,
     vtrace_policy_losses,
 )
-from torchbeast_tpu.ops.pallas_opt import FusedTailState
 
 
 class HParams(NamedTuple):
@@ -56,12 +55,6 @@ class HParams(NamedTuple):
     total_steps: int = 100_000_000
     unroll_length: int = 80
     batch_size: int = 8
-    # V-trace backward recursion: "associative" (lax.associative_scan,
-    # O(log T) depth — the default; 2.56x at T=4000 and within noise at
-    # T=80 in a 2026-07-31 chip record), "sequential" (lax.scan, the
-    # reference formulation), or "pallas" (the fused single-kernel
-    # variant — TPU-compiled, interpreted elsewhere).
-    vtrace_impl: str = "associative"
     # RMSprop second-moment STORAGE dtype: "f32" or "bf16". The EMA is
     # always accumulated in f32 (the precision module's f32-accumulate
     # contract); bf16 halves the optimizer-state bytes each update
@@ -80,14 +73,6 @@ class HParams(NamedTuple):
     # with the torch denominator form): the aggressive optimizer-state
     # compression lever beyond bf16 storage.
     opt_factored: bool = False
-    # Optimizer-tail implementation (--opt_impl): "xla" composes the
-    # optax chain (clip -> torch-RMSprop -> momentum -> LR [-> master
-    # rebase]) and lets XLA fuse it; "pallas" runs the whole tail as
-    # ONE VMEM-resident kernel per leaf chunk (ops/pallas_opt.py —
-    # global-norm finalize, clip, RMSprop/momentum, f32 master write,
-    # bf16 narrowing cast in a single pass; TPU-compiled, interpreted
-    # elsewhere). Identical semantics, pinned by tests/test_pallas_opt.
-    opt_impl: str = "xla"
     # Objective family (--loss): "vtrace" (IMPALA, the default) or
     # "impact" — the clipped target-network surrogate (ops/impact.py)
     # that tolerates 10x the policy lag and unlocks K'-fold sample
@@ -337,12 +322,7 @@ def apply_updates(params, updates, opt_state):
     """optax.apply_updates, resident-aware: when the optimizer is the
     bf16-resident wrapper (its state is a MasterParamsState), `updates`
     IS the new f32 master and the resident params are one narrowing
-    cast per leaf; when it is the fused Pallas tail (FusedTailState),
-    `updates` already IS the new resident params — the kernel performed
-    the master write and the narrowing cast in-pass; otherwise the
-    stock optax apply."""
-    if isinstance(opt_state, FusedTailState):
-        return updates
+    cast per leaf; otherwise the stock optax apply."""
     if isinstance(opt_state, MasterParamsState):
         return jax.tree_util.tree_map(
             lambda nm, p: nm.astype(p.dtype), updates, params
@@ -359,8 +339,7 @@ def _rmsprop_torch(
     trace, then the LR (torch: buf = m*buf + update; param -= lr*buf).
     The installed optax's own rmsprop(eps_in_sqrt=False, momentum=m)
     applies the LR *before* the trace, which is a different optimizer
-    once the LR is scheduled — tests/test_pallas_opt.py's momentum case
-    tells them apart — so the chain is composed here for every
+    once the LR is scheduled, so the chain is composed here for every
     configuration, compact state (`state_dtype`/`factored`) included."""
     if factored:
         parts = [_scale_by_factored_rms_torch(decay, eps)]
@@ -395,39 +374,11 @@ def make_optimizer(hp: HParams) -> optax.GradientTransformation:
             f"param_dtype must be 'f32' or 'bf16', got "
             f"{hp.param_dtype!r}"
         )
-    if hp.opt_impl not in ("xla", "pallas"):
-        raise ValueError(
-            f"opt_impl must be 'xla' or 'pallas', got {hp.opt_impl!r}"
-        )
     schedule = optax.linear_schedule(
         init_value=hp.learning_rate,
         end_value=0.0,
         transition_steps=updates_horizon(hp),
     )
-    if hp.opt_impl == "pallas":
-        if hp.opt_factored:
-            # The factored row/col estimator needs per-leaf reductions
-            # along matrix axes — a different kernel family, and an
-            # approximation besides; the fused tail keeps exact
-            # torch-RMSprop semantics only.
-            raise ValueError(
-                "--opt_impl pallas does not compose with "
-                "--factored_opt_state (the fused tail implements the "
-                "exact elementwise torch-RMSprop only)"
-            )
-        from torchbeast_tpu.ops.pallas_opt import fused_rmsprop_tail
-
-        return fused_rmsprop_tail(
-            schedule,
-            decay=hp.rmsprop_alpha,
-            eps=hp.rmsprop_eps,
-            momentum=hp.rmsprop_momentum,
-            max_norm=hp.grad_norm_clipping,
-            param_dtype=hp.param_dtype,
-            state_dtype=(
-                jnp.bfloat16 if hp.opt_state_dtype == "bf16" else None
-            ),
-        )
     clip = (
         _clip_by_global_norm_f32(hp.grad_norm_clipping)
         if hp.param_dtype == "bf16"
@@ -577,7 +528,6 @@ def compute_loss(
                 values=values,
                 target_net_bootstrap_value=target_net_baseline_full[-1],
                 clip_epsilon=hp.impact_clip,
-                scan_impl=hp.vtrace_impl,
             )
         else:
             pg_loss, baseline_loss = vtrace_policy_losses(
@@ -588,7 +538,6 @@ def compute_loss(
                 rewards=rewards,
                 values=values,
                 bootstrap_value=bootstrap_value,
-                scan_impl=hp.vtrace_impl,
             )
     with telemetry.device_scope("loss_terms"):
         baseline_loss = hp.baseline_cost * baseline_loss
@@ -638,7 +587,7 @@ def add_param_steps(params, steps, opt_state=None):
     the parameter at its path, and how many leaves that was. Such a
     leaf's gradient is zero (nothing differentiable reads it), so the
     optimizer leaves it where it is and this is all that moves it."""
-    if isinstance(opt_state, (MasterParamsState, FusedTailState)):
+    if isinstance(opt_state, MasterParamsState):
         raise NotImplementedError(
             "a model whose parameters move by what it sows (a router's "
             "selection bias) trains with float32 resident parameters: "

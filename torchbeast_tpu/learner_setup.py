@@ -35,7 +35,6 @@ log = logging.getLogger(__name__)
 # declares the field and does not refuse it (`models.takes_flag`), so
 # `{families}` is read from the registry, not typed here.
 _FAMILY_FIELD_REFUSALS = {
-    "attention_impl": "--attention_impl applies to --model {families} only",
     "num_layers": (
         "--num_layers is a positive depth or window of --model {families}"
     ),
@@ -109,15 +108,6 @@ def add_learner_arguments(parser, *, model_default,
                         help="Total environment frames to train for.")
     parser.add_argument("--batch_size", type=int, default=8,
                         help="Learner batch size.")
-    parser.add_argument("--vtrace_impl", default="associative",
-                        choices=["sequential", "associative", "pallas"],
-                        help="V-trace backward recursion: "
-                             "lax.associative_scan (O(log T) depth, the "
-                             "default), lax.scan (the reference's "
-                             "T-dependent-steps formulation), or the "
-                             "fused Pallas kernel (vs + advantages in "
-                             "one VMEM pass; TPU-compiled, interpreted "
-                             "elsewhere).")
     parser.add_argument("--unroll_length", type=int, default=80,
                         help="The unroll length (time dimension).")
     parser.add_argument("--model", default=model_default,
@@ -368,16 +358,6 @@ def add_learner_arguments(parser, *, model_default,
                              "[K, T+1, B] stack + XLA temps). 0 = the "
                              "device's reported limit, else the "
                              "15.75 GiB v5e default.")
-    parser.add_argument("--opt_impl", default="xla",
-                        choices=["xla", "pallas"],
-                        help="Optimizer-tail implementation: 'xla' "
-                             "composes the optax chain; 'pallas' runs "
-                             "grad-clip finalize -> torch-RMSprop/"
-                             "momentum -> f32 master write -> bf16 "
-                             "narrowing cast as ONE VMEM-resident "
-                             "kernel per leaf (ops/pallas_opt.py; "
-                             "TPU-compiled, interpreted elsewhere; "
-                             "identical numerics, pinned by test).")
     parser.add_argument("--superstep_k", type=int, default=1,
                         help="Learner superstep: fuse K SGD updates "
                              "into ONE lax.scan dispatch over a "
@@ -518,11 +498,9 @@ def hparams_from_flags(flags) -> learner_lib.HParams:
         total_steps=flags.total_steps,
         unroll_length=flags.unroll_length,
         batch_size=flags.batch_size,
-        vtrace_impl=getattr(flags, "vtrace_impl", "associative"),
         opt_state_dtype=policy.opt_state_dtype,
         param_dtype=policy.param_dtype,
         opt_factored=getattr(flags, "factored_opt_state", False),
-        opt_impl=getattr(flags, "opt_impl", "xla"),
         loss=getattr(flags, "loss", "vtrace"),
         impact_clip=getattr(flags, "impact_clip", 0.2),
         replay_reuse=max(1, getattr(flags, "replay_reuse", 1) or 1),
@@ -608,10 +586,6 @@ def init_model_and_params(flags, num_actions, batch_size, frame_shape,
     # falls back to bf16-trunk-only anywhere.
     if policy.head_dtype != jnp.float32:
         extra["head_dtype"] = policy.head_dtype
-    attention_impl = getattr(flags, "attention_impl", "dense")
-    if attention_impl != "dense":
-        _check_family_takes(flags.model, "attention_impl")
-        extra["attention_impl"] = attention_impl
     seq_par = getattr(flags, "sequence_parallel", 0)
     if (
         getattr(flags, "ring_schedule", "contiguous") != "contiguous"
@@ -634,15 +608,6 @@ def init_model_and_params(flags, num_actions, batch_size, frame_shape,
             raise ValueError(
                 "--sequence_parallel needs --model transformer (the "
                 "conv+LSTM families have no sequence-sharded formulation)"
-            )
-        if attention_impl != "dense":
-            # In _Block the ring branch wins whenever T divides the seq
-            # axis, so the fused kernel would silently only serve the
-            # T=1 acting path — reject instead of surprising the user.
-            raise ValueError(
-                "--attention_impl pallas and --sequence_parallel are "
-                "mutually exclusive (the ring path replaces the fused "
-                "kernel on the learner forward)"
             )
         ring_schedule = getattr(flags, "ring_schedule", "contiguous")
         sp_strategy = getattr(flags, "sp_strategy", "ring")

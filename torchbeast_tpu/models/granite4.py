@@ -186,7 +186,7 @@ class _AttentionLayer(nn.Module):
 class Granite4Net(TransformerNet):
     # Fields the published table sets, or that the layers do not read:
     # no flag reaches them (models/__init__.py `takes_flag`).
-    flag_refused_fields = ("num_experts", "attention_impl")
+    flag_refused_fields = ("num_experts",)
 
     num_layers: int = PUBLISHED["num_layers"]
     layer_types: Tuple[str, ...] = PUBLISHED["layer_types"]

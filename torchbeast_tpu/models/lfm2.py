@@ -295,7 +295,7 @@ class _AttentionBlock(_Layer):
 class Lfm2Net(TransformerNet):
     # Fields the published table sets, or that the blocks do not read:
     # no flag reaches them (models/__init__.py `takes_flag`).
-    flag_refused_fields = ("num_experts", "attention_impl")
+    flag_refused_fields = ("num_experts",)
 
     num_layers: int = PUBLISHED["num_layers"]
     layer_types: Tuple[str, ...] = PUBLISHED["layer_types"]

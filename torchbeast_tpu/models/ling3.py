@@ -568,7 +568,7 @@ class _FeedForwardBlock(nn.Module):
 class Ling3Net(TransformerNet):
     # Fields the published table sets, or that the blocks do not read:
     # no flag reaches them (models/__init__.py `takes_flag`).
-    flag_refused_fields = ("num_experts", "attention_impl")
+    flag_refused_fields = ("num_experts",)
 
     num_layers: int = PUBLISHED["num_layers"]
     # What `num_layers` is measured against: all of them, or a cut.

@@ -232,7 +232,7 @@ class _Mellum2Block(nn.Module):
 class Mellum2Net(TransformerNet):
     # Fields the published table sets, or that the block does not read:
     # no flag reaches them (models/__init__.py `takes_flag`).
-    flag_refused_fields = ("num_experts", "attention_impl")
+    flag_refused_fields = ("num_experts",)
 
     num_layers: int = PUBLISHED["num_layers"]
     d_model: int = PUBLISHED["d_model"]

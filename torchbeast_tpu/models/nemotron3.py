@@ -622,7 +622,7 @@ def held_mixers(mixer_share, mamba_heads, mamba_groups, num_heads, kv_heads):
 class Nemotron3Net(TransformerNet):
     # Fields the published table sets, or that the blocks do not read:
     # no flag reaches them (models/__init__.py `takes_flag`).
-    flag_refused_fields = ("num_experts", "attention_impl")
+    flag_refused_fields = ("num_experts",)
 
     num_layers: int = PUBLISHED["num_layers"]
     layer_pattern: str = PUBLISHED["layer_pattern"]

@@ -205,7 +205,7 @@ class _OLMoEBlock(nn.Module):
 class OLMoENet(TransformerNet):
     # Fields the published table sets, or that the block does not read:
     # no flag reaches them (models/__init__.py `takes_flag`).
-    flag_refused_fields = ("num_experts", "attention_impl")
+    flag_refused_fields = ("num_experts",)
     # `make_block` below does not read `remat`: `--remat` has no lever.
     remat_lever = None
 

@@ -481,7 +481,7 @@ class _MemoryBlock(_Layer):
 class Phi4FlashNet(TransformerNet):
     # Fields the published table sets, or that the blocks do not read:
     # no flag reaches them (models/__init__.py `takes_flag`).
-    flag_refused_fields = ("num_experts", "attention_impl")
+    flag_refused_fields = ("num_experts",)
 
     num_layers: int = PUBLISHED["num_layers"]
     d_model: int = PUBLISHED["d_model"]

@@ -683,7 +683,7 @@ class _GatedAttentionBlock(nn.Module):
 class Qwen3NextNet(TransformerNet):
     # Fields the published table sets, or that the blocks do not read:
     # no flag reaches them (models/__init__.py `takes_flag`).
-    flag_refused_fields = ("num_experts", "attention_impl")
+    flag_refused_fields = ("num_experts",)
 
     num_layers: int = PUBLISHED["num_layers"]
     attention_interval: int = PUBLISHED["attention_interval"]

@@ -286,7 +286,6 @@ class _Block(nn.Module):
     mesh: Any = None  # set -> ring attention over mesh axis `seq_axis`
     seq_axis: str = "seq"
     ring_schedule: str = "contiguous"  # or "zigzag" (balanced causal work)
-    attention_impl: str = "dense"  # or "pallas": fused single-chip kernel
     sp_strategy: str = "ring"  # or "ulysses": all-to-all head sharding
     batch_axis: Any = None  # composite mesh: batch dim's data axis name
     num_experts: int = 0  # >0 -> MoE FFN (models/moe.py)
@@ -294,18 +293,15 @@ class _Block(nn.Module):
     moe_mesh: Any = None  # mesh with an `expert` axis -> expert parallel
 
     @nn.compact
-    def __call__(self, x, cache_state, cache_mask, seq_mask, seg=None,
-                 cache_valid=None, no_done=None):
+    def __call__(self, x, cache_state, cache_mask, seq_mask, seg=None):
         """The block contract of `TransformerNet`'s walk. x: [B, T, d];
         cache_state: (k, v), the layer's cache AS THE STATE HOLDS IT,
         [M, B, H, hd]; cache_mask [B, T, M] and seq_mask [B, T, T]
         (True = may attend): the masks of the two legs, the cache and
         the unroll, apart. seg [B, T] feeds the ring path (which
         rebuilds the in-unroll band/segment mask per block instead of
-        materializing [T, T]); cache_valid [B, M] and no_done [B, T]
-        feed the fused pallas kernel (which rebuilds the whole mask
-        in-kernel). Returns (y, new_k, new_v) where new_k/new_v are
-        this unroll's [B, T, H, hd].
+        materializing [T, T]). Returns (y, new_k, new_v) where
+        new_k/new_v are this unroll's [B, T, H, hd].
 
         A family's block takes of these what it reads. The OLMoE and
         Ouro blocks read the state and the two masks as they come
@@ -389,23 +385,6 @@ class _Block(nn.Module):
                 schedule=self.ring_schedule,
                 batch_axis=self.batch_axis,
             ).astype(v.dtype)
-        elif self.attention_impl == "pallas":
-            from torchbeast_tpu.ops.pallas_attention import (
-                attention_interpret_default,
-                transformer_attention,
-            )
-
-            k_all = jnp.concatenate([cache[0].astype(k.dtype), k], axis=1)
-            v_all = jnp.concatenate([cache[1].astype(v.dtype), v], axis=1)
-            attended = transformer_attention(
-                self.memory_len,
-                attention_interpret_default(),
-                q, k_all, v_all,
-                seg.astype(jnp.int32),
-                cache_valid.astype(jnp.float32),
-                no_done,
-                rel_bias,
-            ).astype(v.dtype)
         else:
             k_all = jnp.concatenate([cache[0].astype(k.dtype), k], axis=1)
             v_all = jnp.concatenate([cache[1].astype(v.dtype), v], axis=1)
@@ -461,7 +440,6 @@ class TransformerNet(nn.Module):
     mesh: Optional[Any] = None  # sequence-parallel training mesh
     seq_axis: str = "seq"
     ring_schedule: str = "contiguous"  # "contiguous" | "zigzag"
-    attention_impl: str = "dense"  # "dense" | "pallas" (fused kernel)
     sp_strategy: str = "ring"  # "ring" | "ulysses" (all-to-all heads)
     batch_axis: Optional[str] = None  # composite (data x seq) mesh: the
     # name of the axis the batch dim shards over (usually "data")
@@ -631,8 +609,7 @@ class TransformerNet(nn.Module):
                     # buffer for its backward pass, not a copy.
                     x, k_new, v_new = blocks[layer](
                         x, (k_cache, v_cache), cache_mask, seq_mask,
-                        seg=seg, cache_valid=valid_b, no_done=no_done_yet,
-                        **received,
+                        seg=seg, **received,
                     )
                     for name in gives:
                         # What the layer attended over: its cache
@@ -685,7 +662,6 @@ class TransformerNet(nn.Module):
             memory_len=self.memory_len, dtype=self.dtype,
             mesh=self.mesh, seq_axis=self.seq_axis,
             ring_schedule=self.ring_schedule,
-            attention_impl=self.attention_impl,
             sp_strategy=self.sp_strategy,
             batch_axis=self.batch_axis,
             num_experts=self.num_experts,

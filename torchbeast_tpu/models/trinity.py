@@ -240,7 +240,7 @@ class _TrinityBlock(nn.Module):
 class TrinityNet(TransformerNet):
     # Fields the published table sets, or that the block does not read:
     # no flag reaches them (models/__init__.py `takes_flag`).
-    flag_refused_fields = ("num_experts", "attention_impl")
+    flag_refused_fields = ("num_experts",)
 
     num_layers: int = PUBLISHED["num_layers"]
     published_layers: int = PUBLISHED["published_layers"]

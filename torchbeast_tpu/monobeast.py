@@ -62,12 +62,6 @@ def make_parser():
     # The sync trainer's own.
     parser.add_argument("--serial_envs", action="store_true",
                         help="Step envs in-process (tests/cheap envs).")
-    parser.add_argument("--attention_impl", default="dense",
-                        choices=["dense", "pallas"],
-                        help="Transformer attention implementation: XLA "
-                             "dense ops, or the fused Pallas kernel "
-                             "(single-chip; compiled on TPU, interpreted "
-                             "elsewhere).")
     parser.add_argument("--pipeline_stages", type=int, default=0,
                         help="Total tower depth (pipelined_mlp stages / "
                              "pipelined_transformer layers). Default: "
@@ -167,13 +161,6 @@ def train(flags):
                 f"batch_size {flags.batch_size} not divisible by "
                 f"num_learner_devices {n_dev}"
             )
-        if getattr(flags, "opt_impl", "xla") == "pallas":
-            raise ValueError(
-                "--opt_impl pallas does not compose with "
-                "--num_learner_devices > 1 yet (the fused tail is a "
-                "per-chip kernel; its sharded-update story is the "
-                "Sebulba item's)"
-            )
     # Sebulba device split (ISSUE 15, runtime/placement.py): resolved
     # and composition-checked before any side effects. None covers the
     # single-device degradation.
@@ -190,11 +177,6 @@ def train(flags):
         parallel_flags=("sequence_parallel", "expert_parallel",
                         "pipeline_parallel"),
     )
-    if split is not None and getattr(flags, "opt_impl", "xla") == "pallas":
-        raise ValueError(
-            "--opt_impl pallas does not compose with --device_split "
-            "yet (the fused tail is a per-chip kernel)"
-        )
     if flags.xpid is None:
         flags.xpid = "torchbeast-tpu-%s" % time.strftime("%Y%m%d-%H%M%S")
     plogger = FileWriter(
@@ -793,7 +775,7 @@ def test(flags):
 
 def main(flags):
     configure_logging()
-    log_backend(log, flags)
+    log_backend(log)
     if flags.mode == "train":
         return train(flags)
     return test(flags)

@@ -15,8 +15,8 @@ Three policies meet in this loss:
 
 The V-trace correction runs between mu and pi_target (both
 constants w.r.t. theta, so the whole scan is gradient-free and the
-fused machinery in ops/vtrace.py — sequential / associative / pallas —
-is reused as-is), producing corrected value targets `vs` and clipped
+fused machinery in ops/vtrace.py — sequential / associative — is
+reused as-is), producing corrected value targets `vs` and clipped
 advantages from the TARGET network's values. The policy gradient then
 flows through a PPO-style clipped surrogate on the pi_theta/pi_target
 ratio:
@@ -66,9 +66,8 @@ def impact_policy_losses(
     (pg_loss, baseline_loss), both sum-reduced scalars.
 
     Mirrors `vtrace_policy_losses`' layout: [T, B(, A)] inputs, the
-    same scan_impl passthrough (the pallas variant fuses the backward
-    solve + advantage epilogue into one kernel), and `baseline_loss`
-    returned WITHOUT the driver's cost coefficient. Gradients flow
+    same scan_impl passthrough, and `baseline_loss` returned WITHOUT
+    the driver's cost coefficient. Gradients flow
     only through `learner_policy_logits` (the clipped surrogate) and
     `values` (the baseline regression against the corrected targets);
     everything derived from mu / the target network is a constant.
@@ -121,24 +120,13 @@ def impact_policy_losses(
         if clip_pg_rho_threshold is not None else rhos
     )
 
-    if scan_impl == "pallas":
-        from torchbeast_tpu.ops import pallas_vtrace
-
-        vs, pg_advantages = pallas_vtrace.vtrace_targets(
-            discounts * cs, deltas, clipped_pg_rhos, rewards, discounts,
-            target_values, bootstrap_value,
-            interpret=vtrace_lib._pallas_interpret(),
-        )
-    else:
-        vs = vtrace_lib._vs_minus_v(
-            deltas, discounts, cs, bootstrap_value, scan_impl
-        ) + target_values
-        vs_t_plus_1 = jnp.concatenate(
-            [vs[1:], bootstrap_value[None]], axis=0
-        )
-        pg_advantages = clipped_pg_rhos * (
-            rewards + discounts * vs_t_plus_1 - target_values
-        )
+    vs = vtrace_lib._vs_minus_v(
+        deltas, discounts, cs, bootstrap_value, scan_impl
+    ) + target_values
+    vs_t_plus_1 = jnp.concatenate([vs[1:], bootstrap_value[None]], axis=0)
+    pg_advantages = clipped_pg_rhos * (
+        rewards + discounts * vs_t_plus_1 - target_values
+    )
 
     vs = lax.stop_gradient(vs)
     pg_advantages = lax.stop_gradient(pg_advantages)

@@ -61,9 +61,7 @@ def vtrace_policy_losses(
     VTraceFromLogitsReturns is never built, and the advantages are
     consumed by their sum-reductions in place instead of surviving the
     target computation as named arrays — nothing here can escape to HBM
-    between the scan and the losses. With scan_impl="pallas" the solve
-    and the advantage epilogue run as ONE kernel
-    (ops/pallas_vtrace.py).
+    between the scan and the losses.
 
     `baseline_loss` comes back WITHOUT the driver's cost coefficient
     (same contract as compute_baseline_loss). Everything accumulates in
@@ -104,24 +102,13 @@ def vtrace_policy_losses(
         if clip_pg_rho_threshold is not None else rhos
     )
 
-    if scan_impl == "pallas":
-        from torchbeast_tpu.ops import pallas_vtrace
-
-        vs, pg_advantages = pallas_vtrace.vtrace_targets(
-            discounts * cs, deltas, clipped_pg_rhos, rewards, discounts,
-            values_sg, bootstrap_value,
-            interpret=vtrace_lib._pallas_interpret(),
-        )
-    else:
-        vs = vtrace_lib._vs_minus_v(
-            deltas, discounts, cs, bootstrap_value, scan_impl
-        ) + values_sg
-        vs_t_plus_1 = jnp.concatenate(
-            [vs[1:], bootstrap_value[None]], axis=0
-        )
-        pg_advantages = clipped_pg_rhos * (
-            rewards + discounts * vs_t_plus_1 - values_sg
-        )
+    vs = vtrace_lib._vs_minus_v(
+        deltas, discounts, cs, bootstrap_value, scan_impl
+    ) + values_sg
+    vs_t_plus_1 = jnp.concatenate([vs[1:], bootstrap_value[None]], axis=0)
+    pg_advantages = clipped_pg_rhos * (
+        rewards + discounts * vs_t_plus_1 - values_sg
+    )
 
     vs = lax.stop_gradient(vs)
     pg_advantages = lax.stop_gradient(pg_advantages)

@@ -19,19 +19,15 @@ by far the leanest in HBM.
   operands are ALL kh*kw input-sized padded tensors, inflating peak
   memory by ~18 input-sizes (observed pushing the T=80 B=32 learner step
   to 22 GB on TPU before the platform split existed).
-- **everything else (TPU/GPU)**: the native reduce_window autodiff —
-  unless TBT_POOL_PALLAS=1, which switches the supported 3x3/stride-2
-  configuration to the fused Pallas backward kernel (ops/pallas_pool.py).
-  Off by default until its win is confirmed on the target chip.
+- **everything else (TPU/GPU)**: the native reduce_window autodiff.
 
-Tie semantics (CPU and Pallas paths): where several inputs in one window
-tie at the max, the cotangent is credited to EVERY tying position (a
+Tie semantics (CPU path): where several inputs in one window tie at the
+max, the cotangent is credited to EVERY tying position (a
 valid subgradient); SelectAndScatter credits only the first in scan
 order. Ties are measure-zero for conv outputs, so training is unaffected.
 """
 
 import functools
-import os
 from typing import Tuple
 
 import jax
@@ -102,22 +98,6 @@ def _bwd(window, strides, padding, residuals, g):
 _max_pool2d_tapsum.defvjp(_fwd, _bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def _max_pool2d_pallas(x, window: Pair, strides: Pair,
-                       padding: Tuple[Pair, Pair]):
-    return _reduce_max(x, window, strides, padding)
-
-
-def _pallas_bwd(window, strides, padding, residuals, g):
-    from torchbeast_tpu.ops.pallas_pool import pool_bwd
-
-    x, y = residuals
-    return (pool_bwd(x, y, g),)
-
-
-_max_pool2d_pallas.defvjp(_fwd, _pallas_bwd)
-
-
 def max_pool2d(x, window: Pair = (3, 3), strides: Pair = (2, 2),
                padding: Tuple[Pair, Pair] = ((1, 1), (1, 1))):
     """NHWC max pooling, forward-identical to flax.linen.max_pool.
@@ -127,12 +107,4 @@ def max_pool2d(x, window: Pair = (3, 3), strides: Pair = (2, 2),
     """
     if jax.default_backend() == "cpu":
         return _max_pool2d_tapsum(x, window, strides, padding)
-    if (
-        os.environ.get("TBT_POOL_PALLAS") == "1"
-        and jax.default_backend() == "tpu"  # Mosaic-geometry kernel
-    ):
-        from torchbeast_tpu.ops import pallas_pool
-
-        if pallas_pool.supports(x, window, strides, padding):
-            return _max_pool2d_pallas(x, window, strides, padding)
     return _reduce_max(x, window, strides, padding)

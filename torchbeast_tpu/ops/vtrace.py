@@ -9,15 +9,13 @@ is a first-order linear recurrence, so it runs by default as a
 O(log T) depth, 2.56x over the sequential scan at T=4000 and within
 noise at T=80 (2026-07-31 chip record, deleted in PR 21) — fused into
 the learner's XLA program. The reference's sequential `lax.scan`
-formulation stays available (`scan_impl="sequential"`), and a fused
-Pallas kernel variant (`"pallas"`, ops/pallas_vtrace.py) computes vs
-AND the pg advantages in one VMEM-resident pass (TPU-compiled,
-interpreted elsewhere).
+formulation stays available (`scan_impl="sequential"`): the oracle the
+tests call directly.
 
 Numerics contract: V-trace is part of the f32-accumulate surface
 (torchbeast_tpu/precision.py) — inputs are upcast to float32 on entry
 whatever the batch's storage dtype, so a bf16_train run solves the
-recurrence at full precision. The three impls agree to float-
+recurrence at full precision. The two impls agree to float-
 reassociation tolerance (pinned by the tests/test_vtrace.py parity
 matrix). Behavioral parity with the reference
 (/root/reference/torchbeast/core/vtrace.py:50-139): same clipping rules
@@ -45,7 +43,7 @@ VTraceFromLogitsReturns = collections.namedtuple(
 
 VTraceReturns = collections.namedtuple("VTraceReturns", "vs pg_advantages")
 
-SCAN_IMPLS = ("sequential", "associative", "pallas")
+SCAN_IMPLS = ("sequential", "associative")
 
 
 def action_log_probs(policy_logits, actions):
@@ -69,8 +67,7 @@ def _f32(*arrays):
 def _vs_minus_v(deltas, discounts, cs, bootstrap_value, scan_impl):
     """Solve the backward recurrence; returns acc ([T, ...]) with
     vs = acc + values. The shared core of the unfused targets and the
-    fused loss path (pallas solves the FUSED form elsewhere — this
-    helper never sees scan_impl='pallas')."""
+    fused loss path."""
     if scan_impl == "sequential":
 
         def scan_fn(acc, xs):
@@ -106,22 +103,6 @@ def _check_impl(scan_impl):
         raise ValueError(
             f"scan_impl {scan_impl!r} must be one of {SCAN_IMPLS}"
         )
-
-
-def _pallas_interpret() -> bool:
-    """The kernel compiles via Mosaic on TPU and runs the Pallas
-    interpreter everywhere else (numerically identical; how CPU CI
-    exercises the fused path). TORCHBEAST_VTRACE_PALLAS_COMPILE=1
-    forces the compiled form regardless of backend — for CROSS-lowering
-    (jax.export / .lower(lowering_platforms=("tpu",)) on a chipless
-    host), where the interpreter would otherwise be inlined into the
-    lowered module (learner_bench's bytes accounting, the Mosaic
-    lowering pin)."""
-    import os
-
-    if os.environ.get("TORCHBEAST_VTRACE_PALLAS_COMPILE"):
-        return False
-    return jax.default_backend() != "tpu"
 
 
 def from_logits(
@@ -186,9 +167,6 @@ def from_importance_weights(
       tests/test_vtrace.py).
     - "sequential": `lax.scan(reverse=True)` — T dependent steps, the
       reference formulation.
-    - "pallas": the fused single-kernel variant (ops/pallas_vtrace.py)
-      computing vs and the advantages in one VMEM-resident pass;
-      Mosaic-compiled on TPU, interpreted elsewhere.
     """
     _check_impl(scan_impl)
     log_rhos, discounts, rewards, values, bootstrap_value = _f32(
@@ -211,18 +189,6 @@ def from_importance_weights(
         clipped_pg_rhos = jnp.minimum(rhos, clip_pg_rho_threshold)
     else:
         clipped_pg_rhos = rhos
-
-    if scan_impl == "pallas":
-        from torchbeast_tpu.ops import pallas_vtrace
-
-        vs, pg_advantages = pallas_vtrace.vtrace_targets(
-            discounts * cs, deltas, clipped_pg_rhos, rewards, discounts,
-            values, bootstrap_value, interpret=_pallas_interpret(),
-        )
-        return VTraceReturns(
-            vs=lax.stop_gradient(vs),
-            pg_advantages=lax.stop_gradient(pg_advantages),
-        )
 
     vs = _vs_minus_v(deltas, discounts, cs, bootstrap_value,
                      scan_impl) + values

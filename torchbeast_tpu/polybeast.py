@@ -333,7 +333,7 @@ def train(flags):
         # flag or TORCHBEAST_COORDINATOR env.
         initialize_distributed(flags.coordinator_address)
     # After the rendezvous: asking for devices initialises the backend.
-    log_backend(log, flags)
+    log_backend(log)
     proc_count = jax.process_count()
     proc_id = jax.process_index()
     # ONE host identity for every host-scoped convention below (xpid
@@ -667,16 +667,6 @@ def train(flags):
             # global mesh would report.
             mesh_shape["data"] *= n_hosts
         tele.set_static("learner.mesh_shape", mesh_shape)
-        if (
-            getattr(flags, "opt_impl", "xla") == "pallas"
-            and learner_mesh is not None
-        ):
-            raise ValueError(
-                "--opt_impl pallas does not compose with the sharded "
-                "learner meshes yet (the fused tail is a per-chip "
-                "kernel; its sharded-update story is the Sebulba "
-                "item's)"
-            )
         optimizer = learner_lib.make_optimizer(hp)
         opt_state = optimizer.init(params)
 
